@@ -4,7 +4,11 @@
 #   docker run ... dlt-coordinator --metrics-port 9100
 #   docker run ... dlt-host --host <coordinator> --port 65432
 # On TPU VMs, base on a TPU-enabled JAX image instead and the same
-# entry points apply (jax[tpu] resolves the libtpu runtime).
+# entry points apply (jax[tpu] resolves the libtpu runtime).  There, set
+# JAX_COMPILATION_CACHE_DIR to a directory on a volume that outlives the
+# container: it is the one way to place the XLA compilation cache (unset,
+# a TPU run caches inside the installed tree, which dies with the
+# container), and a restarted server then skips its first compiles.
 FROM python:3.12-slim
 
 # g++ enables the native IO tier (distributed_llms_tpu/native); the package

@@ -19,6 +19,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
+import os
 import sys
 
 from ..checkpoint import convert, store
@@ -27,6 +28,7 @@ from ..cluster.coordinator import Coordinator
 from ..cluster.worker import WorkerHost
 from ..core.config import Config, load_config
 from ..core.observability import METRICS, get_logger
+from . import init_backend
 
 log = get_logger("cli")
 
@@ -38,8 +40,6 @@ async def _ainput(prompt: str) -> str:
 def init_store(model_id: str, num_shards: int, cfg: Config) -> str:
     """Fetch checkpoint, convert to param tree, write the shard store."""
     local = fetch_model(model_id, cache_dir=cfg.checkpoint.cache_dir)
-    import os
-
     with open(os.path.join(local, "config.json")) as f:
         model_cfg = convert.config_from_hf(json.load(f))
     params = convert.convert_state_dict(convert.load_state_dict(local), model_cfg)
@@ -223,10 +223,24 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--platform", default=None, choices=["cpu", "tpu"],
                     help="force a JAX platform (e.g. cpu for a CPU-only host)")
     args = ap.parse_args(argv)
+    if args.local_proc and (
+        args.platform or os.environ.get("JAX_PLATFORMS")
+    ) != "cpu":
+        # Every worker process initialises JAX, and so does this one (a
+        # checkpoint conversion, in-process workers).  An accelerator
+        # belongs to one process at a time: the second to ask hangs or
+        # fails, so refuse here instead.
+        raise SystemExit(
+            "--local-proc starts worker processes that each initialise "
+            "JAX, and a chip belongs to one process at a time: run one "
+            "dlt-host per chip, use --local N for in-process workers, or "
+            "pin the simulation to the CPU with --platform cpu"
+        )
     if args.platform:
         import jax
 
         jax.config.update("jax_platforms", args.platform)
+    init_backend()
     try:
         asyncio.run(amain(args))
     except KeyboardInterrupt:

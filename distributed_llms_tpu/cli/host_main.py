@@ -11,11 +11,13 @@ import sys
 from ..cluster.distributed import initialize_distributed
 from ..cluster.worker import WorkerHost
 from ..core.config import load_config
+from . import init_backend
 
 
 async def amain(args: argparse.Namespace) -> None:
     cfg = load_config(args.config, args.override)
     initialize_distributed(cfg.cluster)
+    init_backend()
     # CLI flags win when given; otherwise the config file decides.
     host = args.host if args.host is not None else cfg.cluster.coordinator_host
     port = args.port if args.port is not None else cfg.cluster.coordinator_port

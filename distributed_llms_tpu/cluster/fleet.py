@@ -494,6 +494,9 @@ class ReplicaFleet:
                     "committed_tokens": h.committed_tokens,
                     "inflight": len(h.inflight),
                     "restarts": h.restarts,
+                    # The devices holding this replica's weights: N
+                    # replicas on one device are not N chips.
+                    "device": getattr(h.server, "device", None),
                 }
                 for h in self.replicas
             },

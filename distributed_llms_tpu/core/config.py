@@ -224,10 +224,6 @@ class RuntimeConfig:
     remat: bool = False  # jax.checkpoint on decoder blocks
     seed: int = 0
     profile_dir: str | None = None  # capture jax.profiler traces of generate
-    # Persistent XLA compilation cache: a serving process restarted on the
-    # same model skips the first-compile wait (~20-40 s on TPU for a 7B
-    # decode graph).  Enabled once per process, before the first jit.
-    compilation_cache_dir: str | None = None
     # Paged KV cache for continuous batching (runtime/batcher.py): rows
     # allocate pages from a shared pool instead of owning max_seq_len slots;
     # a dry pool back-pressures admission.  None = contiguous per-slot KV.

@@ -450,6 +450,15 @@ METRIC_DOCS: dict[str, str] = {
                                "(graceful-only)",
     "autoscale.*.scale_failures": "tier scale actions that failed or "
                                   "were vetoed — the tier kept its size",
+    # -- kernel dispatch (ops/dispatch.py) --
+    "ops.dispatch.*.*": "trace-time dispatches of a Pallas op (quant_matmul, "
+                        "paged_decode, ragged_decode, flash) by the path "
+                        "taken: kernel (compiled), interpret (Pallas "
+                        "interpreter) or fallback (dense jax.numpy)",
+    "ops.dispatch.*.shard_map": "of those, dispatches traced inside the "
+                                "per-shard shard_map body of a "
+                                "tensor-parallel mesh (the kernel then "
+                                "runs on every shard)",
     # -- fault injection (runtime/faults.py) --
     "faults.fired": "injected faults triggered, total",
     "faults.fired.*": "injected faults triggered, by action",

@@ -10,7 +10,8 @@ plan.md:297-300).  Here:
 - :class:`StepTimer` measures wall-per-step and derived throughput into the
   global METRICS registry (tokens/s, p50/p95 step time — the BASELINE.md
   north-star metrics);
-- :func:`record_memory_stats` snapshots per-device HBM occupancy gauges.
+- :func:`record_memory_stats` snapshots per-device HBM occupancy gauges;
+- :func:`device_report` names the backend a process actually runs on.
 """
 
 from __future__ import annotations
@@ -104,4 +105,22 @@ def record_memory_stats(prefix: str = "device") -> dict[str, float]:
                 # graftlint: ignore[GL302](gauge names are per-device — "<prefix><i>.bytes_in_use" — an open-ended family no registry entry can enumerate)
                 METRICS.set_gauge(name, float(stats[key]))
                 out[name] = float(stats[key])
+    return out
+
+
+def device_report(params=None) -> dict:
+    """The devices JAX reports (``platform``, ``device_kind``, ``count``)
+    and, given a param tree, ``weights_on``: the ids of the devices that
+    hold it.  With JAX_PLATFORMS unset a failed TPU init is a silent CPU
+    run, so /healthz, the boot log and every benchmark row carry this."""
+    devs = jax.devices()
+    out = {
+        "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind,
+        "count": len(devs),
+    }
+    if params is not None:
+        out["weights_on"] = sorted({
+            d.id for leaf in jax.tree.leaves(params) for d in leaf.devices()
+        })
     return out

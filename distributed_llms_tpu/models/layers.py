@@ -171,13 +171,16 @@ def _is_quantized(w: Any) -> bool:
     return hasattr(w, "bits") and hasattr(w, "scale") and hasattr(w, "data")
 
 
-def _contract(x: jax.Array, w: Any, eq: str, k_lead: int) -> jax.Array:
+def _contract(x: jax.Array, w: Any, eq: str, k_lead: int, shard: str) -> jax.Array:
     """einsum for plain weights; fused dequant-matmul (ops/quant_matmul) for
-    QuantizedTensor weights under weight-only quantized serving."""
+    QuantizedTensor weights under weight-only quantized serving.  ``shard``
+    is the weight's tensor-parallel role, "n" (output axis split over
+    'model') or "k" (contracted axis split), which the kernel needs to run
+    per shard; XLA partitions the plain einsum by itself."""
     if _is_quantized(w):
         from ..ops.quant_matmul import quant_contract
 
-        return quant_contract(x, w, k_lead, eq)
+        return quant_contract(x, w, k_lead, eq, shard=shard)
     return jnp.einsum(eq, x, w)
 
 
@@ -196,9 +199,9 @@ def qkv_project(x: jax.Array, p: Params, cfg: ModelConfig) -> tuple[jax.Array, j
     Weight layout: wq [D, H, hd], wk/wv [D, KVH, hd] — head axis explicit so
     tensor-parallel sharding annotates the head dim directly.
     """
-    q = _contract(x, p["wq"], "btd,dhk->bthk", 1)
-    k = _contract(x, p["wk"], "btd,dhk->bthk", 1)
-    v = _contract(x, p["wv"], "btd,dhk->bthk", 1)
+    q = _contract(x, p["wq"], "btd,dhk->bthk", 1, "n")
+    k = _contract(x, p["wk"], "btd,dhk->bthk", 1, "n")
+    v = _contract(x, p["wv"], "btd,dhk->bthk", 1, "n")
     if "bq" in p:
         q = q + _plain(p["bq"])
         k = k + _plain(p["bk"])
@@ -208,7 +211,7 @@ def qkv_project(x: jax.Array, p: Params, cfg: ModelConfig) -> tuple[jax.Array, j
 
 def out_project(x: jax.Array, p: Params) -> jax.Array:
     """x: [B, T, H, hd] -> [B, T, D].  wo: [H, hd, D]."""
-    out = _contract(x, p["wo"], "bthk,hkd->btd", 2)
+    out = _contract(x, p["wo"], "bthk,hkd->btd", 2, "k")
     if "bo" in p:
         out = out + _plain(p["bo"])
     return out
@@ -218,7 +221,7 @@ def mlp_gelu(x: jax.Array, p: Params, activation: str = "gelu") -> jax.Array:
     """GPT-2-layout MLP: act(x W_in + b) W_out + b.  ``activation``:
     "relu" (OPT), "gelu_exact" (erf gelu — HF's "gelu"), anything else the
     tanh approximation (HF's "gelu_new", GPT-2's convention)."""
-    h = _contract(x, p["w_in"], "btd,df->btf", 1) + _plain(p["b_in"])
+    h = _contract(x, p["w_in"], "btd,df->btf", 1, "n") + _plain(p["b_in"])
     if activation == "relu":
         h = jax.nn.relu(h)
     elif activation == "gelu_exact":
@@ -227,20 +230,20 @@ def mlp_gelu(x: jax.Array, p: Params, activation: str = "gelu") -> jax.Array:
         h = jax.nn.gelu(h, approximate=True)
     else:  # loud, not silently-gelu: wrong activation = wrong logits
         raise ValueError(f"unsupported MLP activation {activation!r}")
-    return _contract(h, p["w_out"], "btf,fd->btd", 1) + _plain(p["b_out"])
+    return _contract(h, p["w_out"], "btf,fd->btd", 1, "k") + _plain(p["b_out"])
 
 
 def mlp_swiglu(x: jax.Array, p: Params, gate_act: str = "silu") -> jax.Array:
     """Gated MLP: (act(x W_gate) * (x W_up)) W_down, no biases.
     ``gate_act``: "silu" (Llama/Qwen2) or "gelu_tanh" (Gemma's GeGLU)."""
-    gate = _contract(x, p["w_gate"], "btd,df->btf", 1)
-    up = _contract(x, p["w_up"], "btd,df->btf", 1)
+    gate = _contract(x, p["w_gate"], "btd,df->btf", 1, "n")
+    up = _contract(x, p["w_up"], "btd,df->btf", 1, "n")
     act = (
         jax.nn.silu if gate_act == "silu"
         else lambda g: jax.nn.gelu(g, approximate=True)
     )
     h = act(gate) * up
-    return _contract(h, p["w_down"], "btf,fd->btd", 1)
+    return _contract(h, p["w_down"], "btf,fd->btd", 1, "k")
 
 
 def moe_swiglu(

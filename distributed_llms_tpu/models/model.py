@@ -14,13 +14,14 @@ Layout conventions:
 
 from __future__ import annotations
 
+import functools
+import zlib
 from dataclasses import dataclass
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from ..core import jaxcompat
 from ..core.config import ModelConfig
 from . import layers
 from .layers import Params
@@ -488,7 +489,7 @@ def _seq_cached_attention(
             "seq-parallel cached decode needs attn_mask=(prefill_mask, "
             "decode_mask) — ParallelModel.forward splits the global mask"
         )
-    t_pref_global = ck_pref.shape[1] * jaxcompat.axis_size("seq")
+    t_pref_global = ck_pref.shape[1] * jax.lax.axis_size("seq")
     di = cache_index - t_pref_global
     ck_dec = jax.lax.dynamic_update_slice(ck_dec, k.astype(ck_dec.dtype), (0, di, 0, 0))
     cv_dec = jax.lax.dynamic_update_slice(cv_dec, v.astype(cv_dec.dtype), (0, di, 0, 0))
@@ -786,6 +787,92 @@ def init_params(rng: jax.Array, cfg: ModelConfig, dtype: Any = None) -> Params:
     if not cfg.tie_embeddings:
         params["lm_head"] = {"w": dense(next(keys), (D, cfg.vocab_size), D)}
     return params
+
+
+def init_params_quantized(
+    rng: jax.Array, cfg: ModelConfig, bits: int, mesh: Any = None
+) -> Params:
+    """Seeded random params with the decoder-block matmul weights born
+    int8/int4 (``QuantizedTensor`` leaves), built on the device leaf by
+    leaf: what a full-width preset needs to reach one chip.
+
+    :func:`init_params` draws each stacked leaf whole in float32 before
+    casting (a 7.6 GB transient for one 7B MLP leaf) and the finished bf16
+    tree may not fit at all.  Here every leaf is one jitted program that
+    walks the layer axis with ``lax.map``, so the float32 transient is a
+    single layer, and with ``mesh`` the leaf is born under
+    parallel.specs.param_specs' sharding — no leaf ever sits whole on
+    device 0.  Same tree, shapes, dtypes, fan-in scaling and
+    quantization plan (checkpoint.quantize.leaf_plan) as
+    ``quantize_tree(init_params(...)["blocks"])``; the values differ (one
+    folded key per leaf and layer) and, the threefry generator being
+    partitionable, do not depend on the mesh."""
+    from ..checkpoint import quantize as quant_lib
+
+    shapes = jax.eval_shape(lambda k: init_params(k, cfg), rng)
+    specs = None
+    if mesh is not None:
+        from jax.sharding import NamedSharding
+
+        from ..parallel import api as parallel_api
+        from ..parallel import specs as specs_lib
+
+        specs = specs_lib.param_specs(cfg, mesh)
+    fan_ins = {
+        "wo": cfg.num_heads * cfg.head_dim_,
+        "w_out": cfg.intermediate_size, "w_down": cfg.intermediate_size,
+    }
+
+    def build(path, sd, spec=None):
+        name = "/".join(str(p.key) for p in path)
+        leaf = path[-1].key
+        key = jax.random.fold_in(rng, zlib.crc32(name.encode()))
+        scale = fan_ins.get(leaf, cfg.hidden_size) ** -0.5
+        should, pack_axis = quant_lib.leaf_plan(name, sd)
+        quant = should and name.startswith("blocks/")
+        repeat = 1
+        sharding = None
+        if spec is not None:
+            sharding = NamedSharding(mesh, spec)
+            if quant:
+                qt = jax.eval_shape(functools.partial(
+                    quant_lib.quantize, bits=bits, pack_axis=pack_axis), sd)
+                spec, repeat = parallel_api.quantized_layout(
+                    qt.data.shape, qt.scale.shape, bits, pack_axis, spec,
+                    mesh, name,
+                )
+                sharding = (NamedSharding(mesh, spec),) * 2
+
+        def dense(k, shape):
+            return jax.random.normal(k, shape, jnp.float32) * scale
+
+        def one_layer(i):
+            x = dense(jax.random.fold_in(key, i), sd.shape[1:])
+            if not quant:
+                return x.astype(sd.dtype)
+            qt = quant_lib.quantize(x, bits=bits, pack_axis=pack_axis)
+            return qt.data, jnp.repeat(qt.scale, repeat, axis=-1)
+
+        def gen():
+            if leaf == "scale":
+                return jnp.ones(sd.shape, sd.dtype)
+            if leaf.startswith("b"):  # bias, bq/bk/bv/bo, b_in/b_out
+                return jnp.zeros(sd.shape, sd.dtype)
+            if name.startswith("blocks/"):
+                return jax.lax.map(one_layer, jnp.arange(sd.shape[0]))
+            return dense(key, sd.shape).astype(sd.dtype)
+
+        out = jax.jit(gen, out_shardings=sharding)()
+        if not quant:
+            return out
+        return quant_lib.QuantizedTensor(
+            data=out[0], scale=out[1], bits=bits,
+            orig_shape=tuple(sd.shape), pack_axis=pack_axis,
+        )
+
+    if specs is None:
+        return jax.tree_util.tree_map_with_path(build, shapes)
+    return jax.tree_util.tree_map_with_path(build, shapes, specs)
 
 
 def count_params(params: Params) -> int:

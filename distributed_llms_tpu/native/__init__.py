@@ -7,13 +7,16 @@ over a C++ thread pool with CRC32 integrity computed in-pass; the pure-
 Python fallback keeps every caller working when no compiler is available.
 
 Build model: single-file ``g++ -O3 -shared`` compiled lazily into
-``_cache/`` next to the source (rebuilt when the source is newer), no
+``_cache/`` next to the source, under a name keyed on the source's
+content — a binary built from any other source (or copied in from another
+machine's tree, where git never saw it) is never the one loaded.  No
 setuptools/pybind11 dependency.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -28,7 +31,6 @@ log = get_logger("native")
 
 _SRC = os.path.join(os.path.dirname(__file__), "csrc", "dlt_io.cpp")
 _CACHE = os.path.join(os.path.dirname(__file__), "_cache")
-_SO = os.path.join(_CACHE, "dlt_io.so")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -37,20 +39,23 @@ _tried = False
 
 def _build() -> str | None:
     os.makedirs(_CACHE, exist_ok=True)
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so_path = os.path.join(_CACHE, f"dlt_io.{digest}.so")
+    if os.path.exists(so_path):
+        return so_path
     # Per-process temp name: concurrent cold-start builds (e.g. the
     # process-isolated local sim spawning N workers) must not interleave
     # writes; os.replace makes the final install atomic either way.
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    tmp = f"{so_path}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
         _SRC, "-o", tmp,
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
-        return _SO
+        os.replace(tmp, so_path)
+        return so_path
     except (OSError, subprocess.SubprocessError) as e:
         detail = getattr(e, "stderr", b"") or b""
         log.warning("native build failed (%s); using Python IO fallback: %s",

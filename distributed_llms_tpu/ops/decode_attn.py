@@ -36,14 +36,14 @@ No reference counterpart: the reference's compute was a placeholder matmul
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-from ..core import jaxcompat
+from . import dispatch
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -206,30 +206,7 @@ def _dense_reference(q, k, v, lengths, window=None):
 def _mode() -> str:
     """DLT_RAGGED_DECODE: "kernel" | "interpret" | "fallback" | "auto"
     (kernel iff TPU) — same resolution scheme as ops/quant_matmul.py."""
-    mode = os.environ.get("DLT_RAGGED_DECODE", "auto")
-    if mode in ("kernel", "interpret", "fallback"):
-        return mode
-    return "kernel" if jax.default_backend() == "tpu" else "fallback"
-
-
-def _use_spmd(mode: str) -> bool:
-    """Whether a call tracing under a GSPMD-partitioned jit should route
-    through the custom_partitioning wrappers below (the decode-attention
-    analogue of quant_matmul's DLT_QUANT_MATMUL_SPMD dispatch).  A plain
-    pallas_call has no SPMD partitioning rule — without the wrapper XLA
-    would all-gather the KV pool to one shard, defeating the sharded
-    page pool entirely.  The dense fallback path needs no wrapper (XLA
-    partitions plain lax ops itself), so "fallback" mode skips it.
-    DLT_DECODE_ATTN_SPMD: "0" kill-switch, "1" force, default "auto"
-    (wrapper whenever the kernel itself would run)."""
-    from .quant_matmul import in_spmd_trace
-
-    if not in_spmd_trace():
-        return False
-    env = os.environ.get("DLT_DECODE_ATTN_SPMD", "auto")
-    if env == "0":
-        return False
-    return env == "1" or mode != "fallback"
+    return dispatch.kernel_mode("DLT_RAGGED_DECODE")
 
 
 def ragged_decode_attention(
@@ -249,21 +226,27 @@ def ragged_decode_attention(
 ) -> jax.Array:
     """Returns [B, 1, H, D] in q.dtype.  Inference-only (no VJP).
 
-    Under a GSPMD-partitioned trace (tensor-parallel serving) the call
-    routes through :func:`_ragged_spmd` — each shard runs the kernel on
-    its local KV-head slice; lengths shard with the batch axis (or
-    replicate on a pure-TP mesh)."""
+    Under a tensor-parallel mesh (:func:`dispatch.sharded`) each shard
+    runs the unchanged kernel on its local KV-head slice, with no
+    collective (attention heads are independent per KV head); lengths
+    shard with the batch axis (or replicate on a pure-TP mesh)."""
     mode = _mode()
     quant = _check_quant(k, k_scale, v_scale)
-    if _use_spmd(mode):
-        f = _ragged_spmd(block_k, window, quant, mode)
-        args = (q, k, v, lengths.astype(jnp.int32))
-        if quant:
-            args += (k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32))
-        return f(*args)
-    return _ragged_impl(q, k, v, lengths, k_scale, v_scale,
-                        block_k=block_k, window=window, mode=mode)
+    impl = functools.partial(
+        _ragged_impl, block_k=block_k, window=window, mode=mode
+    )
+    args = (q, k, v, lengths.astype(jnp.int32))
+    if quant:
+        args += (k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
+    mesh = dispatch.mesh()
+    if mesh is None or mode == "fallback":  # XLA partitions the dense path
+        return impl(*args)
+    specs, out_spec = spmd_operand_specs(
+        mesh, q.shape, k.shape, paged=False, quant=quant
+    )
+    return dispatch.per_shard(
+        impl, mesh, tuple(specs.values()), out_spec
+    )(*args)
 
 
 def _ragged_impl(
@@ -292,9 +275,11 @@ def _ragged_impl(
     )
     tileable = bk is not None and d % 128 == 0
     if mode == "fallback" or not tileable:
+        dispatch.record("ragged_decode", "fallback", (b, s, h, kvh, d))
         if quant:
             k, v = _dequant(k, v, k_scale, v_scale, q.dtype)
         return _dense_reference(q, k, v, lengths, window)
+    dispatch.record("ragged_decode", mode, (b, s, h, kvh, d))
 
     gp = _round_up(g, 8)  # sublane-pad the per-kv-head query group
     # [B, KVH, G, D]: head ordering h = kv*g + i matches repeat_kv /
@@ -389,23 +374,27 @@ def paged_decode_attention(
     DMA walks its own pages and reads only its real depth.  Returns
     [B, 1, H, D] in q.dtype.  Inference-only.
 
-    Under a GSPMD-partitioned trace (tensor-parallel paged serving) the
-    call routes through :func:`_paged_spmd`: the pool (and its int8
-    scales) shard over the KV-head axis, each shard runs the kernel on
-    its local head slice, and the page table + lengths replicate on a
-    pure-TP mesh (they shard only with an explicit batch axis)."""
+    Under a tensor-parallel mesh (:func:`dispatch.sharded`) the pool (and
+    its int8 scales) shard over the KV-head axis, each shard runs the
+    kernel on its local head slice, and the page table + lengths
+    replicate on a pure-TP mesh (they shard only with an explicit batch
+    axis)."""
     mode = _mode()
     quant = _check_quant(k_pages, k_scale, v_scale)
-    if _use_spmd(mode):
-        f = _paged_spmd(quant, mode)
-        args = (q, k_pages, v_pages, lengths.astype(jnp.int32),
-                tables.astype(jnp.int32))
-        if quant:
-            args += (k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32))
-        return f(*args)
-    return _paged_impl(q, k_pages, v_pages, lengths, tables,
-                       k_scale, v_scale, mode=mode)
+    impl = functools.partial(_paged_impl, mode=mode)
+    args = (q, k_pages, v_pages, lengths.astype(jnp.int32),
+            tables.astype(jnp.int32))
+    if quant:
+        args += (k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
+    mesh = dispatch.mesh()
+    if mesh is None or mode == "fallback":  # XLA partitions the dense path
+        return impl(*args)
+    specs, out_spec = spmd_operand_specs(
+        mesh, q.shape, k_pages.shape, paged=True, quant=quant
+    )
+    return dispatch.per_shard(
+        impl, mesh, tuple(specs.values()), out_spec
+    )(*args)
 
 
 def _paged_impl(
@@ -423,6 +412,7 @@ def _paged_impl(
         blk % 8 == 0 and d % 128 == 0 and _kv_vmem_ok(blk, kvh, d, k_pages.dtype)
     )
     if mode == "fallback" or not tileable:
+        dispatch.record("paged_decode", "fallback", (b, blk, h, kvh, d))
         # Gather the rows' pages into contiguous [B, P*BLK] caches (the
         # fallback materializes; the kernel never does).  Int8 pools
         # dequantize the gathered rows at kv_dequantize numerics.
@@ -437,6 +427,7 @@ def _paged_impl(
             )
         return _dense_reference(q, k_rows, v_rows, lengths)
 
+    dispatch.record("paged_decode", mode, (b, blk, h, kvh, d))
     gp = _round_up(g, 8)
     qt = q[:, 0].reshape(b, kvh, g, d)
     if gp != g:
@@ -492,70 +483,17 @@ def _paged_impl(
     return out.reshape(b, 1, h, d)
 
 # ---------------------------------------------------------------------------
-# SPMD partitioning rules (tensor-parallel serving meshes)
+# Operand placement on tensor-parallel serving meshes
 # ---------------------------------------------------------------------------
 #
-# pallas_call has no built-in SPMD partitioning rule: traced bare under a
-# GSPMD jit, XLA would all-gather the whole KV pool onto every shard —
-# defeating the sharded page pool (and the contiguous mesh cache) entirely.
-# The wrappers below supply the rule via jax.experimental.custom_partitioning,
-# following the in-repo exemplar ops/quant_matmul._qmm_spmd: attention
-# output heads are independent per KV head, so each shard runs the kernel
-# unchanged on its LOCAL head slice (q heads and KV heads shard together
-# over the same mesh axis; the grouped ratio g = H/KVH is shard-invariant)
-# and no collective is needed.  Lengths and page tables shard only with an
-# explicit batch axis — on a pure-TP mesh they replicate; int8 absmax
-# scales shard with their pages on the KV-head axis.
-
-
-def _spec_tuple(info, rank: int) -> tuple:
-    spec = getattr(getattr(info, "sharding", None), "spec", None)
-    t = tuple(spec) if spec is not None else ()
-    return t + (None,) * (rank - len(t))
-
-
-def _names(ax) -> tuple:
-    return () if ax is None else (ax if isinstance(ax, tuple) else (ax,))
-
-
-def _axis_sz(mesh, ax) -> int:
-    sz = 1
-    for nm in _names(ax):
-        sz *= mesh.shape.get(nm, 1)
-    return sz
-
-
-def _resolve_decode_axes(mesh, q_info, kv_info, *, kv_batched: bool):
-    """(batch_axis, head_axis) with divisibility enforced — shared by
-    infer and partition (and the graftcheck GC2 audit surface) so they
-    cannot disagree.  ``kv_info`` is the K operand: [B, S, KVH, D]
-    contiguous (kv_batched) or [NB, BLK, KVH, D] pool pages."""
-    qs = _spec_tuple(q_info, 4)
-    ks = _spec_tuple(kv_info, 4)
-    b_ax = qs[0]
-    if b_ax is None and kv_batched:
-        b_ax = ks[0]
-    h_ax = ks[2] if ks[2] is not None else qs[2]
-    b, _, h, _ = q_info.shape
-    kvh = kv_info.shape[2]
-    # Every shard must hold WHOLE heads on both operands (the kernel's
-    # static head loop) — replicate the head axis when it doesn't divide.
-    hs = _axis_sz(mesh, h_ax)
-    if hs > 1 and (h % hs or kvh % hs):
-        h_ax = None
-    bs = _axis_sz(mesh, b_ax)
-    if bs > 1 and b % bs:
-        b_ax = None
-    # A mesh axis may appear once per spec: on a collision keep the head
-    # sharding (the sharded pool is the point) and replicate batch.
-    if set(_names(b_ax)) & set(_names(h_ax)):
-        b_ax = None
-    return b_ax, h_ax
+# q heads and KV heads shard together over 'model' (the grouped ratio
+# g = H/KVH is shard-invariant), the batch over 'data'.  Lengths and page
+# tables shard only with the batch axis — on a pure-TP mesh they replicate;
+# int8 absmax scales shard with their pages on the KV-head axis.  Pool
+# pages are shared across rows, so the page axis never shards.
 
 
 def _ragged_operand_specs(b_ax, h_ax, quant: bool) -> dict:
-    from jax.sharding import PartitionSpec as P
-
     specs = {
         "q": P(b_ax, None, h_ax, None),
         "k": P(b_ax, None, h_ax, None),
@@ -569,8 +507,6 @@ def _ragged_operand_specs(b_ax, h_ax, quant: bool) -> dict:
 
 
 def _paged_operand_specs(b_ax, h_ax, quant: bool) -> dict:
-    from jax.sharding import PartitionSpec as P
-
     specs = {
         "q": P(b_ax, None, h_ax, None),
         "k_pages": P(None, None, h_ax, None),
@@ -586,138 +522,18 @@ def _paged_operand_specs(b_ax, h_ax, quant: bool) -> dict:
 
 def spmd_operand_specs(
     mesh, q_shape: tuple, kv_shape: tuple, *, paged: bool,
-    quant: bool = False, batch_axis="data", head_axis="model",
+    quant: bool = False,
 ):
-    """The operand PartitionSpecs the SPMD rule resolves for canonical
-    inputs (batch over ``batch_axis``, KV heads over ``head_axis``) on
-    ``mesh`` — the audit surface tools/graftcheck GC2 structure-matches
+    """(operand-spec dict in call order, output spec) for one decode call
+    on ``mesh``: the in_specs/out_specs the shard_map dispatch above runs
+    with, and the audit surface tools/graftcheck GC2 structure-matches
     against abstract operand trees (axis names, rank, divisibility).
-    Returns (operand-spec dict, output spec).  Built on the SAME
-    ``_resolve_decode_axes`` the partition rule runs, so the audit can
-    never drift from the lowering."""
-    from jax.sharding import PartitionSpec as P
-
-    class _Info:
-        def __init__(self, shape, spec):
-            self.shape = shape
-            self.sharding = type("S", (), {"spec": spec})()
-
-    q_info = _Info(q_shape, P(batch_axis, None, head_axis, None))
-    kv_spec = (P(batch_axis, None, head_axis, None) if not paged
-               else P(None, None, head_axis, None))
-    kv_info = _Info(kv_shape, kv_spec)
-    b_ax, h_ax = _resolve_decode_axes(
-        mesh, q_info, kv_info, kv_batched=not paged
-    )
+    Every shard must hold WHOLE heads on both operands (the kernel's
+    static head loop), so an axis that does not divide replicates.
+    ``kv_shape`` is the K operand: [B, S, KVH, D] contiguous or
+    [NB, BLK, KVH, D] pool pages."""
+    b, _, h, _ = q_shape
+    b_ax = dispatch.axis(mesh, "data", b)
+    h_ax = dispatch.axis(mesh, "model", h, kv_shape[2])
     build = _paged_operand_specs if paged else _ragged_operand_specs
     return build(b_ax, h_ax, quant), P(b_ax, None, h_ax, None)
-
-
-@functools.lru_cache(maxsize=None)
-def _ragged_spmd(block_k: int, window: int | None, quant: bool,
-                 mode: str):
-    """custom_partitioning wrapper for the ragged kernel: each shard runs
-    :func:`_ragged_impl` on its local (batch, head) slice — untileable
-    LOCAL shapes take the dense fallback inside the shard, so the wrapper
-    is total over any placement.  lru_cache keyed on the static config —
-    the RESOLVED mode included: a DLT_DECODE_ATTN_SPMD=1 force on a
-    backend whose mode is "fallback" must run the dense body per shard,
-    never the TPU kernel — so jit retracing reuses one wrapper instance
-    per configuration."""
-    from jax.experimental.custom_partitioning import custom_partitioning
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    def impl(q, k, v, lengths, k_scale=None, v_scale=None):
-        return _ragged_impl(q, k, v, lengths, k_scale, v_scale,
-                            block_k=block_k, window=window, mode=mode)
-
-    if quant:
-        @custom_partitioning
-        def f(q, k, v, lengths, k_scale, v_scale):
-            return impl(q, k, v, lengths, k_scale, v_scale)
-    else:
-        @custom_partitioning
-        def f(q, k, v, lengths):
-            return impl(q, k, v, lengths)
-
-    def _shardings(mesh, arg_infos):
-        b_ax, h_ax = _resolve_decode_axes(
-            mesh, arg_infos[0], arg_infos[1], kv_batched=True
-        )
-        specs = _ragged_operand_specs(b_ax, h_ax, quant)
-        return (
-            tuple(NamedSharding(mesh, s) for s in specs.values()),
-            NamedSharding(mesh, P(b_ax, None, h_ax, None)),
-        )
-
-    def infer(mesh, arg_infos, result_infos):
-        return _shardings(mesh, arg_infos)[1]
-
-    def partition(mesh, arg_infos, result_infos):
-        args, out = _shardings(mesh, arg_infos)
-        return mesh, impl, out, args
-
-    # Shardy factor rule: batch and heads propagate to the output; the
-    # cache width and KV-head axes are independent factors (H != KVH
-    # under GQA, so q's head axis cannot share the KV operands' factor).
-    rule = "b u h d, b s k d, b s k d, b -> b u h d"
-    if quant:
-        rule = "b u h d, b s k d, b s k d, b, b s k, b s k -> b u h d"
-    jaxcompat.def_partition(
-        f, infer_sharding_from_operands=infer, partition=partition,
-        sharding_rule=rule,
-    )
-    return f
-
-
-@functools.lru_cache(maxsize=None)
-def _paged_spmd(quant: bool, mode: str):
-    """custom_partitioning wrapper for the paged kernel: the page pool
-    (and its int8 scales) shard over the KV-head axis, each shard runs
-    :func:`_paged_impl` on its local head slice, and the page table +
-    lengths replicate on a pure-TP mesh (they shard only with an explicit
-    batch axis).  No collective: attention output heads are independent
-    per KV head.  Keyed on the RESOLVED mode (see _ragged_spmd)."""
-    from jax.experimental.custom_partitioning import custom_partitioning
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    def impl(q, k_pages, v_pages, lengths, tables, k_scale=None,
-             v_scale=None):
-        return _paged_impl(q, k_pages, v_pages, lengths, tables,
-                           k_scale, v_scale, mode=mode)
-
-    if quant:
-        @custom_partitioning
-        def f(q, k_pages, v_pages, lengths, tables, k_scale, v_scale):
-            return impl(q, k_pages, v_pages, lengths, tables, k_scale,
-                        v_scale)
-    else:
-        @custom_partitioning
-        def f(q, k_pages, v_pages, lengths, tables):
-            return impl(q, k_pages, v_pages, lengths, tables)
-
-    def _shardings(mesh, arg_infos):
-        b_ax, h_ax = _resolve_decode_axes(
-            mesh, arg_infos[0], arg_infos[1], kv_batched=False
-        )
-        specs = _paged_operand_specs(b_ax, h_ax, quant)
-        return (
-            tuple(NamedSharding(mesh, s) for s in specs.values()),
-            NamedSharding(mesh, P(b_ax, None, h_ax, None)),
-        )
-
-    def infer(mesh, arg_infos, result_infos):
-        return _shardings(mesh, arg_infos)[1]
-
-    def partition(mesh, arg_infos, result_infos):
-        args, out = _shardings(mesh, arg_infos)
-        return mesh, impl, out, args
-
-    rule = "b u h d, n p k d, n p k d, b, b t -> b u h d"
-    if quant:
-        rule = "b u h d, n p k d, n p k d, b, b t, n p k, n p k -> b u h d"
-    jaxcompat.def_partition(
-        f, infer_sharding_from_operands=infer, partition=partition,
-        sharding_rule=rule,
-    )
-    return f

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..core import jaxcompat
+from . import dispatch
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -226,17 +226,21 @@ def _pad_to(x: jax.Array, axis: int, mult: int, value) -> jax.Array:
 def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k, interpret, window):
     # Inside shard_map (e.g. the Ulysses body) the inputs carry varying
     # manual axes (vma); the output must declare the same set.
-    vma = frozenset().union(*(jaxcompat.vma_of(x) for x in (q, k, v)))
+    vma = frozenset().union(*(jax.typeof(x).vma for x in (q, k, v)))
+    b, tq, h, d = q.shape
+    s = k.shape[1]
+    kvh = k.shape[2]
     if interpret and vma:
         # The Pallas HLO *interpreter* (off-TPU test path) loses vma on its
         # internal dynamic_slices; run the numerically-identical dense
         # reference there.  Real TPU lowering takes the kernel.
+        dispatch.record("flash", "fallback", (b, tq, s, h, kvh, d))
         return _dense_reference(
             q, k, v, q_positions, k_positions, k_valid, causal, window
         )
-    b, tq, h, d = q.shape
-    s = k.shape[1]
-    kvh = k.shape[2]
+    dispatch.record(
+        "flash", "interpret" if interpret else "kernel", (b, tq, s, h, kvh, d)
+    )
     assert h % kvh == 0, (h, kvh)
     g = h // kvh
     scale = d**-0.5
@@ -267,7 +271,7 @@ def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
     ]
     q_spec = pl.BlockSpec((1, bq, d), lambda bi, hi, qi, ki: (bi * h + hi, qi, 0))
     o_spec = pl.BlockSpec((1, bq, d), lambda bi, hi, qi, ki: (bi * h + hi, qi, 0))
-    out_shape = jaxcompat.shape_dtype_struct((b * h, tq_p, d), q.dtype, vma=vma)
+    out_shape = jax.ShapeDtypeStruct((b * h, tq_p, d), q.dtype, vma=vma)
     args = (
         qt.reshape(b * h, tq_p, d),
         kt.reshape(b * kvh, s_p, d),
@@ -309,7 +313,7 @@ def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
         # mesh axis; align them with q/k/v so vma tracking stays consistent
         # inside shard_map bodies (same trick as ops/ring.py).
         align = (
-            (lambda x: jaxcompat.pcast(x, tuple(vma), to="varying")) if vma
+            (lambda x: jax.lax.pcast(x, tuple(vma), to="varying")) if vma
             else (lambda x: x)
         )
         if q_positions is None:

@@ -22,44 +22,16 @@ runs full-dtype weights).
 
 from __future__ import annotations
 
-import contextlib
-import contextvars
 import functools
-import os
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-from ..core import jaxcompat
-
-# Trace-time marker: "this contraction is being traced under a
-# GSPMD-partitioned jit" (tensor-parallel serving).  A plain pallas_call has
-# no SPMD partitioning rule there — XLA would all-gather the full weight,
-# defeating quantized residency — so quant_contract either takes the
-# custom_partitioning wrapper (_qmm_spmd, the default when the kernel would
-# run) or the dequantize+einsum fallback (DLT_QUANT_MATMUL_SPMD=0, or
-# non-TPU).  ParallelModel wraps its GSPMD forward in spmd_fallback().
-_SPMD_FALLBACK = contextvars.ContextVar("dlt_quant_spmd_fallback", default=False)
-
-
-@contextlib.contextmanager
-def spmd_fallback():
-    token = _SPMD_FALLBACK.set(True)
-    try:
-        yield
-    finally:
-        _SPMD_FALLBACK.reset(token)
-
-
-def in_spmd_trace() -> bool:
-    """Whether the current trace runs under a GSPMD-partitioned jit
-    (ParallelModel.forward wraps its GSPMD path in :func:`spmd_fallback`).
-    Shared marker: ops/decode_attn.py consults it to route its kernels
-    through their own custom_partitioning wrappers on tensor-parallel
-    serving meshes."""
-    return _SPMD_FALLBACK.get()
+from . import dispatch
 
 # Candidate tile sizes, largest first; a dimension uses the first candidate
 # that divides it (grids must tile exactly — no masking on the K/N axes).
@@ -135,7 +107,7 @@ def _quant_matmul_2d(
         _kernel, bits=bits, block=block, nk=grid[2], out_dtype=x.dtype
     )
     flops = 2 * m * k_dim * n
-    out_shape = jaxcompat.shape_dtype_struct((m, n), x.dtype, vma=vma)
+    out_shape = jax.ShapeDtypeStruct((m, n), x.dtype, vma=vma)
     return pl.pallas_call(
         kernel,
         out_shape=out_shape,
@@ -202,9 +174,9 @@ def _dequant_flat(q2: jax.Array, s2: jax.Array, bits: int, dtype) -> jax.Array:
 def _qmm_flat(x2: jax.Array, q2: jax.Array, s2: jax.Array, *, bits: int,
               interpret: bool) -> jax.Array:
     """[M, K] @ dequant([K(-packed), N]) from flat operands.  Shapes are the
-    LOCAL (per-shard, under custom_partitioning) shapes: tile sizes, M
-    padding, and the scale regroup all derive from them; untileable shapes
-    take the dequant+matmul fallback, so this is total over any shard."""
+    LOCAL ones (per shard, inside shard_map): tile sizes, M padding and the
+    scale regroup all derive from them; untileable shapes take the
+    dequant+matmul fallback, so this is total over any shard."""
     m, k = x2.shape
     n = q2.shape[1]
     nb = s2.shape[1]
@@ -216,17 +188,19 @@ def _qmm_flat(x2: jax.Array, q2: jax.Array, s2: jax.Array, *, bits: int,
         and block % 128 == 0 and bn % block == 0
         and (bits == 8 or bk // 2 >= 8)
     )
-    if not tileable:
+    # Inside a vma-checked shard_map (the pipeline stage body) operands
+    # carry varying manual axes; the kernel's out_shape must declare the
+    # same set.  The Pallas HLO *interpreter* (off-TPU test path) loses vma
+    # on its internal dynamic_slices (same limitation as ops/flash.py), so
+    # it runs the numerically-identical flat dequant there.
+    vma = frozenset().union(*(jax.typeof(a).vma for a in (x2, q2, s2)))
+    if not tileable or (vma and interpret):
+        dispatch.record("quant_matmul", "fallback", (m, k, n, bits))
         return x2 @ _dequant_flat(q2, s2, bits, x2.dtype)
-    # Inside shard_map (the pipeline stage body) operands carry varying
-    # manual axes; the kernel's out_shape must declare the same set.
-    vma = frozenset().union(*(jaxcompat.vma_of(a) for a in (x2, q2, s2)))
-    if vma and interpret:
-        # The Pallas HLO *interpreter* (off-TPU test path) loses vma on its
-        # internal dynamic_slices (same limitation as ops/flash.py); run the
-        # numerically-identical flat dequant there.  Real TPU lowering takes
-        # the kernel, with vma declared on its out_shape.
-        return x2 @ _dequant_flat(q2, s2, bits, x2.dtype)
+    dispatch.record(
+        "quant_matmul", "interpret" if interpret else "kernel",
+        (m, k, n, bits),
+    )
     bm = min(_BM_MAX, max(16, -(-m // 16) * 16))
     m_pad = -(-m // bm) * bm
     if m_pad != m:
@@ -241,125 +215,39 @@ def _qmm_flat(x2: jax.Array, q2: jax.Array, s2: jax.Array, *, bits: int,
     )[:m]
 
 
-def _spec_tuple(info, rank: int) -> tuple:
-    spec = getattr(getattr(info, "sharding", None), "spec", None)
-    t = tuple(spec) if spec is not None else ()
-    return t + (None,) * (rank - len(t))
+def _qmm_sharded(mesh, x2, q2, s2, *, bits: int, interpret: bool,
+                 shard: str | None, whole: int, batch: int) -> jax.Array:
+    """:func:`_qmm_flat` per shard of a tensor-parallel mesh
+    (:func:`dispatch.per_shard`).  ``shard`` is the
+    weight's Megatron role: "n" splits the output axis over 'model'
+    (wq/wk/wv, w_in/w_gate/w_up — embarrassingly parallel), "k" splits the
+    contracted axis (wo, w_out/w_down — partial products, psum over
+    'model').  The specs mirror parallel.specs.param_specs, so placed
+    weights enter without a reshard: the split axis must divide into
+    ``whole`` slices of the weight's first such axis (heads, for the
+    attention weights), whole rows or columns and whole scale blocks, or
+    it stays replicated (redundant compute, same numerics).  ``batch`` is
+    x's leading axis, which shards over 'data' when it divides."""
+    m_ax = dispatch.axis(mesh, "data", batch)
+    n_ax = k_ax = None
+    if shard == "n":
+        n_ax = dispatch.axis(mesh, "model", whole, q2.shape[1], s2.shape[1])
+    elif shard == "k":
+        k_ax = dispatch.axis(mesh, "model", whole, q2.shape[0], s2.shape[0])
 
+    def body(x2, q2, s2):
+        y = _qmm_flat(x2, q2, s2, bits=bits, interpret=interpret)
+        return jax.lax.psum(y, k_ax) if k_ax else y
 
-@functools.lru_cache(maxsize=None)
-def _qmm_spmd(bits: int, interpret: bool):
-    """SPMD-partitionable fused quant matmul (default under GSPMD whenever
-    the kernel would run; DLT_QUANT_MATMUL_SPMD=0 disables).  pallas_call
-    has no built-in SPMD partitioning rule; this wrapper supplies one via
-    jax.experimental.custom_partitioning: each shard runs the kernel on its
-    local tiles (N-sharded weights run embarrassingly parallel; K-sharded
-    weights — wo under tensor parallelism — compute partial products and
-    psum over the contracted mesh axes).
-
-    History: earlier JAX releases failed on custom_partitioning inside
-    ``lax.scan`` (op_sharding superdim KeyError), which forced round 3's
-    GSPMD serving onto the dequantize+einsum fallback.  The JAX in this
-    image compiles the wrapper under a scan both with scan-invariant
-    weights and with the stacked weights scanned as xs (pinned by
-    tests/parallel/test_quantized_mesh.py::
-    test_spmd_kernel_wrapper_under_scan), so GSPMD quantized serving now
-    takes the kernel by default; DLT_QUANT_MATMUL_SPMD=0 is the
-    kill-switch if real-TPU Mosaic lowering disagrees."""
-    from jax.experimental.custom_partitioning import custom_partitioning
-
-    @custom_partitioning
-    def f(x2, q2, s2):
-        return _qmm_flat(x2, q2, s2, bits=bits, interpret=interpret)
-
-    def _names(ax):
-        return () if ax is None else (ax if isinstance(ax, tuple) else (ax,))
-
-    def _resolve_axes(mesh, arg_infos):
-        """(m_ax, k_ax, n_ax) with every mesh axis used at most once —
-        shared by infer and partition so they cannot disagree."""
-
-        def axis_size(ax):
-            sz = 1
-            for nm in _names(ax):
-                sz *= mesh.shape.get(nm, 1)
-            return sz
-
-        xs = _spec_tuple(arg_infos[0], 2)
-        qs = _spec_tuple(arg_infos[1], 2)
-        m_ax = xs[0]
-        n_ax = qs[1]
-        k_ax = qs[0] if qs[0] is not None else xs[1]
-        # Scale blocks must divide over the N shards or each shard's local
-        # block derivation goes wrong — when they don't, keep q AND s
-        # replicated along N together (redundant compute, correct numerics).
-        # Placement-time refinement (parallel.api._place_quantized) normally
-        # makes them divide.
-        nb = arg_infos[2].shape[1]
-        if nb % max(axis_size(n_ax), 1):
-            n_ax = None
-        # A mesh axis may appear once per spec: prefer the weight's N
-        # sharding over a colliding activation-K sharding, and replicate M
-        # when the batch axis collides with either (FSDP-style placements) —
-        # rather than crash at inference/lowering.
-        if set(_names(k_ax)) & set(_names(n_ax)):
-            k_ax = None
-        if set(_names(m_ax)) & (set(_names(k_ax)) | set(_names(n_ax))):
-            m_ax = None
-        return m_ax, k_ax, n_ax
-
-    def infer(mesh, arg_infos, result_infos):
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        m_ax, _, n_ax = _resolve_axes(mesh, arg_infos)
-        return NamedSharding(mesh, P(m_ax, n_ax))
-
-    def partition(mesh, arg_infos, result_infos):
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        m_ax, k_ax, n_ax = _resolve_axes(mesh, arg_infos)
-        k_names = _names(k_ax)
-
-        def lower(x2, q2, s2):
-            y = _qmm_flat(x2, q2, s2, bits=bits, interpret=interpret)
-            if k_names:
-                y = jax.lax.psum(y, k_names)
-            return y
-
-        args = (
-            NamedSharding(mesh, P(m_ax, k_ax)),
-            NamedSharding(mesh, P(k_ax, n_ax)),
-            NamedSharding(mesh, P(k_ax, n_ax)),
-        )
-        return mesh, lower, NamedSharding(mesh, P(m_ax, n_ax)), args
-
-    jaxcompat.def_partition(
-        f,
-        infer_sharding_from_operands=infer,
-        partition=partition,
-        # Shardy factor rule: m/n propagate to the output; the contracted and
-        # block axes are independent factors (int4 packs K, so x's K and q's
-        # rows differ in size and cannot share a factor).  (Attached only on
-        # runtimes whose def_partition takes it — jaxcompat.def_partition —
-        # the 0.4.x signature raised TypeError, which silently disarmed this
-        # wrapper on the current image.)
-        sharding_rule="m k, p n, q b -> m n",
-    )
-    return f
-
-
-def _kernel_mode() -> str:
-    """Resolve DLT_QUANT_MATMUL: "kernel" (compiled Pallas), "interpret"
-    (Pallas interpret mode — the CI leg that runs the kernel's exact program
-    on CPU), "fallback" (dequantize+einsum), or "auto" (kernel iff TPU)."""
-    mode = os.environ.get("DLT_QUANT_MATMUL", "auto")
-    if mode in ("kernel", "interpret", "fallback"):
-        return mode
-    return "kernel" if jax.default_backend() == "tpu" else "fallback"
+    return dispatch.per_shard(
+        body, mesh, (P(m_ax, k_ax), P(k_ax, n_ax), P(k_ax, n_ax)),
+        P(m_ax, n_ax),
+    )(x2, q2, s2)
 
 
 def quant_contract(
-    x: jax.Array, qt, k_lead: int, eq: str | None = None, *, interpret: bool = False
+    x: jax.Array, qt, k_lead: int, eq: str | None = None, *,
+    shard: str | None = None, interpret: bool = False,
 ):
     """x[..., K-axes] @ dequant(W)[K-axes, N-axes] with W blockwise-quantized.
 
@@ -367,59 +255,41 @@ def quant_contract(
     wq/wk/wv/w_in/w_gate/w_up/w_down, 2 for wo [H, hd, D]).  The matching
     trailing axes of ``x`` flatten to K; the weight's remaining axes are
     restored on the output.  Dispatches to the Pallas kernel on TPU (or when
-    DLT_QUANT_MATMUL=kernel); otherwise dequantize + einsum over ``eq`` —
-    bit-identical to the pre-kernel serving path.
+    DLT_QUANT_MATMUL=kernel|interpret), per shard under a tensor-parallel
+    mesh (``shard``: see :func:`_qmm_sharded`); otherwise dequantize +
+    einsum over ``eq``, which XLA partitions itself.
     """
     out_tail = list(qt.data.shape[k_lead:])  # N axes are never packed
     lead = x.shape[: x.ndim - k_lead]
-    k = 1
-    for d in x.shape[x.ndim - k_lead:]:
-        k *= d
+    k = math.prod(x.shape[x.ndim - k_lead:])
     x2 = x.reshape(-1, k)
 
-    mode = _kernel_mode()
-    in_gspmd = _SPMD_FALLBACK.get()
-    spmd_env = os.environ.get("DLT_QUANT_MATMUL_SPMD", "auto")
-    # Under a GSPMD trace the kernel needs its custom_partitioning wrapper
-    # (plain pallas_call has no SPMD rule; XLA would all-gather the weight).
-    # Default ("auto"): take the wrapper whenever the kernel itself would run
-    # — the JAX in this image no longer hits the op_sharding superdim bug
-    # with the wrapper under lax.scan, even with the stacked weights scanned
-    # as xs (verified both ways; see test_spmd_kernel_wrapper_under_scan).
-    # "0" restores the round-3 dequant+einsum fallback (kill-switch if
-    # Mosaic + scan misbehaves on real hardware); "1" forces the wrapper
-    # even when mode would resolve to fallback.
-    use_spmd_kernel = in_gspmd and (
-        spmd_env == "1" or (spmd_env != "0" and mode != "fallback")
-    )
-    if in_gspmd and not use_spmd_kernel:
-        mode = "fallback"
-    elif use_spmd_kernel and mode == "fallback":
-        # "1" really does force the wrapper, even on a backend whose mode
-        # resolved to fallback — otherwise the dispatch gate below would
-        # quietly run dequant+einsum while the operator believes the
-        # wrapper was exercised.
-        mode = "kernel"
-    if interpret:  # explicit test request wins even inside spmd_fallback
-        mode = "interpret"
+    mode = "interpret" if interpret else dispatch.kernel_mode("DLT_QUANT_MATMUL")
     # int4: the kernel's sublane unpack (and _dequant_flat) assume the pack
     # pairs run along the LAST K axis (quantize_tree's convention).
     pack_ok = qt.bits == 8 or qt.data.ndim + qt.pack_axis == k_lead - 1
     if mode != "fallback" and pack_ok:
-        interpret = mode == "interpret"
-        q2, s2, n, block = flatten_qt(qt, k_lead)
-        if use_spmd_kernel:
-            # GSPMD trace: the custom_partitioning wrapper gives the kernel
-            # an SPMD rule (per-shard tiles; psum over contracted axes).
-            y2 = _qmm_spmd(qt.bits, interpret)(x2, q2, s2)
+        q2, s2, _, _ = flatten_qt(qt, k_lead)
+        kw = dict(bits=qt.bits, interpret=mode == "interpret")
+        mesh = dispatch.mesh()
+        if mesh is None:
+            y2 = _qmm_flat(x2, q2, s2, **kw)
         else:
-            y2 = _qmm_flat(x2, q2, s2, bits=qt.bits, interpret=interpret)
+            y2 = _qmm_sharded(
+                mesh, x2, q2, s2, shard=shard,
+                whole=qt.data.shape[0] if shard == "k" else out_tail[0],
+                batch=lead[0] if lead else 1, **kw,
+            )
         return y2.reshape(*lead, *out_tail)
 
     # Fallback: dequantize then contract (XLA fuses what it can).  Matches
     # models/model.py's historical dequant-at-use numerics exactly.
     from ..checkpoint.quantize import dequantize
 
+    dispatch.record(
+        "quant_matmul", "fallback",
+        (x2.shape[0], k, math.prod(out_tail), qt.bits),
+    )
     w = dequantize(qt, x.dtype)
     if eq is not None:
         return jnp.einsum(eq, x, w)

@@ -30,8 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..core import jaxcompat
-
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 
@@ -88,7 +86,7 @@ def ring_attention(
     per step is only [B, H, Tq/S, Tk/S].
     """
     try:
-        num_blocks = jaxcompat.axis_size(axis_name)
+        num_blocks = jax.lax.axis_size(axis_name)
     except NameError as e:
         raise RuntimeError(
             f"ring attention needs a bound {axis_name!r} mesh axis — call it "
@@ -100,7 +98,7 @@ def ring_attention(
     if k_valid is None:
         # Freshly created => not device-varying over the ring axis yet; mark
         # it so the rotating scan carry has consistent vma types.
-        k_valid = jaxcompat.pcast(
+        k_valid = jax.lax.pcast(
             jnp.ones(k_positions.shape, dtype=bool), (axis_name,), to="varying"
         )
 
@@ -137,7 +135,7 @@ def ring_attention(
         return (k_blk, v_blk, kpos_blk, kvalid_blk, *acc), None
 
     # Accumulators are device-varying over the ring axis (vma tracking).
-    varying = lambda x: jaxcompat.pcast(x, (axis_name,), to="varying")
+    varying = lambda x: jax.lax.pcast(x, (axis_name,), to="varying")
     num0 = varying(jnp.zeros((b, tq, h, d), jnp.float32))
     den0 = varying(jnp.zeros((b, h, tq), jnp.float32))
     max0 = varying(jnp.full((b, h, tq), _NEG_INF, jnp.float32))
@@ -225,7 +223,7 @@ def ring_self_attention(
     fn = functools.partial(ring_attention, axis_name=seq_axis, causal=causal)
     seq_sharded = P(None, seq_axis, None, None)
     pos_sharded = P(None, seq_axis)
-    return jaxcompat.shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(seq_sharded, seq_sharded, seq_sharded, pos_sharded, pos_sharded),

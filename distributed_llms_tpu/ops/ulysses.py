@@ -25,7 +25,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..core import jaxcompat
 from . import flash
 
 
@@ -41,7 +40,7 @@ def ulysses_attention(
     """Ulysses attention body — call *inside* ``shard_map`` with the sequence
     axis sharded over ``axis_name``.  Returns [B, T_local, H, D]."""
     try:
-        s = jaxcompat.axis_size(axis_name)
+        s = jax.lax.axis_size(axis_name)
     except NameError as e:
         raise RuntimeError(
             f"ulysses attention needs a bound {axis_name!r} mesh axis — call "

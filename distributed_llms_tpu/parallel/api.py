@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core import jaxcompat
 from ..core.config import MeshConfig, ModelConfig
 from ..models import model as model_lib
 from ..models.model import KVCache
@@ -62,8 +61,12 @@ def _axis_sz(mesh: Mesh, name) -> int:
     return sz
 
 
-def _place_quantized(leaf, spec: P, mesh: Mesh, path: str):
-    """Shard a QuantizedTensor under the plain weight's PartitionSpec.
+def quantized_layout(
+    data_shape: tuple, scale_shape: tuple, bits: int, pack_axis: int,
+    spec: P, mesh: Mesh, path: str,
+) -> tuple[P, int]:
+    """How a QuantizedTensor shards under the plain weight's PartitionSpec:
+    (the spec for data AND scale, the scale refinement factor).
 
     data shards exactly like the weight (for int4 the pack axis holds
     adjacent-row pairs, so a contiguous shard of packed rows unpacks to the
@@ -73,51 +76,57 @@ def _place_quantized(leaf, spec: P, mesh: Mesh, path: str):
     numerically identical) until shard boundaries land on block boundaries.
     Un-shardable layouts replicate the leaf, loudly.
     """
-    from ..checkpoint.quantize import QuantizedTensor
     from ..core.observability import get_logger
 
-    data, scale = leaf.data, leaf.scale
-    s = tuple(spec)
-    s = s + (None,) * (data.ndim - len(s))  # pad to rank; trailing = replicated
+    rank = len(data_shape)
+    s = tuple(spec) + (None,) * (rank - len(spec))  # trailing = replicated
 
-    def replicate(reason: str):
+    def replicate(reason: str) -> tuple[P, int]:
         get_logger("parallel").warning(
             "quantized leaf %s cannot shard under %s (%s); replicating",
             path, spec, reason,
         )
-        rep = NamedSharding(mesh, P())
-        return QuantizedTensor(
-            data=jax.device_put(data, rep), scale=jax.device_put(scale, rep),
-            bits=leaf.bits, orig_shape=leaf.orig_shape, pack_axis=leaf.pack_axis,
-        )
+        return P(), 1
 
-    pack_ax = data.ndim + leaf.pack_axis if leaf.bits == 4 else None
     # Divisibility of every sharded data axis (jax would raise; we want the
     # replicate fallback instead).
     for ax, name in enumerate(s):
-        if _axis_sz(mesh, name) > 1 and data.shape[ax] % _axis_sz(mesh, name):
-            return replicate(f"data axis {ax} ({data.shape[ax]}) % shards")
-    last = data.ndim - 1
+        if _axis_sz(mesh, name) > 1 and data_shape[ax] % _axis_sz(mesh, name):
+            return replicate(f"data axis {ax} ({data_shape[ax]}) % shards")
+    last = rank - 1
     tp_last = _axis_sz(mesh, s[last])
-    if tp_last > 1 and pack_ax == last:
+    if tp_last > 1 and bits == 4 and rank + pack_axis == last:
         return replicate("spec shards the int4 pack axis at the last dim")
+    repeat = 1
     if tp_last > 1:
-        dim = data.shape[last]  # last axis is never int4-packed here
-        n_blocks = scale.shape[-1]
-        block = dim // n_blocks
+        dim = data_shape[last]  # last axis is never int4-packed here
+        block = dim // scale_shape[-1]
         per_shard = dim // tp_last
         if per_shard % block:
             # Refine: new block g divides both the old block and the shard
             # width, so each shard holds whole (finer) blocks.
             import math
 
-            g = math.gcd(block, per_shard)
-            scale = jnp.repeat(scale, block // g, axis=-1)
+            repeat = block // math.gcd(block, per_shard)
     # scale has data's rank (last axis in block units; the int4 pack axis is
     # 2x data's, divisible whenever data's is) — the same spec applies.
+    return P(*s), repeat
+
+
+def _place_quantized(leaf, spec: P, mesh: Mesh, path: str):
+    """Shard a QuantizedTensor under the plain weight's PartitionSpec
+    (:func:`quantized_layout`)."""
+    from ..checkpoint.quantize import QuantizedTensor
+
+    spec, repeat = quantized_layout(
+        leaf.data.shape, leaf.scale.shape, leaf.bits, leaf.pack_axis, spec,
+        mesh, path,
+    )
+    scale = leaf.scale if repeat == 1 else jnp.repeat(leaf.scale, repeat, axis=-1)
+    sharding = NamedSharding(mesh, spec)
     return QuantizedTensor(
-        data=jax.device_put(data, NamedSharding(mesh, P(*s))),
-        scale=jax.device_put(scale, NamedSharding(mesh, P(*s))),
+        data=jax.device_put(leaf.data, sharding),
+        scale=jax.device_put(scale, sharding),
         bits=leaf.bits, orig_shape=leaf.orig_shape, pack_axis=leaf.pack_axis,
     )
 
@@ -312,28 +321,12 @@ class ParallelModel:
 
     # -- execution ---------------------------------------------------------
 
-    @staticmethod
-    def _require_native_seq() -> None:
-        """The seq-parallel schedules execute only on the jax >= 0.5
-        shard_map: under the 0.4.x experimental one (check_rep off, no vma
-        types) the compiled ring/merge programs abort XLA:CPU outright —
-        a hard process crash, not a failure — so refuse up front.  Abstract
-        tracing (tools/graftcheck) goes through ops.ring/ops.ulysses
-        directly and stays available on every runtime."""
-        if not hasattr(jax, "shard_map"):
-            raise RuntimeError(
-                "sequence-parallel execution requires jax >= 0.5 "
-                "(jax.shard_map); this runtime has only the experimental "
-                "shard_map, whose compiled seq schedules crash XLA:CPU"
-            )
-
     def _seq_forward(self, params, tokens, positions, remat):
         """Full forward under shard_map over {'seq'}: sequence axis sharded,
         global positions passed through so RoPE/causality stay correct;
         attention runs the ppermute ring (ops/ring.py) or, when the user set
         attn_impl='ulysses', the all-to-all head scatter (ops/ulysses.py);
         'data'/'model' axes remain GSPMD-auto inside the body."""
-        self._require_native_seq()
         cfg = _seq_cfg(self.cfg)
         b, t = tokens.shape
         if positions is None:
@@ -345,7 +338,7 @@ class ParallelModel:
             )
             return logits
 
-        return jaxcompat.shard_map(
+        return jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(P(), P(None, "seq"), P(None, "seq")),
@@ -356,7 +349,6 @@ class ParallelModel:
     def _seq_prefill_cached(self, params, tokens, positions, cache, cache_index, remat):
         """Cached prefill under 'seq': tokens sharded over the sequence,
         each device writes its prefill-region KV block locally."""
-        self._require_native_seq()
         cfg = _seq_cfg(self.cfg)
         b, t = tokens.shape
         seq_ax = self.mesh.shape["seq"]
@@ -379,7 +371,7 @@ class ParallelModel:
             return logits, npk, npv, ndk, ndv
 
         seq_kv = P(None, None, "seq", None, None)
-        logits, npk, npv, ndk, ndv = jaxcompat.shard_map(
+        logits, npk, npv, ndk, ndv = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(P(), P(None, "seq"), P(None, "seq"), seq_kv, seq_kv, P(), P()),
@@ -391,7 +383,6 @@ class ParallelModel:
     def _seq_decode_cached(self, params, tokens, positions, cache, cache_index, attn_mask, remat):
         """Single-token decode over the seq-sharded cache: partial softmax
         stats merge across 'seq' with one psum; the query is replicated."""
-        self._require_native_seq()
         cfg = _seq_cfg(self.cfg)
         (pk, dk), (pv, dv) = cache.k, cache.v
         t_pref = pk.shape[2]
@@ -413,7 +404,7 @@ class ParallelModel:
             return logits, npk, npv, ndk, ndv
 
         seq_kv = P(None, None, "seq", None, None)
-        logits, npk, npv, ndk, ndv = jaxcompat.shard_map(
+        logits, npk, npv, ndk, ndv = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(P(), P(), P(), seq_kv, seq_kv, P(), P(),
@@ -437,8 +428,8 @@ class ParallelModel:
         #   (sliding-window decode under the right-padded generate layout)
         kv_tables: jax.Array | None = None,  # [B, P] page table — the cache
         #   holds page POOLS sharded over 'model' on KV heads (mesh-native
-        #   paged serving; GSPMD path only — the paged decode kernel's
-        #   custom_partitioning rule partitions it)
+        #   paged serving; GSPMD path only — the paged decode kernel runs
+        #   per shard on its local heads)
     ) -> tuple[jax.Array, KVCache | None] | tuple[jax.Array, KVCache | None, jax.Array]:
         """Same contract as models.model.forward, but mesh-parallel.
         ``return_aux`` (MoE load-balance loss) flows through on the
@@ -490,15 +481,14 @@ class ParallelModel:
             return (logits, None, jnp.float32(0.0)) if return_aux else (logits, None)
         cfg = _local_cfg(cfg)
         if not self.pipelined:
-            # GSPMD path: mark the trace so quantized contractions route
-            # through the custom_partitioning kernel wrapper (per-shard
-            # Pallas tiles + psum over contracted axes — the bandwidth win
-            # now applies to plain-TP serving) or, on non-TPU backends /
-            # DLT_QUANT_MATMUL_SPMD=0, the dequant+einsum fallback XLA can
-            # partition.  A bare pallas_call here would all-gather weights.
-            from ..ops.quant_matmul import spmd_fallback
+            # GSPMD path: name the mesh for the trace, so the Pallas ops
+            # (quantized contractions, decode attention) run per shard
+            # under shard_map — a bare pallas_call has no SPMD
+            # partitioning.  Their dense fallbacks are plain lax ops XLA
+            # partitions itself.
+            from ..ops import dispatch
 
-            with spmd_fallback():
+            with dispatch.sharded(self.mesh):
                 return model_lib.forward(
                     params, cfg, tokens, positions=positions, cache=cache,
                     cache_index=cache_index, remat=remat, attn_mask=attn_mask,

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..core import jaxcompat
 from ..core.config import ModelConfig
 from ..models import model as model_lib
 from ..runtime import sampling
@@ -105,7 +104,7 @@ def pipeline_blocks(
         cv = cache_v[0] if use_cache else None
 
         # Mark per-stage buffers as varying over 'pipe' for vma tracking.
-        out_mb = jaxcompat.pcast(jnp.zeros_like(x_mb), ("pipe",), to="varying")
+        out_mb = jax.lax.pcast(jnp.zeros_like(x_mb), ("pipe",), to="varying")
 
         def tick(carry, t):
             state, out_mb, ck, cv = carry
@@ -164,7 +163,7 @@ def pipeline_blocks(
             )
             return (state, out_mb, ck, cv), None
 
-        state0 = jaxcompat.pcast(jnp.zeros_like(x_mb[0]), ("pipe",), to="varying")
+        state0 = jax.lax.pcast(jnp.zeros_like(x_mb[0]), ("pipe",), to="varying")
         carry = (state0, out_mb, ck, cv)
         (state, out_mb, ck, cv), _ = jax.lax.scan(
             tick, carry, jnp.arange(m + num_stages - 1)
@@ -184,7 +183,7 @@ def pipeline_blocks(
     )
     out_specs = (P("pipe"), P("pipe"), P("pipe")) if use_cache else (P("pipe"),)
 
-    result = jaxcompat.shard_map(
+    result = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
@@ -281,7 +280,7 @@ def pipeline_decode(
             return model_lib.embed(head, cfg, tok[:, None], pos[:, None])
 
         # Stage-0 state (vma-varying; other stages carry discarded copies).
-        var = lambda a: jaxcompat.pcast(a, ("pipe",), to="varying")
+        var = lambda a: jax.lax.pcast(a, ("pipe",), to="varying")
         buf0 = jnp.stack([emb(tok0_mb[m], plens_mb[m]) for m in range(m_)])
         buf = var(buf0.astype(dtype))  # [M, mb, 1, D] next-token embeds
         done0 = (tok0_mb == eos_id) if eos_id >= 0 else jnp.zeros((m_, mb), bool)
@@ -381,7 +380,7 @@ def pipeline_decode(
 
     tok0_mb = tok0.reshape(m_, mb)
     plens_mb = prompt_lens.reshape(m_, mb)
-    out_all, new_ck, new_cv = jaxcompat.shard_map(
+    out_all, new_ck, new_cv = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P("pipe"), head_specs, P(), P(), P(), P("pipe"), P("pipe")),

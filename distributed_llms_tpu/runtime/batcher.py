@@ -2024,8 +2024,8 @@ class ContinuousBatcher:
             ):
                 # Mesh-native paged serving: the pool shards its KV-head
                 # axis over 'model' (parallel.specs.page_pool_specs) and
-                # the paged decode kernel partitions through its SPMD rule
-                # (ops/decode_attn._paged_spmd) — each shard holds whole
+                # the paged decode kernel runs per shard under shard_map
+                # (ops/decode_attn.py) — each shard holds whole
                 # heads, so the head count must divide.  Pipelined /
                 # seq-parallel meshes fall through to the generic
                 # rejection below (paged x pipelined stays unsupported
@@ -2171,25 +2171,30 @@ class ContinuousBatcher:
         # Decode-chunk variant of the config: ragged decode attention (row b
         # reads only its cache prefix — ops/decode_attn.py) when the kernel
         # would actually run (TPU, or DLT_RAGGED_DECODE=kernel/interpret).
-        # Meshes included: the ragged/paged kernels carry their own SPMD
-        # partitioning rules now (ops/decode_attn._ragged_spmd/_paged_spmd
-        # — each shard runs its local head slice; DLT_DECODE_ATTN_SPMD=0
-        # is the kill-switch).  Not on the CPU "fallback" mode, whose
-        # dense math is a different op from the masked dot path (the
-        # exact-token invariant is against the latter).
+        # Meshes included: the ragged/paged kernels run per shard under
+        # shard_map (ops/decode_attn.py — each shard its local head
+        # slice).  Not on the CPU "fallback" mode, whose dense math is a
+        # different op from the masked dot path (the exact-token invariant
+        # is against the latter); that choice goes on the dispatch record
+        # like any other fallback.
         import dataclasses
 
-        from ..ops import decode_attn
+        from ..ops import decode_attn, dispatch
 
         # (Sliding-window models ride the ragged kernel too: it takes the
         # window bound and reads only [length - window, length) per row —
         # slot == position in this contiguous layout, so the slot-space
         # band equals the position-space window exactly.)
-        self.cfg_decode = (
-            dataclasses.replace(cfg, ragged_decode=True)
-            if decode_attn._mode() != "fallback"
-            else cfg
-        )
+        if decode_attn._mode() != "fallback":
+            self.cfg_decode = dataclasses.replace(cfg, ragged_decode=True)
+        else:
+            self.cfg_decode = cfg
+            if paged_pages is None:  # paged decode records its own path
+                dispatch.record(
+                    "ragged_decode", "fallback",
+                    (batch_slots, max_len, cfg.num_heads, cfg.num_kv_heads,
+                     cfg.head_dim_),
+                )
         self.params = params
         self.tokenizer = tokenizer
         self.b = batch_slots

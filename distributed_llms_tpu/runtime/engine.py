@@ -57,6 +57,19 @@ class GenerationResult:
         return self.generated_tokens / max(self.seconds, 1e-9)
 
 
+def _parallel_model(cfg: ModelConfig, rt: RuntimeConfig, mesh_cfg: Any):
+    """The ParallelModel for a multi-device ``MeshConfig``, else None."""
+    if mesh_cfg is None or mesh_cfg.num_devices <= 1:
+        return None
+    from ..parallel.api import make_parallel_model
+
+    return make_parallel_model(
+        cfg, mesh_cfg,
+        num_microbatches=max(rt.microbatches, 1),
+        kv_dtype=rt.kv_cache_dtype,
+    )
+
+
 class InferenceEngine:
     """Inference engine, single-device or mesh-parallel.
 
@@ -79,14 +92,6 @@ class InferenceEngine:
         self.cfg = cfg
         self.rt = rt
         self.parallel = parallel
-        if rt.compilation_cache_dir:
-            # Persistent compile cache: a restarted server skips the
-            # first-compile wait.  Only the dir is set here — JAX's own
-            # min-compile-time/threshold knobs stay whatever the operator
-            # configured.  Note JAX initializes the cache once per process:
-            # the first engine's dir wins; later different values are
-            # ignored by JAX, not errored.
-            jax.config.update("jax_compilation_cache_dir", rt.compilation_cache_dir)
         self.tokenizer = tokenizer or get_tokenizer(None)
         # Out-of-vocab ids silently become NaN embeddings (jnp.take fills
         # OOB gathers) — reject the mismatch loudly instead.
@@ -157,11 +162,35 @@ class InferenceEngine:
 
     @classmethod
     def from_preset(
-        cls, name: str, rt: RuntimeConfig | None = None, rng_seed: int = 0, **overrides
+        cls, name: str, rt: RuntimeConfig | None = None, rng_seed: int = 0,
+        mesh_cfg: Any = None,  # core.config.MeshConfig
+        quantization: str | None = None,  # "int8" | "int4" block weights,
+        #   required (and only used) under rt.serve_quantized
+        **overrides,
     ) -> "InferenceEngine":
+        """Random weights from a seed at a preset's shapes, optionally
+        mesh-parallel like :meth:`from_store`.  With ``rt.serve_quantized``
+        the block weights are generated quantized, leaf by leaf and already
+        sharded (models.model.init_params_quantized) — the only way a
+        full-width 7B preset fits one chip."""
         cfg = get_preset(name, **overrides)
-        params = model_lib.init_params(jax.random.key(rng_seed), cfg)
-        return cls(cfg, rt or RuntimeConfig(), params)
+        rt = rt or RuntimeConfig()
+        parallel = _parallel_model(cfg, rt, mesh_cfg)
+        key = jax.random.key(rng_seed)
+        if rt.serve_quantized:
+            bits = {"int8": 8, "int4": 4}.get(quantization)
+            if bits is None:
+                raise ValueError(
+                    "serve_quantized=True on a preset needs "
+                    "checkpoint.quantization='int8'|'int4', got "
+                    f"{quantization!r}"
+                )
+            params = model_lib.init_params_quantized(
+                key, cfg, bits, mesh=parallel.mesh if parallel else None
+            )
+        else:
+            params = model_lib.init_params(key, cfg)
+        return cls(cfg, rt, params, parallel=parallel)
 
     @classmethod
     def from_store(
@@ -229,16 +258,8 @@ class InferenceEngine:
             }
         else:
             params = store_lib.reconstruct(store_dir, dtype=cfg.dtype)
-        parallel = None
-        if mesh_cfg is not None and mesh_cfg.num_devices > 1:
-            from ..parallel.api import make_parallel_model
-
-            parallel = make_parallel_model(
-                cfg, mesh_cfg,
-                num_microbatches=max(rt.microbatches, 1),
-                kv_dtype=rt.kv_cache_dtype,
-            )
-        return cls(cfg, rt, params, tokenizer=tokenizer, parallel=parallel)
+        return cls(cfg, rt, params, tokenizer=tokenizer,
+                   parallel=_parallel_model(cfg, rt, mesh_cfg))
 
     def _batch_multiple(self) -> int:
         """Batch rows must divide evenly over the data axis, times the

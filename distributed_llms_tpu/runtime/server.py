@@ -82,6 +82,7 @@ import threading
 import time
 import uuid
 
+from ..core import profiling
 from ..core.observability import METRICS, get_logger
 from .scheduler import ANON_TENANT
 
@@ -297,6 +298,10 @@ class InferenceServer:
                 "KV handoff ships content-addressed pool pages"
             )
         self.batcher = batcher
+        # Which backend this replica really runs on, and which devices hold
+        # its weights: fixed for the server's life (a respawned batcher
+        # shares the engine's params by reference).
+        self.device = profiling.device_report(batcher.params)
         self.model_name = model_name
         self.host = host
         self.port = port
@@ -989,6 +994,7 @@ class InferenceServer:
             # decode-capable replicas and handoffs only on prefill ones —
             # the role rides the same probe that carries health.
             "role": self.role,
+            "device": self.device,
             "engine_alive": alive,
             "engine_stalled": stalled,
             "seconds_since_last_chunk": round(age, 3),
@@ -1021,6 +1027,7 @@ class InferenceServer:
             pool = getattr(self.batcher, "pool", None)
             if pool is not None:
                 pool.publish_gauges()
+            profiling.record_memory_stats()
             await self._respond(
                 writer, 200, "text/plain; version=0.0.4; charset=utf-8",
                 METRICS.prometheus_text().encode(),

@@ -29,13 +29,15 @@ def _rand(key, shape, dtype=jnp.float32):
         #   block stepping must keep the kernel (bk=128), not fall back dense
     ],
 )
-def test_kernel_matches_dense_reference(monkeypatch, b, s, h, kvh, d, lengths):
+def test_kernel_matches_dense_reference(monkeypatch, dispatched, b, s, h,
+                                        kvh, d, lengths):
     monkeypatch.setenv("DLT_RAGGED_DECODE", "interpret")
     q = _rand(0, (b, 1, h, d))
     k = _rand(1, (b, s, kvh, d))
     v = _rand(2, (b, s, kvh, d))
     ln = jnp.asarray(lengths, jnp.int32)
     got = decode_attn.ragged_decode_attention(q, k, v, ln)
+    assert dispatched() == {"ragged_decode.interpret": 1}
     want = decode_attn._dense_reference(q, k, v, ln)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
@@ -105,7 +107,8 @@ def test_block_stepping_keeps_kernel_at_384(monkeypatch):
         (4, 64, 8, 8, 8, 8, 128, [64, 1, 33, 17]),  # tiny 8-slot pages
     ],
 )
-def test_paged_matches_contiguous(monkeypatch, b, pool, blk, pages, h, kvh, d, lengths):
+def test_paged_matches_contiguous(monkeypatch, dispatched, b, pool, blk,
+                                  pages, h, kvh, d, lengths):
     """Rows' KV scattered over a shuffled page pool must attend exactly like
     the same data laid out contiguously."""
     monkeypatch.setenv("DLT_RAGGED_DECODE", "interpret")
@@ -124,14 +127,16 @@ def test_paged_matches_contiguous(monkeypatch, b, pool, blk, pages, h, kvh, d, l
     )
     ln = jnp.asarray(lengths, jnp.int32)
     got = decode_attn.paged_decode_attention(q, k_pool, v_pool, ln, tables)
+    assert dispatched() == {"paged_decode.interpret": 1}
     want = decode_attn._dense_reference(q, k_rows, v_rows, ln)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
     )
 
 
-def test_paged_fallback_matches_reference(monkeypatch):
-    """The dense fallback (untileable head_dim) gathers pages correctly."""
+def test_paged_fallback_matches_reference(monkeypatch, dispatched):
+    """The dense fallback (untileable head_dim) gathers pages correctly,
+    and leaves its trace on the dispatch record."""
     monkeypatch.setenv("DLT_RAGGED_DECODE", "interpret")
     b, pool, blk, pages, h, d = 2, 8, 16, 2, 4, 64  # d=64: fallback path
     tables = jnp.asarray([[3, 0], [5, 7]], jnp.int32)
@@ -146,13 +151,14 @@ def test_paged_fallback_matches_reference(monkeypatch):
     )
     ln = jnp.asarray([17, 32], jnp.int32)
     got = decode_attn.paged_decode_attention(q, k_pool, v_pool, ln, tables)
+    assert dispatched() == {"paged_decode.fallback": 1}
     want = decode_attn._dense_reference(q, k_rows, v_rows, ln)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
     )
 
 
-def test_untileable_head_dim_falls_back(monkeypatch):
+def test_untileable_head_dim_falls_back(monkeypatch, dispatched):
     """d=64 is not a 128-lane multiple: the dense fallback must serve it."""
     monkeypatch.setenv("DLT_RAGGED_DECODE", "interpret")
     q = _rand(0, (2, 1, 4, 64))
@@ -160,10 +166,61 @@ def test_untileable_head_dim_falls_back(monkeypatch):
     v = _rand(2, (2, 128, 4, 64))
     ln = jnp.asarray([5, 99], jnp.int32)
     got = decode_attn.ragged_decode_attention(q, k, v, ln)
+    assert dispatched() == {"ragged_decode.fallback": 1}
     want = decode_attn._dense_reference(q, k, v, ln)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
     )
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_sharded_kernels_match_single_shard(monkeypatch, devices8, dispatched,
+                                            quant):
+    """Under a tensor-parallel mesh (dispatch.sharded) the ragged and paged
+    kernels run per shard inside shard_map — each shard its local KV-head
+    slice, no collective — and equal the single-shard call bit for bit,
+    bf16 and int8 pages alike; the record counts the traces under
+    ``shard_map``."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_llms_tpu.checkpoint.quantize import kv_quantize
+    from distributed_llms_tpu.ops import dispatch
+
+    monkeypatch.setenv("DLT_RAGGED_DECODE", "interpret")
+    mesh = Mesh(np.array(devices8[:2]).reshape(1, 2), ("data", "model"))
+    b, s, blk, h, kvh, d = 2, 128, 64, 4, 2, 128
+    q = _rand(0, (b, 1, h, d))
+    k, v = _rand(1, (b, s, kvh, d)), _rand(2, (b, s, kvh, d))
+    ln = jnp.asarray([70, 128], jnp.int32)
+    tables = jnp.asarray([[2, 0], [1, 3]], jnp.int32)
+
+    def pool(rows):  # row-major pages of a [B, S, ...] array
+        return jnp.zeros((4, blk, *rows.shape[2:]), rows.dtype).at[
+            tables.reshape(-1)].set(rows.reshape(b * 2, blk, *rows.shape[2:]))
+
+    scales, pool_scales = {}, {}
+    if quant:
+        (k, ks), (v, vs) = kv_quantize(k), kv_quantize(v)
+        scales = dict(k_scale=ks, v_scale=vs)
+        pool_scales = dict(k_scale=pool(ks), v_scale=pool(vs))
+    want_r = decode_attn.ragged_decode_attention(q, k, v, ln, **scales)
+    want_p = decode_attn.paged_decode_attention(
+        q, pool(k), pool(v), ln, tables, **pool_scales)
+    heads = NamedSharding(mesh, P(None, None, "model", None))
+    put = lambda x: jax.device_put(
+        x, heads if x.ndim == 4 else NamedSharding(mesh, P(None, None, "model")))
+    base = dispatched()
+    with dispatch.sharded(mesh):
+        got_r = jax.jit(decode_attn.ragged_decode_attention)(
+            put(q), put(k), put(v), ln,
+            **{n: put(x) for n, x in scales.items()})
+        got_p = jax.jit(decode_attn.paged_decode_attention)(
+            put(q), put(pool(k)), put(pool(v)), ln, tables,
+            **{n: put(x) for n, x in pool_scales.items()})
+    np.testing.assert_array_equal(np.asarray(got_r), np.asarray(want_r))
+    np.testing.assert_array_equal(np.asarray(got_p), np.asarray(want_p))
+    new = {k_: v_ - base.get(k_, 0) for k_, v_ in dispatched().items()}
+    assert new["ragged_decode.shard_map"] == new["paged_decode.shard_map"] == 1
 
 
 def test_batcher_exact_tokens_with_ragged_decode(monkeypatch):

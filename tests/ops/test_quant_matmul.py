@@ -111,13 +111,15 @@ def test_parity_bf16_activations(kernel_calls):
     )
 
 
-def test_untileable_falls_back(kernel_calls):
-    """K not divisible by any tile candidate → clean fallback, same answer."""
+def test_untileable_falls_back(kernel_calls, dispatched):
+    """K not divisible by any tile candidate → clean fallback, same answer,
+    and the dispatch record says so."""
     qt = _make((100, 256), 8, pack_axis=-2)
     x = jax.random.normal(jax.random.key(6), (4, 100), jnp.float32)
     got = qm.quant_contract(x, qt, 1, "mk,kn->mn", interpret=True)
     want = _fallback(x, qt, "mk,kn->mn")
     assert len(kernel_calls) == 0
+    assert dispatched() == {"quant_matmul.fallback": 1}
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
 
 
@@ -132,7 +134,7 @@ def test_int4_wrong_pack_axis_falls_back(kernel_calls):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
 
 
-def test_env_interpret_mode(monkeypatch, kernel_calls):
+def test_env_interpret_mode(monkeypatch, kernel_calls, dispatched):
     """DLT_QUANT_MATMUL=interpret (the CI leg) routes through the kernel in
     interpret mode without the caller passing interpret=True."""
     monkeypatch.setenv("DLT_QUANT_MATMUL", "interpret")
@@ -141,13 +143,15 @@ def test_env_interpret_mode(monkeypatch, kernel_calls):
     got = qm.quant_contract(x, qt, 1, "mk,kn->mn")
     want = _fallback(x, qt, "mk,kn->mn")
     assert len(kernel_calls) == 1
+    assert dispatched() == {"quant_matmul.interpret": 1}
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
-def test_env_fallback_mode(monkeypatch, kernel_calls):
+def test_env_fallback_mode(monkeypatch, kernel_calls, dispatched):
     """DLT_QUANT_MATMUL=fallback forces einsum even where tileable."""
     monkeypatch.setenv("DLT_QUANT_MATMUL", "fallback")
     qt = _make((256, 256), 8, pack_axis=-2)
     x = jax.random.normal(jax.random.key(9), (4, 256), jnp.float32)
     qm.quant_contract(x, qt, 1, "mk,kn->mn")
     assert len(kernel_calls) == 0
+    assert dispatched() == {"quant_matmul.fallback": 1}

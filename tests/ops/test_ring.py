@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distributed_llms_tpu.core import jaxcompat
 from distributed_llms_tpu.core.mesh import mesh_from_devices
 from distributed_llms_tpu.models import layers
 from distributed_llms_tpu.ops import ring
@@ -87,7 +86,7 @@ def test_ring_fully_masked_rows_are_zero():
         )
     sh = P(None, "seq", None, None)
     ps = P(None, "seq")
-    out = jaxcompat.shard_map(
+    out = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(sh, sh, sh, ps, ps, ps),

@@ -13,7 +13,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from distributed_llms_tpu.core.config import MeshConfig, ModelConfig
-from distributed_llms_tpu.core import jaxcompat
 from distributed_llms_tpu.core.mesh import mesh_from_devices
 from distributed_llms_tpu.models import layers, model as model_lib
 from distributed_llms_tpu.ops import ulysses
@@ -29,7 +28,7 @@ def _reference(q, k, v, positions, causal, q_per_kv):
 def _run(mesh, q, k, v, positions, causal=True):
     sh = P(None, "seq", None, None)
     ps = P(None, "seq")
-    return jaxcompat.shard_map(
+    return jax.shard_map(
         lambda q, k, v, p: ulysses.ulysses_attention(
             q, k, v, p, axis_name="seq", causal=causal
         ),

@@ -2,10 +2,10 @@
 next-step 9, r3 next-step 7): mesh placement keeps QuantizedTensor leaves —
 data and scale sharded under the plain weight's PartitionSpec, scale blocks
 refined where a shard boundary would split a block — instead of rehydrating
-to full dtype.  The GSPMD forward routes quantized contractions through the
-custom_partitioning kernel wrapper whenever the kernel would run (per-shard
-Pallas tiles; the bandwidth win applies to plain-TP serving), falling back
-to dequantize+einsum on non-TPU backends or DLT_QUANT_MATMUL_SPMD=0.
+to full dtype.  The GSPMD forward runs quantized contractions per shard
+under shard_map whenever the kernel would run (per-shard Pallas tiles; the
+bandwidth win applies to plain-TP serving), falling back to
+dequantize+einsum on non-TPU backends.
 """
 
 import jax
@@ -59,6 +59,38 @@ def test_unshardable_leaf_replicates(devices8):
     )
 
 
+def test_preset_weights_are_born_quantized_and_sharded(devices8):
+    """InferenceEngine.from_preset under serve_quantized builds the block
+    weights quantized leaf by leaf (models.model.init_params_quantized) —
+    on a mesh already under param_specs' sharding, scale blocks refined
+    like placement refines them — and, the generator's values not
+    depending on the mesh, serves the single-device engine's tokens."""
+    rt = RuntimeConfig(max_decode_steps=6, serve_quantized=True)
+    kw = dict(rt=rt, quantization="int8", vocab_size=512)
+    ref = InferenceEngine.from_preset("llama-tiny", **kw)
+    eng = InferenceEngine.from_preset(
+        "llama-tiny", mesh_cfg=MeshConfig(data=2, model=4), **kw
+    )
+    for e in (ref, eng):
+        blocks = e.params["blocks"]
+        assert len(_qleaves(blocks)) == 7  # wq wk wv wo + the three MLP
+        assert not _qleaves({k: v for k, v in e.params.items() if k != "blocks"})
+    w_gate = eng.params["blocks"]["mlp"]["w_gate"]
+    assert w_gate.data.sharding.spec == P(None, None, "model")
+    # 176 columns over 4 shards = 44 a shard: the 16-wide blocks refine to 4.
+    assert w_gate.scale.shape[-1] == 44
+    assert ref.params["blocks"]["mlp"]["w_gate"].scale.shape[-1] == 11
+    np.testing.assert_array_equal(
+        np.asarray(quant_lib.dequantize(w_gate)),
+        np.asarray(quant_lib.dequantize(ref.params["blocks"]["mlp"]["w_gate"])),
+    )
+    out_ref = ref.generate_text(["born quantized"], max_new_tokens=6)
+    out = eng.generate_text(["born quantized"], max_new_tokens=6)
+    assert out.tokens.tolist() == out_ref.tokens.tolist()
+    with pytest.raises(ValueError, match="checkpoint.quantization"):
+        InferenceEngine.from_preset("llama-tiny", rt=rt, vocab_size=512)
+
+
 @pytest.mark.parametrize("quantization", ["int8", "int4"])
 def test_tp_mesh_serves_quantized_resident(tmp_path, devices8, quantization):
     """data=2 x model=4 mesh: block weights stay quantized on the mesh and
@@ -91,39 +123,36 @@ def test_tp_mesh_serves_quantized_resident(tmp_path, devices8, quantization):
 
 
 @pytest.mark.parametrize(
-    "case,wshape,wspec,xshape,xspec,k_lead,eq",
+    "case,wshape,wspec,xshape,xspec,k_lead,eq,shard",
     [
         # Shapes chosen so the LOCAL shard is kernel-tileable (block=128,
         # local n a multiple of 128) — the Pallas program, not the dequant
         # fallback, is what runs per shard (asserted via the spy below).
         ("w_in N-sharded", (256, 1024), P(None, "model"), (4, 256),
-         P("data", None), 1, "md,df->mf"),
+         P("data", None), 1, "md,df->mf", "n"),
         ("wq head-sharded", (256, 4, 128), P(None, "model", None), (4, 256),
-         P("data", None), 1, "md,dhk->mhk"),
+         P("data", None), 1, "md,dhk->mhk", "n"),
         ("wo K-sharded psum", (4, 128, 256), P("model", None, None),
-         (4, 4, 128), P("data", "model", None), 2, "mhk,hkd->md"),
+         (4, 4, 128), P("data", "model", None), 2, "mhk,hkd->md", "k"),
         ("x batched 3d", (256, 1024), P(None, "model"), (2, 3, 256),
-         P("data", None, None), 1, "btd,df->btf"),
+         P("data", None, None), 1, "btd,df->btf", "n"),
     ],
 )
-def test_spmd_kernel_wrapper_partitions(
-    devices8, monkeypatch, case, wshape, wspec, xshape, xspec, k_lead, eq
+def test_sharded_kernel_partitions(
+    devices8, monkeypatch, case, wshape, wspec, xshape, xspec, k_lead, eq,
+    shard,
 ):
-    """DLT_QUANT_MATMUL_SPMD=1: the custom_partitioning wrapper runs the
-    kernel program per shard under GSPMD (interpret mode on CPU) — N-sharded
-    weights embarrassingly parallel, K-sharded wo with a psum — matching the
-    dense dequant+einsum exactly.  (The block *scan* cannot take this path
-    yet — custom_partitioning under lax.scan hits a JAX op_sharding
-    unflattening bug — so this pins the op-level contract.)"""
+    """Under a tensor-parallel mesh the kernel program runs per shard
+    inside shard_map (interpret mode on CPU) — N-sharded weights
+    embarrassingly parallel, K-sharded wo with a psum — matching the dense
+    dequant+einsum exactly."""
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
 
     from distributed_llms_tpu.checkpoint.quantize import dequantize, quantize
-    from distributed_llms_tpu.ops import quant_matmul as qm
+    from distributed_llms_tpu.ops import dispatch, quant_matmul as qm
 
-    monkeypatch.setenv("DLT_QUANT_MATMUL_SPMD", "1")
     monkeypatch.setenv("DLT_QUANT_MATMUL", "interpret")
-    qm._qmm_spmd.cache_clear()  # fresh wrapper so the spy below is seen
     kernel_calls = []
     orig = qm._quant_matmul_2d
     monkeypatch.setattr(
@@ -142,18 +171,15 @@ def test_spmd_kernel_wrapper_partitions(
         jax.random.normal(jax.random.key(1), xshape, jnp.float32),
         NamedSharding(mesh, xspec),
     )
-    token = qm._SPMD_FALLBACK.set(True)
-    try:
+    with dispatch.sharded(mesh):
         f = jax.jit(lambda x_, d_, s_: qm.quant_contract(
             x_,
             type(qt)(data=d_, scale=s_, bits=qt.bits,
                      orig_shape=qt.orig_shape, pack_axis=qt.pack_axis),
-            k_lead, eq,
+            k_lead, eq, shard=shard,
         ))
         y = f(x, sharded.data, sharded.scale)
-    finally:
-        qm._SPMD_FALLBACK.reset(token)
-    assert kernel_calls, "Pallas kernel program was not run under the wrapper"
+    assert kernel_calls, "Pallas kernel program was not run per shard"
     ref = jnp.einsum(eq, x, dequantize(qt, x.dtype))
     np.testing.assert_allclose(
         np.asarray(y), np.asarray(ref), rtol=2e-5, atol=2e-5
@@ -161,22 +187,17 @@ def test_spmd_kernel_wrapper_partitions(
 
 
 @pytest.mark.parametrize("stacked_xs", [False, True])
-def test_spmd_kernel_wrapper_under_scan(devices8, monkeypatch, stacked_xs):
-    """The wrapper compiles and matches the dense reference INSIDE a
-    ``lax.scan`` — both with scan-invariant (closed-over) weights, the shape
-    of the decode loop, and with stacked weights scanned as xs, the shape of
-    the layer loop.  Earlier JAX releases failed here (op_sharding superdim
-    KeyError — the round-3 reason GSPMD serving was forced onto the
-    dequant+einsum fallback); this pins the fix the default path now relies
-    on.  If it regresses after a JAX upgrade, set DLT_QUANT_MATMUL_SPMD=0."""
+def test_sharded_kernel_under_scan(devices8, monkeypatch, stacked_xs):
+    """The per-shard kernel compiles and matches the dense reference INSIDE
+    a ``lax.scan`` — both with scan-invariant (closed-over) weights, the
+    shape of the decode loop, and with stacked weights scanned as xs, the
+    shape of the layer loop."""
     from jax.sharding import NamedSharding
 
     from distributed_llms_tpu.checkpoint.quantize import dequantize, quantize
-    from distributed_llms_tpu.ops import quant_matmul as qm
+    from distributed_llms_tpu.ops import dispatch, quant_matmul as qm
 
     monkeypatch.setenv("DLT_QUANT_MATMUL", "interpret")
-    monkeypatch.delenv("DLT_QUANT_MATMUL_SPMD", raising=False)  # auto
-    qm._qmm_spmd.cache_clear()
     kernel_calls = []
     orig = qm._quant_matmul_2d
     monkeypatch.setattr(
@@ -185,7 +206,7 @@ def test_spmd_kernel_wrapper_under_scan(devices8, monkeypatch, stacked_xs):
     )
     mesh = Mesh(np.array(devices8).reshape(2, 4), ("data", "model"))
     # Local N per 'model' shard must stay kernel-tileable (>=128, block 128)
-    # or the wrapper's per-shard dispatch takes its internal dequant branch
+    # or the per-shard dispatch takes its internal dequant branch
     # and the spy below would prove nothing.
     L, d = 3, 1024
     w = jax.random.normal(jax.random.key(0), (L, d, d), jnp.float32) * d**-0.5
@@ -201,7 +222,7 @@ def test_spmd_kernel_wrapper_under_scan(devices8, monkeypatch, stacked_xs):
     def layer(c, d_, s_):
         q = type(qt)(data=d_, scale=s_, bits=qt.bits,
                      orig_shape=(d, d), pack_axis=qt.pack_axis)
-        return qm.quant_contract(c, q, 1, "md,df->mf")
+        return qm.quant_contract(c, q, 1, "md,df->mf", shard="n")
 
     if stacked_xs:
         def f(x_, d_, s_):
@@ -214,11 +235,8 @@ def test_spmd_kernel_wrapper_under_scan(devices8, monkeypatch, stacked_xs):
                 return layer(c, d_[0], s_[0]), None
             return jax.lax.scan(body, x_, None, length=L)[0]
 
-    token = qm._SPMD_FALLBACK.set(True)
-    try:
+    with dispatch.sharded(mesh):
         y = jax.jit(f)(x, data, scale)
-    finally:
-        qm._SPMD_FALLBACK.reset(token)
     assert kernel_calls, "kernel program did not run under the scan"
     ref = np.asarray(x)
     wd = np.asarray(dequantize(qt, jnp.float32))
@@ -234,7 +252,7 @@ def test_spmd_kernel_wrapper_under_scan(devices8, monkeypatch, stacked_xs):
 def test_tp_mesh_quantized_kernel_active(tmp_path, devices8, monkeypatch):
     """VERDICT r3 next-step 7 done-criterion: plain-TP (GSPMD) quantized
     serving dispatches the fused kernel program (spy on _quant_matmul_2d —
-    the Pallas program itself, wrapped by custom_partitioning) under the
+    the Pallas program itself, per shard under shard_map) under the
     layer scan AND the decode scan, and the tokens match fallback serving
     exactly."""
     from distributed_llms_tpu.ops import quant_matmul as qm
@@ -255,8 +273,6 @@ def test_tp_mesh_quantized_kernel_active(tmp_path, devices8, monkeypatch):
     out_ref = ref.generate_text(["kernel under gspmd"], max_new_tokens=4)
 
     monkeypatch.setenv("DLT_QUANT_MATMUL", "interpret")
-    monkeypatch.delenv("DLT_QUANT_MATMUL_SPMD", raising=False)  # auto: on
-    qm._qmm_spmd.cache_clear()
     kernel_calls = []
     orig = qm._quant_matmul_2d
     monkeypatch.setattr(
@@ -269,38 +285,6 @@ def test_tp_mesh_quantized_kernel_active(tmp_path, devices8, monkeypatch):
     assert _qleaves(eng.params["blocks"])
     out = eng.generate_text(["kernel under gspmd"], max_new_tokens=4)
     assert kernel_calls, "fused kernel was not dispatched under GSPMD serving"
-    assert out.tokens.tolist() == out_ref.tokens.tolist()
-
-
-def test_tp_mesh_quantized_spmd_kill_switch(tmp_path, devices8, monkeypatch):
-    """DLT_QUANT_MATMUL_SPMD=0 restores the round-3 dequant+einsum fallback
-    under GSPMD (the hardware-day escape hatch) — same tokens, no kernel."""
-    from distributed_llms_tpu.ops import quant_matmul as qm
-
-    cfg = presets.get_preset("llama-tiny", vocab_size=512)
-    params = model_lib.init_params(jax.random.key(0), cfg)
-    store_dir = str(tmp_path / "s")
-    store_lib.save_shards(
-        params, store_dir, num_shards=1, model_config=cfg, quantization="int8",
-        quant_block=32,
-    )
-    rt = RuntimeConfig(max_decode_steps=4, serve_quantized=True)
-    monkeypatch.setenv("DLT_QUANT_MATMUL", "interpret")
-    monkeypatch.setenv("DLT_QUANT_MATMUL_SPMD", "0")
-    kernel_calls = []
-    orig = qm._quant_matmul_2d
-    monkeypatch.setattr(
-        qm, "_quant_matmul_2d",
-        lambda *a, **kw: kernel_calls.append(1) or orig(*a, **kw),
-    )
-    ref = InferenceEngine.from_store(store_dir, rt=rt)
-    out_ref = ref.generate_text(["kill switch"], max_new_tokens=4)
-    n_single = len(kernel_calls)  # single-device engine: kernel allowed
-    eng = InferenceEngine.from_store(
-        store_dir, rt=rt, mesh_cfg=MeshConfig(data=2, model=4)
-    )
-    out = eng.generate_text(["kill switch"], max_new_tokens=4)
-    assert len(kernel_calls) == n_single, "kill switch did not disable wrapper"
     assert out.tokens.tolist() == out_ref.tokens.tolist()
 
 
@@ -327,10 +311,9 @@ def test_pipelined_mesh_serves_quantized_resident(tmp_path, devices8, quantizati
 
 
 def test_pipelined_mesh_kernel_inside_shard_map(tmp_path, devices8, monkeypatch):
-    """Unlike the GSPMD path (custom_partitioning + scan is blocked by a JAX
-    bug), the PIPELINED mesh runs blocks inside shard_map where operands are
-    already local — the fused kernel dispatch (_qmm_flat) runs under the
-    layer scan there.  On CPU the Pallas interpreter loses vma, so the
+    """The PIPELINED mesh runs blocks inside its own vma-checked shard_map,
+    where operands are already local — the fused kernel dispatch
+    (_qmm_flat) runs under the layer scan there.  On CPU the Pallas interpreter loses vma, so the
     numerically-identical flat-dequant branch executes (same limitation and
     same answer as ops/flash.py's interpret path); on real TPU the kernel
     lowers with vma declared.  A spy proves the kernel dispatch path (not

@@ -49,6 +49,23 @@ def _paged(cfg, params, **kw):
     return ContinuousBatcher(cfg, params, **kw)
 
 
+def test_head_dim_16_model_shows_its_fallback(tiny, monkeypatch, dispatched):
+    """llama-tiny's head dim (16) cannot tile the paged kernel: even with
+    the kernel asked for, every decode trace takes the dense path — and
+    the dispatch record (ops/dispatch.py) says so instead of staying
+    silent."""
+    monkeypatch.setenv("DLT_RAGGED_DECODE", "interpret")
+    cfg, params = tiny
+    # Shapes no other test compiles: the record is written while tracing,
+    # and a jit cache hit traces nothing.
+    b = _paged(cfg, params, batch_slots=5, max_len=80, paged_pages=13)
+    rid = b.submit([7, 1, 9], max_new_tokens=5)
+    assert b.run()[rid] == solo(cfg, params, [7, 1, 9], 5)
+    took = dispatched()
+    assert took.get("paged_decode.fallback", 0) >= 1
+    assert "paged_decode.interpret" not in took
+
+
 def test_paged_mixed_budgets_match_solo(tiny):
     """More requests than slots, mixed lengths/budgets, pool smaller than
     slots*max_len — every request equals its solo run."""

@@ -122,6 +122,12 @@ def test_health_models_metrics(tiny):
         assert health["draining"] is False
         assert health["engine_restarts"] == 0
         assert "seconds_since_last_chunk" in health
+        # The backend the replica really runs on, and where its weights
+        # sit (a failed TPU init is otherwise a silent CPU run).
+        assert health["device"] == {
+            "platform": "cpu", "device_kind": "cpu", "count": 8,
+            "weights_on": [0],
+        }
         status, body = await _request(host, port, "GET", "/v1/models")
         assert status == 200
         models = json.loads(body)

@@ -117,14 +117,13 @@ def test_gc2_catches_the_unguarded_pipe_shard_regression():
 
 
 def test_gc2_collective_axis_must_exist_on_mesh():
-    from distributed_llms_tpu.core import jaxcompat
     from jax.sharding import PartitionSpec as P
 
     def build():
         trace_mesh = fake_mesh(seq=2)
 
         def fn(x):
-            return jaxcompat.shard_map(
+            return jax.shard_map(
                 lambda x: jax.lax.psum(x, "seq"),
                 mesh=trace_mesh, in_specs=P("seq"), out_specs=P(),
                 axis_names={"seq"},
@@ -134,7 +133,7 @@ def test_gc2_collective_axis_must_exist_on_mesh():
         # all: the traced psum's axis is missing there -> GC205.
         from jax.sharding import AbstractMesh
 
-        return fn, (sds((4,), jnp.float32),), AbstractMesh((("model", 2),))
+        return fn, (sds((4,), jnp.float32),), AbstractMesh((2,), ("model",))
 
     findings = sharding.check_collectives(
         [CollectiveAudit("seeded.psum", "pkg/op.py", "seeded", build)])
@@ -170,7 +169,7 @@ def test_gc3_float64_fires_under_x64():
     contract = HotFnContract(
         "seeded.x64", "pkg/hot.py", "seeded",
         lambda: (widens, (sds((8,), jnp.float32),)), frozenset())
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         findings = dtypes.check([contract])
     assert "GC301" in _rules(findings)
     assert any("widens" in f.message for f in findings)

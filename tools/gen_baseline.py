@@ -5,8 +5,7 @@ VERDICT r3 weak #2 / next-step 8: BASELINE.md's performance claims must come
 from the measured artifact, not hand-maintained prose — a config that is
 merely *instrumented* must read NOT YET MEASURED until a row with a
 ``measured_on`` stamp exists.  This script rewrites everything between the
-AUTOGEN markers in BASELINE.md from the JSON; run it after every ladder run
-(tools/tpu_runbook.sh reminds you).
+AUTOGEN markers in BASELINE.md from the JSON; run it after every ladder run.
 """
 from __future__ import annotations
 

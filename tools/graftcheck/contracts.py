@@ -91,7 +91,7 @@ def fake_mesh(**axes: int):
     from jax.sharding import AbstractMesh
 
     names = ("data", "pipe", "model", "seq", "expert")
-    return AbstractMesh(tuple((n, axes.get(n, 1)) for n in names))
+    return AbstractMesh(tuple(axes.get(n, 1) for n in names), names)
 
 
 # ---------------------------------------------------------------------------
@@ -157,7 +157,6 @@ def _flash_cases() -> list[OpCase]:
 def _ring_cases() -> list[OpCase]:
     import functools as ft
 
-    from distributed_llms_tpu.core import jaxcompat
     from distributed_llms_tpu.ops import ring
     from jax.sharding import PartitionSpec as P
 
@@ -172,7 +171,7 @@ def _ring_cases() -> list[OpCase]:
         sh, ps = P(None, "seq", None, None), P(None, "seq")
 
         def fn(q, k, v, pos, body=body, mesh=mesh, sh=sh, ps=ps):
-            return jaxcompat.shard_map(
+            return jax.shard_map(
                 lambda q, k, v, p: body(q, k, v, p, p),
                 mesh=mesh, in_specs=(sh, sh, sh, ps), out_specs=sh,
                 axis_names={"seq"},
@@ -189,7 +188,6 @@ def _ring_cases() -> list[OpCase]:
 
 
 def _seq_decode_cases() -> list[OpCase]:
-    from distributed_llms_tpu.core import jaxcompat
     from distributed_llms_tpu.ops import ring
     from jax.sharding import PartitionSpec as P
 
@@ -200,7 +198,7 @@ def _seq_decode_cases() -> list[OpCase]:
         seq_kv = P(None, "seq", None, None)
 
         def fn(q, ck, cv, dk, dv, ml, md, mesh=mesh, seq_kv=seq_kv):
-            return jaxcompat.shard_map(
+            return jax.shard_map(
                 lambda q, ck, cv, dk, dv, ml, md:
                     ring.seq_cached_decode_attention(
                         q, ck, cv, dk, dv, ml, md, axis_name="seq"),
@@ -228,7 +226,6 @@ def _seq_decode_cases() -> list[OpCase]:
 def _ulysses_cases() -> list[OpCase]:
     import functools as ft
 
-    from distributed_llms_tpu.core import jaxcompat
     from distributed_llms_tpu.ops import ulysses
     from jax.sharding import PartitionSpec as P
 
@@ -239,7 +236,7 @@ def _ulysses_cases() -> list[OpCase]:
         body = ft.partial(ulysses.ulysses_attention, axis_name="seq")
 
         def fn(q, k, v, pos, body=body, mesh=mesh, sh=sh, ps=ps):
-            return jaxcompat.shard_map(
+            return jax.shard_map(
                 body, mesh=mesh, in_specs=(sh, sh, sh, ps), out_specs=sh,
                 axis_names={"seq"},
             )(q, k, v, pos)
@@ -346,7 +343,7 @@ def _decode_int8_cases() -> list[OpCase]:
 
 def _decode_spmd_cases() -> list[OpCase]:
     """Per-SHARD shapes of the decode-attention SPMD rule (mesh-native
-    paged serving): under `ops.decode_attn._ragged_spmd`/`_paged_spmd`
+    paged serving): under `ops.dispatch.per_shard`
     each device runs the kernel on its local head slice — H and KVH both
     divided by tp, page table and cache width intact.  These cases trace
     exactly those local calls at tp2/tp4 slices of the full-head
@@ -883,8 +880,8 @@ def _page_pool_audits() -> list[SpecAudit]:
 
 def _decode_spmd_audits() -> list[SpecAudit]:
     """The decode-attention SPMD rule's operand placement
-    (`ops.decode_attn.spmd_operand_specs` — built on the SAME axis
-    resolver the custom_partitioning lowering runs): every operand spec
+    (`ops.decode_attn.spmd_operand_specs` — the very specs the
+    shard_map dispatch runs with): every operand spec
     must name real mesh axes and divide its dims over the ladder, for
     the ragged and paged legs at both KV widths."""
     out = []

@@ -40,7 +40,7 @@ BASELINE_NAME = "graftcheck_baseline.txt"
 # Collective primitives whose axis names must exist on the mesh they are
 # traced under (GC205).  psum2 is what newer lowerings emit for psum.
 COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pmax", "pmin", "ppermute", "pbroadcast",
+    "psum", "psum_invariant", "pmax", "pmin", "ppermute", "pbroadcast",
     "all_gather", "all_to_all", "reduce_scatter", "axis_index",
 })
 

@@ -34,10 +34,12 @@ _WIDE = {jnp.dtype("float64"), jnp.dtype("complex128")}
 def _frame_of(eqn) -> tuple[str, str]:
     if source_info_util is None:
         return ("?", "?")
-    frame = source_info_util.user_frame(eqn.source_info)
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
     if frame is None:
         return ("?", "?")
-    return (frame.file_name.rsplit("/", 1)[-1], frame.function_name)
+    # function_name is the qualified name; allowlists name the bare one.
+    return (frame.file_name.rsplit("/", 1)[-1],
+            frame.function_name.rsplit(".", 1)[-1])
 
 
 def _avals(eqn):
