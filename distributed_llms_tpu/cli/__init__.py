@@ -15,8 +15,9 @@ COMPILE_CACHE_DIR = os.path.join(
 
 
 def init_backend() -> dict:
-    """Initialise the JAX backend, say once which devices it found, and
-    place the compilation cache; returns core.profiling.device_report().
+    """Initialise the JAX backend, say once which devices it found, start
+    counting compiles (``runtime.compiles_total``) and place the
+    compilation cache; returns core.profiling.device_report().
 
     Call after any ``jax_platforms`` pin and ``jax.distributed``
     initialisation, before the first jit.  Where JAX_COMPILATION_CACHE_DIR
@@ -28,8 +29,9 @@ def init_backend() -> dict:
     import jax
 
     from ..core.observability import get_logger
-    from ..core.profiling import device_report
+    from ..core.profiling import count_compiles, device_report
 
+    count_compiles()
     report = device_report()
     cache = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache and report["platform"] == "tpu":

@@ -125,6 +125,14 @@ class Metrics:
         with self._lock:
             return self._counters.get(name, 0.0)
 
+    def get_histogram(self, name: str) -> tuple[int, float]:
+        """Point read of one histogram's lifetime (count, sum) — what a
+        scrape exports as ``_count``/``_sum``; (0, 0.0) when never
+        observed.  Window differences of these are how spans are read."""
+        with self._lock:
+            h = self._hists.get(name)
+            return (h.total_count, h.total_sum) if h else (0, 0.0)
+
     def get_gauge(self, name: str, default: float = 0.0) -> float:
         with self._lock:
             return self._gauges.get(name, default)
@@ -237,18 +245,45 @@ METRIC_DOCS: dict[str, str] = {
     "batcher.overlap.carry_syncs": "decode spans ended by syncing the "
                                    "device carry into the host mirrors "
                                    "(scheduling work was pending)",
-    "batcher.overlap.host_lag_seconds": "host work per overlapped chunk "
-                                        "(D2H + delivery + digest "
-                                        "pre-hashing), concurrent with the "
-                                        "next chunk on device (histogram)",
-    "batcher.overlap.device_gap_seconds": "host time between a chunk "
-                                          "completing and the next chunk "
-                                          "dispatching — 0 by construction "
-                                          "for dispatched-ahead chunks "
-                                          "(histogram)",
     "batcher.overlap.depth": "current dispatch depth: 1 while a chunk is "
                              "dispatched ahead of its predecessor's host "
                              "work, 0 at a carry sync (gauge)",
+    # -- engine-thread spans (core.profiling.span: each is a profiler
+    #    annotation of the same name AND this histogram).  The thread is
+    #    serial, so the batcher.loop.* sums partition its wall time. --
+    "batcher.loop.admit_seconds": "one admission round (_admit_pending): "
+                                  "host work plus the device's prefill — "
+                                  "resident rows wait this long "
+                                  "(histogram)",
+    "batcher.admit.row_seconds": "one request's admission program and its "
+                                 "first-token fetch, inside "
+                                 "batcher.loop.admit (histogram; the "
+                                 "annotation carries rid, prompt_tokens, "
+                                 "cached_tokens, bucket)",
+    "batcher.loop.grow_seconds": "chunk-boundary page growth, preemption "
+                                 "included (histogram)",
+    "batcher.loop.plan_seconds": "span planning and the per-chunk "
+                                 "dispatch-ahead decision (histogram)",
+    "batcher.loop.dispatch_seconds": "enqueueing one decode chunk — host "
+                                     "cost, not device time (histogram)",
+    "batcher.loop.wait_device_seconds": "the engine thread blocked in the "
+                                        "chunk's device_get: decode on the "
+                                        "chip (histogram)",
+    "batcher.loop.deliver_seconds": "per-chunk delivery callbacks, result "
+                                    "publishing and digest pre-hashing "
+                                    "(histogram)",
+    "batcher.queue_wait_seconds": "submit (or the requeue after a "
+                                  "preemption) to admission start, one "
+                                  "sample per admission (histogram)",
+    "batcher.decode.slot_steps": "decode legs the dispatched chunks had "
+                                 "room for (slots x chunk_steps per "
+                                 "chunk): decode_tokens / slot_steps is "
+                                 "the row fill",
+    "batcher.decode.committed_tokens": "tokens the decode chunks delivered "
+                                       "to callers (admission tokens "
+                                       "excluded): committed / "
+                                       "decode_tokens is the useful share "
+                                       "of the legs run",
     # -- paged speculative decoding (batcher spec_chunk) --
     "batcher.spec.rounds": "speculative draft/verify rounds dispatched",
     "batcher.spec.accepted_tokens": "drafted tokens the verify pass "
@@ -298,6 +333,10 @@ METRIC_DOCS: dict[str, str] = {
     "server.disconnects": "requests whose client went away mid-serve",
     "server.request_seconds": "request latency, receipt to close (histogram)",
     "server.ttft_seconds": "time to first token, from receipt (histogram)",
+    "server.pre_submit_seconds": "receipt to batcher.submit: parsing, "
+                                 "tokenizing, the shed gates (histogram)",
+    "server.engine.idle_seconds": "the engine thread parked with no "
+                                  "request to serve (histogram; a span)",
     "server.request_timeouts": "requests that hit their deadline mid-flight",
     "server.requests_shed_total": "requests answered 429/503 unworked",
     "server.requests_shed.*": "shed requests by reason (queue_full, "
@@ -310,16 +349,21 @@ METRIC_DOCS: dict[str, str] = {
                                "(/v1/prefill)",
     # -- engine / sessions / profiling --
     "engine.generated_tokens": "tokens generated by engine entry points",
-    "engine.generate_seconds": "wall seconds per generate call (histogram)",
+    "engine.generate_seconds": "wall seconds per generate call (histogram; "
+                               "the engine.generate span)",
     "engine.spec_acceptance": "speculative decoding acceptance fraction",
     "kv_spill.spills": "session KV caches spilled to host DRAM",
     "kv_spill.restores": "session KV caches restored to device",
     "kv_spill.host_bytes": "bytes of session KV resident on host (gauge)",
     "kv_spill.resident_sessions": "session caches resident in HBM (gauge)",
     "kv_spill.spilled_sessions": "session caches parked on host (gauge)",
-    "*.step_seconds": "per-StepTimer step latency (histogram; name prefix "
-                      "is the timer's, e.g. engine.generate)",
-    "*.tokens_per_second": "per-StepTimer sliding-window throughput gauge",
+    "runtime.compiles_total": "trips through the backend compiler (a compile "
+                              "or a load from the persistent cache), from "
+                              "jax.monitoring; the program's name is logged "
+                              "at INFO — a recompile in a live server is a "
+                              "counter to alarm on",
+    "runtime.compile_seconds": "wall seconds spent in those trips "
+                               "(counter: the sum)",
     # -- replica fleet router (runtime/router.py + cluster/fleet.py) --
     "router.requests": "requests through the router front door",
     "router.placements": "placement decisions onto a replica",

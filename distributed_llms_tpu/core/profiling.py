@@ -7,9 +7,10 @@ plan.md:297-300).  Here:
 - :func:`trace` captures a TensorBoard/Perfetto trace of everything run
   inside it (XLA ops, host callbacks, transfers) via ``jax.profiler``;
 - :func:`annotate` labels host-side regions so they show up on the trace;
-- :class:`StepTimer` measures wall-per-step and derived throughput into the
-  global METRICS registry (tokens/s, p50/p95 step time — the BASELINE.md
-  north-star metrics);
+- :class:`span` is the one span mechanism of the serving path: an
+  annotation on the profiler's clock AND a ``<name>_seconds`` histogram in
+  the global METRICS registry, from one ``with`` block;
+- :func:`count_compiles` turns every backend compile into a counter;
 - :func:`record_memory_stats` snapshots per-device HBM occupancy gauges;
 - :func:`device_report` names the backend a process actually runs on.
 """
@@ -40,55 +41,72 @@ def trace(log_dir: str, host_tracer_level: int = 2) -> Iterator[None]:
     log.info("profiler trace written to %s", log_dir)
 
 
-def annotate(name: str):
-    """Label a host-side region on the profiler timeline (and in nested
-    StepTimer logs)."""
-    return jax.profiler.TraceAnnotation(name)
+def annotate(name: str, **attrs):
+    """Label a host-side region on the profiler timeline.  ``attrs`` ride
+    the event as metadata (``rid=7``), not in its name."""
+    return jax.profiler.TraceAnnotation(name, **attrs)
 
 
-class StepTimer:
-    """Times steps and feeds METRICS.
+class span:
+    """One host span, two sinks: a ``TraceAnnotation`` named ``name`` (so
+    the region sits on the profiler's clock beside the device operations
+    it caused) and, on exit, ``METRICS.observe(name + "_seconds", dt)`` (so
+    /metrics and the benchmark read the same interval as a window
+    difference).  ``clock`` is injectable: the batcher passes its lockstep
+    clock, tests a fake one.  With no profiler session the annotation is a
+    flag test; the histogram is one lock and one append.
 
-        timer = StepTimer("train")
-        for batch in data:
-            with timer.step(tokens=batch.size):
-                run_step(batch)
+        with span("batcher.loop.admit"):
+            ...
 
-    Records ``<name>.step_seconds`` (histogram -> p50/p95) and a
-    ``<name>.tokens_per_second`` gauge over a sliding window.
-    """
+    Call-site rule: never inside a per-token or per-row Python loop, and
+    nothing but ``jax.named_scope`` inside a jitted function.  Names are
+    literals registered in METRIC_DOCS as ``<name>_seconds`` (graftlint
+    GL302 reads ``span("...")`` calls as emitters of that histogram)."""
 
-    def __init__(self, name: str, window: int = 32,
-                 clock=time.perf_counter) -> None:
-        self.name = name
-        self._window = window
-        self._samples: list[tuple[float, int]] = []  # (seconds, tokens)
-        self.steps = 0
-        # Injectable clock: tests drive a fake monotonic counter instead of
-        # sleeping wall-clock time to make dt nonzero (graftlint GL501).
+    __slots__ = ("_name", "_clock", "_ann", "_t0")
+
+    def __init__(self, name: str, clock=time.perf_counter, **attrs) -> None:
+        self._name = name
         self._clock = clock
+        self._ann = annotate(name, **attrs)
 
-    @contextlib.contextmanager
-    def step(self, tokens: int = 0) -> Iterator[None]:
-        t0 = self._clock()
-        with annotate(f"{self.name}.step"):
-            yield
-        dt = self._clock() - t0
-        self.steps += 1
-        METRICS.observe(f"{self.name}.step_seconds", dt)
-        if tokens:
-            self._samples.append((dt, tokens))
-            if len(self._samples) > self._window:
-                self._samples = self._samples[-self._window :]
-            total_t = sum(s for s, _ in self._samples)
-            total_tok = sum(n for _, n in self._samples)
-            METRICS.set_gauge(
-                f"{self.name}.tokens_per_second", total_tok / max(total_t, 1e-9)
-            )
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self._t0 = self._clock()
+        return self
 
-    @property
-    def tokens_per_second(self) -> float:
-        return METRICS.snapshot()["gauges"].get(f"{self.name}.tokens_per_second", 0.0)
+    def __exit__(self, *exc) -> None:
+        dt = self._clock() - self._t0
+        self._ann.__exit__(*exc)
+        # graftlint: ignore[GL302](the name is the span's: GL302 checks every span("...") call site against METRIC_DOCS as "<name>_seconds")
+        METRICS.observe(self._name + "_seconds", dt)
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_counting_compiles = False
+
+
+def count_compiles() -> None:
+    """Count trips through the backend compiler from inside the process: a
+    ``jax.monitoring`` listener (registered once, however often this is
+    called) that increments ``runtime.compiles_total`` and
+    ``runtime.compile_seconds`` and logs the program's name.  The event
+    wraps compile-or-load, so a load from the persistent cache counts too;
+    a cached jit call fires nothing."""
+    global _counting_compiles
+    if _counting_compiles:
+        return
+    _counting_compiles = True
+
+    def on_duration(event: str, duration: float, **kw) -> None:
+        if event != _COMPILE_EVENT:
+            return
+        METRICS.inc("runtime.compiles_total")
+        METRICS.inc("runtime.compile_seconds", duration)
+        log.info("compiled %s in %.2f s", kw.get("fun_name", "?"), duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
 
 
 def record_memory_stats(prefix: str = "device") -> dict[str, float]:

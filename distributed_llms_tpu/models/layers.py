@@ -217,6 +217,7 @@ def out_project(x: jax.Array, p: Params) -> jax.Array:
     return out
 
 
+@jax.named_scope("mlp")  # profiler scope; HLO metadata only
 def mlp_gelu(x: jax.Array, p: Params, activation: str = "gelu") -> jax.Array:
     """GPT-2-layout MLP: act(x W_in + b) W_out + b.  ``activation``:
     "relu" (OPT), "gelu_exact" (erf gelu — HF's "gelu"), anything else the
@@ -233,6 +234,7 @@ def mlp_gelu(x: jax.Array, p: Params, activation: str = "gelu") -> jax.Array:
     return _contract(h, p["w_out"], "btf,fd->btd", 1, "k") + _plain(p["b_out"])
 
 
+@jax.named_scope("mlp")  # profiler scope; HLO metadata only
 def mlp_swiglu(x: jax.Array, p: Params, gate_act: str = "silu") -> jax.Array:
     """Gated MLP: (act(x W_gate) * (x W_up)) W_down, no biases.
     ``gate_act``: "silu" (Llama/Qwen2) or "gelu_tanh" (Gemma's GeGLU)."""
@@ -246,6 +248,7 @@ def mlp_swiglu(x: jax.Array, p: Params, gate_act: str = "silu") -> jax.Array:
     return _contract(h, p["w_down"], "btf,fd->btd", 1, "k")
 
 
+@jax.named_scope("mlp")  # profiler scope; HLO metadata only
 def moe_swiglu(
     x: jax.Array, p: Params, cfg: ModelConfig
 ) -> tuple[jax.Array, jax.Array]:

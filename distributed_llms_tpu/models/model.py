@@ -150,6 +150,7 @@ def _paged_window_attention(q, k, v, p, layer_cache, cache_index, kv_tables):
     return layers.out_project(out, p), new_cache
 
 
+@jax.named_scope("attn")  # profiler scope; HLO metadata only
 def _attention(
     x: jax.Array,
     p: Params,
@@ -628,6 +629,7 @@ def embed(params: Params, cfg: ModelConfig, tokens: jax.Array, positions: jax.Ar
     return x
 
 
+@jax.named_scope("head")  # final norm + lm head
 def unembed(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     if cfg.family in ("gpt2", "opt", "neox"):
         x = layers.layer_norm(x, params["final_norm"]["scale"], params["final_norm"]["bias"], cfg.norm_eps)

@@ -340,6 +340,7 @@ def _ragged_impl(
         ),
         out_shape=jax.ShapeDtypeStruct((b, kvh * gp, d), q.dtype),
         interpret=mode == "interpret",
+        name="ragged_decode_attn",  # the operation's name in a trace
     )(*operands)
     out = out.reshape(b, kvh, gp, d)[:, :, :g]  # [B, KVH, G, D]
     return out.reshape(b, 1, h, d)
@@ -478,6 +479,7 @@ def _paged_impl(
         ),
         out_shape=jax.ShapeDtypeStruct((b, kvh * gp, d), q.dtype),
         interpret=mode == "interpret",
+        name="paged_decode_attn",  # the operation's name in a trace
     )(*operands)
     out = out.reshape(b, kvh, gp, d)[:, :, :g]
     return out.reshape(b, 1, h, d)

@@ -307,6 +307,7 @@ def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
             out_shape=out_shape,
             scratch_shapes=scratch,
             interpret=interpret,
+            name="flash_attn",  # the operation's name in a trace
         )(*args)
     else:
         # Freshly created defaults are not device-varying over any manual
@@ -354,6 +355,7 @@ def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
             out_shape=out_shape,
             scratch_shapes=scratch,
             interpret=interpret,
+            name="flash_attn",  # the operation's name in a trace
         )(
             qpos.reshape(b * nq, 1, bq),
             kpos.reshape(b * nk, 1, bk),

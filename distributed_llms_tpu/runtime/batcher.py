@@ -84,6 +84,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import profiling
 from ..core.config import ModelConfig
 from ..core.observability import METRICS, get_logger
 from ..models import model as model_lib
@@ -1153,57 +1154,61 @@ def _decode_steps(
                 active[:, None] & (slots[None, :] == real_lens[:, None])
             )
         real_lens = real_lens + active.astype(jnp.int32)
-        if cnts is not None:
-            sample_from = (
-                logits
-                - freq_row[:, None] * cnts.astype(logits.dtype)
-                - pres_row[:, None] * (cnts > 0).astype(logits.dtype)
-            )
-        else:
-            sample_from = logits
-        # Grammar/bias mask: gather each row's state mask AFTER penalties
-        # (the -1e30 forbidden entries dominate any finite adjustment;
-        # free rows gather state 0's all-zero row — exact identity).
-        bias = (constrain_lib.gather_bias(mask_stack, dstate)
-                if dstate is not None else None)
-        if temp_row is None:
-            src = sample_from if bias is None else sample_from + bias
-            tok = sampling.sample(rng_step, src, temperature, top_k,
-                                  top_p)
-        else:
-            tok = sampling.sample_rows(
-                rng_step, sample_from, temp_row, top_k,
-                1.0 if topp_row is None else topp_row,
-                top_k_rows=topk_row, mask_rows=bias,
-            )
-        if dstate is not None:
-            # Advance each (pre-step-)active row's automaton on its
-            # sampled token — one gather, device-resident, so a chained
-            # dispatch-ahead chunk consumes the advanced state directly.
-            dstate = jnp.where(
-                carry[4],
-                constrain_lib.advance_states(next_stack, dstate, tok),
-                dstate,
-            )
-        if cnts is not None:
-            cnts = cnts.at[
-                jnp.arange(cnts.shape[0]), tok
-            ].add(active.astype(jnp.int32))
-        budget = budget - active.astype(jnp.int32)
-        if eos_id >= 0:
-            active = active & (tok != eos_id)
-        active = active & (budget > 0)
-        out = jnp.where(
-            carry[4], tok, jnp.int32(pad_id)
-        )  # mask with PRE-step active
-        # Chosen-token logprob under the raw distribution (serving's
-        # OpenAI logprobs field) — one log-softmax reduction per step.
-        lp = jnp.take_along_axis(
-            jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1),
-            tok[:, None], axis=-1,
-        )[:, 0]
-        lp = jnp.where(carry[4], lp, 0.0)
-        last_tok = jnp.where(carry[4], tok, last_tok)
+        # Everything after the forward — penalties, the grammar mask,
+        # sampling, the chosen token's logprob — under one profiler
+        # scope (metadata only: the program is the same).
+        with jax.named_scope("sample"):
+            if cnts is not None:
+                sample_from = (
+                    logits
+                    - freq_row[:, None] * cnts.astype(logits.dtype)
+                    - pres_row[:, None] * (cnts > 0).astype(logits.dtype)
+                )
+            else:
+                sample_from = logits
+            # Grammar/bias mask: gather each row's state mask AFTER penalties
+            # (the -1e30 forbidden entries dominate any finite adjustment;
+            # free rows gather state 0's all-zero row — exact identity).
+            bias = (constrain_lib.gather_bias(mask_stack, dstate)
+                    if dstate is not None else None)
+            if temp_row is None:
+                src = sample_from if bias is None else sample_from + bias
+                tok = sampling.sample(rng_step, src, temperature, top_k,
+                                      top_p)
+            else:
+                tok = sampling.sample_rows(
+                    rng_step, sample_from, temp_row, top_k,
+                    1.0 if topp_row is None else topp_row,
+                    top_k_rows=topk_row, mask_rows=bias,
+                )
+            if dstate is not None:
+                # Advance each (pre-step-)active row's automaton on its
+                # sampled token — one gather, device-resident, so a chained
+                # dispatch-ahead chunk consumes the advanced state directly.
+                dstate = jnp.where(
+                    carry[4],
+                    constrain_lib.advance_states(next_stack, dstate, tok),
+                    dstate,
+                )
+            if cnts is not None:
+                cnts = cnts.at[
+                    jnp.arange(cnts.shape[0]), tok
+                ].add(active.astype(jnp.int32))
+            budget = budget - active.astype(jnp.int32)
+            if eos_id >= 0:
+                active = active & (tok != eos_id)
+            active = active & (budget > 0)
+            out = jnp.where(
+                carry[4], tok, jnp.int32(pad_id)
+            )  # mask with PRE-step active
+            # Chosen-token logprob under the raw distribution (serving's
+            # OpenAI logprobs field) — one log-softmax reduction per step.
+            lp = jnp.take_along_axis(
+                jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1),
+                tok[:, None], axis=-1,
+            )[:, 0]
+            lp = jnp.where(carry[4], lp, 0.0)
+            last_tok = jnp.where(carry[4], tok, last_tok)
         return (
             (cache, last_tok, real_lens, valid, active, budget, cnts,
              dstate),
@@ -1406,6 +1411,28 @@ def _reset_count_row(counts, slot, tok):
 # tools.graftcheck's GC4 gate traces this path against shapes.bucket_count.
 
 
+FINISHED_KEEP = 1024  # finished-request ring (/debug/requests)
+
+
+@dataclass
+class _Timeline:
+    """Where one request's time went, on the batcher's clock.  WRITE-ONLY
+    stamps: they feed ``batcher.queue_wait_seconds`` and the finished-
+    request ring (``/debug/requests``) and reach no scheduling decision
+    (graftsync GS1 stays clean without an entry).  One object per rid,
+    shared by the resume requests a preemption mints, so the parts add up
+    across residencies."""
+    t_submit: float = 0.0   # submit()
+    t_queued: float = 0.0   # submit, or the requeue after a preemption
+    t_admit: float = 0.0    # the latest admission's start (_unqueue)
+    residencies: int = 0    # admissions: 1 + preemptions survived
+    queue_s: float = 0.0    # sum over residencies of t_admit - t_queued
+    admit_s: float = 0.0    # sum of admission start -> row resident
+    pre_submit_s: float = 0.0  # gateway: receipt -> submit (its clock)
+    prompt_tokens: int = 0  # as submitted (a resume's ids grow)
+    cached_tokens: int = 0  # of the first admission
+
+
 @dataclass(eq=False)  # identity equality: deque.remove/queue scans then
 #   compare C-level object pointers instead of running a generated Python
 #   __eq__ per element — the engine thread's queue scans stay atomic under
@@ -1459,6 +1486,7 @@ class _Request:
     swap_pages: int = 0
     swap_last_tok: int = 0
     swap_pos: int = 0
+    timeline: _Timeline = field(default_factory=_Timeline)
 
 
 @dataclass
@@ -2395,6 +2423,11 @@ class ContinuousBatcher:
         # at the next round boundary — the pool gather is a device call,
         # same ownership rule as imports.
         self._kv_exports: deque = deque()  # guarded-by: self._lock
+        # The operator's slow-request record: the last FINISHED_KEEP
+        # finished requests with where their time went (the gateway
+        # serves it at GET /debug/requests; the supervisor carries it
+        # over a respawn).  Engine thread appends, the serving loop reads.
+        self.finished: deque[dict] = deque(maxlen=FINISHED_KEEP)  # guarded-by: self._lock
 
     # -- prefix caching ------------------------------------------------------
 
@@ -2741,6 +2774,8 @@ class ContinuousBatcher:
         #   request bills against (weighted-fair admission order, virtual
         #   token counters, resident-row caps — runtime/scheduler.py
         #   TenantScheduler).  None = the anonymous bucket.
+        pre_submit_s: float = 0.0,  # gateway: receipt -> this call, kept
+        #   only for the finished-request record
     ) -> int:
         """Queue a request.  ``temperature``/``top_p``/``top_k`` override
         the batcher's sampling config FOR THIS REQUEST (serving
@@ -2892,6 +2927,11 @@ class ContinuousBatcher:
                 f"prompt ({pfx_len}+{len(ids)} tokens) + {max_new_tokens} new "
                 f"exceeds slot capacity {self.s}"
             )
+        now = self._clock()
+        timeline = _Timeline(
+            t_submit=now, t_queued=now, pre_submit_s=float(pre_submit_s),
+            prompt_tokens=pfx_len + len(ids),
+        )
         with self._lock:
             rid = self._next_rid
             self._next_rid += 1
@@ -2902,9 +2942,48 @@ class ContinuousBatcher:
                 frequency_penalty=float(frequency_penalty),
                 constraint=constraint,
                 prefix_cache=prefix_cache, priority=priority,
-                deadline=deadline, tenant=tenant,
+                deadline=deadline, tenant=tenant, timeline=timeline,
             ))
         return rid
+
+    def _span(self, name: str, **attrs) -> profiling.span:
+        """A :class:`core.profiling.span` on the batcher's clock."""
+        # graftlint: ignore[GL302](forwarded: GL302 checks the self._span("...") call sites)
+        return profiling.span(name, clock=self._clock, **attrs)
+
+    def _note_resident(self, req: "_Request") -> None:
+        """An admission ended (row resident, or finished by its admission
+        token): close the timeline's admission part."""
+        tl = req.timeline
+        tl.admit_s += self._clock() - tl.t_admit
+
+    def _note_finished(self, req: "_Request", out_tokens: int,
+                       finish: str) -> None:
+        """Append the request's record to the finished ring.  ``finish``
+        is what the batcher saw: ``eos``/``length`` (ran to its end),
+        ``cancelled`` (the gateway's timeout, stop string or disconnect),
+        ``shed`` (queue deadline).  decode_ms is the rest of submit ->
+        done once queue and admission are taken out: resident time,
+        neighbours' admissions included."""
+        tl = req.timeline
+        total = self._clock() - tl.t_submit
+        rec = {
+            "rid": req.rid, "tenant": req.tenant,
+            "prompt_tokens": tl.prompt_tokens,
+            "cached_tokens": tl.cached_tokens, "out_tokens": out_tokens,
+            "pre_submit_ms": tl.pre_submit_s * 1e3,
+            "queue_ms": tl.queue_s * 1e3, "admit_ms": tl.admit_s * 1e3,
+            "decode_ms": max(0.0, total - tl.queue_s - tl.admit_s) * 1e3,
+            "residencies": tl.residencies, "finish": finish,
+        }
+        with self._lock:
+            self.finished.append(rec)
+
+    def finished_requests(self, n: int = FINISHED_KEEP) -> list[dict]:
+        """The last ``n`` finished requests, oldest first (any thread)."""
+        with self._lock:
+            recs = list(self.finished)
+        return recs[-n:] if n > 0 else []
 
     def _drop_req_swap(self, req: "_Request") -> None:
         """Free a queued resume request's host swap parcel (cancel/shed:
@@ -2947,6 +3026,7 @@ class ContinuousBatcher:
             self.results[rid] = list(dropped.resume_emitted or [])
             self.result_logprobs[rid] = list(dropped.resume_lps or [])
             METRICS.inc("batcher.cancelled")
+            self._note_finished(dropped, len(self.results[rid]), "cancelled")
             return True
         for i in range(self.b):
             row = self.rows[i]
@@ -2965,6 +3045,8 @@ class ContinuousBatcher:
                 self._prefills.pop(i, None)
                 if row.req is not None:
                     self.sched.note_freed(row.req, len(row.emitted))
+                    self._note_finished(row.req, len(row.emitted),
+                                        "cancelled")
                 self.rows[i] = _RowState()
                 self.active[i] = False
                 self.budget[i] = 0
@@ -3008,6 +3090,12 @@ class ContinuousBatcher:
         with self._lock:
             self.queue.remove(req)
         self.sched.note_admitted(req, len(req.ids) + req.max_new_tokens)
+        tl = req.timeline
+        tl.t_admit = self._clock()
+        tl.residencies += 1
+        tl.queue_s += tl.t_admit - tl.t_queued
+        METRICS.observe("batcher.queue_wait_seconds",
+                        tl.t_admit - tl.t_queued)
 
     def _shed_expired_queued(self) -> None:
         """Drop queued requests whose deadline has already passed: a
@@ -3056,6 +3144,9 @@ class ContinuousBatcher:
                 self.shed[req.rid] = "queue deadline expired before admission"
                 METRICS.inc("batcher.shed_total")
                 log.info("shed queued request %d (deadline expired)", req.rid)
+            self._note_finished(
+                req, len(req.resume_emitted or []),
+                "cancelled" if req.resume_emitted else "shed")
             if self._on_tokens is not None:
                 self._on_tokens(req.rid, [], True, None)
 
@@ -3117,7 +3208,7 @@ class ContinuousBatcher:
                 prefix_cache=req.prefix_cache, priority=req.priority,
                 deadline=req.deadline, tenant=req.tenant,
                 resume_emitted=list(row.emitted),
-                resume_lps=list(row.lps),
+                resume_lps=list(row.lps), timeline=req.timeline,
             )
             # SWAP tier (host_pages): park the victim's raw pages on the
             # host instead of throwing the prefix away — restore scatters
@@ -3144,6 +3235,7 @@ class ContinuousBatcher:
         self.rows[i] = _RowState()
         self.active[i] = False
         self.budget[i] = 0
+        resume.timeline.t_queued = self._clock()
         with self._lock:
             self.queue.append(resume)
         self.preemptions += 1
@@ -3296,6 +3388,7 @@ class ContinuousBatcher:
         METRICS.inc("batcher.kv_swaps.in")
         log.info("restored swapped rid %d into slot %d (%d page(s))",
                  req.rid, i, n)
+        self._note_resident(req)
         return True
 
     def _ensure_pages(self, need: int, tag: str,
@@ -3501,205 +3594,212 @@ class ContinuousBatcher:
         first, then preempting the lowest-priority / most-recently-admitted
         victim (possibly the growing row itself: it requeues for recompute
         and higher-priority residents keep their pages)."""
-        blk = self.page_size
-        for i in range(self.b):
-            row = self.rows[i]
-            if row.rid is None or not self.active[i] or row.prefilling:
-                continue
-            if self.speculative:
-                # The verify window writes slots real_lens..real_lens+k
-                # REGARDLESS of budget (rollback clamps commits, not
-                # writes) — pages must cover the whole window before the
-                # round dispatches, exactly the contiguous engine's
-                # headroom contract.
-                horizon = int(self.real_lens[i]) + self.spec_k + 1
-            else:
-                horizon = int(self.real_lens[i]) + min(
-                    self.chunk_steps, int(self.budget[i])
-                )
-            need_pages = -(-horizon // blk)
-            have = len(row.pages)
-            if need_pages <= have:
-                continue
-            n = need_pages - have
-            # The fault site (tag "grow") fires only when a row actually
-            # needs new pages, so rule windows count real allocation
-            # attempts.
-            if not self._ensure_pages(n, "grow", self_slot=i):
-                continue  # the grower itself was preempted
-            fresh = self._alloc_pages(n)
-            row.pages.extend(fresh)
-            self.tables[i][have:need_pages] = fresh
-            METRICS.inc("batcher.pages_grown", n)
+        with self._span("batcher.loop.grow"):
+            blk = self.page_size
+            for i in range(self.b):
+                row = self.rows[i]
+                if row.rid is None or not self.active[i] or row.prefilling:
+                    continue
+                if self.speculative:
+                    # The verify window writes slots real_lens..real_lens+k
+                    # REGARDLESS of budget (rollback clamps commits, not
+                    # writes) — pages must cover the whole window before the
+                    # round dispatches, exactly the contiguous engine's
+                    # headroom contract.
+                    horizon = int(self.real_lens[i]) + self.spec_k + 1
+                else:
+                    horizon = int(self.real_lens[i]) + min(
+                        self.chunk_steps, int(self.budget[i])
+                    )
+                need_pages = -(-horizon // blk)
+                have = len(row.pages)
+                if need_pages <= have:
+                    continue
+                n = need_pages - have
+                # The fault site (tag "grow") fires only when a row actually
+                # needs new pages, so rule windows count real allocation
+                # attempts.
+                if not self._ensure_pages(n, "grow", self_slot=i):
+                    continue  # the grower itself was preempted
+                fresh = self._alloc_pages(n)
+                row.pages.extend(fresh)
+                self.tables[i][have:need_pages] = fresh
+                METRICS.inc("batcher.pages_grown", n)
 
     def _admit_pending(self) -> None:
-        if self.faults is not None:
-            # Injection site "batcher.admit": one hit per admission round.
-            self.faults.fire("batcher.admit")
-        # Adopt handed-off KV pages FIRST: a transfer that raced this
-        # round's admissions should be matchable by them.  Then serve
-        # cross-replica export requests — after imports, so a freshly
-        # landed run is immediately re-exportable.
-        self._drain_kv_imports()
-        self._drain_kv_exports()
-        self._shed_expired_queued()
-        # Advance pending chunked prefills.  ALTERNATE: one serialized
-        # prefill_chunk_step bite per prefill per round (up to
-        # prefill_concurrency * prefill_chunk stall tokens).  MIXED:
-        # while decode rows are live, bites ride the fused span instead
-        # (_decode_span), so only completed prompts run their finishing
-        # splice here; with no decode rows live the classic advance
-        # runs.  Re-evaluated per slot: a finishing splice earlier in
-        # this loop activates a decode row, and later bites must then
-        # ride the span, not stall it.
-        for slot in list(self._prefills):
-            fused = self.sched.fuse_prefill() and bool(self.active.any())
-            self._advance_chunk(slot, advance=not fused)
-        while True:
-            i = self._free_slot()
-            if i is None:
-                return
-            req = self._next_request()
-            if req is None:
-                return
-            if req.swap_handle is not None:
-                # Swap-preempted resume: scatter the parked pages back
-                # instead of recomputing the prefix.  True = restored
-                # (next loop iteration admits more); False = the parcel
-                # was unusable and the request fell through to recompute
-                # (still queued, swap_handle cleared — re-selected next
-                # iteration); None = back-pressure, stop this round.
-                got = self._try_restore_swapped(i, req)
-                if got is None:
+        with self._span("batcher.loop.admit"):
+            if self.faults is not None:
+                # Injection site "batcher.admit": one hit per admission round.
+                self.faults.fire("batcher.admit")
+            # Adopt handed-off KV pages FIRST: a transfer that raced this
+            # round's admissions should be matchable by them.  Then serve
+            # cross-replica export requests — after imports, so a freshly
+            # landed run is immediately re-exportable.
+            self._drain_kv_imports()
+            self._drain_kv_exports()
+            self._shed_expired_queued()
+            # Advance pending chunked prefills.  ALTERNATE: one serialized
+            # prefill_chunk_step bite per prefill per round (up to
+            # prefill_concurrency * prefill_chunk stall tokens).  MIXED:
+            # while decode rows are live, bites ride the fused span instead
+            # (_decode_span), so only completed prompts run their finishing
+            # splice here; with no decode rows live the classic advance
+            # runs.  Re-evaluated per slot: a finishing splice earlier in
+            # this loop activates a decode row, and later bites must then
+            # ride the span, not stall it.
+            for slot in list(self._prefills):
+                fused = self.sched.fuse_prefill() and bool(self.active.any())
+                self._advance_chunk(slot, advance=not fused)
+            while True:
+                i = self._free_slot()
+                if i is None:
                     return
-                continue
-            pfx = self.prefixes[req.prefix] if req.prefix is not None else None
-            pfx_len = len(pfx.ids) if pfx else 0
-            total_len = pfx_len + len(req.ids)
-            thr = self.sched.chunk_threshold()
-            if thr is not None and len(req.ids) > thr:
-                if len(self._prefills) >= self.prefill_concurrency:
-                    # Prefill slots full, and strict admission order: stop
-                    # admitting (the selected request never gets jumped).
+                req = self._next_request()
+                if req is None:
                     return
-                self._unqueue(req)
-                self._start_chunked(i, req, pfx)
-                continue
-            pages: list[int] = []
-            cached_pages: list[int] = []
-            cached_len = 0
-            digests: list[bytes] = []
-            if self.paged:
-                got = self._reserve_row_pages(i, req, total_len, pfx)
-                if got is None:
-                    # Dry pool with no preemptable victim: back-pressure.
-                    # The request stays queued (never removed), admission
-                    # stops for this round.
-                    return
-                page_list, pages, cached_pages, cached_len, digests = got
-            self._unqueue(req)
-            # Bucket for compile reuse, but never past what fits after the
-            # prefix: forward's contract is cache_index + T <= max_len, and
-            # dynamic_update_slice CLAMPS an overflowing start — the suffix
-            # K/V would land misaligned with its mask/positions, silently
-            # corrupting the row.  (submit() guaranteed the real prompt fits.)
-            tp = min(_bucket(len(req.ids)), self.s - pfx_len)
-            prompt = np.full((tp,), self.pad_id, np.int32)
-            prompt[: len(req.ids)] = req.ids
-            # Per-request sampling: traced scalar overrides (no recompile
-            # per value) only when the request diverges from the config.
-            req_t = (self.sampling["temperature"] if req.temperature is None
-                     else float(req.temperature))
-            req_p = (self.sampling["top_p"] if req.top_p is None
-                     else float(req.top_p))
-            req_k = (self.sampling["top_k"] if req.top_k is None
-                     else int(req.top_k))
-            custom = (req_t != self.sampling["temperature"]
-                      or req_p != self.sampling["top_p"]
-                      or req_k != self.sampling["top_k"])
-            extra = (
-                dict(temp_req=jnp.float32(req_t), topp_req=jnp.float32(req_p))
-                if custom else {}
-            )
-            if custom and req_k != self.sampling["top_k"]:
-                extra["topk_req"] = jnp.int32(req_k)
-            if req.constraint is not None:
-                # The first output token draws under the automaton's
-                # start-state mask (a resumed request replays its emitted
-                # prefix to recover the state first).
-                st0 = req.constraint.advance(0, req.resume_emitted or [])
-                extra["mask_req"] = jnp.asarray(req.constraint.bias[st0])
-            if self.paged and pfx is not None:
-                self.cache, tok, lp = admit_row_with_prefix_paged(
-                    self.params, self.cfg, self.cache, jnp.asarray(page_list),
-                    pfx.k, pfx.v, jnp.int32(pfx_len),
-                    jnp.asarray(prompt), jnp.int32(len(req.ids)),
-                    self._split_rng(), pm=self.pm, **self.sampling, **extra,
-                )
-                row_valid = np.arange(self.valid.shape[1]) < total_len
-            elif self.paged and cached_len:
-                # Prefix-cache HIT: the cached run seeds the row through a
-                # pool gather; only the suffix prefills.  Writes for the
-                # cached positions are routed to the scratch page — shared
-                # pages are read-only while any row references them.
-                write_list = page_list.copy()
-                write_list[: len(cached_pages)] = 0
-                suffix = req.ids[cached_len:]
-                tc = min(_bucket(len(suffix)), self.s - cached_len)
-                chunk = np.full((tc,), self.pad_id, np.int32)
-                chunk[: len(suffix)] = suffix
-                self.cache, tok, lp = admit_row_auto_paged(
-                    self.params, self.cfg, self.cache,
-                    jnp.asarray(page_list), jnp.asarray(write_list),
-                    jnp.int32(cached_len), jnp.asarray(chunk),
-                    jnp.int32(len(suffix)), self._split_rng(),
-                    pm=self.pm, **self.sampling, **extra,
-                )
-                row_valid = np.arange(self.valid.shape[1]) < total_len
-            elif self.paged:
-                self.cache, tok, lp = admit_row_paged(
-                    self.params, self.cfg, self.cache, jnp.asarray(page_list),
-                    jnp.asarray(prompt), jnp.int32(len(req.ids)),
-                    self._split_rng(), pm=self.pm, **self.sampling, **extra,
-                )
-                row_valid = np.arange(self.valid.shape[1]) < total_len
-            elif pfx is not None:
-                self.cache, tok, row_valid, lp = admit_row_with_prefix(
-                    self.params, self.cfg, self.cache, jnp.int32(i),
-                    pfx.k, pfx.v, jnp.int32(pfx_len),
-                    jnp.asarray(prompt), jnp.int32(len(req.ids)),
-                    self._split_rng(), pm=self.pm, **self.sampling, **extra,
-                )
-            else:
-                self.cache, tok, row_valid, lp = admit_row(
-                    self.params, self.cfg, self.cache, jnp.int32(i),
-                    jnp.asarray(prompt), jnp.int32(len(req.ids)),
-                    self._split_rng(), pm=self.pm, **self.sampling, **extra,
-                )
-            if digests:
-                # Publish the row's full prompt pages (first writer wins;
-                # a digest another page already holds leaves ours private).
-                # Pages inside the cached run are already published; the
-                # fresh ones now hold exactly the hashed content — the
-                # admission scatter just wrote it.
-                for j in range(len(cached_pages), len(digests)):
-                    self.pool.publish_prefix(int(page_list[j]), digests[j])
-            if self.speculative:
-                # Seed the DRAFT cache for this row: full prompt (prefix
-                # caching stores only target KV, so the draft prefills
-                # prefix + suffix; bucketed for compile reuse).
-                full_ids = (pfx.ids if pfx else []) + req.ids
-                td = min(_bucket(len(full_ids)), self.s)
-                dprompt = np.full((td,), self.pad_id, np.int32)
-                dprompt[: len(full_ids)] = full_ids
-                self.draft_cache = admit_row_kv(
-                    self.draft_params, self.draft_cfg, self.draft_cache,
-                    jnp.int32(i), jnp.asarray(dprompt),
-                    jnp.int32(len(full_ids)),
-                )
-            self._activate_row(i, req, tok, lp, row_valid, total_len,
-                               req_t, req_p, cached_pages + pages,
-                               req_k=req_k, cached_len=cached_len)
+                if req.swap_handle is not None:
+                    # Swap-preempted resume: scatter the parked pages back
+                    # instead of recomputing the prefix.  True = restored
+                    # (next loop iteration admits more); False = the parcel
+                    # was unusable and the request fell through to recompute
+                    # (still queued, swap_handle cleared — re-selected next
+                    # iteration); None = back-pressure, stop this round.
+                    got = self._try_restore_swapped(i, req)
+                    if got is None:
+                        return
+                    continue
+                pfx = self.prefixes[req.prefix] if req.prefix is not None else None
+                pfx_len = len(pfx.ids) if pfx else 0
+                total_len = pfx_len + len(req.ids)
+                thr = self.sched.chunk_threshold()
+                if thr is not None and len(req.ids) > thr:
+                    if len(self._prefills) >= self.prefill_concurrency:
+                        # Prefill slots full, and strict admission order: stop
+                        # admitting (the selected request never gets jumped).
+                        return
+                    self._unqueue(req)
+                    self._start_chunked(i, req, pfx)
+                    continue
+                pages: list[int] = []
+                cached_pages: list[int] = []
+                cached_len = 0
+                digests: list[bytes] = []
+                if self.paged:
+                    got = self._reserve_row_pages(i, req, total_len, pfx)
+                    if got is None:
+                        # Dry pool with no preemptable victim: back-pressure.
+                        # The request stays queued (never removed), admission
+                        # stops for this round.
+                        return
+                    page_list, pages, cached_pages, cached_len, digests = got
+                with self._span(
+                    "batcher.admit.row", rid=req.rid,
+                    prompt_tokens=total_len, cached_tokens=cached_len,
+                    bucket=_bucket(len(req.ids) - cached_len),
+                ):
+                    self._unqueue(req)
+                    # Bucket for compile reuse, but never past what fits after the
+                    # prefix: forward's contract is cache_index + T <= max_len, and
+                    # dynamic_update_slice CLAMPS an overflowing start — the suffix
+                    # K/V would land misaligned with its mask/positions, silently
+                    # corrupting the row.  (submit() guaranteed the real prompt fits.)
+                    tp = min(_bucket(len(req.ids)), self.s - pfx_len)
+                    prompt = np.full((tp,), self.pad_id, np.int32)
+                    prompt[: len(req.ids)] = req.ids
+                    # Per-request sampling: traced scalar overrides (no recompile
+                    # per value) only when the request diverges from the config.
+                    req_t = (self.sampling["temperature"] if req.temperature is None
+                             else float(req.temperature))
+                    req_p = (self.sampling["top_p"] if req.top_p is None
+                             else float(req.top_p))
+                    req_k = (self.sampling["top_k"] if req.top_k is None
+                             else int(req.top_k))
+                    custom = (req_t != self.sampling["temperature"]
+                              or req_p != self.sampling["top_p"]
+                              or req_k != self.sampling["top_k"])
+                    extra = (
+                        dict(temp_req=jnp.float32(req_t), topp_req=jnp.float32(req_p))
+                        if custom else {}
+                    )
+                    if custom and req_k != self.sampling["top_k"]:
+                        extra["topk_req"] = jnp.int32(req_k)
+                    if req.constraint is not None:
+                        # The first output token draws under the automaton's
+                        # start-state mask (a resumed request replays its emitted
+                        # prefix to recover the state first).
+                        st0 = req.constraint.advance(0, req.resume_emitted or [])
+                        extra["mask_req"] = jnp.asarray(req.constraint.bias[st0])
+                    if self.paged and pfx is not None:
+                        self.cache, tok, lp = admit_row_with_prefix_paged(
+                            self.params, self.cfg, self.cache, jnp.asarray(page_list),
+                            pfx.k, pfx.v, jnp.int32(pfx_len),
+                            jnp.asarray(prompt), jnp.int32(len(req.ids)),
+                            self._split_rng(), pm=self.pm, **self.sampling, **extra,
+                        )
+                        row_valid = np.arange(self.valid.shape[1]) < total_len
+                    elif self.paged and cached_len:
+                        # Prefix-cache HIT: the cached run seeds the row through a
+                        # pool gather; only the suffix prefills.  Writes for the
+                        # cached positions are routed to the scratch page — shared
+                        # pages are read-only while any row references them.
+                        write_list = page_list.copy()
+                        write_list[: len(cached_pages)] = 0
+                        suffix = req.ids[cached_len:]
+                        tc = min(_bucket(len(suffix)), self.s - cached_len)
+                        chunk = np.full((tc,), self.pad_id, np.int32)
+                        chunk[: len(suffix)] = suffix
+                        self.cache, tok, lp = admit_row_auto_paged(
+                            self.params, self.cfg, self.cache,
+                            jnp.asarray(page_list), jnp.asarray(write_list),
+                            jnp.int32(cached_len), jnp.asarray(chunk),
+                            jnp.int32(len(suffix)), self._split_rng(),
+                            pm=self.pm, **self.sampling, **extra,
+                        )
+                        row_valid = np.arange(self.valid.shape[1]) < total_len
+                    elif self.paged:
+                        self.cache, tok, lp = admit_row_paged(
+                            self.params, self.cfg, self.cache, jnp.asarray(page_list),
+                            jnp.asarray(prompt), jnp.int32(len(req.ids)),
+                            self._split_rng(), pm=self.pm, **self.sampling, **extra,
+                        )
+                        row_valid = np.arange(self.valid.shape[1]) < total_len
+                    elif pfx is not None:
+                        self.cache, tok, row_valid, lp = admit_row_with_prefix(
+                            self.params, self.cfg, self.cache, jnp.int32(i),
+                            pfx.k, pfx.v, jnp.int32(pfx_len),
+                            jnp.asarray(prompt), jnp.int32(len(req.ids)),
+                            self._split_rng(), pm=self.pm, **self.sampling, **extra,
+                        )
+                    else:
+                        self.cache, tok, row_valid, lp = admit_row(
+                            self.params, self.cfg, self.cache, jnp.int32(i),
+                            jnp.asarray(prompt), jnp.int32(len(req.ids)),
+                            self._split_rng(), pm=self.pm, **self.sampling, **extra,
+                        )
+                    if digests:
+                        # Publish the row's full prompt pages (first writer wins;
+                        # a digest another page already holds leaves ours private).
+                        # Pages inside the cached run are already published; the
+                        # fresh ones now hold exactly the hashed content — the
+                        # admission scatter just wrote it.
+                        for j in range(len(cached_pages), len(digests)):
+                            self.pool.publish_prefix(int(page_list[j]), digests[j])
+                    if self.speculative:
+                        # Seed the DRAFT cache for this row: full prompt (prefix
+                        # caching stores only target KV, so the draft prefills
+                        # prefix + suffix; bucketed for compile reuse).
+                        full_ids = (pfx.ids if pfx else []) + req.ids
+                        td = min(_bucket(len(full_ids)), self.s)
+                        dprompt = np.full((td,), self.pad_id, np.int32)
+                        dprompt[: len(full_ids)] = full_ids
+                        self.draft_cache = admit_row_kv(
+                            self.draft_params, self.draft_cfg, self.draft_cache,
+                            jnp.int32(i), jnp.asarray(dprompt),
+                            jnp.int32(len(full_ids)),
+                        )
+                    self._activate_row(i, req, tok, lp, row_valid, total_len,
+                                       req_t, req_p, cached_pages + pages,
+                                       req_k=req_k, cached_len=cached_len)
 
     def _activate_row(self, i, req, tok, lp, row_valid, total_len,
                       req_t, req_p, pages, req_k=None, cached_len=0):
@@ -3727,6 +3827,8 @@ class ContinuousBatcher:
         self.freq_row[i] = req.frequency_penalty
         if self.prefix_cache is not None:
             self.prefix_cached_tokens[req.rid] = cached_len
+        if req.timeline.residencies == 1:
+            req.timeline.cached_tokens = cached_len
         prior = list(req.resume_emitted or [])
         prior_lps = list(req.resume_lps or [])
         if req.presence_penalty or req.frequency_penalty:
@@ -3773,6 +3875,7 @@ class ContinuousBatcher:
             self.rows[i].streamed = len(prior) + 1
             self._on_tokens(req.rid, [tok], False, [float(lp)])
         METRICS.inc("batcher.admitted")
+        self._note_resident(req)
 
     # -- chunked prefill ---------------------------------------------------
 
@@ -3964,10 +4067,12 @@ class ContinuousBatcher:
         # mirrors are stale while the carry is device-resident); the
         # synchronous path leaves it None and reads the freshly-synced
         # mirror, exactly as before.
+        committed = 0
         for i in range(self.b):
             row = self.rows[i]
             if row.rid is None or not was_active[i]:
                 continue
+            committed -= len(row.emitted)
             # Speculative rounds emit a VARIABLE count per row; columns past
             # counts[i] are padding, not tokens (a legit pad-id token inside
             # the count still collects).  decode_chunk's fixed-step output
@@ -3983,6 +4088,9 @@ class ContinuousBatcher:
                 row.remaining -= 1
                 if t == self.eos_id:
                     break
+            committed += len(row.emitted)
+        if committed:
+            METRICS.inc("batcher.decode.committed_tokens", committed)
         # Rows that finished this chunk publish their result and free up.
         # (Chunked prefills in flight are inactive but NOT finished.)
         if active_host is None:
@@ -4004,6 +4112,10 @@ class ContinuousBatcher:
                 final_lps = row.lps[row.streamed:]
                 if row.req is not None:  # tenant true-up at completion
                     self.sched.note_freed(row.req, len(row.emitted))
+                    self._note_finished(
+                        row.req, len(row.emitted),
+                        "eos" if row.emitted[-1:] == [self.eos_id]
+                        else "length")
                 self.rows[i] = _RowState()
                 METRICS.inc("batcher.completed")
                 if self._on_tokens is not None:
@@ -4072,9 +4184,10 @@ class ContinuousBatcher:
             was_active = self.active.copy()
             if not was_active.any():
                 self._t_complete = None  # idle boundary: no chunk to gap
-                self._collect(
-                    np.zeros((self.b, 0), np.int32), was_active
-                )
+                with self._span("batcher.loop.deliver"):
+                    self._collect(
+                        np.zeros((self.b, 0), np.int32), was_active
+                    )
                 if not self.has_queued() and not self.has_kv_imports() \
                         and not self.has_kv_exports() \
                         and all(r.rid is None for r in self.rows):
@@ -4229,112 +4342,122 @@ class ContinuousBatcher:
         ``m`` the speculative per-row commit counts (None on the plain
         path); ``self.cache``/``self.draft_cache``/``self.tok_counts``
         advance to the new chunk's (not-yet-materialized) outputs."""
-        last_tok, real_lens, valid, active, budget = carry
-        self.overlap_stats["chunks"] += 1
-        m = None
-        dfa_out = None
-        if self.speculative:
-            per_spec = dict(plan["per_spec"])
-            if plan["counts"]:
-                per_spec["counts"] = self.tok_counts
-            if self.sampling["temperature"] > 0.0:
-                # Sampled rounds consume RNG; greedy rounds must not
-                # (greedy spec stays bit-stable across configs).
-                per_spec["rng"] = self._split_rng()
-            if self.faults is not None:
-                # Injection site "batcher.spec_verify": the round is ONE
-                # compiled draft+verify program, so both tags fire at its
-                # dispatch — the tag selects which drill phase a rule
-                # targets ('draft' = the k draft steps, 'verify' = the
-                # (k+1)-token target pass).  A 'raise' here is the
-                # supervisor-restart drill for the speculative leg.
-                self.faults.fire("batcher.spec_verify", tag="draft")
-                self.faults.fire("batcher.spec_verify", tag="verify")
-            # Per-dispatch adaptive clamp (the scheduler's spec_round_k
-            # hook: token-budget clamp + acceptance-EMA downshift).
-            # Greedy engines only: the sampled forced-stop draw is
-            # distribution-preserving but changes the per-seed stream,
-            # and flipping the downshift on must never change sampled
-            # outputs.  The clamp is ALWAYS passed as a traced [B]
-            # vector (full k when inert) so one compiled program serves
-            # every value.  Mid-span the activity mirrors are stale by
-            # construction — stale the same way every run, so the
-            # downshift schedule stays deterministic.
-            live = self.active & np.asarray(
-                [r.rid is not None for r in self.rows]
-            )
-            emas = tuple(
-                float(self.spec_ema[i]) if live[i] else 1.0
-                for i in range(self.b)
-            )
-            if self.sampling["temperature"] == 0.0:
-                ks = self.sched.spec_round_k(
-                    self.spec_k, emas, int(live.sum())
+        with self._span("batcher.loop.dispatch"):
+            if self._tables_dirty:
+                # In-span growth extended a row's table: this chunk
+                # must read/write through the grown pages.
+                plan["tables"] = jnp.asarray(self.tables)
+                self._tables_dirty = False
+            last_tok, real_lens, valid, active, budget = carry
+            self.overlap_stats["chunks"] += 1
+            m = None
+            dfa_out = None
+            if self.speculative:
+                per_spec = dict(plan["per_spec"])
+                if plan["counts"]:
+                    per_spec["counts"] = self.tok_counts
+                if self.sampling["temperature"] > 0.0:
+                    # Sampled rounds consume RNG; greedy rounds must not
+                    # (greedy spec stays bit-stable across configs).
+                    per_spec["rng"] = self._split_rng()
+                if self.faults is not None:
+                    # Injection site "batcher.spec_verify": the round is ONE
+                    # compiled draft+verify program, so both tags fire at its
+                    # dispatch — the tag selects which drill phase a rule
+                    # targets ('draft' = the k draft steps, 'verify' = the
+                    # (k+1)-token target pass).  A 'raise' here is the
+                    # supervisor-restart drill for the speculative leg.
+                    self.faults.fire("batcher.spec_verify", tag="draft")
+                    self.faults.fire("batcher.spec_verify", tag="verify")
+                # Per-dispatch adaptive clamp (the scheduler's spec_round_k
+                # hook: token-budget clamp + acceptance-EMA downshift).
+                # Greedy engines only: the sampled forced-stop draw is
+                # distribution-preserving but changes the per-seed stream,
+                # and flipping the downshift on must never change sampled
+                # outputs.  The clamp is ALWAYS passed as a traced [B]
+                # vector (full k when inert) so one compiled program serves
+                # every value.  Mid-span the activity mirrors are stale by
+                # construction — stale the same way every run, so the
+                # downshift schedule stays deterministic.
+                live = self.active & np.asarray(
+                    [r.rid is not None for r in self.rows]
                 )
-            else:
-                ks = [self.spec_k] * self.b
-            kh = np.clip(np.asarray(ks, np.int32), 1, self.spec_k)
-            plan["k_hist"].append(kh)
-            per_spec["k_row"] = jnp.asarray(kh)
-            METRICS.inc("batcher.spec.rounds")
-            self.spec_stats["rounds"] += 1
-            # Budget accounting: a round charges (k_row+1) COMMITTABLE
-            # tokens per live row against the ledger (spec_round_k
-            # already clamped the sum against token_budget).  The
-            # dispatched program is always k+1 wide — the ledger bounds
-            # commits, not flops (one compile key).
-            METRICS.inc("batcher.sched.decode_tokens",
-                        int(np.sum((kh + 1)[live])))
-            if bool((kh[live] < self.spec_k).any()):
-                METRICS.inc("batcher.spec.k_downshifts")
-                self.spec_stats["downshifts"] += 1
-            (toks, m, lps, self.cache, self.draft_cache, last_tok,
-             real_lens, valid, active, budget, counts_out) = spec_chunk(
-                self.params, self.cfg, self.draft_params, self.draft_cfg,
-                self.cache, self.draft_cache, last_tok, real_lens, valid,
-                active, budget, k=self.spec_k, eos_id=self.eos_id,
-                pad_id=self.pad_id, tables=plan["tables"],
-                **self.sampling, **per_spec,
-            )
-        else:
-            per_row = dict(plan["per_row"])
-            if plan["counts"]:
-                per_row["counts"] = self.tok_counts
-            if plan["constrain"]:
-                # The automaton-state carry chains like the KV cache: a
-                # dispatched-ahead chunk consumes the PREVIOUS chunk's
-                # (not-yet-materialized) state output directly.
-                per_row["dfa_state"] = self._dfa_carry
-            METRICS.inc("batcher.sched.decode_tokens",
-                        plan["n_active"] * self.chunk_steps)
-            pp = (self._prefills.get(plan["mixed"])
-                  if plan["mixed"] is not None else None)
-            if pp is not None and pp.done < pp.total_len:
-                (toks, self.cache, last_tok, real_lens, valid, active,
-                 budget, lps, counts_out, dfa_out) = self._dispatch_mixed(
-                    plan, (last_tok, real_lens, valid, active, budget),
-                    per_row, pp,
+                emas = tuple(
+                    float(self.spec_ema[i]) if live[i] else 1.0
+                    for i in range(self.b)
                 )
-            else:
-                if self.faults is not None and self.sched.fuse_prefill():
-                    # Injection site "batcher.mixed_step" tag "decode":
-                    # a mixed-schedule dispatch with no prefill riding.
-                    self.faults.fire("batcher.mixed_step", tag="decode")
-                (toks, self.cache, last_tok, real_lens, valid, active,
-                 budget, lps, counts_out, dfa_out) = \
-                    decode_chunk(
-                        self.params, self.cfg_decode, self.cache, last_tok,
-                        real_lens, valid, active, budget,
-                        self._split_rng(), self.chunk_steps,
-                        eos_id=self.eos_id, pad_id=self.pad_id, pm=self.pm,
-                        tables=plan["tables"],
-                        **self.sampling, **per_row,
+                if self.sampling["temperature"] == 0.0:
+                    ks = self.sched.spec_round_k(
+                        self.spec_k, emas, int(live.sum())
                     )
-        if counts_out is not None:
-            self.tok_counts = counts_out
-        if dfa_out is not None:
-            self._dfa_carry = dfa_out
-        return toks, lps, m, (last_tok, real_lens, valid, active, budget)
+                else:
+                    ks = [self.spec_k] * self.b
+                kh = np.clip(np.asarray(ks, np.int32), 1, self.spec_k)
+                plan["k_hist"].append(kh)
+                per_spec["k_row"] = jnp.asarray(kh)
+                METRICS.inc("batcher.spec.rounds")
+                self.spec_stats["rounds"] += 1
+                # Budget accounting: a round charges (k_row+1) COMMITTABLE
+                # tokens per live row against the ledger (spec_round_k
+                # already clamped the sum against token_budget).  The
+                # dispatched program is always k+1 wide — the ledger bounds
+                # commits, not flops (one compile key).
+                METRICS.inc("batcher.sched.decode_tokens",
+                            int(np.sum((kh + 1)[live])))
+                METRICS.inc("batcher.decode.slot_steps",
+                            self.b * (self.spec_k + 1))
+                if bool((kh[live] < self.spec_k).any()):
+                    METRICS.inc("batcher.spec.k_downshifts")
+                    self.spec_stats["downshifts"] += 1
+                (toks, m, lps, self.cache, self.draft_cache, last_tok,
+                 real_lens, valid, active, budget, counts_out) = spec_chunk(
+                    self.params, self.cfg, self.draft_params, self.draft_cfg,
+                    self.cache, self.draft_cache, last_tok, real_lens, valid,
+                    active, budget, k=self.spec_k, eos_id=self.eos_id,
+                    pad_id=self.pad_id, tables=plan["tables"],
+                    **self.sampling, **per_spec,
+                )
+            else:
+                per_row = dict(plan["per_row"])
+                if plan["counts"]:
+                    per_row["counts"] = self.tok_counts
+                if plan["constrain"]:
+                    # The automaton-state carry chains like the KV cache: a
+                    # dispatched-ahead chunk consumes the PREVIOUS chunk's
+                    # (not-yet-materialized) state output directly.
+                    per_row["dfa_state"] = self._dfa_carry
+                METRICS.inc("batcher.sched.decode_tokens",
+                            plan["n_active"] * self.chunk_steps)
+                METRICS.inc("batcher.decode.slot_steps",
+                            self.b * self.chunk_steps)
+                pp = (self._prefills.get(plan["mixed"])
+                      if plan["mixed"] is not None else None)
+                if pp is not None and pp.done < pp.total_len:
+                    (toks, self.cache, last_tok, real_lens, valid, active,
+                     budget, lps, counts_out, dfa_out) = self._dispatch_mixed(
+                        plan, (last_tok, real_lens, valid, active, budget),
+                        per_row, pp,
+                    )
+                else:
+                    if self.faults is not None and self.sched.fuse_prefill():
+                        # Injection site "batcher.mixed_step" tag "decode":
+                        # a mixed-schedule dispatch with no prefill riding.
+                        self.faults.fire("batcher.mixed_step", tag="decode")
+                    (toks, self.cache, last_tok, real_lens, valid, active,
+                     budget, lps, counts_out, dfa_out) = \
+                        decode_chunk(
+                            self.params, self.cfg_decode, self.cache, last_tok,
+                            real_lens, valid, active, budget,
+                            self._split_rng(), self.chunk_steps,
+                            eos_id=self.eos_id, pad_id=self.pad_id, pm=self.pm,
+                            tables=plan["tables"],
+                            **self.sampling, **per_row,
+                        )
+            if counts_out is not None:
+                self.tok_counts = counts_out
+            if dfa_out is not None:
+                self._dfa_carry = dfa_out
+            return toks, lps, m, (last_tok, real_lens, valid, active, budget)
 
     def _mixed_width(self, done: int) -> int:
         """Prefill-leg width of a fused step: ONE bucket sized to the
@@ -4518,7 +4641,6 @@ class ContinuousBatcher:
         stream runs back-to-back."""
         self.overlap_stats["device_gap_s"] += gap_s
         self.overlap_stats["gap_samples"] += 1
-        METRICS.observe("batcher.overlap.device_gap_seconds", gap_s)
 
     def _grow_ahead(self, horizon_chunks: int) -> bool:
         """Page growth ON the overlapped window: growth needs the page
@@ -4576,7 +4698,8 @@ class ContinuousBatcher:
         carry stays device-resident."""
         toks, lps, m, carry = out
         extras = () if m is None else (m,)
-        got = jax.device_get((toks, lps) + extras + (carry[3],))
+        with self._span("batcher.loop.wait_device"):
+            got = jax.device_get((toks, lps) + extras + (carry[3],))
         self._t_complete = time.perf_counter()
         toks_h, lps_h, *rest = got
         return toks_h, lps_h, (rest[0] if m is not None else None), rest[-1]
@@ -4592,7 +4715,8 @@ class ContinuousBatcher:
         activity bit for them is stale by construction."""
         toks, lps, m, carry = out
         extras = () if m is None else (m,)
-        got = jax.device_get((toks, lps) + extras + carry)
+        with self._span("batcher.loop.wait_device"):
+            got = jax.device_get((toks, lps) + extras + carry)
         self._t_complete = time.perf_counter()
         toks_h, lps_h, *rest = got
         m_h = rest[0] if m is not None else None
@@ -4652,7 +4776,8 @@ class ContinuousBatcher:
         # cancel recorded before this point already landed on them — only
         # a cancel taken DURING the span must force the next sync.
         self._cancel_dirty = False
-        plan = self._span_plan()
+        with self._span("batcher.loop.plan"):
+            plan = self._span_plan()
         t_disp = time.perf_counter()
         if self._t_complete is not None:
             # First chunk of a span follows an OBSERVED completion (the
@@ -4664,14 +4789,13 @@ class ContinuousBatcher:
             self.budget,
         ))
         chunks = 1
-        while self.overlap and self._overlap_ok(was_active, chunks):
+        while self.overlap:
+            with self._span("batcher.loop.plan"):
+                ahead = self._overlap_ok(was_active, chunks)
+            if not ahead:
+                break
             if self.faults is not None:
                 self.faults.fire("batcher.decode")
-            if self._tables_dirty:
-                # In-span growth extended a row's table: the next chunk
-                # must read/write through the grown pages.
-                plan["tables"] = jnp.asarray(self.tables)
-                self._tables_dirty = False
             rng_before = self._rng  # ghost refund point (below)
             nxt = self._dispatch_chunk(plan, out[3])
             self._note_gap(0.0)
@@ -4682,49 +4806,50 @@ class ContinuousBatcher:
             # Chunk N's host work, concurrent with chunk N+1 on device.
             host_t0 = time.perf_counter()
             toks, lps, m, active_after = self._fetch_chunk(out)
-            if self.speculative:
-                self._spec_note(m, was_active, plan)
-            if not active_after.any():
-                # Every row died (EOS) during the chunk we just fetched:
-                # the chunk dispatched ahead of it is a GHOST — all rows
-                # inactive, nothing sampled, its rng value irrelevant.
-                # REFUND its split so the engine RNG stream stays aligned
-                # with the synchronous loop (which never dispatches the
-                # ghost): sampled outputs of later requests match overlap
-                # off, not just temp-0 ones.  Only the last chunk of a
-                # span can be a ghost — the next _overlap_ok sees the
-                # all-idle activity vector and syncs.
-                self._rng = rng_before
-            self._collect(toks, was_active, counts=m, lps=lps,
-                          active_host=active_after)
-            self._prehash_queued()
-            lag = time.perf_counter() - host_t0
-            self.overlap_stats["host_lag_s"] += lag
-            METRICS.observe("batcher.overlap.host_lag_seconds", lag)
+            with self._span("batcher.loop.deliver"):
+                if self.speculative:
+                    self._spec_note(m, was_active, plan)
+                if not active_after.any():
+                    # Every row died (EOS) during the chunk we just
+                    # fetched: the chunk dispatched ahead of it is a GHOST
+                    # — all rows inactive, nothing sampled, its rng value
+                    # irrelevant.  REFUND its split so the engine RNG
+                    # stream stays aligned with the synchronous loop (which
+                    # never dispatches the ghost): sampled outputs of
+                    # later requests match overlap off, not just temp-0
+                    # ones.  Only the last chunk of a span can be a ghost
+                    # — the next _overlap_ok sees the all-idle activity
+                    # vector and syncs.
+                    self._rng = rng_before
+                self._collect(toks, was_active, counts=m, lps=lps,
+                              active_host=active_after)
+                self._prehash_queued()
+            self.overlap_stats["host_lag_s"] += time.perf_counter() - host_t0
             was_active = active_after
             out = nxt
         # Sync exit: mirrors refresh BEFORE _collect, so a cancel taken
         # inside the delivery callbacks lands on fresh state (the
         # synchronous loop's exact ordering).
         toks, lps, m = self._sync_carry(out)
-        if self.speculative:
-            self._spec_note(m, was_active, plan)
-        METRICS.set_gauge("batcher.overlap.depth", 0)
-        if self.overlap:
-            self.overlap_stats["carry_syncs"] += 1
-            METRICS.inc("batcher.overlap.carry_syncs")
-        if plan["constrain"]:
-            # Span boundary: pull the advanced automaton states back into
-            # the LOCAL per-row mirrors (abs index minus the row's stack
-            # offset) — preemption/cancel/admission decisions run against
-            # fresh dfa_row, like every other scheduling mirror.  Rows
-            # whose host bookkeeping dropped them mid-span are skipped
-            # (rid mismatch — their state is garbage by construction).
-            abs_states = np.asarray(jax.device_get(self._dfa_carry))
-            for i, off, rid in plan["constrain"]:
-                row = self.rows[i]
-                if row.rid == rid and row.req is not None \
-                        and row.req.constraint is not None:
-                    self.dfa_row[i] = int(abs_states[i]) - off
-        self._dfa_carry = None
-        self._collect(toks, was_active, counts=m, lps=lps)
+        with self._span("batcher.loop.deliver"):
+            if self.speculative:
+                self._spec_note(m, was_active, plan)
+            METRICS.set_gauge("batcher.overlap.depth", 0)
+            if self.overlap:
+                self.overlap_stats["carry_syncs"] += 1
+                METRICS.inc("batcher.overlap.carry_syncs")
+            if plan["constrain"]:
+                # Span boundary: pull the advanced automaton states back into
+                # the LOCAL per-row mirrors (abs index minus the row's stack
+                # offset) — preemption/cancel/admission decisions run against
+                # fresh dfa_row, like every other scheduling mirror.  Rows
+                # whose host bookkeeping dropped them mid-span are skipped
+                # (rid mismatch — their state is garbage by construction).
+                abs_states = np.asarray(jax.device_get(self._dfa_carry))
+                for i, off, rid in plan["constrain"]:
+                    row = self.rows[i]
+                    if row.rid == rid and row.req is not None \
+                            and row.req.constraint is not None:
+                        self.dfa_row[i] = int(abs_states[i]) - off
+            self._dfa_carry = None
+            self._collect(toks, was_active, counts=m, lps=lps)

@@ -116,7 +116,6 @@ class InferenceEngine:
             self._make_cache = lambda cfg_, b, s, prompt_len=None: model_lib.init_cache(
                 cfg_, b, s, dtype=kv_dtype
             )
-        self._timer = profiling.StepTimer("engine.generate")
         if rt.spec_decode:
             # CONFIG-DRIVEN knob policy (same as runtime.paged_pages on a
             # mesh engine): one shared cluster config with spec_decode on
@@ -308,7 +307,7 @@ class InferenceEngine:
             else contextlib.nullcontext()
         )
         t0 = time.perf_counter()
-        with profile_ctx, self._timer.step(tokens=n_real * n_new):
+        with profile_ctx, profiling.span("engine.generate"):
             out = gen_lib.generate_tokens(
                 self.params, self.cfg,
                 jnp.asarray(prompt_arr), jnp.asarray(lens), rng,
@@ -325,7 +324,6 @@ class InferenceEngine:
         texts = [tok.decode(row) for row in out]
         gen_count = int(out.shape[0] * out.shape[1])
         METRICS.inc("engine.generated_tokens", gen_count)
-        METRICS.observe("engine.generate_seconds", dt)
         return GenerationResult(
             text=texts, tokens=out,
             prompt_tokens=int(lens[:n_real].sum()), generated_tokens=gen_count,
@@ -398,7 +396,7 @@ class InferenceEngine:
         tok = self.tokenizer
         rng = jax.random.key(seed if seed is not None else self.rt.seed)
         t0 = time.perf_counter()
-        with self._timer.step(tokens=sess.n_real * n_new):
+        with profiling.span("engine.generate"):
             toks, cache, valid, real, spos = session_lib.session_step(
                 self.params, self.cfg, chunk, lens,
                 sess.real_lens, sess.valid_mask, sess.cache,
@@ -417,7 +415,6 @@ class InferenceEngine:
         texts = [tok.decode(row) for row in out]
         gen_count = int(out.shape[0] * out.shape[1])
         METRICS.inc("engine.generated_tokens", gen_count)
-        METRICS.observe("engine.generate_seconds", dt)
         return GenerationResult(
             text=texts, tokens=out,
             prompt_tokens=int(np.asarray(lens)[: sess.n_real].sum()),
@@ -850,7 +847,7 @@ class InferenceEngine:
             else contextlib.nullcontext()
         )
         t0 = time.perf_counter()
-        with profile_ctx, self._timer.step(tokens=n_real * n_new):
+        with profile_ctx, profiling.span("engine.generate"):
             out, stats = speculative_generate_tokens(
                 self.params, self.cfg, self.draft_params, self.draft_cfg,
                 jnp.asarray(prompt_arr), jnp.asarray(lens),
@@ -864,7 +861,6 @@ class InferenceEngine:
         profiling.record_memory_stats()
         drafted = max(int(stats["drafted"]), 1)
         METRICS.inc("engine.generated_tokens", int(out.shape[0] * out.shape[1]))
-        METRICS.observe("engine.generate_seconds", dt)
         METRICS.observe("engine.spec_acceptance",
                         int(stats["accepted"]) / drafted)
         return GenerationResult(

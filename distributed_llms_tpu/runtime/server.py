@@ -80,10 +80,12 @@ import json
 import math
 import threading
 import time
+import urllib.parse
 import uuid
 
 from ..core import profiling
 from ..core.observability import METRICS, get_logger
+from .batcher import FINISHED_KEEP
 from .scheduler import ANON_TENANT
 
 log = get_logger("server")
@@ -647,7 +649,8 @@ class InferenceServer:
 
     def _engine_loop(self) -> None:
         while True:
-            self._work.wait()
+            with profiling.span("server.engine.idle"):
+                self._work.wait()
             self._work.clear()
             if self._stopping:
                 # Drain before exiting: a request submitted between the
@@ -715,6 +718,9 @@ class InferenceServer:
         # Named prefixes are host-side KV (never donated); carry them over
         # so registered system prompts survive the restart.
         new.prefixes.update(old.prefixes)
+        # So does the slow-request record: what finished before the crash
+        # is what an operator will ask about after it.
+        new.finished.extend(old.finished_requests())  # graftlint: unguarded-ok(new is not yet published to any other thread)
         retried: list[int] = []
         failed: list[int] = []
         with self._submit_lock:
@@ -1032,6 +1038,19 @@ class InferenceServer:
                 writer, 200, "text/plain; version=0.0.4; charset=utf-8",
                 METRICS.prometheus_text().encode(),
             )
+        elif method == "GET" and path.partition("?")[0] == "/debug/requests":
+            # The slow-request record: the last finished requests with
+            # where each one's time went (batcher._note_finished); the
+            # spans of one request share its rid with these rows.
+            query = urllib.parse.parse_qs(path.partition("?")[2])
+            n = query.get("n", [str(FINISHED_KEEP)])[-1]
+            if not n.isdigit():
+                await self._json(writer, 400, _err_body(
+                    "'n' must be a non-negative integer"))
+                return
+            await self._json(writer, 200, {
+                "requests": self.batcher.finished_requests(int(n)),
+            })
         elif method == "GET" and path == "/v1/models":
             await self._json(writer, 200, {
                 "object": "list",
@@ -1361,6 +1380,8 @@ class InferenceServer:
             mbox.deadline = deadline
             mbox.meta = meta
             mboxes.append(mbox)
+        pre_submit_s = time.perf_counter() - t0
+        METRICS.observe("server.pre_submit_seconds", pre_submit_s)
         with self._submit_lock:
             for idx, mbox in enumerate(mboxes):
                 rid = self.batcher.next_rid
@@ -1374,6 +1395,7 @@ class InferenceServer:
                         deadline=deadline, response_format=response_format,
                         logit_bias=logit_bias, banned_tokens=banned_tokens,
                         constraint=dfa, tenant=tenant,
+                        pre_submit_s=pre_submit_s,
                     )
                     assert got == rid
                 except (ValueError, KeyError) as e:
@@ -2104,7 +2126,10 @@ class InferenceServer:
                 items = (ids[lp_sent:len(lps)], lps[lp_sent:])
                 lp_sent = len(lps)
                 return items
-            if delta and not done:
+            # With logprobs asked, every delivery that carries tokens is an
+            # event, text or none: a token that decodes to no text (a
+            # byte of a multi-byte character) is still one a client times.
+            if (delta or (want_lp and len(lps) > lp_sent)) and not done:
                 await emit(chunk(delta, None, lp_slice()))
             if done:
                 if reason == "length" and (stopped or (

@@ -650,3 +650,99 @@ def test_n_choices_blocking_and_stream(tiny):
         assert status == 400
 
     run_with_server(make_batcher(tiny), fn)
+
+
+# -- tracing: the slow-request record and per-delivery events ----------------
+
+
+def test_debug_requests_lists_finished_requests(tiny):
+    """GET /debug/requests: the batcher's finished ring as JSON, every
+    field there, `n` bounding the answer."""
+    fields = {"rid", "tenant", "prompt_tokens", "cached_tokens",
+              "out_tokens", "pre_submit_ms", "queue_ms", "admit_ms",
+              "decode_ms", "residencies", "finish"}
+
+    async def fn(host, port, srv):
+        status, body = await _request(host, port, "GET", "/debug/requests")
+        assert status == 200 and json.loads(body) == {"requests": []}
+        for i, prompt in enumerate(["first one", "second"]):
+            status, _ = await _request(
+                host, port, "POST", "/v1/completions",
+                {"prompt": prompt, "max_tokens": 5 + i, "tenant": "acme"},
+            )
+            assert status == 200
+        status, body = await _request(host, port, "GET", "/debug/requests")
+        recs = json.loads(body)["requests"]
+        assert status == 200 and len(recs) == 2
+        for rec, prompt, n in zip(recs, ["first one", "second"], [5, 6]):
+            assert set(rec) == fields
+            assert rec["tenant"] == "acme" and rec["finish"] == "length"
+            assert rec["prompt_tokens"] == len(srv.batcher.tokenizer.encode(prompt))
+            assert rec["out_tokens"] == n and rec["residencies"] == 1
+            assert rec["pre_submit_ms"] > 0 and rec["decode_ms"] >= 0
+        status, body = await _request(host, port, "GET", "/debug/requests?n=1")
+        assert json.loads(body)["requests"] == recs[-1:]
+        status, _ = await _request(host, port, "GET", "/debug/requests?n=x")
+        assert status == 400
+        # The gateway's own part of a request's time is a histogram too.
+        status, body = await _request(host, port, "GET", "/metrics")
+        assert b"server_pre_submit_seconds_count" in body
+        assert b"batcher_queue_wait_seconds_count" in body
+        assert b"server_engine_idle_seconds_count" in body
+
+    run_with_server(make_batcher(tiny), fn)
+
+
+class _MuteTokenizer(ByteTokenizer):
+    """Every token decodes to no text: what a real vocabulary gives under
+    random weights (a byte of a multi-byte character)."""
+
+    def decode(self, ids):
+        return ""
+
+
+def test_stream_with_logprobs_has_an_event_per_delivery(tiny):
+    """With logprobs asked, a delivery that carries tokens is an SSE event
+    even when its text delta is empty: a client can time every delivery
+    (and the first).  Without logprobs the stream keeps its old shape:
+    nothing to say, one final event."""
+    cfg, params = tiny
+    tok = _MuteTokenizer()
+    b = ContinuousBatcher(cfg, params, tokenizer=tok, eos_id=-1,
+                          pad_id=tok.pad_id, batch_slots=2, max_len=96,
+                          chunk_steps=4)
+
+    async def fn(host, port, srv):
+        deliveries = []
+        real = srv._deliver
+
+        def spy(rid, toks, done, lps=None):
+            deliveries.append(len(toks))
+            return real(rid, toks, done, lps)
+
+        srv._deliver = spy
+        status, events = await _sse_events(
+            host, port, "/v1/completions",
+            {"prompt": "abc", "max_tokens": 9, "logprobs": True,
+             "stream": True},
+        )
+        assert status == 200 and events[-1] == "[DONE]"
+        carried = [len(e["choices"][0]["logprobs"]["tokens"])
+                   for e in events[:-1]]
+        assert all(e["choices"][0]["text"] == "" for e in events[:-1])
+        assert sum(carried) == 9
+        # Admission token, then chunk by chunk; the last event is the
+        # finish, which carries whatever the done delivery brought.
+        with_tokens = [n for n in deliveries if n]
+        assert len(with_tokens) >= 3
+        assert [n for n in carried if n] == with_tokens
+        assert events[-2]["choices"][0]["finish_reason"] == "length"
+        assert all(e["choices"][0]["finish_reason"] is None
+                   for e in events[:-2])
+        status, events = await _sse_events(
+            host, port, "/v1/completions",
+            {"prompt": "abc", "max_tokens": 9, "stream": True},
+        )
+        assert status == 200 and len(events) == 2      # finish + [DONE]
+
+    run_with_server(b, fn)

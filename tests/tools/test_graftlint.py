@@ -226,6 +226,44 @@ def test_metric_drift(tmp_path):
     assert any("stale.gauge" in f.message for f in findings)
 
 
+def test_span_calls_emit_their_seconds_histogram(tmp_path):
+    """span("x") (core/profiling.py) observes "x_seconds": the call site
+    is the emitter GL302 checks and GL305 counts, through any of the
+    spellings the package uses."""
+    project = _project(tmp_path, {
+        "pkg/core/observability.py": (
+            "METRICS = None\n"
+            "METRIC_DOCS: dict[str, str] = {\n"
+            "    'loop.admit_seconds': 'a span',\n"
+            "    'loop.plan_seconds': 'a span',\n"
+            "    'engine.idle_seconds': 'a span',\n"
+            "    'loop.dead_seconds': 'no span emits this',\n"
+            "}\n"
+        ),
+        "pkg/srv.py": (
+            "from .core import profiling\n"
+            "from .core.profiling import span\n"
+            "class B:\n"
+            "    def f(self, name):\n"
+            "        with self._span('loop.admit', rid=3):\n"   # OK
+            "            pass\n"
+            "        with profiling.span('loop.plan'):\n"       # OK
+            "            pass\n"
+            "        with span('engine.idle'):\n"               # OK
+            "            pass\n"
+            "        with span('loop.amdit'):\n"                # typo: GL302
+            "            pass\n"
+            "        with self._span(name):\n"                  # dynamic
+            "            pass\n"
+        ),
+    })
+    findings = registry.check_metrics(project)
+    assert _rules(findings) == ["GL302", "GL302", "GL305"]
+    assert any("loop.amdit_seconds" in f.message for f in findings)
+    assert any("runtime-computed" in f.message for f in findings)
+    assert any("loop.dead_seconds" in f.message for f in findings)
+
+
 def test_cli_flag_short_alias_is_not_invisible(tmp_path):
     """add_argument('-p', '--port', ...) declares --port: the long name
     must be found even when a short alias is the first positional."""

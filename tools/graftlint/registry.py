@@ -13,7 +13,8 @@ declared registry:
   ``FAULT_SITES`` in ``runtime/faults.py``.
 - GL302: every metric name passed to ``METRICS.inc / set_gauge /
   set_gauges / observe / timer`` in the package must appear in
-  ``METRIC_DOCS`` in ``core/observability.py``.  f-string names are
+  ``METRIC_DOCS`` in ``core/observability.py``; a ``span("x")`` call
+  (``core/profiling.py``) emits the histogram ``x_seconds``.  f-string names are
   checked as patterns (each interpolation becomes ``*``) and must be
   registered VERBATIM as that pattern (e.g. ``faults.fired.*``); a fully
   dynamic name needs an ``ignore[GL302](<reason>)``.
@@ -186,12 +187,29 @@ def _pattern_of(node: ast.expr) -> str | None:
     return None
 
 
+def _span_call(node: ast.Call) -> bool:
+    """``span("x")``, ``profiling.span("x")`` or ``self._span("x")``: the
+    one span mechanism (core/profiling.py) observes ``x_seconds``."""
+    f = node.func
+    name = f.id if isinstance(f, ast.Name) else getattr(f, "attr", None)
+    return name in ("span", "_span") and bool(node.args)
+
+
 def _metric_name_nodes(sf: SourceFile) -> list[tuple[ast.expr, int]]:
     out: list[tuple[ast.expr, int]] = []
     for node in ast.walk(sf.tree):
         if not isinstance(node, ast.Call):
             continue
         f = node.func
+        if _span_call(node):
+            arg = node.args[0]
+            if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
+                arg = ast.copy_location(
+                    ast.Constant(arg.value + "_seconds"), arg)
+            # A non-literal span name stays as it is: reported as a
+            # runtime-computed metric name, like any other.
+            out.append((arg, node.lineno))
+            continue
         if not (isinstance(f, ast.Attribute) and isinstance(f.value, ast.Name)
                 and f.value.id == "METRICS"):
             continue

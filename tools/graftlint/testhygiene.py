@@ -4,7 +4,8 @@ A ``time.sleep`` in a non-``slow`` test is either a hidden race (the test
 passes because 50 ms usually suffices — until CI is loaded) or wasted
 wall-clock multiplied by every tier-1 run.  The deterministic levers this
 tree already owns — the fault plane's ``stall``/``delay`` actions, the
-injectable ``StepTimer`` clock — replace both shapes.
+injectable clocks (``profiling.span``, ``ContinuousBatcher``) — replace both
+shapes.
 
 Flagged: any ``time.sleep(...)`` (or bare ``sleep`` imported from
 ``time``) under ``tests/`` whose enclosing function, class, or module is
