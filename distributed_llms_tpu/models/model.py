@@ -10,6 +10,9 @@ Layout conventions:
   under ``lax.scan`` so XLA traces one block and reuses it L times.
 - KV cache is a preallocated [L, B, S, KVH, HD] pair living in HBM, updated
   with ``dynamic_update_slice`` at jit-static shapes.
+- Paged serving keeps the KV as a page pool instead, one stack
+  [L, NB, BLK, KVH, HD] for all layers, which the layer scan CARRIES and
+  scatters into where it lies (``run_blocks``, ``_paged_attention``).
 """
 
 from __future__ import annotations
@@ -98,56 +101,65 @@ def init_cache(
 # Blocks
 # ---------------------------------------------------------------------------
 
-def _paged_window_attention(q, k, v, p, layer_cache, cache_index, kv_tables):
-    """T-token paged attention window (T >= 2, static): the speculative
-    draft/verify pass against a page-pool cache.  K/V for all T tokens
-    scatter through the page table first (slots cache_index..+T-1 — the
-    caller's growth loop guaranteed pages cover the window), then each
-    query offset j reads its row's prefix through slot cache_index+j via
-    the paged decode kernel.  Freed rows' tables are zeroed to the shared
-    scratch page, so duplicate (page, off) scatter targets are possible
-    and tolerated exactly as in the single-token leg (XLA picks a winner;
-    no live row reads the scratch page).  An int8 pool (4-tuple
-    layer_cache) quantizes the whole window once at the write and hands
-    the kernel the scales — pool reads stay 1 byte/elem."""
+def _paged_attention(q, k, v, p, pool, layer, cache_index, kv_tables):
+    """Attention of T new tokens a row (T static: 1 in a decode step,
+    spec_k + 1 in the speculative draft/verify pass) against the page
+    pool.  ``pool`` is the WHOLE stack, every layer's pages — leaves
+    [L, NB, BLK, KVH, HD], and on an int8 pool (four leaves) the scale
+    stacks [L, NB, BLK, KVH] — and ``layer`` the traced index of this
+    layer: the stack is the layer scan's carry, written where it lies and
+    read by the kernel through (layer, page), so no program ever holds a
+    layer's slice of it as a buffer of its own.
+
+    K/V for all T tokens scatter through the page table first (row b's
+    slots cache_index[b]..+T-1 — the caller's growth loop guaranteed pages
+    cover them), then query j reads its row's prefix through slot
+    cache_index[b]+j: per-offset lengths give exact causality inside the
+    window while the paged kernel's prefix contract covers everything
+    before it.  The reads unroll into T kernel calls inside ONE compiled
+    program.  Rollback is free: slots past the committed frontier hold
+    junk no read ever admits (lengths cap every read), awaiting overwrite.
+
+    LIVE rows own distinct pages, but FREED rows' tables are zeroed to the
+    shared scratch page, so two inactive rows CAN produce identical
+    (page, off) indices — the scatter must tolerate duplicates (XLA picks
+    a winner; the scratch page is never read by a live row).  Do NOT add
+    unique_indices=True here.
+
+    An int8 pool quantizes each new K/V vector per (row, head) once, at
+    the write (checkpoint.quantize.kv_quantize), and hands the kernel the
+    scales: its int8 leg folds them into the attention contraction, so
+    the pool is read at 1 byte/elem and never dequantized in HBM."""
     from ..ops import decode_attn
 
     t_w = q.shape[1]
     rows = jnp.arange(q.shape[0], dtype=jnp.int32)
-    quant = len(layer_cache) == 4
-    blk = layer_cache[0].shape[1]
+    blk = pool[0].shape[2]
     idx = cache_index[:, None] + jnp.arange(t_w, dtype=jnp.int32)[None, :]
     page = kv_tables[rows[:, None], idx // blk]  # [B, T]
     off = idx % blk
-    if quant:
+    if len(pool) == 4:
         from ..checkpoint.quantize import kv_quantize
 
-        ck, cv, sk, sv = layer_cache
-        kq, ks = kv_quantize(k)  # [B, T, KVH, HD] i8, [B, T, KVH] f32
-        vq, vs = kv_quantize(v)
-        ck = ck.at[page, off].set(kq)
-        cv = cv.at[page, off].set(vq)
-        sk = sk.at[page, off].set(ks)
-        sv = sv.at[page, off].set(vs)
-        new_cache = (ck, cv, sk, sv)
-        scales = {"k_scale": sk, "v_scale": sv}
+        (k, ks), (v, vs) = kv_quantize(k), kv_quantize(v)  # [B, T, KVH, HD]
+        new = (k, v, ks, vs)  #                    i8 and [B, T, KVH] f32
     else:
-        ck, cv = layer_cache
-        ck = ck.at[page, off].set(k.astype(ck.dtype))
-        cv = cv.at[page, off].set(v.astype(cv.dtype))
-        new_cache = (ck, cv)
-        scales = {}
+        new = (k.astype(pool[0].dtype), v.astype(pool[1].dtype))
+    pool = tuple(
+        leaf.at[layer, page, off].set(x) for leaf, x in zip(pool, new)
+    )
+    scales = dict(zip(("k_scale", "v_scale"), pool[2:]))
     out = jnp.concatenate(
         [
             decode_attn.paged_decode_attention(
-                q[:, j: j + 1], ck, cv, cache_index + 1 + j, kv_tables,
-                **scales,
+                q[:, j: j + 1], pool[0], pool[1], cache_index + 1 + j,
+                kv_tables, layer=layer, **scales,
             )
             for j in range(t_w)
         ],
         axis=1,
     )
-    return layers.out_project(out, p), new_cache
+    return layers.out_project(out, p), pool
 
 
 @jax.named_scope("attn")  # profiler scope; HLO metadata only
@@ -163,12 +175,14 @@ def _attention(
     std_layout: bool = False,  # positions are the standard arange (forward
     #                            generated them itself) — unlocks the flash
     #                            kernel's static-causal fast path
-    kv_tables: jax.Array | None = None,  # [B, P] int32 page table: the
-    #                            layer cache is a PAGE POOL [NB, BLK, KVH,
-    #                            HD] and row b's slot s lives at
-    #                            (tables[b, s//BLK], s%BLK).  Decode-only
-    #                            (T == 1, per-row cache_index); the mask is
-    #                            implicitly the prefix [0, cache_index[b]].
+    kv_tables: jax.Array | None = None,  # [B, P] int32 page table:
+    #                            layer_cache is the whole PAGE POOL, the
+    #                            stack [L, NB, BLK, KVH, HD] of every layer
+    #                            (see _paged_attention), and row b's slot s
+    #                            lives at (layer, tables[b, s//BLK], s%BLK).
+    #                            Decode-only (per-row cache_index); the
+    #                            mask is implicitly the prefix
+    #                            [0, cache_index[b]].
     key_positions: jax.Array | None = None,  # [B, S] true RoPE position of
     #                            each cache slot — ONLY consulted by the
     #                            sliding-window mask.  Contiguous layouts
@@ -181,6 +195,8 @@ def _attention(
     #                            slot T+j but position len+j) AND multi-turn
     #                            sessions (session_step carries the map as
     #                            Session.slot_positions state).
+    layer: jax.Array | None = None,  # this layer's index into the pool
+    #                            stack (with kv_tables only)
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array] | None]:
     q, k, v = layers.qkv_project(x, p, cfg)
     if use_rope:
@@ -217,71 +233,9 @@ def _attention(
                 "paged decode attends each row's full cache prefix; it "
                 "cannot honor sliding_window"
             )
-        from ..ops import decode_attn
-
-        if x.shape[1] > 1:
-            # Multi-token paged WINDOW (the speculative verify pass): row
-            # b's T tokens scatter their K/V through the page table at
-            # slots cache_index[b]..cache_index[b]+T-1, and query j reads
-            # its row's prefix through slot cache_index[b]+j — per-offset
-            # lengths give exact causality inside the window while the
-            # paged kernel's prefix contract covers everything before it.
-            # T is static (spec_k + 1), so the per-offset reads unroll
-            # into T kernel calls inside ONE compiled program; the MXU
-            # still sees the (k+1)-token matmuls everywhere else in the
-            # block, which is the point of verification.  Rollback is
-            # free, exactly like the contiguous spec cache: slots past
-            # the committed frontier hold junk no read ever admits
-            # (lengths cap every read), awaiting overwrite.
-            return _paged_window_attention(
-                q, k, v, p, layer_cache, cache_index, kv_tables
-            )
-
-        if len(layer_cache) == 4:
-            # Int8-quantized pool (QuantKVCache per layer): quantize this
-            # step's single K/V vector per (row, head) with the absmax
-            # scale machinery (checkpoint.quantize.kv_quantize), scatter
-            # int8 data + f32 scale, and hand the kernel the scales — the
-            # int8 leg folds them into the attention contraction, so the
-            # pool is read at 1 byte/elem and never dequantized in HBM.
-            from ..checkpoint.quantize import kv_quantize
-
-            ck, cv, sk, sv = layer_cache
-            blk = ck.shape[1]
-            rows = jnp.arange(x.shape[0], dtype=jnp.int32)
-            page = kv_tables[rows, cache_index // blk]
-            off = cache_index % blk
-            kq, ks = kv_quantize(k[:, 0])  # [B, KVH, HD] i8, [B, KVH] f32
-            vq, vs = kv_quantize(v[:, 0])
-            # Same duplicate-tolerant scatter contract as the full-width
-            # branch below (freed rows share the scratch page).
-            ck = ck.at[page, off].set(kq)
-            cv = cv.at[page, off].set(vq)
-            sk = sk.at[page, off].set(ks)
-            sv = sv.at[page, off].set(vs)
-            out = decode_attn.paged_decode_attention(
-                q, ck, cv, cache_index + 1, kv_tables,
-                k_scale=sk, v_scale=sv,
-            )
-            return layers.out_project(out, p), (ck, cv, sk, sv)
-
-        ck, cv = layer_cache  # [NB, BLK, KVH, HD] page pools
-        blk = ck.shape[1]
-        rows = jnp.arange(x.shape[0], dtype=jnp.int32)
-        page = kv_tables[rows, cache_index // blk]
-        off = cache_index % blk
-        # Per-row single-slot write into each row's current page.  LIVE
-        # rows own distinct pages, but FREED rows' tables are zeroed to the
-        # shared scratch page, so two inactive rows CAN produce identical
-        # (page, off) indices — the scatter must tolerate duplicates (XLA
-        # picks a winner; the scratch page is never read by a live row).
-        # Do NOT add unique_indices=True here.
-        ck = ck.at[page, off].set(k[:, 0].astype(ck.dtype))
-        cv = cv.at[page, off].set(v[:, 0].astype(cv.dtype))
-        out = decode_attn.paged_decode_attention(
-            q, ck, cv, cache_index + 1, kv_tables
+        return _paged_attention(
+            q, k, v, p, layer_cache, layer, cache_index, kv_tables
         )
-        return layers.out_project(out, p), (ck, cv)
 
     if (
         cfg.attn_impl == "flash"
@@ -503,22 +457,22 @@ def _seq_cached_attention(
     return layers.out_project(out, p), ((ck_pref, ck_dec), (cv_pref, cv_dec))
 
 
-def gpt2_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, std_layout=False, kv_tables=None, key_positions=None):
+def gpt2_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, std_layout=False, kv_tables=None, key_positions=None, layer=None):
     """-> (x, new_cache, aux): aux is the MoE load-balance term (0 here).
     Shared by the gpt2 and opt families (pre-LN + learned positions);
     cfg.activation picks the MLP nonlinearity (gelu vs relu)."""
     h = layers.layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"], cfg.norm_eps)
-    attn_out, new_cache = _attention(h, p["attn"], cfg, positions, layer_cache, cache_index, use_rope=False, attn_mask=attn_mask, std_layout=std_layout, kv_tables=kv_tables, key_positions=key_positions)
+    attn_out, new_cache = _attention(h, p["attn"], cfg, positions, layer_cache, cache_index, use_rope=False, attn_mask=attn_mask, std_layout=std_layout, kv_tables=kv_tables, key_positions=key_positions, layer=layer)
     x = x + attn_out
     h = layers.layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"], cfg.norm_eps)
     x = x + layers.mlp_gelu(h, p["mlp"], cfg.activation)
     return x, new_cache, jnp.float32(0.0)
 
 
-def llama_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, std_layout=False, kv_tables=None, key_positions=None):
+def llama_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, std_layout=False, kv_tables=None, key_positions=None, layer=None):
     """-> (x, new_cache, aux): aux is the MoE load-balance term."""
     h = layers.rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
-    attn_out, new_cache = _attention(h, p["attn"], cfg, positions, layer_cache, cache_index, use_rope=True, attn_mask=attn_mask, std_layout=std_layout, kv_tables=kv_tables, key_positions=key_positions)
+    attn_out, new_cache = _attention(h, p["attn"], cfg, positions, layer_cache, cache_index, use_rope=True, attn_mask=attn_mask, std_layout=std_layout, kv_tables=kv_tables, key_positions=key_positions, layer=layer)
     x = x + attn_out
     h = layers.rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
     if "router" in p["mlp"]:  # MoE block (cfg.num_experts > 0)
@@ -528,13 +482,13 @@ def llama_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, 
     return x, new_cache, jnp.float32(0.0)
 
 
-def neox_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, std_layout=False, kv_tables=None, key_positions=None):
+def neox_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, std_layout=False, kv_tables=None, key_positions=None, layer=None):
     """GPT-NeoX/Pythia: LayerNorm + (partial) rotary + optionally PARALLEL
     residual — out = x + attn(ln1 x) + mlp(ln2 x), both norms reading the
     SAME input (HF use_parallel_residual, the NeoX default); sequential
     pre-LN otherwise.  -> (x, new_cache, aux)."""
     h = layers.layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"], cfg.norm_eps)
-    attn_out, new_cache = _attention(h, p["attn"], cfg, positions, layer_cache, cache_index, use_rope=True, attn_mask=attn_mask, std_layout=std_layout, kv_tables=kv_tables, key_positions=key_positions)
+    attn_out, new_cache = _attention(h, p["attn"], cfg, positions, layer_cache, cache_index, use_rope=True, attn_mask=attn_mask, std_layout=std_layout, kv_tables=kv_tables, key_positions=key_positions, layer=layer)
     if cfg.parallel_residual:
         h2 = layers.layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"], cfg.norm_eps)
         return x + attn_out + layers.mlp_gelu(h2, p["mlp"], cfg.activation), new_cache, jnp.float32(0.0)
@@ -552,7 +506,8 @@ def run_blocks(
     blocks: Params,
     cfg: ModelConfig,
     positions: jax.Array,
-    cache_k: jax.Array | None,  # [L, B, S, KVH, HD] slice for these blocks
+    cache_k: jax.Array | None,  # these blocks' keys: [L, B, S, KVH, HD]
+    #   contiguous, or with kv_tables the page pool [L, NB, BLK, KVH, HD]
     cache_v: jax.Array | None,
     cache_index: jax.Array | None,
     remat: bool = False,
@@ -561,13 +516,22 @@ def run_blocks(
     kv_tables: jax.Array | None = None,
     key_positions: jax.Array | None = None,  # see _attention
     cache_sk: jax.Array | None = None,  # [L, NB, BLK, KVH] f32 absmax
-    #   scales of an int8 page pool (QuantKVCache); layer_cache becomes a
-    #   4-tuple per layer and the paged decode reads/writes quantized
+    #   scales of an int8 page pool (QuantKVCache): the pool then has four
+    #   leaves and the paged decode reads/writes quantized
     cache_sv: jax.Array | None = None,
 ) -> tuple[jax.Array, tuple | None, jax.Array]:
     """Scan the stacked blocks over x.  Used both for the whole model and for
     a single pipeline stage (blocks then hold only the stage's layer slice).
-    Returns (x, caches, aux) — aux sums the MoE load-balance terms.
+    Returns (x, cache', aux) — aux sums the MoE load-balance terms.
+
+    A contiguous cache enters the scan as scanned inputs and leaves it as
+    stacked outputs, one layer's [B, S, KVH, HD] at a time.  A page pool
+    (``kv_tables``) is the scan's CARRY instead, beside x: each layer
+    scatters its new K/V into the stack at (layer, page, off) and the
+    paged kernel reads its pages out of the stack, so the pool is updated
+    where it lies.  Sliced per layer it would be copied whole four times
+    a decode step (a Pallas call takes each operand as a buffer of its
+    own, and the stacked outputs are a second pool).
 
     Blocks may carry ``QuantizedTensor`` leaves (weight-only quantized
     serving): weights live in HBM at int8/int4 and flow through the scan to
@@ -581,33 +545,35 @@ def run_blocks(
             y, _, aux = block_fn(carry, layer_params, cfg, positions, None, None, attn_mask, std_layout)
             return y, aux
 
-        if remat:
-            body = jax.checkpoint(body)
-        x, auxs = jax.lax.scan(body, x, blocks)
-        return x, None, jnp.sum(auxs)
+        init, xs = x, blocks
+    elif kv_tables is not None:
+        def body(carry, xs):
+            y, pool = carry
+            layer_params, layer = xs
+            y, pool, aux = block_fn(y, layer_params, cfg, positions, pool, cache_index, attn_mask, std_layout, kv_tables, key_positions, layer)
+            return (y, pool), aux
 
-    if cache_sk is not None:
-        def body_q(carry, xs):
-            layer_params, ck, cv, sk, sv = xs
-            y, new_cache, aux = block_fn(carry, layer_params, cfg, positions, (ck, cv, sk, sv), cache_index, attn_mask, std_layout, kv_tables, key_positions)
+        pool = (cache_k, cache_v)
+        if cache_sk is not None:
+            pool += (cache_sk, cache_sv)
+        init = (x, pool)
+        xs = (blocks, jnp.arange(cache_k.shape[0], dtype=jnp.int32))
+    else:
+        def body(carry, xs):
+            layer_params, ck, cv = xs
+            y, new_cache, aux = block_fn(carry, layer_params, cfg, positions, (ck, cv), cache_index, attn_mask, std_layout, None, key_positions)
             return y, (new_cache, aux)
 
-        if remat:
-            body_q = jax.checkpoint(body_q)
-        x, (new_cache, auxs) = jax.lax.scan(
-            body_q, x, (blocks, cache_k, cache_v, cache_sk, cache_sv)
-        )
-        return x, new_cache, jnp.sum(auxs)
-
-    def body(carry, xs):
-        layer_params, ck, cv = xs
-        y, new_cache, aux = block_fn(carry, layer_params, cfg, positions, (ck, cv), cache_index, attn_mask, std_layout, kv_tables, key_positions)
-        return y, (new_cache, aux)
+        init, xs = x, (blocks, cache_k, cache_v)
 
     if remat:
         body = jax.checkpoint(body)
-    x, ((new_k, new_v), auxs) = jax.lax.scan(body, x, (blocks, cache_k, cache_v))
-    return x, (new_k, new_v), jnp.sum(auxs)
+    out, ys = jax.lax.scan(body, init, xs)
+    if cache_k is None:
+        return out, None, jnp.sum(ys)
+    if kv_tables is not None:
+        return *out, jnp.sum(ys)
+    return out, ys[0], jnp.sum(ys[1])
 
 
 # ---------------------------------------------------------------------------

@@ -109,8 +109,8 @@ def _kernel(
             # Int8 leg: the cast is the only widening (one block in VMEM);
             # the absmax scales fold into the contraction below instead of
             # dequantizing the block.
-            kb = k_ref[0, :, hh, :].astype(q_ref.dtype)
-            vb = v_ref[0, :, hh, :].astype(q_ref.dtype)
+            kb = _head(k_ref, hh).astype(q_ref.dtype)
+            vb = _head(v_ref, hh).astype(q_ref.dtype)
             s = (
                 jax.lax.dot_general(
                     q_ref[0, r0:r1, :], kb, (((1,), (1,)), ((), ())),
@@ -154,11 +154,18 @@ def _kernel(
         o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
-def _kernel_paged(lengths_ref, tables_ref, *rest, **kw):
-    """Paged variant: the page table is consumed ONLY by the BlockSpec index
-    maps (it redirects each K block's DMA to the row's page in the pool);
-    the compute body is identical to the contiguous kernel."""
-    del tables_ref
+def _head(ref, hh: int):
+    """Head ``hh``'s [bk, D] out of a K/V block: [1, bk, KVH, D], or the
+    [1, bk, D] block of a pool that holds a single head (see _paged_impl)."""
+    return ref[0, :, hh, :] if len(ref.shape) == 4 else ref[0]
+
+
+def _kernel_paged(lengths_ref, tables_ref, layer_ref, *rest, **kw):
+    """Paged variant: the page table and the layer index are consumed ONLY
+    by the BlockSpec index maps (they redirect each K block's DMA to the
+    row's page in that layer of the pool); the compute body is identical
+    to the contiguous kernel."""
+    del tables_ref, layer_ref
     return _kernel(lengths_ref, *rest, **kw)
 
 
@@ -354,26 +361,34 @@ def _kv_vmem_ok(bk: int, kvh: int, d: int, dtype) -> bool:
 
 def paged_decode_attention(
     q: jax.Array,  # [B, 1, H, D]
-    k_pages: jax.Array,  # [NB, BLK, KVH, D] — the shared page pool
-    v_pages: jax.Array,  # [NB, BLK, KVH, D]
+    k_pages: jax.Array,  # [L, NB, BLK, KVH, D] — the shared page pool, every
+    #                     layer's pages in one stack (or one layer's
+    #                     [NB, BLK, KVH, D]: the stack of one layer)
+    v_pages: jax.Array,  # same shape
     lengths: jax.Array,  # [B] int32 — row b attends its first lengths[b] slots
     tables: jax.Array,  # [B, P] int32 — page ids; entries past the row's
     #                     depth may be arbitrary (never dereferenced by the
     #                     kernel: the index map clamps to the last needed
     #                     page; the fallback masks their scores)
-    k_scale: jax.Array | None = None,  # [NB, BLK, KVH] f32 absmax scales —
-    #                     int8 leg: pages are int8 (QuantKVCache pools) and
+    k_scale: jax.Array | None = None,  # [L, NB, BLK, KVH] f32 absmax scales
+    #                     (one axis fewer for a rank-4 pool) — int8 leg:
+    #                     pages are int8 (QuantKVCache pools) and
     #                     the kernel fuses scale into the contraction, so
     #                     the pool reads 1 byte/elem and a dequantized page
     #                     never exists in HBM
     v_scale: jax.Array | None = None,
+    layer: jax.Array | int = 0,  # which layer of the stack to read (traced
+    #                     inside the layer scan)
 ) -> jax.Array:
     """Paged variant of :func:`ragged_decode_attention`: the KV cache lives
     as pool pages indexed per row through a block table (vLLM-style memory
-    management, TPU-native static shapes).  The page table is scalar-
-    prefetched and consumed by the K/V BlockSpec index maps, so each row's
-    DMA walks its own pages and reads only its real depth.  Returns
-    [B, 1, H, D] in q.dtype.  Inference-only.
+    management, TPU-native static shapes).  The page table and the layer
+    index are scalar-prefetched and consumed by the K/V BlockSpec index
+    maps, so each row's DMA walks its own pages in that layer of the stack
+    and reads only its real depth: the caller never slices a layer out of
+    the pool (a Pallas call wants each operand as a buffer of its own, so
+    a slice handed in is a copy of that layer, every layer, every step).
+    Returns [B, 1, H, D] in q.dtype.  Inference-only.
 
     Under a tensor-parallel mesh (:func:`dispatch.sharded`) the pool (and
     its int8 scales) shard over the KV-head axis, each shard runs the
@@ -382,9 +397,14 @@ def paged_decode_attention(
     axis)."""
     mode = _mode()
     quant = _check_quant(k_pages, k_scale, v_scale)
+    if k_pages.ndim == 4:  # one layer's pages: the stack of one layer
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        if quant:
+            k_scale, v_scale = k_scale[None], v_scale[None]
     impl = functools.partial(_paged_impl, mode=mode)
     args = (q, k_pages, v_pages, lengths.astype(jnp.int32),
-            tables.astype(jnp.int32))
+            tables.astype(jnp.int32),
+            jnp.asarray(layer, jnp.int32).reshape(1))
     if quant:
         args += (k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
     mesh = dispatch.mesh()
@@ -399,14 +419,16 @@ def paged_decode_attention(
 
 
 def _paged_impl(
-    q, k_pages, v_pages, lengths, tables, k_scale=None, v_scale=None, *,
-    mode: str = "fallback",
+    q, k_pages, v_pages, lengths, tables, layer, k_scale=None, v_scale=None,
+    *, mode: str = "fallback",
 ) -> jax.Array:
-    """Single-shard body of the paged kernel (see _ragged_impl)."""
+    """Single-shard body of the paged kernel (see _ragged_impl): the pool
+    is the stack [L, NB, BLK, KVH, D] and ``layer`` [1] int32 names the
+    layer to read."""
     b, t, h, d = q.shape
     assert t == 1, "paged decode attention is single-token by construction"
     quant = k_scale is not None
-    nb, blk, kvh = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
+    blk, kvh = k_pages.shape[2], k_pages.shape[3]
     p = tables.shape[1]
     g = h // kvh
     tileable = (
@@ -414,16 +436,17 @@ def _paged_impl(
     )
     if mode == "fallback" or not tileable:
         dispatch.record("paged_decode", "fallback", (b, blk, h, kvh, d))
-        # Gather the rows' pages into contiguous [B, P*BLK] caches (the
-        # fallback materializes; the kernel never does).  Int8 pools
-        # dequantize the gathered rows at kv_dequantize numerics.
-        k_rows = k_pages[tables].reshape(b, p * blk, kvh, d)
-        v_rows = v_pages[tables].reshape(b, p * blk, kvh, d)
+        # Gather the rows' pages out of the layer into contiguous
+        # [B, P*BLK] caches (the fallback materializes; the kernel never
+        # does).  Int8 pools dequantize the gathered rows at
+        # kv_dequantize numerics.
+        k_rows = k_pages[layer[0]][tables].reshape(b, p * blk, kvh, d)
+        v_rows = v_pages[layer[0]][tables].reshape(b, p * blk, kvh, d)
         if quant:
             k_rows, v_rows = _dequant(
                 k_rows, v_rows,
-                k_scale[tables].reshape(b, p * blk, kvh),
-                v_scale[tables].reshape(b, p * blk, kvh),
+                k_scale[layer[0]][tables].reshape(b, p * blk, kvh),
+                v_scale[layer[0]][tables].reshape(b, p * blk, kvh),
                 q.dtype,
             )
         return _dense_reference(q, k_rows, v_rows, lengths)
@@ -434,42 +457,66 @@ def _paged_impl(
     if gp != g:
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
 
-    def kv_index(bi, ji, lengths_ref, tables_ref):
+    def page(bi, ji, lengths_ref, tables_ref):
         last = jax.lax.div(jnp.maximum(lengths_ref[bi] - 1, 0), blk)
-        return (tables_ref[bi, jnp.minimum(ji, last)], 0, 0, 0)
+        return tables_ref[bi, jnp.minimum(ji, last)]
 
-    def scale_index(bi, ji, lengths_ref, tables_ref):
-        return kv_index(bi, ji, lengths_ref, tables_ref)[:3]
-
+    # The layer axis is squeezed out of the block (None), so the kernel
+    # body sees today's (1, blk, kvh, d) page whatever the stack's depth.
+    kv_block = (None, 1, blk, kvh, d)
+    if kvh == 1:
+        # A pool of ONE KV head (qwen2's four over mesh.model=4): the
+        # device keeps [L, NB, BLK, 1, D] tiled over (BLK, D), the
+        # degenerate axis out of the way, where a Pallas operand of that
+        # rank must be tiled over (1, D) — the whole stack would be
+        # copied into that layout, in every layer.  Without the axis the
+        # reshape is free and the operand is the pool as it lies.
+        k_pages, v_pages = (x.reshape(*x.shape[:3], d)
+                            for x in (k_pages, v_pages))
+        kv_block = (None, 1, blk, d)
+    kv_spec = pl.BlockSpec(
+        kv_block,
+        lambda bi, ji, L, T, Y: (Y[0], page(bi, ji, L, T))
+        + (0,) * (len(kv_block) - 2),
+    )
     in_specs = [
         pl.BlockSpec(
-            (1, kvh * gp, d), lambda bi, ji, L, T: (bi, 0, 0)
+            (1, kvh * gp, d), lambda bi, ji, L, T, Y: (bi, 0, 0)
         ),
-        pl.BlockSpec((1, blk, kvh, d), kv_index),
-        pl.BlockSpec((1, blk, kvh, d), kv_index),
+        kv_spec,
+        kv_spec,
     ]
     operands = [
-        lengths.astype(jnp.int32), tables.astype(jnp.int32),
+        lengths.astype(jnp.int32), tables.astype(jnp.int32), layer,
         qt.reshape(b, kvh * gp, d), k_pages, v_pages,
     ]
     if quant:
+        # The scales go in as THIS layer's [1, NB, BLK, KVH] slice, not
+        # as the stack: a Pallas operand is tiled over its last two axes,
+        # and (BLK, KVH) pads four heads to 128 lanes, 32-fold.  The
+        # slice is padded as it is cut (17 MB for 512 pages); the stack
+        # handed whole would be padded whole, in every layer.
         in_specs += [
-            pl.BlockSpec((1, blk, kvh), scale_index),
-            pl.BlockSpec((1, blk, kvh), scale_index),
+            pl.BlockSpec(
+                (None, 1, blk, kvh),
+                lambda bi, ji, L, T, Y: (0, page(bi, ji, L, T), 0, 0),
+            )
+        ] * 2
+        operands += [
+            jax.lax.dynamic_index_in_dim(x.astype(jnp.float32), layer[0])
+            for x in (k_scale, v_scale)
         ]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
     out = pl.pallas_call(
         functools.partial(
             _kernel_paged, scale=d**-0.5, block_k=blk, num_k_blocks=p,
             kvh=kvh, gp=gp, quant=quant,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b, p),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
-                (1, kvh * gp, d), lambda bi, ji, L, T: (bi, 0, 0)
+                (1, kvh * gp, d), lambda bi, ji, L, T, Y: (bi, 0, 0)
             ),
             scratch_shapes=[
                 pltpu.VMEM((kvh * gp, d), jnp.float32),
@@ -492,7 +539,8 @@ def _paged_impl(
 # g = H/KVH is shard-invariant), the batch over 'data'.  Lengths and page
 # tables shard only with the batch axis — on a pure-TP mesh they replicate;
 # int8 absmax scales shard with their pages on the KV-head axis.  Pool
-# pages are shared across rows, so the page axis never shards.
+# pages are shared across rows, so the page axis never shards; nor does the
+# pool's leading layer axis, and the layer index replicates.
 
 
 def _ragged_operand_specs(b_ax, h_ax, quant: bool) -> dict:
@@ -511,14 +559,15 @@ def _ragged_operand_specs(b_ax, h_ax, quant: bool) -> dict:
 def _paged_operand_specs(b_ax, h_ax, quant: bool) -> dict:
     specs = {
         "q": P(b_ax, None, h_ax, None),
-        "k_pages": P(None, None, h_ax, None),
-        "v_pages": P(None, None, h_ax, None),
+        "k_pages": P(None, None, None, h_ax, None),
+        "v_pages": P(None, None, None, h_ax, None),
         "lengths": P(b_ax),
         "tables": P(b_ax, None),
+        "layer": P(None),
     }
     if quant:
-        specs["k_scale"] = P(None, None, h_ax)
-        specs["v_scale"] = P(None, None, h_ax)
+        specs["k_scale"] = P(None, None, None, h_ax)
+        specs["v_scale"] = P(None, None, None, h_ax)
     return specs
 
 
@@ -532,10 +581,10 @@ def spmd_operand_specs(
     against abstract operand trees (axis names, rank, divisibility).
     Every shard must hold WHOLE heads on both operands (the kernel's
     static head loop), so an axis that does not divide replicates.
-    ``kv_shape`` is the K operand: [B, S, KVH, D] contiguous or
-    [NB, BLK, KVH, D] pool pages."""
+    ``kv_shape`` is the K operand: [B, S, KVH, D] contiguous or the
+    [L, NB, BLK, KVH, D] pool stack."""
     b, _, h, _ = q_shape
     b_ax = dispatch.axis(mesh, "data", b)
-    h_ax = dispatch.axis(mesh, "model", h, kv_shape[2])
+    h_ax = dispatch.axis(mesh, "model", h, kv_shape[-2])
     build = _paged_operand_specs if paged else _ragged_operand_specs
     return build(b_ax, h_ax, quant), P(b_ax, None, h_ax, None)

@@ -901,7 +901,12 @@ def _pool_constrain(pm, cache):
 def _paged_pool(cfg: ModelConfig, num_pages: int, page_size: int, dtype=None,
                 kv_bits: int = 16):
     """KV page pools [L, NB, BLK, KVH, HD] (distinct k/v buffers — the
-    chunk fns donate the cache).  ``kv_bits=8`` builds an int8
+    chunk fns donate the cache).  Each is ONE stack of every layer's
+    pages and stays one: the decode programs carry it through the layer
+    scan and update it in place (models.model.run_blocks), the paged
+    kernel reads (layer, page) out of it, and admissions write whole
+    pages into it (:func:`_write_pages`); nothing holds a layer's slice
+    or a second stack.  ``kv_bits=8`` builds an int8
     :class:`~..models.model.QuantKVCache` pool (data int8 + one f32 absmax
     scale per head-dim vector) at roughly half the bytes per token; the
     full-width dtype survives as ``row_dtype`` so gathers/transient rows
@@ -960,34 +965,53 @@ def _paged_splice(cache, page_list, row_cache, logits, last_idx, rng,
     p = page_list.shape[0]
     blk = cache.k.shape[2]
 
+    def as_pages(row):  # [L, 1, P*BLK, KVH, HD] -> [L, P, BLK, KVH, HD]
+        l, _, _, kvh, hd = row.shape
+        return row[:, 0].reshape(l, p, blk, kvh, hd)
+
+    k, v = as_pages(row_cache.k), as_pages(row_cache.v)
     if isinstance(cache, QuantKVCache):
         # Quantize ONCE at the write: each page's head-dim vectors get
         # int8 data + one f32 absmax scale (checkpoint.quantize
         # machinery); pool storage never sees the full-width row again.
         from ..checkpoint.quantize import kv_quantize
 
-        def qsplice(pool, spool, row):
-            l, _, _, kvh, hd = row.shape
-            pages = row[:, 0].reshape(l, p, blk, kvh, hd)
-            data, scale = kv_quantize(pages)
-            return (pool.at[:, page_list].set(data),
-                    spool.at[:, page_list].set(scale))
-
-        k, sk = qsplice(cache.k, cache.k_scale, row_cache.k)
-        v, sv = qsplice(cache.v, cache.v_scale, row_cache.v)
+        (k, sk), (v, sv) = kv_quantize(k), kv_quantize(v)
+        k, v, sk, sv = _write_pages(
+            (cache.k, cache.v, cache.k_scale, cache.v_scale), page_list,
+            (k, v, sk, sv),
+        )
         cache = QuantKVCache(k=k, v=v, k_scale=sk, v_scale=sv,
                              row_dtype=cache.row_dtype)
-        return (_pool_constrain(pm, cache), *_replicated(pm, tok, lp))
-
-    def splice(pool, row):  # row: [L, 1, P*BLK, KVH, HD]
-        l, _, _, kvh, hd = row.shape
-        pages = row[:, 0].reshape(l, p, blk, kvh, hd).astype(pool.dtype)
-        return pool.at[:, page_list].set(pages)
-
-    cache = KVCache(
-        k=splice(cache.k, row_cache.k), v=splice(cache.v, row_cache.v)
-    )
+    else:
+        k, v = _write_pages(
+            (cache.k, cache.v), page_list,
+            (k.astype(cache.k.dtype), v.astype(cache.v.dtype)),
+        )
+        cache = KVCache(k=k, v=v)
     return (_pool_constrain(pm, cache), *_replicated(pm, tok, lp))
+
+
+def _write_pages(pools: tuple, page_list: jax.Array, pages: tuple) -> tuple:
+    """Write ``pages`` ([L, P, ...] a leaf) into the donated pool stacks
+    ([L, NB, ...]) at ``page_list`` [P], where the stacks lie: one
+    ``dynamic_update_slice`` a page, the stacks the loop's carry.  As one
+    scatter on the page axis (``pool.at[:, page_list].set``) the compiler
+    moves a pool of few KV heads (qwen2's 4) into a layout of its own,
+    scatters there and moves it back: four pool-sized copies an admission
+    (AOT compile for the v5e, PR 26).  A page listed twice (the scratch
+    page pads the list) keeps the last write."""
+
+    def write(i, pools):
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                pool, jax.lax.dynamic_slice_in_dim(new, i, 1, axis=1),
+                page_list[i], axis=1,
+            )
+            for pool, new in zip(pools, pages)
+        )
+
+    return jax.lax.fori_loop(0, page_list.shape[0], write, pools)
 
 
 @partial(

@@ -18,6 +18,24 @@ def _rand(key, shape, dtype=jnp.float32):
     return jax.random.normal(jax.random.key(key), shape, dtype)
 
 
+# How a paged case hands over its pool: None is one layer's rank-4 pages;
+# a number is that layer of a 3-layer stack [L, NB, BLK, KVH, D] whose
+# other layers hold noise, read through the kernel's ``layer`` operand.
+STACKS = [None, 0, 2]
+
+
+def _stacked(pool, layer, key=7):
+    """``pool`` as STACKS says: itself, or that layer of a noisy stack."""
+    if layer is None:
+        return pool
+    noise = _rand(key, (3, *pool.shape)) * 40.0
+    return noise.astype(pool.dtype).at[layer].set(pool)
+
+
+def _layer_kw(layer) -> dict:
+    return {} if layer is None else {"layer": layer}
+
+
 @pytest.mark.parametrize(
     "b,s,h,kvh,d,lengths",
     [
@@ -99,18 +117,23 @@ def test_block_stepping_keeps_kernel_at_384(monkeypatch):
     )
 
 
+@pytest.mark.parametrize("stack", STACKS)
 @pytest.mark.parametrize(
     "b,pool,blk,pages,h,kvh,d,lengths",
     [
         (3, 32, 64, 4, 8, 4, 128, [1, 130, 256]),   # GQA, scattered pages
         (2, 16, 128, 2, 4, 4, 128, [255, 7]),       # page == K block
         (4, 64, 8, 8, 8, 8, 128, [64, 1, 33, 17]),  # tiny 8-slot pages
+        (2, 16, 64, 2, 7, 1, 128, [100, 128]),      # ONE KV head (qwen2's
+        #   shard under mesh.model=4): the kernel takes the pool without
+        #   its head axis
     ],
 )
 def test_paged_matches_contiguous(monkeypatch, dispatched, b, pool, blk,
-                                  pages, h, kvh, d, lengths):
+                                  pages, h, kvh, d, lengths, stack):
     """Rows' KV scattered over a shuffled page pool must attend exactly like
-    the same data laid out contiguously."""
+    the same data laid out contiguously — read as one layer's pages or out
+    of a stack of layers."""
     monkeypatch.setenv("DLT_RAGGED_DECODE", "interpret")
     rng = np.random.RandomState(0)
     # Distinct physical pages per (row, logical page).
@@ -126,7 +149,9 @@ def test_paged_matches_contiguous(monkeypatch, dispatched, b, pool, blk,
         v_rows.reshape(b * pages, blk, kvh, d)
     )
     ln = jnp.asarray(lengths, jnp.int32)
-    got = decode_attn.paged_decode_attention(q, k_pool, v_pool, ln, tables)
+    got = decode_attn.paged_decode_attention(
+        q, _stacked(k_pool, stack, 7), _stacked(v_pool, stack, 8), ln,
+        tables, **_layer_kw(stack))
     assert dispatched() == {"paged_decode.interpret": 1}
     want = decode_attn._dense_reference(q, k_rows, v_rows, ln)
     np.testing.assert_allclose(
@@ -134,9 +159,11 @@ def test_paged_matches_contiguous(monkeypatch, dispatched, b, pool, blk,
     )
 
 
-def test_paged_fallback_matches_reference(monkeypatch, dispatched):
+@pytest.mark.parametrize("stack", STACKS)
+def test_paged_fallback_matches_reference(monkeypatch, dispatched, stack):
     """The dense fallback (untileable head_dim) gathers pages correctly,
-    and leaves its trace on the dispatch record."""
+    out of the layer asked for, and leaves its trace on the dispatch
+    record."""
     monkeypatch.setenv("DLT_RAGGED_DECODE", "interpret")
     b, pool, blk, pages, h, d = 2, 8, 16, 2, 4, 64  # d=64: fallback path
     tables = jnp.asarray([[3, 0], [5, 7]], jnp.int32)
@@ -150,7 +177,9 @@ def test_paged_fallback_matches_reference(monkeypatch, dispatched):
         v_rows.reshape(b * pages, blk, h, d)
     )
     ln = jnp.asarray([17, 32], jnp.int32)
-    got = decode_attn.paged_decode_attention(q, k_pool, v_pool, ln, tables)
+    got = decode_attn.paged_decode_attention(
+        q, _stacked(k_pool, stack, 7), _stacked(v_pool, stack, 8), ln,
+        tables, **_layer_kw(stack))
     assert dispatched() == {"paged_decode.fallback": 1}
     want = decode_attn._dense_reference(q, k_rows, v_rows, ln)
     np.testing.assert_allclose(
@@ -173,14 +202,15 @@ def test_untileable_head_dim_falls_back(monkeypatch, dispatched):
     )
 
 
+@pytest.mark.parametrize("stack", STACKS)
 @pytest.mark.parametrize("quant", [False, True])
 def test_sharded_kernels_match_single_shard(monkeypatch, devices8, dispatched,
-                                            quant):
+                                            quant, stack):
     """Under a tensor-parallel mesh (dispatch.sharded) the ragged and paged
     kernels run per shard inside shard_map — each shard its local KV-head
     slice, no collective — and equal the single-shard call bit for bit,
-    bf16 and int8 pages alike; the record counts the traces under
-    ``shard_map``."""
+    bf16 and int8 pages alike, the pool one layer's pages or a stack of
+    layers; the record counts the traces under ``shard_map``."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from distributed_llms_tpu.checkpoint.quantize import kv_quantize
@@ -195,8 +225,9 @@ def test_sharded_kernels_match_single_shard(monkeypatch, devices8, dispatched,
     tables = jnp.asarray([[2, 0], [1, 3]], jnp.int32)
 
     def pool(rows):  # row-major pages of a [B, S, ...] array
-        return jnp.zeros((4, blk, *rows.shape[2:]), rows.dtype).at[
+        pages = jnp.zeros((4, blk, *rows.shape[2:]), rows.dtype).at[
             tables.reshape(-1)].set(rows.reshape(b * 2, blk, *rows.shape[2:]))
+        return _stacked(pages, stack)
 
     scales, pool_scales = {}, {}
     if quant:
@@ -204,19 +235,28 @@ def test_sharded_kernels_match_single_shard(monkeypatch, devices8, dispatched,
         scales = dict(k_scale=ks, v_scale=vs)
         pool_scales = dict(k_scale=pool(ks), v_scale=pool(vs))
     want_r = decode_attn.ragged_decode_attention(q, k, v, ln, **scales)
+    layer = _layer_kw(stack)
     want_p = decode_attn.paged_decode_attention(
-        q, pool(k), pool(v), ln, tables, **pool_scales)
-    heads = NamedSharding(mesh, P(None, None, "model", None))
-    put = lambda x: jax.device_put(
-        x, heads if x.ndim == 4 else NamedSharding(mesh, P(None, None, "model")))
+        q, pool(k), pool(v), ln, tables, **pool_scales, **layer)
+
+    def put(x, heads_axis=2):  # shard the (KV-)head axis over 'model'
+        spec = [None] * x.ndim
+        spec[heads_axis] = "model"
+        return jax.device_put(x, NamedSharding(mesh, P(*spec)))
+
+    put_pool = lambda x: put(x, 2 if stack is None else 3)
+    # A jit of its own a case: the record is written while tracing, and a
+    # cache hit (the ragged call is the same under every ``stack``) traces
+    # nothing.
+    fresh = lambda fn: jax.jit(lambda *a, **kw: fn(*a, **kw))
     base = dispatched()
     with dispatch.sharded(mesh):
-        got_r = jax.jit(decode_attn.ragged_decode_attention)(
+        got_r = fresh(decode_attn.ragged_decode_attention)(
             put(q), put(k), put(v), ln,
             **{n: put(x) for n, x in scales.items()})
-        got_p = jax.jit(decode_attn.paged_decode_attention)(
-            put(q), put(pool(k)), put(pool(v)), ln, tables,
-            **{n: put(x) for n, x in pool_scales.items()})
+        got_p = fresh(decode_attn.paged_decode_attention)(
+            put(q), put_pool(pool(k)), put_pool(pool(v)), ln, tables,
+            **{n: put_pool(x) for n, x in pool_scales.items()}, **layer)
     np.testing.assert_array_equal(np.asarray(got_r), np.asarray(want_r))
     np.testing.assert_array_equal(np.asarray(got_p), np.asarray(want_p))
     new = {k_: v_ - base.get(k_, 0) for k_, v_ in dispatched().items()}

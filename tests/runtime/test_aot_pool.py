@@ -1,0 +1,63 @@
+"""What the TPU compiler makes of paged ``decode_chunk``: the pool stays
+where it lies.
+
+Compiled ahead of time for one v5e on the compile-only TPU client
+(tools/aot_decode.py; no chip), at qwen2-7b's widths with 2 layers and the
+benchmark's 512 pages.  Before PR 26 the layer scan took the pool as
+scanned inputs and gave it back as stacked outputs, and the compiled step
+sliced a layer out for the Pallas call, copied it, wrote it into a fresh
+pool-sized stack and copied that stack into the carry: four passes over
+the pool a decode step.  This is the guard against the copy coming back
+with a JAX upgrade; it skips where the installation has no such client.
+"""
+
+import dataclasses
+
+import pytest
+
+LAYERS, PAGES, BLK, KVH, HD = 2, 512, 64, 4, 128
+POOL_BYTES = 2 * LAYERS * PAGES * BLK * KVH * HD * 2  # k + v, bf16
+
+
+@pytest.fixture(scope="module")
+def compiled():
+    """The report of tools.aot_decode on ``decode_chunk``, compiled in
+    this process (the TPU's library is one process's at a time) and only
+    once a test of this file runs."""
+    from distributed_llms_tpu.models.presets import get_preset
+    from tools import aot_decode
+
+    try:
+        aot_decode.v5e_devices()
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cfg = dataclasses.replace(get_preset("qwen2-7b"), num_layers=LAYERS)
+    with pytest.MonkeyPatch.context() as mp:
+        # The default backend is the CPU: "auto" would trace the dense
+        # fallbacks, and the chip runs the kernels.
+        mp.setenv("DLT_QUANT_MATMUL", "kernel")
+        mp.setenv("DLT_RAGGED_DECODE", "kernel")
+        return aot_decode.analyse(
+            "decode_chunk", cfg, slots=16, max_len=4096, pages=PAGES,
+            page_size=BLK,
+        )
+
+
+def test_only_in_place_scatters_produce_a_pool_shaped_array(compiled):
+    """No instruction but the step's two scatters (K and V, each with the
+    fusion around it) produces a [512,64,4,128] or [2,512,64,4,128] array:
+    no slice of a layer, no copy, no ``AllocateBuffer`` of the stack's
+    shape (a second pool), no dynamic-update-slice into a fresh stack."""
+    found = compiled["pool_shaped"]
+    assert {e[0] for e in found} == {"scatter", "fusion:scatter"}, found
+    assert all(f"[{LAYERS},{PAGES},{BLK},{KVH},{HD}]" in e[2] for e in found)
+    assert len(found) == 4, found
+
+
+def test_temporaries_hold_no_second_pool(compiled):
+    """Half the pool plus what the step needs beside it (0.106 GB of
+    temporaries in all at these shapes; with the pool sliced per layer,
+    0.388 GB: AOT compiles for the v5e, PR 26)."""
+    assert compiled["temp_gb"] * 1e9 < POOL_BYTES / 2 + 120e6
+    # The donated pool is written in place: the output aliases it whole.
+    assert compiled["alias_gb"] * 1e9 >= POOL_BYTES

@@ -1,0 +1,286 @@
+#!/usr/bin/env python
+"""Compile the paged serving programs for one TPU v5e without a chip and
+say what they do to the KV page pool.
+
+``decode_chunk`` and the paged admission programs are compiled ahead of
+time on the compile-only TPU client (abstract int8 weights, the served
+shapes), and the report gives XLA's memory analysis next to every
+instruction of the optimised HLO that produces an array shaped like one
+layer of the pool or like the whole stack.  The pool must stay where it
+lies: the only instructions allowed on that list are the in-place
+scatters of a step's keys and values (tests/runtime/test_aot_pool.py
+holds the decode program to it).
+
+    python tools/aot_decode.py qwen2-7b --slots 16 --max-len 4096 --pages 512
+    python tools/aot_decode.py pythia-6.9b --slots 8 --max-len 2048 --pages 96 \
+        --programs decode_chunk,admit_row_paged --hlo-dir /tmp/hlo
+"""
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import os
+import re
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+PROGRAMS = ("decode_chunk", "admit_row_paged", "admit_row_auto_paged",
+            "finish_chunked_admission_paged")
+
+
+def v5e_devices(n: int = 1) -> list:
+    """``n`` compile-only ``TPU v5 lite`` devices (raises where libtpu
+    cannot describe the topology)."""
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2"
+    )
+    return list(topo.devices)[:n]
+
+
+def _abstract(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _abstract_on_mesh(tree, specs, mesh):
+    """``tree`` as ShapeDtypeStructs placed by ``specs`` (one
+    PartitionSpec a weight): a quantized leaf's data and scale both take
+    the weight's spec, as parallel.api.quantized_layout gives them
+    wherever the shards divide (they do at the served widths)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_llms_tpu.checkpoint.quantize import QuantizedTensor
+
+    def place(leaf, spec):
+        def sds(x):
+            full = tuple(spec) + (None,) * (x.ndim - len(tuple(spec)))
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, P(*full)))
+
+        return jax.tree.map(sds, leaf)
+
+    return jax.tree.map(
+        place, tree, specs, is_leaf=lambda x: isinstance(x, QuantizedTensor)
+    )
+
+
+def lower_program(
+    program: str, cfg, *, slots: int, max_len: int, pages: int,
+    page_size: int = 64, chunk_steps: int = 8, kv_bits: int = 16,
+    prompt_len: int = 512, mesh_model: int = 1,
+):
+    """``jax.stages.Lowered`` of one paged serving program at the given
+    shapes (weights int8, as the benchmark serves), for one v5e device or,
+    with ``mesh_model`` > 1, for that many under ``mesh.model``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_llms_tpu.models import model as model_lib
+    from distributed_llms_tpu.runtime import batcher as batcher_lib
+
+    devices = v5e_devices(mesh_model)
+    key_shape = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    pool = lambda: batcher_lib._paged_pool(  # noqa: E731
+        cfg, pages, page_size, kv_bits=kv_bits)
+    if mesh_model > 1:
+        from distributed_llms_tpu.core.config import MeshConfig
+        from distributed_llms_tpu.parallel import specs as specs_lib
+        from distributed_llms_tpu.parallel.api import make_parallel_model
+
+        pm = make_parallel_model(
+            cfg, MeshConfig(model=mesh_model), devices=devices)
+        sh = NamedSharding(pm.mesh, P())
+        params = _abstract_on_mesh(
+            jax.eval_shape(lambda k: model_lib.init_params_quantized(
+                k, cfg, 8, mesh=pm.mesh), key_shape),
+            specs_lib.param_specs(cfg, pm.mesh), pm.mesh)
+        cache = _abstract_on_mesh(
+            jax.eval_shape(pool),
+            specs_lib.page_pool_specs(cfg, pm.mesh, kv_bits=kv_bits),
+            pm.mesh)
+        on_mesh = {"pm": pm}
+    else:
+        sh = SingleDeviceSharding(devices[0])
+        params = _abstract(jax.eval_shape(
+            lambda k: model_lib.init_params_quantized(k, cfg, 8), key_shape),
+            sh)
+        cache = _abstract(jax.eval_shape(pool), sh)
+        on_mesh = {}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sh)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    p = max_len // page_size
+    if program == "decode_chunk":
+        cfg_decode = dataclasses.replace(cfg, ragged_decode=True)
+        return batcher_lib.decode_chunk.lower(
+            params, cfg_decode, cache, arr((slots,)), arr((slots,)),
+            arr((slots, 1), jnp.bool_), arr((slots,), jnp.bool_),
+            arr((slots,)), key, chunk_steps, tables=arr((slots, p)),
+            **on_mesh,
+        )
+    if program == "admit_row_paged":
+        return batcher_lib.admit_row_paged.lower(
+            params, cfg, cache, arr((p,)), arr((prompt_len,)), arr(()), key,
+            **on_mesh,
+        )
+    if program == "admit_row_auto_paged":
+        return batcher_lib.admit_row_auto_paged.lower(
+            params, cfg, cache, arr((p,)), arr((p,)), arr(()),
+            arr((prompt_len,)), arr(()), key, **on_mesh,
+        )
+    if program == "finish_chunked_admission_paged":
+        dt = batcher_lib._row_dtype_of(cache)
+        row = arr((cfg.num_layers, 1, max_len, cfg.num_kv_heads,
+                   cfg.head_dim_), dt)
+        return batcher_lib.finish_chunked_admission_paged.lower(
+            cache, arr((p,)), row, row,
+            arr((1, cfg.vocab_size), jnp.float32), key, **on_mesh,
+        )
+    raise ValueError(f"unknown program {program!r}; one of {PROGRAMS}")
+
+
+_INSTR = re.compile(
+    r"^\s*(ROOT\s+)?(%?[\w.\-]+)\s*=\s*(\([^=]*?\)|\S+)\s+([\w\-]+)\("
+)
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%?[\w.\-]+)\s+\(.*\{\s*$")
+_CALLS = re.compile(r"calls=(%?[\w.\-]+)")
+
+
+def pool_shaped(hlo_text: str, cfg, pages: int, page_size: int,
+                shards: int = 1) -> list:
+    """(opcode, name, result type) of every instruction of the optimised
+    HLO whose result holds an array shaped like one layer of the pool,
+    like the whole stack, or like either without its head-dim axis (the
+    int8 pool's scales).  A fusion is reported with the opcode of its
+    root, as ``fusion:scatter``.  Parameters, tuples and their plumbing
+    are not instructions that move data and are left out.  ``shards`` is
+    the size of ``mesh.model``: a device's program holds that share of the
+    KV heads."""
+    layer = (f"{pages},{page_size},{cfg.num_kv_heads // shards},"
+             f"{cfg.head_dim_}")
+    stack = f"{cfg.num_layers},{layer}"
+    shapes = [f"[{s}]" for s in (layer, stack)]
+    shapes += [f"[{s.rsplit(',', 1)[0]}]" for s in (layer, stack)]
+    if cfg.num_kv_heads == shards:  # one head a device: the axis may go
+        shapes += [s.replace(",1,", ",") for s in shapes[:2]]
+    skip = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+            "conditional", "call", "opt-barrier"}
+    roots, computation = {}, None
+    lines = hlo_text.splitlines()
+    for line in lines:
+        c = _COMPUTATION.match(line)
+        if c:
+            computation = c.group(1)
+        m = _INSTR.match(line)
+        if m and m.group(1):
+            roots[computation] = m.group(4)
+    found = []
+    for line in lines:
+        m = _INSTR.match(line)
+        if not m or m.group(4) in skip:
+            continue
+        if any(s in m.group(3) for s in shapes):
+            opcode = m.group(4)
+            calls = _CALLS.search(line)
+            if opcode == "fusion" and calls:
+                opcode = f"fusion:{roots.get(calls.group(1), '?')}"
+            found.append((opcode, m.group(2), m.group(3)))
+    return found
+
+
+def in_place_scatter(entry: tuple) -> bool:
+    """Whether a :func:`pool_shaped` entry is a scatter (bare or the root
+    of a fusion): the one update the pool takes where it lies."""
+    return entry[0] in ("scatter", "fusion:scatter")
+
+
+def analyse(program: str, cfg, **shape_kw) -> dict:
+    compiled = lower_program(program, cfg, **shape_kw).compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    found = pool_shaped(text, cfg, shape_kw["pages"],
+                        shape_kw.get("page_size", 64),
+                        shape_kw.get("mesh_model", 1))
+    return {
+        "program": program,
+        "argument_gb": mem.argument_size_in_bytes / 1e9,
+        "output_gb": mem.output_size_in_bytes / 1e9,
+        "alias_gb": mem.alias_size_in_bytes / 1e9,
+        "temp_gb": mem.temp_size_in_bytes / 1e9,
+        "pool_shaped": found,
+        "hlo": text,
+    }
+
+
+def main() -> int:
+    from distributed_llms_tpu.models.presets import get_preset
+
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("preset")
+    ap.add_argument("--layers", type=int, default=None)
+    ap.add_argument("--slots", type=int, default=16)
+    ap.add_argument("--max-len", type=int, default=4096)
+    ap.add_argument("--pages", type=int, default=512)
+    ap.add_argument("--page-size", type=int, default=64)
+    ap.add_argument("--chunk-steps", type=int, default=8)
+    ap.add_argument("--kv-bits", type=int, default=16)
+    ap.add_argument("--prompt-len", type=int, default=512)
+    ap.add_argument("--mesh-model", type=int, default=1,
+                    help="compile for this many chips under mesh.model")
+    ap.add_argument("--programs", default=",".join(PROGRAMS))
+    ap.add_argument("--hlo-dir", default=None,
+                    help="write each program's optimised HLO here")
+    a = ap.parse_args()
+    try:
+        v5e_devices()
+    except Exception as e:  # no compile-only TPU client in this installation
+        print(json.dumps({"no_client": str(e).splitlines()[0][:400]}))
+        return 3
+    cfg = get_preset(a.preset)
+    if a.layers:
+        cfg = dataclasses.replace(cfg, num_layers=a.layers)
+    for program in a.programs.split(","):
+        try:
+            r = analyse(
+                program, cfg, slots=a.slots, max_len=a.max_len,
+                pages=a.pages, page_size=a.page_size,
+                chunk_steps=a.chunk_steps, kv_bits=a.kv_bits,
+                prompt_len=a.prompt_len, mesh_model=a.mesh_model,
+            )
+        except Exception as e:  # the compiler's refusal IS the finding
+            print(json.dumps({"program": program,
+                              "error": str(e).splitlines()[0][:400]}))
+            continue
+        hlo = r.pop("hlo")
+        if a.hlo_dir:
+            os.makedirs(a.hlo_dir, exist_ok=True)
+            name = f"{a.preset}.{program}.p{a.pages}.kv{a.kv_bits}.hlo"
+            with open(os.path.join(a.hlo_dir, name), "w") as f:
+                f.write(hlo)
+        by_op: dict = {}
+        for e in r["pool_shaped"]:
+            by_op[e[0]] = by_op.get(e[0], 0) + 1
+        r["pool_shaped_by_opcode"] = by_op
+        r["pool_shaped"] = [list(e) for e in r["pool_shaped"]]
+        print(json.dumps(r))
+    return 0
+
+
+if __name__ == "__main__":
+    jax.config.update("jax_platforms", "cpu")
+    # The default backend is the CPU here, so "auto" would trace the dense
+    # fallbacks; the compiled kernels are what the chip runs.
+    os.environ.setdefault("DLT_QUANT_MATMUL", "kernel")
+    os.environ.setdefault("DLT_RAGGED_DECODE", "kernel")
+    sys.exit(main())

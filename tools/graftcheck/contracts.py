@@ -276,6 +276,12 @@ def _ragged_cases() -> list[OpCase]:
     return cases
 
 
+# Layers of the stacked pool [L, NB, BLK, KVH, D] the paged contracts hand
+# the kernel, with the layer to read as a traced scalar — the operands the
+# layer scan passes (models.model._paged_attention).
+_POOL_LAYERS = 3
+
+
 def _paged_cases() -> list[OpCase]:
     from distributed_llms_tpu.ops import decode_attn
 
@@ -286,14 +292,28 @@ def _paged_cases() -> list[OpCase]:
         (2, 32, 16, 8, 8, 2, 128),
     ]:
         dt = jnp.bfloat16
+        pool = (_POOL_LAYERS, nb, blk, kvh, d)
         cases.append(OpCase(
             label=f"b{b} nb{nb} blk{blk} p{p} h{h}/{kvh} d{d}",
-            fn=decode_attn.paged_decode_attention,
-            args=(sds((b, 1, h, d), dt), sds((nb, blk, kvh, d), dt),
-                  sds((nb, blk, kvh, d), dt), sds((b,), jnp.int32),
-                  sds((b, p), jnp.int32)),
+            fn=lambda q, k, v, ln, tb, layer:
+                decode_attn.paged_decode_attention(
+                    q, k, v, ln, tb, layer=layer),
+            args=(sds((b, 1, h, d), dt), sds(pool, dt), sds(pool, dt),
+                  sds((b,), jnp.int32), sds((b, p), jnp.int32),
+                  sds((), jnp.int32)),
             want=(((b, 1, h, d), "bfloat16"),),
         ))
+    # One layer's rank-4 pages: the stack of one layer, layer 0.
+    b, nb, blk, p, h, kvh, d = 2, 32, 16, 8, 8, 2, 128
+    cases.append(OpCase(
+        label=f"rank-4 pool b{b} nb{nb} blk{blk} p{p} h{h}/{kvh} d{d}",
+        fn=decode_attn.paged_decode_attention,
+        args=(sds((b, 1, h, d), jnp.bfloat16),
+              sds((nb, blk, kvh, d), jnp.bfloat16),
+              sds((nb, blk, kvh, d), jnp.bfloat16), sds((b,), jnp.int32),
+              sds((b, p), jnp.int32)),
+        want=(((b, 1, h, d), "bfloat16"),),
+    ))
     return cases
 
 
@@ -327,15 +347,16 @@ def _decode_int8_cases() -> list[OpCase]:
         (3, 8, 64, 2, 4, 4, 64),     # untileable d -> gather fallback
         (2, 32, 16, 8, 8, 2, 128),
     ]:
+        pool = (_POOL_LAYERS, nb, blk, kvh, d)
         cases.append(OpCase(
             label=f"paged b{b} nb{nb} blk{blk} p{p} h{h}/{kvh} d{d}",
-            fn=lambda q, k, v, ln, tb, ks, vs:
+            fn=lambda q, k, v, ln, tb, ks, vs, layer:
                 decode_attn.paged_decode_attention(
-                    q, k, v, ln, tb, k_scale=ks, v_scale=vs),
-            args=(sds((b, 1, h, d), dt), sds((nb, blk, kvh, d), jnp.int8),
-                  sds((nb, blk, kvh, d), jnp.int8), sds((b,), jnp.int32),
-                  sds((b, p), jnp.int32), sds((nb, blk, kvh), jnp.float32),
-                  sds((nb, blk, kvh), jnp.float32)),
+                    q, k, v, ln, tb, k_scale=ks, v_scale=vs, layer=layer),
+            args=(sds((b, 1, h, d), dt), sds(pool, jnp.int8),
+                  sds(pool, jnp.int8), sds((b,), jnp.int32),
+                  sds((b, p), jnp.int32), sds(pool[:-1], jnp.float32),
+                  sds(pool[:-1], jnp.float32), sds((), jnp.int32)),
             want=(((b, 1, h, d), "bfloat16"),),
         ))
     return cases
@@ -381,23 +402,26 @@ def _decode_spmd_cases() -> list[OpCase]:
     for tp, b, nb, blk, p, h, kvh, d in [(2, 2, 16, 8, 4, 8, 4, 128),
                                          (4, 1, 32, 16, 8, 8, 4, 128)]:
         hl, kl = h // tp, kvh // tp
+        pool = (_POOL_LAYERS, nb, blk, kl, d)
         cases.append(OpCase(
             label=f"paged tp{tp} shard b{b} nb{nb} blk{blk} h{hl}/{kl}",
-            fn=decode_attn.paged_decode_attention,
-            args=(sds((b, 1, hl, d), dt), sds((nb, blk, kl, d), dt),
-                  sds((nb, blk, kl, d), dt), sds((b,), jnp.int32),
-                  sds((b, p), jnp.int32)),
+            fn=lambda q, k, v, ln, tb, layer:
+                decode_attn.paged_decode_attention(
+                    q, k, v, ln, tb, layer=layer),
+            args=(sds((b, 1, hl, d), dt), sds(pool, dt), sds(pool, dt),
+                  sds((b,), jnp.int32), sds((b, p), jnp.int32),
+                  sds((), jnp.int32)),
             want=(((b, 1, hl, d), "bfloat16"),),
         ))
         cases.append(OpCase(
             label=f"paged-int8 tp{tp} shard b{b} nb{nb} blk{blk} h{hl}/{kl}",
-            fn=lambda q, k, v, ln, tb, ks, vs:
+            fn=lambda q, k, v, ln, tb, ks, vs, layer:
                 decode_attn.paged_decode_attention(
-                    q, k, v, ln, tb, k_scale=ks, v_scale=vs),
-            args=(sds((b, 1, hl, d), dt), sds((nb, blk, kl, d), jnp.int8),
-                  sds((nb, blk, kl, d), jnp.int8), sds((b,), jnp.int32),
-                  sds((b, p), jnp.int32), sds((nb, blk, kl), jnp.float32),
-                  sds((nb, blk, kl), jnp.float32)),
+                    q, k, v, ln, tb, k_scale=ks, v_scale=vs, layer=layer),
+            args=(sds((b, 1, hl, d), dt), sds(pool, jnp.int8),
+                  sds(pool, jnp.int8), sds((b,), jnp.int32),
+                  sds((b, p), jnp.int32), sds(pool[:-1], jnp.float32),
+                  sds(pool[:-1], jnp.float32), sds((), jnp.int32)),
             want=(((b, 1, hl, d), "bfloat16"),),
         ))
     return cases
@@ -894,7 +918,8 @@ def _decode_spmd_audits() -> list[SpecAudit]:
                     from distributed_llms_tpu.ops import decode_attn
 
                     mesh = fake_mesh(**axes)
-                    kv_shape = (nb, blk, kvh, d) if paged else (b, s, kvh, d)
+                    kv_shape = ((_POOL_LAYERS, nb, blk, kvh, d) if paged
+                                else (b, s, kvh, d))
                     kv_dt = jnp.int8 if quant else jnp.bfloat16
                     tree = {"q": sds((b, 1, h, d), jnp.bfloat16),
                             "lengths": sds((b,), jnp.int32)}
@@ -902,6 +927,7 @@ def _decode_spmd_audits() -> list[SpecAudit]:
                         tree["k_pages"] = sds(kv_shape, kv_dt)
                         tree["v_pages"] = sds(kv_shape, kv_dt)
                         tree["tables"] = sds((b, p), jnp.int32)
+                        tree["layer"] = sds((1,), jnp.int32)
                     else:
                         tree["k"] = sds(kv_shape, kv_dt)
                         tree["v"] = sds(kv_shape, kv_dt)

@@ -45,6 +45,27 @@ from distributed_llms_tpu.ops.quant_matmul import quant_contract
 # at head dim 128, --page-size 64), beside the 128-slot pages of the
 # original legs.
 SERVED = dict(blk=64, h=28, kvh=4)
+# pythia-6.9b's: 32 heads with no grouping, a page block eight times wider.
+SERVED_MHA = dict(blk=64, h=32, kvh=32)
+# One device's share of qwen2-7b under mesh.model=4: a single KV head,
+# which the paged kernel reads out of a pool without its head axis.
+SERVED_TP4 = dict(blk=64, h=7, kvh=1)
+
+
+def _stacked(pool, layer, fill):
+    """``pool`` as layer ``layer`` of a 3-layer stack [L, NB, BLK, ...] —
+    what the serving programs hand the paged kernel — the other layers
+    holding ``fill``; ``pool`` itself for the rank-4 form (``layer``
+    None)."""
+    if layer is None:
+        return pool
+    return jnp.full((3, *pool.shape), fill, pool.dtype).at[layer].set(pool)
+
+
+def _layer_kw(layer) -> dict:
+    """The keyword that reads layer ``layer`` of a stack (none for the
+    rank-4 form)."""
+    return {} if layer is None else {"layer": layer}
 
 
 def check(name: str, got, want, rtol: float, atol: float) -> None:
@@ -100,7 +121,8 @@ def flash_parity() -> None:
     check(f"flash windowed T{t} win{win}", got, want, rtol=3e-2, atol=3e-2)
 
 
-def paged_parity(blk: int = 128, h: int = 8, kvh: int = 4) -> None:
+def paged_parity(blk: int = 128, h: int = 8, kvh: int = 4,
+                 layer: int | None = None) -> None:
     key = jax.random.PRNGKey(3)
     b, pool, pages, d = 4, 48, 8, 128
     rng = np.random.RandomState(0)
@@ -119,11 +141,13 @@ def paged_parity(blk: int = 128, h: int = 8, kvh: int = 4) -> None:
     ].set(v_rows.reshape(b * pages, blk, kvh, d))
     ln = jnp.asarray([1, 2 * blk + 44, pages * blk, blk + 1], jnp.int32)
     got = jax.jit(decode_attn.paged_decode_attention)(
-        q, k_pool, v_pool, ln, tables
+        q, _stacked(k_pool, layer, 3.0), _stacked(v_pool, layer, -3.0), ln,
+        tables, **_layer_kw(layer)
     )
     want = decode_attn._dense_reference(q, k_rows, v_rows, ln)
-    check(f"paged decode B{b} pool{pool} blk{blk} H{h}/{kvh}", got, want,
-          rtol=3e-2, atol=3e-2)
+    form = "" if layer is None else f" L3[{layer}]"
+    check(f"paged decode B{b} pool{pool} blk{blk} H{h}/{kvh}{form}", got,
+          want, rtol=3e-2, atol=3e-2)
 
 
 def ragged_parity() -> None:
@@ -189,7 +213,8 @@ def ragged_int8_parity() -> None:
           _int8_reference(q, kq, ksc, vq, vsc, ln), rtol=3e-2, atol=3e-2)
 
 
-def paged_int8_parity(blk: int = 128, h: int = 8, kvh: int = 4) -> None:
+def paged_int8_parity(blk: int = 128, h: int = 8, kvh: int = 4,
+                      layer: int | None = None) -> None:
     b, pool, pages, d = 4, 48, 4, 128
     q, kq, ksc, vq, vsc = _int8_inputs(b, pages * blk, h, kvh)
     rng = np.random.RandomState(1)
@@ -197,19 +222,22 @@ def paged_int8_parity(blk: int = 128, h: int = 8, kvh: int = 4) -> None:
         rng.permutation(pool)[: b * pages].reshape(b, pages), jnp.int32
     )
 
-    def to_pool(rows, fill, dtype):
+    def to_pool(rows, fill, dtype, noise):
         tail = rows.shape[2:]
-        return jnp.full((pool, blk, *tail), fill, dtype).at[
+        pages_ = jnp.full((pool, blk, *tail), fill, dtype).at[
             tables.reshape(-1)
         ].set(rows.reshape(b * pages, blk, *tail))
+        return _stacked(pages_, layer, noise)
 
     ln = jnp.asarray([1, 2 * blk + 44, pages * blk, blk + 1], jnp.int32)
     got = jax.jit(decode_attn.paged_decode_attention)(
-        q, to_pool(kq, 0, jnp.int8), to_pool(vq, 0, jnp.int8), ln, tables,
-        k_scale=to_pool(ksc, 1, jnp.float32),
-        v_scale=to_pool(vsc, 1, jnp.float32),
+        q, to_pool(kq, 0, jnp.int8, 77), to_pool(vq, 0, jnp.int8, -77), ln,
+        tables, k_scale=to_pool(ksc, 1, jnp.float32, 9.0),
+        v_scale=to_pool(vsc, 1, jnp.float32, 9.0),
+        **_layer_kw(layer),
     )
-    check(f"paged int8 B{b} pool{pool} blk{blk} H{h}/{kvh}", got,
+    form = "" if layer is None else f" L3[{layer}]"
+    check(f"paged int8 B{b} pool{pool} blk{blk} H{h}/{kvh}{form}", got,
           _int8_reference(q, kq, ksc, vq, vsc, ln), rtol=3e-2, atol=3e-2)
 
 
@@ -227,6 +255,14 @@ def main() -> int:
     paged_int8_parity()
     paged_parity(**SERVED)
     paged_int8_parity(**SERVED)
+    # The stacked form the serving programs use: the pool is every layer's
+    # pages and the kernel reads layer 2 (GQA) or layer 0 (no grouping).
+    paged_parity(**SERVED, layer=2)
+    paged_int8_parity(**SERVED, layer=2)
+    paged_parity(**SERVED_MHA, layer=0)
+    paged_int8_parity(**SERVED_MHA, layer=0)
+    paged_parity(**SERVED_TP4, layer=1)
+    paged_int8_parity(**SERVED_TP4, layer=1)
     # No leg may pass on another path than the one asked for: the dispatch
     # record (ops/dispatch.py) counts every trace by the path it took.
     took = {k[len("ops.dispatch."):]: int(v)
@@ -237,9 +273,10 @@ def main() -> int:
     if stray:
         raise AssertionError(f"legs dispatched off the {MODE} path: {stray}")
     mode = "compiled" if ON_TPU else "interpret"
-    # v4: the paged legs (bf16 and int8) also run at the page size and GQA
-    # geometry chip_smoke.py serves — 15 legs.
-    print(f"kernel_parity: ALL PASS v4 ({mode}, backend={backend})")
+    # v5: the paged legs (bf16 and int8) also run at the page size and
+    # head geometries the benchmark serves, as one layer's pages and as a
+    # layer of the stacked pool — 21 legs.
+    print(f"kernel_parity: ALL PASS v5 ({mode}, backend={backend})")
     return 0
 
 
