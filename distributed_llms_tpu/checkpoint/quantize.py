@@ -50,6 +50,10 @@ class QuantizedTensor:
     bits: int
     orig_shape: tuple[int, ...]
     pack_axis: int = -2
+    # The axis the absmax blocks run along: -1 (the last one) for every 2-D
+    # weight; -2 for the expert stacks [E, K, N], whose scales [E, K/128, N]
+    # are then lane-dense for ops/moe_experts.py.  int8 only.
+    block_axis: int = -1
 
     @property
     def unpacked_shape(self) -> tuple[int, ...]:
@@ -65,15 +69,25 @@ class QuantizedTensor:
 jax.tree_util.register_dataclass(
     QuantizedTensor,
     data_fields=["data", "scale"],
-    meta_fields=["bits", "orig_shape", "pack_axis"],
+    meta_fields=["bits", "orig_shape", "pack_axis", "block_axis"],
 )
 
 
 def quantize(
-    x: jax.Array, bits: int = 8, block: int = 128, pack_axis: int = -2
+    x: jax.Array, bits: int = 8, block: int = 128, pack_axis: int = -2,
+    block_axis: int = -1,
 ) -> QuantizedTensor:
     if bits not in (8, 4):
         raise ValueError(f"bits must be 8 or 4, got {bits}")
+    if block_axis == -2:
+        if bits != 8:
+            raise ValueError("blocks along the contracted axis are int8 only")
+        qt = quantize(jnp.swapaxes(x, -1, -2), bits, block)
+        return QuantizedTensor(
+            data=jnp.swapaxes(qt.data, -1, -2),
+            scale=jnp.swapaxes(qt.scale, -1, -2), bits=bits,
+            orig_shape=tuple(x.shape), pack_axis=pack_axis, block_axis=-2,
+        )
     orig_shape = tuple(x.shape)
     block = min(block, x.shape[-1])
     if x.shape[-1] % block:
@@ -115,6 +129,13 @@ def dequantize(qt: QuantizedTensor, dtype: Any = jnp.float32) -> jax.Array:
     stacked [L, ...] QuantizedTensor (what lax.scan hands the decoder-block
     body when serving quantized weights) carries stale orig_shape metadata
     but self-consistent data/scale."""
+    if qt.block_axis == -2:
+        t = QuantizedTensor(
+            data=jnp.swapaxes(qt.data, -1, -2),
+            scale=jnp.swapaxes(qt.scale, -1, -2), bits=qt.bits,
+            orig_shape=qt.orig_shape, pack_axis=qt.pack_axis,
+        )
+        return jnp.swapaxes(dequantize(t, dtype), -1, -2)
     q = qt.data
     if qt.bits == 4:
         a = q.ndim + qt.pack_axis
@@ -142,10 +163,26 @@ _PACK_AXIS_BY_NAME = {"wq": -3, "wk": -3, "wv": -3}
 _BIAS_NAMES = frozenset({"bq", "bk", "bv", "bo", "b_in", "b_out", "b_gate", "b_up", "b_down"})
 
 
+# Hybrid-family leaves (blocks/conv/..., blocks/moe/...) that stay float
+# although they have two axes or more: the convolution's taps [L, D, K], and
+# the router [L, D, E] and the selection bias [L, E], which decide the
+# chosen set in float32.
+_HYBRID_FLOAT = frozenset({"taps", "router", "expert_bias"})
+
+
+def block_axis_of(path: str) -> int:
+    """The axis a named leaf's absmax blocks run along: the contracted one
+    for the expert stacks [E, K, N] under ``.../experts/`` (consumed by
+    ops/moe_experts.py), the last one for every other weight."""
+    return -2 if "experts" in path.split("/") else -1
+
+
 def _should_quantize(path: str, x: Any) -> bool:
     if not hasattr(x, "ndim") or x.ndim < 2:
         return False
     leaf = path.split("/")[-1]
+    if path.startswith(("blocks/conv/", "blocks/moe/")) and leaf in _HYBRID_FLOAT:
+        return False
     if "norm" in path or "ln" in path.split("/")[-2:][0]:
         return False
     if leaf in _BIAS_NAMES:
@@ -170,7 +207,8 @@ def quantize_tree(params: Any, bits: int = 8, block: int = 128) -> Any:
         key = "/".join(str(getattr(p, "key", p)) for p in path)
         should, pack_axis = leaf_plan(key, x)
         if should:
-            return quantize(x, bits=bits, block=block, pack_axis=pack_axis)
+            return quantize(x, bits=bits, block=block, pack_axis=pack_axis,
+                            block_axis=block_axis_of(key))
         return x
 
     return jax.tree_util.tree_map_with_path(visit, params)

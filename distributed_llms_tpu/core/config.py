@@ -38,7 +38,7 @@ class ModelConfig:
     (run_master.py:17, facebook/opt-125m).
     """
 
-    family: str = "gpt2"  # "gpt2" | "opt" | "llama" | "neox"
+    family: str = "gpt2"  # "gpt2" | "opt" | "llama" | "neox" | "hybrid"
     vocab_size: int = 50257
     hidden_size: int = 768
     intermediate_size: int = 3072
@@ -109,6 +109,8 @@ class ModelConfig:
     sliding_window: int | None = None
 
     def __post_init__(self):
+        # A list from a JSON file: the config is a static jit argument.
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
         if self.attn_impl not in _ATTN_IMPLS:
             raise ValueError(
                 f"unknown attn_impl {self.attn_impl!r}; choose from {sorted(_ATTN_IMPLS)}"
@@ -117,6 +119,28 @@ class ModelConfig:
             raise ValueError(
                 f"unknown gate_act {self.gate_act!r}; choose silu or gelu_tanh"
             )
+        if self.moe_score_fn not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"unknown moe_score_fn {self.moe_score_fn!r}; choose softmax "
+                "or sigmoid"
+            )
+        if (self.family == "hybrid") != bool(self.layer_types):
+            raise ValueError(
+                "layer_types is the hybrid family's layer pattern: give both "
+                "or neither"
+            )
+        if self.family == "hybrid" and self.num_experts and self.moe_capacity:
+            raise ValueError(
+                "the hybrid family routes without a capacity rule "
+                "(layers.moe_dropless): set moe_capacity=False"
+            )
+        if self.layer_types:
+            bad = set(self.layer_types) - {"conv", "attn"}
+            if bad or len(self.layer_types) != self.num_layers:
+                raise ValueError(
+                    f"layer_types must name {self.num_layers} layers as "
+                    f"'conv' or 'attn', got {self.layer_types!r}"
+                )
         if self.gate_act != "silu" and self.num_experts > 0:
             # moe_swiglu hardcodes silu (Mixtral); accepting another
             # activation here would silently ignore it.
@@ -158,6 +182,41 @@ class ModelConfig:
     moe_capacity_factor: float = 1.25
     # Weight of the Switch-style load-balance aux loss added by lm_loss.
     moe_aux_loss_weight: float = 0.02
+    # Routing rule (layers.route_experts).  "softmax": top-k of the router
+    # logits, gates = softmax over the k chosen (Mixtral).  "sigmoid": scores
+    # = sigmoid(logits) in float32; the chosen set is top-k of score + a
+    # per-expert selection bias (``moe_expert_bias``: the bias picks, it
+    # does not weigh), weights = the chosen scores, divided by their sum +
+    # 1e-6 when ``moe_norm_topk``, times ``moe_routed_scale`` (LFM2-MoE).
+    # (``moe_norm_topk``, ``moe_routed_scale`` and ``conv_kernel`` below
+    # mirror published keys and have ONE value in use, LFM2's: constants
+    # until a second model needs another.)
+    moe_score_fn: str = "softmax"
+    moe_expert_bias: bool = False
+    moe_norm_topk: bool = True
+    moe_routed_scale: float = 1.0
+    # Which expert layer a block runs.  True: layers.moe_swiglu, the GShard
+    # capacity buffers above, which drop what overflows and give the
+    # load-balance loss (mixtral-8x7b / moe-tiny as they are trained).
+    # False: layers.moe_dropless, every token gets its k experts whatever
+    # the other tokens chose (pairs grouped by expert, one grouped matmul:
+    # ops/moe_experts.py), so a row's logits never depend on its
+    # batch-mates: what a SERVED model needs, and the only rule the hybrid
+    # family has.
+    moe_capacity: bool = True
+    # Expert FFN width where it differs from the dense one (None: the same).
+    moe_intermediate_size: int | None = None
+    # Leading layers whose FFN is dense although num_experts > 0.
+    num_dense_layers: int = 0
+    # Per-layer operator, for a model whose layers differ ("hybrid" family):
+    # "conv" (gated short convolution, layers.short_conv) or "attn".  Empty
+    # for the families whose layers are all alike.
+    layer_types: tuple[str, ...] = ()
+    # Taps of the depthwise causal convolution of a "conv" layer; its whole
+    # memory is the last ``conv_kernel - 1`` gated inputs a row.
+    conv_kernel: int = 3
+    # RMS-normalise q and k per head (learned [head_dim] scales) before RoPE.
+    qk_norm: bool = False
 
     @property
     def head_dim_(self) -> int:
@@ -166,6 +225,24 @@ class ModelConfig:
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
+
+    @property
+    def expert_size(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def attn_layers(self) -> tuple[int, ...]:
+        """Indices of the layers that hold keys and values: every layer,
+        but for a hybrid model only its "attn" ones (the KV cache's and the
+        page pool's layer axis counts these)."""
+        if not self.layer_types:
+            return tuple(range(self.num_layers))
+        return tuple(i for i, t in enumerate(self.layer_types) if t == "attn")
+
+    @property
+    def conv_layers(self) -> tuple[int, ...]:
+        """Indices of the layers that keep convolution state a row."""
+        return tuple(i for i, t in enumerate(self.layer_types) if t == "conv")
 
 
 @dataclass(frozen=True)

@@ -229,7 +229,11 @@ METRIC_DOCS: dict[str, str] = {
     "batcher.prefix_cache.lookups": "automatic prefix-cache lookups",
     "batcher.prefix_cache.hits": "lookups that matched >= 1 cached page",
     "batcher.prefix_cache.hit_tokens": "prompt tokens served from cache",
-    "batcher.prefix_cache.miss_tokens": "prompt tokens prefilled fresh",
+    "batcher.prefix_cache.miss_tokens": "prompt tokens prefilled fresh, by "
+                                        "every admission: a lookup's misses, "
+                                        "and the whole prompt where no "
+                                        "lookup is made (no cache, opt-out, "
+                                        "named prefix); no bucket padding",
     "batcher.prefix_cache.hit_rate": "cumulative hit_tokens fraction (gauge)",
     "batcher.prefix_cache.evicted_pages": "cached pages evicted under pressure",
     "batcher.pool.*": "KV page-pool occupancy gauges (free/cached/held/"
@@ -494,9 +498,26 @@ METRIC_DOCS: dict[str, str] = {
                                "(graceful-only)",
     "autoscale.*.scale_failures": "tier scale actions that failed or "
                                   "were vetoed — the tier kept its size",
+    "batcher.conv_state_bytes": "bytes of the state a hybrid model keeps "
+                                "beside its pages: each convolution "
+                                "layer's last gated inputs, one entry a "
+                                "batch slot (gauge)",
+    # -- expert layers (models/layers.py moe_dropless; real tokens only,
+    #    carried out of each admission and decode chunk, added at delivery) --
+    "moe.routed_pairs": "(token, expert) pairs routed, summed over the "
+                        "expert layers: tokens x experts a token x layers",
+    "moe.layer_passes": "expert-layer passes that had a real token (22 a "
+                        "forward pass in LFM2-8B-A1B)",
+    "moe.experts_touched": "experts with at least one real token, summed "
+                           "over layer passes: over experts x layer_passes "
+                           "it is the share of expert weights a pass reads",
+    "moe.max_load_tokens": "the fullest expert's tokens, summed over layer "
+                           "passes: x experts over routed_pairs is the "
+                           "load imbalance (1.0 is even)",
     # -- kernel dispatch (ops/dispatch.py) --
     "ops.dispatch.*.*": "trace-time dispatches of a Pallas op (quant_matmul, "
-                        "paged_decode, ragged_decode, flash) by the path "
+                        "paged_decode, ragged_decode, flash, moe_experts) "
+                        "by the path "
                         "taken: kernel (compiled), interpret (Pallas "
                         "interpreter) or fallback (dense jax.numpy)",
     "ops.dispatch.*.shard_map": "of those, dispatches traced inside the "

@@ -199,6 +199,15 @@ def qkv_project(x: jax.Array, p: Params, cfg: ModelConfig) -> tuple[jax.Array, j
     Weight layout: wq [D, H, hd], wk/wv [D, KVH, hd] — head axis explicit so
     tensor-parallel sharding annotates the head dim directly.
     """
+    if getattr(p["wq"], "data", p["wq"]).ndim == 2:
+        # [D, H * hd], the head axes stored flat (the hybrid family).
+        q, k, v = (
+            _contract(x, p[w], "btd,dn->btn", 1, "n").reshape(
+                *x.shape[:2], n, cfg.head_dim_)
+            for w, n in (("wq", cfg.num_heads), ("wk", cfg.num_kv_heads),
+                         ("wv", cfg.num_kv_heads))
+        )
+        return q, k, v
     q = _contract(x, p["wq"], "btd,dhk->bthk", 1, "n")
     k = _contract(x, p["wk"], "btd,dhk->bthk", 1, "n")
     v = _contract(x, p["wv"], "btd,dhk->bthk", 1, "n")
@@ -248,6 +257,143 @@ def mlp_swiglu(x: jax.Array, p: Params, gate_act: str = "silu") -> jax.Array:
     return _contract(h, p["w_down"], "btf,fd->btd", 1, "k")
 
 
+def route_experts(
+    logits: jax.Array, cfg: ModelConfig, bias: jax.Array | None = None
+) -> tuple[jax.Array, jax.Array]:
+    """Router logits [S, E] float32 -> (weights [S, k] float32, expert ids
+    [S, k]).  ``cfg.moe_score_fn``: "softmax" takes the top k logits and
+    softmaxes over them (Mixtral); "sigmoid" scores every expert by
+    ``sigmoid(logit)``, picks the top k of score + ``bias`` (the selection
+    bias picks, it does not weigh), weighs by the chosen scores over their
+    sum + 1e-6 (``moe_norm_topk``) times ``moe_routed_scale`` (LFM2-MoE)."""
+    k = cfg.num_experts_per_token
+    if cfg.moe_score_fn == "softmax":
+        topv, topi = jax.lax.top_k(logits, k)
+        return jax.nn.softmax(topv, axis=-1), topi
+    scores = jax.nn.sigmoid(logits)
+    _, topi = jax.lax.top_k(scores if bias is None else scores + bias, k)
+    w = jnp.take_along_axis(scores, topi, axis=-1)
+    if cfg.moe_norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    return w * cfg.moe_routed_scale, topi
+
+
+def moe_dropless(
+    x: jax.Array, p: Params, cfg: ModelConfig,
+    token_mask: jax.Array | None = None, layer: jax.Array | int = 0,
+) -> tuple[jax.Array, jax.Array]:
+    """Expert FFN without a capacity rule (``cfg.moe_capacity`` False; the
+    llama family comes here through :func:`moe_dropless_layer`): every
+    token gets its k experts whatever the other tokens chose, so a row's
+    result never depends on its batch-mates.  The pairs are grouped
+    by expert and run through ops/moe_experts.py, which reads int8 expert
+    stacks a tile at a time and only the experts some token chose.
+
+    p holds EVERY expert layer's leaves, stacked, and ``layer`` names the
+    one to run (traced inside a layer scan): router [L, D, E] float32,
+    expert_bias [L, E] (with cfg.moe_expert_bias), experts/w_gate_up
+    [L, E, D, 2F], experts/w_down [L, E, F, D].  The router's slice is
+    cut out here; the expert stacks go to the kernel whole.
+    ``token_mask`` [B, T] marks the real tokens (padding of an admission
+    bucket and rows that are not decoding are routed too, which is cheaper
+    than masking them, but are not counted).  Returns (y, stats): stats
+    int32 [4] = routed pairs, layer passes (1 if any token is real),
+    experts with at least one real token, the fullest expert's real tokens
+    — the sources of ``moe.*`` counters (runtime/batcher.py)."""
+    from ..ops import moe_experts
+
+    b, t, d = x.shape
+    xf = x.reshape(b * t, d)
+    with jax.named_scope("moe_route"):
+        logits = jnp.einsum(
+            "sd,de->se", xf.astype(jnp.float32),
+            p["router"][layer].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        bias = p["expert_bias"][layer] if "expert_bias" in p else None
+        w, topi = route_experts(logits, cfg, bias)
+        real = (jnp.ones((b * t,), bool) if token_mask is None
+                else token_mask.reshape(b * t))
+        load = jnp.sum(  # real tokens an expert: [E]
+            jax.nn.one_hot(topi, cfg.num_experts, dtype=jnp.int32)
+            * real[:, None, None], axis=(0, 1))
+        stats = jnp.stack([
+            jnp.sum(load), jnp.any(real).astype(jnp.int32),
+            jnp.sum(load > 0, dtype=jnp.int32), jnp.max(load),
+        ])
+    with jax.named_scope("moe_experts"):
+        ex = p["experts"]
+        y = moe_experts.grouped_swiglu(
+            xf, topi, ex["w_gate_up"], ex["w_down"], layer)
+        y = jnp.sum(y.astype(jnp.float32) * w[:, :, None], axis=1)
+    return y.reshape(b, t, d).astype(x.dtype), stats
+
+
+@jax.named_scope("mlp")  # profiler scope; HLO metadata only
+def moe_dropless_layer(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
+    """:func:`moe_dropless` for ONE layer's leaves as the llama family
+    keeps them (router [D, E], w_gate / w_up [E, D, F], w_down [E, F, D]:
+    what :func:`moe_swiglu` takes), for a config whose ``moe_capacity`` is
+    False.  Gate and up are laid side by side here, a copy a call, and
+    quantized leaves (blocks along their last axis) are dequantized first
+    as moe_swiglu does: the float leg of ops/moe_experts.py runs them.  A
+    model that serves int8 experts at speed stacks them as the hybrid
+    family does."""
+    if any(_is_quantized(w) for w in p.values()):
+        from ..checkpoint.quantize import dequantize_tree
+
+        p = dequantize_tree(p, x.dtype)
+    stacked = {
+        "router": p["router"][None],
+        "experts": {
+            "w_gate_up": jnp.concatenate(
+                [p["w_gate"], p["w_up"]], axis=-1)[None],
+            "w_down": p["w_down"][None],
+        },
+    }
+    if "expert_bias" in p:
+        stacked["expert_bias"] = p["expert_bias"][None]
+    return moe_dropless(x, stacked, cfg)[0]
+
+
+@jax.named_scope("conv")  # profiler scope; HLO metadata only
+def short_conv(
+    x: jax.Array, p: Params, state: jax.Array | None = None,
+    seq_lens: jax.Array | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """Gated short convolution (LFM2): ``[B, C, z] = split3(x W_in)``,
+    ``g = B * z``, a depthwise causal convolution of K taps a channel over
+    g, ``(C * conv) W_out``.  x: [Bt, T, D]; p: in_proj [D, 3D], taps
+    [D, K], out_proj [D, D].
+
+    The layer's whole memory is the last K-1 gated inputs a row:
+    ``state`` [Bt, K-1, D] (None: zeros, a sequence's start) goes in front
+    of g, and the state handed back is the K-1 rows that end at the row's
+    ``seq_lens`` REAL new tokens (None: all T).  So a right-padded prompt
+    leaves the state of its true length, not of the bucket's end, a row
+    with no real token this step (seq_lens 0) keeps its state, and a
+    prompt shorter than K-1 keeps zeros in front."""
+    bcz = _contract(x, p["in_proj"], "btd,df->btf", 1, "n")
+    gate_b, gate_c, z = jnp.split(bcz, 3, axis=-1)
+    g = gate_b * z
+    taps = p["taps"].astype(jnp.float32)  # [D, K]
+    k = taps.shape[-1]
+    bt, t, d = g.shape
+    if state is None:
+        state = jnp.zeros((bt, k - 1, d), g.dtype)
+    win = jnp.concatenate([state.astype(g.dtype), g], axis=1)  # [Bt, T+K-1, D]
+    conv = sum(
+        taps[:, j] * win[:, j: j + t].astype(jnp.float32) for j in range(k)
+    ).astype(x.dtype)
+    out = _contract(gate_c * conv, p["out_proj"], "btd,de->bte", 1, "k")
+    if seq_lens is None:
+        return out, win[:, t:]
+    new = jax.vmap(
+        lambda w, n: jax.lax.dynamic_slice_in_dim(w, n, k - 1, axis=0)
+    )(win, seq_lens)
+    return out, new
+
+
 @jax.named_scope("mlp")  # profiler scope; HLO metadata only
 def moe_swiglu(
     x: jax.Array, p: Params, cfg: ModelConfig
@@ -256,8 +402,8 @@ def moe_swiglu(
     capacity semantics, scatter-based dispatch).  Net-new vs the reference
     (SURVEY §2.3: MoE absent).  Returns (output, aux_load_balance_loss).
 
-    - router: top-k experts per token, gates = softmax over the k logits
-      (Mixtral convention);
+    - router: :func:`route_experts` (top-k experts per token, gates =
+      softmax over the k logits unless the config says otherwise);
     - dispatch: every (token, choice) claim computes its slot index
       ``expert * cap + position_in_expert`` and the token rows are
       scatter-added into a per-expert buffer [E, C, D] — O(tokens·D) memory,
@@ -277,7 +423,9 @@ def moe_swiglu(
 
     Quantized-resident expert weights rehydrate here (per layer, inside the
     scan): the fused kernel targets 2D contractions, not the batched
-    per-expert einsums below.
+    per-expert einsums below.  This is the path WITH the capacity rule
+    (``cfg.moe_capacity``: the training path, mixtral-8x7b, moe-tiny); a
+    served model takes :func:`moe_dropless`.
     """
     if any(_is_quantized(w) for w in p.values()):
         from ..checkpoint.quantize import dequantize_tree
@@ -292,8 +440,7 @@ def moe_swiglu(
     logits = jnp.einsum(
         "sd,de->se", xf, p["router"], preferred_element_type=jnp.float32
     )
-    topv, topi = jax.lax.top_k(logits, k)  # [s, k]
-    gates = jax.nn.softmax(topv, axis=-1)  # [s, k] f32
+    gates, topi = route_experts(logits, cfg, p.get("expert_bias"))  # [s, k]
 
     # Choice-major claim order: every token's 1st choice claims capacity
     # before any 2nd choice does.  eid: [k*s] expert id per claim.

@@ -85,6 +85,19 @@ jax.tree_util.register_dataclass(
 )
 
 
+@jax.tree_util.register_dataclass
+@dataclass
+class HybridCache(KVCache):
+    """Cache of a model whose layers differ (family "hybrid"): two kinds of
+    state a row.  ``k``/``v`` as in :class:`KVCache` (contiguous or the page
+    pool), their layer axis counting the ATTENTION layers only
+    (``cfg.attn_layers``); ``conv`` [conv layers, B, K-1, D] holds each
+    short-convolution layer's last K-1 gated inputs a row (a batch slot of
+    the batcher: the state is not paged), in the activations' dtype."""
+
+    conv: Any
+
+
 def init_cache(
     cfg: ModelConfig, batch: int, max_len: int, dtype: Any = None,
     prompt_len: int | None = None,
@@ -93,8 +106,20 @@ def init_cache(
     seq-parallel cache splits regions there); the dense layout ignores it."""
     del prompt_len
     dtype = dtype or jnp.dtype(cfg.dtype)
-    shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim_)
-    return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+    shape = (len(cfg.attn_layers), batch, max_len, cfg.num_kv_heads,
+             cfg.head_dim_)
+    k, v = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    if cfg.family != "hybrid":
+        return KVCache(k=k, v=v)
+    return HybridCache(k=k, v=v, conv=conv_state(cfg, batch))
+
+
+def conv_state(cfg: ModelConfig, rows: int) -> jax.Array:
+    """What a :class:`HybridCache` holds beside k and v, zeroed: the
+    convolution state of ``rows`` rows."""
+    return jnp.zeros(
+        (len(cfg.conv_layers), rows, cfg.conv_kernel - 1, cfg.hidden_size),
+        jnp.dtype(cfg.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -144,7 +169,10 @@ def _paged_attention(q, k, v, p, pool, layer, cache_index, kv_tables):
         (k, ks), (v, vs) = kv_quantize(k), kv_quantize(v)  # [B, T, KVH, HD]
         new = (k, v, ks, vs)  #                    i8 and [B, T, KVH] f32
     else:
-        new = (k.astype(pool[0].dtype), v.astype(pool[1].dtype))
+        # (reshaped to the pool's last two axes: narrow heads lie folded
+        # there, ops.decode_attn.pool_head_shape; the same bytes)
+        new = tuple(x.astype(leaf.dtype).reshape(*x.shape[:2], *leaf.shape[3:])
+                    for x, leaf in zip((k, v), pool))
     pool = tuple(
         leaf.at[layer, page, off].set(x) for leaf, x in zip(pool, new)
     )
@@ -199,6 +227,9 @@ def _attention(
     #                            stack (with kv_tables only)
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array] | None]:
     q, k, v = layers.qkv_project(x, p, cfg)
+    if cfg.qk_norm:  # per head, over the head dim, before the rotation
+        q = layers.rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = layers.rms_norm(k, p["k_norm"], cfg.norm_eps)
     if use_rope:
         rope_scale = (
             (cfg.rope_scaling_factor, cfg.rope_low_freq_factor,
@@ -476,8 +507,11 @@ def llama_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, 
     x = x + attn_out
     h = layers.rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
     if "router" in p["mlp"]:  # MoE block (cfg.num_experts > 0)
-        mlp_out, aux = layers.moe_swiglu(h, p["mlp"], cfg)
-        return x + mlp_out, new_cache, aux
+        if cfg.moe_capacity:
+            mlp_out, aux = layers.moe_swiglu(h, p["mlp"], cfg)
+        else:  # no drops, and no load to balance: nothing is dropped
+            mlp_out, aux = layers.moe_dropless_layer(h, p["mlp"], cfg), 0.0
+        return x + mlp_out, new_cache, jnp.float32(aux)
     x = x + layers.mlp_swiglu(h, p["mlp"], cfg.gate_act)
     return x, new_cache, jnp.float32(0.0)
 
@@ -576,6 +610,171 @@ def run_blocks(
     return out, ys[0], jnp.sum(ys[1])
 
 
+def layer_runs(cfg: ModelConfig) -> tuple:
+    """The hybrid family's layers as runs: ((unit, repeats), ...), a unit a
+    tuple of layers (operator kind, FFN kind) that repeats ``repeats``
+    times in the published order.  LFM2-8B-A1B: a convolution layer with a
+    dense FFN twice, (attention, conv, conv, conv) with experts four times,
+    (attention, conv, conv) with experts twice.  A run that repeats is one
+    ``lax.scan`` (:func:`run_layers`), so a program holds each unit's
+    kernels once: 44 Pallas calls where 24 unrolled layers hold 116, and
+    compiles in a third of the time (PERF.md, PR 28)."""
+    seq = [
+        (t, "dense" if l < cfg.num_dense_layers or not cfg.num_experts
+         else "moe")
+        for l, t in enumerate(cfg.layer_types)
+    ]
+    runs, i = [], 0
+    while i < len(seq):
+        best = (1, 1)  # (period, repeats) covering the most layers
+        for period in range(1, (len(seq) - i) // 2 + 1):
+            unit, reps = seq[i: i + period], 1
+            while seq[i + reps * period: i + (reps + 1) * period] == unit:
+                reps += 1
+            if reps > 1 and period * reps > best[0] * best[1]:
+                best = (period, reps)
+        runs.append((tuple(seq[i: i + best[0]]), best[1]))
+        i += best[0] * best[1]
+    return tuple(runs)
+
+
+def run_layers(
+    x: jax.Array,
+    blocks: Params,  # params["blocks"]: one stack a KIND of layer
+    cfg: ModelConfig,
+    positions: jax.Array,
+    cache: HybridCache | None,
+    cache_index: jax.Array | None,
+    attn_mask: jax.Array | None = None,
+    std_layout: bool = False,
+    kv_tables: jax.Array | None = None,
+    key_positions: jax.Array | None = None,
+    seq_lens: jax.Array | None = None,  # [B] real new tokens a row
+) -> tuple[jax.Array, HybridCache | None, jax.Array]:
+    """The "hybrid" family's layers (LFM2-MoE), which differ: layer l is
+    ``x + op_l(rms(x))`` then ``+ ffn_l(rms(.))`` with op_l a gated short
+    convolution or GQA attention (``cfg.layer_types``) and ffn_l a dense
+    SwiGLU for the first ``cfg.num_dense_layers`` layers, an expert layer
+    after.
+
+    The weights are stacked by KIND (``blocks["conv"]`` [18, ...],
+    ``["attn"]`` [6, ...], ``["dense"]`` [2, ...], ``["moe"]`` [22, ...];
+    each layer's two norms lie with its operator) and walked in the
+    published order as :func:`layer_runs` has it, a repeating run under
+    one ``lax.scan`` with the layer's index into each stack computed from
+    the iteration.  The expert stacks go into their kernel whole, indexed
+    by (layer, expert) (ops/moe_experts.py), so no program copies or
+    dequantizes them; the other weights, 5% of the bytes, are sliced a
+    layer as the dense families' are.
+
+    The cache is the scans' carry beside x.  An attention layer reads and
+    writes it at its own index among the attention layers: the page pool
+    whole (``kv_tables``; see :func:`_paged_attention`), a contiguous
+    cache by its layer slice.  A convolution layer reads and writes its
+    [B, K-1, D] slice of ``cache.conv``.
+
+    Returns (x, cache', stats): stats int32 [4] adds up what the expert
+    layers routed for the real tokens of this pass (layers.moe_dropless:
+    pairs, layer passes, experts touched, fullest expert's tokens), a
+    by-product like the dense families' aux loss and no part of the
+    state."""
+    k = v = conv = None
+    moe = jnp.zeros((4,), jnp.int32)
+    if cache is not None:
+        k, v, conv = cache.k, cache.v, cache.conv
+    token_mask = None
+    if seq_lens is not None:
+        token_mask = (jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
+                      < seq_lens[:, None])
+
+    def layer(carry, op, ffn, at):
+        """One layer; ``at`` its index into each kind's stack."""
+        x, k, v, conv, moe = carry
+        p = jax.tree.map(lambda a: a[at[op]], blocks[op])
+        h = layers.rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
+        if op == "conv":
+            out, new = layers.short_conv(
+                h, p, None if conv is None else conv[at[op]], seq_lens)
+            if conv is not None:
+                conv = conv.at[at[op]].set(new.astype(conv.dtype))
+        else:
+            ai = at[op]
+            if k is None:
+                layer_cache = None
+            elif kv_tables is not None:
+                layer_cache = (k, v)
+            else:
+                layer_cache = (k[ai], v[ai])
+            out, new = _attention(
+                h, p, cfg, positions, layer_cache, cache_index,
+                use_rope=True, attn_mask=attn_mask, std_layout=std_layout,
+                kv_tables=kv_tables, key_positions=key_positions, layer=ai,
+            )
+            if kv_tables is not None:
+                k, v = new
+            elif k is not None:
+                k, v = k.at[ai].set(new[0]), v.at[ai].set(new[1])
+        x = x + out
+        h = layers.rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
+        if ffn == "moe":
+            y, stats = layers.moe_dropless(
+                h, blocks["moe"], cfg, token_mask, layer=at[ffn])
+            x, moe = x + y, moe + stats
+        else:
+            dense = jax.tree.map(lambda a: a[at[ffn]], blocks["dense"])
+            x = x + layers.mlp_swiglu(h, dense, cfg.gate_act)
+        return x, k, v, conv, moe
+
+    carry = (x, k, v, conv, moe)
+    base = dict(conv=0, attn=0, dense=0, moe=0)
+    for unit, reps in layer_runs(cfg):
+        kinds = [kind for pair in unit for kind in pair]
+        per_unit = {kind: kinds.count(kind) for kind in base}
+
+        def run(carry, rep, unit=unit, per_unit=per_unit, base=dict(base)):
+            seen = dict.fromkeys(base, 0)
+            for op, ffn in unit:
+                at = {kind: base[kind] + rep * per_unit[kind] + seen[kind]
+                      for kind in (op, ffn)}
+                carry = layer(carry, op, ffn, at)
+                seen[op] += 1
+                seen[ffn] += 1
+            return carry, None
+
+        if reps == 1:
+            carry, _ = run(carry, 0)
+        else:
+            carry, _ = jax.lax.scan(
+                run, carry, jnp.arange(reps, dtype=jnp.int32))
+        for kind in base:
+            base[kind] += reps * per_unit[kind]
+    x, k, v, conv, moe = carry
+    if cache is None:
+        return x, None, moe
+    return x, HybridCache(k=k, v=v, conv=conv), moe
+
+
+def hybrid_layers(params: Params, cfg: ModelConfig):
+    """``params`` of a hybrid model, a layer at a time in the published
+    order: dicts {"ln1", "ln2": {"scale"}, "conv" | "attn": {...}, "mlp":
+    {...}} as models/reference/lfm2_moe.py reads them.  A generator, so a
+    caller that dequantizes what it is handed holds one layer in float32."""
+    at = dict(conv=0, attn=0, dense=0, moe=0)
+    blocks = params["blocks"]
+
+    def take(kind):
+        out = jax.tree.map(lambda a: a[at[kind]], blocks[kind])
+        at[kind] += 1
+        return out
+
+    for l, op in enumerate(cfg.layer_types):
+        p = take(op)
+        ffn = "dense" if l < cfg.num_dense_layers or not cfg.num_experts \
+            else "moe"
+        yield {"ln1": p.pop("ln1"), "ln2": p.pop("ln2"), op: p,
+               "mlp": take(ffn)}
+
+
 # ---------------------------------------------------------------------------
 # Full model forward
 # ---------------------------------------------------------------------------
@@ -620,17 +819,25 @@ def forward(
     #   [B] int32 per-row offsets (continuous batching; attn_mask required)
     remat: bool = False,
     attn_mask: jax.Array | None = None,  # broadcastable to [B, H, Tq, S]; True = attend
-    return_aux: bool = False,  # also return the MoE load-balance aux loss
+    return_aux: bool = False,  # also return the expert layers' by-product:
+    #   the load-balance aux loss; for the hybrid family, which is not
+    #   trained, its routing counts int32 [4] (run_layers)
     kv_tables: jax.Array | None = None,  # [B, P] page table: the cache holds
     #   page POOLS [L, NB, BLK, KVH, HD] (paged continuous batching; see
     #   _attention's kv_tables contract — decode-only)
     key_positions: jax.Array | None = None,  # [B, S] true RoPE positions of
     #   cache slots, for the sliding-window mask under gapped (right-padded
     #   generate) cache layouts — see _attention's parameter comment
+    seq_lens: jax.Array | None = None,  # [B] int32: how many of the T tokens
+    #   of each row are real (right-padded input; 0 for a batch row that is
+    #   not decoding).  Only a model with state that is not keys and values
+    #   needs it (family "hybrid": layers.short_conv, layers.moe_dropless);
+    #   None means all T
 ) -> tuple[jax.Array, KVCache | None] | tuple[jax.Array, KVCache | None, jax.Array]:
     """Full forward.  Returns (logits [B, T, V] float32, updated cache), plus
     the summed MoE aux loss when ``return_aux`` (scale by
-    cfg.moe_aux_loss_weight and add to the task loss when training MoE).
+    cfg.moe_aux_loss_weight and add to the task loss when training MoE;
+    the hybrid family hands out its routing counts there instead).
 
     Contract: ``cache_index + T`` must not exceed ``cache.max_len`` — XLA's
     ``dynamic_update_slice`` clamps out-of-range starts, which would silently
@@ -645,6 +852,18 @@ def forward(
         base = cache_index if cache_index is not None else 0
         positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32) + base, (b, t))
     x = embed(params, cfg, tokens, positions)
+    if cfg.family == "hybrid":
+        if isinstance(cache, QuantKVCache) or remat:
+            raise ValueError(
+                "the hybrid family serves a full-width HybridCache and is "
+                "not trained: no int8 pool, no remat"
+            )
+        x, cache, stats = run_layers(
+            x, params["blocks"], cfg, positions, cache, cache_index,
+            attn_mask, std_layout, kv_tables, key_positions, seq_lens,
+        )
+        out = (unembed(params, cfg, x), cache)
+        return (*out, stats) if return_aux else out
     if cache is None:
         x, _, aux = run_blocks(x, params["blocks"], cfg, positions, None, None, None, remat, attn_mask, std_layout)
         out = (unembed(params, cfg, x), None)
@@ -746,15 +965,98 @@ def init_params(rng: jax.Array, cfg: ModelConfig, dtype: Any = None) -> Params:
             "attn": attn,
             "mlp": mlp,
         }
+    elif cfg.family == "hybrid":
+        params["blocks"] = _init_hybrid_blocks(rng, cfg, dtype)
     else:
         raise ValueError(f"unknown family {cfg.family!r}")
-    if cfg.num_experts > 0 and cfg.family != "llama":
+    if cfg.num_experts > 0 and cfg.family not in ("llama", "hybrid"):
         raise ValueError("MoE (num_experts > 0) is supported for the llama family")
     if cfg.family == "neox" and cfg.tie_embeddings:
         raise ValueError("neox checkpoints untie embeddings (embed_out)")
     if not cfg.tie_embeddings:
         params["lm_head"] = {"w": dense(next(keys), (D, cfg.vocab_size), D)}
     return params
+
+
+def hybrid_fan_in(name: str, shape: tuple) -> int:
+    """Fan-in of a hybrid-family weight leaf [L, ...], by its path: what
+    the random init scales by (shared by :func:`init_params` and
+    :func:`init_params_quantized`, so that both draw at one scale)."""
+    parts = name.split("/")
+    if parts[-1] == "wo":  # [L, H, hd, D]
+        return shape[1] * shape[2]
+    if parts[-1] == "taps":  # [L, D, K]
+        return shape[2]
+    if "experts" in parts:  # [L, E, K, N]
+        return shape[2]
+    return shape[1]
+
+
+def _init_hybrid_blocks(rng: jax.Array, cfg: ModelConfig, dtype: Any) -> Params:
+    """One stack a kind of layer (models.model.run_layers): ``conv`` and
+    ``attn`` (each with its layers' two norms), ``dense`` for the first
+    ``cfg.num_dense_layers`` FFNs and ``moe`` (router + experts) for the
+    rest.  The router and the selection bias are float32 whatever the
+    model's dtype (the scores pick the experts); ``expert_bias`` is drawn
+    N(0, 0.1) so that it changes the chosen set (a trained model's is
+    learned)."""
+    D, F, FE = cfg.hidden_size, cfg.intermediate_size, cfg.expert_size
+    H, KVH, HD = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    E = cfg.num_experts
+    NC, NA = len(cfg.conv_layers), len(cfg.attn_layers)
+    ND = cfg.num_dense_layers if E else cfg.num_layers
+    NM = cfg.num_layers - ND
+
+    def dense(name, shape, dt=dtype):
+        full = f"blocks/{name}"
+        key = jax.random.fold_in(rng, zlib.crc32(full.encode()))
+        scale = hybrid_fan_in(full, shape) ** -0.5
+        return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dt)
+
+    def norms(n):
+        return {"ln1": {"scale": jnp.ones((n, D), dtype)},
+                "ln2": {"scale": jnp.ones((n, D), dtype)}}
+
+    blocks: Params = {
+        "conv": {
+            **norms(NC),
+            "in_proj": dense("conv/in_proj", (NC, D, 3 * D)),
+            "taps": dense("conv/taps", (NC, D, cfg.conv_kernel)),
+            "out_proj": dense("conv/out_proj", (NC, D, D)),
+        },
+        "attn": {
+            **norms(NA),
+            # [D, H * hd], the head axes flat: quantized with their own
+            # axis last, heads of 64 would get absmax blocks of 64, which
+            # the fused kernel cannot tile (layers.qkv_project unflattens).
+            "wq": dense("attn/wq", (NA, D, H * HD)),
+            "wk": dense("attn/wk", (NA, D, KVH * HD)),
+            "wv": dense("attn/wv", (NA, D, KVH * HD)),
+            "wo": dense("attn/wo", (NA, H, HD, D)),
+        },
+        "dense": {
+            "w_gate": dense("dense/w_gate", (ND, D, F)),
+            "w_up": dense("dense/w_up", (ND, D, F)),
+            "w_down": dense("dense/w_down", (ND, F, D)),
+        },
+    }
+    if cfg.qk_norm:
+        blocks["attn"]["q_norm"] = jnp.ones((NA, HD), dtype)
+        blocks["attn"]["k_norm"] = jnp.ones((NA, HD), dtype)
+    if NM:
+        blocks["moe"] = {
+            "router": dense("moe/router", (NM, D, E), jnp.float32),
+            "experts": {
+                "w_gate_up": dense("moe/experts/w_gate_up", (NM, E, D, 2 * FE)),
+                "w_down": dense("moe/experts/w_down", (NM, E, FE, D)),
+            },
+        }
+        if cfg.moe_expert_bias:
+            key = jax.random.fold_in(
+                rng, zlib.crc32(b"blocks/moe/expert_bias"))
+            blocks["moe"]["expert_bias"] = 0.1 * jax.random.normal(
+                key, (NM, E), jnp.float32)
+    return blocks
 
 
 def init_params_quantized(
@@ -795,8 +1097,11 @@ def init_params_quantized(
         name = "/".join(str(p.key) for p in path)
         leaf = path[-1].key
         key = jax.random.fold_in(rng, zlib.crc32(name.encode()))
-        scale = fan_ins.get(leaf, cfg.hidden_size) ** -0.5
+        hybrid = cfg.family == "hybrid" and name.startswith("blocks/")
+        scale = (hybrid_fan_in(name, sd.shape) if hybrid and sd.ndim > 2
+                 else fan_ins.get(leaf, cfg.hidden_size)) ** -0.5
         should, pack_axis = quant_lib.leaf_plan(name, sd)
+        block_axis = quant_lib.block_axis_of(name)
         quant = should and name.startswith("blocks/")
         repeat = 1
         sharding = None
@@ -818,12 +1123,15 @@ def init_params_quantized(
             x = dense(jax.random.fold_in(key, i), sd.shape[1:])
             if not quant:
                 return x.astype(sd.dtype)
-            qt = quant_lib.quantize(x, bits=bits, pack_axis=pack_axis)
+            qt = quant_lib.quantize(x, bits=bits, pack_axis=pack_axis,
+                                    block_axis=block_axis)
             return qt.data, jnp.repeat(qt.scale, repeat, axis=-1)
 
         def gen():
-            if leaf == "scale":
+            if leaf in ("scale", "q_norm", "k_norm"):
                 return jnp.ones(sd.shape, sd.dtype)
+            if leaf == "expert_bias":  # drawn, so that it changes the choice
+                return 0.1 * jax.random.normal(key, sd.shape, sd.dtype)
             if leaf.startswith("b"):  # bias, bq/bk/bv/bo, b_in/b_out
                 return jnp.zeros(sd.shape, sd.dtype)
             if name.startswith("blocks/"):
@@ -836,6 +1144,7 @@ def init_params_quantized(
         return quant_lib.QuantizedTensor(
             data=out[0], scale=out[1], bits=bits,
             orig_shape=tuple(sd.shape), pack_axis=pack_axis,
+            block_axis=block_axis,
         )
 
     if specs is None:

@@ -95,7 +95,37 @@ PRESETS: dict[str, ModelConfig] = {
         rope_theta=1e6, norm_eps=1e-5, tie_embeddings=False,
         num_experts=8, num_experts_per_token=2,
     ),
+    # LFM2-8B-A1B (LiquidAI, model_type lfm2_moe): 18 gated short
+    # convolutions and 6 GQA attention layers (at 2, 6, 10, 14, 18, 21: no
+    # fixed period), heads of 64 with QK-norm, two dense FFNs then 32 experts
+    # of 1,792 routed 4 a token by biased sigmoid scores, no capacity rule.
+    # The head is tied to the embedding, as published.
+    "lfm2-8b-a1b": ModelConfig(
+        family="hybrid", vocab_size=65536, hidden_size=2048,
+        intermediate_size=7168, moe_intermediate_size=1792, num_layers=24,
+        num_dense_layers=2, num_heads=32, num_kv_heads=8, max_seq_len=128000,
+        rope_theta=1e6, norm_eps=1e-5, tie_embeddings=True, qk_norm=True,
+        conv_kernel=3,
+        layer_types=tuple(
+            "attn" if l in (2, 6, 10, 14, 18, 21) else "conv"
+            for l in range(24)
+        ),
+        num_experts=32, num_experts_per_token=4, moe_score_fn="sigmoid",
+        moe_expert_bias=True, moe_norm_topk=True, moe_routed_scale=1.0,
+        moe_capacity=False,
+    ),
     # Tiny configs for unit tests / CPU fake-mesh integration tests.
+    "lfm2-tiny": ModelConfig(
+        family="hybrid", vocab_size=256, hidden_size=64,
+        intermediate_size=128, moe_intermediate_size=32, num_layers=8,
+        num_dense_layers=2, num_heads=4, num_kv_heads=2, head_dim=16,
+        max_seq_len=128, rope_theta=1e6, tie_embeddings=True, qk_norm=True,
+        dtype="float32", conv_kernel=3,
+        layer_types=("conv", "conv", "attn", "conv", "conv", "conv", "attn",
+                     "conv"),
+        num_experts=8, num_experts_per_token=2, moe_score_fn="sigmoid",
+        moe_expert_bias=True, moe_capacity=False,
+    ),
     "moe-tiny": ModelConfig(
         family="llama", vocab_size=256, hidden_size=64, intermediate_size=128,
         num_layers=2, num_heads=4, num_kv_heads=2, max_seq_len=128,
@@ -141,6 +171,7 @@ HF_REPOS: dict[str, str] = {
     "mistral-7b": "mistralai/Mistral-7B-v0.1",
     "phi-3-mini-4k": "microsoft/Phi-3-mini-4k-instruct",
     "pythia-6.9b": "EleutherAI/pythia-6.9b",
+    "lfm2-8b-a1b": "LiquidAI/LFM2-8B-A1B",
 }
 
 

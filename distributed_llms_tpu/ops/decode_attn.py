@@ -428,11 +428,16 @@ def _paged_impl(
     b, t, h, d = q.shape
     assert t == 1, "paged decode attention is single-token by construction"
     quant = k_scale is not None
-    blk, kvh = k_pages.shape[2], k_pages.shape[3]
+    blk = k_pages.shape[2]
+    # A pool of heads narrower than a 128-lane row keeps ``fold`` of them
+    # to a row (pool_head_shape): [.., KVH / fold, fold * D].
+    fold = k_pages.shape[4] // d
+    kvh = k_pages.shape[3] * fold
     p = tables.shape[1]
     g = h // kvh
     tileable = (
-        blk % 8 == 0 and d % 128 == 0 and _kv_vmem_ok(blk, kvh, d, k_pages.dtype)
+        blk % 8 == 0 and (d * fold) % 128 == 0
+        and _kv_vmem_ok(blk, kvh, d, k_pages.dtype)
     )
     if mode == "fallback" or not tileable:
         dispatch.record("paged_decode", "fallback", (b, blk, h, kvh, d))
@@ -452,8 +457,12 @@ def _paged_impl(
         return _dense_reference(q, k_rows, v_rows, lengths)
 
     dispatch.record("paged_decode", mode, (b, blk, h, kvh, d))
-    gp = _round_up(g, 8)
+    scale = d**-0.5
     qt = q[:, 0].reshape(b, kvh, g, d)
+    if fold > 1:
+        qt = _fold_queries(qt, fold)
+        _, kvh, g, d = qt.shape
+    gp = _round_up(g, 8)
     if gp != g:
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
 
@@ -508,7 +517,7 @@ def _paged_impl(
         ]
     out = pl.pallas_call(
         functools.partial(
-            _kernel_paged, scale=d**-0.5, block_k=blk, num_k_blocks=p,
+            _kernel_paged, scale=scale, block_k=blk, num_k_blocks=p,
             kvh=kvh, gp=gp, quant=quant,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -529,7 +538,45 @@ def _paged_impl(
         name="paged_decode_attn",  # the operation's name in a trace
     )(*operands)
     out = out.reshape(b, kvh, gp, d)[:, :, :g]
-    return out.reshape(b, 1, h, d)
+    if fold > 1:
+        # Query group i of a folded head reads its answer in lane group i.
+        out = jnp.einsum(
+            "bjigkd,ik->bjigd",
+            out.reshape(b, kvh, fold, g // fold, fold, d // fold),
+            jnp.eye(fold, dtype=out.dtype),
+        )
+    return out.reshape(b, 1, h, q.shape[-1])
+
+
+def pool_head_shape(kvh: int, d: int, fold_narrow: bool) -> tuple[int, int]:
+    """(heads, width) of a page pool's last two axes.  With ``fold_narrow``
+    heads narrower than the 128 lanes of a row lie 128 // d to a row,
+    [.., KVH / fold, fold * D]: a [.., 8, 64] array is tiled (8, 128), half
+    of it padding, and handing it to the 128-lane kernel reshaped would
+    copy the whole pool into the other layout in every layer.  The bytes
+    and their order are those of [.., KVH, D]."""
+    fold = 128 // d if fold_narrow and d < 128 and 128 % d == 0 else 1
+    return (kvh // fold, d * fold) if kvh % fold == 0 else (kvh, d)
+
+
+def _fold_queries(qt, fold: int):
+    """Serve heads of 128 / ``fold`` lanes from the 128-lane kernel.  The
+    pool keeps ``fold`` neighbouring KV heads in one 128-lane row
+    (:func:`pool_head_shape`), which the kernel reads as ONE head; their
+    query groups go in side by side, each with zeros in the other heads'
+    lanes.  The zeros make the score of query group i the dot product with
+    head i alone, and the value product leaves head i's answer in lane
+    group i (:func:`_paged_impl` picks it out).  The kernel's body is the
+    128-wide one; the price is ``fold`` times the arithmetic of an
+    operation that is bound by its reads.
+
+    qt [B, KVH, G, D] -> [B, KVH/fold, fold*G, fold*D]."""
+    b, kvh, g, d = qt.shape
+    return jnp.einsum(
+        "bjigd,ik->bjigkd", qt.reshape(b, kvh // fold, fold, g, d),
+        jnp.eye(fold, dtype=qt.dtype),
+    ).reshape(b, kvh // fold, fold * g, fold * d)
+
 
 # ---------------------------------------------------------------------------
 # Operand placement on tensor-parallel serving meshes

@@ -35,6 +35,18 @@ def _axis_size(mesh: Mesh, name: str) -> int:
 
 def param_specs(cfg: ModelConfig, mesh: Mesh) -> Params:
     """PartitionSpec pytree matching models.model param trees."""
+    if cfg.family == "hybrid":
+        # No sharding rule yet for the stacks by kind, the expert stacks
+        # or the convolution state: every leaf replicated (the engine and
+        # the batcher refuse a mesh for this family: runtime/batcher.py
+        # refuse_unpaged_state; ROADMAP Reach).
+        import jax
+
+        from ..models.model import init_params
+
+        shapes = jax.eval_shape(
+            lambda: init_params(jax.random.key(0), cfg))
+        return jax.tree.map(lambda x: P(*(None,) * x.ndim), shapes)
     tp = _axis_size(mesh, "model")
     # The stacked layer axis shards over 'pipe' only when it divides evenly;
     # an uneven split (e.g. 3 layers over pipe=2) would leave XLA padding a

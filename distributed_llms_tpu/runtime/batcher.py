@@ -72,6 +72,7 @@ host-RAM KV tier lives in runtime/kv_tier.py (re-exported here).
 
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import threading
 import time
@@ -88,7 +89,7 @@ from ..core import profiling
 from ..core.config import ModelConfig
 from ..core.observability import METRICS, get_logger
 from ..models import model as model_lib
-from ..models.model import KVCache, QuantKVCache
+from ..models.model import HybridCache, KVCache, QuantKVCache
 from . import constrain as constrain_lib
 from . import sampling
 from . import scheduler as scheduler_lib
@@ -163,17 +164,23 @@ def _sample_first(logits, last_idx, rng, temperature, top_k, top_p,
     return tok, lp
 
 
-def _prefill_row(fwd, params, cfg, cache_dtype, s, prompt):
+def _prefill_row(fwd, params, cfg, cache_dtype, s, prompt, plen=None):
     """Dense causal prefill of one request into a transient single-row
     cache (flash-eligible: attn_mask=None) — shared by the contiguous and
     paged admissions.  ``fwd`` is _fwd(pm): the mesh-parallel forward on a
-    mesh batcher, the plain model forward otherwise."""
+    mesh batcher, the plain model forward otherwise.  A model with state
+    that is not keys and values (family "hybrid") is told the prompt's true
+    length ``plen``, leaves in the row cache the state AT that length, not
+    at the padded bucket's end, and returns its expert layers' counts of
+    the real tokens third (forward's ``return_aux``)."""
     (tp,) = prompt.shape
     row_cache = model_lib.init_cache(cfg, 1, s, dtype=cache_dtype)
     positions = jnp.arange(tp, dtype=jnp.int32)[None, :]
+    state = ({"seq_lens": plen[None], "return_aux": True}
+             if cfg.family == "hybrid" else {})
     return fwd(
         params, cfg, prompt[None, :], positions=positions,
-        cache=row_cache, cache_index=jnp.int32(0),
+        cache=row_cache, cache_index=jnp.int32(0), **state,
     )
 
 
@@ -899,7 +906,7 @@ def _pool_constrain(pm, cache):
 
 
 def _paged_pool(cfg: ModelConfig, num_pages: int, page_size: int, dtype=None,
-                kv_bits: int = 16):
+                kv_bits: int = 16, slots: int = 0):
     """KV page pools [L, NB, BLK, KVH, HD] (distinct k/v buffers — the
     chunk fns donate the cache).  Each is ONE stack of every layer's
     pages and stays one: the decode programs carry it through the layer
@@ -910,10 +917,20 @@ def _paged_pool(cfg: ModelConfig, num_pages: int, page_size: int, dtype=None,
     :class:`~..models.model.QuantKVCache` pool (data int8 + one f32 absmax
     scale per head-dim vector) at roughly half the bytes per token; the
     full-width dtype survives as ``row_dtype`` so gathers/transient rows
-    restore to it."""
-    l, kvh, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim_
+    restore to it.  A hybrid model's pool counts its attention layers
+    only and comes with the state that is not paged: each convolution
+    layer's, one entry a batch slot (``slots``), in a
+    :class:`~..models.model.HybridCache`."""
+    from ..ops.decode_attn import pool_head_shape
+
+    l = len(cfg.attn_layers)
+    kvh, hd = pool_head_shape(cfg.num_kv_heads, cfg.head_dim_,
+                              fold_narrow=pages_are_private(cfg))
     dt = jnp.dtype(dtype) if dtype else jnp.dtype(cfg.dtype)
     shape = (l, num_pages, page_size, kvh, hd)
+    if cfg.family == "hybrid":
+        return HybridCache(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt),
+                           conv=model_lib.conv_state(cfg, slots))
     if kv_bits == 8:
         sshape = (l, num_pages, page_size, kvh)
         return QuantKVCache(
@@ -938,7 +955,7 @@ def pool_page_bytes(cfg: ModelConfig, page_size: int, kv_bits: int = 16,
                     dtype=None) -> int:
     """Bytes one pool page costs (k + v + scales) — the denominator of the
     capacity-per-byte comparison bench.py's kv-tiering row stamps."""
-    l, kvh, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim_
+    l, kvh, hd = len(cfg.attn_layers), cfg.num_kv_heads, cfg.head_dim_
     elems = l * page_size * kvh * hd
     if kv_bits == 8:
         return 2 * (elems + l * page_size * kvh * 4)
@@ -948,7 +965,7 @@ def pool_page_bytes(cfg: ModelConfig, page_size: int, kv_bits: int = 16,
 
 def _paged_splice(cache, page_list, row_cache, logits, last_idx, rng,
                   temperature, top_k, top_p, temp_req=None, topp_req=None,
-                  topk_req=None, mask_req=None, pm=None):
+                  topk_req=None, mask_req=None, pm=None, slot=None):
     """Admission tail for the paged pool: sample the first token, then
     scatter the contiguous transient row cache into the row's pages.
     ``page_list`` [P] is padded with the reserved scratch page 0 past the
@@ -959,15 +976,18 @@ def _paged_splice(cache, page_list, row_cache, logits, last_idx, rng,
     positions to the scratch page: the shared pages already hold exactly
     that KV and must never be rewritten while other rows read them.
     On a mesh batcher (``pm``) the pool result is re-constrained to its
-    sharding and the sampled token/logprob replicate (lockstep mirrors)."""
+    sharding and the sampled token/logprob replicate (lockstep mirrors).
+    A :class:`HybridCache` also takes the row's convolution state into
+    batch slot ``slot`` (all of it: whatever the slot's last row left is
+    overwritten)."""
     tok, lp = _sample_first(logits, last_idx, rng, temperature, top_k, top_p,
                             temp_req, topp_req, topk_req, mask_req)
     p = page_list.shape[0]
     blk = cache.k.shape[2]
 
     def as_pages(row):  # [L, 1, P*BLK, KVH, HD] -> [L, P, BLK, KVH, HD]
-        l, _, _, kvh, hd = row.shape
-        return row[:, 0].reshape(l, p, blk, kvh, hd)
+        # (the pool's own last two axes: narrow heads may lie folded there)
+        return row[:, 0].reshape(row.shape[0], p, blk, *cache.k.shape[3:])
 
     k, v = as_pages(row_cache.k), as_pages(row_cache.v)
     if isinstance(cache, QuantKVCache):
@@ -988,6 +1008,12 @@ def _paged_splice(cache, page_list, row_cache, logits, last_idx, rng,
             (cache.k, cache.v), page_list,
             (k.astype(cache.k.dtype), v.astype(cache.v.dtype)),
         )
+        if isinstance(cache, HybridCache):
+            conv = jax.lax.dynamic_update_slice_in_dim(
+                cache.conv, row_cache.conv.astype(cache.conv.dtype), slot,
+                axis=1,
+            )
+            return HybridCache(k=k, v=v, conv=conv), tok, lp
         cache = KVCache(k=k, v=v)
     return (_pool_constrain(pm, cache), *_replicated(pm, tok, lp))
 
@@ -1035,18 +1061,21 @@ def admit_row_paged(
     topp_req: jax.Array | None = None,
     topk_req: jax.Array | None = None,
     mask_req: jax.Array | None = None,  # [V] constrained first-token mask
+    slot: jax.Array | None = None,  # scalar int32 batch slot: where a
+    #   hybrid model's convolution state goes (nothing else needs it)
 ) -> tuple[Any, jax.Array, jax.Array]:
     """Paged admission: dense causal prefill on a transient contiguous row
     cache, then scatter its pages into the pool.
-    Returns (cache', tok, logprob)."""
-    logits, row_cache = _prefill_row(
+    Returns (cache', tok, logprob), and for a hybrid model the prefill's
+    expert counts (see :func:`_prefill_row`)."""
+    logits, row_cache, *moe = _prefill_row(
         _fwd(pm), params, cfg, _row_dtype_of(cache),
-        page_list.shape[0] * cache.k.shape[2], prompt,
+        page_list.shape[0] * cache.k.shape[2], prompt, plen,
     )
-    return _paged_splice(
+    return (*_paged_splice(
         cache, page_list, row_cache, logits, plen, rng, temperature, top_k,
-        top_p, temp_req, topp_req, topk_req, mask_req, pm=pm,
-    )
+        top_p, temp_req, topp_req, topk_req, mask_req, pm=pm, slot=slot,
+    ), *moe)
 
 
 @partial(
@@ -1150,6 +1179,7 @@ def _decode_steps(
     def step(carry, rng_step):
         (cache, last_tok, real_lens, valid, active, budget, cnts,
          dstate) = carry
+        moe = None  # a hybrid model's expert counts of this step
         # One batched forward with PER-ROW write slots (models.model accepts
         # a [B] cache_index: only the KV write scatters; all matmuls stay
         # batched).  Paged mode: the page table routes each row's read and
@@ -1157,10 +1187,19 @@ def _decode_steps(
         # admits each row's valid slots plus the slot its own token was
         # just written to.
         if tables is not None:
-            logits, cache = _fwd(pm)(
+            # A hybrid model's state that is not paged advances only for
+            # the rows that decode (seq_lens 1), so a finished or free row
+            # neither moves its own state nor is counted by the experts,
+            # whose counts come out beside the logits (return_aux).
+            state = ({"seq_lens": active.astype(jnp.int32),
+                      "return_aux": True}
+                     if isinstance(cache, HybridCache) else {})
+            logits, cache, *aux = _fwd(pm)(
                 params, cfg, last_tok[:, None], positions=real_lens[:, None],
                 cache=cache, cache_index=real_lens, kv_tables=tables,
+                **state,
             )
+            moe = aux[0] if aux else None
         else:
             mask = (valid | (slots[None, :] == real_lens[:, None]))[:, None, None, :]
             logits, cache = _fwd(pm)(
@@ -1236,14 +1275,14 @@ def _decode_steps(
         return (
             (cache, last_tok, real_lens, valid, active, budget, cnts,
              dstate),
-            (out, lp),
+            (out, lp, moe),
         )
 
     rngs = jax.random.split(rng, chunk_steps)
     carry0 = (cache, last_tok, real_lens, valid, active, budget, counts,
               dfa_state)
     ((cache, last_tok, real_lens, valid, active, budget, counts,
-      dfa_state), (toks, lps)) = jax.lax.scan(step, carry0, rngs)
+      dfa_state), (toks, lps, moe)) = jax.lax.scan(step, carry0, rngs)
     toks, lps, last_tok, real_lens, valid, active, budget = _replicated(
         pm, toks.T, lps.T, last_tok, real_lens, valid, active, budget
     )
@@ -1260,8 +1299,11 @@ def _decode_steps(
         # heads over 'model') so chained dispatch-ahead chunks and the
         # scatter/gather jits all consume one placement (no-op off-mesh).
         cache = _pool_constrain(pm, cache)
+    # A hybrid model's expert counts, summed over the chunk's steps, leave
+    # as one more output.
+    moe = () if moe is None else (jnp.sum(moe, axis=0),)
     return (toks, cache, last_tok, real_lens, valid, active, budget, lps,
-            counts, dfa_state)
+            counts, dfa_state, *moe)
 
 
 @partial(
@@ -1885,6 +1927,66 @@ class _RowState:
     streamed: int = 0  # tokens already delivered to run()'s on_tokens
 
 
+def pages_are_private(cfg: ModelConfig) -> bool:
+    """True where nothing but the admission's splice and the decode step
+    ever touches a page: :func:`refuse_unpaged_state` has refused every
+    feature that reads [.., KVH, HD] rows out of the pool (prefix cache,
+    named prefixes, tiering, import/export, chunked prefill, speculation,
+    the int8 pool, a mesh), which it does for the family that keeps
+    convolution state beside its pages.  Only then may heads narrower than
+    a 128-lane row lie folded in the pool
+    (ops.decode_attn.pool_head_shape; heads of 128 never fold)."""
+    return cfg.family == "hybrid"
+
+
+def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
+    """Refuse, by name and with the reason, every feature that moves or
+    keeps keys and values and does not yet carry the state a hybrid model
+    holds beside them (each convolution layer's last gated inputs a row:
+    :class:`~..models.model.HybridCache`).  Served anyway, such a feature
+    would hand a row its pages without its state.  ``asked`` maps a
+    feature's name to whether it was asked for; ``paged_pages`` is the one
+    that must be set."""
+    if not pages_are_private(cfg):
+        return
+    why = {
+        "prefix_cache": "a cached page run restores keys and values, not "
+                        "the convolution state at its end",
+        "kv_bits": "the int8 pool's write path knows no convolution state "
+                   "(ask for kv_bits 16)",
+        "host_pages": "the host tier and swap-out park pages, not the "
+                      "convolution state that belongs to them",
+        "speculative": "a rejected draft would have to roll the "
+                       "convolution state back",
+        "prefill_chunk": "a chunked prefill would have to hand the "
+                         "convolution state from bite to bite",
+        "token_budget": "it chunks prefills, which would have to hand the "
+                        "convolution state from bite to bite",
+        "mesh": "the convolution state and the expert stacks have no "
+                "sharding rule yet (mesh.model > 1 included)",
+        "named_prefix": "a registered prefix keeps keys and values, not "
+                        "the convolution state at its end",
+        "kv_import": "KV import/export ships pages, not convolution state",
+        "kv_export": "KV import/export ships pages, not convolution state",
+        "sessions": "a session keeps keys and values between turns, not "
+                    "the convolution state",
+        "padded_generate": "generate_text pads rows of unlike length, and "
+                           "the convolution state would be taken at the "
+                           "padded end; serve through continuous_batcher",
+    }
+    if asked.pop("paged_pages", 1) is None:
+        raise ValueError(
+            f"{cfg.family} model: the batcher serves its keys and values "
+            "from the page pool only; pass paged_pages"
+        )
+    for name, value in asked.items():
+        if value:
+            raise ValueError(
+                f"{name} is not supported for a model with convolution "
+                f"state (family {cfg.family!r}): {why[name]}"
+            )
+
+
 class ContinuousBatcher:
     """Slot-based continuous batching — single-device, or GSPMD dp/tp mesh
     when built with ``parallel=`` (see module docstring).
@@ -2053,6 +2155,24 @@ class ContinuousBatcher:
         # the reference (not a call) below is the single default-wiring
         # point.
         self._clock = clock if clock is not None else time.perf_counter
+        refuse_unpaged_state(
+            cfg, paged_pages=paged_pages, prefix_cache=prefix_cache,
+            kv_bits=kv_bits == 8, host_pages=host_pages,
+            speculative=draft_params is not None,
+            prefill_chunk=prefill_chunk, token_budget=token_budget,
+            mesh=parallel is not None,
+        )
+        if cfg.num_experts and cfg.moe_capacity:
+            if parallel is None:
+                # A served model never drops: under the capacity rule a
+                # row's logits would depend on what its batch-mates chose.
+                cfg = dataclasses.replace(cfg, moe_capacity=False)
+            else:
+                log.warning(
+                    "expert layers keep their capacity rule on a mesh (the "
+                    "expert-parallel path has no other): a row's output "
+                    "may depend on its batch-mates"
+                )
         if max_len > cfg.max_seq_len:
             raise ValueError(
                 f"max_len {max_len} exceeds model max_seq_len {cfg.max_seq_len}"
@@ -2229,8 +2349,6 @@ class ContinuousBatcher:
         # different op from the masked dot path (the exact-token invariant
         # is against the latter); that choice goes on the dispatch record
         # like any other fallback.
-        import dataclasses
-
         from ..ops import decode_attn, dispatch
 
         # (Sliding-window models ride the ragged kernel too: it takes the
@@ -2272,8 +2390,6 @@ class ContinuousBatcher:
             if kv_dtype is not None:
                 want = jnp.dtype(kv_dtype).name
                 if parallel.kv_dtype is None:
-                    import dataclasses
-
                     parallel = self.pm = dataclasses.replace(
                         parallel, kv_dtype=want
                     )
@@ -2313,8 +2429,11 @@ class ContinuousBatcher:
             self.cache = _paged_pool(
                 cfg, paged_pages, page_size,
                 dtype=jnp.dtype(kv_dtype) if kv_dtype else None,
-                kv_bits=kv_bits,
+                kv_bits=kv_bits, slots=batch_slots,
             )
+            if isinstance(self.cache, HybridCache):
+                METRICS.set_gauge("batcher.conv_state_bytes",
+                                  float(self.cache.conv.nbytes))
         else:
             self.cache = model_lib.init_cache(
                 cfg, batch_slots, cache_len,
@@ -2459,6 +2578,7 @@ class ContinuousBatcher:
         """Prefill a shared prefix (e.g. a system prompt) ONCE; requests
         submitted with ``prefix=name`` reuse its KV instead of recomputing
         it — admission then prefills only the request's suffix."""
+        refuse_unpaged_state(self.cfg, named_prefix=True)
         ids = (
             self.tokenizer.encode(prefix)
             if isinstance(prefix, str)
@@ -2605,6 +2725,7 @@ class ContinuousBatcher:
         matcher caps hits the same way, so shipping the last partial page
         would be dead weight.  Pages are retained across the gather so
         pool pressure cannot reclaim them mid-export."""
+        refuse_unpaged_state(self.cfg, kv_export=True)
         pc = self.prefix_cache
         if self.pool is None or pc is None:
             return None
@@ -2642,6 +2763,7 @@ class ContinuousBatcher:
         The engine thread applies it at its next round boundary and calls
         ``on_done(ok, reason)`` from there; the caller is responsible for
         waking the engine."""
+        refuse_unpaged_state(self.cfg, kv_import=True)
         with self._lock:
             self._kv_imports.append((digests, k_pages, v_pages, on_done))
 
@@ -2674,6 +2796,7 @@ class ContinuousBatcher:
         round boundary and calls ``on_done(payload_or_None)`` from there
         (the :meth:`export_prefix_pages` result); the caller is
         responsible for waking the engine."""
+        refuse_unpaged_state(self.cfg, kv_export=True)
         with self._lock:
             self._kv_exports.append((list(ids), on_done))
 
@@ -3722,6 +3845,7 @@ class ContinuousBatcher:
                     bucket=_bucket(len(req.ids) - cached_len),
                 ):
                     self._unqueue(req)
+                    self._note_unmatched(req, pfx)
                     # Bucket for compile reuse, but never past what fits after the
                     # prefix: forward's contract is cache_index + T <= max_len, and
                     # dynamic_update_slice CLAMPS an overflowing start — the suffix
@@ -3781,11 +3905,14 @@ class ContinuousBatcher:
                         )
                         row_valid = np.arange(self.valid.shape[1]) < total_len
                     elif self.paged:
-                        self.cache, tok, lp = admit_row_paged(
+                        if self.cfg.family == "hybrid":
+                            extra["slot"] = jnp.int32(i)
+                        self.cache, tok, lp, *moe = admit_row_paged(
                             self.params, self.cfg, self.cache, jnp.asarray(page_list),
                             jnp.asarray(prompt), jnp.int32(len(req.ids)),
                             self._split_rng(), pm=self.pm, **self.sampling, **extra,
                         )
+                        self._note_moe(*moe)
                         row_valid = np.arange(self.valid.shape[1]) < total_len
                     elif pfx is not None:
                         self.cache, tok, row_valid, lp = admit_row_with_prefix(
@@ -3923,6 +4050,7 @@ class ContinuousBatcher:
         cached_len = 0
         digests: list[bytes] = []
         pc = self.prefix_cache
+        self._note_unmatched(req, pfx)
         if pfx is not None:
             row_k, row_v, done = jnp.copy(pfx.k), jnp.copy(pfx.v), len(pfx.ids)
             total_len = done + len(req.ids)
@@ -4362,9 +4490,10 @@ class ContinuousBatcher:
         scheduling carry (last_tok, real_lens, valid, active, budget):
         host mirrors for the first chunk of a span, the PREVIOUS chunk's
         device-resident outputs for a dispatched-ahead chunk — both feed
-        the same compiled program.  Returns (toks, lps, m, carry') with
-        ``m`` the speculative per-row commit counts (None on the plain
-        path); ``self.cache``/``self.draft_cache``/``self.tok_counts``
+        the same compiled program.  Returns (toks, lps, m, carry', moe)
+        with ``m`` the speculative per-row commit counts (None on the plain
+        path) and ``moe`` a hybrid model's expert counts of the chunk (None
+        for any other model); ``self.cache``/``self.draft_cache``/``self.tok_counts``
         advance to the new chunk's (not-yet-materialized) outputs."""
         with self._span("batcher.loop.dispatch"):
             if self._tables_dirty:
@@ -4374,7 +4503,7 @@ class ContinuousBatcher:
                 self._tables_dirty = False
             last_tok, real_lens, valid, active, budget = carry
             self.overlap_stats["chunks"] += 1
-            m = None
+            m = moe = None
             dfa_out = None
             if self.speculative:
                 per_spec = dict(plan["per_spec"])
@@ -4468,7 +4597,7 @@ class ContinuousBatcher:
                         # a mixed-schedule dispatch with no prefill riding.
                         self.faults.fire("batcher.mixed_step", tag="decode")
                     (toks, self.cache, last_tok, real_lens, valid, active,
-                     budget, lps, counts_out, dfa_out) = \
+                     budget, lps, counts_out, dfa_out, *moe) = \
                         decode_chunk(
                             self.params, self.cfg_decode, self.cache, last_tok,
                             real_lens, valid, active, budget,
@@ -4477,11 +4606,15 @@ class ContinuousBatcher:
                             tables=plan["tables"],
                             **self.sampling, **per_row,
                         )
+                    # A hybrid model's expert counts: fetched with the
+                    # chunk's tokens, added at delivery (_note_moe).
+                    moe = moe[0] if moe else None
             if counts_out is not None:
                 self.tok_counts = counts_out
             if dfa_out is not None:
                 self._dfa_carry = dfa_out
-            return toks, lps, m, (last_tok, real_lens, valid, active, budget)
+            return (toks, lps, m, (last_tok, real_lens, valid, active, budget),
+                    moe)
 
     def _mixed_width(self, done: int) -> int:
         """Prefill-leg width of a fused step: ONE bucket sized to the
@@ -4714,19 +4847,41 @@ class ContinuousBatcher:
             METRICS.inc("batcher.pages_grown", need)
         return True
 
+    def _note_unmatched(self, req: _Request, pfx) -> None:
+        """A prompt that no prefix-cache lookup covers (a batcher without
+        the cache, a request that opted out, a named prefix) is prefilled
+        fresh, every token of it.  Counted under the name the lookups count
+        their misses under (:meth:`PrefixCache.record_lookup`), so that
+        ``batcher.prefix_cache.miss_tokens`` is the prompt tokens prefilled
+        by every admission, with or without the cache: real tokens, no
+        bucket padding."""
+        if self.prefix_cache is None or pfx is not None or not req.prefix_cache:
+            METRICS.inc("batcher.prefix_cache.miss_tokens", len(req.ids))
+
+    def _note_moe(self, stats=None) -> None:
+        """Add a program's expert counts (layers.moe_dropless, real tokens
+        only) to ``moe.*``: what a hybrid model's admission or decode chunk
+        handed out beside its tokens, None for any other model."""
+        if stats is None:
+            return
+        pairs, passes, touched, fullest = (int(x) for x in np.asarray(stats))
+        METRICS.inc("moe.routed_pairs", pairs)
+        METRICS.inc("moe.layer_passes", passes)
+        METRICS.inc("moe.experts_touched", touched)
+        METRICS.inc("moe.max_load_tokens", fullest)
+
     def _fetch_chunk(self, out: tuple) -> tuple:
         """Host work's D2H for a dispatched-ahead chunk: tokens, logprobs,
         speculative commit counts, and the post-chunk activity vector in
         ONE ``jax.device_get`` (blocks until the chunk completes — the
         NEXT chunk is already executing behind it).  The rest of the
         carry stays device-resident."""
-        toks, lps, m, carry = out
-        extras = () if m is None else (m,)
+        toks, lps, m, carry, moe = out
         with self._span("batcher.loop.wait_device"):
-            got = jax.device_get((toks, lps) + extras + (carry[3],))
+            toks_h, lps_h, m_h, moe_h, active_h = jax.device_get(
+                (toks, lps, m, moe, carry[3]))
         self._t_complete = time.perf_counter()
-        toks_h, lps_h, *rest = got
-        return toks_h, lps_h, (rest[0] if m is not None else None), rest[-1]
+        return toks_h, lps_h, m_h, moe_h, active_h
 
     def _sync_carry(self, out: tuple) -> tuple:
         """Refresh the host scheduling mirrors from the chunk's outputs —
@@ -4737,14 +4892,11 @@ class ContinuousBatcher:
         host bookkeeping dropped the row while the carry was device-
         resident (cancel mid-span) are forced inactive — the device's
         activity bit for them is stale by construction."""
-        toks, lps, m, carry = out
-        extras = () if m is None else (m,)
+        toks, lps, m, carry, moe = out
         with self._span("batcher.loop.wait_device"):
-            got = jax.device_get((toks, lps) + extras + carry)
+            toks_h, lps_h, m_h, moe_h, (lt, rl, va, ac, bu) = jax.device_get(
+                (toks, lps, m, moe, carry))
         self._t_complete = time.perf_counter()
-        toks_h, lps_h, *rest = got
-        m_h = rest[0] if m is not None else None
-        lt, rl, va, ac, bu = rest[-5:]
         self.last_tok = _writable(lt)
         self.real_lens = _writable(rl)
         self.valid = _writable(va)
@@ -4755,7 +4907,7 @@ class ContinuousBatcher:
                 self.active[i] = False
                 self.budget[i] = 0
         self._cancel_dirty = False
-        return toks_h, lps_h, m_h
+        return toks_h, lps_h, m_h, moe_h
 
     def _prehash_queued(self) -> None:
         """Overlapped host window: memoize page digests for requests that
@@ -4829,8 +4981,9 @@ class ContinuousBatcher:
             METRICS.set_gauge("batcher.overlap.depth", 1)
             # Chunk N's host work, concurrent with chunk N+1 on device.
             host_t0 = time.perf_counter()
-            toks, lps, m, active_after = self._fetch_chunk(out)
+            toks, lps, m, moe, active_after = self._fetch_chunk(out)
             with self._span("batcher.loop.deliver"):
+                self._note_moe(moe)
                 if self.speculative:
                     self._spec_note(m, was_active, plan)
                 if not active_after.any():
@@ -4854,8 +5007,9 @@ class ContinuousBatcher:
         # Sync exit: mirrors refresh BEFORE _collect, so a cancel taken
         # inside the delivery callbacks lands on fresh state (the
         # synchronous loop's exact ordering).
-        toks, lps, m = self._sync_carry(out)
+        toks, lps, m, moe = self._sync_carry(out)
         with self._span("batcher.loop.deliver"):
+            self._note_moe(moe)
             if self.speculative:
                 self._spec_note(m, was_active, plan)
             METRICS.set_gauge("batcher.overlap.depth", 0)

@@ -93,6 +93,7 @@ class InferenceEngine:
         self.rt = rt
         self.parallel = parallel
         self.tokenizer = tokenizer or get_tokenizer(None)
+        self._refuse(mesh=parallel is not None, speculative=rt.spec_decode)
         # Out-of-vocab ids silently become NaN embeddings (jnp.take fills
         # OOB gathers) — reject the mismatch loudly instead.
         tok_vocab = getattr(self.tokenizer, "vocab_size", None)
@@ -158,6 +159,13 @@ class InferenceEngine:
         self.sessions = SessionManager(
             max_resident=rt.max_resident_sessions if rt.kv_host_spill else (1 << 30)
         )
+
+    def _refuse(self, **asked) -> None:
+        """Refuse by name what a model with state beside its keys and
+        values cannot be served with (batcher.refuse_unpaged_state)."""
+        from .batcher import refuse_unpaged_state
+
+        refuse_unpaged_state(self.cfg, **asked)
 
     @classmethod
     def from_preset(
@@ -272,6 +280,7 @@ class InferenceEngine:
     def generate_text(
         self, prompts: list[str], max_new_tokens: int | None = None, seed: int | None = None
     ) -> GenerationResult:
+        self._refuse(padded_generate=True)
         tok = self.tokenizer
         prompt_arr, lens, n_real = self._encode_rows(prompts, batch=None)
         n_new = self.rt.max_decode_steps if max_new_tokens is None else max_new_tokens
@@ -427,6 +436,7 @@ class InferenceEngine:
     ) -> tuple[str, GenerationResult]:
         """Open a session: prefill + decode, keeping the KV cache for
         continuation turns.  Returns (session_id, result)."""
+        self._refuse(sessions=True)
         n_new = self.rt.max_decode_steps if max_new_tokens is None else max_new_tokens
         max_len = self._session_max_len()
         chunk, lens, n_real = self._encode_rows(prompts, batch=None)

@@ -72,6 +72,67 @@ def test_moe_capacity_drops_tokens_to_zero():
     zero_rows = int(jnp.sum(jnp.all(out[0] == 0.0, axis=-1)))
     assert zero_rows >= 14  # 16 tokens, 2 experts x 1 slot
 
+def test_dropless_layer_matches_per_token_reference():
+    """``moe_capacity=False`` on the llama family's leaves: every token
+    gets its k experts, at a capacity factor that would drop nearly all."""
+    cfg = ModelConfig(
+        family="llama", num_experts=4, num_experts_per_token=2,
+        moe_capacity_factor=0.01, moe_capacity=False,
+    )
+    d, e, f = 16, 4, 32
+    p = _moe_params(jax.random.key(0), d, e, f)
+    x = jax.random.normal(jax.random.key(1), (2, 5, d), jnp.float32)
+    out = layers.moe_dropless_layer(x, p, cfg)
+    np.testing.assert_allclose(
+        np.asarray(out), _reference_moe(x, p, 2), atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("capacity", [False, True])
+def test_moe_capacity_selects_the_expert_layer_of_a_model(capacity):
+    """The field selects, in the model: without the capacity rule a row's
+    logits are those of its solo forward whatever rows share the batch;
+    with it, at a capacity that holds one token an expert, they are not."""
+    cfg = dataclasses.replace(
+        get_preset("moe-tiny"), dtype="float32", moe_capacity=capacity,
+        moe_capacity_factor=0.01)
+    params = model_lib.init_params(jax.random.key(0), cfg)
+    toks = jax.random.randint(
+        jax.random.key(1), (3, 9), 0, cfg.vocab_size, dtype=jnp.int32)
+    batch, _ = model_lib.forward(params, cfg, toks)
+    solo, _ = model_lib.forward(params, cfg, toks[1:2])
+    same = np.allclose(np.asarray(batch[1]), np.asarray(solo[0]), atol=1e-4)
+    assert same == (not capacity)
+
+
+def test_a_served_model_never_drops():
+    """The batcher takes a model's capacity rule away: moe-tiny at a
+    capacity of one token an expert gives each request its solo stream
+    whatever rows share the batch."""
+    from distributed_llms_tpu.runtime.batcher import ContinuousBatcher
+
+    cfg = dataclasses.replace(
+        get_preset("moe-tiny"), dtype="float32", moe_capacity_factor=0.01)
+    params = model_lib.init_params(jax.random.key(0), cfg)
+    rs = np.random.RandomState(0)
+    jobs = [[int(x) for x in rs.randint(0, 256, n)] for n in (5, 9, 12)]
+
+    def serve(prompts):
+        b = ContinuousBatcher(cfg, params, batch_slots=4, max_len=64,
+                              chunk_steps=4, paged_pages=24, page_size=8)
+        assert not b.cfg.moe_capacity
+        rids = [b.submit(ids, max_new_tokens=6) for ids in prompts]
+        out = b.run()
+        return [out[r] for r in rids]
+
+    together = serve(jobs)
+    assert together == [serve([ids])[0] for ids in jobs]
+
+
+def test_the_hybrid_family_has_no_capacity_path():
+    with pytest.raises(ValueError, match="moe_capacity=False"):
+        dataclasses.replace(get_preset("lfm2-tiny"), moe_capacity=True)
+
+
 def test_moe_model_forward_and_grad():
     cfg = get_preset("moe-tiny")
     params = model_lib.init_params(jax.random.key(0), cfg)

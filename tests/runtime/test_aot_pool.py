@@ -61,3 +61,46 @@ def test_temporaries_hold_no_second_pool(compiled):
     assert compiled["temp_gb"] * 1e9 < POOL_BYTES / 2 + 120e6
     # The donated pool is written in place: the output aliases it whole.
     assert compiled["alias_gb"] * 1e9 >= POOL_BYTES
+
+
+@pytest.fixture(scope="module")
+def compiled_hybrid():
+    """``decode_chunk`` of lfm2-8b-a1b at full depth and the cell's shapes
+    (three scanned runs: some 10 s to lower and compile)."""
+    from distributed_llms_tpu.models.presets import get_preset
+    from tools import aot_decode
+
+    try:
+        aot_decode.v5e_devices()
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DLT_QUANT_MATMUL", "kernel")
+        mp.setenv("DLT_RAGGED_DECODE", "kernel")
+        mp.setenv("DLT_MOE_EXPERTS", "kernel")
+        return aot_decode.analyse(
+            "decode_chunk", get_preset("lfm2-8b-a1b"), slots=16,
+            max_len=4096, pages=PAGES, page_size=BLK,
+        )
+
+
+def test_hybrid_pool_of_six_layers_is_written_in_place(compiled_hybrid):
+    """PR 26's property for a pool that counts the 6 attention layers and
+    keeps heads of 64 two to a row: nothing but in-place scatters produces
+    a [6,512,64,4,128] array or a layer of it."""
+    found = compiled_hybrid["pool_shaped"]
+    assert found and {e[0] for e in found} == {"scatter", "fusion:scatter"}
+    assert all("[6,512,64,4,128]" in e[2] for e in found), found
+    pool_bytes = 2 * 6 * PAGES * BLK * 8 * 64 * 2
+    assert compiled_hybrid["alias_gb"] * 1e9 >= pool_bytes
+    assert compiled_hybrid["temp_gb"] < 0.3
+
+
+def test_no_expert_stack_is_copied_or_dequantized(compiled_hybrid):
+    """No instruction produces an array shaped like the expert stacks
+    ([22,32,2048,3584], [22,32,1792,2048]), like one layer's, or like one
+    expert's [2048,3584], at any dtype: the stacks go into the kernel
+    whole and are read there a tile at a time."""
+    assert compiled_hybrid["expert_shaped"] == []
+    # Weights (8.73 GB) and the pool (0.40 GB) are all the program is given.
+    assert 9.0 < compiled_hybrid["argument_gb"] < 9.3

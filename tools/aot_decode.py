@@ -90,7 +90,7 @@ def lower_program(
     devices = v5e_devices(mesh_model)
     key_shape = jax.ShapeDtypeStruct((2,), jnp.uint32)
     pool = lambda: batcher_lib._paged_pool(  # noqa: E731
-        cfg, pages, page_size, kv_bits=kv_bits)
+        cfg, pages, page_size, kv_bits=kv_bits, slots=slots)
     if mesh_model > 1:
         from distributed_llms_tpu.core.config import MeshConfig
         from distributed_llms_tpu.parallel import specs as specs_lib
@@ -130,9 +130,12 @@ def lower_program(
             **on_mesh,
         )
     if program == "admit_row_paged":
+        # A hybrid model's admission is told the batch slot its
+        # convolution state goes to.
+        slot = {"slot": arr(())} if cfg.family == "hybrid" else {}
         return batcher_lib.admit_row_paged.lower(
             params, cfg, cache, arr((p,)), arr((prompt_len,)), arr(()), key,
-            **on_mesh,
+            **on_mesh, **slot,
         )
     if program == "admit_row_auto_paged":
         return batcher_lib.admit_row_auto_paged.lower(
@@ -167,13 +170,40 @@ def pool_shaped(hlo_text: str, cfg, pages: int, page_size: int,
     are not instructions that move data and are left out.  ``shards`` is
     the size of ``mesh.model``: a device's program holds that share of the
     KV heads."""
-    layer = (f"{pages},{page_size},{cfg.num_kv_heads // shards},"
-             f"{cfg.head_dim_}")
-    stack = f"{cfg.num_layers},{layer}"
+    from distributed_llms_tpu.ops.decode_attn import pool_head_shape
+    from distributed_llms_tpu.runtime.batcher import pages_are_private
+
+    kvh, hd = pool_head_shape(cfg.num_kv_heads // shards, cfg.head_dim_,
+                              fold_narrow=pages_are_private(cfg))
+    layer = f"{pages},{page_size},{kvh},{hd}"
+    stack = f"{len(cfg.attn_layers)},{layer}"
     shapes = [f"[{s}]" for s in (layer, stack)]
     shapes += [f"[{s.rsplit(',', 1)[0]}]" for s in (layer, stack)]
     if cfg.num_kv_heads == shards:  # one head a device: the axis may go
         shapes += [s.replace(",1,", ",") for s in shapes[:2]]
+    return shaped_like(hlo_text, shapes)
+
+
+def expert_shaped(hlo_text: str, cfg) -> list:
+    """Every instruction whose result is shaped like a layer's expert
+    stack or like one expert's weights, at any dtype: an expert stack
+    dequantized or copied whole would be on this list.  Empty for a model
+    without experts."""
+    if not cfg.num_experts or cfg.moe_capacity:
+        return []
+    d, f, e = cfg.hidden_size, cfg.expert_size, cfg.num_experts
+    shapes = []
+    for one in (f"{d},{2 * f}", f"{f},{d}"):
+        shapes += [f"[{e},{one}]",
+                   f"[{cfg.num_layers - cfg.num_dense_layers},{e},{one}]"]
+    # (one expert's [D, 2F] too; its [F, D] is left out: the dense FFN's
+    # w_down, prefetched a quarter at a time, has that shape in LFM2)
+    return shaped_like(hlo_text, shapes + [f"[{d},{2 * f}]"])
+
+
+def shaped_like(hlo_text: str, shapes: list) -> list:
+    """(opcode, name, result type) of every instruction of the optimised
+    HLO whose result type holds one of ``shapes`` (``"[a,b,c]"``)."""
     skip = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
             "conditional", "call", "opt-barrier"}
     roots, computation = {}, None
@@ -213,6 +243,7 @@ def analyse(program: str, cfg, **shape_kw) -> dict:
                         shape_kw.get("page_size", 64),
                         shape_kw.get("mesh_model", 1))
     return {
+        "expert_shaped": [list(e) for e in expert_shaped(text, cfg)],
         "program": program,
         "argument_gb": mem.argument_size_in_bytes / 1e9,
         "output_gb": mem.output_size_in_bytes / 1e9,
@@ -238,7 +269,10 @@ def main() -> int:
     ap.add_argument("--prompt-len", type=int, default=512)
     ap.add_argument("--mesh-model", type=int, default=1,
                     help="compile for this many chips under mesh.model")
-    ap.add_argument("--programs", default=",".join(PROGRAMS))
+    ap.add_argument("--programs", default=None,
+                    help="default: all four; the two without a prefix "
+                         "cache or a chunked prefill for a hybrid model, "
+                         "which refuses both")
     ap.add_argument("--hlo-dir", default=None,
                     help="write each program's optimised HLO here")
     a = ap.parse_args()
@@ -250,7 +284,9 @@ def main() -> int:
     cfg = get_preset(a.preset)
     if a.layers:
         cfg = dataclasses.replace(cfg, num_layers=a.layers)
-    for program in a.programs.split(","):
+    programs = a.programs or ",".join(
+        PROGRAMS[:2] if cfg.family == "hybrid" else PROGRAMS)
+    for program in programs.split(","):
         try:
             r = analyse(
                 program, cfg, slots=a.slots, max_len=a.max_len,
@@ -283,4 +319,5 @@ if __name__ == "__main__":
     # fallbacks; the compiled kernels are what the chip runs.
     os.environ.setdefault("DLT_QUANT_MATMUL", "kernel")
     os.environ.setdefault("DLT_RAGGED_DECODE", "kernel")
+    os.environ.setdefault("DLT_MOE_EXPERTS", "kernel")
     sys.exit(main())

@@ -1,10 +1,10 @@
 #!/usr/bin/env python
 """On-device kernel parity check: the first thing to run on a new chip.
 
-Compiles the four Pallas kernels (fused dequant-matmul, flash attention,
-ragged and paged decode attention) on the default JAX backend and compares
-against the einsum/dense references.  Interpret-mode CI (tests/ops/) proves
-the kernels' *programs*; this script proves Mosaic *lowering* — tiling, VMEM
+Compiles the five Pallas kernels (fused dequant-matmul, flash attention,
+ragged and paged decode attention, the grouped expert matmul) on the
+default JAX backend and compares against the einsum/dense references.
+Interpret-mode CI (tests/ops/) proves the kernels' *programs*; this script proves Mosaic *lowering* — tiling, VMEM
 budgets, sublane int4 unpack — which interpret mode cannot catch.  Exit 0 =
 all parities hold compiled on this backend; exit 1 = mismatch, lowering
 failure (stack trace printed), or a leg that did not take the path it was
@@ -31,13 +31,15 @@ ON_TPU = jax.default_backend() == "tpu"
 MODE = "kernel" if ON_TPU else "interpret"
 os.environ["DLT_QUANT_MATMUL"] = MODE
 os.environ["DLT_RAGGED_DECODE"] = MODE
+os.environ["DLT_MOE_EXPERTS"] = MODE
 
 import jax.numpy as jnp
 import numpy as np
 
-from distributed_llms_tpu.checkpoint.quantize import dequantize, quantize
+from distributed_llms_tpu.checkpoint.quantize import (
+    QuantizedTensor, dequantize, quantize)
 from distributed_llms_tpu.core.observability import METRICS
-from distributed_llms_tpu.ops import decode_attn
+from distributed_llms_tpu.ops import decode_attn, moe_experts
 from distributed_llms_tpu.ops.flash import _dense_reference, flash_attention
 from distributed_llms_tpu.ops.quant_matmul import quant_contract
 
@@ -50,6 +52,8 @@ SERVED_MHA = dict(blk=64, h=32, kvh=32)
 # One device's share of qwen2-7b under mesh.model=4: a single KV head,
 # which the paged kernel reads out of a pool without its head axis.
 SERVED_TP4 = dict(blk=64, h=7, kvh=1)
+# lfm2-8b-a1b's: GQA 32/8 at head dim 64, two heads to a 128-lane pool row.
+SERVED_H64 = dict(blk=64, h=32, kvh=8, d=64)
 
 
 def _stacked(pool, layer, fill):
@@ -94,7 +98,9 @@ def quant_parity() -> None:
 
 def flash_parity() -> None:
     key = jax.random.PRNGKey(1)
-    for b, t, s, h, kvh, d in ((2, 512, 512, 8, 4, 128), (1, 2048, 2048, 8, 8, 128)):
+    for b, t, s, h, kvh, d in ((2, 512, 512, 8, 4, 128),
+                               (1, 2048, 2048, 8, 8, 128),
+                               (1, 2048, 2048, 32, 8, 64)):  # lfm2's heads
         ks = jax.random.split(jax.random.fold_in(key, t), 3)
         q = jax.random.normal(ks[0], (b, t, h, d), jnp.bfloat16)
         kk = jax.random.normal(ks[1], (b, s, kvh, d), jnp.bfloat16)
@@ -104,7 +110,7 @@ def flash_parity() -> None:
                                             interpret=not ON_TPU)
         )(q, kk, v)
         want = _dense_reference(q, kk, v, None, None, None, True)
-        check(f"flash causal B{b} T{t} S{s} H{h}/{kvh}", got, want,
+        check(f"flash causal B{b} T{t} S{s} H{h}/{kvh} D{d}", got, want,
               rtol=3e-2, atol=3e-2)
     # Sliding-window band (Mistral/Phi-3 prefill): dead-tile clamping +
     # boundary iota masks on both edges must survive Mosaic lowering.
@@ -122,9 +128,9 @@ def flash_parity() -> None:
 
 
 def paged_parity(blk: int = 128, h: int = 8, kvh: int = 4,
-                 layer: int | None = None) -> None:
+                 layer: int | None = None, d: int = 128) -> None:
     key = jax.random.PRNGKey(3)
-    b, pool, pages, d = 4, 48, 8, 128
+    b, pool, pages = 4, 48, 8
     rng = np.random.RandomState(0)
     tables = jnp.asarray(
         rng.permutation(pool)[: b * pages].reshape(b, pages), jnp.int32
@@ -139,6 +145,10 @@ def paged_parity(blk: int = 128, h: int = 8, kvh: int = 4,
     v_pool = jnp.zeros((pool, blk, kvh, d), jnp.bfloat16).at[
         tables.reshape(-1)
     ].set(v_rows.reshape(b * pages, blk, kvh, d))
+    # Heads narrower than a row lie folded in the pool, as the batcher of
+    # a hybrid model keeps them (decode_attn.pool_head_shape).
+    fold = decode_attn.pool_head_shape(kvh, d, fold_narrow=True)
+    k_pool, v_pool = (x.reshape(pool, blk, *fold) for x in (k_pool, v_pool))
     ln = jnp.asarray([1, 2 * blk + 44, pages * blk, blk + 1], jnp.int32)
     got = jax.jit(decode_attn.paged_decode_attention)(
         q, _stacked(k_pool, layer, 3.0), _stacked(v_pool, layer, -3.0), ln,
@@ -146,8 +156,55 @@ def paged_parity(blk: int = 128, h: int = 8, kvh: int = 4,
     )
     want = decode_attn._dense_reference(q, k_rows, v_rows, ln)
     form = "" if layer is None else f" L3[{layer}]"
-    check(f"paged decode B{b} pool{pool} blk{blk} H{h}/{kvh}{form}", got,
+    check(f"paged decode B{b} pool{pool} blk{blk} H{h}/{kvh} D{d}{form}", got,
           want, rtol=3e-2, atol=3e-2)
+
+
+def moe_parity() -> None:
+    """The expert kernel at lfm2-8b-a1b's widths (32 experts of 2048 x
+    1792, int8 with blocks along the contracted axis), layer 1 of a
+    2-layer stack: a decode step's 64 pairs (tiles of 16 rows, most
+    experts one tile, some none) and an admission's 2,048 (tiles of 128)."""
+    e, d, f, k = 32, 2048, 1792, 4
+    key = jax.random.PRNGKey(5)
+    key13, key2 = jax.random.split(key)
+
+    def stack(key, kd, n):
+        """[2, E, kd, n] drawn and quantized an expert at a time."""
+        def one(i):
+            w = jax.random.normal(jax.random.fold_in(key, i), (kd, n),
+                                  jnp.float32) * kd ** -0.5
+            qt = quantize(w, block_axis=-2)
+            return qt.data, qt.scale
+        data, scale = jax.lax.map(one, jnp.arange(2 * e))
+        return QuantizedTensor(
+            data=data.reshape(2, e, kd, n),
+            scale=scale.reshape(2, e, kd // 128, n), bits=8,
+            orig_shape=(2, e, kd, n), block_axis=-2)
+
+    w13 = jax.jit(lambda: stack(key13, d, 2 * f))()
+    w2 = jax.jit(lambda: stack(key2, f, d))()
+    for s in (16, 512):
+        kx, kt = jax.random.split(jax.random.fold_in(key, s))
+        x = jax.random.normal(kx, (s, d), jnp.bfloat16)
+        topi = jax.random.randint(kt, (s, k), 0, e, jnp.int32)
+        got = jax.jit(lambda x, t, a, b: moe_experts.grouped_swiglu(
+            x, t, a, b, 1))(x, topi, w13, w2)
+
+        def want_of(x, topi, w13, w2):
+            # Every expert for every token, the chosen ones picked out.
+            xf = x.astype(jnp.float32)
+            d13 = dequantize(jax.tree.map(lambda a: a[1], w13), jnp.float32)
+            d2 = dequantize(jax.tree.map(lambda a: a[1], w2), jnp.float32)
+            g = jnp.einsum("sd,edf->sef", xf, d13)
+            y = jnp.einsum("sef,efd->sed",
+                           jax.nn.silu(g[..., :f]) * g[..., f:], d2)
+            return jnp.take_along_axis(y, topi[:, :, None], axis=1)
+
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(want_of)(x, topi, w13, w2)
+        check(f"moe experts S{s} k{k} E{e} [{d}x{2 * f}] [{f}x{d}]", got,
+              want, rtol=3e-2, atol=3e-2)
 
 
 def ragged_parity() -> None:
@@ -263,6 +320,8 @@ def main() -> int:
     paged_int8_parity(**SERVED_MHA, layer=0)
     paged_parity(**SERVED_TP4, layer=1)
     paged_int8_parity(**SERVED_TP4, layer=1)
+    paged_parity(**SERVED_H64, layer=2)
+    moe_parity()
     # No leg may pass on another path than the one asked for: the dispatch
     # record (ops/dispatch.py) counts every trace by the path it took.
     took = {k[len("ops.dispatch."):]: int(v)
@@ -275,8 +334,10 @@ def main() -> int:
     mode = "compiled" if ON_TPU else "interpret"
     # v5: the paged legs (bf16 and int8) also run at the page size and
     # head geometries the benchmark serves, as one layer's pages and as a
-    # layer of the stacked pool — 21 legs.
-    print(f"kernel_parity: ALL PASS v5 ({mode}, backend={backend})")
+    # layer of the stacked pool — 21 legs.  v6: flash and the paged kernel at
+    # a head of 64, and the expert kernel at a decode step's and an
+    # admission's pairs — 25 legs.
+    print(f"kernel_parity: ALL PASS v6 ({mode}, backend={backend})")
     return 0
 
 

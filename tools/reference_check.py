@@ -1,0 +1,399 @@
+#!/usr/bin/env python
+"""Hold the served path of a hybrid model to its plain reference, in logits.
+
+    python tools/reference_check.py --config lfm2-8b-a1b-int8     # the chip
+    JAX_PLATFORMS=cpu python tools/reference_check.py --rehearsal # lfm2-tiny
+
+For each of the benchmark's four probe prompts the tool takes the logits
+the SERVED path produces, admission at the prompt's own bucket and then 8
+decode steps through the page pool and the convolution state, in a batch
+slot of a pool shaped as the cell serves it, and compares them with the
+reference's full forward (models/reference/lfm2_moe.py: float32 at
+``highest`` precision, no cache, no kernel) over the same tokens.  Both
+sides hold the same seed-0 int8 weights; the reference gets them
+dequantized a layer at a time and never holds more than one layer in
+float32.
+
+Two comparisons a probe, because a network of random weights amplifies a
+rounding of its activations sixty-fold (PERF.md, PR 28) and bfloat16 alone
+then moves the logits by a quarter of their spread:
+
+- ``mechanism``: the served path with float32 activations at ``highest``
+  precision (the same programs' code, weights, kernels, pages and state).
+  What is left is summation order, so the tolerance is tight, and a dropped
+  expert, a bias that weighs, a state taken at the bucket's end or a page
+  read from the wrong layer cannot pass it.
+- ``as_served``: bfloat16 activations, as the cell runs.  It says how far
+  the precision moves the logits, and its first-token logprob decides where
+  the benchmark's golden comes from.
+
+The served logits come from two small programs built from the functions
+the batcher's own programs are built from (``_prefill_row`` +
+``_paged_splice``; the forward call of ``_decode_steps``), because
+``admit_row_paged`` and ``decode_chunk`` hand out tokens and logprobs, not
+logits.  The batcher itself then serves the same probe, and its tokens and
+logprobs must be the ones those logits give: that ties what is compared
+here to the programs the benchmark times.
+
+Last, one control from the SERVED path: the expert stacks moved onto the
+int4 grid where they lie (the precision below the configuration's, still
+read by the int8 kernel) and the ``as_served`` leg run again on the first
+probe, fed the same tokens.  It has to land outside the ``as_served``
+tolerances, or they would pass a 4-bit expert leg in the timed programs.
+
+Tolerances (``TOL``), why, and what would break them are in PERF.md,
+section 6, PR 28.  Writes ``chiprun_out/reference_check/<config>.json``
+(every number) and ``.golden.json`` (the reference's logprobs of the served
+tokens, in the benchmark's golden format).  Exit 0 iff every probe is
+inside every tolerance.
+"""
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import os
+import sys
+import time
+from functools import partial
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from benchmark.run import PROBE_BYTES, PROBE_TOKENS, probe_prompt  # noqa: E402
+
+# Each limit lies between what the chip gave and what a wrong model gives
+# on the same probe (PERF.md, section 6, PR 28, has both readings).
+TOL = {
+    # float32 activations: only the order of summation differs.  The chip
+    # gave 7.7e-5 / 1.3e-5 at the most; three experts a token for four give
+    # 3.35 / 0.56, int4 weights 5.69 / 0.95.
+    "mechanism": {"max_abs_logit": 2e-3, "mean_abs_logit": 2e-4},
+    # bfloat16 activations through 24 layers that amplify: the chip gave
+    # 1.79 / 0.24 at the most over the four probes (the same to the last
+    # digit run after run), and 2.14 / 0.34 on probe 32 with the expert
+    # stacks on the int4 grid in the SERVED programs (the control at the
+    # end of main): each limit lies between its two readings.
+    "as_served": {"max_abs_logit": 1.96, "mean_abs_logit": 0.29},
+}
+GOLDEN_FROM_REFERENCE = 0.025  # half of benchmark/run.py GOLDEN_TOL
+
+
+def reference_cfg(cfg) -> dict:
+    return dict(
+        norm_eps=cfg.norm_eps, rope_theta=cfg.rope_theta,
+        layer_types=cfg.layer_types, num_dense_layers=cfg.num_dense_layers,
+        num_experts_per_token=cfg.num_experts_per_token,
+        norm_topk_prob=cfg.moe_norm_topk,
+        routed_scaling_factor=cfg.moe_routed_scale,
+    )
+
+
+@jax.jit
+def _round_to_int4(w):
+    """``w`` rounded to 4 bits with 128-wide absmax blocks along its last
+    axis (checkpoint.quantize's int4 grid), in one fused pass."""
+    n = w.shape[-1]
+    blocks = w.reshape(*w.shape[:-1], n // 128, 128) if n % 128 == 0 \
+        else w[..., None, :]
+    scale = jnp.max(jnp.abs(blocks), axis=-1, keepdims=True) / 7.0
+    scale = jnp.where(scale > 0, scale, 1.0)
+    return (jnp.clip(jnp.round(blocks / scale), -7, 7) * scale).reshape(w.shape)
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _int8_on_int4_grid(q):
+    """int8 block data moved onto the 15 levels of the int4 grid of the
+    same absmax blocks (a block's largest value is 127 on the one grid and
+    7 on the other), in place: what the stack would hold had it been
+    quantized to 4 bits, in the form the int8 kernel reads."""
+    levels = jnp.round(q.astype(jnp.float32) * (7.0 / 127.0))
+    return jnp.round(levels * (127.0 / 7.0)).astype(jnp.int8)
+
+
+def reference_logits(params, cfg, tokens, int4=False, **changed):
+    """The reference's logits [T, V]; the layers are dequantized as the
+    reference asks for them, one at a time.  ``int4`` rounds every block
+    weight to 4 bits on the way (the precision below the configuration's);
+    ``changed`` overrides keys of the reference's configuration."""
+    from distributed_llms_tpu.checkpoint import quantize as quant_lib
+    from distributed_llms_tpu.models import model as model_lib
+    from distributed_llms_tpu.models.reference import lfm2_moe
+
+    def floats(tree):
+        def one(x):
+            if not isinstance(x, quant_lib.QuantizedTensor):
+                return x
+            w = quant_lib.dequantize(x, jnp.float32)
+            return _round_to_int4(w) if int4 else w
+
+        return jax.tree.map(
+            one, tree,
+            is_leaf=lambda x: isinstance(x, quant_lib.QuantizedTensor))
+
+    lazy = {"embed": params["embed"], "final_norm": params["final_norm"],
+            "layers": (floats(p) for p in model_lib.hybrid_layers(params, cfg))}
+    return lfm2_moe.forward(lazy, {**reference_cfg(cfg), **changed},
+                            jnp.asarray(tokens, jnp.int32))
+
+
+def served_programs(cfg, cfg_decode):
+    from distributed_llms_tpu.models import model as model_lib
+    from distributed_llms_tpu.runtime import batcher as B
+
+    @partial(jax.jit, donate_argnums=(1,))
+    def admit(params, cache, page_list, prompt, plen, slot):
+        logits, row, _ = B._prefill_row(
+            model_lib.forward, params, cfg, B._row_dtype_of(cache),
+            page_list.shape[0] * cache.k.shape[2], prompt, plen)
+        cache, tok, lp = B._paged_splice(
+            cache, page_list, row, logits, plen, jax.random.key(0), 0.0, 0,
+            1.0, slot=slot)
+        return cache, logits[0, plen - 1], tok, lp
+
+    @partial(jax.jit, donate_argnums=(1,))
+    def step(params, cache, last_tok, real_lens, active, tables):
+        logits, cache = model_lib.forward(
+            params, cfg_decode, last_tok[:, None],
+            positions=real_lens[:, None], cache=cache, cache_index=real_lens,
+            kv_tables=tables, seq_lens=active.astype(jnp.int32))
+        return logits[:, 0], cache
+
+    return admit, step
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--config", default="lfm2-8b-a1b-int8")
+    ap.add_argument("--rehearsal", action="store_true",
+                    help="lfm2-tiny on the CPU at a tiny shape: the same "
+                         "code, no number that means anything")
+    ap.add_argument("--slot", type=int, default=3)
+    ap.add_argument("--probes", type=int, default=len(PROBE_BYTES),
+                    help="how many of the probes to run, from the first "
+                         "(the controls ride on the first; fewer than all "
+                         "of them writes no golden worth keeping)")
+    a = ap.parse_args()
+
+    from distributed_llms_tpu.models import model as model_lib
+    from distributed_llms_tpu.models.presets import get_preset
+    from distributed_llms_tpu.ops import decode_attn
+    from distributed_llms_tpu.runtime import batcher as B
+    from distributed_llms_tpu.runtime.shapes import bucket_length
+    from distributed_llms_tpu.runtime.tokenizer import get_tokenizer
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           a.config + ".json")) as f:
+        config = json.load(f)
+    serve = dict(config["serve"])
+    preset, probe_bytes = config["preset"], PROBE_BYTES
+    if a.rehearsal:
+        preset, probe_bytes = "lfm2-tiny", (5, 9, 33, 60)
+        serve.update(slots=4, max_len=128, page_size=8, paged_pages=40)
+    cfg = get_preset(preset)
+    tok = get_tokenizer(None)
+    if cfg.vocab_size < tok.vocab_size:  # as dlt-serve widens a tiny preset
+        cfg = dataclasses.replace(cfg, vocab_size=512)
+    t0 = time.time()
+    params = model_lib.init_params_quantized(jax.random.key(0), cfg, 8)
+    jax.block_until_ready(params)
+    dev = jax.devices()[0]
+    print(f"weights on {dev.device_kind} after {time.time() - t0:.1f} s",
+          flush=True)
+
+    slots, blk = serve["slots"], serve["page_size"]
+    ppr = serve["max_len"] // blk
+    kernels = decode_attn._mode() != "fallback"
+    batcher = B.ContinuousBatcher(
+        cfg, params, tok, batch_slots=slots, max_len=serve["max_len"],
+        chunk_steps=serve["chunk_steps"], paged_pages=serve["paged_pages"],
+        page_size=blk)
+
+    def served_logits(dtype, ids, force=None):
+        """[8, V] logits of the served path with ``dtype`` activations:
+        positions len(ids)-1 .. +7, each decode step fed the greedy token
+        (so both dtypes and the reference may see other tokens after the
+        first; the reference is run on each's own), or the tokens
+        ``force`` names."""
+        c = dataclasses.replace(cfg, dtype=dtype)
+        admit, step = served_programs(
+            c, dataclasses.replace(c, ragged_decode=kernels))
+        plen, bucket = len(ids), bucket_length(len(ids))
+        n_pages = -(-(plen + PROBE_TOKENS) // blk)
+        page_list = np.zeros((ppr,), np.int32)
+        page_list[:n_pages] = 1 + np.arange(n_pages)
+        prompt = np.zeros((bucket,), np.int32)
+        prompt[:plen] = ids
+        cache = B._paged_pool(c, serve["paged_pages"], blk, slots=slots)
+        cache, first, tok0, _ = admit(
+            params, cache, jnp.asarray(page_list), jnp.asarray(prompt),
+            jnp.int32(plen), jnp.int32(a.slot))
+        logits = [np.asarray(first, np.float32)]
+        toks = [int(tok0) if force is None else force[0]]
+        tables = np.zeros((slots, ppr), np.int32)
+        tables[a.slot] = page_list
+        active = np.zeros((slots,), bool)
+        active[a.slot] = True
+        last = np.zeros((slots,), np.int32)
+        lens = np.zeros((slots,), np.int32)
+        for j in range(PROBE_TOKENS - 1):
+            last[a.slot], lens[a.slot] = toks[-1], plen + j
+            lg, cache = step(params, cache, jnp.asarray(last),
+                             jnp.asarray(lens), jnp.asarray(active),
+                             jnp.asarray(tables))
+            logits.append(np.asarray(lg[a.slot], np.float32))
+            toks.append(int(np.argmax(logits[-1])) if force is None
+                        else force[j + 1])
+        return np.stack(logits), toks
+
+    def logprobs(logits, toks):
+        return [float(jax.nn.log_softmax(jnp.asarray(row))[t])
+                for row, t in zip(logits, toks)]
+
+    def against(served, ref):
+        d = np.abs(served - ref)
+        return {"max_abs_logit_diff": float(d.max()),
+                "mean_abs_logit_diff": float(d.mean())}
+
+    report = {"config": a.config, "preset": preset, "tolerances": TOL,
+              "device_kind": dev.device_kind, "probes": []}
+    ok = True
+    for n in probe_bytes[:a.probes]:
+        ids = list(tok.encode(probe_prompt(n)))
+        plen = len(ids)
+        row = {"bytes": n, "prompt_tokens": plen,
+               "bucket": bucket_length(plen)}
+        for leg, dtype in (("mechanism", "float32"), ("as_served", cfg.dtype)):
+            with jax.default_matmul_precision(
+                    "highest" if leg == "mechanism" else "default"):
+                served, toks = served_logits(dtype, ids)
+            t1 = time.time()
+            ref = np.asarray(reference_logits(params, cfg, ids + toks[:-1]),
+                             np.float32)[plen - 1: plen - 1 + PROBE_TOKENS]
+            got = against(served, ref)
+            got.update(
+                tokens=toks, logit_rms=float(np.sqrt((ref ** 2).mean())),
+                reference_argmax_equal=[int(np.argmax(r)) for r in ref] == toks,
+                served_logprobs=logprobs(served, toks),
+                reference_logprobs=logprobs(ref, toks),
+                reference_seconds=time.time() - t1)
+            got["first_logprob_diff"] = abs(
+                got["served_logprobs"][0] - got["reference_logprobs"][0])
+            got["within_tolerances"] = bool(
+                got["max_abs_logit_diff"] <= TOL[leg]["max_abs_logit"]
+                and got["mean_abs_logit_diff"] <= TOL[leg]["mean_abs_logit"])
+            row[leg] = got
+        if n == probe_bytes[0]:
+            # (of the as_served leg, for the control at the end)
+            control = (ids, toks, ref, got["served_logprobs"])
+            # What a wrong model does to the same probe's reference logits:
+            # each has to land outside the tolerances, or they guard nothing.
+            wrongs = {
+                "three_experts": {"num_experts_per_token":
+                                  cfg.num_experts_per_token - 1},
+                "no_norm_topk": {"norm_topk_prob": False},
+                "int4_weights": {"int4": True},
+            }
+            row["wrong"] = {
+                name: against(np.asarray(reference_logits(
+                    params, cfg, ids + toks[:-1], **changed),
+                    np.float32)[plen - 1: plen - 1 + PROBE_TOKENS], ref)
+                for name, changed in wrongs.items()}
+
+        # The batcher's own programs on the same probe, sent alone: their
+        # tokens and logprobs are the ones the as-served logits give.
+        rid = batcher.submit(ids, max_new_tokens=PROBE_TOKENS)
+        out = batcher.run()
+        mine = row["as_served"]
+        row["batcher_tokens_equal"] = list(out[rid]) == mine["tokens"]
+        row["batcher_logprobs"] = [float(x) for x in
+                                   batcher.result_logprobs[rid]]
+        row["batcher_logprob_max_abs_diff"] = max(
+            abs(x - y) for x, y in
+            zip(row["batcher_logprobs"], mine["served_logprobs"]))
+        good = (row["mechanism"]["within_tolerances"]
+                and mine["within_tolerances"] and row["batcher_tokens_equal"]
+                and row["batcher_logprob_max_abs_diff"] < 1e-3)
+        row["ok"] = bool(good)
+        ok &= good or a.rehearsal
+        report["probes"].append(row)
+        print(json.dumps({k: ({x: y for x, y in v.items()
+                               if not x.endswith("logprobs")}
+                              if isinstance(v, dict) else v)
+                          for k, v in row.items()
+                          if k != "batcher_logprobs"}), flush=True)
+    # The control from the served path (the batcher above is done with the
+    # weights: the expert stacks change where they lie).
+    from distributed_llms_tpu.checkpoint.quantize import QuantizedTensor
+
+    ids, toks, ref, sound = control
+    experts = params["blocks"]["moe"]["experts"]
+    for name, w in experts.items():
+        if isinstance(w, QuantizedTensor):
+            experts[name] = dataclasses.replace(
+                w, data=_int8_on_int4_grid(w.data))
+    served, _ = served_logits(cfg.dtype, ids, force=toks)
+    got = against(served, ref)
+    got["outside_as_served_tolerances"] = bool(
+        got["max_abs_logit_diff"] > TOL["as_served"]["max_abs_logit"]
+        or got["mean_abs_logit_diff"] > TOL["as_served"]["mean_abs_logit"])
+    # What the benchmark's ``correct`` would see: the probe's logprobs
+    # against those the sound programs gave for the same tokens (its limit
+    # is 0.05, benchmark/run.py GOLDEN_TOL).
+    got["max_abs_logprob_shift"] = max(
+        abs(x - y) for x, y in zip(logprobs(served, toks), sound))
+    report["served_int4_experts"] = got
+    ok &= got["outside_as_served_tolerances"] or a.rehearsal
+    print(json.dumps({"served_int4_experts": got}), flush=True)
+    report["golden_from_reference"] = all(
+        p["as_served"]["first_logprob_diff"] <= GOLDEN_FROM_REFERENCE
+        for p in report["probes"])
+
+    from distributed_llms_tpu.core.observability import METRICS
+
+    report["dispatch"] = {k: v for k, v in
+                          METRICS.snapshot()["counters"].items()
+                          if k.startswith("ops.dispatch.")}
+    stats = dev.memory_stats() or {}
+    report["memory_peak_bytes"] = stats.get("peak_bytes_in_use")
+    out_dir = os.path.join(ROOT, "chiprun_out", "reference_check")
+    os.makedirs(out_dir, exist_ok=True)
+    name = a.config + (".rehearsal" if a.rehearsal else "")
+    with open(os.path.join(out_dir, name + ".json"), "w") as f:
+        json.dump(report, f, indent=1)
+    # The benchmark's golden: the reference's logprobs of the served tokens
+    # where the served first-token logprob is within half the golden's
+    # tolerance of them on every probe, the served path's own otherwise
+    # (with the reference's beside them).
+    source = "reference" if report["golden_from_reference"] else "batcher"
+    with open(os.path.join(out_dir, name + ".golden.json"), "w") as f:
+        json.dump({
+            "device_kind": dev.device_kind, "tolerance": 0.05,
+            "from": source,
+            "probes": [
+                {"bytes": p["bytes"], "logprobs": [round(x, 6) for x in (
+                    p["as_served"]["reference_logprobs"]
+                    if source == "reference" else p["batcher_logprobs"])]}
+                for p in report["probes"]],
+            "reference": [
+                {"bytes": p["bytes"],
+                 "logprobs": [round(x, 6) for x in
+                              p["as_served"]["reference_logprobs"]],
+                 "first_logprob_diff": p["as_served"]["first_logprob_diff"],
+                 "max_abs_logit_diff": p["as_served"]["max_abs_logit_diff"],
+                 "mean_abs_logit_diff": p["as_served"]["mean_abs_logit_diff"]}
+                for p in report["probes"]],
+        }, f, indent=1)
+    print(json.dumps({"ok": bool(ok),
+                      "golden_from_reference": report["golden_from_reference"],
+                      "dispatch": report["dispatch"],
+                      "memory_peak_bytes": report["memory_peak_bytes"]}))
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
