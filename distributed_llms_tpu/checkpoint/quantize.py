@@ -15,15 +15,24 @@ model dtype.  A quantized tree stores ``QuantizedTensor`` leaves that
 kernel (ops/quant_matmul.py) consumes directly without ever writing the
 full-precision weights back to HBM.
 
-int4 pack layout: two values per byte along ``pack_axis`` — the weight's
-*reduction* axis (adjacent rows k, k+1 share a byte; low nibble = even row).
+A quantized weight is stored as the MATRIX the kernel reads, whatever axes
+the model gives it: the contracted axes flattened to K, the output axes to
+N, and the scales with the block index second-minor and K on the lanes
+([N/block, K]: lane-dense, where [K, N/block] would be padded up to 128
+lanes on the device).  The kernel takes a layer's tiles out of the stacked
+leaves as they lie; no served program re-lays out a weight or a scale.
+
+int4 pack layout: two values per byte along ``pack_axis`` — the matrix's
+rows (adjacent rows k, k+1 share a byte; low nibble = even row).
 Row-packing (rather than packing along the last axis) is what lets the TPU
 kernel unpack with a sublane interleave, which Mosaic supports for any
-width; scales always run along the LAST axis regardless.
+width; scale blocks always run along N regardless.
 """
 
 from __future__ import annotations
 
+import dataclasses
+import math
 from dataclasses import dataclass
 from typing import Any
 
@@ -34,15 +43,25 @@ import numpy as np
 
 @dataclass
 class QuantizedTensor:
-    """Blockwise-quantized array.
+    """Blockwise-quantized weight, stored as a (stack of) matrix.
 
-    data: int8; for int4, two values packed per byte along ``pack_axis``
-    (low nibble = even index, high nibble = odd index along that axis).
-    scale: float32, shape = unpacked shape with the last axis divided into
-    blocks.
-    pack_axis: negative axis index the int4 pairs run along — negative so a
-    leading stacked-layer axis can be sliced off (lax.scan) without
-    invalidating it.  Unused for int8.
+    data: int8 [*lead, K, N]: the weight's ``k_axes`` contracted axes
+    flattened to K and its ``n_axes`` output axes to N.  For int4, two
+    values packed per byte along ``pack_axis`` (low nibble = even index,
+    high nibble = odd index along that axis).
+    scale: float32 [*lead, N/block, K] (``block_axis`` -1): the block index
+    second-minor, K on the lanes.
+    pack_axis: negative axis index of ``data`` the int4 pairs run along: -2
+    (rows, what the kernel unpacks) or -1 (columns: dequantized, never fed
+    to the kernel) — negative so a leading stacked-layer axis can be sliced
+    off (lax.scan) without invalidating it.  Unused for int8.
+    orig_shape: the weight's shape when it was quantized.  Its last
+    ``k_axes + n_axes`` entries say how K and N unflatten (wq [D, H, hd]:
+    1 and 2; wo [H, hd, D]: 2 and 1); the leading ones go stale on
+    stacked-layer slices and are never read.
+    layer: None, or the int32 index of the ONE layer of a stack [L, K, N]
+    this leaf stands for (:meth:`at`): what a layer scan hands a matmul
+    site in place of a slice, which would be a copy.
     """
 
     data: jax.Array
@@ -54,85 +73,116 @@ class QuantizedTensor:
     # weight; -2 for the expert stacks [E, K, N], whose scales [E, K/128, N]
     # are then lane-dense for ops/moe_experts.py.  int8 only.
     block_axis: int = -1
+    k_axes: int = 1
+    n_axes: int = 1
+    layer: jax.Array | None = None
+
+    def at(self, layer: jax.Array) -> "QuantizedTensor":
+        """This stack [L, ...] read at ``layer`` (traced inside a scan)."""
+        return dataclasses.replace(self, layer=layer)
+
+    def layer_slice(self) -> "QuantizedTensor":
+        """The leaf of :meth:`at` as a leaf of its own: a copy of one
+        layer (the paths without the kernel)."""
+        if self.layer is None:
+            return self
+        return dataclasses.replace(
+            self, data=self.data[self.layer], scale=self.scale[self.layer],
+            layer=None)
+
+    @property
+    def tail_shape(self) -> tuple[tuple[int, ...], tuple[int, ...]]:
+        """(K axes, N axes) of the weight as the model has it."""
+        if self.k_axes == self.n_axes == 1:
+            k, n = self.data.shape[-2:]
+            if self.bits == 4:
+                k, n = ((k, 2 * n) if self.pack_axis == -1 else (2 * k, n))
+            return (k,), (n,)
+        tail = tuple(self.orig_shape[-(self.k_axes + self.n_axes):])
+        return tail[: self.k_axes], tail[self.k_axes:]
 
     @property
     def unpacked_shape(self) -> tuple[int, ...]:
-        """Shape of the dequantized array — derived from data (NOT
+        """Shape of the dequantized array — the leading axes from data (NOT
         orig_shape, which goes stale on stacked-layer slices)."""
-        shape = list(self.data.shape)
-        if self.bits == 4:
-            shape[self.pack_axis] *= 2
-        return tuple(shape)
+        k_shape, n_shape = self.tail_shape
+        return (*self.data.shape[:-2], *k_shape, *n_shape)
 
 
-# data/scale are pytree children; the rest is static metadata.
+# data/scale (and the layer index) are pytree children; the rest is static
+# metadata.
 jax.tree_util.register_dataclass(
     QuantizedTensor,
-    data_fields=["data", "scale"],
-    meta_fields=["bits", "orig_shape", "pack_axis", "block_axis"],
+    data_fields=["data", "scale", "layer"],
+    meta_fields=["bits", "orig_shape", "pack_axis", "block_axis", "k_axes",
+                 "n_axes"],
 )
 
 
 def quantize(
     x: jax.Array, bits: int = 8, block: int = 128, pack_axis: int = -2,
-    block_axis: int = -1,
+    block_axis: int = -1, k_axes: int = 1, n_axes: int = 1,
 ) -> QuantizedTensor:
+    """Quantize ``x`` [*lead, *K axes, *N axes] (``k_axes`` contracted axes,
+    then ``n_axes`` output axes) to its matrix form.  Blocks run along the
+    flattened N and never straddle the last axis of ``x``."""
     if bits not in (8, 4):
         raise ValueError(f"bits must be 8 or 4, got {bits}")
+    orig_shape = tuple(x.shape)
     if block_axis == -2:
         if bits != 8:
             raise ValueError("blocks along the contracted axis are int8 only")
         qt = quantize(jnp.swapaxes(x, -1, -2), bits, block)
         return QuantizedTensor(
-            data=jnp.swapaxes(qt.data, -1, -2),
-            scale=jnp.swapaxes(qt.scale, -1, -2), bits=bits,
-            orig_shape=tuple(x.shape), pack_axis=pack_axis, block_axis=-2,
+            data=jnp.swapaxes(qt.data, -1, -2), scale=qt.scale, bits=bits,
+            orig_shape=orig_shape, pack_axis=pack_axis, block_axis=-2,
         )
-    orig_shape = tuple(x.shape)
+    if pack_axis not in (-1, -2):
+        raise ValueError(f"pack_axis must be -1 or -2, got {pack_axis}")
     block = min(block, x.shape[-1])
     if x.shape[-1] % block:
         # shrink to the largest common divisor so any width quantizes
-        import math
-
         block = math.gcd(x.shape[-1], block)
-    n = x.shape[-1]
-    xb = jnp.asarray(x, jnp.float32).reshape(*x.shape[:-1], n // block, block)
+    tail = k_axes + n_axes
+    lead = orig_shape[: x.ndim - tail]
+    k = math.prod(orig_shape[x.ndim - tail: x.ndim - n_axes])
+    n = math.prod(orig_shape[x.ndim - n_axes:])
+    xb = jnp.asarray(x, jnp.float32).reshape(*lead, k, n // block, block)
     qmax = 127.0 if bits == 8 else 7.0
     absmax = jnp.max(jnp.abs(xb), axis=-1, keepdims=True)
     scale = jnp.where(absmax > 0, absmax / qmax, 1.0)
     q = jnp.clip(jnp.round(xb / scale), -qmax, qmax).astype(jnp.int8)
-    q = q.reshape(orig_shape)
-    scale = scale[..., 0]  # [..., n_blocks]
+    q = q.reshape(*lead, k, n)
+    scale = jnp.swapaxes(scale[..., 0], -1, -2)  # [*lead, n_blocks, k]
     if bits == 4:
-        if not -x.ndim <= pack_axis < 0:
-            raise ValueError(f"pack_axis must be negative, got {pack_axis}")
-        a = x.ndim + pack_axis
-        if x.shape[a] % 2:
+        a = q.ndim + pack_axis
+        if q.shape[a] % 2:
             raise ValueError(
                 f"int4 packing requires even size along pack_axis {pack_axis} "
-                f"(shape {orig_shape})"
+                f"(shape {orig_shape} as a matrix {q.shape})"
             )
-        idx_lo = [slice(None)] * x.ndim
-        idx_hi = [slice(None)] * x.ndim
+        idx_lo = [slice(None)] * q.ndim
+        idx_hi = [slice(None)] * q.ndim
         idx_lo[a] = slice(0, None, 2)
         idx_hi[a] = slice(1, None, 2)
         lo = q[tuple(idx_lo)] & 0x0F
         hi = (q[tuple(idx_hi)] & 0x0F) << 4
         q = (lo | hi).astype(jnp.int8)
     return QuantizedTensor(
-        data=q, scale=scale, bits=bits, orig_shape=orig_shape, pack_axis=pack_axis
+        data=q, scale=scale, bits=bits, orig_shape=orig_shape,
+        pack_axis=pack_axis, k_axes=k_axes, n_axes=n_axes,
     )
 
 
 def dequantize(qt: QuantizedTensor, dtype: Any = jnp.float32) -> jax.Array:
-    """Shapes derive from data/scale, NOT orig_shape: a per-layer slice of a
-    stacked [L, ...] QuantizedTensor (what lax.scan hands the decoder-block
-    body when serving quantized weights) carries stale orig_shape metadata
-    but self-consistent data/scale."""
+    """The weight as the model has it, [*lead, *K axes, *N axes].  Shapes
+    derive from data/scale and the TRAILING entries of orig_shape: a
+    per-layer slice of a stacked [L, ...] QuantizedTensor carries stale
+    leading entries but self-consistent data/scale."""
+    qt = qt.layer_slice()
     if qt.block_axis == -2:
         t = QuantizedTensor(
-            data=jnp.swapaxes(qt.data, -1, -2),
-            scale=jnp.swapaxes(qt.scale, -1, -2), bits=qt.bits,
+            data=jnp.swapaxes(qt.data, -1, -2), scale=qt.scale, bits=qt.bits,
             orig_shape=qt.orig_shape, pack_axis=qt.pack_axis,
         )
         return jnp.swapaxes(dequantize(t, dtype), -1, -2)
@@ -146,16 +196,15 @@ def dequantize(qt: QuantizedTensor, dtype: Any = jnp.float32) -> jax.Array:
         q = jnp.stack([lo, hi], axis=a + 1).reshape(shape)
     qf = q.astype(jnp.float32)
     n = q.shape[-1]
-    n_blocks = qt.scale.shape[-1]
-    block = n // n_blocks
-    qb = qf.reshape(*q.shape[:-1], n_blocks, block)
-    out = qb * qt.scale[..., None]
-    return out.reshape(q.shape).astype(dtype)
+    n_blocks = qt.scale.shape[-2]
+    qb = qf.reshape(*q.shape[:-1], n_blocks, n // n_blocks)
+    out = qb * jnp.swapaxes(qt.scale, -1, -2)[..., None]
+    return out.reshape(qt.unpacked_shape).astype(dtype)
 
 
-# Weights whose trailing TWO axes are output axes ([D, H, hd]): their
-# reduction axis sits at -3, everything else contracts at -2.
-_PACK_AXIS_BY_NAME = {"wq": -3, "wk": -3, "wv": -3}
+# (contracted axes, output axes) of the block leaves that are no plain
+# matrix: wq/wk/wv [D, H, hd] put out two axes, wo [H, hd, D] contracts two.
+_AXES_BY_NAME = {"wq": (1, 2), "wk": (1, 2), "wv": (1, 2), "wo": (2, 1)}
 
 
 # Bias leaves by exact name — matched explicitly (not by "b" prefix) so a
@@ -190,14 +239,20 @@ def _should_quantize(path: str, x: Any) -> bool:
     return True
 
 
-def leaf_plan(path: str, x: Any) -> tuple[bool, int]:
-    """(quantize?, pack_axis) for a named leaf — the single source of truth
-    for which leaves quantize and how they pack, shared by quantize_tree
-    and streaming builders (bench.py generates-and-quantizes on device leaf
-    by leaf and must make the exact decisions the serving path makes)."""
+def leaf_plan(path: str, x: Any) -> tuple[bool, int, int]:
+    """(quantize?, k_axes, n_axes) for a named leaf — the single source of
+    truth for which leaves quantize and which of their axes contract,
+    shared by quantize_tree and streaming builders (bench.py
+    generates-and-quantizes on device leaf by leaf and must make the exact
+    decisions the serving path makes).  A leaf with heads that some family
+    stores flat already (the hybrid's wq [D, H * hd]) is a plain matrix."""
     if not _should_quantize(path, x):
-        return False, -2
-    return True, _PACK_AXIS_BY_NAME.get(path.split("/")[-1], -2)
+        return False, 1, 1
+    k_axes, n_axes = _AXES_BY_NAME.get(path.split("/")[-1], (1, 1))
+    # params["blocks"] leaves carry the stacked layer axis in front.
+    if x.ndim - path.startswith("blocks/") < k_axes + n_axes:
+        return True, 1, 1
+    return True, k_axes, n_axes
 
 
 def quantize_tree(params: Any, bits: int = 8, block: int = 128) -> Any:
@@ -205,10 +260,10 @@ def quantize_tree(params: Any, bits: int = 8, block: int = 128) -> Any:
 
     def visit(path, x):
         key = "/".join(str(getattr(p, "key", p)) for p in path)
-        should, pack_axis = leaf_plan(key, x)
+        should, k_axes, n_axes = leaf_plan(key, x)
         if should:
-            return quantize(x, bits=bits, block=block, pack_axis=pack_axis,
-                            block_axis=block_axis_of(key))
+            return quantize(x, bits=bits, block=block, k_axes=k_axes,
+                            n_axes=n_axes, block_axis=block_axis_of(key))
         return x
 
     return jax.tree_util.tree_map_with_path(visit, params)

@@ -152,6 +152,10 @@ def save_shards(
                 "dtype": "quantized",
                 "bits": leaf.bits,
                 "pack_axis": leaf.pack_axis,
+                # Stored as a matrix [K, N] with scales [N/block, K]
+                # (checkpoint/quantize.py): how the axes of "shape" flatten.
+                "axes": [leaf.k_axes, leaf.n_axes],
+                "block_axis": leaf.block_axis,
             }
         else:
             arr = np.asarray(leaf)
@@ -281,16 +285,21 @@ def load_shards(
         if meta["shard"] not in wanted:
             continue
         if meta["dtype"] == "quantized":
+            if "axes" not in meta:
+                raise ValueError(
+                    f"store {store_dir} holds {name} in the quantized layout "
+                    "of before PR 29 (weights with their model axes, scales "
+                    "[..., K, N/block]); this build reads matrices with "
+                    "scales [N/block, K]: re-quantize it with save_store"
+                )
             qt = QuantizedTensor(
                 data=jnp.asarray(arrays[name + ".q"]),
                 scale=jnp.asarray(arrays[name + ".scale"]),
                 bits=meta["bits"],
                 orig_shape=tuple(meta["shape"]),
-                # Legacy stores (written before pack_axis landed) packed int4
-                # pairs along the LAST axis; missing key must decode as -1,
-                # not the modern default of -2, or unpack runs along the
-                # wrong axis and dequantize fails/corrupts.
-                pack_axis=meta.get("pack_axis", -1),
+                pack_axis=meta["pack_axis"],
+                block_axis=meta["block_axis"],
+                k_axes=meta["axes"][0], n_axes=meta["axes"][1],
             )
             flat[name] = quant_lib.dequantize(qt, dtype or jnp.float32) if dequantize else qt
         elif meta["dtype"] == "bfloat16":

@@ -520,6 +520,13 @@ METRIC_DOCS: dict[str, str] = {
                         "by the path "
                         "taken: kernel (compiled), interpret (Pallas "
                         "interpreter) or fallback (dense jax.numpy)",
+    "ops.dispatch.quant_matmul.stacked": "of quant_matmul's kernel (or "
+                                         "interpret) traces, those handed a "
+                                         "stack of layers and the index of "
+                                         "the one to read; in a served dense "
+                                         "model it equals them, and a value "
+                                         "below says a call site slices a "
+                                         "layer out (a copy a step)",
     "ops.dispatch.*.shard_map": "of those, dispatches traced inside the "
                                 "per-shard shard_map body of a "
                                 "tensor-parallel mesh (the kernel then "

@@ -171,12 +171,21 @@ def _is_quantized(w: Any) -> bool:
     return hasattr(w, "bits") and hasattr(w, "scale") and hasattr(w, "data")
 
 
+def _weight_ndim(w: Any) -> int:
+    """Axes of ONE layer's weight as the model has it: a quantized leaf is
+    stored as a matrix whatever they are, and may be a whole stack read at
+    an index (``QuantizedTensor.at``)."""
+    return sum(map(len, w.tail_shape)) if _is_quantized(w) else w.ndim
+
+
 def _contract(x: jax.Array, w: Any, eq: str, k_lead: int, shard: str) -> jax.Array:
     """einsum for plain weights; fused dequant-matmul (ops/quant_matmul) for
-    QuantizedTensor weights under weight-only quantized serving.  ``shard``
-    is the weight's tensor-parallel role, "n" (output axis split over
-    'model') or "k" (contracted axis split), which the kernel needs to run
-    per shard; XLA partitions the plain einsum by itself."""
+    QuantizedTensor weights under weight-only quantized serving: one
+    layer's, or the stack of every layer's carrying the index of the one
+    to read (``QuantizedTensor.at``), which the kernel reads where it lies.
+    ``shard`` is the weight's tensor-parallel role, "n" (output axis split
+    over 'model') or "k" (contracted axis split), which the kernel needs to
+    run per shard; XLA partitions the plain einsum by itself."""
     if _is_quantized(w):
         from ..ops.quant_matmul import quant_contract
 
@@ -199,7 +208,7 @@ def qkv_project(x: jax.Array, p: Params, cfg: ModelConfig) -> tuple[jax.Array, j
     Weight layout: wq [D, H, hd], wk/wv [D, KVH, hd] — head axis explicit so
     tensor-parallel sharding annotates the head dim directly.
     """
-    if getattr(p["wq"], "data", p["wq"]).ndim == 2:
+    if _weight_ndim(p["wq"]) == 2:
         # [D, H * hd], the head axes stored flat (the hybrid family).
         q, k, v = (
             _contract(x, p[w], "btd,dn->btn", 1, "n").reshape(

@@ -17,6 +17,7 @@ Layout conventions:
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import zlib
 from dataclasses import dataclass
@@ -531,6 +532,28 @@ def neox_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, s
     return x + layers.mlp_gelu(h2, p["mlp"], cfg.activation), new_cache, jnp.float32(0.0)
 
 
+def layer_of(blocks: Params, layer: jax.Array) -> Params:
+    """Layer ``layer`` of stacked block leaves, for the body of a layer
+    scan.  A quantized stack of matrices [L, K, N] stays whole and carries
+    the index (``QuantizedTensor.at``): layers._contract hands both to the
+    kernel, which reads the layer's tiles where they lie, where a slice
+    would be a copy of the layer's weights in every step (a Pallas call
+    takes each operand as a buffer of its own).  Every other leaf, norms
+    and biases, float weights, expert stacks of the capacity path, is
+    sliced."""
+    from ..checkpoint.quantize import QuantizedTensor
+
+    def is_q(a):
+        return isinstance(a, QuantizedTensor)
+
+    def take(a):
+        if is_q(a) and a.data.ndim == 3:
+            return a.at(layer)
+        return jax.tree.map(lambda v: v[layer], a)
+
+    return jax.tree.map(take, blocks, is_leaf=is_q)
+
+
 BLOCK_FNS = {"gpt2": gpt2_block, "opt": gpt2_block, "llama": llama_block,
              "neox": neox_block}
 
@@ -568,11 +591,16 @@ def run_blocks(
     own, and the stacked outputs are a second pool).
 
     Blocks may carry ``QuantizedTensor`` leaves (weight-only quantized
-    serving): weights live in HBM at int8/int4 and flow through the scan to
-    each matmul site, where layers._contract runs the fused dequant-matmul
-    Pallas kernel (ops/quant_matmul.py) on TPU — the weights are read at
-    their quantized width and never materialized full-dtype in HBM."""
+    serving): weights live in HBM at int8/int4.  With a cache the scan
+    runs over the layer's index alone and the blocks are closed over:
+    :func:`layer_of` slices the small leaves and hands each matmul site
+    its whole stack and the index, and layers._contract runs the fused
+    dequant-matmul Pallas kernel (ops/quant_matmul.py) on the layer's
+    tiles where they lie — the weights are read once, at their quantized
+    width, never copied and never materialized full-dtype in HBM."""
     block_fn = BLOCK_FNS[cfg.family]
+    layer_index = jnp.arange(
+        jax.tree.leaves(blocks)[0].shape[0], dtype=jnp.int32)
 
     if cache_k is None:
         def body(carry, layer_params):
@@ -581,24 +609,23 @@ def run_blocks(
 
         init, xs = x, blocks
     elif kv_tables is not None:
-        def body(carry, xs):
+        def body(carry, layer):
             y, pool = carry
-            layer_params, layer = xs
-            y, pool, aux = block_fn(y, layer_params, cfg, positions, pool, cache_index, attn_mask, std_layout, kv_tables, key_positions, layer)
+            y, pool, aux = block_fn(y, layer_of(blocks, layer), cfg, positions, pool, cache_index, attn_mask, std_layout, kv_tables, key_positions, layer)
             return (y, pool), aux
 
         pool = (cache_k, cache_v)
         if cache_sk is not None:
             pool += (cache_sk, cache_sv)
         init = (x, pool)
-        xs = (blocks, jnp.arange(cache_k.shape[0], dtype=jnp.int32))
+        xs = layer_index
     else:
         def body(carry, xs):
-            layer_params, ck, cv = xs
-            y, new_cache, aux = block_fn(carry, layer_params, cfg, positions, (ck, cv), cache_index, attn_mask, std_layout, None, key_positions)
+            layer, ck, cv = xs
+            y, new_cache, aux = block_fn(carry, layer_of(blocks, layer), cfg, positions, (ck, cv), cache_index, attn_mask, std_layout, None, key_positions)
             return y, (new_cache, aux)
 
-        init, xs = x, (blocks, cache_k, cache_v)
+        init, xs = x, (layer_index, cache_k, cache_v)
 
     if remat:
         body = jax.checkpoint(body)
@@ -663,9 +690,9 @@ def run_layers(
     published order as :func:`layer_runs` has it, a repeating run under
     one ``lax.scan`` with the layer's index into each stack computed from
     the iteration.  The expert stacks go into their kernel whole, indexed
-    by (layer, expert) (ops/moe_experts.py), so no program copies or
-    dequantizes them; the other weights, 5% of the bytes, are sliced a
-    layer as the dense families' are.
+    by (layer, expert) (ops/moe_experts.py), and the other quantized
+    weights go into theirs the same way (:func:`layer_of`), so no program
+    copies or dequantizes a weight; norms and taps are sliced a layer.
 
     The cache is the scans' carry beside x.  An attention layer reads and
     writes it at its own index among the attention layers: the page pool
@@ -690,7 +717,7 @@ def run_layers(
     def layer(carry, op, ffn, at):
         """One layer; ``at`` its index into each kind's stack."""
         x, k, v, conv, moe = carry
-        p = jax.tree.map(lambda a: a[at[op]], blocks[op])
+        p = layer_of(blocks[op], at[op])
         h = layers.rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
         if op == "conv":
             out, new = layers.short_conv(
@@ -721,8 +748,8 @@ def run_layers(
                 h, blocks["moe"], cfg, token_mask, layer=at[ffn])
             x, moe = x + y, moe + stats
         else:
-            dense = jax.tree.map(lambda a: a[at[ffn]], blocks["dense"])
-            x = x + layers.mlp_swiglu(h, dense, cfg.gate_act)
+            x = x + layers.mlp_swiglu(
+                h, layer_of(blocks["dense"], at[ffn]), cfg.gate_act)
         return x, k, v, conv, moe
 
     carry = (x, k, v, conv, moe)
@@ -1100,21 +1127,21 @@ def init_params_quantized(
         hybrid = cfg.family == "hybrid" and name.startswith("blocks/")
         scale = (hybrid_fan_in(name, sd.shape) if hybrid and sd.ndim > 2
                  else fan_ins.get(leaf, cfg.hidden_size)) ** -0.5
-        should, pack_axis = quant_lib.leaf_plan(name, sd)
-        block_axis = quant_lib.block_axis_of(name)
+        should, k_axes, n_axes = quant_lib.leaf_plan(name, sd)
         quant = should and name.startswith("blocks/")
+        quantize = functools.partial(
+            quant_lib.quantize, bits=bits, k_axes=k_axes, n_axes=n_axes,
+            block_axis=quant_lib.block_axis_of(name))
+        stacked = jax.eval_shape(quantize, sd) if quant else None
         repeat = 1
         sharding = None
         if spec is not None:
             sharding = NamedSharding(mesh, spec)
             if quant:
-                qt = jax.eval_shape(functools.partial(
-                    quant_lib.quantize, bits=bits, pack_axis=pack_axis), sd)
-                spec, repeat = parallel_api.quantized_layout(
-                    qt.data.shape, qt.scale.shape, bits, pack_axis, spec,
-                    mesh, name,
-                )
-                sharding = (NamedSharding(mesh, spec),) * 2
+                data_spec, scale_spec, repeat = parallel_api.quantized_layout(
+                    stacked, spec, mesh, name)
+                sharding = (NamedSharding(mesh, data_spec),
+                            NamedSharding(mesh, scale_spec))
 
         def dense(k, shape):
             return jax.random.normal(k, shape, jnp.float32) * scale
@@ -1123,9 +1150,8 @@ def init_params_quantized(
             x = dense(jax.random.fold_in(key, i), sd.shape[1:])
             if not quant:
                 return x.astype(sd.dtype)
-            qt = quant_lib.quantize(x, bits=bits, pack_axis=pack_axis,
-                                    block_axis=block_axis)
-            return qt.data, jnp.repeat(qt.scale, repeat, axis=-1)
+            qt = quantize(x)
+            return qt.data, jnp.repeat(qt.scale, repeat, axis=-2)
 
         def gen():
             if leaf in ("scale", "q_norm", "k_norm"):
@@ -1141,11 +1167,7 @@ def init_params_quantized(
         out = jax.jit(gen, out_shardings=sharding)()
         if not quant:
             return out
-        return quant_lib.QuantizedTensor(
-            data=out[0], scale=out[1], bits=bits,
-            orig_shape=tuple(sd.shape), pack_axis=pack_axis,
-            block_axis=block_axis,
-        )
+        return dataclasses.replace(stacked, data=out[0], scale=out[1])
 
     if specs is None:
         return jax.tree_util.tree_map_with_path(build, shapes)

@@ -61,73 +61,75 @@ def _axis_sz(mesh: Mesh, name) -> int:
     return sz
 
 
-def quantized_layout(
-    data_shape: tuple, scale_shape: tuple, bits: int, pack_axis: int,
-    spec: P, mesh: Mesh, path: str,
-) -> tuple[P, int]:
-    """How a QuantizedTensor shards under the plain weight's PartitionSpec:
-    (the spec for data AND scale, the scale refinement factor).
+def quantized_layout(qt, spec: P, mesh: Mesh, path: str) -> tuple[P, P, int]:
+    """How a QuantizedTensor (arrays or shapes) shards under the plain
+    weight's PartitionSpec: (the spec for data, the spec for scale, the
+    scale refinement factor).
 
-    data shards exactly like the weight (for int4 the pack axis holds
-    adjacent-row pairs, so a contiguous shard of packed rows unpacks to the
-    same contiguous rows — exact).  scale has the weight's shape with the
-    last axis in block units; when the spec shards that last axis, scales
-    are refined (each block's scale repeated k times = block size / k —
-    numerically identical) until shard boundaries land on block boundaries.
+    The leaf is stored as a matrix [*lead, K, N] (checkpoint/quantize.py):
+    its flattened contracted axes shard as the weight's first contracted
+    axis does, its flattened output axes as the first output axis (whole
+    heads, for the attention weights; for int4 the rows hold adjacent-row
+    pairs, so a contiguous shard of packed rows unpacks to the same
+    contiguous rows — exact).  scale [*lead, N/block, K] takes the two
+    names the other way round; when the spec shards N, scales are refined
+    (each block's scale repeated k times = block size / k — numerically
+    identical) until shard boundaries land on block boundaries.
     Un-shardable layouts replicate the leaf, loudly.
     """
     from ..core.observability import get_logger
 
-    rank = len(data_shape)
-    s = tuple(spec) + (None,) * (rank - len(spec))  # trailing = replicated
-
-    def replicate(reason: str) -> tuple[P, int]:
+    def replicate(reason: str) -> tuple[P, P, int]:
         get_logger("parallel").warning(
             "quantized leaf %s cannot shard under %s (%s); replicating",
             path, spec, reason,
         )
-        return P(), 1
+        return P(), P(), 1
 
+    data_shape = tuple(qt.data.shape)
+    n_lead = len(data_shape) - 2
+    tail = qt.k_axes + qt.n_axes
+    s = tuple(spec) + (None,) * (n_lead + tail - len(spec))  # trailing = replicated
+    k_names = s[n_lead: n_lead + qt.k_axes]
+    n_names = s[n_lead + qt.k_axes:]
+    if any(k_names[1:]) or any(n_names[1:]):
+        return replicate("an inner axis of a flattened group is sharded")
+    flat = (*s[:n_lead], k_names[0], n_names[0])
     # Divisibility of every sharded data axis (jax would raise; we want the
     # replicate fallback instead).
-    for ax, name in enumerate(s):
+    for ax, name in enumerate(flat):
         if _axis_sz(mesh, name) > 1 and data_shape[ax] % _axis_sz(mesh, name):
             return replicate(f"data axis {ax} ({data_shape[ax]}) % shards")
-    last = rank - 1
-    tp_last = _axis_sz(mesh, s[last])
-    if tp_last > 1 and bits == 4 and rank + pack_axis == last:
+    if qt.block_axis == -2:  # expert stacks: scale [.., K/128, N]
+        return P(*flat), P(*flat), 1
+    tp_n = _axis_sz(mesh, flat[-1])
+    if tp_n > 1 and qt.bits == 4 and qt.pack_axis == -1:
         return replicate("spec shards the int4 pack axis at the last dim")
     repeat = 1
-    if tp_last > 1:
-        dim = data_shape[last]  # last axis is never int4-packed here
-        block = dim // scale_shape[-1]
-        per_shard = dim // tp_last
+    if tp_n > 1:
+        dim = data_shape[-1]  # N is never int4-packed here
+        block = dim // qt.scale.shape[-2]
+        per_shard = dim // tp_n
         if per_shard % block:
             # Refine: new block g divides both the old block and the shard
             # width, so each shard holds whole (finer) blocks.
             import math
 
             repeat = block // math.gcd(block, per_shard)
-    # scale has data's rank (last axis in block units; the int4 pack axis is
-    # 2x data's, divisible whenever data's is) — the same spec applies.
-    return P(*s), repeat
+    return P(*flat), P(*flat[:-2], flat[-1], flat[-2]), repeat
 
 
 def _place_quantized(leaf, spec: P, mesh: Mesh, path: str):
     """Shard a QuantizedTensor under the plain weight's PartitionSpec
     (:func:`quantized_layout`)."""
-    from ..checkpoint.quantize import QuantizedTensor
+    import dataclasses
 
-    spec, repeat = quantized_layout(
-        leaf.data.shape, leaf.scale.shape, leaf.bits, leaf.pack_axis, spec,
-        mesh, path,
-    )
-    scale = leaf.scale if repeat == 1 else jnp.repeat(leaf.scale, repeat, axis=-1)
-    sharding = NamedSharding(mesh, spec)
-    return QuantizedTensor(
-        data=jax.device_put(leaf.data, sharding),
-        scale=jax.device_put(scale, sharding),
-        bits=leaf.bits, orig_shape=leaf.orig_shape, pack_axis=leaf.pack_axis,
+    data_spec, scale_spec, repeat = quantized_layout(leaf, spec, mesh, path)
+    scale = leaf.scale if repeat == 1 else jnp.repeat(leaf.scale, repeat, axis=-2)
+    return dataclasses.replace(
+        leaf,
+        data=jax.device_put(leaf.data, NamedSharding(mesh, data_spec)),
+        scale=jax.device_put(scale, NamedSharding(mesh, scale_spec)),
     )
 
 

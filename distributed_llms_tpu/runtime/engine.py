@@ -246,10 +246,11 @@ class InferenceEngine:
                     )
         if rt.serve_quantized:
             # Weight-only quantized serving: decoder-block weights stay
-            # int8/int4 in HBM; QuantizedTensor leaves flow through the block
-            # scan into layers._contract, which feeds the fused dequant-matmul
-            # Pallas kernel on TPU (ops/quant_matmul.py) or dequantize+einsum
-            # elsewhere.  Embedding/unembedding tables are rehydrated —
+            # int8/int4 in HBM; the block scan hands layers._contract each
+            # QuantizedTensor stack whole with the layer's index, and the
+            # fused dequant-matmul Pallas kernel reads the layer's tiles
+            # where they lie on TPU (ops/quant_matmul.py); dequantize+einsum
+            # on the layer's slice elsewhere.  Embedding/unembedding tables are rehydrated —
             # gathers can't consume QuantizedTensor leaves.
             if not manifest.get("quantization"):
                 raise ValueError(

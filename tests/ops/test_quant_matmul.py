@@ -5,8 +5,10 @@ quantized models; every quantized-serving test on the CPU backend otherwise
 exercises only the dequantize+einsum fallback.  These tests run the kernel's
 exact program via Pallas interpret mode and compare against the fallback,
 covering the matrix the kernel special-cases: bits {8, 4}, k_lead {1, 2}
-(qkv/mlp vs wo), pack_axis {-2, -3}, and M values that exercise the padding
-path (decode-shaped M=1, odd M, multi-tile M).
+(qkv/mlp vs wo), weights with two output axes, M values that exercise the
+padding path (decode-shaped M=1, odd M, multi-tile M), and the stacked form
+the layer scans hand over: every layer's weight in one operand, read at an
+index.
 
 Reference's quantization design: /root/reference/snippets.md:675-833 (absmax
 int8 + packed int4, dequantize-before-use); the fused kernel is the
@@ -27,9 +29,9 @@ def _fallback(x, qt, eq):
     return jnp.einsum(eq, x, w)
 
 
-def _make(shape, bits, pack_axis, seed=0):
+def _make(shape, bits, pack_axis=-2, seed=0, **axes):
     w = jax.random.normal(jax.random.key(seed), shape, jnp.float32)
-    return quantize(w, bits=bits, block=128, pack_axis=pack_axis)
+    return quantize(w, bits=bits, block=128, pack_axis=pack_axis, **axes)
 
 
 @pytest.fixture
@@ -60,11 +62,14 @@ def test_parity_2d_klead1(bits, m, kernel_calls):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("bits,pack_axis", [(8, -2), (4, -3)])
-def test_parity_qkv_layout(bits, pack_axis, kernel_calls):
-    """wq/wk/wv layout [D, H, hd]: reduction axis is axis 0, so int4 packs
-    along -3; output restores the [H, hd] tail."""
-    qt = _make((256, 2, 128), bits, pack_axis=pack_axis)
+@pytest.mark.parametrize("bits", [8, 4])
+def test_parity_qkv_layout(bits, kernel_calls):
+    """wq/wk/wv layout [D, H, hd]: stored as the matrix [D, H * hd] (int4
+    pairs down its rows, the reduction axis); output restores the [H, hd]
+    tail."""
+    qt = _make((256, 2, 128), bits, n_axes=2)
+    assert qt.data.shape == (256 // (1 if bits == 8 else 2), 256)
+    assert qt.scale.shape == (2, 256)
     x = jax.random.normal(jax.random.key(2), (4, 9, 256), jnp.float32)
     got = qm.quant_contract(x, qt, 1, "btd,dhk->bthk", interpret=True)
     want = _fallback(x, qt, "btd,dhk->bthk")
@@ -75,9 +80,9 @@ def test_parity_qkv_layout(bits, pack_axis, kernel_calls):
 
 @pytest.mark.parametrize("bits", [8, 4])
 def test_parity_wo_layout_klead2(bits, kernel_calls):
-    """wo layout [H, hd, D] with k_lead=2: both leading axes contract; int4
-    packs along -2 (hd — the last K axis)."""
-    qt = _make((2, 128, 256), bits, pack_axis=-2)
+    """wo layout [H, hd, D] with k_lead=2: both leading axes contract, and
+    flatten to the matrix's rows; int4 pairs lie along hd, the last K axis."""
+    qt = _make((2, 128, 256), bits, k_axes=2)
     x = jax.random.normal(jax.random.key(3), (4, 9, 2, 128), jnp.float32)
     got = qm.quant_contract(x, qt, 2, "bthk,hkd->btd", interpret=True)
     want = _fallback(x, qt, "bthk,hkd->btd")
@@ -155,3 +160,99 @@ def test_env_fallback_mode(monkeypatch, kernel_calls, dispatched):
     qm.quant_contract(x, qt, 1, "mk,kn->mn")
     assert len(kernel_calls) == 0
     assert dispatched() == {"quant_matmul.fallback": 1}
+
+
+def _stack(layers, k_lead, bits, shape):
+    """A stack of ``layers`` weights of ``shape`` quantized as one leaf."""
+    axes = dict(k_axes=2) if k_lead == 2 else {}
+    return _make((layers, *shape), bits, seed=11, **axes)
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+@pytest.mark.parametrize("k_lead,shape,x_tail,eq", [
+    (1, (256, 384), (256,), "mk,kn->mn"),
+    (2, (2, 128, 256), (2, 128), "mhk,hkd->md"),
+])
+@pytest.mark.parametrize("m", [1, 16, 300])
+def test_stacked_parity(bits, k_lead, shape, x_tail, eq, m, kernel_calls,
+                        dispatched):
+    """The stack [L, K, N] goes into the kernel whole and ``at(layer)``
+    names the layer, traced inside a lax.scan as a layer scan has it: each
+    layer's result equals dequantize + einsum on that layer's slice, and
+    the kernel is handed the stack itself, never a slice."""
+    qt = _stack(3, k_lead, bits, shape)
+    x = jax.random.normal(jax.random.key(12), (m, *x_tail), jnp.float32)
+
+    def body(_, layer):
+        return None, qm.quant_contract(x, qt.at(layer), k_lead, eq,
+                                       interpret=True)
+
+    _, got = jax.lax.scan(body, None, jnp.arange(3, dtype=jnp.int32))
+    assert len(kernel_calls) == 1  # one trace of the scan body
+    assert dispatched() == {"quant_matmul.interpret": 1,
+                            "quant_matmul.stacked": 1}
+    want = jnp.einsum("l" + eq.replace(",", ",l").replace("->", "->l"),
+                      jnp.broadcast_to(x, (3, *x.shape)), dequantize(qt))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+    # ...and to the last bit what the kernel gives for the layer's slice.
+    one = jax.tree.map(lambda a: a[2], qt)
+    np.testing.assert_array_equal(
+        np.asarray(got[2]),
+        np.asarray(qm.quant_contract(x, one, k_lead, eq, interpret=True)))
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_stack_of_one(bits, kernel_calls, dispatched):
+    """A weight that is no stack is a stack of one read at layer 0: the
+    same kernel, and not counted as stacked."""
+    qt = _make((1, 256, 256), bits, seed=13)
+    x = jax.random.normal(jax.random.key(14), (4, 256), jnp.float32)
+    got = qm.quant_contract(x, qt.at(0), 1, "mk,kn->mn", interpret=True)
+    one = jax.tree.map(lambda a: a[0], qt)
+    want = qm.quant_contract(x, one, 1, "mk,kn->mn", interpret=True)
+    assert len(kernel_calls) == 2
+    assert dispatched() == {"quant_matmul.interpret": 2}
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_fallback(x, one, "mk,kn->mn")),
+        rtol=2e-5, atol=2e-5)
+
+
+def test_stacked_fallback_reads_the_layer(monkeypatch, dispatched):
+    """DLT_QUANT_MATMUL=fallback on a stack read at an index dequantizes
+    that layer's slice."""
+    monkeypatch.setenv("DLT_QUANT_MATMUL", "fallback")
+    qt = _make((3, 256, 256), 8, seed=15)
+    x = jax.random.normal(jax.random.key(16), (4, 256), jnp.float32)
+    got = qm.quant_contract(x, qt.at(jnp.int32(1)), 1, "mk,kn->mn")
+    want = x @ dequantize(qt)[1]
+    assert dispatched() == {"quant_matmul.fallback": 1}
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
+
+
+def test_scales_are_stored_lane_dense():
+    """The stored scales have K on the last axis ([N/block, K]): the layout
+    the kernel's BlockSpec reads, so no served program re-lays them out."""
+    qt = _make((3, 512, 384), 8)
+    assert qt.data.shape == (3, 512, 384) and qt.scale.shape == (3, 3, 512)
+    w = jax.random.normal(jax.random.key(0), (3, 512, 384), jnp.float32)
+    absmax = jnp.max(jnp.abs(w.reshape(3, 512, 3, 128)), axis=-1)
+    np.testing.assert_allclose(
+        np.asarray(qt.scale), np.asarray(jnp.swapaxes(absmax / 127.0, 1, 2)),
+        rtol=1e-6)
+
+
+@pytest.mark.parametrize("bits,shape", [(4, (512, 384)), (4, (512, 768)),
+                                        (4, (1024, 1536)), (8, (256, 640))])
+def test_scale_rows_shared_by_tiles_or_past_the_end(bits, shape, kernel_calls):
+    """A tile whose own scale rows do not fill 8 sublanes reads a block of 8
+    that several j-tiles share (int4's one-piece tiles; [1024, 1536]: 12
+    rows in blocks of 8, the second half past the end), and a block may be
+    longer than the scales are (3 or 6 rows): each tile still finds its own
+    rows."""
+    qt = _make((2, *shape), bits, seed=17)
+    x = jax.random.normal(jax.random.key(18), (5, shape[0]), jnp.float32)
+    got = qm.quant_contract(x, qt.at(1), 1, "mk,kn->mn", interpret=True)
+    want = x @ dequantize(qt)[1]
+    assert len(kernel_calls) == 1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)

@@ -8,6 +8,8 @@ bandwidth win applies to plain-TP serving), falling back to
 dequantize+einsum on non-TPU backends.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -38,7 +40,7 @@ def test_scale_refinement_is_exact(devices8):
     w = jax.random.normal(jax.random.key(0), (64, 256), jnp.float32)
     qt = quant_lib.quantize(w, bits=8, block=128)  # 2 blocks; 8 shards of 32
     placed = api_lib._place_quantized(qt, P(None, "model"), mesh, "w")
-    assert placed.scale.shape[-1] == 8  # refined 128 -> 32-wide blocks
+    assert placed.scale.shape[-2] == 8  # refined 128 -> 32-wide blocks
     np.testing.assert_array_equal(
         np.asarray(quant_lib.dequantize(qt)), np.asarray(quant_lib.dequantize(placed))
     )
@@ -78,8 +80,9 @@ def test_preset_weights_are_born_quantized_and_sharded(devices8):
     w_gate = eng.params["blocks"]["mlp"]["w_gate"]
     assert w_gate.data.sharding.spec == P(None, None, "model")
     # 176 columns over 4 shards = 44 a shard: the 16-wide blocks refine to 4.
-    assert w_gate.scale.shape[-1] == 44
-    assert ref.params["blocks"]["mlp"]["w_gate"].scale.shape[-1] == 11
+    assert w_gate.scale.shape[-2] == 44
+    assert w_gate.scale.sharding.spec == P(None, "model", None)
+    assert ref.params["blocks"]["mlp"]["w_gate"].scale.shape[-2] == 11
     np.testing.assert_array_equal(
         np.asarray(quant_lib.dequantize(w_gate)),
         np.asarray(quant_lib.dequantize(ref.params["blocks"]["mlp"]["w_gate"])),
@@ -123,24 +126,30 @@ def test_tp_mesh_serves_quantized_resident(tmp_path, devices8, quantization):
 
 
 @pytest.mark.parametrize(
-    "case,wshape,wspec,xshape,xspec,k_lead,eq,shard",
+    "case,wshape,wspec,xshape,xspec,k_lead,eq,shard,layers",
     [
         # Shapes chosen so the LOCAL shard is kernel-tileable (block=128,
         # local n a multiple of 128) — the Pallas program, not the dequant
         # fallback, is what runs per shard (asserted via the spy below).
         ("w_in N-sharded", (256, 1024), P(None, "model"), (4, 256),
-         P("data", None), 1, "md,df->mf", "n"),
+         P("data", None), 1, "md,df->mf", "n", 0),
         ("wq head-sharded", (256, 4, 128), P(None, "model", None), (4, 256),
-         P("data", None), 1, "md,dhk->mhk", "n"),
+         P("data", None), 1, "md,dhk->mhk", "n", 0),
         ("wo K-sharded psum", (4, 128, 256), P("model", None, None),
-         (4, 4, 128), P("data", "model", None), 2, "mhk,hkd->md", "k"),
+         (4, 4, 128), P("data", "model", None), 2, "mhk,hkd->md", "k", 0),
         ("x batched 3d", (256, 1024), P(None, "model"), (2, 3, 256),
-         P("data", None, None), 1, "btd,df->btf", "n"),
+         P("data", None, None), 1, "btd,df->btf", "n", 0),
+        # The stack of every layer's weight, read at an index: the layer
+        # axis of data and scales unsharded, the index replicated.
+        ("stacked wq N-sharded", (256, 4, 128), P(None, "model", None),
+         (4, 256), P("data", None), 1, "md,dhk->mhk", "n", 3),
+        ("stacked wo K-sharded psum", (4, 128, 256), P("model", None, None),
+         (4, 4, 128), P("data", "model", None), 2, "mhk,hkd->md", "k", 3),
     ],
 )
 def test_sharded_kernel_partitions(
     devices8, monkeypatch, case, wshape, wspec, xshape, xspec, k_lead, eq,
-    shard,
+    shard, layers, dispatched,
 ):
     """Under a tensor-parallel mesh the kernel program runs per shard
     inside shard_map (interpret mode on CPU) — N-sharded weights
@@ -160,25 +169,26 @@ def test_sharded_kernel_partitions(
         lambda *a, **kw: kernel_calls.append(1) or orig(*a, **kw),
     )
     mesh = Mesh(np.array(devices8).reshape(2, 4), ("data", "model"))
-    w = jax.random.normal(jax.random.key(0), wshape, jnp.float32)
-    qt = quantize(w, bits=8, block=128)
-    sharded = type(qt)(
-        data=jax.device_put(qt.data, NamedSharding(mesh, wspec)),
-        scale=jax.device_put(qt.scale, NamedSharding(mesh, wspec)),
-        bits=qt.bits, orig_shape=qt.orig_shape, pack_axis=qt.pack_axis,
-    )
+    lead = (layers,) if layers else ()
+    w = jax.random.normal(jax.random.key(0), lead + wshape, jnp.float32)
+    qt = quantize(w, bits=8, block=128, k_axes=k_lead,
+                  n_axes=len(wshape) - k_lead)
+    sharded = api_lib._place_quantized(
+        qt, P(*(None,) * len(lead), *wspec), mesh, case)
+    assert "model" in sharded.data.sharding.spec
+    assert "model" in sharded.scale.sharding.spec
     x = jax.device_put(
         jax.random.normal(jax.random.key(1), xshape, jnp.float32),
         NamedSharding(mesh, xspec),
     )
+    at = jnp.int32(layers - 1)
     with dispatch.sharded(mesh):
-        f = jax.jit(lambda x_, d_, s_: qm.quant_contract(
-            x_,
-            type(qt)(data=d_, scale=s_, bits=qt.bits,
-                     orig_shape=qt.orig_shape, pack_axis=qt.pack_axis),
-            k_lead, eq, shard=shard,
-        ))
-        y = f(x, sharded.data, sharded.scale)
+        f = jax.jit(lambda x_, q_, at_: qm.quant_contract(
+            x_, q_.at(at_) if layers else q_, k_lead, eq, shard=shard))
+        y = f(x, sharded, at)
+    if layers:
+        w, qt = w[layers - 1], jax.tree.map(lambda a: a[layers - 1], qt)
+        assert dispatched()["quant_matmul.stacked"] == 1
     assert kernel_calls, "Pallas kernel program was not run per shard"
     ref = jnp.einsum(eq, x, dequantize(qt, x.dtype))
     np.testing.assert_allclose(
@@ -186,12 +196,13 @@ def test_sharded_kernel_partitions(
     )
 
 
-@pytest.mark.parametrize("stacked_xs", [False, True])
+@pytest.mark.parametrize("stacked_xs", [False, True, "at"])
 def test_sharded_kernel_under_scan(devices8, monkeypatch, stacked_xs):
     """The per-shard kernel compiles and matches the dense reference INSIDE
-    a ``lax.scan`` — both with scan-invariant (closed-over) weights, the
-    shape of the decode loop, and with stacked weights scanned as xs, the
-    shape of the layer loop."""
+    a ``lax.scan`` — with scan-invariant (closed-over) weights, the shape
+    of the decode loop; with stacked weights scanned as xs; and with the
+    stack closed over and read at the scan's index, the shape of the layer
+    loop."""
     from jax.sharding import NamedSharding
 
     from distributed_llms_tpu.checkpoint.quantize import dequantize, quantize
@@ -211,20 +222,25 @@ def test_sharded_kernel_under_scan(devices8, monkeypatch, stacked_xs):
     L, d = 3, 1024
     w = jax.random.normal(jax.random.key(0), (L, d, d), jnp.float32) * d**-0.5
     qt = quant_lib.quantize(w, bits=8, block=128)
-    wspec = P(None, None, "model")
-    data = jax.device_put(qt.data, NamedSharding(mesh, wspec))
-    scale = jax.device_put(qt.scale, NamedSharding(mesh, wspec))
+    placed = api_lib._place_quantized(qt, P(None, None, "model"), mesh, "w")
+    data, scale = placed.data, placed.scale
+    assert scale.sharding.spec == P(None, "model", None)
     x = jax.device_put(
         jax.random.normal(jax.random.key(1), (4, d), jnp.float32),
         NamedSharding(mesh, P("data", None)),
     )
 
-    def layer(c, d_, s_):
-        q = type(qt)(data=d_, scale=s_, bits=qt.bits,
-                     orig_shape=(d, d), pack_axis=qt.pack_axis)
+    def layer(c, d_, s_, at=None):
+        q = dataclasses.replace(qt, data=d_, scale=s_, layer=at)
         return qm.quant_contract(c, q, 1, "md,df->mf", shard="n")
 
-    if stacked_xs:
+    if stacked_xs == "at":  # the stack closed over, read at the scan's index
+        def f(x_, d_, s_):
+            return jax.lax.scan(
+                lambda c, at: (layer(c, d_, s_, at), None), x_,
+                jnp.arange(L, dtype=jnp.int32),
+            )[0]
+    elif stacked_xs:
         def f(x_, d_, s_):
             return jax.lax.scan(
                 lambda c, xs: (layer(c, *xs), None), x_, (d_, s_)

@@ -1,5 +1,5 @@
-"""What the TPU compiler makes of paged ``decode_chunk``: the pool stays
-where it lies.
+"""What the TPU compiler makes of paged ``decode_chunk``: the pool and the
+quantized weights stay where they lie.
 
 Compiled ahead of time for one v5e on the compile-only TPU client
 (tools/aot_decode.py; no chip), at qwen2-7b's widths with 2 layers and the
@@ -9,6 +9,10 @@ sliced a layer out for the Pallas call, copied it, wrote it into a fresh
 pool-sized stack and copied that stack into the carry: four passes over
 the pool a decode step.  This is the guard against the copy coming back
 with a JAX upgrade; it skips where the installation has no such client.
+Before PR 29 the same program sliced every layer's int8 weights out of
+their stacks for ``quant_matmul`` (a copy each) and re-laid out every
+layer's scales, a third of a decode step: ``weight_shaped`` holds both
+families' programs to none of it.
 """
 
 import dataclasses
@@ -64,6 +68,43 @@ def test_temporaries_hold_no_second_pool(compiled):
 
 
 @pytest.fixture(scope="module")
+def compiled_full_depth():
+    """``decode_chunk`` of qwen2-7b at its 28 layers: the layout the device
+    gives a stack depends on its depth (two layers of scales are stored
+    layer-minor and turned once a program), and a scan compiles as fast
+    at any depth."""
+    from distributed_llms_tpu.models.presets import get_preset
+    from tools import aot_decode
+
+    try:
+        aot_decode.v5e_devices()
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DLT_QUANT_MATMUL", "kernel")
+        mp.setenv("DLT_RAGGED_DECODE", "kernel")
+        return aot_decode.analyse(
+            "decode_chunk", get_preset("qwen2-7b"), slots=16, max_len=4096,
+            pages=PAGES, page_size=BLK,
+        )
+
+
+def test_no_layer_of_a_weight_or_of_its_scales_is_copied(compiled_full_depth):
+    """No instruction, inside the loops or before them, produces an int8
+    array shaped like one layer of wq/wk/wv/wo or of the FFN's matrices
+    ([3584,18944], [18944,3584], [3584,3584], [3584,512]), a float32 one
+    shaped like a layer's scales in either order ([148,3584] and
+    [3584,148], ...), or a whole stack of either: the kernel takes its
+    tiles out of the stacked leaves where they lie."""
+    assert compiled_full_depth["weight_shaped"] == []
+    assert compiled_full_depth["weight_shaped_once"] == []
+    # ...so the step needs next to nothing beside its arguments (0.6 MB;
+    # 0.557 GB with a layer's weights and scales copied out, PR 28).
+    assert compiled_full_depth["temp_gb"] < 0.05
+    assert 10.7 < compiled_full_depth["argument_gb"] < 10.9
+
+
+@pytest.fixture(scope="module")
 def compiled_hybrid():
     """``decode_chunk`` of lfm2-8b-a1b at full depth and the cell's shapes
     (three scanned runs: some 10 s to lower and compile)."""
@@ -93,7 +134,7 @@ def test_hybrid_pool_of_six_layers_is_written_in_place(compiled_hybrid):
     assert all("[6,512,64,4,128]" in e[2] for e in found), found
     pool_bytes = 2 * 6 * PAGES * BLK * 8 * 64 * 2
     assert compiled_hybrid["alias_gb"] * 1e9 >= pool_bytes
-    assert compiled_hybrid["temp_gb"] < 0.3
+    assert compiled_hybrid["temp_gb"] < 0.15
 
 
 def test_no_expert_stack_is_copied_or_dequantized(compiled_hybrid):
@@ -104,3 +145,11 @@ def test_no_expert_stack_is_copied_or_dequantized(compiled_hybrid):
     assert compiled_hybrid["expert_shaped"] == []
     # Weights (8.73 GB) and the pool (0.40 GB) are all the program is given.
     assert 9.0 < compiled_hybrid["argument_gb"] < 9.3
+
+
+def test_no_layer_of_the_other_weights_is_copied_either(compiled_hybrid):
+    """in_proj, out_proj, wq..wo and the dense FFN, stacked by kind of
+    layer, reach ``quant_matmul`` as stacks read at ``at[kind]``: no
+    instruction is shaped like one layer of any of them or of its scales
+    (0.93 GB a step were sliced out before PR 29)."""
+    assert compiled_hybrid["weight_shaped"] == []

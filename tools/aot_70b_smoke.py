@@ -15,6 +15,7 @@ because the fake-device count must be set before JAX backend init.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 import sys
 import time
@@ -59,20 +60,21 @@ def abstract_sharded(tree, specs, mesh):
     def place(kp, leaf):
         spec = spec_by_path[jax.tree_util.keystr(kp)]
         if is_q(leaf):
-            # Mirror _place_quantized's happy path: data and scale take the
-            # weight's spec (shard-divisibility holds for the 70B dims).
-            s = tuple(spec) + (None,) * (leaf.data.ndim - len(tuple(spec)))
-            return quant_lib.QuantizedTensor(
+            # _place_quantized's layout, minus the device_put (no scale is
+            # refined at the 70B dims).
+            from distributed_llms_tpu.parallel.api import quantized_layout
+
+            data, scale, _ = quantized_layout(leaf, spec, mesh, "")
+            return dataclasses.replace(
+                leaf,
                 data=jax.ShapeDtypeStruct(
                     leaf.data.shape, leaf.data.dtype,
-                    sharding=NamedSharding(mesh, P(*s)),
+                    sharding=NamedSharding(mesh, data),
                 ),
                 scale=jax.ShapeDtypeStruct(
                     leaf.scale.shape, leaf.scale.dtype,
-                    sharding=NamedSharding(mesh, P(*s)),
+                    sharding=NamedSharding(mesh, scale),
                 ),
-                bits=leaf.bits, orig_shape=leaf.orig_shape,
-                pack_axis=leaf.pack_axis,
             )
         return jax.ShapeDtypeStruct(
             leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, spec)

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Compile the paged serving programs for one TPU v5e without a chip and
-say what they do to the KV page pool.
+say what they do to the KV page pool and to the quantized weights.
 
 ``decode_chunk`` and the paged admission programs are compiled ahead of
 time on the compile-only TPU client (abstract int8 weights, the served
@@ -9,7 +9,10 @@ instruction of the optimised HLO that produces an array shaped like one
 layer of the pool or like the whole stack.  The pool must stay where it
 lies: the only instructions allowed on that list are the in-place
 scatters of a step's keys and values (tests/runtime/test_aot_pool.py
-holds the decode program to it).
+holds the decode program to it).  Beside it: every instruction shaped like
+a layer's expert stack (``expert_shaped``) and like one layer of a
+quantized weight or of its scales (``weight_shaped``); both lists are
+empty when the kernels read the stacks where they lie.
 
     python tools/aot_decode.py qwen2-7b --slots 16 --max-len 4096 --pages 512
     python tools/aot_decode.py pythia-6.9b --slots 8 --max-len 2048 --pages 96 \
@@ -53,20 +56,26 @@ def _abstract(tree, sharding):
 
 def _abstract_on_mesh(tree, specs, mesh):
     """``tree`` as ShapeDtypeStructs placed by ``specs`` (one
-    PartitionSpec a weight): a quantized leaf's data and scale both take
-    the weight's spec, as parallel.api.quantized_layout gives them
-    wherever the shards divide (they do at the served widths)."""
+    PartitionSpec a weight): a quantized leaf's data and scale take what
+    parallel.api.quantized_layout makes of the weight's spec (they divide
+    at the served widths, so no scale is refined)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from distributed_llms_tpu.checkpoint.quantize import QuantizedTensor
+    from distributed_llms_tpu.parallel.api import quantized_layout
+
+    def sds(x, spec):
+        full = tuple(spec) + (None,) * (x.ndim - len(tuple(spec)))
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, P(*full)))
 
     def place(leaf, spec):
-        def sds(x):
-            full = tuple(spec) + (None,) * (x.ndim - len(tuple(spec)))
-            return jax.ShapeDtypeStruct(
-                x.shape, x.dtype, sharding=NamedSharding(mesh, P(*full)))
-
-        return jax.tree.map(sds, leaf)
+        if not isinstance(leaf, QuantizedTensor):
+            return sds(leaf, spec)
+        data, scale, repeat = quantized_layout(leaf, spec, mesh, "")
+        assert repeat == 1, (leaf, spec)
+        return dataclasses.replace(
+            leaf, data=sds(leaf.data, data), scale=sds(leaf.scale, scale))
 
     return jax.tree.map(
         place, tree, specs, is_leaf=lambda x: isinstance(x, QuantizedTensor)
@@ -158,6 +167,9 @@ _INSTR = re.compile(
 )
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%?[\w.\-]+)\s+\(.*\{\s*$")
 _CALLS = re.compile(r"calls=(%?[\w.\-]+)")
+_CALLED = re.compile(
+    r"(?:calls|to_apply|body|condition|branch_computations)=\{?([^,}\s]+(?:,\s*%?[\w.\-]+)*)\}?")
+_BODY = re.compile(r"body=(%?[\w.\-]+)")
 
 
 def pool_shaped(hlo_text: str, cfg, pages: int, page_size: int,
@@ -201,9 +213,96 @@ def expert_shaped(hlo_text: str, cfg) -> list:
     return shaped_like(hlo_text, shapes + [f"[{d},{2 * f}]"])
 
 
-def shaped_like(hlo_text: str, shapes: list) -> list:
+_MOVES = {"copy", "copy-start", "copy-done", "slice", "slice-start",
+          "slice-done", "dynamic-slice", "transpose", "custom-call",
+          "bitcast", "concatenate"}
+
+
+def weight_shapes(cfg, shards: int = 1) -> list:
+    """The result types :func:`weight_shaped` looks for: an int8 array
+    shaped like one layer of a quantized block weight's matrix, a float32
+    one shaped like one layer of its scales in either order of their axes,
+    or the whole stack of either.  ``shards`` is the size of
+    ``mesh.model``: a device's program holds that share of a weight's rows
+    or of its columns.  The expert stacks are :func:`expert_shaped`'s."""
+    from distributed_llms_tpu.checkpoint.quantize import QuantizedTensor
+    from distributed_llms_tpu.models import model as model_lib
+
+    params = jax.eval_shape(
+        lambda k: model_lib.init_params_quantized(k, cfg, 8),
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    leaves = [q for q in jax.tree.leaves(
+        params["blocks"], is_leaf=lambda x: isinstance(x, QuantizedTensor))
+        if isinstance(q, QuantizedTensor) and q.block_axis == -1
+        and q.data.ndim == 3]
+    shapes = set()
+    for q in leaves:
+        layers, k, n = q.data.shape
+        nb = q.scale.shape[1]
+        for kd, nd in {(1, 1), (shards, 1), (1, shards)}:
+            if k % kd or nb % nd:
+                continue
+            w = f"{k // kd},{n // nd}"
+            shapes |= {f"s8[{w}]", f"s8[{layers},{w}]"}
+            if nb // nd > 1:  # one block a row is a vector like any other
+                sc = (f"{nb // nd},{k // kd}", f"{k // kd},{nb // nd}")
+                shapes |= {f"f32[{x}]" for x in sc}
+                shapes |= {f"f32[{layers},{x}]" for x in sc}
+    return sorted(shapes)
+
+
+def weight_shaped(hlo_text: str, shapes: list, in_loops: bool | None = True,
+                  prefetched: bool = False) -> list:
+    """Every instruction inside the program's loops (the layer scan; in
+    ``decode_chunk`` the step scan around it) whose result has one of
+    :func:`weight_shapes`: a copy or a slice of a layer's int8 weights,
+    or of its scales (a float32 array may be activations of the same
+    shape, lfm2: 16 rows of the dense FFN's 7168, so for the scales only
+    what moves data counts).  The kernel of ops/quant_matmul.py reads a
+    layer's tiles out of the stacked leaves where they lie, so the list is
+    empty when no call site slices a weight and nothing re-lays out a
+    scale.  ``in_loops=False`` lists what runs ONCE a program instead.
+    ``prefetched`` lists, in place of all that, XLA's own asynchronous
+    copies of a stack (or of a quarter of one) into fast memory (``S(1)``
+    in the result's layout): the compiler's choice for an operand of a
+    custom call, made or not by its budget of fast memory, and no slice a
+    call site cut."""
+    found = [e for e in shaped_like(hlo_text, shapes, in_loops)
+             if "s8[" in e[2] or e[0].split(":")[-1] in _MOVES]
+    # (the -done of a copy that leaves fast memory names only where it lands)
+    fast = {e[1].replace("-start", "-done") for e in found if "S(1)" in e[2]}
+    return [e for e in found
+            if ("S(1)" in e[2] or e[1] in fast) == prefetched]
+
+
+def _loop_computations(lines: list) -> set:
+    """Names of the computations that run inside a ``while`` loop: the
+    loops' bodies and everything they call."""
+    callees, bodies, computation = {}, set(), None
+    for line in lines:
+        c = _COMPUTATION.match(line)
+        if c:
+            computation = c.group(1)
+        names = _CALLED.findall(line)
+        callees.setdefault(computation, set()).update(
+            n for group in names for n in re.findall(r"%?[\w.\-]+", group))
+        bodies.update(_BODY.findall(line))
+    inside, todo = set(), list(bodies)
+    while todo:
+        name = todo.pop()
+        if name not in inside:
+            inside.add(name)
+            todo.extend(callees.get(name, ()))
+    return inside
+
+
+def shaped_like(hlo_text: str, shapes: list, in_loops: bool | None = None
+                ) -> list:
     """(opcode, name, result type) of every instruction of the optimised
-    HLO whose result type holds one of ``shapes`` (``"[a,b,c]"``)."""
+    HLO whose result type holds one of ``shapes`` (``"[a,b,c]"``).
+    ``in_loops`` keeps only the instructions that run inside a ``while``
+    loop, a layer scan or a step scan (True), or only those that run once
+    a program (False)."""
     skip = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
             "conditional", "call", "opt-barrier"}
     roots, computation = {}, None
@@ -215,10 +314,16 @@ def shaped_like(hlo_text: str, shapes: list) -> list:
         m = _INSTR.match(line)
         if m and m.group(1):
             roots[computation] = m.group(4)
+    loops = _loop_computations(lines) if in_loops is not None else set()
     found = []
     for line in lines:
+        c = _COMPUTATION.match(line)
+        if c:
+            computation = c.group(1)
         m = _INSTR.match(line)
         if not m or m.group(4) in skip:
+            continue
+        if in_loops is not None and (computation in loops) != in_loops:
             continue
         if any(s in m.group(3) for s in shapes):
             opcode = m.group(4)
@@ -239,11 +344,17 @@ def analyse(program: str, cfg, **shape_kw) -> dict:
     compiled = lower_program(program, cfg, **shape_kw).compile()
     mem = compiled.memory_analysis()
     text = compiled.as_text()
+    shapes = weight_shapes(cfg, shape_kw.get("mesh_model", 1))
     found = pool_shaped(text, cfg, shape_kw["pages"],
                         shape_kw.get("page_size", 64),
                         shape_kw.get("mesh_model", 1))
     return {
         "expert_shaped": [list(e) for e in expert_shaped(text, cfg)],
+        "weight_shaped": [list(e) for e in weight_shaped(text, shapes)],
+        "weight_shaped_once": [
+            list(e) for e in weight_shaped(text, shapes, in_loops=False)],
+        "weight_prefetched": [list(e) for e in weight_shaped(
+            text, shapes, in_loops=None, prefetched=True)],
         "program": program,
         "argument_gb": mem.argument_size_in_bytes / 1e9,
         "output_gb": mem.output_size_in_bytes / 1e9,

@@ -94,6 +94,35 @@ def quant_parity() -> None:
             # signal; match the suite's bf16 tolerance.
             check(f"quant int{bits} [{m}x{k}]@[{k}x{n}]", got, want,
                   rtol=2e-2, atol=2e-2)
+    # The stacked form the layer scans hand over: every layer's weight in
+    # one operand, read at an index traced inside a scan, at qwen2-7b's
+    # shapes (tiles of seven row runs for w_gate, of seven column pieces
+    # for w_down; wo's two contracted axes) and as int4 (one piece a tile).
+    for bits, (k_shape, n), layers in (
+            (8, ((3584,), 18944), 2), (8, ((18944,), 3584), 2),
+            (8, ((28, 128), 3584), 3), (4, ((4096,), 1024), 3)):
+        kx, kw = jax.random.split(jax.random.fold_in(key, bits + n))
+        x = jax.random.normal(kx, (16, *k_shape), jnp.bfloat16)
+        k = int(np.prod(k_shape))
+
+        def draw(i):
+            w = jax.random.normal(jax.random.fold_in(kw, i), (*k_shape, n),
+                                  jnp.float32) / np.sqrt(k)
+            qt = quantize(w, bits=bits, k_axes=len(k_shape))
+            return qt.data, qt.scale
+
+        data, scale = jax.jit(lambda: jax.lax.map(draw, jnp.arange(layers)))()
+        qt = QuantizedTensor(data=data, scale=scale, bits=bits,
+                             orig_shape=(layers, *k_shape, n),
+                             k_axes=len(k_shape))
+        got = jax.jit(lambda x, qt: jax.lax.scan(
+            lambda c, at: (c, quant_contract(x, qt.at(at), len(k_shape))),
+            None, jnp.arange(layers, dtype=jnp.int32))[1])(x, qt)
+        want = jnp.einsum(
+            "mk,lkn->lmn", jnp.asarray(x, jnp.float32).reshape(16, k),
+            dequantize(qt, jnp.float32).reshape(layers, k, n))
+        check(f"quant int{bits} stacked L{layers} [16x{k}]@[{k}x{n}]", got,
+              want, rtol=2e-2, atol=2e-2)
 
 
 def flash_parity() -> None:
@@ -328,6 +357,10 @@ def main() -> int:
             for k, v in METRICS.snapshot()["counters"].items()
             if k.startswith("ops.dispatch.")}
     print(f"  dispatch record: {took}")
+    # (quant_matmul.stacked counts stacks handed over whole, beside the path)
+    stacked = took.pop("quant_matmul.stacked", 0)
+    if stacked != 4:
+        raise AssertionError(f"{stacked} of 4 stacked legs went in as stacks")
     stray = {k: v for k, v in took.items() if not k.endswith("." + MODE)}
     if stray:
         raise AssertionError(f"legs dispatched off the {MODE} path: {stray}")
@@ -336,8 +369,9 @@ def main() -> int:
     # head geometries the benchmark serves, as one layer's pages and as a
     # layer of the stacked pool — 21 legs.  v6: flash and the paged kernel at
     # a head of 64, and the expert kernel at a decode step's and an
-    # admission's pairs — 25 legs.
-    print(f"kernel_parity: ALL PASS v6 ({mode}, backend={backend})")
+    # admission's pairs — 25 legs.  v7: quant_matmul's stacks read at an
+    # index, at qwen2-7b's shapes — 29 legs.
+    print(f"kernel_parity: ALL PASS v7 ({mode}, backend={backend})")
     return 0
 
 
