@@ -242,9 +242,10 @@ def _should_quantize(path: str, x: Any) -> bool:
 def leaf_plan(path: str, x: Any) -> tuple[bool, int, int]:
     """(quantize?, k_axes, n_axes) for a named leaf — the single source of
     truth for which leaves quantize and which of their axes contract,
-    shared by quantize_tree and streaming builders (bench.py
-    generates-and-quantizes on device leaf by leaf and must make the exact
-    decisions the serving path makes).  A leaf with heads that some family
+    shared by quantize_tree and streaming builders
+    (models.model.init_params_quantized generates and quantizes on the
+    device leaf by leaf and must make the exact decisions the serving path
+    makes).  A leaf with heads that some family
     stores flat already (the hybrid's wq [D, H * hd]) is a plain matrix."""
     if not _should_quantize(path, x):
         return False, 1, 1
@@ -281,7 +282,7 @@ def dequantize_tree(params: Any, dtype: Any = None) -> Any:
 
 
 # ---------------------------------------------------------------------------
-# KV-cache quantization (int8 KV pages, runtime/batcher.py PagePool tiering)
+# KV-cache quantization (int8 KV pages: models/kv_cache.py QuantKVCache)
 #
 # The same absmax scheme as quantize()/dequantize() above, specialised to the
 # KV layout: one float32 scale per head-dim VECTOR (block == head_dim along

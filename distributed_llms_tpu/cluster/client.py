@@ -3,8 +3,8 @@ coordinator for status/metrics or submit generation, over the JSON protocol.
 Plus :class:`ServingClient`, an overload-aware HTTP client for the serving
 gateway (runtime/server.py): 429/503 answers carry ``Retry-After``, and the
 client honors it with jittered exponential backoff on top — the polite-load
-half of the server's shedding contract (bench.py's overload ladder row and
-the overload tests drive traffic through it)."""
+half of the server's shedding contract (the overload tests drive traffic
+through it)."""
 
 from __future__ import annotations
 

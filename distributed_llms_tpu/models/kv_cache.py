@@ -1,0 +1,464 @@
+"""How keys and values are stored: the cache pytrees and everything that
+knows their layout.  Nothing outside this module tells one format from
+another (tests/models/test_kv_cache.py): ``models.model.forward`` writes a
+step's tokens (:func:`write_tokens`) and hands the kernel its operands,
+the batcher's programs move whole pages (:func:`write_row`,
+:func:`gather_row`, :func:`export_raw`, :func:`import_raw`,
+:func:`import_full`), the mesh places the pool by :func:`pool_specs`, and
+what a format cannot do yet is refused in :func:`refuse_unpaged_state`.
+A new format is a case in :func:`_paged_fields` and :func:`_encode` first.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from dataclasses import dataclass
+from functools import partial
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ..core.config import ModelConfig
+
+
+@jax.tree_util.register_dataclass
+@dataclass
+class KVCache:
+    """Preallocated per-layer KV cache, [L, B, S, KVH, HD].
+
+    Under sequence parallelism ``k``/``v`` are two-region tuples
+    ``(prefill, decode)`` instead (see models.model._seq_cached_attention);
+    every consumer treats the fields as opaque pytrees."""
+
+    k: Any
+    v: Any
+
+    @property
+    def max_len(self) -> int:
+        if isinstance(self.k, tuple):  # seq-parallel two-region layout
+            return self.k[0].shape[2] + self.k[1].shape[2]
+        return self.k.shape[2]
+
+
+@dataclass
+class QuantKVCache:
+    """Int8-quantized KV page pool (``--kv-bits 8`` tiering): ``k``/``v``
+    hold the pool pages at int8 ([L, NB, BLK, KVH, HD]) and
+    ``k_scale``/``v_scale`` one float32 absmax scale per head-dim vector
+    ([L, NB, BLK, KVH] — checkpoint.quantize.kv_quantize's layout).  Pages
+    are quantized ONCE at the write (admission splice / decode-step
+    scatter) and dequantized inside the attention read (the decode
+    kernel's int8 leg folds the scales into the contraction), so pool
+    storage is never materialized full-width.  ``row_dtype`` names the
+    dequantized dtype transient row caches (and gathers) restore to —
+    static metadata, so jit keys stay stable.
+
+    Decode-only through ``models.model.forward`` (requires ``kv_tables``):
+    the contiguous per-row and prefill paths keep full-width caches."""
+
+    k: Any
+    v: Any
+    k_scale: Any
+    v_scale: Any
+    row_dtype: str = "bfloat16"
+
+
+# data/scales are pytree children; row_dtype is static metadata (hashable,
+# part of the jit key — exactly how QuantizedTensor registers its bits).
+jax.tree_util.register_dataclass(
+    QuantKVCache,
+    data_fields=["k", "v", "k_scale", "v_scale"],
+    meta_fields=["row_dtype"],
+)
+
+
+@jax.tree_util.register_dataclass
+@dataclass
+class HybridCache(KVCache):
+    """Cache of a model whose layers differ (family "hybrid"): two kinds of
+    state a row.  ``k``/``v`` as in :class:`KVCache` (contiguous or the page
+    pool), their layer axis counting the ATTENTION layers only
+    (``cfg.attn_layers``); ``conv`` [conv layers, B, K-1, D] holds each
+    short-convolution layer's last K-1 gated inputs a row (a batch slot of
+    the batcher: the state is not paged), in the activations' dtype."""
+
+    conv: Any
+
+
+def init_cache(
+    cfg: ModelConfig, batch: int, max_len: int, dtype: Any = None,
+    prompt_len: int | None = None,
+) -> KVCache:
+    """``prompt_len`` is part of the shared make_cache protocol (the
+    seq-parallel cache splits regions there); the dense layout ignores it."""
+    del prompt_len
+    dtype = dtype or jnp.dtype(cfg.dtype)
+    shape = (len(cfg.attn_layers), batch, max_len, cfg.num_kv_heads,
+             cfg.head_dim_)
+    k, v = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    if cfg.family != "hybrid":
+        return KVCache(k=k, v=v)
+    return HybridCache(k=k, v=v, conv=conv_state(cfg, batch))
+
+
+def conv_state(cfg: ModelConfig, rows: int) -> jax.Array:
+    """What a :class:`HybridCache` holds beside k and v, zeroed: the
+    convolution state of ``rows`` rows."""
+    return jnp.zeros(
+        (len(cfg.conv_layers), rows, cfg.conv_kernel - 1, cfg.hidden_size),
+        jnp.dtype(cfg.dtype))
+
+
+# ---------------------------------------------------------------------------
+# The page pool: construction, size, placement
+# ---------------------------------------------------------------------------
+
+def make_pool(cfg: ModelConfig, num_pages: int, page_size: int,
+              kv_bits: int = 16, dtype=None, slots: int = 0):
+    """KV page pools [L, NB, BLK, KVH, HD] (distinct k/v buffers — the
+    chunk fns donate the cache).  Each is ONE stack of every layer's
+    pages and stays one: the decode programs carry it through the layer
+    scan and update it in place (models.model.run_blocks), the paged
+    kernel reads (layer, page) out of it, and admissions write whole
+    pages into it (:func:`write_row`); nothing holds a layer's slice
+    or a second stack.  ``kv_bits=8`` builds an int8 :class:`QuantKVCache`
+    pool (data int8 + one f32 absmax scale per head-dim vector) at roughly
+    half the bytes per token; the full-width dtype survives as
+    ``row_dtype`` so gathers/transient rows restore to it.  A hybrid
+    model's pool counts its attention layers only and comes with the
+    state that is not paged: each convolution layer's, one entry a batch
+    slot (``slots``), in a :class:`HybridCache`."""
+    from ..ops.decode_attn import pool_head_shape
+
+    l = len(cfg.attn_layers)
+    kvh, hd = pool_head_shape(cfg.num_kv_heads, cfg.head_dim_,
+                              fold_narrow=pages_are_private(cfg))
+    dt = jnp.dtype(dtype) if dtype else jnp.dtype(cfg.dtype)
+    shape = (l, num_pages, page_size, kvh, hd)
+    if cfg.family == "hybrid":
+        return HybridCache(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt),
+                           conv=conv_state(cfg, slots))
+    if kv_bits == 8:
+        sshape = (l, num_pages, page_size, kvh)
+        return QuantKVCache(
+            k=jnp.zeros(shape, jnp.int8), v=jnp.zeros(shape, jnp.int8),
+            k_scale=jnp.ones(sshape, jnp.float32),
+            v_scale=jnp.ones(sshape, jnp.float32),
+            row_dtype=dt.name,
+        )
+    return KVCache(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt))
+
+
+def page_bytes(cfg: ModelConfig, page_size: int, kv_bits: int = 16,
+               dtype=None) -> int:
+    """Bytes one pool page costs (k + v + scales, every layer): those of
+    :func:`make_pool`'s paged leaves at one page."""
+    pool = jax.eval_shape(
+        lambda: make_pool(cfg, 1, page_size, kv_bits, dtype))
+    return sum(x.size * x.dtype.itemsize
+               for x in (getattr(pool, f) for f in _paged_fields(pool)))
+
+
+def _paged_fields(pool) -> tuple[str, ...]:
+    """The pool's leaves that have a page axis ([L, NB, BLK, ...]), in the
+    tree's order.  What else a format holds (a hybrid model's state a
+    batch slot) is not paged and moves with no page."""
+    match pool:
+        case QuantKVCache():
+            return ("k", "v", "k_scale", "v_scale")
+        case _:
+            return ("k", "v")
+
+
+def _encode(pool, k: jax.Array, v: jax.Array) -> tuple:
+    """Keys and values [..., KVH, HD] as the pool stores them, one array a
+    paged field.  An int8 pool quantizes each head-dim vector once, here,
+    at the write (checkpoint.quantize.kv_quantize: int8 data + one f32
+    absmax scale); a full-width pool casts, and lays the last two axes out
+    as its own (narrow heads lie folded there,
+    ops.decode_attn.pool_head_shape; the same bytes)."""
+    match pool:
+        case QuantKVCache():
+            from ..checkpoint.quantize import kv_quantize
+
+            (k, ks), (v, vs) = kv_quantize(k), kv_quantize(v)
+            return (k, v, ks, vs)
+        case _:
+            return tuple(
+                x.astype(leaf.dtype).reshape(*x.shape[:-2], *leaf.shape[3:])
+                for x, leaf in zip((k, v), (pool.k, pool.v)))
+
+
+def row_dtype(pool) -> Any:
+    """Dtype transient single-row caches (and pool gathers) use: the
+    pool's own dtype, or the declared full-width dtype of an int8 pool.
+    Safe inside jit — the pytree TYPE of ``pool`` is static."""
+    match pool:
+        case QuantKVCache():
+            return jnp.dtype(pool.row_dtype)
+        case _:
+            return pool.k.dtype
+
+
+def pool_specs(cfg: ModelConfig, mesh: Mesh, pool) -> Any:
+    """PartitionSpec pytree of ``pool``'s own structure (the pool or its
+    ``jax.eval_shape``): paged data leaves [L, NB, BLK, KVH, HD] shard the
+    KV-head axis over 'model' (Megatron-style tensor parallelism — each
+    chip holds its heads' slice of every page, so per-chip pool bytes
+    divide by tp); int8 absmax scales [L, NB, BLK, KVH] shard the same
+    axis; what is not paged replicates.  Pages are shared across rows
+    (prefix cache, handoff imports), so the page axis never shards over
+    'data' — scheduling state replicates instead.  Non-divisible KV heads
+    replicate (the batcher REJECTS that combination up front; the spec
+    mirrors param_specs' degrade convention so the graftcheck GC2 audit
+    stays total over the mesh ladder)."""
+    tp = mesh.shape.get("model", 1)
+    kv_ax = "model" if cfg.num_kv_heads % max(tp, 1) == 0 else None
+    specs = jax.tree.map(lambda _: P(), pool)
+    return dataclasses.replace(specs, **{
+        f: P(None, None, None, kv_ax, *(None,) * (getattr(pool, f).ndim - 4))
+        for f in _paged_fields(pool)
+    })
+
+
+def constrain(pm, pool):
+    """Pin a page pool's leaves to their mesh sharding (:func:`pool_specs`),
+    the layout every paged program produces and consumes on a mesh
+    batcher.  Applied to every program output that carries the pool
+    (splice, decode chunk, import scatters) so XLA can never hand back a
+    differently-placed pool and force a resharding copy (or a fresh
+    compile key) on the next call.  No-op single-device (``pm`` None)."""
+    if pm is None:
+        return pool
+    return jax.tree.map(
+        lambda x, s: jax.lax.with_sharding_constraint(
+            x, NamedSharding(pm.mesh, s)
+        ),
+        pool, pool_specs(pm.cfg, pm.mesh, pool),
+    )
+
+
+# ---------------------------------------------------------------------------
+# The movements of pages
+# ---------------------------------------------------------------------------
+
+def write_tokens(pool, layer, page, off, k: jax.Array, v: jax.Array):
+    """Scatter the new tokens' keys and values ([B, T, KVH, HD]) into
+    layer ``layer`` of the pool at (``page``, ``off``) [B, T], where the
+    stacks lie (they are the layer scan's carry).
+
+    LIVE rows own distinct pages, but FREED rows' tables are zeroed to the
+    shared scratch page, so two inactive rows CAN produce identical
+    (page, off) indices — the scatter must tolerate duplicates (XLA picks
+    a winner; the scratch page is never read by a live row).  Do NOT add
+    unique_indices=True here."""
+    return dataclasses.replace(pool, **{
+        f: getattr(pool, f).at[layer, page, off].set(x)
+        for f, x in zip(_paged_fields(pool), _encode(pool, k, v))
+    })
+
+
+def kernel_operands(pool) -> tuple[jax.Array, jax.Array, dict]:
+    """(k pages, v pages, scales) as ops.decode_attn.paged_decode_attention
+    takes them: ``scales`` is its ``k_scale``/``v_scale`` keywords on an
+    int8 pool (the kernel's int8 leg folds them into the attention
+    contraction, so the pool is read at 1 byte/elem and never dequantized
+    in HBM), empty otherwise."""
+    return pool.k, pool.v, {
+        f: getattr(pool, f) for f in _paged_fields(pool)[2:]}
+
+
+def _write_pages(pools: tuple, page_list: jax.Array, pages: tuple) -> tuple:
+    """Write ``pages`` ([L, P, ...] a leaf) into the donated pool stacks
+    ([L, NB, ...]) at ``page_list`` [P], where the stacks lie: one
+    ``dynamic_update_slice`` a page, the stacks the loop's carry.  As one
+    scatter on the page axis (``pool.at[:, page_list].set``) the compiler
+    moves a pool of few KV heads (qwen2's 4) into a layout of its own,
+    scatters there and moves it back: four pool-sized copies an admission
+    (AOT compile for the v5e, PR 26).  A page listed twice (the scratch
+    page pads the list) keeps the last write."""
+
+    def write(i, pools):
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                pool, jax.lax.dynamic_slice_in_dim(new, i, 1, axis=1),
+                page_list[i], axis=1,
+            )
+            for pool, new in zip(pools, pages)
+        )
+
+    return jax.lax.fori_loop(0, page_list.shape[0], write, pools)
+
+
+def write_row(pool, page_list: jax.Array, row_cache, slot=None):
+    """Write a contiguous transient row cache ([L, 1, P*BLK, KVH, HD]
+    leaves) into the row's pages.  ``page_list`` [P] is padded with the
+    reserved scratch page 0 past the allocation, so the fixed-shape write
+    stays compiled once — the extra writes land in the scratch page, whose
+    contents no LIVE row ever reads (freed rows' clamped decode reads do
+    touch it, but their outputs are masked to pad).  Prefix-cache-hit
+    admissions also route their CACHED positions to the scratch page: the
+    shared pages already hold exactly that KV and must never be rewritten
+    while other rows read them.  A :class:`HybridCache` also takes the
+    row's convolution state into batch slot ``slot`` (all of it: whatever
+    the slot's last row left is overwritten)."""
+    p = page_list.shape[0]
+    blk = pool.k.shape[2]
+
+    def as_pages(row):  # [L, 1, P*BLK, KVH, HD] -> [L, P, BLK, KVH, HD]
+        # (the pool's own last two axes: narrow heads may lie folded there)
+        return row[:, 0].reshape(row.shape[0], p, blk, *pool.k.shape[3:])
+
+    fields = _paged_fields(pool)
+    leaves = _write_pages(
+        tuple(getattr(pool, f) for f in fields), page_list,
+        _encode(pool, as_pages(row_cache.k), as_pages(row_cache.v)),
+    )
+    new = dict(zip(fields, leaves))
+    match pool:
+        case HybridCache():
+            new["conv"] = jax.lax.dynamic_update_slice_in_dim(
+                pool.conv, row_cache.conv.astype(pool.conv.dtype), slot,
+                axis=1,
+            )
+    return dataclasses.replace(pool, **new)
+
+
+@jax.jit
+def gather_row(pool, read_list: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Gather a row's pages out of the pool into a transient contiguous
+    row cache ([L, 1, P*BLK, KVH, HD] k/v pair) — the chunked-prefill
+    analogue of admit_row_auto_paged's in-program gather.  A cache-hit
+    chunked admission seeds its transient row from the shared pages ONCE
+    (the "prefix" is then already resident, exactly as if those chunks had
+    run), and only the un-cached suffix chunks through the model.  The
+    outputs are fresh buffers, so every later prefill_chunk_step may
+    donate them.  An int8 pool dequantizes the gathered pages to its
+    ``row_dtype``: transient rows always run full-width; only POOL storage
+    is quantized."""
+    l, _, blk, kvh, hd = pool.k.shape
+    p = read_list.shape[0]
+    match pool:
+        case QuantKVCache():
+            from ..checkpoint.quantize import kv_dequantize
+
+            dt = jnp.dtype(pool.row_dtype)
+
+            def gather(pages, scale):
+                full = kv_dequantize(
+                    pages[:, read_list], scale[:, read_list], dt)
+                return full.reshape(l, 1, p * blk, kvh, hd)
+
+            return gather(pool.k, pool.k_scale), gather(pool.v, pool.v_scale)
+        case _:
+            return tuple(x[:, read_list].reshape(l, 1, p * blk, kvh, hd)
+                         for x in (pool.k, pool.v))
+
+
+@jax.jit
+def export_raw(pool, page_list: jax.Array) -> tuple:
+    """Gather pages VERBATIM in pool layout and pool dtype — one page
+    stack a paged field (k, v, and the scale stacks on an int8 pool).
+    This is the host-tier parcel format (swap-preemption, prefix-cache
+    spill): re-importing the exact bytes via :func:`import_raw` restores
+    the pool state bit-for-bit, which is what makes a swap-restored row's
+    stream byte-exact against its never-preempted run at EITHER kv
+    width."""
+    return tuple(getattr(pool, f)[:, page_list] for f in _paged_fields(pool))
+
+
+@partial(jax.jit, static_argnames=("pm",))
+def import_raw(pool, page_list: jax.Array, *pages: jax.Array, pm: Any = None):
+    """Scatter a raw host-tier parcel (:func:`export_raw`'s layout) back
+    into freshly allocated pool pages, verbatim — no quantize/dequantize
+    hop, so restore is exact by construction."""
+    return constrain(pm, dataclasses.replace(pool, **{
+        f: getattr(pool, f).at[:, page_list].set(x)
+        for f, x in zip(_paged_fields(pool), pages, strict=True)
+    }))
+
+
+@partial(jax.jit, static_argnames=("pm",))
+def import_full(pool, page_list: jax.Array, k_pages: jax.Array,
+                v_pages: jax.Array, pm: Any = None):
+    """Scatter HANDED-OFF KV pages into the pool (disaggregated serving:
+    a prefill-role engine shipped a finished row's pages over
+    cluster/kv_transfer.py and this decode-role engine adopts them).
+    ``k_pages``/``v_pages`` are full-width [L, P, BLK, KVH, HD] page
+    stacks; ``page_list`` [P] names the freshly allocated destination
+    pages.  An int8 pool re-quantizes the payload on the way in —
+    byte-stable when the payload was itself dequantized from int8 pages
+    (kv_quantize's exact round-trip property), which is how a kv-bits-8
+    fleet ships pages without a second lossy step.  The pool is NOT
+    donated: import is a rare, off-hot-path event and the caller reuses
+    the returned pool exactly like the admission splices do."""
+    return constrain(pm, dataclasses.replace(pool, **{
+        f: getattr(pool, f).at[:, page_list].set(x)
+        for f, x in zip(_paged_fields(pool), _encode(pool, k_pages, v_pages))
+    }))
+
+
+# ---------------------------------------------------------------------------
+# What a format cannot do yet
+# ---------------------------------------------------------------------------
+
+def pages_are_private(cfg: ModelConfig) -> bool:
+    """True where nothing but the admission's splice and the decode step
+    ever touches a page: :func:`refuse_unpaged_state` has refused every
+    feature that reads [.., KVH, HD] rows out of the pool (prefix cache,
+    named prefixes, tiering, import/export, chunked prefill, speculation,
+    the int8 pool, a mesh), which it does for the family that keeps
+    convolution state beside its pages.  Only then may heads narrower than
+    a 128-lane row lie folded in the pool
+    (ops.decode_attn.pool_head_shape; heads of 128 never fold)."""
+    return cfg.family == "hybrid"
+
+
+def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
+    """Refuse, by name and with the reason, every feature that moves or
+    keeps keys and values and does not yet carry the state a hybrid model
+    holds beside them (each convolution layer's last gated inputs a row:
+    :class:`HybridCache`).  Served anyway, such a feature
+    would hand a row its pages without its state.  ``asked`` maps a
+    feature's name to whether it was asked for; ``paged_pages`` is the one
+    that must be set."""
+    if not pages_are_private(cfg):
+        return
+    why = {
+        "prefix_cache": "a cached page run restores keys and values, not "
+                        "the convolution state at its end",
+        "kv_bits": "the int8 pool's write path knows no convolution state "
+                   "(ask for kv_bits 16)",
+        "host_pages": "the host tier and swap-out park pages, not the "
+                      "convolution state that belongs to them",
+        "speculative": "a rejected draft would have to roll the "
+                       "convolution state back",
+        "prefill_chunk": "a chunked prefill would have to hand the "
+                         "convolution state from bite to bite",
+        "token_budget": "it chunks prefills, which would have to hand the "
+                        "convolution state from bite to bite",
+        "mesh": "the convolution state and the expert stacks have no "
+                "sharding rule yet (mesh.model > 1 included)",
+        "named_prefix": "a registered prefix keeps keys and values, not "
+                        "the convolution state at its end",
+        "kv_import": "KV import/export ships pages, not convolution state",
+        "kv_export": "KV import/export ships pages, not convolution state",
+        "sessions": "a session keeps keys and values between turns, not "
+                    "the convolution state",
+        "padded_generate": "generate_text pads rows of unlike length, and "
+                           "the convolution state would be taken at the "
+                           "padded end; serve through continuous_batcher",
+    }
+    if asked.pop("paged_pages", 1) is None:
+        raise ValueError(
+            f"{cfg.family} model: the batcher serves its keys and values "
+            "from the page pool only; pass paged_pages"
+        )
+    for name, value in asked.items():
+        if value:
+            raise ValueError(
+                f"{name} is not supported for a model with convolution "
+                f"state (family {cfg.family!r}): {why[name]}"
+            )

@@ -20,107 +20,14 @@ from __future__ import annotations
 import dataclasses
 import functools
 import zlib
-from dataclasses import dataclass
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
 from ..core.config import ModelConfig
-from . import layers
+from . import kv_cache, layers
 from .layers import Params
-
-
-# ---------------------------------------------------------------------------
-# KV cache
-# ---------------------------------------------------------------------------
-
-@jax.tree_util.register_dataclass
-@dataclass
-class KVCache:
-    """Preallocated per-layer KV cache, [L, B, S, KVH, HD].
-
-    Under sequence parallelism ``k``/``v`` are two-region tuples
-    ``(prefill, decode)`` instead (see models.model._seq_cached_attention);
-    every consumer treats the fields as opaque pytrees."""
-
-    k: Any
-    v: Any
-
-    @property
-    def max_len(self) -> int:
-        if isinstance(self.k, tuple):  # seq-parallel two-region layout
-            return self.k[0].shape[2] + self.k[1].shape[2]
-        return self.k.shape[2]
-
-
-@dataclass
-class QuantKVCache:
-    """Int8-quantized KV page pool (``--kv-bits 8`` tiering): ``k``/``v``
-    hold the pool pages at int8 ([L, NB, BLK, KVH, HD]) and
-    ``k_scale``/``v_scale`` one float32 absmax scale per head-dim vector
-    ([L, NB, BLK, KVH] — checkpoint.quantize.kv_quantize's layout).  Pages
-    are quantized ONCE at the write (admission splice / decode-step
-    scatter) and dequantized inside the attention read (the decode
-    kernel's int8 leg folds the scales into the contraction), so pool
-    storage is never materialized full-width.  ``row_dtype`` names the
-    dequantized dtype transient row caches (and gathers) restore to —
-    static metadata, so jit keys stay stable.
-
-    Decode-only through :func:`forward` (requires ``kv_tables``): the
-    contiguous per-row and prefill paths keep full-width caches."""
-
-    k: Any
-    v: Any
-    k_scale: Any
-    v_scale: Any
-    row_dtype: str = "bfloat16"
-
-
-# data/scales are pytree children; row_dtype is static metadata (hashable,
-# part of the jit key — exactly how QuantizedTensor registers its bits).
-jax.tree_util.register_dataclass(
-    QuantKVCache,
-    data_fields=["k", "v", "k_scale", "v_scale"],
-    meta_fields=["row_dtype"],
-)
-
-
-@jax.tree_util.register_dataclass
-@dataclass
-class HybridCache(KVCache):
-    """Cache of a model whose layers differ (family "hybrid"): two kinds of
-    state a row.  ``k``/``v`` as in :class:`KVCache` (contiguous or the page
-    pool), their layer axis counting the ATTENTION layers only
-    (``cfg.attn_layers``); ``conv`` [conv layers, B, K-1, D] holds each
-    short-convolution layer's last K-1 gated inputs a row (a batch slot of
-    the batcher: the state is not paged), in the activations' dtype."""
-
-    conv: Any
-
-
-def init_cache(
-    cfg: ModelConfig, batch: int, max_len: int, dtype: Any = None,
-    prompt_len: int | None = None,
-) -> KVCache:
-    """``prompt_len`` is part of the shared make_cache protocol (the
-    seq-parallel cache splits regions there); the dense layout ignores it."""
-    del prompt_len
-    dtype = dtype or jnp.dtype(cfg.dtype)
-    shape = (len(cfg.attn_layers), batch, max_len, cfg.num_kv_heads,
-             cfg.head_dim_)
-    k, v = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
-    if cfg.family != "hybrid":
-        return KVCache(k=k, v=v)
-    return HybridCache(k=k, v=v, conv=conv_state(cfg, batch))
-
-
-def conv_state(cfg: ModelConfig, rows: int) -> jax.Array:
-    """What a :class:`HybridCache` holds beside k and v, zeroed: the
-    convolution state of ``rows`` rows."""
-    return jnp.zeros(
-        (len(cfg.conv_layers), rows, cfg.conv_kernel - 1, cfg.hidden_size),
-        jnp.dtype(cfg.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -130,12 +37,11 @@ def conv_state(cfg: ModelConfig, rows: int) -> jax.Array:
 def _paged_attention(q, k, v, p, pool, layer, cache_index, kv_tables):
     """Attention of T new tokens a row (T static: 1 in a decode step,
     spec_k + 1 in the speculative draft/verify pass) against the page
-    pool.  ``pool`` is the WHOLE stack, every layer's pages — leaves
-    [L, NB, BLK, KVH, HD], and on an int8 pool (four leaves) the scale
-    stacks [L, NB, BLK, KVH] — and ``layer`` the traced index of this
-    layer: the stack is the layer scan's carry, written where it lies and
-    read by the kernel through (layer, page), so no program ever holds a
-    layer's slice of it as a buffer of its own.
+    pool.  ``pool`` is the WHOLE stack, every layer's pages (a page-pool
+    pytree of models/kv_cache.py, whatever its format), and ``layer`` the
+    traced index of this layer: the stack is the layer scan's carry,
+    written where it lies and read by the kernel through (layer, page), so
+    no program ever holds a layer's slice of it as a buffer of its own.
 
     K/V for all T tokens scatter through the page table first (row b's
     slots cache_index[b]..+T-1 — the caller's growth loop guaranteed pages
@@ -145,43 +51,23 @@ def _paged_attention(q, k, v, p, pool, layer, cache_index, kv_tables):
     before it.  The reads unroll into T kernel calls inside ONE compiled
     program.  Rollback is free: slots past the committed frontier hold
     junk no read ever admits (lengths cap every read), awaiting overwrite.
-
-    LIVE rows own distinct pages, but FREED rows' tables are zeroed to the
-    shared scratch page, so two inactive rows CAN produce identical
-    (page, off) indices — the scatter must tolerate duplicates (XLA picks
-    a winner; the scratch page is never read by a live row).  Do NOT add
-    unique_indices=True here.
-
-    An int8 pool quantizes each new K/V vector per (row, head) once, at
-    the write (checkpoint.quantize.kv_quantize), and hands the kernel the
-    scales: its int8 leg folds them into the attention contraction, so
-    the pool is read at 1 byte/elem and never dequantized in HBM."""
+    How a token is stored (an int8 pool quantizes it once, at this write)
+    and what the kernel is handed are the format's:
+    kv_cache.write_tokens, kv_cache.kernel_operands."""
     from ..ops import decode_attn
 
     t_w = q.shape[1]
     rows = jnp.arange(q.shape[0], dtype=jnp.int32)
-    blk = pool[0].shape[2]
+    blk = pool.k.shape[2]
     idx = cache_index[:, None] + jnp.arange(t_w, dtype=jnp.int32)[None, :]
     page = kv_tables[rows[:, None], idx // blk]  # [B, T]
     off = idx % blk
-    if len(pool) == 4:
-        from ..checkpoint.quantize import kv_quantize
-
-        (k, ks), (v, vs) = kv_quantize(k), kv_quantize(v)  # [B, T, KVH, HD]
-        new = (k, v, ks, vs)  #                    i8 and [B, T, KVH] f32
-    else:
-        # (reshaped to the pool's last two axes: narrow heads lie folded
-        # there, ops.decode_attn.pool_head_shape; the same bytes)
-        new = tuple(x.astype(leaf.dtype).reshape(*x.shape[:2], *leaf.shape[3:])
-                    for x, leaf in zip((k, v), pool))
-    pool = tuple(
-        leaf.at[layer, page, off].set(x) for leaf, x in zip(pool, new)
-    )
-    scales = dict(zip(("k_scale", "v_scale"), pool[2:]))
+    pool = kv_cache.write_tokens(pool, layer, page, off, k, v)
+    k_pages, v_pages, scales = kv_cache.kernel_operands(pool)
     out = jnp.concatenate(
         [
             decode_attn.paged_decode_attention(
-                q[:, j: j + 1], pool[0], pool[1], cache_index + 1 + j,
+                q[:, j: j + 1], k_pages, v_pages, cache_index + 1 + j,
                 kv_tables, layer=layer, **scales,
             )
             for j in range(t_w)
@@ -563,20 +449,15 @@ def run_blocks(
     blocks: Params,
     cfg: ModelConfig,
     positions: jax.Array,
-    cache_k: jax.Array | None,  # these blocks' keys: [L, B, S, KVH, HD]
-    #   contiguous, or with kv_tables the page pool [L, NB, BLK, KVH, HD]
-    cache_v: jax.Array | None,
+    cache: Any,  # these blocks' cache (models/kv_cache.py) or None: leaves
+    #   [L, B, S, KVH, HD] contiguous, or with kv_tables the page pool
     cache_index: jax.Array | None,
     remat: bool = False,
     attn_mask: jax.Array | None = None,
     std_layout: bool = False,
     kv_tables: jax.Array | None = None,
     key_positions: jax.Array | None = None,  # see _attention
-    cache_sk: jax.Array | None = None,  # [L, NB, BLK, KVH] f32 absmax
-    #   scales of an int8 page pool (QuantKVCache): the pool then has four
-    #   leaves and the paged decode reads/writes quantized
-    cache_sv: jax.Array | None = None,
-) -> tuple[jax.Array, tuple | None, jax.Array]:
+) -> tuple[jax.Array, Any, jax.Array]:
     """Scan the stacked blocks over x.  Used both for the whole model and for
     a single pipeline stage (blocks then hold only the stage's layer slice).
     Returns (x, cache', aux) — aux sums the MoE load-balance terms.
@@ -602,7 +483,7 @@ def run_blocks(
     layer_index = jnp.arange(
         jax.tree.leaves(blocks)[0].shape[0], dtype=jnp.int32)
 
-    if cache_k is None:
+    if cache is None:
         def body(carry, layer_params):
             y, _, aux = block_fn(carry, layer_params, cfg, positions, None, None, attn_mask, std_layout)
             return y, aux
@@ -614,10 +495,7 @@ def run_blocks(
             y, pool, aux = block_fn(y, layer_of(blocks, layer), cfg, positions, pool, cache_index, attn_mask, std_layout, kv_tables, key_positions, layer)
             return (y, pool), aux
 
-        pool = (cache_k, cache_v)
-        if cache_sk is not None:
-            pool += (cache_sk, cache_sv)
-        init = (x, pool)
+        init = (x, cache)
         xs = layer_index
     else:
         def body(carry, xs):
@@ -625,16 +503,17 @@ def run_blocks(
             y, new_cache, aux = block_fn(carry, layer_of(blocks, layer), cfg, positions, (ck, cv), cache_index, attn_mask, std_layout, None, key_positions)
             return y, (new_cache, aux)
 
-        init, xs = x, (layer_index, cache_k, cache_v)
+        init, xs = x, (layer_index, cache.k, cache.v)
 
     if remat:
         body = jax.checkpoint(body)
     out, ys = jax.lax.scan(body, init, xs)
-    if cache_k is None:
+    if cache is None:
         return out, None, jnp.sum(ys)
     if kv_tables is not None:
         return *out, jnp.sum(ys)
-    return out, ys[0], jnp.sum(ys[1])
+    new_k, new_v = ys[0]
+    return out, dataclasses.replace(cache, k=new_k, v=new_v), jnp.sum(ys[1])
 
 
 def layer_runs(cfg: ModelConfig) -> tuple:
@@ -670,14 +549,14 @@ def run_layers(
     blocks: Params,  # params["blocks"]: one stack a KIND of layer
     cfg: ModelConfig,
     positions: jax.Array,
-    cache: HybridCache | None,
+    cache: kv_cache.HybridCache | None,
     cache_index: jax.Array | None,
     attn_mask: jax.Array | None = None,
     std_layout: bool = False,
     kv_tables: jax.Array | None = None,
     key_positions: jax.Array | None = None,
     seq_lens: jax.Array | None = None,  # [B] real new tokens a row
-) -> tuple[jax.Array, HybridCache | None, jax.Array]:
+) -> tuple[jax.Array, kv_cache.HybridCache | None, jax.Array]:
     """The "hybrid" family's layers (LFM2-MoE), which differ: layer l is
     ``x + op_l(rms(x))`` then ``+ ffn_l(rms(.))`` with op_l a gated short
     convolution or GQA attention (``cfg.layer_types``) and ffn_l a dense
@@ -705,10 +584,7 @@ def run_layers(
     pairs, layer passes, experts touched, fullest expert's tokens), a
     by-product like the dense families' aux loss and no part of the
     state."""
-    k = v = conv = None
     moe = jnp.zeros((4,), jnp.int32)
-    if cache is not None:
-        k, v, conv = cache.k, cache.v, cache.conv
     token_mask = None
     if seq_lens is not None:
         token_mask = (jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
@@ -716,31 +592,35 @@ def run_layers(
 
     def layer(carry, op, ffn, at):
         """One layer; ``at`` its index into each kind's stack."""
-        x, k, v, conv, moe = carry
+        x, cache, moe = carry
         p = layer_of(blocks[op], at[op])
         h = layers.rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
         if op == "conv":
             out, new = layers.short_conv(
-                h, p, None if conv is None else conv[at[op]], seq_lens)
-            if conv is not None:
-                conv = conv.at[at[op]].set(new.astype(conv.dtype))
+                h, p, None if cache is None else cache.conv[at[op]],
+                seq_lens)
+            if cache is not None:
+                cache = dataclasses.replace(cache, conv=cache.conv.at[
+                    at[op]].set(new.astype(cache.conv.dtype)))
         else:
             ai = at[op]
-            if k is None:
+            if cache is None:
                 layer_cache = None
             elif kv_tables is not None:
-                layer_cache = (k, v)
+                layer_cache = cache
             else:
-                layer_cache = (k[ai], v[ai])
+                layer_cache = (cache.k[ai], cache.v[ai])
             out, new = _attention(
                 h, p, cfg, positions, layer_cache, cache_index,
                 use_rope=True, attn_mask=attn_mask, std_layout=std_layout,
                 kv_tables=kv_tables, key_positions=key_positions, layer=ai,
             )
             if kv_tables is not None:
-                k, v = new
-            elif k is not None:
-                k, v = k.at[ai].set(new[0]), v.at[ai].set(new[1])
+                cache = new
+            elif cache is not None:
+                cache = dataclasses.replace(
+                    cache, k=cache.k.at[ai].set(new[0]),
+                    v=cache.v.at[ai].set(new[1]))
         x = x + out
         h = layers.rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
         if ffn == "moe":
@@ -750,9 +630,9 @@ def run_layers(
         else:
             x = x + layers.mlp_swiglu(
                 h, layer_of(blocks["dense"], at[ffn]), cfg.gate_act)
-        return x, k, v, conv, moe
+        return x, cache, moe
 
-    carry = (x, k, v, conv, moe)
+    carry = (x, cache, moe)
     base = dict(conv=0, attn=0, dense=0, moe=0)
     for unit, reps in layer_runs(cfg):
         kinds = [kind for pair in unit for kind in pair]
@@ -775,10 +655,7 @@ def run_layers(
                 run, carry, jnp.arange(reps, dtype=jnp.int32))
         for kind in base:
             base[kind] += reps * per_unit[kind]
-    x, k, v, conv, moe = carry
-    if cache is None:
-        return x, None, moe
-    return x, HybridCache(k=k, v=v, conv=conv), moe
+    return carry
 
 
 def hybrid_layers(params: Params, cfg: ModelConfig):
@@ -841,7 +718,7 @@ def forward(
     cfg: ModelConfig,
     tokens: jax.Array,  # [B, T] int32
     positions: jax.Array | None = None,  # [B, T] int32
-    cache: KVCache | None = None,
+    cache: kv_cache.KVCache | None = None,
     cache_index: jax.Array | None = None,  # scalar int32 write offset, or
     #   [B] int32 per-row offsets (continuous batching; attn_mask required)
     remat: bool = False,
@@ -860,7 +737,7 @@ def forward(
     #   not decoding).  Only a model with state that is not keys and values
     #   needs it (family "hybrid": layers.short_conv, layers.moe_dropless);
     #   None means all T
-) -> tuple[jax.Array, KVCache | None] | tuple[jax.Array, KVCache | None, jax.Array]:
+) -> tuple[jax.Array, Any] | tuple[jax.Array, Any, jax.Array]:
     """Full forward.  Returns (logits [B, T, V] float32, updated cache), plus
     the summed MoE aux loss when ``return_aux`` (scale by
     cfg.moe_aux_loss_weight and add to the task loss when training MoE;
@@ -880,7 +757,7 @@ def forward(
         positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32) + base, (b, t))
     x = embed(params, cfg, tokens, positions)
     if cfg.family == "hybrid":
-        if isinstance(cache, QuantKVCache) or remat:
+        if isinstance(cache, kv_cache.QuantKVCache) or remat:
             raise ValueError(
                 "the hybrid family serves a full-width HybridCache and is "
                 "not trained: no int8 pool, no remat"
@@ -891,31 +768,18 @@ def forward(
         )
         out = (unembed(params, cfg, x), cache)
         return (*out, stats) if return_aux else out
-    if cache is None:
-        x, _, aux = run_blocks(x, params["blocks"], cfg, positions, None, None, None, remat, attn_mask, std_layout)
-        out = (unembed(params, cfg, x), None)
-    elif isinstance(cache, QuantKVCache):
+    if isinstance(cache, kv_cache.QuantKVCache) and kv_tables is None:
         # Int8 page pool: decode-only (the per-step quantized write and the
         # scale-fused attention read both live on the kv_tables path).
-        if kv_tables is None:
-            raise ValueError(
-                "QuantKVCache serves paged decode only (pass kv_tables); "
-                "prefill runs against full-width transient rows"
-            )
-        x, (new_k, new_v, new_sk, new_sv), aux = run_blocks(
-            x, params["blocks"], cfg, positions, cache.k, cache.v,
-            cache_index, remat, attn_mask, std_layout, kv_tables,
-            key_positions, cache_sk=cache.k_scale, cache_sv=cache.v_scale,
+        raise ValueError(
+            "QuantKVCache serves paged decode only (pass kv_tables); "
+            "prefill runs against full-width transient rows"
         )
-        out = (unembed(params, cfg, x), QuantKVCache(
-            k=new_k, v=new_v, k_scale=new_sk, v_scale=new_sv,
-            row_dtype=cache.row_dtype,
-        ))
-    else:
-        x, (new_k, new_v), aux = run_blocks(
-            x, params["blocks"], cfg, positions, cache.k, cache.v, cache_index, remat, attn_mask, std_layout, kv_tables, key_positions
-        )
-        out = (unembed(params, cfg, x), KVCache(k=new_k, v=new_v))
+    x, cache, aux = run_blocks(
+        x, params["blocks"], cfg, positions, cache, cache_index, remat,
+        attn_mask, std_layout, kv_tables, key_positions,
+    )
+    out = (unembed(params, cfg, x), cache)
     return (*out, aux) if return_aux else out
 
 

@@ -3,9 +3,9 @@
 Replaces the dequantize-then-einsum path (models/model.py run_blocks) for
 int8 / packed-int4 blockwise-quantized weights (checkpoint/quantize.py).
 On the dequantize path XLA materializes a full-precision copy of every
-weight in HBM each layer — measured ~9 bytes/param of HBM traffic per
-decode step on a v5e (BASELINE.md config 3-int8: 52.8 tok/s, ~12% of HBM
-bandwidth).  Decode is weight-bandwidth-bound, so the ceiling is set by
+weight in HBM each layer, several bytes of HBM traffic a parameter per
+decode step.  Decode is weight-bandwidth-bound (PERF.md, section 5:
+``quant_matmul_roofline``), so the ceiling is set by
 bytes-read-per-param: this kernel streams the int8/int4 weights HBM→VMEM,
 dequantizes tiles in VMEM (VPU), and feeds the MXU directly, never writing
 a dequantized copy back to HBM: 1 (int8) or 0.5 (int4) bytes a parameter

@@ -25,7 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.config import MeshConfig, ModelConfig
 from ..models import model as model_lib
-from ..models.model import KVCache
+from ..models.kv_cache import KVCache
 from . import pipeline as pipeline_lib
 from . import specs as specs_lib
 

@@ -33,6 +33,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.config import ModelConfig
 from ..models import model as model_lib
+from ..models.kv_cache import KVCache
 from ..runtime import sampling
 
 Params = Any
@@ -132,19 +133,20 @@ def pipeline_blocks(
                 row0 = mb_idx * mb_size
                 ck_mb = jax.lax.dynamic_slice_in_dim(ck, row0, mb_size, axis=1)
                 cv_mb = jax.lax.dynamic_slice_in_dim(cv, row0, mb_size, axis=1)
-                y, (nk, nv), _ = model_lib.run_blocks(
-                    x_in, blocks, cfg, pos, ck_mb, cv_mb, cache_index,
-                    remat=remat, attn_mask=amask, key_positions=kpos,
+                y, new, _ = model_lib.run_blocks(
+                    x_in, blocks, cfg, pos, KVCache(k=ck_mb, v=cv_mb),
+                    cache_index, remat=remat, attn_mask=amask,
+                    key_positions=kpos,
                 )
-                nk = jnp.where(valid, nk, ck_mb)
-                nv = jnp.where(valid, nv, cv_mb)
+                nk = jnp.where(valid, new.k, ck_mb)
+                nv = jnp.where(valid, new.v, cv_mb)
                 ck = jax.lax.dynamic_update_slice_in_dim(ck, nk, row0, axis=1)
                 cv = jax.lax.dynamic_update_slice_in_dim(cv, nv, row0, axis=1)
             else:
                 # MoE aux loss is not threaded through the pipeline schedule
                 # (train MoE with data/tensor/expert axes, not 'pipe').
                 y, _, _ = model_lib.run_blocks(
-                    x_in, blocks, cfg, pos, None, None, None,
+                    x_in, blocks, cfg, pos, None, None,
                     remat=remat, attn_mask=amask,
                 )
 
@@ -358,12 +360,12 @@ def pipeline_decode(
                     slots[None, :] < t_base, slots[None, :],
                     plens_m[:, None] + (slots[None, :] - t_base),
                 )
-            y, (nk, nv), _ = model_lib.run_blocks(
-                x_in, blocks, cfg, pos, ck_mb, cv_mb, t_base + j,
-                attn_mask=mask, key_positions=kpos,
+            y, new, _ = model_lib.run_blocks(
+                x_in, blocks, cfg, pos, KVCache(k=ck_mb, v=cv_mb),
+                t_base + j, attn_mask=mask, key_positions=kpos,
             )
-            nk = jnp.where(valid, nk, ck_mb)
-            nv = jnp.where(valid, nv, cv_mb)
+            nk = jnp.where(valid, new.k, ck_mb)
+            nv = jnp.where(valid, new.v, cv_mb)
             ck = jax.lax.dynamic_update_slice_in_dim(ck, nk, row0, axis=1)
             cv = jax.lax.dynamic_update_slice_in_dim(cv, nv, row0, axis=1)
 

@@ -38,7 +38,7 @@ def param_specs(cfg: ModelConfig, mesh: Mesh) -> Params:
     if cfg.family == "hybrid":
         # No sharding rule yet for the stacks by kind, the expert stacks
         # or the convolution state: every leaf replicated (the engine and
-        # the batcher refuse a mesh for this family: runtime/batcher.py
+        # the batcher refuse a mesh for this family: models/kv_cache.py
         # refuse_unpaged_state; ROADMAP Reach).
         import jax
 
@@ -109,37 +109,6 @@ def param_specs(cfg: ModelConfig, mesh: Mesh) -> Params:
     if not cfg.tie_embeddings:
         specs["lm_head"] = {"w": P(None, vocab_ax)}
     return specs
-
-
-def page_pool_specs(cfg: ModelConfig, mesh: Mesh, kv_bits: int = 16,
-                    row_dtype: str | None = None) -> Any:
-    """PartitionSpec pytree matching the paged KV pool (runtime/batcher.py
-    ``_paged_pool``): data leaves [L, NB, BLK, KVH, HD] shard the KV-head
-    axis over 'model' (Megatron-style tensor parallelism — each chip holds
-    its heads' slice of every page, so per-chip pool bytes divide by tp);
-    int8 absmax scales [L, NB, BLK, KVH] shard the same axis.  Pages are
-    shared across rows (prefix cache, handoff imports), so the page axis
-    never shards over 'data' — scheduling state replicates instead.
-    Non-divisible KV heads replicate (the batcher REJECTS that combination
-    up front; the spec mirrors param_specs' degrade convention so the
-    graftcheck GC2 audit stays total over the mesh ladder)."""
-    from ..models.model import KVCache, QuantKVCache
-
-    tp = _axis_size(mesh, "model")
-    kv_ax = "model" if cfg.num_kv_heads % max(tp, 1) == 0 else None
-    data = P(None, None, None, kv_ax, None)
-    if kv_bits == 8:
-        scale = P(None, None, None, kv_ax)
-        # row_dtype is QuantKVCache's STATIC pytree metadata: the spec
-        # tree must carry the pool's value or tree.map over (pool, specs)
-        # rejects the structures as different node types.
-        import jax.numpy as jnp
-
-        return QuantKVCache(
-            k=data, v=data, k_scale=scale, v_scale=scale,
-            row_dtype=row_dtype or jnp.dtype(cfg.dtype).name,
-        )
-    return KVCache(k=data, v=data)
 
 
 def shard_params(params: Params, cfg: ModelConfig, mesh: Mesh) -> Params:

@@ -24,7 +24,7 @@ them loudly.
 
 PAGED mode is mesh-native too (ROADMAP item 3): the page pool (and the
 int8 QuantKVCache pool) shards its KV-head axis over 'model'
-(parallel.specs.page_pool_specs — per-chip pool bytes divide by tp, so
+(models.kv_cache.pool_specs — per-chip pool bytes divide by tp, so
 per-chip row capacity multiplies by the mesh), the ragged/paged decode
 kernels partition through their own custom_partitioning rules
 (ops/decode_attn — each shard runs its local head slice; page tables and
@@ -67,16 +67,17 @@ delegates through ``self.sched``); this module keeps the MECHANISM.  The
 default ``schedule="mixed"`` policy runs chunked-prefill bites INSIDE the
 decode dispatch (:func:`mixed_step` — one fused token-budget program), so
 resident decode rows never stall for a serialized prefill forward; the
-host-RAM KV tier lives in runtime/kv_tier.py (re-exported here).
+host-RAM KV tier lives in runtime/kv_tier.py, the page allocator and the
+prefix cache's index in runtime/pages.py, and how a page is stored in
+models/kv_cache.py.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import hashlib
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import deque
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Any
@@ -88,13 +89,13 @@ import numpy as np
 from ..core import profiling
 from ..core.config import ModelConfig
 from ..core.observability import METRICS, get_logger
-from ..models import model as model_lib
-from ..models.model import HybridCache, KVCache, QuantKVCache
+from ..models import kv_cache, model as model_lib
+from ..models.kv_cache import KVCache
 from . import constrain as constrain_lib
 from . import sampling
 from . import scheduler as scheduler_lib
-# Re-export: the host-RAM KV tier lives in kv_tier.py since round 16.
 from .kv_tier import HostTier
+from .pages import PagePool, PrefixCache
 from .scheduler import make_scheduler
 from .shapes import bucket_length as _bucket
 
@@ -106,9 +107,19 @@ log = get_logger("batcher")
 _SPEC_EMA_ALPHA = 0.2
 
 
-def _batch_axis(leaf_ndim: int) -> int:
-    # KVCache leaves end in [..., B, S, KVH, HD]; batch is 4th from the right.
-    return leaf_ndim - 4
+def _splice_row(cache, slot, row_cache):
+    """Overwrite batch row ``slot`` of a contiguous cache with a prefilled
+    single-row cache (leaves end in [..., B, S, KVH, HD]: the batch axis
+    is the 4th from the right)."""
+    def splice(full, row):
+        start = [0] * full.ndim
+        start[full.ndim - 4] = slot
+        return jax.lax.dynamic_update_slice(
+            full, row.astype(full.dtype), tuple(start)
+        )
+
+    return KVCache(k=splice(cache.k, row_cache.k),
+                   v=splice(cache.v, row_cache.v))
 
 
 def _fwd(pm):
@@ -174,7 +185,7 @@ def _prefill_row(fwd, params, cfg, cache_dtype, s, prompt, plen=None):
     at the padded bucket's end, and returns its expert layers' counts of
     the real tokens third (forward's ``return_aux``)."""
     (tp,) = prompt.shape
-    row_cache = model_lib.init_cache(cfg, 1, s, dtype=cache_dtype)
+    row_cache = kv_cache.init_cache(cfg, 1, s, dtype=cache_dtype)
     positions = jnp.arange(tp, dtype=jnp.int32)[None, :]
     state = ({"seq_lens": plen[None], "return_aux": True}
              if cfg.family == "hybrid" else {})
@@ -213,16 +224,7 @@ def _finish_admission(
     row into the shared cache, report the row's valid slots."""
     tok, lp = _sample_first(logits, last_idx, rng, temperature, top_k, top_p,
                             temp_req, topp_req, topk_req, mask_req)
-    ax = _batch_axis(cache.k.ndim)
-
-    def splice(full, row):
-        start = [0] * full.ndim
-        start[ax] = slot
-        return jax.lax.dynamic_update_slice(
-            full, row.astype(full.dtype), tuple(start)
-        )
-
-    cache = KVCache(k=splice(cache.k, row_cache.k), v=splice(cache.v, row_cache.v))
+    cache = _splice_row(cache, slot, row_cache)
     s = cache.k.shape[-3]
     row_valid = jnp.arange(s, dtype=jnp.int32) < total_len
     return cache, tok, row_valid, lp
@@ -284,17 +286,7 @@ def admit_row_kv(
         model_lib.forward, params, cfg, cache.k.dtype, cache.k.shape[-3],
         prompt,
     )
-    ax = _batch_axis(cache.k.ndim)
-
-    def splice(full, row):
-        start = [0] * full.ndim
-        start[ax] = slot
-        return jax.lax.dynamic_update_slice(
-            full, row.astype(full.dtype), tuple(start)
-        )
-
-    return KVCache(k=splice(cache.k, row_cache.k),
-                   v=splice(cache.v, row_cache.v))
+    return _splice_row(cache, slot, row_cache)
 
 
 @partial(
@@ -778,266 +770,19 @@ def finish_chunked_admission_paged(
     )
 
 
-@partial(jax.jit, static_argnames=("pm",))
-def _import_pages(cache: Any, page_list: jax.Array, k_pages: jax.Array,
-                  v_pages: jax.Array, pm: Any = None) -> Any:
-    """Scatter HANDED-OFF KV pages into the pool (disaggregated serving:
-    a prefill-role engine shipped a finished row's pages over
-    cluster/kv_transfer.py and this decode-role engine adopts them).
-    ``k_pages``/``v_pages`` are [L, P, BLK, KVH, HD] page stacks in pool
-    layout; ``page_list`` [P] names the freshly allocated destination
-    pages.  An int8 pool re-quantizes the full-width payload on the way in
-    — byte-stable when the payload was itself dequantized from int8 pages
-    (kv_quantize's exact round-trip property), which is how a kv-bits-8
-    fleet ships pages without a second lossy step.  The cache is NOT
-    donated: import is a rare, off-hot-path event and the caller reuses
-    the returned pool exactly like the admission splices do."""
-    if isinstance(cache, QuantKVCache):
-        from ..checkpoint.quantize import kv_quantize
-
-        kq, ks = kv_quantize(k_pages)
-        vq, vs = kv_quantize(v_pages)
-        return _pool_constrain(pm, QuantKVCache(
-            k=cache.k.at[:, page_list].set(kq),
-            v=cache.v.at[:, page_list].set(vq),
-            k_scale=cache.k_scale.at[:, page_list].set(ks),
-            v_scale=cache.v_scale.at[:, page_list].set(vs),
-            row_dtype=cache.row_dtype,
-        ))
-    return _pool_constrain(pm, KVCache(
-        k=cache.k.at[:, page_list].set(k_pages.astype(cache.k.dtype)),
-        v=cache.v.at[:, page_list].set(v_pages.astype(cache.v.dtype)),
-    ))
-
-
-@jax.jit
-def _export_pages_raw(cache: Any, page_list: jax.Array) -> tuple:
-    """Gather pages VERBATIM in pool layout and pool dtype — (k, v) page
-    stacks, plus the scale stacks on an int8 pool.  This is the host-tier
-    parcel format (swap-preemption, prefix-cache spill): re-importing the
-    exact bytes via :func:`_import_pages_raw` restores the pool state
-    bit-for-bit, which is what makes a swap-restored row's stream
-    byte-exact against its never-preempted run at EITHER kv width."""
-    if isinstance(cache, QuantKVCache):
-        return (cache.k[:, page_list], cache.v[:, page_list],
-                cache.k_scale[:, page_list], cache.v_scale[:, page_list])
-    return (cache.k[:, page_list], cache.v[:, page_list])
-
-
-@partial(jax.jit, static_argnames=("pm",))
-def _import_pages_raw(cache: Any, page_list: jax.Array, k_pages: jax.Array,
-                      v_pages: jax.Array, k_scale: jax.Array | None = None,
-                      v_scale: jax.Array | None = None,
-                      pm: Any = None) -> Any:
-    """Scatter a raw host-tier parcel (``_export_pages_raw`` layout) back
-    into freshly allocated pool pages, verbatim — no quantize/dequantize
-    hop, so restore is exact by construction."""
-    if isinstance(cache, QuantKVCache):
-        return _pool_constrain(pm, QuantKVCache(
-            k=cache.k.at[:, page_list].set(k_pages),
-            v=cache.v.at[:, page_list].set(v_pages),
-            k_scale=cache.k_scale.at[:, page_list].set(k_scale),
-            v_scale=cache.v_scale.at[:, page_list].set(v_scale),
-            row_dtype=cache.row_dtype,
-        ))
-    return _pool_constrain(pm, KVCache(
-        k=cache.k.at[:, page_list].set(k_pages),
-        v=cache.v.at[:, page_list].set(v_pages)))
-
-
-@jax.jit
-def _gather_row_pages(cache: Any, read_list: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Gather a row's pages out of the pool into a transient contiguous
-    row cache ([L, 1, P*BLK, KVH, HD] k/v pair) — the chunked-prefill
-    analogue of admit_row_auto_paged's in-program gather.  A cache-hit
-    chunked admission seeds its transient row from the shared pages ONCE
-    (the "prefix" is then already resident, exactly as if those chunks had
-    run), and only the un-cached suffix chunks through the model.  The
-    outputs are fresh buffers, so every later prefill_chunk_step may
-    donate them."""
-    l, _, blk, kvh, hd = cache.k.shape
-    p = read_list.shape[0]
-
-    if isinstance(cache, QuantKVCache):
-        # Int8 pool: dequantize the gathered pages to the declared
-        # full-width dtype — transient rows always run full-width; only
-        # POOL storage is quantized.
-        from ..checkpoint.quantize import kv_dequantize
-
-        dt = jnp.dtype(cache.row_dtype)
-
-        def gather_q(pool, scale):
-            full = kv_dequantize(pool[:, read_list], scale[:, read_list], dt)
-            return full.reshape(l, 1, p * blk, kvh, hd)
-
-        return (gather_q(cache.k, cache.k_scale),
-                gather_q(cache.v, cache.v_scale))
-
-    def gather(pool):
-        return pool[:, read_list].reshape(l, 1, p * blk, kvh, hd)
-
-    return gather(cache.k), gather(cache.v)
-
-
-def _pool_constrain(pm, cache):
-    """Pin a page pool's leaves to their mesh sharding — KV heads over
-    'model' (parallel.specs.page_pool_specs), the layout every paged jit
-    in this module produces and consumes on a mesh batcher.  Applied to
-    every program output that carries the pool (splice, decode chunk,
-    import scatters) so XLA can never hand back a differently-placed pool
-    and force a resharding copy (or a fresh compile key) on the next
-    call.  No-op single-device."""
-    if pm is None:
-        return cache
-    from jax.sharding import NamedSharding
-
-    from ..parallel.specs import page_pool_specs
-    quant = isinstance(cache, QuantKVCache)
-    specs = page_pool_specs(
-        pm.cfg, pm.mesh, kv_bits=8 if quant else 16,
-        row_dtype=cache.row_dtype if quant else None,
-    )
-    return jax.tree.map(
-        lambda x, s: jax.lax.with_sharding_constraint(
-            x, NamedSharding(pm.mesh, s)
-        ),
-        cache, specs,
-    )
-
-
-def _paged_pool(cfg: ModelConfig, num_pages: int, page_size: int, dtype=None,
-                kv_bits: int = 16, slots: int = 0):
-    """KV page pools [L, NB, BLK, KVH, HD] (distinct k/v buffers — the
-    chunk fns donate the cache).  Each is ONE stack of every layer's
-    pages and stays one: the decode programs carry it through the layer
-    scan and update it in place (models.model.run_blocks), the paged
-    kernel reads (layer, page) out of it, and admissions write whole
-    pages into it (:func:`_write_pages`); nothing holds a layer's slice
-    or a second stack.  ``kv_bits=8`` builds an int8
-    :class:`~..models.model.QuantKVCache` pool (data int8 + one f32 absmax
-    scale per head-dim vector) at roughly half the bytes per token; the
-    full-width dtype survives as ``row_dtype`` so gathers/transient rows
-    restore to it.  A hybrid model's pool counts its attention layers
-    only and comes with the state that is not paged: each convolution
-    layer's, one entry a batch slot (``slots``), in a
-    :class:`~..models.model.HybridCache`."""
-    from ..ops.decode_attn import pool_head_shape
-
-    l = len(cfg.attn_layers)
-    kvh, hd = pool_head_shape(cfg.num_kv_heads, cfg.head_dim_,
-                              fold_narrow=pages_are_private(cfg))
-    dt = jnp.dtype(dtype) if dtype else jnp.dtype(cfg.dtype)
-    shape = (l, num_pages, page_size, kvh, hd)
-    if cfg.family == "hybrid":
-        return HybridCache(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt),
-                           conv=model_lib.conv_state(cfg, slots))
-    if kv_bits == 8:
-        sshape = (l, num_pages, page_size, kvh)
-        return QuantKVCache(
-            k=jnp.zeros(shape, jnp.int8), v=jnp.zeros(shape, jnp.int8),
-            k_scale=jnp.ones(sshape, jnp.float32),
-            v_scale=jnp.ones(sshape, jnp.float32),
-            row_dtype=dt.name,
-        )
-    return KVCache(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt))
-
-
-def _row_dtype_of(cache) -> Any:
-    """Dtype transient single-row caches (and pool gathers) use: the
-    pool's own dtype, or the declared full-width dtype of an int8 pool.
-    Safe inside jit — the pytree TYPE of ``cache`` is static."""
-    if isinstance(cache, QuantKVCache):
-        return jnp.dtype(cache.row_dtype)
-    return cache.k.dtype
-
-
-def pool_page_bytes(cfg: ModelConfig, page_size: int, kv_bits: int = 16,
-                    dtype=None) -> int:
-    """Bytes one pool page costs (k + v + scales) — the denominator of the
-    capacity-per-byte comparison bench.py's kv-tiering row stamps."""
-    l, kvh, hd = len(cfg.attn_layers), cfg.num_kv_heads, cfg.head_dim_
-    elems = l * page_size * kvh * hd
-    if kv_bits == 8:
-        return 2 * (elems + l * page_size * kvh * 4)
-    dt = jnp.dtype(dtype) if dtype else jnp.dtype(cfg.dtype)
-    return 2 * elems * dt.itemsize
-
-
 def _paged_splice(cache, page_list, row_cache, logits, last_idx, rng,
                   temperature, top_k, top_p, temp_req=None, topp_req=None,
                   topk_req=None, mask_req=None, pm=None, slot=None):
     """Admission tail for the paged pool: sample the first token, then
-    scatter the contiguous transient row cache into the row's pages.
-    ``page_list`` [P] is padded with the reserved scratch page 0 past the
-    allocation, so the fixed-shape scatter stays compiled once — the extra
-    writes land in the scratch page, whose contents no LIVE row ever reads
-    (freed rows' clamped decode reads do touch it, but their outputs are
-    masked to pad).  Prefix-cache-hit admissions also route their CACHED
-    positions to the scratch page: the shared pages already hold exactly
-    that KV and must never be rewritten while other rows read them.
-    On a mesh batcher (``pm``) the pool result is re-constrained to its
-    sharding and the sampled token/logprob replicate (lockstep mirrors).
-    A :class:`HybridCache` also takes the row's convolution state into
-    batch slot ``slot`` (all of it: whatever the slot's last row left is
-    overwritten)."""
+    write the contiguous transient row cache into the row's pages
+    (kv_cache.write_row: ``page_list`` is scratch-padded, ``slot`` is
+    where a hybrid model's convolution state goes).  On a mesh batcher
+    (``pm``) the pool result is re-constrained to its sharding and the
+    sampled token/logprob replicate (lockstep mirrors)."""
     tok, lp = _sample_first(logits, last_idx, rng, temperature, top_k, top_p,
                             temp_req, topp_req, topk_req, mask_req)
-    p = page_list.shape[0]
-    blk = cache.k.shape[2]
-
-    def as_pages(row):  # [L, 1, P*BLK, KVH, HD] -> [L, P, BLK, KVH, HD]
-        # (the pool's own last two axes: narrow heads may lie folded there)
-        return row[:, 0].reshape(row.shape[0], p, blk, *cache.k.shape[3:])
-
-    k, v = as_pages(row_cache.k), as_pages(row_cache.v)
-    if isinstance(cache, QuantKVCache):
-        # Quantize ONCE at the write: each page's head-dim vectors get
-        # int8 data + one f32 absmax scale (checkpoint.quantize
-        # machinery); pool storage never sees the full-width row again.
-        from ..checkpoint.quantize import kv_quantize
-
-        (k, sk), (v, sv) = kv_quantize(k), kv_quantize(v)
-        k, v, sk, sv = _write_pages(
-            (cache.k, cache.v, cache.k_scale, cache.v_scale), page_list,
-            (k, v, sk, sv),
-        )
-        cache = QuantKVCache(k=k, v=v, k_scale=sk, v_scale=sv,
-                             row_dtype=cache.row_dtype)
-    else:
-        k, v = _write_pages(
-            (cache.k, cache.v), page_list,
-            (k.astype(cache.k.dtype), v.astype(cache.v.dtype)),
-        )
-        if isinstance(cache, HybridCache):
-            conv = jax.lax.dynamic_update_slice_in_dim(
-                cache.conv, row_cache.conv.astype(cache.conv.dtype), slot,
-                axis=1,
-            )
-            return HybridCache(k=k, v=v, conv=conv), tok, lp
-        cache = KVCache(k=k, v=v)
-    return (_pool_constrain(pm, cache), *_replicated(pm, tok, lp))
-
-
-def _write_pages(pools: tuple, page_list: jax.Array, pages: tuple) -> tuple:
-    """Write ``pages`` ([L, P, ...] a leaf) into the donated pool stacks
-    ([L, NB, ...]) at ``page_list`` [P], where the stacks lie: one
-    ``dynamic_update_slice`` a page, the stacks the loop's carry.  As one
-    scatter on the page axis (``pool.at[:, page_list].set``) the compiler
-    moves a pool of few KV heads (qwen2's 4) into a layout of its own,
-    scatters there and moves it back: four pool-sized copies an admission
-    (AOT compile for the v5e, PR 26).  A page listed twice (the scratch
-    page pads the list) keeps the last write."""
-
-    def write(i, pools):
-        return tuple(
-            jax.lax.dynamic_update_slice_in_dim(
-                pool, jax.lax.dynamic_slice_in_dim(new, i, 1, axis=1),
-                page_list[i], axis=1,
-            )
-            for pool, new in zip(pools, pages)
-        )
-
-    return jax.lax.fori_loop(0, page_list.shape[0], write, pools)
+    cache = kv_cache.write_row(cache, page_list, row_cache, slot)
+    return (kv_cache.constrain(pm, cache), *_replicated(pm, tok, lp))
 
 
 @partial(
@@ -1069,7 +814,7 @@ def admit_row_paged(
     Returns (cache', tok, logprob), and for a hybrid model the prefill's
     expert counts (see :func:`_prefill_row`)."""
     logits, row_cache, *moe = _prefill_row(
-        _fwd(pm), params, cfg, _row_dtype_of(cache),
+        _fwd(pm), params, cfg, kv_cache.row_dtype(cache),
         page_list.shape[0] * cache.k.shape[2], prompt, plen,
     )
     return (*_paged_splice(
@@ -1151,7 +896,7 @@ def admit_row_auto_paged(
     program (an int8 pool dequantizes the gathered run to row_dtype — the
     suffix continues from the same values decode attends to).
     Returns (cache', tok, logprob)."""
-    row_k, row_v = _gather_row_pages(cache, read_list)
+    row_k, row_v = kv_cache.gather_row(cache, read_list)
     logits, row_cache = _prefill_row_with_prefix(
         _fwd(pm), params, cfg, row_k, row_v,
         prefix_len, chunk,
@@ -1193,7 +938,7 @@ def _decode_steps(
             # whose counts come out beside the logits (return_aux).
             state = ({"seq_lens": active.astype(jnp.int32),
                       "return_aux": True}
-                     if isinstance(cache, HybridCache) else {})
+                     if cfg.family == "hybrid" else {})
             logits, cache, *aux = _fwd(pm)(
                 params, cfg, last_tok[:, None], positions=real_lens[:, None],
                 cache=cache, cache_index=real_lens, kv_tables=tables,
@@ -1298,7 +1043,7 @@ def _decode_steps(
         # Mesh paged decode: pin the pool carry back to its sharding (KV
         # heads over 'model') so chained dispatch-ahead chunks and the
         # scatter/gather jits all consume one placement (no-op off-mesh).
-        cache = _pool_constrain(pm, cache)
+        cache = kv_cache.constrain(pm, cache)
     # A hybrid model's expert counts, summed over the chunk's steps, leave
     # as one more output.
     moe = () if moe is None else (jnp.sum(moe, axis=0),)
@@ -1562,323 +1307,6 @@ class _Prefix:
     v: Any
 
 
-class PrefixCache:
-    """Content-addressed index of pool pages for AUTOMATIC prefix caching
-    (vLLM/SGLang-style): every FULL page of an admitted prompt is keyed by
-    a chained content digest (a page's digest commits to every token before
-    it, so equal digests mean equal full prefixes), and later admissions
-    reuse the longest cached page-run copy-free through their page tables.
-
-    Ownership model: refcounts live with the batcher's pool allocator; this
-    class only maps digests <-> pages and keeps the LRU of UNREFERENCED
-    pages whose cached content is still resident — those are reclaimable
-    (evicted oldest-first under pool pressure) but serve hits until then.
-    Stats are cumulative per batcher and mirrored into the process-wide
-    METRICS registry (gateway /metrics)."""
-
-    def __init__(self) -> None:
-        self.by_hash: dict[bytes, int] = {}
-        self.page_hash: dict[int, bytes] = {}
-        self.lru: OrderedDict[int, None] = OrderedDict()  # oldest first
-        self.hit_tokens = 0
-        self.miss_tokens = 0
-        self.lookups = 0
-        self.hits = 0
-        self.evictions = 0
-
-    @staticmethod
-    def page_digests(ids: list[int], page_size: int, n_pages: int,
-                     kv_bits: int = 16) -> list[bytes]:
-        """Chained blake2b digests of the first ``n_pages`` full pages:
-        digest_i = H(digest_{i-1} || tokens of page i).  ``kv_bits`` salts
-        the chain seed: a page's stored bytes are a deterministic function
-        of (token prefix, kv width), so folding the width into the digest
-        keeps sharing content-addressed over the QUANTIZED bytes — an int8
-        page can never alias a bf16 page (locally, across a handoff, or in
-        router affinity), while all default-width digests stay unchanged."""
-        digests: list[bytes] = []
-        prev = (b"dlt-prefix-cache-v1" if kv_bits == 16
-                else b"dlt-prefix-cache-v1:kv%d" % kv_bits)
-        # ONE token-id conversion for the whole prompt, sliced per page —
-        # the old per-page np.asarray paid a fresh list->array
-        # materialization inside every blake2b update; the chain bytes
-        # are identical (tests/runtime/test_overlap.py pins equality
-        # against the per-page construction).
-        flat = np.asarray(ids[: n_pages * page_size], np.int64)
-        for i in range(n_pages):
-            h = hashlib.blake2b(prev, digest_size=16)
-            h.update(flat[i * page_size: (i + 1) * page_size].tobytes())
-            prev = h.digest()
-            digests.append(prev)
-        return digests
-
-    def match(self, digests: list[bytes]) -> list[int]:
-        """Pages of the longest cached run from the start (maybe empty)."""
-        pages: list[int] = []
-        for d in digests:
-            p = self.by_hash.get(d)
-            if p is None:
-                break
-            pages.append(p)
-        return pages
-
-    def register(self, page: int, digest: bytes) -> None:
-        """Publish ``page`` as the holder of ``digest``.  First writer wins:
-        if another page already holds this content, the new page stays
-        private (it frees normally when its row releases it)."""
-        if digest not in self.by_hash:
-            self.by_hash[digest] = page
-            self.page_hash[page] = digest
-
-    def forget(self, page: int) -> None:
-        """Drop a page's cache entry (eviction): its content is no longer
-        addressable and the page returns to plain-allocator life."""
-        d = self.page_hash.pop(page, None)
-        if d is not None:
-            self.by_hash.pop(d, None)
-        self.lru.pop(page, None)
-
-    def record_lookup(self, hit_tokens: int, miss_tokens: int) -> None:
-        self.lookups += 1
-        self.hits += hit_tokens > 0
-        self.hit_tokens += hit_tokens
-        self.miss_tokens += miss_tokens
-        METRICS.inc("batcher.prefix_cache.lookups")
-        if hit_tokens > 0:
-            METRICS.inc("batcher.prefix_cache.hits")
-        METRICS.inc("batcher.prefix_cache.hit_tokens", hit_tokens)
-        METRICS.inc("batcher.prefix_cache.miss_tokens", miss_tokens)
-        total = self.hit_tokens + self.miss_tokens
-        if total:
-            METRICS.set_gauge(
-                "batcher.prefix_cache.hit_rate", self.hit_tokens / total
-            )
-
-
-
-class PagePool:
-    """Refcounted KV page allocator for paged mode.  Owns the free list and
-    per-page refcounts, and cooperates with an optional :class:`PrefixCache`
-    whose LRU parks unreferenced-but-content-cached pages (still serving
-    hits, reclaimable under pressure).  Page 0 is the permanent scratch
-    page: never allocated, never freed, never read by a live row.
-
-    Extracted from the batcher so the invariants have one owner and one
-    audit (:meth:`assert_consistent`) — the recovery path's leak class
-    (dangling refcounts / pinned cache pages after a crashed ``run``) is
-    exactly a violation of these invariants, and the serving supervisor
-    runs the audit after every engine restart."""
-
-    def __init__(self, num_pages: int,
-                 prefix_cache: "PrefixCache | None" = None,
-                 host_tier: "HostTier | None" = None) -> None:
-        self.num_pages = num_pages
-        # Optional host-RAM tier BEHIND the pool (KV tiering): the batcher
-        # spills eviction candidates into it before alloc reclaims them,
-        # and swap-preemption parks whole rows there.  The pool itself
-        # only audits and reports it — all data movement is the batcher's
-        # (device calls never run under the allocator lock).
-        self.host_tier = host_tier
-        # Allocator lock: mutation happens on the engine thread, but the
-        # occupancy view (stats/publish_gauges behind /metrics, the
-        # supervisor's audit) reads from the serving loop thread — PR 3
-        # published those gauges off GIL-atomic len() reads, the pattern
-        # graftlint's GL101 now rejects.  The PrefixCache LRU is covered by
-        # THIS lock too: every lru mutation goes through alloc/retain/
-        # release (engine thread), every cross-thread read through stats().
-        self._lock = threading.Lock()
-        self.free_pages: list[int] = list(range(1, num_pages))  # guarded-by: self._lock
-        # Refcounts of allocated pages (prefix-cache hits share pages
-        # across rows; a page returns to free/LRU only at refcount 0).
-        self.page_refs: dict[int, int] = {}  # guarded-by: self._lock
-        self.prefix_cache = prefix_cache
-        # Watermarks: the least headroom an admission has ever seen and the
-        # most pages rows have ever held at once — the two numbers that say
-        # whether a production pool is sized right (a min_available of 0
-        # means admissions back-pressured or preempted; a peak_held far
-        # under num_pages means the pool is over-provisioned).
-        self.min_available = num_pages - 1  # guarded-by: self._lock
-        self.peak_held = 0  # guarded-by: self._lock
-
-    # graftlint: holds(self._lock)
-    def _note_watermarks(self) -> None:
-        avail = self._available_locked()
-        if avail < self.min_available:
-            self.min_available = avail
-        held = len(self.page_refs)
-        if held > self.peak_held:
-            self.peak_held = held
-
-    def stats(self) -> dict[str, int]:
-        """Occupancy snapshot: every usable page is exactly one of free /
-        LRU-cached / row-held (the partition assert_consistent audits).
-        Safe from any thread (the /metrics scrape path)."""
-        pc = self.prefix_cache
-        with self._lock:
-            return {
-                "total_pages": self.num_pages - 1,  # page 0 is scratch
-                "free_pages": len(self.free_pages),
-                "cached_pages": len(pc.lru) if pc is not None else 0,
-                "held_pages": len(self.page_refs),
-                "min_available": self.min_available,
-                "peak_held": self.peak_held,
-            }
-
-    def publish_gauges(self) -> None:
-        """Mirror the occupancy view into the process-wide METRICS registry
-        (rendered as batcher_pool_* on the gateway's /metrics); the host
-        tier's occupancy rides along as batcher_host_tier_*."""
-        METRICS.set_gauges({
-            f"batcher.pool.{k}": float(v) for k, v in self.stats().items()
-        })
-        if self.host_tier is not None:
-            METRICS.set_gauges({
-                f"batcher.host_tier.{k}": float(v)
-                for k, v in self.host_tier.stats().items()
-            })
-
-    def eviction_candidates(self, n: int) -> list[tuple[int, bytes]]:
-        """The (page, digest) pairs :meth:`alloc`\\ (n) would evict from
-        the LRU, oldest first — the spill plane reads these BEFORE the
-        alloc so their content can move to the host tier.  Engine thread
-        only: nothing may mutate the pool between this and the alloc."""
-        pc = self.prefix_cache
-        with self._lock:
-            if pc is None:
-                return []
-            m = max(0, n - len(self.free_pages))
-            out: list[tuple[int, bytes]] = []
-            for p in pc.lru:
-                if len(out) >= m:
-                    break
-                out.append((p, pc.page_hash[p]))
-            return out
-
-    # graftlint: holds(self._lock)
-    def _available_locked(self) -> int:
-        pc = self.prefix_cache
-        return len(self.free_pages) + (len(pc.lru) if pc else 0)
-
-    def available(self) -> int:
-        """Pages an admission could obtain: the free list plus every
-        LRU-parked cached page (reclaimable under pressure)."""
-        with self._lock:
-            return self._available_locked()
-
-    def alloc(self, n: int) -> list[int]:
-        """Allocate ``n`` pages at refcount 1, evicting LRU-cold cached
-        pages when the free list runs dry (the caller checked
-        :meth:`available` first)."""
-        pc = self.prefix_cache
-        out: list[int] = []
-        with self._lock:
-            for _ in range(n):
-                if self.free_pages:
-                    p = self.free_pages.pop()
-                else:
-                    p, _ = pc.lru.popitem(last=False)  # the coldest entry
-                    pc.forget(p)
-                    pc.evictions += 1
-                    METRICS.inc("batcher.prefix_cache.evicted_pages")
-                self.page_refs[p] = 1
-                out.append(p)
-            self._note_watermarks()
-        return out
-
-    def retain(self, p: int) -> None:
-        """Take a reference on a cached page (a prefix-cache hit): pages
-        referenced by live rows bump their refcount; LRU-parked ones come
-        back referenced (their content stays addressable)."""
-        with self._lock:
-            if p in self.page_refs:
-                self.page_refs[p] += 1
-            else:
-                del self.prefix_cache.lru[p]
-                self.page_refs[p] = 1
-            self._note_watermarks()
-
-    def release(self, pages: list[int]) -> None:
-        """Drop one reference per page.  At refcount 0 a content-cached
-        page parks at the LRU's most-recently-used end — still serving
-        hits until pool pressure reclaims it — while an uncached page
-        returns straight to the free list."""
-        pc = self.prefix_cache
-        with self._lock:
-            for p in pages:
-                left = self.page_refs[p] - 1
-                if left:
-                    self.page_refs[p] = left
-                    continue
-                del self.page_refs[p]
-                if pc is not None and p in pc.page_hash:
-                    pc.lru[p] = None
-                else:
-                    self.free_pages.append(p)
-
-    def publish_prefix(self, page: int, digest: bytes) -> None:
-        """Publish a page's cached content (:meth:`PrefixCache.register`)
-        under the allocator lock: the hash maps are engine-thread-written,
-        but :meth:`assert_consistent` snapshots them from any thread —
-        every cross-thread-visible PrefixCache mutation rides this lock
-        (``forget`` runs inside the locked :meth:`alloc`)."""
-        with self._lock:
-            self.prefix_cache.register(page, digest)
-
-    def assert_consistent(self, live_rows=(), swap_handles=()) -> None:
-        """Audit the allocator's partition invariants; AssertionError on
-        the first violation.  ``live_rows`` is the page lists of currently
-        resident rows — every reference comes from exactly one row hold,
-        so per-page refcounts must EQUAL the row-hold counts (a dangling
-        ref or a pinned cache page after a crashed run fails here).
-        With a host tier attached the audit extends across tiers:
-        ``swap_handles`` is the swap handles of queued resume requests,
-        and every parked parcel must be owned by exactly one of them
-        (:meth:`HostTier.assert_consistent`) — a stranded handle is the
-        host-RAM analogue of a dangling refcount.
-        Takes one consistent snapshot under the allocator lock; callable
-        from any thread."""
-        if self.host_tier is not None:
-            self.host_tier.assert_consistent(swap_handles)
-        pc = self.prefix_cache
-        with self._lock:
-            lru = set(pc.lru) if pc is not None else set()
-            hashed = set(pc.page_hash) if pc is not None else set()
-            free_list = list(self.free_pages)
-            refs = dict(self.page_refs)
-        free = set(free_list)
-        refed = set(refs)
-        assert len(free) == len(free_list), (
-            f"free list holds duplicates: {sorted(free_list)}"
-        )
-        assert 0 not in (free | refed | lru), "scratch page 0 escaped the pool"
-        for a, b, what in ((free, refed, "free and refcounted"),
-                           (free, lru, "free and LRU-parked"),
-                           (refed, lru, "refcounted and LRU-parked")):
-            assert not (a & b), f"pages both {what}: {sorted(a & b)}"
-        accounted = free | refed | lru
-        expect = set(range(1, self.num_pages))
-        assert accounted == expect, (
-            f"pages leaked (neither free, refcounted, nor LRU-parked): "
-            f"{sorted(expect - accounted)}; "
-            f"foreign pages: {sorted(accounted - expect)}"
-        )
-        assert all(v >= 1 for v in refs.values()), (
-            f"non-positive refcounts: {refs}"
-        )
-        holds: dict[int, int] = {}
-        for pages in live_rows:
-            for p in pages:
-                holds[p] = holds.get(p, 0) + 1
-        assert holds == refs, (
-            f"refcounts diverge from live-row holds: refs={refs} "
-            f"holds={holds}"
-        )
-        for p in lru:
-            assert p in hashed, (
-                f"LRU-parked page {p} has no cached content"
-            )
-
-
 @dataclass
 class _PendingPrefill:
     """A chunked prefill in flight: the request's prompt enters the row's
@@ -1925,66 +1353,6 @@ class _RowState:
     pages: list[int] = field(default_factory=list)  # paged mode: the pool
     #                     pages this row owns (freed on completion)
     streamed: int = 0  # tokens already delivered to run()'s on_tokens
-
-
-def pages_are_private(cfg: ModelConfig) -> bool:
-    """True where nothing but the admission's splice and the decode step
-    ever touches a page: :func:`refuse_unpaged_state` has refused every
-    feature that reads [.., KVH, HD] rows out of the pool (prefix cache,
-    named prefixes, tiering, import/export, chunked prefill, speculation,
-    the int8 pool, a mesh), which it does for the family that keeps
-    convolution state beside its pages.  Only then may heads narrower than
-    a 128-lane row lie folded in the pool
-    (ops.decode_attn.pool_head_shape; heads of 128 never fold)."""
-    return cfg.family == "hybrid"
-
-
-def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
-    """Refuse, by name and with the reason, every feature that moves or
-    keeps keys and values and does not yet carry the state a hybrid model
-    holds beside them (each convolution layer's last gated inputs a row:
-    :class:`~..models.model.HybridCache`).  Served anyway, such a feature
-    would hand a row its pages without its state.  ``asked`` maps a
-    feature's name to whether it was asked for; ``paged_pages`` is the one
-    that must be set."""
-    if not pages_are_private(cfg):
-        return
-    why = {
-        "prefix_cache": "a cached page run restores keys and values, not "
-                        "the convolution state at its end",
-        "kv_bits": "the int8 pool's write path knows no convolution state "
-                   "(ask for kv_bits 16)",
-        "host_pages": "the host tier and swap-out park pages, not the "
-                      "convolution state that belongs to them",
-        "speculative": "a rejected draft would have to roll the "
-                       "convolution state back",
-        "prefill_chunk": "a chunked prefill would have to hand the "
-                         "convolution state from bite to bite",
-        "token_budget": "it chunks prefills, which would have to hand the "
-                        "convolution state from bite to bite",
-        "mesh": "the convolution state and the expert stacks have no "
-                "sharding rule yet (mesh.model > 1 included)",
-        "named_prefix": "a registered prefix keeps keys and values, not "
-                        "the convolution state at its end",
-        "kv_import": "KV import/export ships pages, not convolution state",
-        "kv_export": "KV import/export ships pages, not convolution state",
-        "sessions": "a session keeps keys and values between turns, not "
-                    "the convolution state",
-        "padded_generate": "generate_text pads rows of unlike length, and "
-                           "the convolution state would be taken at the "
-                           "padded end; serve through continuous_batcher",
-    }
-    if asked.pop("paged_pages", 1) is None:
-        raise ValueError(
-            f"{cfg.family} model: the batcher serves its keys and values "
-            "from the page pool only; pass paged_pages"
-        )
-    for name, value in asked.items():
-        if value:
-            raise ValueError(
-                f"{name} is not supported for a model with convolution "
-                f"state (family {cfg.family!r}): {why[name]}"
-            )
 
 
 class ContinuousBatcher:
@@ -2155,7 +1523,7 @@ class ContinuousBatcher:
         # the reference (not a call) below is the single default-wiring
         # point.
         self._clock = clock if clock is not None else time.perf_counter
-        refuse_unpaged_state(
+        kv_cache.refuse_unpaged_state(
             cfg, paged_pages=paged_pages, prefix_cache=prefix_cache,
             kv_bits=kv_bits == 8, host_pages=host_pages,
             speculative=draft_params is not None,
@@ -2195,7 +1563,7 @@ class ContinuousBatcher:
                 parallel.pipelined or parallel.seq_parallel
             ):
                 # Mesh-native paged serving: the pool shards its KV-head
-                # axis over 'model' (parallel.specs.page_pool_specs) and
+                # axis over 'model' (models.kv_cache.pool_specs) and
                 # the paged decode kernel runs per shard under shard_map
                 # (ops/decode_attn.py) — each shard holds whole
                 # heads, so the head count must divide.  Pipelined /
@@ -2410,11 +1778,10 @@ class ContinuousBatcher:
                 pm_built = parallel
 
                 def build_pool():
-                    return _pool_constrain(pm_built, _paged_pool(
-                        cfg, paged_pages, page_size,
+                    return kv_cache.constrain(pm_built, kv_cache.make_pool(
+                        cfg, paged_pages, page_size, kv_bits=kv_bits,
                         dtype=(jnp.dtype(parallel.kv_dtype)
                                if parallel.kv_dtype else None),
-                        kv_bits=kv_bits,
                     ))
 
                 self.cache = jax.jit(build_pool)()
@@ -2426,21 +1793,21 @@ class ContinuousBatcher:
                     lambda: parallel.init_cache(batch_slots, max_len)
                 )()
         elif paged_pages is not None:
-            self.cache = _paged_pool(
-                cfg, paged_pages, page_size,
+            self.cache = kv_cache.make_pool(
+                cfg, paged_pages, page_size, kv_bits=kv_bits,
                 dtype=jnp.dtype(kv_dtype) if kv_dtype else None,
-                kv_bits=kv_bits, slots=batch_slots,
+                slots=batch_slots,
             )
-            if isinstance(self.cache, HybridCache):
+            if cfg.family == "hybrid":
                 METRICS.set_gauge("batcher.conv_state_bytes",
                                   float(self.cache.conv.nbytes))
         else:
-            self.cache = model_lib.init_cache(
+            self.cache = kv_cache.init_cache(
                 cfg, batch_slots, cache_len,
                 dtype=jnp.dtype(kv_dtype) if kv_dtype else None,
             )
         if self.speculative:
-            self.draft_cache = model_lib.init_cache(
+            self.draft_cache = kv_cache.init_cache(
                 draft_cfg, batch_slots, cache_len,
                 dtype=jnp.dtype(kv_dtype) if kv_dtype else None,
             )
@@ -2578,7 +1945,7 @@ class ContinuousBatcher:
         """Prefill a shared prefix (e.g. a system prompt) ONCE; requests
         submitted with ``prefix=name`` reuse its KV instead of recomputing
         it — admission then prefills only the request's suffix."""
-        refuse_unpaged_state(self.cfg, named_prefix=True)
+        kv_cache.refuse_unpaged_state(self.cfg, named_prefix=True)
         ids = (
             self.tokenizer.encode(prefix)
             if isinstance(prefix, str)
@@ -2592,12 +1959,12 @@ class ContinuousBatcher:
         # headroom slots and the admission splice needs shape-matched rows.
         # Paged mode: the TABLE width (pages_per_row * page_size — equal to
         # self.s except under speculation, whose tables carry scratch-tail
-        # pages), since _paged_splice reshapes the row into exactly the
+        # pages), since kv_cache.write_row reshapes the row into exactly the
         # page-list's pages.
         width = (self.pages_per_row * self.page_size if self.paged
                  else self.cache.k.shape[-3])
-        row_cache = model_lib.init_cache(
-            self.cfg, 1, width, dtype=_row_dtype_of(self.cache)
+        row_cache = kv_cache.init_cache(
+            self.cfg, 1, width, dtype=kv_cache.row_dtype(self.cache)
         )
         positions = jnp.arange(len(ids), dtype=jnp.int32)[None, :]
         _, row_cache = _fwd(self.pm)(
@@ -2650,7 +2017,7 @@ class ContinuousBatcher:
             return
         corrupt = rule is not None and rule.action == "corrupt"
         pages = [p for p, _ in cand]
-        payload = _export_pages_raw(
+        payload = kv_cache.export_raw(
             self.cache, jnp.asarray(self._padded_page_list(pages))
         )
         parked = self.host_tier.park_spill(
@@ -2725,7 +2092,7 @@ class ContinuousBatcher:
         matcher caps hits the same way, so shipping the last partial page
         would be dead weight.  Pages are retained across the gather so
         pool pressure cannot reclaim them mid-export."""
-        refuse_unpaged_state(self.cfg, kv_export=True)
+        kv_cache.refuse_unpaged_state(self.cfg, kv_export=True)
         pc = self.prefix_cache
         if self.pool is None or pc is None:
             return None
@@ -2740,7 +2107,7 @@ class ContinuousBatcher:
         for p in pages:
             self._retain_page(p)
         try:
-            row_k, row_v = _gather_row_pages(
+            row_k, row_v = kv_cache.gather_row(
                 self.cache, jnp.asarray(np.asarray(pages, np.int32))
             )
             l, _one, _w, kvh, hd = row_k.shape
@@ -2763,7 +2130,7 @@ class ContinuousBatcher:
         The engine thread applies it at its next round boundary and calls
         ``on_done(ok, reason)`` from there; the caller is responsible for
         waking the engine."""
-        refuse_unpaged_state(self.cfg, kv_import=True)
+        kv_cache.refuse_unpaged_state(self.cfg, kv_import=True)
         with self._lock:
             self._kv_imports.append((digests, k_pages, v_pages, on_done))
 
@@ -2796,7 +2163,7 @@ class ContinuousBatcher:
         round boundary and calls ``on_done(payload_or_None)`` from there
         (the :meth:`export_prefix_pages` result); the caller is
         responsible for waking the engine."""
-        refuse_unpaged_state(self.cfg, kv_export=True)
+        kv_cache.refuse_unpaged_state(self.cfg, kv_export=True)
         with self._lock:
             self._kv_exports.append((list(ids), on_done))
 
@@ -2847,7 +2214,7 @@ class ContinuousBatcher:
         # The scatter's page count is a compile dimension; distinct
         # overlap widths compile distinct (tiny) programs — bounded by
         # pages_per_row, and imports sit far off the decode hot path.
-        self.cache = _import_pages(
+        self.cache = kv_cache.import_full(
             self.cache, jnp.asarray(np.asarray(pages, np.int32)),
             jnp.asarray(np.ascontiguousarray(k_pages[:, missing])),
             jnp.asarray(np.ascontiguousarray(v_pages[:, missing])),
@@ -3414,7 +2781,7 @@ class ContinuousBatcher:
         if not tier.can_fit(len(row.pages)):
             METRICS.inc("batcher.kv_swaps.fallback")
             return None
-        payload = _export_pages_raw(
+        payload = kv_cache.export_raw(
             self.cache, jnp.asarray(self._padded_page_list(row.pages))
         )
         handle = tier.park_swap(payload, len(row.pages), corrupt=corrupt)
@@ -3467,7 +2834,7 @@ class ContinuousBatcher:
         self.tables[i] = page_list
         # The parcel was exported bucket-padded; scatter through the same
         # padded list (pad slots rewrite the scratch page — never read).
-        self.cache = _import_pages_raw(
+        self.cache = kv_cache.import_raw(
             self.cache, jnp.asarray(self._padded_page_list(pages)),
             *(jnp.asarray(a) for a in payload), pm=self.pm,
         )
@@ -3664,7 +3031,7 @@ class ContinuousBatcher:
                 )
             return jnp.asarray(s)
 
-        self.cache = _import_pages_raw(
+        self.cache = kv_cache.import_raw(
             self.cache, jnp.asarray(padded),
             *(stack(j) for j in range(len(payloads[0]))), pm=self.pm,
         )
@@ -4076,13 +3443,13 @@ class ContinuousBatcher:
             if cached_pages:
                 read_list = np.zeros((self.pages_per_row,), np.int32)
                 read_list[: len(cached_pages)] = cached_pages
-                row_k, row_v = _gather_row_pages(
+                row_k, row_v = kv_cache.gather_row(
                     self.cache, jnp.asarray(read_list)
                 )
                 done = cached_len
             else:
-                rc = model_lib.init_cache(self.cfg, 1, self.s,
-                                          dtype=_row_dtype_of(self.cache))
+                rc = kv_cache.init_cache(self.cfg, 1, self.s,
+                                          dtype=kv_cache.row_dtype(self.cache))
                 row_k, row_v, done = rc.k, rc.v, 0
         self._admit_seq += 1
         # The reserving row holds the cached pages so cancel_row /

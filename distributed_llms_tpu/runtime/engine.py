@@ -22,7 +22,7 @@ import contextlib
 from ..core.config import Config, ModelConfig, RuntimeConfig
 from ..core.observability import METRICS, get_logger
 from ..core import profiling
-from ..models import model as model_lib
+from ..models import kv_cache, model as model_lib
 from ..models.presets import get_preset
 from . import generate as gen_lib
 from . import shapes as shapes_lib
@@ -93,7 +93,8 @@ class InferenceEngine:
         self.rt = rt
         self.parallel = parallel
         self.tokenizer = tokenizer or get_tokenizer(None)
-        self._refuse(mesh=parallel is not None, speculative=rt.spec_decode)
+        kv_cache.refuse_unpaged_state(
+            cfg, mesh=parallel is not None, speculative=rt.spec_decode)
         # Out-of-vocab ids silently become NaN embeddings (jnp.take fills
         # OOB gathers) — reject the mismatch loudly instead.
         tok_vocab = getattr(self.tokenizer, "vocab_size", None)
@@ -114,7 +115,7 @@ class InferenceEngine:
             # KV-cache dtype knob: bound once so the jitted decode sees a
             # stable (identity-hashed) make_cache and caches the compilation.
             kv_dtype = jnp.dtype(rt.kv_cache_dtype)
-            self._make_cache = lambda cfg_, b, s, prompt_len=None: model_lib.init_cache(
+            self._make_cache = lambda cfg_, b, s, prompt_len=None: kv_cache.init_cache(
                 cfg_, b, s, dtype=kv_dtype
             )
         if rt.spec_decode:
@@ -159,13 +160,6 @@ class InferenceEngine:
         self.sessions = SessionManager(
             max_resident=rt.max_resident_sessions if rt.kv_host_spill else (1 << 30)
         )
-
-    def _refuse(self, **asked) -> None:
-        """Refuse by name what a model with state beside its keys and
-        values cannot be served with (batcher.refuse_unpaged_state)."""
-        from .batcher import refuse_unpaged_state
-
-        refuse_unpaged_state(self.cfg, **asked)
 
     @classmethod
     def from_preset(
@@ -281,7 +275,7 @@ class InferenceEngine:
     def generate_text(
         self, prompts: list[str], max_new_tokens: int | None = None, seed: int | None = None
     ) -> GenerationResult:
-        self._refuse(padded_generate=True)
+        kv_cache.refuse_unpaged_state(self.cfg, padded_generate=True)
         tok = self.tokenizer
         prompt_arr, lens, n_real = self._encode_rows(prompts, batch=None)
         n_new = self.rt.max_decode_steps if max_new_tokens is None else max_new_tokens
@@ -437,7 +431,7 @@ class InferenceEngine:
     ) -> tuple[str, GenerationResult]:
         """Open a session: prefill + decode, keeping the KV cache for
         continuation turns.  Returns (session_id, result)."""
-        self._refuse(sessions=True)
+        kv_cache.refuse_unpaged_state(self.cfg, sessions=True)
         n_new = self.rt.max_decode_steps if max_new_tokens is None else max_new_tokens
         max_len = self._session_max_len()
         chunk, lens, n_real = self._encode_rows(prompts, batch=None)
@@ -581,7 +575,7 @@ class InferenceEngine:
             prefix_cache = self.rt.prefix_cache
         if paged_pages is not None and self.parallel is not None:
             # Mesh-native paged serving: the pool shards its KV-head axis
-            # over 'model' (batcher + parallel.specs.page_pool_specs), so
+            # over 'model' (batcher + models.kv_cache.pool_specs), so
             # the head count must divide.  Explicit requests that cannot
             # shard error loudly; a config-inherited paged_pages on a
             # mesh whose head count doesn't divide degrades to contiguous

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.config import ModelConfig, RuntimeConfig
-from ..models import model as model_lib
+from ..models import kv_cache, model as model_lib
 from . import sampling
 
 
@@ -89,7 +89,7 @@ def generate_tokens(
     if forward_fn is None:
         forward_fn = _default_forward
     if make_cache is None:
-        make_cache = model_lib.init_cache
+        make_cache = kv_cache.init_cache
     b, t = prompt.shape
     max_len = t + max_new_tokens
     cache = make_cache(cfg, b, max_len, prompt_len=t)

@@ -6,7 +6,7 @@ parcels for preemption victims and spilled prefix-cache pages, with a
 single-worker D2H pipeline and checksum verification — while batcher.py
 keeps the batching mechanism and runtime/scheduler.py the policy.  See
 :class:`HostTier` for the contract; tests/runtime/test_kv_tiering.py pins
-it (imports re-exported through runtime.batcher stay valid).
+it.
 """
 
 from __future__ import annotations

@@ -74,7 +74,7 @@ import json
 import time
 
 from ..core.observability import METRICS, get_logger
-from .batcher import PrefixCache
+from .pages import PrefixCache
 # One definition of the HTTP front-door limits/reasons/error shape for
 # both tiers — the router must shed/parse exactly like the replicas do.
 from .server import (

@@ -1739,7 +1739,7 @@ class InferenceServer:
         digest-chain recompute, the ``xfer.recv``/``xfer.verify`` sites)
         and hand verified payloads to the engine thread for adoption."""
         from ..cluster import kv_transfer
-        from .batcher import PrefixCache
+        from .pages import PrefixCache
 
         self._conns.add(writer)
         try:

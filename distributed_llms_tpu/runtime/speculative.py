@@ -1,8 +1,8 @@
 """Speculative decoding: draft k tokens with a cheap model, verify them all
 in ONE target forward, commit the longest agreeing prefix plus one token.
 
-Decode on TPU is weight-bandwidth bound (BASELINE.md: one token per full
-weight stream).  Verification reads the target's weights once per ROUND of
+Decode on TPU is weight-bandwidth bound (one token per full weight
+stream; PERF.md, section 5).  Verification reads the target's weights once per ROUND of
 up to k+1 tokens instead of once per token, so end-to-end speed multiplies
 by ~(mean accepted + 1) while the MXU does a (k+1)-token matmul it is far
 better shaped for than single-token decode.  The reference framework has no
@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.config import ModelConfig
-from ..models import model as model_lib
+from ..models import kv_cache, model as model_lib
 from . import sampling
 
 
@@ -138,7 +138,7 @@ def backfill_coords(
 
 def _prefill(params, cfg, prompt, prompt_lens, max_len):
     b, t = prompt.shape
-    cache = model_lib.init_cache(cfg, b, max_len)
+    cache = kv_cache.init_cache(cfg, b, max_len)
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
     logits, cache = model_lib.forward(
         params, cfg, prompt, positions=positions, cache=cache,

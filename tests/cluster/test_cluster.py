@@ -34,7 +34,7 @@ def fast_cfg(**kw):
 import numpy as np
 
 from distributed_llms_tpu.cluster import kv_transfer
-from distributed_llms_tpu.runtime.batcher import PrefixCache
+from distributed_llms_tpu.runtime.pages import PrefixCache
 
 
 def _kv_payload(page_size=4, n_pages=2, tid="tx1"):

@@ -1,0 +1,167 @@
+"""The seam of models/kv_cache.py, the one owner of how a page of keys and
+values is stored: each movement of pages holds for every format (the
+full-width pool, the int8 pool, the hybrid pool with its folded heads and
+its state that is not paged), and nothing under runtime/, parallel/ or
+cluster/ tells one format from another."""
+
+import dataclasses
+import pathlib
+import re
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import PartitionSpec as P
+
+from distributed_llms_tpu.checkpoint.quantize import (kv_dequantize,
+                                                      kv_quantize)
+from distributed_llms_tpu.models import kv_cache
+from distributed_llms_tpu.models.presets import get_preset
+
+PAGES, BLK, SLOTS = 12, 8, 3
+PACKAGE = pathlib.Path(kv_cache.__file__).resolve().parents[1]
+
+
+def _dense(kv_bits):
+    return get_preset("llama-tiny"), dict(kv_bits=kv_bits,
+                                          dtype=jnp.bfloat16)
+
+
+def _hybrid():
+    # two KV heads of 64: they lie folded, one 128-lane row, in the pool
+    return dataclasses.replace(get_preset("lfm2-tiny"), head_dim=64), \
+        dict(slots=SLOTS)
+
+
+FORMATS = {"bf16": lambda: _dense(16), "int8": lambda: _dense(8),
+           "hybrid": _hybrid}
+
+
+@pytest.fixture(params=sorted(FORMATS))
+def fmt(request):
+    """(name, cfg, a pool full of noise): a write that strays, or a leaf
+    that is dropped, shows wherever it lands."""
+    cfg, kw = FORMATS[request.param]()
+    pool = kv_cache.make_pool(cfg, PAGES, BLK, **kw)
+    keys = iter(jax.random.split(jax.random.key(5), 8))
+
+    def noise(x):
+        r = jax.random.normal(next(keys), x.shape) * 4.0
+        if x.dtype == jnp.int8:
+            return jnp.clip(jnp.round(r * 8), -127, 127).astype(jnp.int8)
+        return (jnp.abs(r) + 0.5 if request.param == "int8" else r
+                ).astype(x.dtype)
+
+    return request.param, cfg, jax.tree.map(noise, pool)
+
+
+def _row(cfg, pool, pages):
+    """A transient row cache of ``pages`` pages, as a prefill leaves it:
+    full-width [L, 1, P*BLK, KVH, HD] in the pool's row dtype."""
+    row = kv_cache.init_cache(cfg, 1, pages * BLK,
+                              dtype=kv_cache.row_dtype(pool))
+    keys = iter(jax.random.split(jax.random.key(9), 4))
+    return jax.tree.map(
+        lambda x: jax.random.normal(next(keys), x.shape).astype(x.dtype),
+        row)
+
+
+def test_write_row_then_gather_row_returns_the_row(fmt):
+    name, cfg, pool = fmt
+    row = _row(cfg, pool, 3)
+    page_list = jnp.asarray([7, 2, 9], jnp.int32)
+    before = jax.tree.map(np.asarray, pool)
+    # (op by op, as the reference below is computed: a fused x / scale
+    # may round one element in thousands the other way)
+    new = kv_cache.write_row(pool, page_list, row, jnp.int32(1))
+    got_k, got_v = kv_cache.gather_row(new, page_list)
+    for got, want in ((got_k, row.k), (got_v, row.v)):
+        if name == "int8":  # kv_quantize's round trip, nothing further
+            want = kv_dequantize(*kv_quantize(want), want.dtype)
+        # (a folded pool hands its own last two axes back: the same bytes)
+        np.testing.assert_array_equal(
+            np.asarray(got).reshape(want.shape), np.asarray(want))
+    # ... and no other page moved.
+    others = np.setdiff1d(np.arange(PAGES), np.asarray(page_list))
+    for leaf, old in zip(jax.tree.leaves(new), jax.tree.leaves(before)):
+        if leaf.shape[1:3] == (PAGES, BLK):
+            np.testing.assert_array_equal(
+                np.asarray(leaf)[:, others], old[:, others])
+    if name == "hybrid":  # the state that is not paged lands in its slot
+        np.testing.assert_array_equal(
+            np.asarray(new.conv[:, 1]), np.asarray(row.conv[:, 0]))
+        np.testing.assert_array_equal(
+            np.asarray(new.conv[:, 0]), before.conv[:, 0])
+
+
+def test_export_raw_then_import_raw_restores_the_pool_bit_for_bit(fmt):
+    _, _, pool = fmt
+    page_list = jnp.asarray([3, 1, 5, 0], jnp.int32)  # scratch-padded
+    parcel = kv_cache.export_raw(pool, page_list)
+    wiped = kv_cache.import_raw(
+        pool, page_list, *(jnp.zeros_like(x) for x in parcel))
+    assert not all(
+        np.array_equal(a, b) for a, b in
+        zip(jax.tree.leaves(wiped), jax.tree.leaves(pool)))
+    back = kv_cache.import_raw(wiped, page_list, *parcel)
+    assert jax.tree.structure(back) == jax.tree.structure(pool)
+    for got, want in zip(jax.tree.leaves(back), jax.tree.leaves(pool)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_pool_specs_has_the_pools_tree_structure(fmt):
+    _, cfg, pool = fmt
+    mesh = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
+    specs = kv_cache.pool_specs(cfg, mesh, jax.eval_shape(lambda: pool))
+    is_spec = lambda x: isinstance(x, P)  # noqa: E731
+    assert jax.tree.structure(specs, is_leaf=is_spec) \
+        == jax.tree.structure(pool)
+    # tree.map over (pool, specs) is what constrain() does on a mesh
+    ranks = jax.tree.map(lambda x, s: (x.ndim, len(s)), pool, specs)
+    for ndim, spec_len in jax.tree.leaves(
+            ranks, is_leaf=lambda x: isinstance(x, tuple)):
+        assert spec_len <= ndim
+    assert specs.k[3] == specs.v[3] == "model"
+
+
+def test_page_bytes_is_what_a_page_of_the_pool_holds(fmt):
+    name, cfg, pool = fmt
+    paged = [x for x in jax.tree.leaves(pool)
+             if x.shape[1:3] == (PAGES, BLK)]
+    assert len(paged) == (4 if name == "int8" else 2)
+    want = sum(x.nbytes for x in paged) // PAGES
+    assert kv_cache.page_bytes(
+        cfg, BLK, kv_bits=8 if name == "int8" else 16,
+        dtype=kv_cache.row_dtype(pool)) == want
+
+
+def test_nothing_above_the_seam_tells_one_format_from_another():
+    """runtime/, parallel/ and cluster/ hold a pool and hand it on; which
+    format it is, and its scales, are models/kv_cache.py's to know."""
+    banned = re.compile(
+        r"isinstance\([^)]*(KVCache|QuantKVCache|HybridCache)|\.k_scale")
+    found = [
+        f"{path.relative_to(PACKAGE)}:{n}: {line.strip()}"
+        for sub in ("runtime", "parallel", "cluster")
+        for path in sorted((PACKAGE / sub).rglob("*.py"))
+        for n, line in enumerate(path.read_text().splitlines(), 1)
+        if banned.search(line)
+    ]
+    assert not found, "\n".join(found)
+    model = (PACKAGE / "models" / "model.py").read_text()
+    for spelling in ("len(pool)", ".k_scale", "cache_sk", "cache_sv"):
+        assert spelling not in model, spelling
+
+
+def test_the_host_side_of_the_pool_imports_no_jax():
+    """The router and the gateway hash pages with PrefixCache.page_digests:
+    they must not load an engine's dependencies to do it."""
+    code = ("import sys, distributed_llms_tpu.runtime.pages as p; "
+            "assert 'jax' not in sys.modules, 'pages imports jax'; "
+            "assert p.PagePool and p.PrefixCache.page_digests")
+    subprocess.run([sys.executable, "-c", code], check=True,
+                   cwd=PACKAGE.parent)

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from distributed_llms_tpu.core.config import ModelConfig
-from distributed_llms_tpu.models import layers, model as model_lib
+from distributed_llms_tpu.models import kv_cache, layers, model as model_lib
 from distributed_llms_tpu.models.presets import get_preset
 from distributed_llms_tpu.models.reference import lfm2_moe
 from tools.reference_check import reference_cfg
@@ -53,7 +53,7 @@ def test_padded_prefill_then_decode_is_the_reference(tiny, n, bucket):
     ref = reference(params, cfg, toks)
     padded = np.zeros((1, bucket), np.int32)
     padded[0, :n] = toks[:n]
-    cache = model_lib.init_cache(cfg, 1, 80)
+    cache = kv_cache.init_cache(cfg, 1, 80)
     logits, cache, stats = model_lib.forward(
         params, cfg, jnp.asarray(padded), cache=cache,
         cache_index=jnp.int32(0), seq_lens=jnp.asarray([n], jnp.int32),
@@ -79,13 +79,13 @@ def test_state_at_the_buckets_end_would_be_wrong(tiny):
     padded = np.zeros((1, 8), np.int32)
     padded[0, :5] = toks
     run = lambda **kw: model_lib.forward(  # noqa: E731
-        params, cfg, jnp.asarray(padded), cache=model_lib.init_cache(cfg, 1, 16),
+        params, cfg, jnp.asarray(padded), cache=kv_cache.init_cache(cfg, 1, 16),
         cache_index=jnp.int32(0), **kw)[1].conv
     right = run(seq_lens=jnp.asarray([5], jnp.int32))
     assert float(jnp.max(jnp.abs(right - run()))) > 1e-3
     exact = model_lib.forward(
         params, cfg, jnp.asarray(toks)[None],
-        cache=model_lib.init_cache(cfg, 1, 16), cache_index=jnp.int32(0))[1]
+        cache=kv_cache.init_cache(cfg, 1, 16), cache_index=jnp.int32(0))[1]
     np.testing.assert_allclose(np.asarray(right), np.asarray(exact.conv),
                                atol=1e-6)
 

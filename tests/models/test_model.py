@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distributed_llms_tpu.models import model, presets
+from distributed_llms_tpu.models import kv_cache, model, presets
 from distributed_llms_tpu.checkpoint import convert
 
 
@@ -31,7 +31,7 @@ def test_kv_cache_matches_full_forward(name):
     full_logits, _ = model.forward(params, cfg, toks)
 
     # prefill 6 tokens, then decode 3 incrementally
-    cache = model.init_cache(cfg, 2, 16)
+    cache = kv_cache.init_cache(cfg, 2, 16)
     pre_logits, cache = model.forward(params, cfg, toks[:, :6], cache=cache, cache_index=jnp.int32(0))
     np.testing.assert_allclose(np.asarray(full_logits[:, :6]), np.asarray(pre_logits), rtol=1e-4, atol=1e-4)
     for t in range(6, 9):

@@ -16,7 +16,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from distributed_llms_tpu.models import model as model_lib, presets
+from distributed_llms_tpu.models import kv_cache, model as model_lib, presets
 from distributed_llms_tpu.runtime import batcher as batcher_lib
 
 LAYERS, PAGES, BLK, SLOTS, STEPS = 3, 7, 16, 2, 2
@@ -30,7 +30,7 @@ def tiny():
 
 def _noise_pool(cfg, kv_bits):
     """A pool full of noise: a write that strays shows wherever it lands."""
-    pool = batcher_lib._paged_pool(cfg, PAGES, BLK, kv_bits=kv_bits)
+    pool = kv_cache.make_pool(cfg, PAGES, BLK, kv_bits=kv_bits)
     keys = iter(jax.random.split(jax.random.key(3), 4))
 
     def noise(x):
@@ -56,7 +56,7 @@ def test_pool_leaves_are_carries_of_the_layer_scan(tiny, kv_bits):
     carries: no constant, scanned input or stacked output of it (nor of
     the step scan around it) has a pool leaf's shape."""
     cfg, params = tiny
-    pool = batcher_lib._paged_pool(cfg, PAGES, BLK, kv_bits=kv_bits)
+    pool = kv_cache.make_pool(cfg, PAGES, BLK, kv_bits=kv_bits)
     shapes = {x.shape for x in jax.tree.leaves(pool)}
     i32 = lambda *s: jnp.zeros(s, jnp.int32)
     jaxpr = jax.make_jaxpr(

@@ -25,7 +25,7 @@ import pytest
 
 from distributed_llms_tpu.checkpoint import convert
 from distributed_llms_tpu.core.config import ModelConfig
-from distributed_llms_tpu.models import model, presets
+from distributed_llms_tpu.models import kv_cache, model, presets
 
 
 def _windowed_tiny(window=4, num_layers=4):
@@ -73,7 +73,7 @@ def test_kv_cache_matches_full_forward_windowed():
     toks = jax.random.randint(jax.random.key(1), (2, 9), 0, cfg.vocab_size,
                               dtype=jnp.int32)
     full_logits, _ = model.forward(params, cfg, toks)
-    cache = model.init_cache(cfg, 2, 16)
+    cache = kv_cache.init_cache(cfg, 2, 16)
     pre, cache = model.forward(params, cfg, toks[:, :6], cache=cache,
                                cache_index=jnp.int32(0))
     np.testing.assert_allclose(np.asarray(full_logits[:, :6]), np.asarray(pre),

@@ -17,7 +17,7 @@ import pytest
 
 from distributed_llms_tpu.checkpoint.quantize import QuantizedTensor
 from distributed_llms_tpu.core.config import ModelConfig
-from distributed_llms_tpu.models import model as model_lib
+from distributed_llms_tpu.models import kv_cache, model as model_lib
 from distributed_llms_tpu.runtime import batcher as batcher_lib
 
 # Widths the kernel can tile: heads of 128 (a scale block a head), the FFN
@@ -58,13 +58,13 @@ def _prefill_contiguous(params, cfg):
     if cfg.family == "hybrid":
         kw["seq_lens"] = jnp.asarray([9, 6], jnp.int32)
     logits, cache = model_lib.forward(
-        params, cfg, tokens, cache=model_lib.init_cache(cfg, 2, 32),
+        params, cfg, tokens, cache=kv_cache.init_cache(cfg, 2, 32),
         cache_index=jnp.int32(0), **kw)
     return logits, cache
 
 
 def _decode_paged(params, cfg):
-    pool = batcher_lib._paged_pool(cfg, PAGES, BLK, slots=2)
+    pool = kv_cache.make_pool(cfg, PAGES, BLK, slots=2)
     lens = jnp.asarray([17, 35], jnp.int32)
     kw = {}
     if cfg.family == "hybrid":

@@ -454,7 +454,7 @@ def test_kv_import_partial_overlap_allocates_only_missing(warmed):
     and the pool audits clean throughout."""
     import numpy as np
 
-    from distributed_llms_tpu.runtime.batcher import PrefixCache
+    from distributed_llms_tpu.runtime.pages import PrefixCache
 
     b = _replica_batcher(tiny)
     l, _nb, blk, kvh, hd = b.cache.k.shape

@@ -528,20 +528,41 @@ def test_stop_hit_before_deadline_reports_stop(tiny):
     """A stop-sequence hit followed by the deadline expiring during the
     cancel-ack drain is a STOP, not a timeout: the response legitimately
     terminated before the deadline; only the row-free ack was late."""
-    want = expected_text(tiny, "halt", 8)
-    # First chunk lands fast and contains the stop; every later chunk
-    # (the ack carrier) stalls past the deadline but inside the grace.
-    plane = FaultPlane.parse("batcher.decode:stall@2+:1.5")
+    # The stop must first show in the FIRST decode chunk's delivery (the
+    # admission's token, then chunk_steps=4 more), so that the chunk after
+    # it carries the cancel's ack.  Which characters a random tiny model
+    # emits changes with its initialisation (most ids of the 512 are not
+    # text at all), so the stop is read off the expected stream and the
+    # premise is asserted, not assumed: "halt", this test's prompt until
+    # PR 30, had come to emit no text in its first chunk, and the test
+    # failed as a timeout in every driver run from PR 21 to PR 29.
+    prompt = "stop"
+    head = expected_text(tiny, prompt, 1)
+    first = expected_text(tiny, prompt, 5)
+    assert first.startswith(head)
+    stop = next((c for c in first[len(head):] if c not in head), None)
+    assert stop is not None, (head, first)
+    # Every chunk after the first (the ack carrier) stalls past the
+    # deadline but inside the server's 10 s ack grace.  overlap=False,
+    # because with dispatch-ahead the engine fires chunk 2's site BEFORE
+    # it delivers chunk 1 and the stall would hold the stop itself back:
+    # the order this test is about is fire 1, deliver 1, fire 2.  The
+    # deadline is the server's wall clock and the first chunk shares a CPU
+    # with five other test workers: 4 s it cannot miss where the old 0.6 s
+    # could, and a stall longer than the deadline outlasts it wherever in
+    # those 4 s the first chunk lands.
+    plane = FaultPlane.parse("batcher.decode:stall@2+:5.0")
 
     async def fn(host, port, srv):
         status, raw = await _request(
             host, port, "POST", "/v1/completions",
-            {"prompt": "halt", "max_tokens": 64, "timeout_s": 0.6,
-             "stop": [want[0]]},
+            {"prompt": prompt, "max_tokens": 64, "timeout_s": 4.0,
+             "stop": [stop]},
         )
         assert status == 200
         out = json.loads(raw)
         assert out["choices"][0]["finish_reason"] == "stop", out
+        assert out["choices"][0]["text"] == first[:first.index(stop)]
         # The ack drained: row freed, pool clean.
         for _ in range(100):
             if all(r.rid is None for r in srv.batcher.rows):
@@ -550,4 +571,4 @@ def test_stop_hit_before_deadline_reports_stop(tiny):
         assert all(r.rid is None for r in srv.batcher.rows)
         srv.batcher.assert_pool_consistent()
 
-    run_with_server(make_batcher(tiny, faults=plane), fn)
+    run_with_server(make_batcher(tiny, faults=plane, overlap=False), fn)

@@ -9,10 +9,9 @@ import numpy as np
 import pytest
 
 from distributed_llms_tpu.core.observability import METRICS
-from distributed_llms_tpu.models import model as model_lib
+from distributed_llms_tpu.models import kv_cache, model as model_lib
 from distributed_llms_tpu.models.presets import get_preset
 from distributed_llms_tpu.models.reference import lfm2_moe
-from distributed_llms_tpu.runtime import batcher as batcher_lib
 from distributed_llms_tpu.runtime.batcher import ContinuousBatcher
 from tools.reference_check import reference_cfg
 
@@ -105,7 +104,7 @@ def test_pool_counts_attention_layers_and_the_state_has_a_gauge(tiny):
     b = batcher(cfg, params)
     assert b.cache.k.shape == (2, 24, 8, 2, 16)  # 2 attention layers of 8
     assert b.cache.conv.shape == (6, 4, 2, 64)   # 6 conv layers, 4 slots
-    assert batcher_lib.pool_page_bytes(cfg, 8) == 2 * 2 * 8 * 2 * 16 * 4
+    assert kv_cache.page_bytes(cfg, 8) == 2 * 2 * 8 * 2 * 16 * 4
     assert METRICS.snapshot()["gauges"]["batcher.conv_state_bytes"] == \
         6 * 4 * 2 * 64 * 4
     assert b.capacity_tokens() == 23 * 8
@@ -114,7 +113,7 @@ def test_pool_counts_attention_layers_and_the_state_has_a_gauge(tiny):
 def test_narrow_heads_lie_folded_in_the_pool():
     cfg = get_preset("lfm2-8b-a1b")
     pool = jax.eval_shape(
-        lambda: batcher_lib._paged_pool(cfg, 512, 64, slots=16))
+        lambda: kv_cache.make_pool(cfg, 512, 64, slots=16))
     assert pool.k.shape == (6, 512, 64, 4, 128)  # 8 heads of 64, two a row
     assert pool.conv.shape == (18, 16, 2, 2048)
 
@@ -142,9 +141,9 @@ def test_speculative_and_unpaged_and_mesh_refuse(tiny):
     with pytest.raises(ValueError, match="pass paged_pages"):
         batcher(cfg, params, paged_pages=None)
     with pytest.raises(ValueError, match="mesh is not supported"):
-        batcher_lib.refuse_unpaged_state(cfg, mesh=True)
+        kv_cache.refuse_unpaged_state(cfg, mesh=True)
     # ... and every other family is let through whatever is asked.
-    batcher_lib.refuse_unpaged_state(
+    kv_cache.refuse_unpaged_state(
         get_preset("llama-tiny"), mesh=True, prefix_cache=True, kv_bits=8)
 
 

@@ -28,12 +28,12 @@ from distributed_llms_tpu.checkpoint.quantize import (kv_dequantize,
                                                       kv_quantize)
 from distributed_llms_tpu.core.observability import METRICS
 from distributed_llms_tpu.models import model as model_lib, presets
-from distributed_llms_tpu.models.model import QuantKVCache
+from distributed_llms_tpu.models.kv_cache import QuantKVCache, page_bytes
 from distributed_llms_tpu.runtime import generate as gen_lib
-from distributed_llms_tpu.runtime.batcher import (ContinuousBatcher,
-                                                  HostTier, PrefixCache,
-                                                  pool_page_bytes)
+from distributed_llms_tpu.runtime.batcher import ContinuousBatcher
 from distributed_llms_tpu.runtime.faults import FaultPlane
+from distributed_llms_tpu.runtime.kv_tier import HostTier
+from distributed_llms_tpu.runtime.pages import PrefixCache
 
 
 @pytest.fixture(scope="module")
@@ -113,7 +113,7 @@ def test_int8_pool_storage_and_capacity(tiny):
     assert b.cache.k_scale.dtype == jnp.float32
     b16 = _paged(cfg, params)
     assert b.capacity_tokens() == b16.capacity_tokens()
-    ratio = (pool_page_bytes(cfg, 16, 16) / pool_page_bytes(cfg, 16, 8))
+    ratio = (page_bytes(cfg, 16, 16) / page_bytes(cfg, 16, 8))
     assert ratio >= 1.8, f"int8 pages only {ratio:.2f}x denser"
     b.assert_pool_consistent()
     b16.assert_pool_consistent()

@@ -11,7 +11,7 @@ The acceptance contract pinned here:
   restore, the int8 QuantKVCache pool, and the dispatch-ahead overlap
   loop on or off.  Sharding changes placement, never results.
 - **The pool actually shards.**  Every pool leaf splits its KV-head axis
-  over 'model' (parallel.specs.page_pool_specs) — per-chip pool bytes
+  over 'model' (models.kv_cache.pool_specs) — per-chip pool bytes
   divide by tp, which is the capacity claim of ROADMAP item 3.
 - **Illegal layouts fail at construction.**  KV heads that do not divide
   over 'model', and the still-unsupported paged x pipelined combination,
@@ -26,9 +26,8 @@ import pytest
 from distributed_llms_tpu.core.config import MeshConfig, RuntimeConfig
 from distributed_llms_tpu.core.observability import METRICS
 from distributed_llms_tpu.models import model as model_lib, presets
-from distributed_llms_tpu.models.model import QuantKVCache
+from distributed_llms_tpu.models.kv_cache import QuantKVCache, pool_specs
 from distributed_llms_tpu.parallel import api as api_lib
-from distributed_llms_tpu.parallel.specs import page_pool_specs
 from distributed_llms_tpu.runtime import generate as gen_lib
 from distributed_llms_tpu.runtime.batcher import ContinuousBatcher
 
@@ -97,7 +96,7 @@ def test_pool_shards_kv_heads_over_model(tiny, devices8):
         assert shard[:3] + shard[4:] == leaf.shape[:3] + leaf.shape[4:]
     # The spec registry matches what the batcher built (graftcheck GC2
     # audits the same function over the fake-mesh ladder).
-    specs = page_pool_specs(cfg, b.pm.mesh)
+    specs = pool_specs(cfg, b.pm.mesh, b.cache)
     assert tuple(specs.k) == (None, None, None, "model", None)
 
 
@@ -109,7 +108,7 @@ def test_int8_pool_shards_scales_with_pages(tiny, devices8):
         assert not leaf.sharding.is_fully_replicated
         assert leaf.sharding.shard_shape(leaf.shape)[3] \
             == cfg.num_kv_heads // 2
-    specs = page_pool_specs(cfg, b.pm.mesh, kv_bits=8)
+    specs = pool_specs(cfg, b.pm.mesh, b.cache)
     assert tuple(specs.k_scale) == (None, None, None, "model")
 
 

@@ -24,10 +24,9 @@ import jax
 
 from distributed_llms_tpu.core.observability import METRICS
 from distributed_llms_tpu.models import model as model_lib, presets
-from distributed_llms_tpu.runtime.batcher import (
-    ContinuousBatcher, PrefixCache,
-)
+from distributed_llms_tpu.runtime.batcher import ContinuousBatcher
 from distributed_llms_tpu.runtime.faults import FaultPlane
+from distributed_llms_tpu.runtime.pages import PrefixCache
 from distributed_llms_tpu.runtime.server import InferenceServer
 from distributed_llms_tpu.runtime.tokenizer import ByteTokenizer
 

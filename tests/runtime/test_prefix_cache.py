@@ -1,5 +1,5 @@
 """Automatic prefix caching: hash-block KV reuse in the paged pool
-(runtime/batcher.py PrefixCache + refcounted page allocator).
+(runtime/pages.py PrefixCache + refcounted page allocator).
 
 Invariants pinned here:
 - exact tokens: at temperature 0 every request served with the automatic

@@ -18,6 +18,7 @@ and ``ServingClient``'s client-side multi-endpoint failover.
 
 import asyncio
 import json
+import threading
 
 import pytest
 
@@ -70,9 +71,13 @@ def warmed(tiny):
 
 def server_factory(tiny, **srv_kw):
     """() -> a fresh, unstarted replica: full server/batcher stack with
-    its own supervisor, small paged pool (7 usable pages = 112 tokens),
-    and a fast watchdog so stall drills resolve quickly."""
-    srv_kw.setdefault("watchdog_timeout_s", 0.4)
+    its own supervisor and a small paged pool (7 usable pages = 112
+    tokens).  The watchdog is slack; a stall drill sets its victim's to
+    ``FAST_WATCHDOG``.  At 0.4 s for every replica it took a first chunk
+    on a busy CPU for a wedged engine, marked the survivors unhealthy too
+    and answered 503 (4 of 22 runs of this file beside five busy workers,
+    PR 30)."""
+    srv_kw.setdefault("watchdog_timeout_s", 60.0)
 
     def make_server():
         return InferenceServer(
@@ -81,6 +86,9 @@ def server_factory(tiny, **srv_kw):
         )
 
     return make_server
+
+
+FAST_WATCHDOG = 0.4  # seconds: a stall drill's victim flips /healthz quickly
 
 
 def run_with_fleet(tiny, n, fn, faults=None, srv_kw=None, router_kw=None):
@@ -291,25 +299,42 @@ def test_chaos_crash_close_drill_fails_over_exact(warmed):
     """The fleet's own chaos site: a ``replica.crash ... close`` rule
     kills the in-flight replica at the next probe tick (no direct
     fleet.kill from the test) — the zero-streamed request re-sends
-    verbatim to the survivor and completes byte-exact."""
+    verbatim to the survivor and completes byte-exact.
+
+    The drill needs the request still in flight when the tick lands, and
+    32 tokens of a warmed tiny model take less than one 50 ms probe
+    interval on an idle CPU (the request finished first and nothing
+    failed over: this test failed in every driver run from PR 21 to
+    PR 29).  So no clock decides it: every replica's decode chunks wait on
+    ``held`` until the rule has fired, and the test waits on the rule,
+    bounded by run_with_fleet's own limit and by nothing shorter."""
     plane = FaultPlane()
     reqs = [("chaos crash request", 32)]
     wants = expected_texts(tiny, reqs)
+    held = threading.Event()
+
+    class HoldDecode(FaultPlane):
+        def fire(self, site, *args, **kw):
+            if site == "batcher.decode":
+                held.wait(120.0)
+            return super().fire(site, *args, **kw)
 
     async def fn(host, port, fleet, router):
         f0 = METRICS.get_counter("router.failovers")
-        task = asyncio.create_task(_request(
-            host, port, "POST", "/v1/completions",
-            {"prompt": reqs[0][0], "max_tokens": reqs[0][1]},
-        ))
-        victim = await _wait_inflight(fleet)
-        rule = plane.add("replica.crash", "close", when="1",
-                         tag=victim.name)
-        for _ in range(400):  # the kill lands at the next probe tick
-            if rule.fired:
-                break
-            await asyncio.sleep(0.01)
-        assert rule.fired == 1
+        for h in fleet.replicas:
+            h.server.batcher.faults = HoldDecode()
+        try:
+            task = asyncio.create_task(_request(
+                host, port, "POST", "/v1/completions",
+                {"prompt": reqs[0][0], "max_tokens": reqs[0][1]},
+            ))
+            victim = await _wait_inflight(fleet)
+            rule = plane.add("replica.crash", "close", when="1",
+                             tag=victim.name)
+            while not rule.fired:  # the kill lands at the next probe tick
+                await asyncio.sleep(0.01)
+        finally:
+            held.set()
         status, _, raw = await task
         body = json.loads(raw)
         assert status == 200, body
@@ -339,6 +364,7 @@ def test_stall_past_watchdog_fails_over(warmed):
         # BEFORE sending, so its FIRST decode chunk stalls: /healthz flips
         # stalled, the probe marks it unhealthy, the proxy aborts.
         victim = fleet["r0"]
+        victim.server.watchdog_timeout_s = FAST_WATCHDOG
         rule = plane.add("replica.stall", "delay", when="1", arg=2.0,
                          tag="r0")
         for _ in range(200):  # the wedge arms at the next probe tick
@@ -602,6 +628,7 @@ def test_chaos_fleet_crash_stall_drain_storm(warmed):
         await fleet.kill("r0")
         # Phase 2 — STALL r1's engine past the watchdog (heals in 1.2s).
         await asyncio.sleep(0.1)
+        fleet["r1"].server.watchdog_timeout_s = FAST_WATCHDOG
         plane.add("replica.stall", "delay", when="1", arg=1.2, tag="r1")
         for _ in range(600):  # wait for the stall to be observed + healed
             if fleet["r1"].state == "healthy" and plane.rules[-1].fired:

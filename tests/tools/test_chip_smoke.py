@@ -44,24 +44,6 @@ def test_alone_in_a_directory_it_fails(tmp_path):
     assert r.returncode != 0 and '"rehearsal": "passed"' not in r.stdout
 
 
-def test_bench_refuses_to_time_the_cpu_unasked(monkeypatch):
-    """bench.py on a host where JAX found only the CPU fails, unless the
-    caller itself set JAX_PLATFORMS=cpu."""
-    monkeypatch.syspath_prepend(REPO)
-    import bench
-
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    monkeypatch.setattr(sys, "argv", ["bench.py"])
-    with pytest.raises(SystemExit, match="no accelerator"):
-        bench.main()
-    with pytest.raises(ValueError, match="PEAK_FLOPS"):
-        monkeypatch.setattr(
-            bench.jax, "devices",
-            lambda: [type("D", (), {"platform": "tpu", "device_kind": "TPU v9"})()],
-        )
-        bench._peak(bench.PEAK_FLOPS, "PEAK_FLOPS")
-
-
 def test_local_proc_refuses_unless_pinned_to_the_cpu(monkeypatch):
     """dlt-coordinator --local-proc would start worker processes that each
     initialise JAX; on a chip host that hangs, so it refuses at once."""

@@ -39,6 +39,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from distributed_llms_tpu.checkpoint import quantize as quant_lib
 from distributed_llms_tpu.core.config import MeshConfig
 from distributed_llms_tpu.models import model as model_lib
+from distributed_llms_tpu.models.kv_cache import KVCache
 from distributed_llms_tpu.models.presets import get_preset
 from distributed_llms_tpu.parallel import api as api_lib, pipeline as pipeline_lib
 from distributed_llms_tpu.parallel.api import make_parallel_model
@@ -137,7 +138,7 @@ def main() -> int:
         (pipe, l // pipe, b, s, kvh, hd), jnp.dtype(cfg.dtype),
         sharding=NamedSharding(mesh, cache_spec),
     )
-    abs_cache = model_lib.KVCache(k=cache_leaf, v=cache_leaf)
+    abs_cache = KVCache(k=cache_leaf, v=cache_leaf)
     kv_bytes = leaf_bytes_per_device(abs_cache, mesh)
     print(f"per-device KV bytes (b={b}, s={s}): {kv_bytes / 1e9:.2f} GB")
     assert w_bytes + kv_bytes < HBM_PER_CHIP, "weights + KV exceed HBM"

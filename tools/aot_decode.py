@@ -93,13 +93,13 @@ def lower_program(
     from jax.sharding import NamedSharding, PartitionSpec as P
     from jax.sharding import SingleDeviceSharding
 
-    from distributed_llms_tpu.models import model as model_lib
+    from distributed_llms_tpu.models import kv_cache, model as model_lib
     from distributed_llms_tpu.runtime import batcher as batcher_lib
 
     devices = v5e_devices(mesh_model)
     key_shape = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    pool = lambda: batcher_lib._paged_pool(  # noqa: E731
-        cfg, pages, page_size, kv_bits=kv_bits, slots=slots)
+    pool = jax.eval_shape(lambda: kv_cache.make_pool(
+        cfg, pages, page_size, kv_bits=kv_bits, slots=slots))
     if mesh_model > 1:
         from distributed_llms_tpu.core.config import MeshConfig
         from distributed_llms_tpu.parallel import specs as specs_lib
@@ -113,16 +113,14 @@ def lower_program(
                 k, cfg, 8, mesh=pm.mesh), key_shape),
             specs_lib.param_specs(cfg, pm.mesh), pm.mesh)
         cache = _abstract_on_mesh(
-            jax.eval_shape(pool),
-            specs_lib.page_pool_specs(cfg, pm.mesh, kv_bits=kv_bits),
-            pm.mesh)
+            pool, kv_cache.pool_specs(cfg, pm.mesh, pool), pm.mesh)
         on_mesh = {"pm": pm}
     else:
         sh = SingleDeviceSharding(devices[0])
         params = _abstract(jax.eval_shape(
             lambda k: model_lib.init_params_quantized(k, cfg, 8), key_shape),
             sh)
-        cache = _abstract(jax.eval_shape(pool), sh)
+        cache = _abstract(pool, sh)
         on_mesh = {}
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sh)
 
@@ -152,7 +150,7 @@ def lower_program(
             arr((prompt_len,)), arr(()), key, **on_mesh,
         )
     if program == "finish_chunked_admission_paged":
-        dt = batcher_lib._row_dtype_of(cache)
+        dt = kv_cache.row_dtype(cache)
         row = arr((cfg.num_layers, 1, max_len, cfg.num_kv_heads,
                    cfg.head_dim_), dt)
         return batcher_lib.finish_chunked_admission_paged.lower(
@@ -182,8 +180,8 @@ def pool_shaped(hlo_text: str, cfg, pages: int, page_size: int,
     are not instructions that move data and are left out.  ``shards`` is
     the size of ``mesh.model``: a device's program holds that share of the
     KV heads."""
+    from distributed_llms_tpu.models.kv_cache import pages_are_private
     from distributed_llms_tpu.ops.decode_attn import pool_head_shape
-    from distributed_llms_tpu.runtime.batcher import pages_are_private
 
     kvh, hd = pool_head_shape(cfg.num_kv_heads // shards, cfg.head_dim_,
                               fold_narrow=pages_are_private(cfg))

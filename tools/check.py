@@ -21,9 +21,7 @@ ERROR here, not a warning.  Debt that got fixed must leave the baseline
 in the same PR — run the matching ``--baseline-write`` to prune — or the
 baseline rots into a list nobody can audit.
 
-Per-tool wall time prints on stderr (the ``analysis-wall`` bench row
-stamps the same numbers into BASELINE.md so the gate's cost stays
-visible).
+Per-tool wall time prints on stderr, so the gate's cost stays visible.
 
 Exit status: 0 = all clean and no stale entries; 1 = new findings or
 stale entries anywhere; 2 = usage error.

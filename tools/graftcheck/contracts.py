@@ -29,6 +29,7 @@ P_ULYSSES = "distributed_llms_tpu/ops/ulysses.py"
 P_DECODE = "distributed_llms_tpu/ops/decode_attn.py"
 P_QMM = "distributed_llms_tpu/ops/quant_matmul.py"
 P_MODEL = "distributed_llms_tpu/models/model.py"
+P_KV_CACHE = "distributed_llms_tpu/models/kv_cache.py"
 P_SPECS = "distributed_llms_tpu/parallel/specs.py"
 P_SAMPLING = "distributed_llms_tpu/runtime/sampling.py"
 P_CONSTRAIN = "distributed_llms_tpu/runtime/constrain.py"
@@ -62,25 +63,18 @@ def abstract_params(cfg):
 
 
 def abstract_cache(cfg, batch: int, max_len: int):
-    from distributed_llms_tpu.models import model as model_lib
+    from distributed_llms_tpu.models import kv_cache
 
-    return jax.eval_shape(lambda: model_lib.init_cache(cfg, batch, max_len))
-
-
-def abstract_pool(cfg, num_pages: int, page_size: int):
-    from distributed_llms_tpu.runtime import batcher as batcher_lib
-
-    return jax.eval_shape(
-        lambda: batcher_lib._paged_pool(cfg, num_pages, page_size)
-    )
+    return jax.eval_shape(lambda: kv_cache.init_cache(cfg, batch, max_len))
 
 
-def abstract_quant_pool(cfg, num_pages: int, page_size: int):
-    """Int8 KV page pool (QuantKVCache: data int8 + f32 absmax scales)."""
-    from distributed_llms_tpu.runtime import batcher as batcher_lib
+def abstract_pool(cfg, num_pages: int, page_size: int, kv_bits: int = 16):
+    """KV page pool; ``kv_bits=8`` the int8 one (QuantKVCache: data int8
+    + f32 absmax scales)."""
+    from distributed_llms_tpu.models import kv_cache
 
     return jax.eval_shape(
-        lambda: batcher_lib._paged_pool(cfg, num_pages, page_size, kv_bits=8)
+        lambda: kv_cache.make_pool(cfg, num_pages, page_size, kv_bits)
     )
 
 
@@ -511,14 +505,13 @@ def _forward_cases() -> list[OpCase]:
     # Int8 paged decode (--kv-bits 8): the pool round-trips at int8 with
     # f32 scales — logits stay f32, nothing silently re-widens.
     for b, nb, blk, p in [(2, 8, 8, 4), (1, 16, 8, 8)]:
-        qpool = abstract_quant_pool(cfg, nb, blk)
+        qpool = abstract_pool(cfg, nb, blk, kv_bits=8)
         l, kvh, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim_
         cases.append(OpCase(
             label=f"llama-tiny int8-pageddecode b{b} nb{nb} blk{blk}",
             fn=functools.partial(
                 lambda cfg, prm, tok, pos, c, ci, tb: (
-                    lambda out: (out[0], out[1].k, out[1].v,
-                                 out[1].k_scale, out[1].v_scale)
+                    lambda out: (out[0], *jax.tree.leaves(out[1]))
                 )(model_lib.forward(
                     prm, cfg, tok, positions=pos, cache=c, cache_index=ci,
                     kv_tables=tb)), cfg),
@@ -541,7 +534,7 @@ def _kv_transfer_cases() -> list[OpCase]:
     silently-promoted dtype would corrupt every later admission."""
     import jax.numpy as jnp
 
-    from distributed_llms_tpu.runtime import batcher as batcher_lib
+    from distributed_llms_tpu.models import kv_cache
 
     cfg = preset("llama-tiny", dtype="bfloat16")
     l, kvh, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim_
@@ -552,7 +545,7 @@ def _kv_transfer_cases() -> list[OpCase]:
         pool = abstract_pool(cfg, nb, blk)
         cases.append(OpCase(
             label=f"export gather nb{nb} blk{blk} p{p}",
-            fn=batcher_lib._gather_row_pages,
+            fn=kv_cache.gather_row,
             args=(pool, sds((p,), jnp.int32)),
             want=(((l, 1, p * blk, kvh, hd), "bfloat16"),
                   ((l, 1, p * blk, kvh, hd), "bfloat16")),
@@ -561,7 +554,7 @@ def _kv_transfer_cases() -> list[OpCase]:
             label=f"import scatter nb{nb} blk{blk} p{p}",
             fn=lambda c, pl, k, v: (
                 lambda out: (out.k, out.v)
-            )(batcher_lib._import_pages(c, pl, k, v)),
+            )(kv_cache.import_full(c, pl, k, v)),
             args=(pool, sds((p,), jnp.int32),
                   sds((l, p, blk, kvh, hd), jnp.float32),  # host payload
                   sds((l, p, blk, kvh, hd), jnp.float32)),
@@ -666,7 +659,7 @@ def _spec_chunk_paged_cases() -> list[OpCase]:
         draft_want = (((l, b, s, kvh, hd), "bfloat16"),) * 2
         for kv_bits in (16, 8):
             if kv_bits == 8:
-                pool = abstract_quant_pool(cfg, nb, blk)
+                pool = abstract_pool(cfg, nb, blk, kv_bits=8)
                 pool_want = (
                     ((l, nb, blk, kvh, hd), "int8"),
                     ((l, nb, blk, kvh, hd), "int8"),
@@ -782,7 +775,7 @@ def op_contracts() -> list[OpContract]:
                    "mask gather [B,V] f32 + DFA advance [B] i32 over a "
                    "batch/state/vocab sweep",
                    _constrain_cases),
-        OpContract("batcher.kv_page_transfer", P_BATCHER,
+        OpContract("models.kv_cache.page_transfer", P_KV_CACHE,
                    "handoff export/import: pool shape+dtype round-trip, "
                    "payload cast to pool dtype",
                    _kv_transfer_cases),
@@ -875,8 +868,8 @@ _MESH_PAGED_LADDER: tuple[tuple[str, dict], ...] = (
 
 def _page_pool_audits() -> list[SpecAudit]:
     """Sharded page-pool layout (mesh-native paged serving): the pool
-    trees `_paged_pool` builds must structure-match
-    `parallel.specs.page_pool_specs` — KV heads over 'model', int8 absmax
+    trees `make_pool` builds must structure-match
+    `models.kv_cache.pool_specs` — KV heads over 'model', int8 absmax
     scales sharded with their pages — with axis names and divisibility
     checked over the tp ladder.  llama-tiny (2 KV heads) exercises the
     non-divisible degrade at tp4; gpt2-tiny (4 heads) shards at both."""
@@ -885,19 +878,16 @@ def _page_pool_audits() -> list[SpecAudit]:
         for mlabel, axes in _MESH_PAGED_LADDER:
             for bits in (16, 8):
                 def build(pname=pname, axes=axes, bits=bits):
-                    from distributed_llms_tpu.parallel import (
-                        specs as specs_lib,
-                    )
+                    from distributed_llms_tpu.models import kv_cache
 
                     cfg = preset(pname)
                     mesh = fake_mesh(**axes)
-                    pool = (abstract_quant_pool if bits == 8
-                            else abstract_pool)(cfg, 16, 16)
-                    return pool, specs_lib.page_pool_specs(
-                        cfg, mesh, kv_bits=bits), mesh
+                    pool = abstract_pool(cfg, 16, 16, kv_bits=bits)
+                    return pool, kv_cache.pool_specs(cfg, mesh, pool), mesh
 
                 out.append(SpecAudit(
-                    f"page-pool[kv{bits}|{pname}]@{mlabel}", P_SPECS, build
+                    f"page-pool[kv{bits}|{pname}]@{mlabel}", P_KV_CACHE,
+                    build,
                 ))
     return out
 
@@ -1141,7 +1131,7 @@ def recompile_scenarios() -> list[RecompileScenario]:
 
         b, nb, blk, p = 4, 16, 16, 8
         params = abstract_params(cfg)
-        pool = abstract_quant_pool(cfg, nb, blk)
+        pool = abstract_pool(cfg, nb, blk, kv_bits=8)
         return jaxpr_hash(
             lambda prm, c, lt, rl, va, ac, bu, rng, tb:
                 batcher_lib.decode_chunk(
@@ -1533,7 +1523,7 @@ def contracts_table() -> str:
     )
     paged_meshes = ", ".join(label for label, _ in _MESH_PAGED_LADDER)
     rows.append(
-        f"| GC2 | `parallel.specs.page_pool_specs` | sharded page-pool "
+        f"| GC2 | `models.kv_cache.pool_specs` | sharded page-pool "
         f"layout (KV heads over 'model'; int8 scales shard with their "
         f"pages) over {{kv16, kv8}} x ({paged_meshes}) |"
     )

@@ -61,9 +61,9 @@ def check(scenarios=None) -> list[Finding]:
 
 
 def measure_keys(scenario) -> dict[str, int]:
-    """Compile keys a scenario produces (bench.py compile-stability row):
-    key-hash -> width.  Raises on trace failure — the bench row should
-    error loudly, not stamp garbage."""
+    """Compile keys a scenario produces: key-hash -> width.  Raises on
+    trace failure (a caller that reports the count must not report
+    garbage)."""
     out: dict[str, int] = {}
     for n in scenario.ladder:
         w = scenario.width_of(n)

@@ -92,8 +92,8 @@ MODULE_ALIASES = ("protocol", "kv_transfer", "faults", "batcher", "fleet")
 # suffixes). Everything else is out of scope by design — the single-file
 # rules live in graftlint.
 SCOPE_SUFFIXES = (
-    "runtime/batcher.py", "runtime/server.py", "runtime/router.py",
-    "runtime/faults.py", "core/observability.py",
+    "runtime/batcher.py", "runtime/pages.py", "runtime/server.py",
+    "runtime/router.py", "runtime/faults.py", "core/observability.py",
     "cluster/fleet.py", "cluster/kv_transfer.py", "cluster/protocol.py",
     "cluster/coordinator.py", "cluster/worker.py", "cluster/client.py",
     "cluster/metrics_http.py", "cluster/distributed.py",

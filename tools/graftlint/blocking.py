@@ -130,10 +130,12 @@ def _blocking_calls(sf: SourceFile, fn: ast.AST) -> list[tuple[int, str]]:
 
 
 def check(project: Project) -> list[Finding]:
-    # The graph spans the batcher module and the fault plane it consults.
+    # The graph spans the batcher module, the page allocator it drives
+    # and the fault plane it consults.
     scope = [sf for sf in project.package_files()
-             if sf.rel.endswith(("runtime/batcher.py", "runtime/faults.py"))
-             or sf.rel in ("batcher.py", "faults.py")]
+             if sf.rel.endswith(("runtime/batcher.py", "runtime/pages.py",
+                                 "runtime/faults.py"))
+             or sf.rel in ("batcher.py", "pages.py", "faults.py")]
     defs = _collect_defs(scope)
     entry = next((k for k in defs
                   if k.cls == ENTRY_CLASS and k.name == ENTRY_METHOD), None)

@@ -52,6 +52,7 @@ EVENT_LOOP = "event-loop"
 # threaded serving core whose cross-thread contracts this rule exists for.
 REQUIRED_MODULES = (
     "distributed_llms_tpu/runtime/batcher.py",
+    "distributed_llms_tpu/runtime/pages.py",
     "distributed_llms_tpu/runtime/server.py",
     "distributed_llms_tpu/core/observability.py",
     "distributed_llms_tpu/cluster/coordinator.py",

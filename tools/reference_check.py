@@ -142,13 +142,13 @@ def reference_logits(params, cfg, tokens, int4=False, **changed):
 
 
 def served_programs(cfg, cfg_decode):
-    from distributed_llms_tpu.models import model as model_lib
+    from distributed_llms_tpu.models import kv_cache, model as model_lib
     from distributed_llms_tpu.runtime import batcher as B
 
     @partial(jax.jit, donate_argnums=(1,))
     def admit(params, cache, page_list, prompt, plen, slot):
         logits, row, _ = B._prefill_row(
-            model_lib.forward, params, cfg, B._row_dtype_of(cache),
+            model_lib.forward, params, cfg, kv_cache.row_dtype(cache),
             page_list.shape[0] * cache.k.shape[2], prompt, plen)
         cache, tok, lp = B._paged_splice(
             cache, page_list, row, logits, plen, jax.random.key(0), 0.0, 0,
@@ -179,7 +179,7 @@ def main() -> int:
                          "of them writes no golden worth keeping)")
     a = ap.parse_args()
 
-    from distributed_llms_tpu.models import model as model_lib
+    from distributed_llms_tpu.models import kv_cache, model as model_lib
     from distributed_llms_tpu.models.presets import get_preset
     from distributed_llms_tpu.ops import decode_attn
     from distributed_llms_tpu.runtime import batcher as B
@@ -228,7 +228,8 @@ def main() -> int:
         page_list[:n_pages] = 1 + np.arange(n_pages)
         prompt = np.zeros((bucket,), np.int32)
         prompt[:plen] = ids
-        cache = B._paged_pool(c, serve["paged_pages"], blk, slots=slots)
+        cache = kv_cache.make_pool(
+            c, serve["paged_pages"], blk, slots=slots)
         cache, first, tok0, _ = admit(
             params, cache, jnp.asarray(page_list), jnp.asarray(prompt),
             jnp.int32(plen), jnp.int32(a.slot))
