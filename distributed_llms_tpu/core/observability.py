@@ -527,6 +527,11 @@ METRIC_DOCS: dict[str, str] = {
                                          "model it equals them, and a value "
                                          "below says a call site slices a "
                                          "layer out (a copy a step)",
+    "ops.dispatch.paged_decode.run_pages": "pages of a row the paged decode "
+                                           "kernel walks at a time, as its "
+                                           "last trace worked it out from "
+                                           "the pool's shapes (a gauge; "
+                                           "ops/decode_attn._run_pages)",
     "ops.dispatch.*.shard_map": "of those, dispatches traced inside the "
                                 "per-shard shard_map body of a "
                                 "tensor-parallel mesh (the kernel then "

@@ -19,6 +19,21 @@ KVH == 1, which the first on-chip parity sweep caught (interpret mode
 cannot).  Whole-KVH blocks satisfy the rule for every head count at the
 same total HBM traffic per row.
 
+The PAGED kernel (:func:`paged_decode_attention`: every served decode
+step) reads the same depth out of a pool of pages, and walks a row's pages
+a RUN at a time: its grid is the rows, and inside a row it loops over runs
+of about a mebibyte of pages (:func:`_run_pages`: 8 pages of qwen2's, 1 of
+pythia's), fetching each page of a run with a DMA of its own out of the
+pool where it lies, all of a run's in flight together and the next run's
+started before this one is computed on.  A row takes ceil(pages held /
+run) turns and reads the pages it holds, no more; a page slot it cannot
+fill costs nothing.  One BlockSpec block a grid step, as the contiguous
+kernel has them, cost 2.2 us a 128-KB page and 0.14 us an empty slot, a
+tenth of HBM's rate (PERF.md, PR 31).  A run is computed on as it lies,
+rows (token, KV head): one score product for all heads
+(:func:`_softmax_all_heads`), because pulling one head out of interleaved
+rows costs more than fetching them.
+
 The contract matches the batcher's canonical mask exactly: row ``b``
 attends to cache slots ``[0, lengths[b])`` (its valid prefix INCLUDING the
 slot its own token was just written to — lengths = cache_index + 1).
@@ -43,6 +58,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from ..core.observability import METRICS
 from . import dispatch
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
@@ -55,9 +71,9 @@ def _round_up(x: int, m: int) -> int:
 def _kernel(
     lengths_ref,  # scalar-prefetch [B] int32
     q_ref,  # [1, KVH*Gp, D] — per-kv-head query groups, sublane-padded
-    k_ref,  # [1, bk, KVH, D] — a block of the cache in its NATIVE layout
-    v_ref,  # [1, bk, KVH, D]
-    *rest,  # int8 leg: [ks_ref [1, bk, KVH] f32, vs_ref], then o_ref and
+    k_ref,  # [bk, KVH, D] — a block of the cache in its NATIVE layout
+    v_ref,  # [bk, KVH, D]
+    *rest,  # int8 leg: [ks_ref [bk, KVH] f32, vs_ref], then o_ref and
     #   the three VMEM scratch refs (acc [KVH*Gp, D], m/l [KVH*Gp, 128])
     scale: float,
     block_k: int,
@@ -74,8 +90,9 @@ def _kernel(
 ):
     if quant:
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        scales = (ks_ref, vs_ref)
     else:
-        ks_ref = vs_ref = None
+        scales = None
         o_ref, acc_ref, m_ref, l_ref = rest
     bi, ji = pl.program_id(0), pl.program_id(1)
     length = lengths_ref[bi]
@@ -89,84 +106,255 @@ def _kernel(
 
     @pl.when(ji == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _softmax_init(acc_ref, m_ref, l_ref)
 
     @pl.when(jnp.logical_and(ji <= last_needed, ji >= first_needed))
     def _block():
-        key_pos = ji * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (gp, block_k), dimension=1
+        _softmax_block(
+            q_ref, k_ref, v_ref, scales, acc_ref, m_ref, l_ref,
+            first_key=ji * block_k, length=length, scale=scale, kvh=kvh,
+            gp=gp, window=window,
         )
-        # Static unrolled loop over kv heads: each iteration slices one
-        # head out of the whole-KVH block already resident in VMEM and
-        # updates its own Gp-row slice of the online-softmax state.
-        for hh in range(kvh):
-            r0, r1 = hh * gp, (hh + 1) * gp
-            # Per-head cast to the compute dtype: the cache may live at a
-            # different dtype (kv_dtype knob) and casting here keeps the
-            # HBM read at the cache's width — never a full-cache copy.
-            # Int8 leg: the cast is the only widening (one block in VMEM);
-            # the absmax scales fold into the contraction below instead of
-            # dequantizing the block.
-            kb = _head(k_ref, hh).astype(q_ref.dtype)
-            vb = _head(v_ref, hh).astype(q_ref.dtype)
-            s = (
-                jax.lax.dot_general(
-                    q_ref[0, r0:r1, :], kb, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                * scale
-            )  # [Gp, bk] f32
-            if quant:
-                # score = (q . k_i8) * k_scale — per-(slot, head) scales
-                # sit outside the head-dim dot product by construction
-                # (checkpoint.quantize.kv_quantize blocks on HD).
-                s = s * ks_ref[0, :, hh][None, :]
-            keep = key_pos < length
-            if window is not None:
-                # layers.and_window in slot space: keys in
-                # [length - window, length) == positions (p - window, p].
-                keep = jnp.logical_and(keep, key_pos >= length - window)
-            s = jnp.where(keep, s, _NEG_INF)
-            m_prev = m_ref[r0:r1, 0]
-            l_prev = l_ref[r0:r1, 0]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-            safe = jnp.where(m_new <= _NEG_INF * 0.5, 0.0, m_new)
-            p = jnp.exp(s - safe[:, None])
-            alpha = jnp.exp(m_prev - safe)
-            l_ref[r0:r1, 0] = l_prev * alpha + jnp.sum(p, axis=-1)
-            if quant:
-                # out = sum_i p_i * (v_scale_i * v_i8_i): fold the scale
-                # into the softmax weights (f32) before the value matmul.
-                p = p * vs_ref[0, :, hh][None, :]
-            acc_ref[r0:r1, :] = acc_ref[r0:r1, :] * alpha[
-                :, None
-            ] + jax.lax.dot_general(
-                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_ref[r0:r1, 0] = m_new
 
     @pl.when(ji == num_k_blocks - 1)
     def _done():
-        l = jnp.maximum(l_ref[:, 0], 1e-37)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        _softmax_done(o_ref, acc_ref, l_ref)
+
+
+def _softmax_init(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _softmax_done(o_ref, acc_ref, l_ref):
+    l = jnp.maximum(l_ref[:, 0], 1e-37)
+    o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+
+
+def _softmax_block(
+    q_ref,  # [1, KVH*Gp, D]
+    k_ref,  # [bk, KVH, D] keys in the cache's layout ([bk, D]: one head)
+    v_ref,
+    scales,  # int8 leg: (ks_ref, vs_ref), each [bk, KVH] f32; else None
+    acc_ref, m_ref, l_ref,
+    *, first_key, length, scale: float, kvh: int, gp: int,
+    window: int | None = None,
+):
+    """One online-softmax update of every head's state with the ``bk``
+    keys at positions ``first_key ...``: the body the contiguous kernel
+    runs a K block at a time and the paged kernel a run of pages at a
+    time."""
+    bk = k_ref.shape[0]
+    key_pos = first_key + jax.lax.broadcasted_iota(
+        jnp.int32, (gp, bk), dimension=1
+    )
+    keep = key_pos < length
+    if window is not None:
+        # layers.and_window in slot space: keys in
+        # [length - window, length) == positions (p - window, p].
+        keep = jnp.logical_and(keep, key_pos >= length - window)
+    # Static unrolled loop over kv heads: each iteration slices one head
+    # out of the whole-KVH block already resident in VMEM and updates its
+    # own Gp-row slice of the online-softmax state.
+    for hh in range(kvh):
+        # Per-head cast to the compute dtype: the cache may live at a
+        # different dtype (kv_dtype knob) and casting here keeps the
+        # HBM read at the cache's width — never a full-cache copy.
+        # Int8 leg: the cast is the only widening (one block in VMEM);
+        # the absmax scales fold into the contraction below instead of
+        # dequantizing the block.
+        kb = _head(k_ref, hh).astype(q_ref.dtype)
+        vb = _head(v_ref, hh).astype(q_ref.dtype)
+        s = (
+            jax.lax.dot_general(
+                q_ref[0, hh * gp:(hh + 1) * gp, :], kb,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            * scale
+        )  # [Gp, bk] f32
+        if scales is not None:
+            # score = (q . k_i8) * k_scale — per-(slot, head) scales
+            # sit outside the head-dim dot product by construction
+            # (checkpoint.quantize.kv_quantize blocks on HD).
+            s = s * scales[0][:, hh][None, :]
+        # out = sum_i p_i * (v_scale_i * v_i8_i): the scale folds into
+        # the softmax weights (f32) before the value matmul.
+        _softmax_update(
+            jnp.where(keep, s, _NEG_INF), vb, acc_ref, m_ref, l_ref,
+            rows=slice(hh * gp, (hh + 1) * gp),
+            v_scale=None if scales is None else scales[1][:, hh][None, :],
+        )
+
+
+def _softmax_update(s, vb, acc_ref, m_ref, l_ref, rows=slice(None),
+                    v_scale=None):
+    """Fold masked scores ``s`` [R, bk] and their values ``vb`` [bk, D]
+    into rows ``rows`` of the running maximum, sum and accumulator."""
+    m_prev = m_ref[rows, 0]
+    l_prev = l_ref[rows, 0]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    safe = jnp.where(m_new <= _NEG_INF * 0.5, 0.0, m_new)
+    p = jnp.exp(s - safe[:, None])
+    alpha = jnp.exp(m_prev - safe)
+    l_ref[rows, 0] = l_prev * alpha + jnp.sum(p, axis=-1)
+    if v_scale is not None:
+        p = p * v_scale
+    acc_ref[rows, :] = acc_ref[rows, :] * alpha[:, None] + (
+        jax.lax.dot_general(
+            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    )
+    m_ref[rows, 0] = m_new
 
 
 def _head(ref, hh: int):
-    """Head ``hh``'s [bk, D] out of a K/V block: [1, bk, KVH, D], or the
-    [1, bk, D] block of a pool that holds a single head (see _paged_impl)."""
-    return ref[0, :, hh, :] if len(ref.shape) == 4 else ref[0]
+    """Head ``hh``'s [bk, D] out of K/V keys [bk, KVH, D], or the [bk, D]
+    keys of a pool that holds a single head (see _paged_impl)."""
+    return ref[:, hh, :] if len(ref.shape) == 3 else ref[...]
 
 
-def _kernel_paged(lengths_ref, tables_ref, layer_ref, *rest, **kw):
-    """Paged variant: the page table and the layer index are consumed ONLY
-    by the BlockSpec index maps (they redirect each K block's DMA to the
-    row's page in that layer of the pool); the compute body is identical
-    to the contiguous kernel."""
-    del tables_ref, layer_ref
-    return _kernel(lengths_ref, *rest, **kw)
+def _softmax_all_heads(
+    q_ref,  # [1, Hp, D]: every head's query, head h = row // g
+    k_ref,  # [n, D]: keys as rows (token, KV head), the order a page
+    v_ref,  #   [BLK, KVH, D] lies in memory
+    acc_ref, m_ref, l_ref,
+    *, first_key, length, scale: float, kvh: int, g: int,
+):
+    """:func:`_softmax_block` without its loop over heads: ONE score
+    product of all the queries with all the rows, each query keeping the
+    columns of its own KV head (``col % kvh``), and one value product in
+    which the others' weights are zeros.  Pulling a head's [bk, D] out of
+    rows that interleave the heads costs the vector unit a cycle a row,
+    more than the DMA that brought them (PERF.md, PR 31); the matrix unit
+    takes the rows as they lie, and its time goes by the rows of keys,
+    which are the same.  The arithmetic a (query, key) pair sees is
+    _softmax_block's."""
+    n = k_ref.shape[0]
+    qa = q_ref[0]
+    kb = k_ref[...].astype(qa.dtype)
+    vb = v_ref[...].astype(qa.dtype)
+    s = (
+        jax.lax.dot_general(
+            qa, kb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        * scale
+    )  # [Hp, n] f32
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (qa.shape[0], 1), 0)
+    keep = jnp.logical_and(
+        col % kvh == row // g,  # (a padded query row is no head's)
+        first_key + col // kvh < length,
+    )
+    _softmax_update(jnp.where(keep, s, _NEG_INF), vb, acc_ref, m_ref, l_ref)
+
+
+def _kernel_paged(
+    lengths_ref,  # scalar-prefetch [B] int32
+    tables_ref,  # scalar-prefetch [B, P] int32: each row's page ids
+    layer_ref,  # scalar-prefetch [1] int32: the layer of the stack to read
+    q_ref,  # [1, Hp, D] (int8 leg: [1, KVH*Gp, D], as _kernel's)
+    k_hbm,  # the pool where it lies, never blocked or copied:
+    v_hbm,  #   [L, NB, BLK*KVH, D] (int8 leg: [L, NB, BLK, KVH, D])
+    *rest,  # int8 leg: [ks_hbm [NB, BLK, 128] f32 (this layer's), vs_hbm],
+    #   then o_ref and the scratch: k_buf / v_buf [2, run pages' rows, D]
+    #   ([ks_buf / vs_buf [2, run*BLK, 128]]), sem DMA[2], slot SMEM[1],
+    #   acc [Hp, D], m / l [Hp, 128]
+    scale: float,
+    blk: int,
+    run: int,  # pages a run: fetched together, one softmax update for all
+    kvh: int,
+    g: int,  # queries a KV head (int8 leg: padded to eight, _kernel's gp)
+    quant: bool = False,
+):
+    """Paged variant: grid ``(B,)``, and inside a row a loop over its RUNS
+    of ``run`` pages.  The kernel fetches a run itself — a DMA a page (and
+    its scales'), straight from the page table's entry in this layer of
+    the pool, all of a run's in flight together — into one of two buffers,
+    and starts the next run's (the row's, or the next row's first) before
+    it computes on this one's.  A row takes ceil(pages it holds / run)
+    turns of the loop and none for page slots it cannot fill; only pages
+    the row holds are read.  The compute is one online-softmax update a
+    run: :func:`_softmax_all_heads`, or for int8 pages, whose scales lie a
+    head to a lane, the contiguous kernel's :func:`_softmax_block`."""
+    if quant:
+        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, slot_ref,
+         acc_ref, m_ref, l_ref) = rest
+    else:
+        o_ref, k_buf, v_buf, sem, slot_ref, acc_ref, m_ref, l_ref = rest
+    bi, rows = pl.program_id(0), pl.num_programs(0)
+    layer = layer_ref[0]
+    page_rows = k_buf.shape[1] // run
+
+    def pages_of(b):
+        # At least one: a row of length 0 takes one (wholly masked) run.
+        return jnp.clip(
+            pl.cdiv(lengths_ref[b], blk), 1, tables_ref.shape[1])
+
+    def copies(b, j, slot, start: bool):
+        """Start, or wait for, the DMAs of row ``b``'s run ``j`` into
+        buffer ``slot``: one a page the row holds there (a wait needs the
+        copy's shape and semaphore, not its source)."""
+        first = j * run
+
+        def page(r, carry):
+            pg = tables_ref[b, first + r] if start else 0
+            into = pl.ds(r * page_rows, page_rows)
+            pairs = [(k_hbm.at[layer, pg], k_buf.at[slot, into]),
+                     (v_hbm.at[layer, pg], v_buf.at[slot, into])]
+            if quant:
+                into = pl.ds(r * blk, blk)
+                pairs += [(ks_hbm.at[pg], ks_buf.at[slot, into]),
+                          (vs_hbm.at[pg], vs_buf.at[slot, into])]
+            for src, dst in pairs:
+                dma = pltpu.make_async_copy(src, dst, sem.at[slot])
+                dma.start() if start else dma.wait()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(run, pages_of(b) - first), page, 0)
+
+    @pl.when(bi == 0)
+    def _first():
+        # A run's buffer past the row's last page keeps what an earlier
+        # run left there, masked out of the scores (``keep``) but still
+        # multiplied (by 0.0) in the value product: never-written VMEM
+        # could hold a NaN, so the value side starts as zeros.
+        v_buf[...] = jnp.zeros_like(v_buf)
+        if quant:
+            vs_buf[...] = jnp.zeros_like(vs_buf)
+        slot_ref[0] = 0
+        copies(0, 0, 0, start=True)
+
+    _softmax_init(acc_ref, m_ref, l_ref)
+    length = lengths_ref[bi]
+    runs = pl.cdiv(pages_of(bi), run)
+
+    def one_run(j, slot):
+        @pl.when(j + 1 < runs)
+        def _next_run():
+            copies(bi, j + 1, 1 - slot, start=True)
+
+        @pl.when(jnp.logical_and(j + 1 == runs, bi + 1 < rows))
+        def _next_row():
+            copies(bi + 1, 0, 1 - slot, start=True)
+
+        copies(bi, j, slot, start=False)
+        state = dict(first_key=j * (run * blk), length=length, scale=scale,
+                     kvh=kvh)
+        if quant:
+            _softmax_block(q_ref, k_buf.at[slot], v_buf.at[slot],
+                           (ks_buf.at[slot], vs_buf.at[slot]),
+                           acc_ref, m_ref, l_ref, gp=g, **state)
+        else:
+            _softmax_all_heads(q_ref, k_buf.at[slot], v_buf.at[slot],
+                               acc_ref, m_ref, l_ref, g=g, **state)
+        return 1 - slot
+
+    slot_ref[0] = jax.lax.fori_loop(0, runs, one_run, slot_ref[0])
+    _softmax_done(o_ref, acc_ref, l_ref)
 
 
 def _dequant(k, v, k_scale, v_scale, dtype):
@@ -316,14 +504,14 @@ def _ragged_impl(
 
     in_specs = [
         pl.BlockSpec((1, kvh * gp, d), lambda bi, ji, L: (bi, 0, 0)),
-        pl.BlockSpec((1, bk, kvh, d), kv_index),
-        pl.BlockSpec((1, bk, kvh, d), kv_index),
+        pl.BlockSpec((None, bk, kvh, d), kv_index),
+        pl.BlockSpec((None, bk, kvh, d), kv_index),
     ]
     operands = [lengths.astype(jnp.int32), qt.reshape(b, kvh * gp, d), k, v]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, bk, kvh), scale_index),
-            pl.BlockSpec((1, bk, kvh), scale_index),
+            pl.BlockSpec((None, bk, kvh), scale_index),
+            pl.BlockSpec((None, bk, kvh), scale_index),
         ]
         operands += [k_scale.astype(jnp.float32),
                      v_scale.astype(jnp.float32)]
@@ -359,6 +547,17 @@ def _kv_vmem_ok(bk: int, kvh: int, d: int, dtype) -> bool:
     return 4 * bk * kvh * d * jnp.dtype(dtype).itemsize <= 8 * 1024 * 1024
 
 
+def _run_pages(blk: int, kvh: int, d: int, dtype, p: int) -> int:
+    """Pages the paged kernel walks at a time: a mebibyte of keys and
+    values, at most the ``p`` a row can hold.  A run's copies then take
+    HBM 1.3 us and a turn of the kernel's loop costs 0.15 us beside them;
+    a run of one 128-KB page takes 0.5 us for 0.16 us of copies (PERF.md,
+    PR 31).  Two such buffers each for keys and values are a quarter of
+    what :func:`_kv_vmem_ok` allows."""
+    page = 2 * blk * kvh * d * jnp.dtype(dtype).itemsize
+    return max(1, min((1 << 20) // page, p))
+
+
 def paged_decode_attention(
     q: jax.Array,  # [B, 1, H, D]
     k_pages: jax.Array,  # [L, NB, BLK, KVH, D] — the shared page pool, every
@@ -368,8 +567,8 @@ def paged_decode_attention(
     lengths: jax.Array,  # [B] int32 — row b attends its first lengths[b] slots
     tables: jax.Array,  # [B, P] int32 — page ids; entries past the row's
     #                     depth may be arbitrary (never dereferenced by the
-    #                     kernel: the index map clamps to the last needed
-    #                     page; the fallback masks their scores)
+    #                     kernel: it fetches the pages a row holds; the
+    #                     fallback masks their scores)
     k_scale: jax.Array | None = None,  # [L, NB, BLK, KVH] f32 absmax scales
     #                     (one axis fewer for a rank-4 pool) — int8 leg:
     #                     pages are int8 (QuantKVCache pools) and
@@ -383,11 +582,13 @@ def paged_decode_attention(
     """Paged variant of :func:`ragged_decode_attention`: the KV cache lives
     as pool pages indexed per row through a block table (vLLM-style memory
     management, TPU-native static shapes).  The page table and the layer
-    index are scalar-prefetched and consumed by the K/V BlockSpec index
-    maps, so each row's DMA walks its own pages in that layer of the stack
-    and reads only its real depth: the caller never slices a layer out of
-    the pool (a Pallas call wants each operand as a buffer of its own, so
-    a slice handed in is a copy of that layer, every layer, every step).
+    index are scalar-prefetched; the pool stays in HBM whole, and the
+    kernel copies each row's pages out of that layer of the stack itself,
+    a run of pages at a time (:func:`_kernel_paged`), so it reads only the
+    row's real depth and takes no step for page slots the row cannot
+    fill: the caller never slices a layer out of the pool (a Pallas call
+    wants each operand as a buffer of its own, so a slice handed in is a
+    copy of that layer, every layer, every step).
     Returns [B, 1, H, D] in q.dtype.  Inference-only.
 
     Under a tensor-parallel mesh (:func:`dispatch.sharded`) the pool (and
@@ -420,11 +621,12 @@ def paged_decode_attention(
 
 def _paged_impl(
     q, k_pages, v_pages, lengths, tables, layer, k_scale=None, v_scale=None,
-    *, mode: str = "fallback",
+    *, mode: str = "fallback", run: int | None = None,
 ) -> jax.Array:
     """Single-shard body of the paged kernel (see _ragged_impl): the pool
     is the stack [L, NB, BLK, KVH, D] and ``layer`` [1] int32 names the
-    layer to read."""
+    layer to read.  ``run`` is :func:`_run_pages`' to work out; only
+    tools/paged_attn_bench.py, which times the others, names one."""
     b, t, h, d = q.shape
     assert t == 1, "paged decode attention is single-token by construction"
     quant = k_scale is not None
@@ -456,88 +658,90 @@ def _paged_impl(
             )
         return _dense_reference(q, k_rows, v_rows, lengths)
 
-    dispatch.record("paged_decode", mode, (b, blk, h, kvh, d))
+    run = run or _run_pages(blk, kvh, d, k_pages.dtype, p)
+    dispatch.record("paged_decode", mode, (b, blk, h, kvh, d, run))
+    METRICS.set_gauge("ops.dispatch.paged_decode.run_pages", run)
     scale = d**-0.5
     qt = q[:, 0].reshape(b, kvh, g, d)
     if fold > 1:
         qt = _fold_queries(qt, fold)
         _, kvh, g, d = qt.shape
-    gp = _round_up(g, 8)
-    if gp != g:
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
-
-    def page(bi, ji, lengths_ref, tables_ref):
-        last = jax.lax.div(jnp.maximum(lengths_ref[bi] - 1, 0), blk)
-        return tables_ref[bi, jnp.minimum(ji, last)]
-
-    # The layer axis is squeezed out of the block (None), so the kernel
-    # body sees today's (1, blk, kvh, d) page whatever the stack's depth.
-    kv_block = (None, 1, blk, kvh, d)
-    if kvh == 1:
-        # A pool of ONE KV head (qwen2's four over mesh.model=4): the
-        # device keeps [L, NB, BLK, 1, D] tiled over (BLK, D), the
-        # degenerate axis out of the way, where a Pallas operand of that
-        # rank must be tiled over (1, D) — the whole stack would be
-        # copied into that layout, in every layer.  Without the axis the
-        # reshape is free and the operand is the pool as it lies.
-        k_pages, v_pages = (x.reshape(*x.shape[:3], d)
-                            for x in (k_pages, v_pages))
-        kv_block = (None, 1, blk, d)
-    kv_spec = pl.BlockSpec(
-        kv_block,
-        lambda bi, ji, L, T, Y: (Y[0], page(bi, ji, L, T))
-        + (0,) * (len(kv_block) - 2),
-    )
-    in_specs = [
-        pl.BlockSpec(
-            (1, kvh * gp, d), lambda bi, ji, L, T, Y: (bi, 0, 0)
-        ),
-        kv_spec,
-        kv_spec,
-    ]
-    operands = [
-        lengths.astype(jnp.int32), tables.astype(jnp.int32), layer,
-        qt.reshape(b, kvh * gp, d), k_pages, v_pages,
-    ]
     if quant:
-        # The scales go in as THIS layer's [1, NB, BLK, KVH] slice, not
-        # as the stack: a Pallas operand is tiled over its last two axes,
-        # and (BLK, KVH) pads four heads to 128 lanes, 32-fold.  The
+        # The contiguous kernel's rows: a KV head's queries padded to
+        # eight, its keys pulled out of the page a head at a time.
+        gp = _round_up(g, 8)
+        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
+        # A pool of ONE KV head (qwen2's four over mesh.model=4) goes in
+        # without the axis: the device keeps [L, NB, BLK, 1, D] tiled over
+        # (BLK, D), where a Pallas operand of that rank must be tiled
+        # over (1, D) — the whole stack would be copied into that layout,
+        # in every layer.  Without the axis the reshape is free.
+        page = (blk, kvh, d) if kvh > 1 else (blk, d)
+    else:
+        # Every head's queries in one block, and a page as the rows
+        # (token, KV head) it is in memory: the device tiles
+        # [.., BLK, KVH, D] and [.., BLK * KVH, D] alike, so the reshape
+        # is free and the operand is the pool as it lies.
+        gp = g
+        page = (blk * kvh, d)
+    k_pages, v_pages = (x.reshape(*x.shape[:2], *page)
+                        for x in (k_pages, v_pages))
+    hp = _round_up(kvh * gp, 8)
+    qt = jnp.pad(qt.reshape(b, kvh * gp, d),
+                 ((0, 0), (0, hp - kvh * gp), (0, 0)))
+    # The pool stays in HBM whole (pl.ANY): the kernel copies the pages a
+    # row holds, a run at a time, into its own two buffers.
+    q_spec = pl.BlockSpec((1, hp, d), lambda bi, L, T, Y: (bi, 0, 0))
+    in_specs = [q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    operands = [
+        lengths.astype(jnp.int32), tables.astype(jnp.int32), layer, qt,
+        k_pages, v_pages,
+    ]
+    scratch = [
+        pltpu.VMEM((2, run * page[0], *page[1:]), k_pages.dtype)] * 2
+    if quant:
+        # The scales go in as THIS layer's slice, not as the stack, and
+        # a page's as [BLK, 128] float32 rows with the heads in the first
+        # KVH lanes: an operand left in HBM is tiled a 128-lane row at a
+        # time, so four heads pad to 128 lanes either way, 32-fold.  The
         # slice is padded as it is cut (17 MB for 512 pages); the stack
         # handed whole would be padded whole, in every layer.
-        in_specs += [
-            pl.BlockSpec(
-                (None, 1, blk, kvh),
-                lambda bi, ji, L, T, Y: (0, page(bi, ji, L, T), 0, 0),
-            )
-        ] * 2
+        lanes = _round_up(kvh, 128)
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
         operands += [
-            jax.lax.dynamic_index_in_dim(x.astype(jnp.float32), layer[0])
+            jnp.pad(
+                jax.lax.dynamic_index_in_dim(
+                    x.astype(jnp.float32), layer[0], keepdims=False),
+                ((0, 0), (0, 0), (0, lanes - kvh)))
             for x in (k_scale, v_scale)
         ]
+        scratch += [pltpu.VMEM((2, run * blk, lanes), jnp.float32)] * 2
     out = pl.pallas_call(
         functools.partial(
-            _kernel_paged, scale=scale, block_k=blk, num_k_blocks=p,
-            kvh=kvh, gp=gp, quant=quant,
+            _kernel_paged, scale=scale, blk=blk, run=run, kvh=kvh, g=gp,
+            quant=quant,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b, p),
+            grid=(b,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, kvh * gp, d), lambda bi, ji, L, T, Y: (bi, 0, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((kvh * gp, d), jnp.float32),
-                pltpu.VMEM((kvh * gp, 128), jnp.float32),
-                pltpu.VMEM((kvh * gp, 128), jnp.float32),
+            out_specs=q_spec,
+            scratch_shapes=scratch + [
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((hp, d), jnp.float32),
+                pltpu.VMEM((hp, 128), jnp.float32),
+                pltpu.VMEM((hp, 128), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, kvh * gp, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hp, d), q.dtype),
+        # Rows run in order: each starts the next one's first fetch.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=mode == "interpret",
         name="paged_decode_attn",  # the operation's name in a trace
     )(*operands)
-    out = out.reshape(b, kvh, gp, d)[:, :, :g]
+    out = out[:, : kvh * gp].reshape(b, kvh, gp, d)[:, :, :g]
     if fold > 1:
         # Query group i of a folded head reads its answer in lane group i.
         out = jnp.einsum(

@@ -117,41 +117,98 @@ def test_block_stepping_keeps_kernel_at_384(monkeypatch):
     )
 
 
+# What walking a row's pages a run at a time makes new.  A run is a
+# mebibyte of pages: 4 of these float32 pages of 64 (256 keys), 16 of the
+# int8 ones (1,024 keys), and 19 page slots a row are a whole number of
+# neither.  Rows of length 1, ending inside a run (300), on a page boundary
+# inside one (64), on a run's last slot (512; int8: 1024), on the first
+# key of the next run (513; 1025), and filling every slot (1216), all in
+# one call.
+RUN_WALK = (8, 160, 64, 19, 8, 4, 128,
+            [1, 300, 512, 513, 1024, 1216, 1025, 64])
+# The same walk over a pool of ONE KV head (runs of 16 pages), and over
+# pages as large as a run.
+RUN_WALK_1 = (4, 80, 64, 19, 7, 1, 128, [1025, 1216, 1, 1024])
+RUN_WALK_512 = (3, 16, 512, 3, 4, 2, 128, [1, 513, 1536])
+
+
 @pytest.mark.parametrize("stack", STACKS)
 @pytest.mark.parametrize(
-    "b,pool,blk,pages,h,kvh,d,lengths",
+    "b,pool,blk,pages,h,kvh,d,lengths,tables_are,quant",
     [
-        (3, 32, 64, 4, 8, 4, 128, [1, 130, 256]),   # GQA, scattered pages
-        (2, 16, 128, 2, 4, 4, 128, [255, 7]),       # page == K block
-        (4, 64, 8, 8, 8, 8, 128, [64, 1, 33, 17]),  # tiny 8-slot pages
-        (2, 16, 64, 2, 7, 1, 128, [100, 128]),      # ONE KV head (qwen2's
-        #   shard under mesh.model=4): the kernel takes the pool without
-        #   its head axis
+        (3, 32, 64, 4, 8, 4, 128, [1, 130, 256], "scattered", False),
+        #   GQA, scattered pages
+        (2, 16, 128, 2, 4, 4, 128, [255, 7], "scattered", False),
+        #   page == K block
+        (4, 64, 8, 8, 8, 8, 128, [64, 1, 33, 17], "scattered", False),
+        #   tiny 8-slot pages
+        (2, 16, 64, 2, 7, 1, 128, [100, 128], "scattered", False),
+        #   ONE KV head (qwen2's shard under mesh.model=4): the kernel
+        #   takes the pool without its head axis
+        (*RUN_WALK, "scattered", False),
+        (*RUN_WALK, "scattered", True),  # the int8 leg
+        # Page ids repeated between rows: a shared prefix of ten pages.
+        (*RUN_WALK, "shared", False),
+        (*RUN_WALK, "shared", True),
+        # Junk ids past a row's depth name a page of NaNs (int8: of NaN
+        # scales): one fetched would show in the answer.
+        (*RUN_WALK, "junk", False),
+        (*RUN_WALK, "junk", True),
+        (*RUN_WALK_1, "scattered", False),
+        (*RUN_WALK_1, "junk", True),
+        (*RUN_WALK_512, "junk", False),
     ],
 )
 def test_paged_matches_contiguous(monkeypatch, dispatched, b, pool, blk,
-                                  pages, h, kvh, d, lengths, stack):
+                                  pages, h, kvh, d, lengths, tables_are,
+                                  quant, stack):
     """Rows' KV scattered over a shuffled page pool must attend exactly like
     the same data laid out contiguously — read as one layer's pages or out
-    of a stack of layers."""
+    of a stack of layers, float pages or int8 pages with their scales."""
+    from distributed_llms_tpu.checkpoint.quantize import (
+        kv_dequantize, kv_quantize)
+
     monkeypatch.setenv("DLT_RAGGED_DECODE", "interpret")
     rng = np.random.RandomState(0)
-    # Distinct physical pages per (row, logical page).
-    perm = rng.permutation(pool)[: b * pages]
-    tables = jnp.asarray(perm.reshape(b, pages), jnp.int32)
+    # Distinct physical pages per (row, logical page); the last page of
+    # the pool is no row's.
+    perm = rng.permutation(pool - 1)[: b * pages]
+    tables = perm.reshape(b, pages)
     q = _rand(0, (b, 1, h, d))
     k_rows = _rand(1, (b, pages * blk, kvh, d))
     v_rows = _rand(2, (b, pages * blk, kvh, d))
-    k_pool = jnp.zeros((pool, blk, kvh, d)).at[tables.reshape(-1)].set(
-        k_rows.reshape(b * pages, blk, kvh, d)
-    )
-    v_pool = jnp.zeros((pool, blk, kvh, d)).at[tables.reshape(-1)].set(
-        v_rows.reshape(b * pages, blk, kvh, d)
-    )
+    if tables_are == "shared":  # every later row starts as row 0 does
+        share = min(10, pages)
+        tables[1:, :share] = tables[0, :share]
+        k_rows = k_rows.at[1:, : share * blk].set(k_rows[0, : share * blk])
+        v_rows = v_rows.at[1:, : share * blk].set(v_rows[0, : share * blk])
     ln = jnp.asarray(lengths, jnp.int32)
+
+    def to_pool(rows, fill, junk):
+        tail = rows.shape[2:]
+        pages_ = jnp.full((pool, blk, *tail), fill, rows.dtype).at[
+            tables.reshape(-1)
+        ].set(rows.reshape(b * pages, blk, *tail))
+        return _stacked(pages_.at[pool - 1].set(junk), stack, 7)
+
+    scales = {}
+    if quant:
+        (kq, ks), (vq, vs) = kv_quantize(k_rows), kv_quantize(v_rows)
+        k_rows, v_rows = (kv_dequantize(kq, ks, q.dtype),
+                          kv_dequantize(vq, vs, q.dtype))
+        k_pool, v_pool = to_pool(kq, 0, 99), to_pool(vq, 0, 99)
+        scales = dict(k_scale=to_pool(ks, 1.0, np.nan),
+                      v_scale=to_pool(vs, 1.0, np.nan))
+    else:
+        k_pool, v_pool = (to_pool(k_rows, 0.0, np.nan),
+                          to_pool(v_rows, 0.0, np.nan))
+    if tables_are == "junk":
+        held = -(-np.asarray(lengths) // blk)
+        tables = np.where(np.arange(pages)[None, :] < held[:, None], tables,
+                          pool - 1)
     got = decode_attn.paged_decode_attention(
-        q, _stacked(k_pool, stack, 7), _stacked(v_pool, stack, 8), ln,
-        tables, **_layer_kw(stack))
+        q, k_pool, v_pool, ln, jnp.asarray(tables, jnp.int32), **scales,
+        **_layer_kw(stack))
     assert dispatched() == {"paged_decode.interpret": 1}
     want = decode_attn._dense_reference(q, k_rows, v_rows, ln)
     np.testing.assert_allclose(
@@ -208,9 +265,10 @@ def test_sharded_kernels_match_single_shard(monkeypatch, devices8, dispatched,
                                             quant, stack):
     """Under a tensor-parallel mesh (dispatch.sharded) the ragged and paged
     kernels run per shard inside shard_map — each shard its local KV-head
-    slice, no collective — and equal the single-shard call bit for bit,
-    bf16 and int8 pages alike, the pool one layer's pages or a stack of
-    layers; the record counts the traces under ``shard_map``."""
+    slice, no collective — and equal the single-shard call (bit for bit,
+    but for float pages' last bit), bf16 and int8 pages alike, the pool
+    one layer's pages or a stack of layers; the record counts the traces
+    under ``shard_map``."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from distributed_llms_tpu.checkpoint.quantize import kv_quantize
@@ -258,7 +316,14 @@ def test_sharded_kernels_match_single_shard(monkeypatch, devices8, dispatched,
             put(q), put_pool(pool(k)), put_pool(pool(v)), ln, tables,
             **{n: put_pool(x) for n, x in pool_scales.items()}, **layer)
     np.testing.assert_array_equal(np.asarray(got_r), np.asarray(want_r))
-    np.testing.assert_array_equal(np.asarray(got_p), np.asarray(want_p))
+    if quant:
+        np.testing.assert_array_equal(np.asarray(got_p), np.asarray(want_p))
+    else:
+        # Float pages go through one product for all the heads a shard
+        # holds: its sums run over the other heads' rows too, as zeros,
+        # so two heads to a shard and one round the last bit apart.
+        np.testing.assert_allclose(
+            np.asarray(got_p), np.asarray(want_p), rtol=1e-6, atol=1e-6)
     new = {k_: v_ - base.get(k_, 0) for k_, v_ in dispatched().items()}
     assert new["ragged_decode.shard_map"] == new["paged_decode.shard_map"] == 1
 

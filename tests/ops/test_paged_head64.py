@@ -8,25 +8,31 @@ import pytest
 
 from distributed_llms_tpu.ops import decode_attn
 
-L, NB, BLK, KVH, D, H, B, P = 3, 12, 8, 4, 64, 16, 3, 4
+L, KVH, D, H, B = 3, 4, 64, 16, 3
 
 
-@pytest.fixture(scope="module")
-def case():
+# (pages in the pool, page, page slots a row, lengths): tiny pages walked in
+# one run, and pages of 64 walked 8 to a run over 11 slots — rows of length
+# 1, on the first key of the second run, and filling every slot.
+@pytest.fixture(scope="module", params=[(12, 8, 4, [5, 17, 32]),
+                                        (40, 64, 11, [1, 513, 704])],
+                ids=["one-run", "run-walk"])
+def case(request):
+    nb, blk, p, lengths = request.param
     rs = np.random.RandomState(0)
     kvh, d = decode_attn.pool_head_shape(KVH, D, fold_narrow=True)
     assert (kvh, d) == (2, 128)
-    k = jnp.asarray(rs.randn(L, NB, BLK, kvh, d), jnp.float32)
-    v = jnp.asarray(rs.randn(L, NB, BLK, kvh, d), jnp.float32)
+    k = jnp.asarray(rs.randn(L, nb, blk, kvh, d), jnp.float32)
+    v = jnp.asarray(rs.randn(L, nb, blk, kvh, d), jnp.float32)
     q = jnp.asarray(rs.randn(B, 1, H, D), jnp.float32)
-    tables = jnp.asarray(rs.randint(1, NB, (B, P)), jnp.int32)
-    return q, k, v, jnp.asarray([5, 17, 32], jnp.int32), tables
+    tables = jnp.asarray(rs.randint(1, nb, (B, p)), jnp.int32)
+    return q, k, v, jnp.asarray(lengths, jnp.int32), tables
 
 
 def dense(q, k, v, lengths, tables, layer):
     """Plain softmax attention over each row's gathered pages, the pool read
     as what it holds: [.., KVH, D] rows."""
-    k, v = (x[layer][tables].reshape(B, P * BLK, KVH, D) for x in (k, v))
+    k, v = (x[layer][tables].reshape(B, -1, KVH, D) for x in (k, v))
     out = np.zeros((B, 1, H, D), np.float32)
     for b in range(B):
         n = int(lengths[b])

@@ -156,32 +156,65 @@ def flash_parity() -> None:
     check(f"flash windowed T{t} win{win}", got, want, rtol=3e-2, atol=3e-2)
 
 
+# The paged legs' rows: 19 page slots each (no run length divides it), six
+# rows whose depths the walk a run at a time treats apart.
+PAGED_ROWS, PAGED_SLOTS = 6, 19
+
+
+def _paged_case(blk: int, kvh: int, d: int, dtype):
+    """(lengths, tables, pool size) of a paged leg.  Depths, with ``run``
+    the pages the kernel walks at a time: 1 token; inside a run; a run's
+    last slot; the next run's first key; every slot full; one page.  The
+    last row starts with the pages of the row before it (a shared prefix),
+    and every id past a row's depth names the pool's last page, which the
+    leg fills with NaNs (int8: NaN scales): never to be read."""
+    b, pages = PAGED_ROWS, PAGED_SLOTS
+    pool = b * pages + 1
+    run = decode_attn._run_pages(blk, kvh, d, dtype, pages)
+    lengths = [1, 2 * blk + 44, run * blk, run * blk + 1, pages * blk, blk]
+    rng = np.random.RandomState(0)
+    tables = rng.permutation(pool - 1).reshape(b, pages)
+    tables[5, :2] = tables[4, :2]
+    held = -(-np.asarray(lengths) // blk)
+    junk = np.where(np.arange(pages)[None, :] < held[:, None], tables,
+                    pool - 1)
+    return jnp.asarray(lengths, jnp.int32), tables, junk, pool
+
+
+def _to_pool(rows, tables, pool, fill, junk, layer, noise):
+    """[B, S, ...] rows as pages [pool, BLK, ...] at ``tables``, the last
+    page ``junk``, the whole as ``_stacked`` has it."""
+    b, pages = tables.shape
+    tail = rows.shape[2:]
+    blk = rows.shape[1] // pages
+    pages_ = jnp.full((pool, blk, *tail), fill, rows.dtype).at[
+        tables.reshape(-1)
+    ].set(rows.reshape(b * pages, blk, *tail)).at[pool - 1].set(junk)
+    return _stacked(pages_, layer, noise)
+
+
 def paged_parity(blk: int = 128, h: int = 8, kvh: int = 4,
                  layer: int | None = None, d: int = 128) -> None:
     key = jax.random.PRNGKey(3)
-    b, pool, pages = 4, 48, 8
-    rng = np.random.RandomState(0)
-    tables = jnp.asarray(
-        rng.permutation(pool)[: b * pages].reshape(b, pages), jnp.int32
-    )
+    # Heads narrower than a row lie folded in the pool, as the batcher of
+    # a hybrid model keeps them (decode_attn.pool_head_shape).
+    fold = decode_attn.pool_head_shape(kvh, d, fold_narrow=True)
+    ln, tables, junk, pool = _paged_case(blk, *fold, jnp.bfloat16)
+    b, pages = tables.shape
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (b, 1, h, d), jnp.bfloat16)
     k_rows = jax.random.normal(ks[1], (b, pages * blk, kvh, d), jnp.bfloat16)
     v_rows = jax.random.normal(ks[2], (b, pages * blk, kvh, d), jnp.bfloat16)
-    k_pool = jnp.zeros((pool, blk, kvh, d), jnp.bfloat16).at[
-        tables.reshape(-1)
-    ].set(k_rows.reshape(b * pages, blk, kvh, d))
-    v_pool = jnp.zeros((pool, blk, kvh, d), jnp.bfloat16).at[
-        tables.reshape(-1)
-    ].set(v_rows.reshape(b * pages, blk, kvh, d))
-    # Heads narrower than a row lie folded in the pool, as the batcher of
-    # a hybrid model keeps them (decode_attn.pool_head_shape).
-    fold = decode_attn.pool_head_shape(kvh, d, fold_narrow=True)
-    k_pool, v_pool = (x.reshape(pool, blk, *fold) for x in (k_pool, v_pool))
-    ln = jnp.asarray([1, 2 * blk + 44, pages * blk, blk + 1], jnp.int32)
+    shared = 2 * blk
+    k_rows = k_rows.at[5, :shared].set(k_rows[4, :shared])
+    v_rows = v_rows.at[5, :shared].set(v_rows[4, :shared])
+    k_pool, v_pool = (
+        _to_pool(x.reshape(b, pages * blk, *fold), tables, pool, 0, np.nan,
+                 layer, fill)
+        for x, fill in ((k_rows, 3.0), (v_rows, -3.0)))
     got = jax.jit(decode_attn.paged_decode_attention)(
-        q, _stacked(k_pool, layer, 3.0), _stacked(v_pool, layer, -3.0), ln,
-        tables, **_layer_kw(layer)
+        q, k_pool, v_pool, ln, jnp.asarray(junk, jnp.int32),
+        **_layer_kw(layer)
     )
     want = decode_attn._dense_reference(q, k_rows, v_rows, ln)
     form = "" if layer is None else f" L3[{layer}]"
@@ -301,25 +334,18 @@ def ragged_int8_parity() -> None:
 
 def paged_int8_parity(blk: int = 128, h: int = 8, kvh: int = 4,
                       layer: int | None = None) -> None:
-    b, pool, pages, d = 4, 48, 4, 128
+    ln, tables, junk, pool = _paged_case(blk, kvh, 128, jnp.int8)
+    b, pages = tables.shape
     q, kq, ksc, vq, vsc = _int8_inputs(b, pages * blk, h, kvh)
-    rng = np.random.RandomState(1)
-    tables = jnp.asarray(
-        rng.permutation(pool)[: b * pages].reshape(b, pages), jnp.int32
-    )
-
-    def to_pool(rows, fill, dtype, noise):
-        tail = rows.shape[2:]
-        pages_ = jnp.full((pool, blk, *tail), fill, dtype).at[
-            tables.reshape(-1)
-        ].set(rows.reshape(b * pages, blk, *tail))
-        return _stacked(pages_, layer, noise)
-
-    ln = jnp.asarray([1, 2 * blk + 44, pages * blk, blk + 1], jnp.int32)
+    shared = 2 * blk
+    kq, ksc, vq, vsc = (x.at[5, :shared].set(x[4, :shared])
+                        for x in (kq, ksc, vq, vsc))
     got = jax.jit(decode_attn.paged_decode_attention)(
-        q, to_pool(kq, 0, jnp.int8, 77), to_pool(vq, 0, jnp.int8, -77), ln,
-        tables, k_scale=to_pool(ksc, 1, jnp.float32, 9.0),
-        v_scale=to_pool(vsc, 1, jnp.float32, 9.0),
+        q, _to_pool(kq, tables, pool, 0, 99, layer, 77),
+        _to_pool(vq, tables, pool, 0, 99, layer, -77), ln,
+        jnp.asarray(junk, jnp.int32),
+        k_scale=_to_pool(ksc, tables, pool, 1, np.nan, layer, 9.0),
+        v_scale=_to_pool(vsc, tables, pool, 1, np.nan, layer, 9.0),
         **_layer_kw(layer),
     )
     form = "" if layer is None else f" L3[{layer}]"
@@ -370,8 +396,10 @@ def main() -> int:
     # layer of the stacked pool — 21 legs.  v6: flash and the paged kernel at
     # a head of 64, and the expert kernel at a decode step's and an
     # admission's pairs — 25 legs.  v7: quant_matmul's stacks read at an
-    # index, at qwen2-7b's shapes — 29 legs.
-    print(f"kernel_parity: ALL PASS v7 ({mode}, backend={backend})")
+    # index, at qwen2-7b's shapes — 29 legs.  v8: the 11 paged legs' rows
+    # have the depths a walk by runs treats apart, share pages and carry
+    # junk ids (_paged_case).
+    print(f"kernel_parity: ALL PASS v8 ({mode}, backend={backend})")
     return 0
 
 
