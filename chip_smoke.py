@@ -62,10 +62,21 @@ REHEARSAL = dict(
 # (the server refuses the pair) and has a third main-path kernel.
 DENSE_KERNELS = ("quant_matmul", "paged_decode")
 HYBRID = dict(prefix_cache=False, kernels=DENSE_KERNELS + ("moe_experts",))
+# ``--preset ax-k1-ep16``: one chip's share of A.X-K1 (latent attention on
+# latent pages, 12 of 192 int8 experts), as its benchmark cell serves it:
+# 64 slots, 2,176 pages, the prefix cache ON, and a fourth kernel.  It also
+# sends a long prompt again with a new suffix (``suffix_check``): the
+# suffix is admitted behind the cached run, which is expanded from latent
+# rows, and must give the first-token logprob a fresh admission gives.
+LATENT = dict(kernels=DENSE_KERNELS + ("moe_experts", "mla_paged_decode"),
+              suffix_check=True)
 SHAPES = {
     "qwen2-7b": (CHIP, REHEARSAL),
     "lfm2-8b-a1b": (dict(CHIP, preset="lfm2-8b-a1b", **HYBRID),
                     dict(REHEARSAL, preset="lfm2-tiny", **HYBRID)),
+    "ax-k1-ep16": (dict(CHIP, preset="ax-k1-ep16", slots=64, pages=2176,
+                        **LATENT),
+                   dict(REHEARSAL, preset="ax-k1-tiny", **LATENT)),
 }
 
 
@@ -328,6 +339,28 @@ def serve_requests(srv: Server, shape: dict) -> None:
         "uncached request again")
     check(a == b, f"identical text and logprobs both times ({a[0]!r}, "
                   f"{len(a[1])} logprobs)")
+
+    if shape.get("suffix_check"):
+        print("a long prompt again with a new suffix:", flush=True)
+        n_base = min(1500, cap - 100) if cap > 400 else cap - 20
+        n_suffix = 100 if cap > 400 else 10
+        base = prompt_of(n_base, "base")
+        check_answer(srv.complete(base + prompt_of(n_suffix, "s1"),
+                                  max_tokens=n_new), "base + first suffix")
+        again = base + prompt_of(n_suffix, "s2")
+        hit = srv.complete(again, max_tokens=n_new, logprobs=True)
+        cold = srv.complete(again, max_tokens=n_new, logprobs=True,
+                            prefix_cache=False)
+        (_, lh), (_, lc) = (check_answer(hit, "base + second suffix"),
+                            check_answer(cold, "the same, cache off"))
+        cached = hit["usage"].get("prompt_tokens_details", {}).get(
+            "cached_tokens", 0)
+        check(cached >= (n_base // page) * page,
+              f"the suffix was admitted behind {cached} cached tokens "
+              f"(>= {(n_base // page) * page})")
+        check(abs(lh[0] - lc[0]) <= 0.05,
+              f"first-token logprob behind the cached run {lh[0]:.6f}, "
+              f"fresh {lc[0]:.6f}: within 0.05")
 
 
 def check_dispatch(metrics: dict[str, float], chips: int,

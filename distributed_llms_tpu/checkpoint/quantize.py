@@ -215,8 +215,13 @@ _BIAS_NAMES = frozenset({"bq", "bk", "bv", "bo", "b_in", "b_out", "b_gate", "b_u
 # Hybrid-family leaves (blocks/conv/..., blocks/moe/...) that stay float
 # although they have two axes or more: the convolution's taps [L, D, K], and
 # the router [L, D, E] and the selection bias [L, E], which decide the
-# chosen set in float32.
-_HYBRID_FLOAT = frozenset({"taps", "router", "expert_bias"})
+# chosen set in float32; of a latent-attention layer (blocks/mla/...) the
+# down-projection W_kva [L, D, r + rope], whose 576 columns at A.X-K1's
+# widths are not whole 128-wide blocks, and the up-projection W_kvb
+# [L, r, H * (nope + v)], which an admission reads as it lies and a decode
+# step transposed (the absorbed form): both in the model's dtype, so the two
+# paths read the same stored values.
+_HYBRID_FLOAT = frozenset({"taps", "router", "expert_bias", "wkv_a", "wkv_b"})
 
 
 def block_axis_of(path: str) -> int:
@@ -230,7 +235,8 @@ def _should_quantize(path: str, x: Any) -> bool:
     if not hasattr(x, "ndim") or x.ndim < 2:
         return False
     leaf = path.split("/")[-1]
-    if path.startswith(("blocks/conv/", "blocks/moe/")) and leaf in _HYBRID_FLOAT:
+    if path.startswith(("blocks/conv/", "blocks/moe/", "blocks/mla/")) \
+            and leaf in _HYBRID_FLOAT:
         return False
     if "norm" in path or "ln" in path.split("/")[-2:][0]:
         return False

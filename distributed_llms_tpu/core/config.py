@@ -39,6 +39,9 @@ class ModelConfig:
     """
 
     family: str = "gpt2"  # "gpt2" | "opt" | "llama" | "neox" | "hybrid"
+    #   ("hybrid": layers stacked by KIND and walked in ``layer_types``'
+    #   order, models.model.run_layers: LFM2's convolutions beside GQA,
+    #   A.X-K1's latent attention)
     vocab_size: int = 50257
     hidden_size: int = 768
     intermediate_size: int = 3072
@@ -52,8 +55,11 @@ class ModelConfig:
     # frequency rescale that stretches low-frequency (long-wavelength)
     # components by `factor` while keeping high-frequency ones, with a
     # smooth ramp between — how 3.1/3.2 extend 8k-trained RoPE to 128k.
-    # factor == 1.0 disables (plain RoPE).  Other HF rope_type values
-    # (linear, dynamic, yarn, longrope) are rejected at convert.
+    # factor == 1.0 disables (plain RoPE).  ``rope_scaling_type`` "yarn"
+    # reads the same factor and original length by YaRN's rule instead
+    # (layers.rope_frequencies, the fields further down); the converter
+    # still rejects every HF rope_type but llama3 (linear, dynamic, yarn,
+    # longrope): no checkpoint of a yarn model is converted yet.
     rope_scaling_factor: float = 1.0
     rope_low_freq_factor: float = 1.0
     rope_high_freq_factor: float = 4.0
@@ -135,12 +141,43 @@ class ModelConfig:
                 "(layers.moe_dropless): set moe_capacity=False"
             )
         if self.layer_types:
-            bad = set(self.layer_types) - {"conv", "attn"}
+            bad = set(self.layer_types) - {"conv", "attn", "mla"}
             if bad or len(self.layer_types) != self.num_layers:
                 raise ValueError(
                     f"layer_types must name {self.num_layers} layers as "
-                    f"'conv' or 'attn', got {self.layer_types!r}"
+                    f"'conv', 'attn' or 'mla', got {self.layer_types!r}"
                 )
+        if ("mla" in self.layer_types) != (self.kv_lora_rank > 0) or (
+                self.kv_lora_rank and set(self.layer_types) != {"mla"}):
+            raise ValueError(
+                "latent attention is every layer's or none's: layer_types "
+                "all 'mla' together with kv_lora_rank, q_lora_rank and the "
+                "three head sizes"
+            )
+        if self.rope_scaling_type not in ("llama3", "yarn"):
+            raise ValueError(
+                f"unknown rope_scaling_type {self.rope_scaling_type!r}; "
+                "choose llama3 or yarn"
+            )
+        if self.num_experts and (
+                self.num_experts % self.moe_n_group
+                or not 1 <= self.moe_topk_group <= self.moe_n_group
+                or self.num_experts_per_token > self.moe_topk_group
+                * (self.num_experts // self.moe_n_group)):
+            raise ValueError(
+                f"{self.num_experts} experts do not split into "
+                f"{self.moe_n_group} groups of which {self.moe_topk_group} "
+                f"hold {self.num_experts_per_token} choices"
+            )
+        if self.experts_held is not None and (
+                self.moe_capacity or self.experts_offset < 0
+                or not 0 < self.experts_held
+                <= self.num_experts - self.experts_offset):
+            raise ValueError(
+                f"experts_held {self.experts_held} at offset "
+                f"{self.experts_offset} is no run of the {self.num_experts} "
+                "experts of a model routed without a capacity rule"
+            )
         if self.gate_act != "silu" and self.num_experts > 0:
             # moe_swiglu hardcodes silu (Mixtral); accepting another
             # activation here would silently ignore it.
@@ -217,6 +254,49 @@ class ModelConfig:
     conv_kernel: int = 3
     # RMS-normalise q and k per head (learned [head_dim] scales) before RoPE.
     qk_norm: bool = False
+    # Multi-head latent attention (layer kind "mla"; the published keys of
+    # DeepSeek-V2's family, which A.X-K1 follows).  Queries go through a
+    # rank-``q_lora_rank`` bottleneck; keys and values are up-projections of
+    # ONE latent of ``kv_lora_rank`` a token, which is what is cached,
+    # beside one rotated key of ``qk_rope_head_dim`` that all heads share.
+    # A head's query and key are [nope | rope] wide, its value v_head_dim.
+    # The rope part rotates the pairs (2i, 2i + 1) together (DeepSeek's
+    # layout; the other families rotate (i, i + half)).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0  # > 0: the model's "mla" layers exist
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # "llama3" (the four rope_* fields above) or "yarn": frequencies whose
+    # wavelength the original context holds fewer than ``yarn_beta_slow``
+    # times are divided by the factor, those it holds more than
+    # ``yarn_beta_fast`` times are kept, a linear ramp between; the
+    # attention scale grows by (0.1 * yarn_mscale_all_dim * ln(factor) +
+    # 1)^2 (models.model.mla_scale) and cos/sin by mscale(yarn_mscale) /
+    # mscale(yarn_mscale_all_dim).
+    rope_scaling_type: str = "llama3"
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    # Experts every token goes through beside its routed ones (one SwiGLU
+    # of n_shared_experts x expert_size).
+    n_shared_experts: int = 0
+    # Selection by groups (layers.route_experts): the experts are
+    # ``moe_n_group`` consecutive runs, a group scores as its best expert,
+    # and the top-k is taken among the ``moe_topk_group`` best groups.
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    # Added to the chosen scores' sum before the division (moe_norm_topk).
+    moe_norm_eps: float = 1e-6
+    # A chip's share of the routed experts: the stacks hold experts
+    # [experts_offset, experts_offset + experts_held) of num_experts (None:
+    # all).  The router keeps num_experts outputs; pairs routed to an
+    # expert that is not held are left out of the layer's sum
+    # (layers.moe_dropless), as they are on a chip of an expert-parallel
+    # deployment before the combine.
+    experts_held: int | None = None
+    experts_offset: int = 0
 
     @property
     def head_dim_(self) -> int:
@@ -237,7 +317,22 @@ class ModelConfig:
         page pool's layer axis counts these)."""
         if not self.layer_types:
             return tuple(range(self.num_layers))
-        return tuple(i for i, t in enumerate(self.layer_types) if t == "attn")
+        return tuple(i for i, t in enumerate(self.layer_types)
+                     if t in ("attn", "mla"))
+
+    @property
+    def held_experts(self) -> int:
+        """Experts the stacks hold (all of them unless ``experts_held``)."""
+        return (self.num_experts if self.experts_held is None
+                else self.experts_held)
+
+    @property
+    def latent_width(self) -> int:
+        """Lanes of a token's row in a latent page: the latent and the
+        shared rotated key side by side, padded to whole 128-lane rows
+        (the device tiles a row by 128 lanes, so 576 values occupy 640
+        wherever they lie; the pad lanes hold zeros).  0 without MLA."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
     @property
     def conv_layers(self) -> tuple[int, ...]:

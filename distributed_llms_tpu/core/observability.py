@@ -504,6 +504,13 @@ METRIC_DOCS: dict[str, str] = {
                                 "batch slot (gauge)",
     # -- expert layers (models/layers.py moe_dropless; real tokens only,
     #    carried out of each admission and decode chunk, added at delivery) --
+    "batcher.latent_page_bytes": "bytes of one page of a latent (MLA) pool, "
+                                 "every layer's rows (kv_cache.page_bytes)",
+    "moe.held_pairs": "routed pairs that fell on an expert this chip holds "
+                      "(ModelConfig.experts_held), real tokens only",
+    "mla.decode.resident_tokens": "tokens the decoding rows held, summed "
+                                  "over decode steps: what the latent decode "
+                                  "kernel read a layer",
     "moe.routed_pairs": "(token, expert) pairs routed, summed over the "
                         "expert layers: tokens x experts a token x layers",
     "moe.layer_passes": "expert-layer passes that had a real token (22 a "

@@ -87,6 +87,23 @@ class HybridCache(KVCache):
     conv: Any
 
 
+@jax.tree_util.register_dataclass
+@dataclass
+class LatentCache:
+    """Cache of a model with multi-head latent attention: ONE leaf, and no
+    KV-head axis.  ``k`` [L, B, S, W] (contiguous) or [L, NB, BLK, W] (the
+    page pool) holds a token's row a layer, W = ``cfg.latent_width``
+    lanes: the normalised latent c_kv (``kv_lora_rank``), the rotated key
+    every head shares (``qk_rope_head_dim``), zeros up to whole 128-lane
+    rows.  All heads' keys AND values are functions of that row: the
+    decode step attends to it as it lies (ops.decode_attn, the absorbed
+    form), an admission expands it (models.model.mla_attention).  A row's
+    whole state is its pages, so the prefix cache works on them as on
+    key/value pages."""
+
+    k: Any
+
+
 def init_cache(
     cfg: ModelConfig, batch: int, max_len: int, dtype: Any = None,
     prompt_len: int | None = None,
@@ -95,10 +112,13 @@ def init_cache(
     seq-parallel cache splits regions there); the dense layout ignores it."""
     del prompt_len
     dtype = dtype or jnp.dtype(cfg.dtype)
+    if cfg.kv_lora_rank:
+        return LatentCache(k=jnp.zeros(
+            (len(cfg.attn_layers), batch, max_len, cfg.latent_width), dtype))
     shape = (len(cfg.attn_layers), batch, max_len, cfg.num_kv_heads,
              cfg.head_dim_)
     k, v = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
-    if cfg.family != "hybrid":
+    if not cfg.conv_layers:
         return KVCache(k=k, v=v)
     return HybridCache(k=k, v=v, conv=conv_state(cfg, batch))
 
@@ -129,15 +149,23 @@ def make_pool(cfg: ModelConfig, num_pages: int, page_size: int,
     ``row_dtype`` so gathers/transient rows restore to it.  A hybrid
     model's pool counts its attention layers only and comes with the
     state that is not paged: each convolution layer's, one entry a batch
-    slot (``slots``), in a :class:`HybridCache`."""
+    slot (``slots``), in a :class:`HybridCache`.  A model with latent
+    attention gets the one leaf of a :class:`LatentCache`,
+    [L, NB, BLK, latent_width]."""
     from ..ops.decode_attn import pool_head_shape
 
     l = len(cfg.attn_layers)
+    if cfg.kv_lora_rank:
+        if kv_bits != 16:
+            refuse_unpaged_state(cfg, kv_bits=True)
+        return LatentCache(k=jnp.zeros(
+            (l, num_pages, page_size, cfg.latent_width),
+            jnp.dtype(dtype) if dtype else jnp.dtype(cfg.dtype)))
     kvh, hd = pool_head_shape(cfg.num_kv_heads, cfg.head_dim_,
                               fold_narrow=pages_are_private(cfg))
     dt = jnp.dtype(dtype) if dtype else jnp.dtype(cfg.dtype)
     shape = (l, num_pages, page_size, kvh, hd)
-    if cfg.family == "hybrid":
+    if cfg.conv_layers:
         return HybridCache(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt),
                            conv=conv_state(cfg, slots))
     if kv_bits == 8:
@@ -161,6 +189,20 @@ def page_bytes(cfg: ModelConfig, page_size: int, kv_bits: int = 16,
                for x in (getattr(pool, f) for f in _paged_fields(pool)))
 
 
+def format_bytes(pool, cfg: ModelConfig) -> dict[str, float]:
+    """Sizes that only one format has, for its gauges: the bytes of the
+    state a :class:`HybridCache` keeps beside its pages (``conv_state``),
+    the bytes of one page of a :class:`LatentCache` (``latent_page``)."""
+    match pool:
+        case HybridCache():
+            return {"conv_state": float(pool.conv.nbytes)}
+        case LatentCache():
+            return {"latent_page": float(
+                page_bytes(cfg, pool.k.shape[2], dtype=pool.k.dtype))}
+        case _:
+            return {}
+
+
 def _paged_fields(pool) -> tuple[str, ...]:
     """The pool's leaves that have a page axis ([L, NB, BLK, ...]), in the
     tree's order.  What else a format holds (a hybrid model's state a
@@ -168,11 +210,27 @@ def _paged_fields(pool) -> tuple[str, ...]:
     match pool:
         case QuantKVCache():
             return ("k", "v", "k_scale", "v_scale")
+        case LatentCache():
+            return ("k",)
         case _:
             return ("k", "v")
 
 
-def _encode(pool, k: jax.Array, v: jax.Array) -> tuple:
+def _row_fields(pool) -> tuple[str, ...]:
+    """The leaves of a transient contiguous row cache of the pool's kind
+    (:func:`init_cache`): full-width keys and values, or latent rows."""
+    return ("k",) if isinstance(pool, LatentCache) else ("k", "v")
+
+
+def row_cache_of(pool, *rows: jax.Array):
+    """The contiguous row cache that :func:`gather_row` gathered out of
+    ``pool``, as the pytree models.model.forward takes."""
+    if isinstance(pool, LatentCache):
+        return LatentCache(*rows)
+    return KVCache(*rows)
+
+
+def _encode(pool, k: jax.Array, v: jax.Array | None = None) -> tuple:
     """Keys and values [..., KVH, HD] as the pool stores them, one array a
     paged field.  An int8 pool quantizes each head-dim vector once, here,
     at the write (checkpoint.quantize.kv_quantize: int8 data + one f32
@@ -185,6 +243,8 @@ def _encode(pool, k: jax.Array, v: jax.Array) -> tuple:
 
             (k, ks), (v, vs) = kv_quantize(k), kv_quantize(v)
             return (k, v, ks, vs)
+        case LatentCache():  # rows [..., W] as the model made them
+            return (k.astype(pool.k.dtype),)
         case _:
             return tuple(
                 x.astype(leaf.dtype).reshape(*x.shape[:-2], *leaf.shape[3:])
@@ -217,6 +277,10 @@ def pool_specs(cfg: ModelConfig, mesh: Mesh, pool) -> Any:
     tp = mesh.shape.get("model", 1)
     kv_ax = "model" if cfg.num_kv_heads % max(tp, 1) == 0 else None
     specs = jax.tree.map(lambda _: P(), pool)
+    if isinstance(pool, LatentCache):
+        # No head axis to split: replicated (and a mesh is refused for the
+        # format, refuse_unpaged_state).
+        return dataclasses.replace(specs, k=P(*(None,) * pool.k.ndim))
     return dataclasses.replace(specs, **{
         f: P(None, None, None, kv_ax, *(None,) * (getattr(pool, f).ndim - 4))
         for f in _paged_fields(pool)
@@ -244,8 +308,10 @@ def constrain(pm, pool):
 # The movements of pages
 # ---------------------------------------------------------------------------
 
-def write_tokens(pool, layer, page, off, k: jax.Array, v: jax.Array):
-    """Scatter the new tokens' keys and values ([B, T, KVH, HD]) into
+def write_tokens(pool, layer, page, off, k: jax.Array,
+                 v: jax.Array | None = None):
+    """Scatter the new tokens' keys and values ([B, T, KVH, HD]; a latent
+    pool's rows [B, T, W] as ``k`` alone) into
     layer ``layer`` of the pool at (``page``, ``off``) [B, T], where the
     stacks lie (they are the layer scan's carry).
 
@@ -308,13 +374,15 @@ def write_row(pool, page_list: jax.Array, row_cache, slot=None):
     blk = pool.k.shape[2]
 
     def as_pages(row):  # [L, 1, P*BLK, KVH, HD] -> [L, P, BLK, KVH, HD]
-        # (the pool's own last two axes: narrow heads may lie folded there)
+        # (the pool's own last two axes: narrow heads may lie folded there;
+        # a latent row's one)
         return row[:, 0].reshape(row.shape[0], p, blk, *pool.k.shape[3:])
 
     fields = _paged_fields(pool)
     leaves = _write_pages(
         tuple(getattr(pool, f) for f in fields), page_list,
-        _encode(pool, as_pages(row_cache.k), as_pages(row_cache.v)),
+        _encode(pool, *(as_pages(getattr(row_cache, f))
+                        for f in _row_fields(pool))),
     )
     new = dict(zip(fields, leaves))
     match pool:
@@ -327,9 +395,10 @@ def write_row(pool, page_list: jax.Array, row_cache, slot=None):
 
 
 @jax.jit
-def gather_row(pool, read_list: jax.Array) -> tuple[jax.Array, jax.Array]:
+def gather_row(pool, read_list: jax.Array) -> tuple[jax.Array, ...]:
     """Gather a row's pages out of the pool into a transient contiguous
-    row cache ([L, 1, P*BLK, KVH, HD] k/v pair) — the chunked-prefill
+    row cache ([L, 1, P*BLK, KVH, HD] k/v pair, or a latent pool's one
+    leaf; :func:`row_cache_of` makes the pytree) — the chunked-prefill
     analogue of admit_row_auto_paged's in-program gather.  A cache-hit
     chunked admission seeds its transient row from the shared pages ONCE
     (the "prefix" is then already resident, exactly as if those chunks had
@@ -338,8 +407,11 @@ def gather_row(pool, read_list: jax.Array) -> tuple[jax.Array, jax.Array]:
     donate them.  An int8 pool dequantizes the gathered pages to its
     ``row_dtype``: transient rows always run full-width; only POOL storage
     is quantized."""
-    l, _, blk, kvh, hd = pool.k.shape
     p = read_list.shape[0]
+    if isinstance(pool, LatentCache):  # [L, 1, P*BLK, W], one leaf
+        l, _, blk, w = pool.k.shape
+        return (pool.k[:, read_list].reshape(l, 1, p * blk, w),)
+    l, _, blk, kvh, hd = pool.k.shape
     match pool:
         case QuantKVCache():
             from ..checkpoint.quantize import kv_dequantize
@@ -413,7 +485,32 @@ def pages_are_private(cfg: ModelConfig) -> bool:
     convolution state beside its pages.  Only then may heads narrower than
     a 128-lane row lie folded in the pool
     (ops.decode_attn.pool_head_shape; heads of 128 never fold)."""
-    return cfg.family == "hybrid"
+    return bool(cfg.conv_layers)
+
+
+_LATENT_REFUSALS = {
+    "kv_bits": "the int8 pool quantizes a head's vector, and a latent row "
+               "is no head's (ask for kv_bits 16)",
+    "host_pages": "the host tier's parcels and its swap-in are written "
+                  "for key/value page pairs",
+    "speculative": "the verify pass reads several query tokens a row, "
+                   "and no draft model of the family exists",
+    "prefill_chunk": "a chunked prefill hands key/value rows from bite to "
+                     "bite, not latent rows",
+    "token_budget": "it chunks prefills, which hand key/value rows from "
+                    "bite to bite, not latent rows",
+    "mesh": "a latent page has no head axis to split (pool_specs), and "
+            "the expert stacks have no sharding rule yet",
+    "named_prefix": "a registered prefix keeps key/value rows, not latent "
+                    "rows (the automatic prefix cache serves them)",
+    "kv_import": "KV import/export ships key/value page pairs",
+    "kv_export": "KV import/export ships key/value page pairs",
+    "sessions": "a session keeps key/value rows between turns, not latent "
+                "rows",
+    "padded_generate": "generate_text's padded batch decodes against a "
+                       "contiguous key/value cache; serve through "
+                       "continuous_batcher",
+}
 
 
 def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
@@ -423,7 +520,26 @@ def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
     :class:`HybridCache`).  Served anyway, such a feature
     would hand a row its pages without its state.  ``asked`` maps a
     feature's name to whether it was asked for; ``paged_pages`` is the one
-    that must be set."""
+    that must be set.
+
+    The latent format (:class:`LatentCache`) refuses here too, with its own
+    reasons: what is written for key/value page pairs and has no latent
+    case yet.  Its pages are whole rows' states, so ``prefix_cache`` is
+    served."""
+    if cfg.kv_lora_rank:
+        if asked.pop("paged_pages", 1) is None:
+            raise ValueError(
+                "a model with latent attention is served from the page "
+                "pool only; pass paged_pages"
+            )
+        asked.pop("prefix_cache", None)
+        for name, value in asked.items():
+            if value:
+                raise ValueError(
+                    f"{name} is not supported for latent (MLA) pages: "
+                    f"{_LATENT_REFUSALS[name]}"
+                )
+        return
     if not pages_are_private(cfg):
         return
     why = {

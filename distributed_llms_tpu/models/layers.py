@@ -14,6 +14,7 @@ the decoder blocks are real, written TPU-first:
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -72,6 +73,50 @@ def rope_frequencies(
     return inv_freq
 
 
+def yarn_frequencies(
+    dim: int, theta: float, factor: float, original_max_len: int,
+    beta_fast: float = 32.0, beta_slow: float = 1.0,
+) -> jax.Array:
+    """YaRN's inverse frequencies (DeepSeek's form), [dim // 2] float32.
+    ``corr(r)`` is the (fractional) index of the frequency that turns r
+    times within the original context; below ``floor(corr(beta_fast))`` a
+    frequency is kept, above ``ceil(corr(beta_slow))`` it is divided by
+    ``factor``, and a linear ramp lies between."""
+    f = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+
+    def corr(rotations):
+        return (dim * math.log(original_max_len / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), dim - 1)
+    ramp = jnp.clip(
+        (jnp.arange(dim // 2, dtype=jnp.float32) - low)
+        / max(high - low, 0.001), 0.0, 1.0)
+    return f / factor * ramp + f * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope_pairs(
+    x: jax.Array, positions: jax.Array, inv_freq: jax.Array,
+    mscale: float = 1.0,
+) -> jax.Array:
+    """Rotate the pairs (2i, 2i + 1) of x's last axis together (DeepSeek's
+    layout, which the latent-attention layers use) by ``positions *
+    inv_freq``, cos and sin times ``mscale``.  x: [B, T, ..., D];
+    positions [B, T]."""
+    angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B, T, D/2]
+    angles = angles.reshape(*angles.shape[:2], *(1,) * (x.ndim - 3), -1)
+    cos, sin = jnp.cos(angles) * mscale, jnp.sin(angles) * mscale
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    x1, x2 = xf[..., 0], xf[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
 def apply_rope(
     x: jax.Array, positions: jax.Array, theta: float,
     scaling: tuple[float, float, float, int] | None = None,
@@ -113,11 +158,13 @@ def dot_product_attention(
     k: jax.Array,  # [B, Tk, H, D]
     v: jax.Array,  # [B, Tk, H, D]
     mask: jax.Array | None,  # broadcastable to [B, H, Tq, Tk]; True = attend
+    scale: float | None = None,  # default: head width ** -0.5
 ) -> jax.Array:
     """Softmax(QK^T)V with f32 accumulation.  XLA fuses this into MXU-friendly
     batched matmuls; the Pallas flash kernel in ops/ is the drop-in for long
-    sequences."""
-    scale = q.shape[-1] ** -0.5
+    sequences.  v's heads may be narrower than q's and k's."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
     logits = logits * scale
     if mask is not None:
@@ -274,16 +321,29 @@ def route_experts(
     softmaxes over them (Mixtral); "sigmoid" scores every expert by
     ``sigmoid(logit)``, picks the top k of score + ``bias`` (the selection
     bias picks, it does not weigh), weighs by the chosen scores over their
-    sum + 1e-6 (``moe_norm_topk``) times ``moe_routed_scale`` (LFM2-MoE)."""
+    sum + ``moe_norm_eps`` (``moe_norm_topk``) times ``moe_routed_scale``
+    (LFM2-MoE).  With ``moe_n_group`` > 1 the experts are that many
+    consecutive runs, a group scores as its best member, and only the
+    experts of the ``moe_topk_group`` best groups can be picked (A.X-K1,
+    DeepSeek-V3's rule without a correction bias)."""
     k = cfg.num_experts_per_token
     if cfg.moe_score_fn == "softmax":
         topv, topi = jax.lax.top_k(logits, k)
         return jax.nn.softmax(topv, axis=-1), topi
     scores = jax.nn.sigmoid(logits)
-    _, topi = jax.lax.top_k(scores if bias is None else scores + bias, k)
+    pick = scores if bias is None else scores + bias
+    if cfg.moe_n_group > 1:
+        s, e = pick.shape
+        grouped = pick.reshape(s, cfg.moe_n_group, e // cfg.moe_n_group)
+        _, best = jax.lax.top_k(jnp.max(grouped, axis=-1), cfg.moe_topk_group)
+        kept = jnp.any(
+            best[:, :, None] == jnp.arange(cfg.moe_n_group)[None, None, :],
+            axis=1)  # [S, groups]
+        pick = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(s, e)
+    _, topi = jax.lax.top_k(pick, k)
     w = jnp.take_along_axis(scores, topi, axis=-1)
     if cfg.moe_norm_topk:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + cfg.moe_norm_eps)
     return w * cfg.moe_routed_scale, topi
 
 
@@ -308,7 +368,15 @@ def moe_dropless(
     than masking them, but are not counted).  Returns (y, stats): stats
     int32 [4] = routed pairs, layer passes (1 if any token is real),
     experts with at least one real token, the fullest expert's real tokens
-    — the sources of ``moe.*`` counters (runtime/batcher.py)."""
+    — the sources of ``moe.*`` counters (runtime/batcher.py).
+
+    A config that holds a chip's share of the experts
+    (``cfg.experts_held``) routes over all ``num_experts`` and computes
+    the pairs that fell on experts [offset, offset + held): the rest add
+    nothing here (their chips would), experts touched and the fullest
+    expert's load count the held ones, and stats has a fifth entry, the
+    pairs that fell on a held expert.  (A shared expert, which every
+    token goes through, is the caller's to add: models.model.run_layers.)"""
     from ..ops import moe_experts
 
     b, t, d = x.shape
@@ -323,17 +391,23 @@ def moe_dropless(
         w, topi = route_experts(logits, cfg, bias)
         real = (jnp.ones((b * t,), bool) if token_mask is None
                 else token_mask.reshape(b * t))
-        load = jnp.sum(  # real tokens an expert: [E]
-            jax.nn.one_hot(topi, cfg.num_experts, dtype=jnp.int32)
+        share = cfg.experts_held is not None
+        # An id outside the held run has no one-hot column: not counted.
+        local = topi - cfg.experts_offset if share else topi
+        load = jnp.sum(  # real tokens an expert (a HELD expert): [E]
+            jax.nn.one_hot(local, cfg.held_experts, dtype=jnp.int32)
             * real[:, None, None], axis=(0, 1))
-        stats = jnp.stack([
-            jnp.sum(load), jnp.any(real).astype(jnp.int32),
+        stats = [
+            jnp.sum(real) * topi.shape[1] if share else jnp.sum(load),
+            jnp.any(real).astype(jnp.int32),
             jnp.sum(load > 0, dtype=jnp.int32), jnp.max(load),
-        ])
+        ]
+        stats = jnp.stack(stats + ([jnp.sum(load)] if share else []))
     with jax.named_scope("moe_experts"):
         ex = p["experts"]
         y = moe_experts.grouped_swiglu(
-            xf, topi, ex["w_gate_up"], ex["w_down"], layer)
+            xf, local, ex["w_gate_up"], ex["w_down"], layer,
+            of_experts=cfg.num_experts if share else None)
         y = jnp.sum(y.astype(jnp.float32) * w[:, :, None], axis=1)
     return y.reshape(b, t, d).astype(x.dtype), stats
 

@@ -375,6 +375,176 @@ def _seq_cached_attention(
     return layers.out_project(out, p), ((ck_pref, ck_dec), (cv_pref, cv_dec))
 
 
+def mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale of a latent-attention head: width ** -0.5, times
+    YaRN's mscale(factor, mscale_all_dim) squared."""
+    m = (layers.yarn_mscale(cfg.rope_scaling_factor, cfg.yarn_mscale_all_dim)
+         if cfg.rope_scaling_type == "yarn" else 1.0)
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def mla_rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Rotate the rope part of latent attention's queries or shared key
+    (pairs (2i, 2i + 1) together), with YaRN where the config has it."""
+    if cfg.rope_scaling_type == "yarn" and cfg.rope_scaling_factor != 1.0:
+        f = cfg.rope_scaling_factor
+        inv_freq = layers.yarn_frequencies(
+            x.shape[-1], cfg.rope_theta, f, cfg.rope_original_max_len,
+            cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+        m = (layers.yarn_mscale(f, cfg.yarn_mscale)
+             / layers.yarn_mscale(f, cfg.yarn_mscale_all_dim))
+    else:
+        inv_freq, m = layers.rope_frequencies(x.shape[-1], cfg.rope_theta), 1.0
+    return layers.apply_rope_pairs(x, positions, inv_freq, m)
+
+
+_MLA_QUERY_BLOCK = 256
+
+
+def _expanded_attention(q, k, v, mask, scale):
+    """Dense attention of an admission's expanded heads, the queries a
+    block at a time where there are many: 64 heads x 2,048 queries x 4,096
+    slots of float32 scores would be 2 GiB at once."""
+    b, t, h, _ = q.shape
+    n = t // _MLA_QUERY_BLOCK
+    if n < 2 or t % _MLA_QUERY_BLOCK:
+        return layers.dot_product_attention(q, k, v, mask, scale)
+    mask = jnp.broadcast_to(mask, (b, mask.shape[1], t, k.shape[1]))
+
+    def block(args):
+        qb, mb = args
+        return layers.dot_product_attention(qb, k, v, mb, scale)
+
+    out = jax.lax.map(block, (
+        jnp.moveaxis(q.reshape(b, n, _MLA_QUERY_BLOCK, h, -1), 1, 0),
+        jnp.moveaxis(mask.reshape(b, -1, n, _MLA_QUERY_BLOCK, k.shape[1]),
+                     2, 0),
+    ))
+    return jnp.moveaxis(out, 0, 1).reshape(b, t, h, -1)
+
+
+@jax.named_scope("attn")  # profiler scope; HLO metadata only
+def mla_attention(
+    x: jax.Array,  # [B, T, D], normed
+    p: Params,  # wq_a, q_norm, wq_b, wkv_a, kv_norm, wkv_b, wo
+    cfg: ModelConfig,
+    positions: jax.Array,
+    layer_cache: Any,  # None; this layer's latent rows [B, S, W]; or with
+    #   kv_tables the whole latent page pool (kv_cache.LatentCache)
+    cache_index: jax.Array | None,
+    attn_mask: jax.Array | None = None,
+    kv_tables: jax.Array | None = None,
+    layer: jax.Array | None = None,
+) -> tuple[jax.Array, Any]:
+    """Multi-head latent attention.  ``c_q = rms(x W_qa)``, a head's query
+    ``[q_nope | rope(q_rope)] = c_q W_qb``; ``[c | k_r] = x W_kva``, the
+    token's cached row ``[rms(c) | rope(k_r)]`` (one rotated key for all
+    heads); a head's key ``[c_kv W_uk | k_rope]`` and value ``c_kv W_uv``
+    with ``W_kvb = [W_uk | W_uv]`` a head.
+
+    A decode step against the page pool (``kv_tables``) ABSORBS the
+    up-projection: ``q_lat = q_nope W_uk^T`` attends to the rows as they
+    lie and the weighted sum of latents goes through ``W_uv`` afterwards
+    (ops.decode_attn.mla_paged_decode_attention), so a page is read once
+    and no head's key or value is ever formed.  Every other call EXPANDS
+    keys and values from the rows (an admission: the cached run's too,
+    behind a prefix hit) and attends densely, as the other families'
+    admissions do.  Both read the same stored rows and the same W_kvb."""
+    from ..ops import decode_attn
+
+    b, t, _ = x.shape
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    w = cfg.latent_width
+    scale = mla_scale(cfg)
+    with jax.named_scope("mla_q"):
+        cq = layers.rms_norm(
+            layers._contract(x, p["wq_a"], "btd,dn->btn", 1, "n"),
+            p["q_norm"], cfg.norm_eps)
+        q = layers._contract(cq, p["wq_b"], "btd,dn->btn", 1, "n").reshape(
+            b, t, h, dn + dr)
+        q_nope, q_rope = q[..., :dn], mla_rope(q[..., dn:], positions, cfg)
+    with jax.named_scope("mla_kv"):
+        ckr = jnp.einsum("btd,dn->btn", x, p["wkv_a"].astype(x.dtype))
+        row = jnp.concatenate([
+            layers.rms_norm(ckr[..., :r], p["kv_norm"], cfg.norm_eps),
+            mla_rope(ckr[..., r:], positions, cfg),
+            jnp.zeros((b, t, w - r - dr), x.dtype),
+        ], axis=-1)  # [B, T, W]: what is cached
+    wkv_b = p["wkv_b"].astype(x.dtype).reshape(r, h, dn + dv)
+
+    def project(o):  # [B, T, H, dv] -> [B, T, D]
+        return layers._contract(
+            o.reshape(b, t, h * dv), p["wo"], "btn,nd->btd", 1, "k")
+
+    if kv_tables is not None:
+        if layer_cache is None or getattr(cache_index, "ndim", 0) != 1:
+            raise ValueError(
+                "paged attention is per-row decode (a per-row cache_index "
+                "over a page-pool cache)"
+            )
+        pool = layer_cache
+        rows = jnp.arange(b, dtype=jnp.int32)
+        blk = pool.k.shape[2]
+        idx = cache_index[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+        pool = kv_cache.write_tokens(
+            pool, layer, kv_tables[rows[:, None], idx // blk], idx % blk, row)
+        with jax.named_scope("mla_absorb"):
+            q_abs = jnp.concatenate([
+                jnp.einsum("bthn,rhn->bthr", q_nope, wkv_b[..., :dn]),
+                q_rope, jnp.zeros((b, t, h, w - r - dr), x.dtype),
+            ], axis=-1).astype(pool.k.dtype)
+        # T > 1 (no caller today): a call a token, as _paged_attention.
+        o_lat = jnp.concatenate([
+            decode_attn.mla_paged_decode_attention(
+                q_abs[:, j: j + 1], pool.k, cache_index + 1 + j, kv_tables,
+                latent=r, scale=scale, layer=layer)
+            for j in range(t)
+        ], axis=1)
+        with jax.named_scope("mla_absorb"):
+            o = jnp.einsum("bthr,rhv->bthv", o_lat.astype(x.dtype),
+                           wkv_b[..., dn:])
+        return project(o), pool
+
+    if layer_cache is None:
+        keys, new_cache = row, None
+        mask = (layers.causal_mask(positions, positions)
+                if attn_mask is None else attn_mask)
+    else:
+        ck = layer_cache  # [B, S, W]
+        if getattr(cache_index, "ndim", 0) == 1:
+            if attn_mask is None:
+                raise ValueError(
+                    "per-row cache_index requires an explicit attn_mask")
+            ck = jax.vmap(
+                lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (i, 0))
+            )(ck, row.astype(ck.dtype), cache_index)
+        else:
+            ck = jax.lax.dynamic_update_slice(
+                ck, row.astype(ck.dtype), (0, cache_index, 0))
+        new_cache, keys, mask = ck, ck.astype(x.dtype), attn_mask
+        if mask is None:
+            if not isinstance(cache_index, jax.core.Tracer):
+                # The write offset is known while tracing (an admission's
+                # fresh row: 0), so no slot past offset + T can be valid:
+                # expand and attend the slots that can.
+                keys = keys[:, : int(cache_index) + t]
+            s = keys.shape[1]
+            k_pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+            mask = layers.causal_mask(positions, k_pos,
+                                      k_pos < cache_index + t)
+    kv = jnp.einsum("bsr,rhn->bshn", keys[..., :r], wkv_b)
+    k = jnp.concatenate([
+        kv[..., :dn],
+        jnp.broadcast_to(keys[:, :, None, r: r + dr],
+                         (*kv.shape[:3], dr)),
+    ], axis=-1)
+    out = _expanded_attention(
+        jnp.concatenate([q_nope, q_rope], axis=-1), k, kv[..., dn:], mask,
+        scale)
+    return project(out), new_cache
+
+
 def gpt2_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, std_layout=False, kv_tables=None, key_positions=None, layer=None):
     """-> (x, new_cache, aux): aux is the MoE load-balance term (0 here).
     Shared by the gpt2 and opt families (pre-LN + learned positions);
@@ -584,7 +754,7 @@ def run_layers(
     pairs, layer passes, experts touched, fullest expert's tokens), a
     by-product like the dense families' aux loss and no part of the
     state."""
-    moe = jnp.zeros((4,), jnp.int32)
+    moe = jnp.zeros((4 if cfg.experts_held is None else 5,), jnp.int32)
     token_mask = None
     if seq_lens is not None:
         token_mask = (jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
@@ -602,6 +772,19 @@ def run_layers(
             if cache is not None:
                 cache = dataclasses.replace(cache, conv=cache.conv.at[
                     at[op]].set(new.astype(cache.conv.dtype)))
+        elif op == "mla":
+            ai = at[op]
+            if cache is None or kv_tables is not None:
+                layer_cache = cache
+            else:
+                layer_cache = cache.k[ai]
+            out, new = mla_attention(
+                h, p, cfg, positions, layer_cache, cache_index, attn_mask,
+                kv_tables, ai)
+            if kv_tables is not None:
+                cache = new
+            elif cache is not None:
+                cache = dataclasses.replace(cache, k=cache.k.at[ai].set(new))
         else:
             ai = at[op]
             if cache is None:
@@ -627,13 +810,18 @@ def run_layers(
             y, stats = layers.moe_dropless(
                 h, blocks["moe"], cfg, token_mask, layer=at[ffn])
             x, moe = x + y, moe + stats
+            if cfg.n_shared_experts:
+                with jax.named_scope("shared_expert"):
+                    x = x + layers.mlp_swiglu(
+                        h, layer_of(blocks["moe"]["shared"], at[ffn]),
+                        cfg.gate_act)
         else:
             x = x + layers.mlp_swiglu(
                 h, layer_of(blocks["dense"], at[ffn]), cfg.gate_act)
         return x, cache, moe
 
     carry = (x, cache, moe)
-    base = dict(conv=0, attn=0, dense=0, moe=0)
+    base = dict(conv=0, attn=0, mla=0, dense=0, moe=0)
     for unit, reps in layer_runs(cfg):
         kinds = [kind for pair in unit for kind in pair]
         per_unit = {kind: kinds.count(kind) for kind in base}
@@ -663,7 +851,7 @@ def hybrid_layers(params: Params, cfg: ModelConfig):
     order: dicts {"ln1", "ln2": {"scale"}, "conv" | "attn": {...}, "mlp":
     {...}} as models/reference/lfm2_moe.py reads them.  A generator, so a
     caller that dequantizes what it is handed holds one layer in float32."""
-    at = dict(conv=0, attn=0, dense=0, moe=0)
+    at = dict(conv=0, attn=0, mla=0, dense=0, moe=0)
     blocks = params["blocks"]
 
     def take(kind):
@@ -874,7 +1062,7 @@ def hybrid_fan_in(name: str, shape: tuple) -> int:
     the random init scales by (shared by :func:`init_params` and
     :func:`init_params_quantized`, so that both draw at one scale)."""
     parts = name.split("/")
-    if parts[-1] == "wo":  # [L, H, hd, D]
+    if parts[-1] == "wo" and len(shape) == 4:  # [L, H, hd, D]
         return shape[1] * shape[2]
     if parts[-1] == "taps":  # [L, D, K]
         return shape[2]
@@ -909,13 +1097,40 @@ def _init_hybrid_blocks(rng: jax.Array, cfg: ModelConfig, dtype: Any) -> Params:
                 "ln2": {"scale": jnp.ones((n, D), dtype)}}
 
     blocks: Params = {
-        "conv": {
+        "dense": {
+            "w_gate": dense("dense/w_gate", (ND, D, F)),
+            "w_up": dense("dense/w_up", (ND, D, F)),
+            "w_down": dense("dense/w_down", (ND, F, D)),
+        },
+    }
+    if NC:
+        blocks["conv"] = {
             **norms(NC),
             "in_proj": dense("conv/in_proj", (NC, D, 3 * D)),
             "taps": dense("conv/taps", (NC, D, cfg.conv_kernel)),
             "out_proj": dense("conv/out_proj", (NC, D, D)),
-        },
-        "attn": {
+        }
+    if cfg.kv_lora_rank:
+        # Latent attention (models.model.mla_attention).  Every leaf a
+        # matrix, the head axes flat.  W_kva (its 576 columns are not whole
+        # 128-wide blocks) and W_kvb (read in two orientations: expanded by
+        # an admission, absorbed by a decode step) stay in the model's
+        # dtype (checkpoint.quantize), 25 MB a layer at A.X-K1's widths.
+        QR, R = cfg.q_lora_rank, cfg.kv_lora_rank
+        DN, DR, DV = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        blocks["mla"] = {
+            **norms(NA),
+            "wq_a": dense("mla/wq_a", (NA, D, QR)),
+            "q_norm": jnp.ones((NA, QR), dtype),
+            "wq_b": dense("mla/wq_b", (NA, QR, H * (DN + DR))),
+            "wkv_a": dense("mla/wkv_a", (NA, D, R + DR)),
+            "kv_norm": jnp.ones((NA, R), dtype),
+            "wkv_b": dense("mla/wkv_b", (NA, R, H * (DN + DV))),
+            "wo": dense("mla/wo", (NA, H * DV, D)),
+        }
+    elif NA:
+        blocks["attn"] = {
             **norms(NA),
             # [D, H * hd], the head axes flat: quantized with their own
             # axis last, heads of 64 would get absmax blocks of 64, which
@@ -924,24 +1139,26 @@ def _init_hybrid_blocks(rng: jax.Array, cfg: ModelConfig, dtype: Any) -> Params:
             "wk": dense("attn/wk", (NA, D, KVH * HD)),
             "wv": dense("attn/wv", (NA, D, KVH * HD)),
             "wo": dense("attn/wo", (NA, H, HD, D)),
-        },
-        "dense": {
-            "w_gate": dense("dense/w_gate", (ND, D, F)),
-            "w_up": dense("dense/w_up", (ND, D, F)),
-            "w_down": dense("dense/w_down", (ND, F, D)),
-        },
-    }
-    if cfg.qk_norm:
+        }
+    if cfg.qk_norm and "attn" in blocks:
         blocks["attn"]["q_norm"] = jnp.ones((NA, HD), dtype)
         blocks["attn"]["k_norm"] = jnp.ones((NA, HD), dtype)
     if NM:
+        EH = cfg.held_experts  # (a chip's share; the router scores all E)
         blocks["moe"] = {
             "router": dense("moe/router", (NM, D, E), jnp.float32),
             "experts": {
-                "w_gate_up": dense("moe/experts/w_gate_up", (NM, E, D, 2 * FE)),
-                "w_down": dense("moe/experts/w_down", (NM, E, FE, D)),
+                "w_gate_up": dense("moe/experts/w_gate_up", (NM, EH, D, 2 * FE)),
+                "w_down": dense("moe/experts/w_down", (NM, EH, FE, D)),
             },
         }
+        if cfg.n_shared_experts:
+            FS = cfg.n_shared_experts * FE
+            blocks["moe"]["shared"] = {
+                "w_gate": dense("moe/shared/w_gate", (NM, D, FS)),
+                "w_up": dense("moe/shared/w_up", (NM, D, FS)),
+                "w_down": dense("moe/shared/w_down", (NM, FS, D)),
+            }
         if cfg.moe_expert_bias:
             key = jax.random.fold_in(
                 rng, zlib.crc32(b"blocks/moe/expert_bias"))
@@ -1018,7 +1235,7 @@ def init_params_quantized(
             return qt.data, jnp.repeat(qt.scale, repeat, axis=-2)
 
         def gen():
-            if leaf in ("scale", "q_norm", "k_norm"):
+            if leaf in ("scale", "q_norm", "k_norm", "kv_norm"):
                 return jnp.ones(sd.shape, sd.dtype)
             if leaf == "expert_bias":  # drawn, so that it changes the choice
                 return 0.1 * jax.random.normal(key, sd.shape, sd.dtype)

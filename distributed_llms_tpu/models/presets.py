@@ -114,7 +114,46 @@ PRESETS: dict[str, ModelConfig] = {
         moe_expert_bias=True, moe_norm_topk=True, moe_routed_scale=1.0,
         moe_capacity=False,
     ),
+    # A.X-K1 (SKT, model_type axk1) as ONE CHIP'S SHARE of a 16-chip
+    # pipeline stage: the published widths (latent attention at 64 heads,
+    # q/kv ranks 1536/512, heads of 128 + 64 and 128, YaRN 32 x 4,096; a
+    # dense SwiGLU of 18,432 then expert layers of 192 routed (8 a token,
+    # sigmoid scores, 8 groups of which 4 are kept, normalised, x 2.5) and
+    # one shared expert of 2,048), cut in depth to 1 + 12 of the 61 layers,
+    # to experts 0-11 of every layer's 192 (the router keeps all 192
+    # outputs) and to a 20,480-row slice of the vocabulary:
+    # benchmark/configs/ax-k1-int8-ep16.json has the deployment.
+    "ax-k1-ep16": ModelConfig(
+        family="hybrid", vocab_size=20480, hidden_size=7168,
+        intermediate_size=18432, moe_intermediate_size=2048, num_layers=13,
+        num_dense_layers=1, num_heads=64, num_kv_heads=64, head_dim=192,
+        max_seq_len=131072, rope_theta=10000.0, norm_eps=1e-6,
+        tie_embeddings=False, layer_types=("mla",) * 13,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+        rope_scaling_type="yarn", rope_scaling_factor=32.0,
+        rope_original_max_len=4096, yarn_beta_fast=32.0, yarn_beta_slow=1.0,
+        yarn_mscale=1.0, yarn_mscale_all_dim=1.0,
+        num_experts=192, num_experts_per_token=8, moe_score_fn="sigmoid",
+        moe_norm_topk=True, moe_norm_eps=1e-20, moe_routed_scale=2.5,
+        moe_capacity=False, n_shared_experts=1, moe_n_group=8,
+        moe_topk_group=4, experts_held=12, experts_offset=0,
+    ),
     # Tiny configs for unit tests / CPU fake-mesh integration tests.
+    "ax-k1-tiny": ModelConfig(
+        family="hybrid", vocab_size=256, hidden_size=64,
+        intermediate_size=128, moe_intermediate_size=32, num_layers=4,
+        num_dense_layers=1, num_heads=4, num_kv_heads=4, head_dim=24,
+        max_seq_len=256, rope_theta=10000.0, norm_eps=1e-6,
+        tie_embeddings=False, dtype="float32", layer_types=("mla",) * 4,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=12,
+        rope_scaling_type="yarn", rope_scaling_factor=4.0,
+        rope_original_max_len=32, yarn_mscale_all_dim=1.0,
+        num_experts=16, num_experts_per_token=4, moe_score_fn="sigmoid",
+        moe_norm_eps=1e-20, moe_routed_scale=2.5, moe_capacity=False,
+        n_shared_experts=1, moe_n_group=4, moe_topk_group=2,
+    ),
     "lfm2-tiny": ModelConfig(
         family="hybrid", vocab_size=256, hidden_size=64,
         intermediate_size=128, moe_intermediate_size=32, num_layers=8,

@@ -258,17 +258,22 @@ def _kernel_paged(
     layer_ref,  # scalar-prefetch [1] int32: the layer of the stack to read
     q_ref,  # [1, Hp, D] (int8 leg: [1, KVH*Gp, D], as _kernel's)
     k_hbm,  # the pool where it lies, never blocked or copied:
-    v_hbm,  #   [L, NB, BLK*KVH, D] (int8 leg: [L, NB, BLK, KVH, D])
-    *rest,  # int8 leg: [ks_hbm [NB, BLK, 128] f32 (this layer's), vs_hbm],
-    #   then o_ref and the scratch: k_buf / v_buf [2, run pages' rows, D]
-    #   ([ks_buf / vs_buf [2, run*BLK, 128]]), sem DMA[2], slot SMEM[1],
-    #   acc [Hp, D], m / l [Hp, 128]
+    #   [L, NB, BLK*KVH, D] (int8 leg: [L, NB, BLK, KVH, D]; latent pages:
+    #   [L, NB, BLK, W])
+    *rest,  # v_hbm (not for latent pages: the values are the first
+    #   ``latent`` columns of the keys' rows); int8 leg: [ks_hbm
+    #   [NB, BLK, 128] f32 (this layer's), vs_hbm]; then o_ref and the
+    #   scratch: k_buf / v_buf [2, run pages' rows, D] ([ks_buf / vs_buf
+    #   [2, run*BLK, 128]]), sem DMA[2], slot SMEM[1], acc [Hp, Dv],
+    #   m / l [Hp, 128]
     scale: float,
     blk: int,
     run: int,  # pages a run: fetched together, one softmax update for all
     kvh: int,
     g: int,  # queries a KV head (int8 leg: padded to eight, _kernel's gp)
     quant: bool = False,
+    latent: int | None = None,  # latent (MLA) pages: one buffer, every
+    #   head reads every row, values = its first ``latent`` columns
 ):
     """Paged variant: grid ``(B,)``, and inside a row a loop over its RUNS
     of ``run`` pages.  The kernel fetches a run itself — a DMA a page (and
@@ -280,11 +285,14 @@ def _kernel_paged(
     the row holds are read.  The compute is one online-softmax update a
     run: :func:`_softmax_all_heads`, or for int8 pages, whose scales lie a
     head to a lane, the contiguous kernel's :func:`_softmax_block`."""
-    if quant:
-        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, slot_ref,
-         acc_ref, m_ref, l_ref) = rest
+    if latent:
+        o_ref, k_buf, sem, slot_ref, acc_ref, m_ref, l_ref = rest
+        v_buf = k_buf
+    elif quant:
+        (v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem,
+         slot_ref, acc_ref, m_ref, l_ref) = rest
     else:
-        o_ref, k_buf, v_buf, sem, slot_ref, acc_ref, m_ref, l_ref = rest
+        v_hbm, o_ref, k_buf, v_buf, sem, slot_ref, acc_ref, m_ref, l_ref = rest
     bi, rows = pl.program_id(0), pl.num_programs(0)
     layer = layer_ref[0]
     page_rows = k_buf.shape[1] // run
@@ -303,8 +311,9 @@ def _kernel_paged(
         def page(r, carry):
             pg = tables_ref[b, first + r] if start else 0
             into = pl.ds(r * page_rows, page_rows)
-            pairs = [(k_hbm.at[layer, pg], k_buf.at[slot, into]),
-                     (v_hbm.at[layer, pg], v_buf.at[slot, into])]
+            pairs = [(k_hbm.at[layer, pg], k_buf.at[slot, into])]
+            if not latent:
+                pairs += [(v_hbm.at[layer, pg], v_buf.at[slot, into])]
             if quant:
                 into = pl.ds(r * blk, blk)
                 pairs += [(ks_hbm.at[pg], ks_buf.at[slot, into]),
@@ -348,6 +357,10 @@ def _kernel_paged(
             _softmax_block(q_ref, k_buf.at[slot], v_buf.at[slot],
                            (ks_buf.at[slot], vs_buf.at[slot]),
                            acc_ref, m_ref, l_ref, gp=g, **state)
+        elif latent:
+            _softmax_all_heads(q_ref, k_buf.at[slot],
+                               k_buf.at[slot, :, pl.ds(0, latent)],
+                               acc_ref, m_ref, l_ref, g=g, **state)
         else:
             _softmax_all_heads(q_ref, k_buf.at[slot], v_buf.at[slot],
                                acc_ref, m_ref, l_ref, g=g, **state)
@@ -750,6 +763,93 @@ def _paged_impl(
             jnp.eye(fold, dtype=out.dtype),
         )
     return out.reshape(b, 1, h, q.shape[-1])
+
+
+def _latent_run_pages(blk: int, w: int, dtype, p: int) -> int:
+    """:func:`_run_pages` for latent pages, which have one buffer where
+    keys and values have two: a mebibyte of rows (12 pages of 64 x 640
+    bf16), at most the ``p`` a row can hold."""
+    return max(1, min((1 << 20) // (blk * w * jnp.dtype(dtype).itemsize), p))
+
+
+def mla_paged_decode_attention(
+    q: jax.Array,  # [B, 1, H, W]: a head's absorbed query, [q_nope W_uk^T
+    #               (latent) | rotated q_rope | zeros] as a page row lies
+    pages: jax.Array,  # [L, NB, BLK, W] latent page pool, every layer's
+    lengths: jax.Array,  # [B] int32
+    tables: jax.Array,  # [B, P] int32
+    *, latent: int,  # the first ``latent`` columns of a row are its values
+    scale: float,
+    layer: jax.Array | int = 0,
+) -> jax.Array:
+    """:func:`paged_decode_attention` for latent (MLA) pages, the absorbed
+    form: a token's row [c_kv | k_rope | 0] is the key of EVERY head (one
+    KV head, H queries a group) and its first ``latent`` columns are every
+    head's value, so a page is fetched once and serves both products.  The
+    walk is :func:`_kernel_paged`'s (the pool left in HBM, a run of about a
+    mebibyte of pages in flight, the next run's copies started before this
+    one is computed on); the body is :func:`_softmax_all_heads` at one KV
+    head.  Returns [B, 1, H, latent]: per head the attention-weighted sum
+    of latents, which the caller multiplies by the head's W_uv.
+    Single-device (the format refuses a mesh)."""
+    return _mla_paged_impl(
+        q, pages, lengths.astype(jnp.int32), tables.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), latent=latent, scale=scale,
+        mode=_mode())
+
+
+def _mla_paged_impl(q, pages, lengths, tables, layer, *, latent: int,
+                    scale: float, mode: str = "fallback",
+                    run: int | None = None) -> jax.Array:
+    b, t, h, w = q.shape
+    assert t == 1, "paged decode attention is single-token by construction"
+    blk = pages.shape[2]
+    p = tables.shape[1]
+    tileable = (blk % 8 == 0 and w % 128 == 0 and latent % 128 == 0
+                and h % 8 == 0)
+    if mode == "fallback" or not tileable:
+        # ("paged_decode" too: the family of kernels that serve a decode
+        # step from pages, which the benchmark's dispatch check names.)
+        dispatch.record("paged_decode", "fallback", (b, blk, h, 1, w))
+        dispatch.record("mla_paged_decode", "fallback", (b, blk, h, w))
+        rows = pages[layer[0]][tables].reshape(b, p * blk, w).astype(q.dtype)
+        s = jnp.einsum("bhw,bsw->bhs", q[:, 0], rows,
+                       preferred_element_type=jnp.float32) * scale
+        mask = jnp.arange(p * blk, dtype=jnp.int32)[None, :] < lengths[:, None]
+        s = jnp.where(mask[:, None, :], s, jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhs,bsc->bhc", probs, rows[..., :latent])[:, None]
+    run = run or _latent_run_pages(blk, w, pages.dtype, p)
+    dispatch.record("paged_decode", mode, (b, blk, h, 1, w, run))
+    dispatch.record("mla_paged_decode", mode, (b, blk, h, w, run))
+    q_spec = pl.BlockSpec((1, h, w), lambda bi, L, T, Y: (bi, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(
+            _kernel_paged, scale=scale, blk=blk, run=run, kvh=1, g=h,
+            latent=latent,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(
+                (1, h, latent), lambda bi, L, T, Y: (bi, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, run * blk, w), pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((h, latent), jnp.float32),
+                pltpu.VMEM((h, 128), jnp.float32),
+                pltpu.VMEM((h, 128), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, latent), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=mode == "interpret",
+        name="mla_paged_decode_attn",  # the operation's name in a trace
+    )(lengths, tables, layer, q[:, 0], pages)
+    return out[:, None]
 
 
 def pool_head_shape(kvh: int, d: int, fold_narrow: bool) -> tuple[int, int]:

@@ -69,17 +69,23 @@ def _tiles(k: int, n: int, qb: int) -> tuple[int, int] | None:
     of bytes in HBM (LFM2: [1024, 3584] and a whole [1792, 2048]), and on
     the chip its time does not depend on where the stacks lie, where the
     half-width tiles of a 2 MiB budget ([1024, 1792], 56 KB runs at a 112
-    KB stride) were 3% slower at most addresses (PERF.md, PR 28)."""
+    KB stride) were 3% slower at most addresses (PERF.md, PR 28).  A.X-K1:
+    [1024, 4096] of [7168, 4096], whole rows; [1024, 3584] of [2048,
+    7168], where 1024 whole rows are 7 MiB."""
     if qb != 128 or k % qb or n % 128:
         return None
-    bk, bn = k, n
+    # Row blocks that keep the scale tile whole sublanes: the whole axis,
+    # or a divisor of it that is a multiple of 8 scale blocks.
+    bks = [k] + [b for b in range(k - k % (8 * qb), 0, -8 * qb)
+                 if b < k and k % b == 0]
+    for bk in bks:
+        if bk * n <= _TILE_BYTES:
+            return bk, n
+    bk, bn = bks[-1], n  # no run of whole rows fits: halve the columns
     while bk * bn > _TILE_BYTES:
-        if bk % 2 == 0 and (bk // 2) % (8 * qb) == 0:
-            bk //= 2
-        elif bn % 2 == 0 and (bn // 2) % 128 == 0:
-            bn //= 2
-        else:
+        if bn % 2 or (bn // 2) % 128:
             return None
+        bn //= 2
     return bk, bn
 
 
@@ -117,7 +123,9 @@ def _grouped_quant_matmul(x, q, s, tile_expert, num_tiles, layer, *, bm, bk,
     would be copied, 0.36 GB a layer a step in LFM2 (a Pallas call takes
     each operand as a buffer of its own).  Tiles at and past ``num_tiles``
     are skipped: their index maps repeat the last real step's blocks, so
-    nothing is fetched, computed or written for them."""
+    nothing is fetched, computed or written for them.  ``num_tiles`` may
+    be 0 (a chip's share of the experts, and no pair fell on it): every
+    step then names block 0, and nothing is computed."""
     pp, kd = x.shape
     n = q.shape[3]
     qb = kd // s.shape[2]
@@ -125,9 +133,11 @@ def _grouped_quant_matmul(x, q, s, tile_expert, num_tiles, layer, *, bm, bk,
     last = (grid[1] - 1, grid[2] - 1)
 
     def where(t, j, k, nt):
+        # (no tile at all, when no pair fell on a held expert: block 0,
+        # fetched once and never computed on)
         live = t < nt[0]
-        return (jnp.where(live, t, nt[0] - 1), jnp.where(live, j, last[0]),
-                jnp.where(live, k, last[1]))
+        return (jnp.where(live, t, jnp.maximum(nt[0] - 1, 0)),
+                jnp.where(live, j, last[0]), jnp.where(live, k, last[1]))
 
     def x_map(t, j, k, te, nt, ly):
         t, _, k = where(t, j, k, nt)
@@ -180,14 +190,22 @@ def _layer_of(w, layer, dtype):
 
 
 def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
-                   layer: jax.Array | int = 0) -> jax.Array:
+                   layer: jax.Array | int = 0,
+                   of_experts: int | None = None) -> jax.Array:
     """Every (token, choice) pair through its expert's SwiGLU.
 
     xf [S, D]; topi [S, k] int32 expert ids; w_gate_up [L, E, D, 2F] and
     w_down [L, E, F, D], every layer's experts in one stack, arrays or
     ``QuantizedTensor`` (block_axis -2); ``layer`` names the layer to read
     (traced inside a layer scan).  Returns the pairs' outputs [S, k, D] in
-    xf.dtype, unweighted: the caller applies the routing weights."""
+    xf.dtype, unweighted: the caller applies the routing weights.
+
+    ``of_experts``: the stacks hold E of that many experts, a chip's
+    share, and ``topi`` counts from the first one held, so an id outside
+    [0, E) names an expert that is elsewhere.  Such a pair leaves the
+    grouped list (it is given no row, and its output is zeros); the row
+    tile is sized for the share of the pairs that an even routing sends
+    here, the list for all of them."""
     s, d = xf.shape
     k = topi.shape[1]
     quant = _is_quantized(w_gate_up)
@@ -207,7 +225,9 @@ def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
             for w, kd, n in ((w_gate_up, d, f2), (w_down, f, d))
         ]
         tiles = plans if all(plans) else None
-    bm = row_tile(p, e) if tiles else 1  # the fallback pads no group
+    share = of_experts is not None
+    # (the fallback pads no group)
+    bm = row_tile(p * e // of_experts if share else p, e) if tiles else 1
 
     # Each pair's row in the grouped layout: its expert's first row (every
     # group padded to whole tiles of bm) plus its rank among the expert's
@@ -220,8 +240,15 @@ def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
     ends = jnp.cumsum(padded)
     dest = jnp.sum(oh * (ends - padded), axis=1) + rank  # [P]
     rows = (-(-p // bm) + e) * bm if tiles else p  # sum_e ceil(c_e/bm) tiles
-    src = jnp.zeros((rows,), jnp.int32).at[dest].set(token)
+    if share:  # an id outside [0, E) has no one-hot column: no row
+        here = jnp.logical_and(eid >= 0, eid < e)
+        dest = jnp.where(here, dest, rows)
+    src = jnp.zeros((rows,), jnp.int32).at[dest].set(token, mode="drop")
     xp = xf[src]  # padding rows repeat token 0: computed, never read back
+
+    def pairs(yp):
+        y = yp.at[dest].get(mode="fill", fill_value=0) if share else yp[dest]
+        return y.reshape(s, k, d)
 
     if tiles is None:
         dispatch.record("moe_experts", "fallback", (p, e, d, f))
@@ -229,7 +256,7 @@ def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
             xp, _layer_of(w_gate_up, layer, xf.dtype), counts)
         a = jax.nn.silu(h[:, :f]) * h[:, f:]
         yp = jax.lax.ragged_dot(a, _layer_of(w_down, layer, xf.dtype), counts)
-        return yp[dest].reshape(s, k, d)
+        return pairs(yp)
 
     dispatch.record("moe_experts", mode, (p, e, d, f))
     first_row = jnp.arange(rows // bm, dtype=jnp.int32) * bm
@@ -245,4 +272,4 @@ def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
     a = jax.nn.silu(h[:, :f]) * h[:, f:]
     yp = _grouped_quant_matmul(a, w_down.data, w_down.scale, *where,
                                bk=bk2, bn=bn2, **kw)
-    return yp[dest].reshape(s, k, d)
+    return pairs(yp)

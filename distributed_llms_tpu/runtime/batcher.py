@@ -195,23 +195,28 @@ def _prefill_row(fwd, params, cfg, cache_dtype, s, prompt, plen=None):
     )
 
 
-def _prefill_row_with_prefix(fwd, params, cfg, prefix_k, prefix_v, prefix_len,
-                             chunk):
+def _prefill_row_with_prefix(fwd, params, cfg, row_cache, prefix_len, chunk,
+                             clen=None):
     """Prefix-seeded prefill: only the request's suffix runs through the
     model (session-style continuation math) — shared by the contiguous and
-    paged prefix admissions."""
+    paged prefix admissions.  ``row_cache`` is the transient contiguous row
+    cache that holds the prefix (key/value rows, or latent rows:
+    kv_cache.row_cache_of).  A hybrid-family model is told the suffix's
+    true length ``clen`` and returns its expert counts third, as in
+    :func:`_prefill_row`."""
     (tc,) = chunk.shape
-    s = prefix_k.shape[-3]
+    s = row_cache.k.shape[2]
     slots = jnp.arange(s, dtype=jnp.int32)
-    row_cache = KVCache(k=prefix_k, v=prefix_v)
     positions = (prefix_len + jnp.arange(tc, dtype=jnp.int32))[None, :]
     from .session import continuation_mask
 
     prefix_valid = (slots < prefix_len)[None, :]  # [1, S]
     mask = continuation_mask(prefix_valid, prefix_len, tc, slots)  # [1,1,Tc,S]
+    state = ({"seq_lens": clen[None], "return_aux": True}
+             if cfg.family == "hybrid" and clen is not None else {})
     return fwd(
         params, cfg, chunk[None, :], positions=positions,
-        cache=row_cache, cache_index=prefix_len, attn_mask=mask,
+        cache=row_cache, cache_index=prefix_len, attn_mask=mask, **state,
     )
 
 
@@ -647,7 +652,8 @@ def admit_row_with_prefix(
     session-style continuation math (runtime/session.py) for one row.
     Returns (cache', first_token, row_valid, first_token_logprob)."""
     logits, row_cache = _prefill_row_with_prefix(
-        _fwd(pm), params, cfg, prefix_k, prefix_v, prefix_len, chunk
+        _fwd(pm), params, cfg, KVCache(k=prefix_k, v=prefix_v), prefix_len,
+        chunk,
     )
     cache, tok, row_valid, lp = _finish_admission(
         cache, slot, row_cache, logits, clen, rng, temperature, top_k, top_p,
@@ -690,7 +696,7 @@ def _prefill_leg(params, cfg, row_k, row_v, done, chunk, clen, pm):
     single definition is what keeps the two schedules trivially
     byte-identical."""
     logits, row_cache = _prefill_row_with_prefix(
-        _fwd(pm), params, cfg, row_k, row_v, done, chunk
+        _fwd(pm), params, cfg, KVCache(k=row_k, v=row_v), done, chunk
     )
     last = jnp.take_along_axis(
         logits, jnp.maximum(clen - 1, 0)[None, None, None], axis=1
@@ -852,7 +858,8 @@ def admit_row_with_prefix_paged(
     cache, only the suffix prefills, then the pages scatter into the pool.
     Returns (cache', tok, logprob)."""
     logits, row_cache = _prefill_row_with_prefix(
-        _fwd(pm), params, cfg, prefix_k, prefix_v, prefix_len, chunk
+        _fwd(pm), params, cfg, KVCache(k=prefix_k, v=prefix_v), prefix_len,
+        chunk,
     )
     return _paged_splice(
         cache, page_list, row_cache, logits, clen, rng, temperature, top_k,
@@ -895,16 +902,17 @@ def admit_row_auto_paged(
     reads the pool BEFORE the splice updates it, all inside one donated
     program (an int8 pool dequantizes the gathered run to row_dtype — the
     suffix continues from the same values decode attends to).
-    Returns (cache', tok, logprob)."""
-    row_k, row_v = kv_cache.gather_row(cache, read_list)
-    logits, row_cache = _prefill_row_with_prefix(
-        _fwd(pm), params, cfg, row_k, row_v,
-        prefix_len, chunk,
+    Returns (cache', tok, logprob), and for a hybrid-family model the
+    suffix's expert counts."""
+    logits, row_cache, *moe = _prefill_row_with_prefix(
+        _fwd(pm), params, cfg,
+        kv_cache.row_cache_of(cache, *kv_cache.gather_row(cache, read_list)),
+        prefix_len, chunk, clen,
     )
-    return _paged_splice(
+    return (*_paged_splice(
         cache, write_list, row_cache, logits, clen, rng, temperature, top_k,
         top_p, temp_req, topp_req, topk_req, mask_req, pm=pm,
-    )
+    ), *moe)
 
 
 def _decode_steps(
@@ -945,6 +953,15 @@ def _decode_steps(
                 **state,
             )
             moe = aux[0] if aux else None
+            if cfg.kv_lora_rank:
+                # Sixth (_note_moe), behind the fifth that only a chip's
+                # share of the experts counts: what the latent decode
+                # kernel read this step, the tokens each decoding row
+                # holds, its new one included.
+                moe = jnp.concatenate([
+                    moe, jnp.zeros((5 - moe.shape[0],), jnp.int32),
+                    jnp.sum(jnp.where(active, real_lens + 1, 0),
+                            dtype=jnp.int32)[None]])
         else:
             mask = (valid | (slots[None, :] == real_lens[:, None]))[:, None, None, :]
             logits, cache = _fwd(pm)(
@@ -1798,9 +1815,13 @@ class ContinuousBatcher:
                 dtype=jnp.dtype(kv_dtype) if kv_dtype else None,
                 slots=batch_slots,
             )
-            if cfg.family == "hybrid":
+            sizes = kv_cache.format_bytes(self.cache, cfg)
+            if "conv_state" in sizes:
                 METRICS.set_gauge("batcher.conv_state_bytes",
-                                  float(self.cache.conv.nbytes))
+                                  sizes["conv_state"])
+            if "latent_page" in sizes:
+                METRICS.set_gauge("batcher.latent_page_bytes",
+                                  sizes["latent_page"])
         else:
             self.cache = kv_cache.init_cache(
                 cfg, batch_slots, cache_len,
@@ -3263,13 +3284,14 @@ class ContinuousBatcher:
                         tc = min(_bucket(len(suffix)), self.s - cached_len)
                         chunk = np.full((tc,), self.pad_id, np.int32)
                         chunk[: len(suffix)] = suffix
-                        self.cache, tok, lp = admit_row_auto_paged(
+                        self.cache, tok, lp, *moe = admit_row_auto_paged(
                             self.params, self.cfg, self.cache,
                             jnp.asarray(page_list), jnp.asarray(write_list),
                             jnp.int32(cached_len), jnp.asarray(chunk),
                             jnp.int32(len(suffix)), self._split_rng(),
                             pm=self.pm, **self.sampling, **extra,
                         )
+                        self._note_moe(*moe)
                         row_valid = np.arange(self.valid.shape[1]) < total_len
                     elif self.paged:
                         if self.cfg.family == "hybrid":
@@ -4228,14 +4250,21 @@ class ContinuousBatcher:
     def _note_moe(self, stats=None) -> None:
         """Add a program's expert counts (layers.moe_dropless, real tokens
         only) to ``moe.*``: what a hybrid model's admission or decode chunk
-        handed out beside its tokens, None for any other model."""
+        handed out beside its tokens, None for any other model.  Four
+        counts; a fifth where the config holds a chip's share of the
+        experts; a decode chunk against latent pages hands out six
+        (_decode_steps)."""
         if stats is None:
             return
-        pairs, passes, touched, fullest = (int(x) for x in np.asarray(stats))
-        METRICS.inc("moe.routed_pairs", pairs)
-        METRICS.inc("moe.layer_passes", passes)
-        METRICS.inc("moe.experts_touched", touched)
-        METRICS.inc("moe.max_load_tokens", fullest)
+        counts = [int(x) for x in np.asarray(stats)]
+        METRICS.inc("moe.routed_pairs", counts[0])
+        METRICS.inc("moe.layer_passes", counts[1])
+        METRICS.inc("moe.experts_touched", counts[2])
+        METRICS.inc("moe.max_load_tokens", counts[3])
+        if len(counts) > 4:  # a chip's share of the experts
+            METRICS.inc("moe.held_pairs", counts[4])
+        if len(counts) > 5:  # a decode chunk against latent pages
+            METRICS.inc("mla.decode.resident_tokens", counts[5])
 
     def _fetch_chunk(self, out: tuple) -> tuple:
         """Host work's D2H for a dispatched-ahead chunk: tokens, logprobs,

@@ -143,7 +143,8 @@ def test_nothing_above_the_seam_tells_one_format_from_another():
     """runtime/, parallel/ and cluster/ hold a pool and hand it on; which
     format it is, and its scales, are models/kv_cache.py's to know."""
     banned = re.compile(
-        r"isinstance\([^)]*(KVCache|QuantKVCache|HybridCache)|\.k_scale")
+        r"isinstance\([^)]*(KVCache|QuantKVCache|HybridCache|LatentCache)"
+        r"|\.k_scale")
     found = [
         f"{path.relative_to(PACKAGE)}:{n}: {line.strip()}"
         for sub in ("runtime", "parallel", "cluster")

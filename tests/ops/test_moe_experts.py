@@ -86,3 +86,31 @@ def test_tiles_at_the_published_widths():
     assert moe_experts._tiles(2048, 3584, 64) is None
     assert moe_experts.row_tile(64, 32) == 16
     assert moe_experts.row_tile(8192, 32) == 256
+
+
+@pytest.mark.parametrize("mode", ["interpret", "fallback"])
+def test_a_share_that_no_pair_fell_on_gives_zeros(monkeypatch, mode):
+    """A chip's share of the experts (``of_experts``) in a step in which
+    every pair names an absent expert: no tile at all (the index maps must
+    not name block -1), and every pair's output is zeros; with some pairs
+    held, theirs are the held experts' and the rest zeros."""
+    monkeypatch.setenv("DLT_MOE_EXPERTS", mode)
+    e, d, f, s, k = 4, 128, 128, 8, 2
+    rng = np.random.RandomState(0)
+    w13, w2 = (
+        quant_lib.quantize(
+            jnp.asarray(rng.randn(1, e, kd, n), jnp.float32) * kd ** -0.5,
+            block_axis=-2)
+        for kd, n in ((d, 2 * f), (f, d)))
+    x = jnp.asarray(rng.randn(s, d), jnp.float32)
+    absent = jnp.asarray(rng.randint(e, 16, (s, k)), jnp.int32)
+    y = moe_experts.grouped_swiglu(x, absent, w13, w2, 0, of_experts=16)
+    assert y.shape == (s, k, d) and not np.asarray(y).any()
+    mixed = absent.at[::2, 0].set(jnp.arange(s // 2) % e).at[1, 1].set(-3)
+    y = moe_experts.grouped_swiglu(x, mixed, w13, w2, 0, of_experts=16)
+    whole = moe_experts.grouped_swiglu(
+        x, jnp.clip(mixed, 0, e - 1), w13, w2, 0)
+    held = np.asarray((mixed >= 0) & (mixed < e))
+    np.testing.assert_allclose(np.asarray(y)[held], np.asarray(whole)[held],
+                               atol=1e-5)
+    assert not np.asarray(y)[~held].any()

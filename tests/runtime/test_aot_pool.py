@@ -153,3 +153,58 @@ def test_no_layer_of_the_other_weights_is_copied_either(compiled_hybrid):
     instruction is shaped like one layer of any of them or of its scales
     (0.93 GB a step were sliced out before PR 29)."""
     assert compiled_hybrid["weight_shaped"] == []
+
+
+@pytest.fixture(scope="module")
+def compiled_latent():
+    """``decode_chunk`` of ax-k1-ep16 at its 13 layers and the cell's
+    shapes (64 slots, 2,176 latent pages; two runs, one scanned: some 10 s
+    to lower and compile)."""
+    from distributed_llms_tpu.models.presets import get_preset
+    from tools import aot_decode
+
+    try:
+        aot_decode.v5e_devices()
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DLT_QUANT_MATMUL", "kernel")
+        mp.setenv("DLT_RAGGED_DECODE", "kernel")
+        mp.setenv("DLT_MOE_EXPERTS", "kernel")
+        return aot_decode.analyse(
+            "decode_chunk", get_preset("ax-k1-ep16"), slots=64,
+            max_len=4096, pages=2176, page_size=BLK,
+        )
+
+
+def test_latent_pool_is_one_leaf_written_in_place(compiled_latent):
+    """The latent pool is ONE stack [13,2176,64,640] (no value pages, no
+    head axis): nothing but the step's in-place scatter of the new rows
+    produces an array of that shape or a layer of it, and the donated pool
+    is the output's alias."""
+    found = compiled_latent["pool_shaped"]
+    assert found and {e[0] for e in found} == {"scatter", "fusion:scatter"}
+    assert all("[13,2176,64,640]" in e[2] for e in found), found
+    pool_bytes = 13 * 2176 * BLK * 640 * 2
+    assert compiled_latent["alias_gb"] * 1e9 >= pool_bytes
+    # 0.43 GB of temporaries (the gathered rows of 704 x 7168 a layer, the
+    # absorbed queries), a fifth of the pool: no second pool among them.
+    assert compiled_latent["temp_gb"] * 1e9 < pool_bytes / 4
+
+
+def test_a_chips_share_of_the_experts_is_read_where_it_lies(compiled_latent):
+    """No instruction is shaped like the held experts' stacks
+    ([12,12,7168,4096], [12,12,2048,7168]), one layer's or one expert's;
+    weights (9.66 GB) and the pool (2.32 GB) are all the program is given,
+    with 3.3 GB of the chip to spare."""
+    assert compiled_latent["expert_shaped"] == []
+    assert 11.9 < compiled_latent["argument_gb"] < 12.05
+    assert compiled_latent["argument_gb"] + compiled_latent["temp_gb"] < 12.6
+
+
+def test_latent_attentions_and_the_shared_experts_weights_stay_stacked(
+        compiled_latent):
+    """W_qa, W_qb, W_o, the dense layer and the shared expert reach
+    ``quant_matmul`` as stacks read at an index: no instruction inside the
+    loops is shaped like one layer of any of them or of its scales."""
+    assert compiled_latent["weight_shaped"] == []

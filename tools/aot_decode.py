@@ -17,6 +17,7 @@ empty when the kernels read the stacks where they lie.
     python tools/aot_decode.py qwen2-7b --slots 16 --max-len 4096 --pages 512
     python tools/aot_decode.py pythia-6.9b --slots 8 --max-len 2048 --pages 96 \
         --programs decode_chunk,admit_row_paged --hlo-dir /tmp/hlo
+    python tools/aot_decode.py ax-k1-ep16 --slots 64 --pages 2176 --prompt-len 2048
 """
 from __future__ import annotations
 
@@ -183,6 +184,10 @@ def pool_shaped(hlo_text: str, cfg, pages: int, page_size: int,
     from distributed_llms_tpu.models.kv_cache import pages_are_private
     from distributed_llms_tpu.ops.decode_attn import pool_head_shape
 
+    if cfg.kv_lora_rank:  # latent pages: one leaf, no head axis
+        layer = f"{pages},{page_size},{cfg.latent_width}"
+        return shaped_like(
+            hlo_text, [f"[{layer}]", f"[{len(cfg.attn_layers)},{layer}]"])
     kvh, hd = pool_head_shape(cfg.num_kv_heads // shards, cfg.head_dim_,
                               fold_narrow=pages_are_private(cfg))
     layer = f"{pages},{page_size},{kvh},{hd}"
@@ -201,7 +206,7 @@ def expert_shaped(hlo_text: str, cfg) -> list:
     without experts."""
     if not cfg.num_experts or cfg.moe_capacity:
         return []
-    d, f, e = cfg.hidden_size, cfg.expert_size, cfg.num_experts
+    d, f, e = cfg.hidden_size, cfg.expert_size, cfg.held_experts
     shapes = []
     for one in (f"{d},{2 * f}", f"{f},{d}"):
         shapes += [f"[{e},{one}]",
@@ -380,8 +385,9 @@ def main() -> int:
                     help="compile for this many chips under mesh.model")
     ap.add_argument("--programs", default=None,
                     help="default: all four; the two without a prefix "
-                         "cache or a chunked prefill for a hybrid model, "
-                         "which refuses both")
+                         "cache or a chunked prefill for a model with "
+                         "convolution state, which refuses both; no "
+                         "chunked prefill for latent pages")
     ap.add_argument("--hlo-dir", default=None,
                     help="write each program's optimised HLO here")
     a = ap.parse_args()
@@ -394,7 +400,9 @@ def main() -> int:
     if a.layers:
         cfg = dataclasses.replace(cfg, num_layers=a.layers)
     programs = a.programs or ",".join(
-        PROGRAMS[:2] if cfg.family == "hybrid" else PROGRAMS)
+        PROGRAMS if cfg.family != "hybrid"
+        # (latent pages serve the prefix cache; convolution state none)
+        else PROGRAMS[:3] if cfg.kv_lora_rank else PROGRAMS[:2])
     for program in programs.split(","):
         try:
             r = analyse(

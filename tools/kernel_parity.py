@@ -161,7 +161,7 @@ def flash_parity() -> None:
 PAGED_ROWS, PAGED_SLOTS = 6, 19
 
 
-def _paged_case(blk: int, kvh: int, d: int, dtype):
+def _paged_case(blk: int, kvh: int, d: int, dtype, run: int | None = None):
     """(lengths, tables, pool size) of a paged leg.  Depths, with ``run``
     the pages the kernel walks at a time: 1 token; inside a run; a run's
     last slot; the next run's first key; every slot full; one page.  The
@@ -170,7 +170,7 @@ def _paged_case(blk: int, kvh: int, d: int, dtype):
     leg fills with NaNs (int8: NaN scales): never to be read."""
     b, pages = PAGED_ROWS, PAGED_SLOTS
     pool = b * pages + 1
-    run = decode_attn._run_pages(blk, kvh, d, dtype, pages)
+    run = run or decode_attn._run_pages(blk, kvh, d, dtype, pages)
     lengths = [1, 2 * blk + 44, run * blk, run * blk + 1, pages * blk, blk]
     rng = np.random.RandomState(0)
     tables = rng.permutation(pool - 1).reshape(b, pages)
@@ -222,12 +222,48 @@ def paged_parity(blk: int = 128, h: int = 8, kvh: int = 4,
           want, rtol=3e-2, atol=3e-2)
 
 
-def moe_parity() -> None:
+def mla_paged_parity(blk: int = 64, h: int = 64, latent: int = 512,
+                     rope: int = 64, layer: int = 2) -> None:
+    """The latent decode kernel at A.X-K1's shapes: 64 heads against page
+    rows of 640 lanes (latent 512, the shared rotated key 64, 64 zeros),
+    values the first 512 columns of the same rows, layer 2 of a 3-layer
+    stack.  The rows are :func:`_paged_case`'s (length 1, inside a run, on
+    a run's last slot, on the next run's first key, full, one page that
+    shares the pages of the row before it), junk ids name a NaN page."""
+    w = -(-(latent + rope) // 128) * 128
+    run = decode_attn._latent_run_pages(blk, w, jnp.bfloat16, PAGED_SLOTS)
+    ln, tables, junk, pool = _paged_case(blk, 1, w, jnp.bfloat16, run=run)
+    b, pages = tables.shape
+    kq, kr = jax.random.split(jax.random.PRNGKey(11))
+    lanes = (jnp.arange(w) < latent + rope).astype(jnp.bfloat16)
+    q = jax.random.normal(kq, (b, 1, h, w), jnp.bfloat16) * lanes
+    rows = jax.random.normal(kr, (b, pages * blk, w), jnp.bfloat16) * lanes
+    rows = rows.at[5, : 2 * blk].set(rows[4, : 2 * blk])
+    scale = 0.1 * (latent + rope) ** -0.5
+    got = jax.jit(lambda q, p, ln, t: decode_attn.mla_paged_decode_attention(
+        q, p, ln, t, latent=latent, scale=scale, layer=layer))(
+        q, _to_pool(rows, tables, pool, 0, np.nan, layer, 3.0), ln,
+        jnp.asarray(junk, jnp.int32))
+    s = jnp.einsum("bhw,bsw->bhs", q[:, 0], rows,
+                   preferred_element_type=jnp.float32) * scale
+    keep = jnp.arange(pages * blk)[None, :] < ln[:, None]
+    probs = jax.nn.softmax(jnp.where(keep[:, None, :], s, -jnp.inf), axis=-1)
+    want = jnp.einsum("bhs,bsc->bhc", probs.astype(jnp.bfloat16),
+                      rows[..., :latent])[:, None]
+    check(f"mla paged decode B{b} pool{pool} blk{blk} H{h} W{w} run{run} "
+          f"L3[{layer}]", got, want, rtol=3e-2, atol=3e-2)
+
+
+def moe_parity(e: int = 32, d: int = 2048, f: int = 1792, k: int = 4,
+               of_experts: int | None = None) -> None:
     """The expert kernel at lfm2-8b-a1b's widths (32 experts of 2048 x
     1792, int8 with blocks along the contracted axis), layer 1 of a
     2-layer stack: a decode step's 64 pairs (tiles of 16 rows, most
-    experts one tile, some none) and an admission's 2,048 (tiles of 128)."""
-    e, d, f, k = 32, 2048, 1792, 4
+    experts one tile, some none) and an admission's 2,048 (tiles of 128).
+    ``of_experts``: the stack holds ``e`` of that many (A.X-K1: 12 of 192
+    at [7168 x 4096] and [2048 x 7168]); ids are drawn over twice the held
+    ones, so half the pairs name an absent expert, leave the list and come
+    back as zeros."""
     key = jax.random.PRNGKey(5)
     key13, key2 = jax.random.split(key)
 
@@ -249,9 +285,10 @@ def moe_parity() -> None:
     for s in (16, 512):
         kx, kt = jax.random.split(jax.random.fold_in(key, s))
         x = jax.random.normal(kx, (s, d), jnp.bfloat16)
-        topi = jax.random.randint(kt, (s, k), 0, e, jnp.int32)
+        topi = jax.random.randint(
+            kt, (s, k), 0, 2 * e if of_experts else e, jnp.int32)
         got = jax.jit(lambda x, t, a, b: moe_experts.grouped_swiglu(
-            x, t, a, b, 1))(x, topi, w13, w2)
+            x, t, a, b, 1, of_experts=of_experts))(x, topi, w13, w2)
 
         def want_of(x, topi, w13, w2):
             # Every expert for every token, the chosen ones picked out.
@@ -261,12 +298,15 @@ def moe_parity() -> None:
             g = jnp.einsum("sd,edf->sef", xf, d13)
             y = jnp.einsum("sef,efd->sed",
                            jax.nn.silu(g[..., :f]) * g[..., f:], d2)
-            return jnp.take_along_axis(y, topi[:, :, None], axis=1)
+            y = jnp.take_along_axis(
+                y, jnp.minimum(topi, e - 1)[:, :, None], axis=1)
+            return jnp.where((topi < e)[:, :, None], y, 0.0)
 
         with jax.default_matmul_precision("highest"):
             want = jax.jit(want_of)(x, topi, w13, w2)
-        check(f"moe experts S{s} k{k} E{e} [{d}x{2 * f}] [{f}x{d}]", got,
-              want, rtol=3e-2, atol=3e-2)
+        held = f" of {of_experts}" if of_experts else ""
+        check(f"moe experts S{s} k{k} E{e}{held} [{d}x{2 * f}] [{f}x{d}]",
+              got, want, rtol=3e-2, atol=3e-2)
 
 
 def ragged_parity() -> None:
@@ -377,6 +417,11 @@ def main() -> int:
     paged_int8_parity(**SERVED_TP4, layer=1)
     paged_parity(**SERVED_H64, layer=2)
     moe_parity()
+    mla_paged_parity()
+    # A chip's share of the experts at A.X-K1's widths (off the chip the
+    # interpreter gets the same list at a tenth of the widths).
+    moe_parity(e=12, d=7168, f=2048, k=8, of_experts=192) if ON_TPU else \
+        moe_parity(e=12, d=1024, f=256, k=8, of_experts=192)
     # No leg may pass on another path than the one asked for: the dispatch
     # record (ops/dispatch.py) counts every trace by the path it took.
     took = {k[len("ops.dispatch."):]: int(v)
@@ -398,8 +443,10 @@ def main() -> int:
     # admission's pairs — 25 legs.  v7: quant_matmul's stacks read at an
     # index, at qwen2-7b's shapes — 29 legs.  v8: the 11 paged legs' rows
     # have the depths a walk by runs treats apart, share pages and carry
-    # junk ids (_paged_case).
-    print(f"kernel_parity: ALL PASS v8 ({mode}, backend={backend})")
+    # junk ids (_paged_case).  v9: the latent (MLA) decode kernel on those
+    # rows, and the expert kernel holding 12 of 192 experts at A.X-K1's
+    # widths with absent experts' pairs in the list — 32 legs.
+    print(f"kernel_parity: ALL PASS v9 ({mode}, backend={backend})")
     return 0
 
 

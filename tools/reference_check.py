@@ -2,14 +2,18 @@
 """Hold the served path of a hybrid model to its plain reference, in logits.
 
     python tools/reference_check.py --config lfm2-8b-a1b-int8     # the chip
+    python tools/reference_check.py --config ax-k1-int8-ep16      # the chip
     JAX_PLATFORMS=cpu python tools/reference_check.py --rehearsal # lfm2-tiny
+    (--config ax-k1-int8-ep16 --rehearsal: ax-k1-tiny)
 
 For each of the benchmark's four probe prompts the tool takes the logits
 the SERVED path produces, admission at the prompt's own bucket and then 8
 decode steps through the page pool and the convolution state, in a batch
 slot of a pool shaped as the cell serves it, and compares them with the
-reference's full forward (models/reference/lfm2_moe.py: float32 at
-``highest`` precision, no cache, no kernel) over the same tokens.  Both
+reference's full forward (models/reference/lfm2_moe.py, or axk1.py for a
+model with latent attention, which gets the chip's share of the experts
+the configuration holds: float32 at ``highest`` precision, no cache, no
+kernel) over the same tokens.  Both
 sides hold the same seed-0 int8 weights; the reference gets them
 dequantized a layer at a time and never holds more than one layer in
 float32.
@@ -68,7 +72,8 @@ from benchmark.run import PROBE_BYTES, PROBE_TOKENS, probe_prompt  # noqa: E402
 
 # Each limit lies between what the chip gave and what a wrong model gives
 # on the same probe (PERF.md, section 6, PR 28, has both readings).
-TOL = {
+TOLS = {
+  "lfm2-8b-a1b-int8": {
     # float32 activations: only the order of summation differs.  The chip
     # gave 7.7e-5 / 1.3e-5 at the most; three experts a token for four give
     # 3.35 / 0.56, int4 weights 5.69 / 0.95.
@@ -79,18 +84,57 @@ TOL = {
     # stacks on the int4 grid in the SERVED programs (the control at the
     # end of main): each limit lies between its two readings.
     "as_served": {"max_abs_logit": 1.96, "mean_abs_logit": 0.29},
+  },
+  "ax-k1-int8-ep16": {
+    # float32 activations, latent pages in float32: the order of summation
+    # (the absorbed form against expanded heads).  The chip gave 3.6e-4 /
+    # 5.7e-5 at the most (probe 1500); seven experts a token for eight give
+    # 0.93 / 0.098, no groups 0.91 / 0.11, int4 weights 2.79 / 0.50.
+    "mechanism": {"max_abs_logit": 2e-3, "mean_abs_logit": 2e-4},
+    # bfloat16 activations through 13 layers: the chip gave 0.390 / 0.044
+    # at the most over the four probes (0.334 / 0.044 on probe 32), and
+    # 0.564 / 0.081 on probe 32 with the held experts' stacks on the int4
+    # grid in the SERVED programs: each limit lies between its readings.
+    "as_served": {"max_abs_logit": 0.47, "mean_abs_logit": 0.062},
+  },
 }
 GOLDEN_FROM_REFERENCE = 0.025  # half of benchmark/run.py GOLDEN_TOL
 
 
 def reference_cfg(cfg) -> dict:
+    """What the plain references read, under their own (the published)
+    names: lfm2_moe's keys, and axk1's beside them."""
+    yarn = cfg.rope_scaling_type == "yarn" and cfg.rope_scaling_factor != 1.0
     return dict(
         norm_eps=cfg.norm_eps, rope_theta=cfg.rope_theta,
         layer_types=cfg.layer_types, num_dense_layers=cfg.num_dense_layers,
         num_experts_per_token=cfg.num_experts_per_token,
         norm_topk_prob=cfg.moe_norm_topk,
         routed_scaling_factor=cfg.moe_routed_scale,
+        num_heads=cfg.num_heads, kv_lora_rank=cfg.kv_lora_rank,
+        qk_nope_head_dim=cfg.qk_nope_head_dim,
+        qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
+        n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
+        rope_scaling=dict(
+            factor=cfg.rope_scaling_factor,
+            original_max_position_embeddings=cfg.rope_original_max_len,
+            beta_fast=cfg.yarn_beta_fast, beta_slow=cfg.yarn_beta_slow,
+            mscale=cfg.yarn_mscale, mscale_all_dim=cfg.yarn_mscale_all_dim,
+        ) if yarn else None,
     )
+
+
+class _ExpertsOneAtATime:
+    """An expert stack [E, K, N] (quantized or not) that hands the
+    reference expert ``e`` in float32 when it asks for it: A.X-K1's 12
+    held experts of a layer are 2.1 GB in float32 at once."""
+
+    def __init__(self, stack, floats):
+        self.stack, self.floats = stack, floats
+        self.shape = getattr(stack, "unpacked_shape", None) or stack.shape
+
+    def __getitem__(self, e):
+        return self.floats(jax.tree.map(lambda a: a[e], self.stack))
 
 
 @jax.jit
@@ -122,7 +166,7 @@ def reference_logits(params, cfg, tokens, int4=False, **changed):
     ``changed`` overrides keys of the reference's configuration."""
     from distributed_llms_tpu.checkpoint import quantize as quant_lib
     from distributed_llms_tpu.models import model as model_lib
-    from distributed_llms_tpu.models.reference import lfm2_moe
+    from distributed_llms_tpu.models.reference import axk1, lfm2_moe
 
     def floats(tree):
         def one(x):
@@ -135,10 +179,23 @@ def reference_logits(params, cfg, tokens, int4=False, **changed):
             one, tree,
             is_leaf=lambda x: isinstance(x, quant_lib.QuantizedTensor))
 
-    lazy = {"embed": params["embed"], "final_norm": params["final_norm"],
-            "layers": (floats(p) for p in model_lib.hybrid_layers(params, cfg))}
-    return lfm2_moe.forward(lazy, {**reference_cfg(cfg), **changed},
-                            jnp.asarray(tokens, jnp.int32))
+    def layer(p):
+        ex = p["mlp"].pop("experts", None) if cfg.kv_lora_rank else None
+        p = floats(p)
+        if ex is not None:
+            p["mlp"]["experts"] = {
+                k: _ExpertsOneAtATime(v, floats) for k, v in ex.items()}
+        return p
+
+    lazy = {k: v for k, v in params.items() if k != "blocks"}
+    lazy["layers"] = (layer(p) for p in model_lib.hybrid_layers(params, cfg))
+    ref_cfg = {**reference_cfg(cfg), **changed}
+    toks = jnp.asarray(tokens, jnp.int32)
+    if not cfg.kv_lora_rank:
+        return lfm2_moe.forward(lazy, ref_cfg, toks)
+    held = (None if cfg.experts_held is None
+            else (cfg.experts_offset, cfg.experts_held))
+    return axk1.forward(lazy, ref_cfg, toks, experts_held=held)
 
 
 def served_programs(cfg, cfg_decode):
@@ -191,8 +248,10 @@ def main() -> int:
         config = json.load(f)
     serve = dict(config["serve"])
     preset, probe_bytes = config["preset"], PROBE_BYTES
+    TOL = TOLS[a.config]
     if a.rehearsal:
-        preset, probe_bytes = "lfm2-tiny", (5, 9, 33, 60)
+        preset = "ax-k1-tiny" if preset.startswith("ax-k1") else "lfm2-tiny"
+        probe_bytes = (5, 9, 33, 60)
         serve.update(slots=4, max_len=128, page_size=8, paged_pages=40)
     cfg = get_preset(preset)
     tok = get_tokenizer(None)
@@ -208,10 +267,14 @@ def main() -> int:
     slots, blk = serve["slots"], serve["page_size"]
     ppr = serve["max_len"] // blk
     kernels = decode_attn._mode() != "fallback"
-    batcher = B.ContinuousBatcher(
-        cfg, params, tok, batch_slots=slots, max_len=serve["max_len"],
-        chunk_steps=serve["chunk_steps"], paged_pages=serve["paged_pages"],
-        page_size=blk)
+
+    def make_batcher():
+        # One a probe, dropped after it: its pool and the legs' own would
+        # not fit side by side beside A.X-K1's weights.
+        return B.ContinuousBatcher(
+            cfg, params, tok, batch_slots=slots, max_len=serve["max_len"],
+            chunk_steps=serve["chunk_steps"],
+            paged_pages=serve["paged_pages"], page_size=blk)
 
     def served_logits(dtype, ids, force=None):
         """[8, V] logits of the served path with ``dtype`` activations:
@@ -228,8 +291,12 @@ def main() -> int:
         page_list[:n_pages] = 1 + np.arange(n_pages)
         prompt = np.zeros((bucket,), np.int32)
         prompt[:plen] = ids
-        cache = kv_cache.make_pool(
-            c, serve["paged_pages"], blk, slots=slots)
+        # (float32 pages of A.X-K1's latent pool would be 4.6 GB beside
+        # 9.7 GB of weights: that leg's pool holds a quarter of the pages,
+        # forty times what a probe fills)
+        pages = serve["paged_pages"] // (
+            4 if dtype == "float32" and c.kv_lora_rank else 1)
+        cache = kv_cache.make_pool(c, pages, blk, slots=slots)
         cache, first, tok0, _ = admit(
             params, cache, jnp.asarray(page_list), jnp.asarray(prompt),
             jnp.int32(plen), jnp.int32(a.slot))
@@ -272,6 +339,7 @@ def main() -> int:
             with jax.default_matmul_precision(
                     "highest" if leg == "mechanism" else "default"):
                 served, toks = served_logits(dtype, ids)
+            print(f"  probe {n} {leg}: served", flush=True)
             t1 = time.time()
             ref = np.asarray(reference_logits(params, cfg, ids + toks[:-1]),
                              np.float32)[plen - 1: plen - 1 + PROBE_TOKENS]
@@ -299,6 +367,8 @@ def main() -> int:
                 "no_norm_topk": {"norm_topk_prob": False},
                 "int4_weights": {"int4": True},
             }
+            if cfg.moe_n_group > 1:
+                wrongs["no_groups"] = {"n_group": 1, "topk_group": 1}
             row["wrong"] = {
                 name: against(np.asarray(reference_logits(
                     params, cfg, ids + toks[:-1], **changed),
@@ -307,6 +377,7 @@ def main() -> int:
 
         # The batcher's own programs on the same probe, sent alone: their
         # tokens and logprobs are the ones the as-served logits give.
+        batcher = make_batcher()
         rid = batcher.submit(ids, max_new_tokens=PROBE_TOKENS)
         out = batcher.run()
         mine = row["as_served"]
@@ -316,6 +387,7 @@ def main() -> int:
         row["batcher_logprob_max_abs_diff"] = max(
             abs(x - y) for x, y in
             zip(row["batcher_logprobs"], mine["served_logprobs"]))
+        del batcher
         good = (row["mechanism"]["within_tolerances"]
                 and mine["within_tolerances"] and row["batcher_tokens_equal"]
                 and row["batcher_logprob_max_abs_diff"] < 1e-3)
