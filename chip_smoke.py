@@ -376,6 +376,9 @@ def check_dispatch(metrics: dict[str, float], chips: int,
         if chips > 1:
             check(disp.get(f"{op}_shard_map") == disp[f"{op}_kernel"],
                   f"every {op} kernel trace ran per shard")
+    if "quant_matmul" in kernels:
+        check(disp.get("quant_matmul_k_minor") == disp["quant_matmul_kernel"],
+              "every quant_matmul kernel trace took the lane-dense leg")
     bad = {k: v for k, v in disp.items()
            if k.endswith(("_fallback", "_interpret")) and v}
     check(not bad, f"no op on fallback or interpret ({bad or 'none'})")

@@ -17,16 +17,20 @@ full-precision weights back to HBM.
 
 A quantized weight is stored as the MATRIX the kernel reads, whatever axes
 the model gives it: the contracted axes flattened to K, the output axes to
-N, and the scales with the block index second-minor and K on the lanes
-([N/block, K]: lane-dense, where [K, N/block] would be padded up to 128
-lanes on the device).  The kernel takes a layer's tiles out of the stacked
+N, and both the weight ([N, K]) and its scales ([N/block, K]: the block
+index second-minor) with K on the lanes.  A block's scales are then a ROW
+over the block's 128 weight rows: the kernel multiplies a block by its row
+as it lies (a sublane broadcast), where a weight with N on its lanes wants
+a scale column broadcast over the lanes for every register of weights
+(PERF.md, PR 33).  The kernel takes a layer's tiles out of the stacked
 leaves as they lie; no served program re-lays out a weight or a scale.
 
-int4 pack layout: two values per byte along ``pack_axis`` — the matrix's
-rows (adjacent rows k, k+1 share a byte; low nibble = even row).
-Row-packing (rather than packing along the last axis) is what lets the TPU
-kernel unpack with a sublane interleave, which Mosaic supports for any
-width; scale blocks always run along N regardless.
+int4 pack layout: two values per byte along ``pack_axis`` — the stored
+matrix's rows, which are N (adjacent output columns n, n+1 of the model's
+weight share a byte; low nibble = even row).  Row-packing (rather than
+packing along the lanes) is what lets the TPU kernel unpack with a sublane
+interleave, which Mosaic supports for any width; scale blocks always run
+along N regardless.
 """
 
 from __future__ import annotations
@@ -45,21 +49,22 @@ import numpy as np
 class QuantizedTensor:
     """Blockwise-quantized weight, stored as a (stack of) matrix.
 
-    data: int8 [*lead, K, N]: the weight's ``k_axes`` contracted axes
-    flattened to K and its ``n_axes`` output axes to N.  For int4, two
-    values packed per byte along ``pack_axis`` (low nibble = even index,
-    high nibble = odd index along that axis).
-    scale: float32 [*lead, N/block, K] (``block_axis`` -1): the block index
-    second-minor, K on the lanes.
+    data: int8 [*lead, N, K] (``block_axis`` -1): the weight's ``n_axes``
+    output axes flattened to N and its ``k_axes`` contracted axes to K, K
+    on the lanes.  For int4, two values packed per byte along ``pack_axis``
+    (low nibble = even index, high nibble = odd index along that axis).
+    scale: float32 [*lead, N/block, K]: the block index second-minor, K on
+    the lanes, like the weight it belongs to.
     pack_axis: negative axis index of ``data`` the int4 pairs run along: -2
-    (rows, what the kernel unpacks) or -1 (columns: dequantized, never fed
-    to the kernel) — negative so a leading stacked-layer axis can be sliced
-    off (lax.scan) without invalidating it.  Unused for int8.
+    (the stored rows, N: what the kernel unpacks) or -1 (the lanes, K:
+    dequantized, never fed to the kernel) — negative so a leading
+    stacked-layer axis can be sliced off (lax.scan) without invalidating
+    it.  Unused for int8.
     orig_shape: the weight's shape when it was quantized.  Its last
     ``k_axes + n_axes`` entries say how K and N unflatten (wq [D, H, hd]:
     1 and 2; wo [H, hd, D]: 2 and 1); the leading ones go stale on
     stacked-layer slices and are never read.
-    layer: None, or the int32 index of the ONE layer of a stack [L, K, N]
+    layer: None, or the int32 index of the ONE layer of a stack [L, N, K]
     this leaf stands for (:meth:`at`): what a layer scan hands a matmul
     site in place of a slice, which would be a copy.
     """
@@ -69,9 +74,10 @@ class QuantizedTensor:
     bits: int
     orig_shape: tuple[int, ...]
     pack_axis: int = -2
-    # The axis the absmax blocks run along: -1 (the last one) for every 2-D
-    # weight; -2 for the expert stacks [E, K, N], whose scales [E, K/128, N]
-    # are then lane-dense for ops/moe_experts.py.  int8 only.
+    # The axis OF THE MODEL'S WEIGHT [.., K, N] the absmax blocks run along:
+    # -1 (N) for every 2-D weight, stored turned as above; -2 (K) for the
+    # expert stacks, stored as they are ([E, K, N], scales [E, K/128, N]:
+    # lane-dense for ops/moe_experts.py).  int8 only.
     block_axis: int = -1
     k_axes: int = 1
     n_axes: int = 1
@@ -94,9 +100,11 @@ class QuantizedTensor:
     def tail_shape(self) -> tuple[tuple[int, ...], tuple[int, ...]]:
         """(K axes, N axes) of the weight as the model has it."""
         if self.k_axes == self.n_axes == 1:
-            k, n = self.data.shape[-2:]
+            if self.block_axis == -2:
+                return tuple((d,) for d in self.data.shape[-2:])
+            n, k = self.data.shape[-2:]
             if self.bits == 4:
-                k, n = ((k, 2 * n) if self.pack_axis == -1 else (2 * k, n))
+                n, k = ((n, 2 * k) if self.pack_axis == -1 else (2 * n, k))
             return (k,), (n,)
         tail = tuple(self.orig_shape[-(self.k_axes + self.n_axes):])
         return tail[: self.k_axes], tail[self.k_axes:]
@@ -132,11 +140,11 @@ def quantize(
     if block_axis == -2:
         if bits != 8:
             raise ValueError("blocks along the contracted axis are int8 only")
+        # The turned weight [.., N, K] quantized along ITS last axis is
+        # stored turned again: as x lies.
         qt = quantize(jnp.swapaxes(x, -1, -2), bits, block)
-        return QuantizedTensor(
-            data=jnp.swapaxes(qt.data, -1, -2), scale=qt.scale, bits=bits,
-            orig_shape=orig_shape, pack_axis=pack_axis, block_axis=-2,
-        )
+        return dataclasses.replace(
+            qt, orig_shape=orig_shape, pack_axis=pack_axis, block_axis=-2)
     if pack_axis not in (-1, -2):
         raise ValueError(f"pack_axis must be -1 or -2, got {pack_axis}")
     block = min(block, x.shape[-1])
@@ -152,7 +160,8 @@ def quantize(
     absmax = jnp.max(jnp.abs(xb), axis=-1, keepdims=True)
     scale = jnp.where(absmax > 0, absmax / qmax, 1.0)
     q = jnp.clip(jnp.round(xb / scale), -qmax, qmax).astype(jnp.int8)
-    q = q.reshape(*lead, k, n)
+    # Laid down turned, K on the lanes: the values are the [K, N] matrix's.
+    q = jnp.swapaxes(q.reshape(*lead, k, n), -1, -2)  # [*lead, n, k]
     scale = jnp.swapaxes(scale[..., 0], -1, -2)  # [*lead, n_blocks, k]
     if bits == 4:
         a = q.ndim + pack_axis
@@ -180,12 +189,9 @@ def dequantize(qt: QuantizedTensor, dtype: Any = jnp.float32) -> jax.Array:
     per-layer slice of a stacked [L, ...] QuantizedTensor carries stale
     leading entries but self-consistent data/scale."""
     qt = qt.layer_slice()
-    if qt.block_axis == -2:
-        t = QuantizedTensor(
-            data=jnp.swapaxes(qt.data, -1, -2), scale=qt.scale, bits=qt.bits,
-            orig_shape=qt.orig_shape, pack_axis=qt.pack_axis,
-        )
-        return jnp.swapaxes(dequantize(t, dtype), -1, -2)
+    if qt.block_axis == -2:  # the turned weight's stored form: see quantize
+        turned = dataclasses.replace(qt, block_axis=-1)
+        return jnp.swapaxes(dequantize(turned, dtype), -1, -2)
     q = qt.data
     if qt.bits == 4:
         a = q.ndim + qt.pack_axis
@@ -195,11 +201,11 @@ def dequantize(qt: QuantizedTensor, dtype: Any = jnp.float32) -> jax.Array:
         shape[a] *= 2
         q = jnp.stack([lo, hi], axis=a + 1).reshape(shape)
     qf = q.astype(jnp.float32)
-    n = q.shape[-1]
+    n, k = q.shape[-2:]
     n_blocks = qt.scale.shape[-2]
-    qb = qf.reshape(*q.shape[:-1], n_blocks, n // n_blocks)
-    out = qb * jnp.swapaxes(qt.scale, -1, -2)[..., None]
-    return out.reshape(qt.unpacked_shape).astype(dtype)
+    qb = qf.reshape(*q.shape[:-2], n_blocks, n // n_blocks, k)
+    out = (qb * qt.scale[..., :, None, :]).reshape(q.shape)
+    return jnp.swapaxes(out, -1, -2).reshape(qt.unpacked_shape).astype(dtype)
 
 
 # (contracted axes, output axes) of the block leaves that are no plain

@@ -152,10 +152,13 @@ def save_shards(
                 "dtype": "quantized",
                 "bits": leaf.bits,
                 "pack_axis": leaf.pack_axis,
-                # Stored as a matrix [K, N] with scales [N/block, K]
-                # (checkpoint/quantize.py): how the axes of "shape" flatten.
+                # Stored as a matrix [N, K] with scales [N/block, K], K on
+                # the lanes of both (checkpoint/quantize.py; the expert
+                # stacks, block_axis -2, as [K, N] with [K/block, N]): how
+                # the axes of "shape" flatten.
                 "axes": [leaf.k_axes, leaf.n_axes],
                 "block_axis": leaf.block_axis,
+                "k_minor": leaf.block_axis == -1,
             }
         else:
             arr = np.asarray(leaf)
@@ -285,12 +288,14 @@ def load_shards(
         if meta["shard"] not in wanted:
             continue
         if meta["dtype"] == "quantized":
-            if "axes" not in meta:
+            if "axes" not in meta or (
+                    meta["block_axis"] == -1 and not meta.get("k_minor")):
                 raise ValueError(
-                    f"store {store_dir} holds {name} in the quantized layout "
-                    "of before PR 29 (weights with their model axes, scales "
-                    "[..., K, N/block]); this build reads matrices with "
-                    "scales [N/block, K]: re-quantize it with save_store"
+                    f"store {store_dir} holds {name} in a quantized layout "
+                    "of before PR 33 (weights with their model axes, or as "
+                    "matrices [K, N]); this build reads matrices [N, K] "
+                    "with scales [N/block, K], K on the lanes of both: "
+                    "re-quantize it with save_shards"
                 )
             qt = QuantizedTensor(
                 data=jnp.asarray(arrays[name + ".q"]),

@@ -534,6 +534,13 @@ METRIC_DOCS: dict[str, str] = {
                                          "model it equals them, and a value "
                                          "below says a call site slices a "
                                          "layer out (a copy a step)",
+    "ops.dispatch.quant_matmul.k_minor": "of quant_matmul's kernel (or "
+                                         "interpret) traces, those that "
+                                         "took the lane-dense leg: weight "
+                                         "[N, K] and scales [N/block, K] "
+                                         "both with K on the lanes, a "
+                                         "block's scales one row; since PR "
+                                         "33 it equals them",
     "ops.dispatch.paged_decode.run_pages": "pages of a row the paged decode "
                                            "kernel walks at a time, as its "
                                            "last trace worked it out from "

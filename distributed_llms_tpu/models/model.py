@@ -590,7 +590,7 @@ def neox_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, s
 
 def layer_of(blocks: Params, layer: jax.Array) -> Params:
     """Layer ``layer`` of stacked block leaves, for the body of a layer
-    scan.  A quantized stack of matrices [L, K, N] stays whole and carries
+    scan.  A quantized stack of matrices [L, N, K] stays whole and carries
     the index (``QuantizedTensor.at``): layers._contract hands both to the
     kernel, which reads the layer's tiles where they lie, where a slice
     would be a copy of the layer's weights in every step (a Pallas call

@@ -11,20 +11,24 @@ dequantizes tiles in VMEM (VPU), and feeds the MXU directly, never writing
 a dequantized copy back to HBM: 1 (int8) or 0.5 (int4) bytes a parameter
 and 4/block more for the scales (1.03 at blocks of 128).
 
-The operands are the STACKED leaves, every layer's weight [L, K, N] and
-scales [L, N/block, K] as checkpoint/quantize.py stores them, and the
-index of the layer to read, a scalar-prefetch operand the BlockSpecs'
-index maps take (as ops/moe_experts.py and the paged decode kernel do).
-A Pallas call takes each operand as a buffer of its own, so a layer sliced
-out of its stack is a copy: 11.4 GB read and written a decode step in
-qwen2-7b, a third of the step (PERF.md, PR 29).  A weight that is no stack
-goes in as a stack of one.  The scales are stored the way the kernel reads
-them, K on the lanes: a [K, N/block] array is padded to 128 lanes on the
-device, as many bytes as the weight it belongs to.  The kernel turns a
-scale tile's [rows, 512] pieces as it goes.  A weight tile is several
-[512, 512] pieces (:func:`_tiles`), dequantized and multiplied one after
-another in the order a grid of such tiles would take them: the result does
-not depend on the tile, only the number of grid steps does.
+The operands are the STACKED leaves, every layer's weight [L, N, K] and
+scales [L, N/block, K] as checkpoint/quantize.py stores them, both with K
+on the lanes, and the index of the layer to read, a scalar-prefetch operand
+the BlockSpecs' index maps take (as ops/moe_experts.py and the paged decode
+kernel do).  A Pallas call takes each operand as a buffer of its own, so a
+layer sliced out of its stack is a copy: 11.4 GB read and written a decode
+step in qwen2-7b, a third of the step (PERF.md, PR 29).  A weight that is no
+stack goes in as a stack of one.
+
+The dequantization is lane-dense (PERF.md, PR 33): a block of 128 weight
+rows (output columns) shares ONE row of scales, so a block is dequantized
+by one multiply with that row broadcast down the sublanes, and contracted
+with the activations on the last axis of both (``x . w^T``, the form
+ops/decode_attn.py runs against its key rows).  A weight with N on its
+lanes wants a column of scales broadcast over the lanes for every register
+of weights, and takes twice what its bytes take.  K is summed in runs of
+512 (:func:`_tiles`) whatever the tile, in float32, so a result does not
+depend on the tile.
 
 The reference's quantization design (snippets.md:675-833) dequantized to
 full precision before each use; there is no fused-kernel counterpart to
@@ -50,16 +54,15 @@ from jax.sharding import PartitionSpec as P
 from ..core.observability import METRICS
 from . import dispatch
 
-# One pass of the MXU's accumulation: the kernel adds up K in runs of this
-# many rows (the first candidate that divides K; grids must tile exactly —
-# no masking on the K/N axes), and a row of columns this wide at a time.
+# The kernel adds up K in runs of this many lanes, in float32 (the first
+# candidate that divides K; grids tile exactly, nothing is masked).
 _BK_CANDIDATES = (512, 256, 128)
-_BN_CANDIDATES = (512, 256, 128)
 _BM_MAX = 256
-# One int8 weight tile, double-buffered: several runs of rows or several
-# rows of columns, so that a decode step's matmul is tens of grid steps and
-# not hundreds (0.5 us a step is what a [512, 512] tile's bytes take).
+# One int8 weight tile, double-buffered: whole rows of K where a block of
+# 128 of them fits, so that a decode step's matmul is tens of grid steps of
+# one run of bytes each (0.5 us a step is what 512 KB take).
 _TILE_BYTES = 2 * 1024 * 1024
+_NT = (((1,), (1,)), ((), ()))  # x [M, K] . w [N, K]^T
 
 
 def _pick(n: int, candidates: tuple[int, ...]) -> int | None:
@@ -70,37 +73,43 @@ def _pick(n: int, candidates: tuple[int, ...]) -> int | None:
 
 
 def _tiles(k: int, n: int, block: int, bits: int = 8
-           ) -> tuple[int, int, int, int] | None:
-    """(bk, bn, ck, cn) of the weight tile [bk, bn] and of the [ck, cn]
-    pieces the kernel dequantizes and multiplies one after another, or
-    None if the shape cannot be tiled.  The pieces are what the candidates
-    give (K is summed in runs of ck whatever the tile, so a result does
-    not depend on the tile); the tile is the largest of whole pieces
-    within ``_TILE_BYTES`` whose scale rows the kernel can address: bn
-    either the whole of N, or a multiple of 8 blocks, or a piece that
-    divides 8 blocks (the float32 sublane tile of the stored
-    [N/block, K] scales).  A packed int4 tile is one piece: its unpacking
-    unrolled sixteen times takes the compiler a minute a kernel."""
-    ck, cn = _pick(k, _BK_CANDIDATES), _pick(n, _BN_CANDIDATES)
-    if ck is None or cn is None or block % 128 or cn % block:
+           ) -> tuple[int, int, int] | None:
+    """(bn, bk, ck) of the weight tile [bn, bk] of the stored [N, K] and of
+    the run of K the kernel sums at a time, or None if the shape cannot be
+    tiled.  K is summed in runs of ck whatever the tile, so a result does
+    not depend on the tile.  A tile is whole blocks of rows, dividing N,
+    whose scale rows the kernel can address: the whole of N/block, or a
+    multiple of 8 of them, or a divisor of 8 (the float32 sublane tile of
+    the stored [N/block, K] scales, which 8 // rows tiles then share).
+    Whole rows of K come first, as many blocks as fit ``_TILE_BYTES``: a
+    tile is then one run of bytes and the activations stay where they are
+    from one tile to the next.  Where not one block of whole rows fits
+    (K of 18,432 and more), the most rows that do, so that the activations
+    pass as few times as can be: every row's runs of 512 for qwen2's w_down
+    ([3584, 512] of [3584, 18944]), [1024, 2048] for A.X-K1's ([7168,
+    18432]); a few rows of half a K each ([128, 9216]) took 1.4 to 2.5
+    times as long (PERF.md, PR 33).  A packed int4 tile is one run of at
+    most four blocks: its unpacking unrolled over a whole tile takes the
+    compiler 5 s a kernel, not half of one."""
+    ck = _pick(k, _BK_CANDIDATES)
+    if ck is None or block % 128 or n % block:
         return None
-    best = (ck, cn)
+    nb = n // block
     if bits == 4:
-        return (ck, cn, ck, cn)
-    for bk in range(ck, k + 1, ck):
-        for bn in range(cn, n + 1, cn):
-            rows = bn // block
-            if (k % bk or n % bn or bk * bn > _TILE_BYTES
-                    or not (bn == n or rows % 8 == 0 or bn == cn)):
-                continue
-            if (bk * bn, bn) > (best[0] * best[1], best[1]):
-                best = (bk, bn)
-    return (*best, ck, cn)
+        return (max(per for per in (4, 2, 1) if nb % per == 0) * block, ck, ck)
+    pers = [per for per in range(nb, 0, -1) if nb % per == 0
+            and (per == nb or per % 8 == 0 or 8 % per == 0)]
+    part = [bk for bk in range(k - ck, 0, -ck) if k % bk == 0]
+    for per, bk in ([(per, k) for per in pers]
+                    + [(per, bk) for per in pers for bk in part]):
+        if per * block * bk <= _TILE_BYTES:
+            return (per * block, bk, ck)
+    return None
 
 
 def _unpack_int4_rows(q: jax.Array) -> jax.Array:
-    """[Kp, N] int32 packed nibbles -> [2*Kp, N] int32 values.  Low nibble =
-    even K-row, high = odd (quantize() packs along the reduction axis):
+    """[Np, K] int32 packed nibbles -> [2*Np, K] int32 values.  Low nibble =
+    even stored row, high = odd (quantize() packs down the stored rows, N):
     sign-extend via int32 shifts, then a sublane interleave, which Mosaic
     supports at any lane width.  Shared by the kernel and its flat-dequant
     fallback so the two layouts cannot diverge."""
@@ -110,7 +119,7 @@ def _unpack_int4_rows(q: jax.Array) -> jax.Array:
 
 
 def _kernel(ly_ref, x_ref, q_ref, s_ref, o_ref, acc_ref, *, bits, block, ck,
-            cn, nk, out_dtype):
+            share, nk, out_dtype):
     del ly_ref  # read by the index maps only
     j, k = pl.program_id(1), pl.program_id(2)
 
@@ -118,35 +127,22 @@ def _kernel(ly_ref, x_ref, q_ref, s_ref, o_ref, acc_ref, *, bits, block, ck,
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    bk, bn = x_ref.shape[1], o_ref.shape[1]
-    ckp = ck // 2 if bits == 4 else ck
-    rows, per = s_ref.shape[0], cn // block  # scale rows: held, a piece's
-    for r in range(bk // ck):
-        # [rows, ck] of the stored [N/block, K] scales, K on the lanes.  A
-        # block shorter than its 8 rows holds 8 // per j-tiles' scales:
-        # bring this tile's to the front.  Then turned, so that a block's
-        # scales run down the weight piece's rows.
-        sr = s_ref[:, r * ck:(r + 1) * ck]
-        if bn // block < rows:
-            held = sr
-            for g in range(1, rows // per):
-                sr = jnp.where(j % (rows // per) == g,
-                               pltpu.roll(held, rows - g * per, 0), sr)
-        st = sr.T  # [ck, rows]
-        for c in range(bn // cn):
-            q = q_ref[r * ckp:(r + 1) * ckp, c * cn:(c + 1) * cn]
-            q = q.astype(jnp.int32)  # [ck, cn] int8, or [ck//2, cn] int4
-            if bits == 4:
-                q = _unpack_int4_rows(q)
-            qf = q.astype(jnp.float32)
-            w = jnp.concatenate(
-                [qf[:, b * block:(b + 1) * block]
-                 * st[:, c * per + b:c * per + b + 1] for b in range(per)],
-                axis=1,
-            ).astype(x_ref.dtype)
-            acc_ref[:, c * cn:(c + 1) * cn] += jnp.dot(
-                x_ref[:, r * ck:(r + 1) * ck], w,
-                preferred_element_type=jnp.float32)
+    bn, bk = o_ref.shape[1], x_ref.shape[1]
+    per, packed = bn // block, block // 2 if bits == 4 else block
+    # A block of scale rows longer than the tile's own serves ``share``
+    # j-tiles: this one's rows start at ``base``.
+    base = (j % share) * per if share > 1 else 0
+    for b in range(per):
+        q = q_ref[b * packed:(b + 1) * packed, :].astype(jnp.int32)
+        if bits == 4:
+            q = _unpack_int4_rows(q)
+        # [block, bk] weights times their [1, bk] row of scales, in float32
+        w = (q.astype(jnp.float32) * s_ref[pl.ds(base + b, 1), :]
+             ).astype(x_ref.dtype)
+        for r in range(bk // ck):
+            acc_ref[:, b * block:(b + 1) * block] += jax.lax.dot_general(
+                x_ref[:, r * ck:(r + 1) * ck], w[:, r * ck:(r + 1) * ck],
+                _NT, preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
     def _():
@@ -159,30 +155,30 @@ def _kernel(ly_ref, x_ref, q_ref, s_ref, o_ref, acc_ref, *, bits, block, ck,
 )
 def _quant_matmul_2d(
     x: jax.Array,  # [M, K] float (M padded to a multiple of bm by caller)
-    q: jax.Array,  # [L, K, N] int8, or [L, K//2, N] packed int4 (row-packed)
+    q: jax.Array,  # [L, N, K] int8, or [L, N//2, K] packed int4 (row-packed)
     s: jax.Array,  # [L, N // block, K] float32, as stored
     layer: jax.Array,  # [1] int32: the layer of the stack to read
     *,
     bits: int,
     bm: int,
-    tiles: tuple[int, int, int, int],  # :func:`_tiles`
+    tiles: tuple[int, int, int],  # :func:`_tiles`
     interpret: bool = False,
     vma: frozenset = frozenset(),  # varying manual axes inside shard_map
 ) -> jax.Array:
     m, k_dim = x.shape
-    n, nb = q.shape[2], s.shape[1]
+    nb = s.shape[1]
+    n = q.shape[1] * (2 if bits == 4 else 1)
     block = n // nb
-    bk, bn, ck, cn = tiles
+    bn, bk, ck = tiles
     grid = (m // bm, n // bn, k_dim // bk)
-    bkp = bk // 2 if bits == 4 else bk
     # Scale rows a block: the tile's own, or 8 that 8 // (its own) j-tiles
-    # share (a tile of one piece whose blocks do not fill the sublanes).
+    # share (a tile whose blocks do not fill the sublanes).
     rows = bn // block
     if bn != n and rows % 8:
         rows = 8
     share = rows // (bn // block)  # j-tiles a block of scale rows serves
     kernel = functools.partial(
-        _kernel, bits=bits, block=block, ck=ck, cn=cn, nk=grid[2],
+        _kernel, bits=bits, block=block, ck=ck, share=share, nk=grid[2],
         out_dtype=x.dtype,
     )
     return pl.pallas_call(
@@ -192,8 +188,8 @@ def _quant_matmul_2d(
             grid=grid,
             in_specs=[
                 pl.BlockSpec((bm, bk), lambda mi, j, k, ly: (mi, k)),
-                pl.BlockSpec((None, bkp, bn),
-                             lambda mi, j, k, ly: (ly[0], k, j)),
+                pl.BlockSpec((None, bn // 2 if bits == 4 else bn, bk),
+                             lambda mi, j, k, ly: (ly[0], j, k)),
                 pl.BlockSpec((None, rows, bk),
                              lambda mi, j, k, ly: (ly[0], j // share, k)),
             ],
@@ -202,52 +198,52 @@ def _quant_matmul_2d(
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype, vma=vma),
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=32 * 1024 * 1024),
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
         name="_quant_matmul_2d",  # the operation's name in a trace
     )(layer, x, q, s)
 
 
 def _dequant_flat(q2: jax.Array, s2: jax.Array, bits: int, dtype) -> jax.Array:
-    """Dequantize one layer's stored operands ([K(-packed), N] and
-    [N/block, K]) without the kernel — the local fallback when a (shard's)
-    shape is untileable.  Same math as checkpoint.quantize.dequantize."""
+    """Dequantize one layer's stored operands ([N(-packed), K] and
+    [N/block, K]) to [N, K] without the kernel — the local fallback when a
+    (shard's) shape is untileable.  Same math as
+    checkpoint.quantize.dequantize."""
     q = q2.astype(jnp.int32)
     if bits == 4:
         q = _unpack_int4_rows(q)
-    n = q.shape[1]
+    n, k = q.shape
     nb = s2.shape[0]
-    w = (
-        q.astype(jnp.float32).reshape(q.shape[0], nb, n // nb)
-        * s2.T[:, :, None]
-    ).reshape(q.shape[0], n)
-    return w.astype(dtype)
+    w = q.astype(jnp.float32).reshape(nb, n // nb, k) * s2[:, None, :]
+    return w.reshape(n, k).astype(dtype)
 
 
 def _qmm_flat(x2: jax.Array, q3: jax.Array, s3: jax.Array, layer: jax.Array,
               *, bits: int, interpret: bool) -> jax.Array:
-    """[M, K] @ dequant(layer ``layer`` [1] of [L, K(-packed), N]) with the
-    scales [L, N/block, K].  Shapes are the LOCAL ones (per shard, inside
-    shard_map): tile sizes and M padding derive from them; untileable
-    shapes take the dequant+matmul fallback on the layer's slice, so this
-    is total over any shard."""
+    """[M, K] @ dequant(layer ``layer`` [1] of [L, N(-packed), K])^T with
+    the scales [L, N/block, K].  Shapes are the LOCAL ones (per shard,
+    inside shard_map): tile sizes and M padding derive from them;
+    untileable shapes take the dequant+matmul fallback on the layer's
+    slice, so this is total over any shard."""
     m, k = x2.shape
-    n, nb = q3.shape[2], s3.shape[1]
+    nb = s3.shape[1]
+    n = q3.shape[1] * (2 if bits == 4 else 1)
     tiles = _tiles(k, n, n // nb, bits)
-    tileable = tiles is not None and (bits == 8 or tiles[2] // 2 >= 8)
     # Inside a vma-checked shard_map (the pipeline stage body) operands
     # carry varying manual axes; the kernel's out_shape must declare the
     # same set.  The Pallas HLO *interpreter* (off-TPU test path) loses vma
     # on its internal dynamic_slices (same limitation as ops/flash.py), so
     # it runs the numerically-identical flat dequant there.
     vma = frozenset().union(*(jax.typeof(a).vma for a in (x2, q3, s3)))
-    if not tileable or (vma and interpret):
+    if tiles is None or (vma and interpret):
         dispatch.record("quant_matmul", "fallback", (m, k, n, bits))
-        return x2 @ _dequant_flat(q3[layer[0]], s3[layer[0]], bits, x2.dtype)
+        w = _dequant_flat(q3[layer[0]], s3[layer[0]], bits, x2.dtype)
+        return jax.lax.dot_general(x2, w, _NT)
     dispatch.record(
         "quant_matmul", "interpret" if interpret else "kernel",
         (m, k, n, bits),
     )
+    METRICS.inc("ops.dispatch.quant_matmul.k_minor")
     if q3.shape[0] > 1:  # a stack read by index, not a layer's slice
         METRICS.inc("ops.dispatch.quant_matmul.stacked")
     bm = min(_BM_MAX, max(16, -(-m // 16) * 16))
@@ -268,7 +264,8 @@ def _qmm_sharded(mesh, x2, q3, s3, layer, *, bits: int, interpret: bool,
     (wq/wk/wv, w_in/w_gate/w_up — embarrassingly parallel), "k" splits the
     contracted axis (wo, w_out/w_down — partial products, psum over
     'model').  The specs mirror parallel.specs.param_specs (the layer axis
-    of the stacks and the layer index unsharded), so placed weights enter
+    of the stacks and the layer index unsharded; the weight [L, N, K] and
+    its scales [L, N/block, K] split alike), so placed weights enter
     without a reshard: the split axis must divide into ``whole`` slices of
     the weight's first such axis (heads, for the attention weights), whole
     rows or columns and whole scale blocks, or it stays replicated
@@ -277,18 +274,17 @@ def _qmm_sharded(mesh, x2, q3, s3, layer, *, bits: int, interpret: bool,
     m_ax = dispatch.axis(mesh, "data", batch)
     n_ax = k_ax = None
     if shard == "n":
-        n_ax = dispatch.axis(mesh, "model", whole, q3.shape[2], s3.shape[1])
+        n_ax = dispatch.axis(mesh, "model", whole, q3.shape[1], s3.shape[1])
     elif shard == "k":
-        k_ax = dispatch.axis(mesh, "model", whole, q3.shape[1], s3.shape[2])
+        k_ax = dispatch.axis(mesh, "model", whole, q3.shape[2], s3.shape[2])
 
     def body(x2, q3, s3, layer):
         y = _qmm_flat(x2, q3, s3, layer, bits=bits, interpret=interpret)
         return jax.lax.psum(y, k_ax) if k_ax else y
 
+    w_spec = P(None, n_ax, k_ax)
     return dispatch.per_shard(
-        body, mesh,
-        (P(m_ax, k_ax), P(None, k_ax, n_ax), P(None, n_ax, k_ax), P(None)),
-        P(m_ax, n_ax),
+        body, mesh, (P(m_ax, k_ax), w_spec, w_spec, P(None)), P(m_ax, n_ax),
     )(x2, q3, s3, layer)
 
 
@@ -314,8 +310,8 @@ def quant_contract(
     x2 = x.reshape(-1, k)
 
     mode = "interpret" if interpret else dispatch.kernel_mode("DLT_QUANT_MATMUL")
-    # The kernel reads matrices [K, N] with blocks along N, int4 pairs down
-    # the rows, one at a time or out of a stack [L, K, N] by index.
+    # The kernel reads matrices [N, K] with blocks of rows, int4 pairs down
+    # the rows, one at a time or out of a stack [L, N, K] by index.
     stack = qt.data.ndim == (2 if qt.layer is None else 3)
     if (mode != "fallback" and stack and qt.block_axis == -1
             and k_lead == len(k_shape) and (qt.bits == 8 or qt.pack_axis == -2)):

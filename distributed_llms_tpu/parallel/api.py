@@ -16,6 +16,7 @@ behind a single ``forward`` with the same signature family as
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any
 
@@ -66,15 +67,16 @@ def quantized_layout(qt, spec: P, mesh: Mesh, path: str) -> tuple[P, P, int]:
     weight's PartitionSpec: (the spec for data, the spec for scale, the
     scale refinement factor).
 
-    The leaf is stored as a matrix [*lead, K, N] (checkpoint/quantize.py):
-    its flattened contracted axes shard as the weight's first contracted
-    axis does, its flattened output axes as the first output axis (whole
-    heads, for the attention weights; for int4 the rows hold adjacent-row
-    pairs, so a contiguous shard of packed rows unpacks to the same
-    contiguous rows — exact).  scale [*lead, N/block, K] takes the two
-    names the other way round; when the spec shards N, scales are refined
-    (each block's scale repeated k times = block size / k — numerically
-    identical) until shard boundaries land on block boundaries.
+    The leaf is stored as a matrix [*lead, N, K] (checkpoint/quantize.py;
+    the expert stacks [*lead, K, N]): its flattened contracted axes shard as
+    the weight's first contracted axis does, its flattened output axes as
+    the first output axis (whole heads, for the attention weights; for int4
+    the stored rows hold adjacent-row pairs, so a contiguous shard of
+    packed rows unpacks to the same contiguous rows — exact).  scale
+    [*lead, N/block, K] takes the same names as the weight it lies beside;
+    when the spec shards N, scales are refined (each block's scale repeated
+    k times = block size / k — numerically identical) until shard
+    boundaries land on block boundaries.
     Un-shardable layouts replicate the leaf, loudly.
     """
     from ..core.observability import get_logger
@@ -94,29 +96,29 @@ def quantized_layout(qt, spec: P, mesh: Mesh, path: str) -> tuple[P, P, int]:
     n_names = s[n_lead + qt.k_axes:]
     if any(k_names[1:]) or any(n_names[1:]):
         return replicate("an inner axis of a flattened group is sharded")
-    flat = (*s[:n_lead], k_names[0], n_names[0])
+    experts = qt.block_axis == -2  # stored [.., K, N], scale [.., K/128, N]
+    flat = (*s[:n_lead], *((k_names[0], n_names[0]) if experts
+                           else (n_names[0], k_names[0])))
     # Divisibility of every sharded data axis (jax would raise; we want the
     # replicate fallback instead).
     for ax, name in enumerate(flat):
         if _axis_sz(mesh, name) > 1 and data_shape[ax] % _axis_sz(mesh, name):
             return replicate(f"data axis {ax} ({data_shape[ax]}) % shards")
-    if qt.block_axis == -2:  # expert stacks: scale [.., K/128, N]
+    if experts:
         return P(*flat), P(*flat), 1
-    tp_n = _axis_sz(mesh, flat[-1])
-    if tp_n > 1 and qt.bits == 4 and qt.pack_axis == -1:
+    if _axis_sz(mesh, flat[-1]) > 1 and qt.bits == 4 and qt.pack_axis == -1:
         return replicate("spec shards the int4 pack axis at the last dim")
+    tp_n = _axis_sz(mesh, flat[-2])
     repeat = 1
     if tp_n > 1:
-        dim = data_shape[-1]  # N is never int4-packed here
+        dim = math.prod(qt.tail_shape[1])  # N, unpacked
         block = dim // qt.scale.shape[-2]
         per_shard = dim // tp_n
         if per_shard % block:
             # Refine: new block g divides both the old block and the shard
             # width, so each shard holds whole (finer) blocks.
-            import math
-
             repeat = block // math.gcd(block, per_shard)
-    return P(*flat), P(*flat[:-2], flat[-1], flat[-2]), repeat
+    return P(*flat), P(*flat), repeat
 
 
 def _place_quantized(leaf, spec: P, mesh: Mesh, path: str):

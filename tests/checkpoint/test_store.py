@@ -30,7 +30,7 @@ def test_int4_pack_unpack_exact():
     qt = q.quantize(jnp.asarray(vals * 0.5), bits=4, block=32)
     back = np.asarray(q.dequantize(qt))
     assert np.allclose(back / 0.5, vals, atol=1e-5)
-    assert qt.data.shape == (4, 32)  # packed pairs along the reduction axis
+    assert qt.data.shape == (16, 8)  # [N, K], pairs down its rows
 
 
 def test_quantize_tree_policy():
@@ -64,6 +64,69 @@ def test_store_roundtrip(tmp_path, quantization):
         else:
             tol = 0.02 if quantization == "int8" else 0.35
             assert np.abs(a - b).max() <= max(tol * np.abs(a).max(), 1e-6), name
+
+
+def _parents_values(w, k_axes, n_axes, block=128):
+    """Blockwise absmax int8 along the last axis of the model's weight, as
+    every build since PR 21 computed it: (dequantized weight, q as the
+    [.., K, N] matrix, scales [.., K, N/block])."""
+    w = np.asarray(w, np.float32)
+    tail = k_axes + n_axes
+    lead = w.shape[: w.ndim - tail]
+    k = int(np.prod(w.shape[w.ndim - tail: w.ndim - n_axes]))
+    n = int(np.prod(w.shape[w.ndim - n_axes:]))
+    block = min(block, w.shape[-1])
+    wb = w.reshape(*lead, k, n // block, block)
+    absmax = np.abs(wb).max(axis=-1, keepdims=True)
+    scale = np.where(absmax > 0, absmax / np.float32(127.0),
+                     np.float32(1.0)).astype(np.float32)
+    qv = np.clip(np.round(wb / scale), -127, 127).astype(np.float32)
+    return ((qv * scale).reshape(w.shape), qv.reshape(*lead, k, n),
+            scale[..., 0])
+
+
+@pytest.mark.parametrize("shape,k_axes,n_axes", [
+    ((2, 256, 384), 1, 1), ((2, 256, 2, 128), 1, 2), ((2, 2, 128, 256), 2, 1),
+])
+def test_quantize_roundtrip_keeps_the_parents_values(shape, k_axes, n_axes):
+    """The leaf lies turned ([.., N, K] beside [.., N/block, K]) and holds
+    the VALUES it always held: every q, every scale and every dequantized
+    weight equal to the [K, N] form's, whichever axes the model gives."""
+    w = jax.random.normal(jax.random.key(7), shape, jnp.float32)
+    qt = q.quantize(w, bits=8, k_axes=k_axes, n_axes=n_axes)
+    want, q_kn, scale_kn = _parents_values(w, k_axes, n_axes)
+    assert qt.data.shape == (2, *q_kn.shape[:0:-1])
+    np.testing.assert_array_equal(
+        np.asarray(qt.data), np.swapaxes(q_kn, -1, -2).astype(np.int8))
+    np.testing.assert_array_equal(
+        np.asarray(qt.scale), np.swapaxes(scale_kn, -1, -2))
+    back = q.dequantize(qt)
+    assert back.shape == shape
+    np.testing.assert_array_equal(np.asarray(back), want)
+
+
+@pytest.mark.parametrize("strip", [("axes", "k_minor"), ("k_minor",)])
+def test_store_of_an_older_layout_is_refused(tmp_path, strip):
+    """A store written before the quantized leaves lay [N, K] (PRs 29-32:
+    matrices [K, N]; before: the model's axes) is refused with a message
+    that says what to do, not read as if it were turned."""
+    import json
+
+    cfg = presets.get_preset("llama-tiny")
+    params = model.init_params(jax.random.key(0), cfg)
+    store.save_shards(params, str(tmp_path), num_shards=1, model_config=cfg,
+                      quantization="int8")
+    assert store.load_shards(str(tmp_path))  # as written: read
+    path = tmp_path / store.MANIFEST
+    manifest = json.loads(path.read_text())
+    for meta in manifest["params"].values():
+        if meta["dtype"] == "quantized":
+            assert meta["k_minor"] is True
+            for key in strip:
+                del meta[key]
+    path.write_text(json.dumps(manifest))
+    with pytest.raises(ValueError, match="before PR 33.*save_shards"):
+        store.load_shards(str(tmp_path))
 
 
 def test_store_partial_load(tmp_path):

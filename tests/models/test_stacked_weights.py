@@ -124,7 +124,7 @@ def test_fallback_reads_the_layer_out_of_the_stack(monkeypatch, dispatched):
 
 def test_layer_of_slices_everything_but_matrix_stacks():
     """Norms, biases, float weights and the capacity path's expert stacks
-    [L, E, D, F] are sliced; a quantized [L, K, N] stays whole and carries
+    [L, E, D, F] are sliced; a quantized [L, N, K] stays whole and carries
     the index."""
     params = model_lib.init_params_quantized(jax.random.key(0), DENSE, 8)
     p = model_lib.layer_of(params["blocks"], jnp.int32(1))

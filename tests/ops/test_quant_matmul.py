@@ -64,8 +64,8 @@ def test_parity_2d_klead1(bits, m, kernel_calls):
 
 @pytest.mark.parametrize("bits", [8, 4])
 def test_parity_qkv_layout(bits, kernel_calls):
-    """wq/wk/wv layout [D, H, hd]: stored as the matrix [D, H * hd] (int4
-    pairs down its rows, the reduction axis); output restores the [H, hd]
+    """wq/wk/wv layout [D, H, hd]: stored as the matrix [H * hd, D] (int4
+    pairs down its rows, the output columns); output restores the [H, hd]
     tail."""
     qt = _make((256, 2, 128), bits, n_axes=2)
     assert qt.data.shape == (256 // (1 if bits == 8 else 2), 256)
@@ -81,7 +81,7 @@ def test_parity_qkv_layout(bits, kernel_calls):
 @pytest.mark.parametrize("bits", [8, 4])
 def test_parity_wo_layout_klead2(bits, kernel_calls):
     """wo layout [H, hd, D] with k_lead=2: both leading axes contract, and
-    flatten to the matrix's rows; int4 pairs lie along hd, the last K axis."""
+    flatten to the stored matrix's lanes; int4 pairs lie down its rows, D."""
     qt = _make((2, 128, 256), bits, k_axes=2)
     x = jax.random.normal(jax.random.key(3), (4, 9, 2, 128), jnp.float32)
     got = qm.quant_contract(x, qt, 2, "bthk,hkd->btd", interpret=True)
@@ -129,9 +129,9 @@ def test_untileable_falls_back(kernel_calls, dispatched):
 
 
 def test_int4_wrong_pack_axis_falls_back(kernel_calls):
-    """int4 packed along a non-K axis cannot use the sublane unpack — must
-    fall back rather than miscompute."""
-    qt = _make((256, 256), 4, pack_axis=-1)  # packed along N, not K
+    """int4 packed along the stored matrix's lanes cannot use the sublane
+    unpack — must fall back rather than miscompute."""
+    qt = _make((256, 256), 4, pack_axis=-1)  # pairs along K, the lanes
     x = jax.random.normal(jax.random.key(7), (4, 256), jnp.float32)
     got = qm.quant_contract(x, qt, 1, "mk,kn->mn", interpret=True)
     want = _fallback(x, qt, "mk,kn->mn")
@@ -148,7 +148,8 @@ def test_env_interpret_mode(monkeypatch, kernel_calls, dispatched):
     got = qm.quant_contract(x, qt, 1, "mk,kn->mn")
     want = _fallback(x, qt, "mk,kn->mn")
     assert len(kernel_calls) == 1
-    assert dispatched() == {"quant_matmul.interpret": 1}
+    assert dispatched() == {"quant_matmul.interpret": 1,
+                            "quant_matmul.k_minor": 1}
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
@@ -176,7 +177,7 @@ def _stack(layers, k_lead, bits, shape):
 @pytest.mark.parametrize("m", [1, 16, 300])
 def test_stacked_parity(bits, k_lead, shape, x_tail, eq, m, kernel_calls,
                         dispatched):
-    """The stack [L, K, N] goes into the kernel whole and ``at(layer)``
+    """The stack [L, N, K] goes into the kernel whole and ``at(layer)``
     names the layer, traced inside a lax.scan as a layer scan has it: each
     layer's result equals dequantize + einsum on that layer's slice, and
     the kernel is handed the stack itself, never a slice."""
@@ -190,6 +191,7 @@ def test_stacked_parity(bits, k_lead, shape, x_tail, eq, m, kernel_calls,
     _, got = jax.lax.scan(body, None, jnp.arange(3, dtype=jnp.int32))
     assert len(kernel_calls) == 1  # one trace of the scan body
     assert dispatched() == {"quant_matmul.interpret": 1,
+                            "quant_matmul.k_minor": 1,
                             "quant_matmul.stacked": 1}
     want = jnp.einsum("l" + eq.replace(",", ",l").replace("->", "->l"),
                       jnp.broadcast_to(x, (3, *x.shape)), dequantize(qt))
@@ -211,7 +213,8 @@ def test_stack_of_one(bits, kernel_calls, dispatched):
     one = jax.tree.map(lambda a: a[0], qt)
     want = qm.quant_contract(x, one, 1, "mk,kn->mn", interpret=True)
     assert len(kernel_calls) == 2
-    assert dispatched() == {"quant_matmul.interpret": 2}
+    assert dispatched() == {"quant_matmul.interpret": 2,
+                            "quant_matmul.k_minor": 2}
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(_fallback(x, one, "mk,kn->mn")),
@@ -231,28 +234,100 @@ def test_stacked_fallback_reads_the_layer(monkeypatch, dispatched):
 
 
 def test_scales_are_stored_lane_dense():
-    """The stored scales have K on the last axis ([N/block, K]): the layout
-    the kernel's BlockSpec reads, so no served program re-lays them out."""
+    """The stored scales AND the stored weight have K on the last axis
+    ([N/block, K] beside [N, K]): the layout the kernel's BlockSpecs read,
+    a block's scales a row over its 128 weight rows, so no served program
+    re-lays either out and the kernel turns nothing."""
     qt = _make((3, 512, 384), 8)
-    assert qt.data.shape == (3, 512, 384) and qt.scale.shape == (3, 3, 512)
+    assert qt.data.shape == (3, 384, 512) and qt.scale.shape == (3, 3, 512)
     w = jax.random.normal(jax.random.key(0), (3, 512, 384), jnp.float32)
     absmax = jnp.max(jnp.abs(w.reshape(3, 512, 3, 128)), axis=-1)
     np.testing.assert_allclose(
         np.asarray(qt.scale), np.asarray(jnp.swapaxes(absmax / 127.0, 1, 2)),
         rtol=1e-6)
+    # the weight's values are the [K, N] matrix's, laid down turned
+    scale_kn = jnp.repeat(jnp.swapaxes(qt.scale, 1, 2), 128, axis=2)
+    np.testing.assert_array_equal(
+        np.asarray(qt.data),
+        np.asarray(jnp.swapaxes(jnp.round(w / scale_kn), 1, 2), np.int8))
+    # int4: the same matrix, pairs down its rows
+    assert _make((3, 512, 384), 4).data.shape == (3, 192, 512)
 
 
 @pytest.mark.parametrize("bits,shape", [(4, (512, 384)), (4, (512, 768)),
-                                        (4, (1024, 1536)), (8, (256, 640))])
+                                        (4, (1024, 1536)), (8, (2048, 1536)),
+                                        (8, (256, 640))])
 def test_scale_rows_shared_by_tiles_or_past_the_end(bits, shape, kernel_calls):
     """A tile whose own scale rows do not fill 8 sublanes reads a block of 8
-    that several j-tiles share (int4's one-piece tiles; [1024, 1536]: 12
-    rows in blocks of 8, the second half past the end), and a block may be
-    longer than the scales are (3 or 6 rows): each tile still finds its own
-    rows."""
+    that several j-tiles share (int4's tiles of 1, 2 and 4 blocks; int8
+    [2048, 1536]: tiles of 4 blocks, 12 rows in blocks of 8, the second half
+    past the end), and a block may be longer than the scales are (3 or 6
+    rows): each tile still finds its own rows.  [256, 640] is one tile, its
+    5 rows the whole of the scales."""
     qt = _make((2, *shape), bits, seed=17)
     x = jax.random.normal(jax.random.key(18), (5, shape[0]), jnp.float32)
     got = qm.quant_contract(x, qt.at(1), 1, "mk,kn->mn", interpret=True)
     want = x @ dequantize(qt)[1]
     assert len(kernel_calls) == 1
+    bn, _, _ = kernel_calls[0]["tiles"]
+    assert (bn // 128) == {384: 1, 768: 2, 1536: 4, 640: 5}[shape[1]]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
+
+
+def _parent_arithmetic(x, q_kn, scale, ck=512):
+    """What the kernel of PRs 29-32 computed from the [K, N] matrix: every
+    weight ``bf16(f32(q) * s)``, K summed in float32 in runs of ``ck``."""
+    k, n = q_kn.shape
+    nb = scale.shape[0]
+    w = (q_kn.astype(jnp.float32).reshape(k, nb, n // nb)
+         * scale.T[:, :, None]).reshape(k, n).astype(x.dtype)
+    acc = jnp.zeros((x.shape[0], n), jnp.float32)
+    for r in range(0, k, ck):
+        acc += jnp.dot(x[:, r:r + ck], w[r:r + ck],
+                       preferred_element_type=jnp.float32)
+    return w, acc.astype(x.dtype)
+
+
+# one block weight of each cell's configuration: qwen2-7b's wk, pythia-6.9b's
+# wq, lfm2-8b-a1b's wk, ax-k1's wq_a
+@pytest.mark.parametrize("k,n", [(3584, 512), (4096, 4096), (2048, 512),
+                                 (7168, 1536)])
+@pytest.mark.parametrize("m", [16, 256])
+def test_kernel_against_the_parents_arithmetic(k, n, m, kernel_calls):
+    """The lane-dense kernel on the turned leaf gives what the [K, N]
+    kernel gave: rows of x that pick one k each hand back the dequantized
+    weights themselves, equal bit for bit to ``bf16(f32(q) * s)``; for
+    random rows the outputs are equal, or one bf16 ulp apart where the
+    backend sums a run's partial products in another order."""
+    w = jax.random.normal(jax.random.key(k + n), (k, n), jnp.float32)
+    qt = quantize(w, bits=8, block=128)
+    q_kn = jnp.swapaxes(qt.data, 0, 1)
+    picks = jax.random.permutation(jax.random.key(m), k)[:m]
+    x_pick = jax.nn.one_hot(picks, k, dtype=jnp.bfloat16)
+    x_rand = jax.random.normal(jax.random.key(m + 1), (m, k), jnp.bfloat16)
+    w_ref, _ = _parent_arithmetic(x_pick, q_kn, qt.scale)
+    got_w = qm.quant_contract(x_pick, qt, 1, "mk,kn->mn", interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(got_w, np.float32), np.asarray(w_ref[picks], np.float32))
+    _, want = _parent_arithmetic(x_rand, q_kn, qt.scale)
+    got = qm.quant_contract(x_rand, qt, 1, "mk,kn->mn", interpret=True)
+    assert len(kernel_calls) == 2
+    g = np.asarray(got).view(np.int16).astype(np.int32)
+    r = np.asarray(want).view(np.int16).astype(np.int32)
+    assert np.max(np.abs(g - r)) <= 1, "more than one bf16 ulp apart"
+
+
+def test_k_minor_counts_the_kernels_traces(dispatched):
+    """``ops.dispatch.quant_matmul.k_minor`` counts the traces that took
+    the lane-dense leg: every kernel (or interpret) trace, no fallback."""
+    qt = _make((2, 256, 256), 8, seed=19)
+    x = jax.random.normal(jax.random.key(20), (4, 256), jnp.float32)
+    qm.quant_contract(x, qt.at(0), 1, "mk,kn->mn", interpret=True)
+    qm.quant_contract(x[:3], qt.at(1), 1, "mk,kn->mn", interpret=True)
+    assert dispatched() == {"quant_matmul.interpret": 2,
+                            "quant_matmul.k_minor": 2,
+                            "quant_matmul.stacked": 2}
+    bad = _make((100, 256), 8)  # untileable: the fallback counts nothing
+    qm.quant_contract(x[:, :100], bad, 1, "mk,kn->mn", interpret=True)
+    assert dispatched()["quant_matmul.k_minor"] == 2
+    assert dispatched()["quant_matmul.fallback"] == 1

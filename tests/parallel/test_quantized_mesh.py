@@ -44,8 +44,9 @@ def test_scale_refinement_is_exact(devices8):
     np.testing.assert_array_equal(
         np.asarray(quant_lib.dequantize(qt)), np.asarray(quant_lib.dequantize(placed))
     )
-    # data really is sharded over 'model'
-    assert placed.data.sharding.spec == P(None, "model")
+    # data [N, K] really is sharded over 'model', rows beside scale rows
+    assert placed.data.sharding.spec == P("model", None)
+    assert placed.scale.sharding.spec == P("model", None)
 
 
 def test_unshardable_leaf_replicates(devices8):
@@ -53,8 +54,8 @@ def test_unshardable_leaf_replicates(devices8):
     (loudly) instead of corrupting."""
     mesh = Mesh(np.array(devices8).reshape(8), ("model",))
     w = jax.random.normal(jax.random.key(0), (64, 256), jnp.float32)
-    qt = quant_lib.quantize(w, bits=4, block=128, pack_axis=-1)  # legacy layout
-    placed = api_lib._place_quantized(qt, P(None, "model"), mesh, "w")
+    qt = quant_lib.quantize(w, bits=4, block=128, pack_axis=-1)  # pairs along K
+    placed = api_lib._place_quantized(qt, P("model", None), mesh, "w")
     assert placed.data.sharding.spec == P()
     np.testing.assert_array_equal(
         np.asarray(quant_lib.dequantize(qt)), np.asarray(quant_lib.dequantize(placed))
@@ -78,7 +79,7 @@ def test_preset_weights_are_born_quantized_and_sharded(devices8):
         assert len(_qleaves(blocks)) == 7  # wq wk wv wo + the three MLP
         assert not _qleaves({k: v for k, v in e.params.items() if k != "blocks"})
     w_gate = eng.params["blocks"]["mlp"]["w_gate"]
-    assert w_gate.data.sharding.spec == P(None, None, "model")
+    assert w_gate.data.sharding.spec == P(None, "model", None)  # [L, N, K]
     # 176 columns over 4 shards = 44 a shard: the 16-wide blocks refine to 4.
     assert w_gate.scale.shape[-2] == 44
     assert w_gate.scale.sharding.spec == P(None, "model", None)

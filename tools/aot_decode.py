@@ -240,13 +240,15 @@ def weight_shapes(cfg, shards: int = 1) -> list:
         and q.data.ndim == 3]
     shapes = set()
     for q in leaves:
-        layers, k, n = q.data.shape
+        layers, n, k = q.data.shape  # stored [L, N, K], K on the lanes
         nb = q.scale.shape[1]
         for kd, nd in {(1, 1), (shards, 1), (1, shards)}:
             if k % kd or nb % nd:
                 continue
-            w = f"{k // kd},{n // nd}"
-            shapes |= {f"s8[{w}]", f"s8[{layers},{w}]"}
+            # as it lies and turned: a [K, N] temporary would be the copy
+            # PR 29 removed, back again
+            for w in (f"{n // nd},{k // kd}", f"{k // kd},{n // nd}"):
+                shapes |= {f"s8[{w}]", f"s8[{layers},{w}]"}
             if nb // nd > 1:  # one block a row is a vector like any other
                 sc = (f"{nb // nd},{k // kd}", f"{k // kd},{nb // nd}")
                 shapes |= {f"f32[{x}]" for x in sc}

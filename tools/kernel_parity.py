@@ -80,6 +80,16 @@ def check(name: str, got, want, rtol: float, atol: float) -> None:
     print(f"  PASS {name:40s} max|err|={err:.3e}")
 
 
+STACKED_LEGS = (
+    (8, ((3584,), 18944), 2), (8, ((18944,), 3584), 2),
+    (8, ((28, 128), 3584), 3), (4, ((4096,), 1024), 3),
+    (8, ((4096,), 16384), 2), (8, ((16384,), 4096), 2),
+    (8, ((2048,), 6144), 2), (8, ((7168,), 2048), 2),
+    (8, ((7168,), 1536), 2), (8, ((1536,), 12288), 2),
+    (8, ((8192,), 7168), 2),
+)
+
+
 def quant_parity() -> None:
     key = jax.random.PRNGKey(0)
     for bits in (8, 4):
@@ -94,13 +104,14 @@ def quant_parity() -> None:
             # signal; match the suite's bf16 tolerance.
             check(f"quant int{bits} [{m}x{k}]@[{k}x{n}]", got, want,
                   rtol=2e-2, atol=2e-2)
-    # The stacked form the layer scans hand over: every layer's weight in
-    # one operand, read at an index traced inside a scan, at qwen2-7b's
-    # shapes (tiles of seven row runs for w_gate, of seven column pieces
-    # for w_down; wo's two contracted axes) and as int4 (one piece a tile).
-    for bits, (k_shape, n), layers in (
-            (8, ((3584,), 18944), 2), (8, ((18944,), 3584), 2),
-            (8, ((28, 128), 3584), 3), (4, ((4096,), 1024), 3)):
+    # The stacked form the layer scans hand over: every layer's weight
+    # [L, N, K] in one operand, read at an index traced inside a scan, at
+    # qwen2-7b's shapes (tiles of four blocks of whole rows for w_gate, of
+    # every row's runs of 512 for w_down; wo's two contracted axes), as
+    # int4 (one block a tile), and at pythia-6.9b's (w_in, w_out: a block
+    # of whole rows a tile), lfm2-8b-a1b's (the convolution's in_proj, the
+    # dense FFN's w_down) and A.X-K1's (wq_a, wq_b, wo).
+    for bits, (k_shape, n), layers in STACKED_LEGS:
         kx, kw = jax.random.split(jax.random.fold_in(key, bits + n))
         x = jax.random.normal(kx, (16, *k_shape), jnp.bfloat16)
         k = int(np.prod(k_shape))
@@ -123,6 +134,51 @@ def quant_parity() -> None:
             dequantize(qt, jnp.float32).reshape(layers, k, n))
         check(f"quant int{bits} stacked L{layers} [16x{k}]@[{k}x{n}]", got,
               want, rtol=2e-2, atol=2e-2)
+
+
+def quant_parent_arithmetic(k: int = 3584, n: int = 18944) -> None:
+    """The lane-dense kernel on the turned leaf [N, K] against what the
+    kernel of PRs 29-32 computed from the matrix [K, N]: every weight
+    ``bf16(f32(q) * s)``, K summed in float32 in runs of 512.  Rows of x
+    that pick one k each hand back the dequantized weights themselves:
+    equal bit for bit.  Random rows: equal, or one bf16 ulp apart where the
+    sums of a run's partial products are taken in another order; the leg
+    prints which."""
+    kx, kw = jax.random.split(jax.random.PRNGKey(33))
+    w = jax.random.normal(kw, (k, n), jnp.float32) / np.sqrt(k)
+    qt = jax.jit(quantize)(w)
+    scale_kn = jnp.swapaxes(qt.scale, 0, 1)  # [K, N/128]
+    w_ref = (jnp.swapaxes(qt.data, 0, 1).astype(jnp.float32).reshape(
+        k, n // 128, 128) * scale_kn[:, :, None]).reshape(k, n).astype(
+            jnp.bfloat16)
+    run = jax.jit(lambda x, qt: quant_contract(x, qt, k_lead=1))
+
+    @jax.jit
+    def parent(x):
+        acc = jnp.zeros((x.shape[0], n), jnp.float32)
+        for r in range(0, k, 512):
+            acc += jnp.dot(x[:, r:r + 512], w_ref[r:r + 512],
+                           preferred_element_type=jnp.float32)
+        return acc.astype(x.dtype)
+
+    for m in (16, 256):
+        picks = jax.random.permutation(jax.random.fold_in(kx, m), k)[:m]
+        got_w = run(jax.nn.one_hot(picks, k, dtype=jnp.bfloat16), qt)
+        if not bool(jnp.all(got_w == w_ref[picks])):
+            raise AssertionError(
+                f"dequantized weights differ from bf16(f32(q) * s) at M={m}")
+        x = jax.random.normal(jax.random.fold_in(kx, m + 1), (m, k),
+                              jnp.bfloat16)
+        bits = [np.asarray(a).view(np.int16).astype(np.int32)
+                for a in (run(x, qt), parent(x))]
+        ulp = int(np.max(np.abs(bits[0] - bits[1])))
+        if ulp > 1:
+            raise AssertionError(f"outputs {ulp} bf16 ulp apart at M={m}")
+        said = ("equal" if ulp == 0 else
+                f"{int(np.sum(bits[0] != bits[1]))} of {m * n} one bf16 ulp "
+                "apart (summation order)")
+        print(f"  PASS quant int8 [{m}x{k}]@[{k}x{n}] weights bit-equal to "
+              f"the [K, N] form's; outputs {said}")
 
 
 def flash_parity() -> None:
@@ -400,6 +456,7 @@ def main() -> int:
         print(f"  WARNING: backend={backend} — running kernels in INTERPRET "
               "mode (validates this script, NOT Mosaic lowering).")
     quant_parity()
+    quant_parent_arithmetic()
     flash_parity()
     ragged_parity()
     paged_parity()
@@ -428,10 +485,14 @@ def main() -> int:
             for k, v in METRICS.snapshot()["counters"].items()
             if k.startswith("ops.dispatch.")}
     print(f"  dispatch record: {took}")
-    # (quant_matmul.stacked counts stacks handed over whole, beside the path)
+    # (quant_matmul.stacked counts stacks handed over whole, beside the
+    # path; quant_matmul.k_minor the traces of the lane-dense leg: all)
     stacked = took.pop("quant_matmul.stacked", 0)
-    if stacked != 4:
-        raise AssertionError(f"{stacked} of 4 stacked legs went in as stacks")
+    if stacked != len(STACKED_LEGS):
+        raise AssertionError(f"{stacked} of {len(STACKED_LEGS)} stacked legs "
+                             "went in as stacks")
+    if took.pop("quant_matmul.k_minor", 0) != took.get(f"quant_matmul.{MODE}"):
+        raise AssertionError("a quant_matmul trace left the lane-dense leg")
     stray = {k: v for k, v in took.items() if not k.endswith("." + MODE)}
     if stray:
         raise AssertionError(f"legs dispatched off the {MODE} path: {stray}")
@@ -445,8 +506,11 @@ def main() -> int:
     # have the depths a walk by runs treats apart, share pages and carry
     # junk ids (_paged_case).  v9: the latent (MLA) decode kernel on those
     # rows, and the expert kernel holding 12 of 192 experts at A.X-K1's
-    # widths with absent experts' pairs in the list — 32 legs.
-    print(f"kernel_parity: ALL PASS v9 ({mode}, backend={backend})")
+    # widths with absent experts' pairs in the list — 32 legs.  v10: the
+    # quantized leaves lie [N, K]; the stacked legs at pythia's, lfm2's and
+    # A.X-K1's shapes too, and one leg against the arithmetic of the [K, N]
+    # kernel (weights bit-equal, outputs equal or one ulp) — 40 legs.
+    print(f"kernel_parity: ALL PASS v10 ({mode}, backend={backend})")
     return 0
 
 
