@@ -70,6 +70,15 @@ HYBRID = dict(prefix_cache=False, kernels=DENSE_KERNELS + ("moe_experts",))
 # rows, and must give the first-token logprob a fresh admission gives.
 LATENT = dict(kernels=DENSE_KERNELS + ("moe_experts", "mla_paged_decode"),
               suffix_check=True)
+# ``--preset k-exaone-ep8``: one chip's share of K-EXAONE (windowed and full
+# attention layers mixed: pages for the full layers, a ring a row for the
+# rest; 16 of 128 int8 experts), as its benchmark cell serves it: 64 slots,
+# --max-len 8192, 3,712 pages, no prefix cache (the server refuses the
+# pair), and the rings' kernel on the dispatch record.  It also sends a
+# prompt of 6,000 bytes (``long_check``): admitted at the 8,192 bucket in
+# blocks, 94 pages deep, 46 wraps of the ring.
+WINDOWED = dict(prefix_cache=False, long_check=True,
+                kernels=DENSE_KERNELS + ("moe_experts", "swa_decode"))
 SHAPES = {
     "qwen2-7b": (CHIP, REHEARSAL),
     "lfm2-8b-a1b": (dict(CHIP, preset="lfm2-8b-a1b", **HYBRID),
@@ -77,6 +86,9 @@ SHAPES = {
     "ax-k1-ep16": (dict(CHIP, preset="ax-k1-ep16", slots=64, pages=2176,
                         **LATENT),
                    dict(REHEARSAL, preset="ax-k1-tiny", **LATENT)),
+    "k-exaone-ep8": (dict(CHIP, preset="k-exaone-ep8", slots=64,
+                          max_len=8192, pages=3712, **WINDOWED),
+                     dict(REHEARSAL, preset="k-exaone-tiny", **WINDOWED)),
 }
 
 
@@ -339,6 +351,13 @@ def serve_requests(srv: Server, shape: dict) -> None:
         "uncached request again")
     check(a == b, f"identical text and logprobs both times ({a[0]!r}, "
                   f"{len(a[1])} logprobs)")
+
+    if shape.get("long_check"):
+        print("one long prompt, twice:", flush=True)
+        p = prompt_of(6000 if cap > 6100 else cap - 20, "sixk")
+        a, b = (check_answer(srv.complete(p, max_tokens=n_new, logprobs=True),
+                             f"{len(p)}-byte prompt") for _ in range(2))
+        check(a == b, f"identical text and logprobs both times ({a[0]!r})")
 
     if shape.get("suffix_check"):
         print("a long prompt again with a new suffix:", flush=True)

@@ -112,6 +112,10 @@ class ModelConfig:
     # construction).  The KV cache keeps max_seq_len slots (no rolling
     # buffer yet) — masking is what bounds the attention span, not cache
     # size.
+    # A hybrid model (``layer_types``) gives the window to its "swa" layers
+    # alone: its "attn" layers attend the whole prefix, and the served
+    # (paged) path keeps a swa layer's last ``sliding_window`` keys and
+    # values a row in a ring beside the pool (models/kv_cache.py).
     sliding_window: int | None = None
 
     def __post_init__(self):
@@ -141,12 +145,19 @@ class ModelConfig:
                 "(layers.moe_dropless): set moe_capacity=False"
             )
         if self.layer_types:
-            bad = set(self.layer_types) - {"conv", "attn", "mla"}
+            bad = set(self.layer_types) - {"conv", "attn", "mla", "swa"}
             if bad or len(self.layer_types) != self.num_layers:
                 raise ValueError(
                     f"layer_types must name {self.num_layers} layers as "
-                    f"'conv', 'attn' or 'mla', got {self.layer_types!r}"
+                    f"'conv', 'attn', 'swa' or 'mla', got "
+                    f"{self.layer_types!r}"
                 )
+        if ("swa" in self.layer_types) != (
+                bool(self.layer_types) and self.sliding_window is not None):
+            raise ValueError(
+                "a hybrid model's sliding_window is the window of its 'swa' "
+                "layers: give both or neither"
+            )
         if ("mla" in self.layer_types) != (self.kv_lora_rank > 0) or (
                 self.kv_lora_rank and set(self.layer_types) != {"mla"}):
             raise ValueError(
@@ -246,9 +257,15 @@ class ModelConfig:
     # Leading layers whose FFN is dense although num_experts > 0.
     num_dense_layers: int = 0
     # Per-layer operator, for a model whose layers differ ("hybrid" family):
-    # "conv" (gated short convolution, layers.short_conv) or "attn".  Empty
-    # for the families whose layers are all alike.
+    # "conv" (gated short convolution, layers.short_conv), "attn" (GQA over
+    # the whole prefix), "swa" (the same weights' shapes, over the last
+    # ``sliding_window`` positions) or "mla".  Empty for the families whose
+    # layers are all alike.
     layer_types: tuple[str, ...] = ()
+    # Whether a hybrid model's "attn" layers rotate queries and keys (its
+    # "swa" layers always do).  False: no position enters a full layer
+    # (EXAONE 4.0's hybrid attention).
+    attn_rope: bool = True
     # Taps of the depthwise causal convolution of a "conv" layer; its whole
     # memory is the last ``conv_kernel - 1`` gated inputs a row.
     conv_kernel: int = 3
@@ -319,6 +336,19 @@ class ModelConfig:
             return tuple(range(self.num_layers))
         return tuple(i for i, t in enumerate(self.layer_types)
                      if t in ("attn", "mla"))
+
+    @property
+    def swa_layers(self) -> tuple[int, ...]:
+        """Indices of the layers that keep a ring of ``sliding_window``
+        keys and values a row (kv_cache.HybridCache.ring_k): not paged."""
+        return tuple(i for i, t in enumerate(self.layer_types) if t == "swa")
+
+    @property
+    def model_window(self) -> int | None:
+        """``sliding_window`` where it is every layer's (the llama family:
+        masks and kernel bands over ONE cache); None for a hybrid model,
+        whose window is its "swa" layers' own."""
+        return None if self.layer_types else self.sliding_window
 
     @property
     def held_experts(self) -> int:

@@ -504,6 +504,23 @@ METRIC_DOCS: dict[str, str] = {
                                 "batch slot (gauge)",
     # -- expert layers (models/layers.py moe_dropless; real tokens only,
     #    carried out of each admission and decode chunk, added at delivery) --
+    "batcher.window_state_bytes": "bytes of the rings a model of windowed "
+                                  "and full attention layers keeps beside "
+                                  "its pages: each windowed layer's last "
+                                  "sliding_window keys and values, one ring "
+                                  "a batch slot, whatever the rows hold "
+                                  "(gauge)",
+    "batcher.pool_token_bytes": "bytes a resident token costs the page "
+                                "pool, every paged layer's (page_bytes over "
+                                "the page size; gauge)",
+    "attn.decode.resident_tokens": "tokens the decoding rows held, summed "
+                                   "over decode steps: what the paged decode "
+                                   "kernel read a full attention layer (a "
+                                   "model with windowed layers beside them)",
+    "swa.decode.window_tokens": "the same sum of min(tokens held, "
+                                "sliding_window): what the rings' decode "
+                                "kernel read a windowed layer, never more "
+                                "than the window a row a step",
     "batcher.latent_page_bytes": "bytes of one page of a latent (MLA) pool, "
                                  "every layer's rows (kv_cache.page_bytes)",
     "moe.held_pairs": "routed pairs that fell on an expert this chip holds "

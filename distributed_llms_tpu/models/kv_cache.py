@@ -79,12 +79,21 @@ jax.tree_util.register_dataclass(
 class HybridCache(KVCache):
     """Cache of a model whose layers differ (family "hybrid"): two kinds of
     state a row.  ``k``/``v`` as in :class:`KVCache` (contiguous or the page
-    pool), their layer axis counting the ATTENTION layers only
-    (``cfg.attn_layers``); ``conv`` [conv layers, B, K-1, D] holds each
-    short-convolution layer's last K-1 gated inputs a row (a batch slot of
-    the batcher: the state is not paged), in the activations' dtype."""
+    pool), their layer axis counting the layers that attend a row's WHOLE
+    prefix only (``cfg.attn_layers``); beside them what a row keeps at a
+    fixed size, one entry a batch slot of the batcher (not paged), None
+    where the model has no such layer: ``conv`` [conv layers, B, K-1, D],
+    each short-convolution layer's last K-1 gated inputs a row, in the
+    activations' dtype; ``ring_k``/``ring_v`` [swa layers, B, W, KVH, HD],
+    each windowed attention layer's last W = ``cfg.sliding_window`` keys
+    and values a row.  The key of position p lies at p mod W, already
+    rotated, so order inside the ring does not matter, a row longer than
+    the window overwrites what fell out of it, and a reader masks by count
+    (min(length, W)): a slot's ring may hold a finished row's leftovers."""
 
-    conv: Any
+    conv: Any = None
+    ring_k: Any = None
+    ring_v: Any = None
 
 
 @jax.tree_util.register_dataclass
@@ -118,9 +127,9 @@ def init_cache(
     shape = (len(cfg.attn_layers), batch, max_len, cfg.num_kv_heads,
              cfg.head_dim_)
     k, v = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
-    if not cfg.conv_layers:
+    if not pages_are_private(cfg):
         return KVCache(k=k, v=v)
-    return HybridCache(k=k, v=v, conv=conv_state(cfg, batch))
+    return HybridCache(k=k, v=v, **slot_state(cfg, batch, dtype))
 
 
 def conv_state(cfg: ModelConfig, rows: int) -> jax.Array:
@@ -129,6 +138,21 @@ def conv_state(cfg: ModelConfig, rows: int) -> jax.Array:
     return jnp.zeros(
         (len(cfg.conv_layers), rows, cfg.conv_kernel - 1, cfg.hidden_size),
         jnp.dtype(cfg.dtype))
+
+
+def slot_state(cfg: ModelConfig, rows: int, dtype) -> dict:
+    """What a :class:`HybridCache` holds beside k and v for ``rows`` rows,
+    zeroed, by field: the convolution layers' state, the windowed layers'
+    rings (in the keys' dtype)."""
+    out = {}
+    if cfg.conv_layers:
+        out["conv"] = conv_state(cfg, rows)
+    if cfg.swa_layers:
+        shape = (len(cfg.swa_layers), rows, cfg.sliding_window,
+                 cfg.num_kv_heads, cfg.head_dim_)
+        out["ring_k"] = jnp.zeros(shape, dtype)
+        out["ring_v"] = jnp.zeros(shape, dtype)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -147,9 +171,10 @@ def make_pool(cfg: ModelConfig, num_pages: int, page_size: int,
     pool (data int8 + one f32 absmax scale per head-dim vector) at roughly
     half the bytes per token; the full-width dtype survives as
     ``row_dtype`` so gathers/transient rows restore to it.  A hybrid
-    model's pool counts its attention layers only and comes with the
-    state that is not paged: each convolution layer's, one entry a batch
-    slot (``slots``), in a :class:`HybridCache`.  A model with latent
+    model's pool counts the layers that attend the whole prefix only and
+    comes with the state that is not paged: each convolution layer's and
+    each windowed attention layer's ring, one entry a batch slot
+    (``slots``), in a :class:`HybridCache`.  A model with latent
     attention gets the one leaf of a :class:`LatentCache`,
     [L, NB, BLK, latent_width]."""
     from ..ops.decode_attn import pool_head_shape
@@ -165,9 +190,9 @@ def make_pool(cfg: ModelConfig, num_pages: int, page_size: int,
                               fold_narrow=pages_are_private(cfg))
     dt = jnp.dtype(dtype) if dtype else jnp.dtype(cfg.dtype)
     shape = (l, num_pages, page_size, kvh, hd)
-    if cfg.conv_layers:
+    if pages_are_private(cfg):
         return HybridCache(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt),
-                           conv=conv_state(cfg, slots))
+                           **slot_state(cfg, slots, dt))
     if kv_bits == 8:
         sshape = (l, num_pages, page_size, kvh)
         return QuantKVCache(
@@ -191,11 +216,18 @@ def page_bytes(cfg: ModelConfig, page_size: int, kv_bits: int = 16,
 
 def format_bytes(pool, cfg: ModelConfig) -> dict[str, float]:
     """Sizes that only one format has, for its gauges: the bytes of the
-    state a :class:`HybridCache` keeps beside its pages (``conv_state``),
-    the bytes of one page of a :class:`LatentCache` (``latent_page``)."""
+    state a :class:`HybridCache` keeps beside its pages (``conv_state``,
+    and ``window_state``: the windowed layers' rings), the bytes of one
+    page of a :class:`LatentCache` (``latent_page``)."""
     match pool:
         case HybridCache():
-            return {"conv_state": float(pool.conv.nbytes)}
+            sizes = {}
+            if pool.conv is not None:
+                sizes["conv_state"] = float(pool.conv.nbytes)
+            if pool.ring_k is not None:
+                sizes["window_state"] = float(
+                    pool.ring_k.nbytes + pool.ring_v.nbytes)
+            return sizes
         case LatentCache():
             return {"latent_page": float(
                 page_bytes(cfg, pool.k.shape[2], dtype=pool.k.dtype))}
@@ -326,6 +358,20 @@ def write_tokens(pool, layer, page, off, k: jax.Array,
     })
 
 
+def write_ring(pool: HybridCache, layer, at: jax.Array, k: jax.Array,
+               v: jax.Array) -> HybridCache:
+    """Put each row's new key and value ([B, KVH, HD]) into windowed layer
+    ``layer``'s ring at index ``at`` [B] (the token's position mod the
+    window), where the rings lie: they are the layer scans' carry."""
+    rows = jnp.arange(at.shape[0], dtype=jnp.int32)
+    return dataclasses.replace(
+        pool,
+        ring_k=pool.ring_k.at[layer, rows, at].set(
+            k.astype(pool.ring_k.dtype)),
+        ring_v=pool.ring_v.at[layer, rows, at].set(
+            v.astype(pool.ring_v.dtype)))
+
+
 def kernel_operands(pool) -> tuple[jax.Array, jax.Array, dict]:
     """(k pages, v pages, scales) as ops.decode_attn.paged_decode_attention
     takes them: ``scales`` is its ``k_scale``/``v_scale`` keywords on an
@@ -368,8 +414,9 @@ def write_row(pool, page_list: jax.Array, row_cache, slot=None):
     admissions also route their CACHED positions to the scratch page: the
     shared pages already hold exactly that KV and must never be rewritten
     while other rows read them.  A :class:`HybridCache` also takes the
-    row's convolution state into batch slot ``slot`` (all of it: whatever
-    the slot's last row left is overwritten)."""
+    row's state that is not paged (convolution state, rings) into batch
+    slot ``slot`` (all of it: whatever the slot's last row left is
+    overwritten)."""
     p = page_list.shape[0]
     blk = pool.k.shape[2]
 
@@ -387,10 +434,11 @@ def write_row(pool, page_list: jax.Array, row_cache, slot=None):
     new = dict(zip(fields, leaves))
     match pool:
         case HybridCache():
-            new["conv"] = jax.lax.dynamic_update_slice_in_dim(
-                pool.conv, row_cache.conv.astype(pool.conv.dtype), slot,
-                axis=1,
-            )
+            for f in ("conv", "ring_k", "ring_v"):
+                if (state := getattr(pool, f)) is not None:
+                    new[f] = jax.lax.dynamic_update_slice_in_dim(
+                        state, getattr(row_cache, f).astype(state.dtype),
+                        slot, axis=1)
     return dataclasses.replace(pool, **new)
 
 
@@ -481,11 +529,12 @@ def pages_are_private(cfg: ModelConfig) -> bool:
     ever touches a page: :func:`refuse_unpaged_state` has refused every
     feature that reads [.., KVH, HD] rows out of the pool (prefix cache,
     named prefixes, tiering, import/export, chunked prefill, speculation,
-    the int8 pool, a mesh), which it does for the family that keeps
-    convolution state beside its pages.  Only then may heads narrower than
+    the int8 pool, a mesh), which it does for a model that keeps state
+    beside its pages (convolution state, the windowed layers' rings:
+    :class:`HybridCache`).  Only then may heads narrower than
     a 128-lane row lie folded in the pool
     (ops.decode_attn.pool_head_shape; heads of 128 never fold)."""
-    return bool(cfg.conv_layers)
+    return bool(cfg.conv_layers or cfg.swa_layers)
 
 
 _LATENT_REFUSALS = {
@@ -513,12 +562,41 @@ _LATENT_REFUSALS = {
 }
 
 
+_RING_REFUSALS = {
+    "prefix_cache": "a cached page run restores the full layers' keys and "
+                    "values, not the last keys of the windowed layers",
+    "kv_bits": "the int8 pool's write path knows no ring (ask for kv_bits "
+               "16)",
+    "host_pages": "the host tier and swap-out park pages, not the rings "
+                  "that belong to them",
+    "speculative": "a rejected draft would have to take its keys back out "
+                   "of the rings",
+    "prefill_chunk": "a chunked prefill would have to hand the rings from "
+                     "bite to bite",
+    "token_budget": "it chunks prefills, which would have to hand the "
+                    "rings from bite to bite",
+    "mesh": "the rings and the expert stacks have no sharding rule yet "
+            "(mesh.model > 1 included)",
+    "named_prefix": "a registered prefix keeps the full layers' keys and "
+                    "values, not the windowed layers' last keys at its end",
+    "kv_import": "KV import/export ships pages, not rings",
+    "kv_export": "KV import/export ships pages, not rings",
+    "sessions": "a session keeps contiguous keys and values between turns, "
+                "not rings",
+    "padded_generate": "generate_text pads rows of unlike length, and the "
+                       "rings would be taken at the padded end; serve "
+                       "through continuous_batcher",
+}
+
+
 def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
     """Refuse, by name and with the reason, every feature that moves or
     keeps keys and values and does not yet carry the state a hybrid model
     holds beside them (each convolution layer's last gated inputs a row:
     :class:`HybridCache`).  Served anyway, such a feature
-    would hand a row its pages without its state.  ``asked`` maps a
+    would hand a row its pages without its state.  A model whose windowed
+    attention layers keep a ring a row refuses the same list for the
+    rings' sake (``_RING_REFUSALS``).  ``asked`` maps a
     feature's name to whether it was asked for; ``paged_pages`` is the one
     that must be set.
 
@@ -572,9 +650,12 @@ def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
             f"{cfg.family} model: the batcher serves its keys and values "
             "from the page pool only; pass paged_pages"
         )
+    what = "convolution state"
+    if not cfg.conv_layers:  # window and full attention layers mixed
+        why, what = _RING_REFUSALS, "windowed layers' rings"
     for name, value in asked.items():
         if value:
             raise ValueError(
-                f"{name} is not supported for a model with convolution "
-                f"state (family {cfg.family!r}): {why[name]}"
+                f"{name} is not supported for a model with {what} "
+                f"(family {cfg.family!r}): {why[name]}"
             )

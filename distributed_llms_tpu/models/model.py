@@ -146,7 +146,7 @@ def _attention(
                 "paged attention is per-row decode (a per-row cache_index "
                 "over a page-pool cache)"
             )
-        if cfg.sliding_window is not None:
+        if cfg.model_window is not None:
             raise ValueError(
                 "paged decode attends each row's full cache prefix; it "
                 "cannot honor sliding_window"
@@ -171,7 +171,7 @@ def _attention(
             q, k, v,
             q_positions=None if std_layout else positions,
             k_positions=None if std_layout else positions,
-            causal=True, window=cfg.sliding_window,
+            causal=True, window=cfg.model_window,
         )
         return layers.out_project(out, p), None
 
@@ -232,7 +232,7 @@ def _attention(
                 # block in VMEM, so a kv_dtype != compute dtype never costs
                 # a full-width HBM copy of the cache.
                 out = decode_attn.ragged_decode_attention(
-                    q, ck, cv, cache_index + 1, window=cfg.sliding_window,
+                    q, ck, cv, cache_index + 1, window=cfg.model_window,
                 )
                 return layers.out_project(out, p), (ck, cv)
         else:
@@ -243,7 +243,7 @@ def _attention(
             k_positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (x.shape[0], s))
             k_valid = k_positions < (cache_index + x.shape[1])
             if (cfg.attn_impl == "flash" and x.shape[1] > 1
-                    and (cfg.sliding_window is None or key_positions is None)):
+                    and (cfg.model_window is None or key_positions is None)):
                 # Prefill into a (longer, padded) cache: the flash kernel
                 # masks the unwritten tail instead of computing a dense
                 # [Tq, max_len] score matrix.  Single-token decode stays on
@@ -260,19 +260,19 @@ def _attention(
                 out = flash.flash_attention(
                     q, ck.astype(q.dtype), cv.astype(q.dtype),
                     q_positions=positions, k_positions=k_positions,
-                    k_valid=k_valid, causal=True, window=cfg.sliding_window,
+                    k_valid=k_valid, causal=True, window=cfg.model_window,
                 )
                 return layers.out_project(out, p), (ck, cv)
             # Causality/validity compare SLOT indices (the write frontier);
             # the window compares POSITIONS — for gapped layouts the caller
             # supplies key_positions (see the parameter comment above).
             attn_mask = layers.causal_mask(positions, k_positions, k_valid)
-            if cfg.sliding_window is not None:
+            if cfg.model_window is not None:
                 kpos = k_positions if key_positions is None else key_positions
                 attn_mask = layers.and_window(
-                    attn_mask, positions, kpos, cfg.sliding_window
+                    attn_mask, positions, kpos, cfg.model_window
                 )
-        elif cfg.sliding_window is not None:
+        elif cfg.model_window is not None:
             # Caller-supplied masks (continuous batching's per-row prefix
             # masks, padded prefill) carry causality/validity but not the
             # window — AND it in here so no dense cached path can silently
@@ -283,7 +283,7 @@ def _attention(
                     jnp.arange(s, dtype=jnp.int32), (x.shape[0], s)
                 )
             attn_mask = layers.and_window(
-                attn_mask, positions, key_positions, cfg.sliding_window
+                attn_mask, positions, key_positions, cfg.model_window
             )
         k_full = layers.repeat_kv(ck.astype(q.dtype), cfg.q_per_kv)
         v_full = layers.repeat_kv(cv.astype(q.dtype), cfg.q_per_kv)
@@ -291,12 +291,12 @@ def _attention(
         new_cache = (ck, cv)
     else:
         if attn_mask is None:
-            mask = layers.causal_mask(positions, positions, window=cfg.sliding_window)
+            mask = layers.causal_mask(positions, positions, window=cfg.model_window)
         else:
             mask = attn_mask
-            if cfg.sliding_window is not None:
+            if cfg.model_window is not None:
                 mask = layers.and_window(
-                    mask, positions, positions, cfg.sliding_window
+                    mask, positions, positions, cfg.model_window
                 )
         k_full = layers.repeat_kv(k, cfg.q_per_kv)
         v_full = layers.repeat_kv(v, cfg.q_per_kv)
@@ -545,6 +545,125 @@ def mla_attention(
     return project(out), new_cache
 
 
+_TOKEN_BLOCK = 2048  # tokens a long admission's FFNs take at a time
+
+
+def _self_attention(q, k, v, positions, window: int | None = None):
+    """Causal attention of T tokens over themselves: a row's start (q
+    [B, T, H, hd], k and v [B, T, KVH, hd]; ``positions`` [B, T] rise by
+    one along the block).  On the chip the flash kernel (ops/flash.py, its
+    static-causal path: no [T, T] score matrix exists, tiles above the
+    diagonal and, with ``window``, below the band are skipped and never
+    fetched, so a windowed layer's work grows with T x window and an
+    8,192-token admission fits); with ``DLT_RAGGED_DECODE=fallback`` (the
+    CPU's default) layers.dot_product_attention under layers.causal_mask,
+    the numbers the kernel is parity-tested against.  (As one XLA softmax
+    over 8,192 keys the row maximum compiles to a reduce-window that takes
+    24 ms a block of 128 queries: PERF.md, PR 34.)"""
+    from ..ops import decode_attn, dispatch, flash
+
+    mode = decode_attn._mode()
+    if mode == "fallback":
+        dispatch.record("flash", "fallback", (*q.shape, k.shape[2]))
+        g = q.shape[2] // k.shape[2]
+        return layers.dot_product_attention(
+            q, layers.repeat_kv(k, g), layers.repeat_kv(v, g),
+            layers.causal_mask(positions, positions, window=window))
+    # A band of 128 inside tiles of 1,024 would score eight times the keys
+    # it needs: tiles of 512 for a windowed layer.
+    block = 1024 if window is None else 512
+    return flash.flash_attention(
+        q, k, v, causal=True, window=window, block_q=block, block_k=block,
+        interpret=mode == "interpret")
+
+
+def mixed_attention(
+    x: jax.Array,  # [B, T, D], normed
+    p: Params,  # wq, wk, wv, wo (+ q_norm, k_norm)
+    cfg: ModelConfig,
+    positions: jax.Array,
+    cache: kv_cache.HybridCache | None,  # the whole cache: the page pool
+    #   (``kv_tables``) or a fresh row's contiguous cache, and the rings
+    cache_index: jax.Array | None,
+    kind: str,  # "attn": the whole prefix; "swa": the last sliding_window
+    layer: jax.Array | int,  # index among the layers of its kind
+    kv_tables: jax.Array | None = None,
+    seq_lens: jax.Array | None = None,
+) -> tuple[jax.Array, kv_cache.HybridCache | None]:
+    """GQA attention of a model whose layers mix full and windowed
+    attention (``cfg.swa_layers``: K-EXAONE's pattern of three windowed
+    layers and a full one).  Both kinds: q, k RMS-normalised per head
+    (``cfg.qk_norm``); a windowed layer rotates them, a full one only if
+    ``cfg.attn_rope``; query head g reads key/value head g // q_per_kv.
+
+    A decode step (per-row ``cache_index``, one token a row): a full layer
+    writes and reads its pages (:func:`_paged_attention`); a windowed one
+    writes the new key and value into the row's ring at position mod W and
+    attends to the ring's min(length, W) valid entries
+    (ops.decode_attn.swa_decode_attention), so it reads at most W tokens a
+    row however long the row is.  Otherwise the T tokens are a row's start
+    (no cache, or a fresh row's cache at offset 0: an admission) and
+    attend among themselves (:func:`_self_attention`, a windowed layer
+    through its band); a full layer leaves its keys and values in the
+    row cache's first T slots, a windowed one leaves in the ring the last
+    min(n, W) tokens of the ``seq_lens`` REAL ones (None: all T), at the
+    prompt's true length and not at the padded bucket's end."""
+    from ..ops import decode_attn
+
+    w = cfg.sliding_window if kind == "swa" else None
+    q, k, v = layers.qkv_project(x, p, cfg)
+    if cfg.qk_norm:
+        q = layers.rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = layers.rms_norm(k, p["k_norm"], cfg.norm_eps)
+    if kind == "swa" or cfg.attn_rope:
+        q = layers.apply_rope(q, positions, cfg.rope_theta)
+        k = layers.apply_rope(k, positions, cfg.rope_theta)
+    b, t = x.shape[:2]
+    if cache is not None and getattr(cache_index, "ndim", 0) == 1:
+        if t != 1 or (kind == "attn" and kv_tables is None):
+            raise ValueError(
+                "a model of windowed and full attention layers decodes one "
+                "token a row against the page pool and the rings"
+            )
+        if kind == "attn":
+            return _paged_attention(
+                q, k, v, p, cache, layer, cache_index, kv_tables)
+        cache = kv_cache.write_ring(
+            cache, layer, cache_index % w, k[:, 0], v[:, 0])
+        out = decode_attn.swa_decode_attention(
+            q, cache.ring_k, cache.ring_v, jnp.minimum(cache_index + 1, w),
+            layer)
+        return layers.out_project(out, p), cache
+    if cache is not None and (isinstance(cache_index, jax.core.Tracer)
+                              or int(cache_index) != 0):
+        raise ValueError(
+            "a model of windowed and full attention layers prefills a row "
+            "from its start (cache_index 0): the rings hold no prefix to "
+            "continue from"
+        )
+    out = layers.out_project(_self_attention(q, k, v, positions, w), p)
+    if cache is None:
+        return out, None
+    if kind == "attn":
+        return out, dataclasses.replace(
+            cache,
+            k=cache.k.at[layer, :, :t].set(k.astype(cache.k.dtype)),
+            v=cache.v.at[layer, :, :t].set(v.astype(cache.v.dtype)))
+    # Ring entry j holds the last real position that is j mod W (before
+    # position 0: whatever, past the count a reader masks by).
+    last = (jnp.full((b,), t, jnp.int32) if seq_lens is None
+            else seq_lens) - 1
+    j = jnp.arange(w, dtype=jnp.int32)
+    src = jnp.clip(last[:, None] - (last[:, None] - j[None, :]) % w, 0, t - 1)
+    take = src[:, :, None, None]
+    return out, dataclasses.replace(
+        cache,
+        ring_k=cache.ring_k.at[layer].set(
+            jnp.take_along_axis(k, take, axis=1).astype(cache.ring_k.dtype)),
+        ring_v=cache.ring_v.at[layer].set(
+            jnp.take_along_axis(v, take, axis=1).astype(cache.ring_v.dtype)))
+
+
 def gpt2_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, std_layout=False, kv_tables=None, key_positions=None, layer=None):
     """-> (x, new_cache, aux): aux is the MoE load-balance term (0 here).
     Shared by the gpt2 and opt families (pre-LN + learned positions);
@@ -747,7 +866,12 @@ def run_layers(
     writes it at its own index among the attention layers: the page pool
     whole (``kv_tables``; see :func:`_paged_attention`), a contiguous
     cache by its layer slice.  A convolution layer reads and writes its
-    [B, K-1, D] slice of ``cache.conv``.
+    [B, K-1, D] slice of ``cache.conv``.  In a model that mixes windowed
+    ("swa") and full ("attn") attention layers both kinds go through
+    :func:`mixed_attention`: the full ones hold pages, the windowed ones a
+    ring a row in ``cache.ring_k`` / ``ring_v``.  The position-wise half
+    of a layer runs ``_TOKEN_BLOCK`` tokens at a time where an admission
+    is longer than that.
 
     Returns (x, cache', stats): stats int32 [4] adds up what the expert
     layers routed for the real tokens of this pass (layers.moe_dropless:
@@ -772,6 +896,11 @@ def run_layers(
             if cache is not None:
                 cache = dataclasses.replace(cache, conv=cache.conv.at[
                     at[op]].set(new.astype(cache.conv.dtype)))
+        elif cfg.swa_layers:  # windowed and full attention layers mixed
+            with jax.named_scope("swa_attn" if op == "swa" else "full_attn"):
+                out, cache = mixed_attention(
+                    h, p, cfg, positions, cache, cache_index, op, at[op],
+                    kv_tables, seq_lens)
         elif op == "mla":
             ai = at[op]
             if cache is None or kv_tables is not None:
@@ -806,22 +935,42 @@ def run_layers(
                     v=cache.v.at[ai].set(new[1]))
         x = x + out
         h = layers.rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
-        if ffn == "moe":
+
+        def add_ffn(x, h, mask):
+            """x + ffn(h) and the expert layer's counts (zeros: dense)."""
+            if ffn != "moe":
+                return x + layers.mlp_swiglu(
+                    h, layer_of(blocks["dense"], at[ffn]), cfg.gate_act
+                ), jnp.zeros_like(moe)
             y, stats = layers.moe_dropless(
-                h, blocks["moe"], cfg, token_mask, layer=at[ffn])
-            x, moe = x + y, moe + stats
+                h, blocks["moe"], cfg, mask, layer=at[ffn])
+            x = x + y
             if cfg.n_shared_experts:
                 with jax.named_scope("shared_expert"):
                     x = x + layers.mlp_swiglu(
                         h, layer_of(blocks["moe"]["shared"], at[ffn]),
                         cfg.gate_act)
-        else:
-            x = x + layers.mlp_swiglu(
-                h, layer_of(blocks["dense"], at[ffn]), cfg.gate_act)
-        return x, cache, moe
+            return x, stats
+
+        b, t, d = x.shape
+        n = t // _TOKEN_BLOCK
+        if n < 2 or t % _TOKEN_BLOCK:
+            x, stats = add_ffn(x, h, token_mask)
+            return x, cache, moe + stats
+        # A long admission: the position-wise part a block of tokens at a
+        # time, so that its temporaries (the dense layer's 18,432 columns,
+        # the grouped list of token x k pairs) do not grow with the
+        # bucket.  A token sees the same arithmetic; an expert layer's
+        # counts add up over the blocks, each a pass of its own.
+        mask = (jnp.ones((b, t), bool) if token_mask is None else token_mask)
+        x, stats = jax.lax.map(lambda a: add_ffn(*a), tuple(
+            jnp.moveaxis(a.reshape(b, n, _TOKEN_BLOCK, *a.shape[2:]), 1, 0)
+            for a in (x, h, mask)))
+        return (jnp.moveaxis(x, 0, 1).reshape(b, t, d), cache,
+                moe + jnp.sum(stats, axis=0))
 
     carry = (x, cache, moe)
-    base = dict(conv=0, attn=0, mla=0, dense=0, moe=0)
+    base = dict(conv=0, attn=0, swa=0, mla=0, dense=0, moe=0)
     for unit, reps in layer_runs(cfg):
         kinds = [kind for pair in unit for kind in pair]
         per_unit = {kind: kinds.count(kind) for kind in base}
@@ -851,7 +1000,7 @@ def hybrid_layers(params: Params, cfg: ModelConfig):
     order: dicts {"ln1", "ln2": {"scale"}, "conv" | "attn": {...}, "mlp":
     {...}} as models/reference/lfm2_moe.py reads them.  A generator, so a
     caller that dequantizes what it is handed holds one layer in float32."""
-    at = dict(conv=0, attn=0, mla=0, dense=0, moe=0)
+    at = dict(conv=0, attn=0, swa=0, mla=0, dense=0, moe=0)
     blocks = params["blocks"]
 
     def take(kind):
@@ -1072,8 +1221,10 @@ def hybrid_fan_in(name: str, shape: tuple) -> int:
 
 
 def _init_hybrid_blocks(rng: jax.Array, cfg: ModelConfig, dtype: Any) -> Params:
-    """One stack a kind of layer (models.model.run_layers): ``conv`` and
-    ``attn`` (each with its layers' two norms), ``dense`` for the first
+    """One stack a kind of layer (models.model.run_layers): ``conv``,
+    ``attn`` and ``swa`` (each with its layers' two norms; the windowed
+    attention layers' weights have the full ones' shapes), ``dense`` for
+    the first
     ``cfg.num_dense_layers`` FFNs and ``moe`` (router + experts) for the
     rest.  The router and the selection bias are float32 whatever the
     model's dtype (the scores pick the experts); ``expert_bias`` is drawn
@@ -1129,20 +1280,23 @@ def _init_hybrid_blocks(rng: jax.Array, cfg: ModelConfig, dtype: Any) -> Params:
             "wkv_b": dense("mla/wkv_b", (NA, R, H * (DN + DV))),
             "wo": dense("mla/wo", (NA, H * DV, D)),
         }
-    elif NA:
-        blocks["attn"] = {
-            **norms(NA),
+    for kind, n in (("attn", 0 if cfg.kv_lora_rank else NA),
+                    ("swa", len(cfg.swa_layers))):
+        if not n:
+            continue
+        blocks[kind] = {
+            **norms(n),
             # [D, H * hd], the head axes flat: quantized with their own
             # axis last, heads of 64 would get absmax blocks of 64, which
             # the fused kernel cannot tile (layers.qkv_project unflattens).
-            "wq": dense("attn/wq", (NA, D, H * HD)),
-            "wk": dense("attn/wk", (NA, D, KVH * HD)),
-            "wv": dense("attn/wv", (NA, D, KVH * HD)),
-            "wo": dense("attn/wo", (NA, H, HD, D)),
+            "wq": dense(f"{kind}/wq", (n, D, H * HD)),
+            "wk": dense(f"{kind}/wk", (n, D, KVH * HD)),
+            "wv": dense(f"{kind}/wv", (n, D, KVH * HD)),
+            "wo": dense(f"{kind}/wo", (n, H, HD, D)),
         }
-    if cfg.qk_norm and "attn" in blocks:
-        blocks["attn"]["q_norm"] = jnp.ones((NA, HD), dtype)
-        blocks["attn"]["k_norm"] = jnp.ones((NA, HD), dtype)
+        if cfg.qk_norm:
+            blocks[kind]["q_norm"] = jnp.ones((n, HD), dtype)
+            blocks[kind]["k_norm"] = jnp.ones((n, HD), dtype)
     if NM:
         EH = cfg.held_experts  # (a chip's share; the router scores all E)
         blocks["moe"] = {

@@ -139,7 +139,42 @@ PRESETS: dict[str, ModelConfig] = {
         moe_capacity=False, n_shared_experts=1, moe_n_group=8,
         moe_topk_group=4, experts_held=12, experts_offset=0,
     ),
+    # K-EXAONE-236B-A23B (LG AI Research, model_type exaone_moe) as ONE
+    # CHIP'S SHARE of an 8-chip pipeline stage: the published widths (64
+    # query heads over 8 key/value heads of 128 with QK-norm; layers "LLLG",
+    # three with a window of 128 and rotation, one over the whole prefix
+    # with NO rotation; a dense SwiGLU of 18,432 then expert layers of 128
+    # routed (8 a token, sigmoid scores, no groups, normalised, x 2.5) and
+    # one shared expert of 2,048), cut in depth to stage 0's 12 of the 48
+    # layers, to experts 0-15 of every layer's 128 (the router keeps all
+    # 128 outputs) and to a 19,200-row slice of the vocabulary; the
+    # multi-token-prediction layer is not served:
+    # benchmark/configs/k-exaone-int8-ep8.json has the deployment.
+    "k-exaone-ep8": ModelConfig(
+        family="hybrid", vocab_size=19200, hidden_size=6144,
+        intermediate_size=18432, moe_intermediate_size=2048, num_layers=12,
+        num_dense_layers=1, num_heads=64, num_kv_heads=8, head_dim=128,
+        max_seq_len=262144, rope_theta=1e6, norm_eps=1e-5,
+        tie_embeddings=False, qk_norm=True, attn_rope=False,
+        sliding_window=128, layer_types=("swa", "swa", "swa", "attn") * 3,
+        num_experts=128, num_experts_per_token=8, moe_score_fn="sigmoid",
+        moe_norm_topk=True, moe_norm_eps=1e-20, moe_routed_scale=2.5,
+        moe_capacity=False, n_shared_experts=1, experts_held=16,
+        experts_offset=0,
+    ),
     # Tiny configs for unit tests / CPU fake-mesh integration tests.
+    "k-exaone-tiny": ModelConfig(
+        family="hybrid", vocab_size=256, hidden_size=64,
+        intermediate_size=128, moe_intermediate_size=32, num_layers=8,
+        num_dense_layers=1, num_heads=4, num_kv_heads=2, head_dim=16,
+        max_seq_len=256, rope_theta=1e6, norm_eps=1e-5,
+        tie_embeddings=False, dtype="float32", qk_norm=True,
+        attn_rope=False, sliding_window=8,
+        layer_types=("swa", "swa", "swa", "attn") * 2,
+        num_experts=16, num_experts_per_token=4, moe_score_fn="sigmoid",
+        moe_norm_eps=1e-20, moe_routed_scale=2.5, moe_capacity=False,
+        n_shared_experts=1,
+    ),
     "ax-k1-tiny": ModelConfig(
         family="hybrid", vocab_size=256, hidden_size=64,
         intermediate_size=128, moe_intermediate_size=32, num_layers=4,

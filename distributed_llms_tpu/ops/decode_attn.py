@@ -222,6 +222,7 @@ def _softmax_all_heads(
     v_ref,  #   [BLK, KVH, D] lies in memory
     acc_ref, m_ref, l_ref,
     *, first_key, length, scale: float, kvh: int, g: int,
+    zero_unread: bool = False,
 ):
     """:func:`_softmax_block` without its loop over heads: ONE score
     product of all the queries with all the rows, each query keeping the
@@ -231,11 +232,17 @@ def _softmax_all_heads(
     more than the DMA that brought them (PERF.md, PR 31); the matrix unit
     takes the rows as they lie, and its time goes by the rows of keys,
     which are the same.  The arithmetic a (query, key) pair sees is
-    _softmax_block's."""
+    _softmax_block's.  ``zero_unread``: the rows past ``length`` are read as
+    zeros on the value side too (a ring's entries past its count are
+    another row's leftovers, whatever they hold: 0.0 x NaN is NaN)."""
     n = k_ref.shape[0]
     qa = q_ref[0]
     kb = k_ref[...].astype(qa.dtype)
     vb = v_ref[...].astype(qa.dtype)
+    if zero_unread:
+        token = first_key + jax.lax.broadcasted_iota(
+            jnp.int32, (n, 1), 0) // kvh
+        vb = jnp.where(token < length, vb, jnp.zeros_like(vb))
     s = (
         jax.lax.dot_general(
             qa, kb, (((1,), (1,)), ((), ())),
@@ -274,6 +281,7 @@ def _kernel_paged(
     quant: bool = False,
     latent: int | None = None,  # latent (MLA) pages: one buffer, every
     #   head reads every row, values = its first ``latent`` columns
+    zero_unread: bool = False,  # see _softmax_all_heads (the rings)
 ):
     """Paged variant: grid ``(B,)``, and inside a row a loop over its RUNS
     of ``run`` pages.  The kernel fetches a run itself — a DMA a page (and
@@ -363,7 +371,8 @@ def _kernel_paged(
                                acc_ref, m_ref, l_ref, g=g, **state)
         else:
             _softmax_all_heads(q_ref, k_buf.at[slot], v_buf.at[slot],
-                               acc_ref, m_ref, l_ref, g=g, **state)
+                               acc_ref, m_ref, l_ref, g=g,
+                               zero_unread=zero_unread, **state)
         return 1 - slot
 
     slot_ref[0] = jax.lax.fori_loop(0, runs, one_run, slot_ref[0])
@@ -635,11 +644,15 @@ def paged_decode_attention(
 def _paged_impl(
     q, k_pages, v_pages, lengths, tables, layer, k_scale=None, v_scale=None,
     *, mode: str = "fallback", run: int | None = None,
+    op: str = "paged_decode",
 ) -> jax.Array:
     """Single-shard body of the paged kernel (see _ragged_impl): the pool
     is the stack [L, NB, BLK, KVH, D] and ``layer`` [1] int32 names the
     layer to read.  ``run`` is :func:`_run_pages`' to work out; only
-    tools/paged_attn_bench.py, which times the others, names one."""
+    tools/paged_attn_bench.py, which times the others, names one.  ``op``
+    names the call in the dispatch record and, with ``_attn`` behind it,
+    in a trace: "swa_decode" reads the windowed layers' rings
+    (:func:`swa_decode_attention`)."""
     b, t, h, d = q.shape
     assert t == 1, "paged decode attention is single-token by construction"
     quant = k_scale is not None
@@ -655,7 +668,7 @@ def _paged_impl(
         and _kv_vmem_ok(blk, kvh, d, k_pages.dtype)
     )
     if mode == "fallback" or not tileable:
-        dispatch.record("paged_decode", "fallback", (b, blk, h, kvh, d))
+        dispatch.record(op, "fallback", (b, blk, h, kvh, d))
         # Gather the rows' pages out of the layer into contiguous
         # [B, P*BLK] caches (the fallback materializes; the kernel never
         # does).  Int8 pools dequantize the gathered rows at
@@ -672,8 +685,9 @@ def _paged_impl(
         return _dense_reference(q, k_rows, v_rows, lengths)
 
     run = run or _run_pages(blk, kvh, d, k_pages.dtype, p)
-    dispatch.record("paged_decode", mode, (b, blk, h, kvh, d, run))
-    METRICS.set_gauge("ops.dispatch.paged_decode.run_pages", run)
+    dispatch.record(op, mode, (b, blk, h, kvh, d, run))
+    if op == "paged_decode":
+        METRICS.set_gauge("ops.dispatch.paged_decode.run_pages", run)
     scale = d**-0.5
     qt = q[:, 0].reshape(b, kvh, g, d)
     if fold > 1:
@@ -732,7 +746,7 @@ def _paged_impl(
     out = pl.pallas_call(
         functools.partial(
             _kernel_paged, scale=scale, blk=blk, run=run, kvh=kvh, g=gp,
-            quant=quant,
+            quant=quant, zero_unread=op == "swa_decode",
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -752,7 +766,7 @@ def _paged_impl(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=mode == "interpret",
-        name="paged_decode_attn",  # the operation's name in a trace
+        name=f"{op}_attn",  # the operation's name in a trace
     )(*operands)
     out = out[:, : kvh * gp].reshape(b, kvh, gp, d)[:, :, :g]
     if fold > 1:
@@ -763,6 +777,32 @@ def _paged_impl(
             jnp.eye(fold, dtype=out.dtype),
         )
     return out.reshape(b, 1, h, q.shape[-1])
+
+
+def swa_decode_attention(
+    q: jax.Array,  # [B, 1, H, D]
+    ring_k: jax.Array,  # [Lw, B, W, KVH, D]: every windowed layer's rings,
+    ring_v: jax.Array,  #   one a batch slot (kv_cache.HybridCache)
+    counts: jax.Array,  # [B] int32: row b attends entries [0, counts[b])
+    layer: jax.Array | int = 0,  # which windowed layer's rings to read
+) -> jax.Array:
+    """A decode step's attention over a windowed layer's RINGS: row b's
+    last min(length, W) keys and values lie in ring b in no order that
+    matters (the key of position p at p mod W, already rotated), so the
+    read is the paged kernel's with the rings as a pool of one W-token
+    page a row, the page table the identity: the stack stays in HBM whole,
+    the kernel copies (layer, row)'s ring where it lies and masks by
+    count.  Entries past the count (a shorter row, a finished row's
+    leftovers) are read as zeros whatever they hold.  Under its own name
+    in a trace (``swa_decode_attn``) and in the dispatch record
+    (``ops.dispatch.swa_decode.*``).  Returns [B, 1, H, D].  Single-device
+    (the rings refuse a mesh)."""
+    b = q.shape[0]
+    return _paged_impl(
+        q, ring_k, ring_v, counts.astype(jnp.int32),
+        jnp.arange(b, dtype=jnp.int32)[:, None],
+        jnp.asarray(layer, jnp.int32).reshape(1), mode=_mode(),
+        op="swa_decode")
 
 
 def _latent_run_pages(blk: int, w: int, dtype, p: int) -> int:

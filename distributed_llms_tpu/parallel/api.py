@@ -273,7 +273,7 @@ class ParallelModel:
         cached paths stay guarded: ring/Ulysses attention and the
         two-region seq cache are causal-only and do not carry a window
         bound — decoding there would silently attend past the window."""
-        if self.cfg.sliding_window is not None and self.seq_parallel:
+        if self.cfg.model_window is not None and self.seq_parallel:
             raise ValueError(
                 "sequence-parallel decode of sliding_window models is "
                 "unsupported (ring/Ulysses attention is causal-only, no "

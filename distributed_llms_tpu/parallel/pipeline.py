@@ -355,7 +355,7 @@ def pipeline_decode(
             # holds position len + i) — same formula as
             # runtime.generate.window_key_positions, per microbatch.
             kpos = None
-            if cfg.sliding_window is not None:
+            if cfg.model_window is not None:
                 kpos = jnp.where(
                     slots[None, :] < t_base, slots[None, :],
                     plens_m[:, None] + (slots[None, :] - t_base),

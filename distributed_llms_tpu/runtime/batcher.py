@@ -189,9 +189,12 @@ def _prefill_row(fwd, params, cfg, cache_dtype, s, prompt, plen=None):
     positions = jnp.arange(tp, dtype=jnp.int32)[None, :]
     state = ({"seq_lens": plen[None], "return_aux": True}
              if cfg.family == "hybrid" else {})
+    # (a model of windowed and full layers must SEE that the row starts
+    # here, while tracing: its rings hold no prefix to continue from)
+    start = 0 if cfg.swa_layers else jnp.int32(0)
     return fwd(
         params, cfg, prompt[None, :], positions=positions,
-        cache=row_cache, cache_index=jnp.int32(0), **state,
+        cache=row_cache, cache_index=start, **state,
     )
 
 
@@ -953,15 +956,21 @@ def _decode_steps(
                 **state,
             )
             moe = aux[0] if aux else None
-            if cfg.kv_lora_rank:
+            if cfg.kv_lora_rank or cfg.swa_layers:
                 # Sixth (_note_moe), behind the fifth that only a chip's
-                # share of the experts counts: what the latent decode
-                # kernel read this step, the tokens each decoding row
-                # holds, its new one included.
+                # share of the experts counts: what the decode kernel of
+                # the paged layers (latent pages; the full layers' beside
+                # windowed ones) read this step, the tokens each decoding
+                # row holds, its new one included.  Seventh, with windowed
+                # layers: the min(that, window) of them its ring holds.
+                held = jnp.where(active, real_lens + 1, 0)
+                read = [jnp.sum(held, dtype=jnp.int32)[None]]
+                if cfg.swa_layers:
+                    read.append(jnp.sum(
+                        jnp.minimum(held, cfg.sliding_window),
+                        dtype=jnp.int32)[None])
                 moe = jnp.concatenate([
-                    moe, jnp.zeros((5 - moe.shape[0],), jnp.int32),
-                    jnp.sum(jnp.where(active, real_lens + 1, 0),
-                            dtype=jnp.int32)[None]])
+                    moe, jnp.zeros((5 - moe.shape[0],), jnp.int32), *read])
         else:
             mask = (valid | (slots[None, :] == real_lens[:, None]))[:, None, None, :]
             logits, cache = _fwd(pm)(
@@ -1595,7 +1604,7 @@ class ContinuousBatcher:
                         f"{cfg.num_kv_heads} must divide over 'model' "
                         f"({tp})"
                     )
-            if cfg.sliding_window is not None:
+            if cfg.model_window is not None:
                 raise ValueError(
                     "paged KV cannot serve sliding-window models (the paged "
                     "decode kernel attends the full cache prefix); use "
@@ -1822,6 +1831,12 @@ class ContinuousBatcher:
             if "latent_page" in sizes:
                 METRICS.set_gauge("batcher.latent_page_bytes",
                                   sizes["latent_page"])
+            if "window_state" in sizes:
+                METRICS.set_gauge("batcher.window_state_bytes",
+                                  sizes["window_state"])
+                METRICS.set_gauge(
+                    "batcher.pool_token_bytes",
+                    kv_cache.page_bytes(cfg, page_size) // page_size)
         else:
             self.cache = kv_cache.init_cache(
                 cfg, batch_slots, cache_len,
@@ -4252,8 +4267,8 @@ class ContinuousBatcher:
         only) to ``moe.*``: what a hybrid model's admission or decode chunk
         handed out beside its tokens, None for any other model.  Four
         counts; a fifth where the config holds a chip's share of the
-        experts; a decode chunk against latent pages hands out six
-        (_decode_steps)."""
+        experts; a decode chunk against latent pages hands out six, one
+        against pages and rings seven (_decode_steps)."""
         if stats is None:
             return
         counts = [int(x) for x in np.asarray(stats)]
@@ -4263,8 +4278,13 @@ class ContinuousBatcher:
         METRICS.inc("moe.max_load_tokens", counts[3])
         if len(counts) > 4:  # a chip's share of the experts
             METRICS.inc("moe.held_pairs", counts[4])
-        if len(counts) > 5:  # a decode chunk against latent pages
+        if len(counts) > 5 and self.cfg.swa_layers:  # a decode chunk: the
+            # tokens its rows held (full layers' pages; latent pages)
+            METRICS.inc("attn.decode.resident_tokens", counts[5])
+        elif len(counts) > 5:
             METRICS.inc("mla.decode.resident_tokens", counts[5])
+        if len(counts) > 6:  # ... and of them, those inside the window
+            METRICS.inc("swa.decode.window_tokens", counts[6])
 
     def _fetch_chunk(self, out: tuple) -> tuple:
         """Host work's D2H for a dispatched-ahead chunk: tokens, logprobs,

@@ -447,7 +447,7 @@ class InferenceEngine:
         real = jnp.zeros((b,), jnp.int32)
         sess = self.sessions.new_session(cache, valid, real, base=0, max_len=max_len)
         sess.n_real = n_real
-        if self.cfg.sliding_window is not None:
+        if self.cfg.model_window is not None:
             # Sliding-window session state: the padded multi-turn layout
             # makes slot != position, and the window mask compares positions
             # (session_step maintains the map turn by turn).

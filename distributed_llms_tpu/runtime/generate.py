@@ -123,7 +123,7 @@ def generate_tokens(
     # len+j.  Hand the true slot->position map to the forward or the window
     # silently widens by the pad amount (models.model._attention).
     win_kwargs = {}
-    if cfg.sliding_window is not None:
+    if cfg.model_window is not None:
         win_kwargs["key_positions"] = window_key_positions(t, prompt_lens, max_len)
 
     def step(carry, inputs):

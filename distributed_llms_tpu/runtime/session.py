@@ -102,7 +102,7 @@ def session_step(
     s = cache.k.shape[-3]  # [..., B, S, KVH, HD] -> S
     slots = jnp.arange(s, dtype=jnp.int32)  # [S]
 
-    windowed = cfg.sliding_window is not None
+    windowed = cfg.model_window is not None
     if windowed and slot_positions is None:
         raise ValueError(
             "sliding-window sessions need the slot_positions state (the "

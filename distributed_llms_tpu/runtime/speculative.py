@@ -220,7 +220,7 @@ def speculative_generate_tokens(
     from .generate import window_key_positions
 
     def _win_kwargs(cfg):
-        if cfg.sliding_window is None:
+        if cfg.model_window is None:
             return {}
         return {"key_positions": window_key_positions(t, prompt_lens, max_len)}
 

@@ -208,3 +208,74 @@ def test_latent_attentions_and_the_shared_experts_weights_stay_stacked(
     ``quant_matmul`` as stacks read at an index: no instruction inside the
     loops is shaped like one layer of any of them or of its scales."""
     assert compiled_latent["weight_shaped"] == []
+
+
+@pytest.fixture(scope="module")
+def compiled_windowed():
+    """``decode_chunk`` and ``admit_row_paged`` at the 8,192 bucket of
+    k-exaone-ep8 at its 12 layers and the cell's shapes (64 slots, 3,712
+    pages of the 3 full layers, the 9 windowed layers' rings beside them;
+    some 35 s to lower and compile the two)."""
+    from distributed_llms_tpu.models.presets import get_preset
+    from tools import aot_decode
+
+    try:
+        aot_decode.v5e_devices()
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DLT_QUANT_MATMUL", "kernel")
+        mp.setenv("DLT_RAGGED_DECODE", "kernel")
+        mp.setenv("DLT_MOE_EXPERTS", "kernel")
+        return {
+            program: aot_decode.analyse(
+                program, get_preset("k-exaone-ep8"), slots=64, max_len=8192,
+                pages=3712, page_size=BLK, prompt_len=8192)
+            for program in ("decode_chunk", "admit_row_paged")}
+
+
+def test_pool_of_the_full_layers_and_the_rings_are_written_in_place(
+        compiled_windowed):
+    """The pool is the 3 full layers' [3,3712,64,8,128] and the rings
+    [9,64,128,8,128]: nothing but the step's in-place scatters produces an
+    array of either shape or a layer of it, and both are the output's
+    alias (2 x 2.919 GB + 2 x 0.151 GB)."""
+    decode = compiled_windowed["decode_chunk"]
+    found = decode["pool_shaped"]
+    assert found and {e[0] for e in found} == {"scatter", "fusion:scatter"}
+    assert all("[3,3712,64,8,128]" in e[2] for e in found), found
+    rings = decode["ring_shaped"]
+    assert rings and {e[0] for e in rings} == {"scatter", "fusion:scatter"}
+    assert all("[9,64,128,8,128]" in e[2] for e in rings), rings
+    carried = 2 * 3 * 3712 * BLK * 8 * 128 * 2 + 301_989_888
+    assert decode["alias_gb"] * 1e9 >= carried
+    assert decode["temp_gb"] < 0.05  # 8 MB: no second pool, no second ring
+
+
+def test_the_windowed_models_experts_and_weights_stay_where_they_lie(
+        compiled_windowed):
+    """No instruction of the decode step is shaped like the held experts'
+    stacks ([11,16,6144,4096], [11,16,2048,6144]) or like a layer of the
+    other int8 weights; weights (9.54 GB), pool and rings (3.22 GB) are all
+    the program is given."""
+    decode = compiled_windowed["decode_chunk"]
+    assert decode["expert_shaped"] == []
+    assert decode["weight_shaped"] == []
+    assert 12.7 < decode["argument_gb"] < 12.8
+
+
+def test_an_admission_at_the_8192_bucket_leaves_half_a_gigabyte(
+        compiled_windowed):
+    """Attention through the flash kernel (no score matrix), FFNs in blocks
+    of 2,048 tokens: 1.4 GB of temporaries beside 12.76 GB of weights, pool
+    and rings, inside the chip's 15.75 GB with more than 0.5 GB to spare;
+    the rings are written where they lie (a dynamic-update-slice into the
+    slot), the pool a page at a time; no expert stack is copied."""
+    admit = compiled_windowed["admit_row_paged"]
+    assert admit["argument_gb"] + admit["temp_gb"] < 15.75 - 0.5
+    assert admit["temp_gb"] < 1.8
+    assert admit["expert_shaped"] == []
+    assert {e[0] for e in admit["pool_shaped"]} <= {
+        "dynamic-update-slice", "fusion:dynamic-update-slice"}
+    assert {e[0] for e in admit["ring_shaped"]} <= {
+        "dynamic-update-slice", "fusion:dynamic-update-slice"}

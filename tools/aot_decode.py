@@ -12,12 +12,16 @@ scatters of a step's keys and values (tests/runtime/test_aot_pool.py
 holds the decode program to it).  Beside it: every instruction shaped like
 a layer's expert stack (``expert_shaped``) and like one layer of a
 quantized weight or of its scales (``weight_shaped``); both lists are
-empty when the kernels read the stacks where they lie.
+empty when the kernels read the stacks where they lie.  And, for a model
+whose windowed layers keep a ring a row beside the pool, every instruction
+shaped like the rings (``ring_shaped``): the in-place writes alone.
 
     python tools/aot_decode.py qwen2-7b --slots 16 --max-len 4096 --pages 512
     python tools/aot_decode.py pythia-6.9b --slots 8 --max-len 2048 --pages 96 \
         --programs decode_chunk,admit_row_paged --hlo-dir /tmp/hlo
     python tools/aot_decode.py ax-k1-ep16 --slots 64 --pages 2176 --prompt-len 2048
+    python tools/aot_decode.py k-exaone-ep8 --slots 64 --max-len 8192 \
+        --pages 3712 --prompt-len 8192
 """
 from __future__ import annotations
 
@@ -199,6 +203,21 @@ def pool_shaped(hlo_text: str, cfg, pages: int, page_size: int,
     return shaped_like(hlo_text, shapes)
 
 
+def ring_shaped(hlo_text: str, cfg, slots: int) -> list:
+    """Every instruction whose result is shaped like the windowed layers'
+    rings, one layer's or the stack of all ([swa layers, slots, window,
+    KVH, HD]): like the pool they are the scans' carry, so only the
+    scatter of a step's keys and values (decode) or the write of a row's
+    ring into its slot (admission) may be on the list.  Empty for a model
+    without windowed layers."""
+    if not cfg.swa_layers:
+        return []
+    layer = (f"{slots},{cfg.sliding_window},{cfg.num_kv_heads},"
+             f"{cfg.head_dim_}")
+    return shaped_like(
+        hlo_text, [f"[{layer}]", f"[{len(cfg.swa_layers)},{layer}]"])
+
+
 def expert_shaped(hlo_text: str, cfg) -> list:
     """Every instruction whose result is shaped like a layer's expert
     stack or like one expert's weights, at any dtype: an expert stack
@@ -354,6 +373,8 @@ def analyse(program: str, cfg, **shape_kw) -> dict:
                         shape_kw.get("page_size", 64),
                         shape_kw.get("mesh_model", 1))
     return {
+        "ring_shaped": [list(e) for e in ring_shaped(
+            text, cfg, shape_kw["slots"])],
         "expert_shaped": [list(e) for e in expert_shaped(text, cfg)],
         "weight_shaped": [list(e) for e in weight_shaped(text, shapes)],
         "weight_shaped_once": [

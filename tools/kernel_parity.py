@@ -210,6 +210,21 @@ def flash_parity() -> None:
     )(q, kk, v)
     want = _dense_reference(q, kk, v, None, None, None, True, win)
     check(f"flash windowed T{t} win{win}", got, want, rtol=3e-2, atol=3e-2)
+    # K-EXAONE's admissions (models.model._self_attention): 8 query heads a
+    # key/value head on a long row, the full layers in tiles of 1,024 and
+    # the windowed layers' band of 128 in tiles of 512.
+    ks = jax.random.split(jax.random.fold_in(key, 11), 3)
+    b, t, h, kvh, d = (1, 4096, 16, 2, 128) if ON_TPU else (1, 1024, 8, 1, 128)
+    q = jax.random.normal(ks[0], (b, t, h, d), jnp.bfloat16)
+    kk = jax.random.normal(ks[1], (b, t, kvh, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, t, kvh, d), jnp.bfloat16)
+    for win, block in ((None, 1024), (128, 512)):
+        got = jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=win, block_q=block, block_k=block,
+            interpret=not ON_TPU))(q, kk, v)
+        want = _dense_reference(q, kk, v, None, None, None, True, win)
+        check(f"flash T{t} H{h}/{kvh} win{win} tiles{block}", got, want,
+              rtol=3e-2, atol=3e-2)
 
 
 # The paged legs' rows: 19 page slots each (no run length divides it), six
@@ -217,17 +232,21 @@ def flash_parity() -> None:
 PAGED_ROWS, PAGED_SLOTS = 6, 19
 
 
-def _paged_case(blk: int, kvh: int, d: int, dtype, run: int | None = None):
+def _paged_case(blk: int, kvh: int, d: int, dtype, run: int | None = None,
+                rows: tuple | None = None):
     """(lengths, tables, pool size) of a paged leg.  Depths, with ``run``
     the pages the kernel walks at a time: 1 token; inside a run; a run's
     last slot; the next run's first key; every slot full; one page.  The
     last row starts with the pages of the row before it (a shared prefix),
     and every id past a row's depth names the pool's last page, which the
-    leg fills with NaNs (int8: NaN scales): never to be read."""
+    leg fills with NaNs (int8: NaN scales): never to be read.  ``rows`` =
+    (page slots, the six depths in tokens) names other rows."""
     b, pages = PAGED_ROWS, PAGED_SLOTS
-    pool = b * pages + 1
     run = run or decode_attn._run_pages(blk, kvh, d, dtype, pages)
     lengths = [1, 2 * blk + 44, run * blk, run * blk + 1, pages * blk, blk]
+    if rows:
+        pages, lengths = rows
+    pool = b * pages + 1
     rng = np.random.RandomState(0)
     tables = rng.permutation(pool - 1).reshape(b, pages)
     tables[5, :2] = tables[4, :2]
@@ -250,18 +269,19 @@ def _to_pool(rows, tables, pool, fill, junk, layer, noise):
 
 
 def paged_parity(blk: int = 128, h: int = 8, kvh: int = 4,
-                 layer: int | None = None, d: int = 128) -> None:
+                 layer: int | None = None, d: int = 128,
+                 rows: tuple | None = None) -> None:
     key = jax.random.PRNGKey(3)
     # Heads narrower than a row lie folded in the pool, as the batcher of
     # a hybrid model keeps them (decode_attn.pool_head_shape).
     fold = decode_attn.pool_head_shape(kvh, d, fold_narrow=True)
-    ln, tables, junk, pool = _paged_case(blk, *fold, jnp.bfloat16)
+    ln, tables, junk, pool = _paged_case(blk, *fold, jnp.bfloat16, rows=rows)
     b, pages = tables.shape
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (b, 1, h, d), jnp.bfloat16)
     k_rows = jax.random.normal(ks[1], (b, pages * blk, kvh, d), jnp.bfloat16)
     v_rows = jax.random.normal(ks[2], (b, pages * blk, kvh, d), jnp.bfloat16)
-    shared = 2 * blk
+    shared = min(2, pages) * blk
     k_rows = k_rows.at[5, :shared].set(k_rows[4, :shared])
     v_rows = v_rows.at[5, :shared].set(v_rows[4, :shared])
     k_pool, v_pool = (
@@ -276,6 +296,42 @@ def paged_parity(blk: int = 128, h: int = 8, kvh: int = 4,
     form = "" if layer is None else f" L3[{layer}]"
     check(f"paged decode B{b} pool{pool} blk{blk} H{h}/{kvh} D{d}{form}", got,
           want, rtol=3e-2, atol=3e-2)
+
+
+def swa_parity(w: int = 128, h: int = 64, kvh: int = 8, d: int = 128,
+               layer: int = 1) -> None:
+    """The rings' decode kernel at K-EXAONE's shapes: 64 query heads over
+    8 key/value heads, a ring of 128 tokens a row, layer 1 of a 3-layer
+    stack.  Rows: one token; shorter than the window; exactly the window;
+    wrapped (300 tokens went by: the key of position p lies at p mod 128,
+    the oldest overwritten, and the answer is that of the last 128 in
+    their own order).  Entries past a row's count hold NaNs: never to be
+    read, on the value side either."""
+    lengths = [1, 37, w, 300]
+    b, s = len(lengths), max(lengths)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(13), 3)
+    q = jax.random.normal(kq, (b, 1, h, d), jnp.bfloat16)
+    k_rows = jax.random.normal(kk, (b, s, kvh, d), jnp.bfloat16)
+    v_rows = jax.random.normal(kv, (b, s, kvh, d), jnp.bfloat16)
+    pos = np.arange(s)
+
+    def ring(rows):
+        out = np.full((b, w, kvh, d), np.nan, np.float32)
+        for r, n in enumerate(lengths):
+            out[r, pos[:n] % w] = np.asarray(rows[r, :n], np.float32)
+        return _stacked(jnp.asarray(out, jnp.bfloat16), layer, 3.0)
+
+    counts = jnp.minimum(jnp.asarray(lengths, jnp.int32), w)
+    got = jax.jit(decode_attn.swa_decode_attention)(
+        q, ring(k_rows), ring(v_rows), counts, layer)
+    # The reference: each row's last min(n, w) tokens, first in first.
+    last = jnp.stack([jnp.roll(x, -max(n - w, 0), axis=0)
+                      for x, n in zip(k_rows, lengths)])
+    last_v = jnp.stack([jnp.roll(x, -max(n - w, 0), axis=0)
+                        for x, n in zip(v_rows, lengths)])
+    want = decode_attn._dense_reference(q, last, last_v, counts)
+    check(f"swa decode B{b} ring{w} H{h}/{kvh} D{d} L3[{layer}]", got, want,
+          rtol=3e-2, atol=3e-2)
 
 
 def mla_paged_parity(blk: int = 64, h: int = 64, latent: int = 512,
@@ -479,6 +535,17 @@ def main() -> int:
     # interpreter gets the same list at a tenth of the widths).
     moe_parity(e=12, d=7168, f=2048, k=8, of_experts=192) if ON_TPU else \
         moe_parity(e=12, d=1024, f=256, k=8, of_experts=192)
+    # K-EXAONE's: the paged kernel at 8 query heads a key/value head on
+    # rows of one page, 94 pages and all 128 of an 8,192-token row (junk
+    # ids past every depth), layer 2 of the stack; the rings' kernel; 16 of
+    # 128 experts held at [6144 x 4096] and [2048 x 6144].
+    slots = 128 if ON_TPU else 12
+    deep = slots * 64
+    paged_parity(blk=64, h=64, kvh=8, layer=2, rows=(slots, [
+        1, 64, deep * 94 // 128 - 17, deep - 63, deep, 64]))
+    swa_parity()
+    moe_parity(e=16, d=6144, f=2048, k=8, of_experts=128) if ON_TPU else \
+        moe_parity(e=16, d=768, f=256, k=8, of_experts=128)
     # No leg may pass on another path than the one asked for: the dispatch
     # record (ops/dispatch.py) counts every trace by the path it took.
     took = {k[len("ops.dispatch."):]: int(v)
@@ -509,8 +576,12 @@ def main() -> int:
     # widths with absent experts' pairs in the list — 32 legs.  v10: the
     # quantized leaves lie [N, K]; the stacked legs at pythia's, lfm2's and
     # A.X-K1's shapes too, and one leg against the arithmetic of the [K, N]
-    # kernel (weights bit-equal, outputs equal or one ulp) — 40 legs.
-    print(f"kernel_parity: ALL PASS v10 ({mode}, backend={backend})")
+    # kernel (weights bit-equal, outputs equal or one ulp) — 40 legs.  v11:
+    # the paged kernel at 64 query heads over 8 on rows 128 pages deep, the
+    # rings' kernel (swa_decode_attn: short, full, wrapped, NaNs past the
+    # count) and the expert kernel holding 16 of 128 at K-EXAONE's widths
+    # and the flash kernel as its admissions call it — 46 legs.
+    print(f"kernel_parity: ALL PASS v11 ({mode}, backend={backend})")
     return 0
 
 

@@ -3,17 +3,23 @@
 
     python tools/reference_check.py --config lfm2-8b-a1b-int8     # the chip
     python tools/reference_check.py --config ax-k1-int8-ep16      # the chip
+    python tools/reference_check.py --config k-exaone-int8-ep8    # the chip
     JAX_PLATFORMS=cpu python tools/reference_check.py --rehearsal # lfm2-tiny
-    (--config ax-k1-int8-ep16 --rehearsal: ax-k1-tiny)
+    (--config ax-k1-int8-ep16 --rehearsal: ax-k1-tiny; --config
+    k-exaone-int8-ep8 --rehearsal: k-exaone-tiny)
 
 For each of the benchmark's four probe prompts the tool takes the logits
 the SERVED path produces, admission at the prompt's own bucket and then 8
-decode steps through the page pool and the convolution state, in a batch
+decode steps through the page pool and the state beside it (convolution
+state, the windowed layers' rings), in a batch
 slot of a pool shaped as the cell serves it, and compares them with the
-reference's full forward (models/reference/lfm2_moe.py, or axk1.py for a
-model with latent attention, which gets the chip's share of the experts
+reference's full forward (models/reference/lfm2_moe.py; axk1.py for a
+model with latent attention and exaone_moe.py for one of windowed and full
+attention layers, which get the chip's share of the experts
 the configuration holds: float32 at ``highest`` precision, no cache, no
-kernel) over the same tokens.  Both
+kernel) over the same tokens.  A model with windowed layers gets a fifth
+probe of ``LONG_PROBE`` bytes, which its 8,192 bucket admits in blocks
+(its reference then scores the queries 512 at a time).  Both
 sides hold the same seed-0 int8 weights; the reference gets them
 dequantized a layer at a time and never holds more than one layer in
 float32.
@@ -97,7 +103,25 @@ TOLS = {
     # grid in the SERVED programs: each limit lies between its readings.
     "as_served": {"max_abs_logit": 0.47, "mean_abs_logit": 0.062},
   },
+  "k-exaone-int8-ep8": {
+    # float32 activations, pages and rings in float32: the order of
+    # summation (a ring in slot order, a prefix in pages, the flash
+    # kernel's tiles against one masked score matrix).  The chip gave
+    # 9.6e-5 / 1.5e-5 at the most (the 6,000-byte probe; 1.7e-5 / 2.8e-6
+    # on probe 32); on probe 200 seven experts a token for eight give 0.59
+    # / 0.089, rotation on the full layers 0.57 / 0.070, no QK-norm 0.97 /
+    # 0.15, a window of 256 1.71 / 0.29, int4 weights 2.89 / 0.50.
+    "mechanism": {"max_abs_logit": 2e-3, "mean_abs_logit": 2e-4},
+    # bfloat16 activations through 12 layers: the chip gave 0.525 / 0.059
+    # at the most over the five probes (probe 32; 0.411 / 0.018 on the
+    # 6,000-byte one), and 0.808 / 0.092 on probe 32 with the held experts'
+    # stacks on the int4 grid in the SERVED programs: each limit lies
+    # between its readings.  (A rotation on the full layers moves the
+    # reference by 0.57 / 0.070, inside these: the mechanism leg tells it.)
+    "as_served": {"max_abs_logit": 0.65, "mean_abs_logit": 0.075},
+  },
 }
+LONG_PROBE = 6000  # bytes: 46 wraps of a 128-token ring, 94 pages deep
 GOLDEN_FROM_REFERENCE = 0.025  # half of benchmark/run.py GOLDEN_TOL
 
 
@@ -112,6 +136,9 @@ def reference_cfg(cfg) -> dict:
         norm_topk_prob=cfg.moe_norm_topk,
         routed_scaling_factor=cfg.moe_routed_scale,
         num_heads=cfg.num_heads, kv_lora_rank=cfg.kv_lora_rank,
+        num_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim_,
+        sliding_window=cfg.sliding_window, qk_norm=cfg.qk_norm,
+        full_rope=cfg.attn_rope,
         qk_nope_head_dim=cfg.qk_nope_head_dim,
         qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
         n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
@@ -166,7 +193,8 @@ def reference_logits(params, cfg, tokens, int4=False, **changed):
     ``changed`` overrides keys of the reference's configuration."""
     from distributed_llms_tpu.checkpoint import quantize as quant_lib
     from distributed_llms_tpu.models import model as model_lib
-    from distributed_llms_tpu.models.reference import axk1, lfm2_moe
+    from distributed_llms_tpu.models.reference import (
+        axk1, exaone_moe, lfm2_moe)
 
     def floats(tree):
         def one(x):
@@ -180,7 +208,8 @@ def reference_logits(params, cfg, tokens, int4=False, **changed):
             is_leaf=lambda x: isinstance(x, quant_lib.QuantizedTensor))
 
     def layer(p):
-        ex = p["mlp"].pop("experts", None) if cfg.kv_lora_rank else None
+        ex = (p["mlp"].pop("experts", None)
+              if cfg.experts_held is not None else None)
         p = floats(p)
         if ex is not None:
             p["mlp"]["experts"] = {
@@ -191,11 +220,15 @@ def reference_logits(params, cfg, tokens, int4=False, **changed):
     lazy["layers"] = (layer(p) for p in model_lib.hybrid_layers(params, cfg))
     ref_cfg = {**reference_cfg(cfg), **changed}
     toks = jnp.asarray(tokens, jnp.int32)
-    if not cfg.kv_lora_rank:
+    if not (cfg.kv_lora_rank or cfg.swa_layers):
         return lfm2_moe.forward(lazy, ref_cfg, toks)
     held = (None if cfg.experts_held is None
             else (cfg.experts_offset, cfg.experts_held))
-    return axk1.forward(lazy, ref_cfg, toks, experts_held=held)
+    if cfg.kv_lora_rank:
+        return axk1.forward(lazy, ref_cfg, toks, experts_held=held)
+    return exaone_moe.forward(
+        lazy, ref_cfg, toks, experts_held=held,
+        query_block=512 if len(toks) > 2048 else None)
 
 
 def served_programs(cfg, cfg_decode):
@@ -230,7 +263,7 @@ def main() -> int:
                     help="lfm2-tiny on the CPU at a tiny shape: the same "
                          "code, no number that means anything")
     ap.add_argument("--slot", type=int, default=3)
-    ap.add_argument("--probes", type=int, default=len(PROBE_BYTES),
+    ap.add_argument("--probes", type=int, default=None,
                     help="how many of the probes to run, from the first "
                          "(the controls ride on the first; fewer than all "
                          "of them writes no golden worth keeping)")
@@ -250,10 +283,13 @@ def main() -> int:
     preset, probe_bytes = config["preset"], PROBE_BYTES
     TOL = TOLS[a.config]
     if a.rehearsal:
-        preset = "ax-k1-tiny" if preset.startswith("ax-k1") else "lfm2-tiny"
+        preset = next((tiny for tiny in ("ax-k1-tiny", "k-exaone-tiny")
+                       if preset.startswith(tiny[:-5])), "lfm2-tiny")
         probe_bytes = (5, 9, 33, 60)
         serve.update(slots=4, max_len=128, page_size=8, paged_pages=40)
     cfg = get_preset(preset)
+    if cfg.swa_layers:
+        probe_bytes += (100 if a.rehearsal else LONG_PROBE,)
     tok = get_tokenizer(None)
     if cfg.vocab_size < tok.vocab_size:  # as dlt-serve widens a tiny preset
         cfg = dataclasses.replace(cfg, vocab_size=512)
@@ -294,8 +330,11 @@ def main() -> int:
         # (float32 pages of A.X-K1's latent pool would be 4.6 GB beside
         # 9.7 GB of weights: that leg's pool holds a quarter of the pages,
         # forty times what a probe fills)
-        pages = serve["paged_pages"] // (
-            4 if dtype == "float32" and c.kv_lora_rank else 1)
+        # (and K-EXAONE's float32 pages 5.8 GB beside 9.5: an eighth, five
+        # times what the long probe fills)
+        pages = max(ppr + 1, serve["paged_pages"] // (
+            1 if dtype != "float32" else 4 if c.kv_lora_rank
+            else 8 if c.swa_layers else 1))
         cache = kv_cache.make_pool(c, pages, blk, slots=slots)
         cache, first, tok0, _ = admit(
             params, cache, jnp.asarray(page_list), jnp.asarray(prompt),
@@ -359,6 +398,8 @@ def main() -> int:
         if n == probe_bytes[0]:
             # (of the as_served leg, for the control at the end)
             control = (ids, toks, ref, got["served_logprobs"])
+        # (a wrong window shows on a probe longer than the window: the second)
+        if n == probe_bytes[1 if cfg.swa_layers else 0]:
             # What a wrong model does to the same probe's reference logits:
             # each has to land outside the tolerances, or they guard nothing.
             wrongs = {
@@ -369,6 +410,10 @@ def main() -> int:
             }
             if cfg.moe_n_group > 1:
                 wrongs["no_groups"] = {"n_group": 1, "topk_group": 1}
+            if cfg.swa_layers:
+                wrongs.update(
+                    full_rope={"full_rope": True}, no_qk_norm={"qk_norm": False},
+                    window_x2={"sliding_window": 2 * cfg.sliding_window})
             row["wrong"] = {
                 name: against(np.asarray(reference_logits(
                     params, cfg, ids + toks[:-1], **changed),
@@ -387,10 +432,22 @@ def main() -> int:
         row["batcher_logprob_max_abs_diff"] = max(
             abs(x - y) for x, y in
             zip(row["batcher_logprobs"], mine["served_logprobs"]))
+        # Above 2,048 tokens an admission runs its FFNs in blocks and XLA
+        # compiles the batcher's program and the leg's apart (PR 34: the
+        # 6,000-byte probe's first logprob differs by 0.0025, later ones by
+        # what bfloat16 moves them): there the batcher's logprobs are held
+        # to the REFERENCE's, at the as-served limit.
+        row["batcher_logprob_against_reference"] = max(
+            abs(x - y) for x, y in
+            zip(row["batcher_logprobs"], mine["reference_logprobs"]))
         del batcher
+        tied = row["batcher_logprob_max_abs_diff"] < 1e-3 or (
+            row["bucket"] > model_lib._TOKEN_BLOCK
+            and row["batcher_logprob_against_reference"]
+            <= TOL["as_served"]["max_abs_logit"])
         good = (row["mechanism"]["within_tolerances"]
                 and mine["within_tolerances"] and row["batcher_tokens_equal"]
-                and row["batcher_logprob_max_abs_diff"] < 1e-3)
+                and tied)
         row["ok"] = bool(good)
         ok &= good or a.rehearsal
         report["probes"].append(row)
@@ -451,7 +508,7 @@ def main() -> int:
                 {"bytes": p["bytes"], "logprobs": [round(x, 6) for x in (
                     p["as_served"]["reference_logprobs"]
                     if source == "reference" else p["batcher_logprobs"])]}
-                for p in report["probes"]],
+                for p in report["probes"][:len(PROBE_BYTES)]],
             "reference": [
                 {"bytes": p["bytes"],
                  "logprobs": [round(x, 6) for x in
