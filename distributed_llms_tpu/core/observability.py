@@ -263,7 +263,16 @@ METRIC_DOCS: dict[str, str] = {
                                  "first-token fetch, inside "
                                  "batcher.loop.admit (histogram; the "
                                  "annotation carries rid, prompt_tokens, "
-                                 "cached_tokens, bucket)",
+                                 "cached_tokens, bucket, key_slots: the "
+                                 "keys a query of it is scored against)",
+    "batcher.admit.self_attention": "admissions whose attention read their "
+                                    "own bucket of tokens: a fresh row, "
+                                    "whose start the model sees while "
+                                    "tracing",
+    "batcher.admit.row_cache_attention": "admissions whose attention read "
+                                         "every slot of the row cache: a "
+                                         "suffix behind a named or cached "
+                                         "prefix, whose start is traced",
     "batcher.loop.grow_seconds": "chunk-boundary page growth, preemption "
                                  "included (histogram)",
     "batcher.loop.plan_seconds": "span planning and the per-chunk "

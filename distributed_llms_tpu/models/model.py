@@ -201,6 +201,18 @@ def _attention(
             out = ulysses.ulysses_attention(q, k, v, positions, axis_name="seq")
         return layers.out_project(out, p), None
 
+    if layer_cache is not None and _row_start(
+            cache_index, attn_mask, key_positions):
+        # An admission's fresh row: the T tokens attend among themselves
+        # and take the row cache's first T slots; no slot past T is read,
+        # repeated to the query heads or scored.
+        ck, cv = layer_cache  # [B, S, KVH, HD]
+        t = x.shape[1]
+        out = _self_attention(q, k, v, positions, cfg.model_window)
+        return layers.out_project(out, p), (
+            ck.at[:, :t].set(k.astype(ck.dtype)),
+            cv.at[:, :t].set(v.astype(cv.dtype)))
+
     if layer_cache is not None:
         ck, cv = layer_cache  # [B, S, KVH, HD]
         if getattr(cache_index, "ndim", 0) == 1:
@@ -401,10 +413,11 @@ def mla_rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig) -> jax.Array:
 _MLA_QUERY_BLOCK = 256
 
 
-def _expanded_attention(q, k, v, mask, scale):
-    """Dense attention of an admission's expanded heads, the queries a
-    block at a time where there are many: 64 heads x 2,048 queries x 4,096
-    slots of float32 scores would be 2 GiB at once."""
+def _expanded_attention(q, k, v, mask, scale=None):
+    """Dense attention of an admission, every query head with a key and
+    value head of its own, the queries a block at a time where there are
+    many: 64 heads x 2,048 queries x 4,096 slots of float32 scores would
+    be 2 GiB at once."""
     b, t, h, _ = q.shape
     n = t // _MLA_QUERY_BLOCK
     if n < 2 or t % _MLA_QUERY_BLOCK:
@@ -447,9 +460,11 @@ def mla_attention(
     lie and the weighted sum of latents goes through ``W_uv`` afterwards
     (ops.decode_attn.mla_paged_decode_attention), so a page is read once
     and no head's key or value is ever formed.  Every other call EXPANDS
-    keys and values from the rows (an admission: the cached run's too,
-    behind a prefix hit) and attends densely, as the other families'
-    admissions do.  Both read the same stored rows and the same W_kvb."""
+    keys and values from the rows and attends as the other families'
+    admissions do: a fresh row (:func:`_row_start`) its own T rows, among
+    themselves (:func:`_self_attention`); a suffix behind a prefix hit the
+    row cache's every slot, the cached run's too, densely.  All read the
+    same stored rows and the same W_kvb."""
     from ..ops import decode_attn
 
     b, t, _ = x.shape
@@ -506,6 +521,23 @@ def mla_attention(
                            wkv_b[..., dn:])
         return project(o), pool
 
+    def expand(rows):  # latent rows [B, S, W] -> a head's keys and values
+        kv = jnp.einsum("bsr,rhn->bshn", rows[..., :r], wkv_b)
+        return jnp.concatenate([
+            kv[..., :dn],
+            jnp.broadcast_to(rows[:, :, None, r: r + dr],
+                             (*kv.shape[:3], dr)),
+        ], axis=-1), kv[..., dn:]
+
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    if layer_cache is not None and _row_start(cache_index, attn_mask):
+        # An admission's fresh row: W_kvb expands the T new rows only, they
+        # attend among themselves and take the row cache's first T slots.
+        ck = row.astype(layer_cache.dtype)
+        out = _self_attention(
+            q, *expand(ck.astype(x.dtype)), positions, scale=scale)
+        return project(out), layer_cache.at[:, :t].set(ck)
+
     if layer_cache is None:
         keys, new_cache = row, None
         mask = (layers.causal_mask(positions, positions)
@@ -524,57 +556,84 @@ def mla_attention(
                 ck, row.astype(ck.dtype), (0, cache_index, 0))
         new_cache, keys, mask = ck, ck.astype(x.dtype), attn_mask
         if mask is None:
-            if not isinstance(cache_index, jax.core.Tracer):
-                # The write offset is known while tracing (an admission's
-                # fresh row: 0), so no slot past offset + T can be valid:
-                # expand and attend the slots that can.
-                keys = keys[:, : int(cache_index) + t]
             s = keys.shape[1]
             k_pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
             mask = layers.causal_mask(positions, k_pos,
                                       k_pos < cache_index + t)
-    kv = jnp.einsum("bsr,rhn->bshn", keys[..., :r], wkv_b)
-    k = jnp.concatenate([
-        kv[..., :dn],
-        jnp.broadcast_to(keys[:, :, None, r: r + dr],
-                         (*kv.shape[:3], dr)),
-    ], axis=-1)
-    out = _expanded_attention(
-        jnp.concatenate([q_nope, q_rope], axis=-1), k, kv[..., dn:], mask,
-        scale)
+    out = _expanded_attention(q, *expand(keys), mask, scale)
     return project(out), new_cache
 
 
 _TOKEN_BLOCK = 2048  # tokens a long admission's FFNs take at a time
 
 
-def _self_attention(q, k, v, positions, window: int | None = None):
-    """Causal attention of T tokens over themselves: a row's start (q
-    [B, T, H, hd], k and v [B, T, KVH, hd]; ``positions`` [B, T] rise by
-    one along the block).  On the chip the flash kernel (ops/flash.py, its
-    static-causal path: no [T, T] score matrix exists, tiles above the
-    diagonal and, with ``window``, below the band are skipped and never
-    fetched, so a windowed layer's work grows with T x window and an
-    8,192-token admission fits); with ``DLT_RAGGED_DECODE=fallback`` (the
-    CPU's default) layers.dot_product_attention under layers.causal_mask,
-    the numbers the kernel is parity-tested against.  (As one XLA softmax
-    over 8,192 keys the row maximum compiles to a reduce-window that takes
-    24 ms a block of 128 queries: PERF.md, PR 34.)"""
+def _row_start(cache_index, attn_mask, key_positions=None) -> bool:
+    """Whether a call with a cache to fill holds a row's START: the write
+    offset is known while tracing and is 0 (runtime.batcher._prefill_row
+    passes the Python 0), and the caller brings no mask and no map of the
+    slots' positions of its own.  Then the T new tokens can see nothing
+    but each other, whatever the cache's length."""
+    return (
+        attn_mask is None and key_positions is None
+        and not isinstance(cache_index, jax.core.Tracer)
+        and getattr(cache_index, "ndim", 0) == 0 and int(cache_index) == 0
+    )
+
+
+_LANES = 128  # a register's lanes: the flash kernel's tiles are whole ones
+
+
+def _self_attention(q, k, v, positions, window: int | None = None,
+                    scale: float | None = None):
+    """Causal attention of T tokens over themselves, a row's start: the one
+    place an admission's fresh row is scored (q [B, T, H, hd], k [B, T,
+    KVH, hd], v [B, T, KVH, hv]; ``positions`` [B, T] rise by one along the
+    block; ``scale`` None: hd ** -0.5).  The body is chosen by what the
+    call can see:
+
+    - on the chip, one shard, heads a whole number of registers wide (128:
+      qwen2, pythia, k-exaone), and on the interpreter whatever the heads:
+      the flash kernel (ops/flash.py, its static-causal path: no [T, T]
+      score matrix exists, tiles above the diagonal and, with ``window``,
+      below the band are skipped and never fetched, so a windowed layer's
+      work grows with T x window and an 8,192-token admission fits);
+    - on the chip, heads that fill a register in part (lfm2's 64; latent
+      attention's expanded 192 for q and k beside 128 for v):
+      layers.dot_product_attention over the T keys under
+      layers.causal_mask, in query blocks where T is long.  Against scoring
+      a row cache's every slot it drops only terms that are exactly zero,
+      which is what these two configurations' goldens (the served path's
+      own first-token logprobs, held to 0.05) ask for: the kernel sums in
+      another order and put A.X-K1's 700-byte probe 0.060 off, lfm2's four
+      0.10-0.23 (PERF.md, PR 35; the kernel takes 192 / 128 and a
+      ``scale`` and was the faster body there, so the rule is the
+      goldens', not the tiles');
+    - under a mesh (the kernel has no per-shard wrapper) and with
+      ``DLT_RAGGED_DECODE=fallback`` (the CPU's default; counted as the
+      kernel's fallback), the same dense body: the numbers the kernel is
+      parity-tested against.
+
+    (As one XLA softmax over 8,192 keys the row maximum compiles to a
+    reduce-window that takes 24 ms a block of 128 queries: PERF.md, PR 34.)"""
     from ..ops import decode_attn, dispatch, flash
 
     mode = decode_attn._mode()
-    if mode == "fallback":
-        dispatch.record("flash", "fallback", (*q.shape, k.shape[2]))
+    # (the interpreter, the tests' leg of the kernel's program, has no lanes)
+    partial = mode == "kernel" and q.shape[-1] % _LANES != 0
+    if mode == "fallback" or dispatch.mesh() is not None or partial:
+        if mode == "fallback":  # (a body chosen by shape or mesh is no
+            # fallback: the record would read as a kernel that failed)
+            dispatch.record("flash", "fallback", (*q.shape, k.shape[2]))
         g = q.shape[2] // k.shape[2]
-        return layers.dot_product_attention(
+        return _expanded_attention(
             q, layers.repeat_kv(k, g), layers.repeat_kv(v, g),
-            layers.causal_mask(positions, positions, window=window))
+            layers.causal_mask(positions, positions, window=window), scale)
     # A band of 128 inside tiles of 1,024 would score eight times the keys
     # it needs: tiles of 512 for a windowed layer.
     block = 1024 if window is None else 512
     return flash.flash_attention(
         q, k, v, causal=True, window=window, block_q=block, block_k=block,
-        interpret=mode == "interpret")
+        interpret=mode == "interpret", scale=scale)
 
 
 def mixed_attention(
@@ -634,8 +693,7 @@ def mixed_attention(
             q, cache.ring_k, cache.ring_v, jnp.minimum(cache_index + 1, w),
             layer)
         return layers.out_project(out, p), cache
-    if cache is not None and (isinstance(cache_index, jax.core.Tracer)
-                              or int(cache_index) != 0):
+    if cache is not None and not _row_start(cache_index, None):
         raise ValueError(
             "a model of windowed and full attention layers prefills a row "
             "from its start (cache_index 0): the rings hold no prefix to "

@@ -222,28 +222,30 @@ def _pad_to(x: jax.Array, axis: int, mult: int, value) -> jax.Array:
     return jnp.pad(x, widths, constant_values=value)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
-def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k, interpret, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k, interpret, window, scale=None):
     # Inside shard_map (e.g. the Ulysses body) the inputs carry varying
     # manual axes (vma); the output must declare the same set.
     vma = frozenset().union(*(jax.typeof(x).vma for x in (q, k, v)))
     b, tq, h, d = q.shape
     s = k.shape[1]
     kvh = k.shape[2]
+    dv = v.shape[3]  # a value head may be narrower than a key's (MLA)
     if interpret and vma:
         # The Pallas HLO *interpreter* (off-TPU test path) loses vma on its
         # internal dynamic_slices; run the numerically-identical dense
         # reference there.  Real TPU lowering takes the kernel.
         dispatch.record("flash", "fallback", (b, tq, s, h, kvh, d))
         return _dense_reference(
-            q, k, v, q_positions, k_positions, k_valid, causal, window
+            q, k, v, q_positions, k_positions, k_valid, causal, window, scale
         )
     dispatch.record(
         "flash", "interpret" if interpret else "kernel", (b, tq, s, h, kvh, d)
     )
     assert h % kvh == 0, (h, kvh)
     g = h // kvh
-    scale = d**-0.5
+    if scale is None:
+        scale = d**-0.5
 
     # Q tile: sublane dim of the score tile (min 8 rows); K tile: lane dim
     # (pad short sequences up to one 128-lane tile).
@@ -265,17 +267,17 @@ def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
     nq, nk = tq_p // bq, s_p // bk
     grid = (b, h, nq, nk)
     scratch = [
-        pltpu.VMEM((bq, d), jnp.float32),
+        pltpu.VMEM((bq, dv), jnp.float32),
         pltpu.VMEM((bq, 128), jnp.float32),
         pltpu.VMEM((bq, 128), jnp.float32),
     ]
     q_spec = pl.BlockSpec((1, bq, d), lambda bi, hi, qi, ki: (bi * h + hi, qi, 0))
-    o_spec = pl.BlockSpec((1, bq, d), lambda bi, hi, qi, ki: (bi * h + hi, qi, 0))
-    out_shape = jax.ShapeDtypeStruct((b * h, tq_p, d), q.dtype, vma=vma)
+    o_spec = pl.BlockSpec((1, bq, dv), lambda bi, hi, qi, ki: (bi * h + hi, qi, 0))
+    out_shape = jax.ShapeDtypeStruct((b * h, tq_p, dv), q.dtype, vma=vma)
     args = (
         qt.reshape(b * h, tq_p, d),
         kt.reshape(b * kvh, s_p, d),
-        vt.reshape(b * kvh, s_p, d),
+        vt.reshape(b * kvh, s_p, dv),
     )
 
     if static_causal:
@@ -301,7 +303,7 @@ def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
             in_specs=[
                 q_spec,
                 pl.BlockSpec((1, bk, d), kv_index),
-                pl.BlockSpec((1, bk, d), kv_index),
+                pl.BlockSpec((1, bk, dv), kv_index),
             ],
             out_specs=o_spec,
             out_shape=out_shape,
@@ -349,7 +351,7 @@ def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
                 pl.BlockSpec((1, 1, bk), lambda bi, hi, qi, ki: (bi * nk + ki, 0, 0)),
                 q_spec,
                 pl.BlockSpec((1, bk, d), lambda bi, hi, qi, ki: (bi * kvh + hi // g, ki, 0)),
-                pl.BlockSpec((1, bk, d), lambda bi, hi, qi, ki: (bi * kvh + hi // g, ki, 0)),
+                pl.BlockSpec((1, bk, dv), lambda bi, hi, qi, ki: (bi * kvh + hi // g, ki, 0)),
             ],
             out_specs=o_spec,
             out_shape=out_shape,
@@ -362,7 +364,7 @@ def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
             kval.reshape(b * nk, 1, bk),
             *args,
         )
-    out = out.reshape(b, h, tq_p, d)[:, :, :tq]
+    out = out.reshape(b, h, tq_p, dv)[:, :, :tq]
     return out.transpose(0, 2, 1, 3)
 
 
@@ -371,7 +373,7 @@ def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
 # ---------------------------------------------------------------------------
 
 def _dense_reference(q, k, v, q_positions, k_positions, k_valid, causal,
-                     window=None):
+                     window=None, scale=None):
     """Same math and masking semantics as the kernel, in plain XLA ops — the
     VJP target for the backward pass."""
     b, tq, h, d = q.shape
@@ -379,9 +381,11 @@ def _dense_reference(q, k, v, q_positions, k_positions, k_valid, causal,
     g = h // kvh
     if g > 1:
         k = jnp.broadcast_to(k[:, :, :, None, :], (b, s, kvh, g, d)).reshape(b, s, h, d)
-        v = jnp.broadcast_to(v[:, :, :, None, :], (b, s, kvh, g, d)).reshape(b, s, h, d)
+        v = jnp.broadcast_to(
+            v[:, :, :, None, :], (b, s, kvh, g, v.shape[3])
+        ).reshape(b, s, h, v.shape[3])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
-    logits = logits * (d**-0.5)
+    logits = logits * (d**-0.5 if scale is None else scale)
     qp = (
         jnp.broadcast_to(jnp.arange(tq, dtype=jnp.int32), (b, tq))
         if q_positions is None
@@ -408,19 +412,20 @@ def _dense_reference(q, k, v, q_positions, k_positions, k_valid, causal,
     return out.astype(q.dtype)
 
 
-def _flash_fwd(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k, interpret, window):
+def _flash_fwd(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k, interpret, window, scale):
     out = _flash(
         q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
-        interpret, window,
+        interpret, window, scale,
     )
     return out, (q, k, v, q_positions, k_positions, k_valid)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, window, res, g):
+def _flash_bwd(causal, block_q, block_k, interpret, window, scale, res, g):
     q, k, v, q_positions, k_positions, k_valid = res
     _, vjp = jax.vjp(
         lambda q_, k_, v_: _dense_reference(
-            q_, k_, v_, q_positions, k_positions, k_valid, causal, window
+            q_, k_, v_, q_positions, k_positions, k_valid, causal, window,
+            scale,
         ),
         q, k, v,
     )
@@ -434,7 +439,8 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "block_q", "block_k", "interpret", "window"),
+    static_argnames=("causal", "block_q", "block_k", "interpret", "window",
+                     "scale"),
 )
 def flash_attention(
     q: jax.Array,  # [B, Tq, H, D]
@@ -451,11 +457,15 @@ def flash_attention(
     #   semantics: keys at positions (p - window, p]); static.  The
     #   static-causal path skips — and never DMAs — tiles fully outside
     #   the window band, so windowed prefill work scales with the window.
+    scale: float | None = None,  # the softmax scale; None: D ** -0.5.  Static
+    #   (latent attention's is YaRN's, and its zero-padded heads' width is
+    #   not the width the scale is of)
 ) -> jax.Array:
     """Fused attention.  Matches ``layers.dot_product_attention`` with mask
     ``(k_pos <= q_pos if causal) & k_valid [& window band]`` but never
-    materializes the [Tq, S] score matrix in the forward.  Differentiable
-    (dense-recompute backward).  Returns [B, Tq, H, D] in q.dtype."""
+    materializes the [Tq, S] score matrix in the forward.  v's heads may be
+    of another width Dv than q's and k's.  Differentiable (dense-recompute
+    backward).  Returns [B, Tq, H, Dv] in q.dtype."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if window is not None:
@@ -465,5 +475,5 @@ def flash_attention(
             raise ValueError(f"window must be >= 1, got {window}")
     return _flash(
         q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
-        interpret, window,
+        interpret, window, scale,
     )

@@ -176,10 +176,16 @@ def _sample_first(logits, last_idx, rng, temperature, top_k, top_p,
 
 
 def _prefill_row(fwd, params, cfg, cache_dtype, s, prompt, plen=None):
-    """Dense causal prefill of one request into a transient single-row
-    cache (flash-eligible: attn_mask=None) — shared by the contiguous and
-    paged admissions.  ``fwd`` is _fwd(pm): the mesh-parallel forward on a
-    mesh batcher, the plain model forward otherwise.  A model with state
+    """Causal prefill of one request into a transient single-row cache of
+    ``s`` slots — shared by the contiguous and paged admissions.  The model
+    is told, while tracing, that the row STARTS here (the Python 0, no
+    mask): the prompt's T tokens attend among themselves and take the
+    cache's first T slots, and no slot past T is read or scored, however
+    long the cache (models.model._self_attention: the flash kernel on the
+    chip for heads of whole 128-lane registers, dense over the T keys for
+    other heads, on the CPU and under a mesh).  ``fwd`` is _fwd(pm): the
+    mesh-parallel forward on a mesh batcher, the plain model forward
+    otherwise.  A model with state
     that is not keys and values (family "hybrid") is told the prompt's true
     length ``plen``, leaves in the row cache the state AT that length, not
     at the padded bucket's end, and returns its expert layers' counts of
@@ -189,12 +195,9 @@ def _prefill_row(fwd, params, cfg, cache_dtype, s, prompt, plen=None):
     positions = jnp.arange(tp, dtype=jnp.int32)[None, :]
     state = ({"seq_lens": plen[None], "return_aux": True}
              if cfg.family == "hybrid" else {})
-    # (a model of windowed and full layers must SEE that the row starts
-    # here, while tracing: its rings hold no prefix to continue from)
-    start = 0 if cfg.swa_layers else jnp.int32(0)
     return fwd(
         params, cfg, prompt[None, :], positions=positions,
-        cache=row_cache, cache_index=start, **state,
+        cache=row_cache, cache_index=0, **state,
     )
 
 
@@ -3242,13 +3245,23 @@ class ContinuousBatcher:
                         # stops for this round.
                         return
                     page_list, pages, cached_pages, cached_len, digests = got
+                # A fresh row attends among its own bucket of tokens; one
+                # behind a prefix (named or cached) scores the suffix against
+                # every slot of the row cache.
+                fresh = pfx is None and not cached_len
+                bucket = _bucket(len(req.ids) - cached_len)
                 with self._span(
                     "batcher.admit.row", rid=req.rid,
                     prompt_tokens=total_len, cached_tokens=cached_len,
-                    bucket=_bucket(len(req.ids) - cached_len),
+                    bucket=bucket,
+                    key_slots=min(bucket, self.s) if fresh else self.s,
                 ):
                     self._unqueue(req)
                     self._note_unmatched(req, pfx)
+                    if fresh:
+                        METRICS.inc("batcher.admit.self_attention")
+                    else:
+                        METRICS.inc("batcher.admit.row_cache_attention")
                     # Bucket for compile reuse, but never past what fits after the
                     # prefix: forward's contract is cache_index + T <= max_len, and
                     # dynamic_update_slice CLAMPS an overflowing start — the suffix

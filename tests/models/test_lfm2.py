@@ -87,8 +87,10 @@ def test_state_at_the_buckets_end_would_be_wrong(tiny):
     exact = model_lib.forward(
         params, cfg, jnp.asarray(toks)[None],
         cache=kv_cache.init_cache(cfg, 1, 16), cache_index=jnp.int32(0))[1]
+    # (a row's start is scored over its own T keys, 8 and 5 here: the sums
+    # run in another order, some float32 ulps apart)
     np.testing.assert_allclose(np.asarray(right), np.asarray(exact.conv),
-                               atol=1e-6)
+                               atol=1e-5)
 
 
 def test_a_row_without_a_real_token_keeps_its_state():

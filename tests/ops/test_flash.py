@@ -224,3 +224,58 @@ def test_generate_flash_matches_dot():
     ref = gen_lib.generate_tokens(params, cfg_dot, prompt, lens, rng, max_new_tokens=6)
     out = gen_lib.generate_tokens(params, cfg_flash, prompt, lens, rng, max_new_tokens=6)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+# -- an admission's fresh row (models.model._self_attention), PR 35 ---------
+
+def _admission_case(t, h, kvh, d, dv, seed):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(ks[0], (1, t, h, d), jnp.float32)
+    k = jax.random.normal(ks[1], (1, t, kvh, d), jnp.float32)
+    v = jax.random.normal(ks[2], (1, t, kvh, dv), jnp.float32)
+    pos = jnp.arange(t, dtype=jnp.int32)[None]
+    return q, k, v, layers.causal_mask(pos, pos)
+
+
+@pytest.mark.parametrize("h,kvh,t", [
+    (28, 4, 64), (28, 4, 2048),  # qwen2-7b
+    (32, 32, 256),  # pythia-6.9b
+    (64, 8, 320),  # k-exaone
+])
+def test_the_cells_head_layouts_at_an_admissions_tiles(h, kvh, t):
+    """The static-causal path as an admission calls it: heads of 128, the
+    cells' query heads over their key heads, tiles of 1,024."""
+    q, k, v, mask = _admission_case(t, h, kvh, 128, 128, seed=t)
+    out = flash_attention(q, k, v, block_q=1024, block_k=1024, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_dense(q, k, v, mask)), atol=2e-5)
+
+
+@pytest.mark.parametrize("t", [64, 1024])
+@pytest.mark.parametrize("lanes", [192, 256])
+def test_latent_heads_a_scale_and_a_value_width_of_its_own(t, lanes):
+    """Latent attention's expanded heads: 64 heads of 192 for q and k, 128
+    for v, YaRN's scale.  ``lanes`` 192: the widths as they are (what
+    _self_attention hands the kernel, on the interpreter here); 256: zero
+    lanes on q and k, which add nothing to a score."""
+    scale = 0.1147
+    q, k, v, mask = _admission_case(t, 64, 64, 192, 128, seed=7 + t)
+    want = layers.dot_product_attention(q, k, v, mask, scale)
+    pad = ((0, 0),) * 3 + ((0, lanes - 192),)
+    out = flash_attention(
+        jnp.pad(q, pad), jnp.pad(k, pad), v, block_q=1024, block_k=1024,
+        interpret=True, scale=scale)
+    assert out.shape == (1, t, 64, 128)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+
+
+def test_a_scale_reaches_the_backward_pass():
+    q, k, v, mask = _admission_case(24, 4, 2, 16, 8, seed=11)
+    f = lambda q, k, v: jnp.sum(flash_attention(  # noqa: E731
+        q, k, v, block_q=16, block_k=128, interpret=True, scale=0.3) ** 2)
+    g = lambda q, k, v: jnp.sum(layers.dot_product_attention(  # noqa: E731
+        q, layers.repeat_kv(k, 2), layers.repeat_kv(v, 2), mask, 0.3) ** 2)
+    for got, want in zip(jax.grad(f, (0, 1, 2))(q, k, v),
+                         jax.grad(g, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-4)

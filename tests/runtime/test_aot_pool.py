@@ -279,3 +279,54 @@ def test_an_admission_at_the_8192_bucket_leaves_half_a_gigabyte(
         "dynamic-update-slice", "fusion:dynamic-update-slice"}
     assert {e[0] for e in admit["ring_shaped"]} <= {
         "dynamic-update-slice", "fusion:dynamic-update-slice"}
+
+
+# (preset, slots, max_len, pages, bucket, GB of temporaries held to; what
+# tools/aot_decode.py printed for the parent of PR 35 and for PR 35.  qwen2's
+# are the float32 logits of 2,048 positions, 1.25 GB, then and now.)
+ADMISSIONS = [
+    ("qwen2-7b", 16, 4096, 512, 2048, 1.73),  # 1.746 -> 1.716
+    ("pythia-6.9b", 8, 2048, 96, 512, 1.15),  # 1.343 -> 1.075
+    ("lfm2-8b-a1b", 16, 4096, 512, 2048, 0.60),  # 1.325 -> 0.550
+    ("ax-k1-ep16", 64, 4096, 2176, 2048, 0.80),  # 0.941 -> 0.757
+]
+
+
+@pytest.mark.parametrize("preset,slots,max_len,pages,bucket,temp_gb",
+                         ADMISSIONS, ids=[a[0] for a in ADMISSIONS])
+def test_a_fresh_rows_admission_holds_no_scores_over_the_row_cache(
+        preset, slots, max_len, pages, bucket, temp_gb):
+    """``admit_row_paged`` of the four configurations whose admissions
+    scored a bucket's queries against every slot of the row cache before
+    PR 35, at the cell's shapes and full depth: no float32 ``[.., bucket,
+    max_len]`` is left in the compiled program, and its temporaries are
+    what an admission among its own tokens needs."""
+    from distributed_llms_tpu.models.presets import get_preset
+    from tools import aot_decode
+
+    try:
+        aot_decode.v5e_devices()
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DLT_QUANT_MATMUL", "kernel")
+        mp.setenv("DLT_RAGGED_DECODE", "kernel")
+        mp.setenv("DLT_MOE_EXPERTS", "kernel")
+        admit = aot_decode.analyse(
+            "admit_row_paged", get_preset(preset), slots=slots,
+            max_len=max_len, pages=pages, page_size=BLK, prompt_len=bucket)
+    assert admit["score_shaped"] == []
+    assert admit["temp_gb"] < temp_gb
+    assert admit["argument_gb"] + admit["temp_gb"] < 15.75 - 0.5
+
+
+def test_score_shaped_finds_a_score_matrix():
+    from tools import aot_decode
+
+    hlo = "\n".join([
+        "HloModule m", "ENTRY %main {",
+        "  %a.1 = f32[28,2048,4096]{2,1,0} fusion(%p), kind=kLoop, calls=%f",
+        "  %b.2 = f32[2048,4096]{1,0} copy(%q)",  # a stack of scales
+        "  %c.3 = bf16[28,2048,4096]{2,1,0} copy(%r)", "}"])
+    assert [e[1] for e in aot_decode.score_shaped(hlo, 2048, 4096)] == ["%a.1"]
+    assert aot_decode.score_shaped(hlo, 4096, 4096) == []

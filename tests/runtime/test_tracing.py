@@ -88,6 +88,8 @@ def test_spans_are_host_events_of_the_profile(tiny, tmp_path):
     assert sorted(int(r["rid"]) for r in rows) == sorted(rids)
     assert all(int(r["prompt_tokens"]) >= 1 and "bucket" in r
                and "cached_tokens" in r for r in rows)
+    # fresh rows: a query is scored against its own bucket of keys
+    assert all(int(r["key_slots"]) == int(r["bucket"]) for r in rows)
 
 
 @pytest.mark.parametrize("overlap", [True, False])

@@ -358,6 +358,20 @@ def shaped_like(hlo_text: str, shapes: list, in_loops: bool | None = None
     return found
 
 
+def score_shaped(hlo_text: str, prompt_len: int, max_len: int) -> list:
+    """(opcode, name, result type) of every instruction whose result is a
+    float32 array ``[.., prompt_len, max_len]`` of three or more
+    dimensions: a bucket's queries scored against every slot of the row
+    cache, a head at a time.  Since PR 35 a fresh row attends among its
+    own tokens and ``admit_row_paged`` holds none.  Nothing to tell where
+    the two lengths are equal."""
+    if prompt_len == max_len:
+        return []
+    tail = re.compile(rf"f32\[(\d+,)+{prompt_len},{max_len}\]")
+    return [e for e in shaped_like(hlo_text, [f"{prompt_len},{max_len}]"])
+            if tail.search(e[2])]
+
+
 def in_place_scatter(entry: tuple) -> bool:
     """Whether a :func:`pool_shaped` entry is a scatter (bare or the root
     of a fusion): the one update the pool takes where it lies."""
@@ -381,6 +395,9 @@ def analyse(program: str, cfg, **shape_kw) -> dict:
             list(e) for e in weight_shaped(text, shapes, in_loops=False)],
         "weight_prefetched": [list(e) for e in weight_shaped(
             text, shapes, in_loops=None, prefetched=True)],
+        "score_shaped": [list(e) for e in score_shaped(
+            text, shape_kw.get("prompt_len", 512), shape_kw["max_len"])]
+        if program.startswith("admit_row") else [],
         "program": program,
         "argument_gb": mem.argument_size_in_bytes / 1e9,
         "output_gb": mem.output_size_in_bytes / 1e9,

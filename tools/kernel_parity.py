@@ -225,6 +225,36 @@ def flash_parity() -> None:
         want = _dense_reference(q, kk, v, None, None, None, True, win)
         check(f"flash T{t} H{h}/{kvh} win{win} tiles{block}", got, want,
               rtol=3e-2, atol=3e-2)
+    # Admissions since PR 35 (a fresh row attends among its own tokens):
+    # the static-causal path in tiles of 1,024 at qwen2-7b's and
+    # pythia-6.9b's head layouts, a small and the largest bucket; and a
+    # scale and a value width of the kernel's own at A.X-K1's expanded
+    # latent heads, 64 of 192 for q and k and 128 for v (their widths as
+    # they are, and with zero lanes to 256: the same bits).  That cell's
+    # admissions take the dense body (its golden: models.model.
+    # _self_attention); a latent model with heads of whole registers would
+    # take this one.
+    for t, h, kvh, d, dv, scale in (
+            (64, 28, 4, 128, 128, None), (2048, 28, 4, 128, 128, None),
+            (512, 32, 32, 128, 128, None),
+            (2048 if ON_TPU else 512, 64, 64, 192, 128, 0.1147)):
+        ks = jax.random.split(jax.random.fold_in(key, 13 + t + h), 3)
+        q = jax.random.normal(ks[0], (1, t, h, d), jnp.bfloat16)
+        kk = jax.random.normal(ks[1], (1, t, kvh, d), jnp.bfloat16)
+        v = jax.random.normal(ks[2], (1, t, kvh, dv), jnp.bfloat16)
+        run = jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=1024, block_k=1024,
+            interpret=not ON_TPU, scale=scale))
+        got = run(q, kk, v)
+        want = _dense_reference(q, kk, v, None, None, None, True, None, scale)
+        check(f"flash admission T{t} H{h}/{kvh} D{d}/{dv}", got, want,
+              rtol=3e-2, atol=3e-2)
+        if d % 128:
+            pad = ((0, 0),) * 3 + ((0, -d % 128),)
+            if not bool(jnp.all(run(jnp.pad(q, pad), jnp.pad(kk, pad), v)
+                                == got)):
+                raise AssertionError(
+                    f"zero lanes to {d + -d % 128} change the output")
 
 
 # The paged legs' rows: 19 page slots each (no run length divides it), six
@@ -580,8 +610,12 @@ def main() -> int:
     # the paged kernel at 64 query heads over 8 on rows 128 pages deep, the
     # rings' kernel (swa_decode_attn: short, full, wrapped, NaNs past the
     # count) and the expert kernel holding 16 of 128 at K-EXAONE's widths
-    # and the flash kernel as its admissions call it — 46 legs.
-    print(f"kernel_parity: ALL PASS v11 ({mode}, backend={backend})")
+    # and the flash kernel as its admissions call it — 46 legs.  v12: the
+    # flash kernel as the dense cells' admissions call it since PR 35, at
+    # qwen2-7b's and pythia-6.9b's heads, and with a scale and a value
+    # width of its own (192 / 128: A.X-K1's expanded latent heads) — 50
+    # legs.
+    print(f"kernel_parity: ALL PASS v12 ({mode}, backend={backend})")
     return 0
 
 
