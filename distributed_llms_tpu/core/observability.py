@@ -13,7 +13,6 @@ from __future__ import annotations
 import json
 import logging
 import threading
-import time
 from collections import defaultdict
 from dataclasses import dataclass, field
 
@@ -115,9 +114,6 @@ class Metrics:
         with self._lock:
             self._hists[name].observe(value)
 
-    def timer(self, name: str) -> "_Timer":
-        return _Timer(self, name)
-
     def get_counter(self, name: str) -> float:
         """Point read of one counter (0.0 when never incremented) — the
         supervisor's restart accounting and tests read through this
@@ -178,23 +174,10 @@ class Metrics:
         return "\n".join(lines) + "\n"
 
 
-class _Timer:
-    def __init__(self, metrics: Metrics, name: str) -> None:
-        self._metrics = metrics
-        self._name = name
-
-    def __enter__(self) -> "_Timer":
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self._metrics.observe(self._name, time.perf_counter() - self._t0)
-
-
 METRICS = Metrics()
 
 # THE registry of metric names this package emits.  Every name passed to
-# METRICS.inc/set_gauge/set_gauges/observe/timer must appear here (or match
+# METRICS.inc/set_gauge/set_gauges/observe must appear here (or match
 # a declared ``*`` pattern — f-string names register VERBATIM as their
 # pattern, e.g. ``faults.fired.*``).  graftlint's GL302 pins emission
 # sites to this dict, GL305 flags dead entries, and the README metric
@@ -265,6 +248,14 @@ METRIC_DOCS: dict[str, str] = {
                                  "annotation carries rid, prompt_tokens, "
                                  "cached_tokens, bucket, key_slots: the "
                                  "keys a query of it is scored against)",
+    "batcher.admit.wait_device_seconds": "the engine thread blocked in an "
+                                         "admission's ONE device_get "
+                                         "(first token, logprob, expert "
+                                         "counts), inside "
+                                         "batcher.admit.row: prefill on "
+                                         "the chip; row minus this is the "
+                                         "host's part of an admission "
+                                         "(histogram)",
     "batcher.admit.self_attention": "admissions whose attention read their "
                                     "own bucket of tokens: a fresh row, "
                                     "whose start the model sees while "
@@ -288,6 +279,30 @@ METRIC_DOCS: dict[str, str] = {
     "batcher.queue_wait_seconds": "submit (or the requeue after a "
                                   "preemption) to admission start, one "
                                   "sample per admission (histogram)",
+    # -- the time no model program was in flight (from a blocking fetch
+    #    that returned the newest one's output to the next dispatch call),
+    #    charged to the batcher.loop.* span it fell in: a lower bound of
+    #    the device's idle time, over the whole window.  Time parked with
+    #    no request is not counted. --
+    "batcher.starved.admit_seconds": "seconds the device had nothing to "
+                                     "run inside admission rounds: host "
+                                     "work before an admission's dispatch "
+                                     "and after its fetch (counter)",
+    "batcher.starved.grow_seconds": "... inside chunk-boundary page "
+                                    "growth (counter)",
+    "batcher.starved.plan_seconds": "... inside span planning: the turn "
+                                    "into a decode span (counter; 0 for "
+                                    "dispatched-ahead chunks)",
+    "batcher.starved.dispatch_seconds": "... inside batcher.loop.dispatch, "
+                                        "up to the call that dispatches "
+                                        "the chunk (counter)",
+    "batcher.starved.deliver_seconds": "... inside delivery after a span's "
+                                       "sync (counter; 0 while a chunk is "
+                                       "dispatched ahead)",
+    "batcher.decode.chunks": "decode / speculative / mixed chunks "
+                             "dispatched: the loop's wait_device + plan + "
+                             "dispatch + deliver + grow seconds over "
+                             "chunks x chunk_steps is the engine's step",
     "batcher.decode.slot_steps": "decode legs the dispatched chunks had "
                                  "room for (slots x chunk_steps per "
                                  "chunk): decode_tokens / slot_steps is "

@@ -53,8 +53,12 @@ class span:
     it caused) and, on exit, ``METRICS.observe(name + "_seconds", dt)`` (so
     /metrics and the benchmark read the same interval as a window
     difference).  ``clock`` is injectable: the batcher passes its lockstep
-    clock, tests a fake one.  With no profiler session the annotation is a
-    flag test; the histogram is one lock and one append.
+    clock, tests a fake one.  ``on_exit`` is called with the clock reading
+    the histogram closes on: accounting that must split at the span's own
+    boundary (the batcher charges the time no program was in flight to the
+    loop span it fell in) hangs there and reads no second clock.  With no
+    profiler session the annotation is a flag test; the histogram is one
+    lock and one append.
 
         with span("batcher.loop.admit"):
             ...
@@ -64,11 +68,13 @@ class span:
     literals registered in METRIC_DOCS as ``<name>_seconds`` (graftlint
     GL302 reads ``span("...")`` calls as emitters of that histogram)."""
 
-    __slots__ = ("_name", "_clock", "_ann", "_t0")
+    __slots__ = ("_name", "_clock", "_on_exit", "_ann", "_t0")
 
-    def __init__(self, name: str, clock=time.perf_counter, **attrs) -> None:
+    def __init__(self, name: str, clock=time.perf_counter, on_exit=None,
+                 **attrs) -> None:
         self._name = name
         self._clock = clock
+        self._on_exit = on_exit
         self._ann = annotate(name, **attrs)
 
     def __enter__(self) -> "span":
@@ -77,10 +83,12 @@ class span:
         return self
 
     def __exit__(self, *exc) -> None:
-        dt = self._clock() - self._t0
+        now = self._clock()
         self._ann.__exit__(*exc)
+        if self._on_exit is not None:
+            self._on_exit(now)
         # graftlint: ignore[GL302](the name is the span's: GL302 checks every span("...") call site against METRIC_DOCS as "<name>_seconds")
-        METRICS.observe(self._name + "_seconds", dt)
+        METRICS.observe(self._name + "_seconds", now - self._t0)
 
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"

@@ -1534,10 +1534,9 @@ class ContinuousBatcher:
         # (e.g. derived from the scheduling round counter) so every
         # process sheds the same requests in the same round — decision
         # paths reading the wall clock directly are a graftsync GS101
-        # finding (LOCKSTEP_DECISIONS, runtime/scheduler.py).  Metrics
-        # and timer stamps (_t_complete, host-lag) are observability,
-        # not decisions, and stay on the wall clock at the declared
-        # HOST_SYNC_SITES.
+        # finding (LOCKSTEP_DECISIONS, runtime/scheduler.py).  The spans
+        # and the starved-time account read this clock too: they are
+        # observability, never decisions.
         clock: "Callable[[], float] | None" = None,
     ) -> None:
         # Snapshot the constructor arguments FIRST (before any local
@@ -1918,21 +1917,28 @@ class ContinuousBatcher:
         # never see a penalty).
         self.tok_counts: jax.Array | None = None
         self.rows = [_RowState() for _ in range(batch_slots)]
-        # Dispatch-ahead engine loop (overlap): per-batcher counters the
-        # bench and tests read directly (mirrored into METRICS as they
-        # accrue).  ``_cancel_dirty`` flags a resident-row cancel taken
-        # while the decode carry was device-resident — the next chunk
-        # boundary must SYNC so the cancelled row actually stops;
-        # ``_t_complete`` stamps when the host last observed a chunk
-        # complete (the device-gap metric's reference point).
+        # Dispatch-ahead engine loop (overlap): per-batcher counts the
+        # tests read directly, each beside its METRICS counter
+        # (``batcher.overlap.*``).  ``_cancel_dirty`` flags a resident-row
+        # cancel taken while the decode carry was device-resident — the
+        # next chunk boundary must SYNC so the cancelled row actually
+        # stops.
         self.overlap = bool(overlap)
-        self.overlap_stats = {
-            "chunks": 0, "dispatched_ahead": 0, "carry_syncs": 0,
-            "host_lag_s": 0.0, "device_gap_s": 0.0, "gap_samples": 0,
-        }
+        self.overlap_stats = {"dispatched_ahead": 0, "carry_syncs": 0}
         self._cancel_dirty = False
         self._tables_dirty = False
-        self._t_complete: float | None = None
+        # The engine thread's account of the device (``_launch``,
+        # ``_note_fetched``, ``_charge_starved``), on the batcher's clock:
+        # model programs dispatched and, of them, those a blocking fetch
+        # has shown complete (the device runs them in order, so one count
+        # each); while the two are equal nothing is in flight and
+        # ``_starved_at`` is since when (None while something is, and
+        # before a run's first dispatch: a parked engine is not starved);
+        # ``_loop_at`` names the batcher.loop.* span the thread is in.
+        self._n_dispatched = 0
+        self._n_fetched = 0
+        self._starved_at: float | None = None
+        self._loop_at: str | None = None
         # Submission lock: the ONE cross-thread boundary of this class.
         # Serving front-ends submit() from their own thread while the
         # engine thread scans/admits; PR 3 relied on GIL-atomic deque ops
@@ -2500,9 +2506,77 @@ class ContinuousBatcher:
         return rid
 
     def _span(self, name: str, **attrs) -> profiling.span:
-        """A :class:`core.profiling.span` on the batcher's clock."""
+        """A :class:`core.profiling.span` on the batcher's clock.  The six
+        ``batcher.loop.*`` spans partition the engine thread's time, so
+        each also says which of them the thread is in and, on exit, takes
+        the starved time that fell in it (:meth:`_charge_starved`)."""
+        on_exit = None
+        if name.startswith("batcher.loop."):
+            self._loop_at = name
+            on_exit = self._charge_starved
         # graftlint: ignore[GL302](forwarded: GL302 checks the self._span("...") call sites)
-        return profiling.span(name, clock=self._clock, **attrs)
+        return profiling.span(name, clock=self._clock, on_exit=on_exit,
+                              **attrs)
+
+    def _launch(self, program, *operands, **kw):
+        """Dispatch a model program (an admission program, a prefill bite,
+        a draft prefill, a decode / speculative / mixed chunk: what keeps
+        the device busy until a later fetch; not ``_split_rng``'s split or
+        a one-row scatter).  The caller's operands are built by now (Python
+        evaluated them before this call), so if nothing was in flight the
+        starved time ends HERE, at the call that dispatches.  The program's
+        ticket for :meth:`_note_fetched` is ``_n_dispatched`` on return."""
+        if self._starved_at is not None:
+            self._charge_starved(self._clock())
+            self._starved_at = None
+        self._n_dispatched += 1
+        return program(*operands, **kw)
+
+    def _note_fetched(self, ticket: int) -> None:
+        """A blocking fetch (under a ``*.wait_device`` span) returned an
+        output of program ``ticket``: it and every program dispatched
+        before it are complete.  If none was dispatched after it the
+        device is starved from now until the next :meth:`_launch`.
+        A lower bound of the device's idle time by construction: the
+        device finished a little before the fetch returned and starts a
+        little after the dispatch call."""
+        self._n_fetched = max(self._n_fetched, ticket)
+        if self._n_fetched == self._n_dispatched:
+            self._starved_at = self._clock()
+
+    def _charge_starved(self, now: float) -> None:
+        """Add the time nothing has been in flight since it was last
+        charged, up to ``now``, to the counter of the loop span the thread
+        is in: a gap that crosses spans is split at their boundaries (each
+        span's exit calls this).  What falls between two spans goes to the
+        later one; ``wait_device`` has no counter (something is in flight
+        whenever the thread enters it)."""
+        if self._starved_at is None:
+            return
+        dt, self._starved_at = now - self._starved_at, now
+        match self._loop_at:
+            case "batcher.loop.admit":
+                METRICS.inc("batcher.starved.admit_seconds", dt)
+            case "batcher.loop.grow":
+                METRICS.inc("batcher.starved.grow_seconds", dt)
+            case "batcher.loop.plan":
+                METRICS.inc("batcher.starved.plan_seconds", dt)
+            case "batcher.loop.dispatch":
+                METRICS.inc("batcher.starved.dispatch_seconds", dt)
+            case "batcher.loop.deliver":
+                METRICS.inc("batcher.starved.deliver_seconds", dt)
+
+    def _fetch_admission(self, ticket: int, *outs) -> tuple:
+        """The ONE blocking fetch of an admission: what the host needs of
+        its outputs (first token, its logprob, and where the program
+        hands them out the row's mask and the expert counts) in one
+        ``jax.device_get``, after every dispatch the admission makes.  A
+        row's self time (``batcher.admit.row`` minus this span) is the
+        host's part of an admission."""
+        with self._span("batcher.admit.wait_device"):
+            host = jax.device_get(outs)
+        self._note_fetched(ticket)
+        return host
 
     def _note_resident(self, req: "_Request") -> None:
         """An admission ended (row resident, or finished by its admission
@@ -2918,7 +2992,8 @@ class ContinuousBatcher:
             td = min(_bucket(len(seed_ids)), self.s)
             dprompt = np.full((td,), self.pad_id, np.int32)
             dprompt[: len(seed_ids)] = seed_ids
-            self.draft_cache = admit_row_kv(
+            self.draft_cache = self._launch(
+                admit_row_kv,
                 self.draft_params, self.draft_cfg, self.draft_cache,
                 jnp.int32(i), jnp.asarray(dprompt),
                 jnp.int32(len(seed_ids)),
@@ -3293,9 +3368,10 @@ class ContinuousBatcher:
                         # prefix to recover the state first).
                         st0 = req.constraint.advance(0, req.resume_emitted or [])
                         extra["mask_req"] = jnp.asarray(req.constraint.bias[st0])
+                    moe: list = []
                     if self.paged and pfx is not None:
-                        self.cache, tok, lp = admit_row_with_prefix_paged(
-                            self.params, self.cfg, self.cache, jnp.asarray(page_list),
+                        self.cache, tok, lp = self._launch(
+                            admit_row_with_prefix_paged, self.params, self.cfg, self.cache, jnp.asarray(page_list),
                             pfx.k, pfx.v, jnp.int32(pfx_len),
                             jnp.asarray(prompt), jnp.int32(len(req.ids)),
                             self._split_rng(), pm=self.pm, **self.sampling, **extra,
@@ -3312,38 +3388,41 @@ class ContinuousBatcher:
                         tc = min(_bucket(len(suffix)), self.s - cached_len)
                         chunk = np.full((tc,), self.pad_id, np.int32)
                         chunk[: len(suffix)] = suffix
-                        self.cache, tok, lp, *moe = admit_row_auto_paged(
+                        self.cache, tok, lp, *moe = self._launch(
+                            admit_row_auto_paged,
                             self.params, self.cfg, self.cache,
                             jnp.asarray(page_list), jnp.asarray(write_list),
                             jnp.int32(cached_len), jnp.asarray(chunk),
                             jnp.int32(len(suffix)), self._split_rng(),
                             pm=self.pm, **self.sampling, **extra,
                         )
-                        self._note_moe(*moe)
                         row_valid = np.arange(self.valid.shape[1]) < total_len
                     elif self.paged:
                         if self.cfg.family == "hybrid":
                             extra["slot"] = jnp.int32(i)
-                        self.cache, tok, lp, *moe = admit_row_paged(
+                        self.cache, tok, lp, *moe = self._launch(
+                            admit_row_paged,
                             self.params, self.cfg, self.cache, jnp.asarray(page_list),
                             jnp.asarray(prompt), jnp.int32(len(req.ids)),
                             self._split_rng(), pm=self.pm, **self.sampling, **extra,
                         )
-                        self._note_moe(*moe)
                         row_valid = np.arange(self.valid.shape[1]) < total_len
                     elif pfx is not None:
-                        self.cache, tok, row_valid, lp = admit_row_with_prefix(
+                        self.cache, tok, row_valid, lp = self._launch(
+                            admit_row_with_prefix,
                             self.params, self.cfg, self.cache, jnp.int32(i),
                             pfx.k, pfx.v, jnp.int32(pfx_len),
                             jnp.asarray(prompt), jnp.int32(len(req.ids)),
                             self._split_rng(), pm=self.pm, **self.sampling, **extra,
                         )
                     else:
-                        self.cache, tok, row_valid, lp = admit_row(
+                        self.cache, tok, row_valid, lp = self._launch(
+                            admit_row,
                             self.params, self.cfg, self.cache, jnp.int32(i),
                             jnp.asarray(prompt), jnp.int32(len(req.ids)),
                             self._split_rng(), pm=self.pm, **self.sampling, **extra,
                         )
+                    ticket = self._n_dispatched
                     if digests:
                         # Publish the row's full prompt pages (first writer wins;
                         # a digest another page already holds leaves ours private).
@@ -3360,11 +3439,15 @@ class ContinuousBatcher:
                         td = min(_bucket(len(full_ids)), self.s)
                         dprompt = np.full((td,), self.pad_id, np.int32)
                         dprompt[: len(full_ids)] = full_ids
-                        self.draft_cache = admit_row_kv(
+                        self.draft_cache = self._launch(
+                            admit_row_kv,
                             self.draft_params, self.draft_cfg, self.draft_cache,
                             jnp.int32(i), jnp.asarray(dprompt),
                             jnp.int32(len(full_ids)),
                         )
+                    tok, lp, row_valid, moe = self._fetch_admission(
+                        ticket, tok, lp, row_valid, moe)
+                    self._note_moe(*moe)
                     self._activate_row(i, req, tok, lp, row_valid, total_len,
                                        req_t, req_p, cached_pages + pages,
                                        req_k=req_k, cached_len=cached_len)
@@ -3373,8 +3456,10 @@ class ContinuousBatcher:
                       req_t, req_p, pages, req_k=None, cached_len=0):
         """Host bookkeeping tail of EVERY admission (monolithic and
         chunked): record the sampled first token, arm the row's scheduling
-        state, stream the token."""
-        tok = int(tok)  # replicated scalar — identical on every process
+        state, stream the token.  ``tok``, ``lp`` and ``row_valid`` are host
+        values (:meth:`_fetch_admission`; replicated outputs, identical on
+        every process)."""
+        tok = int(tok)
         self.last_tok[i] = tok
         self.spec_ema[i] = 1.0  # fresh rows draft the full k (optimistic)
         if req.constraint is not None:
@@ -3419,7 +3504,7 @@ class ContinuousBatcher:
                     self.tok_counts, jnp.int32(i), jnp.int32(tok)
                 )
         self.real_lens[i] = total_len
-        self.valid[i] = np.asarray(row_valid)
+        self.valid[i] = row_valid
         self.active[i] = True
         # The first token came out of admission; the row may emit
         # budget-1 more from decode chunks.
@@ -3547,8 +3632,8 @@ class ContinuousBatcher:
             tc = min(_bucket(clen), self.s - pp.done)
             chunk = np.full((tc,), self.pad_id, np.int32)
             chunk[:clen] = pp.ids[off: off + clen]
-            pp.row_k, pp.row_v, pp.last_logits = prefill_chunk_step(
-                self.params, self.cfg, pp.row_k, pp.row_v, jnp.int32(pp.done),
+            pp.row_k, pp.row_v, pp.last_logits = self._launch(
+                prefill_chunk_step, self.params, self.cfg, pp.row_k, pp.row_v, jnp.int32(pp.done),
                 jnp.asarray(chunk), jnp.int32(clen), pm=self.pm,
             )
             pp.done += clen
@@ -3603,8 +3688,8 @@ class ContinuousBatcher:
             # reading them (same write routing as admit_row_auto_paged).
             write_list = page_list.copy()
             write_list[:n_cached] = 0
-            self.cache, tok, lp = finish_chunked_admission_paged(
-                self.cache, jnp.asarray(write_list), pp.row_k, pp.row_v,
+            self.cache, tok, lp = self._launch(
+                finish_chunked_admission_paged, self.cache, jnp.asarray(write_list), pp.row_k, pp.row_v,
                 pp.last_logits, self._split_rng(), pm=self.pm,
                 **self.sampling, **extra,
             )
@@ -3616,12 +3701,14 @@ class ContinuousBatcher:
             pages = pp.cached_pages + pages
         else:
             pages = []
-            self.cache, tok, row_valid, lp = finish_chunked_admission(
-                self.cfg, self.cache, jnp.int32(i), pp.row_k, pp.row_v,
+            self.cache, tok, row_valid, lp = self._launch(
+                finish_chunked_admission, self.cfg, self.cache, jnp.int32(i), pp.row_k, pp.row_v,
                 pp.last_logits, jnp.int32(pp.total_len), self._split_rng(),
                 pm=self.pm, **self.sampling, **extra,
             )
         del self._prefills[i]
+        tok, lp, row_valid = self._fetch_admission(
+            self._n_dispatched, tok, lp, row_valid)
         self._activate_row(i, req, tok, lp, row_valid, pp.total_len,
                            req_t, req_p, pages=pages, req_k=req_k,
                            cached_len=pp.cached_len)
@@ -3735,9 +3822,9 @@ class ContinuousBatcher:
             self._on_tokens = None
 
     def _run_loop(self) -> dict[int, list[int]]:
-        # Publish any 1-token requests finished by admission alone.
-        self._t_complete = None  # device-gap timing: a fresh run's first
-        #                          chunk follows no observed completion
+        # A fresh run's first dispatch follows no observed completion: since
+        # the last run's last fetch the engine was parked, not starved.
+        self._starved_at = None
         while self.has_queued() or bool(self.active.any()) or any(
             r.rid is not None for r in self.rows
         ) or self.has_kv_imports() or self.has_kv_exports():
@@ -3752,7 +3839,7 @@ class ContinuousBatcher:
                 self._grow_rows()
             was_active = self.active.copy()
             if not was_active.any():
-                self._t_complete = None  # idle boundary: no chunk to gap
+                # Publish any 1-token requests finished by admission alone.
                 with self._span("batcher.loop.deliver"):
                     self._collect(
                         np.zeros((self.b, 0), np.int32), was_active
@@ -3907,10 +3994,12 @@ class ContinuousBatcher:
         scheduling carry (last_tok, real_lens, valid, active, budget):
         host mirrors for the first chunk of a span, the PREVIOUS chunk's
         device-resident outputs for a dispatched-ahead chunk — both feed
-        the same compiled program.  Returns (toks, lps, m, carry', moe)
-        with ``m`` the speculative per-row commit counts (None on the plain
-        path) and ``moe`` a hybrid model's expert counts of the chunk (None
-        for any other model); ``self.cache``/``self.draft_cache``/``self.tok_counts``
+        the same compiled program.  Returns (toks, lps, m, carry', moe,
+        ticket) with ``m`` the speculative per-row commit counts (None on
+        the plain path), ``moe`` a hybrid model's expert counts of the chunk
+        (None for any other model) and ``ticket`` the chunk's place in the
+        order of dispatches (:meth:`_launch`);
+        ``self.cache``/``self.draft_cache``/``self.tok_counts``
         advance to the new chunk's (not-yet-materialized) outputs."""
         with self._span("batcher.loop.dispatch"):
             if self._tables_dirty:
@@ -3919,7 +4008,7 @@ class ContinuousBatcher:
                 plan["tables"] = jnp.asarray(self.tables)
                 self._tables_dirty = False
             last_tok, real_lens, valid, active, budget = carry
-            self.overlap_stats["chunks"] += 1
+            METRICS.inc("batcher.decode.chunks")
             m = moe = None
             dfa_out = None
             if self.speculative:
@@ -3980,8 +4069,8 @@ class ContinuousBatcher:
                     METRICS.inc("batcher.spec.k_downshifts")
                     self.spec_stats["downshifts"] += 1
                 (toks, m, lps, self.cache, self.draft_cache, last_tok,
-                 real_lens, valid, active, budget, counts_out) = spec_chunk(
-                    self.params, self.cfg, self.draft_params, self.draft_cfg,
+                 real_lens, valid, active, budget, counts_out) = self._launch(
+                    spec_chunk, self.params, self.cfg, self.draft_params, self.draft_cfg,
                     self.cache, self.draft_cache, last_tok, real_lens, valid,
                     active, budget, k=self.spec_k, eos_id=self.eos_id,
                     pad_id=self.pad_id, tables=plan["tables"],
@@ -4015,8 +4104,8 @@ class ContinuousBatcher:
                         self.faults.fire("batcher.mixed_step", tag="decode")
                     (toks, self.cache, last_tok, real_lens, valid, active,
                      budget, lps, counts_out, dfa_out, *moe) = \
-                        decode_chunk(
-                            self.params, self.cfg_decode, self.cache, last_tok,
+                        self._launch(
+                            decode_chunk, self.params, self.cfg_decode, self.cache, last_tok,
                             real_lens, valid, active, budget,
                             self._split_rng(), self.chunk_steps,
                             eos_id=self.eos_id, pad_id=self.pad_id, pm=self.pm,
@@ -4031,7 +4120,7 @@ class ContinuousBatcher:
             if dfa_out is not None:
                 self._dfa_carry = dfa_out
             return (toks, lps, m, (last_tok, real_lens, valid, active, budget),
-                    moe)
+                    moe, self._n_dispatched)
 
     def _mixed_width(self, done: int) -> int:
         """Prefill-leg width of a fused step: ONE bucket sized to the
@@ -4102,8 +4191,8 @@ class ContinuousBatcher:
             self.faults.fire("batcher.mixed_step", tag="prefill")
         (toks, cache, last_tok, real_lens, valid, active, budget, lps,
          counts_out, dfa_out, pp.row_k, pp.row_v, pp.last_logits) = \
-            mixed_step(
-                self.params, self.cfg_decode, self.cfg, self.cache,
+            self._launch(
+                mixed_step, self.params, self.cfg_decode, self.cfg, self.cache,
                 last_tok, real_lens, valid, active, budget,
                 self._split_rng(), self.chunk_steps,
                 pp.row_k, pp.row_v, jnp.int32(pp.done),
@@ -4207,15 +4296,6 @@ class ContinuousBatcher:
         if acc + rej:
             METRICS.observe("engine.spec_acceptance", acc / (acc + rej))
 
-    def _note_gap(self, gap_s: float) -> None:
-        """Record one per-chunk device gap: the host time between the
-        previous chunk completing and this chunk dispatching.  A
-        dispatched-ahead chunk records 0 by construction — its dispatch
-        strictly precedes the predecessor's completion, so the device
-        stream runs back-to-back."""
-        self.overlap_stats["device_gap_s"] += gap_s
-        self.overlap_stats["gap_samples"] += 1
-
     def _grow_ahead(self, horizon_chunks: int) -> bool:
         """Page growth ON the overlapped window: growth needs the page
         POOL, not the carry mirrors, so a span can keep dispatching ahead
@@ -4278,13 +4358,13 @@ class ContinuousBatcher:
     def _note_moe(self, stats=None) -> None:
         """Add a program's expert counts (layers.moe_dropless, real tokens
         only) to ``moe.*``: what a hybrid model's admission or decode chunk
-        handed out beside its tokens, None for any other model.  Four
-        counts; a fifth where the config holds a chip's share of the
-        experts; a decode chunk against latent pages hands out six, one
-        against pages and rings seven (_decode_steps)."""
+        handed out beside its tokens, fetched with them (host values), None
+        for any other model.  Four counts; a fifth where the config holds a
+        chip's share of the experts; a decode chunk against latent pages
+        hands out six, one against pages and rings seven (_decode_steps)."""
         if stats is None:
             return
-        counts = [int(x) for x in np.asarray(stats)]
+        counts = [int(x) for x in stats]
         METRICS.inc("moe.routed_pairs", counts[0])
         METRICS.inc("moe.layer_passes", counts[1])
         METRICS.inc("moe.experts_touched", counts[2])
@@ -4305,27 +4385,28 @@ class ContinuousBatcher:
         ONE ``jax.device_get`` (blocks until the chunk completes — the
         NEXT chunk is already executing behind it).  The rest of the
         carry stays device-resident."""
-        toks, lps, m, carry, moe = out
+        toks, lps, m, carry, moe, ticket = out
         with self._span("batcher.loop.wait_device"):
             toks_h, lps_h, m_h, moe_h, active_h = jax.device_get(
                 (toks, lps, m, moe, carry[3]))
-        self._t_complete = time.perf_counter()
+        self._note_fetched(ticket)
         return toks_h, lps_h, m_h, moe_h, active_h
 
     def _sync_carry(self, out: tuple) -> tuple:
         """Refresh the host scheduling mirrors from the chunk's outputs —
         one batched ``jax.device_get`` of tokens + logprobs + the whole
-        carry (replicated outputs: every process reads identical values;
-        copies are taken only where the backend hands back read-only
-        views, since admission writes into the mirrors).  Slots whose
+        carry + a constrained span's automaton states (replicated outputs:
+        every process reads identical values; copies are taken only where
+        the backend hands back read-only views, since admission writes
+        into the mirrors).  Slots whose
         host bookkeeping dropped the row while the carry was device-
         resident (cancel mid-span) are forced inactive — the device's
         activity bit for them is stale by construction."""
-        toks, lps, m, carry, moe = out
+        toks, lps, m, carry, moe, ticket = out
         with self._span("batcher.loop.wait_device"):
-            toks_h, lps_h, m_h, moe_h, (lt, rl, va, ac, bu) = jax.device_get(
-                (toks, lps, m, moe, carry))
-        self._t_complete = time.perf_counter()
+            toks_h, lps_h, m_h, moe_h, (lt, rl, va, ac, bu), dfa_h = \
+                jax.device_get((toks, lps, m, moe, carry, self._dfa_carry))
+        self._note_fetched(ticket)
         self.last_tok = _writable(lt)
         self.real_lens = _writable(rl)
         self.valid = _writable(va)
@@ -4336,7 +4417,7 @@ class ContinuousBatcher:
                 self.active[i] = False
                 self.budget[i] = 0
         self._cancel_dirty = False
-        return toks_h, lps_h, m_h, moe_h
+        return toks_h, lps_h, m_h, moe_h, dfa_h
 
     def _prehash_queued(self) -> None:
         """Overlapped host window: memoize page digests for requests that
@@ -4383,12 +4464,12 @@ class ContinuousBatcher:
         self._cancel_dirty = False
         with self._span("batcher.loop.plan"):
             plan = self._span_plan()
-        t_disp = time.perf_counter()
-        if self._t_complete is not None:
-            # First chunk of a span follows an OBSERVED completion (the
-            # previous span's sync): the host time in between is genuine
-            # device idle — collect/admit/grow ran with nothing in flight.
-            self._note_gap(max(0.0, t_disp - self._t_complete))
+        # The first chunk of a span follows an OBSERVED completion (the
+        # previous span's sync, an admission's fetch): deliver / admit /
+        # grow / plan ran with nothing in flight, and each was charged its
+        # part of that starved time (_charge_starved).  A dispatched-ahead
+        # chunk is charged nothing by construction: its dispatch precedes
+        # its predecessor's fetch.
         out = self._dispatch_chunk(plan, (
             self.last_tok, self.real_lens, self.valid, self.active,
             self.budget,
@@ -4403,13 +4484,11 @@ class ContinuousBatcher:
                 self.faults.fire("batcher.decode")
             rng_before = self._rng  # ghost refund point (below)
             nxt = self._dispatch_chunk(plan, out[3])
-            self._note_gap(0.0)
             chunks += 1
             self.overlap_stats["dispatched_ahead"] += 1
             METRICS.inc("batcher.overlap.dispatched_ahead")
             METRICS.set_gauge("batcher.overlap.depth", 1)
             # Chunk N's host work, concurrent with chunk N+1 on device.
-            host_t0 = time.perf_counter()
             toks, lps, m, moe, active_after = self._fetch_chunk(out)
             with self._span("batcher.loop.deliver"):
                 self._note_moe(moe)
@@ -4430,13 +4509,12 @@ class ContinuousBatcher:
                 self._collect(toks, was_active, counts=m, lps=lps,
                               active_host=active_after)
                 self._prehash_queued()
-            self.overlap_stats["host_lag_s"] += time.perf_counter() - host_t0
             was_active = active_after
             out = nxt
         # Sync exit: mirrors refresh BEFORE _collect, so a cancel taken
         # inside the delivery callbacks lands on fresh state (the
         # synchronous loop's exact ordering).
-        toks, lps, m, moe = self._sync_carry(out)
+        toks, lps, m, moe, abs_states = self._sync_carry(out)
         with self._span("batcher.loop.deliver"):
             self._note_moe(moe)
             if self.speculative:
@@ -4452,7 +4530,6 @@ class ContinuousBatcher:
                 # fresh dfa_row, like every other scheduling mirror.  Rows
                 # whose host bookkeeping dropped them mid-span are skipped
                 # (rid mismatch — their state is garbage by construction).
-                abs_states = np.asarray(jax.device_get(self._dfa_carry))
                 for i, off, rid in plan["constrain"]:
                     row = self.rows[i]
                     if row.rid == rid and row.req is not None \

@@ -139,9 +139,11 @@ HOST_SYNC_SITES: dict[str, str] = {
     "ContinuousBatcher._fetch_chunk":
         "one batched D2H per dispatched chunk (tokens+logprobs+activity)",
     "ContinuousBatcher._sync_carry":
-        "span exit: the whole scheduling carry returns to host mirrors",
-    "ContinuousBatcher._decode_span":
-        "span boundary: automaton state read-back + host-lag stamping",
+        "span exit: the whole scheduling carry (and a constrained span's "
+        "automaton states) returns to host mirrors",
+    "ContinuousBatcher._fetch_admission":
+        "one batched D2H per admission (first token, its logprob, the "
+        "row mask and expert counts where the program hands them out)",
     "ContinuousBatcher.register_prefix":
         "prefix registration materializes the row cache once, at admit",
     "engine._to_host":

@@ -199,27 +199,44 @@ def test_streaming_deliveries_identical(tiny):
     assert streams[0] == streams[1]
 
 
-def test_dispatch_ahead_engages_and_counts(tiny):
+def test_dispatch_ahead_engages_and_counts(tiny, monkeypatch):
     """Steady decode with nothing queued: nearly every chunk dispatches
-    ahead (device gap 0 by construction), the span ends in exactly one
-    carry sync, chunk count matches the synchronous leg (no ghost
-    chunks), and the METRICS mirrors move."""
-    ahead0 = METRICS.get_counter("batcher.overlap.dispatched_ahead")
-    syncs0 = METRICS.get_counter("batcher.overlap.carry_syncs")
-    b_off = mk(tiny, False)
-    drive(b_off, [("steady state", 33)])
-    b_on = mk(tiny, True)
-    drive(b_on, [("steady state", 33)])
+    ahead (its delivery starves the device of nothing, by construction),
+    the span ends in exactly one carry sync, chunk count matches the
+    synchronous leg (no ghost chunks), and the METRICS mirrors move."""
+    names = ("batcher.overlap.dispatched_ahead", "batcher.overlap.carry_syncs",
+             "batcher.decode.chunks", "batcher.starved.deliver_seconds")
+
+    def drive_counted(overlap):
+        # A clock that moves one second in every delivery and nowhere else.
+        now = [0.0]
+        b = mk(tiny, overlap, clock=lambda: now[0])
+        collect = b._collect
+
+        def ticking(*a, **k):
+            now[0] += 1.0
+            return collect(*a, **k)
+
+        monkeypatch.setattr(b, "_collect", ticking)
+        c0 = {n: METRICS.get_counter(n) for n in names}
+        drive(b, [("steady state", 33)])
+        return b, {n.rsplit(".", 1)[1]: METRICS.get_counter(n) - c0[n]
+                   for n in names}
+
+    b_off, off = drive_counted(False)
+    b_on, on = drive_counted(True)
     s = b_on.overlap_stats
-    assert s["chunks"] == b_off.overlap_stats["chunks"]  # no ghosts
-    assert s["dispatched_ahead"] == s["chunks"] - 1  # all but the first
+    assert on["chunks"] == off["chunks"]  # no ghosts
+    assert s["dispatched_ahead"] == on["chunks"] - 1  # all but the first
     assert s["carry_syncs"] == 1
-    assert s["device_gap_s"] == 0.0  # every gap sample was dispatched-ahead
+    # Every delivery of the synchronous leg ran with nothing in flight; of
+    # the dispatched-ahead leg's, only the one after the sync that ends
+    # the span: the chunks dispatched ahead left the device no gap.
+    assert off["deliver_seconds"] == off["chunks"]
+    assert on["deliver_seconds"] == 1.0
     assert b_off.overlap_stats["dispatched_ahead"] == 0  # off leg: none
-    assert METRICS.get_counter(
-        "batcher.overlap.dispatched_ahead") - ahead0 == s["dispatched_ahead"]
-    assert METRICS.get_counter(
-        "batcher.overlap.carry_syncs") - syncs0 == 1
+    assert on["dispatched_ahead"] == s["dispatched_ahead"]
+    assert on["carry_syncs"] == 1
 
 
 def test_arrival_mid_span_syncs_and_admits(tiny):

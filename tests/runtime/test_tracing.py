@@ -3,8 +3,11 @@
 Deterministic: the batcher's injectable clock is a fake one that the test
 advances, nothing sleeps.  Pinned here: the engine-thread spans appear on
 the profiler's timeline under their registered names and partition the
-loop's wall time; the occupancy counters count what they say; a request's
-queue wait is sampled once per admission; the finished-request ring.
+loop's wall time; every blocking fetch of the engine thread sits under a
+``*.wait_device`` span; the time no program was in flight is charged to the
+loop span it fell in; the occupancy counters count what they say; a
+request's queue wait is sampled once per admission; the finished-request
+ring.
 """
 
 import glob
@@ -21,6 +24,7 @@ from distributed_llms_tpu.runtime import batcher as batcher_mod
 from distributed_llms_tpu.runtime.batcher import FINISHED_KEEP, ContinuousBatcher
 
 LOOP_SPANS = ("admit", "grow", "plan", "dispatch", "wait_device", "deliver")
+HOST_SPANS = ("admit", "grow", "plan", "dispatch", "deliver")
 
 
 @pytest.fixture(scope="module")
@@ -45,6 +49,45 @@ def loop_sums() -> dict[str, float]:
             for s in LOOP_SPANS}
 
 
+def starved() -> dict[str, float]:
+    return {s: METRICS.get_counter(f"batcher.starved.{s}_seconds")
+            for s in HOST_SPANS}
+
+
+def ticking_batcher(tiny, monkeypatch, cost, on_tick=None, **kw):
+    """A paged batcher on a clock that moves only when one of the named
+    pieces runs: methods of the batcher, ``device_get``, or a jitted
+    program of the batcher's module.  -> (batcher, now, calls)."""
+    now = [0.0]
+    b = paged(tiny, clock=lambda: now[0], **kw)
+    calls = dict.fromkeys(cost, 0)
+
+    def ticking(name, fn):
+        def wrapped(*a, **k):
+            calls[name] += 1
+            now[0] += cost[name]
+            if on_tick is not None:
+                on_tick(name)
+            return fn(*a, **k)
+        return wrapped
+
+    for name in cost:
+        if name == "device_get":
+            monkeypatch.setattr(batcher_mod.jax, "device_get",
+                                ticking(name, jax.device_get))
+        elif hasattr(b, name):
+            monkeypatch.setattr(b, name, ticking(name, getattr(b, name)))
+        else:
+            monkeypatch.setattr(batcher_mod, name,
+                                ticking(name, getattr(batcher_mod, name)))
+    return b, now, calls
+
+
+# Seconds the fake clock moves in each piece of the loop's work.
+COST = {"_span_plan": 0.5, "_overlap_ok": 0.25, "_collect": 1.0,
+        "_prehash_queued": 0.125, "_activate_row": 4.0,
+        "_alloc_pages": 2.0, "device_get": 16.0, "decode_chunk": 32.0,
+        "admit_row_paged": 64.0}
 REQS = [([7, 1, 9], 6), ([4, 4, 4, 4, 4, 4], 13), ([100, 3, 5, 2], 3),
         ([9, 8, 7, 6, 5], 9), ([42], 8)]
 
@@ -52,7 +95,10 @@ REQS = [([7, 1, 9], 6), ([4, 4, 4, 4, 4, 4], 13), ([100, 3, 5, 2], 3),
 def test_every_span_is_registered():
     for s in LOOP_SPANS:
         assert f"batcher.loop.{s}_seconds" in METRIC_DOCS
+    for s in HOST_SPANS:
+        assert f"batcher.starved.{s}_seconds" in METRIC_DOCS
     for name in ("batcher.admit.row_seconds", "server.engine.idle_seconds",
+                 "batcher.admit.wait_device_seconds", "batcher.decode.chunks",
                  "batcher.queue_wait_seconds", "server.pre_submit_seconds",
                  "batcher.decode.slot_steps", "batcher.decode.committed_tokens",
                  "runtime.compiles_total", "runtime.compile_seconds"):
@@ -74,6 +120,7 @@ def test_spans_are_host_events_of_the_profile(tiny, tmp_path):
     (path,) = glob.glob(os.path.join(
         str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
     names: dict[str, list] = {}
+    spans: dict[str, list] = {}
     for plane in ProfileData.from_file(path).planes:
         if plane.name.startswith("/device:"):
             continue
@@ -81,8 +128,16 @@ def test_spans_are_host_events_of_the_profile(tiny, tmp_path):
             for ev in line.events:
                 if ev.name.startswith(("batcher.", "server.")):
                     names.setdefault(ev.name, []).append(dict(ev.stats))
-    assert {f"batcher.loop.{s}" for s in LOOP_SPANS} | {"batcher.admit.row"} \
+                    spans.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    assert {f"batcher.loop.{s}" for s in LOOP_SPANS} | {
+        "batcher.admit.row", "batcher.admit.wait_device"} \
         <= set(names), sorted(names)
+    # an admission's one fetch is a host event inside its row's
+    assert len(spans["batcher.admit.wait_device"]) == len(REQS)
+    for (w0, w1), (r0, r1) in zip(sorted(spans["batcher.admit.wait_device"]),
+                                  sorted(spans["batcher.admit.row"])):
+        assert r0 <= w0 <= w1 <= r1
     rows = names["batcher.admit.row"]
     assert len(rows) == len(REQS)
     assert sorted(int(r["rid"]) for r in rows) == sorted(rids)
@@ -99,51 +154,231 @@ def test_loop_spans_partition_the_loop_wall_time(tiny, monkeypatch, overlap):
     runs, the span sums add up to the loop's wall time, each piece under
     the span that names it.  With dispatch-ahead on, growth rides the
     per-chunk decision (plan); with it off, _grow_rows does it all."""
-    now = [0.0]
-    b = paged(tiny, clock=lambda: now[0], overlap=overlap)
-    cost = {"_span_plan": 0.5, "_overlap_ok": 0.25, "_collect": 1.0,
-            "_prehash_queued": 0.125, "_activate_row": 4.0,
-            "_alloc_pages": 2.0, "device_get": 16.0, "decode_chunk": 32.0}
-    calls = dict.fromkeys(cost, 0)
-
-    def ticking(name, fn):
-        def wrapped(*a, **k):
-            calls[name] += 1
-            now[0] += cost[name]
-            return fn(*a, **k)
-        return wrapped
-
-    for name in ("_span_plan", "_overlap_ok", "_collect", "_prehash_queued",
-                 "_activate_row", "_alloc_pages"):
-        monkeypatch.setattr(b, name, ticking(name, getattr(b, name)))
-    monkeypatch.setattr(batcher_mod.jax, "device_get",
-                        ticking("device_get", jax.device_get))
-    monkeypatch.setattr(batcher_mod, "decode_chunk",
-                        ticking("decode_chunk", batcher_mod.decode_chunk))
+    cost = COST
+    b, now, calls = ticking_batcher(tiny, monkeypatch, cost, overlap=overlap)
     reqs = REQS + [([11, 12], 40)]            # crosses two page boundaries
     for ids, n in reqs:
         b.submit(ids, max_new_tokens=n)
     before = loop_sums()
     row0 = METRICS.get_histogram("batcher.admit.row_seconds")
+    wait0 = METRICS.get_histogram("batcher.admit.wait_device_seconds")
     b.run()
     got = {s: v - before[s] for s, v in loop_sums().items()}
     assert sum(got.values()) == pytest.approx(now[0])     # nothing outside
-    assert got["wait_device"] == pytest.approx(16.0 * calls["device_get"])
+    # an admission's fetch counts under admit, a chunk's under wait_device
+    assert calls["admit_row_paged"] == len(reqs)
+    assert got["wait_device"] == pytest.approx(
+        16.0 * (calls["device_get"] - len(reqs)))
     assert got["dispatch"] == pytest.approx(32.0 * calls["decode_chunk"])
     assert got["deliver"] == pytest.approx(
         calls["_collect"] + 0.125 * calls["_prehash_queued"])
     planning = 0.5 * calls["_span_plan"] + 0.25 * calls["_overlap_ok"]
     assert got["admit"] + got["grow"] + got["plan"] == pytest.approx(
-        4.0 * calls["_activate_row"] + 2.0 * calls["_alloc_pages"] + planning)
+        4.0 * calls["_activate_row"] + 2.0 * calls["_alloc_pages"] + planning
+        + (64.0 + 16.0) * len(reqs))
     assert calls["_alloc_pages"] > len(reqs)  # a row grew past its pages
     if overlap:
         assert calls["_overlap_ok"] and calls["_prehash_queued"]
     else:
         assert got["plan"] == pytest.approx(planning) and got["grow"] > 0
-    # The child span: one per admission, holding that admission's work.
+    # The child spans: one row and one fetch per admission; the row holds
+    # that admission's program, fetch and activation, the fetch its wait.
     row1 = METRICS.get_histogram("batcher.admit.row_seconds")
-    assert row1[0] - row0[0] == len(reqs)
-    assert row1[1] - row0[1] == pytest.approx(4.0 * len(reqs))
+    wait1 = METRICS.get_histogram("batcher.admit.wait_device_seconds")
+    assert row1[0] - row0[0] == wait1[0] - wait0[0] == len(reqs)
+    assert wait1[1] - wait0[1] == pytest.approx(16.0 * len(reqs))
+    assert row1[1] - row0[1] == pytest.approx((64.0 + 16.0 + 4.0) * len(reqs))
+
+
+@pytest.mark.parametrize("overlap", [True, False])
+def test_starved_time_is_charged_to_the_span_it_fell_in(tiny, monkeypatch,
+                                                        overlap):
+    """The device is starved from a blocking fetch that returned the
+    newest program's output to the next call that dispatches one.  A
+    shadow of that rule kept by the test (programs and fetches in order:
+    the device runs them so) says, for every piece of host work that
+    ticks the clock, whether it ran starved and in which loop span: each
+    batcher.starved.* counter equals its sum, and together they never
+    exceed the five host spans."""
+    cost = COST
+    programs = ("decode_chunk", "admit_row_paged")
+    shadow = {"dispatched": 0, "fetched": 0, "seen": False, "at": None}
+    want = dict.fromkeys(HOST_SPANS, 0.0)
+
+    def on_tick(name):
+        if name in programs:            # the call that dispatches: the
+            shadow["dispatched"] += 1   # program's own time is not starved
+        elif name == "device_get":      # blocked: something is in flight
+            assert shadow["dispatched"] > shadow["fetched"]
+            shadow["fetched"] += 1
+            shadow["seen"] = True
+        elif shadow["seen"] and shadow["dispatched"] == shadow["fetched"]:
+            want[shadow["at"]] += cost[name]
+
+    b, now, calls = ticking_batcher(tiny, monkeypatch, cost, on_tick,
+                                    overlap=overlap)
+    span = b._span
+
+    def spying(name, **attrs):
+        if name.startswith("batcher.loop."):
+            shadow["at"] = name.rsplit(".", 1)[1]
+        return span(name, **attrs)
+
+    monkeypatch.setattr(b, "_span", spying)
+    for ids, n in REQS + [([11, 12], 40)]:
+        b.submit(ids, max_new_tokens=n)
+    s0, l0 = starved(), loop_sums()
+    b.run()
+    got = {k: v - s0[k] for k, v in starved().items()}
+    loop = {k: v - l0[k] for k, v in loop_sums().items()}
+    assert got == pytest.approx(want)
+    assert all(got[k] <= loop[k] + 1e-9 for k in HOST_SPANS)
+    assert sum(got.values()) <= sum(loop[k] for k in HOST_SPANS) + 1e-9
+    # an admission's activation and the turn into the span are starved ...
+    assert got["admit"] >= 4.0 * (calls["_activate_row"] - 1)
+    assert got["plan"] > 0 and got["deliver"] > 0
+    # ... a program's own dispatch call never is
+    assert got["dispatch"] == 0.0
+    if not overlap:                     # every delivery follows a sync
+        assert got["deliver"] == pytest.approx(1.0 * calls["_collect"])
+
+
+def test_a_steady_span_dispatched_ahead_starves_nothing(tiny, monkeypatch):
+    """One request decoding alone with dispatch-ahead on: chunk N+1 is in
+    flight while chunk N is fetched and delivered, so the span charges
+    plan, dispatch and deliver nothing but the turn into it (the first
+    plan, after the admission's fetch) and the last delivery (after the
+    sync that ends it)."""
+    cost = {"_span_plan": 0.5, "_overlap_ok": 0.25, "_collect": 1.0,
+            "_prehash_queued": 0.125, "_activate_row": 4.0,
+            "device_get": 16.0, "decode_chunk": 32.0}
+    b, now, calls = ticking_batcher(tiny, monkeypatch, cost, paged_pages=None,
+                                    overlap=True)
+    b.submit([7, 1, 9], max_new_tokens=33)
+    s0 = starved()
+    chunks0 = METRICS.get_counter("batcher.decode.chunks")
+    b.run()
+    got = {k: v - s0[k] for k, v in starved().items()}
+    assert METRICS.get_counter("batcher.decode.chunks") - chunks0 == 8
+    assert calls["_overlap_ok"] == 8 and calls["_collect"] == 8
+    assert got == pytest.approx({"admit": 4.0, "grow": 0.0, "plan": 0.5,
+                                 "dispatch": 0.0, "deliver": 1.0})
+
+
+def _host_reads_outside_wait_device(b, monkeypatch):
+    """Patch every way a device array's value reaches the host (they all
+    read ``ArrayImpl._value``: ``int()``, ``float()``, ``np.asarray``,
+    ``jax.device_get``) to note a read made while no ``*.wait_device`` span
+    is open.  This backend enforces no device-to-host transfer guard."""
+    from jax._src.array import ArrayImpl
+
+    waiting, outside = [0], []
+    span, value = b._span, ArrayImpl.__dict__["_value"]
+
+    class watched:
+        def __init__(self, name, **attrs):
+            self.inner, self.wait = span(name, **attrs), \
+                name.endswith(".wait_device")
+
+        def __enter__(self):
+            waiting[0] += self.wait
+            return self.inner.__enter__()
+
+        def __exit__(self, *exc):
+            waiting[0] -= self.wait
+            return self.inner.__exit__(*exc)
+
+    def read(arr):
+        if not waiting[0]:
+            import traceback
+            outside.append("".join(traceback.format_stack(limit=6)))
+        return value.fget(arr)
+
+    monkeypatch.setattr(b, "_span", watched)
+    monkeypatch.setattr(ArrayImpl, "_value", property(read))
+    return outside
+
+
+@pytest.mark.parametrize("kind", ["fresh", "prefix-hit", "experts"])
+def test_no_blocking_fetch_outside_a_wait_device_span(tiny, monkeypatch, kind):
+    """On the paths the benchmark's cells run (admit_row_paged,
+    admit_row_auto_paged behind cached pages, decode_chunk, and an expert
+    model's counts) the engine thread reads a device value only under a
+    ``*.wait_device`` span: an admission's ONE explicit fetch, a chunk's."""
+    if kind == "experts":
+        cfg = presets.get_preset("lfm2-tiny")
+        b = ContinuousBatcher(cfg, model_lib.init_params(jax.random.key(0), cfg),
+                              batch_slots=3, max_len=64, chunk_steps=4,
+                              paged_pages=24, page_size=8)
+    else:
+        b = paged(tiny, prefix_cache=kind == "prefix-hit")
+    doc = list(range(40, 75))                 # two full pages and a bit
+    if kind == "prefix-hit":
+        b.submit(doc + [3], max_new_tokens=2)
+        b.run()                               # the pages are cached now
+    hits0 = METRICS.get_counter("batcher.prefix_cache.hit_tokens")
+    moe0 = METRICS.get_counter("moe.layer_passes")
+    rids = [b.submit(doc + [5, 6], max_new_tokens=9),
+            b.submit([7, 1, 9], max_new_tokens=6)]
+    outside = _host_reads_outside_wait_device(b, monkeypatch)
+    out = b.run()
+    assert not outside, outside[0]
+    assert [len(out[r]) for r in rids] == [9, 6]
+    if kind == "prefix-hit":
+        assert METRICS.get_counter("batcher.prefix_cache.hit_tokens") > hits0
+    if kind == "experts":
+        assert METRICS.get_counter("moe.layer_passes") > moe0
+
+
+def _spec_models():
+    cfg = presets.get_preset("llama-tiny", vocab_size=512)
+    dcfg = presets.get_preset("llama-tiny", vocab_size=512, num_layers=2)
+    return (cfg, model_lib.init_params(jax.random.key(0), cfg),
+            dcfg, model_lib.init_params(jax.random.key(99), dcfg))
+
+
+@pytest.mark.parametrize("kind", [
+    "prefix-hit", "experts",
+    pytest.param("speculative", marks=pytest.mark.fragile_xla_cpu)])
+def test_the_explicit_fetch_changes_no_token(tiny, monkeypatch, kind):
+    """Tokens and logprobs are those of the implicit synchronisations the
+    explicit fetch replaced: with ``_fetch_admission`` handing back the
+    device arrays unfetched, ``int(tok)``, ``float(lp)`` and the counts'
+    ``int(x)`` synchronise one by one as they did before."""
+    def make():
+        if kind == "experts":
+            cfg = presets.get_preset("lfm2-tiny")
+            return ContinuousBatcher(
+                cfg, model_lib.init_params(jax.random.key(0), cfg),
+                batch_slots=3, max_len=64, chunk_steps=4, paged_pages=24,
+                page_size=8)
+        if kind == "speculative":
+            cfg, params, dcfg, dparams = _spec_models()
+            return ContinuousBatcher(
+                cfg, params, batch_slots=2, max_len=64, chunk_steps=4,
+                paged_pages=24, page_size=16, prefix_cache=True, spec_k=3,
+                draft_params=dparams, draft_cfg=dcfg)
+        return paged(tiny, prefix_cache=True)
+
+    doc = list(range(40, 75))
+    jobs = [(doc + [3], 4), (doc + [5, 6], 9), ([7, 1, 9], 6),
+            (doc + [8], 7)]
+
+    def serve(b):
+        first = b.submit(*jobs[0][:1], max_new_tokens=jobs[0][1])
+        b.run()                               # later prompts hit its pages
+        rids = [first] + [b.submit(ids, max_new_tokens=n)
+                          for ids, n in jobs[1:]]
+        out = b.run()
+        return ([out[r] for r in rids],
+                [b.result_logprobs[r] for r in rids])
+
+    explicit = serve(make())
+    implicit_b = make()
+    monkeypatch.setattr(implicit_b, "_fetch_admission",
+                        lambda ticket, *outs: outs)
+    assert serve(implicit_b) == explicit
+    assert [len(t) for t in explicit[0]] == [n for _, n in jobs]
 
 
 def test_occupancy_counters(tiny):
@@ -153,7 +388,8 @@ def test_occupancy_counters(tiny):
     admission."""
     b = paged(tiny)
     names = ("batcher.decode.slot_steps", "batcher.sched.decode_tokens",
-             "batcher.decode.committed_tokens", "batcher.admitted")
+             "batcher.decode.committed_tokens", "batcher.admitted",
+             "batcher.decode.chunks")
     c0 = {n: METRICS.get_counter(n) for n in names}
     delivered = []
     for ids, n in REQS:
@@ -164,7 +400,7 @@ def test_occupancy_counters(tiny):
     assert d["batcher.admitted"] == len(REQS)
     assert d["batcher.decode.committed_tokens"] == len(delivered) - len(REQS)
     assert d["batcher.decode.slot_steps"] == \
-        b.overlap_stats["chunks"] * b.b * b.chunk_steps
+        d["batcher.decode.chunks"] * b.b * b.chunk_steps
     assert d["batcher.decode.committed_tokens"] \
         <= d["batcher.sched.decode_tokens"] <= d["batcher.decode.slot_steps"]
 

@@ -12,7 +12,7 @@ declared registry:
   literals — in tests too, for dotted site names) must appear in
   ``FAULT_SITES`` in ``runtime/faults.py``.
 - GL302: every metric name passed to ``METRICS.inc / set_gauge /
-  set_gauges / observe / timer`` in the package must appear in
+  set_gauges / observe`` in the package must appear in
   ``METRIC_DOCS`` in ``core/observability.py``; a ``span("x")`` call
   (``core/profiling.py``) emits the histogram ``x_seconds``.  f-string names are
   checked as patterns (each interpolation becomes ``*``) and must be
@@ -48,7 +48,7 @@ OBS_MODULE = "core/observability.py"
 SERVE_MODULE = "cli/serve_main.py"
 CONFIG_MODULE = "core/config.py"
 
-_METRIC_METHODS = {"inc", "set_gauge", "observe", "timer"}
+_METRIC_METHODS = {"inc", "set_gauge", "observe"}
 
 
 def _find_module(project: Project, suffix: str) -> SourceFile | None:

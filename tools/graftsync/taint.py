@@ -24,8 +24,8 @@ suppressions:
   observability — allowlisted via :data:`core.METRICS_BOUNDARY`;
 - a function declared in ``HOST_SYNC_SITES`` IS a sync point — the one
   place timer reads belong (``_fetch_chunk``/``_sync_carry`` stamping
-  ``_t_complete``), because the host is already serialized against the
-  device there.
+  when the device was last seen to finish), because the host is already
+  serialized against the device there.
 
 Everything else needs ``# graftsync: lockstep-ok(<reason>)`` on the line
 — and the reason should say why the value never crosses a process
