@@ -67,6 +67,9 @@ class QuantizedTensor:
     layer: None, or the int32 index of the ONE layer of a stack [L, N, K]
     this leaf stands for (:meth:`at`): what a layer scan hands a matmul
     site in place of a slice, which would be a copy.
+    rows: None, or [1] int32: how many leading rows of the activations this
+    leaf is about to meet are real (one right-padded sequence, :meth:`at`),
+    which ops/quant_matmul.py uses to skip the row tiles of padding.
     """
 
     data: jax.Array
@@ -82,10 +85,13 @@ class QuantizedTensor:
     k_axes: int = 1
     n_axes: int = 1
     layer: jax.Array | None = None
+    rows: jax.Array | None = None
 
-    def at(self, layer: jax.Array) -> "QuantizedTensor":
-        """This stack [L, ...] read at ``layer`` (traced inside a scan)."""
-        return dataclasses.replace(self, layer=layer)
+    def at(self, layer: jax.Array, rows: jax.Array | None = None
+           ) -> "QuantizedTensor":
+        """This stack [L, ...] read at ``layer`` (traced inside a scan), for
+        activations of which the first ``rows`` rows are real."""
+        return dataclasses.replace(self, layer=layer, rows=rows)
 
     def layer_slice(self) -> "QuantizedTensor":
         """The leaf of :meth:`at` as a leaf of its own: a copy of one
@@ -117,11 +123,11 @@ class QuantizedTensor:
         return (*self.data.shape[:-2], *k_shape, *n_shape)
 
 
-# data/scale (and the layer index) are pytree children; the rest is static
-# metadata.
+# data/scale (and the layer index and the row count) are pytree children;
+# the rest is static metadata.
 jax.tree_util.register_dataclass(
     QuantizedTensor,
-    data_fields=["data", "scale", "layer"],
+    data_fields=["data", "scale", "layer", "rows"],
     meta_fields=["bits", "orig_shape", "pack_axis", "block_axis", "k_axes",
                  "n_axes"],
 )

@@ -246,8 +246,10 @@ METRIC_DOCS: dict[str, str] = {
                                  "first-token fetch, inside "
                                  "batcher.loop.admit (histogram; the "
                                  "annotation carries rid, prompt_tokens, "
-                                 "cached_tokens, bucket, key_slots: the "
-                                 "keys a query of it is scored against)",
+                                 "cached_tokens, bucket, live_rows: the "
+                                 "bucket's rows the quantized matmuls "
+                                 "compute, key_slots: the keys a query of "
+                                 "it is scored against)",
     "batcher.admit.wait_device_seconds": "the engine thread blocked in an "
                                          "admission's ONE device_get "
                                          "(first token, logprob, expert "
@@ -264,6 +266,12 @@ METRIC_DOCS: dict[str, str] = {
                                          "every slot of the row cache: a "
                                          "suffix behind a named or cached "
                                          "prefix, whose start is traced",
+    "batcher.admit.matmul_rows": "rows the admissions handed the quantized "
+                                 "matmul kernel: each one's bucket",
+    "batcher.admit.matmul_rows_live": "of those, the rows of the row tiles "
+                                      "that held a real token; the tiles "
+                                      "past them are skipped "
+                                      "(ops.quant_matmul.live_rows)",
     "batcher.loop.grow_seconds": "chunk-boundary page growth, preemption "
                                  "included (histogram)",
     "batcher.loop.plan_seconds": "span planning and the per-chunk "

@@ -229,14 +229,15 @@ def _contract(x: jax.Array, w: Any, eq: str, k_lead: int, shard: str) -> jax.Arr
     """einsum for plain weights; fused dequant-matmul (ops/quant_matmul) for
     QuantizedTensor weights under weight-only quantized serving: one
     layer's, or the stack of every layer's carrying the index of the one
-    to read (``QuantizedTensor.at``), which the kernel reads where it lies.
+    to read (``QuantizedTensor.at``), which the kernel reads where it lies,
+    and beside it the count of real rows of a padded admission, if known.
     ``shard`` is the weight's tensor-parallel role, "n" (output axis split
     over 'model') or "k" (contracted axis split), which the kernel needs to
     run per shard; XLA partitions the plain einsum by itself."""
     if _is_quantized(w):
         from ..ops.quant_matmul import quant_contract
 
-        return quant_contract(x, w, k_lead, eq, shard=shard)
+        return quant_contract(x, w, k_lead, eq, shard=shard, rows=w.rows)
     return jnp.einsum(eq, x, w)
 
 

@@ -765,15 +765,17 @@ def neox_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, s
     return x + layers.mlp_gelu(h2, p["mlp"], cfg.activation), new_cache, jnp.float32(0.0)
 
 
-def layer_of(blocks: Params, layer: jax.Array) -> Params:
+def layer_of(blocks: Params, layer: jax.Array,
+             rows: jax.Array | None = None) -> Params:
     """Layer ``layer`` of stacked block leaves, for the body of a layer
     scan.  A quantized stack of matrices [L, N, K] stays whole and carries
     the index (``QuantizedTensor.at``): layers._contract hands both to the
     kernel, which reads the layer's tiles where they lie, where a slice
     would be a copy of the layer's weights in every step (a Pallas call
-    takes each operand as a buffer of its own).  Every other leaf, norms
-    and biases, float weights, expert stacks of the capacity path, is
-    sliced."""
+    takes each operand as a buffer of its own).  It carries ``rows`` too
+    (:func:`real_rows`), for the kernel to skip an admission's padding.
+    Every other leaf, norms and biases, float weights, expert stacks of the
+    capacity path, is sliced."""
     from ..checkpoint.quantize import QuantizedTensor
 
     def is_q(a):
@@ -781,10 +783,20 @@ def layer_of(blocks: Params, layer: jax.Array) -> Params:
 
     def take(a):
         if is_q(a) and a.data.ndim == 3:
-            return a.at(layer)
+            return a.at(layer, rows)
         return jax.tree.map(lambda v: v[layer], a)
 
     return jax.tree.map(take, blocks, is_leaf=is_q)
+
+
+def real_rows(seq_lens: jax.Array | None, batch: int) -> jax.Array | None:
+    """[1] int32: how many leading rows of the [B * T, K] activations are
+    real, where that is known: ONE right-padded sequence (an admission) whose
+    count of real tokens ``seq_lens`` [1] the caller gave.  None otherwise:
+    a batch's real rows are no run from the top."""
+    if seq_lens is None or batch != 1:
+        return None
+    return seq_lens.astype(jnp.int32).reshape(1)
 
 
 BLOCK_FNS = {"gpt2": gpt2_block, "opt": gpt2_block, "llama": llama_block,
@@ -804,6 +816,7 @@ def run_blocks(
     std_layout: bool = False,
     kv_tables: jax.Array | None = None,
     key_positions: jax.Array | None = None,  # see _attention
+    rows: jax.Array | None = None,  # :func:`real_rows`
 ) -> tuple[jax.Array, Any, jax.Array]:
     """Scan the stacked blocks over x.  Used both for the whole model and for
     a single pipeline stage (blocks then hold only the stage's layer slice).
@@ -839,7 +852,7 @@ def run_blocks(
     elif kv_tables is not None:
         def body(carry, layer):
             y, pool = carry
-            y, pool, aux = block_fn(y, layer_of(blocks, layer), cfg, positions, pool, cache_index, attn_mask, std_layout, kv_tables, key_positions, layer)
+            y, pool, aux = block_fn(y, layer_of(blocks, layer, rows), cfg, positions, pool, cache_index, attn_mask, std_layout, kv_tables, key_positions, layer)
             return (y, pool), aux
 
         init = (x, cache)
@@ -847,7 +860,7 @@ def run_blocks(
     else:
         def body(carry, xs):
             layer, ck, cv = xs
-            y, new_cache, aux = block_fn(carry, layer_of(blocks, layer), cfg, positions, (ck, cv), cache_index, attn_mask, std_layout, None, key_positions)
+            y, new_cache, aux = block_fn(carry, layer_of(blocks, layer, rows), cfg, positions, (ck, cv), cache_index, attn_mask, std_layout, None, key_positions)
             return y, (new_cache, aux)
 
         init, xs = x, (layer_index, cache.k, cache.v)
@@ -941,11 +954,12 @@ def run_layers(
     if seq_lens is not None:
         token_mask = (jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
                       < seq_lens[:, None])
+    rows = real_rows(seq_lens, x.shape[0])
 
     def layer(carry, op, ffn, at):
         """One layer; ``at`` its index into each kind's stack."""
         x, cache, moe = carry
-        p = layer_of(blocks[op], at[op])
+        p = layer_of(blocks[op], at[op], rows)
         h = layers.rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
         if op == "conv":
             out, new = layers.short_conv(
@@ -994,11 +1008,11 @@ def run_layers(
         x = x + out
         h = layers.rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
 
-        def add_ffn(x, h, mask):
+        def add_ffn(x, h, mask, rows):
             """x + ffn(h) and the expert layer's counts (zeros: dense)."""
             if ffn != "moe":
                 return x + layers.mlp_swiglu(
-                    h, layer_of(blocks["dense"], at[ffn]), cfg.gate_act
+                    h, layer_of(blocks["dense"], at[ffn], rows), cfg.gate_act
                 ), jnp.zeros_like(moe)
             y, stats = layers.moe_dropless(
                 h, blocks["moe"], cfg, mask, layer=at[ffn])
@@ -1006,24 +1020,28 @@ def run_layers(
             if cfg.n_shared_experts:
                 with jax.named_scope("shared_expert"):
                     x = x + layers.mlp_swiglu(
-                        h, layer_of(blocks["moe"]["shared"], at[ffn]),
+                        h, layer_of(blocks["moe"]["shared"], at[ffn], rows),
                         cfg.gate_act)
             return x, stats
 
         b, t, d = x.shape
         n = t // _TOKEN_BLOCK
         if n < 2 or t % _TOKEN_BLOCK:
-            x, stats = add_ffn(x, h, token_mask)
+            x, stats = add_ffn(x, h, token_mask, rows)
             return x, cache, moe + stats
         # A long admission: the position-wise part a block of tokens at a
         # time, so that its temporaries (the dense layer's 18,432 columns,
         # the grouped list of token x k pairs) do not grow with the
         # bucket.  A token sees the same arithmetic; an expert layer's
-        # counts add up over the blocks, each a pass of its own.
+        # counts add up over the blocks, each a pass of its own, and block
+        # i holds what is left of the real rows after i blocks.
         mask = (jnp.ones((b, t), bool) if token_mask is None else token_mask)
-        x, stats = jax.lax.map(lambda a: add_ffn(*a), tuple(
+        left = None if rows is None else jnp.clip(
+            rows - _TOKEN_BLOCK * jnp.arange(n, dtype=jnp.int32)[:, None],
+            0, _TOKEN_BLOCK)  # [n, 1]
+        x, stats = jax.lax.map(lambda a: add_ffn(*a), (*(
             jnp.moveaxis(a.reshape(b, n, _TOKEN_BLOCK, *a.shape[2:]), 1, 0)
-            for a in (x, h, mask)))
+            for a in (x, h, mask)), left))
         return (jnp.moveaxis(x, 0, 1).reshape(b, t, d), cache,
                 moe + jnp.sum(stats, axis=0))
 
@@ -1131,7 +1149,8 @@ def forward(
     #   of each row are real (right-padded input; 0 for a batch row that is
     #   not decoding).  Only a model with state that is not keys and values
     #   needs it (family "hybrid": layers.short_conv, layers.moe_dropless);
-    #   None means all T
+    #   every family hands a lone row's count to the quantized matmuls
+    #   (:func:`real_rows`), and nothing else of it.  None means all T
 ) -> tuple[jax.Array, Any] | tuple[jax.Array, Any, jax.Array]:
     """Full forward.  Returns (logits [B, T, V] float32, updated cache), plus
     the summed MoE aux loss when ``return_aux`` (scale by
@@ -1173,6 +1192,7 @@ def forward(
     x, cache, aux = run_blocks(
         x, params["blocks"], cfg, positions, cache, cache_index, remat,
         attn_mask, std_layout, kv_tables, key_positions,
+        real_rows(seq_lens, b),
     )
     out = (unembed(params, cfg, x), cache)
     return (*out, aux) if return_aux else out

@@ -30,6 +30,21 @@ of weights, and takes twice what its bytes take.  K is summed in runs of
 512 (:func:`_tiles`) whatever the tile, in float32, so a result does not
 depend on the tile.
 
+An admission pads its prompt to a bucket, and every row tile of 256 rows
+streams and dequantizes the whole weight again.  Where the caller knows how
+many of the M rows are real (``rows``) and M is more than two row tiles, the
+kernel's grid ends at the last row tile that holds a real row (a traced
+bound: the same kernel, the same index maps), so the tiles past it fetch
+nothing and compute nothing, and a second, trivial kernel writes them as
+zeros where they lie (:func:`_zero_kernel`).  The tile that holds the last
+real row is computed whole, so a real row's result does not depend on the
+count.  A call of one or two row tiles, or with no count, is the call
+without it: a caller that pads to powers of two never leaves the second of
+two tiles empty, and every program that holds a counted call costs set-up a
+half second more.  (A branch around the kernel's body in a grid over every tile does the same
+on the chip and costs five times the body's trace on the server's host, a
+fifth of a second a kernel, every boot: PERF.md, PR 39.)
+
 The reference's quantization design (snippets.md:675-833) dequantized to
 full precision before each use; there is no fused-kernel counterpart to
 cite — this is the TPU-native replacement for that whole mechanism.
@@ -107,6 +122,21 @@ def _tiles(k: int, n: int, block: int, bits: int = 8
     return None
 
 
+def _row_tile(m: int) -> tuple[int, int]:
+    """(bm, m_pad): the rows of a row tile and M padded to whole tiles."""
+    bm = min(_BM_MAX, max(16, -(-m // 16) * 16))
+    return bm, -(-m // bm) * bm
+
+
+def live_rows(m: int, rows: int) -> int:
+    """Of a call's ``m`` rows, those the kernel computes when told that the
+    first ``rows`` are real: the row tiles that hold one, and all of ``m``
+    where it is one tile or two (:func:`_qmm_flat`).  Host arithmetic, for
+    the batcher's counters."""
+    bm, m_pad = _row_tile(m)
+    return m if m_pad <= 2 * bm else min(m, -(-rows // bm) * bm)
+
+
 def _unpack_int4_rows(q: jax.Array) -> jax.Array:
     """[Np, K] int32 packed nibbles -> [2*Np, K] int32 values.  Low nibble =
     even stored row, high = odd (quantize() packs down the stored rows, N):
@@ -149,6 +179,14 @@ def _kernel(ly_ref, x_ref, q_ref, s_ref, o_ref, acc_ref, *, bits, block, ck,
         o_ref[:] = acc_ref[:].astype(out_dtype)
 
 
+def _zero_kernel(first_ref, y_ref, o_ref):
+    """Zeros into the row tiles the matmul's grid did not reach (the index
+    map starts at ``first_ref[0]``).  Zeros and not what lay there: later
+    layers mask padding by position, and 0 times a NaN is a NaN."""
+    del first_ref, y_ref  # the output is y itself: the other tiles stay
+    o_ref[:] = jnp.zeros_like(o_ref)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("bits", "bm", "tiles", "interpret", "vma"),
@@ -158,6 +196,8 @@ def _quant_matmul_2d(
     q: jax.Array,  # [L, N, K] int8, or [L, N//2, K] packed int4 (row-packed)
     s: jax.Array,  # [L, N // block, K] float32, as stored
     layer: jax.Array,  # [1] int32: the layer of the stack to read
+    real: jax.Array | None = None,  # [1] int32: the first ``real`` of the M
+    #   rows are real (a call of more than two row tiles); None: all of them
     *,
     bits: int,
     bm: int,
@@ -170,7 +210,12 @@ def _quant_matmul_2d(
     n = q.shape[1] * (2 if bits == 4 else 1)
     block = n // nb
     bn, bk, ck = tiles
-    grid = (m // bm, n // bn, k_dim // bk)
+    # The row tiles that hold a real row: the grid stops there (a traced
+    # bound), so the tiles past them are not fetched, dequantized or
+    # multiplied, and the kernel and its index maps are the same either way.
+    live = m // bm if real is None else jnp.clip(
+        pl.cdiv(real[0], bm), 0, m // bm)
+    grid = (live, n // bn, k_dim // bk)
     # Scale rows a block: the tile's own, or 8 that 8 // (its own) j-tiles
     # share (a tile whose blocks do not fill the sublanes).
     rows = bn // block
@@ -181,7 +226,7 @@ def _quant_matmul_2d(
         _kernel, bits=bits, block=block, ck=ck, share=share, nk=grid[2],
         out_dtype=x.dtype,
     )
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -202,6 +247,23 @@ def _quant_matmul_2d(
         interpret=interpret,
         name="_quant_matmul_2d",  # the operation's name in a trace
     )(layer, x, q, s)
+    if real is None:
+        return y
+    # ... and are written as zeros, where they lie in y (:func:`_zero_kernel`).
+    return pl.pallas_call(
+        _zero_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(m // bm - live, n // bn),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(
+                (bm, bn), lambda mi, j, first: (first[0] + mi, j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype, vma=vma),
+        input_output_aliases={1: 0},
+        interpret=interpret,
+        name="_quant_matmul_2d_padding",
+    )(live.reshape(1), y)
 
 
 def _dequant_flat(q2: jax.Array, s2: jax.Array, bits: int, dtype) -> jax.Array:
@@ -219,12 +281,16 @@ def _dequant_flat(q2: jax.Array, s2: jax.Array, bits: int, dtype) -> jax.Array:
 
 
 def _qmm_flat(x2: jax.Array, q3: jax.Array, s3: jax.Array, layer: jax.Array,
-              *, bits: int, interpret: bool) -> jax.Array:
+              rows: jax.Array | None = None, *, bits: int, interpret: bool
+              ) -> jax.Array:
     """[M, K] @ dequant(layer ``layer`` [1] of [L, N(-packed), K])^T with
     the scales [L, N/block, K].  Shapes are the LOCAL ones (per shard,
     inside shard_map): tile sizes and M padding derive from them;
     untileable shapes take the dequant+matmul fallback on the layer's
-    slice, so this is total over any shard."""
+    slice, so this is total over any shard.  ``rows`` [1] (None: M) says
+    how many leading rows are real: the kernel is handed it where M is more
+    than two row tiles and leaves the tiles past it as zeros; every other
+    call is the call without it."""
     m, k = x2.shape
     nb = s3.shape[1]
     n = q3.shape[1] * (2 if bits == 4 else 1)
@@ -246,18 +312,20 @@ def _qmm_flat(x2: jax.Array, q3: jax.Array, s3: jax.Array, layer: jax.Array,
     METRICS.inc("ops.dispatch.quant_matmul.k_minor")
     if q3.shape[0] > 1:  # a stack read by index, not a layer's slice
         METRICS.inc("ops.dispatch.quant_matmul.stacked")
-    bm = min(_BM_MAX, max(16, -(-m // 16) * 16))
-    m_pad = -(-m // bm) * bm
+    bm, m_pad = _row_tile(m)
     if m_pad != m:
         x2 = jnp.pad(x2, ((0, m_pad - m), (0, 0)))
+    if m_pad <= 2 * bm:  # one row tile, or two of which a bucket fills both
+        rows = None
     return _quant_matmul_2d(
-        x2, q3, s3, layer, bits=bits, bm=bm, tiles=tiles,
+        x2, q3, s3, layer, rows, bits=bits, bm=bm, tiles=tiles,
         interpret=interpret, vma=vma,
     )[:m]
 
 
-def _qmm_sharded(mesh, x2, q3, s3, layer, *, bits: int, interpret: bool,
-                 shard: str | None, whole: int, batch: int) -> jax.Array:
+def _qmm_sharded(mesh, x2, q3, s3, layer, rows=None, *, bits: int,
+                 interpret: bool, shard: str | None, whole: int, batch: int
+                 ) -> jax.Array:
     """:func:`_qmm_flat` per shard of a tensor-parallel mesh
     (:func:`dispatch.per_shard`).  ``shard`` is the
     weight's Megatron role: "n" splits the output axis over 'model'
@@ -270,27 +338,33 @@ def _qmm_sharded(mesh, x2, q3, s3, layer, *, bits: int, interpret: bool,
     the weight's first such axis (heads, for the attention weights), whole
     rows or columns and whole scale blocks, or it stays replicated
     (redundant compute, same numerics).  ``batch`` is x's leading axis,
-    which shards over 'data' when it divides."""
+    which shards over 'data' when it divides; the count of real rows
+    (``rows``, see :func:`_qmm_flat`) rides in replicated, like the layer
+    index, and is dropped where the rows are split."""
     m_ax = dispatch.axis(mesh, "data", batch)
+    if m_ax:
+        rows = None
     n_ax = k_ax = None
     if shard == "n":
         n_ax = dispatch.axis(mesh, "model", whole, q3.shape[1], s3.shape[1])
     elif shard == "k":
         k_ax = dispatch.axis(mesh, "model", whole, q3.shape[2], s3.shape[2])
 
-    def body(x2, q3, s3, layer):
-        y = _qmm_flat(x2, q3, s3, layer, bits=bits, interpret=interpret)
+    def body(x2, q3, s3, layer, rows):
+        y = _qmm_flat(x2, q3, s3, layer, rows, bits=bits, interpret=interpret)
         return jax.lax.psum(y, k_ax) if k_ax else y
 
     w_spec = P(None, n_ax, k_ax)
     return dispatch.per_shard(
-        body, mesh, (P(m_ax, k_ax), w_spec, w_spec, P(None)), P(m_ax, n_ax),
-    )(x2, q3, s3, layer)
+        body, mesh, (P(m_ax, k_ax), w_spec, w_spec, P(None),
+                     None if rows is None else P(None)), P(m_ax, n_ax),
+    )(x2, q3, s3, layer, rows)
 
 
 def quant_contract(
     x: jax.Array, qt, k_lead: int, eq: str | None = None, *,
     shard: str | None = None, interpret: bool = False,
+    rows: jax.Array | None = None,
 ):
     """x[..., K-axes] @ dequant(W)[K-axes, N-axes] with W blockwise-quantized.
 
@@ -303,6 +377,9 @@ def quant_contract(
     DLT_QUANT_MATMUL=kernel|interpret), per shard under a tensor-parallel
     mesh (``shard``: see :func:`_qmm_sharded`); otherwise dequantize +
     einsum over ``eq`` on the layer's slice, which XLA partitions itself.
+    ``rows`` ([1] int32, None: all): of x flattened to [M, K] only the
+    first ``rows`` rows are real (one padded sequence); the kernel may
+    leave the others as zeros (:func:`_qmm_flat`).
     """
     k_shape, out_tail = qt.tail_shape
     lead = x.shape[: x.ndim - k_lead]
@@ -323,10 +400,10 @@ def quant_contract(
         kw = dict(bits=qt.bits, interpret=mode == "interpret")
         mesh = dispatch.mesh()
         if mesh is None:
-            y2 = _qmm_flat(x2, q3, s3, layer, **kw)
+            y2 = _qmm_flat(x2, q3, s3, layer, rows, **kw)
         else:
             y2 = _qmm_sharded(
-                mesh, x2, q3, s3, layer, shard=shard,
+                mesh, x2, q3, s3, layer, rows, shard=shard,
                 whole=k_shape[0] if shard == "k" else out_tail[0],
                 batch=lead[0] if lead else 1, **kw,
             )

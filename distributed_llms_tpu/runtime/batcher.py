@@ -91,6 +91,7 @@ from ..core.config import ModelConfig
 from ..core.observability import METRICS, get_logger
 from ..models import kv_cache, model as model_lib
 from ..models.kv_cache import KVCache
+from ..ops.quant_matmul import live_rows
 from . import constrain as constrain_lib
 from . import sampling
 from . import scheduler as scheduler_lib
@@ -175,6 +176,22 @@ def _sample_first(logits, last_idx, rng, temperature, top_k, top_p,
     return tok, lp
 
 
+def _row_state(fwd, cfg, n):
+    """What the forward is told of one right-padded row's ``n`` real
+    tokens (None: nothing known).  A model with state that is not keys and
+    values (family "hybrid") needs the count and hands back its expert
+    layers' counts; every other family's quantized matmuls use it to skip
+    the row tiles that hold only padding (models.model.real_rows), which
+    changes no real token's arithmetic.  The mesh-parallel forward takes
+    no count."""
+    if n is None or (cfg.family != "hybrid" and fwd is not model_lib.forward):
+        return {}
+    state = {"seq_lens": n[None]}
+    if cfg.family == "hybrid":
+        state["return_aux"] = True
+    return state
+
+
 def _prefill_row(fwd, params, cfg, cache_dtype, s, prompt, plen=None):
     """Causal prefill of one request into a transient single-row cache of
     ``s`` slots — shared by the contiguous and paged admissions.  The model
@@ -185,19 +202,17 @@ def _prefill_row(fwd, params, cfg, cache_dtype, s, prompt, plen=None):
     chip for heads of whole 128-lane registers, dense over the T keys for
     other heads, on the CPU and under a mesh).  ``fwd`` is _fwd(pm): the
     mesh-parallel forward on a mesh batcher, the plain model forward
-    otherwise.  A model with state
-    that is not keys and values (family "hybrid") is told the prompt's true
-    length ``plen``, leaves in the row cache the state AT that length, not
+    otherwise.  The model is told the prompt's true length ``plen``
+    (:func:`_row_state`): a model with state that is not keys and values
+    (family "hybrid") leaves in the row cache the state AT that length, not
     at the padded bucket's end, and returns its expert layers' counts of
     the real tokens third (forward's ``return_aux``)."""
     (tp,) = prompt.shape
     row_cache = kv_cache.init_cache(cfg, 1, s, dtype=cache_dtype)
     positions = jnp.arange(tp, dtype=jnp.int32)[None, :]
-    state = ({"seq_lens": plen[None], "return_aux": True}
-             if cfg.family == "hybrid" else {})
     return fwd(
         params, cfg, prompt[None, :], positions=positions,
-        cache=row_cache, cache_index=0, **state,
+        cache=row_cache, cache_index=0, **_row_state(fwd, cfg, plen),
     )
 
 
@@ -207,9 +222,9 @@ def _prefill_row_with_prefix(fwd, params, cfg, row_cache, prefix_len, chunk,
     model (session-style continuation math) — shared by the contiguous and
     paged prefix admissions.  ``row_cache`` is the transient contiguous row
     cache that holds the prefix (key/value rows, or latent rows:
-    kv_cache.row_cache_of).  A hybrid-family model is told the suffix's
-    true length ``clen`` and returns its expert counts third, as in
-    :func:`_prefill_row`."""
+    kv_cache.row_cache_of).  The model is told the suffix's true length
+    ``clen``, and a hybrid-family model returns its expert counts third, as
+    in :func:`_prefill_row`."""
     (tc,) = chunk.shape
     s = row_cache.k.shape[2]
     slots = jnp.arange(s, dtype=jnp.int32)
@@ -218,11 +233,10 @@ def _prefill_row_with_prefix(fwd, params, cfg, row_cache, prefix_len, chunk,
 
     prefix_valid = (slots < prefix_len)[None, :]  # [1, S]
     mask = continuation_mask(prefix_valid, prefix_len, tc, slots)  # [1,1,Tc,S]
-    state = ({"seq_lens": clen[None], "return_aux": True}
-             if cfg.family == "hybrid" and clen is not None else {})
     return fwd(
         params, cfg, chunk[None, :], positions=positions,
-        cache=row_cache, cache_index=prefix_len, attn_mask=mask, **state,
+        cache=row_cache, cache_index=prefix_len, attn_mask=mask,
+        **_row_state(fwd, cfg, clen),
     )
 
 
@@ -269,7 +283,7 @@ def admit_row(
     mesh-constrained: batch 1 can't shard over 'data'; XLA places it (TP
     still shards the matmuls via the weights)."""
     logits, row_cache = _prefill_row(
-        _fwd(pm), params, cfg, cache.k.dtype, cache.k.shape[-3], prompt
+        _fwd(pm), params, cfg, cache.k.dtype, cache.k.shape[-3], prompt, plen
     )
     cache, tok, row_valid, lp = _finish_admission(
         cache, slot, row_cache, logits, plen, rng, temperature, top_k, top_p,
@@ -3325,14 +3339,19 @@ class ContinuousBatcher:
                 # every slot of the row cache.
                 fresh = pfx is None and not cached_len
                 bucket = _bucket(len(req.ids) - cached_len)
+                # The rows of the bucket the quantized matmuls compute: the
+                # row tiles that hold a token of the prompt (or suffix).
+                live = live_rows(bucket, len(req.ids) - cached_len)
                 with self._span(
                     "batcher.admit.row", rid=req.rid,
                     prompt_tokens=total_len, cached_tokens=cached_len,
-                    bucket=bucket,
+                    bucket=bucket, live_rows=live,
                     key_slots=min(bucket, self.s) if fresh else self.s,
                 ):
                     self._unqueue(req)
                     self._note_unmatched(req, pfx)
+                    METRICS.inc("batcher.admit.matmul_rows", bucket)
+                    METRICS.inc("batcher.admit.matmul_rows_live", live)
                     if fresh:
                         METRICS.inc("batcher.admit.self_attention")
                     else:

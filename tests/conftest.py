@@ -110,3 +110,33 @@ def dispatched():
     before = counts()
     return lambda: {k: int(v - before.get(k, 0)) for k, v in counts().items()
                     if v != before.get(k, 0)}
+
+
+@pytest.fixture
+def counted_kernels():
+    """``counted_kernels(jaxpr)`` -> for every ``_quant_matmul_2d`` Pallas
+    call in the (closed) jaxpr and the jaxprs inside it, in order, whether
+    its grid ends at the last row tile that holds a real row (a traced
+    bound: ops/quant_matmul.py) or walks every tile, as the parent's call
+    does; a counted call has its ``_quant_matmul_2d_padding`` behind it."""
+    from jax.extend import core as jex_core
+
+    def walk(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                bounds = eqn.params["grid_mapping"].num_dynamic_grid_bounds
+                if name == "_quant_matmul_2d":
+                    out.append(bounds == 1)
+                    assert eqn.params["grid_mapping"].num_index_operands == 1
+                elif name == "_quant_matmul_2d_padding":
+                    assert out and out[-1] and bounds == 1
+                continue
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if isinstance(sub, jex_core.Jaxpr):
+                        walk(sub, out)
+        return out
+
+    return lambda jaxpr: walk(getattr(jaxpr, "jaxpr", jaxpr), [])

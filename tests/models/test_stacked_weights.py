@@ -46,8 +46,10 @@ HYBRID = ModelConfig(
 PAGES, BLK = 7, 16
 
 
-def _sliced_layer_of(blocks, layer):
-    """``layer_of`` as the scans had it before PR 29: every leaf sliced."""
+def _sliced_layer_of(blocks, layer, rows=None):
+    """``layer_of`` as the scans had it before PR 29: every leaf sliced
+    (and no count of real rows carried)."""
+    del rows
     return jax.tree.map(lambda a: a[layer], blocks)
 
 

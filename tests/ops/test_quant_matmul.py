@@ -331,3 +331,77 @@ def test_k_minor_counts_the_kernels_traces(dispatched):
     qm.quant_contract(x[:, :100], bad, 1, "mk,kn->mn", interpret=True)
     assert dispatched()["quant_matmul.k_minor"] == 2
     assert dispatched()["quant_matmul.fallback"] == 1
+
+
+# -- the count of real rows (PR 39) ---------------------------------------
+_SMALL_TILE = 128 * 512  # forces tiles of [128, 512]: a grid over N and K
+
+
+@pytest.mark.parametrize("rows", [0, 1, 256, 257, -1, None],
+                         ids=lambda r: f"rows{r}")
+@pytest.mark.parametrize("m", [768, 1024, 2048])
+@pytest.mark.parametrize("stack", ["index", "one", "index-tiled"])
+@pytest.mark.parametrize("bits", [8, 4])
+def test_row_tiles_of_padding_are_skipped(bits, stack, m, rows, monkeypatch,
+                                          kernel_calls, counted_kernels):
+    """A call of more than two row tiles that is told how many rows are real:
+    every row of a tile that holds a real row equals, bit for bit, the call
+    without a count, and the tiles past them are zeros, whether the kernel
+    walks one weight tile or a grid of them ("tiled")."""
+    rows = {-1: m - 1, None: m}.get(rows, rows)
+    k, n = (1024, 256) if stack == "index-tiled" else (256, 256)
+    if stack == "index-tiled":
+        monkeypatch.setattr(qm, "_TILE_BYTES", _SMALL_TILE)
+        assert qm._tiles(k, n, 128, bits)[:2] in ((128, 512), (256, 512))
+    qt = _make((1 if stack == "one" else 3, k, n), bits, seed=21)
+    qt = jax.tree.map(lambda a: a[0], qt) if stack == "one" else qt.at(
+        jnp.int32(2))
+    x = jax.random.normal(jax.random.key(22), (1, m, k), jnp.bfloat16)
+
+    def run(count):
+        return np.asarray(qm.quant_contract(
+            x, qt, 1, "btk,kn->btn", interpret=True, rows=count
+        )[0].astype(jnp.float32))
+
+    want, got = run(None), run(jnp.array([rows], jnp.int32))
+    live = -(-rows // 256) * 256
+    np.testing.assert_array_equal(got[:live], want[:live])
+    assert not got[live:].any()
+    np.testing.assert_allclose(
+        want, np.asarray(_fallback(x, qt, "btk,kn->btn")[0], np.float32),
+        rtol=2e-2, atol=2e-1)
+    assert len(kernel_calls) == 2
+    # what the two calls trace to: a grid over every row tile, then one
+    # that ends with the real rows
+    f = lambda c: jax.make_jaxpr(lambda x_: qm.quant_contract(  # noqa: E731
+        x_, qt, 1, "btk,kn->btn", interpret=True, rows=c))(x)
+    assert counted_kernels(f(None)) == [False]
+    assert counted_kernels(f(jnp.array([rows], jnp.int32))) == [True]
+
+
+@pytest.mark.parametrize("m", [1, 16, 64, 256, 512])
+@pytest.mark.parametrize("bits", [8, 4])
+def test_one_row_tile_makes_the_parents_call(bits, m, counted_kernels):
+    """A count handed to a call of one row tile is dropped, and of two (a
+    bucket of 512 holds more than 256 tokens): what is traced is the call
+    without it, to the letter (a decode step, a bucket of 512 or less)."""
+    qt = _make((3, 256, 256), bits, seed=23).at(jnp.int32(1))
+    x = jax.random.normal(jax.random.key(24), (1, m, 256), jnp.bfloat16)
+
+    def traced(count):
+        return jax.make_jaxpr(lambda x_: qm.quant_contract(
+            x_, qt, 1, "btk,kn->btn", interpret=True, rows=count))(x)
+
+    plain, counted = traced(None), traced(jnp.array([m // 2], jnp.int32))
+    assert counted_kernels(plain) == counted_kernels(counted) == [False]
+    assert str(plain) == str(counted)
+
+
+def test_live_rows_is_the_kernels_rule():
+    """The batcher's counter reckons what the kernel computes."""
+    assert qm.live_rows(2048, 1100) == 1280
+    assert qm.live_rows(2048, 0) == 0
+    assert qm.live_rows(2048, 2048) == qm.live_rows(2048, 1793) == 2048
+    assert qm.live_rows(256, 3) == 256 and qm.live_rows(64, 3) == 64
+    assert qm.live_rows(512, 256) == qm.live_rows(512, 257) == 512
+    assert qm.live_rows(768, 256) == 256 and qm.live_rows(768, 257) == 512

@@ -197,6 +197,47 @@ def test_sharded_kernel_partitions(
     )
 
 
+@pytest.mark.parametrize("case,wshape,wspec,xtail,k_lead,eq,shard", [
+    ("w_up N-sharded", (256, 1024), P(None, "model"), (256,), 1,
+     "btd,df->btf", "n"),
+    ("wo K-sharded psum", (4, 128, 256), P("model", None, None), (4, 128), 2,
+     "bthk,hkd->btd", "k"),
+])
+@pytest.mark.parametrize("rows", [0, 257, 1024])
+def test_sharded_kernel_skips_padding(
+    devices8, monkeypatch, case, wshape, wspec, xtail, k_lead, eq, shard,
+    rows, counted_kernels,
+):
+    """Under the mesh the count of an admission's real rows rides into every
+    shard replicated, like the layer index: the row tiles that hold a real
+    row equal the call without a count bit for bit, the others are zeros
+    (a psum of zeros where K is split), and each shard's kernel stops at
+    the real rows."""
+    from distributed_llms_tpu.ops import dispatch, quant_matmul as qm
+
+    monkeypatch.setenv("DLT_QUANT_MATMUL", "interpret")
+    mesh = Mesh(np.array(devices8).reshape(2, 4), ("data", "model"))
+    w = jax.random.normal(jax.random.key(0), (3, *wshape), jnp.float32)
+    qt = quant_lib.quantize(w, bits=8, block=128, k_axes=k_lead,
+                            n_axes=len(wshape) - k_lead)
+    qt = api_lib._place_quantized(qt, P(None, *wspec), mesh, case)
+    x = jax.random.normal(jax.random.key(1), (1, 1024, *xtail), jnp.bfloat16)
+
+    def f(x_, q_, count=None):
+        return qm.quant_contract(x_, q_.at(jnp.int32(2)), k_lead, eq,
+                                 shard=shard, rows=count)
+
+    count = jnp.array([rows], jnp.int32)
+    with dispatch.sharded(mesh):
+        want = np.asarray(jax.jit(f)(x, qt)[0].astype(jnp.float32))
+        got = np.asarray(jax.jit(f)(x, qt, count)[0].astype(jnp.float32))
+        assert counted_kernels(jax.make_jaxpr(f)(x, qt)) == [False]
+        assert counted_kernels(jax.make_jaxpr(f)(x, qt, count)) == [True]
+    live = -(-rows // 256) * 256
+    np.testing.assert_array_equal(got[:live], want[:live])
+    assert not got[live:].any()
+
+
 @pytest.mark.parametrize("stacked_xs", [False, True, "at"])
 def test_sharded_kernel_under_scan(devices8, monkeypatch, stacked_xs):
     """The per-shard kernel compiles and matches the dense reference INSIDE

@@ -17,6 +17,8 @@ families' programs to none of it.
 
 import dataclasses
 
+import re
+
 import pytest
 
 LAYERS, PAGES, BLK, KVH, HD = 2, 512, 64, 4, 128
@@ -275,6 +277,7 @@ def test_an_admission_at_the_8192_bucket_leaves_half_a_gigabyte(
     assert admit["argument_gb"] + admit["temp_gb"] < 15.75 - 0.5
     assert admit["temp_gb"] < 1.8
     assert admit["expert_shaped"] == []
+    assert admit["weight_shaped"] == []  # the counted kernel too (PR 39)
     assert {e[0] for e in admit["pool_shaped"]} <= {
         "dynamic-update-slice", "fusion:dynamic-update-slice"}
     assert {e[0] for e in admit["ring_shaped"]} <= {
@@ -316,6 +319,14 @@ def test_a_fresh_rows_admission_holds_no_scores_over_the_row_cache(
             "admit_row_paged", get_preset(preset), slots=slots,
             max_len=max_len, pages=pages, page_size=BLK, prompt_len=bucket)
     assert admit["score_shaped"] == []
+    # ...and since PR 39 the matmuls of these buckets are told the count of
+    # real rows: the kernel whose grid ends with them still reads every
+    # weight where it lies, and the temporaries are no larger.
+    # (lfm2's routing weights of 2,048 tokens, [2048, 4], are shaped like
+    # wk's scales turned, at the parent too: what has the bucket among its
+    # dimensions is an activation.)
+    assert [e for e in admit["weight_shaped"]
+            if not re.search(rf"[\[,]{bucket}[,\]]", e[2])] == []
     assert admit["temp_gb"] < temp_gb
     assert admit["argument_gb"] + admit["temp_gb"] < 15.75 - 0.5
 

@@ -3,11 +3,14 @@ shape each operation writes: what an admission consists of, outside any
 server.  PERF.md's tables of an admission's fusions by shape come from it.
 
     chiprun --chips 1 -- python tools/admit_dig.py qwen2-7b --slots 16 \
-        --max-len 4096 --pages 512 --buckets 256,2048 --out chiprun_out/a.json
+        --max-len 4096 --pages 512 --buckets 256,2048,2048:1100 \
+        --out chiprun_out/a.json
 
 Weights are ``init_params_quantized`` (int8) from seed 0, the pool and the
 row's page list are the cell's (``max_len // page`` entries, the bucket's
-own pages first, the scratch page after), the prompt random bytes.
+own pages first, the scratch page after), the prompt random bytes: three
+short of the bucket, or ``BUCKET:LEN`` tokens of it (since PR 39 the
+quantized matmuls skip the row tiles past them).
 ``--tree DIR`` profiles another checkout's programs (a parent under
 ``_chip/``) in the same call.  ``--rehearsal`` runs a tiny preset on the
 CPU and reads no trace (there are no device lines to read).
@@ -42,7 +45,8 @@ def main() -> None:
     ap.add_argument("--max-len", type=int, default=4096)
     ap.add_argument("--pages", type=int, default=512)
     ap.add_argument("--page-size", type=int, default=64)
-    ap.add_argument("--buckets", default="256,2048")
+    ap.add_argument("--buckets", default="256,2048",
+                    help="BUCKET or BUCKET:LEN (the prompt's real tokens)")
     ap.add_argument("--tree", default=None)
     ap.add_argument("--out", default="chiprun_out/admit_dig.json")
     ap.add_argument("--rehearsal", action="store_true")
@@ -72,7 +76,10 @@ def main() -> None:
     report = {"preset": a.preset, "tree": root, "max_len": a.max_len,
               "device": jax.devices()[0].device_kind, "buckets": {}}
     tdir = os.path.join(os.path.dirname(os.path.abspath(a.out)), "_admit_trace")
-    for bucket in (int(x) for x in a.buckets.split(",")):
+    for item in a.buckets.split(","):
+        bucket, _, plen = item.partition(":")
+        bucket = int(bucket)
+        plen = int(plen) if plen else bucket - 3
         own = bucket // a.page_size
         prompt = jnp.asarray(
             np.random.RandomState(0).randint(0, 250, bucket), jnp.int32)
@@ -81,7 +88,7 @@ def main() -> None:
 
         def admit(pool):
             out = batcher.admit_row_paged(
-                params, cfg, pool, page_list, prompt, jnp.int32(bucket - 3),
+                params, cfg, pool, page_list, prompt, jnp.int32(plen),
                 jax.random.key(1), slot=jnp.int32(1))
             jax.block_until_ready(out[1])
             return out[0]
@@ -91,7 +98,7 @@ def main() -> None:
         t1 = time.time()
         for _ in range(3):
             pool = admit(pool)
-        entry = {"wall_ms": (time.time() - t1) / 3 * 1e3}
+        entry = {"prompt_len": plen, "wall_ms": (time.time() - t1) / 3 * 1e3}
         if not a.rehearsal:
             shutil.rmtree(tdir, ignore_errors=True)
             jax.profiler.start_trace(tdir)
@@ -124,8 +131,8 @@ def main() -> None:
                     for (stem, shape), (ns, n) in sorted(
                         by.items(), key=lambda kv: -kv[1][0])[:40]],
             )
-        report["buckets"][str(bucket)] = entry
-        print(bucket, json.dumps(entry)[:7000], flush=True)
+        report["buckets"][item] = entry
+        print(item, json.dumps(entry)[:7000], flush=True)
     os.makedirs(os.path.dirname(os.path.abspath(a.out)), exist_ok=True)
     with open(a.out, "w") as f:
         json.dump(report, f, indent=1)

@@ -19,6 +19,7 @@ shaped like the rings (``ring_shaped``): the in-place writes alone.
     python tools/aot_decode.py qwen2-7b --slots 16 --max-len 4096 --pages 512
     python tools/aot_decode.py pythia-6.9b --slots 8 --max-len 2048 --pages 96 \
         --programs decode_chunk,admit_row_paged --hlo-dir /tmp/hlo
+    (cd _chip/parent && python tools/aot_decode.py ...) and cmp the .noloc files
     python tools/aot_decode.py ax-k1-ep16 --slots 64 --pages 2176 --prompt-len 2048
     python tools/aot_decode.py k-exaone-ep8 --slots 64 --max-len 8192 \
         --pages 3712 --prompt-len 8192
@@ -378,6 +379,40 @@ def in_place_scatter(entry: tuple) -> bool:
     return entry[0] in ("scatter", "fusion:scatter")
 
 
+_KERNEL_BODY = re.compile(r'("custom_call_config":\{"body":")([A-Za-z0-9+/=]+)"')
+
+
+def without_locations(hlo_text: str) -> str:
+    """The optimised HLO with everything that names a file, a function or a
+    line taken out, so that the programs of two checkouts (a parent under
+    ``_chip/``) can be compared with ``cmp``: the header's tables, every
+    instruction's ``metadata``, and each Mosaic kernel's body, which is its
+    MLIR serialized WITH locations, replaced by the hash of its text
+    printed without them."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True  # the serialized `stable_mosaic`
+    bodies: dict = {}
+
+    def body(m):
+        if m.group(2) not in bodies:
+            with ctx:
+                text = ir.Module.parse(base64.b64decode(m.group(2))
+                                       ).operation.get_asm(enable_debug_info=False)
+            bodies[m.group(2)] = hashlib.sha256(text.encode()).hexdigest()
+        return f'{m.group(1)}mlir-sha256:{bodies[m.group(2)]}"'
+
+    text = re.sub(r"(?ms)^(FileNames|FunctionNames|FileLocations|StackFrames)"
+                  r"\n.*?\n\n", "", hlo_text)
+    text = re.sub(r", metadata=\{[^{}]*\}", "", text)
+    return _KERNEL_BODY.sub(body, text)
+
+
 def analyse(program: str, cfg, **shape_kw) -> dict:
     compiled = lower_program(program, cfg, **shape_kw).compile()
     mem = compiled.memory_analysis()
@@ -429,7 +464,9 @@ def main() -> int:
                          "convolution state, which refuses both; no "
                          "chunked prefill for latent pages")
     ap.add_argument("--hlo-dir", default=None,
-                    help="write each program's optimised HLO here")
+                    help="write each program's optimised HLO here, and "
+                         "beside it the same without locations (.noloc), "
+                         "which two checkouts' programs can be compared by")
     a = ap.parse_args()
     try:
         v5e_devices()
@@ -461,6 +498,8 @@ def main() -> int:
             name = f"{a.preset}.{program}.p{a.pages}.kv{a.kv_bits}.hlo"
             with open(os.path.join(a.hlo_dir, name), "w") as f:
                 f.write(hlo)
+            with open(os.path.join(a.hlo_dir, name + ".noloc"), "w") as f:
+                f.write(without_locations(hlo))
         by_op: dict = {}
         for e in r["pool_shaped"]:
             by_op[e[0]] = by_op.get(e[0], 0) + 1

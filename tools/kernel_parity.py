@@ -535,6 +535,38 @@ def paged_int8_parity(blk: int = 128, h: int = 8, kvh: int = 4,
           _int8_reference(q, kq, ksc, vq, vsc, ln), rtol=3e-2, atol=3e-2)
 
 
+def quant_padding_rows(m: int = 2048) -> None:
+    """A call of eight row tiles (three off the chip) told how many of its rows are real (an
+    admission's bucket; ops/quant_matmul.py, PR 39) against the call that
+    is not told, at qwen2-7b's w_gate and w_down and as int4: the row
+    tiles that hold a real row equal it bit for bit, the others are
+    zeros."""
+    key = jax.random.PRNGKey(39)
+    m = m if ON_TPU else 768
+    for bits, k, n in ((8, 3584, 18944), (8, 18944, 3584), (4, 4096, 1024)):
+        if not ON_TPU:
+            k, n = k // 8 // 128 * 128, n // 8 // 128 * 128
+        kx, kw = jax.random.split(jax.random.fold_in(key, k))
+        x = jax.random.normal(kx, (1, m, k), jnp.bfloat16)
+        qt = jax.jit(lambda w: quantize(w, bits=bits))(
+            jax.random.normal(kw, (k, n), jnp.float32) / np.sqrt(k))
+        run = jax.jit(lambda x, qt, rows=None: quant_contract(
+            x, qt, k_lead=1, rows=rows))
+        want = np.asarray(run(x, qt)[0].astype(jnp.float32))
+        for rows in (0, 1, m * 1100 // 2048, m - 1, m):
+            got = np.asarray(run(x, qt, jnp.array([rows], jnp.int32)
+                                 )[0].astype(jnp.float32))
+            live = -(-rows // 256) * 256
+            if not (np.array_equal(got[:live], want[:live])
+                    and not got[live:].any()):
+                raise AssertionError(
+                    f"int{bits} [{m}x{k}]@[{k}x{n}] told {rows} real rows: "
+                    "a live tile differs or a padding tile is not zeros")
+        print(f"  PASS quant int{bits} [{m}x{k}]@[{k}x{n}] told 0, 1, "
+              f"{m * 1100 // 2048}, {m - 1}, {m} real rows: live tiles "
+              "bit-equal, padding tiles zeros")
+
+
 def main() -> int:
     backend = jax.default_backend()
     print(f"kernel_parity: backend={backend} devices={jax.device_count()}")
@@ -543,6 +575,7 @@ def main() -> int:
               "mode (validates this script, NOT Mosaic lowering).")
     quant_parity()
     quant_parent_arithmetic()
+    quant_padding_rows()
     flash_parity()
     ragged_parity()
     paged_parity()

@@ -1,7 +1,11 @@
 #!/usr/bin/env python
 """Time ``_quant_matmul_2d`` alone on the chip: one matmul a layer under a
 ``lax.scan`` over a stack of 8 int8 layers, at every block weight's shape of
-the cells' configurations, at M = 16, 64 and 256 rows.
+the cells' configurations, at M = 16, 64 and 256 rows (a decode step, a
+bucket of one row tile) and at what an admission of the cells hands it:
+M = 2,048 of which 1,100 rows are real, and M = 8,192 of which 5,000 are, in
+blocks of 2,048 as ``models.model.run_layers`` runs K-EXAONE's FFNs
+(``--ms M:REAL``; a tree whose kernel takes no count computes every row).
 
 A row of the report is one (shape, M): the tile ``ops/quant_matmul._tiles``
 chose, microseconds a call on the host's clock (``wall_us``: the scan's
@@ -10,7 +14,10 @@ median device time from a profiler trace of the same program
 (``kernel_us``), the time the weight's and scales' bytes take at the chip's
 819 GB/s (``bytes_us``: what is left of ``kernel_us`` is the dequantization,
 the MXU at hundreds of rows, and a call's fixed cost) and the rate
-(``gb_s``).
+(``gb_s``).  A row with a count of real rows has, beside them, the device
+time of a whole call, every block of it (``call_us``), the row tiles that
+hold a real row (``live_tiles``) and ``call_us`` over them
+(``us_per_live_tile``): the kernel's time a live tile, apart from a cell.
 
     python tools/quant_matmul_bench.py --out chiprun_out/quant_matmul_bench.json
     python tools/quant_matmul_bench.py --tree _chip/parent   # another checkout
@@ -27,6 +34,7 @@ runs the interpreter at a toy size to check itself (``--rehearsal``).
 from __future__ import annotations
 
 import argparse
+import inspect
 import json
 import math
 import os
@@ -39,6 +47,7 @@ PRESETS = ("qwen2-7b", "pythia-6.9b", "lfm2-8b-a1b", "ax-k1-ep16")
 HBM_GB_S = 819.0
 BLOCK = 128
 LAYERS = 8
+TOKEN_BLOCK = 2048  # models.model._TOKEN_BLOCK
 
 
 def cell_shapes(presets) -> dict[tuple[int, int], list[str]]:
@@ -66,23 +75,31 @@ def cell_shapes(presets) -> dict[tuple[int, int], list[str]]:
     return shapes
 
 
-def kernel_us(trace_dir: str) -> float | None:
-    """Median device time of the `_quant_matmul_2d` events of a trace."""
+def kernel_us(trace_dir: str) -> tuple[float, float] | None:
+    """(median of the `_quant_matmul_2d` events' device times, sum of them
+    and of the padding kernel's behind a counted call) of a trace,
+    microseconds."""
     from benchmark import trace_reduce
 
     path = trace_reduce.find_xplane(trace_dir)
-    durs = sorted(
-        e.dur_ns / 1e3 for e in (trace_reduce.load_xplane(path) if path else ())
+    events = [
+        (e.name, e.dur_ns / 1e3)
+        for e in (trace_reduce.load_xplane(path) if path else ())
         if e.plane.startswith("/device:TPU:0") and e.line == "XLA Ops"
-        and e.name == "_quant_matmul_2d")
-    return durs[len(durs) // 2] if durs else None
+        and e.name.startswith("_quant_matmul_2d")]
+    durs = sorted(d for name, d in events if name == "_quant_matmul_2d")
+    if not durs:
+        return None
+    return durs[len(durs) // 2], sum(d for _, d in events)
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--tree", default=HERE)
     ap.add_argument("--presets", default=",".join(PRESETS))
-    ap.add_argument("--ms", default="16,64,256")
+    ap.add_argument("--ms", default="16,64,256,2048:1100,8192:5000",
+                    help="rows a call, M or M:REAL (REAL of them real; over "
+                         "2,048 in blocks of 2,048)")
     ap.add_argument("--tiles", default="",
                     help="KxN=BNxBK[,KxN=BNxBK...]: other tiles to time")
     ap.add_argument("--pads-mb", default="0")
@@ -102,10 +119,14 @@ def main() -> int:
               "checks the script on the interpreter)", file=sys.stderr)
         return 2
     if args.rehearsal:
-        shapes, ms, layers = {(256, 384): ["rehearsal"]}, [16], 2
+        shapes, ms, layers = {(256, 384): ["rehearsal"]}, "16,768:300,4096:2500", 2
     else:
-        shapes = cell_shapes(args.presets.split(","))
-        ms, layers = [int(m) for m in args.ms.split(",")], LAYERS
+        shapes, ms, layers = cell_shapes(args.presets.split(",")), args.ms, LAYERS
+    # (M, real rows or None) a call
+    ms = [(int(m), int(real) if real else None) for m, _, real in
+          (item.partition(":") for item in ms.split(","))]
+    counted = "real" in inspect.signature(
+        qm._quant_matmul_2d.__wrapped__).parameters
     extra: dict[tuple[int, int], list[tuple[int, int]]] = {}
     for item in filter(None, args.tiles.split(",")):
         kn, tile = item.split("=")
@@ -117,7 +138,7 @@ def main() -> int:
     report = {"device": {"platform": dev.platform,
                          "device_kind": dev.device_kind},
               "tree": os.path.abspath(args.tree), "k_minor": k_minor,
-              "rows": []}
+              "counted": counted, "rows": []}
 
     def timed(fn, *a):
         """(host microseconds a call, the kernel's own) of the jitted
@@ -129,13 +150,14 @@ def main() -> int:
         out.block_until_ready()
         wall = (time.perf_counter() - t0) / reps / layers * 1e6
         if not on_tpu:
-            return wall, None
+            return wall, None, None
         with tempfile.TemporaryDirectory() as td:
             with jax.profiler.trace(td):
                 for _ in range(3):
                     out = fn(*a)
                 out.block_until_ready()
-            return wall, kernel_us(td)
+            median, total = kernel_us(td) or (None, None)
+            return wall, median, total and total / (3 * layers)
 
     for (k, n), leaves in sorted(shapes.items()):
         own = qm._tiles(k, n, BLOCK, 8)
@@ -152,17 +174,32 @@ def main() -> int:
                 -127, 128, jnp.int8).block_until_ready()
             s = jax.random.uniform(keys[1], (layers, n // BLOCK, k),
                                    jnp.float32, 1e-3, 3e-3)
-            for m in ms:
+            for m, real in ms:
                 x = jax.random.normal(keys[2], (m, k), jnp.bfloat16)
-                bm = min(qm._BM_MAX, m)
+                # an admission over TOKEN_BLOCK tokens: a block at a time
+                mb = min(m, TOKEN_BLOCK)
+                bm = min(qm._BM_MAX, mb)
+                left = None if real is None else jnp.clip(
+                    real - mb * jnp.arange(m // mb, dtype=jnp.int32)[:, None],
+                    0, mb)  # [blocks, 1]: the real rows of each
                 tiles = [own] + [(*t, own[2]) for t in extra.get((k, n), ())
                                  if k_minor]
                 for tile in tiles:
                     def stack(x, q, s, tile=tile):
-                        def layer(acc, i):
-                            y = qm._quant_matmul_2d(
-                                x, q, s, i.reshape(1), bits=8, bm=bm,
+                        def block(i, xb, rows=None):
+                            given = () if rows is None or not counted else (rows,)
+                            return qm._quant_matmul_2d(
+                                xb, q, s, i.reshape(1), *given, bits=8, bm=bm,
                                 tiles=tile, interpret=not on_tpu)
+
+                        def layer(acc, i):
+                            if m == mb:
+                                y = block(i, x, None if left is None else left[0])
+                            else:
+                                y = jax.lax.map(
+                                    lambda a: block(i, *a),
+                                    (x.reshape(m // mb, mb, k), left)
+                                ).reshape(m, n)
                             return acc + y.astype(jnp.float32), None
                         return jax.lax.scan(
                             layer, jnp.zeros((m, n), jnp.float32),
@@ -171,7 +208,7 @@ def main() -> int:
                     row = {"k": k, "n": n, "m": m, "tile": list(tile),
                            "pad_mb": pad_mb, "leaves": leaves}
                     try:
-                        wall, kern = timed(jax.jit(stack), x, q, s)
+                        wall, kern, call = timed(jax.jit(stack), x, q, s)
                     except Exception as e:  # a tile too large for VMEM
                         row["error"] = f"{type(e).__name__}: {e}"[:300]
                     else:
@@ -180,6 +217,13 @@ def main() -> int:
                                    bytes_us=bytes_us)
                         if kern:
                             row["gb_s"] = bytes_us / kern * HBM_GB_S
+                        if real is not None:
+                            live = sum(qm.live_rows(mb, int(r[0])) for r in left
+                                       ) // bm if counted else m // bm
+                            row.update(real_rows=real, live_tiles=live,
+                                       call_us=call)
+                            if call:
+                                row["us_per_live_tile"] = call / live
                     report["rows"].append(row)
                     print(json.dumps(row), flush=True)
             del q, s, pad
