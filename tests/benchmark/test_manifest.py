@@ -3,14 +3,20 @@ by data, and one rehearsal end to end.
 
 Every check of the manifest's structure is a function of a manifest, so that
 the file and a copy with a later PR's entries appended go through the same
-code (PR 41): a check that breaks when someone appends fails here first."""
+code (PR 41): a check that breaks when someone appends fails here first.  No
+test says how many entries the manifest has: what holds for today's count is
+written from ``len`` of the list, and one test appends real files to a copy of
+the benchmark's tree and runs this whole directory on it (PR 43)."""
 
 import copy
+import importlib.util
 import json
 import os
 import re
+import shutil
 import subprocess
 import sys
+from xml.etree import ElementTree
 
 import pytest
 
@@ -152,7 +158,6 @@ def check_moves_and_cells(m):
     # (collectives, a model or state that is sharded, a router over
     # replicas), since it costs four times the chip time in every later
     # check: at most a quarter of the cells, rounded down, and one always.
-    # Today none does (PERF.md, section 4 and Open questions).
     assert all(w["chips"] in (1, 4) for w in m["workloads"])
     four = [w["name"] for w in m["workloads"] if w["chips"] == 4]
     assert len(four) <= max(1, len(m["workloads"]) // 4), four
@@ -167,26 +172,51 @@ def test_moves_and_cells():
 DECLARED = [(t.CELL, t.DECLARED, t.NEW) for t in (
     test_moe_metrics, test_axk1_metrics, test_kexaone_metrics)]
 LATER = "later-model-int8.later-mix"
+BASE = {"unit": "%", "better": "higher", "source": "device_trace",
+        "layer": "kernels", "moves": "out_tok_s"}
 
 
-def appended(chips):
-    """A copy of the manifest after a later PR: one configuration, one cell
-    of it, one per-layer metric of that cell alone and one of every cell,
-    each at the END of its list."""
-    m = copy.deepcopy(manifest())
+def append_cell(m, config, traffic, chips, own):
+    """What one ``model_config`` PR appends to manifest ``m``: a
+    configuration, one cell of it and the per-layer metric ``own`` of that
+    cell alone, each at the END of its list.  Returns the cell's name."""
+    cell = f"{config}.{traffic}"
     m["configs"].append({
-        "name": "later-model-int8", "source": "https://example.org/later",
-        "file": "benchmark/configs/later-model-int8.json",
+        "name": config, "source": f"https://example.org/{config}",
+        "file": f"benchmark/configs/{config}.json",
         "reduced": ["num_hidden_layers"], "why": "a layer kind no cell has"})
     m["workloads"].append({
-        "name": LATER, "config": "later-model-int8", "traffic": "later-mix",
-        "chips": chips, "why": "what a later PR measures, on as many chips "
-                               "as it needs"})
-    base = {"unit": "%", "better": "higher", "source": "device_trace",
-            "layer": "kernels", "moves": "out_tok_s"}
-    m["per_layer"].append({"name": "later_scan_roofline", **base,
-                           "workloads": [LATER]})
-    m["per_layer"].append({"name": "later_step_mfu", **base})
+        "name": cell, "config": config, "traffic": traffic, "chips": chips,
+        "why": "what a later PR measures, on as many chips as it needs"})
+    m["per_layer"].append({"name": own, **BASE, "workloads": [cell]})
+    return cell
+
+
+def appended(chips, earlier=0):
+    """A copy of the manifest after ``earlier`` PRs that each appended a
+    cell of one chip, and then a later one: its cell on ``chips`` chips, one
+    per-layer metric of that cell alone and one of every cell.  Returns the
+    copy and the names that were made up."""
+    m = copy.deepcopy(manifest())
+    invented = set()
+    for i in range(earlier):
+        own = f"earlier_{i}_roofline"
+        invented |= {own, append_cell(m, f"earlier-model-{i}-int8",
+                                      "earlier-mix", 1, own)}
+    invented |= {"later_scan_roofline", "later_step_mfu",
+                 append_cell(m, "later-model-int8", "later-mix", chips,
+                             "later_scan_roofline")}
+    m["per_layer"].append({"name": "later_step_mfu", **BASE})
+    return m, invented
+
+
+def on_four_chips(m, k):
+    """A copy of ``m`` in which exactly ``k`` cells ask for four chips: those
+    that do already come first, then the others from the top of the list."""
+    m = copy.deepcopy(m)
+    cells = sorted(m["workloads"], key=lambda w: w["chips"] != 4)
+    for i, w in enumerate(cells):
+        w["chips"] = 4 if i < k else 1
     return m
 
 
@@ -199,16 +229,18 @@ def check_all(m, invented=()):
         check_declared(m, cell, triple, new)
 
 
+@pytest.mark.parametrize("earlier", [0, 1, 2, 4])
 @pytest.mark.parametrize("chips", [1, 4])
-def test_a_later_pr_appends_by_data(chips):
-    """What the next ``model_config`` PR does, done to a copy: every check of
-    this benchmark's manifest passes with its entries appended, and the new
-    cell's own declaration is found where it was put."""
-    invented = {LATER, "later_scan_roofline", "later_step_mfu"}
-    m = appended(chips)
-    assert len(m["workloads"]) == 7
+def test_a_later_pr_appends_by_data(chips, earlier):
+    """What the next ``model_config`` PR does, done to a copy, after
+    ``earlier`` PRs have done it before: every check of this benchmark's
+    manifest passes with its entries appended, and the new cell's own
+    declaration is found where it was put."""
+    m, invented = appended(chips, earlier)
+    assert len(m["workloads"]) == len(manifest()["workloads"]) + earlier + 1
     check_all(m, invented)
-    with pytest.raises(AssertionError):      # ... which needs its reader file
+    # ... which needs its reader file, and is refused for nothing else.
+    with pytest.raises(AssertionError, match="later_scan_roofline"):
         check_declared(m, LATER, ("later-model-int8", "later-mix", chips),
                        ["later_scan_roofline"])
     # An entry put in the MIDDLE of the list parts a cell's metrics: refused
@@ -221,23 +253,141 @@ def test_a_later_pr_appends_by_data(chips):
     check_moves_and_cells(parted)
     with pytest.raises(AssertionError):
         check_declared(parted, cell, triple, new)
-    # The chip rule bites: two four-chip cells among seven are one too many.
-    second = copy.deepcopy(m)
-    for w in second["workloads"][:2 if chips == 1 else 1]:
-        w["chips"] = 4
+    # The chip rule bites, written from the count: as many four-chip cells as
+    # a quarter of the cells rounded down (one always) pass, and one more is
+    # refused, whatever the later cell itself asks for.
+    allowed = max(1, len(m["workloads"]) // 4)
+    check_moves_and_cells(on_four_chips(m, allowed))
     with pytest.raises(AssertionError):
-        check_moves_and_cells(second)
+        check_moves_and_cells(on_four_chips(m, allowed + 1))
 
 
-def test_the_chip_rule_counts_a_quarter_rounded_down():
-    """Among eight cells two may ask for four chips; none may ask for two."""
-    m = appended(4)
-    m["workloads"].append({**m["workloads"][-1], "name": LATER + "-2",
-                           "traffic": "later-mix-2"})     # two among eight
-    check_moves_and_cells(m)
-    m["workloads"][0]["chips"] = 2
+@pytest.mark.parametrize("n, allowed", [(6, 1), (7, 1), (8, 2), (9, 2),
+                                        (10, 2), (11, 2), (12, 3)])
+def test_the_chip_rule_counts_a_quarter_rounded_down(n, allowed):
+    """Among ``n`` cells ``allowed`` may ask for four chips and no more; none
+    may ask for two.  The manifests are built to their length, so the table
+    stands whatever BENCHMARK.json holds."""
+    built = {
+        "workloads": [{"name": f"model-{i}.mix", "chips": 1}
+                      for i in range(n)],
+        "end_to_end": [{"name": "out_tok_s"}, {"name": "setup_s"}],
+        "per_layer": [{"name": "step_mfu", "moves": "out_tok_s"}]}
+    check_moves_and_cells(on_four_chips(built, allowed))
     with pytest.raises(AssertionError):
-        check_moves_and_cells(m)
+        check_moves_and_cells(on_four_chips(built, allowed + 1))
+    built["workloads"][0]["chips"] = 2
+    with pytest.raises(AssertionError):
+        check_moves_and_cells(built)
+
+
+# The appended cell's own test module, as its PR writes it; the names in
+# braces are filled in by the test below.
+APPENDED_TEST = '''"""The appended cell's declaration, and that this run reads the copy."""
+import json
+import os
+
+from benchmark import metrics
+
+from declared_cell import check_declared
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+CELL = {cell!r}
+DECLARED = {declared!r}   # config, traffic, chips
+NEW = ["appended_useful_share"]
+
+
+def manifest():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def test_the_cell_is_declared():
+    check_declared(manifest(), CELL, DECLARED, NEW)
+
+
+def test_this_run_reads_the_copy():
+    assert len(manifest()["workloads"]) == {cells}
+    assert os.path.samefile(ROOT, {copy!r})
+    assert os.path.samefile(metrics.LAYER_DIR,
+                            os.path.join(ROOT, "benchmark", "layer_metrics"))
+'''
+
+
+def test_a_real_cell_appended_to_a_copy_of_the_tree(tmp_path):
+    """What the driver's next ``model_config`` PR does, done with REAL files
+    to a copy of the benchmark's tree, and every test of this directory run
+    on the result: a check that cannot take an appended configuration, cell,
+    reader or entry, in any file here, fails in the PR that writes it."""
+    m = manifest()
+    tree = str(tmp_path / "tree")
+    for path in m["paths"]:                 # the program is not copied
+        shutil.copytree(os.path.join(ROOT, path), os.path.join(tree, path),
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    # Entries at the END of their lists only: the newest cell's configuration
+    # under another name on the same traffic, one metric of the new cell
+    # alone and one of every cell, both counted by the batcher.
+    donor = m["workloads"][-1]
+    name = "appended-model-int8"
+    cell = append_cell(m, name, donor["traffic"], 1, "appended_useful_share")
+    counted = {**BASE, "source": "program_counter",
+               "layer": "scheduler and batcher"}
+    m["per_layer"][-1].update(counted)
+    m["per_layer"].append({"name": "appended_row_fill", **counted})
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           donor["config"] + ".json")) as f:
+        cfg = json.load(f)
+    cfg.update(name=name, source=m["configs"][-1]["source"])
+    m["configs"][-1]["reduced"] = cfg["reduced"]
+    # New files only: the configuration, the cell's own test, and copies of
+    # accepted files under the new names: the golden and two ratio readers.
+    new = {
+        f"benchmark/configs/{name}.json": json.dumps(cfg),
+        "tests/benchmark/test_appended_metrics.py": APPENDED_TEST.format(
+            cell=cell, declared=(name, donor["traffic"], 1),
+            cells=len(m["workloads"]), copy=tree)}
+    copies = {
+        f"benchmark/golden/{name}.json":
+            f"benchmark/golden/{donor['config']}.json",
+        "benchmark/layer_metrics/appended_useful_share.json":
+            "benchmark/layer_metrics/decode_useful_share.json",
+        "benchmark/layer_metrics/appended_row_fill.json":
+            "benchmark/layer_metrics/decode_row_fill.json"}
+    assert not any(os.path.exists(os.path.join(tree, rel))
+                   for rel in (*new, *copies))
+    for rel, src in copies.items():
+        shutil.copy(os.path.join(tree, src), os.path.join(tree, rel))
+    new["BENCHMARK.json"] = json.dumps(m, indent=1)   # the one file rewritten
+    for rel, text in new.items():
+        with open(os.path.join(tree, rel), "w") as f:
+            f.write(text)
+    collected = subprocess.run(
+        [sys.executable, "-m", "pytest", os.path.dirname(__file__),
+         "--collect-only", "-q", "-o", "addopts=", "-p", "no:cacheprovider"],
+        capture_output=True, text=True, timeout=120, cwd=ROOT)
+    said = re.search(r"(\d+) tests collected", collected.stdout)
+    assert collected.returncode == 0 and said, collected.stdout[-2000:]
+    today = int(said.group(1))
+    me = "tests/benchmark/test_manifest.py::"
+    program = importlib.util.find_spec("distributed_llms_tpu").origin
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env.update(PYTHONPATH=os.path.dirname(os.path.dirname(program)),
+               JAX_PLATFORMS="cpu")
+    report = str(tmp_path / "inner.xml")
+    inner = subprocess.run(
+        [sys.executable, "-m", "pytest", "tests/benchmark", "-q",
+         "-p", "no:cacheprovider", "--rootdir", tree, "--junitxml", report,
+         "--deselect", me + "test_a_real_cell_appended_to_a_copy_of_the_tree",
+         "--deselect", me + "test_rehearsal_end_to_end"],
+        capture_output=True, text=True, timeout=300, env=env, cwd=tree)
+    assert inner.returncode == 0, inner.stdout[-6000:] + inner.stderr[-2000:]
+    cases = ElementTree.parse(report).getroot().iter("testcase")
+    passed = {(c.get("classname"), c.get("name")) for c in cases if not len(c)}
+    # Every test the directory has today but the two left out, and the new
+    # cell's own, which saw the copy's manifest: one cell more than this one.
+    assert len(passed) >= today - 2 + 2, inner.stdout[-2000:]
+    assert {("tests.benchmark.test_appended_metrics", name) for name in (
+        "test_the_cell_is_declared", "test_this_run_reads_the_copy")} <= passed
 
 
 def test_benchmark_imports_no_jax():
