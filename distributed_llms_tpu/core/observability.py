@@ -242,22 +242,38 @@ METRIC_DOCS: dict[str, str] = {
                                   "host work plus the device's prefill — "
                                   "resident rows wait this long "
                                   "(histogram)",
-    "batcher.admit.row_seconds": "one request's admission program and its "
-                                 "first-token fetch, inside "
-                                 "batcher.loop.admit (histogram; the "
-                                 "annotation carries rid, prompt_tokens, "
-                                 "cached_tokens, bucket, live_rows: the "
-                                 "bucket's rows the quantized matmuls "
-                                 "compute, key_slots: the keys a query of "
-                                 "it is scored against)",
+    "batcher.admit.row_seconds": "one request's admission, inside "
+                                 "batcher.loop.admit: its operands and "
+                                 "its program's dispatch, then (overlap "
+                                 "on) the fetch and the activation of the "
+                                 "admission launched BEFORE it, then the "
+                                 "selection and the pages of the next; "
+                                 "its own fetch where nothing follows at "
+                                 "once (histogram; the annotation carries "
+                                 "rid, prompt_tokens, cached_tokens, "
+                                 "bucket, live_rows: the bucket's rows "
+                                 "the quantized matmuls compute, "
+                                 "key_slots: the keys a query of it is "
+                                 "scored against, fetched_rid: the "
+                                 "earlier admission whose outputs it "
+                                 "fetched)",
     "batcher.admit.wait_device_seconds": "the engine thread blocked in an "
                                          "admission's ONE device_get "
                                          "(first token, logprob, expert "
-                                         "counts), inside "
-                                         "batcher.admit.row: prefill on "
-                                         "the chip; row minus this is the "
-                                         "host's part of an admission "
+                                         "counts), inside a "
+                                         "batcher.admit.row (overlap on: "
+                                         "the NEXT admission's, after its "
+                                         "launch; the round's last in its "
+                                         "own): prefill on the chip; the "
+                                         "row spans minus this is the "
+                                         "host's part of the admissions "
                                          "(histogram)",
+    "batcher.admit.overlapped": "admissions launched while the admission "
+                                "before them was still unfetched (the "
+                                "pipelined ones; beside batcher.admitted: "
+                                "admissions less pipelined runs, a round "
+                                "being one unless a swap restore or a "
+                                "chunked start parts it)",
     "batcher.admit.self_attention": "admissions whose attention read their "
                                     "own bucket of tokens: a fresh row, "
                                     "whose start the model sees while "
@@ -285,8 +301,11 @@ METRIC_DOCS: dict[str, str] = {
                                     "publishing and digest pre-hashing "
                                     "(histogram)",
     "batcher.queue_wait_seconds": "submit (or the requeue after a "
-                                  "preemption) to admission start, one "
-                                  "sample per admission (histogram)",
+                                  "preemption) to admission start: the "
+                                  "request's selection or, launched "
+                                  "behind an admission still on the "
+                                  "chip, that one's fetch; one sample "
+                                  "per admission (histogram)",
     # -- the time no model program was in flight (from a blocking fetch
     #    that returned the newest one's output to the next dispatch call),
     #    charged to the batcher.loop.* span it fell in: a lower bound of

@@ -1278,7 +1278,9 @@ class _Timeline:
     across residencies."""
     t_submit: float = 0.0   # submit()
     t_queued: float = 0.0   # submit, or the requeue after a preemption
-    t_admit: float = 0.0    # the latest admission's start (_unqueue)
+    t_admit: float = 0.0    # the latest admission's start: its
+    #                         selection, or the fetch of the admission it
+    #                         was launched behind (_note_admit_start)
     residencies: int = 0    # admissions: 1 + preemptions survived
     queue_s: float = 0.0    # sum over residencies of t_admit - t_queued
     admit_s: float = 0.0    # sum of admission start -> row resident
@@ -1373,6 +1375,34 @@ class _PendingPrefill:
     cached_pages: list[int] = field(default_factory=list)
     cached_len: int = 0
     digests: list = field(default_factory=list)
+
+
+@dataclass(eq=False)
+class _Admission:
+    """A request between its selection for a slot and its activation
+    there.  ``serial`` names a path that never pipelines (a ``"swap"``
+    restore, a ``"chunked"`` start) and leaves the rest unset.  Of a
+    monolithic admission the page fields are what
+    :meth:`ContinuousBatcher._reserve_row_pages` handed out; once launched,
+    ``outs`` are the program's outputs still on the device (first token,
+    logprob, row mask, expert counts) and ``ticket`` its place in the
+    engine thread's account of the device.  It owns ``slot`` and its pages
+    until it is settled, with no row to show for them yet."""
+
+    slot: int
+    req: _Request
+    pfx: "_Prefix | None" = None
+    serial: str | None = None
+    total_len: int = 0
+    page_list: Any = None  # [pages_per_row] int32, paged mode
+    pages: list[int] = field(default_factory=list)
+    cached_pages: list[int] = field(default_factory=list)
+    cached_len: int = 0
+    digests: list = field(default_factory=list)
+    sampling: tuple = ()  # (temperature, top_p, top_k) as the row decodes
+    ticket: int = 0
+    outs: tuple = ()
+    cancelled: bool = False  # cancel_row took it in flight
 
 
 @dataclass
@@ -1941,6 +1971,10 @@ class ContinuousBatcher:
         self.overlap_stats = {"dispatched_ahead": 0, "carry_syncs": 0}
         self._cancel_dirty = False
         self._tables_dirty = False
+        # The admission side of the same switch: with ``overlap`` on a
+        # round's admissions are pipelined one deep (:meth:`_admit_pending`),
+        # and this is the one launched whose outputs nobody has fetched.
+        self._admit_inflight: _Admission | None = None
         # The engine thread's account of the device (``_launch``,
         # ``_note_fetched``, ``_charge_starved``), on the batcher's clock:
         # model programs dispatched and, of them, those a blocking fetch
@@ -2584,9 +2618,12 @@ class ContinuousBatcher:
         """The ONE blocking fetch of an admission: what the host needs of
         its outputs (first token, its logprob, and where the program
         hands them out the row's mask and the expert counts) in one
-        ``jax.device_get``, after every dispatch the admission makes.  A
-        row's self time (``batcher.admit.row`` minus this span) is the
-        host's part of an admission."""
+        ``jax.device_get``, after every dispatch the admission makes and,
+        with ``overlap`` on, after the NEXT admission's launch
+        (:meth:`_admit_pending`), so the fetch that returns leaves a program in
+        flight and opens no starved interval but at a round's end.  The
+        row spans' self time (``batcher.admit.row`` minus this span) is
+        the host's part of the admissions."""
         with self._span("batcher.admit.wait_device"):
             host = jax.device_get(outs)
         self._note_fetched(ticket)
@@ -2669,6 +2706,23 @@ class ContinuousBatcher:
             METRICS.inc("batcher.cancelled")
             self._note_finished(dropped, len(self.results[rid]), "cancelled")
             return True
+        adm = self._admit_inflight
+        if adm is not None and adm.req.rid == rid and not adm.cancelled:
+            # Launched and not yet activated (the canceller runs in the
+            # callback of the admission before it): its program runs to its
+            # end on the chip, its token is never delivered.  The pages go
+            # back now (the device orders later writers behind it, as behind
+            # a cancelled row's last chunk), the slot when it is settled.
+            adm.cancelled = True
+            self.results[rid] = list(adm.req.resume_emitted or [])
+            self.result_logprobs[rid] = list(adm.req.resume_lps or [])
+            if adm.cached_pages or adm.pages:
+                self._release_pages(adm.cached_pages + adm.pages)
+                self.tables[adm.slot] = 0
+            self.sched.note_freed(adm.req, len(self.results[rid]))
+            self._note_finished(adm.req, len(self.results[rid]), "cancelled")
+            METRICS.inc("batcher.cancelled")
+            return True
         for i in range(self.b):
             row = self.rows[i]
             if row.rid == rid:
@@ -2708,8 +2762,10 @@ class ContinuousBatcher:
         return sub
 
     def _free_slot(self) -> int | None:
+        taken = self._admit_inflight
         for i in range(self.b):
-            if not self.active[i] and self.rows[i].rid is None:
+            if not self.active[i] and self.rows[i].rid is None \
+                    and (taken is None or i != taken.slot):
                 return i
         return None
 
@@ -2720,20 +2776,29 @@ class ContinuousBatcher:
         with self._lock:
             return self.sched.admission_order(self.queue)
 
-    def _unqueue(self, req: "_Request") -> None:
+    def _unqueue(self, req: "_Request", behind: bool = False) -> None:
         """Remove an admitted request from the queue (identity compare —
         _Request is eq=False) under the submission lock.  This is the
         ONE admission-commit point (plain, chunked-start, and swap-
         restore paths all pass through it), so the scheduler's tenant
         accounting charges exactly once per residency here — the paired
         ``note_freed`` fires wherever the row later releases its slot
-        (completion sweep, cancel, preemption)."""
+        (completion sweep, cancel, preemption).  Its queue wait ends here
+        too, unless it is launched ``behind`` an admission still on the
+        chip: then it ends when that one's fetch returns
+        (:meth:`_settle_admission`)."""
         with self._lock:
             self.queue.remove(req)
         self.sched.note_admitted(req, len(req.ids) + req.max_new_tokens)
+        req.timeline.residencies += 1
+        if not behind:
+            self._note_admit_start(req)
+
+    def _note_admit_start(self, req: "_Request") -> None:
+        """The request's wait in the queue is over and its admission
+        begins: the chip is free to take it up."""
         tl = req.timeline
         tl.t_admit = self._clock()
-        tl.residencies += 1
         tl.queue_s += tl.t_admit - tl.t_queued
         METRICS.observe("batcher.queue_wait_seconds",
                         tl.t_admit - tl.t_queued)
@@ -3050,8 +3115,14 @@ class ContinuousBatcher:
         (nothing was allocated)."""
         rule = (self.faults.fire("batcher.page_alloc", tag=tag)
                 if self.faults is not None else None)
-        avail = (0 if rule is not None and rule.action == "exhaust"
-                 else self._pages_available())
+        exhaust = rule is not None and rule.action == "exhaust"
+        avail = 0 if exhaust else self._pages_available()
+        if avail < need and self._admit_inflight is not None:
+            # Victims are chosen among the rows the serial order would
+            # show: the admission in flight becomes one first, and what its
+            # callback cancels gives its pages back.
+            self._drain_admission()
+            avail = 0 if exhaust else self._pages_available()
         while avail < need:
             v = self._pick_victim(below_priority=below_priority)
             if v is None:
@@ -3292,184 +3363,289 @@ class ContinuousBatcher:
             for slot in list(self._prefills):
                 fused = self.sched.fuse_prefill() and bool(self.active.any())
                 self._advance_chunk(slot, advance=not fused)
-            while True:
-                i = self._free_slot()
-                if i is None:
-                    return
-                req = self._next_request()
-                if req is None:
-                    return
-                if req.swap_handle is not None:
-                    # Swap-preempted resume: scatter the parked pages back
-                    # instead of recomputing the prefix.  True = restored
-                    # (next loop iteration admits more); False = the parcel
-                    # was unusable and the request fell through to recompute
-                    # (still queued, swap_handle cleared — re-selected next
-                    # iteration); None = back-pressure, stop this round.
-                    got = self._try_restore_swapped(i, req)
-                    if got is None:
-                        return
-                    continue
-                pfx = self.prefixes[req.prefix] if req.prefix is not None else None
-                pfx_len = len(pfx.ids) if pfx else 0
-                total_len = pfx_len + len(req.ids)
-                thr = self.sched.chunk_threshold()
-                if thr is not None and len(req.ids) > thr:
-                    if len(self._prefills) >= self.prefill_concurrency:
-                        # Prefill slots full, and strict admission order: stop
-                        # admitting (the selected request never gets jumped).
-                        return
-                    self._unqueue(req)
-                    self._start_chunked(i, req, pfx)
-                    continue
-                pages: list[int] = []
-                cached_pages: list[int] = []
-                cached_len = 0
-                digests: list[bytes] = []
-                if self.paged:
-                    got = self._reserve_row_pages(i, req, total_len, pfx)
-                    if got is None:
-                        # Dry pool with no preemptable victim: back-pressure.
-                        # The request stays queued (never removed), admission
-                        # stops for this round.
-                        return
-                    page_list, pages, cached_pages, cached_len, digests = got
-                # A fresh row attends among its own bucket of tokens; one
-                # behind a prefix (named or cached) scores the suffix against
-                # every slot of the row cache.
-                fresh = pfx is None and not cached_len
-                bucket = _bucket(len(req.ids) - cached_len)
-                # The rows of the bucket the quantized matmuls compute: the
-                # row tiles that hold a token of the prompt (or suffix).
-                live = live_rows(bucket, len(req.ids) - cached_len)
-                with self._span(
-                    "batcher.admit.row", rid=req.rid,
-                    prompt_tokens=total_len, cached_tokens=cached_len,
-                    bucket=bucket, live_rows=live,
-                    key_slots=min(bucket, self.s) if fresh else self.s,
-                ):
-                    self._unqueue(req)
-                    self._note_unmatched(req, pfx)
-                    METRICS.inc("batcher.admit.matmul_rows", bucket)
-                    METRICS.inc("batcher.admit.matmul_rows_live", live)
-                    if fresh:
-                        METRICS.inc("batcher.admit.self_attention")
-                    else:
-                        METRICS.inc("batcher.admit.row_cache_attention")
-                    # Bucket for compile reuse, but never past what fits after the
-                    # prefix: forward's contract is cache_index + T <= max_len, and
-                    # dynamic_update_slice CLAMPS an overflowing start — the suffix
-                    # K/V would land misaligned with its mask/positions, silently
-                    # corrupting the row.  (submit() guaranteed the real prompt fits.)
-                    tp = min(_bucket(len(req.ids)), self.s - pfx_len)
-                    prompt = np.full((tp,), self.pad_id, np.int32)
-                    prompt[: len(req.ids)] = req.ids
-                    # Per-request sampling: traced scalar overrides (no recompile
-                    # per value) only when the request diverges from the config.
-                    req_t = (self.sampling["temperature"] if req.temperature is None
-                             else float(req.temperature))
-                    req_p = (self.sampling["top_p"] if req.top_p is None
-                             else float(req.top_p))
-                    req_k = (self.sampling["top_k"] if req.top_k is None
-                             else int(req.top_k))
-                    custom = (req_t != self.sampling["temperature"]
-                              or req_p != self.sampling["top_p"]
-                              or req_k != self.sampling["top_k"])
-                    extra = (
-                        dict(temp_req=jnp.float32(req_t), topp_req=jnp.float32(req_p))
-                        if custom else {}
-                    )
-                    if custom and req_k != self.sampling["top_k"]:
-                        extra["topk_req"] = jnp.int32(req_k)
-                    if req.constraint is not None:
-                        # The first output token draws under the automaton's
-                        # start-state mask (a resumed request replays its emitted
-                        # prefix to recover the state first).
-                        st0 = req.constraint.advance(0, req.resume_emitted or [])
-                        extra["mask_req"] = jnp.asarray(req.constraint.bias[st0])
-                    moe: list = []
-                    if self.paged and pfx is not None:
-                        self.cache, tok, lp = self._launch(
-                            admit_row_with_prefix_paged, self.params, self.cfg, self.cache, jnp.asarray(page_list),
-                            pfx.k, pfx.v, jnp.int32(pfx_len),
-                            jnp.asarray(prompt), jnp.int32(len(req.ids)),
-                            self._split_rng(), pm=self.pm, **self.sampling, **extra,
+            # The round's admissions, pipelined one deep: launch admission
+            # k+1, THEN fetch and activate k (its program has been running
+            # all the while, and the device takes k+1 up behind it through
+            # the donated cache), then select k+2.  So the host's part of a
+            # row (operands, the dispatch call, the activation and the
+            # stream callback of the one before, the selection and the pages
+            # of the one after) runs with a program in flight; what stays
+            # exposed in a round is its first selection and launch and its
+            # last activation.  Every fetch lies inside a row span: the
+            # predecessor's after this launch (``fetched_rid``), an
+            # admission's own where nothing follows it at once (the round
+            # ends, or a swap restore or a chunked start comes next: those
+            # run serially, as they did).  :meth:`_select_admission` is
+            # where that is decided, and the one place that settles an
+            # admission for what comes next; ``overlap`` off settles each
+            # before the next is selected: the serial order, the control
+            # of tests/runtime/test_admit_pipeline.py.  An exception leaves
+            # through the ``finally``, which settles what is in flight.
+            # The programs are dispatched from THIS frame: an admission
+            # program's lowering is a quarter slower from two frames deeper
+            # (PERF.md section 6, PR 44), and that is set-up time.
+            try:
+                adm = self._select_admission()
+                while adm is not None:
+                    if adm.serial == "swap":
+                        # Swap-preempted resume: scatter the parked pages back
+                        # instead of recomputing the prefix.  True = restored
+                        # (the next selection admits more); False = the parcel
+                        # was unusable and the request fell through to
+                        # recompute (still queued, swap_handle cleared:
+                        # selected again); None = back-pressure, stop this
+                        # round.
+                        if self._try_restore_swapped(adm.slot,
+                                                     adm.req) is None:
+                            return
+                        adm = self._select_admission()
+                        continue
+                    if adm.serial == "chunked":
+                        self._unqueue(adm.req)
+                        self._start_chunked(adm.slot, adm.req, adm.pfx)
+                        adm = self._select_admission()
+                        continue
+                    i, req, pfx = adm.slot, adm.req, adm.pfx
+                    pfx_len = len(pfx.ids) if pfx else 0
+                    total_len, page_list = adm.total_len, adm.page_list
+                    cached_pages, cached_len = adm.cached_pages, adm.cached_len
+                    digests = adm.digests
+                    # A fresh row attends among its own bucket of tokens; one
+                    # behind a prefix (named or cached) scores the suffix
+                    # against every slot of the row cache.
+                    fresh = pfx is None and not cached_len
+                    bucket = _bucket(len(req.ids) - cached_len)
+                    # The rows of the bucket the quantized matmuls compute:
+                    # the row tiles that hold a token of the prompt (or
+                    # suffix).
+                    live = live_rows(bucket, len(req.ids) - cached_len)
+                    prev = self._admit_inflight
+                    ahead = {} if prev is None else {"fetched_rid": prev.req.rid}
+                    with self._span(
+                        "batcher.admit.row", rid=req.rid,
+                        prompt_tokens=total_len, cached_tokens=cached_len,
+                        bucket=bucket, live_rows=live,
+                        key_slots=min(bucket, self.s) if fresh else self.s,
+                        **ahead,
+                    ):
+                        METRICS.inc("batcher.admit.matmul_rows", bucket)
+                        METRICS.inc("batcher.admit.matmul_rows_live", live)
+                        if fresh:
+                            METRICS.inc("batcher.admit.self_attention")
+                        else:
+                            METRICS.inc("batcher.admit.row_cache_attention")
+                        self._unqueue(req, behind=prev is not None)
+                        self._note_unmatched(req, pfx)
+                        # Bucket for compile reuse, but never past what fits after the
+                        # prefix: forward's contract is cache_index + T <= max_len, and
+                        # dynamic_update_slice CLAMPS an overflowing start — the suffix
+                        # K/V would land misaligned with its mask/positions, silently
+                        # corrupting the row.  (submit() guaranteed the real prompt fits.)
+                        tp = min(_bucket(len(req.ids)), self.s - pfx_len)
+                        prompt = np.full((tp,), self.pad_id, np.int32)
+                        prompt[: len(req.ids)] = req.ids
+                        # Per-request sampling: traced scalar overrides (no recompile
+                        # per value) only when the request diverges from the config.
+                        req_t = (self.sampling["temperature"] if req.temperature is None
+                                 else float(req.temperature))
+                        req_p = (self.sampling["top_p"] if req.top_p is None
+                                 else float(req.top_p))
+                        req_k = (self.sampling["top_k"] if req.top_k is None
+                                 else int(req.top_k))
+                        custom = (req_t != self.sampling["temperature"]
+                                  or req_p != self.sampling["top_p"]
+                                  or req_k != self.sampling["top_k"])
+                        extra = (
+                            dict(temp_req=jnp.float32(req_t), topp_req=jnp.float32(req_p))
+                            if custom else {}
                         )
-                        row_valid = np.arange(self.valid.shape[1]) < total_len
-                    elif self.paged and cached_len:
-                        # Prefix-cache HIT: the cached run seeds the row through a
-                        # pool gather; only the suffix prefills.  Writes for the
-                        # cached positions are routed to the scratch page — shared
-                        # pages are read-only while any row references them.
-                        write_list = page_list.copy()
-                        write_list[: len(cached_pages)] = 0
-                        suffix = req.ids[cached_len:]
-                        tc = min(_bucket(len(suffix)), self.s - cached_len)
-                        chunk = np.full((tc,), self.pad_id, np.int32)
-                        chunk[: len(suffix)] = suffix
-                        self.cache, tok, lp, *moe = self._launch(
-                            admit_row_auto_paged,
-                            self.params, self.cfg, self.cache,
-                            jnp.asarray(page_list), jnp.asarray(write_list),
-                            jnp.int32(cached_len), jnp.asarray(chunk),
-                            jnp.int32(len(suffix)), self._split_rng(),
-                            pm=self.pm, **self.sampling, **extra,
-                        )
-                        row_valid = np.arange(self.valid.shape[1]) < total_len
-                    elif self.paged:
-                        if self.cfg.family == "hybrid":
-                            extra["slot"] = jnp.int32(i)
-                        self.cache, tok, lp, *moe = self._launch(
-                            admit_row_paged,
-                            self.params, self.cfg, self.cache, jnp.asarray(page_list),
-                            jnp.asarray(prompt), jnp.int32(len(req.ids)),
-                            self._split_rng(), pm=self.pm, **self.sampling, **extra,
-                        )
-                        row_valid = np.arange(self.valid.shape[1]) < total_len
-                    elif pfx is not None:
-                        self.cache, tok, row_valid, lp = self._launch(
-                            admit_row_with_prefix,
-                            self.params, self.cfg, self.cache, jnp.int32(i),
-                            pfx.k, pfx.v, jnp.int32(pfx_len),
-                            jnp.asarray(prompt), jnp.int32(len(req.ids)),
-                            self._split_rng(), pm=self.pm, **self.sampling, **extra,
-                        )
-                    else:
-                        self.cache, tok, row_valid, lp = self._launch(
-                            admit_row,
-                            self.params, self.cfg, self.cache, jnp.int32(i),
-                            jnp.asarray(prompt), jnp.int32(len(req.ids)),
-                            self._split_rng(), pm=self.pm, **self.sampling, **extra,
-                        )
-                    ticket = self._n_dispatched
-                    if digests:
-                        # Publish the row's full prompt pages (first writer wins;
-                        # a digest another page already holds leaves ours private).
-                        # Pages inside the cached run are already published; the
-                        # fresh ones now hold exactly the hashed content — the
-                        # admission scatter just wrote it.
-                        for j in range(len(cached_pages), len(digests)):
-                            self.pool.publish_prefix(int(page_list[j]), digests[j])
-                    if self.speculative:
-                        # Seed the DRAFT cache for this row: full prompt (prefix
-                        # caching stores only target KV, so the draft prefills
-                        # prefix + suffix; bucketed for compile reuse).
-                        full_ids = (pfx.ids if pfx else []) + req.ids
-                        td = min(_bucket(len(full_ids)), self.s)
-                        dprompt = np.full((td,), self.pad_id, np.int32)
-                        dprompt[: len(full_ids)] = full_ids
-                        self.draft_cache = self._launch(
-                            admit_row_kv,
-                            self.draft_params, self.draft_cfg, self.draft_cache,
-                            jnp.int32(i), jnp.asarray(dprompt),
-                            jnp.int32(len(full_ids)),
-                        )
-                    tok, lp, row_valid, moe = self._fetch_admission(
-                        ticket, tok, lp, row_valid, moe)
-                    self._note_moe(*moe)
-                    self._activate_row(i, req, tok, lp, row_valid, total_len,
-                                       req_t, req_p, cached_pages + pages,
-                                       req_k=req_k, cached_len=cached_len)
+                        if custom and req_k != self.sampling["top_k"]:
+                            extra["topk_req"] = jnp.int32(req_k)
+                        if req.constraint is not None:
+                            # The first output token draws under the automaton's
+                            # start-state mask (a resumed request replays its emitted
+                            # prefix to recover the state first).
+                            st0 = req.constraint.advance(0, req.resume_emitted or [])
+                            extra["mask_req"] = jnp.asarray(req.constraint.bias[st0])
+                        moe: list = []
+                        if self.paged and pfx is not None:
+                            self.cache, tok, lp = self._launch(
+                                admit_row_with_prefix_paged, self.params, self.cfg, self.cache, jnp.asarray(page_list),
+                                pfx.k, pfx.v, jnp.int32(pfx_len),
+                                jnp.asarray(prompt), jnp.int32(len(req.ids)),
+                                self._split_rng(), pm=self.pm, **self.sampling, **extra,
+                            )
+                            row_valid = np.arange(self.valid.shape[1]) < total_len
+                        elif self.paged and cached_len:
+                            # Prefix-cache HIT: the cached run seeds the row through a
+                            # pool gather; only the suffix prefills.  Writes for the
+                            # cached positions are routed to the scratch page — shared
+                            # pages are read-only while any row references them.
+                            write_list = page_list.copy()
+                            write_list[: len(cached_pages)] = 0
+                            suffix = req.ids[cached_len:]
+                            tc = min(_bucket(len(suffix)), self.s - cached_len)
+                            chunk = np.full((tc,), self.pad_id, np.int32)
+                            chunk[: len(suffix)] = suffix
+                            self.cache, tok, lp, *moe = self._launch(
+                                admit_row_auto_paged,
+                                self.params, self.cfg, self.cache,
+                                jnp.asarray(page_list), jnp.asarray(write_list),
+                                jnp.int32(cached_len), jnp.asarray(chunk),
+                                jnp.int32(len(suffix)), self._split_rng(),
+                                pm=self.pm, **self.sampling, **extra,
+                            )
+                            row_valid = np.arange(self.valid.shape[1]) < total_len
+                        elif self.paged:
+                            if self.cfg.family == "hybrid":
+                                extra["slot"] = jnp.int32(i)
+                            self.cache, tok, lp, *moe = self._launch(
+                                admit_row_paged,
+                                self.params, self.cfg, self.cache, jnp.asarray(page_list),
+                                jnp.asarray(prompt), jnp.int32(len(req.ids)),
+                                self._split_rng(), pm=self.pm, **self.sampling, **extra,
+                            )
+                            row_valid = np.arange(self.valid.shape[1]) < total_len
+                        elif pfx is not None:
+                            self.cache, tok, row_valid, lp = self._launch(
+                                admit_row_with_prefix,
+                                self.params, self.cfg, self.cache, jnp.int32(i),
+                                pfx.k, pfx.v, jnp.int32(pfx_len),
+                                jnp.asarray(prompt), jnp.int32(len(req.ids)),
+                                self._split_rng(), pm=self.pm, **self.sampling, **extra,
+                            )
+                        else:
+                            self.cache, tok, row_valid, lp = self._launch(
+                                admit_row,
+                                self.params, self.cfg, self.cache, jnp.int32(i),
+                                jnp.asarray(prompt), jnp.int32(len(req.ids)),
+                                self._split_rng(), pm=self.pm, **self.sampling, **extra,
+                            )
+                        ticket = self._n_dispatched
+                        if digests:
+                            # Publish the row's full prompt pages (first writer wins;
+                            # a digest another page already holds leaves ours private).
+                            # Pages inside the cached run are already published; the
+                            # fresh ones now hold exactly the hashed content — the
+                            # admission scatter just wrote it.
+                            for j in range(len(cached_pages), len(digests)):
+                                self.pool.publish_prefix(int(page_list[j]), digests[j])
+                        if self.speculative:
+                            # Seed the DRAFT cache for this row: full prompt (prefix
+                            # caching stores only target KV, so the draft prefills
+                            # prefix + suffix; bucketed for compile reuse).
+                            full_ids = (pfx.ids if pfx else []) + req.ids
+                            td = min(_bucket(len(full_ids)), self.s)
+                            dprompt = np.full((td,), self.pad_id, np.int32)
+                            dprompt[: len(full_ids)] = full_ids
+                            self.draft_cache = self._launch(
+                                admit_row_kv,
+                                self.draft_params, self.draft_cfg, self.draft_cache,
+                                jnp.int32(i), jnp.asarray(dprompt),
+                                jnp.int32(len(full_ids)),
+                            )
+                        adm.sampling = (req_t, req_p, req_k)
+                        adm.ticket, adm.outs = ticket, (tok, lp, row_valid, moe)
+                        self._admit_inflight = adm
+                        if prev is not None:
+                            METRICS.inc("batcher.admit.overlapped")
+                            self._settle_admission(prev, then=req)
+                        adm = self._select_admission()
+            finally:
+                self._drain_admission()
+
+    def _select_admission(self) -> "_Admission | None":
+        """The round's next admission, or None where the round ends.  ONE
+        rule orders it against the admission in flight: only a monolithic
+        admission is selected ahead of that one's fetch, and this is then
+        the host work its program hides (its slot and its pages are taken
+        though no row shows them yet: :meth:`_free_slot`, and the pool has
+        handed the pages out).  Whatever else the pick comes to (the round's
+        end, a swap restore, a chunked start, and every pick with
+        ``overlap`` off) is made in the serial order: the admission in
+        flight is settled first and the pick made AGAIN, since its stream
+        callback may cancel the very request picked (the server's sweep
+        does) or free a slot.  So what the caller gets with ``serial`` set,
+        and a None, were picked with nothing in flight."""
+        while True:
+            if not self.overlap:
+                self._drain_admission()
+            ahead = self._admit_inflight
+            adm = self._pick_admission()
+            if ahead is None:
+                return adm
+            if self._admit_inflight is None:
+                # Page pressure settled it in the middle of the pick
+                # (:meth:`_ensure_pages`).  The pick stands unless the
+                # callback took its request from the queue: then its pages
+                # go back.
+                with self._lock:
+                    stands = adm is None or adm.req in self.queue
+                if stands:
+                    return adm
+                if adm.cached_pages or adm.pages:
+                    self._release_pages(adm.cached_pages + adm.pages)
+                    self.tables[adm.slot] = 0
+            elif adm is not None and adm.serial is None:
+                return adm
+            else:
+                self._drain_admission()
+
+    def _pick_admission(self) -> "_Admission | None":
+        """A free slot, the scheduler's pick and, for a monolithic
+        admission in paged mode, its pages.  None: no slot, an empty
+        queue, the prefill slots full (strict admission order: the selected
+        request never gets jumped), or a dry pool with no preemptable
+        victim (back-pressure: the request stays queued, never removed)."""
+        i = self._free_slot()
+        if i is None:
+            return None
+        req = self._next_request()
+        if req is None:
+            return None
+        if req.swap_handle is not None:
+            return _Admission(i, req, serial="swap")
+        pfx = self.prefixes[req.prefix] if req.prefix is not None else None
+        thr = self.sched.chunk_threshold()
+        if thr is not None and len(req.ids) > thr:
+            if len(self._prefills) >= self.prefill_concurrency:
+                return None
+            return _Admission(i, req, pfx, serial="chunked")
+        adm = _Admission(i, req, pfx,
+                         total_len=(len(pfx.ids) if pfx else 0) + len(req.ids))
+        if self.paged:
+            got = self._reserve_row_pages(i, req, adm.total_len, pfx)
+            if got is None:
+                return None
+            (adm.page_list, adm.pages, adm.cached_pages, adm.cached_len,
+             adm.digests) = got
+        return adm
+
+    def _settle_admission(self, adm: _Admission,
+                          then: "_Request | None" = None) -> None:
+        """The admission's ONE blocking fetch, then its activation (the
+        stream callback included).  ``then`` is the request launched
+        behind it: the chip takes that one up as this fetch returns, and
+        its wait in the queue ends here."""
+        tok, lp, row_valid, moe = self._fetch_admission(adm.ticket, *adm.outs)
+        if then is not None:
+            self._note_admit_start(then)
+        self._note_moe(*moe)
+        if adm.cancelled:
+            return
+        req_t, req_p, req_k = adm.sampling
+        self._activate_row(adm.slot, adm.req, tok, lp, row_valid,
+                           adm.total_len, req_t, req_p,
+                           adm.cached_pages + adm.pages, req_k=req_k,
+                           cached_len=adm.cached_len)
+
+    def _drain_admission(self) -> None:
+        """Settle the admission in flight, if one is: nothing that needs
+        the rows as the serial order leaves them (victim selection, a swap
+        restore, a chunked start, the decode span) runs ahead of it."""
+        adm, self._admit_inflight = self._admit_inflight, None
+        if adm is not None:
+            self._settle_admission(adm)
 
     def _activate_row(self, i, req, tok, lp, row_valid, total_len,
                       req_t, req_p, pages, req_k=None, cached_len=0):

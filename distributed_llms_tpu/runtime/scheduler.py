@@ -143,7 +143,11 @@ HOST_SYNC_SITES: dict[str, str] = {
         "automaton states) returns to host mirrors",
     "ContinuousBatcher._fetch_admission":
         "one batched D2H per admission (first token, its logprob, the "
-        "row mask and expert counts where the program hands them out)",
+        "row mask and expert counts where the program hands them out); "
+        "with overlap on it trails one admission: k's fetch follows the "
+        "launch of k+1, and every way out of a round fetches the one in "
+        "flight (a pure function of the queue, so processes stay in "
+        "lockstep)",
     "ContinuousBatcher.register_prefix":
         "prefix registration materializes the row cache once, at admit",
     "engine._to_host":

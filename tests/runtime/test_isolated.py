@@ -75,6 +75,10 @@ ISOLATED = [
     # paged spec_chunk programs.
     "tests/runtime/test_tracing.py::"
     "test_the_explicit_fetch_changes_no_token[speculative]",
+    # Pipelined admissions (PR 44): the speculative leg of the on-vs-off
+    # matrix compiles paged spec_chunk programs.
+    "tests/runtime/test_admit_pipeline.py::"
+    "test_the_two_orders_serve_the_same_tokens[speculative]",
 ]
 
 
