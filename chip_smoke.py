@@ -79,6 +79,12 @@ LATENT = dict(kernels=DENSE_KERNELS + ("moe_experts", "mla_paged_decode"),
 # blocks, 94 pages deep, 46 wraps of the ring.
 WINDOWED = dict(prefix_cache=False, long_check=True,
                 kernels=DENSE_KERNELS + ("moe_experts", "swa_decode"))
+# ``--preset smallthinker-pp4``: stage 0 of SmallThinker-21BA3B (a full
+# layer without rotation then three with a window of 4,096, their rings
+# walked block by block; 64 ReLU-gated int8 experts a layer routed on the
+# block's input; the whole 151,936-row vocabulary), as its benchmark cell
+# serves it: 32 slots, --max-len 16384, 3,712 pages, no prefix cache.  The
+# 6,000-byte prompt wraps the ring once, in the admission.
 SHAPES = {
     "qwen2-7b": (CHIP, REHEARSAL),
     "lfm2-8b-a1b": (dict(CHIP, preset="lfm2-8b-a1b", **HYBRID),
@@ -89,6 +95,10 @@ SHAPES = {
     "k-exaone-ep8": (dict(CHIP, preset="k-exaone-ep8", slots=64,
                           max_len=8192, pages=3712, **WINDOWED),
                      dict(REHEARSAL, preset="k-exaone-tiny", **WINDOWED)),
+    "smallthinker-pp4": (dict(CHIP, preset="smallthinker-pp4", slots=32,
+                              max_len=16384, pages=3712, **WINDOWED),
+                         dict(REHEARSAL, preset="smallthinker-tiny",
+                              **WINDOWED)),
 }
 
 

@@ -87,8 +87,9 @@ class ModelConfig:
     # Llama-layout blocks with q/k/v projection biases (Qwen2's one
     # architectural delta from Llama); gpt2/opt layouts always carry theirs.
     qkv_bias: bool = False
-    # Gated-MLP activation for the llama family: "silu" (Llama/Qwen2) or
-    # "gelu_tanh" (Gemma's GeGLU).  MoE blocks stay silu (Mixtral).
+    # Gated-MLP activation: "silu" (Llama/Qwen2), "gelu_tanh" (Gemma's
+    # GeGLU) or "relu" (a ReGLU).  The dense MLP, a shared expert and the
+    # routed experts of either expert layer all take it (layers.gate_fn).
     gate_act: str = "silu"
     # Embedding multiplier applied after lookup (Gemma: sqrt(hidden_size)).
     embed_scale: float = 1.0
@@ -125,9 +126,21 @@ class ModelConfig:
             raise ValueError(
                 f"unknown attn_impl {self.attn_impl!r}; choose from {sorted(_ATTN_IMPLS)}"
             )
-        if self.gate_act not in ("silu", "gelu_tanh"):
+        if self.gate_act not in ("silu", "gelu_tanh", "relu"):
             raise ValueError(
-                f"unknown gate_act {self.gate_act!r}; choose silu or gelu_tanh"
+                f"unknown gate_act {self.gate_act!r}; choose silu, gelu_tanh "
+                "or relu"
+            )
+        if self.moe_router_input not in ("ffn_norm", "block_input"):
+            raise ValueError(
+                f"unknown moe_router_input {self.moe_router_input!r}; choose "
+                "ffn_norm or block_input"
+            )
+        if self.moe_router_input == "block_input" and (
+                self.family != "hybrid" or not self.num_experts):
+            raise ValueError(
+                "moe_router_input='block_input' is the hybrid family's "
+                "expert layers' (models.model.run_layers)"
             )
         if self.moe_score_fn not in ("softmax", "sigmoid"):
             raise ValueError(
@@ -189,10 +202,6 @@ class ModelConfig:
                 f"{self.experts_offset} is no run of the {self.num_experts} "
                 "experts of a model routed without a capacity rule"
             )
-        if self.gate_act != "silu" and self.num_experts > 0:
-            # moe_swiglu hardcodes silu (Mixtral); accepting another
-            # activation here would silently ignore it.
-            raise ValueError("MoE blocks support gate_act='silu' only")
         if not 0.0 < self.rotary_pct <= 1.0:
             raise ValueError(
                 f"rotary_pct must be in (0, 1], got {self.rotary_pct}"
@@ -240,6 +249,13 @@ class ModelConfig:
     # mirror published keys and have ONE value in use, LFM2's: constants
     # until a second model needs another.)
     moe_score_fn: str = "softmax"
+    # What an expert layer's router reads.  "ffn_norm": the FFN norm's
+    # output, what the experts read (every model but one).  "block_input":
+    # the block's input itself, before ``ln1`` and the operator, so the
+    # routing of a layer is known before its attention runs
+    # (SmallThinker: "router placed before attention"); the hybrid family's
+    # alone (models.model.run_layers carries the logits to the experts).
+    moe_router_input: str = "ffn_norm"
     moe_expert_bias: bool = False
     moe_norm_topk: bool = True
     moe_routed_scale: float = 1.0

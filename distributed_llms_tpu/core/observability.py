@@ -572,6 +572,11 @@ METRIC_DOCS: dict[str, str] = {
                                 "sliding_window): what the rings' decode "
                                 "kernel read a windowed layer, never more "
                                 "than the window a row a step",
+    "swa.decode.ring_tokens": "sliding_window times the rows that decoded, "
+                              "summed over decode steps: the tokens their "
+                              "rings hold room for a windowed layer "
+                              "(window_tokens over it is the share of the "
+                              "rings' bytes that are live)",
     "batcher.latent_page_bytes": "bytes of one page of a latent (MLA) pool, "
                                  "every layer's rows (kv_cache.page_bytes)",
     "moe.held_pairs": "routed pairs that fell on an expert this chip holds "

@@ -300,17 +300,24 @@ def mlp_gelu(x: jax.Array, p: Params, activation: str = "gelu") -> jax.Array:
     return _contract(h, p["w_out"], "btf,fd->btd", 1, "k") + _plain(p["b_out"])
 
 
+def gate_fn(gate_act: str):
+    """The gate's activation of a gated MLP, by ``cfg.gate_act``: "silu"
+    (Llama/Qwen2), "gelu_tanh" (Gemma's GeGLU) or "relu" (a ReGLU:
+    SmallThinker's experts).  The dense MLP, the shared expert and the
+    routed experts of both expert layers read it here."""
+    return {
+        "silu": jax.nn.silu, "relu": jax.nn.relu,
+        "gelu_tanh": lambda g: jax.nn.gelu(g, approximate=True),
+    }[gate_act]
+
+
 @jax.named_scope("mlp")  # profiler scope; HLO metadata only
 def mlp_swiglu(x: jax.Array, p: Params, gate_act: str = "silu") -> jax.Array:
-    """Gated MLP: (act(x W_gate) * (x W_up)) W_down, no biases.
-    ``gate_act``: "silu" (Llama/Qwen2) or "gelu_tanh" (Gemma's GeGLU)."""
+    """Gated MLP: (act(x W_gate) * (x W_up)) W_down, no biases
+    (``gate_act``: :func:`gate_fn`)."""
     gate = _contract(x, p["w_gate"], "btd,df->btf", 1, "n")
     up = _contract(x, p["w_up"], "btd,df->btf", 1, "n")
-    act = (
-        jax.nn.silu if gate_act == "silu"
-        else lambda g: jax.nn.gelu(g, approximate=True)
-    )
-    h = act(gate) * up
+    h = gate_fn(gate_act)(gate) * up
     return _contract(h, p["w_down"], "btf,fd->btd", 1, "k")
 
 
@@ -348,9 +355,19 @@ def route_experts(
     return w * cfg.moe_routed_scale, topi
 
 
+def router_logits(x: jax.Array, router: jax.Array) -> jax.Array:
+    """An expert layer's router: x [..., D] @ router [D, E], both in
+    float32 at the highest precision -> logits [..., E] float32."""
+    return jnp.einsum(
+        "...d,de->...e", x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+
 def moe_dropless(
     x: jax.Array, p: Params, cfg: ModelConfig,
     token_mask: jax.Array | None = None, layer: jax.Array | int = 0,
+    logits: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Expert FFN without a capacity rule (``cfg.moe_capacity`` False; the
     llama family comes here through :func:`moe_dropless_layer`): every
@@ -371,6 +388,12 @@ def moe_dropless(
     experts with at least one real token, the fullest expert's real tokens
     — the sources of ``moe.*`` counters (runtime/batcher.py).
 
+    ``logits`` [B, T, E] float32: the router's logits, where the caller
+    took them from another tensor than ``x`` (``cfg.moe_router_input``
+    "block_input": models.model.run_layers reads the block's input with
+    :func:`router_logits` before the operator runs); None: the router
+    reads ``x``, what the experts read.
+
     A config that holds a chip's share of the experts
     (``cfg.experts_held``) routes over all ``num_experts`` and computes
     the pairs that fell on experts [offset, offset + held): the rest add
@@ -383,11 +406,9 @@ def moe_dropless(
     b, t, d = x.shape
     xf = x.reshape(b * t, d)
     with jax.named_scope("moe_route"):
-        logits = jnp.einsum(
-            "sd,de->se", xf.astype(jnp.float32),
-            p["router"][layer].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
-        )
+        if logits is None:
+            logits = router_logits(xf, p["router"][layer])
+        logits = logits.reshape(b * t, -1)
         bias = p["expert_bias"][layer] if "expert_bias" in p else None
         w, topi = route_experts(logits, cfg, bias)
         real = (jnp.ones((b * t,), bool) if token_mask is None
@@ -408,7 +429,8 @@ def moe_dropless(
         ex = p["experts"]
         y = moe_experts.grouped_swiglu(
             xf, local, ex["w_gate_up"], ex["w_down"], layer,
-            of_experts=cfg.num_experts if share else None)
+            of_experts=cfg.num_experts if share else None,
+            act=gate_fn(cfg.gate_act))
         y = jnp.sum(y.astype(jnp.float32) * w[:, :, None], axis=1)
     return y.reshape(b, t, d).astype(x.dtype), stats
 
@@ -541,7 +563,8 @@ def moe_swiglu(
 
     g = jnp.einsum("ecd,edf->ecf", xe, p["w_gate"])
     u = jnp.einsum("ecd,edf->ecf", xe, p["w_up"])
-    ye = jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * u, p["w_down"])
+    ye = jnp.einsum(
+        "ecf,efd->ecd", gate_fn(cfg.gate_act)(g) * u, p["w_down"])
 
     yflat = jnp.concatenate([ye.reshape(e * cap, d), jnp.zeros((1, d), ye.dtype)])
     gathered = yflat[slot]  # [k*s, d]; dropped claims hit the zero row

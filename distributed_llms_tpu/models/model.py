@@ -960,6 +960,14 @@ def run_layers(
         """One layer; ``at`` its index into each kind's stack."""
         x, cache, moe = carry
         p = layer_of(blocks[op], at[op], rows)
+        route = None
+        if ffn == "moe" and cfg.moe_router_input == "block_input":
+            # The router reads what the block read, before its norm and
+            # its operator: the layer's routing is known before attention
+            # runs, and the logits ride to the expert layer below.
+            with jax.named_scope("moe_route"):
+                route = layers.router_logits(
+                    x, blocks["moe"]["router"][at[ffn]])
         h = layers.rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
         if op == "conv":
             out, new = layers.short_conv(
@@ -1008,14 +1016,16 @@ def run_layers(
         x = x + out
         h = layers.rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
 
-        def add_ffn(x, h, mask, rows):
-            """x + ffn(h) and the expert layer's counts (zeros: dense)."""
+        def add_ffn(x, h, mask, rows, route=None):
+            """x + ffn(h) and the expert layer's counts (zeros: dense);
+            ``route``: the router's logits where it read the block's
+            input."""
             if ffn != "moe":
                 return x + layers.mlp_swiglu(
                     h, layer_of(blocks["dense"], at[ffn], rows), cfg.gate_act
                 ), jnp.zeros_like(moe)
             y, stats = layers.moe_dropless(
-                h, blocks["moe"], cfg, mask, layer=at[ffn])
+                h, blocks["moe"], cfg, mask, layer=at[ffn], logits=route)
             x = x + y
             if cfg.n_shared_experts:
                 with jax.named_scope("shared_expert"):
@@ -1027,7 +1037,7 @@ def run_layers(
         b, t, d = x.shape
         n = t // _TOKEN_BLOCK
         if n < 2 or t % _TOKEN_BLOCK:
-            x, stats = add_ffn(x, h, token_mask, rows)
+            x, stats = add_ffn(x, h, token_mask, rows, route)
             return x, cache, moe + stats
         # A long admission: the position-wise part a block of tokens at a
         # time, so that its temporaries (the dense layer's 18,432 columns,
@@ -1039,9 +1049,11 @@ def run_layers(
         left = None if rows is None else jnp.clip(
             rows - _TOKEN_BLOCK * jnp.arange(n, dtype=jnp.int32)[:, None],
             0, _TOKEN_BLOCK)  # [n, 1]
-        x, stats = jax.lax.map(lambda a: add_ffn(*a), (*(
-            jnp.moveaxis(a.reshape(b, n, _TOKEN_BLOCK, *a.shape[2:]), 1, 0)
-            for a in (x, h, mask)), left))
+        def cut(a):  # [b, t, ..] -> [n, b, _TOKEN_BLOCK, ..]; None: None
+            return None if a is None else jnp.moveaxis(
+                a.reshape(b, n, _TOKEN_BLOCK, *a.shape[2:]), 1, 0)
+        x, stats = jax.lax.map(lambda a: add_ffn(*a), (
+            cut(x), cut(h), cut(mask), left, cut(route)))
         return (jnp.moveaxis(x, 0, 1).reshape(b, t, d), cache,
                 moe + jnp.sum(stats, axis=0))
 
@@ -1111,6 +1123,16 @@ def embed(params: Params, cfg: ModelConfig, tokens: jax.Array, positions: jax.Ar
     return x
 
 
+def hidden_at(x: jax.Array, at: jax.Array | None) -> jax.Array:
+    """Row b's hidden state at position ``at[b]`` alone, [B, 1, D], before
+    the final norm and the head (forward's ``logits_at``); None: all of
+    x."""
+    if at is None:
+        return x
+    return jnp.take_along_axis(
+        x, at.astype(jnp.int32)[:, None, None], axis=1)
+
+
 @jax.named_scope("head")  # final norm + lm head
 def unembed(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     if cfg.family in ("gpt2", "opt", "neox"):
@@ -1151,6 +1173,12 @@ def forward(
     #   needs it (family "hybrid": layers.short_conv, layers.moe_dropless);
     #   every family hands a lone row's count to the quantized matmuls
     #   (:func:`real_rows`), and nothing else of it.  None means all T
+    logits_at: jax.Array | None = None,  # [B] int32: the ONE position of
+    #   each row whose logits the caller wants (an admission samples its
+    #   first token from the last real position and nothing else): the
+    #   final norm and the head then read that position's hidden state
+    #   alone and the logits are [B, 1, V], so the head's float32
+    #   temporaries do not grow with T (16,384 x 151,936 x 4 B otherwise)
 ) -> tuple[jax.Array, Any] | tuple[jax.Array, Any, jax.Array]:
     """Full forward.  Returns (logits [B, T, V] float32, updated cache), plus
     the summed MoE aux loss when ``return_aux`` (scale by
@@ -1180,7 +1208,7 @@ def forward(
             x, params["blocks"], cfg, positions, cache, cache_index,
             attn_mask, std_layout, kv_tables, key_positions, seq_lens,
         )
-        out = (unembed(params, cfg, x), cache)
+        out = (unembed(params, cfg, hidden_at(x, logits_at)), cache)
         return (*out, stats) if return_aux else out
     if isinstance(cache, kv_cache.QuantKVCache) and kv_tables is None:
         # Int8 page pool: decode-only (the per-step quantized write and the
@@ -1194,7 +1222,7 @@ def forward(
         attn_mask, std_layout, kv_tables, key_positions,
         real_rows(seq_lens, b),
     )
-    out = (unembed(params, cfg, x), cache)
+    out = (unembed(params, cfg, hidden_at(x, logits_at)), cache)
     return (*out, aux) if return_aux else out
 
 

@@ -162,7 +162,41 @@ PRESETS: dict[str, ModelConfig] = {
         moe_capacity=False, n_shared_experts=1, experts_held=16,
         experts_offset=0,
     ),
+    # SmallThinker-21BA3B-Instruct (PowerInfer, model_name
+    # smallthinker_21b_instruct) as STAGE 0 of a four-chip host's pipeline:
+    # every published width (28 query heads over 4 key/value heads of 128,
+    # no QK-norm, no biases; layers full / window / window / window, the
+    # windowed ones rotated with a window of 4,096, the full ones NOT
+    # rotated; every layer 64 ReLU-gated experts of 768 routed 6 a token by
+    # a softmax over the chosen, the router reading the block's INPUT; no
+    # dense layer, no shared expert; the whole vocabulary, head untied, the
+    # whole 16,384 context), cut in depth to the stage's 12 of the 52
+    # layers: benchmark/configs/smallthinker-21ba3b-int8.json has the
+    # deployment.
+    "smallthinker-pp4": ModelConfig(
+        family="hybrid", vocab_size=151936, hidden_size=2560,
+        intermediate_size=768, moe_intermediate_size=768, num_layers=12,
+        num_dense_layers=0, num_heads=28, num_kv_heads=4, head_dim=128,
+        max_seq_len=16384, rope_theta=1.5e6, norm_eps=1e-6,
+        tie_embeddings=False, qk_norm=False, attn_rope=False,
+        sliding_window=4096, layer_types=("attn", "swa", "swa", "swa") * 3,
+        gate_act="relu", num_experts=64, num_experts_per_token=6,
+        moe_score_fn="softmax", moe_router_input="block_input",
+        moe_capacity=False, n_shared_experts=0,
+    ),
     # Tiny configs for unit tests / CPU fake-mesh integration tests.
+    "smallthinker-tiny": ModelConfig(
+        family="hybrid", vocab_size=256, hidden_size=64,
+        intermediate_size=32, moe_intermediate_size=32, num_layers=8,
+        num_dense_layers=0, num_heads=4, num_kv_heads=2, head_dim=16,
+        max_seq_len=256, rope_theta=1.5e6, norm_eps=1e-6,
+        tie_embeddings=False, dtype="float32", qk_norm=False,
+        attn_rope=False, sliding_window=8,
+        layer_types=("attn", "swa", "swa", "swa") * 2,
+        gate_act="relu", num_experts=16, num_experts_per_token=6,
+        moe_score_fn="softmax", moe_router_input="block_input",
+        moe_capacity=False, n_shared_experts=0,
+    ),
     "k-exaone-tiny": ModelConfig(
         family="hybrid", vocab_size=256, hidden_size=64,
         intermediate_size=128, moe_intermediate_size=32, num_layers=8,

@@ -779,6 +779,24 @@ def _paged_impl(
     return out.reshape(b, 1, h, q.shape[-1])
 
 
+_RING_BLOCK = 64  # tokens a block of a ring that is walked in blocks
+
+
+def ring_block(w: int, kvh: int, d: int, dtype) -> int:
+    """Tokens a page of a windowed layer's ring of ``w`` tokens, as
+    :func:`swa_decode_attention` hands it to the paged kernel.  A ring
+    whose keys and values are at most the mebibyte the kernel fetches at a
+    time (:func:`_run_pages`) is ONE page, a run of its own (K-EXAONE's 128
+    tokens at 8 heads of 128: 512 KB).  A larger one (4,096 tokens at 4
+    heads of 128: 8 MB, which :func:`_kv_vmem_ok` refuses as a page) is
+    walked in blocks of ``_RING_BLOCK`` tokens, the pool's own page size,
+    so that a row is fetched by the live blocks it holds, a run of them at
+    a time, and a window no multiple of the block stays one page (and then
+    takes the dense body if it is too large)."""
+    one_run = 2 * w * kvh * d * jnp.dtype(dtype).itemsize <= 1 << 20
+    return w if one_run or w % _RING_BLOCK else _RING_BLOCK
+
+
 def swa_decode_attention(
     q: jax.Array,  # [B, 1, H, D]
     ring_k: jax.Array,  # [Lw, B, W, KVH, D]: every windowed layer's rings,
@@ -789,18 +807,28 @@ def swa_decode_attention(
     """A decode step's attention over a windowed layer's RINGS: row b's
     last min(length, W) keys and values lie in ring b in no order that
     matters (the key of position p at p mod W, already rotated), so the
-    read is the paged kernel's with the rings as a pool of one W-token
-    page a row, the page table the identity: the stack stays in HBM whole,
-    the kernel copies (layer, row)'s ring where it lies and masks by
-    count.  Entries past the count (a shorter row, a finished row's
-    leftovers) are read as zeros whatever they hold.  Under its own name
-    in a trace (``swa_decode_attn``) and in the dispatch record
-    (``ops.dispatch.swa_decode.*``).  Returns [B, 1, H, D].  Single-device
-    (the rings refuse a mesh)."""
-    b = q.shape[0]
+    read is the paged kernel's with the rings as a pool: [Lw, B, W, ..] is
+    [Lw, B * W/blk, blk, ..] by a free reshape, row b's page table is
+    arithmetic (b * W/blk + j) and its length the count, and
+    :func:`_paged_impl`'s walk does the rest: the stack stays in HBM
+    whole, the kernel copies the pages (layer, row) holds where they lie,
+    a run at a time, the LIVE ones only (a row of 700 tokens in a ring of
+    4,096 fetches 11 blocks of 64, not 64), and masks by count.  A small
+    ring is one page (:func:`ring_block`).  Entries past the count (a
+    shorter row, a finished row's leftovers) are read as zeros whatever
+    they hold.  Under its own name in a trace (``swa_decode_attn``) and in
+    the dispatch record (``ops.dispatch.swa_decode.*``).  Returns
+    [B, 1, H, D].  Single-device (the rings refuse a mesh)."""
+    lw, b, w, kvh, d = ring_k.shape
+    blk = ring_block(w, kvh, d, ring_k.dtype)
+    n = w // blk  # pages a row
+    tables = jnp.arange(b, dtype=jnp.int32)[:, None]
+    if n > 1:
+        ring_k, ring_v = (x.reshape(lw, b * n, blk, kvh, d)
+                          for x in (ring_k, ring_v))
+        tables = tables * n + jnp.arange(n, dtype=jnp.int32)[None, :]
     return _paged_impl(
-        q, ring_k, ring_v, counts.astype(jnp.int32),
-        jnp.arange(b, dtype=jnp.int32)[:, None],
+        q, ring_k, ring_v, counts.astype(jnp.int32), tables,
         jnp.asarray(layer, jnp.int32).reshape(1), mode=_mode(),
         op="swa_decode")
 

@@ -191,8 +191,11 @@ def _layer_of(w, layer, dtype):
 
 def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
                    layer: jax.Array | int = 0,
-                   of_experts: int | None = None) -> jax.Array:
-    """Every (token, choice) pair through its expert's SwiGLU.
+                   of_experts: int | None = None,
+                   act=jax.nn.silu) -> jax.Array:
+    """Every (token, choice) pair through its expert's gated MLP,
+    ``(act(x W_gate) * (x W_up)) W_down``: ``act`` is the configuration's
+    (layers.gate_fn; silu for a SwiGLU).
 
     xf [S, D]; topi [S, k] int32 expert ids; w_gate_up [L, E, D, 2F] and
     w_down [L, E, F, D], every layer's experts in one stack, arrays or
@@ -254,7 +257,7 @@ def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
         dispatch.record("moe_experts", "fallback", (p, e, d, f))
         h = jax.lax.ragged_dot(
             xp, _layer_of(w_gate_up, layer, xf.dtype), counts)
-        a = jax.nn.silu(h[:, :f]) * h[:, f:]
+        a = act(h[:, :f]) * h[:, f:]
         yp = jax.lax.ragged_dot(a, _layer_of(w_down, layer, xf.dtype), counts)
         return pairs(yp)
 
@@ -269,7 +272,7 @@ def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
     (bk1, bn1), (bk2, bn2) = tiles
     h = _grouped_quant_matmul(xp, w_gate_up.data, w_gate_up.scale, *where,
                               bk=bk1, bn=bn1, **kw)
-    a = jax.nn.silu(h[:, :f]) * h[:, f:]
+    a = act(h[:, :f]) * h[:, f:]
     yp = _grouped_quant_matmul(a, w_down.data, w_down.scale, *where,
                                bk=bk2, bn=bn2, **kw)
     return pairs(yp)

@@ -310,13 +310,14 @@ class ParallelModel:
     def _forward_adapter(
         self, params, cfg, tokens, positions=None, cache=None,
         cache_index=None, attn_mask=None, key_positions=None,
-        kv_tables=None,
+        kv_tables=None, logits_at=None,
     ):
         del cfg  # self.cfg is authoritative
         return self.forward(
             params, tokens, positions=positions, cache=cache,
             cache_index=cache_index, attn_mask=attn_mask,
             key_positions=key_positions, kv_tables=kv_tables,
+            logits_at=logits_at,
         )
 
     def _make_cache_adapter(self, cfg, batch, max_len, prompt_len=None):
@@ -434,12 +435,18 @@ class ParallelModel:
         #   holds page POOLS sharded over 'model' on KV heads (mesh-native
         #   paged serving; GSPMD path only — the paged decode kernel runs
         #   per shard on its local heads)
+        logits_at: jax.Array | None = None,  # [B]: models.model.forward's
+        #   (GSPMD path only: what the mesh batcher's admissions run)
     ) -> tuple[jax.Array, KVCache | None] | tuple[jax.Array, KVCache | None, jax.Array]:
         """Same contract as models.model.forward, but mesh-parallel.
         ``return_aux`` (MoE load-balance loss) flows through on the
         GSPMD paths; the pipeline/seq shard_map schedules return aux=0 —
         train MoE with data/model/expert axes."""
         cfg = self.cfg
+        if logits_at is not None and (self.pipelined or self.seq_parallel):
+            raise NotImplementedError(
+                "logits_at is the GSPMD paths': the pipelined and "
+                "sequence-parallel schedules hand out every position")
         if kv_tables is not None and (self.pipelined or self.seq_parallel):
             raise NotImplementedError(
                 "paged decode (kv_tables) runs on pure data/tensor-parallel "
@@ -497,7 +504,7 @@ class ParallelModel:
                     params, cfg, tokens, positions=positions, cache=cache,
                     cache_index=cache_index, remat=remat, attn_mask=attn_mask,
                     return_aux=return_aux, key_positions=key_positions,
-                    kv_tables=kv_tables,
+                    kv_tables=kv_tables, logits_at=logits_at,
                 )
 
         b, t = tokens.shape

@@ -145,20 +145,27 @@ def _replicated(pm, *xs):
     return out if len(out) > 1 else out[0]
 
 
-def _sample_first(logits, last_idx, rng, temperature, top_k, top_p,
+def _last_real(n):
+    """forward's ``logits_at`` for one right-padded row of ``n`` real
+    tokens: its last real position, [1]."""
+    return jnp.maximum(n - 1, 0)[None]
+
+
+def _sample_first(logits, rng, temperature, top_k, top_p,
                   temp_req=None, topp_req=None, topk_req=None,
                   mask_req=None):
     """Sample the admitted row's first token from the last real position's
-    logits — the one sampling tail shared by every admission path.
+    logits ``logits`` [1, 1, V] — the one sampling tail shared by every
+    admission path.  The prefill computed the head for that position alone
+    (models.model.forward's ``logits_at``: :func:`_last_real`), so no
+    admission holds [T, V] logits.
     ``temp_req``/``topp_req``/``topk_req`` (traced scalars) override the
     static knobs for per-request sampling without a recompile per value.
     ``mask_req`` [V] is a constrained/biased request's start-state token
     mask (runtime/constrain.py): applied before the draw AND the greedy
     argmax, never to the logprob (the logprobs contract stays
     raw-distribution)."""
-    next_logits = jnp.take_along_axis(
-        logits, jnp.maximum(last_idx - 1, 0)[None, None, None], axis=1
-    )[:, 0]
+    next_logits = logits[:, 0]
     src = next_logits if mask_req is None else next_logits + mask_req[None, :]
     if temp_req is None:
         tok = sampling.sample(rng, src, temperature, top_k, top_p)[0]
@@ -206,25 +213,30 @@ def _prefill_row(fwd, params, cfg, cache_dtype, s, prompt, plen=None):
     (:func:`_row_state`): a model with state that is not keys and values
     (family "hybrid") leaves in the row cache the state AT that length, not
     at the padded bucket's end, and returns its expert layers' counts of
-    the real tokens third (forward's ``return_aux``)."""
+    the real tokens third (forward's ``return_aux``).  With ``plen`` the
+    logits are the last real position's alone, [1, 1, V]
+    (:func:`_last_real`); without it (the draft's cache fill, which
+    samples nothing) every position's."""
     (tp,) = prompt.shape
     row_cache = kv_cache.init_cache(cfg, 1, s, dtype=cache_dtype)
     positions = jnp.arange(tp, dtype=jnp.int32)[None, :]
     return fwd(
         params, cfg, prompt[None, :], positions=positions,
         cache=row_cache, cache_index=0, **_row_state(fwd, cfg, plen),
+        **({} if plen is None else {"logits_at": _last_real(plen)}),
     )
 
 
 def _prefill_row_with_prefix(fwd, params, cfg, row_cache, prefix_len, chunk,
-                             clen=None):
+                             last, clen=None):
     """Prefix-seeded prefill: only the request's suffix runs through the
     model (session-style continuation math) — shared by the contiguous and
     paged prefix admissions.  ``row_cache`` is the transient contiguous row
     cache that holds the prefix (key/value rows, or latent rows:
     kv_cache.row_cache_of).  The model is told the suffix's true length
     ``clen``, and a hybrid-family model returns its expert counts third, as
-    in :func:`_prefill_row`."""
+    in :func:`_prefill_row`.  ``last`` is the suffix's true length whoever
+    else is told it: the logits are its last real position's, [1, 1, V]."""
     (tc,) = chunk.shape
     s = row_cache.k.shape[2]
     slots = jnp.arange(s, dtype=jnp.int32)
@@ -236,18 +248,18 @@ def _prefill_row_with_prefix(fwd, params, cfg, row_cache, prefix_len, chunk,
     return fwd(
         params, cfg, chunk[None, :], positions=positions,
         cache=row_cache, cache_index=prefix_len, attn_mask=mask,
-        **_row_state(fwd, cfg, clen),
+        logits_at=_last_real(last), **_row_state(fwd, cfg, clen),
     )
 
 
 def _finish_admission(
-    cache, slot, row_cache, logits, last_idx, rng, temperature, top_k, top_p,
+    cache, slot, row_cache, logits, rng, temperature, top_k, top_p,
     total_len, temp_req=None, topp_req=None, topk_req=None, mask_req=None,
 ):
     """Shared admission tail (plain and prefix-cached paths): sample the
     first token from the last real position's logits, splice the prefilled
     row into the shared cache, report the row's valid slots."""
-    tok, lp = _sample_first(logits, last_idx, rng, temperature, top_k, top_p,
+    tok, lp = _sample_first(logits, rng, temperature, top_k, top_p,
                             temp_req, topp_req, topk_req, mask_req)
     cache = _splice_row(cache, slot, row_cache)
     s = cache.k.shape[-3]
@@ -286,7 +298,7 @@ def admit_row(
         _fwd(pm), params, cfg, cache.k.dtype, cache.k.shape[-3], prompt, plen
     )
     cache, tok, row_valid, lp = _finish_admission(
-        cache, slot, row_cache, logits, plen, rng, temperature, top_k, top_p,
+        cache, slot, row_cache, logits, rng, temperature, top_k, top_p,
         total_len=plen, temp_req=temp_req, topp_req=topp_req,
         topk_req=topk_req, mask_req=mask_req,
     )
@@ -673,10 +685,10 @@ def admit_row_with_prefix(
     Returns (cache', first_token, row_valid, first_token_logprob)."""
     logits, row_cache = _prefill_row_with_prefix(
         _fwd(pm), params, cfg, KVCache(k=prefix_k, v=prefix_v), prefix_len,
-        chunk,
+        chunk, clen,
     )
     cache, tok, row_valid, lp = _finish_admission(
-        cache, slot, row_cache, logits, clen, rng, temperature, top_k, top_p,
+        cache, slot, row_cache, logits, rng, temperature, top_k, top_p,
         total_len=prefix_len + clen, temp_req=temp_req, topp_req=topp_req,
         topk_req=topk_req, mask_req=mask_req,
     )
@@ -716,12 +728,9 @@ def _prefill_leg(params, cfg, row_k, row_v, done, chunk, clen, pm):
     single definition is what keeps the two schedules trivially
     byte-identical."""
     logits, row_cache = _prefill_row_with_prefix(
-        _fwd(pm), params, cfg, KVCache(k=row_k, v=row_v), done, chunk
+        _fwd(pm), params, cfg, KVCache(k=row_k, v=row_v), done, chunk, clen
     )
-    last = jnp.take_along_axis(
-        logits, jnp.maximum(clen - 1, 0)[None, None, None], axis=1
-    )[:, 0]  # [1, V]
-    return row_cache.k, row_cache.v, _replicated(pm, last)
+    return row_cache.k, row_cache.v, _replicated(pm, logits[:, 0])  # [1, V]
 
 
 @partial(
@@ -753,7 +762,7 @@ def finish_chunked_admission(
     monolithic paths, so results are bit-identical."""
     cache, tok, row_valid, lp = _finish_admission(
         cache, slot, KVCache(k=row_k, v=row_v), last_logits[:, None, :],
-        jnp.int32(1), rng, temperature, top_k, top_p, total_len,
+        rng, temperature, top_k, top_p, total_len,
         temp_req=temp_req, topp_req=topp_req, topk_req=topk_req,
         mask_req=mask_req,
     )
@@ -791,12 +800,12 @@ def finish_chunked_admission_paged(
     so a long prompt never pins pool pages while it chunks in."""
     return _paged_splice(
         cache, page_list, KVCache(k=row_k, v=row_v),
-        last_logits[:, None, :], jnp.int32(1), rng, temperature, top_k,
+        last_logits[:, None, :], rng, temperature, top_k,
         top_p, temp_req, topp_req, topk_req, mask_req, pm=pm,
     )
 
 
-def _paged_splice(cache, page_list, row_cache, logits, last_idx, rng,
+def _paged_splice(cache, page_list, row_cache, logits, rng,
                   temperature, top_k, top_p, temp_req=None, topp_req=None,
                   topk_req=None, mask_req=None, pm=None, slot=None):
     """Admission tail for the paged pool: sample the first token, then
@@ -805,7 +814,7 @@ def _paged_splice(cache, page_list, row_cache, logits, last_idx, rng,
     where a hybrid model's convolution state goes).  On a mesh batcher
     (``pm``) the pool result is re-constrained to its sharding and the
     sampled token/logprob replicate (lockstep mirrors)."""
-    tok, lp = _sample_first(logits, last_idx, rng, temperature, top_k, top_p,
+    tok, lp = _sample_first(logits, rng, temperature, top_k, top_p,
                             temp_req, topp_req, topk_req, mask_req)
     cache = kv_cache.write_row(cache, page_list, row_cache, slot)
     return (kv_cache.constrain(pm, cache), *_replicated(pm, tok, lp))
@@ -844,7 +853,7 @@ def admit_row_paged(
         page_list.shape[0] * cache.k.shape[2], prompt, plen,
     )
     return (*_paged_splice(
-        cache, page_list, row_cache, logits, plen, rng, temperature, top_k,
+        cache, page_list, row_cache, logits, rng, temperature, top_k,
         top_p, temp_req, topp_req, topk_req, mask_req, pm=pm, slot=slot,
     ), *moe)
 
@@ -879,10 +888,10 @@ def admit_row_with_prefix_paged(
     Returns (cache', tok, logprob)."""
     logits, row_cache = _prefill_row_with_prefix(
         _fwd(pm), params, cfg, KVCache(k=prefix_k, v=prefix_v), prefix_len,
-        chunk,
+        chunk, clen,
     )
     return _paged_splice(
-        cache, page_list, row_cache, logits, clen, rng, temperature, top_k,
+        cache, page_list, row_cache, logits, rng, temperature, top_k,
         top_p, temp_req, topp_req, topk_req, mask_req, pm=pm,
     )
 
@@ -927,10 +936,10 @@ def admit_row_auto_paged(
     logits, row_cache, *moe = _prefill_row_with_prefix(
         _fwd(pm), params, cfg,
         kv_cache.row_cache_of(cache, *kv_cache.gather_row(cache, read_list)),
-        prefix_len, chunk, clen,
+        prefix_len, chunk, clen, clen,
     )
     return (*_paged_splice(
-        cache, write_list, row_cache, logits, clen, rng, temperature, top_k,
+        cache, write_list, row_cache, logits, rng, temperature, top_k,
         top_p, temp_req, topp_req, topk_req, mask_req, pm=pm,
     ), *moe)
 
@@ -4571,8 +4580,17 @@ class ContinuousBatcher:
             METRICS.inc("attn.decode.resident_tokens", counts[5])
         elif len(counts) > 5:
             METRICS.inc("mla.decode.resident_tokens", counts[5])
-        if len(counts) > 6:  # ... and of them, those inside the window
+        if len(counts) > 6:  # ... and of them, those inside the window,
+            # beside the tokens the rows' rings hold room for: the window
+            # times the row-steps that decoded, which are the pairs routed
+            # over the k choices of every expert layer (no count of its
+            # own in the program: K-EXAONE's decode chunk stays as it was)
             METRICS.inc("swa.decode.window_tokens", counts[6])
+            cfg = self.cfg
+            METRICS.inc(
+                "swa.decode.ring_tokens", cfg.sliding_window * counts[0] // (
+                    cfg.num_experts_per_token
+                    * (cfg.num_layers - cfg.num_dense_layers)))
 
     def _fetch_chunk(self, out: tuple) -> tuple:
         """Host work's D2H for a dispatched-ahead chunk: tokens, logprobs,

@@ -542,3 +542,83 @@ def test_finished_ring_under_concurrent_writers_and_readers(tiny):
     assert not any(t.is_alive() for t in readers + writers)
     assert not bad
     assert len(b.finished_requests()) == FINISHED_KEEP  # 4,800 appended
+
+
+# -- a model whose router reads the block's input (PR 45) -----------------
+def _walk_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk_eqns(sub)
+
+
+def test_the_router_runs_before_the_operator_and_every_scope_is_named():
+    """``smallthinker-tiny``: in program order the first operation under
+    ``moe_route`` (the router's logits, read from the block's input) comes
+    before the first under ``full_attn`` and ``swa_attn``, the routing's
+    rest and ``moe_experts`` behind them; the five scopes a trace is read
+    by are in the lowered program's locations.  K-EXAONE's router, which
+    reads the FFN norm's output, runs behind its operator."""
+    from distributed_llms_tpu.models import model as model_lib
+    from distributed_llms_tpu.models.presets import get_preset
+
+    def first_seen(name):
+        cfg = get_preset(name)
+        params = jax.eval_shape(
+            lambda: model_lib.init_params(jax.random.key(0), cfg))
+        toks = np.zeros((1, 16), np.int32)
+
+        def fwd(params):
+            return model_lib.forward(params, cfg, toks)[0]
+
+        order = []
+        for eqn in _walk_eqns(jax.make_jaxpr(fwd)(params).jaxpr):
+            stack = str(eqn.source_info.name_stack)
+            for scope in ("moe_route", "full_attn", "swa_attn",
+                          "moe_experts", "head"):
+                if scope in stack and scope not in order:
+                    order.append(scope)
+        text = jax.jit(fwd).lower(params).as_text(debug_info=True)
+        assert all(scope in text for scope in order)
+        return order
+
+    assert first_seen("smallthinker-tiny") == [
+        "moe_route", "full_attn", "moe_experts", "swa_attn", "head"]
+    assert first_seen("k-exaone-tiny")[:2] == ["swa_attn", "moe_route"]
+
+
+def test_the_rings_counters_and_gauges_of_a_windowed_model():
+    """``swa.decode.ring_tokens`` is the window times the row-steps that
+    decoded (what the rings hold room for), ``swa.decode.window_tokens``
+    the live part of it, both delivered with the expert counts."""
+    from distributed_llms_tpu.models import model as model_lib
+    from distributed_llms_tpu.models.presets import get_preset
+
+    assert "swa.decode.ring_tokens" in METRIC_DOCS
+    cfg = get_preset("smallthinker-tiny")
+    params = model_lib.init_params(jax.random.key(0), cfg)
+    names = ("swa.decode.ring_tokens", "swa.decode.window_tokens",
+             "attn.decode.resident_tokens", "moe.routed_pairs",
+             "moe.layer_passes", "moe.experts_touched",
+             "moe.max_load_tokens")
+    before = METRICS.snapshot()["counters"]
+    b = batcher_mod.ContinuousBatcher(
+        cfg, params, batch_slots=4, max_len=64, chunk_steps=4,
+        paged_pages=24, page_size=8)
+    b.submit([7, 1, 9, 4, 2], max_new_tokens=4)
+    b.submit(list(range(1, 20)), max_new_tokens=7)
+    b.run()
+    snap = METRICS.snapshot()
+    delta = {k: snap["counters"].get(k, 0) - before.get(k, 0) for k in names}
+    real = (5 + 3) + (19 + 6)  # prompt tokens + decoded tokens fed back
+    assert delta["moe.routed_pairs"] == real * 6 * 8
+    # Decode steps read lengths 6, 7, 8 and 20 .. 25: nine row-steps.
+    assert delta["swa.decode.ring_tokens"] == 9 * 8
+    assert delta["swa.decode.window_tokens"] == 6 + 7 + 8 + 6 * 8
+    assert delta["attn.decode.resident_tokens"] == 6 + 7 + 8 + sum(
+        range(20, 26))
+    assert 0 < delta["moe.experts_touched"] <= 16 * delta["moe.layer_passes"]
+    assert delta["moe.max_load_tokens"] > 0
+    assert snap["gauges"]["batcher.window_state_bytes"] == \
+        2 * 6 * 4 * 8 * 2 * 16 * 4
+    assert snap["gauges"]["batcher.pool_token_bytes"] == 2 * 2 * 2 * 16 * 4

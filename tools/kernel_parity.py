@@ -39,6 +39,7 @@ import numpy as np
 from distributed_llms_tpu.checkpoint.quantize import (
     QuantizedTensor, dequantize, quantize)
 from distributed_llms_tpu.core.observability import METRICS
+from distributed_llms_tpu.models.layers import gate_fn
 from distributed_llms_tpu.ops import decode_attn, moe_experts
 from distributed_llms_tpu.ops.flash import _dense_reference, flash_attention
 from distributed_llms_tpu.ops.quant_matmul import quant_contract
@@ -329,15 +330,18 @@ def paged_parity(blk: int = 128, h: int = 8, kvh: int = 4,
 
 
 def swa_parity(w: int = 128, h: int = 64, kvh: int = 8, d: int = 128,
-               layer: int = 1) -> None:
+               layer: int = 1, lengths=None) -> None:
     """The rings' decode kernel at K-EXAONE's shapes: 64 query heads over
     8 key/value heads, a ring of 128 tokens a row, layer 1 of a 3-layer
     stack.  Rows: one token; shorter than the window; exactly the window;
     wrapped (300 tokens went by: the key of position p lies at p mod 128,
     the oldest overwritten, and the answer is that of the last 128 in
     their own order).  Entries past a row's count hold NaNs: never to be
-    read, on the value side either."""
-    lengths = [1, 37, w, 300]
+    read, on the value side either.  A ring too large for one page
+    (SmallThinker's 4,096 tokens at 4 heads of 128, ``ring_block``) is
+    walked in blocks of 64, the live ones only: the NaNs lie in the blocks
+    a row does not hold as well as in the tail of its last one."""
+    lengths = lengths or [1, 37, w, 300]
     b, s = len(lengths), max(lengths)
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(13), 3)
     q = jax.random.normal(kq, (b, 1, h, d), jnp.bfloat16)
@@ -360,8 +364,9 @@ def swa_parity(w: int = 128, h: int = 64, kvh: int = 8, d: int = 128,
     last_v = jnp.stack([jnp.roll(x, -max(n - w, 0), axis=0)
                         for x, n in zip(v_rows, lengths)])
     want = decode_attn._dense_reference(q, last, last_v, counts)
-    check(f"swa decode B{b} ring{w} H{h}/{kvh} D{d} L3[{layer}]", got, want,
-          rtol=3e-2, atol=3e-2)
+    blk = decode_attn.ring_block(w, kvh, d, jnp.bfloat16)
+    check(f"swa decode B{b} ring{w} blk{blk} H{h}/{kvh} D{d} L3[{layer}]", got,
+          want, rtol=3e-2, atol=3e-2)
 
 
 def mla_paged_parity(blk: int = 64, h: int = 64, latent: int = 512,
@@ -397,7 +402,7 @@ def mla_paged_parity(blk: int = 64, h: int = 64, latent: int = 512,
 
 
 def moe_parity(e: int = 32, d: int = 2048, f: int = 1792, k: int = 4,
-               of_experts: int | None = None) -> None:
+               of_experts: int | None = None, act: str = "silu") -> None:
     """The expert kernel at lfm2-8b-a1b's widths (32 experts of 2048 x
     1792, int8 with blocks along the contracted axis), layer 1 of a
     2-layer stack: a decode step's 64 pairs (tiles of 16 rows, most
@@ -405,7 +410,9 @@ def moe_parity(e: int = 32, d: int = 2048, f: int = 1792, k: int = 4,
     ``of_experts``: the stack holds ``e`` of that many (A.X-K1: 12 of 192
     at [7168 x 4096] and [2048 x 7168]); ids are drawn over twice the held
     ones, so half the pairs name an absent expert, leave the list and come
-    back as zeros."""
+    back as zeros.  ``act``: the gate's activation (layers.gate_fn; relu:
+    SmallThinker's 64 experts of [2560 x 1536] and [768 x 2560])."""
+    gate = gate_fn(act)
     key = jax.random.PRNGKey(5)
     key13, key2 = jax.random.split(key)
 
@@ -430,7 +437,8 @@ def moe_parity(e: int = 32, d: int = 2048, f: int = 1792, k: int = 4,
         topi = jax.random.randint(
             kt, (s, k), 0, 2 * e if of_experts else e, jnp.int32)
         got = jax.jit(lambda x, t, a, b: moe_experts.grouped_swiglu(
-            x, t, a, b, 1, of_experts=of_experts))(x, topi, w13, w2)
+            x, t, a, b, 1, of_experts=of_experts, act=gate))(
+            x, topi, w13, w2)
 
         def want_of(x, topi, w13, w2):
             # Every expert for every token, the chosen ones picked out.
@@ -439,7 +447,7 @@ def moe_parity(e: int = 32, d: int = 2048, f: int = 1792, k: int = 4,
             d2 = dequantize(jax.tree.map(lambda a: a[1], w2), jnp.float32)
             g = jnp.einsum("sd,edf->sef", xf, d13)
             y = jnp.einsum("sef,efd->sed",
-                           jax.nn.silu(g[..., :f]) * g[..., f:], d2)
+                           gate(g[..., :f]) * g[..., f:], d2)
             y = jnp.take_along_axis(
                 y, jnp.minimum(topi, e - 1)[:, :, None], axis=1)
             return jnp.where((topi < e)[:, :, None], y, 0.0)
@@ -447,8 +455,25 @@ def moe_parity(e: int = 32, d: int = 2048, f: int = 1792, k: int = 4,
         with jax.default_matmul_precision("highest"):
             want = jax.jit(want_of)(x, topi, w13, w2)
         held = f" of {of_experts}" if of_experts else ""
-        check(f"moe experts S{s} k{k} E{e}{held} [{d}x{2 * f}] [{f}x{d}]",
-              got, want, rtol=3e-2, atol=3e-2)
+        check(f"moe experts S{s} k{k} E{e}{held} [{d}x{2 * f}] [{f}x{d}] "
+              f"{act}", got, want, rtol=3e-2, atol=3e-2)
+
+
+def flash_band_parity(t: int, h: int, kvh: int, win: int) -> None:
+    """The flash kernel as a windowed layer's long admission calls it
+    (models.model._self_attention: tiles of 512): a band of ``win`` keys on
+    a row of ``t``, so tiles below the band are skipped as well as those
+    above the diagonal."""
+    ks = jax.random.split(jax.random.PRNGKey(17), 3)
+    q = jax.random.normal(ks[0], (1, t, h, 128), jnp.bfloat16)
+    kk = jax.random.normal(ks[1], (1, t, kvh, 128), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, t, kvh, 128), jnp.bfloat16)
+    got = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=win, block_q=512, block_k=512,
+        interpret=not ON_TPU))(q, kk, v)
+    want = _dense_reference(q, kk, v, None, None, None, True, win)
+    check(f"flash T{t} H{h}/{kvh} win{win} tiles512", got, want, rtol=3e-2,
+          atol=3e-2)
 
 
 def ragged_parity() -> None:
@@ -609,6 +634,18 @@ def main() -> int:
     swa_parity()
     moe_parity(e=16, d=6144, f=2048, k=8, of_experts=128) if ON_TPU else \
         moe_parity(e=16, d=768, f=256, k=8, of_experts=128)
+    # SmallThinker's: a ring of 4,096 tokens at 28 query heads over 4,
+    # walked in blocks of 64 (rows of one token, inside a block, on a
+    # block's last slot, of several runs, full, wrapped); 64 ReLU-gated
+    # experts of [2560 x 1536] and [768 x 2560], 6 a token; the flash
+    # kernel with the 4,096 band in tiles of 512 on a row of 8,192 (one
+    # key/value head's group of 7: the reference scores densely).
+    swa_parity(w=4096, h=28, kvh=4, lengths=[1, 37, 64, 700, 4096, 5000]) \
+        if ON_TPU else \
+        swa_parity(w=1024, h=8, kvh=4, lengths=[1, 37, 64, 300, 1024, 1300])
+    moe_parity(e=64, d=2560, f=768, k=6, act="relu") if ON_TPU else \
+        moe_parity(e=64, d=256, f=128, k=6, act="relu")
+    flash_band_parity(*((8192, 7, 1, 4096) if ON_TPU else (1024, 4, 2, 512)))
     # No leg may pass on another path than the one asked for: the dispatch
     # record (ops/dispatch.py) counts every trace by the path it took.
     took = {k[len("ops.dispatch."):]: int(v)
@@ -647,8 +684,9 @@ def main() -> int:
     # flash kernel as the dense cells' admissions call it since PR 35, at
     # qwen2-7b's and pythia-6.9b's heads, and with a scale and a value
     # width of its own (192 / 128: A.X-K1's expanded latent heads) — 50
-    # legs.
-    print(f"kernel_parity: ALL PASS v12 ({mode}, backend={backend})")
+    # legs.  v13: SmallThinker's ring walked in blocks, its ReLU-gated
+    # experts and its admissions' 4,096 band — 54 legs.
+    print(f"kernel_parity: ALL PASS v13 ({mode}, backend={backend})")
     return 0
 
 
