@@ -253,6 +253,8 @@ METRIC_DOCS: dict[str, str] = {
                                  "rid, prompt_tokens, cached_tokens, "
                                  "bucket, live_rows: the bucket's rows "
                                  "the quantized matmuls compute, "
+                                 "attn_pairs_live: the (query, key) pairs "
+                                 "the flash kernel's visited tiles hold, "
                                  "key_slots: the keys a query of it is "
                                  "scored against, fetched_rid: the "
                                  "earlier admission whose outputs it "
@@ -288,6 +290,22 @@ METRIC_DOCS: dict[str, str] = {
                                       "that held a real token; the tiles "
                                       "past them are skipped "
                                       "(ops.quant_matmul.live_rows)",
+    "batcher.admit.attn_pairs": "(query, key) pairs held by the tiles of "
+                                "the flash kernel that are live by the "
+                                "causal band and the window over a fresh "
+                                "admission's bucket, summed over the "
+                                "attention layers by kind, each tile "
+                                "counted by its area; 0 for an admission "
+                                "that does not take the kernel (a suffix "
+                                "behind a prefix, a mesh, heads that fill "
+                                "a register in part)",
+    "batcher.admit.attn_pairs_live": "of those, the pairs of the tiles of "
+                                     "queries that held a real token; the "
+                                     "tiles past them are not visited "
+                                     "(ops.flash.live_tiles; equal to "
+                                     "attn_pairs where no count reaches "
+                                     "the kernel: the families whose "
+                                     "layers are all alike)",
     "batcher.loop.grow_seconds": "chunk-boundary page growth, preemption "
                                  "included (histogram)",
     "batcher.loop.plan_seconds": "span planning and the per-chunk "

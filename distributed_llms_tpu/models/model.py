@@ -584,12 +584,16 @@ _LANES = 128  # a register's lanes: the flash kernel's tiles are whole ones
 
 
 def _self_attention(q, k, v, positions, window: int | None = None,
-                    scale: float | None = None):
+                    scale: float | None = None,
+                    rows: jax.Array | None = None):
     """Causal attention of T tokens over themselves, a row's start: the one
     place an admission's fresh row is scored (q [B, T, H, hd], k [B, T,
     KVH, hd], v [B, T, KVH, hv]; ``positions`` [B, T] rise by one along the
-    block; ``scale`` None: hd ** -0.5).  The body is chosen by what the
-    call can see:
+    block; ``scale`` None: hd ** -0.5; ``rows`` what :func:`real_rows`
+    returns: the kernel ends its grid at the last tile of queries that
+    holds a real token and leaves the tiles past it as zeros, the real
+    tokens' outputs bit for bit what they are without it; the dense bodies
+    score the bucket).  The body is chosen by what the call can see:
 
     - on the chip, one shard, heads a whole number of registers wide (128:
       qwen2, pythia, k-exaone), and on the interpreter whatever the heads:
@@ -615,12 +619,10 @@ def _self_attention(q, k, v, positions, window: int | None = None,
 
     (As one XLA softmax over 8,192 keys the row maximum compiles to a
     reduce-window that takes 24 ms a block of 128 queries: PERF.md, PR 34.)"""
-    from ..ops import decode_attn, dispatch, flash
+    from ..ops import dispatch, flash
 
-    mode = decode_attn._mode()
-    # (the interpreter, the tests' leg of the kernel's program, has no lanes)
-    partial = mode == "kernel" and q.shape[-1] % _LANES != 0
-    if mode == "fallback" or dispatch.mesh() is not None or partial:
+    mode = _flash_mode(q.shape[-1])
+    if mode is None or mode == "fallback":
         if mode == "fallback":  # (a body chosen by shape or mesh is no
             # fallback: the record would read as a kernel that failed)
             dispatch.record("flash", "fallback", (*q.shape, k.shape[2]))
@@ -628,12 +630,57 @@ def _self_attention(q, k, v, positions, window: int | None = None,
         return _expanded_attention(
             q, layers.repeat_kv(k, g), layers.repeat_kv(v, g),
             layers.causal_mask(positions, positions, window=window), scale)
-    # A band of 128 inside tiles of 1,024 would score eight times the keys
-    # it needs: tiles of 512 for a windowed layer.
-    block = 1024 if window is None else 512
+    block = _flash_block(window)
     return flash.flash_attention(
         q, k, v, causal=True, window=window, block_q=block, block_k=block,
-        interpret=mode == "interpret", scale=scale)
+        interpret=mode == "interpret", scale=scale, rows=rows)
+
+
+def _flash_mode(head_dim: int) -> str | None:
+    """How :func:`_self_attention` scores heads ``head_dim`` wide: "kernel"
+    or "interpret" (the flash kernel's two legs), "fallback" (the dense
+    body, asked for by ``DLT_RAGGED_DECODE``), None (the dense body, chosen
+    by the mesh or by heads that fill a register in part)."""
+    from ..ops import decode_attn, dispatch
+
+    mode = decode_attn._mode()
+    if mode == "fallback":
+        return mode
+    # (the interpreter, the tests' leg of the kernel's program, has no lanes)
+    partial = mode == "kernel" and head_dim % _LANES != 0
+    return None if dispatch.mesh() is not None or partial else mode
+
+
+def _flash_block(window: int | None) -> int:
+    """The flash kernel's tile for a row's start.  A band of 128 inside
+    tiles of 1,024 would score eight times the keys it needs: tiles of 512
+    for a windowed layer."""
+    return 1024 if window is None else 512
+
+
+def self_attention_pairs(cfg: ModelConfig, t: int, rows: int
+                         ) -> tuple[int, int]:
+    """((query, key) pairs the flash kernel's live tiles hold over one
+    fresh admission of a bucket of ``t`` tokens, summed over ``cfg``'s
+    attention layers by kind; of them those it scores for a prompt of
+    ``rows`` tokens).  Equal where no count reaches the kernel (the
+    families whose layers are all alike); (0, 0) where the admission does
+    not take the kernel.  Host arithmetic, for the batcher's counters."""
+    from ..ops.flash import live_tiles
+
+    if cfg.kv_lora_rank:
+        head = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    else:
+        head = cfg.head_dim_
+    if _flash_mode(head) not in ("kernel", "interpret"):
+        return 0, 0
+    kinds = [(len(cfg.attn_layers), cfg.model_window)]
+    if cfg.swa_layers:
+        kinds.append((len(cfg.swa_layers), cfg.sliding_window))
+    # (mixed_attention alone hands the kernel the count)
+    real = rows if cfg.swa_layers else t
+    pairs = [(n, live_tiles(t, real, _flash_block(w), w)) for n, w in kinds]
+    return (sum(n * p[0] for n, p in pairs), sum(n * p[1] for n, p in pairs))
 
 
 def mixed_attention(
@@ -699,7 +746,8 @@ def mixed_attention(
             "from its start (cache_index 0): the rings hold no prefix to "
             "continue from"
         )
-    out = layers.out_project(_self_attention(q, k, v, positions, w), p)
+    out = layers.out_project(
+        _self_attention(q, k, v, positions, w, rows=real_rows(seq_lens, b)), p)
     if cache is None:
         return out, None
     if kind == "attn":

@@ -20,7 +20,11 @@ Design (standard flash-attention recurrence, TPU-tiled):
   positions and validity are the standard contiguous layout): above-diagonal
   tiles are skipped *and their K/V index maps are clamped to the diagonal*,
   so the dead tiles issue no new DMA; fully-visible tiles skip masking
-  entirely; only diagonal tiles pay for the iota mask;
+  entirely; only diagonal tiles pay for the iota mask.  Its grid covers the
+  tiles that can hold a live (query, key) pair and no others: a windowed
+  call's K axis spans the band's tiles and not the row's, and a call told
+  how many leading rows are real (``rows``: an admission's padded bucket)
+  ends its Q axis at the last tile that holds one (:func:`live_tiles`);
 - **dynamic path** (ragged prompts, padded KV caches): per-tile masks are
   built from global position / validity vectors, and fully-masked tiles skip
   their MXU work via ``pl.when``.
@@ -45,6 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import dispatch
+from .quant_matmul import _zero_kernel
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -96,6 +101,49 @@ def _finish(o_ref, acc_ref, l_ref):
 # Static-causal kernel (training / prefill hot path)
 # ---------------------------------------------------------------------------
 
+def _band(qi, bq: int, bk: int, window: int | None):
+    """(first, last) of the K tiles that hold a key some row of Q tile ``qi``
+    sees: up to the diagonal and, with ``window``, from the tile of the
+    first row's oldest key (row - window + 1, at least 0).  The one rule of
+    the kernel, its index maps (``qi`` traced) and the host's counts (an
+    int)."""
+    host = isinstance(qi, int)
+    div = (lambda a, b: a // b) if host else jax.lax.div
+    last = div(qi * bq + bq - 1, bk)
+    if window is None:
+        return 0, last
+    first_col = (max if host else jnp.maximum)(qi * bq - (window - 1), 0)
+    return div(first_col, bk), last
+
+
+def _band_widths(nq: int, bq: int, bk: int, window: int | None) -> list[int]:
+    """K tiles in the band of each of ``nq`` Q tiles."""
+    return [last - first + 1 for first, last in
+            (_band(qi, bq, bk, window) for qi in range(nq))]
+
+
+def _tile(t: int, s: int, block_q: int, block_k: int) -> tuple[int, int]:
+    """(bq, bk) of a call of ``t`` queries over ``s`` keys.  Q tile: sublane
+    dim of the score tile (min 8 rows); K tile: lane dim (pad short
+    sequences up to one 128-lane tile)."""
+    return min(block_q, _round_up(t, 8)), min(block_k, _round_up(s, 128))
+
+
+def live_tiles(t: int, rows: int, block: int, window: int | None = None
+               ) -> tuple[int, int]:
+    """(scored pairs, of them scored when told that the first ``rows`` of
+    the ``t`` queries are real) of one static-causal call in tiles of
+    ``block``: the tiles live by the causal band and the window, and those
+    of them whose Q tile holds a real row (every one where ``t`` is a
+    single Q tile), each counted by its area so that calls in different
+    tiles add up.  Host arithmetic, for the batcher's counters."""
+    bq, bk = _tile(t, t, block, block)
+    nq = -(-t // bq)
+    live = nq if nq == 1 else min(nq, -(-rows // bq))
+    tiles = _band_widths(nq, bq, bk, window)
+    return sum(tiles) * bq * bk, sum(tiles[:live]) * bq * bk
+
+
 def _kernel_static(
     q_ref,  # [1, bq, D]
     k_ref,  # [1, bk, D]
@@ -106,7 +154,7 @@ def _kernel_static(
     l_ref,  # [bq, 128] f32
     *,
     scale: float,
-    num_k_blocks: int,
+    num_k_blocks: int,  # steps of the K axis: the row's tiles, or a band's
     block_q: int,
     block_k: int,
     window: int | None = None,  # sliding window: keys in (row - window, row]
@@ -121,7 +169,11 @@ def _kernel_static(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q_start = qi * block_q
-    k_start = ki * block_k
+    if window is not None:  # step ki is the band's ki-th tile (:func:`_band`)
+        ki_abs = ki + _band(qi, block_q, block_k, window)[0]
+    else:
+        ki_abs = ki
+    k_start = ki_abs * block_k
     # Tile classes: fully visible (every (row, col) pair inside the causal —
     # and, when windowed, the window — band), boundary (crosses the diagonal
     # or the window's lower edge: iota-masked), dead (fully outside; index
@@ -222,8 +274,7 @@ def _pad_to(x: jax.Array, axis: int, mult: int, value) -> jax.Array:
     return jnp.pad(x, widths, constant_values=value)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
-def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k, interpret, window, scale=None):
+def _flash_call(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k, interpret, window, scale=None, rows=None):
     # Inside shard_map (e.g. the Ulysses body) the inputs carry varying
     # manual axes (vma); the output must declare the same set.
     vma = frozenset().union(*(jax.typeof(x).vma for x in (q, k, v)))
@@ -247,10 +298,7 @@ def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
     if scale is None:
         scale = d**-0.5
 
-    # Q tile: sublane dim of the score tile (min 8 rows); K tile: lane dim
-    # (pad short sequences up to one 128-lane tile).
-    bq = min(block_q, _round_up(tq, 8))
-    bk = min(block_k, _round_up(s, 128))
+    bq, bk = _tile(tq, s, block_q, block_k)
 
     # The hot path: standard contiguous positions, every key slot valid, and
     # query rows aligned with key slots (training forward / full prefill).
@@ -266,6 +314,12 @@ def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
     tq_p, s_p = qt.shape[2], kt.shape[2]
     nq, nk = tq_p // bq, s_p // bk
     grid = (b, h, nq, nk)
+    if rows is not None and (not static_causal or b != 1):
+        raise ValueError(
+            "rows is the count of real tokens of ONE right-padded sequence "
+            "that attends causally among its own tokens")
+    if nq == 1:  # one Q tile holds every row: nothing to end early
+        rows = None
     scratch = [
         pltpu.VMEM((bq, dv), jnp.float32),
         pltpu.VMEM((bq, 128), jnp.float32),
@@ -281,25 +335,32 @@ def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
     )
 
     if static_causal:
-        # Clamp dead tiles' K/V fetches into the live band (above the
-        # diagonal, and — when windowed — below the window's lower edge):
-        # repeated index => the pipeline issues no new DMA for skipped
-        # tiles, so a windowed prefill's work scales with the window, not
-        # the sequence.
+        # The K axis walks a Q tile's band (:func:`_band`): every tile up
+        # to the diagonal, or with a window as many as the widest band
+        # holds, from the band's first.  A step past the diagonal is dead;
+        # its fetch is clamped to the diagonal's tile: repeated index =>
+        # the pipeline issues no new DMA, so a windowed prefill's work
+        # scales with the window, not the sequence.
+        steps = (nk if window is None
+                 else max(_band_widths(nq, bq, bk, window)))
+
         def kv_index(bi, hi, qi, ki):
-            last_needed = jax.lax.div(qi * bq + bq - 1, bk)
-            kk = jnp.minimum(ki, last_needed)
-            if window is not None:
-                first_col = jnp.maximum(qi * bq - (window - 1), 0)
-                kk = jnp.maximum(kk, jax.lax.div(first_col, bk))
+            first, last_needed = _band(qi, bq, bk, window)
+            kk = jnp.minimum(ki if window is None else ki + first, last_needed)
             return (bi * kvh + hi // g, kk, 0)
 
+        # The Q tiles that hold a real row: the grid stops there (a traced
+        # bound), so the padded queries, the dearest rows of the triangle,
+        # are not scored, and the kernel and its index maps are the same
+        # either way.  (With bq == bk every K tile past them is above the
+        # diagonal already.)
+        live = nq if rows is None else jnp.clip(pl.cdiv(rows[0], bq), 0, nq)
         out = pl.pallas_call(
             functools.partial(
-                _kernel_static, scale=scale, num_k_blocks=nk,
+                _kernel_static, scale=scale, num_k_blocks=steps,
                 block_q=bq, block_k=bk, window=window,
             ),
-            grid=grid,
+            grid=(b, h, live, steps),
             in_specs=[
                 q_spec,
                 pl.BlockSpec((1, bk, d), kv_index),
@@ -311,6 +372,22 @@ def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
             interpret=interpret,
             name="flash_attn",  # the operation's name in a trace
         )(*args)
+        if rows is not None:
+            # ... and are written as zeros, where they lie in the output.
+            out = pl.pallas_call(
+                _zero_kernel,
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=1,
+                    grid=(b * h, nq - live),
+                    in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+                    out_specs=pl.BlockSpec(
+                        (1, bq, dv), lambda i, qi, first: (i, first[0] + qi, 0)),
+                ),
+                out_shape=out_shape,
+                input_output_aliases={1: 0},
+                interpret=interpret,
+                name="flash_attn_padding",
+            )(live.reshape(1), out)
     else:
         # Freshly created defaults are not device-varying over any manual
         # mesh axis; align them with q/k/v so vma tracking stays consistent
@@ -371,6 +448,13 @@ def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
 # ---------------------------------------------------------------------------
 # Autodiff: dense-recompute backward (flash-checkpoint style)
 # ---------------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+def _flash(q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k, interpret, window, scale=None):
+    return _flash_call(
+        q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
+        interpret, window, scale)
+
 
 def _dense_reference(q, k, v, q_positions, k_positions, k_valid, causal,
                      window=None, scale=None):
@@ -460,6 +544,11 @@ def flash_attention(
     scale: float | None = None,  # the softmax scale; None: D ** -0.5.  Static
     #   (latent attention's is YaRN's, and its zero-padded heads' width is
     #   not the width the scale is of)
+    rows: jax.Array | None = None,  # [1] int32: the first ``rows`` of the Tq
+    #   tokens are real, ONE right-padded sequence on the static-causal path
+    #   (an admission's bucket).  The Q tiles past them are not visited and
+    #   come back as zeros; a real token's output is the call's without
+    #   ``rows``, bit for bit.  Forward only.  None: all Tq
 ) -> jax.Array:
     """Fused attention.  Matches ``layers.dot_product_attention`` with mask
     ``(k_pos <= q_pos if causal) & k_valid [& window band]`` but never
@@ -473,6 +562,10 @@ def flash_attention(
             raise ValueError("window requires causal attention")
         if window < 1:
             raise ValueError(f"window must be >= 1, got {window}")
+    if rows is not None:  # (a traced grid bound has no derivative rule)
+        return _flash_call(
+            q, k, v, q_positions, k_positions, k_valid, causal, block_q,
+            block_k, interpret, window, scale, rows)
     return _flash(
         q, k, v, q_positions, k_positions, k_valid, causal, block_q, block_k,
         interpret, window, scale,

@@ -3428,17 +3428,27 @@ class ContinuousBatcher:
                     # the row tiles that hold a token of the prompt (or
                     # suffix).
                     live = live_rows(bucket, len(req.ids) - cached_len)
+                    # The (query, key) pairs the flash kernel's tiles hold
+                    # over a fresh row's bucket, and those it scores: the
+                    # tiles of queries that hold a token of the prompt.
+                    pairs, pairs_live = (
+                        model_lib.self_attention_pairs(
+                            self.cfg, min(bucket, self.s), len(req.ids))
+                        if fresh and self.pm is None else (0, 0))
                     prev = self._admit_inflight
                     ahead = {} if prev is None else {"fetched_rid": prev.req.rid}
                     with self._span(
                         "batcher.admit.row", rid=req.rid,
                         prompt_tokens=total_len, cached_tokens=cached_len,
                         bucket=bucket, live_rows=live,
+                        attn_pairs_live=pairs_live,
                         key_slots=min(bucket, self.s) if fresh else self.s,
                         **ahead,
                     ):
                         METRICS.inc("batcher.admit.matmul_rows", bucket)
                         METRICS.inc("batcher.admit.matmul_rows_live", live)
+                        METRICS.inc("batcher.admit.attn_pairs", pairs)
+                        METRICS.inc("batcher.admit.attn_pairs_live", pairs_live)
                         if fresh:
                             METRICS.inc("batcher.admit.self_attention")
                         else:

@@ -3,7 +3,9 @@ padding (PR 39): for every family that serves a cell, the admission with
 the count of real rows against the same admission without it (the parent's
 route: ``models.model.real_rows`` answering None), which must agree to the
 last bit on everything a real token leaves behind; what the traced programs
-hold; and the batcher's two counters."""
+hold; and the batcher's two counters.  Since PR 46 the flash kernel of the
+two configurations of windowed and full attention layers takes the same
+count: its grid ends at the last tile of queries that holds a real token."""
 
 import functools
 
@@ -244,3 +246,51 @@ def test_counters_say_how_often_the_kernel_skips(monkeypatch):
     assert (rows, live) == (128 + 512 + 1024, 128 + 512 + 768)
     assert [(a["bucket"], a["live_rows"]) for a in spans] == [
         (128, 128), (512, 512), (1024, 768)]
+
+
+# -- the flash kernel's grid ends with the real tokens (PR 46) -------------
+@functools.lru_cache(maxsize=None)
+def banded(name, window):
+    """The tiny preset at a window that the kernel's tiles of 512 cut."""
+    cfg = get_preset(name, max_seq_len=2048, sliding_window=window)
+    return cfg, model_lib.init_params_quantized(jax.random.key(0), cfg, 8)
+
+
+@pytest.mark.parametrize("n", [700, 1030])  # 1 of 2 and 2 of 4 tiles of
+#   queries live; 2 of 2 and 3 of 4
+@pytest.mark.parametrize("name,window", [
+    ("k-exaone-tiny", 8), ("smallthinker-tiny", 600)])
+def test_a_short_prompt_ends_the_flash_kernels_grid(
+        name, window, n, monkeypatch, dispatched):
+    """``forward`` told ``seq_lens`` against the same call with the flash
+    kernel left without the count (the parent's route): the first token,
+    the pages' first ``n`` slots and the rings to the last bit, on the
+    kernel's interpreter leg."""
+    monkeypatch.setenv("DLT_QUANT_MATMUL", "fallback")
+    monkeypatch.setenv("DLT_RAGGED_DECODE", "interpret")
+    cfg, params = banded(name, window)
+    t, counts = 2048, []
+    real = model_lib._self_attention
+    jax.clear_caches()  # the record is written while the kernel is traced
+
+    def admit(counted):
+        def spy(q, k, v, positions, w=None, scale=None, rows=None):
+            counts.append(rows is not None)
+            return real(q, k, v, positions, w, scale,
+                        rows if counted else None)
+
+        with monkeypatch.context() as mp:
+            mp.setattr(model_lib, "_self_attention", spy)
+            return jax.jit(lambda p: batcher_lib._prefill_row(
+                model_lib.forward, params, cfg, jnp.float32, t, p,
+                jnp.int32(n)))(tokens(t))
+
+    got, want = admit(True), admit(False)
+    assert counts and all(counts)  # mixed_attention hands every layer one
+    assert dispatched().get("flash.interpret", 0) > 0
+    assert "flash.fallback" not in dispatched()
+    same_to_the_bit(got, want, n)
+    if n <= 1024:  # the full layers' second tile of queries was not scored:
+        # the deeper layers' keys there come of zeros and not of attention
+        assert not np.array_equal(np.asarray(got[1].k[1:, :, 1024:]),
+                                  np.asarray(want[1].k[1:, :, 1024:]))

@@ -5,6 +5,8 @@ real tensors) for the net-new Pallas kernel: every dispatch mode is checked
 against ``layers.dot_product_attention`` with the equivalent mask.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -279,3 +281,86 @@ def test_a_scale_reaches_the_backward_pass():
                          jax.grad(g, (0, 1, 2))(q, k, v)):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-4)
+
+
+# The shapes an admission of the two hybrid configurations hands the kernel
+# (query heads over key heads, the windowed layers' band in tiles of 512,
+# the full layers in tiles of 1,024), on heads of 16 to keep the
+# interpreter's steps short: (T, window, block, H, KVH).
+_ADMISSIONS = {
+    "full-1024-28over4": (4096, None, 1024, 28, 4),
+    "band128-512-64over8": (2048, 128, 512, 64, 8),
+    "band4096-512-28over4": (6144, 4096, 512, 28, 4),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _whole_call(case):
+    """(q, k, v, the call without ``rows``) of a case."""
+    t, window, block, h, kvh = _ADMISSIONS[case]
+    q, k, v = _qkv(b=1, t=t, h=h, kvh=kvh, d=16, seed=t)
+    return q, k, v, flash_attention(
+        q, k, v, window=window, block_q=block, block_k=block, interpret=True)
+
+
+@pytest.mark.parametrize("real", ["1", "edge", "edge+1", "T-3", "T"])
+@pytest.mark.parametrize("case", list(_ADMISSIONS))
+def test_the_grid_ends_at_the_last_tile_that_holds_a_real_row(case, real):
+    """``rows``: the real tokens' outputs are the call's without it bit for
+    bit, the Q tiles past the last live one come back as zeros, and nothing
+    that lies past that tile (NaN in q, k and v) reaches a real row."""
+    t, window, block, _, _ = _ADMISSIONS[case]
+    real = {"1": 1, "edge": 2 * block, "edge+1": 2 * block + 1,
+            "T-3": t - 3, "T": t}[real]
+    q, k, v, want = _whole_call(case)
+    live = -(-real // block) * block
+    rows = jnp.asarray([real], jnp.int32)
+    kw = dict(window=window, block_q=block, block_k=block, interpret=True)
+    got = flash_attention(q, k, v, rows=rows, **kw)
+    np.testing.assert_array_equal(
+        np.asarray(got[:, :live]), np.asarray(want[:, :live]))
+    assert not np.asarray(got[:, live:]).any()
+    poison = lambda a: a.at[:, live:].set(jnp.nan)  # noqa: E731
+    got = flash_attention(poison(q), poison(k), poison(v), rows=rows, **kw)
+    np.testing.assert_array_equal(
+        np.asarray(got[:, :live]), np.asarray(want[:, :live]))
+    assert not np.asarray(got[:, live:]).any()
+
+
+def test_rows_is_one_sequences_count_on_the_static_causal_path():
+    q, k, v = _qkv(b=2, t=64)
+    rows = jnp.asarray([5], jnp.int32)
+    with pytest.raises(ValueError, match="ONE right-padded sequence"):
+        flash_attention(q, k, v, rows=rows, block_q=16, block_k=128)
+    pos = jnp.arange(64, dtype=jnp.int32)[None]
+    with pytest.raises(ValueError, match="ONE right-padded sequence"):
+        flash_attention(q[:1], k[:1], v[:1], q_positions=pos,
+                        k_positions=pos, rows=rows, block_q=16, block_k=128)
+
+
+@pytest.mark.parametrize("t,rows,block,window", [
+    (4096, 2982, 1024, None), (4096, 2982, 512, 4096), (4096, 1, 512, 128),
+    (2048, 1543, 1024, None), (2048, 1024, 512, 128), (2048, 1025, 512, 700),
+    (1024, 3, 1024, None), (1024, 3, 512, 128), (512, 100, 512, 128),
+    (3072, 3072, 512, 1), (3072, 2049, 1024, None), (320, 7, 1024, None),
+])
+def test_live_tiles_counts_what_the_grid_visits(t, rows, block, window):
+    """``live_tiles`` against a count over every (row, column) pair: a tile
+    is live if it holds a pair inside the causal band and the window, and
+    visited if besides its Q tile holds a real row (a call of one Q tile
+    visits it whatever the count)."""
+    from distributed_llms_tpu.ops.flash import live_tiles
+
+    r, c = np.arange(t)[:, None], np.arange(t)[None, :]
+    seen = c <= r
+    if window is not None:
+        seen &= c > r - window
+    bq, bk = min(block, t), min(block, -(-t // 128) * 128)
+    before = now = 0
+    for q0 in range(0, t, bq):
+        for k0 in range(0, t, bk):
+            if seen[q0:q0 + bq, k0:k0 + bk].any():
+                before += bq * bk
+                now += bq * bk if q0 < rows or t <= bq else 0
+    assert live_tiles(t, rows, block, window) == (before, now)
+    assert 0 < now <= before

@@ -284,6 +284,22 @@ def test_an_admission_at_the_8192_bucket_leaves_half_a_gigabyte(
         "dynamic-update-slice", "fusion:dynamic-update-slice"}
 
 
+def _kernels(hlo: str) -> set:
+    return set(re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*custom_call_target="
+                          r'"tpu_custom_call"', hlo))
+
+
+def test_the_8192_admissions_flash_kernel_ends_with_the_real_tokens(
+        compiled_windowed):
+    """Mosaic takes the flash kernel with a traced bound on its axis of
+    query tiles at 64 heads over 8, tiles of 1,024 and the band of 128 in
+    tiles of 512, and the kernel that zeros the tiles not visited (PR 46)."""
+    assert {"flash_attn", "flash_attn_padding"} <= _kernels(
+        compiled_windowed["admit_row_paged"]["hlo"])
+    assert "flash_attn" not in _kernels(
+        compiled_windowed["decode_chunk"]["hlo"])
+
+
 @pytest.fixture(scope="module")
 def compiled_blocked_rings():
     """``decode_chunk`` and ``admit_row_paged`` at the 16,384 bucket of
@@ -328,10 +344,8 @@ def test_rings_of_4096_tokens_are_walked_by_the_kernel_and_written_in_place(
     assert decode["expert_shaped"] == [] and decode["weight_shaped"] == []
     assert 10.3 < decode["argument_gb"] < 10.45
     assert decode["temp_gb"] < 0.3
-    kernels = re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*custom_call_target="
-                         r'"tpu_custom_call"', decode["hlo"])
     assert {"swa_decode_attn", "paged_decode_attn", "moe_experts",
-            "_quant_matmul_2d"} <= set(kernels)
+            "_quant_matmul_2d"} <= _kernels(decode["hlo"])
     assert not re.search(r"\[32,4096,4,128\]", decode["hlo"])
 
 
@@ -352,6 +366,14 @@ def test_an_admission_at_the_16384_bucket_holds_one_row_of_logits(
         "dynamic-update-slice", "fusion:dynamic-update-slice"}
     assert {e[0] for e in admit["ring_shaped"]} <= {
         "dynamic-update-slice", "fusion:dynamic-update-slice"}
+
+
+def test_the_16384_admissions_flash_kernel_ends_with_the_real_tokens(
+        compiled_blocked_rings):
+    """... and at 28 heads over 4 with the band of 4,096 (9 tiles of 512) on
+    a row of 32 tiles."""
+    assert {"flash_attn", "flash_attn_padding"} <= _kernels(
+        compiled_blocked_rings["admit_row_paged"]["hlo"])
 
 
 # (preset, slots, max_len, pages, bucket, GB of temporaries held to; what

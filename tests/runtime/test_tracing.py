@@ -622,3 +622,63 @@ def test_the_rings_counters_and_gauges_of_a_windowed_model():
     assert snap["gauges"]["batcher.window_state_bytes"] == \
         2 * 6 * 4 * 8 * 2 * 16 * 4
     assert snap["gauges"]["batcher.pool_token_bytes"] == 2 * 2 * 2 * 16 * 4
+
+
+@pytest.mark.parametrize("name,kernel", [
+    ("k-exaone-tiny", True), ("llama-tiny", True), ("llama-tiny", False)])
+def test_counters_say_how_many_pairs_the_flash_kernel_scores(
+        name, kernel, monkeypatch):
+    """A fresh row adds ``ops.flash.live_tiles``' two counts, summed over
+    the attention layers by kind, to ``batcher.admit.attn_pairs`` and
+    ``..._live`` (equal where no count reaches the kernel: the dense
+    families; nothing where the admission takes the dense body), and the
+    span carries ``attn_pairs_live``; a continuation behind a cached prefix
+    adds nothing."""
+    from distributed_llms_tpu.models.presets import get_preset
+    from distributed_llms_tpu.ops.flash import live_tiles
+
+    for name_ in ("batcher.admit.attn_pairs", "batcher.admit.attn_pairs_live"):
+        assert "flash" in METRIC_DOCS[name_]
+    assert "attn_pairs_live" in METRIC_DOCS["batcher.admit.row_seconds"]
+    monkeypatch.setenv("DLT_QUANT_MATMUL", "fallback")
+    monkeypatch.setenv("DLT_RAGGED_DECODE",
+                       "interpret" if kernel else "fallback")
+    hybrid = name != "llama-tiny"
+    cfg = get_preset(name, max_seq_len=2048)
+    params = model_lib.init_params(jax.random.key(0), cfg)
+    b = ContinuousBatcher(
+        cfg, params, batch_slots=2, max_len=2048, chunk_steps=2,
+        paged_pages=300, page_size=16, prefix_cache=not hybrid)
+    spans = []
+    real = b._span
+
+    def span(name, **attrs):
+        if name == "batcher.admit.row":
+            spans.append(attrs)
+        return real(name, **attrs)
+
+    monkeypatch.setattr(b, "_span", span)
+    names = ("batcher.admit.attn_pairs", "batcher.admit.attn_pairs_live")
+    before = [METRICS.get_counter(n) for n in names]
+    prompt = [int(x) for x in np.random.RandomState(3).randint(
+        0, 256, 1100)]  # bucket 2,048
+    b.submit(prompt, max_new_tokens=1)
+    b.run()
+    if not hybrid:  # the same prompt again, behind its cached pages
+        b.submit(prompt + [7, 8, 9], max_new_tokens=1)
+        b.run()
+        assert spans[1]["cached_tokens"] > 0
+    got = tuple(METRICS.get_counter(n) - was for n, was in zip(names, before))
+    if not kernel:
+        want = (0, 0)
+    elif hybrid:
+        full, band = (live_tiles(2048, 1100, 1024),
+                      live_tiles(2048, 1100, 512, cfg.sliding_window))
+        assert full == (3 * 1024 ** 2, 3 * 1024 ** 2)  # 1,100 > one tile
+        assert band == (7 * 512 ** 2, 5 * 512 ** 2)
+        n_full, n_band = len(cfg.attn_layers), len(cfg.swa_layers)
+        want = tuple(n_full * f + n_band * w for f, w in zip(full, band))
+    else:
+        want = (cfg.num_layers * 3 * 1024 ** 2,) * 2
+    assert got == want
+    assert [a["attn_pairs_live"] for a in spans] == [want[1], 0][:len(spans)]

@@ -10,7 +10,9 @@ Weights are ``init_params_quantized`` (int8) from seed 0, the pool and the
 row's page list are the cell's (``max_len // page`` entries, the bucket's
 own pages first, the scratch page after), the prompt random bytes: three
 short of the bucket, or ``BUCKET:LEN`` tokens of it (since PR 39 the
-quantized matmuls skip the row tiles past them).
+quantized matmuls skip the row tiles past them, and since PR 46 the flash
+kernel of a model of windowed and full layers the tiles of queries past
+them: ``flash_attn_ms`` lists its calls singly, in the order they ran).
 ``--tree DIR`` profiles another checkout's programs (a parent under
 ``_chip/``) in the same call.  ``--rehearsal`` runs a tiny preset on the
 CPU and reads no trace (there are no device lines to read).
@@ -106,6 +108,7 @@ def main() -> None:
             jax.profiler.stop_trace()
             path = trace_reduce.find_xplane(tdir)
             by = collections.defaultdict(lambda: [0, 0])
+            flash = []  # (start, instruction, ns) of every flash_attn call
             for plane in ProfileData.from_file(path).planes:
                 if not plane.name.startswith("/device:TPU:0"):
                     continue
@@ -113,9 +116,15 @@ def main() -> None:
                     if line.name != "XLA Ops":
                         continue
                     for ev in line.events:
-                        rec = by[_op(ev.name)]
+                        op = _op(ev.name)
+                        rec = by[op]
                         rec[0] += int(ev.duration_ns)
                         rec[1] += 1
+                        if op[0] == "flash_attn":
+                            flash.append((
+                                int(ev.start_ns),
+                                ev.name.split(" = ")[0].lstrip("%"),
+                                int(ev.duration_ns)))
             shutil.rmtree(tdir, ignore_errors=True)
             kinds = collections.defaultdict(int)
             for (stem, _), (ns, _) in by.items():
@@ -124,6 +133,11 @@ def main() -> None:
                 device_ms=sum(v[0] for v in by.values()) / 1e6,
                 by_kind_ms={k: round(v / 1e6, 3) for k, v in sorted(
                     kinds.items(), key=lambda kv: -kv[1])[:12]},
+                # The flash kernel's calls singly, in the order they ran: a
+                # full and a windowed layer write the same shape, and only
+                # their times tell them apart.
+                flash_attn_ms=[[name, round(ns / 1e6, 3)]
+                               for _, name, ns in sorted(flash)],
                 # Operations XLA wrote itself (no Pallas call), by the shape
                 # they write: the score matrices are the [.., T, S] ones.
                 by_shape_ms=[

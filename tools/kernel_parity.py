@@ -476,6 +476,31 @@ def flash_band_parity(t: int, h: int, kvh: int, win: int) -> None:
           atol=3e-2)
 
 
+def flash_cut_parity(t: int, real: int, h: int, kvh: int, win: int | None,
+                     block: int) -> None:
+    """The flash kernel told an admission's count of real tokens
+    (``rows``): its grid ends at the last tile of queries that holds one.
+    Against the call without the count, which the legs above hold to the
+    dense reference: the live tiles bit for bit, zeros past them, and NaN
+    in q, k and v past them reaching nothing."""
+    ks = jax.random.split(jax.random.PRNGKey(19), 3)
+    live = -(-real // block) * block
+    q, kk, v = (
+        jax.random.normal(key, (1, t, heads, 128), jnp.bfloat16)
+        for key, heads in zip(ks, (h, kvh, kvh)))
+    run = jax.jit(lambda q, k, v, rows=None: flash_attention(
+        q, k, v, causal=True, window=win, block_q=block, block_k=block,
+        interpret=not ON_TPU, rows=rows))
+    want = run(q, kk, v)
+    got = run(*(a.at[:, live:].set(jnp.nan) for a in (q, kk, v)),
+              jnp.asarray([real], jnp.int32))
+    if np.asarray(got[:, live:], np.float32).any():
+        raise AssertionError("a tile of queries past the real tokens is not "
+                             "zeros")
+    check(f"flash T{t} real{real} H{h}/{kvh} win{win} tiles{block}",
+          got[:, :live], want[:, :live], rtol=0, atol=0)
+
+
 def ragged_parity() -> None:
     key = jax.random.PRNGKey(2)
     for b, s, h, kvh, d, lengths in (
@@ -646,6 +671,15 @@ def main() -> int:
     moe_parity(e=64, d=2560, f=768, k=6, act="relu") if ON_TPU else \
         moe_parity(e=64, d=256, f=128, k=6, act="relu")
     flash_band_parity(*((8192, 7, 1, 4096) if ON_TPU else (1024, 4, 2, 512)))
+    # An admission's count of real tokens ends the flash kernel's grid:
+    # SmallThinker's 28 / 4 heads at a mean prompt of its 8,192 bucket, a
+    # windowed layer (tiles of 512) and a full one (1,024); K-EXAONE's 64 /
+    # 8 with its band of 128.
+    for leg in (((8192, 5690, 28, 4, 4096, 512), (8192, 5690, 28, 4, None, 1024),
+                 (8192, 5552, 64, 8, 128, 512)) if ON_TPU else
+                ((1024, 300, 4, 2, 512, 128), (1024, 300, 4, 2, None, 256),
+                 (1024, 513, 8, 1, 128, 128))):
+        flash_cut_parity(*leg)
     # No leg may pass on another path than the one asked for: the dispatch
     # record (ops/dispatch.py) counts every trace by the path it took.
     took = {k[len("ops.dispatch."):]: int(v)
@@ -685,8 +719,10 @@ def main() -> int:
     # qwen2-7b's and pythia-6.9b's heads, and with a scale and a value
     # width of its own (192 / 128: A.X-K1's expanded latent heads) — 50
     # legs.  v13: SmallThinker's ring walked in blocks, its ReLU-gated
-    # experts and its admissions' 4,096 band — 54 legs.
-    print(f"kernel_parity: ALL PASS v13 ({mode}, backend={backend})")
+    # experts and its admissions' 4,096 band — 54 legs.  v14: the flash
+    # kernel's grid ended at an admission's last real tile of queries, at
+    # SmallThinker's and K-EXAONE's heads and bands — 57 legs.
+    print(f"kernel_parity: ALL PASS v14 ({mode}, backend={backend})")
     return 0
 
 
