@@ -77,9 +77,11 @@ class ModelConfig:
     # for OPT); the llama family is SwiGLU regardless.
     activation: str = "gelu"
     # Attention implementation: "dot" (XLA-fused), "flash" (Pallas fused
-    # blockwise kernel, ops/flash.py: prefill and training forwards use it —
-    # note the backward recomputes attention densely at O(T^2) memory —
-    # while single-token decode falls back to dot), "ring" (sequence-parallel
+    # blockwise kernel, ops/flash.py, for a forward with NO cache: training
+    # and eval — note the backward recomputes attention densely at O(T^2)
+    # memory; a prefill into a cache takes the kernel by what the call can
+    # see, whatever this says: models.model._self_attention,
+    # _continuation_attention), "ring" (sequence-parallel
     # ppermute ring over the 'seq' mesh axis; prefill/training only), or
     # "ulysses" (sequence-parallel all-to-all head scatter over 'seq';
     # needs num_heads and num_kv_heads divisible by the seq axis).

@@ -306,6 +306,14 @@ METRIC_DOCS: dict[str, str] = {
                                      "attn_pairs where no count reaches "
                                      "the kernel: the families whose "
                                      "layers are all alike)",
+    "batcher.admit.cont_keys": "slots of the row cache that a layer scored "
+                               "for the admissions behind a named or "
+                               "cached prefix, a row's continuation: the "
+                               "key tiles the flash kernel fetched "
+                               "(ops.flash.live_keys), every slot of the "
+                               "row for the dense body",
+    "batcher.admit.cont_keys_live": "of those, the slots that held a key: "
+                                    "the prefix's tokens and the prompt's",
     "batcher.loop.grow_seconds": "chunk-boundary page growth, preemption "
                                  "included (histogram)",
     "batcher.loop.plan_seconds": "span planning and the per-chunk "

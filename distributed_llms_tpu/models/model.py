@@ -251,40 +251,14 @@ def _attention(
             ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, cache_index, 0, 0))
             cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, cache_index, 0, 0))
         if attn_mask is None:
-            s = ck.shape[1]
-            k_positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (x.shape[0], s))
-            k_valid = k_positions < (cache_index + x.shape[1])
-            if (cfg.attn_impl == "flash" and x.shape[1] > 1
-                    and (cfg.model_window is None or key_positions is None)):
-                # Prefill into a (longer, padded) cache: the flash kernel
-                # masks the unwritten tail instead of computing a dense
-                # [Tq, max_len] score matrix.  Single-token decode stays on
-                # the dense path (the kernel targets block-sized Tq).
-                # Windowed models ride the kernel's window band here too —
-                # the kernel's single k_positions vector drives causality
-                # AND the window, which is exact precisely when slot ==
-                # position for written slots (attn_mask is None and no
-                # key_positions map => the ungapped prefill layout); gapped
-                # layouts supply key_positions and take the dense window
-                # path below.
-                from ..ops import flash
-
-                out = flash.flash_attention(
-                    q, ck.astype(q.dtype), cv.astype(q.dtype),
-                    q_positions=positions, k_positions=k_positions,
-                    k_valid=k_valid, causal=True, window=cfg.model_window,
-                )
-                return layers.out_project(out, p), (ck, cv)
-            # Causality/validity compare SLOT indices (the write frontier);
-            # the window compares POSITIONS — for gapped layouts the caller
-            # supplies key_positions (see the parameter comment above).
-            attn_mask = layers.causal_mask(positions, k_positions, k_valid)
-            if cfg.model_window is not None:
-                kpos = k_positions if key_positions is None else key_positions
-                attn_mask = layers.and_window(
-                    attn_mask, positions, kpos, cfg.model_window
-                )
-        elif cfg.model_window is not None:
+            # A scalar write offset and no mask of the caller's own: the
+            # row's continuation (or a one-shot prefill into a longer,
+            # padded cache).
+            out = _continuation_attention(
+                q, ck, cv, positions, cache_index, cfg.model_window,
+                key_positions)
+            return layers.out_project(out, p), (ck, cv)
+        if cfg.model_window is not None:
             # Caller-supplied masks (continuous batching's per-row prefix
             # masks, padded prefill) carry causality/validity but not the
             # window — AND it in here so no dense cached path can silently
@@ -636,11 +610,74 @@ def _self_attention(q, k, v, positions, window: int | None = None,
         interpret=mode == "interpret", scale=scale, rows=rows)
 
 
+def _continuation_attention(q, ck, cv, positions, cache_index,
+                            window: int | None = None, key_positions=None):
+    """Causal attention of T new tokens over a row cache that holds them
+    behind what came before, a row's CONTINUATION: the one place it is
+    scored (q [B, T, H, hd]; ck, cv [B, S, KVH, hd], the new keys and
+    values already written at slots [cache_index, cache_index + T);
+    ``positions`` [B, T] the new tokens'; ``cache_index`` a scalar, so every
+    row of the batch has its keys in slots [0, cache_index + T) and none
+    past them: a suffix behind a cached prefix, a chunk of a chunked
+    prefill, a one-shot prefill into a cache longer than the prompt).  A
+    query sees the slots at or below its position, with ``window`` those
+    above position - window.  The body is chosen by what the call can see,
+    as :func:`_self_attention`'s is:
+
+    - on the chip, one shard, heads a whole number of registers wide, and on
+      the interpreter whatever the heads: the flash kernel's static-causal
+      path with its diagonal ``cache_index`` down (ops/flash.py,
+      ``start``): no [T, S] score matrix and no copy of the keys at the
+      query heads exists, the cached run's tiles are scored without a
+      mask, and no tile of keys past the new tokens is fetched, so the
+      work grows with the keys the row holds and not with ``max_len``.  The
+      kernel takes slot == position for causality AND the window: exact in
+      the ungapped layout, where ``positions`` are cache_index + 0 .. T - 1;
+      a windowed model whose caller brings a map of the slots' positions
+      (``key_positions``: the right-padded generate layout) and a single
+      token (a decode step: the kernel's tiles are blocks of queries) take
+      the dense body;
+    - heads that fill a register in part, a mesh, and
+      ``DLT_RAGGED_DECODE=fallback`` (the CPU's default; counted as the
+      kernel's fallback): layers.dot_product_attention over all S slots
+      under layers.causal_mask, the numbers the kernel is parity-tested
+      against."""
+    from ..ops import dispatch, flash
+
+    b, t = q.shape[:2]
+    s = ck.shape[1]
+    mode = _flash_mode(q.shape[-1])
+    suited = t > 1 and (window is None or key_positions is None)
+    if suited and mode in ("kernel", "interpret"):
+        return flash.flash_attention(
+            q, ck.astype(q.dtype), cv.astype(q.dtype), causal=True,
+            window=window, block_q=_CONTINUATION_BLOCKS[0],
+            block_k=_CONTINUATION_BLOCKS[1], interpret=mode == "interpret",
+            start=jnp.reshape(cache_index, (1,)))
+    if suited and mode == "fallback":
+        dispatch.record("flash", "fallback", (*q.shape, ck.shape[2]))
+    k_positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    # Causality/validity compare SLOT indices (the write frontier); the
+    # window compares POSITIONS: for gapped layouts the caller supplies
+    # key_positions (see _attention's parameter comment).
+    mask = layers.causal_mask(
+        positions, k_positions, k_positions < (cache_index + t))
+    if window is not None:
+        mask = layers.and_window(
+            mask, positions,
+            k_positions if key_positions is None else key_positions, window)
+    g = q.shape[2] // ck.shape[2]
+    return layers.dot_product_attention(
+        q, layers.repeat_kv(ck.astype(q.dtype), g),
+        layers.repeat_kv(cv.astype(q.dtype), g), mask)
+
+
 def _flash_mode(head_dim: int) -> str | None:
-    """How :func:`_self_attention` scores heads ``head_dim`` wide: "kernel"
-    or "interpret" (the flash kernel's two legs), "fallback" (the dense
-    body, asked for by ``DLT_RAGGED_DECODE``), None (the dense body, chosen
-    by the mesh or by heads that fill a register in part)."""
+    """How :func:`_self_attention` and :func:`_continuation_attention` score
+    heads ``head_dim`` wide: "kernel" or "interpret" (the flash kernel's two
+    legs), "fallback" (the dense body, asked for by ``DLT_RAGGED_DECODE``),
+    None (the dense body, chosen by the mesh or by heads that fill a
+    register in part)."""
     from ..ops import decode_attn, dispatch
 
     mode = decode_attn._mode()
@@ -649,6 +686,13 @@ def _flash_mode(head_dim: int) -> str | None:
     # (the interpreter, the tests' leg of the kernel's program, has no lanes)
     partial = mode == "kernel" and head_dim % _LANES != 0
     return None if dispatch.mesh() is not None or partial else mode
+
+
+# The flash kernel's tile (queries, keys) for a row's continuation: the
+# 7 x 128 rows of a KV group's query heads over a 128-token suffix are one
+# tile of queries, and a cell's rows hold 800-1,900 keys, which tiles of 512
+# follow twice as closely as tiles of 1,024.
+_CONTINUATION_BLOCKS = (1024, 512)
 
 
 def _flash_block(window: int | None) -> int:
@@ -681,6 +725,22 @@ def self_attention_pairs(cfg: ModelConfig, t: int, rows: int
     real = rows if cfg.swa_layers else t
     pairs = [(n, live_tiles(t, real, _flash_block(w), w)) for n, w in kinds]
     return (sum(n * p[0] for n, p in pairs), sum(n * p[1] for n, p in pairs))
+
+
+def continuation_keys(cfg: ModelConfig, t: int, s: int, keys: int) -> int:
+    """Slots of a row cache of ``s`` that one layer scores for a bucket of
+    ``t`` tokens continuing a row that then holds ``keys``: the key tiles
+    the flash kernel fetches where :func:`_continuation_attention` takes it
+    (under a mesh the caller says so: the count is then ``s``), all ``s``
+    for the dense body and for the families whose continuations are not
+    scored there (latent attention's).  Host arithmetic, for the batcher's
+    counters."""
+    from ..ops.flash import live_keys
+
+    if (cfg.family not in BLOCK_FNS or t == 1
+            or _flash_mode(cfg.head_dim_) not in ("kernel", "interpret")):
+        return s
+    return live_keys(s, keys, _CONTINUATION_BLOCKS[1])
 
 
 def mixed_attention(

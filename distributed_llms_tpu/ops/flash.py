@@ -24,7 +24,12 @@ Design (standard flash-attention recurrence, TPU-tiled):
   tiles that can hold a live (query, key) pair and no others: a windowed
   call's K axis spans the band's tiles and not the row's, and a call told
   how many leading rows are real (``rows``: an admission's padded bucket)
-  ends its Q axis at the last tile that holds one (:func:`live_tiles`);
+  ends its Q axis at the last tile that holds one (:func:`live_tiles`).  The
+  same kernel scores a row's CONTINUATION, Tq new tokens behind ``start``
+  cached ones in a cache of ``max_len`` slots: the causal diagonal is
+  shifted by a traced ``start``, the tiles of the cached run are the fully
+  visible ones and no tile past the new tokens is fetched
+  (:func:`_flash_continuation_call`, :func:`live_keys`);
 - **dynamic path** (ragged prompts, padded KV caches): per-tile masks are
   built from global position / validity vectors, and fully-masked tiles skip
   their MXU work via ``pl.when``.
@@ -101,19 +106,37 @@ def _finish(o_ref, acc_ref, l_ref):
 # Static-causal kernel (training / prefill hot path)
 # ---------------------------------------------------------------------------
 
-def _band(qi, bq: int, bk: int, window: int | None):
+def _band(qi, bq: int, bk: int, window: int | None, start=0,
+          tokens: int | None = None):
     """(first, last) of the K tiles that hold a key some row of Q tile ``qi``
     sees: up to the diagonal and, with ``window``, from the tile of the
     first row's oldest key (row - window + 1, at least 0).  The one rule of
     the kernel, its index maps (``qi`` traced) and the host's counts (an
-    int)."""
-    host = isinstance(qi, int)
+    int).  A continuation's tile (:func:`_tile_rows`) sits ``start`` down
+    the diagonal."""
+    host = isinstance(qi, int) and isinstance(start, int)
     div = (lambda a, b: a // b) if host else jax.lax.div
-    last = div(qi * bq + bq - 1, bk)
+    # (the tile's first position is formed anew for each bound, as it always
+    # was: a row start's kernel stays the program it has been, to the bit)
+    q_start, span = _tile_rows(qi, bq, start, tokens)
+    last = div(q_start + span - 1, bk)
     if window is None:
         return 0, last
-    first_col = (max if host else jnp.maximum)(qi * bq - (window - 1), 0)
+    q_start = _tile_rows(qi, bq, start, tokens)[0]
+    first_col = (max if host else jnp.maximum)(q_start - (window - 1), 0)
     return div(first_col, bk), last
+
+
+def _tile_rows(qi, bq: int, start=0, tokens: int | None = None):
+    """(position of its first row, positions it spans) of Q tile ``qi``.  A
+    row's start: tile qi's rows are tokens qi * bq onward, one a position.
+    A continuation (``tokens``: :func:`_flash_continuation_call`): the rows
+    are runs of ``tokens`` tokens, one run a query head, each run's token j
+    at position ``start`` + j, and a tile holds whole runs or a part of
+    one."""
+    if tokens is None:
+        return qi * bq, bq
+    return start + (qi * bq) % tokens, min(bq, tokens)
 
 
 def _band_widths(nq: int, bq: int, bk: int, window: int | None) -> list[int]:
@@ -126,7 +149,12 @@ def _tile(t: int, s: int, block_q: int, block_k: int) -> tuple[int, int]:
     """(bq, bk) of a call of ``t`` queries over ``s`` keys.  Q tile: sublane
     dim of the score tile (min 8 rows); K tile: lane dim (pad short
     sequences up to one 128-lane tile)."""
-    return min(block_q, _round_up(t, 8)), min(block_k, _round_up(s, 128))
+    return min(block_q, _round_up(t, 8)), _k_tile(s, block_k)
+
+
+def _k_tile(s: int, block_k: int) -> int:
+    """The K tile of a call over ``s`` keys (:func:`_tile`'s second)."""
+    return min(block_k, _round_up(s, 128))
 
 
 def live_tiles(t: int, rows: int, block: int, window: int | None = None
@@ -144,6 +172,14 @@ def live_tiles(t: int, rows: int, block: int, window: int | None = None
     return sum(tiles) * bq * bk, sum(tiles[:live]) * bq * bk
 
 
+def live_keys(s: int, keys: int, block_k: int) -> int:
+    """Slots of a cache of ``s`` that a continuation which leaves ``keys``
+    in it fetches and scores: the K tiles up to the last that holds a key.
+    Host arithmetic, for the batcher's counters."""
+    bk = _k_tile(s, block_k)
+    return min(max(-(-keys // bk), 1) * bk, _round_up(s, bk))
+
+
 def _kernel_static(
     q_ref,  # [1, bq, D]
     k_ref,  # [1, bk, D]
@@ -158,6 +194,8 @@ def _kernel_static(
     block_q: int,
     block_k: int,
     window: int | None = None,  # sliding window: keys in (row - window, row]
+    start_ref=None,  # [1] int32 in SMEM: a continuation's first position
+    tokens: int | None = None,  # ... and the length of a head's run of rows
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -168,9 +206,10 @@ def _kernel_static(
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q_start = qi * block_q
+    start = 0 if start_ref is None else start_ref[0]
+    q_start, span = _tile_rows(qi, block_q, start, tokens)
     if window is not None:  # step ki is the band's ki-th tile (:func:`_band`)
-        ki_abs = ki + _band(qi, block_q, block_k, window)[0]
+        ki_abs = ki + _band(qi, block_q, block_k, window, start, tokens)[0]
     else:
         ki_abs = ki
     k_start = ki_abs * block_k
@@ -179,12 +218,12 @@ def _kernel_static(
     # or the window's lower edge: iota-masked), dead (fully outside; index
     # maps clamp its K/V fetch so it costs no DMA and no MXU work).
     visible = k_start + block_k - 1 <= q_start
-    dead = k_start > q_start + block_q - 1  # above the diagonal
+    dead = k_start > q_start + span - 1  # above the diagonal
     if window is not None:
         # Fully visible additionally needs every col > every row - window;
         # fully below the window's lower edge is dead.
         visible = jnp.logical_and(
-            visible, k_start > q_start + block_q - 1 - window
+            visible, k_start > q_start + span - 1 - window
         )
         dead = jnp.logical_or(dead, k_start + block_k - 1 <= q_start - window)
     boundary = jnp.logical_not(jnp.logical_or(visible, dead))
@@ -197,7 +236,11 @@ def _kernel_static(
     @pl.when(boundary)
     def _edge():
         s = _scores(q_ref[0], k_ref[0], scale)
-        rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        if tokens is None:
+            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        else:  # row r of the tile is token r % tokens of its run: a column
+            rows = q_start + jax.lax.broadcasted_iota(
+                jnp.int32, (s.shape[0], 1), 0) % tokens
         cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         keep = cols <= rows
         if window is not None:
@@ -445,6 +488,96 @@ def _flash_call(q, k, v, q_positions, k_positions, k_valid, causal, block_q, blo
     return out.transpose(0, 2, 1, 3)
 
 
+def _flash_continuation_call(q, k, v, start, block_q, block_k, interpret,
+                             window, scale=None):
+    """The static-causal kernel over a row's continuation: the Tq queries
+    are tokens ``start`` .. ``start`` + Tq - 1 of a row whose keys lie in the
+    cache's slots of the same index (slot == position, the cached run first
+    and the new tokens behind it), and no slot past them holds a key.  The
+    diagonal is the row start's, ``start`` down: the cached run's tiles are
+    the fully visible ones (no mask is built for them), the tiles the new
+    tokens lie in are the boundary's, and a step past those fetches nothing
+    (its index is clamped to the diagonal's tile, :func:`_band`).  Laid
+    before the kernel so that a K tile serves a whole KV group:
+
+    - the g query heads of a KV group are g runs of rows behind one
+      another, each of the Tq tokens (padded to whole Q tiles), and a Q
+      tile holds whole runs where they fit (7 x 128 rows of one K tile) or
+      a part of one: a K tile is fetched once a KV head and not once a
+      query head, and a grid step, which costs its 0.6 us whatever it
+      holds, scores g times the pairs;
+    - K and V stay as the cache has them, [B, S, KVH x D] with a head a
+      block of lanes, and are not transposed whole to [KVH, S, D] first
+      (on the chip that takes heads of whole 128-lane registers).
+
+    Forward only."""
+    b, tq, h, d = q.shape
+    s, kvh, dv = k.shape[1], k.shape[2], v.shape[3]
+    assert h % kvh == 0, (h, kvh)
+    g = h // kvh
+    dispatch.record(
+        "flash", "interpret" if interpret else "kernel", (b, tq, s, h, kvh, d))
+    if scale is None:
+        scale = d**-0.5
+    bk = _k_tile(s, block_k)
+    tokens = _round_up(tq, 8)
+    if g * tokens <= block_q:
+        bq = g * tokens  # one tile: every head's run
+    else:
+        bq = min(block_q, tokens)  # tiles of one run; a run is whole tiles
+        tokens = _round_up(tokens, bq)
+    qf = _pad_to(q, 1, tokens, 0).reshape(b, tokens, kvh, g, d).transpose(
+        0, 2, 3, 1, 4).reshape(b * kvh, g * tokens, d)
+    kl = _pad_to(k, 1, bk, 0)
+    vl = _pad_to(v, 1, bk, 0)
+    s_p = kl.shape[1]
+    nq, nk = g * tokens // bq, s_p // bk
+    # The K axis: the row's tiles, or as many as a band can touch (the
+    # window and the tile's own positions, wherever ``start`` puts them).
+    steps = nk if window is None else min(
+        nk, -(-(window - 1 + min(bq, tokens)) // bk) + 1)
+
+    def kv_index(bi, hi, qi, ki, start_ref):
+        first, last_needed = _band(qi, bq, bk, window, start_ref[0], tokens)
+        # (a run's padded rows lie past the last key: what they would see
+        # is not fetched either, and a real row masks it whole)
+        last_key = jax.lax.div(start_ref[0] + (tq - 1), bk)
+        kk = jnp.minimum(ki if window is None else ki + first,
+                         jnp.minimum(last_needed, last_key))
+        return (bi, jnp.minimum(kk, nk - 1), hi)
+
+    out = pl.pallas_call(
+        lambda start_ref, *refs: _kernel_static(
+            *refs, scale=scale, num_k_blocks=steps, block_q=bq, block_k=bk,
+            window=window, start_ref=start_ref, tokens=tokens),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, kvh, nq, steps),
+            in_specs=[
+                pl.BlockSpec((1, bq, d),
+                             lambda bi, hi, qi, ki, _: (bi * kvh + hi, qi, 0)),
+                pl.BlockSpec((1, bk, d), kv_index),
+                pl.BlockSpec((1, bk, dv), kv_index),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, bq, dv), lambda bi, hi, qi, ki, _: (bi * kvh + hi, qi, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((bq, dv), jnp.float32),
+                pltpu.VMEM((bq, 128), jnp.float32),
+                pltpu.VMEM((bq, 128), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b * kvh, g * tokens, dv), q.dtype),
+        interpret=interpret,
+        name="flash_attn",  # the operation's name in a trace
+    )(
+        start.astype(jnp.int32).reshape(1), qf,
+        kl.reshape(b, s_p, kvh * d), vl.reshape(b, s_p, kvh * dv),
+    )
+    out = out.reshape(b, kvh, g, tokens, dv)[:, :, :, :tq]
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, tq, h, dv)
+
+
 # ---------------------------------------------------------------------------
 # Autodiff: dense-recompute backward (flash-checkpoint style)
 # ---------------------------------------------------------------------------
@@ -549,6 +682,13 @@ def flash_attention(
     #   (an admission's bucket).  The Q tiles past them are not visited and
     #   come back as zeros; a real token's output is the call's without
     #   ``rows``, bit for bit.  Forward only.  None: all Tq
+    start: jax.Array | None = None,  # [1] int32: a row's CONTINUATION.  The
+    #   Tq queries are tokens start .. start + Tq - 1 of a row whose keys lie
+    #   in slots [0, start + Tq) of the S, slot == position, every row of
+    #   the batch alike (the cached run and the new tokens behind it, in a
+    #   cache of max_len slots).  It stands for the positions and the
+    #   validity vector; no K tile past the new tokens is fetched
+    #   (:func:`_flash_continuation_call`).  Forward only
 ) -> jax.Array:
     """Fused attention.  Matches ``layers.dot_product_attention`` with mask
     ``(k_pos <= q_pos if causal) & k_valid [& window band]`` but never
@@ -562,6 +702,15 @@ def flash_attention(
             raise ValueError("window requires causal attention")
         if window < 1:
             raise ValueError(f"window must be >= 1, got {window}")
+    if start is not None:
+        if not causal or any(x is not None for x in (
+                q_positions, k_positions, k_valid, rows)):
+            raise ValueError(
+                "start says where a continuation's queries and keys lie: "
+                "it stands for q_positions, k_positions and k_valid, and "
+                "rows is a row's start's")
+        return _flash_continuation_call(
+            q, k, v, start, block_q, block_k, interpret, window, scale)
     if rows is not None:  # (a traced grid bound has no derivative rule)
         return _flash_call(
             q, k, v, q_positions, k_positions, k_valid, causal, block_q,

@@ -236,18 +236,19 @@ def _prefill_row_with_prefix(fwd, params, cfg, row_cache, prefix_len, chunk,
     kv_cache.row_cache_of).  The model is told the suffix's true length
     ``clen``, and a hybrid-family model returns its expert counts third, as
     in :func:`_prefill_row`.  ``last`` is the suffix's true length whoever
-    else is told it: the logits are its last real position's, [1, 1, V]."""
+    else is told it: the logits are its last real position's, [1, 1, V].
+    The row holds an ungapped run, slot == position: ``prefix_len`` slots
+    of what came before and the chunk behind them.  So the model is handed
+    no mask: a scalar write offset says it all, "causal by position, keys
+    below prefix_len + Tc", and the model scores it as a row's continuation
+    (models.model._continuation_attention: the flash kernel over the key
+    tiles the row holds where it can, the dense body over every slot
+    elsewhere)."""
     (tc,) = chunk.shape
-    s = row_cache.k.shape[2]
-    slots = jnp.arange(s, dtype=jnp.int32)
     positions = (prefix_len + jnp.arange(tc, dtype=jnp.int32))[None, :]
-    from .session import continuation_mask
-
-    prefix_valid = (slots < prefix_len)[None, :]  # [1, S]
-    mask = continuation_mask(prefix_valid, prefix_len, tc, slots)  # [1,1,Tc,S]
     return fwd(
         params, cfg, chunk[None, :], positions=positions,
-        cache=row_cache, cache_index=prefix_len, attn_mask=mask,
+        cache=row_cache, cache_index=prefix_len,
         logits_at=_last_real(last), **_row_state(fwd, cfg, clen),
     )
 
@@ -3435,6 +3436,16 @@ class ContinuousBatcher:
                         model_lib.self_attention_pairs(
                             self.cfg, min(bucket, self.s), len(req.ids))
                         if fresh and self.pm is None else (0, 0))
+                    # The slots a layer scores for a row's continuation
+                    # (the suffix's bucket behind a named or cached
+                    # prefix): the row cache's every slot, or the tiles of
+                    # keys the flash kernel fetches.
+                    held = pfx_len + cached_len
+                    behind = min(bucket, self.s - held)  # the suffix's bucket
+                    cont_keys = 0 if fresh else (
+                        self.s if self.pm is not None
+                        else model_lib.continuation_keys(
+                            self.cfg, behind, self.s, held + behind))
                     prev = self._admit_inflight
                     ahead = {} if prev is None else {"fetched_rid": prev.req.rid}
                     with self._span(
@@ -3442,13 +3453,17 @@ class ContinuousBatcher:
                         prompt_tokens=total_len, cached_tokens=cached_len,
                         bucket=bucket, live_rows=live,
                         attn_pairs_live=pairs_live,
-                        key_slots=min(bucket, self.s) if fresh else self.s,
+                        key_slots=(min(bucket, self.s) if fresh
+                                   else cont_keys),
                         **ahead,
                     ):
                         METRICS.inc("batcher.admit.matmul_rows", bucket)
                         METRICS.inc("batcher.admit.matmul_rows_live", live)
                         METRICS.inc("batcher.admit.attn_pairs", pairs)
                         METRICS.inc("batcher.admit.attn_pairs_live", pairs_live)
+                        METRICS.inc("batcher.admit.cont_keys", cont_keys)
+                        METRICS.inc("batcher.admit.cont_keys_live",
+                                    0 if fresh else total_len)
                         if fresh:
                             METRICS.inc("batcher.admit.self_attention")
                         else:

@@ -42,8 +42,10 @@ def continuation_mask(
     """[B, 1, T, S] attention mask for prefilling a chunk at slots
     [base, base+t) against existing cache content: query i attends prior
     valid slots plus chunk slots j <= i (right padding means pad slots have
-    j greater than every real query's i).  Shared by session continuation
-    and the continuous batcher's prefix-cached admission."""
+    j greater than every real query's i).  Session continuation's: its
+    padded multi-turn layout leaves gaps between the turns.  (The continuous
+    batcher's rows have none, slot == position, and hand the model a scalar
+    offset and no mask: models.model._continuation_attention.)"""
     rel = slots[None, :] - base  # [1, S]: slot index within the chunk
     chunk_causal = (rel[:, None, :] >= 0) & (
         rel[:, None, :] <= jnp.arange(t, dtype=jnp.int32)[None, :, None]

@@ -86,10 +86,12 @@ def test_kv_cache_matches_full_forward_windowed():
 
 
 @pytest.mark.fragile_xla_cpu
-def test_flash_impl_matches_windowed_dot():
+def test_flash_impl_matches_windowed_dot(monkeypatch):
     """attn_impl='flash' on a windowed model rides the kernel's window
-    band (ops/flash.py window=) for no-cache forwards AND cached prefill,
-    matching the masked dot path exactly; the windowed generate loop stays
+    band (ops/flash.py window=) for no-cache forwards, and a cached prefill
+    rides it on the kernel's legs whatever ``attn_impl`` says (PR 47:
+    models.model._continuation_attention; here the interpreter's), matching
+    the masked dot path exactly; the windowed generate loop stays
     token-identical too (decode steps keep the dense path)."""
     from distributed_llms_tpu.runtime import generate as gen_lib
 
@@ -109,6 +111,7 @@ def test_flash_impl_matches_windowed_dot():
     ref = gen_lib.generate_tokens(
         params, cfg, prompt, lens, jax.random.key(2), max_new_tokens=8,
     )
+    monkeypatch.setenv("DLT_RAGGED_DECODE", "interpret")
     out = gen_lib.generate_tokens(
         params, cfg_flash, prompt, lens, jax.random.key(2), max_new_tokens=8,
     )

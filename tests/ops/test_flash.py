@@ -210,7 +210,11 @@ def test_offset_positions_match_dot():
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), atol=1e-4)
 
 
-def test_generate_flash_matches_dot():
+def test_generate_flash_matches_dot(monkeypatch, dispatched):
+    """The one-shot prefill into a padded cache (no mask, a traced offset of
+    0: models.model._continuation_attention) on the kernel's interpreter leg
+    against the dense body.  Since PR 47 the body is chosen by what the call
+    can see and not by ``attn_impl``, which still picks the no-cache route."""
     from distributed_llms_tpu.runtime import generate as gen_lib
 
     cfg_dot = ModelConfig(
@@ -224,7 +228,10 @@ def test_generate_flash_matches_dot():
     lens = jnp.array([5, 9], dtype=jnp.int32)
     rng = jax.random.key(2)
     ref = gen_lib.generate_tokens(params, cfg_dot, prompt, lens, rng, max_new_tokens=6)
+    assert "flash.interpret" not in dispatched()
+    monkeypatch.setenv("DLT_RAGGED_DECODE", "interpret")
     out = gen_lib.generate_tokens(params, cfg_flash, prompt, lens, rng, max_new_tokens=6)
+    assert dispatched().get("flash.interpret", 0) > 0
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
@@ -364,3 +371,97 @@ def test_live_tiles_counts_what_the_grid_visits(t, rows, block, window):
                 now += bq * bk if q0 < rows or t <= bq else 0
     assert live_tiles(t, rows, block, window) == (before, now)
     assert 0 < now <= before
+
+
+# -- a row's continuation: the diagonal shifted by ``start`` (PR 47) ---------
+
+_S, _BK = 1024, 256  # a row cache of four tiles of keys
+
+
+@pytest.mark.parametrize("prefix", ["start", "inside", "edge", "end"])
+@pytest.mark.parametrize("heads,window", [((7, 1), None), ((2, 2), 200)])
+@pytest.mark.parametrize("tq", [8, 64, 128])
+def test_a_continuation_walks_the_tiles_that_hold_a_key(
+        tq, heads, window, prefix):
+    """``flash_attention(start=)`` as a continuation calls it, against
+    ``_dense_reference`` on the clean row: Tq new tokens behind a run of
+    ``prefix`` slots (none; one that ends inside a tile; one whose new
+    tokens fill a tile to its edge; one that fills the row), the seven query
+    heads of a KV group over their one head and one over one, a window and
+    none.  Every key past the new tokens is NaN, and every value past the
+    LAST LIVE TILE (a masked key's score is replaced; a masked value is
+    weighted by 0, which a NaN survives, and the row caches hold numbers
+    there): nothing past the live tiles is read."""
+    from distributed_llms_tpu.ops import flash
+
+    h, kvh = heads
+    n = {"start": 0, "inside": 100, "edge": 2 * _BK - tq, "end": _S - tq}[prefix]
+    keys = n + tq
+    ks = jax.random.split(jax.random.key(tq + n), 3)
+    q = jax.random.normal(ks[0], (1, tq, h, 128), jnp.float32)
+    k = jax.random.normal(ks[1], (1, _S, kvh, 128), jnp.float32)
+    v = jax.random.normal(ks[2], (1, _S, kvh, 128), jnp.float32)
+    qpos = (n + jnp.arange(tq, dtype=jnp.int32))[None]
+    ref = flash._dense_reference(
+        q, k, v, qpos, None, (jnp.arange(_S) < keys)[None], True, window)
+    live = flash.live_keys(_S, keys, _BK)
+    assert keys <= live < keys + _BK and live % _BK == 0
+    slot = jnp.arange(_S)[None, :, None, None]
+    out = flash_attention(
+        q, jnp.where(slot >= keys, jnp.nan, k),
+        jnp.where(slot >= live, jnp.nan, v), window=window, block_q=1024,
+        block_k=_BK, interpret=True, start=jnp.asarray([n], jnp.int32))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("tq,heads,window,bq,bk", [
+    (300, (7, 1), None, 128, 256),  # a head's run is three tiles of queries
+    (300, (4, 2), 100, 128, 128),  # ... whose padded rows pass the last key
+    (256, (6, 3), 300, 256, 128), (20, (4, 2), None, 1024, 512)])
+def test_a_long_suffixs_heads_are_runs_of_whole_tiles(
+        tq, heads, window, bq, bk):
+    """Where the heads of a KV group do not fit one tile of queries, each
+    head's run of rows is padded to whole tiles and a tile lies inside one
+    run."""
+    from distributed_llms_tpu.ops import flash
+
+    h, kvh = heads
+    s, n, d = (96, 30, 16) if tq == 20 else (1024, 513, 128)
+    ks = jax.random.split(jax.random.key(tq), 3)
+    q = jax.random.normal(ks[0], (1, tq, h, d), jnp.float32)
+    k = jax.random.normal(ks[1], (1, s, kvh, d), jnp.float32)
+    v = jax.random.normal(ks[2], (1, s, kvh, d), jnp.float32)
+    qpos = (n + jnp.arange(tq, dtype=jnp.int32))[None]
+    ref = flash._dense_reference(
+        q, k, v, qpos, None, (jnp.arange(s) < n + tq)[None], True, window)
+    slot = jnp.arange(s)[None, :, None, None]
+    out = flash_attention(
+        q, jnp.where(slot >= n + tq, jnp.nan, k),
+        jnp.where(slot >= flash.live_keys(s, n + tq, bk), jnp.nan, v),
+        window=window, block_q=bq, block_k=bk, interpret=True,
+        start=jnp.asarray([n], jnp.int32))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+def test_start_stands_for_the_positions_and_the_validity_vector():
+    from distributed_llms_tpu.ops import flash
+
+    q, k, v = _qkv(b=1, t=8, s=64)
+    start = jnp.asarray([32], jnp.int32)
+    qpos = (32 + jnp.arange(8, dtype=jnp.int32))[None]
+    for extra in ({"k_valid": jnp.ones((1, 64), bool)},
+                  {"k_positions": jnp.arange(64, dtype=jnp.int32)[None]},
+                  {"q_positions": qpos}, {"causal": False},
+                  {"rows": jnp.asarray([8], jnp.int32)}):
+        with pytest.raises(ValueError, match="start"):
+            flash_attention(q, k, v, start=start, **extra)
+    # ... and is the dynamic path's call with them spelt out: the queries at
+    # 32 onward, the cache's first 40 slots.
+    got = flash_attention(q, k, v, start=start, interpret=True)
+    want = flash_attention(
+        q, k, v, q_positions=qpos, interpret=True,
+        k_valid=(jnp.arange(64) < 40)[None])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    assert flash.live_keys(64, 40, 512) == 128  # (one tile, padded to lanes)
+    assert flash.live_keys(4096, 1408, 512) == 1536
+    assert flash.live_keys(4096, 4096, 512) == 4096

@@ -424,6 +424,44 @@ def test_a_fresh_rows_admission_holds_no_scores_over_the_row_cache(
     assert admit["argument_gb"] + admit["temp_gb"] < 15.75 - 0.5
 
 
+@pytest.mark.parametrize("preset,slots,max_len,pages,bucket,group", [
+    ("qwen2-7b", 16, 4096, 512, 128, 7), ("qwen2-7b", 16, 4096, 512, 2048, 7),
+    ("pythia-6.9b", 8, 2048, 96, 128, 1)])
+def test_a_continuation_is_the_flash_kernel_over_the_row_as_it_lies(
+        preset, slots, max_len, pages, bucket, group):
+    """``admit_row_auto_paged`` of the dense configurations that serve a
+    prefix cache, compiled for the v5e at the cell's shapes (PR 47): the
+    continuation's attention is the Pallas kernel (Mosaic takes the shifted
+    diagonal's prefetched scalar, a KV group's heads in one tile of queries
+    and K and V as lane blocks of the row), and neither the row's keys at
+    the query heads nor a score matrix over the row is left in the
+    program."""
+    from distributed_llms_tpu.models.presets import get_preset
+    from tools import aot_decode
+
+    try:
+        aot_decode.v5e_devices()
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cfg = get_preset(preset)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DLT_QUANT_MATMUL", "kernel")
+        mp.setenv("DLT_RAGGED_DECODE", "kernel")
+        admit = aot_decode.analyse(
+            "admit_row_auto_paged", cfg, slots=slots, max_len=max_len,
+            pages=pages, page_size=BLK, prompt_len=bucket)
+    calls = re.findall(r"%(flash_attn[\w.]*) = (\S+) custom-call", admit["hlo"])
+    assert calls, "no flash kernel in the program"
+    kvh, hd = cfg.num_kv_heads, cfg.head_dim_
+    rows = group * bucket
+    assert all(shape.startswith(f"bf16[{kvh},{rows},{hd}]")
+               for _, shape in calls), calls
+    assert admit["score_shaped"] == []
+    repeated = f"{max_len},{kvh},{group},{hd}]"  # repeat_kv over the row
+    assert aot_decode.shaped_like(admit["hlo"], [repeated]) == []
+    assert admit["argument_gb"] + admit["temp_gb"] < 15.75 - 0.5
+
+
 def test_score_shaped_finds_a_score_matrix():
     from tools import aot_decode
 

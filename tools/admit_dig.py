@@ -3,8 +3,13 @@ shape each operation writes: what an admission consists of, outside any
 server.  PERF.md's tables of an admission's fusions by shape come from it.
 
     chiprun --chips 1 -- python tools/admit_dig.py qwen2-7b --slots 16 \
-        --max-len 4096 --pages 512 --buckets 256,2048,2048:1100 \
+        --max-len 4096 --pages 512 --buckets 256,2048,2048:1100,128:100@1280 \
         --out chiprun_out/a.json
+
+``BUCKET:LEN@PREFIX`` times one ``admit_row_auto_paged`` instead, a prefix
+cache's hit: a suffix of LEN tokens in a bucket of BUCKET behind PREFIX
+tokens that the pool's pages already hold (PR 47: ``by_part_ms`` splits it
+into the matmuls, the head, the attention kernel and everything else).
 
 Weights are ``init_params_quantized`` (int8) from seed 0, the pool and the
 row's page list are the cell's (``max_len // page`` entries, the bucket's
@@ -48,7 +53,9 @@ def main() -> None:
     ap.add_argument("--pages", type=int, default=512)
     ap.add_argument("--page-size", type=int, default=64)
     ap.add_argument("--buckets", default="256,2048",
-                    help="BUCKET or BUCKET:LEN (the prompt's real tokens)")
+                    help="BUCKET or BUCKET:LEN (the prompt's real tokens), "
+                         "or BUCKET:LEN@PREFIX (a suffix behind PREFIX "
+                         "cached tokens)")
     ap.add_argument("--tree", default=None)
     ap.add_argument("--out", default="chiprun_out/admit_dig.json")
     ap.add_argument("--rehearsal", action="store_true")
@@ -79,19 +86,31 @@ def main() -> None:
               "device": jax.devices()[0].device_kind, "buckets": {}}
     tdir = os.path.join(os.path.dirname(os.path.abspath(a.out)), "_admit_trace")
     for item in a.buckets.split(","):
-        bucket, _, plen = item.partition(":")
-        bucket = int(bucket)
+        spec, _, prefix = item.partition("@")
+        bucket, _, plen = spec.partition(":")
+        bucket, prefix = int(bucket), int(prefix or 0)
         plen = int(plen) if plen else bucket - 3
-        own = bucket // a.page_size
+        # The row's pages: the cached run's first (whole pages: a hit is),
+        # the bucket's own after them, the scratch page past those.
+        held = -(-prefix // a.page_size)
+        own = held + -(-bucket // a.page_size)
         prompt = jnp.asarray(
             np.random.RandomState(0).randint(0, 250, bucket), jnp.int32)
-        page_list = jnp.asarray(
-            np.r_[1:1 + own, np.zeros(per_row - own)], jnp.int32)
+        pages = np.r_[1:1 + own, np.zeros(per_row - own)].astype(np.int32)
+        page_list = jnp.asarray(pages)
+        pages[:held] = 0  # (a shared page is read and never written)
+        write_list = jnp.asarray(pages)
 
         def admit(pool):
-            out = batcher.admit_row_paged(
-                params, cfg, pool, page_list, prompt, jnp.int32(plen),
-                jax.random.key(1), slot=jnp.int32(1))
+            if prefix:
+                out = batcher.admit_row_auto_paged(
+                    params, cfg, pool, page_list, write_list,
+                    jnp.int32(prefix), prompt, jnp.int32(plen),
+                    jax.random.key(1))
+            else:
+                out = batcher.admit_row_paged(
+                    params, cfg, pool, page_list, prompt, jnp.int32(plen),
+                    jax.random.key(1), slot=jnp.int32(1))
             jax.block_until_ready(out[1])
             return out[0]
 
@@ -100,7 +119,8 @@ def main() -> None:
         t1 = time.time()
         for _ in range(3):
             pool = admit(pool)
-        entry = {"prompt_len": plen, "wall_ms": (time.time() - t1) / 3 * 1e3}
+        entry = {"prompt_len": plen, "prefix_len": prefix,
+                 "wall_ms": (time.time() - t1) / 3 * 1e3}
         if not a.rehearsal:
             shutil.rmtree(tdir, ignore_errors=True)
             jax.profiler.start_trace(tdir)
@@ -117,6 +137,8 @@ def main() -> None:
                         continue
                     for ev in line.events:
                         op = _op(ev.name)
+                        if op[0] == "while":  # (a wrapper: its body's
+                            continue  # operations are events of their own)
                         rec = by[op]
                         rec[0] += int(ev.duration_ns)
                         rec[1] += 1
@@ -129,8 +151,23 @@ def main() -> None:
             kinds = collections.defaultdict(int)
             for (stem, _), (ns, _) in by.items():
                 kinds[stem] += ns
+            # An admission's parts: the head (what writes [.., vocab]), the
+            # blocks' quantized matmuls, the attention kernel, and what XLA
+            # wrote itself (the dense attention body, the gather of the row,
+            # the splice, norms and rotations).
+            parts = collections.defaultdict(int)
+            for (stem, shape), (ns, _) in by.items():
+                if re.search(rf"[\[,]{cfg.vocab_size}\]$", shape):
+                    parts["head"] += ns  # (one row of logits: a fusion)
+                elif stem.startswith("_quant_matmul_2d"):
+                    parts["matmuls"] += ns
+                elif stem.startswith("flash_attn"):
+                    parts["flash_attn"] += ns
+                else:
+                    parts["other"] += ns
             entry.update(
                 device_ms=sum(v[0] for v in by.values()) / 1e6,
+                by_part_ms={k: round(v / 1e6, 3) for k, v in parts.items()},
                 by_kind_ms={k: round(v / 1e6, 3) for k, v in sorted(
                     kinds.items(), key=lambda kv: -kv[1])[:12]},
                 # The flash kernel's calls singly, in the order they ran: a

@@ -501,6 +501,36 @@ def flash_cut_parity(t: int, real: int, h: int, kvh: int, win: int | None,
           got[:, :live], want[:, :live], rtol=0, atol=0)
 
 
+def flash_continuation_parity(tq: int, prefix: int, s: int, h: int, kvh: int,
+                              win: int | None = None) -> None:
+    """The flash kernel as a row's continuation calls it (models.model.
+    _continuation_attention: ``start``, Q tiles of 1,024 and K tiles of
+    512): ``tq`` new tokens behind ``prefix`` cached ones in a row cache of
+    ``s`` slots, the query heads of a KV group one tile of queries, K and V
+    read where the cache has them.  Against the dense reference on the
+    clean row; every key past the new tokens is NaN, and every value past
+    the last live tile."""
+    from distributed_llms_tpu.ops.flash import live_keys
+
+    ks = jax.random.split(jax.random.PRNGKey(23), 3)
+    q = jax.random.normal(ks[0], (1, tq, h, 128), jnp.bfloat16)
+    kk = jax.random.normal(ks[1], (1, s, kvh, 128), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, s, kvh, 128), jnp.bfloat16)
+    keys = prefix + tq
+    qpos = (prefix + jnp.arange(tq, dtype=jnp.int32))[None]
+    want = _dense_reference(
+        q, kk, v, qpos, None, (jnp.arange(s) < keys)[None], True, win)
+    slot = jnp.arange(s)[None, :, None, None]
+    got = jax.jit(lambda q, k, v, start: flash_attention(
+        q, k, v, causal=True, window=win, block_q=1024, block_k=512,
+        interpret=not ON_TPU, start=start))(
+            q, jnp.where(slot >= keys, jnp.nan, kk),
+            jnp.where(slot >= live_keys(s, keys, 512), jnp.nan, v),
+            jnp.asarray([prefix], jnp.int32))
+    check(f"flash continuation T{tq} behind {prefix} S{s} H{h}/{kvh} "
+          f"win{win}", got, want, rtol=3e-2, atol=3e-2)
+
+
 def ragged_parity() -> None:
     key = jax.random.PRNGKey(2)
     for b, s, h, kvh, d, lengths in (
@@ -680,6 +710,20 @@ def main() -> int:
                 ((1024, 300, 4, 2, 512, 128), (1024, 300, 4, 2, None, 256),
                  (1024, 513, 8, 1, 128, 128))):
         flash_cut_parity(*leg)
+    # A row's continuation behind cached pages: doc-qa's suffix buckets
+    # behind its shortest, a mean and its longest document at qwen2-7b's
+    # heads and row (a prefix that ends inside a tile, on a tile's edge,
+    # and a row filled to its last slot), a long suffix whose heads are
+    # runs of whole tiles, pythia's heads with no grouping, and a window.
+    for leg in (((128, 784, 4096, 28, 4), (64, 1280, 4096, 28, 4),
+                 (128, 1408, 4096, 28, 4), (128, 3968, 4096, 28, 4),
+                 (2048, 1280, 4096, 28, 4), (128, 640, 2048, 32, 32),
+                 (128, 1280, 4096, 28, 4, 1024)) if ON_TPU else
+                ((16, 100, 1024, 4, 2), (8, 504, 1024, 4, 2),
+                 (16, 496, 1024, 4, 2), (16, 1008, 1024, 4, 2),
+                 (300, 513, 1024, 4, 2), (16, 100, 1024, 2, 2),
+                 (16, 600, 1024, 4, 2, 200))):
+        flash_continuation_parity(*leg)
     # No leg may pass on another path than the one asked for: the dispatch
     # record (ops/dispatch.py) counts every trace by the path it took.
     took = {k[len("ops.dispatch."):]: int(v)
@@ -721,8 +765,12 @@ def main() -> int:
     # legs.  v13: SmallThinker's ring walked in blocks, its ReLU-gated
     # experts and its admissions' 4,096 band — 54 legs.  v14: the flash
     # kernel's grid ended at an admission's last real tile of queries, at
-    # SmallThinker's and K-EXAONE's heads and bands — 57 legs.
-    print(f"kernel_parity: ALL PASS v14 ({mode}, backend={backend})")
+    # SmallThinker's and K-EXAONE's heads and bands — 57 legs.  v15: the
+    # flash kernel over a row's continuation (``start``): the diagonal
+    # shifted by the cached run, a KV group's query heads one tile, K and V
+    # read where the cache has them, at qwen2-7b's and pythia's heads — 64
+    # legs.
+    print(f"kernel_parity: ALL PASS v15 ({mode}, backend={backend})")
     return 0
 
 

@@ -5,6 +5,8 @@
     python tools/reference_check.py --config ax-k1-int8-ep16      # the chip
     python tools/reference_check.py --config k-exaone-int8-ep8    # the chip
     python tools/reference_check.py --config smallthinker-21ba3b-int8  # the chip
+    python tools/reference_check.py --config qwen2-7b-int8  # the chip: the
+        # prefix cache's probes (prefix_cache_probes, below), and nothing else
     JAX_PLATFORMS=cpu python tools/reference_check.py --rehearsal # lfm2-tiny
     (--config ax-k1-int8-ep16 --rehearsal: ax-k1-tiny; --config
     k-exaone-int8-ep8 --rehearsal: k-exaone-tiny; --config
@@ -72,7 +74,9 @@ import sys
 import time
 from functools import partial
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HERE = ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if "--tree" in sys.argv:  # another checkout's programs (a parent's, _chip/)
+    ROOT = os.path.abspath(sys.argv[sys.argv.index("--tree") + 1])
 sys.path.insert(0, ROOT)
 
 import jax
@@ -142,6 +146,23 @@ TOLS = {
     "as_served": {"max_abs_logit": 0.70, "mean_abs_logit": 0.070},
   },
 }
+# A dense model's continuation behind cached pages (prefix_cache_probes): the
+# hit against the same prompt served with the cache off, and each against the
+# plain float32 reference, in the chosen tokens' logprobs (the batcher hands
+# out no logits).  The chip gave, parent | change (my chip runs, PR 47;
+# PERF.md, section 6, PR 47), the 8 tokens equal on every side: hit against
+# fresh 0.0167 | 0.0188 at the most over the 8 logprobs; the first token's
+# against the reference 0.0030 | 0.0063 (hit) and 0.0082 | 0.0082 (fresh:
+# the same program on both sides); over all 8, 0.014 | 0.020 (hit) and
+# 0.013 (fresh).  Both limits are the benchmark's own on a cached answer
+# against a fresh one (benchmark/run.py GOLDEN_TOL, which its warm-up holds
+# every shared run to): the dense body and the kernel sum in different
+# orders, and a seed-0 model's logprobs lie within 0.3 of one another, so a
+# limit much under it would be the rounding's and one much over it nobody's.
+PREFIX_TOLS = {
+  "qwen2-7b-int8": {"hit_against_fresh": 0.05, "against_reference": 0.05},
+}
+PREFIX_PROBE = (1200, 100)  # bytes: the document, a question behind it
 LONG_PROBE = 6000  # bytes: 46 wraps of a 128-token ring, 94 pages deep
 #   (one wrap of a 4,096-token ring, in the admission)
 GOLDEN_FROM_REFERENCE = 0.025  # half of benchmark/run.py GOLDEN_TOL
@@ -256,6 +277,147 @@ def reference_logits(params, cfg, tokens, int4=False, **changed):
         lazy, ref_cfg, toks, experts_held=held, query_block=query_block)
 
 
+def dense_reference_logits(params, cfg, tokens):
+    """A plain forward of the llama family (qwen2: biases on q, k and v) in
+    float32 under the caller's matmul precision, no kernel, no cache, no
+    scan: RMS norms, rotate-half RoPE over the whole head, grouped-query
+    causal softmax of q k^T / sqrt(head_dim), SwiGLU, an untied head.  One
+    layer's weights are dequantized at a time.  -> [T, V]."""
+    from distributed_llms_tpu.checkpoint import quantize as quant_lib
+
+    is_q = lambda x: isinstance(x, quant_lib.QuantizedTensor)
+    floats = lambda tree: jax.tree.map(
+        lambda x: (quant_lib.dequantize(x, jnp.float32) if is_q(x)
+                   else jnp.asarray(x, jnp.float32)), tree, is_leaf=is_q)
+    rms = lambda x, w: w * x * jax.lax.rsqrt(
+        jnp.mean(x * x, axis=-1, keepdims=True) + cfg.norm_eps)
+    toks = jnp.asarray(tokens, jnp.int32)
+    t, hd, g = len(toks), cfg.head_dim_, cfg.num_heads // cfg.num_kv_heads
+    inv = 1.0 / cfg.rope_theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+
+    def rope(x):  # [T, H, hd]
+        x1, x2 = x[..., : hd // 2], x[..., hd // 2:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    x = jnp.asarray(params["embed"]["wte"], jnp.float32)[toks]
+    for layer in range(cfg.num_layers):
+        p = floats(jax.tree.map(lambda a: a[layer], params["blocks"],
+                                is_leaf=lambda a: False))
+        a, h = p["attn"], rms(x, p["ln1"]["scale"])
+        q, k, v = (jnp.einsum("td,dhk->thk", h, a[w]) + a.get(b, 0.0)
+                   for w, b in (("wq", "bq"), ("wk", "bk"), ("wv", "bv")))
+        q, k = rope(q), jnp.repeat(rope(k), g, axis=1)
+        s = jnp.einsum("thk,shk->hts", q, k) * hd ** -0.5
+        pr = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
+        o = jnp.einsum("hts,shk->thk", pr, jnp.repeat(v, g, axis=1))
+        x = x + jnp.einsum("thk,hkd->td", o, a["wo"])
+        m, h = p["mlp"], rms(x, p["ln2"]["scale"])
+        x = x + (jax.nn.silu(h @ m["w_gate"]) * (h @ m["w_up"])) @ m["w_down"]
+    head = (params["embed"]["wte"].T if cfg.tie_embeddings
+            else params["lm_head"]["w"])
+    return rms(x, jnp.asarray(params["final_norm"]["scale"], jnp.float32)) \
+        @ jnp.asarray(head, jnp.float32)
+
+
+def prefix_cache_probes(a, config) -> int:
+    """A dense configuration's probes with the prefix cache ON (PR 47): a
+    document of ``PREFIX_PROBE[0]`` bytes with a question of
+    ``PREFIX_PROBE[1]`` served cold; a second question on the same document,
+    which HITS the first one's pages and is admitted as a row's continuation
+    (``admit_row_auto_paged``; on the chip the flash kernel scores it); and
+    the same second prompt with the cache off.  The hit's 8 chosen-token
+    logprobs against the cold-served ones of the same prompt, and both
+    against the plain float32 reference's logprobs of the same tokens."""
+    from distributed_llms_tpu.core.observability import METRICS
+    from distributed_llms_tpu.models import model as model_lib
+    from distributed_llms_tpu.models.presets import get_preset
+    from distributed_llms_tpu.runtime import batcher as B
+    from distributed_llms_tpu.runtime.tokenizer import get_tokenizer
+
+    serve, preset = dict(config["serve"]), config["preset"]
+    doc_bytes, q_bytes = PREFIX_PROBE
+    if a.rehearsal:
+        preset, (doc_bytes, q_bytes) = "llama-tiny", (70, 9)
+        serve.update(slots=4, max_len=128, page_size=8, paged_pages=40)
+    cfg = get_preset(preset)
+    tok = get_tokenizer(None)
+    if cfg.vocab_size < tok.vocab_size:
+        cfg = dataclasses.replace(cfg, vocab_size=512)
+    params = model_lib.init_params_quantized(jax.random.key(0), cfg, 8)
+    jax.block_until_ready(params)
+    dev = jax.devices()[0]
+    print(f"weights on {dev.device_kind}", flush=True)
+    doc = probe_prompt(doc_bytes)
+    # (two questions that differ from their first byte on)
+    asks = [probe_prompt(doc_bytes + q_bytes + 7 * i)[-q_bytes:][::-1 if i else 1]
+            for i in range(2)]
+    batcher = B.ContinuousBatcher(
+        cfg, params, tok, batch_slots=serve["slots"],
+        max_len=serve["max_len"], chunk_steps=serve["chunk_steps"],
+        paged_pages=serve["paged_pages"], page_size=serve["page_size"],
+        prefix_cache=True)
+
+    def send(text, **kw):
+        ids = list(tok.encode(text))
+        rid = batcher.submit(ids, max_new_tokens=PROBE_TOKENS, **kw)
+        toks = list(batcher.run()[rid])
+        return {"prompt_tokens": len(ids), "tokens": toks, "ids": ids,
+                "logprobs": [float(x) for x in batcher.result_logprobs[rid]],
+                "cached_tokens": batcher.prefix_cached_tokens.get(rid, 0)}
+
+    cold = send(doc + asks[0])
+    hit = send(doc + asks[1])
+    fresh = send(doc + asks[1], prefix_cache=False)
+    tol = PREFIX_TOLS.get(a.config, PREFIX_TOLS["qwen2-7b-int8"])
+    page = serve["page_size"]
+    whole = (len(tok.encode(doc)) // page) * page
+    report = {"config": a.config, "preset": preset, "tolerances": tol,
+              "device_kind": dev.device_kind, "tree": ROOT,
+              "cold": cold, "hit": hit, "fresh": fresh}
+    ok = cold["cached_tokens"] == 0 and fresh["cached_tokens"] == 0 \
+        and hit["cached_tokens"] >= whole - page
+    report["hit_against_fresh"] = max(
+        abs(x - y) for x, y in zip(hit["logprobs"], fresh["logprobs"]))
+    report["tokens_equal"] = hit["tokens"] == fresh["tokens"]
+    ok &= report["hit_against_fresh"] <= tol["hit_against_fresh"]
+    with jax.default_matmul_precision("highest"):
+        for rec in (hit, fresh) if hit["tokens"] != fresh["tokens"] else (hit,):
+            t1 = time.time()
+            seq = rec["ids"] + rec["tokens"][:-1]
+            ref = np.asarray(dense_reference_logits(params, cfg, seq),
+                             np.float32)[len(rec["ids"]) - 1:]
+            rec["reference_logprobs"] = [
+                float(jax.nn.log_softmax(jnp.asarray(r))[t])
+                for r, t in zip(ref, rec["tokens"])]
+            rec["reference_seconds"] = time.time() - t1
+    fresh.setdefault("reference_logprobs", hit["reference_logprobs"])
+    for rec in (hit, fresh):
+        rec.pop("ids")
+        rec["first_logprob_diff"] = abs(
+            rec["logprobs"][0] - rec["reference_logprobs"][0])
+        ok &= rec["first_logprob_diff"] <= tol["against_reference"]
+    cold.pop("ids")
+    took = {k[len("ops.dispatch."):]: int(v)
+            for k, v in METRICS.snapshot()["counters"].items()
+            if k.startswith("ops.dispatch.flash")}
+    report["dispatch"], report["ok"] = took, bool(ok)
+    print(json.dumps({k: report[k] for k in (
+        "hit_against_fresh", "tokens_equal", "dispatch", "ok")}
+        | {"cached_tokens": hit["cached_tokens"],
+           "hit_first": hit["first_logprob_diff"],
+           "fresh_first": fresh["first_logprob_diff"]}), flush=True)
+    out = os.path.join(HERE, "chiprun_out", "reference_check")
+    os.makedirs(out, exist_ok=True)
+    name = a.config + (".prefix.json" if ROOT == HERE else ".prefix.tree.json")
+    with open(os.path.join(out, name), "w") as f:
+        json.dump(report, f, indent=1)
+    print("reference_check:", "PASS" if ok else "FAIL", flush=True)
+    return 0 if ok else 1
+
+
 def served_programs(cfg, cfg_decode):
     from distributed_llms_tpu.models import kv_cache, model as model_lib
     from distributed_llms_tpu.runtime import batcher as B
@@ -288,6 +450,9 @@ def main() -> int:
     ap.add_argument("--rehearsal", action="store_true",
                     help="lfm2-tiny on the CPU at a tiny shape: the same "
                          "code, no number that means anything")
+    ap.add_argument("--tree", default=None,
+                    help="serve another checkout's programs (a parent "
+                         "under _chip/); read before the imports")
     ap.add_argument("--slot", type=int, default=3)
     ap.add_argument("--probes", type=int, default=None,
                     help="how many of the probes to run, from the first "
@@ -305,6 +470,8 @@ def main() -> int:
     with open(os.path.join(ROOT, "benchmark", "configs",
                            a.config + ".json")) as f:
         config = json.load(f)
+    if a.config in PREFIX_TOLS:  # a dense model: the prefix cache's probes
+        return prefix_cache_probes(a, config)
     serve = dict(config["serve"])
     preset, probe_bytes = config["preset"], PROBE_BYTES
     TOL = TOLS[a.config]
