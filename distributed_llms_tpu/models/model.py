@@ -31,6 +31,91 @@ from .layers import Params
 
 
 # ---------------------------------------------------------------------------
+# The call
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True, eq=False)  # (fields are arrays: identity)
+class Call:
+    """What one call of :func:`forward` is, the same for every layer of it:
+    what the caller handed over and what follows from that, worked out once
+    (:func:`call_of`) and nowhere else.  No pytree: the layer scans' bodies
+    close over it.  ``kind`` is a Python value while tracing, one of
+
+    - "plain": no cache (training, evaluation, a reference's comparison);
+    - "start": a fresh row's cache to fill: no mask and no map of the
+      caller's own, and a write offset KNOWN WHILE TRACING to be 0
+      (runtime.batcher._prefill_row passes the Python 0; ``jnp.int32(0)``
+      under ``jit`` is a tracer and no start).  The T new tokens can see
+      nothing but each other, whatever the cache's length;
+    - "continuation": a cache, a scalar offset that is no start and no mask
+      of the caller's: every row holds its keys in slots
+      [0, cache_index + T) (a suffix behind a cached prefix, a chunk of a
+      chunked prefill, a one-shot prefill into a longer cache);
+    - "masked": a cache, a scalar offset and the caller's ``attn_mask``
+      (right-padded generate, sessions);
+    - "decode": a cache and one write slot a row (``cache_index`` [B]):
+      against the page pool where ``kv_tables`` is given, else a contiguous
+      cache under the caller's mask.  The speculative window's T > 1 is
+      read off the input."""
+
+    positions: jax.Array  # [B, T] int32
+    cache_index: Any  # None, a scalar write offset, or [B] per-row offsets
+    attn_mask: Any  # None or broadcastable to [B, H, Tq, S]; True = attend
+    key_positions: Any  # None or [B, S]: the true RoPE position of each
+    #   cache slot, consulted by the sliding-window mask ALONE.  Contiguous
+    #   layouts (slot == position: the continuous batcher) leave it None;
+    #   gapped ones MUST give it or the window silently widens by the pad on
+    #   generated keys: right-padded generate/speculative (prompt slots
+    #   0..T-1, generated token j at slot T+j but position len+j) and
+    #   multi-turn sessions (Session.slot_positions)
+    kv_tables: Any  # None or [B, P] int32 page table: the cache is the PAGE
+    #   POOL, every layer's pages in one stack (:func:`_paged_attention`),
+    #   row b's slot s at (layer, tables[b, s // BLK], s % BLK); "decode"
+    #   only, the mask implicitly the prefix [0, cache_index[b]]
+    seq_lens: Any  # None or [B] int32: the real tokens of each row's T
+    cached: bool  # whether a cache was given
+    kind: str
+    std_layout: bool  # positions are the standard arange forward made
+    #   itself: unlocks the flash kernel's static-causal fast path
+    rows: Any  # None or [1] int32: how many leading rows of the [B * T, K]
+    #   activations are real, where that is known: ONE right-padded
+    #   sequence (an admission) whose ``seq_lens`` the caller gave.  A
+    #   batch's real rows are no run from the top
+    token_mask: Any  # None or [B, T] bool: arange(T) < seq_lens
+
+
+def call_of(shape: tuple[int, int], positions=None, cache_index=None,
+            attn_mask=None, key_positions=None, kv_tables=None,
+            seq_lens=None, cached: bool = False) -> Call:
+    """The :class:`Call` of a [B, T] = ``shape`` input: the one place the
+    kind of a call and what else follows from its facts are decided."""
+    b, t = shape
+    std_layout = positions is None and (cache_index is None or not cached)
+    if positions is None:
+        base = cache_index if cache_index is not None else 0
+        positions = jnp.broadcast_to(
+            jnp.arange(t, dtype=jnp.int32) + base, (b, t))
+    if not cached:
+        kind = "plain"
+    elif getattr(cache_index, "ndim", 0) == 1:
+        kind = "decode"
+    elif (attn_mask is None and key_positions is None
+          and not isinstance(cache_index, jax.core.Tracer)
+          and int(cache_index) == 0):
+        kind = "start"
+    else:
+        kind = "continuation" if attn_mask is None else "masked"
+    rows = token_mask = None
+    if seq_lens is not None:
+        token_mask = (jnp.arange(t, dtype=jnp.int32)[None, :]
+                      < seq_lens[:, None])
+        if b == 1:
+            rows = seq_lens.astype(jnp.int32).reshape(1)
+    return Call(positions, cache_index, attn_mask, key_positions, kv_tables,
+                seq_lens, cached, kind, std_layout, rows, token_mask)
+
+
+# ---------------------------------------------------------------------------
 # Blocks
 # ---------------------------------------------------------------------------
 
@@ -82,37 +167,15 @@ def _attention(
     x: jax.Array,
     p: Params,
     cfg: ModelConfig,
-    positions: jax.Array,
-    layer_cache: tuple[jax.Array, jax.Array] | None,
-    cache_index: jax.Array | None,
     use_rope: bool,
-    attn_mask: jax.Array | None = None,  # broadcastable to [B, H, Tq, S]
-    std_layout: bool = False,  # positions are the standard arange (forward
-    #                            generated them itself) — unlocks the flash
-    #                            kernel's static-causal fast path
-    kv_tables: jax.Array | None = None,  # [B, P] int32 page table:
-    #                            layer_cache is the whole PAGE POOL, the
-    #                            stack [L, NB, BLK, KVH, HD] of every layer
-    #                            (see _paged_attention), and row b's slot s
-    #                            lives at (layer, tables[b, s//BLK], s%BLK).
-    #                            Decode-only (per-row cache_index); the
-    #                            mask is implicitly the prefix
-    #                            [0, cache_index[b]].
-    key_positions: jax.Array | None = None,  # [B, S] true RoPE position of
-    #                            each cache slot — ONLY consulted by the
-    #                            sliding-window mask.  Contiguous layouts
-    #                            (slot == position: the continuous batcher)
-    #                            leave it None; gapped layouts MUST pass it
-    #                            or the window silently widens by the pad
-    #                            amount on generated keys.  Gapped = the
-    #                            right-padded generate/speculative layout
-    #                            (prompt slots 0..T-1, generated token j at
-    #                            slot T+j but position len+j) AND multi-turn
-    #                            sessions (session_step carries the map as
-    #                            Session.slot_positions state).
+    call: Call,
+    layer_cache: Any,  # None; this layer's (k, v) rows [B, S, KVH, HD]; or
+    #   with call.kv_tables the whole page pool
     layer: jax.Array | None = None,  # this layer's index into the pool
-    #                            stack (with kv_tables only)
+    #   stack (with call.kv_tables only)
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array] | None]:
+    positions, cache_index, kind = call.positions, call.cache_index, call.kind
+    attn_mask, key_positions = call.attn_mask, call.key_positions
     q, k, v = layers.qkv_project(x, p, cfg)
     if cfg.qk_norm:  # per head, over the head dim, before the rotation
         q = layers.rms_norm(q, p["q_norm"], cfg.norm_eps)
@@ -140,8 +203,8 @@ def _attention(
             q = layers.apply_rope(q, positions, cfg.rope_theta, rope_scale)
             k = layers.apply_rope(k, positions, cfg.rope_theta, rope_scale)
 
-    if kv_tables is not None:
-        if layer_cache is None or getattr(cache_index, "ndim", 0) != 1:
+    if call.kv_tables is not None:
+        if kind != "decode":
             raise ValueError(
                 "paged attention is per-row decode (a per-row cache_index "
                 "over a page-pool cache)"
@@ -152,15 +215,90 @@ def _attention(
                 "cannot honor sliding_window"
             )
         return _paged_attention(
-            q, k, v, p, layer_cache, layer, cache_index, kv_tables
+            q, k, v, p, layer_cache, layer, cache_index, call.kv_tables
         )
+    if kind == "plain":
+        return _plain_attention(q, k, v, p, cfg, call), None
+    if cfg.attn_impl in ("ring", "ulysses"):
+        # Sequence-parallel cached generation (SURVEY §5.7): the KV cache is
+        # split into a seq-sharded prefill region and a small replicated
+        # decode region (parallel.api builds it; see ParallelModel.init_cache).
+        return _seq_cached_attention(q, k, v, p, cfg, call, layer_cache)
 
-    if (
-        cfg.attn_impl == "flash"
-        and attn_mask is None
-        and layer_cache is None
-    ):
-        # Self-attention over the input block (training / no-cache eval).
+    ck, cv = layer_cache  # [B, S, KVH, HD]
+    if kind == "start":
+        # An admission's fresh row: the T tokens attend among themselves
+        # and take the row cache's first T slots; no slot past T is read,
+        # repeated to the query heads or scored.
+        t = x.shape[1]
+        out = _self_attention(q, k, v, positions, cfg.model_window)
+        return layers.out_project(out, p), (
+            ck.at[:, :t].set(k.astype(ck.dtype)),
+            cv.at[:, :t].set(v.astype(cv.dtype)))
+    if kind == "decode":
+        # Per-ROW write slots (continuous batching: rows admitted at
+        # different times sit at different depths).  Only the KV write
+        # scatters; everything else stays batched.  Callers must supply
+        # attn_mask: nothing below derives one from B frontiers.
+        if attn_mask is None:
+            raise ValueError(
+                "per-row cache_index requires an explicit attn_mask"
+            )
+        row_upd = jax.vmap(
+            lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (i, 0, 0))
+        )
+        ck = row_upd(ck, k.astype(ck.dtype), cache_index)
+        cv = row_upd(cv, v.astype(cv.dtype), cache_index)
+        if cfg.ragged_decode and x.shape[1] == 1:
+            # Ragged read: row b touches only [0, cache_index[b]] of the
+            # cache (lengths = cache_index + 1 includes the slot just
+            # written above).  cfg.ragged_decode is the caller's promise
+            # that attn_mask IS that prefix mask (core/config.py).
+            # Sliding-window models pass the window through: the kernel
+            # reads only [length - window, length) per row — exact
+            # because the ragged contract layout is slot == position.
+            from ..ops import decode_attn
+
+            # ck/cv go in at the CACHE's dtype — the kernel casts per
+            # block in VMEM, so a kv_dtype != compute dtype never costs
+            # a full-width HBM copy of the cache.
+            out = decode_attn.ragged_decode_attention(
+                q, ck, cv, cache_index + 1, window=cfg.model_window,
+            )
+            return layers.out_project(out, p), (ck, cv)
+    else:
+        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, cache_index, 0, 0))
+        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, cache_index, 0, 0))
+    if kind == "continuation":
+        out = _continuation_attention(
+            q, ck, cv, positions, cache_index, cfg.model_window,
+            key_positions)
+        return layers.out_project(out, p), (ck, cv)
+    # "masked", and a decode step the ragged kernel does not take.
+    if cfg.model_window is not None:
+        # Caller-supplied masks (continuous batching's per-row prefix
+        # masks, padded prefill) carry causality/validity but not the
+        # window — AND it in here so no dense cached path can silently
+        # attend past the window.
+        if key_positions is None:
+            s = ck.shape[1]
+            key_positions = jnp.broadcast_to(
+                jnp.arange(s, dtype=jnp.int32), (x.shape[0], s)
+            )
+        attn_mask = layers.and_window(
+            attn_mask, positions, key_positions, cfg.model_window
+        )
+    k_full = layers.repeat_kv(ck.astype(q.dtype), cfg.q_per_kv)
+    v_full = layers.repeat_kv(cv.astype(q.dtype), cfg.q_per_kv)
+    out = layers.dot_product_attention(q, k_full, v_full, attn_mask)
+    return layers.out_project(out, p), (ck, cv)
+
+
+def _plain_attention(q, k, v, p, cfg: ModelConfig, call: Call) -> jax.Array:
+    """Attention of a call without a cache, the input block over itself
+    (q, k, v rotated; -> the projected output)."""
+    positions, attn_mask = call.positions, call.attn_mask
+    if cfg.attn_impl == "flash" and attn_mask is None:
         # Sliding-window models ride the kernel's window band (positions
         # space, layers.and_window semantics): out-of-window tiles are
         # skipped without even a DMA, so windowed prefill work scales with
@@ -169,21 +307,11 @@ def _attention(
 
         out = flash.flash_attention(
             q, k, v,
-            q_positions=None if std_layout else positions,
-            k_positions=None if std_layout else positions,
+            q_positions=None if call.std_layout else positions,
+            k_positions=None if call.std_layout else positions,
             causal=True, window=cfg.model_window,
         )
-        return layers.out_project(out, p), None
-
-    if cfg.attn_impl in ("ring", "ulysses") and layer_cache is not None:
-        # Sequence-parallel cached generation (SURVEY §5.7): the KV cache is
-        # split into a seq-sharded prefill region and a small replicated
-        # decode region (parallel.api builds it; see ParallelModel.init_cache).
-        return _seq_cached_attention(
-            q, k, v, p, cfg, positions, layer_cache, cache_index, attn_mask
-        )
-
-    if cfg.attn_impl in ("ring", "ulysses") and layer_cache is None:
+    elif cfg.attn_impl in ("ring", "ulysses"):
         # Sequence-parallel paths: we are inside a shard_map over the 'seq'
         # mesh axis (ParallelModel handles the wrapping); positions carry
         # *global* indices so causality holds across blocks.
@@ -199,82 +327,6 @@ def _attention(
             from ..ops import ulysses
 
             out = ulysses.ulysses_attention(q, k, v, positions, axis_name="seq")
-        return layers.out_project(out, p), None
-
-    if layer_cache is not None and _row_start(
-            cache_index, attn_mask, key_positions):
-        # An admission's fresh row: the T tokens attend among themselves
-        # and take the row cache's first T slots; no slot past T is read,
-        # repeated to the query heads or scored.
-        ck, cv = layer_cache  # [B, S, KVH, HD]
-        t = x.shape[1]
-        out = _self_attention(q, k, v, positions, cfg.model_window)
-        return layers.out_project(out, p), (
-            ck.at[:, :t].set(k.astype(ck.dtype)),
-            cv.at[:, :t].set(v.astype(cv.dtype)))
-
-    if layer_cache is not None:
-        ck, cv = layer_cache  # [B, S, KVH, HD]
-        if getattr(cache_index, "ndim", 0) == 1:
-            # Per-ROW write slots (continuous batching: rows admitted at
-            # different times sit at different depths).  Only the KV write
-            # scatters; everything else stays batched.  Callers must supply
-            # attn_mask — the shared k_valid derivation below assumes one
-            # scalar frontier.
-            if attn_mask is None:
-                raise ValueError(
-                    "per-row cache_index requires an explicit attn_mask"
-                )
-            row_upd = jax.vmap(
-                lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (i, 0, 0))
-            )
-            ck = row_upd(ck, k.astype(ck.dtype), cache_index)
-            cv = row_upd(cv, v.astype(cv.dtype), cache_index)
-            if cfg.ragged_decode and x.shape[1] == 1:
-                # Ragged read: row b touches only [0, cache_index[b]] of the
-                # cache (lengths = cache_index + 1 includes the slot just
-                # written above).  cfg.ragged_decode is the caller's promise
-                # that attn_mask IS that prefix mask (core/config.py).
-                # Sliding-window models pass the window through: the kernel
-                # reads only [length - window, length) per row — exact
-                # because the ragged contract layout is slot == position.
-                from ..ops import decode_attn
-
-                # ck/cv go in at the CACHE's dtype — the kernel casts per
-                # block in VMEM, so a kv_dtype != compute dtype never costs
-                # a full-width HBM copy of the cache.
-                out = decode_attn.ragged_decode_attention(
-                    q, ck, cv, cache_index + 1, window=cfg.model_window,
-                )
-                return layers.out_project(out, p), (ck, cv)
-        else:
-            ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, cache_index, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, cache_index, 0, 0))
-        if attn_mask is None:
-            # A scalar write offset and no mask of the caller's own: the
-            # row's continuation (or a one-shot prefill into a longer,
-            # padded cache).
-            out = _continuation_attention(
-                q, ck, cv, positions, cache_index, cfg.model_window,
-                key_positions)
-            return layers.out_project(out, p), (ck, cv)
-        if cfg.model_window is not None:
-            # Caller-supplied masks (continuous batching's per-row prefix
-            # masks, padded prefill) carry causality/validity but not the
-            # window — AND it in here so no dense cached path can silently
-            # attend past the window.
-            if key_positions is None:
-                s = ck.shape[1]
-                key_positions = jnp.broadcast_to(
-                    jnp.arange(s, dtype=jnp.int32), (x.shape[0], s)
-                )
-            attn_mask = layers.and_window(
-                attn_mask, positions, key_positions, cfg.model_window
-            )
-        k_full = layers.repeat_kv(ck.astype(q.dtype), cfg.q_per_kv)
-        v_full = layers.repeat_kv(cv.astype(q.dtype), cfg.q_per_kv)
-        out = layers.dot_product_attention(q, k_full, v_full, attn_mask)
-        new_cache = (ck, cv)
     else:
         if attn_mask is None:
             mask = layers.causal_mask(positions, positions, window=cfg.model_window)
@@ -287,8 +339,7 @@ def _attention(
         k_full = layers.repeat_kv(k, cfg.q_per_kv)
         v_full = layers.repeat_kv(v, cfg.q_per_kv)
         out = layers.dot_product_attention(q, k_full, v_full, mask)
-        new_cache = None
-    return layers.out_project(out, p), new_cache
+    return layers.out_project(out, p)
 
 
 def _seq_cached_attention(
@@ -297,10 +348,8 @@ def _seq_cached_attention(
     v: jax.Array,
     p: Params,
     cfg: ModelConfig,
-    positions: jax.Array,
+    call: Call,
     layer_cache: tuple,  # ((ck_pref, ck_dec), (cv_pref, cv_dec))
-    cache_index: jax.Array,
-    attn_mask,
 ) -> tuple[jax.Array, tuple]:
     """Cached attention under sequence parallelism — runs inside a shard_map
     over the 'seq' axis (parallel.api wraps it).
@@ -318,6 +367,7 @@ def _seq_cached_attention(
     seq_cached_decode_attention)."""
     from ..ops import ring
 
+    positions, attn_mask = call.positions, call.attn_mask
     (ck_pref, ck_dec), (cv_pref, cv_dec) = layer_cache
     tq = q.shape[1]
     if tq > 1:
@@ -349,7 +399,7 @@ def _seq_cached_attention(
             "decode_mask) — ParallelModel.forward splits the global mask"
         )
     t_pref_global = ck_pref.shape[1] * jax.lax.axis_size("seq")
-    di = cache_index - t_pref_global
+    di = call.cache_index - t_pref_global
     ck_dec = jax.lax.dynamic_update_slice(ck_dec, k.astype(ck_dec.dtype), (0, di, 0, 0))
     cv_dec = jax.lax.dynamic_update_slice(cv_dec, v.astype(cv_dec.dtype), (0, di, 0, 0))
     m_pref, m_dec = attn_mask
@@ -415,12 +465,9 @@ def mla_attention(
     x: jax.Array,  # [B, T, D], normed
     p: Params,  # wq_a, q_norm, wq_b, wkv_a, kv_norm, wkv_b, wo
     cfg: ModelConfig,
-    positions: jax.Array,
+    call: Call,
     layer_cache: Any,  # None; this layer's latent rows [B, S, W]; or with
-    #   kv_tables the whole latent page pool (kv_cache.LatentCache)
-    cache_index: jax.Array | None,
-    attn_mask: jax.Array | None = None,
-    kv_tables: jax.Array | None = None,
+    #   call.kv_tables the whole latent page pool (kv_cache.LatentCache)
     layer: jax.Array | None = None,
 ) -> tuple[jax.Array, Any]:
     """Multi-head latent attention.  ``c_q = rms(x W_qa)``, a head's query
@@ -435,12 +482,19 @@ def mla_attention(
     (ops.decode_attn.mla_paged_decode_attention), so a page is read once
     and no head's key or value is ever formed.  Every other call EXPANDS
     keys and values from the rows and attends as the other families'
-    admissions do: a fresh row (:func:`_row_start`) its own T rows, among
-    themselves (:func:`_self_attention`); a suffix behind a prefix hit the
-    row cache's every slot, the cached run's too, densely.  All read the
-    same stored rows and the same W_kvb."""
+    admissions do: a fresh row ("start") its own T rows, among themselves
+    (:func:`_self_attention`); a suffix behind a prefix hit the row cache's
+    every slot, the cached run's too, densely.  All read the same stored
+    rows and the same W_kvb."""
     from ..ops import decode_attn
 
+    positions, cache_index, kind = call.positions, call.cache_index, call.kind
+    attn_mask, kv_tables = call.attn_mask, call.kv_tables
+    if call.key_positions is not None:
+        raise ValueError(
+            "latent attention has no window: a map of the slots' positions "
+            "(key_positions) has nothing to say to it"
+        )
     b, t, _ = x.shape
     h, r = cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -467,7 +521,7 @@ def mla_attention(
             o.reshape(b, t, h * dv), p["wo"], "btn,nd->btd", 1, "k")
 
     if kv_tables is not None:
-        if layer_cache is None or getattr(cache_index, "ndim", 0) != 1:
+        if kind != "decode":
             raise ValueError(
                 "paged attention is per-row decode (a per-row cache_index "
                 "over a page-pool cache)"
@@ -504,7 +558,7 @@ def mla_attention(
         ], axis=-1), kv[..., dn:]
 
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    if layer_cache is not None and _row_start(cache_index, attn_mask):
+    if kind == "start":
         # An admission's fresh row: W_kvb expands the T new rows only, they
         # attend among themselves and take the row cache's first T slots.
         ck = row.astype(layer_cache.dtype)
@@ -512,13 +566,13 @@ def mla_attention(
             q, *expand(ck.astype(x.dtype)), positions, scale=scale)
         return project(out), layer_cache.at[:, :t].set(ck)
 
-    if layer_cache is None:
+    if kind == "plain":
         keys, new_cache = row, None
         mask = (layers.causal_mask(positions, positions)
                 if attn_mask is None else attn_mask)
     else:
         ck = layer_cache  # [B, S, W]
-        if getattr(cache_index, "ndim", 0) == 1:
+        if kind == "decode":
             if attn_mask is None:
                 raise ValueError(
                     "per-row cache_index requires an explicit attn_mask")
@@ -529,7 +583,7 @@ def mla_attention(
             ck = jax.lax.dynamic_update_slice(
                 ck, row.astype(ck.dtype), (0, cache_index, 0))
         new_cache, keys, mask = ck, ck.astype(x.dtype), attn_mask
-        if mask is None:
+        if kind == "continuation":
             s = keys.shape[1]
             k_pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
             mask = layers.causal_mask(positions, k_pos,
@@ -541,19 +595,6 @@ def mla_attention(
 _TOKEN_BLOCK = 2048  # tokens a long admission's FFNs take at a time
 
 
-def _row_start(cache_index, attn_mask, key_positions=None) -> bool:
-    """Whether a call with a cache to fill holds a row's START: the write
-    offset is known while tracing and is 0 (runtime.batcher._prefill_row
-    passes the Python 0), and the caller brings no mask and no map of the
-    slots' positions of its own.  Then the T new tokens can see nothing
-    but each other, whatever the cache's length."""
-    return (
-        attn_mask is None and key_positions is None
-        and not isinstance(cache_index, jax.core.Tracer)
-        and getattr(cache_index, "ndim", 0) == 0 and int(cache_index) == 0
-    )
-
-
 _LANES = 128  # a register's lanes: the flash kernel's tiles are whole ones
 
 
@@ -563,8 +604,8 @@ def _self_attention(q, k, v, positions, window: int | None = None,
     """Causal attention of T tokens over themselves, a row's start: the one
     place an admission's fresh row is scored (q [B, T, H, hd], k [B, T,
     KVH, hd], v [B, T, KVH, hv]; ``positions`` [B, T] rise by one along the
-    block; ``scale`` None: hd ** -0.5; ``rows`` what :func:`real_rows`
-    returns: the kernel ends its grid at the last tile of queries that
+    block; ``scale`` None: hd ** -0.5; ``rows`` what ``Call.rows``
+    holds: the kernel ends its grid at the last tile of queries that
     holds a real token and leaves the tiles past it as zeros, the real
     tokens' outputs bit for bit what they are without it; the dense bodies
     score the bucket).  The body is chosen by what the call can see:
@@ -678,9 +719,9 @@ def _flash_mode(head_dim: int) -> str | None:
     legs), "fallback" (the dense body, asked for by ``DLT_RAGGED_DECODE``),
     None (the dense body, chosen by the mesh or by heads that fill a
     register in part)."""
-    from ..ops import decode_attn, dispatch
+    from ..ops import dispatch
 
-    mode = decode_attn._mode()
+    mode = dispatch.attention_mode()
     if mode == "fallback":
         return mode
     # (the interpreter, the tests' leg of the kernel's program, has no lanes)
@@ -747,14 +788,11 @@ def mixed_attention(
     x: jax.Array,  # [B, T, D], normed
     p: Params,  # wq, wk, wv, wo (+ q_norm, k_norm)
     cfg: ModelConfig,
-    positions: jax.Array,
+    op: str,  # "attn": the whole prefix; "swa": the last sliding_window
+    call: Call,
     cache: kv_cache.HybridCache | None,  # the whole cache: the page pool
-    #   (``kv_tables``) or a fresh row's contiguous cache, and the rings
-    cache_index: jax.Array | None,
-    kind: str,  # "attn": the whole prefix; "swa": the last sliding_window
+    #   (``call.kv_tables``) or a fresh row's contiguous cache, and the rings
     layer: jax.Array | int,  # index among the layers of its kind
-    kv_tables: jax.Array | None = None,
-    seq_lens: jax.Array | None = None,
 ) -> tuple[jax.Array, kv_cache.HybridCache | None]:
     """GQA attention of a model whose layers mix full and windowed
     attention (``cfg.swa_layers``: K-EXAONE's pattern of three windowed
@@ -762,55 +800,57 @@ def mixed_attention(
     (``cfg.qk_norm``); a windowed layer rotates them, a full one only if
     ``cfg.attn_rope``; query head g reads key/value head g // q_per_kv.
 
-    A decode step (per-row ``cache_index``, one token a row): a full layer
-    writes and reads its pages (:func:`_paged_attention`); a windowed one
-    writes the new key and value into the row's ring at position mod W and
-    attends to the ring's min(length, W) valid entries
+    A decode step ("decode", one token a row): a full layer writes and
+    reads its pages (:func:`_paged_attention`); a windowed one writes the
+    new key and value into the row's ring at position mod W and attends to
+    the ring's min(length, W) valid entries
     (ops.decode_attn.swa_decode_attention), so it reads at most W tokens a
     row however long the row is.  Otherwise the T tokens are a row's start
-    (no cache, or a fresh row's cache at offset 0: an admission) and
-    attend among themselves (:func:`_self_attention`, a windowed layer
-    through its band); a full layer leaves its keys and values in the
-    row cache's first T slots, a windowed one leaves in the ring the last
-    min(n, W) tokens of the ``seq_lens`` REAL ones (None: all T), at the
-    prompt's true length and not at the padded bucket's end."""
+    ("plain", or "start": an admission) and attend among themselves
+    (:func:`_self_attention`, a windowed layer through its band); a full
+    layer leaves its keys and values in the row cache's first T slots, a
+    windowed one leaves in the ring the last min(n, W) tokens of the
+    ``call.seq_lens`` REAL ones (None: all T), at the prompt's true length
+    and not at the padded bucket's end."""
     from ..ops import decode_attn
 
-    w = cfg.sliding_window if kind == "swa" else None
+    positions, cache_index, seq_lens = (
+        call.positions, call.cache_index, call.seq_lens)
+    w = cfg.sliding_window if op == "swa" else None
     q, k, v = layers.qkv_project(x, p, cfg)
     if cfg.qk_norm:
         q = layers.rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = layers.rms_norm(k, p["k_norm"], cfg.norm_eps)
-    if kind == "swa" or cfg.attn_rope:
+    if op == "swa" or cfg.attn_rope:
         q = layers.apply_rope(q, positions, cfg.rope_theta)
         k = layers.apply_rope(k, positions, cfg.rope_theta)
     b, t = x.shape[:2]
-    if cache is not None and getattr(cache_index, "ndim", 0) == 1:
-        if t != 1 or (kind == "attn" and kv_tables is None):
+    if call.kind == "decode":
+        if t != 1 or (op == "attn" and call.kv_tables is None):
             raise ValueError(
                 "a model of windowed and full attention layers decodes one "
                 "token a row against the page pool and the rings"
             )
-        if kind == "attn":
+        if op == "attn":
             return _paged_attention(
-                q, k, v, p, cache, layer, cache_index, kv_tables)
+                q, k, v, p, cache, layer, cache_index, call.kv_tables)
         cache = kv_cache.write_ring(
             cache, layer, cache_index % w, k[:, 0], v[:, 0])
         out = decode_attn.swa_decode_attention(
             q, cache.ring_k, cache.ring_v, jnp.minimum(cache_index + 1, w),
             layer)
         return layers.out_project(out, p), cache
-    if cache is not None and not _row_start(cache_index, None):
+    if call.kind in ("continuation", "masked"):
         raise ValueError(
             "a model of windowed and full attention layers prefills a row "
-            "from its start (cache_index 0): the rings hold no prefix to "
-            "continue from"
+            "from its start (cache_index 0, no mask and no map of the "
+            "caller's): the rings hold no prefix to continue from"
         )
     out = layers.out_project(
-        _self_attention(q, k, v, positions, w, rows=real_rows(seq_lens, b)), p)
+        _self_attention(q, k, v, positions, w, rows=call.rows), p)
     if cache is None:
         return out, None
-    if kind == "attn":
+    if op == "attn":
         return out, dataclasses.replace(
             cache,
             k=cache.k.at[layer, :, :t].set(k.astype(cache.k.dtype)),
@@ -830,22 +870,22 @@ def mixed_attention(
             jnp.take_along_axis(v, take, axis=1).astype(cache.ring_v.dtype)))
 
 
-def gpt2_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, std_layout=False, kv_tables=None, key_positions=None, layer=None):
+def gpt2_block(x, p, cfg, call, layer_cache, layer=None):
     """-> (x, new_cache, aux): aux is the MoE load-balance term (0 here).
     Shared by the gpt2 and opt families (pre-LN + learned positions);
     cfg.activation picks the MLP nonlinearity (gelu vs relu)."""
     h = layers.layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"], cfg.norm_eps)
-    attn_out, new_cache = _attention(h, p["attn"], cfg, positions, layer_cache, cache_index, use_rope=False, attn_mask=attn_mask, std_layout=std_layout, kv_tables=kv_tables, key_positions=key_positions, layer=layer)
+    attn_out, new_cache = _attention(h, p["attn"], cfg, False, call, layer_cache, layer)
     x = x + attn_out
     h = layers.layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"], cfg.norm_eps)
     x = x + layers.mlp_gelu(h, p["mlp"], cfg.activation)
     return x, new_cache, jnp.float32(0.0)
 
 
-def llama_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, std_layout=False, kv_tables=None, key_positions=None, layer=None):
+def llama_block(x, p, cfg, call, layer_cache, layer=None):
     """-> (x, new_cache, aux): aux is the MoE load-balance term."""
     h = layers.rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
-    attn_out, new_cache = _attention(h, p["attn"], cfg, positions, layer_cache, cache_index, use_rope=True, attn_mask=attn_mask, std_layout=std_layout, kv_tables=kv_tables, key_positions=key_positions, layer=layer)
+    attn_out, new_cache = _attention(h, p["attn"], cfg, True, call, layer_cache, layer)
     x = x + attn_out
     h = layers.rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
     if "router" in p["mlp"]:  # MoE block (cfg.num_experts > 0)
@@ -858,13 +898,13 @@ def llama_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, 
     return x, new_cache, jnp.float32(0.0)
 
 
-def neox_block(x, p, cfg, positions, layer_cache, cache_index, attn_mask=None, std_layout=False, kv_tables=None, key_positions=None, layer=None):
+def neox_block(x, p, cfg, call, layer_cache, layer=None):
     """GPT-NeoX/Pythia: LayerNorm + (partial) rotary + optionally PARALLEL
     residual — out = x + attn(ln1 x) + mlp(ln2 x), both norms reading the
     SAME input (HF use_parallel_residual, the NeoX default); sequential
     pre-LN otherwise.  -> (x, new_cache, aux)."""
     h = layers.layer_norm(x, p["ln1"]["scale"], p["ln1"]["bias"], cfg.norm_eps)
-    attn_out, new_cache = _attention(h, p["attn"], cfg, positions, layer_cache, cache_index, use_rope=True, attn_mask=attn_mask, std_layout=std_layout, kv_tables=kv_tables, key_positions=key_positions, layer=layer)
+    attn_out, new_cache = _attention(h, p["attn"], cfg, True, call, layer_cache, layer)
     if cfg.parallel_residual:
         h2 = layers.layer_norm(x, p["ln2"]["scale"], p["ln2"]["bias"], cfg.norm_eps)
         return x + attn_out + layers.mlp_gelu(h2, p["mlp"], cfg.activation), new_cache, jnp.float32(0.0)
@@ -881,7 +921,7 @@ def layer_of(blocks: Params, layer: jax.Array,
     kernel, which reads the layer's tiles where they lie, where a slice
     would be a copy of the layer's weights in every step (a Pallas call
     takes each operand as a buffer of its own).  It carries ``rows`` too
-    (:func:`real_rows`), for the kernel to skip an admission's padding.
+    (``Call.rows``), for the kernel to skip an admission's padding.
     Every other leaf, norms and biases, float weights, expert stacks of the
     capacity path, is sliced."""
     from ..checkpoint.quantize import QuantizedTensor
@@ -897,16 +937,6 @@ def layer_of(blocks: Params, layer: jax.Array,
     return jax.tree.map(take, blocks, is_leaf=is_q)
 
 
-def real_rows(seq_lens: jax.Array | None, batch: int) -> jax.Array | None:
-    """[1] int32: how many leading rows of the [B * T, K] activations are
-    real, where that is known: ONE right-padded sequence (an admission) whose
-    count of real tokens ``seq_lens`` [1] the caller gave.  None otherwise:
-    a batch's real rows are no run from the top."""
-    if seq_lens is None or batch != 1:
-        return None
-    return seq_lens.astype(jnp.int32).reshape(1)
-
-
 BLOCK_FNS = {"gpt2": gpt2_block, "opt": gpt2_block, "llama": llama_block,
              "neox": neox_block}
 
@@ -915,16 +945,10 @@ def run_blocks(
     x: jax.Array,
     blocks: Params,
     cfg: ModelConfig,
-    positions: jax.Array,
+    call: Call,
     cache: Any,  # these blocks' cache (models/kv_cache.py) or None: leaves
-    #   [L, B, S, KVH, HD] contiguous, or with kv_tables the page pool
-    cache_index: jax.Array | None,
+    #   [L, B, S, KVH, HD] contiguous, or with call.kv_tables the page pool
     remat: bool = False,
-    attn_mask: jax.Array | None = None,
-    std_layout: bool = False,
-    kv_tables: jax.Array | None = None,
-    key_positions: jax.Array | None = None,  # see _attention
-    rows: jax.Array | None = None,  # :func:`real_rows`
 ) -> tuple[jax.Array, Any, jax.Array]:
     """Scan the stacked blocks over x.  Used both for the whole model and for
     a single pipeline stage (blocks then hold only the stage's layer slice).
@@ -932,7 +956,7 @@ def run_blocks(
 
     A contiguous cache enters the scan as scanned inputs and leaves it as
     stacked outputs, one layer's [B, S, KVH, HD] at a time.  A page pool
-    (``kv_tables``) is the scan's CARRY instead, beside x: each layer
+    (``call.kv_tables``) is the scan's CARRY instead, beside x: each layer
     scatters its new K/V into the stack at (layer, page, off) and the
     paged kernel reads its pages out of the stack, so the pool is updated
     where it lies.  Sliced per layer it would be copied whole four times
@@ -951,16 +975,17 @@ def run_blocks(
     layer_index = jnp.arange(
         jax.tree.leaves(blocks)[0].shape[0], dtype=jnp.int32)
 
+    paged = call.kv_tables is not None
     if cache is None:
         def body(carry, layer_params):
-            y, _, aux = block_fn(carry, layer_params, cfg, positions, None, None, attn_mask, std_layout)
+            y, _, aux = block_fn(carry, layer_params, cfg, call, None)
             return y, aux
 
         init, xs = x, blocks
-    elif kv_tables is not None:
+    elif paged:
         def body(carry, layer):
             y, pool = carry
-            y, pool, aux = block_fn(y, layer_of(blocks, layer, rows), cfg, positions, pool, cache_index, attn_mask, std_layout, kv_tables, key_positions, layer)
+            y, pool, aux = block_fn(y, layer_of(blocks, layer, call.rows), cfg, call, pool, layer)
             return (y, pool), aux
 
         init = (x, cache)
@@ -968,7 +993,7 @@ def run_blocks(
     else:
         def body(carry, xs):
             layer, ck, cv = xs
-            y, new_cache, aux = block_fn(carry, layer_of(blocks, layer, rows), cfg, positions, (ck, cv), cache_index, attn_mask, std_layout, None, key_positions)
+            y, new_cache, aux = block_fn(carry, layer_of(blocks, layer, call.rows), cfg, call, (ck, cv))
             return y, (new_cache, aux)
 
         init, xs = x, (layer_index, cache.k, cache.v)
@@ -978,7 +1003,7 @@ def run_blocks(
     out, ys = jax.lax.scan(body, init, xs)
     if cache is None:
         return out, None, jnp.sum(ys)
-    if kv_tables is not None:
+    if paged:
         return *out, jnp.sum(ys)
     new_k, new_v = ys[0]
     return out, dataclasses.replace(cache, k=new_k, v=new_v), jnp.sum(ys[1])
@@ -1016,14 +1041,8 @@ def run_layers(
     x: jax.Array,
     blocks: Params,  # params["blocks"]: one stack a KIND of layer
     cfg: ModelConfig,
-    positions: jax.Array,
+    call: Call,
     cache: kv_cache.HybridCache | None,
-    cache_index: jax.Array | None,
-    attn_mask: jax.Array | None = None,
-    std_layout: bool = False,
-    kv_tables: jax.Array | None = None,
-    key_positions: jax.Array | None = None,
-    seq_lens: jax.Array | None = None,  # [B] real new tokens a row
 ) -> tuple[jax.Array, kv_cache.HybridCache | None, jax.Array]:
     """The "hybrid" family's layers (LFM2-MoE), which differ: layer l is
     ``x + op_l(rms(x))`` then ``+ ffn_l(rms(.))`` with op_l a gated short
@@ -1043,7 +1062,7 @@ def run_layers(
 
     The cache is the scans' carry beside x.  An attention layer reads and
     writes it at its own index among the attention layers: the page pool
-    whole (``kv_tables``; see :func:`_paged_attention`), a contiguous
+    whole (``call.kv_tables``; see :func:`_paged_attention`), a contiguous
     cache by its layer slice.  A convolution layer reads and writes its
     [B, K-1, D] slice of ``cache.conv``.  In a model that mixes windowed
     ("swa") and full ("attn") attention layers both kinds go through
@@ -1058,11 +1077,8 @@ def run_layers(
     by-product like the dense families' aux loss and no part of the
     state."""
     moe = jnp.zeros((4 if cfg.experts_held is None else 5,), jnp.int32)
-    token_mask = None
-    if seq_lens is not None:
-        token_mask = (jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
-                      < seq_lens[:, None])
-    rows = real_rows(seq_lens, x.shape[0])
+    rows, token_mask, paged = (
+        call.rows, call.token_mask, call.kv_tables is not None)
 
     def layer(carry, op, ffn, at):
         """One layer; ``at`` its index into each kind's stack."""
@@ -1080,25 +1096,22 @@ def run_layers(
         if op == "conv":
             out, new = layers.short_conv(
                 h, p, None if cache is None else cache.conv[at[op]],
-                seq_lens)
+                call.seq_lens)
             if cache is not None:
                 cache = dataclasses.replace(cache, conv=cache.conv.at[
                     at[op]].set(new.astype(cache.conv.dtype)))
         elif cfg.swa_layers:  # windowed and full attention layers mixed
             with jax.named_scope("swa_attn" if op == "swa" else "full_attn"):
                 out, cache = mixed_attention(
-                    h, p, cfg, positions, cache, cache_index, op, at[op],
-                    kv_tables, seq_lens)
+                    h, p, cfg, op, call, cache, at[op])
         elif op == "mla":
             ai = at[op]
-            if cache is None or kv_tables is not None:
+            if cache is None or paged:
                 layer_cache = cache
             else:
                 layer_cache = cache.k[ai]
-            out, new = mla_attention(
-                h, p, cfg, positions, layer_cache, cache_index, attn_mask,
-                kv_tables, ai)
-            if kv_tables is not None:
+            out, new = mla_attention(h, p, cfg, call, layer_cache, ai)
+            if paged:
                 cache = new
             elif cache is not None:
                 cache = dataclasses.replace(cache, k=cache.k.at[ai].set(new))
@@ -1106,16 +1119,12 @@ def run_layers(
             ai = at[op]
             if cache is None:
                 layer_cache = None
-            elif kv_tables is not None:
+            elif paged:
                 layer_cache = cache
             else:
                 layer_cache = (cache.k[ai], cache.v[ai])
-            out, new = _attention(
-                h, p, cfg, positions, layer_cache, cache_index,
-                use_rope=True, attn_mask=attn_mask, std_layout=std_layout,
-                kv_tables=kv_tables, key_positions=key_positions, layer=ai,
-            )
-            if kv_tables is not None:
+            out, new = _attention(h, p, cfg, True, call, layer_cache, ai)
+            if paged:
                 cache = new
             elif cache is not None:
                 cache = dataclasses.replace(
@@ -1260,27 +1269,25 @@ def forward(
     params: Params,
     cfg: ModelConfig,
     tokens: jax.Array,  # [B, T] int32
-    positions: jax.Array | None = None,  # [B, T] int32
+    positions: jax.Array | None = None,  # [B, T] int32; None: the arange
+    #   behind ``cache_index``.  This and the five marked (*) are the call's
+    #   facts: :class:`Call` says what each is and what kind they make
     cache: kv_cache.KVCache | None = None,
-    cache_index: jax.Array | None = None,  # scalar int32 write offset, or
-    #   [B] int32 per-row offsets (continuous batching; attn_mask required)
+    cache_index: jax.Array | None = None,  # (*) scalar or [B] write offset
     remat: bool = False,
-    attn_mask: jax.Array | None = None,  # broadcastable to [B, H, Tq, S]; True = attend
+    attn_mask: jax.Array | None = None,  # (*)
     return_aux: bool = False,  # also return the expert layers' by-product:
     #   the load-balance aux loss; for the hybrid family, which is not
     #   trained, its routing counts int32 [4] (run_layers)
-    kv_tables: jax.Array | None = None,  # [B, P] page table: the cache holds
-    #   page POOLS [L, NB, BLK, KVH, HD] (paged continuous batching; see
-    #   _attention's kv_tables contract — decode-only)
-    key_positions: jax.Array | None = None,  # [B, S] true RoPE positions of
-    #   cache slots, for the sliding-window mask under gapped (right-padded
-    #   generate) cache layouts — see _attention's parameter comment
-    seq_lens: jax.Array | None = None,  # [B] int32: how many of the T tokens
-    #   of each row are real (right-padded input; 0 for a batch row that is
-    #   not decoding).  Only a model with state that is not keys and values
-    #   needs it (family "hybrid": layers.short_conv, layers.moe_dropless);
-    #   every family hands a lone row's count to the quantized matmuls
-    #   (:func:`real_rows`), and nothing else of it.  None means all T
+    kv_tables: jax.Array | None = None,  # (*) the cache is a page pool
+    key_positions: jax.Array | None = None,  # (*)
+    seq_lens: jax.Array | None = None,  # (*) [B] int32: how many of the T
+    #   tokens of each row are real (right-padded input; 0 for a batch row
+    #   that is not decoding).  Only a model with state that is not keys and
+    #   values needs it (family "hybrid": layers.short_conv,
+    #   layers.moe_dropless); every family hands a lone row's count to the
+    #   quantized matmuls (``Call.rows``), and nothing else of it.  None
+    #   means all T
     logits_at: jax.Array | None = None,  # [B] int32: the ONE position of
     #   each row whose logits the caller wants (an admission samples its
     #   first token from the last real position and nothing else): the
@@ -1297,25 +1304,16 @@ def forward(
     ``dynamic_update_slice`` clamps out-of-range starts, which would silently
     overwrite the last cache slot.  The decode loop in runtime/ enforces this
     statically (max_decode_steps + prompt_len <= max_seq_len)."""
-    b, t = tokens.shape
-    # Standard layout: forward generated the positions itself with no cache
-    # offset — query rows align with key slots, which lets the flash kernel
-    # take its static-causal fast path (no per-tile position masks).
-    std_layout = positions is None and (cache_index is None or cache is None)
-    if positions is None:
-        base = cache_index if cache_index is not None else 0
-        positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32) + base, (b, t))
-    x = embed(params, cfg, tokens, positions)
+    call = call_of(tokens.shape, positions, cache_index, attn_mask,
+                   key_positions, kv_tables, seq_lens, cache is not None)
+    x = embed(params, cfg, tokens, call.positions)
     if cfg.family == "hybrid":
         if isinstance(cache, kv_cache.QuantKVCache) or remat:
             raise ValueError(
                 "the hybrid family serves a full-width HybridCache and is "
                 "not trained: no int8 pool, no remat"
             )
-        x, cache, stats = run_layers(
-            x, params["blocks"], cfg, positions, cache, cache_index,
-            attn_mask, std_layout, kv_tables, key_positions, seq_lens,
-        )
+        x, cache, stats = run_layers(x, params["blocks"], cfg, call, cache)
         out = (unembed(params, cfg, hidden_at(x, logits_at)), cache)
         return (*out, stats) if return_aux else out
     if isinstance(cache, kv_cache.QuantKVCache) and kv_tables is None:
@@ -1325,11 +1323,7 @@ def forward(
             "QuantKVCache serves paged decode only (pass kv_tables); "
             "prefill runs against full-width transient rows"
         )
-    x, cache, aux = run_blocks(
-        x, params["blocks"], cfg, positions, cache, cache_index, remat,
-        attn_mask, std_layout, kv_tables, key_positions,
-        real_rows(seq_lens, b),
-    )
+    x, cache, aux = run_blocks(x, params["blocks"], cfg, call, cache, remat)
     out = (unembed(params, cfg, hidden_at(x, logits_at)), cache)
     return (*out, aux) if return_aux else out
 

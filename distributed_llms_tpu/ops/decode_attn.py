@@ -420,12 +420,6 @@ def _dense_reference(q, k, v, lengths, window=None):
     return layers.dot_product_attention(q, kf, vf, mask[:, None, None, :])
 
 
-def _mode() -> str:
-    """DLT_RAGGED_DECODE: "kernel" | "interpret" | "fallback" | "auto"
-    (kernel iff TPU) — same resolution scheme as ops/quant_matmul.py."""
-    return dispatch.kernel_mode("DLT_RAGGED_DECODE")
-
-
 def ragged_decode_attention(
     q: jax.Array,  # [B, 1, H, D] — one query token per row
     k: jax.Array,  # [B, S, KVH, D] full cache width
@@ -447,7 +441,7 @@ def ragged_decode_attention(
     runs the unchanged kernel on its local KV-head slice, with no
     collective (attention heads are independent per KV head); lengths
     shard with the batch axis (or replicate on a pure-TP mesh)."""
-    mode = _mode()
+    mode = dispatch.attention_mode()
     quant = _check_quant(k, k_scale, v_scale)
     impl = functools.partial(
         _ragged_impl, block_k=block_k, window=window, mode=mode
@@ -618,7 +612,7 @@ def paged_decode_attention(
     kernel on its local head slice, and the page table + lengths
     replicate on a pure-TP mesh (they shard only with an explicit batch
     axis)."""
-    mode = _mode()
+    mode = dispatch.attention_mode()
     quant = _check_quant(k_pages, k_scale, v_scale)
     if k_pages.ndim == 4:  # one layer's pages: the stack of one layer
         k_pages, v_pages = k_pages[None], v_pages[None]
@@ -829,7 +823,7 @@ def swa_decode_attention(
         tables = tables * n + jnp.arange(n, dtype=jnp.int32)[None, :]
     return _paged_impl(
         q, ring_k, ring_v, counts.astype(jnp.int32), tables,
-        jnp.asarray(layer, jnp.int32).reshape(1), mode=_mode(),
+        jnp.asarray(layer, jnp.int32).reshape(1), mode=dispatch.attention_mode(),
         op="swa_decode")
 
 
@@ -863,7 +857,7 @@ def mla_paged_decode_attention(
     return _mla_paged_impl(
         q, pages, lengths.astype(jnp.int32), tables.astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1), latent=latent, scale=scale,
-        mode=_mode())
+        mode=dispatch.attention_mode())
 
 
 def _mla_paged_impl(q, pages, lengths, tables, layer, *, latent: int,

@@ -54,6 +54,14 @@ def kernel_mode(env_var: str) -> str:
     return "kernel" if jax.default_backend() == "tpu" else "fallback"
 
 
+def attention_mode() -> str:
+    """DLT_RAGGED_DECODE: "kernel" | "interpret" | "fallback" | "auto"
+    (kernel iff TPU): the one mode of the attention kernels, the decode
+    ones (ops/decode_attn.py) and, read by models/model.py, the flash
+    kernel of an admission."""
+    return kernel_mode("DLT_RAGGED_DECODE")
+
+
 def record(op: str, path: str, shape: tuple) -> None:
     """Count one trace-time dispatch of ``op`` onto ``path``; inside a
     :func:`per_shard` body also count it under ``shard_map`` (the body is

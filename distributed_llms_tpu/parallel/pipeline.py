@@ -134,9 +134,10 @@ def pipeline_blocks(
                 ck_mb = jax.lax.dynamic_slice_in_dim(ck, row0, mb_size, axis=1)
                 cv_mb = jax.lax.dynamic_slice_in_dim(cv, row0, mb_size, axis=1)
                 y, new, _ = model_lib.run_blocks(
-                    x_in, blocks, cfg, pos, KVCache(k=ck_mb, v=cv_mb),
-                    cache_index, remat=remat, attn_mask=amask,
-                    key_positions=kpos,
+                    x_in, blocks, cfg, model_lib.call_of(
+                        x_in.shape[:2], pos, cache_index, amask, kpos,
+                        cached=True),
+                    KVCache(k=ck_mb, v=cv_mb), remat,
                 )
                 nk = jnp.where(valid, new.k, ck_mb)
                 nv = jnp.where(valid, new.v, cv_mb)
@@ -146,8 +147,9 @@ def pipeline_blocks(
                 # MoE aux loss is not threaded through the pipeline schedule
                 # (train MoE with data/tensor/expert axes, not 'pipe').
                 y, _, _ = model_lib.run_blocks(
-                    x_in, blocks, cfg, pos, None, None,
-                    remat=remat, attn_mask=amask,
+                    x_in, blocks, cfg,
+                    model_lib.call_of(x_in.shape[:2], pos, attn_mask=amask),
+                    None, remat,
                 )
 
             # Last stage banks its finished microbatch.
@@ -361,8 +363,9 @@ def pipeline_decode(
                     plens_m[:, None] + (slots[None, :] - t_base),
                 )
             y, new, _ = model_lib.run_blocks(
-                x_in, blocks, cfg, pos, KVCache(k=ck_mb, v=cv_mb),
-                t_base + j, attn_mask=mask, key_positions=kpos,
+                x_in, blocks, cfg, model_lib.call_of(
+                    x_in.shape[:2], pos, t_base + j, mask, kpos, cached=True),
+                KVCache(k=ck_mb, v=cv_mb),
             )
             nk = jnp.where(valid, new.k, ck_mb)
             nv = jnp.where(valid, new.v, cv_mb)

@@ -1799,13 +1799,13 @@ class ContinuousBatcher:
         # different op from the masked dot path (the exact-token invariant
         # is against the latter); that choice goes on the dispatch record
         # like any other fallback.
-        from ..ops import decode_attn, dispatch
+        from ..ops import dispatch
 
         # (Sliding-window models ride the ragged kernel too: it takes the
         # window bound and reads only [length - window, length) per row —
         # slot == position in this contiguous layout, so the slot-space
         # band equals the position-space window exactly.)
-        if decode_attn._mode() != "fallback":
+        if dispatch.attention_mode() != "fallback":
             self.cfg_decode = dataclasses.replace(cfg, ragged_decode=True)
         else:
             self.cfg_decode = cfg

@@ -149,14 +149,17 @@ def test_absorbed_decode_is_expanded_attention(tiny):
     x = jnp.asarray(rng.randn(1, 1, 64), jnp.float32)
     pos = jnp.asarray([[n]], jnp.int32)
     mask = (jnp.arange(32) <= n)[None, None, None, :]
+    at = jnp.asarray([n], jnp.int32)
     want, new_rows = model_lib.mla_attention(
-        x, p, cfg, pos, rows, jnp.asarray([n], jnp.int32), attn_mask=mask)
+        x, p, cfg, model_lib.call_of((1, 1), pos, at, mask, cached=True),
+        rows)
     pool = kv_cache.LatentCache(k=jnp.zeros(
         (4, 9, blk, cfg.latent_width)).at[2, 1:5].set(
             rows[0].reshape(4, blk, -1)))
     got, pool = model_lib.mla_attention(
-        x, p, cfg, pos, pool, jnp.asarray([n], jnp.int32),
-        kv_tables=jnp.asarray([[1, 2, 3, 4]], jnp.int32), layer=jnp.int32(2))
+        x, p, cfg, model_lib.call_of(
+            (1, 1), pos, at, kv_tables=jnp.asarray([[1, 2, 3, 4]], jnp.int32),
+            cached=True), pool, jnp.int32(2))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=ATOL)
     np.testing.assert_array_equal(  # both cached the same new row
         np.asarray(pool.k[2, 1:5]).reshape(32, -1), np.asarray(new_rows[0]))

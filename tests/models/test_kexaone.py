@@ -186,11 +186,11 @@ def test_the_windows_edge(tiny, mode, monkeypatch):
     monkeypatch.setenv("DLT_RAGGED_DECODE", mode)
     p0 = jax.tree.map(lambda a: a[0], params["blocks"]["swa"])
     h = jax.random.normal(jax.random.key(9), (1, 32, cfg.hidden_size))
-    pos = jnp.arange(32, dtype=jnp.int32)[None]
+    call = model_lib.call_of((1, 32), jnp.arange(32, dtype=jnp.int32)[None])
 
     def out_at(h, p):
         return np.asarray(model_lib.mixed_attention(
-            h, p0, cfg, pos, None, None, "swa", 0)[0][0, p])
+            h, p0, cfg, "swa", call, None, 0)[0][0, p])
 
     p = 29
     base = out_at(h, p)
@@ -199,11 +199,11 @@ def test_the_windows_edge(tiny, mode, monkeypatch):
     # ... and a full layer reads it, with no rotation.
     full = model_lib.mixed_attention(
         h.at[0, p - W].add(1.0), jax.tree.map(
-            lambda a: a[0], params["blocks"]["attn"]), cfg, pos, None, None,
-        "attn", 0)[0][0, p]
+            lambda a: a[0], params["blocks"]["attn"]), cfg, "attn", call,
+        None, 0)[0][0, p]
     same = model_lib.mixed_attention(
-        h, jax.tree.map(lambda a: a[0], params["blocks"]["attn"]), cfg, pos,
-        None, None, "attn", 0)[0][0, p]
+        h, jax.tree.map(lambda a: a[0], params["blocks"]["attn"]), cfg,
+        "attn", call, None, 0)[0][0, p]
     assert np.abs(np.asarray(full - same)).max() > 1e-4
 
 

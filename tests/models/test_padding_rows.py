@@ -1,12 +1,13 @@
 """An admission's quantized matmuls skip the row tiles that hold only
 padding (PR 39): for every family that serves a cell, the admission with
 the count of real rows against the same admission without it (the parent's
-route: ``models.model.real_rows`` answering None), which must agree to the
+route: the ``rows`` of ``models.model.call_of``'s record None), which must agree to the
 last bit on everything a real token leaves behind; what the traced programs
 hold; and the batcher's two counters.  Since PR 46 the flash kernel of the
 two configurations of windowed and full attention layers takes the same
 count: its grid ends at the last tile of queries that holds a real token."""
 
+import dataclasses
 import functools
 
 import jax
@@ -69,7 +70,9 @@ def admit(name, t, n, monkeypatch, counted, s=None):
     cfg, params = wide(name)
     with monkeypatch.context() as mp:
         if not counted:
-            mp.setattr(model_lib, "real_rows", lambda *a: None)
+            real = model_lib.call_of
+            mp.setattr(model_lib, "call_of", lambda *a, **kw:
+                       dataclasses.replace(real(*a, **kw), rows=None))
         return jax.jit(lambda p: batcher_lib._prefill_row(
             model_lib.forward, params, cfg, jnp.float32, s or t, p,
             jnp.int32(n)))(tokens(t))

@@ -111,7 +111,7 @@ def test_no_admission_holds_a_bucket_by_row_cache_intermediate(name):
 @pytest.mark.parametrize("name", PRESETS + ["k-exaone-tiny"])
 def test_every_attention_layer_of_an_admission_is_self_attention(
         name, monkeypatch):
-    """The static branch fires under ``jit``, in ``_attention``,
+    """The "start" kind fires under ``jit``, in ``_attention``,
     ``mla_attention`` and ``mixed_attention`` alike: one function scores a
     row's start, and it is handed T keys."""
     seen = []
@@ -132,15 +132,78 @@ def test_every_attention_layer_of_an_admission_is_self_attention(
     assert seen and set(seen) == {(T, T, T)}
 
 
-def test_a_traced_start_or_a_callers_mask_keeps_the_row_cache_route():
-    assert model_lib._row_start(0, None)
-    assert model_lib._row_start(np.int32(0), None)
-    assert not model_lib._row_start(1, None)
-    assert not model_lib._row_start(0, jnp.ones((1, 1, 4, 8), bool))
-    assert not model_lib._row_start(0, None, jnp.zeros((1, 8), jnp.int32))
-    assert not model_lib._row_start(jnp.zeros((2,), jnp.int32), None)
-    assert not jax.jit(
-        lambda i: jnp.int32(model_lib._row_start(i, None)))(jnp.int32(0))
+MASK = jnp.ones((2, 1, 4, 8), bool)
+ROWS = jnp.zeros((2,), jnp.int32)  # one write slot a row
+TABLES = jnp.zeros((2, 1), jnp.int32)
+POS = jnp.zeros((2, 4), jnp.int32)  # (a row's offset makes no arange)
+
+
+@pytest.mark.parametrize("facts, traced, kind", [
+    # each kind ...
+    (dict(), False, "plain"),
+    (dict(cached=True, cache_index=0), False, "start"),
+    (dict(cached=True, cache_index=3), False, "continuation"),
+    (dict(cached=True, cache_index=3, attn_mask=MASK), False, "masked"),
+    (dict(cached=True, cache_index=ROWS, attn_mask=MASK, positions=POS),
+     False, "decode"),
+    (dict(cached=True, cache_index=ROWS, kv_tables=TABLES, positions=POS),
+     False, "decode"),
+    # ... and each near miss of a start: the row cache's route is kept
+    (dict(cached=True, cache_index=np.int32(0)), False, "start"),
+    (dict(cached=True, cache_index=0), True, "continuation"),
+    (dict(cached=True, cache_index=0, attn_mask=MASK), False, "masked"),
+    (dict(cached=True, cache_index=0,
+          key_positions=jnp.zeros((2, 8), jnp.int32)), False, "continuation"),
+    (dict(cache_index=0), False, "plain"),  # an offset and no cache
+    (dict(cache_index=ROWS, kv_tables=TABLES, positions=POS), False, "plain"),
+])
+def test_the_kind_of_a_call_is_decided_once(facts, traced, kind):
+    """``models.model.call_of``: a traced zero (``jnp.int32(0)`` under
+    ``jit``), a caller's mask or a map of the slots' positions is no start,
+    and the facts come back as they went in."""
+    seen = []
+
+    def build(index):
+        seen.append(model_lib.call_of(
+            (2, 4), **{**facts, "cache_index": index}))
+        return jnp.int32(0)
+
+    if traced:
+        jax.jit(build)(jnp.int32(facts["cache_index"]))
+    else:
+        build(facts.get("cache_index"))
+    call, = seen
+    assert call.kind == kind
+    assert call.cached == facts.get("cached", False)
+    assert call.positions.shape == (2, 4)
+    assert call.std_layout == ("positions" not in facts and (
+        facts.get("cache_index") is None or not call.cached))
+    for name in ("attn_mask", "key_positions", "kv_tables"):
+        assert getattr(call, name) is facts.get(name)
+    assert call.rows is None and call.token_mask is None
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_what_follows_from_the_facts_of_a_call(batch):
+    """The positions ``forward`` makes itself (the standard layout, but not
+    behind a cache's offset), a lone row's count of real rows, and the mask
+    of the real tokens."""
+    lens = jnp.asarray([3, 1][:batch], jnp.int32)
+    call = model_lib.call_of((batch, 4), seq_lens=lens)
+    assert call.std_layout and call.kind == "plain"
+    np.testing.assert_array_equal(
+        np.asarray(call.positions), np.tile(np.arange(4), (batch, 1)))
+    np.testing.assert_array_equal(
+        np.asarray(call.token_mask),
+        np.arange(4)[None] < np.asarray(lens)[:, None])
+    if batch == 1:  # ONE right-padded sequence: its real rows run from the top
+        np.testing.assert_array_equal(np.asarray(call.rows), [3])
+    else:
+        assert call.rows is None
+    behind = model_lib.call_of((batch, 4), cache_index=5, cached=True)
+    assert not behind.std_layout and int(behind.positions[0, 0]) == 5
+    own = model_lib.call_of((batch, 4), positions=call.positions)
+    assert not own.std_layout and own.positions is call.positions
 
 
 def test_the_two_counters_add_up_to_the_admissions():

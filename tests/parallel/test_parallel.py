@@ -63,7 +63,8 @@ def test_pipeline_matches_plain_blocks(gpt2, devices8):
     toks = jax.random.randint(jax.random.key(1), (B, T), 0, cfg.vocab_size, dtype=jnp.int32)
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     x = model.embed(params, cfg, toks, positions)
-    y_ref, _, _ = model.run_blocks(x, params["blocks"], cfg, positions, None, None)
+    y_ref, _, _ = model.run_blocks(
+        x, params["blocks"], cfg, model.call_of((B, T), positions), None)
     staged = pl.split_stages(params["blocks"], 4)
     y_pipe, _ = pl.pipeline_blocks(mesh, cfg, staged, x, positions, num_microbatches=2)
     np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_pipe), rtol=1e-5, atol=1e-5)
@@ -78,7 +79,8 @@ def test_pipeline_gradients_match(gpt2, devices8):
     x = model.embed(params, cfg, toks, positions)
 
     def loss_plain(blocks):
-        y, _, _ = model.run_blocks(x, blocks, cfg, positions, None, None)
+        y, _, _ = model.run_blocks(
+            x, blocks, cfg, model.call_of((B, T), positions), None)
         return jnp.mean(y.astype(jnp.float32) ** 2)
 
     def loss_pipe(staged):

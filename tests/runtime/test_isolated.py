@@ -9,23 +9,44 @@ on 2026-07-31 crashed there, on five different members of the family
 (backend_compile_and_load, persistent-cache serialize, deserialize) —
 while every fresh-process run passes.  The whole speculative test family
 is therefore marked skip-unless-DLT_RUN_ISOLATED in its home files
-(module-level pytestmark) and executed here in ONE fresh subprocess —
-full coverage, crash domain isolated, and a real failure in those tests
-still fails the suite loudly through this runner.
+(module-level pytestmark) and executed here in fresh processes — full
+coverage, crash domain isolated, and a real failure in those tests still
+fails the suite loudly through this runner.
+
+The list runs over WORKERS fresh processes at once (an inner ``pytest -n``,
+a file a worker at a time): each compiles at most some 25 of the 90 cases,
+far under the ~150 the crash needs, and the runner is no longer the suite's
+longest job (304 s as one process, PR 47's run).  A worker that dies
+fails the run: its test is reported failed, and the "node down" lines are
+counted as the driver's command counts them.
 """
 
 import os
+import re
 import subprocess
 import sys
+
+WORKERS = 6
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 ISOLATED = [
     "tests/runtime/test_speculative.py",
     "tests/runtime/test_spec_batcher.py",
+    # (The four longest files stand first: the inner run hands files out in
+    # this order, and a long one handed out last is the run's tail.)
+    # Paged speculative decoding (round 17): every composition leg
+    # compiles paged spec_chunk programs — same crash class as
+    # test_spec_batcher.
+    "tests/runtime/test_spec_paged.py",
+    # Stall-free mixed batching (round 16): every fused-step composition
+    # compiles mixed_step programs per pool/bucket config — the policy
+    # hook tests at the top of the file are model-free and also run in
+    # the main process.
+    "tests/runtime/test_mixed_step.py",
     # Every OTHER test that compiles a speculative while_loop program —
     # grep for speculative_generate_tokens when adding tests outside the
-    # two files above.
+    # speculative files above.
     "tests/models/test_sliding_window.py::"
     "test_ragged_windowed_speculative_matches_generate",
     # Cluster engine compiles at the suite TAIL (same crash class, plain
@@ -62,15 +83,6 @@ ISOLATED = [
     # Dispatch-ahead overlap (round 13): the speculative leg compiles
     # spec_chunk programs — same crash class as test_spec_batcher.
     "tests/runtime/test_overlap.py::test_speculative_exact_on_vs_off",
-    # Paged speculative decoding (round 17): every composition leg
-    # compiles paged spec_chunk programs — same crash class as
-    # test_spec_batcher.
-    "tests/runtime/test_spec_paged.py",
-    # Stall-free mixed batching (round 16): every fused-step composition
-    # compiles mixed_step programs per pool/bucket config — the policy
-    # hook tests at the top of the file are model-free and also run in
-    # the main process.
-    "tests/runtime/test_mixed_step.py",
     # The explicit admission fetch (PR 36): its speculative leg compiles
     # paged spec_chunk programs.
     "tests/runtime/test_tracing.py::"
@@ -90,10 +102,12 @@ def test_fragile_xla_cpu_tests_in_fresh_process():
     env.pop("DLT_TEST_CACHE_DIR", None)
     r = subprocess.run(
         [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
-         *ISOLATED],
+         "-p", "xdist", "-n", str(WORKERS), "--dist", "loadfile", *ISOLATED],
         env=env, capture_output=True, text=True, timeout=3300, cwd=REPO,
     )
-    assert r.returncode == 0, (
-        f"isolated fragile tests failed (rc={r.returncode}):\n"
+    down = len(re.findall(r"\[gw\d+\] node down", r.stdout + r.stderr))
+    assert r.returncode == 0 and not down, (
+        f"isolated fragile tests failed (rc={r.returncode}, "
+        f"{down} workers down):\n"
         f"{r.stdout[-3000:]}\n{r.stderr[-2000:]}"
     )

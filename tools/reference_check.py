@@ -462,7 +462,7 @@ def main() -> int:
 
     from distributed_llms_tpu.models import kv_cache, model as model_lib
     from distributed_llms_tpu.models.presets import get_preset
-    from distributed_llms_tpu.ops import decode_attn
+    from distributed_llms_tpu.ops import dispatch
     from distributed_llms_tpu.runtime import batcher as B
     from distributed_llms_tpu.runtime.shapes import bucket_length
     from distributed_llms_tpu.runtime.tokenizer import get_tokenizer
@@ -500,7 +500,7 @@ def main() -> int:
 
     slots, blk = serve["slots"], serve["page_size"]
     ppr = serve["max_len"] // blk
-    kernels = decode_attn._mode() != "fallback"
+    kernels = dispatch.attention_mode() != "fallback"
 
     def make_batcher():
         # One a probe, dropped after it: its pool and the legs' own would
