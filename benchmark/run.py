@@ -9,8 +9,13 @@ itself, under ``JAX_PLATFORMS=tpu``), sets the server up (boot, probes
 against the golden, one warm-up per admission shape of the cell's traffic,
 the pre-roll), measures for ``--seconds``, stops the child and prints one
 JSON object as the last line of standard output.  No TPU, no result: there
-is no CPU fallback.  ``--rehearsal`` runs the same code on the CPU with the
-``rehearsal-tiny`` configuration and prints counts only, never a metric.
+is no CPU fallback.  ``--rehearsal`` runs the same code on the CPU with a
+rehearsal configuration (``--rehearsal-config``) and prints counts only,
+never a metric.
+
+What a run holds a cell to beyond its requests is the configuration's to
+say (:func:`held_to`): the kernels it must have taken, whether its rows lie
+in a page pool, and the probes compared with the golden.
 """
 
 from __future__ import annotations
@@ -40,8 +45,11 @@ BOOT_TIMEOUT_S = 900.0
 TRACE_S = 6.0            # the traced part of a --trace 1 window
 TRACE_AFTER = 0.4        # ... which starts this share of the window in
 GOLDEN_TOL = 0.05
-PROBE_BYTES = (32, 200, 700, 1500)
 PROBE_TOKENS = 8
+# What a configuration that says nothing is held to: what every cell was
+# held to before a configuration could say (PR 49).
+PROBE_BYTES = (32, 200, 700, 1500)
+MUST_DISPATCH = ("quant_matmul", "paged_decode")
 COMPILE_LOG = re.compile(
     r"PERSISTENT COMPILATION CACHE MISS for '(\w+)'"
     r"|Persistent compilation cache hit for '(\w+)'"
@@ -153,10 +161,27 @@ class Server:
 
 
 # -- correctness --------------------------------------------------------
-def run_probes(gw: client.Gateway, max_len: int) -> list[dict]:
+def held_to(serve: dict) -> dict:
+    """What a run holds a cell to, read from its configuration's ``serve``:
+    ``must_dispatch``, the operations whose compiled kernel a run must have
+    taken; ``paged_pages``, where 0 is the server's word for no pool; and
+    ``probe_bytes``, the lengths of the probes compared with the golden."""
+    held = {"must_dispatch": list(serve.get("must_dispatch", MUST_DISPATCH)),
+            "paged_pages": serve["paged_pages"],
+            "probe_bytes": list(serve.get("probe_bytes", PROBE_BYTES))}
+    if not held["must_dispatch"] or not all(
+            isinstance(op, str) and op for op in held["must_dispatch"]):
+        raise Failed("'must_dispatch' names at least one operation")
+    if not held["probe_bytes"] or not all(
+            isinstance(n, int) and n >= 1 for n in held["probe_bytes"]):
+        raise Failed("'probe_bytes' lists at least one length in bytes")
+    return held
+
+
+def run_probes(gw: client.Gateway, max_len: int, probe_bytes) -> list[dict]:
     """The golden probes, sent alone with the prefix cache off."""
     out = []
-    for n in PROBE_BYTES:
+    for n in probe_bytes:
         n = min(n, max_len - PROBE_TOKENS - 8)
         rec = client.send_alone(gw, probe_prompt(n), PROBE_TOKENS,
                                 prefix_cache=False)
@@ -172,6 +197,9 @@ def check_golden(probes: list[dict], golden: dict | None) -> list[str]:
     if golden is None:
         return ["no golden is recorded for this configuration"]
     faults = []
+    if len(probes) != len(golden["probes"]):
+        faults.append(f"{len(probes)} probes sent, "
+                      f"{len(golden['probes'])} in the golden")
     for got, want in zip(probes, golden["probes"]):
         if got["bytes"] != want["bytes"]:
             faults.append(f"probe sizes differ: {got['bytes']} / {want['bytes']}")
@@ -190,12 +218,15 @@ def check_golden(probes: list[dict], golden: dict | None) -> list[str]:
     return faults
 
 
-def check_dispatch(m: dict[str, float]) -> list[str]:
+def check_dispatch(m: dict[str, float], must_dispatch) -> list[str]:
+    """What is wrong with the dispatch record (nothing: []): an operation
+    of ``must_dispatch`` that never took its compiled kernel and, whatever
+    the list says, any fallback or interpreter leg that was taken."""
     disp = {k[len("ops_dispatch_"):]: v for k, v in m.items()
             if k.startswith("ops_dispatch_")}
     say(f"  dispatch record: {disp}")
     faults = [f"{op} did not take the compiled kernel"
-              for op in ("quant_matmul", "paged_decode")
+              for op in must_dispatch
               if not disp.get(f"{op}_kernel", 0) > 0]
     faults += [f"{k} = {v}" for k, v in disp.items()
                if k.endswith(("_fallback", "_interpret")) and v]
@@ -205,13 +236,17 @@ def check_dispatch(m: dict[str, float]) -> list[str]:
 def warm_up(gw: client.Gateway, spec: dict, config: dict) -> list[str]:
     """One request per admission shape of the mix, alone; for a shared run
     a second question behind it, which is served from the prefix cache and
-    must agree with a fresh send of itself."""
+    must agree with a fresh send of itself.  A server without a pool has no
+    run of pages to serve it from: there a shared run is sent like any
+    other bytes."""
     rng = random.Random("warm-up")
     n_new = config["serve"]["chunk_steps"] + 1   # admission and one chunk
+    pool = config["serve"]["paged_pages"] != 0
     faults = []
     for shared, prompt, answer in traffic.warmup_turns(
             spec, config["serve"]["page_size"]):
         asked = min(answer, n_new)
+        shared = shared if pool else 0
         doc = traffic.text(rng, shared)
         first = doc + traffic.text(rng, prompt - shared)
         recs = [client.send_alone(gw, first, asked, shared=shared)]
@@ -243,7 +278,7 @@ def warm_up(gw: client.Gateway, spec: dict, config: dict) -> list[str]:
 def run(args) -> dict:
     manifest = load_json(ROOT, "BENCHMARK.json")
     if args.rehearsal:
-        cell = {"name": args.workload, "config": "rehearsal-tiny",
+        cell = {"name": args.workload, "config": args.rehearsal_config,
                 "traffic": args.workload, "chips": 1}
         layer_names = sorted(
             os.path.splitext(f)[0] for f in os.listdir(metrics.LAYER_DIR)
@@ -262,9 +297,11 @@ def run(args) -> dict:
                      "nothing else does")
     platform = "cpu" if args.rehearsal else "tpu"
     spec = traffic.load(cell["traffic"])
-    page = config["serve"]["page_size"]
-    if traffic.worst_case_pages(spec, page) > config["serve"]["paged_pages"] - 1:
-        raise Failed("the mix's worst case does not fit the pool")
+    held = held_to(config["serve"])
+    say(f"held to: {json.dumps(held)}")
+    unfit = traffic.pool_fits(spec, config["serve"], held["must_dispatch"])
+    if unfit:
+        raise Failed("the mix does not fit the server: " + "; ".join(unfit))
     peaks = load_json(HERE, "peaks.json")
     golden_path = os.path.join(HERE, "golden", cell["config"] + ".json")
     golden = load_json(golden_path) if os.path.exists(golden_path) else None
@@ -288,7 +325,8 @@ def run(args) -> dict:
         if not args.rehearsal and dev["device_kind"] not in peaks:
             raise Failed(f"no peaks for device kind {dev['device_kind']!r}")
 
-        probes = run_probes(srv.gw, config["serve"]["max_len"])
+        probes = run_probes(srv.gw, config["serve"]["max_len"],
+                            held["probe_bytes"])
         with open(os.path.join(out_dir, "probes.json"), "w") as f:
             json.dump({"probes": probes, "device_kind": dev["device_kind"],
                        "tolerance": GOLDEN_TOL}, f, indent=1)
@@ -328,7 +366,8 @@ def run(args) -> dict:
         if tracer:
             tracer.join()
 
-        again = run_probes(srv.gw, config["serve"]["max_len"])[:1]
+        again = run_probes(srv.gw, config["serve"]["max_len"],
+                           held["probe_bytes"][:1])
         if again[0]["logprobs"] != probes[0]["logprobs"]:
             faults.append("the first probe answers differently after the "
                           "window than before it")
@@ -375,7 +414,7 @@ def run(args) -> dict:
                       + "; ".join(f"{r.status} {r.finish} {r.n_tokens}/"
                                   f"{r.asked} {r.error}" for r in bad))
     if not args.rehearsal:
-        faults += check_dispatch(m_end)
+        faults += check_dispatch(m_end, held["must_dispatch"])
     if health.get("engine_restarts", 0) or delta.get(
             "server_engine_restarts", 0):
         faults.append("the engine restarted")
@@ -412,7 +451,7 @@ def run(args) -> dict:
     with open(os.path.join(out_dir, "summary.json"), "w") as f:
         json.dump({"faults": faults, "counters": delta, "trace": trace,
                    "setup_s": snap["setup_s"], "compiled": compiled,
-                   "t_open": load.t_open}, f, indent=1)
+                   "t_open": load.t_open, "held_to": held}, f, indent=1)
     for fault in faults:
         say(f"NOT CORRECT: {fault}")
     if args.rehearsal:
@@ -423,9 +462,9 @@ def run(args) -> dict:
                 "counts": {"tokens": metrics.tokens_in_window(records),
                            "layer_metrics_read": sorted(
                                n for n, v in layer.items() if v)},
-                "device": device}
+                "device": device, "held_to": held}
     result = {"correct": not faults, "attempted": attempted, "failed": failed,
-              "metrics": values, "device": device}
+              "metrics": values, "device": device, "held_to": held}
     if trace:
         device["busy_s"] = trace["busy_s"]
         device["window_s"] = trace["window_s"]
@@ -442,8 +481,11 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=None)
     ap.add_argument("--trace", type=int, choices=[0, 1], default=0)
     ap.add_argument("--rehearsal", action="store_true",
-                    help="the same code on the CPU with the rehearsal-tiny "
+                    help="the same code on the CPU with a rehearsal "
                          "configuration; prints counts, never a metric")
+    ap.add_argument("--rehearsal-config", default="rehearsal-tiny",
+                    help="the configuration a --rehearsal runs: a file of "
+                         "configs/ with \"rehearsal\": true")
     ap.add_argument("--keep-trace", action="store_true",
                     help="leave the .xplane.pb under chiprun_out/")
     args = ap.parse_args(argv)

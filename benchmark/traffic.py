@@ -94,6 +94,40 @@ def worst_case_pages(spec: dict, page_size: int) -> int:
     return sum(per_request[: spec["clients"]])
 
 
+def pool_fits(spec: dict, serve: dict, must_dispatch) -> list[str]:
+    """What of the mix does not fit the server its configuration's ``serve``
+    asks for (nothing: []).  Every caller has a batch slot and the longest
+    request (the BOS token counted) a row.  A pool (``paged_pages`` other
+    than 0) holds :func:`worst_case_pages` beside its scratch page, page 0,
+    and a run must have taken ``paged_decode``, the family of kernels that
+    serve a decode step from pages.  ``paged_pages`` 0 is the server's word
+    for no pool: a row's state is the row itself, so there is no page to
+    count, no kernel that reads one and no run of pages to cache.  The run
+    and ``tests/benchmark`` both ask here, so they cannot drift apart."""
+    faults = []
+    if spec["clients"] > serve["slots"]:
+        faults.append(f"{spec['clients']} callers for {serve['slots']} slots")
+    longest = max(p + a for _, p, a in lengths(spec))
+    if longest + 1 > serve["max_len"]:
+        faults.append(f"the longest request holds {longest + 1} tokens, a "
+                      f"row {serve['max_len']}")
+    paged = "paged_decode" in must_dispatch
+    if serve["paged_pages"] == 0:
+        if paged:
+            faults.append("no pool (paged_pages 0), and must_dispatch names "
+                          "paged_decode")
+        if "--prefix-cache" in serve.get("extra_argv", []):
+            faults.append("no pool (paged_pages 0), and --prefix-cache")
+        return faults
+    worst = worst_case_pages(spec, serve["page_size"])
+    if worst > serve["paged_pages"] - 1:
+        faults.append(f"the mix's worst case of {worst} pages does not fit a "
+                      f"pool of {serve['paged_pages']} less its scratch page")
+    if not paged:
+        faults.append("a pool, and must_dispatch does not name paged_decode")
+    return faults
+
+
 def text(rng: random.Random, n: int) -> str:
     """n bytes, one token each."""
     return "".join(rng.choices(_ALPHABET, k=n))

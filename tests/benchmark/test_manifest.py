@@ -20,7 +20,7 @@ from xml.etree import ElementTree
 
 import pytest
 
-from benchmark import metrics
+from benchmark import metrics, run
 
 import test_axk1_metrics
 import test_kexaone_metrics
@@ -132,13 +132,20 @@ def test_every_file_a_cell_names_exists():
 
 
 def test_golden_has_the_probes_the_harness_sends():
-    m = manifest()
-    for c in m["configs"]:
-        with open(os.path.join(ROOT, "benchmark", "golden", c["name"] + ".json")) as f:
+    """Each configuration's golden holds the probes the harness sends it:
+    the lengths its file lists (or the four that a file which lists none is
+    sent), cut as ``run_probes`` cuts them."""
+    for c in manifest()["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            serve = json.load(f)["serve"]
+        with open(os.path.join(ROOT, "benchmark", "golden",
+                               c["name"] + ".json")) as f:
             golden = json.load(f)
-        assert len(golden["probes"]) == 4
+        room = serve["max_len"] - run.PROBE_TOKENS - 8
+        assert [p["bytes"] for p in golden["probes"]] == [
+            min(n, room) for n in run.held_to(serve)["probe_bytes"]], c["name"]
         for p in golden["probes"]:
-            assert len(p["logprobs"]) == 8 and p["bytes"] >= 32
+            assert len(p["logprobs"]) == run.PROBE_TOKENS and p["bytes"] >= 32
 
 
 def check_moves_and_cells(m):
@@ -294,7 +301,7 @@ from declared_cell import check_declared
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CELL = {cell!r}
 DECLARED = {declared!r}   # config, traffic, chips
-NEW = ["appended_useful_share"]
+NEW = {new!r}
 
 
 def manifest():
@@ -314,51 +321,33 @@ def test_this_run_reads_the_copy():
 '''
 
 
-def test_a_real_cell_appended_to_a_copy_of_the_tree(tmp_path):
-    """What the driver's next ``model_config`` PR does, done with REAL files
-    to a copy of the benchmark's tree, and every test of this directory run
-    on the result: a check that cannot take an appended configuration, cell,
-    reader or entry, in any file here, fails in the PR that writes it."""
-    m = manifest()
+# The tests of this file that an inner run of the directory leaves out: the
+# two that make such a run themselves and the two that boot a server.
+LEFT_OUT = ["test_a_real_cell_appended_to_a_copy_of_the_tree",
+            "test_a_state_only_cell_appended_to_a_copy_of_the_tree",
+            "test_rehearsal_end_to_end", "test_rehearsal_without_a_pool"]
+
+
+def copy_of_the_tree(tmp_path):
+    """The manifest's ``paths`` copied to a temporary tree (the program is
+    not copied)."""
     tree = str(tmp_path / "tree")
-    for path in m["paths"]:                 # the program is not copied
+    for path in manifest()["paths"]:
         shutil.copytree(os.path.join(ROOT, path), os.path.join(tree, path),
                         ignore=shutil.ignore_patterns("__pycache__"))
-    # Entries at the END of their lists only: the newest cell's configuration
-    # under another name on the same traffic, one metric of the new cell
-    # alone and one of every cell, both counted by the batcher.
-    donor = m["workloads"][-1]
-    name = "appended-model-int8"
-    cell = append_cell(m, name, donor["traffic"], 1, "appended_useful_share")
-    counted = {**BASE, "source": "program_counter",
-               "layer": "scheduler and batcher"}
-    m["per_layer"][-1].update(counted)
-    m["per_layer"].append({"name": "appended_row_fill", **counted})
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           donor["config"] + ".json")) as f:
-        cfg = json.load(f)
-    cfg.update(name=name, source=m["configs"][-1]["source"])
-    m["configs"][-1]["reduced"] = cfg["reduced"]
-    # New files only: the configuration, the cell's own test, and copies of
-    # accepted files under the new names: the golden and two ratio readers.
-    new = {
-        f"benchmark/configs/{name}.json": json.dumps(cfg),
-        "tests/benchmark/test_appended_metrics.py": APPENDED_TEST.format(
-            cell=cell, declared=(name, donor["traffic"], 1),
-            cells=len(m["workloads"]), copy=tree)}
-    copies = {
-        f"benchmark/golden/{name}.json":
-            f"benchmark/golden/{donor['config']}.json",
-        "benchmark/layer_metrics/appended_useful_share.json":
-            "benchmark/layer_metrics/decode_useful_share.json",
-        "benchmark/layer_metrics/appended_row_fill.json":
-            "benchmark/layer_metrics/decode_row_fill.json"}
+    return tree
+
+
+def run_the_directory_on(tree, tmp_path, m, new, copies, module):
+    """Write ``new`` (path: text) and ``copies`` (path: accepted file) into
+    ``tree`` as NEW files and ``m`` as its manifest, the one file rewritten;
+    run every test of ``tests/benchmark`` there but ``LEFT_OUT``.  All pass,
+    as many as the directory has today and the cell's own ``module``."""
     assert not any(os.path.exists(os.path.join(tree, rel))
                    for rel in (*new, *copies))
     for rel, src in copies.items():
         shutil.copy(os.path.join(tree, src), os.path.join(tree, rel))
-    new["BENCHMARK.json"] = json.dumps(m, indent=1)   # the one file rewritten
-    for rel, text in new.items():
+    for rel, text in {**new, "BENCHMARK.json": json.dumps(m, indent=1)}.items():
         with open(os.path.join(tree, rel), "w") as f:
             f.write(text)
     collected = subprocess.run(
@@ -377,17 +366,124 @@ def test_a_real_cell_appended_to_a_copy_of_the_tree(tmp_path):
     inner = subprocess.run(
         [sys.executable, "-m", "pytest", "tests/benchmark", "-q",
          "-p", "no:cacheprovider", "--rootdir", tree, "--junitxml", report,
-         "--deselect", me + "test_a_real_cell_appended_to_a_copy_of_the_tree",
-         "--deselect", me + "test_rehearsal_end_to_end"],
+         *(arg for name in LEFT_OUT for arg in ("--deselect", me + name))],
         capture_output=True, text=True, timeout=300, env=env, cwd=tree)
     assert inner.returncode == 0, inner.stdout[-6000:] + inner.stderr[-2000:]
     cases = ElementTree.parse(report).getroot().iter("testcase")
     passed = {(c.get("classname"), c.get("name")) for c in cases if not len(c)}
-    # Every test the directory has today but the two left out, and the new
+    # Every test the directory has today but those left out, and the new
     # cell's own, which saw the copy's manifest: one cell more than this one.
-    assert len(passed) >= today - 2 + 2, inner.stdout[-2000:]
-    assert {("tests.benchmark.test_appended_metrics", name) for name in (
+    assert len(passed) >= today - len(LEFT_OUT) + 2, inner.stdout[-2000:]
+    assert {(f"tests.benchmark.{module}", name) for name in (
         "test_the_cell_is_declared", "test_this_run_reads_the_copy")} <= passed
+    return passed
+
+
+def append_counted_cell(m, name, mix, own, every):
+    """Append to ``m`` configuration ``name`` with a cell on traffic ``mix``,
+    metric ``own`` of that cell alone and ``every`` of every cell, both
+    counted by the batcher.  Returns the cell's name and the newest cell's
+    configuration under the new name, for the test to write as a file."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           m["workloads"][-1]["config"] + ".json")) as f:
+        cfg = json.load(f)
+    cell = append_cell(m, name, mix, 1, own)
+    counted = {**BASE, "source": "program_counter",
+               "layer": "scheduler and batcher"}
+    m["per_layer"][-1].update(counted)
+    m["per_layer"].append({"name": every, **counted})
+    cfg.update(name=name, source=m["configs"][-1]["source"])
+    m["configs"][-1]["reduced"] = cfg["reduced"]
+    return cell, cfg
+
+
+def test_a_real_cell_appended_to_a_copy_of_the_tree(tmp_path):
+    """What the driver's next ``model_config`` PR does, done with REAL files
+    to a copy of the benchmark's tree, and every test of this directory run
+    on the result: a check that cannot take an appended configuration, cell,
+    reader or entry, in any file here, fails in the PR that writes it."""
+    m = manifest()
+    tree = copy_of_the_tree(tmp_path)
+    # Entries at the END of their lists only: the newest cell's configuration
+    # under another name on the same traffic, one metric of the new cell
+    # alone and one of every cell, both counted by the batcher.
+    donor = m["workloads"][-1]
+    name = "appended-model-int8"
+    cell, cfg = append_counted_cell(m, name, donor["traffic"],
+                                    "appended_useful_share",
+                                    "appended_row_fill")
+    # New files only: the configuration, the cell's own test, and copies of
+    # accepted files under the new names: the golden and two ratio readers.
+    new = {
+        f"benchmark/configs/{name}.json": json.dumps(cfg),
+        "tests/benchmark/test_appended_metrics.py": APPENDED_TEST.format(
+            cell=cell, declared=(name, donor["traffic"], 1),
+            new=["appended_useful_share"], cells=len(m["workloads"]),
+            copy=tree)}
+    copies = {
+        f"benchmark/golden/{name}.json":
+            f"benchmark/golden/{donor['config']}.json",
+        "benchmark/layer_metrics/appended_useful_share.json":
+            "benchmark/layer_metrics/decode_useful_share.json",
+        "benchmark/layer_metrics/appended_row_fill.json":
+            "benchmark/layer_metrics/decode_row_fill.json"}
+    run_the_directory_on(tree, tmp_path, m, new, copies,
+                         "test_appended_metrics")
+
+
+def test_a_state_only_cell_appended_to_a_copy_of_the_tree(tmp_path):
+    """The same once more for the cell PR 49 made room for, whose rows hold
+    a recurrent state and no page: a configuration with ``paged_pages`` 0, a
+    ``must_dispatch`` and ``probe_bytes`` of its own (one probe past 4,096),
+    its golden of those lengths, a mix of 16 callers whose rows reach 30,721
+    tokens, the cell, two readers and the cell's own test, all NEW files and
+    appended entries.  The checks that count pages, name ``paged_decode`` or
+    count four probes pass on the copy without an edit."""
+    m = manifest()
+    tree = copy_of_the_tree(tmp_path)
+    name, mix = "state-model-int8", "long-rows"
+    cell, cfg = append_counted_cell(m, name, mix, "state_useful_share",
+                                    "state_row_fill")
+    probe_bytes = [32, 200, 700, 1500, 6000]
+    cfg["serve"] = {
+        "slots": 16, "max_len": 32768, "page_size": 64, "paged_pages": 0,
+        "chunk_steps": 8, "must_dispatch": ["quant_matmul", "state_decode"],
+        "probe_bytes": probe_bytes,
+        "extra_argv": [a for a in cfg["serve"]["extra_argv"]
+                       if a != "--prefix-cache"]}
+    golden = {"device_kind": "TPU v5 lite", "tolerance": run.GOLDEN_TOL,
+              "probes": [{"bytes": n, "logprobs": [-8.0] * run.PROBE_TOKENS}
+                         for n in probe_bytes]}
+    # Prompts 4,096-28,672 and answers 384-2,048, the longest together.
+    rows = [(4096 + 24576 * i // 15, 384 + 1664 * i // 15) for i in range(16)]
+    assert rows[0] == (4096, 384) and sum(rows[-1]) + 1 == 30721
+    traffic_file = {
+        "what": "16 callers, one long row each at a time, nothing shared",
+        "clients": 16, "preroll_s": 8, "rate_rps": None,
+        "sessions": [{"shared": 0, "turns": [[p, a]]} for p, a in rows]}
+    new = {
+        f"benchmark/configs/{name}.json": json.dumps(cfg),
+        f"benchmark/golden/{name}.json": json.dumps(golden),
+        f"benchmark/traffic/{mix}.json": json.dumps(traffic_file),
+        "tests/benchmark/test_state_metrics.py": APPENDED_TEST.format(
+            cell=cell, declared=(name, mix, 1), new=["state_useful_share"],
+            cells=len(m["workloads"]), copy=tree)}
+    copies = {
+        "benchmark/layer_metrics/state_useful_share.json":
+            "benchmark/layer_metrics/decode_useful_share.json",
+        "benchmark/layer_metrics/state_row_fill.json":
+            "benchmark/layer_metrics/decode_row_fill.json"}
+    passed = run_the_directory_on(tree, tmp_path, m, new, copies,
+                                  "test_state_metrics")
+    # The checks this cell could not have passed before PR 49 saw it.
+    assert {("tests.benchmark.test_traffic",
+             f"test_worst_case_fits_the_pool[{cell}]"),
+            ("tests.benchmark.test_held_to",
+             f"test_every_configuration_says_what_it_is_held_to[{name}.json]"),
+            ("tests.benchmark.test_manifest",
+             "test_golden_has_the_probes_the_harness_sends"),
+            ("tests.benchmark.test_traffic",
+             f"test_scripts_cover_each_cycle_once[{mix}]")} <= passed
 
 
 def test_benchmark_imports_no_jax():
@@ -399,29 +495,72 @@ def test_benchmark_imports_no_jax():
     subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
 
 
-@pytest.mark.parametrize("mix", ["rehearsal-docs"])
-def test_rehearsal_end_to_end(mix):
-    """benchmark/run.py on the CPU with the tiny preset: the shape of the
-    last line, and that it can never pass for a device result."""
+def rehearse(mix, *args):
+    """One ``--rehearsal`` of ``run.py`` on traffic file ``mix``: the process
+    as it ended and, where it printed one, its last line."""
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     out = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
          "--rehearsal", "--workload", mix, "--seed", str(2**31 + 5),
-         "--seconds", "3", "--trace", "0"],
+         "--seconds", "3", "--trace", "0", *args],
         capture_output=True, text=True, timeout=600, env=env, cwd=ROOT)
+    lines = [ln for ln in out.stdout.splitlines() if ln.startswith("{")]
+    return out, json.loads(lines[-1]) if lines else None
+
+
+@pytest.mark.parametrize("mix", ["rehearsal-docs"])
+def test_rehearsal_end_to_end(mix):
+    """benchmark/run.py on the CPU with the tiny preset: the shape of the
+    last line, and that it can never pass for a device result."""
+    out, last = rehearse(mix)
     assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
-    last = json.loads(out.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] is True and "metrics" not in last
     assert last["correct"] is True, out.stdout[-3000:]
     assert last["attempted"] > 10 and last["failed"] == 0
     assert last["device"]["platform"] == "cpu"
     assert {"prefix_hit_share", "gw_ttft_mean", "requests_in_window",
             "compiles_in_window"} <= set(last["counts"]["layer_metrics_read"])
+    # It says what it held the cell to: rehearsal-tiny's file says nothing.
+    assert last["held_to"] == {
+        "must_dispatch": ["quant_matmul", "paged_decode"], "paged_pages": 40,
+        "probe_bytes": [32, 200, 700, 1500]}
     # Without --rehearsal the name is looked up among the manifest's cells,
     # which run on the TPU or not at all: no result line.
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     bad = subprocess.run(
         [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
          "--workload", mix, "--seconds", "1"],
         capture_output=True, text=True, timeout=120, env=env, cwd=ROOT)
     assert bad.returncode != 0
     assert not any(ln.startswith("{") for ln in bad.stdout.splitlines())
+
+
+def test_rehearsal_without_a_pool():
+    """The whole of run.py against a server that has no pool
+    (``rehearsal-contiguous``: ``paged_pages`` 0, a ``must_dispatch`` and two
+    probes of its own): boot, probes, warm-up, pre-roll, window, and every
+    reader gives a number or nothing, never an exception."""
+    out, last = rehearse("rehearsal-chat",
+                         "--rehearsal-config", "rehearsal-contiguous")
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    assert last["rehearsal"] is True and last["correct"] is True, \
+        out.stdout[-3000:]
+    assert last["attempted"] > 10 and last["failed"] == 0
+    assert last["held_to"] == {
+        "must_dispatch": ["quant_matmul", "ragged_decode"], "paged_pages": 0,
+        "probe_bytes": [32, 90]}
+    assert "--paged-pages 0 " in out.stdout
+    assert "--prefix-cache" not in out.stdout
+    assert "tokens from the cache" not in out.stdout     # no comparison made
+    read = set(last["counts"]["layer_metrics_read"])
+    assert {"preemptions", "decode_row_fill", "queue_wait_mean",
+            "gw_ttft_mean", "requests_in_window"} <= read
+    assert "prefix_hit_share" not in read                # nothing to read
+    with open(os.path.join(ROOT, "chiprun_out", "benchmark",
+                           f"rehearsal-chat-s{2**31 + 5}-t0",
+                           "probes.json")) as f:
+        assert [p["bytes"] for p in json.load(f)["probes"]] == [32, 90]
+    # Only a file that says it is a rehearsal is rehearsed.
+    bad, none = rehearse("rehearsal-chat", "--rehearsal-config",
+                         manifest()["configs"][0]["name"])
+    assert bad.returncode != 0 and none is None

@@ -7,7 +7,7 @@ import os
 
 import pytest
 
-from benchmark import traffic
+from benchmark import run, traffic
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 MIXES = sorted(
@@ -80,15 +80,59 @@ def test_scripts_cover_each_cycle_once(mix):
 @pytest.mark.parametrize(
     "cell", [w["name"] for w in manifest()["workloads"]])
 def test_worst_case_fits_the_pool(cell):
+    """Every cell's mix fits the server its configuration asks for, by the
+    one function the run itself asks before it boots."""
     w = {w["name"]: w for w in manifest()["workloads"]}[cell]
     serve = config(w["config"])["serve"]
     spec = traffic.load(w["traffic"])
-    assert spec["clients"] <= serve["slots"]
-    # Page 0 is the pool's scratch page.
-    assert traffic.worst_case_pages(spec, serve["page_size"]) \
-        <= serve["paged_pages"] - 1
-    longest = max(p + a for _, p, a in traffic.lengths(spec))
-    assert longest + 1 <= serve["max_len"]
+    must = run.held_to(serve)["must_dispatch"]
+    assert traffic.pool_fits(spec, serve, must) == []
+
+
+def mix(clients, longest, answer=1):
+    """``clients`` callers who each send ``longest`` bytes and ask for
+    ``answer`` tokens, beside as many short requests."""
+    return {"clients": clients, "sessions": [
+        {"shared": 0, "turns": [[n, answer]]}
+        for n in (longest, 100) for _ in range(clients)]}
+
+
+POOL = {"slots": 16, "max_len": 4096, "page_size": 64, "paged_pages": 512}
+NO_POOL = {"slots": 16, "max_len": 32768, "page_size": 64, "paged_pages": 0}
+PAGED = ["quant_matmul", "paged_decode"]
+STATE = ["quant_matmul", "state_decode"]
+
+
+@pytest.mark.parametrize("spec, serve, must, faults", [
+    # A pool: 16 rows of 1 + 2,000 + 43 tokens are 16 x 32 = 512 pages, one
+    # more than 512 less the scratch page hold; 15 such rows fit.
+    (mix(16, 2000, 43), POOL, PAGED, ["worst case of 512 pages"]),
+    (mix(15, 2000, 43), POOL, PAGED, []),
+    (mix(16, 1900, 83), POOL, PAGED, []),
+    (mix(17, 100), POOL, PAGED, ["17 callers for 16 slots"]),
+    (mix(2, 4000, 96), POOL, PAGED, ["holds 4097 tokens, a row 4096"]),
+    (mix(2, 100), POOL, STATE, ["does not name paged_decode"]),
+    # No pool: no page is counted, so 16 rows of 30,721 tokens fit where
+    # they fit the rows (7,681 pages of 64 would fit no pool declared) ...
+    (mix(16, 28672, 2048), NO_POOL, STATE, []),
+    (mix(16, 28672, 2048), {**NO_POOL, "paged_pages": 7681}, PAGED,
+     ["worst case of 7696 pages"]),
+    (mix(17, 100), NO_POOL, STATE, ["17 callers for 16 slots"]),
+    (mix(16, 30720, 2048), NO_POOL, STATE,
+     ["holds 32769 tokens, a row 32768"]),
+    # ... and a file without a pool may ask for nothing that needs one.
+    (mix(2, 100), NO_POOL, PAGED, ["must_dispatch names paged_decode"]),
+    (mix(2, 100), {**NO_POOL, "extra_argv": ["--prefix-cache"]}, STATE,
+     ["and --prefix-cache"]),
+    (mix(17, 100), {**NO_POOL, "extra_argv": ["--prefix-cache"]}, PAGED,
+     ["17 callers", "names paged_decode", "and --prefix-cache"]),
+])
+def test_pool_fits(spec, serve, must, faults):
+    traffic.check(spec)
+    got = traffic.pool_fits(spec, serve, must)
+    assert len(got) == len(faults), got
+    for said, want in zip(got, faults):
+        assert want in said
 
 
 def test_doc_qa_asks_each_document_four_times_the_first_cold():
