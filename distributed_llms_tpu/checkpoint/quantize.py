@@ -232,8 +232,10 @@ _BIAS_NAMES = frozenset({"bq", "bk", "bv", "bo", "b_in", "b_out", "b_gate", "b_u
 # widths are not whole 128-wide blocks, and the up-projection W_kvb
 # [L, r, H * (nope + v)], which an admission reads as it lies and a decode
 # step transposed (the absorbed form): both in the model's dtype, so the two
-# paths read the same stored values.
-_HYBRID_FLOAT = frozenset({"taps", "router", "expert_bias", "wkv_a", "wkv_b"})
+# paths read the same stored values; of a retention layer (blocks/ret/...)
+# the gate's projection ``wg`` [L, D, KVH], 8 columns wide.
+_HYBRID_FLOAT = frozenset({"taps", "router", "expert_bias", "wkv_a", "wkv_b",
+                           "wg"})
 
 
 def block_axis_of(path: str) -> int:
@@ -247,7 +249,8 @@ def _should_quantize(path: str, x: Any) -> bool:
     if not hasattr(x, "ndim") or x.ndim < 2:
         return False
     leaf = path.split("/")[-1]
-    if path.startswith(("blocks/conv/", "blocks/moe/", "blocks/mla/")) \
+    if path.startswith(("blocks/conv/", "blocks/moe/", "blocks/mla/",
+                        "blocks/ret/")) \
             and leaf in _HYBRID_FLOAT:
         return False
     if "norm" in path or "ln" in path.split("/")[-2:][0]:

@@ -41,7 +41,7 @@ class ModelConfig:
     family: str = "gpt2"  # "gpt2" | "opt" | "llama" | "neox" | "hybrid"
     #   ("hybrid": layers stacked by KIND and walked in ``layer_types``'
     #   order, models.model.run_layers: LFM2's convolutions beside GQA,
-    #   A.X-K1's latent attention)
+    #   A.X-K1's latent attention, Brumby's power retention)
     vocab_size: int = 50257
     hidden_size: int = 768
     intermediate_size: int = 3072
@@ -160,13 +160,22 @@ class ModelConfig:
                 "(layers.moe_dropless): set moe_capacity=False"
             )
         if self.layer_types:
-            bad = set(self.layer_types) - {"conv", "attn", "mla", "swa"}
+            bad = set(self.layer_types) - {"conv", "attn", "mla", "swa",
+                                           "ret"}
             if bad or len(self.layer_types) != self.num_layers:
                 raise ValueError(
                     f"layer_types must name {self.num_layers} layers as "
-                    f"'conv', 'attn', 'swa' or 'mla', got "
+                    f"'conv', 'attn', 'swa', 'mla' or 'ret', got "
                     f"{self.layer_types!r}"
                 )
+        if "ret" in self.layer_types and (
+                set(self.layer_types) != {"ret"} or self.head_dim_ != 128
+                or not self.qk_norm or self.num_experts):
+            raise ValueError(
+                "power retention is every layer's or none's: layer_types "
+                "all 'ret', heads of 128 (the state's 65 x 128 x 128 "
+                "layout, ops/retention.py), qk_norm, no experts"
+            )
         if ("swa" in self.layer_types) != (
                 bool(self.layer_types) and self.sliding_window is not None):
             raise ValueError(
@@ -277,9 +286,14 @@ class ModelConfig:
     # Per-layer operator, for a model whose layers differ ("hybrid" family):
     # "conv" (gated short convolution, layers.short_conv), "attn" (GQA over
     # the whole prefix), "swa" (the same weights' shapes, over the last
-    # ``sliding_window`` positions) or "mla".  Empty for the families whose
-    # layers are all alike.
+    # ``sliding_window`` positions), "mla" or "ret" (power retention of
+    # degree 2: GQA's projections and a scalar gate a key/value head, the
+    # row's whole memory one float32 state a key/value head and no key:
+    # ops/retention.py).  Empty for the families whose layers are all alike.
     layer_types: tuple[str, ...] = ()
+    # Tokens a chunk of a "ret" layer's admission scan (ops/retention.py):
+    # the attention form inside a chunk, the state between chunks.
+    ret_chunk: int = 256
     # Whether a hybrid model's "attn" layers rotate queries and keys (its
     # "swa" layers always do).  False: no position enters a full layer
     # (EXAONE 4.0's hybrid attention).
@@ -386,6 +400,13 @@ class ModelConfig:
     def conv_layers(self) -> tuple[int, ...]:
         """Indices of the layers that keep convolution state a row."""
         return tuple(i for i, t in enumerate(self.layer_types) if t == "conv")
+
+    @property
+    def ret_layers(self) -> tuple[int, ...]:
+        """Indices of the power-retention layers, which keep a float32
+        state a row and a key/value head and no key
+        (kv_cache.HybridCache.ret_s / ret_z)."""
+        return tuple(i for i, t in enumerate(self.layer_types) if t == "ret")
 
 
 @dataclass(frozen=True)

@@ -590,6 +590,24 @@ METRIC_DOCS: dict[str, str] = {
     "batcher.pool_token_bytes": "bytes a resident token costs the page "
                                 "pool, every paged layer's (page_bytes over "
                                 "the page size; gauge)",
+    "batcher.ret_state_bytes": "bytes of the float32 state a model of "
+                               "power-retention layers keeps, one entry a "
+                               "layer, a batch slot and a key/value head "
+                               "(state and normaliser; its rows hold no "
+                               "key and it is served without a pool; gauge)",
+    # -- retention layers (models.model.retention_counts; real tokens
+    #    only, carried out of each admission and decode chunk, added at
+    #    delivery; a layer's, not summed over the layers) --
+    "ret.admit.tokens": "real prompt tokens the retention layers' chunked "
+                        "scan took in, summed over admissions",
+    "ret.admit.chunks": "chunks of ret_chunk tokens that scan walked: those "
+                        "that hold a real token",
+    "ret.decode.row_steps": "rows that took a recurrence step, summed over "
+                            "decode steps: each reads and writes its state "
+                            "once a layer",
+    "ret.decode.resident_tokens": "tokens those rows held, summed over "
+                                  "decode steps: what keys and values "
+                                  "would have had to be read for them",
     "attn.decode.resident_tokens": "tokens the decoding rows held, summed "
                                    "over decode steps: what the paged decode "
                                    "kernel read a full attention layer (a "

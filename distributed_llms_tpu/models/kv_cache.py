@@ -89,11 +89,20 @@ class HybridCache(KVCache):
     and values a row.  The key of position p lies at p mod W, already
     rotated, so order inside the ring does not matter, a row longer than
     the window overwrites what fell out of it, and a reader masks by count
-    (min(length, W)): a slot's ring may hold a finished row's leftovers."""
+    (min(length, W)): a slot's ring may hold a finished row's leftovers.
+    ``ret_s`` [ret layers, B, KVH, 65, 128, 128] and ``ret_z`` [ret layers,
+    B, KVH, 128, 128], both float32 whatever the activations' dtype: each
+    power-retention layer's state a row and a key/value head and its
+    normaliser (ops/retention.py says how the products of a key lie in
+    them).  They are a row's WHOLE memory in such a layer: a model of
+    retention layers alone holds no key, ``k``/``v`` count zero layers, and
+    it is served without a page pool (:func:`refuse_unpaged_state`)."""
 
     conv: Any = None
     ring_k: Any = None
     ring_v: Any = None
+    ret_s: Any = None
+    ret_z: Any = None
 
 
 @jax.tree_util.register_dataclass
@@ -143,8 +152,16 @@ def conv_state(cfg: ModelConfig, rows: int) -> jax.Array:
 def slot_state(cfg: ModelConfig, rows: int, dtype) -> dict:
     """What a :class:`HybridCache` holds beside k and v for ``rows`` rows,
     zeroed, by field: the convolution layers' state, the windowed layers'
-    rings (in the keys' dtype)."""
+    rings (in the keys' dtype), the retention layers' state and normaliser
+    (float32: ``ops.retention.state_shapes``)."""
     out = {}
+    if cfg.ret_layers:
+        from ..ops.retention import state_shapes
+
+        s, z = state_shapes(cfg.num_kv_heads)
+        lead = (len(cfg.ret_layers), rows)
+        out["ret_s"] = jnp.zeros(lead + s, jnp.float32)
+        out["ret_z"] = jnp.zeros(lead + z, jnp.float32)
     if cfg.conv_layers:
         out["conv"] = conv_state(cfg, rows)
     if cfg.swa_layers:
@@ -217,8 +234,9 @@ def page_bytes(cfg: ModelConfig, page_size: int, kv_bits: int = 16,
 def format_bytes(pool, cfg: ModelConfig) -> dict[str, float]:
     """Sizes that only one format has, for its gauges: the bytes of the
     state a :class:`HybridCache` keeps beside its pages (``conv_state``,
-    and ``window_state``: the windowed layers' rings), the bytes of one
-    page of a :class:`LatentCache` (``latent_page``)."""
+    ``window_state``: the windowed layers' rings, ``ret_state``: the
+    retention layers' state and normaliser), the bytes of one page of a
+    :class:`LatentCache` (``latent_page``)."""
     match pool:
         case HybridCache():
             sizes = {}
@@ -227,12 +245,49 @@ def format_bytes(pool, cfg: ModelConfig) -> dict[str, float]:
             if pool.ring_k is not None:
                 sizes["window_state"] = float(
                     pool.ring_k.nbytes + pool.ring_v.nbytes)
+            if pool.ret_s is not None:
+                sizes["ret_state"] = float(
+                    pool.ret_s.nbytes + pool.ret_z.nbytes)
             return sizes
         case LatentCache():
             return {"latent_page": float(
                 page_bytes(cfg, pool.k.shape[2], dtype=pool.k.dtype))}
         case _:
             return {}
+
+
+# The leaves of a :class:`HybridCache` that hold one entry a batch slot
+# ([layers of the kind, B, ...]) and no page.
+_SLOT_FIELDS = ("conv", "ring_k", "ring_v", "ret_s", "ret_z")
+
+
+def splice_slot(cache: "HybridCache", slot, row_cache: "HybridCache"):
+    """``cache`` with batch slot ``slot`` of every leaf that holds one
+    entry a slot taken from the one-row ``row_cache`` (all of it: whatever
+    the slot's last row left is overwritten), where the leaves lie."""
+    return dataclasses.replace(cache, **{
+        f: jax.lax.dynamic_update_slice_in_dim(
+            state, getattr(row_cache, f).astype(state.dtype), slot, axis=1)
+        for f in _SLOT_FIELDS if (state := getattr(cache, f)) is not None})
+
+
+def splice_row(cache, slot, row_cache):
+    """Overwrite batch row ``slot`` of a CONTIGUOUS cache (the batcher
+    without a pool) with a prefilled single-row cache: keys and values
+    (leaves end in [..., B, S, KVH, HD]: the batch axis is the 4th from the
+    right), or, for a model of retention layers, whose stacks of keys count
+    zero layers, the row's state (:func:`splice_slot`)."""
+    def splice(full, row):
+        start = [0] * full.ndim
+        start[full.ndim - 4] = slot
+        return jax.lax.dynamic_update_slice(
+            full, row.astype(full.dtype), tuple(start)
+        )
+
+    if isinstance(cache, HybridCache):
+        return splice_slot(cache, slot, row_cache)
+    return KVCache(k=splice(cache.k, row_cache.k),
+                   v=splice(cache.v, row_cache.v))
 
 
 def _paged_fields(pool) -> tuple[str, ...]:
@@ -432,14 +487,10 @@ def write_row(pool, page_list: jax.Array, row_cache, slot=None):
                         for f in _row_fields(pool))),
     )
     new = dict(zip(fields, leaves))
-    match pool:
-        case HybridCache():
-            for f in ("conv", "ring_k", "ring_v"):
-                if (state := getattr(pool, f)) is not None:
-                    new[f] = jax.lax.dynamic_update_slice_in_dim(
-                        state, getattr(row_cache, f).astype(state.dtype),
-                        slot, axis=1)
-    return dataclasses.replace(pool, **new)
+    pool = dataclasses.replace(pool, **new)
+    if isinstance(pool, HybridCache):
+        pool = splice_slot(pool, slot, row_cache)
+    return pool
 
 
 @jax.jit
@@ -534,7 +585,7 @@ def pages_are_private(cfg: ModelConfig) -> bool:
     :class:`HybridCache`).  Only then may heads narrower than
     a 128-lane row lie folded in the pool
     (ops.decode_attn.pool_head_shape; heads of 128 never fold)."""
-    return bool(cfg.conv_layers or cfg.swa_layers)
+    return bool(cfg.conv_layers or cfg.swa_layers or cfg.ret_layers)
 
 
 _LATENT_REFUSALS = {
@@ -589,6 +640,34 @@ _RING_REFUSALS = {
 }
 
 
+_STATE_REFUSALS = {
+    "paged_pages": "its rows hold no key and no value to page: serve it "
+                   "without a pool (--paged-pages 0)",
+    "prefix_cache": "it runs over the page pool, and a prefix's state is "
+                    "no page: nothing snapshots the state at a prefix's end",
+    "kv_bits": "the int8 pool quantizes keys and values; the state is "
+               "float32 and is never quantized (ask for kv_bits 16)",
+    "host_pages": "the host tier parks pages; nothing parks a row's 34 MB "
+                  "a layer of state",
+    "speculative": "a rejected draft would have to roll the state back, "
+                   "and a state keeps no past",
+    "prefill_chunk": "a chunked prefill would have to hand the state from "
+                     "bite to bite",
+    "token_budget": "it chunks prefills, which would have to hand the "
+                    "state from bite to bite",
+    "mesh": "the state has no sharding rule yet (mesh.model > 1 included)",
+    "named_prefix": "a registered prefix keeps keys and values; nothing "
+                    "snapshots the state at its end",
+    "kv_import": "KV import/export ships pages, and the state is none",
+    "kv_export": "KV import/export ships pages, and the state is none",
+    "sessions": "a session keeps keys and values between turns, not the "
+                "state",
+    "padded_generate": "generate_text pads rows of unlike length, and the "
+                       "state would be taken at the padded end; serve "
+                       "through continuous_batcher",
+}
+
+
 def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
     """Refuse, by name and with the reason, every feature that moves or
     keeps keys and values and does not yet carry the state a hybrid model
@@ -599,6 +678,11 @@ def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
     rings' sake (``_RING_REFUSALS``).  ``asked`` maps a
     feature's name to whether it was asked for; ``paged_pages`` is the one
     that must be set.
+
+    A model of power-retention layers (``cfg.ret_layers``) holds a state
+    and no key: it is served WITHOUT a pool (``paged_pages`` is refused),
+    and ``_STATE_REFUSALS`` names what cannot snapshot, ship or roll back
+    the state.
 
     The latent format (:class:`LatentCache`) refuses here too, with its own
     reasons: what is written for key/value page pairs and has no latent
@@ -616,6 +700,19 @@ def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
                 raise ValueError(
                     f"{name} is not supported for latent (MLA) pages: "
                     f"{_LATENT_REFUSALS[name]}"
+                )
+        return
+    if cfg.ret_layers:
+        # A model of power-retention layers: a row's whole memory is its
+        # float32 state, one entry a batch slot.  Served WITHOUT a pool,
+        # and whatever would snapshot, ship or roll back the state is
+        # refused until something can.
+        for name, value in asked.items():
+            if value:
+                raise ValueError(
+                    f"{name} is not supported for a model whose rows hold "
+                    f"a recurrent state and no key (family {cfg.family!r}"
+                    f", power retention): {_STATE_REFUSALS[name]}"
                 )
         return
     if not pages_are_private(cfg):

@@ -870,6 +870,90 @@ def mixed_attention(
             jnp.take_along_axis(v, take, axis=1).astype(cache.ring_v.dtype)))
 
 
+def retention_layer(
+    x: jax.Array,  # [B, T, D], normed
+    p: Params,  # wq, wk, wv, wo, q_norm, k_norm, wg
+    cfg: ModelConfig,
+    call: Call,
+    cache: kv_cache.HybridCache | None,  # the slots' states (a decode
+    #   step) or a fresh row's (an admission); None: nothing is kept
+    layer: jax.Array | int,  # index among the retention layers
+) -> tuple[jax.Array, kv_cache.HybridCache | None]:
+    """Power retention of degree 2 (layer kind "ret"; ops/retention.py has
+    the equations and the state's layout).  GQA's projections, q and k
+    RMS-normalised per head and rotated, and a scalar gate a token and a
+    key/value head, ``log g = logsigmoid(x w_g)`` in float32.  A decode
+    step ("decode", one token a row) is one recurrence step against the
+    slots' states, which are updated where they lie; a row that does not
+    decode (``call.seq_lens`` 0) keeps its state.  Otherwise the T tokens
+    are a row's start ("plain", or "start": an admission) and run the
+    chunked scan from an empty state, ``cfg.ret_chunk`` tokens a chunk,
+    leaving in ``cache`` the state at the ``call.seq_lens`` REAL tokens
+    (None: all T): a padded position adds nothing and gates nothing.  A
+    state keeps no past, so there is nothing to continue from."""
+    from ..ops import retention
+
+    with jax.named_scope("ret_qkvg"):
+        q, k, v = layers.qkv_project(x, p, cfg)
+        q = layers.rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = layers.rms_norm(k, p["k_norm"], cfg.norm_eps)
+        q = layers.apply_rope(q, call.positions, cfg.rope_theta)
+        k = layers.apply_rope(k, call.positions, cfg.rope_theta)
+        log_g = jax.nn.log_sigmoid(jnp.einsum(
+            "btd,dh->bth", x, p["wg"].astype(x.dtype),
+            preferred_element_type=jnp.float32))
+    b, t = x.shape[:2]
+    if call.kind == "decode":
+        if t != 1 or cache is None:
+            raise ValueError(
+                "a retention layer decodes one token a row against the "
+                "slots' states"
+            )
+        live = None if call.seq_lens is None else call.seq_lens > 0
+        with jax.named_scope("retention"):
+            o, ret_s, ret_z = retention.retention_decode(
+                q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], cache.ret_s,
+                cache.ret_z, layer, live)
+        return layers.out_project(o[:, None], p), dataclasses.replace(
+            cache, ret_s=ret_s, ret_z=ret_z)
+    if call.kind in ("continuation", "masked"):
+        raise ValueError(
+            "a retention layer prefills a row from its start (cache_index "
+            "0, no mask and no map of the caller's): the state holds no "
+            "prefix to continue from"
+        )
+    with jax.named_scope("retention"):
+        rows = [retention.retention_prefill(
+            q[i], k[i], v[i], log_g[i],
+            None if call.seq_lens is None else call.seq_lens[i],
+            cfg.ret_chunk) for i in range(b)]
+    out = layers.out_project(jnp.stack([r[0] for r in rows]), p)
+    if cache is None:
+        return out, None
+    return out, dataclasses.replace(
+        cache,
+        ret_s=cache.ret_s.at[layer].set(jnp.stack([r[1] for r in rows])),
+        ret_z=cache.ret_z.at[layer].set(jnp.stack([r[2] for r in rows])))
+
+
+def retention_counts(cfg: ModelConfig, call: Call, shape: tuple) -> jax.Array:
+    """What a pass of a retention model did, int32 [4], a by-product like
+    the expert layers' counts (:func:`run_layers`' third value): the real
+    tokens an admission scanned and the chunks it walked (those that hold
+    a real token), the rows a decode step advanced and the tokens those
+    rows then held, the new one included.  A layer, not summed over them."""
+    b, t = shape
+    lens = (jnp.full((b,), t, jnp.int32) if call.seq_lens is None
+            else call.seq_lens.astype(jnp.int32))
+    zero = jnp.zeros((), jnp.int32)
+    if call.kind == "decode":
+        held = jnp.where(lens > 0, call.cache_index + 1, 0)
+        return jnp.stack([zero, zero, jnp.sum((lens > 0).astype(jnp.int32)),
+                          jnp.sum(held, dtype=jnp.int32)])
+    return jnp.stack([jnp.sum(lens), jnp.sum(-(-lens // cfg.ret_chunk)),
+                      zero, zero])
+
+
 def gpt2_block(x, p, cfg, call, layer_cache, layer=None):
     """-> (x, new_cache, aux): aux is the MoE load-balance term (0 here).
     Shared by the gpt2 and opt families (pre-LN + learned positions);
@@ -1077,6 +1161,8 @@ def run_layers(
     by-product like the dense families' aux loss and no part of the
     state."""
     moe = jnp.zeros((4 if cfg.experts_held is None else 5,), jnp.int32)
+    if cfg.ret_layers:  # the retention layers' counts ride there instead
+        moe = retention_counts(cfg, call, x.shape[:2])
     rows, token_mask, paged = (
         call.rows, call.token_mask, call.kv_tables is not None)
 
@@ -1100,6 +1186,8 @@ def run_layers(
             if cache is not None:
                 cache = dataclasses.replace(cache, conv=cache.conv.at[
                     at[op]].set(new.astype(cache.conv.dtype)))
+        elif op == "ret":
+            out, cache = retention_layer(h, p, cfg, call, cache, at[op])
         elif cfg.swa_layers:  # windowed and full attention layers mixed
             with jax.named_scope("swa_attn" if op == "swa" else "full_attn"):
                 out, cache = mixed_attention(
@@ -1175,7 +1263,7 @@ def run_layers(
                 moe + jnp.sum(stats, axis=0))
 
     carry = (x, cache, moe)
-    base = dict(conv=0, attn=0, swa=0, mla=0, dense=0, moe=0)
+    base = dict(conv=0, attn=0, swa=0, mla=0, ret=0, dense=0, moe=0)
     for unit, reps in layer_runs(cfg):
         kinds = [kind for pair in unit for kind in pair]
         per_unit = {kind: kinds.count(kind) for kind in base}
@@ -1205,7 +1293,7 @@ def hybrid_layers(params: Params, cfg: ModelConfig):
     order: dicts {"ln1", "ln2": {"scale"}, "conv" | "attn": {...}, "mlp":
     {...}} as models/reference/lfm2_moe.py reads them.  A generator, so a
     caller that dequantizes what it is handed holds one layer in float32."""
-    at = dict(conv=0, attn=0, swa=0, mla=0, dense=0, moe=0)
+    at = dict(conv=0, attn=0, swa=0, mla=0, ret=0, dense=0, moe=0)
     blocks = params["blocks"]
 
     def take(kind):
@@ -1489,7 +1577,8 @@ def _init_hybrid_blocks(rng: jax.Array, cfg: ModelConfig, dtype: Any) -> Params:
             "wo": dense("mla/wo", (NA, H * DV, D)),
         }
     for kind, n in (("attn", 0 if cfg.kv_lora_rank else NA),
-                    ("swa", len(cfg.swa_layers))):
+                    ("swa", len(cfg.swa_layers)),
+                    ("ret", len(cfg.ret_layers))):
         if not n:
             continue
         blocks[kind] = {
@@ -1505,6 +1594,9 @@ def _init_hybrid_blocks(rng: jax.Array, cfg: ModelConfig, dtype: Any) -> Params:
         if cfg.qk_norm:
             blocks[kind]["q_norm"] = jnp.ones((n, HD), dtype)
             blocks[kind]["k_norm"] = jnp.ones((n, HD), dtype)
+        if kind == "ret":  # the gate: a scalar a token a key/value head,
+            # in the model's dtype (checkpoint.quantize leaves it float)
+            blocks[kind]["wg"] = dense("ret/wg", (n, D, KVH))
     if NM:
         EH = cfg.held_experts  # (a chip's share; the router scores all E)
         blocks["moe"] = {

@@ -184,7 +184,27 @@ PRESETS: dict[str, ModelConfig] = {
         moe_score_fn="softmax", moe_router_input="block_input",
         moe_capacity=False, n_shared_experts=0,
     ),
+    # Brumby-14B-Base (manifestai/Brumby-14B-Base), stage 0 of a four-chip
+    # host cut into 4 pipeline stages of 10 whole layers: 10 of the 40
+    # power-retention layers (40 query heads over 8 key/value heads of 128,
+    # Qwen3's per-head norm on q and k, the rotation kept) in front of a
+    # SwiGLU of 17,408, the whole vocabulary, embedding and head untied
+    # (benchmark/configs/brumby-14b-int8.json has the cut's arithmetic).
+    "brumby-pp4": ModelConfig(
+        family="hybrid", vocab_size=151936, hidden_size=5120,
+        intermediate_size=17408, num_layers=10, num_heads=40,
+        num_kv_heads=8, head_dim=128, max_seq_len=32768, rope_theta=1e6,
+        norm_eps=1e-6, tie_embeddings=False, qk_norm=True,
+        layer_types=("ret",) * 10,
+    ),
     # Tiny configs for unit tests / CPU fake-mesh integration tests.
+    "brumby-tiny": ModelConfig(
+        family="hybrid", vocab_size=256, hidden_size=64,
+        intermediate_size=224, num_layers=2, num_heads=10, num_kv_heads=2,
+        head_dim=128, max_seq_len=512, rope_theta=1e6, norm_eps=1e-6,
+        tie_embeddings=False, dtype="float32", qk_norm=True,
+        layer_types=("ret",) * 2, ret_chunk=64,
+    ),
     "smallthinker-tiny": ModelConfig(
         family="hybrid", vocab_size=256, hidden_size=64,
         intermediate_size=32, moe_intermediate_size=32, num_layers=8,

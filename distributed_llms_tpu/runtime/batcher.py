@@ -108,19 +108,9 @@ log = get_logger("batcher")
 _SPEC_EMA_ALPHA = 0.2
 
 
-def _splice_row(cache, slot, row_cache):
-    """Overwrite batch row ``slot`` of a contiguous cache with a prefilled
-    single-row cache (leaves end in [..., B, S, KVH, HD]: the batch axis
-    is the 4th from the right)."""
-    def splice(full, row):
-        start = [0] * full.ndim
-        start[full.ndim - 4] = slot
-        return jax.lax.dynamic_update_slice(
-            full, row.astype(full.dtype), tuple(start)
-        )
-
-    return KVCache(k=splice(cache.k, row_cache.k),
-                   v=splice(cache.v, row_cache.v))
+# Overwrite batch row ``slot`` of a contiguous cache with a prefilled
+# single-row cache: how a row lies there is models/kv_cache.py's.
+_splice_row = kv_cache.splice_row
 
 
 def _fwd(pm):
@@ -295,7 +285,7 @@ def admit_row(
     real_lens/budget bookkeeping is the caller's.  The transient row cache is deliberately NOT
     mesh-constrained: batch 1 can't shard over 'data'; XLA places it (TP
     still shards the matmuls via the weights)."""
-    logits, row_cache = _prefill_row(
+    logits, row_cache, *counts = _prefill_row(
         _fwd(pm), params, cfg, cache.k.dtype, cache.k.shape[-3], prompt, plen
     )
     cache, tok, row_valid, lp = _finish_admission(
@@ -303,7 +293,9 @@ def admit_row(
         total_len=plen, temp_req=temp_req, topp_req=topp_req,
         topk_req=topk_req, mask_req=mask_req,
     )
-    return (cache, *_replicated(pm, tok, row_valid, lp))
+    # (a hybrid-family model's counts of the pass ride out last: the
+    # retention layers' tokens and chunks, see _prefill_row)
+    return (cache, *_replicated(pm, tok, row_valid, lp), *counts)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))
@@ -969,14 +961,15 @@ def _decode_steps(
         # write; the prefix mask is implicit.  Contiguous mode: the mask
         # admits each row's valid slots plus the slot its own token was
         # just written to.
+        # A hybrid model's state that is not keys and values (convolution
+        # state, rings, a retention layer's state) advances only for the
+        # rows that decode (seq_lens 1), so a finished or free row neither
+        # moves its own state nor is counted, and the step's counts (the
+        # expert layers'; a retention model's) come out beside the logits
+        # (return_aux).
+        state = ({"seq_lens": active.astype(jnp.int32), "return_aux": True}
+                 if cfg.family == "hybrid" else {})
         if tables is not None:
-            # A hybrid model's state that is not paged advances only for
-            # the rows that decode (seq_lens 1), so a finished or free row
-            # neither moves its own state nor is counted by the experts,
-            # whose counts come out beside the logits (return_aux).
-            state = ({"seq_lens": active.astype(jnp.int32),
-                      "return_aux": True}
-                     if cfg.family == "hybrid" else {})
             logits, cache, *aux = _fwd(pm)(
                 params, cfg, last_tok[:, None], positions=real_lens[:, None],
                 cache=cache, cache_index=real_lens, kv_tables=tables,
@@ -1000,10 +993,13 @@ def _decode_steps(
                     moe, jnp.zeros((5 - moe.shape[0],), jnp.int32), *read])
         else:
             mask = (valid | (slots[None, :] == real_lens[:, None]))[:, None, None, :]
-            logits, cache = _fwd(pm)(
+            # (a model of retention layers is the one hybrid served here,
+            # without a pool)
+            logits, cache, *aux = _fwd(pm)(
                 params, cfg, last_tok[:, None], positions=real_lens[:, None],
-                cache=cache, cache_index=real_lens, attn_mask=mask,
+                cache=cache, cache_index=real_lens, attn_mask=mask, **state,
             )
+            moe = aux[0] if aux else None
         logits = logits[:, 0]
         # The row just wrote last_tok's K/V at slot real_lens; mark it valid
         # for rows that were active (inactive rows wrote junk into a slot
@@ -1809,7 +1805,8 @@ class ContinuousBatcher:
             self.cfg_decode = dataclasses.replace(cfg, ragged_decode=True)
         else:
             self.cfg_decode = cfg
-            if paged_pages is None:  # paged decode records its own path
+            if paged_pages is None and cfg.attn_layers:  # (paged decode
+                # records its own path; a model without keys has none)
                 dispatch.record(
                     "ragged_decode", "fallback",
                     (batch_slots, max_len, cfg.num_heads, cfg.num_kv_heads,
@@ -1898,6 +1895,10 @@ class ContinuousBatcher:
                 cfg, batch_slots, cache_len,
                 dtype=jnp.dtype(kv_dtype) if kv_dtype else None,
             )
+            sizes = kv_cache.format_bytes(self.cache, cfg)
+            if "ret_state" in sizes:
+                METRICS.set_gauge("batcher.ret_state_bytes",
+                                  sizes["ret_state"])
         if self.speculative:
             self.draft_cache = kv_cache.init_cache(
                 draft_cfg, batch_slots, cache_len,
@@ -3448,6 +3449,8 @@ class ContinuousBatcher:
                             self.cfg, behind, self.s, held + behind))
                     prev = self._admit_inflight
                     ahead = {} if prev is None else {"fetched_rid": prev.req.rid}
+                    if self.cfg.ret_layers:  # the chunks the scan walks
+                        ahead["chunks"] = -(-total_len // self.cfg.ret_chunk)
                     with self._span(
                         "batcher.admit.row", rid=req.rid,
                         prompt_tokens=total_len, cached_tokens=cached_len,
@@ -3549,7 +3552,7 @@ class ContinuousBatcher:
                                 self._split_rng(), pm=self.pm, **self.sampling, **extra,
                             )
                         else:
-                            self.cache, tok, row_valid, lp = self._launch(
+                            self.cache, tok, row_valid, lp, *moe = self._launch(
                                 admit_row,
                                 self.params, self.cfg, self.cache, jnp.int32(i),
                                 jnp.asarray(prompt), jnp.int32(len(req.ids)),
@@ -4594,6 +4597,16 @@ class ContinuousBatcher:
         if stats is None:
             return
         counts = [int(x) for x in stats]
+        if self.cfg.ret_layers:
+            # A model of retention layers hands out its own four instead
+            # (models.model.retention_counts): an admission's real tokens
+            # and the chunks that hold one, a decode chunk's row-steps and
+            # the tokens those rows held.
+            METRICS.inc("ret.admit.tokens", counts[0])
+            METRICS.inc("ret.admit.chunks", counts[1])
+            METRICS.inc("ret.decode.row_steps", counts[2])
+            METRICS.inc("ret.decode.resident_tokens", counts[3])
+            return
         METRICS.inc("moe.routed_pairs", counts[0])
         METRICS.inc("moe.layer_passes", counts[1])
         METRICS.inc("moe.experts_touched", counts[2])

@@ -166,3 +166,64 @@ def test_the_host_side_of_the_pool_imports_no_jax():
             "assert p.PagePool and p.PrefixCache.page_digests")
     subprocess.run([sys.executable, "-c", code], check=True,
                    cwd=PACKAGE.parent)
+
+
+# -- a state a slot and no page: a model of retention layers (PR 50) --------
+
+def test_a_retention_models_cache_is_its_state_and_no_key():
+    """``slot_state`` sizes the leaves: [ret layers, rows, KVH, 65, 128,
+    128] float32 and its normaliser [.., 128, 128], whatever the
+    activations' dtype; the stacks of keys and values count zero layers."""
+    from distributed_llms_tpu.ops import retention
+
+    cfg = dataclasses.replace(get_preset("brumby-tiny"), dtype="bfloat16")
+    state = kv_cache.slot_state(cfg, 3, jnp.bfloat16)
+    assert set(state) == {"ret_s", "ret_z"}
+    assert state["ret_s"].shape == (2, 3, 2, 65, 128, 128)
+    assert state["ret_z"].shape == (2, 3, 2, 128, 128)
+    assert state["ret_s"].dtype == state["ret_z"].dtype == jnp.float32
+    cache = kv_cache.init_cache(cfg, 3, 64)
+    assert isinstance(cache, kv_cache.HybridCache)
+    assert cache.k.shape == cache.v.shape == (0, 3, 64, 2, 128)
+    assert cache.conv is None and cache.ring_k is None
+    assert kv_cache.format_bytes(cache, cfg) == {
+        "ret_state": 3.0 * 2 * retention.state_bytes(2)}
+    assert retention.state_bytes(8) == 34_603_008  # a row a layer, served
+    assert kv_cache.pages_are_private(cfg)
+    # the other hybrids' caches carry no such leaf
+    other = kv_cache.make_pool(get_preset("lfm2-tiny"), PAGES, BLK,
+                               slots=SLOTS)
+    assert other.ret_s is None and other.ret_z is None
+
+
+def test_splice_slot_overwrites_one_slots_state_and_no_others():
+    cfg = get_preset("brumby-tiny")
+    keys = iter(jax.random.split(jax.random.key(3), 8))
+    noise = lambda x: jax.random.normal(next(keys), x.shape).astype(x.dtype)
+    cache = jax.tree.map(noise, kv_cache.init_cache(cfg, 3, 16))
+    row = jax.tree.map(noise, kv_cache.init_cache(cfg, 1, 16))
+    out = kv_cache.splice_slot(cache, jnp.int32(1), row)
+    for f in ("ret_s", "ret_z"):
+        got = np.asarray(getattr(out, f))
+        np.testing.assert_array_equal(got[:, 1], np.asarray(
+            getattr(row, f))[:, 0])
+        np.testing.assert_array_equal(
+            got[:, [0, 2]], np.asarray(getattr(cache, f))[:, [0, 2]])
+
+
+def test_the_states_refusal_table_names_every_feature_with_a_reason():
+    cfg = get_preset("brumby-tiny")
+    names = set(kv_cache._STATE_REFUSALS)
+    assert names == set(kv_cache._RING_REFUSALS) | {"paged_pages"}
+    assert names - {"prefix_cache", "paged_pages"} == set(
+        kv_cache._LATENT_REFUSALS)
+    for name, why in kv_cache._STATE_REFUSALS.items():
+        with pytest.raises(ValueError) as e:
+            kv_cache.refuse_unpaged_state(cfg, **{name: True})
+        assert str(e.value).startswith(f"{name} is not supported")
+        assert why in str(e.value)
+    # nothing asked for, nothing refused: served WITHOUT a pool
+    kv_cache.refuse_unpaged_state(
+        cfg, paged_pages=None, prefix_cache=False, kv_bits=False,
+        host_pages=0, speculative=False, prefill_chunk=None,
+        token_budget=None, mesh=False)

@@ -342,7 +342,8 @@ def _host_reads_outside_wait_device(b, monkeypatch):
     return outside
 
 
-@pytest.mark.parametrize("kind", ["fresh", "prefix-hit", "experts"])
+@pytest.mark.parametrize("kind", ["fresh", "prefix-hit", "experts",
+                                  "retention"])
 def test_no_blocking_fetch_outside_a_wait_device_span(tiny, monkeypatch, kind):
     """On the paths the benchmark's cells run (admit_row_paged,
     admit_row_auto_paged behind cached pages, decode_chunk, and an expert
@@ -355,8 +356,14 @@ def test_no_blocking_fetch_outside_a_wait_device_span(tiny, monkeypatch, kind):
         b = ContinuousBatcher(cfg, model_lib.init_params(jax.random.key(0), cfg),
                               batch_slots=3, max_len=64, chunk_steps=4,
                               paged_pages=24, page_size=8)
+    elif kind == "retention":  # (no pool: admit_row and the contiguous
+        # decode_chunk, whose counts of the state's work ride out too)
+        cfg = presets.get_preset("brumby-tiny")
+        b = ContinuousBatcher(cfg, model_lib.init_params(jax.random.key(0), cfg),
+                              batch_slots=3, max_len=64, chunk_steps=4)
     else:
         b = paged(tiny, prefix_cache=kind == "prefix-hit")
+    ret0 = METRICS.get_counter("ret.decode.row_steps")
     doc = list(range(40, 75))                 # two full pages and a bit
     if kind == "prefix-hit":
         b.submit(doc + [3], max_new_tokens=2)
@@ -373,6 +380,8 @@ def test_no_blocking_fetch_outside_a_wait_device_span(tiny, monkeypatch, kind):
         assert METRICS.get_counter("batcher.prefix_cache.hit_tokens") > hits0
     if kind == "experts":
         assert METRICS.get_counter("moe.layer_passes") > moe0
+    if kind == "retention":  # 8 and 5 decode steps behind the admissions
+        assert METRICS.get_counter("ret.decode.row_steps") == ret0 + 13
 
 
 def _spec_models():
@@ -585,6 +594,103 @@ def test_the_router_runs_before_the_operator_and_every_scope_is_named():
     assert first_seen("smallthinker-tiny") == [
         "moe_route", "full_attn", "moe_experts", "swa_attn", "head"]
     assert first_seen("k-exaone-tiny")[:2] == ["swa_attn", "moe_route"]
+
+
+def test_a_retention_models_scopes_in_program_order():
+    """``brumby-tiny``: the projections, norms, rotation and gate under
+    ``ret_qkvg``, then the operator under ``retention``, then ``mlp``, a
+    layer; ``head`` last; all in the lowered program's locations, in an
+    admission's program and in a decode step's."""
+    from distributed_llms_tpu.models import kv_cache, model as model_lib
+    from distributed_llms_tpu.models.presets import get_preset
+
+    cfg = get_preset("brumby-tiny")
+    params = jax.eval_shape(
+        lambda: model_lib.init_params(jax.random.key(0), cfg))
+    cache = jax.eval_shape(lambda: kv_cache.init_cache(cfg, 2, 32))
+
+    def admission(params):
+        return model_lib.forward(params, cfg, np.zeros((1, 16), np.int32))[0]
+
+    def step(params, cache):
+        lens = np.asarray([5, 9], np.int32)
+        return model_lib.forward(
+            params, cfg, np.zeros((2, 1), np.int32), positions=lens[:, None],
+            cache=cache, cache_index=lens,
+            seq_lens=np.ones((2,), np.int32))[0]
+
+    for fn, args in ((admission, (params,)), (step, (params, cache))):
+        order = []
+        for eqn in _walk_eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+            stack = str(eqn.source_info.name_stack)
+            for scope in ("ret_qkvg", "retention", "mlp", "head"):
+                if scope in stack and scope not in order:
+                    order.append(scope)
+        assert order == ["ret_qkvg", "retention", "mlp", "head"]
+        text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+        assert all(scope in text for scope in order)
+
+
+def test_a_retention_models_counters_are_added_at_delivery(monkeypatch):
+    """``ret.*`` leave each admission and each decode chunk as outputs and
+    are added on the host: a chunk's inside ``batcher.loop.deliver``, an
+    admission's behind its one fetch inside ``batcher.admit.row``, whose
+    span carries the ``chunks`` the scan walks; the gauge is the slots'
+    state."""
+    from distributed_llms_tpu.models import model as model_lib
+    from distributed_llms_tpu.models.presets import get_preset
+
+    for name in ("ret.admit.tokens", "ret.admit.chunks",
+                 "ret.decode.row_steps", "ret.decode.resident_tokens",
+                 "batcher.ret_state_bytes"):
+        assert name in METRIC_DOCS
+    cfg = get_preset("brumby-tiny")
+    params = model_lib.init_params(jax.random.key(0), cfg)
+    b = batcher_mod.ContinuousBatcher(
+        cfg, params, batch_slots=2, max_len=128, chunk_steps=4, eos_id=-1)
+    open_spans, seen, attrs = [], [], []
+    span = b._span
+
+    class watched:
+        def __init__(self, name, **kw):
+            self.name, self.inner = name, span(name, **kw)
+            if name == "batcher.admit.row":
+                attrs.append(kw)
+
+        def __enter__(self):
+            open_spans.append(self.name)
+            return self.inner.__enter__()
+
+        def __exit__(self, *exc):
+            open_spans.pop()
+            return self.inner.__exit__(*exc)
+
+    inc = METRICS.inc
+
+    def watching(name, n=1):
+        if name.startswith("ret."):
+            seen.append((name, n, tuple(open_spans)))
+        return inc(name, n)
+
+    monkeypatch.setattr(b, "_span", watched)
+    monkeypatch.setattr(METRICS, "inc", watching)
+    before = METRICS.snapshot()["counters"]
+    b.submit(list(range(1, 71)), max_new_tokens=6)  # 70 tokens: 2 chunks
+    b.run()
+    delta = {k: METRICS.snapshot()["counters"].get(k, 0) - before.get(k, 0)
+             for k in ("ret.admit.tokens", "ret.admit.chunks",
+                       "ret.decode.row_steps", "ret.decode.resident_tokens")}
+    assert delta == {"ret.admit.tokens": 70, "ret.admit.chunks": 2,
+                     "ret.decode.row_steps": 5,
+                     "ret.decode.resident_tokens": sum(range(71, 76))}
+    assert [a["chunks"] for a in attrs] == [2]
+    for name, n, spans in seen:
+        if n and name.startswith("ret.decode"):
+            assert spans[-1] == "batcher.loop.deliver", (name, spans)
+        if n and name.startswith("ret.admit"):
+            assert "batcher.loop.wait_device" not in spans
+    assert METRICS.snapshot()["gauges"]["batcher.ret_state_bytes"] == \
+        2 * 2 * 2 * 66 * 128 * 128 * 4
 
 
 def test_the_rings_counters_and_gauges_of_a_windowed_model():

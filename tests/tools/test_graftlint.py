@@ -456,6 +456,24 @@ def test_repo_is_clean():
     )
 
 
+def test_a_directory_gitignore_lists_at_the_root_is_not_walked(tmp_path):
+    """A parent commit's checkout under ``_chip/`` (git-ignored) is another
+    project: a bad file planted there is found nowhere, the same file in a
+    tracked directory is."""
+    bad = "import time\ndef test_a():\n    time.sleep(0.5)\n"
+    project = _project(tmp_path, {
+        ".gitignore": "__pycache__/\n/_chip/\nchiprun_out/\n*.pyc\n",
+        "_chip/parent/tests/test_z.py": bad,
+        "chiprun_out/tests/test_z.py": bad,
+        "tests/test_ok.py": "def test_a():\n    assert True\n",
+    })
+    assert sorted(f.rel for f in project.files) == ["tests/test_ok.py"]
+    assert run_project(project) == []
+    # ... a directory of that name further down is still walked
+    project = _project(tmp_path, {"tests/_chip/test_z.py": bad})
+    assert "GL501" in _rules(run_project(project))
+
+
 def test_cli_exit_codes(tmp_path):
     # Dirty fixture tree -> exit 1 and the finding on stdout ...
     (tmp_path / "tests").mkdir()

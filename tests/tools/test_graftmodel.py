@@ -531,6 +531,22 @@ def test_check_front_door_scopes_across_tools():
         assert f"check: {skipped}:" not in r.stderr
 
 
+def test_a_checkout_under_an_ignored_directory_is_not_a_second_model(tmp_path):
+    """``_chip/parent`` holds a parent commit's copy of every model: with
+    ``_chip/`` in .gitignore the walk leaves it out; without, the copy
+    (here a broken one) is found."""
+    def lose_refund(m):
+        m["faults"][0]["update"] = {"inflight": "inflight - 1"}
+    broken = "TOY_MODEL = " + repr(_toy(lose_refund)) + "\n"
+    _tree(tmp_path)
+    (tmp_path / "_chip" / "parent" / "pkg").mkdir(parents=True)
+    (tmp_path / "_chip" / "parent" / "pkg" / "proto.py").write_text(broken)
+    (tmp_path / ".gitignore").write_text("/_chip/\n")
+    assert run_project(load_project(tmp_path)) == []
+    (tmp_path / ".gitignore").write_text("")
+    assert run_project(load_project(tmp_path)) != []
+
+
 def test_repo_is_clean():
     """The tier-1 gate: the real control-plane models must check clean
     against the checked-in (empty) baseline."""

@@ -23,6 +23,15 @@ shaped like the rings (``ring_shaped``): the in-place writes alone.
     python tools/aot_decode.py ax-k1-ep16 --slots 64 --pages 2176 --prompt-len 2048
     python tools/aot_decode.py k-exaone-ep8 --slots 64 --max-len 8192 \
         --pages 3712 --prompt-len 8192
+    python tools/aot_decode.py brumby-pp4 --slots 16 --max-len 32768 \
+        --pages 0 --prompt-len 16384
+
+``--pages 0`` compiles the programs of a server WITHOUT a pool (a model of
+retention layers, whose rows hold a float32 state and no key):
+``decode_chunk`` over the contiguous cache and ``admit_row``, with every
+instruction shaped like the slots' states (``state_shaped``): the decode
+kernel's update where the stack lies and the admission's write of one row
+into its slot, and nothing else.
 """
 from __future__ import annotations
 
@@ -104,8 +113,12 @@ def lower_program(
 
     devices = v5e_devices(mesh_model)
     key_shape = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    pool = jax.eval_shape(lambda: kv_cache.make_pool(
-        cfg, pages, page_size, kv_bits=kv_bits, slots=slots))
+    if pages:
+        pool = jax.eval_shape(lambda: kv_cache.make_pool(
+            cfg, pages, page_size, kv_bits=kv_bits, slots=slots))
+    else:  # no pool: the contiguous cache, a model of retention layers'
+        pool = jax.eval_shape(
+            lambda: kv_cache.init_cache(cfg, slots, max_len))
     if mesh_model > 1:
         from distributed_llms_tpu.core.config import MeshConfig
         from distributed_llms_tpu.parallel import specs as specs_lib
@@ -134,6 +147,15 @@ def lower_program(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
     p = max_len // page_size
+    if not pages and program == "decode_chunk":
+        return batcher_lib.decode_chunk.lower(
+            params, cfg, cache, arr((slots,)), arr((slots,)),
+            arr((slots, max_len), jnp.bool_), arr((slots,), jnp.bool_),
+            arr((slots,)), key, chunk_steps,
+        )
+    if not pages and program == "admit_row":
+        return batcher_lib.admit_row.lower(
+            params, cfg, cache, arr(()), arr((prompt_len,)), arr(()), key)
     if program == "decode_chunk":
         cfg_decode = dataclasses.replace(cfg, ragged_decode=True)
         return batcher_lib.decode_chunk.lower(
@@ -217,6 +239,24 @@ def ring_shaped(hlo_text: str, cfg, slots: int) -> list:
              f"{cfg.head_dim_}")
     return shaped_like(
         hlo_text, [f"[{layer}]", f"[{len(cfg.swa_layers)},{layer}]"])
+
+
+def state_shaped(hlo_text: str, cfg, slots: int) -> list:
+    """Every instruction whose result is shaped like the retention layers'
+    states: one row's in one layer, a layer's slots, or the stack of all
+    ([ret layers, slots, KVH, 65, 128, 128]).  The stack is the decode
+    scans' carry: only the kernel that updates it where it lies
+    (``custom-call``) and an admission's write of one row into its slot
+    may be on the list.  Empty for a model without retention layers."""
+    if not cfg.ret_layers:
+        return []
+    from distributed_llms_tpu.ops.retention import state_shapes
+
+    row = ",".join(str(n) for n in state_shapes(cfg.num_kv_heads)[0])
+    return shaped_like(hlo_text, [
+        f"[{row}]", f"[1,{row}]", f"[{slots},{row}]",
+        f"[{len(cfg.ret_layers)},{slots},{row}]",
+        f"[{len(cfg.ret_layers)},1,{row}]"])
 
 
 def expert_shaped(hlo_text: str, cfg) -> list:
@@ -420,8 +460,11 @@ def analyse(program: str, cfg, **shape_kw) -> dict:
     shapes = weight_shapes(cfg, shape_kw.get("mesh_model", 1))
     found = pool_shaped(text, cfg, shape_kw["pages"],
                         shape_kw.get("page_size", 64),
-                        shape_kw.get("mesh_model", 1))
+                        shape_kw.get("mesh_model", 1)
+                        ) if shape_kw["pages"] else []
     return {
+        "state_shaped": [list(e) for e in state_shaped(
+            text, cfg, shape_kw["slots"])],
         "ring_shaped": [list(e) for e in ring_shaped(
             text, cfg, shape_kw["slots"])],
         "expert_shaped": [list(e) for e in expert_shaped(text, cfg)],
@@ -477,7 +520,8 @@ def main() -> int:
     if a.layers:
         cfg = dataclasses.replace(cfg, num_layers=a.layers)
     programs = a.programs or ",".join(
-        PROGRAMS if cfg.family != "hybrid"
+        ("decode_chunk", "admit_row") if not a.pages
+        else PROGRAMS if cfg.family != "hybrid"
         # (latent pages serve the prefix cache; convolution state none)
         else PROGRAMS[:3] if cfg.kv_lora_rank else PROGRAMS[:2])
     for program in programs.split(","):

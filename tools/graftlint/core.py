@@ -184,12 +184,33 @@ _SKIP_DIRS = {".git", "__pycache__", ".venv", "venv", "node_modules",
               ".claude", "build", "dist"}
 
 
+def ignored_dirs(root: Path) -> set[str]:
+    """Directories at the walk's ROOT that ``<root>/.gitignore`` lists by
+    name (``_chip/``, ``/chiprun_out/``, ``.jax_cache/``: a line that ends
+    in a slash and holds no wildcard and no inner slash).  The tools walk
+    what the repository tracks: an untracked checkout of a parent commit
+    under such a directory is another project, whose copies of the models
+    and tests would read as duplicates and drift."""
+    try:
+        lines = (root / ".gitignore").read_text(encoding="utf-8").splitlines()
+    except OSError:
+        return set()
+    names = (line.strip() for line in lines)
+    return {n.strip("/") for n in names
+            if n.endswith("/") and not n.startswith(("#", "!"))
+            and not any(c in n.strip("/") for c in "*?[/")}
+
+
 def load_project(root: str | Path) -> Project:
     root = Path(root).resolve()
     files: list[SourceFile] = []
+    ignored = ignored_dirs(root)
     for path in sorted(root.rglob("*.py")):
+        parts = path.relative_to(root).parts[:-1]
+        if parts and parts[0] in ignored:
+            continue
         if any(part in _SKIP_DIRS or part.endswith(".egg-info")
-               for part in path.relative_to(root).parts[:-1]):
+               for part in parts):
             continue
         sf = load_file(root, path)
         if sf is not None:

@@ -531,6 +531,65 @@ def flash_continuation_parity(tq: int, prefix: int, s: int, h: int, kvh: int,
           f"win{win}", got, want, rtol=3e-2, atol=3e-2)
 
 
+def retention_parity(t: int, real: int, h: int, kvh: int, chunk: int,
+                     slots: int, dtype=jnp.float32) -> None:
+    """Power retention's two kernels (ops/retention.py) against the plain
+    ``jax.numpy`` forms: an admission of ``real`` tokens in a bucket of
+    ``t`` (chunks of ``chunk``; the chunks of padding are not walked)
+    against the chunked scan in float32 and, where it fits, against the
+    attention form; then one recurrence step of every batch slot in layer 1
+    of a stack of two, the slots' states where they lie, against the
+    attention form's next row.  Gates near 1, so that every chunk boundary
+    behind a token weighs on it."""
+    from distributed_llms_tpu.ops import retention as R
+
+    ks = jax.random.split(jax.random.key(50), 4)
+    q = jax.random.normal(ks[0], (t, h, 128), dtype)
+    k = jax.random.normal(ks[1], (t, kvh, 128), dtype)
+    v = jax.random.normal(ks[2], (t, kvh, 128), dtype)
+    lg = -0.01 * jnp.abs(jax.random.normal(ks[3], (t, kvh)))
+    n = real - 1  # the admission; token n is the decode step's
+    loose = dtype != jnp.float32
+    with jax.default_matmul_precision("highest"):
+        o, s, z = jax.jit(functools.partial(R.retention_prefill, chunk=chunk))(
+            q, k, v, lg, jnp.int32(n))
+        f32 = lambda x: x.astype(jnp.float32)
+        live = (jnp.arange(t) < n)[:, None]
+        tp = -(-t // chunk) * chunk
+        pad = lambda x: jnp.pad(x, ((0, tp - t),) + ((0, 0),) * (x.ndim - 1))
+        want_o, want_s, want_z = jax.jit(
+            functools.partial(R._prefill_dense, c=min(chunk, tp)))(
+            pad(f32(q)).reshape(tp, kvh, h // kvh, 128),
+            pad(jnp.where(live[:, :, None], f32(k), 0.0)), pad(f32(v)),
+            pad(jnp.where(live, lg, 0.0)))
+        want_o = want_o.reshape(tp, h, 128)
+    tag = f"retention {jnp.dtype(dtype).name} {real}/{t} h{h}/{kvh}"
+    rt, at = (5e-2, 5e-2) if loose else (2e-3, 2e-3)
+    check(f"{tag} admission", o[:n], want_o[:n], rt, at)
+    scale = float(jnp.max(jnp.abs(want_s)))
+    check(f"{tag} state", s / scale, want_s / scale,
+          0, 2e-2 if loose else 1e-5)
+    check(f"{tag} normaliser", z, want_z, rt, (1.0 if loose else 1e-2))
+    # one step for every slot, the row above in slot 1 and in the last one
+    states = jnp.zeros((2, slots, *s.shape), jnp.float32)
+    norms = jnp.zeros((2, slots, *z.shape), jnp.float32)
+    for b in (1, slots - 1):
+        states, norms = states.at[1, b].set(want_s), norms.at[1, b].set(want_z)
+    rows = lambda x: jnp.broadcast_to(x[n], (slots, *x.shape[1:]))
+    live = jnp.ones((slots,), bool).at[0].set(False)
+    step = jax.jit(R.retention_decode, donate_argnums=(4, 5))
+    with jax.default_matmul_precision("highest"):
+        got, states, norms = step(rows(q), rows(k), rows(v), rows(lg), states,
+                                  norms, jnp.int32(1), live)
+        want = R.attention_form(f32(q[:real]), f32(k[:real]), f32(v[:real]),
+                                lg[:real])[n] if real <= 2048 else None
+    if want is not None:
+        check(f"{tag} decode step", got[1], want, rt, at)
+        check(f"{tag} decode step, last slot", got[slots - 1], want, rt, at)
+    assert not np.asarray(states[0]).any(), "layer 0's states were written"
+    assert not np.asarray(states[1, 0]).any(), "a row that did not decode moved"
+
+
 def ragged_parity() -> None:
     key = jax.random.PRNGKey(2)
     for b, s, h, kvh, d, lengths in (
@@ -724,6 +783,13 @@ def main() -> int:
                  (300, 513, 1024, 4, 2), (16, 100, 1024, 2, 2),
                  (16, 600, 1024, 4, 2, 200))):
         flash_continuation_parity(*leg)
+    # Power retention (Brumby: 40 query heads over 8): an admission of the
+    # 2,048 bucket with a mean prompt in float32 and in bfloat16, the
+    # smallest bucket (padded to one chunk of 128), and the 16 slots' step.
+    for leg in (((2048, 1500, 40, 8, 256, 16), (64, 33, 40, 8, 256, 16),
+                 (2048, 1500, 40, 8, 256, 16, jnp.bfloat16)) if ON_TPU else
+                ((192, 150, 4, 2, 64, 3), (8, 5, 4, 2, 64, 3))):
+        retention_parity(*leg)
     # No leg may pass on another path than the one asked for: the dispatch
     # record (ops/dispatch.py) counts every trace by the path it took.
     took = {k[len("ops.dispatch."):]: int(v)
@@ -769,8 +835,9 @@ def main() -> int:
     # flash kernel over a row's continuation (``start``): the diagonal
     # shifted by the cached run, a KV group's query heads one tile, K and V
     # read where the cache has them, at qwen2-7b's and pythia's heads — 64
-    # legs.
-    print(f"kernel_parity: ALL PASS v15 ({mode}, backend={backend})")
+    # legs.  v16: power retention's chunked scan and recurrence step at
+    # Brumby's heads, float32 and bfloat16, the smallest bucket — 67 legs.
+    print(f"kernel_parity: ALL PASS v16 ({mode}, backend={backend})")
     return 0
 
 

@@ -5,12 +5,15 @@
     python tools/reference_check.py --config ax-k1-int8-ep16      # the chip
     python tools/reference_check.py --config k-exaone-int8-ep8    # the chip
     python tools/reference_check.py --config smallthinker-21ba3b-int8  # the chip
+    python tools/reference_check.py --config brumby-14b-int8  # the chip: a
+        # model served WITHOUT a pool (retention_probes, below)
     python tools/reference_check.py --config qwen2-7b-int8  # the chip: the
         # prefix cache's probes (prefix_cache_probes, below), and nothing else
     JAX_PLATFORMS=cpu python tools/reference_check.py --rehearsal # lfm2-tiny
     (--config ax-k1-int8-ep16 --rehearsal: ax-k1-tiny; --config
     k-exaone-int8-ep8 --rehearsal: k-exaone-tiny; --config
-    smallthinker-21ba3b-int8 --rehearsal: smallthinker-tiny)
+    smallthinker-21ba3b-int8 --rehearsal: smallthinker-tiny; --config
+    brumby-14b-int8 --rehearsal: brumby-tiny)
 
 For each of the benchmark's four probe prompts the tool takes the logits
 the SERVED path produces, admission at the prompt's own bucket and then 8
@@ -68,6 +71,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -144,6 +148,33 @@ TOLS = {
     # 0.170 on probe 32 with the expert stacks on the int4 grid in the
     # SERVED programs: each limit lies between its readings.
     "as_served": {"max_abs_logit": 0.70, "mean_abs_logit": 0.070},
+  },
+  "brumby-14b-int8": {
+    # float32 activations, the state float32, both kernels contracting at
+    # full precision: the order of summation (the chunked scan and the
+    # recurrence against one [T, T] matrix of weights) AND the rotation's
+    # angle, position x frequency in float32, whose rounding grows with the
+    # position and is felt a hundred times more here than under a softmax:
+    # q and k are RMS-normalised (|q| |k| = 128), nothing scales q . k, and
+    # a weight is its SQUARE.  The chip gave 6.1e-5 / 7.0e-6 on probe 32,
+    # 8.6e-4 / 1.2e-4 on probe 1500 and 3.5e-3 / 4.7e-4 on the 6,000-byte
+    # one (the most); with the state held in bfloat16 between the programs
+    # the same leg gives 0.148 / 0.0173 at the LEAST over the five probes
+    # (0.211 / 0.0193 at the most); on probe 200 the power 3 gives 6.94 /
+    # 1.12, no gate 6.90 / 1.05, no QK-norm 1.68 / 0.23, no rotation 3.93
+    # / 0.63, int4 weights 4.93 / 0.65.  Each limit lies six times over the
+    # reading and seven under the control's least.
+    "mechanism": {"max_abs_logit": 2e-2, "mean_abs_logit": 2.5e-3},
+    # bfloat16 activations through 10 layers: the chip gave 0.630 / 0.0583
+    # at the most over the five probes (the maximum on probe 32, the mean on
+    # probe 700; the same to the last digit in two calls), and 0.548 /
+    # 0.0625 at the most with the state held in bfloat16 (the maximum on
+    # probe 200, the mean on probe 1500): ONE rounding of the state is one
+    # more bfloat16 rounding among a dozen a layer, so the control moves
+    # the mean by 7% and the maximum not at all, and only the mean's limit
+    # can lie between its two readings (outside by one limit, not by each).
+    # The mechanism leg above tells the same control by a factor of 40.
+    "as_served": {"max_abs_logit": 0.80, "mean_abs_logit": 0.0604},
   },
 }
 # A dense model's continuation behind cached pages (prefix_cache_probes): the
@@ -238,7 +269,7 @@ def reference_logits(params, cfg, tokens, int4=False, **changed):
     from distributed_llms_tpu.checkpoint import quantize as quant_lib
     from distributed_llms_tpu.models import model as model_lib
     from distributed_llms_tpu.models.reference import (
-        axk1, exaone_moe, lfm2_moe, smallthinker)
+        axk1, brumby, exaone_moe, lfm2_moe, smallthinker)
 
     def floats(tree):
         def one(x):
@@ -264,6 +295,9 @@ def reference_logits(params, cfg, tokens, int4=False, **changed):
     lazy["layers"] = (layer(p) for p in model_lib.hybrid_layers(params, cfg))
     ref_cfg = {**reference_cfg(cfg), **changed}
     toks = jnp.asarray(tokens, jnp.int32)
+    if cfg.ret_layers:
+        return brumby.forward(
+            lazy, ref_cfg, toks, 512 if len(toks) > 2048 else None)
     if not (cfg.kv_lora_rank or cfg.swa_layers):
         return lfm2_moe.forward(lazy, ref_cfg, toks)
     held = (None if cfg.experts_held is None
@@ -418,6 +452,265 @@ def prefix_cache_probes(a, config) -> int:
     return 0 if ok else 1
 
 
+def _logprobs(logits, toks):
+    return [float(jax.nn.log_softmax(jnp.asarray(row))[t])
+            for row, t in zip(logits, toks)]
+
+
+def _against(served, ref):
+    d = np.abs(served - ref)
+    return {"max_abs_logit_diff": float(d.max()),
+            "mean_abs_logit_diff": float(d.mean())}
+
+
+def _tie_to_batcher(row, served, theirs, their_logprobs, tol, apart) -> bool:
+    """Tie the batcher's own tokens and logprobs on a probe to the as-served
+    leg's logits ``served``, into ``row``; True where they are the ones
+    those logits give.  Above 2,048 tokens (``apart``) an admission runs
+    its FFNs in blocks and XLA compiles the batcher's program and the leg's
+    apart (PR 34: the 6,000-byte probe's first logprob differs by 0.0025,
+    later ones by what bfloat16 moves them, an expert's choice among them):
+    there the batcher's logprobs are held to the REFERENCE's, at the
+    as-served limit, and a greedy choice may part from the leg's where the
+    leg's OWN logits hold the two tokens within that limit of each other
+    (PR 45: K-EXAONE's probe parts at its fourth token, logprobs of -6.4 on
+    a nearly flat distribution); behind the parting the two feed different
+    tokens and nothing is compared."""
+    mine = row["as_served"]
+    row["batcher_tokens_equal"] = theirs == mine["tokens"]
+    row["batcher_logprobs"] = their_logprobs
+    same = next((j for j, (x, y) in enumerate(zip(theirs, mine["tokens"]))
+                 if x != y), PROBE_TOKENS)
+    tie = True
+    if same < PROBE_TOKENS:
+        row["batcher_parts_at"] = same
+        row["batcher_parting_margin"] = float(
+            served[same][mine["tokens"][same]] - served[same][theirs[same]])
+        tie = apart and row["batcher_parting_margin"] <= tol["max_abs_logit"]
+    row["batcher_logprob_max_abs_diff"] = max((
+        abs(x - y) for x, y in zip(their_logprobs[:same],
+                                   mine["served_logprobs"])), default=0.0)
+    row["batcher_logprob_against_reference"] = max((
+        abs(x - y) for x, y in zip(their_logprobs[:same],
+                                   mine["reference_logprobs"])), default=0.0)
+    tied = row["batcher_logprob_max_abs_diff"] < 1e-3 or (
+        apart and row["batcher_logprob_against_reference"]
+        <= tol["max_abs_logit"])
+    return bool(tie and tied)
+
+
+def retention_probes(a, config) -> int:
+    """A model of power-retention layers (Brumby), which is served WITHOUT
+    a pool: for each of the configuration's probes the ``mechanism`` leg
+    (float32 activations, the same programs, kernels and state) and the
+    ``as_served`` leg against the reference's full forward over the same
+    tokens, admission at the prompt's bucket (the chunked scan) and then 8
+    recurrence steps in a slot of a cache shaped as the cell serves it;
+    what a wrong model does to the reference on the second probe; the
+    batcher's own tokens and logprobs tied to the as-served logits; and
+    the control from the served path on EVERY probe and in BOTH legs: the
+    state and its normaliser held in bfloat16 between the admission and
+    each step (the precision below the configuration's), fed the leg's own
+    tokens, whose worst reading over the probes' decode steps has to land
+    outside the leg's limits, by one of them."""
+    from distributed_llms_tpu.core.observability import METRICS
+    from distributed_llms_tpu.models import kv_cache, model as model_lib
+    from distributed_llms_tpu.models.presets import get_preset
+    from distributed_llms_tpu.ops import dispatch
+    from distributed_llms_tpu.runtime import batcher as B
+    from distributed_llms_tpu.runtime.shapes import bucket_length
+    from distributed_llms_tpu.runtime.tokenizer import get_tokenizer
+
+    serve, preset, TOL = dict(config["serve"]), config["preset"], TOLS[a.config]
+    probe_bytes = tuple(serve["probe_bytes"])
+    if a.rehearsal:
+        preset, probe_bytes = "brumby-tiny", (5, 9, 33, 60, 140)
+        serve.update(slots=4, max_len=256)
+    cfg = get_preset(preset)
+    tok = get_tokenizer(None)
+    if cfg.vocab_size < tok.vocab_size:  # as dlt-serve widens a tiny preset
+        cfg = dataclasses.replace(cfg, vocab_size=512)
+    t0 = time.time()
+    params = model_lib.init_params_quantized(jax.random.key(0), cfg, 8)
+    jax.block_until_ready(params)
+    dev = jax.devices()[0]
+    print(f"weights on {dev.device_kind} after {time.time() - t0:.1f} s",
+          flush=True)
+    slots, s_len = serve["slots"], serve["max_len"]
+
+    @functools.cache
+    def programs(c):  # (one set a dtype: a leg and its control share it)
+        @partial(jax.jit, donate_argnums=(1,))
+        def admit(params, cache, prompt, plen, slot):
+            logits, row, _ = B._prefill_row(
+                model_lib.forward, params, c, cache.k.dtype, s_len, prompt,
+                plen)
+            return B._splice_row(cache, slot, row), logits[0, 0]
+
+        @partial(jax.jit, donate_argnums=(1,))
+        def step(params, cache, last_tok, real_lens, active):
+            mask = (jnp.arange(s_len)[None, :] <= real_lens[:, None])
+            logits, cache = model_lib.forward(
+                params, c, last_tok[:, None], positions=real_lens[:, None],
+                cache=cache, cache_index=real_lens,
+                attn_mask=mask[:, None, None, :],
+                seq_lens=active.astype(jnp.int32))
+            return logits[:, 0], cache
+
+        @partial(jax.jit, donate_argnums=(0,))
+        def to_bf16(cache):  # the state as bfloat16 would hold it (a cast
+            # there and back is dropped on the TPU: excess precision is
+            # allowed by default)
+            return dataclasses.replace(cache, **{
+                f: jax.lax.reduce_precision(getattr(cache, f), 8, 7)
+                for f in ("ret_s", "ret_z")})
+
+        return admit, step, to_bf16
+
+    def served_logits(dtype, ids, force=None, state_bf16=False):
+        """[8, V] logits of the served path with ``dtype`` activations:
+        positions len(ids)-1 .. +7, each step fed the greedy token or the
+        tokens ``force`` names."""
+        c = dataclasses.replace(cfg, dtype=dtype)
+        admit, step, to_bf16 = programs(c)
+        plen, bucket = len(ids), bucket_length(len(ids))
+        prompt = np.zeros((bucket,), np.int32)
+        prompt[:plen] = ids
+        cache = kv_cache.init_cache(c, slots, s_len)
+        cache, first = admit(params, cache, jnp.asarray(prompt),
+                             jnp.int32(plen), jnp.int32(a.slot))
+        logits = [np.asarray(first, np.float32)]
+        toks = [int(np.argmax(logits[0])) if force is None else force[0]]
+        active = np.zeros((slots,), bool)
+        active[a.slot] = True
+        last = np.zeros((slots,), np.int32)
+        lens = np.zeros((slots,), np.int32)
+        for j in range(PROBE_TOKENS - 1):
+            if state_bf16:
+                cache = to_bf16(cache)
+            last[a.slot], lens[a.slot] = toks[-1], plen + j
+            lg, cache = step(params, cache, jnp.asarray(last),
+                             jnp.asarray(lens), jnp.asarray(active))
+            logits.append(np.asarray(lg[a.slot], np.float32))
+            toks.append(int(np.argmax(logits[-1])) if force is None
+                        else force[j + 1])
+        return np.stack(logits), toks
+
+    logprobs, against = _logprobs, _against
+
+    def inside(got, leg):
+        return bool(
+            got["max_abs_logit_diff"] <= TOL[leg]["max_abs_logit"]
+            and got["mean_abs_logit_diff"] <= TOL[leg]["mean_abs_logit"])
+
+    report = {"config": a.config, "preset": preset, "tolerances": TOL,
+              "device_kind": dev.device_kind, "probes": []}
+    ok = True
+    for n in probe_bytes[:a.probes]:
+        ids = list(tok.encode(probe_prompt(n)))
+        plen = len(ids)
+        row = {"bytes": n, "prompt_tokens": plen,
+               "bucket": bucket_length(plen),
+               "chunks": -(-plen // cfg.ret_chunk)}
+        for leg, dtype in (("mechanism", "float32"), ("as_served", cfg.dtype)):
+            precision = "highest" if leg == "mechanism" else "default"
+            with jax.default_matmul_precision(precision):
+                served, toks = served_logits(dtype, ids)
+            print(f"  probe {n} {leg}: served", flush=True)
+            t1 = time.time()
+            ref = np.asarray(reference_logits(params, cfg, ids + toks[:-1]),
+                             np.float32)[plen - 1: plen - 1 + PROBE_TOKENS]
+            got = against(served, ref)
+            got.update(
+                tokens=toks, logit_rms=float(np.sqrt((ref ** 2).mean())),
+                reference_argmax_equal=[int(np.argmax(r)) for r in ref] == toks,
+                served_logprobs=logprobs(served, toks),
+                reference_logprobs=logprobs(ref, toks),
+                reference_seconds=time.time() - t1)
+            got["first_logprob_diff"] = abs(
+                got["served_logprobs"][0] - got["reference_logprobs"][0])
+            got["within_tolerances"] = inside(got, leg)
+            row[leg] = got
+            # The control, in this leg: the same tokens, the same reference
+            # logits, the state held in bfloat16 between the admission and
+            # every step (the decode steps' logits alone pass through it).
+            with jax.default_matmul_precision(precision):
+                lower, _ = served_logits(dtype, ids, force=toks,
+                                         state_bf16=True)
+            key = "state_bf16" + ("_mechanism" if leg == "mechanism" else "")
+            row[key] = against(lower, ref)
+            row[key]["decode_steps"] = against(lower[1:], ref[1:])
+            row[key]["sound_decode_steps"] = against(served[1:], ref[1:])
+            row[key]["max_abs_logprob_shift"] = max(
+                abs(x - y) for x, y in zip(
+                    logprobs(lower, toks), got["served_logprobs"]))
+        if n == probe_bytes[1]:
+            wrongs = {"degree_3": {"degree": 3}, "no_gate": {"gated": False},
+                      "no_qk_norm": {"qk_norm": False},
+                      "no_rope": {"rope": False},
+                      "int4_weights": {"int4": True}}
+            row["wrong"] = {
+                name: against(np.asarray(reference_logits(
+                    params, cfg, ids + toks[:-1], **changed),
+                    np.float32)[plen - 1: plen - 1 + PROBE_TOKENS], ref)
+                for name, changed in wrongs.items()}
+        # The batcher's own programs on the same probe, sent alone.
+        batcher = B.ContinuousBatcher(
+            cfg, params, tok, batch_slots=slots, max_len=s_len,
+            chunk_steps=serve["chunk_steps"])
+        rid = batcher.submit(ids, max_new_tokens=PROBE_TOKENS)
+        out = batcher.run()
+        tied = _tie_to_batcher(
+            row, served, list(out[rid]),
+            [float(x) for x in batcher.result_logprobs[rid]],
+            TOL["as_served"], row["bucket"] > model_lib._TOKEN_BLOCK)
+        del batcher
+        good = (row["mechanism"]["within_tolerances"]
+                and row["as_served"]["within_tolerances"] and tied)
+        row["ok"] = bool(good)
+        ok &= good or a.rehearsal
+        report["probes"].append(row)
+        print(json.dumps({k: ({x: y for x, y in v.items()
+                               if not x.endswith("logprobs")}
+                              if isinstance(v, dict) else v)
+                          for k, v in row.items()
+                          if k != "batcher_logprobs"}), flush=True)
+    # The control's worst readings against the as-served leg's limits and
+    # its worst readings: outside by one of the limits, not by each.
+    worst = lambda leg, k: max(p[leg][k] for p in report["probes"])
+    for leg, key in (("as_served", "state_bf16"),
+                     ("mechanism", "state_bf16_mechanism")):
+        control = {k: worst(key, k) for k in (
+            "max_abs_logit_diff", "mean_abs_logit_diff",
+            "max_abs_logprob_shift")}
+        control["sound_at_most"] = {k: worst(leg, k) for k in (
+            "max_abs_logit_diff", "mean_abs_logit_diff")}
+        control["limits"] = TOL[leg]
+        control["outside_tolerances"] = not inside(control, leg)
+        report[key] = control
+        ok &= control["outside_tolerances"] or a.rehearsal
+        print(json.dumps({key: control}), flush=True)
+    report["golden_from_reference"] = all(
+        p["as_served"]["first_logprob_diff"] <= GOLDEN_FROM_REFERENCE
+        for p in report["probes"])
+    report["dispatch"] = {k: v for k, v in
+                          METRICS.snapshot()["counters"].items()
+                          if k.startswith("ops.dispatch.")}
+    stats = dev.memory_stats() or {}
+    report["memory_peak_bytes"] = stats.get("peak_bytes_in_use")
+    out_dir = os.path.join(ROOT, "chiprun_out", "reference_check")
+    os.makedirs(out_dir, exist_ok=True)
+    name = a.config + (".rehearsal" if a.rehearsal else "")
+    with open(os.path.join(out_dir, name + ".json"), "w") as f:
+        json.dump(report, f, indent=1)
+    print(json.dumps({"ok": bool(ok),
+                      "golden_from_reference": report["golden_from_reference"],
+                      "dispatch": report["dispatch"],
+                      "memory_peak_bytes": report["memory_peak_bytes"]}))
+    print("reference_check:", "PASS" if ok else "FAIL", flush=True)
+    return 0 if ok else 1
+
+
 def served_programs(cfg, cfg_decode):
     from distributed_llms_tpu.models import kv_cache, model as model_lib
     from distributed_llms_tpu.runtime import batcher as B
@@ -472,6 +765,8 @@ def main() -> int:
         config = json.load(f)
     if a.config in PREFIX_TOLS:  # a dense model: the prefix cache's probes
         return prefix_cache_probes(a, config)
+    if get_preset(config["preset"]).ret_layers:  # a state and no pool
+        return retention_probes(a, config)
     serve = dict(config["serve"])
     preset, probe_bytes = config["preset"], PROBE_BYTES
     TOL = TOLS[a.config]
@@ -560,14 +855,7 @@ def main() -> int:
                         else force[j + 1])
         return np.stack(logits), toks
 
-    def logprobs(logits, toks):
-        return [float(jax.nn.log_softmax(jnp.asarray(row))[t])
-                for row, t in zip(logits, toks)]
-
-    def against(served, ref):
-        d = np.abs(served - ref)
-        return {"max_abs_logit_diff": float(d.max()),
-                "mean_abs_logit_diff": float(d.mean())}
+    logprobs, against = _logprobs, _against
 
     report = {"config": a.config, "preset": preset, "tolerances": TOL,
               "device_kind": dev.device_kind, "probes": []}
@@ -634,43 +922,14 @@ def main() -> int:
         batcher = make_batcher()
         rid = batcher.submit(ids, max_new_tokens=PROBE_TOKENS)
         out = batcher.run()
-        mine, theirs = row["as_served"], list(out[rid])
-        row["batcher_tokens_equal"] = theirs == mine["tokens"]
-        row["batcher_logprobs"] = [float(x) for x in
-                                   batcher.result_logprobs[rid]]
-        # Above 2,048 tokens an admission runs its FFNs in blocks and XLA
-        # compiles the batcher's program and the leg's apart (PR 34: the
-        # 6,000-byte probe's first logprob differs by 0.0025, later ones by
-        # what bfloat16 moves them, an expert's choice among them): there
-        # the batcher's logprobs are held to the REFERENCE's, at the
-        # as-served limit, and a greedy choice may part from the leg's
-        # where the leg's OWN logits hold the two tokens within that limit
-        # of each other (PR 45: K-EXAONE's probe parts at its fourth token,
-        # logprobs of -6.4 on a nearly flat distribution); behind the
-        # parting the two feed different tokens and nothing is compared.
-        apart = row["bucket"] > model_lib._TOKEN_BLOCK
-        same = next((j for j, (x, y) in enumerate(zip(theirs, mine["tokens"]))
-                     if x != y), PROBE_TOKENS)
-        tie = True
-        if same < PROBE_TOKENS:
-            row["batcher_parts_at"] = same
-            row["batcher_parting_margin"] = float(
-                served[same][mine["tokens"][same]] - served[same][theirs[same]])
-            tie = apart and row["batcher_parting_margin"] <= \
-                TOL["as_served"]["max_abs_logit"]
-        row["batcher_logprob_max_abs_diff"] = max((
-            abs(x - y) for x, y in zip(row["batcher_logprobs"][:same],
-                                       mine["served_logprobs"])), default=0.0)
-        row["batcher_logprob_against_reference"] = max((
-            abs(x - y) for x, y in zip(row["batcher_logprobs"][:same],
-                                       mine["reference_logprobs"])),
-            default=0.0)
+        mine = row["as_served"]
+        tied = _tie_to_batcher(
+            row, served, list(out[rid]),
+            [float(x) for x in batcher.result_logprobs[rid]],
+            TOL["as_served"], row["bucket"] > model_lib._TOKEN_BLOCK)
         del batcher
-        tied = row["batcher_logprob_max_abs_diff"] < 1e-3 or (
-            apart and row["batcher_logprob_against_reference"]
-            <= TOL["as_served"]["max_abs_logit"])
         good = (row["mechanism"]["within_tolerances"]
-                and mine["within_tolerances"] and tie and tied)
+                and mine["within_tolerances"] and tied)
         row["ok"] = bool(good)
         ok &= good or a.rehearsal
         report["probes"].append(row)
