@@ -106,17 +106,18 @@ def admit_ops(config: dict, tokens: float, admissions: float) -> float:
 
 
 def admit_least_s(ctx: dict):
-    """The least time the traced admissions' ``retention_prefill`` can
-    take: :func:`admit_ops` of the real tokens scanned in the counter
-    window INSIDE the trace (``ret.admit.tokens``, in as many rows as the
-    trace holds admission programs) over the peak bf16 rate.  None where
+    """The least time ``retention_prefill`` can take for the admissions the
+    trace holds whole: :func:`admit_ops` of each admission that the trace
+    pairs with its ``batcher.admit.row`` span (its own span's
+    ``prompt_tokens - cached_tokens``, one row) over the peak bf16 rate
+    (``trace_reduce.reduce``: ``admissions``; PR 52: tokens against device
+    time; set it against ``trace_reduce.inside_s``, the kernel's seconds
+    inside those same programs).  ``ret.admit.tokens`` of
+    ``trace_counters`` is added when an admission is SETTLED: one begun
+    before the trace gave tokens and no whole program.  None where
     something is missing."""
     t, peaks = ctx["trace"], ctx["peaks"]
-    tc = ctx.get("trace_counters") or {}
-    tokens = tc.get("ret_admit_tokens", 0.0)
-    if not t or not peaks or not tokens:
+    if not t or not peaks or not t.get("admissions"):
         return None
-    admissions = sum(n for name, n in t["module_count"].items()
-                     if name.startswith("jit_admit_row"))
-    return (admit_ops(ctx["config"], tokens, admissions)
-            / peaks["bf16_flops_per_s"])
+    return sum(admit_ops(ctx["config"], a["tokens"], 1)
+               for a in t["admissions"]) / peaks["bf16_flops_per_s"]

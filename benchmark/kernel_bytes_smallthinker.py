@@ -17,7 +17,7 @@ the expert reckoning ``kernel_bytes_moe``'s
 
 from __future__ import annotations
 
-from benchmark import kernel_bytes_kexaone, kernel_bytes_moe
+from benchmark import kernel_bytes_kexaone, kernel_bytes_moe, trace_reduce
 
 BF16 = 2
 
@@ -137,33 +137,31 @@ def decode_attn_least_s(ctx: dict, counter: str, layers: int):
 
 
 def experts_least_s(ctx: dict):
-    """The least time the traced passes' expert kernel can take
-    (``moe_experts_roofline``'s reckoning): for each traced decode step
-    the touched experts' bytes over peak HBM bandwidth; for the traced
-    admissions the larger of the same (once an admission, though one above
-    2,048 tokens streams the stacks once a block: a lower bound) and the
-    arithmetic of their prompt tokens' pairs over the peak bf16 rate.  The
-    share of experts touched is a ratio of WHOLE-WINDOW counters; the
-    prompt tokens are ``batcher.prefix_cache.miss_tokens`` of the counter
-    window inside the trace, never more than the traced admissions can
-    have held.  None where something is missing."""
-    t, peaks, tc = ctx["trace"], ctx["peaks"], ctx.get("trace_counters")
+    """The least time the expert kernel can take for the programs the
+    trace holds whole (``moe_experts_roofline``'s reckoning): for each step
+    of the whole decode programs the touched experts' bytes over peak HBM
+    bandwidth; for each admission the trace pairs with its
+    ``batcher.admit.row`` span the larger of the same (once an admission,
+    though one above 2,048 tokens streams the stacks once a block: a lower
+    bound) and the arithmetic of its own span's
+    ``prompt_tokens - cached_tokens`` over the peak bf16 rate
+    (``trace_reduce.least_s``, PR 52: tokens against device time, nothing
+    from ``trace_counters``; set it against ``trace_reduce.inside_s``, the
+    kernel's seconds inside those same programs).  The share of experts
+    touched is a ratio of WHOLE-WINDOW counters.  None where something is
+    missing."""
+    t, peaks = ctx["trace"], ctx["peaks"]
     c, config = ctx["counters"], ctx["config"]
     passes = c.get("moe_layer_passes", 0.0)
-    if not t or not peaks or not tc or not passes:
+    if not t or not peaks or not passes:
         return None
     keys = moe_keys(config)
     touched = c.get("moe_experts_touched", 0.0) / (
         keys["num_experts"] * passes)
-    per_pass = kernel_bytes_moe.touched_bytes_per_pass(keys, touched)
-    decode = config["serve"]["chunk_steps"] * sum(
-        n for name, n in t["module_count"].items()
-        if name.startswith("jit_decode_chunk"))
-    admits = sum(n for name, n in t["module_count"].items()
-                 if name.startswith("jit_admit_row"))
-    tokens = min(tc.get("batcher_prefix_cache_miss_tokens", 0.0),
-                 admits * config["serve"]["max_len"])
-    return decode * per_pass / peaks["hbm_bytes_per_s"] + max(
-        admits * per_pass / peaks["hbm_bytes_per_s"],
-        kernel_bytes_moe.routed_flops(keys, tokens)
-        / peaks["bf16_flops_per_s"])
+    per_pass_s = (kernel_bytes_moe.touched_bytes_per_pass(keys, touched)
+                  / peaks["hbm_bytes_per_s"])
+    return trace_reduce.least_s(
+        t, config["serve"]["chunk_steps"], per_pass_s,
+        lambda tokens: max(per_pass_s,
+                           kernel_bytes_moe.routed_flops(keys, tokens)
+                           / peaks["bf16_flops_per_s"]))

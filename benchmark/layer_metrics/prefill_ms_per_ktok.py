@@ -1,16 +1,20 @@
-"""Device time of the admission programs (``jit_admit_row*``, and the
-chunked-prefill ones where the schedule uses them) in the traced window,
-over the prompt tokens prefilled fresh in it (thousands)."""
+"""Device time of the admission programs (``jit_admit_row*``) over the
+prompt tokens they prefilled fresh (thousands): of the admissions that lie
+WHOLE inside the trace and that the trace pairs with their
+``batcher.admit.row`` span (``trace_reduce.reduce``: ``admissions``), each
+program's seconds over its own span's ``prompt_tokens - cached_tokens``.
+Numerator and denominator are the same admissions' (PR 52): a host
+counter's window is not the device's, and with three admissions of
+4,096-16,384 tokens in the trace one at its edge moved this by a third."""
 UNIT = "ms/ktok"
-PROGRAMS = ("jit_admit_row", "jit_prefill_chunk_step", "jit_finish_chunked")
 
 
 def read(ctx):
-    t, c = ctx["trace"], ctx.get("trace_counters")
-    if not t or not c:
+    t = ctx["trace"]
+    if not t or not t.get("admissions"):
         return None
-    tokens = c.get("batcher_prefix_cache_miss_tokens", 0)
-    secs = sum(v for k, v in t["module_s"].items() if k.startswith(PROGRAMS))
+    tokens = sum(a["tokens"] for a in t["admissions"])
+    secs = sum(a["seconds"] for a in t["admissions"])
     if not tokens or not secs:
         return None
     return 1e3 * secs / (tokens / 1e3)

@@ -2,11 +2,14 @@
 a layer, 12 layers (SmallThinker): ``moe_experts_roofline``'s reckoning
 under this configuration's keys
 (``kernel_bytes_smallthinker.experts_least_s``): the touched experts' bytes
-for each traced decode step, the larger of that and the pairs' arithmetic
-for the traced admissions.  Every term is a lower bound, so the share reads
-low.  Nothing is clamped: a count that is wrong shows as a share over
-100%."""
+for each step of the WHOLE decode programs of the trace, the larger of that
+and its own tokens' arithmetic for each admission the trace pairs with its
+``batcher.admit.row`` span, over the kernel's seconds inside those same
+programs (PR 52: tokens against device time; nothing from
+``trace_counters``).  Every term is a lower bound, so the share reads low.
+Nothing is clamped: a count that is wrong shows as a share over 100%."""
 from benchmark import kernel_bytes_smallthinker as kb
+from benchmark import trace_reduce
 
 UNIT = "%"
 KERNEL = "moe_experts"
@@ -17,7 +20,4 @@ def read(ctx):
     if (not t or not t["op_s"].get(KERNEL)
             or "moe_num_primary_experts" not in config):
         return None
-    least_s = kb.experts_least_s(ctx)
-    if not least_s:
-        return None
-    return 100.0 * least_s / t["op_s"][KERNEL]
+    return trace_reduce.paired_share(t, KERNEL, kb.experts_least_s(ctx))

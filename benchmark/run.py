@@ -450,6 +450,7 @@ def run(args) -> dict:
             f.write(json.dumps(r.to_json()) + "\n")
     with open(os.path.join(out_dir, "summary.json"), "w") as f:
         json.dump({"faults": faults, "counters": delta, "trace": trace,
+                   "trace_counters": ctx["trace_counters"],
                    "setup_s": snap["setup_s"], "compiled": compiled,
                    "t_open": load.t_open, "held_to": held}, f, indent=1)
     for fault in faults:

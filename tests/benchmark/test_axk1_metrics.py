@@ -11,6 +11,7 @@ import pytest
 from benchmark import kernel_bytes, kernel_bytes_axk1 as kb, metrics, traffic
 
 from declared_cell import check_declared
+from paired_trace import paired
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CELL = "ax-k1-int8-ep16.long-answers"
@@ -50,8 +51,8 @@ PEAKS = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 
 
 def ctx(**over):
-    return {"counters": COUNTERS, "trace": TRACE, "peaks": PEAKS,
-            "config": config(),
+    return {"counters": COUNTERS, "trace": paired(TRACE, [600] * 15),
+            "peaks": PEAKS, "config": config(),
             "trace_counters": {"batcher_prefix_cache_miss_tokens": 9000.0},
             **over}
 

@@ -11,6 +11,7 @@ import pytest
 from benchmark import kernel_bytes, kernel_bytes_kexaone as kb, metrics, traffic
 
 from declared_cell import check_declared
+from paired_trace import paired
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CELL = "k-exaone-int8-ep8.mixed-lengths"
@@ -52,8 +53,8 @@ PEAKS = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 
 
 def ctx(**over):
-    return {"counters": COUNTERS, "trace": TRACE, "peaks": PEAKS,
-            "config": config(),
+    return {"counters": COUNTERS, "trace": paired(TRACE, [1500] * 10),
+            "peaks": PEAKS, "config": config(),
             "trace_counters": {"batcher_prefix_cache_miss_tokens": 15000.0},
             **over}
 

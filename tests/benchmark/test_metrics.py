@@ -184,10 +184,27 @@ def test_no_device_plane_is_nothing_to_read():
 def test_trace_readers(events):
     config = {"serve": {"chunk_steps": 8}, "num_hidden_layers": 2,
               "matmuls_per_layer": [[128, 256], [256, 128]]}
-    trace = trace_reduce.reduce(events)
+    # The excerpt was recorded before the row span carried what its
+    # admission prefilled (PR 52) and ends with its program: give it the
+    # span, and one operation after the program so that it lies whole.
+    E = trace_reduce.Event
+    prog = next(e for e in events if e.name == "jit_admit_row_paged")
+
+    def with_span(tokens):
+        return events + [
+            E("/host:CPU", "python3", "batcher.admit.row",
+              prog.start_ns - 400_000, prog.dur_ns + 800_000,
+              {"rid": 1, "prompt_tokens": tokens, "cached_tokens": 0,
+               "bucket": 512, "live_rows": 512}),
+            E(prog.plane, trace_reduce.OPS_LINE, "copy", prog.end_ns + 10, 5)]
+
+    trace = trace_reduce.reduce(with_span(500))
+    assert [(a["tokens"], a["bucket"], a["program"])
+            for a in trace["admissions"]] == [(500, 512, "jit_admit_row_paged")]
     ctx = {"trace": trace, "config": config, "gauges": {},
            "peaks": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12},
-           "trace_counters": {"batcher_prefix_cache_miss_tokens": 500.0}}
+           # (not what the readers below take their tokens from)
+           "trace_counters": {"batcher_prefix_cache_miss_tokens": 9e9}}
     assert metrics.read_layer_metric("prefill_ms_per_ktok", ctx)[0] == \
         pytest.approx(84.063318 / 0.5)
     share = metrics.read_layer_metric("quant_matmul_share", ctx)[0]
@@ -201,12 +218,23 @@ def test_trace_readers(events):
     bytes_pass = weights * (1 + 4 / 128)
     assert kernel_bytes.quant_matmul_bytes_per_pass(config) == bytes_pass
     assert kernel_bytes.quant_matmul_weights(config) == weights
-    kernel_s = trace["op_s"]["_quant_matmul_2d"]
+    # The kernel's time is what it spent INSIDE the paired admission: here
+    # all of it, the excerpt holds no other program that runs it.
+    kernel_s = trace["admissions"][0]["op_s"]["_quant_matmul_2d"]
+    assert kernel_s == pytest.approx(trace["op_s"]["_quant_matmul_2d"])
     roof = metrics.read_layer_metric("quant_matmul_roofline", ctx)[0]
     assert roof == pytest.approx(100 * 2 * 500 * weights / 197e12 / kernel_s)
-    ctx["trace_counters"] = {"batcher_prefix_cache_miss_tokens": 5.0}
+    ctx["trace"] = trace_reduce.reduce(with_span(5))
     roof = metrics.read_layer_metric("quant_matmul_roofline", ctx)[0]
     assert roof == pytest.approx(100 * bytes_pass / 819e9 / kernel_s)
+    # The excerpt as it was recorded: its one admission ends the list and
+    # no span says what it held, so there is nothing to set against its
+    # device time, whatever a counter says.
+    ctx["trace"] = trace_reduce.reduce(events)
+    assert ctx["trace"]["admissions"] == []
+    assert ctx["trace"]["decode"]["count"] == 0
+    assert metrics.read_layer_metric("prefill_ms_per_ktok", ctx) is None
+    assert metrics.read_layer_metric("quant_matmul_roofline", ctx) is None
 
 
 def test_quant_matmul_bytes_of_the_real_configurations():

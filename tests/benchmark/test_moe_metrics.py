@@ -10,6 +10,7 @@ import pytest
 from benchmark import kernel_bytes, kernel_bytes_moe, metrics
 
 from declared_cell import check_declared
+from paired_trace import paired
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CELL = "lfm2-8b-a1b-int8.chat"
@@ -43,9 +44,10 @@ TRACE = {
 PEAKS = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
 
 
-def ctx(**over):
-    return {"counters": COUNTERS, "trace": TRACE, "peaks": PEAKS,
-            "config": config(),
+def ctx(tokens=0.0, **over):
+    """The nine admissions paired, ``tokens`` prompt tokens in all."""
+    return {"counters": COUNTERS, "trace": paired(TRACE, [tokens / 9] * 9),
+            "peaks": PEAKS, "config": config(),
             "trace_counters": {"batcher_prefix_cache_miss_tokens": 0.0},
             **over}
 
@@ -72,16 +74,25 @@ def test_roofline_is_bytes_for_decode_and_the_larger_bound_for_admissions():
     flops = 2 * 30e3 * 4 * 22 * 3 * 2048 * 1792 / 197e12
     assert flops == kernel_bytes_moe.routed_flops(config(), 30e3) / 197e12
     assert flops > 9 * touched / 819e9
-    got = metrics.read_layer_metric("moe_experts_roofline", ctx(
-        trace_counters={"batcher_prefix_cache_miss_tokens": 30e3}))
+    got = metrics.read_layer_metric("moe_experts_roofline", ctx(30e3))
     assert got[0] == pytest.approx(100 * (64 * touched / 819e9 + flops) / 3.0)
     assert got[0] < 100.0
     # A counter window wider than the trace cannot count more prompt
-    # tokens than the traced admissions can have held.
+    # tokens than the traced admissions held: since PR 52 the tokens are
+    # the paired admissions' own and no host counter is read for them.
     wide = metrics.read_layer_metric("moe_experts_roofline", ctx(
-        trace_counters={"batcher_prefix_cache_miss_tokens": 1e9}))
-    most = kernel_bytes_moe.routed_flops(config(), 9 * 4096) / 197e12
-    assert wide[0] == pytest.approx(100 * (64 * touched / 819e9 + most) / 3.0)
+        30e3, trace_counters={"batcher_prefix_cache_miss_tokens": 1e9}))
+    assert wide == got
+    # The larger bound is taken an admission at a time: one of the nine
+    # with every token leaves eight bound by their bytes.
+    one = ctx()
+    one["trace"]["admissions"][0]["tokens"] = 30e3
+    assert metrics.read_layer_metric("moe_experts_roofline", one)[0] == \
+        pytest.approx(100 * ((64 + 8) * touched / 819e9 + flops) / 3.0)
+    # Admissions that could not be paired are not guessed.
+    assert metrics.read_layer_metric("moe_experts_roofline", ctx(
+        trace={**TRACE, "admissions": None, "decode": one["trace"]["decode"]}
+    )) is None
 
 
 def test_a_wrong_count_of_touched_experts_is_not_hidden():

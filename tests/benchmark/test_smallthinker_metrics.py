@@ -14,6 +14,7 @@ from benchmark import kernel_bytes, kernel_bytes_smallthinker as kb
 from benchmark import metrics, traffic
 
 from declared_cell import check_declared
+from paired_trace import paired
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CELL = "smallthinker-21ba3b-int8.long-think"
@@ -66,8 +67,9 @@ TRACE_COUNTERS = {
 
 
 def ctx(**over):
-    return {"counters": COUNTERS, "trace": TRACE, "peaks": PEAKS,
-            "config": config(), "trace_counters": TRACE_COUNTERS, **over}
+    return {"counters": COUNTERS, "trace": paired(TRACE, [4000] * 6),
+            "peaks": PEAKS, "config": config(),
+            "trace_counters": TRACE_COUNTERS, **over}
 
 
 def test_shares_of_busy_time_of_the_experts_and_of_the_rings():
