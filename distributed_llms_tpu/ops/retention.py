@@ -36,6 +36,11 @@ the dispatch record (``ops.dispatch.retention_prefill.*`` /
 - :func:`retention_decode`: one recurrence step for every batch slot,
   against the whole stack of every layer's states, updated where it lies
   (the stack is the decode scans' carry and is never copied).
+
+Both kernels are given q, k, v and the gates as they are and make a
+diagonal's products themselves, one lane rotation a diagonal: nothing the
+size of phi(q) or phi(k) is ever built in HBM.  :func:`phi_q` and
+:func:`phi_k` are the plain bodies' and the tests'.
 """
 
 from __future__ import annotations
@@ -88,25 +93,40 @@ def phi_k(x: jax.Array) -> jax.Array:
 # A decode step
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(layer_ref, aux_ref, pq_ref, pk_ref, vb_ref, s_ref,
-                   so_ref, o_ref, acc_ref, *, groups: int):
+def _decode_kernel(layer_ref, x_ref, s_ref, so_ref, o_ref, acc_ref, ph_ref,
+                   vb_ref, *, groups: int):
     """One (row, key/value head, block of 13 diagonals): S <- g S + v
     phi_k^T where it lies, and the readout of the ``groups`` query heads
-    accumulated a [128, 128] tile each, summed over the lanes at the end."""
+    accumulated a [128, 128] tile each, summed over the lanes at the end.
+    ``x_ref`` is the row's head as it is, a row of 128 lanes each: the
+    query heads, then k, v and the gate (every lane the gate).  The block's
+    products are made here, one lane rotation of all the rows a diagonal:
+    ``ph_ref[i]`` holds ``x_a x_{a + d}`` of every row, the key's row with
+    its weight (phi_q's and phi_k's float32 products to the bit), and
+    ``vb_ref`` v down the rows, transposed once a head."""
     del layer_ref
     blk = pl.program_id(2)
+    kr, vr, gr = groups, groups + 1, groups + 2  # the rows of k, v, g in x
 
     @pl.when(blk == 0)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        vb_ref[...] = jnp.broadcast_to(x_ref[0, 0, vr:vr + 1, :], (HD, HD)).T
 
-    g = aux_ref[0, 0, 0:1, :]  # [1, 128], every lane the gate
-    vb = vb_ref[0, 0]  # [128 v, 128]: v down the rows, the same in every lane
+    x = x_ref[0, 0]  # [rows, 128]
+    key = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) == kr
     for i in range(_PER_BLOCK):
-        new = g * s_ref[0, 0, 0, i] + vb * pk_ref[0, 0, 0, i:i + 1, :]
+        d = blk * _PER_BLOCK + i
+        w = jnp.where((d == 0) | (d == DIAGS - 1), 1.0, 2.0)
+        ph_ref[i] = (x * pltpu.roll(x, (HD - d) % HD, 1)
+                     * jnp.where(key, w, 1.0))
+    g = x_ref[0, 0, gr:gr + 1, :]  # [1, 128], every lane the gate
+    vb = vb_ref[...]  # [128 v, 128]: v down the rows, the same in every lane
+    for i in range(_PER_BLOCK):
+        new = g * s_ref[0, 0, 0, i] + vb * ph_ref[i, kr:kr + 1, :]
         so_ref[0, 0, 0, i] = new
         for j in range(groups):
-            acc_ref[j] += new * pq_ref[0, 0, j, 0, i:i + 1, :]
+            acc_ref[j] += new * ph_ref[i, j:j + 1, :]
 
     @pl.when(blk == _BLOCKS - 1)
     def _():
@@ -120,27 +140,26 @@ def _decode_kernel(layer_ref, aux_ref, pq_ref, pk_ref, vb_ref, s_ref,
             o_ref[0, 0, j:j + 1, :] = r[0:1]
 
 
-def _decode_call(states, layer, aux, pq, pk, vb, *, interpret: bool):
+def _decode_call(states, layer, x, *, groups: int, interpret: bool):
     _, b, kvh = states.shape[:3]
-    groups = pq.shape[2]
+    rows = x.shape[2]
     tile = (1, 1, 1, _PER_BLOCK, HD, HD)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, kvh, _BLOCKS),
         in_specs=[
-            pl.BlockSpec((1, 1, 8, HD), lambda r, h, k, l: (r, h, 0, 0)),
-            pl.BlockSpec((1, 1, groups, 1, _PER_BLOCK, HD),
-                         lambda r, h, k, l: (r, h, 0, k, 0, 0)),
-            pl.BlockSpec((1, 1, 1, _PER_BLOCK, HD),
-                         lambda r, h, k, l: (r, h, k, 0, 0)),
-            pl.BlockSpec((1, 1, HD, HD), lambda r, h, k, l: (r, h, 0, 0)),
+            pl.BlockSpec((1, 1, rows, HD), lambda r, h, k, l: (r, h, 0, 0)),
             pl.BlockSpec(tile, lambda r, h, k, l: (l[0], r, h, k, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec(tile, lambda r, h, k, l: (l[0], r, h, k, 0, 0)),
             pl.BlockSpec((1, 1, 8, HD), lambda r, h, k, l: (r, h, 0, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((groups, HD, HD), F32)],
+        scratch_shapes=[
+            pltpu.VMEM((groups, HD, HD), F32),  # the heads' readout
+            pltpu.VMEM((_PER_BLOCK, rows, HD), F32),  # the block's products
+            pltpu.VMEM((HD, HD), F32),  # v down the rows
+        ],
     )
     return pl.pallas_call(
         functools.partial(_decode_kernel, groups=groups),
@@ -149,15 +168,15 @@ def _decode_call(states, layer, aux, pq, pk, vb, *, interpret: bool):
             jax.ShapeDtypeStruct(states.shape, states.dtype),
             jax.ShapeDtypeStruct((b, kvh, 8, HD), F32),
         ],
-        # operands: layer, aux, pq, pk, vb, states -> the stack is updated
-        # where it lies (only the blocks of ``layer`` are visited)
-        input_output_aliases={5: 0},
+        # operands: layer, x, states -> the stack is updated where it lies
+        # (only the blocks of ``layer`` are visited)
+        input_output_aliases={2: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=32 * 1024 * 1024),
         interpret=interpret,
         name="retention_decode",  # the operation's name in a trace
-    )(layer, aux, pq, pk, vb, states)
+    )(layer, x, states)
 
 
 def retention_decode(
@@ -190,23 +209,23 @@ def retention_decode(
          + kf[..., :, None] * kf[..., None, :])
     den = jnp.einsum("bhga,bhac,bhgc->bhg", qf, z, qf)
     norms = jax.lax.dynamic_update_slice_in_dim(norms, z[None], layer[0], 0)
-    pq, pk = phi_q(qf), phi_k(kf)  # [B, KVH, G, 65, 128], [B, KVH, 65, 128]
     mode = dispatch.attention_mode()
     dispatch.record("retention_decode", mode, (b, kvh, groups))
     if mode == "fallback":
+        pq, pk = phi_q(qf), phi_k(kf)  # [B, KVH, G, 65, 128], [.., 65, 128]
         s = (g[..., None, None, None] * states[layer[0]]
              + vf[:, :, None, :, None] * pk[:, :, :, None, :])
         num = jnp.einsum("bhgda,bhdva->bhgv", pq, s)
         states = jax.lax.dynamic_update_slice_in_dim(
             states, s[None], layer[0], 0)
-    else:
-        aux = jnp.broadcast_to(g[..., None, None], (b, kvh, 8, HD))
-        vb = jnp.broadcast_to(vf[..., :, None], (b, kvh, HD, HD))
+    else:  # the kernel makes its own products: q, k, v and the gate as rows
+        pad = -(groups + 3) % 8
+        x = jnp.concatenate([
+            qf, kf[:, :, None], vf[:, :, None],
+            jnp.broadcast_to(g[..., None, None], (b, kvh, 1 + pad, HD))],
+            axis=2)
         states, num = _decode_call(
-            states, layer, aux,
-            pq.reshape(b, kvh, groups, _BLOCKS, _PER_BLOCK, HD),
-            pk.reshape(b, kvh, _BLOCKS, _PER_BLOCK, HD), vb,
-            interpret=mode == "interpret")
+            states, layer, x, groups=groups, interpret=mode == "interpret")
         num = num[:, :, :groups]
     o = num / jnp.where(den == 0.0, 1.0, den)[..., None]
     return o.reshape(b, h, HD).astype(q.dtype), states, norms

@@ -279,6 +279,78 @@ def test_the_kernels_in_interpreter_mode_are_the_dense_operator(monkeypatch):
     assert took("ops.dispatch.retention_decode.fallback") == 1
 
 
+@pytest.fixture(scope="module")
+def decode_step():
+    """One step of the decode kernel under ``interpret``: 3 rows, 5 query
+    heads a key/value head over 2, layer 1 of two.  Row 0 steps from a
+    random non-zero state, row 1 is not live, row 2 steps from an empty
+    state.  XLA's CPU backend contracts ``g S + v pk`` to a fused
+    multiply-add in the interpreter's loop and not in the plain expression,
+    so row 0's values are chosen to make both products exact in float32
+    (powers of two in the state, k and v of 8 significant bits: a product of
+    the key's is still a product of two of ITS entries, weighed) and the sum
+    rounded once either way; row 2, with nothing to add to, has every bit of
+    k and v."""
+    ks = jax.random.split(jax.random.key(51), 7)
+    b, kvh, groups = 3, 2, 5
+    short = lambda x: x.at[0].set(
+        x[0].astype(jnp.bfloat16).astype(jnp.float32))
+    states = jnp.exp2(jnp.round(jax.random.normal(
+        ks[4], (2, b, kvh, 65, 128, 128)))) * jax.random.rademacher(
+        ks[6], (2, b, kvh, 65, 128, 128), jnp.float32)
+    given = dict(
+        q=jax.random.normal(ks[0], (b, kvh * groups, 128)),
+        k=short(jax.random.normal(ks[1], (b, kvh, 128))),
+        v=short(jax.random.normal(ks[2], (b, kvh, 128))),
+        log_g=-jnp.abs(jax.random.normal(ks[3], (b, kvh))),
+        states=states.at[:, 2].set(0.0),
+        norms=jax.random.normal(ks[5], (2, b, kvh, 128, 128)),
+        layer=1, live=jnp.asarray([True, False, True]))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DLT_RAGGED_DECODE", "interpret")
+        return given, retention.retention_decode(**given)
+
+
+@pytest.mark.parametrize("diagonal", range(retention.DIAGS))
+def test_the_decode_kernel_makes_phi_ks_products_to_the_bit(
+        decode_step, diagonal):
+    """The kernel is given q, k, v and the gate and rotates the products out
+    of them itself: on every one of the 65 diagonals (0 and 64, weight 1,
+    among them) the state it writes is ``g S + v phi_k(k)^T`` with ``phi_k``
+    in ``jax.numpy``, bit for bit; a row that is not live and the other
+    layer keep theirs."""
+    x, (_, new, _) = decode_step
+    old = x["states"][:, :, :, diagonal]  # [L, B, KVH, 128 v, 128 a]
+    pk = retention.phi_k(x["k"])[:, :, diagonal]  # [B, KVH, 128 a]
+    want = (jnp.exp(x["log_g"])[:, :, None, None] * old[1]
+            + x["v"][:, :, :, None] * pk[:, :, None, :])
+    want = jnp.where(x["live"][:, None, None, None], want, old[1])
+    assert np.abs(np.asarray(want[0])).min() > 0  # (row 0: a real sum)
+    np.testing.assert_array_equal(new[1, :, :, diagonal], want)
+    np.testing.assert_array_equal(new[0, :, :, diagonal], old[0])
+
+
+def test_the_decode_kernels_numerator_is_phi_q_against_the_state_it_wrote(
+        decode_step):
+    x, (o, new, norms) = decode_step
+    b, kvh = x["k"].shape[:2]
+    qf = x["q"].reshape(b, kvh, -1, 128)
+    pq, s = (np.asarray(retention.phi_q(qf), np.float64),
+             np.asarray(new[1], np.float64))
+    num = np.einsum("bhgda,bhdva->bhgv", pq, s)
+    # the wrapper's own denominator, which it divided the numerator by
+    den = jnp.einsum("bhga,bhac,bhgc->bhg", qf, norms[1], qf)
+    got = np.asarray(o.reshape(b, kvh, -1, 128) * den[..., None])
+    # relative to a head's largest numerator where the state holds many
+    # tokens' worth (rows 0 and 1), to the 8,320 x 128 terms' sizes summed
+    # where it holds one (row 2: its numerator is v (q . k)^2, a hundredth
+    # of |q|^2 |k|^2 |v|, and float32 leaves 1e-7 of THAT)
+    err = np.abs(got - num)
+    assert (err / np.abs(num).max(axis=-1, keepdims=True))[:2].max() < 1e-5
+    terms = np.einsum("bhgda,bhdva->bhgv", np.abs(pq), np.abs(s))
+    assert (err / terms.max(axis=-1, keepdims=True)).max() < 1e-6
+
+
 # -- (h) the control --------------------------------------------------------
 
 def test_a_bfloat16_state_fails_the_tolerance(tiny):

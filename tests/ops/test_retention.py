@@ -10,8 +10,9 @@ reads any ``*_fallback`` above zero as a fault.  Compiled ahead of time for
 one v5e on the compile-only TPU client (tools/aot_decode.py; no chip) at
 Brumby's widths with 2 layers: ``decode_chunk`` keeps the slots' states
 where they lie (the kernel's aliased update is the only instruction that
-produces a state-shaped array), and an admission writes one row's state
-into its slot.  Skips where the installation has no such client.
+produces a state-shaped array) and feeds the kernel small operands alone
+(``ret_fed``), and an admission writes one row's state into its slot.
+Skips where the installation has no such client.
 """
 
 import dataclasses
@@ -109,6 +110,25 @@ def test_decode_chunk_updates_the_states_where_they_lie(compiled):
     # no second copy, not of one layer's slots (half the two layers')
     assert r["temp_gb"] * 1e9 < 0.5 * state / 2
     assert r["weight_shaped"] == []
+
+
+def test_xla_makes_nothing_large_for_the_decode_kernel(compiled):
+    """The kernel is given q, k, v and the gate as rows of one small block
+    and rotates phi(q) and phi(k) out of them itself: beside the stack its
+    operands are under 1 MB together, and the optimised program holds no
+    ``copy`` and no ``gather`` (inside fusions too) as large as a layer's
+    products of the keys, 16 x 8 x 65 x 128 (the parent built 34.5 MB a
+    layer a step through one gather and two transposing copies)."""
+    from tools import aot_decode
+
+    fed = compiled["decode_chunk"]["ret_fed"]
+    assert fed and {e[0] for e in fed}.isdisjoint({"copy", "gather"})
+    assert sum(aot_decode.type_bytes(e[2]) for e in fed) < 1 << 20
+    hlo = compiled["decode_chunk"]["hlo"]
+    # ... and nothing shaped like phi(q) or phi(k), however it is made
+    assert not aot_decode.shaped_like(hlo, [
+        "[16,8,5,65,128]", "[16,8,65,128]", "[16,8,5,5,13,128]",
+        "[16,8,5,13,128]"])
 
 
 def test_an_admission_writes_one_rows_state_into_its_slot(compiled):

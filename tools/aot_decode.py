@@ -31,13 +31,16 @@ retention layers, whose rows hold a float32 state and no key):
 ``decode_chunk`` over the contiguous cache and ``admit_row``, with every
 instruction shaped like the slots' states (``state_shaped``): the decode
 kernel's update where the stack lies and the admission's write of one row
-into its slot, and nothing else.
+into its slot, and nothing else; and with what XLA makes for the decode
+kernel (``ret_fed``): its small operands, and no relayout of a layer's
+products.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import json
+import math
 import os
 import re
 import sys
@@ -259,6 +262,47 @@ def state_shaped(hlo_text: str, cfg, slots: int) -> list:
         f"[{len(cfg.ret_layers)},1,{row}]"])
 
 
+_ARRAY = re.compile(r"\b(pred|[a-z]+(\d+)\w*)\[([\d,]*)\]")
+
+
+def type_bytes(result_type: str) -> int:
+    """Bytes of an instruction's result type as the HLO prints it (the
+    arrays of a tuple summed)."""
+    return sum(
+        math.prod(int(d) for d in dims.split(",") if d)
+        * max(int(bits or 8) // 8, 1)
+        for _, bits, dims in _ARRAY.findall(result_type))
+
+
+def ret_fed(hlo_text: str, cfg, slots: int) -> list:
+    """(opcode, name, result type) of what XLA makes for the retention
+    layers' decode kernel: every operand of ``retention_decode`` but the
+    stack of states, and every ``copy`` or ``gather`` (inside fusions too)
+    of as many bytes as a layer's products of the keys, float32 [slots,
+    KVH, 65, 128], in a decode step.  The kernel is given q, k, v and the
+    gate as rows and rotates the products out of them itself (PR 53), so
+    the operands are small and no such relayout is left.  Empty for a model
+    without retention layers."""
+    if not cfg.ret_layers:
+        return []
+    from distributed_llms_tpu.ops.retention import DIAGS, HD, state_shapes
+
+    row = ",".join(str(n) for n in state_shapes(cfg.num_kv_heads)[0])
+    products = slots * cfg.num_kv_heads * DIAGS * HD * 4
+    instrs = [(m.group(4), m.group(2), m.group(3), m) for m in map(
+        _INSTR.match, hlo_text.splitlines()) if m]
+    by_name = {name: (opcode, name, kind) for opcode, name, kind, _ in instrs}
+    found = []
+    for opcode, name, kind, m in instrs:
+        if opcode == "custom-call" and "retention_decode" in name:
+            operands = m.string[m.end():m.string.index(")", m.end())]
+            found += [by_name[a] for a in re.findall(r"%[\w.\-]+", operands)
+                      if f",{row}]" not in by_name[a][2]]
+        elif opcode in ("copy", "gather") and type_bytes(kind) >= products:
+            found.append((opcode, name, kind))
+    return found
+
+
 def expert_shaped(hlo_text: str, cfg) -> list:
     """Every instruction whose result is shaped like a layer's expert
     stack or like one expert's weights, at any dtype: an expert stack
@@ -465,6 +509,8 @@ def analyse(program: str, cfg, **shape_kw) -> dict:
     return {
         "state_shaped": [list(e) for e in state_shaped(
             text, cfg, shape_kw["slots"])],
+        "ret_fed": [list(e) for e in ret_fed(text, cfg, shape_kw["slots"])]
+        if program == "decode_chunk" else [],
         "ring_shaped": [list(e) for e in ring_shaped(
             text, cfg, shape_kw["slots"])],
         "expert_shaped": [list(e) for e in expert_shaped(text, cfg)],
