@@ -35,7 +35,9 @@ the dispatch record (``ops.dispatch.retention_prefill.*`` /
   chunks: the attention form inside a chunk, the state between chunks.
 - :func:`retention_decode`: one recurrence step for every batch slot,
   against the whole stack of every layer's states, updated where it lies
-  (the stack is the decode scans' carry and is never copied).
+  (the stack is the decode scans' carry and is never copied): the kernel
+  leaves it in HBM and passes the layer's heads through VMEM by its own
+  copies, reads and writes by turns.
 
 Both kernels are given q, k, v and the gates as they are and make a
 diagonal's products themselves, one lane rotation a diagonal: nothing the
@@ -56,7 +58,14 @@ from . import dispatch
 
 HD = 128  # a head's width: the layout is written for whole 128-lane rows
 DIAGS = HD // 2 + 1  # cyclic diagonals of the symmetric square: 65
-_BLOCKS, _PER_BLOCK = 5, 13  # the decode kernel's walk of the 65 diagonals
+# The decode kernel's loop over a head's diagonals unrolls 5: 13 take twice as
+# long to trace for the same time on the chip, 1 does not hide under the copies
+_RUN = 5
+# The decode call's VMEM: 52 MiB for its heads' buffers, and the rest of 96
+# claimed on purpose: into what the call leaves free XLA copies the next
+# matmuls' whole scale stacks, 36 MB a layer beside the kernel's turns
+_VMEM_BUDGET = 52 * 1024 * 1024
+_VMEM_LIMIT = 96 * 1024 * 1024
 F32 = jnp.float32
 
 
@@ -77,6 +86,17 @@ def _key_weights() -> jax.Array:
     return jnp.where((d == 0) | (d == DIAGS - 1), 1.0, 2.0).astype(F32)
 
 
+def _turn(kv_heads: int) -> int:
+    """Key/value heads of a row that the decode kernel reads, and then
+    writes, in one turn: as many as divide the row's heads and fit
+    ``_VMEM_BUDGET`` three turns deep (one read, one computed, one
+    written).  Longer turns lose less to the change of direction: 675 GB/s
+    at one head a turn, 688 at two, 695 at four (PERF.md, PR 54)."""
+    head = DIAGS * HD * HD * 4
+    return max(t for t in range(1, kv_heads + 1)
+               if kv_heads % t == 0 and 3 * t * head <= _VMEM_BUDGET)
+
+
 def phi_q(x: jax.Array) -> jax.Array:
     """[..., 128] -> [..., 65, 128]: x_a x_{(a + d) mod 128}."""
     idx = (jnp.arange(HD)[None, :] + jnp.arange(DIAGS)[:, None]) % HD
@@ -93,87 +113,160 @@ def phi_k(x: jax.Array) -> jax.Array:
 # A decode step
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(layer_ref, x_ref, s_ref, so_ref, o_ref, acc_ref, ph_ref,
-                   vb_ref, *, groups: int):
-    """One (row, key/value head, block of 13 diagonals): S <- g S + v
-    phi_k^T where it lies, and the readout of the ``groups`` query heads
-    accumulated a [128, 128] tile each, summed over the lanes at the end.
-    ``x_ref`` is the row's head as it is, a row of 128 lanes each: the
-    query heads, then k, v and the gate (every lane the gate).  The block's
-    products are made here, one lane rotation of all the rows a diagonal:
-    ``ph_ref[i]`` holds ``x_a x_{a + d}`` of every row, the key's row with
-    its weight (phi_q's and phi_k's float32 products to the bit), and
-    ``vb_ref`` v down the rows, transposed once a head."""
-    del layer_ref
-    blk = pl.program_id(2)
+def _decode_kernel(layer_ref, x_ref, s_hbm, so_hbm, o_ref, buf, sem, acc_ref,
+                   ph_ref, vb_ref, *, groups: int, turn: int):
+    """Every (row, key/value head) of one layer: S <- g S + v phi_k^T where
+    it lies, and the readout of the ``groups`` query heads.  The stack stays
+    in HBM (``s_hbm`` and ``so_hbm`` are one array) and the kernel copies
+    ``turn`` heads of a row at a time through three buffers, reads and
+    writes BY TURNS and never together: HBM gives a stream that is read and
+    written at once 630-660 GB/s on this chip and 690-695 by turns (reads
+    alone 757, writes alone 657; PERF.md, PR 54), and every vector pass
+    hides under either.  While a turn's heads are computed, the turn
+    before is written and then the turn after is read."""
+    layer = layer_ref[0]
+    _, b, kvh = s_hbm.shape[:3]
+    per_row = kvh // turn
+    turns = b * per_row
+
+    def row_head(t, j):  # of head ``j`` of turn ``t``
+        return t // per_row, (t % per_row) * turn + j
+
+    def copies(t, slot, into_vmem: bool):
+        for j in range(turn):
+            there, here = (layer, *row_head(t, j)), buf.at[slot, j]
+            yield (pltpu.make_async_copy(s_hbm.at[there], here, sem.at[slot])
+                   if into_vmem else pltpu.make_async_copy(
+                       here, so_hbm.at[there], sem.at[slot]))
+
+    def start(t, slot, into_vmem):
+        for dma in copies(t, slot, into_vmem):
+            dma.start()
+
+    def wait(t, slot, into_vmem):
+        for dma in copies(t, slot, into_vmem):
+            dma.wait()
+
+    start(0, 0, True)
+    wait(0, 0, True)
+
+    def one_turn(t, slot):
+        before, after = (slot + 2) % 3, (slot + 1) % 3
+        more = t + 1 < turns
+
+        @pl.when(t > 0)
+        def _():
+            start(t - 1, before, False)
+
+        @pl.when(jnp.logical_and(t == 0, more))
+        def _():  # nothing is being written yet
+            start(1, after, True)
+
+        def head(j, carry):
+            @pl.when(jnp.logical_and(
+                j == turn // 2, jnp.logical_and(t > 0, more)))
+            def _():  # the turn's middle: the copies change direction
+                wait(t - 1, before, False)
+                start(t + 1, after, True)
+
+            _decode_head(x_ref, buf.at[slot, j], o_ref, acc_ref, ph_ref,
+                         vb_ref, *row_head(t, j), groups)
+            return carry
+
+        jax.lax.fori_loop(0, turn, head, 0)
+
+        @pl.when(jnp.logical_and(t > 0, jnp.logical_not(more)))
+        def _():  # the last turn: nothing to read, so nothing to take turns
+            wait(t - 1, before, False)
+
+        @pl.when(more)
+        def _():
+            wait(t + 1, after, True)
+
+        return after
+
+    last = (jax.lax.fori_loop(0, turns, one_turn, 0) + 2) % 3
+    start(turns - 1, last, False)
+    wait(turns - 1, last, False)
+
+
+def _decode_head(x_ref, s_ref, o_ref, acc_ref, ph_ref, vb_ref, r, h,
+                 groups: int):
+    """One (row, key/value head) on its state in VMEM, ``s_ref`` [65, 128,
+    128], updated in place: the 65 diagonals in order, a loop over runs of
+    ``_RUN`` unrolled, the readout accumulated a [128, 128] tile a query
+    head and summed over the lanes at the end.  ``x_ref[r, h]`` is the
+    row's head as it is, a row of 128 lanes each: the query heads, then k,
+    v and the gate (every lane the gate).  A run's products are made here,
+    one lane rotation of all the rows a diagonal: ``ph_ref[i]`` holds ``x_a
+    x_{a + d}`` of every row, the key's row with its weight (phi_q's and
+    phi_k's float32 products to the bit), and ``vb_ref`` v down the rows,
+    transposed once a head."""
     kr, vr, gr = groups, groups + 1, groups + 2  # the rows of k, v, g in x
-
-    @pl.when(blk == 0)
-    def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        vb_ref[...] = jnp.broadcast_to(x_ref[0, 0, vr:vr + 1, :], (HD, HD)).T
-
-    x = x_ref[0, 0]  # [rows, 128]
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    vb_ref[...] = jnp.broadcast_to(x_ref[r, h, vr:vr + 1, :], (HD, HD)).T
+    x = x_ref[r, h]  # [rows, 128]
     key = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) == kr
-    for i in range(_PER_BLOCK):
-        d = blk * _PER_BLOCK + i
-        w = jnp.where((d == 0) | (d == DIAGS - 1), 1.0, 2.0)
-        ph_ref[i] = (x * pltpu.roll(x, (HD - d) % HD, 1)
-                     * jnp.where(key, w, 1.0))
-    g = x_ref[0, 0, gr:gr + 1, :]  # [1, 128], every lane the gate
-    vb = vb_ref[...]  # [128 v, 128]: v down the rows, the same in every lane
-    for i in range(_PER_BLOCK):
-        new = g * s_ref[0, 0, 0, i] + vb * ph_ref[i, kr:kr + 1, :]
-        so_ref[0, 0, 0, i] = new
-        for j in range(groups):
-            acc_ref[j] += new * ph_ref[i, j:j + 1, :]
+    g = x_ref[r, h, gr:gr + 1, :]  # [1, 128], every lane the gate
 
-    @pl.when(blk == _BLOCKS - 1)
-    def _():
-        ones = jnp.ones((8, HD), F32)
-        for j in range(groups):
-            # sum over the lanes (a), laid along the lanes (v): ones @ acc^T
-            r = jax.lax.dot_general(
-                ones, acc_ref[j], (((1,), (1,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=F32)
-            o_ref[0, 0, j:j + 1, :] = r[0:1]
+    def run(n, carry):
+        for i in range(_RUN):
+            d = n * _RUN + i
+            w = jnp.where((d == 0) | (d == DIAGS - 1), 1.0, 2.0)
+            ph_ref[i] = (x * pltpu.roll(x, (HD - d) % HD, 1)
+                         * jnp.where(key, w, 1.0))
+        vb = vb_ref[...]  # [128 v, 128]: v down the rows, in every lane
+        for i in range(_RUN):
+            d = n * _RUN + i
+            new = g * s_ref[d] + vb * ph_ref[i, kr:kr + 1, :]
+            s_ref[d] = new
+            for j in range(groups):
+                acc_ref[j] += new * ph_ref[i, j:j + 1, :]
+        return carry
+
+    jax.lax.fori_loop(0, DIAGS // _RUN, run, 0)
+    ones = jnp.ones((8, HD), F32)
+    for j in range(groups):
+        # sum over the lanes (a), laid along the lanes (v): ones @ acc^T
+        num = jax.lax.dot_general(
+            ones, acc_ref[j], (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=F32)
+        o_ref[r, h, j:j + 1, :] = num[0:1]
 
 
-def _decode_call(states, layer, x, *, groups: int, interpret: bool):
+def _decode_call(states, layer, x, *, groups: int, interpret: bool,
+                 turn: int | None = None):
     _, b, kvh = states.shape[:3]
     rows = x.shape[2]
-    tile = (1, 1, 1, _PER_BLOCK, HD, HD)
+    turn = _turn(kvh) if turn is None else turn
+    whole = lambda shape: pl.BlockSpec(shape, lambda i, l: (0,) * len(shape))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kvh, _BLOCKS),
-        in_specs=[
-            pl.BlockSpec((1, 1, rows, HD), lambda r, h, k, l: (r, h, 0, 0)),
-            pl.BlockSpec(tile, lambda r, h, k, l: (l[0], r, h, k, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec(tile, lambda r, h, k, l: (l[0], r, h, k, 0, 0)),
-            pl.BlockSpec((1, 1, 8, HD), lambda r, h, k, l: (r, h, 0, 0)),
-        ],
+        grid=(1,),
+        in_specs=[whole(x.shape), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY), whole((b, kvh, 8, HD))],
         scratch_shapes=[
-            pltpu.VMEM((groups, HD, HD), F32),  # the heads' readout
-            pltpu.VMEM((_PER_BLOCK, rows, HD), F32),  # the block's products
+            pltpu.VMEM((3, turn, DIAGS, HD, HD), F32),  # three turns' heads
+            pltpu.SemaphoreType.DMA((3,)),
+            pltpu.VMEM((groups, HD, HD), F32),  # a head's readout
+            pltpu.VMEM((_RUN, rows, HD), F32),  # a run's products
             pltpu.VMEM((HD, HD), F32),  # v down the rows
         ],
     )
     return pl.pallas_call(
-        functools.partial(_decode_kernel, groups=groups),
+        functools.partial(_decode_kernel, groups=groups, turn=turn),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(states.shape, states.dtype),
             jax.ShapeDtypeStruct((b, kvh, 8, HD), F32),
         ],
         # operands: layer, x, states -> the stack is updated where it lies
-        # (only the blocks of ``layer`` are visited)
+        # (only the heads of ``layer`` are copied in and out)
         input_output_aliases={2: 0},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=32 * 1024 * 1024),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="retention_decode",  # the operation's name in a trace
     )(layer, x, states)

@@ -351,6 +351,57 @@ def test_the_decode_kernels_numerator_is_phi_q_against_the_state_it_wrote(
     assert (err / terms.max(axis=-1, keepdims=True)).max() < 1e-6
 
 
+@pytest.fixture(scope="module")
+def decode_turns(decode_step):
+    """The same step through ``_decode_call`` a key/value head a turn (six
+    turns: each head read, computed and written on its own) and at the turn
+    the module derives for itself, a row's two heads together: (states,
+    numerator) each."""
+    given, _ = decode_step
+    got = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DLT_RAGGED_DECODE", "interpret")
+        call = retention._decode_call
+        for turn in (1, None):
+            def spy(*args, turn=turn, **kw):
+                got[turn] = call(*args, turn=turn, **kw)
+                return got[turn]
+            mp.setattr(retention, "_decode_call", spy)
+            retention.retention_decode(**given)
+    return got
+
+
+@pytest.mark.parametrize("part", ["state", "numerator"])
+@pytest.mark.parametrize("row", ["live", "not live", "live, empty state"])
+def test_the_decode_kernel_writes_the_same_bits_whatever_its_turn(
+        decode_turns, row, part):
+    """How many heads the kernel copies in a turn, and so which of its three
+    buffers a head passes through and beside which copies it is computed,
+    moves no bit: the state and the 5 query heads' numerators are equal for
+    a row that steps, one that is not live and one that steps from
+    nothing."""
+    assert retention._turn(2) == 2  # (the module's own is another than 1)
+    r = ["live", "not live", "live, empty state"].index(row)
+    (s1, n1), (s, n) = decode_turns[1], decode_turns[None]
+    if part == "state":
+        np.testing.assert_array_equal(s[:, r], s1[:, r])
+        assert np.asarray(s[1, r]).any() or row != "live"
+    else:
+        assert n.shape == (3, 2, 8, 128)
+        np.testing.assert_array_equal(n[r, :, :5], n1[r, :, :5])
+        assert np.asarray(n[r, :, :5]).any()
+
+
+@pytest.mark.parametrize("kv_heads,turn", [(8, 4), (2, 2), (6, 3), (7, 1)])
+def test_a_turn_is_the_most_heads_of_a_row_that_fit_three_deep(
+        kv_heads, turn):
+    """Brumby's 8 heads go four a turn: 17 MB read, then 17 MB written,
+    three turns' buffers 51 MB of the call's 96 MiB."""
+    assert retention._turn(kv_heads) == turn
+    head = retention.DIAGS * 128 * 128 * 4
+    assert 3 * turn * head <= retention._VMEM_BUDGET < retention._VMEM_LIMIT
+
+
 # -- (h) the control --------------------------------------------------------
 
 def test_a_bfloat16_state_fails_the_tolerance(tiny):

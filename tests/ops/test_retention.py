@@ -131,6 +131,31 @@ def test_xla_makes_nothing_large_for_the_decode_kernel(compiled):
         "[16,8,5,13,128]"])
 
 
+def test_three_turns_of_heads_fit_the_decode_calls_vmem(compiled):
+    """The decode kernel leaves the stack in HBM and copies four of a row's
+    heads a turn through three buffers (PR 54): the TPU's compiler took the
+    call, so they fit, and what they come to beside the scratch lies inside
+    the scoped memory the call asks for, ``vmem_limit_bytes``, as the
+    compiled program states it; the call still writes the stack it is
+    given (the two tests above hold the rest of that program)."""
+    import re
+
+    r = compiled["decode_chunk"]
+    (call,) = [line for line in r["hlo"].splitlines()
+               if "custom-call(" in line and "%retention_decode" in line]
+    assert "output_to_operand_aliasing={{0}: (2, {})}" in call
+    scoped = [int(n) for n in re.findall(
+        r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+",'
+        r'"size":"(\d+)"', call)]
+    assert scoped == [retention._VMEM_LIMIT]
+    turn = retention._turn(KVH)
+    assert turn == 4
+    buffers = 3 * turn * retention.DIAGS * 128 * 128 * 4
+    scratch = (H // KVH + 1) * 128 * 128 * 4 + retention._RUN * 8 * 128 * 4
+    small = 2 * 2 * 16 * KVH * 8 * 128 * 4  # x and the numerators, whole
+    assert 51e6 < buffers < buffers + scratch + small < scoped[0]
+
+
 def test_an_admission_writes_one_rows_state_into_its_slot(compiled):
     r = compiled["admit_row"]
     stack = "f32[2,16,8,65,128,128]"
