@@ -233,9 +233,11 @@ _BIAS_NAMES = frozenset({"bq", "bk", "bv", "bo", "b_in", "b_out", "b_gate", "b_u
 # [L, r, H * (nope + v)], which an admission reads as it lies and a decode
 # step transposed (the absorbed form): both in the model's dtype, so the two
 # paths read the same stored values; of a retention layer (blocks/ret/...)
-# the gate's projection ``wg`` [L, D, KVH], 8 columns wide.
+# the gate's projection ``wg`` [L, D, KVH], 8 columns wide; of a Mamba-2
+# layer (blocks/ssm/...) the convolution's taps and bias and a head's
+# ``A_log``, ``dt_bias`` and ``D`` [L, heads], which set what a state forgets.
 _HYBRID_FLOAT = frozenset({"taps", "router", "expert_bias", "wkv_a", "wkv_b",
-                           "wg"})
+                           "wg", "A_log", "dt_bias", "D", "conv_bias"})
 
 
 def block_axis_of(path: str) -> int:
@@ -250,7 +252,7 @@ def _should_quantize(path: str, x: Any) -> bool:
         return False
     leaf = path.split("/")[-1]
     if path.startswith(("blocks/conv/", "blocks/moe/", "blocks/mla/",
-                        "blocks/ret/")) \
+                        "blocks/ret/", "blocks/ssm/")) \
             and leaf in _HYBRID_FLOAT:
         return False
     if "norm" in path or "ln" in path.split("/")[-2:][0]:

@@ -92,6 +92,8 @@ class ModelConfig:
     # Gated-MLP activation: "silu" (Llama/Qwen2), "gelu_tanh" (Gemma's
     # GeGLU) or "relu" (a ReGLU).  The dense MLP, a shared expert and the
     # routed experts of either expert layer all take it (layers.gate_fn).
+    # "relu2": NO gate matrix, ``relu(x W_up)^2 W_down`` (the latent
+    # experts and their shared expert, ``moe_latent_size``).
     gate_act: str = "silu"
     # Embedding multiplier applied after lookup (Gemma: sqrt(hidden_size)).
     embed_scale: float = 1.0
@@ -128,11 +130,12 @@ class ModelConfig:
             raise ValueError(
                 f"unknown attn_impl {self.attn_impl!r}; choose from {sorted(_ATTN_IMPLS)}"
             )
-        if self.gate_act not in ("silu", "gelu_tanh", "relu"):
+        if self.gate_act not in ("silu", "gelu_tanh", "relu", "relu2"):
             raise ValueError(
-                f"unknown gate_act {self.gate_act!r}; choose silu, gelu_tanh "
-                "or relu"
+                f"unknown gate_act {self.gate_act!r}; choose silu, gelu_tanh, "
+                "relu or relu2"
             )
+        object.__setattr__(self, "no_ffn_layers", tuple(self.no_ffn_layers))
         if self.moe_router_input not in ("ffn_norm", "block_input"):
             raise ValueError(
                 f"unknown moe_router_input {self.moe_router_input!r}; choose "
@@ -161,13 +164,38 @@ class ModelConfig:
             )
         if self.layer_types:
             bad = set(self.layer_types) - {"conv", "attn", "mla", "swa",
-                                           "ret"}
+                                           "ret", "ssm"}
             if bad or len(self.layer_types) != self.num_layers:
                 raise ValueError(
                     f"layer_types must name {self.num_layers} layers as "
-                    f"'conv', 'attn', 'swa', 'mla' or 'ret', got "
+                    f"'conv', 'attn', 'swa', 'mla', 'ret' or 'ssm', got "
                     f"{self.layer_types!r}"
                 )
+        if self.no_ffn_layers and (
+                not self.layer_types
+                or not set(self.no_ffn_layers) <= set(range(self.num_layers))):
+            raise ValueError(
+                f"no_ffn_layers {self.no_ffn_layers!r} names blocks of a "
+                f"hybrid model's {self.num_layers}"
+            )
+        if "ssm" in self.layer_types and (
+                set(self.layer_types) - {"ssm", "attn"}
+                or (self.ssm_heads * self.ssm_head_dim) % 128
+                or 128 % self.ssm_head_dim
+                or self.ssm_heads % self.ssm_groups
+                or (self.ssm_heads // self.ssm_groups)
+                % (128 // self.ssm_head_dim)):
+            raise ValueError(
+                "a model of state-space layers mixes 'ssm' with 'attn' "
+                "alone, and its heads lie 128 // ssm_head_dim to a 128-lane "
+                "row, each row inside ONE group (the state's layout, "
+                "ops/ssm.py)"
+            )
+        if (self.gate_act == "relu2") != bool(self.moe_latent_size):
+            raise ValueError(
+                "gate_act 'relu2' is the non-gated expert's, which works in "
+                "a latent (moe_latent_size): give both or neither"
+            )
         if "ret" in self.layer_types and (
                 set(self.layer_types) != {"ret"} or self.head_dim_ != 128
                 or not self.qk_norm or self.num_experts):
@@ -289,7 +317,10 @@ class ModelConfig:
     # ``sliding_window`` positions), "mla" or "ret" (power retention of
     # degree 2: GQA's projections and a scalar gate a key/value head, the
     # row's whole memory one float32 state a key/value head and no key:
-    # ops/retention.py).  Empty for the families whose layers are all alike.
+    # ops/retention.py) or "ssm" (Mamba-2, the ssm_* fields below: a float32
+    # state a head and the convolution's last inputs a row, beside the
+    # "attn" layers' pages).  Empty for the families whose layers are all
+    # alike.
     layer_types: tuple[str, ...] = ()
     # Tokens a chunk of a "ret" layer's admission scan (ops/retention.py):
     # the attention form inside a chunk, the state between chunks.
@@ -347,6 +378,32 @@ class ModelConfig:
     experts_held: int | None = None
     experts_offset: int = 0
 
+    # Blocks of a hybrid model that are an operator ALONE, with no FFN
+    # behind it (indices into ``layer_types``).  A published stack of single
+    # sub-layers ``x <- x + f(rms(x))`` folds into (operator, FFN) blocks;
+    # where two operators follow each other the first is such a block.
+    no_ffn_layers: tuple[int, ...] = ()
+    # Mamba-2 (layer kind "ssm", ops/ssm.py): ``ssm_heads`` heads of
+    # ``ssm_head_dim`` (d_inner their product), B and C shared by the
+    # heads of one of ``ssm_groups`` groups, a state [head_dim, ssm_state]
+    # a head in float32, ``ssm_conv_kernel`` taps of a causal depthwise
+    # convolution over [x | B | C], ``ssm_chunk`` tokens a chunk of an
+    # admission's scan.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_state: int = 128
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
+    # LatentMoE: the routed experts read and write a latent of this width
+    # (``h W_dn``, the weighted sum back through ``W_up``); the router and
+    # the shared expert read the hidden width.  0: experts at the hidden
+    # width.  With it the experts are NOT gated (``gate_act`` "relu2":
+    # ``relu(x W_up)^2 W_down``, two matrices an expert), and so is the
+    # shared expert, ``moe_shared_intermediate_size`` wide.
+    moe_latent_size: int = 0
+    moe_shared_intermediate_size: int | None = None
+
     @property
     def head_dim_(self) -> int:
         return self.head_dim if self.head_dim is not None else self.hidden_size // self.num_heads
@@ -400,6 +457,39 @@ class ModelConfig:
     def conv_layers(self) -> tuple[int, ...]:
         """Indices of the layers that keep convolution state a row."""
         return tuple(i for i, t in enumerate(self.layer_types) if t == "conv")
+
+    @property
+    def ssm_layers(self) -> tuple[int, ...]:
+        """Indices of the Mamba-2 layers, which keep a float32 state a row
+        and a head and the convolution's last inputs
+        (kv_cache.HybridCache.ssm_h / ssm_conv) BESIDE the page pool of
+        the attention layers."""
+        return tuple(i for i, t in enumerate(self.layer_types) if t == "ssm")
+
+    @property
+    def ssm_inner(self) -> int:
+        """Mamba-2's d_inner: heads x head size."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels the convolution runs over: [x | B | C]."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def shared_size(self) -> int:
+        """Width of the shared expert."""
+        return (self.moe_shared_intermediate_size
+                or self.n_shared_experts * self.expert_size)
+
+    @property
+    def ffn_kinds(self) -> tuple[str | None, ...]:
+        """The FFN of each block of a hybrid model: "dense", "moe", or
+        None (``no_ffn_layers``)."""
+        return tuple(
+            None if l in self.no_ffn_layers
+            else "dense" if l < self.num_dense_layers or not self.num_experts
+            else "moe" for l in range(self.num_layers))
 
     @property
     def ret_layers(self) -> tuple[int, ...]:

@@ -608,6 +608,21 @@ METRIC_DOCS: dict[str, str] = {
     "ret.decode.resident_tokens": "tokens those rows held, summed over "
                                   "decode steps: what keys and values "
                                   "would have had to be read for them",
+    "batcher.ssm_state_bytes": "bytes of the state a model of Mamba-2 "
+                               "layers keeps BESIDE its page pool, one "
+                               "entry a layer and a batch slot: the float32 "
+                               "state of every head and the convolution's "
+                               "last inputs, whatever the rows hold (gauge)",
+    # -- state-space layers (models.model.ssm_counts; real tokens only,
+    #    carried out of each admission and decode chunk behind the experts'
+    #    counts, added at delivery; a layer's, not summed over the layers) --
+    "ssm.admit.tokens": "real prompt tokens the Mamba-2 layers' chunked "
+                        "scan took in, summed over admissions",
+    "ssm.admit.chunks": "chunks of ssm_chunk tokens that scan walked: those "
+                        "that hold a real token",
+    "ssm.decode.row_steps": "rows that took a recurrence step, summed over "
+                            "decode steps: each reads and writes its state "
+                            "once a layer",
     "attn.decode.resident_tokens": "tokens the decoding rows held, summed "
                                    "over decode steps: what the paged decode "
                                    "kernel read a full attention layer (a "

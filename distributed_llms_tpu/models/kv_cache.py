@@ -96,13 +96,23 @@ class HybridCache(KVCache):
     normaliser (ops/retention.py says how the products of a key lie in
     them).  They are a row's WHOLE memory in such a layer: a model of
     retention layers alone holds no key, ``k``/``v`` count zero layers, and
-    it is served without a page pool (:func:`refuse_unpaged_state`)."""
+    it is served without a page pool (:func:`refuse_unpaged_state`).
+    ``ssm_h`` [ssm layers, B, R, N, 128] float32 whatever the activations'
+    dtype: each Mamba-2 layer's state a row, heads x head size x state size
+    values as ops/ssm.py lays them (R = heads x head size / 128 rows of N x
+    128); ``ssm_conv`` [ssm layers, B, K-1, x + B + C channels], the last
+    K-1 inputs of its convolution, in the activations' dtype.  They lie
+    BESIDE the pool: such a model's attention layers page their keys as any
+    other's do, ``k``/``v`` count those layers, and it is served from the
+    pool."""
 
     conv: Any = None
     ring_k: Any = None
     ring_v: Any = None
     ret_s: Any = None
     ret_z: Any = None
+    ssm_h: Any = None
+    ssm_conv: Any = None
 
 
 @jax.tree_util.register_dataclass
@@ -153,8 +163,19 @@ def slot_state(cfg: ModelConfig, rows: int, dtype) -> dict:
     """What a :class:`HybridCache` holds beside k and v for ``rows`` rows,
     zeroed, by field: the convolution layers' state, the windowed layers'
     rings (in the keys' dtype), the retention layers' state and normaliser
-    (float32: ``ops.retention.state_shapes``)."""
+    (float32: ``ops.retention.state_shapes``), the Mamba-2 layers' state
+    (float32: ``ops.ssm.state_shape``) and their convolutions' last inputs."""
     out = {}
+    if cfg.ssm_layers:
+        from ..ops.ssm import state_shape
+
+        lead = (len(cfg.ssm_layers), rows)
+        out["ssm_h"] = jnp.zeros(
+            lead + state_shape(cfg.ssm_heads, cfg.ssm_head_dim,
+                               cfg.ssm_state), jnp.float32)
+        out["ssm_conv"] = jnp.zeros(
+            lead + (cfg.ssm_conv_kernel - 1, cfg.ssm_conv_width),
+            jnp.dtype(cfg.dtype))
     if cfg.ret_layers:
         from ..ops.retention import state_shapes
 
@@ -235,8 +256,9 @@ def format_bytes(pool, cfg: ModelConfig) -> dict[str, float]:
     """Sizes that only one format has, for its gauges: the bytes of the
     state a :class:`HybridCache` keeps beside its pages (``conv_state``,
     ``window_state``: the windowed layers' rings, ``ret_state``: the
-    retention layers' state and normaliser), the bytes of one page of a
-    :class:`LatentCache` (``latent_page``)."""
+    retention layers' state and normaliser, ``ssm_state``: the Mamba-2
+    layers' state and their convolutions' last inputs), the bytes of one page
+    of a :class:`LatentCache` (``latent_page``)."""
     match pool:
         case HybridCache():
             sizes = {}
@@ -248,6 +270,9 @@ def format_bytes(pool, cfg: ModelConfig) -> dict[str, float]:
             if pool.ret_s is not None:
                 sizes["ret_state"] = float(
                     pool.ret_s.nbytes + pool.ret_z.nbytes)
+            if pool.ssm_h is not None:
+                sizes["ssm_state"] = float(
+                    pool.ssm_h.nbytes + pool.ssm_conv.nbytes)
             return sizes
         case LatentCache():
             return {"latent_page": float(
@@ -258,7 +283,8 @@ def format_bytes(pool, cfg: ModelConfig) -> dict[str, float]:
 
 # The leaves of a :class:`HybridCache` that hold one entry a batch slot
 # ([layers of the kind, B, ...]) and no page.
-_SLOT_FIELDS = ("conv", "ring_k", "ring_v", "ret_s", "ret_z")
+_SLOT_FIELDS = ("conv", "ring_k", "ring_v", "ret_s", "ret_z", "ssm_h",
+                "ssm_conv")
 
 
 def splice_slot(cache: "HybridCache", slot, row_cache: "HybridCache"):
@@ -581,11 +607,12 @@ def pages_are_private(cfg: ModelConfig) -> bool:
     feature that reads [.., KVH, HD] rows out of the pool (prefix cache,
     named prefixes, tiering, import/export, chunked prefill, speculation,
     the int8 pool, a mesh), which it does for a model that keeps state
-    beside its pages (convolution state, the windowed layers' rings:
-    :class:`HybridCache`).  Only then may heads narrower than
+    beside its pages (convolution state, the windowed layers' rings, a
+    state-space layer's state: :class:`HybridCache`).  Only then may heads narrower than
     a 128-lane row lie folded in the pool
     (ops.decode_attn.pool_head_shape; heads of 128 never fold)."""
-    return bool(cfg.conv_layers or cfg.swa_layers or cfg.ret_layers)
+    return bool(cfg.conv_layers or cfg.swa_layers or cfg.ret_layers
+                or cfg.ssm_layers)
 
 
 _LATENT_REFUSALS = {
@@ -647,8 +674,8 @@ _STATE_REFUSALS = {
                     "no page: nothing snapshots the state at a prefix's end",
     "kv_bits": "the int8 pool quantizes keys and values; the state is "
                "float32 and is never quantized (ask for kv_bits 16)",
-    "host_pages": "the host tier parks pages; nothing parks a row's 34 MB "
-                  "a layer of state",
+    "host_pages": "the host tier parks pages; nothing parks a row's "
+                  "megabytes a layer of state",
     "speculative": "a rejected draft would have to roll the state back, "
                    "and a state keeps no past",
     "prefill_chunk": "a chunked prefill would have to hand the state from "
@@ -684,6 +711,11 @@ def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
     and ``_STATE_REFUSALS`` names what cannot snapshot, ship or roll back
     the state.
 
+    A model of Mamba-2 layers beside attention layers (``cfg.ssm_layers``)
+    holds such a state AND keys: it is served FROM the pool (``paged_pages``
+    is required, as for convolution state), and refuses for the state's sake
+    what ``_STATE_REFUSALS`` names, all but its ``paged_pages`` entry.
+
     The latent format (:class:`LatentCache`) refuses here too, with its own
     reasons: what is written for key/value page pairs and has no latent
     case yet.  Its pages are whole rows' states, so ``prefix_cache`` is
@@ -713,6 +745,25 @@ def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
                     f"{name} is not supported for a model whose rows hold "
                     f"a recurrent state and no key (family {cfg.family!r}"
                     f", power retention): {_STATE_REFUSALS[name]}"
+                )
+        return
+    if cfg.ssm_layers:
+        # A recurrent state BESIDE a pool: the attention layers' keys are
+        # paged, the state is one entry a batch slot, and whatever would
+        # snapshot, ship or roll back the state is refused until something
+        # can.
+        if asked.pop("paged_pages", 1) is None:
+            raise ValueError(
+                f"{cfg.family} model: the batcher serves its attention "
+                "layers' keys and values from the page pool only, the "
+                "state-space layers' state beside it; pass paged_pages"
+            )
+        for name, value in asked.items():
+            if value:
+                raise ValueError(
+                    f"{name} is not supported for a model whose rows hold "
+                    f"a recurrent state beside their pages (family "
+                    f"{cfg.family!r}, Mamba-2): {_STATE_REFUSALS[name]}"
                 )
         return
     if not pages_are_private(cfg):

@@ -300,15 +300,25 @@ def mlp_gelu(x: jax.Array, p: Params, activation: str = "gelu") -> jax.Array:
     return _contract(h, p["w_out"], "btf,fd->btd", 1, "k") + _plain(p["b_out"])
 
 
+def _relu2(g: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(g))
+
+
+def _gelu_tanh(g: jax.Array) -> jax.Array:
+    return jax.nn.gelu(g, approximate=True)
+
+
 def gate_fn(gate_act: str):
     """The gate's activation of a gated MLP, by ``cfg.gate_act``: "silu"
     (Llama/Qwen2), "gelu_tanh" (Gemma's GeGLU) or "relu" (a ReGLU:
     SmallThinker's experts).  The dense MLP, the shared expert and the
-    routed experts of both expert layers read it here."""
-    return {
-        "silu": jax.nn.silu, "relu": jax.nn.relu,
-        "gelu_tanh": lambda g: jax.nn.gelu(g, approximate=True),
-    }[gate_act]
+    routed experts of both expert layers read it here.  "relu2" is
+    ``relu(.)^2`` and gates nothing: the activation of an MLP of two
+    matrices (:func:`mlp_plain`; the latent experts)."""
+    # (module-level functions: the expert kernel takes one as a static
+    # argument, and a fresh lambda a call would be a fresh program a call)
+    return {"silu": jax.nn.silu, "relu": jax.nn.relu,
+            "gelu_tanh": _gelu_tanh, "relu2": _relu2}[gate_act]
 
 
 @jax.named_scope("mlp")  # profiler scope; HLO metadata only
@@ -318,6 +328,13 @@ def mlp_swiglu(x: jax.Array, p: Params, gate_act: str = "silu") -> jax.Array:
     gate = _contract(x, p["w_gate"], "btd,df->btf", 1, "n")
     up = _contract(x, p["w_up"], "btd,df->btf", 1, "n")
     h = gate_fn(gate_act)(gate) * up
+    return _contract(h, p["w_down"], "btf,fd->btd", 1, "k")
+
+
+@jax.named_scope("mlp")  # profiler scope; HLO metadata only
+def mlp_plain(x: jax.Array, p: Params, act: str) -> jax.Array:
+    """MLP of two matrices, no gate, no biases: act(x W_up) W_down."""
+    h = gate_fn(act)(_contract(x, p["w_up"], "btd,df->btf", 1, "n"))
     return _contract(h, p["w_down"], "btf,fd->btd", 1, "k")
 
 
@@ -400,7 +417,13 @@ def moe_dropless(
     nothing here (their chips would), experts touched and the fullest
     expert's load count the held ones, and stats has a fifth entry, the
     pairs that fell on a held expert.  (A shared expert, which every
-    token goes through, is the caller's to add: models.model.run_layers.)"""
+    token goes through, is the caller's to add: models.model.run_layers.)
+
+    With ``cfg.moe_latent_size`` the routed experts work in a latent:
+    ``c = x W_dn`` (p["latent"]["w_dn"] [L, D, latent]) before the pairs are
+    grouped, the experts two matrices each on it (``w_up`` [L, E, latent,
+    F], ``w_down`` [L, E, F, latent], not gated), and the weighted sum goes
+    back through ``W_up`` ([L, latent, D]).  The router still reads ``x``."""
     from ..ops import moe_experts
 
     b, t, d = x.shape
@@ -425,14 +448,33 @@ def moe_dropless(
             jnp.sum(load > 0, dtype=jnp.int32), jnp.max(load),
         ]
         stats = jnp.stack(stats + ([jnp.sum(load)] if share else []))
+    latent = bool(cfg.moe_latent_size)
+    if latent:
+        with jax.named_scope("moe_latent"):
+            xf = _contract(x, _layer_leaf(p["latent"]["w_dn"], layer, x),
+                           "btd,dn->btn", 1, "n").reshape(b * t, -1)
     with jax.named_scope("moe_experts"):
         ex = p["experts"]
         y = moe_experts.grouped_swiglu(
-            xf, local, ex["w_gate_up"], ex["w_down"], layer,
-            of_experts=cfg.num_experts if share else None,
-            act=gate_fn(cfg.gate_act))
+            xf, local, ex["w_up" if latent else "w_gate_up"], ex["w_down"],
+            layer, of_experts=cfg.num_experts if share else None,
+            act=gate_fn(cfg.gate_act), gated=not latent)
         y = jnp.sum(y.astype(jnp.float32) * w[:, :, None], axis=1)
-    return y.reshape(b, t, d).astype(x.dtype), stats
+    y = y.reshape(b, t, -1).astype(x.dtype)
+    if latent:
+        with jax.named_scope("moe_latent"):
+            y = _contract(y, _layer_leaf(p["latent"]["w_up"], layer, x),
+                          "btn,nd->btd", 1, "k")
+    return y, stats
+
+
+def _layer_leaf(w: Any, layer, x: jax.Array) -> Any:
+    """Layer ``layer`` of a stacked matrix [L, K, N]: a quantized stack
+    whole with the index (``QuantizedTensor.at``: the kernel reads the
+    layer's tiles where they lie), a float one sliced."""
+    if _is_quantized(w):
+        return w.at(layer, None)
+    return w[layer].astype(x.dtype)
 
 
 @jax.named_scope("mlp")  # profiler scope; HLO metadata only

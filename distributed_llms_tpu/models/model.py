@@ -954,6 +954,119 @@ def retention_counts(cfg: ModelConfig, call: Call, shape: tuple) -> jax.Array:
                       zero, zero])
 
 
+def ssm_layer(
+    x: jax.Array,  # [B, T, D], normed
+    p: Params,  # in_proj, taps, conv_bias, dt_bias, A_log, D, norm_w, out_proj
+    cfg: ModelConfig,
+    call: Call,
+    cache: kv_cache.HybridCache | None,  # the slots' states and taps (a
+    #   decode step) or a fresh row's (an admission); None: nothing is kept
+    layer: jax.Array | int,  # index among the state-space layers
+) -> tuple[jax.Array, kv_cache.HybridCache | None]:
+    """Mamba-2 (layer kind "ssm"; ops/ssm.py has the scan's equations and
+    the state's layout).  ``[z | xBC | dt] = x W_in``; ``xBC <- silu(causal
+    depthwise conv_K(xBC) + b)``, split ``[x (heads x P) | B (groups x N) |
+    C (groups x N)]``; ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)``
+    in float32; the scan; ``y <- y + D x``; the gate FIRST, ``y silu(z)``,
+    then an RMS norm over each group's channels times ``norm_w``; ``y
+    W_out``.  A decode step ("decode", one token a row) is one recurrence
+    step against the slots' states, updated where they lie, and moves the
+    convolution's taps by one; a row that does not decode (``call.seq_lens``
+    0) keeps both.  Otherwise the T tokens are a row's start ("plain", or
+    "start": an admission) and run the chunked scan from an empty state,
+    ``cfg.ssm_chunk`` tokens a chunk, leaving in ``cache`` the state and the
+    taps at the ``call.seq_lens`` REAL tokens (None: all T).  A state keeps
+    no past, so there is nothing to continue from."""
+    from ..ops import ssm
+
+    if call.kind in ("continuation", "masked"):
+        raise ValueError(
+            "a state-space layer prefills a row from its start (cache_index "
+            "0, no mask and no map of the caller's): the state holds no "
+            "prefix to continue from"
+        )
+    decode = call.kind == "decode"
+    b, t, _ = x.shape
+    if decode and (t != 1 or cache is None):
+        raise ValueError(
+            "a state-space layer decodes one token a row against the "
+            "slots' states"
+        )
+    nh, hd, ng, ns = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                      cfg.ssm_state)
+    inner, width, k = cfg.ssm_inner, cfg.ssm_conv_width, cfg.ssm_conv_kernel
+    with jax.named_scope("ssm_proj"):
+        zxd = layers._contract(x, p["in_proj"], "btd,df->btf", 1, "n")
+        z, xbc = zxd[..., :inner], zxd[..., inner:inner + width]
+        dt = jax.nn.softplus(zxd[..., inner + width:].astype(jnp.float32)
+                             + p["dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(p["A_log"].astype(jnp.float32))
+    with jax.named_scope("ssm_conv"):
+        taps = p["taps"].astype(jnp.float32)  # [C, K]
+        state = (jnp.zeros((b, k - 1, width), xbc.dtype) if cache is None
+                 or not decode else cache.ssm_conv[layer].astype(xbc.dtype))
+        win = jnp.concatenate([state, xbc], axis=1)  # [B, T + K - 1, C]
+        xbc = jax.nn.silu(sum(
+            taps[:, j] * win[:, j: j + t].astype(jnp.float32)
+            for j in range(k)) + p["conv_bias"].astype(jnp.float32)
+        ).astype(x.dtype)
+        # the K - 1 inputs that end at the row's real tokens
+        new_taps = win[:, t:] if call.seq_lens is None else jax.vmap(
+            lambda w, n: jax.lax.dynamic_slice_in_dim(w, n, k - 1, axis=0)
+        )(win, call.seq_lens)
+    xs = xbc[..., :inner].reshape(b, t, nh, hd)
+    bm = xbc[..., inner:inner + ng * ns].reshape(b, t, ng, ns)
+    cm = xbc[..., inner + ng * ns:].reshape(b, t, ng, ns)
+    with jax.named_scope("ssm_scan"):
+        if decode:
+            live = None if call.seq_lens is None else call.seq_lens > 0
+            y, ssm_h = ssm.ssm_decode(
+                xs[:, 0], bm[:, 0], cm[:, 0], dt[:, 0], a, cache.ssm_h,
+                layer, live)
+            y = y[:, None]
+            cache = dataclasses.replace(
+                cache, ssm_h=ssm_h, ssm_conv=cache.ssm_conv.at[layer].set(
+                    new_taps.astype(cache.ssm_conv.dtype)))
+        else:
+            rows = [ssm.ssm_prefill(
+                xs[i], bm[i], cm[i], dt[i], a,
+                None if call.seq_lens is None else call.seq_lens[i],
+                cfg.ssm_chunk) for i in range(b)]
+            y = jnp.stack([r[0] for r in rows])
+            if cache is not None:
+                cache = dataclasses.replace(
+                    cache,
+                    ssm_h=cache.ssm_h.at[layer].set(
+                        jnp.stack([r[1] for r in rows])),
+                    ssm_conv=cache.ssm_conv.at[layer].set(
+                        new_taps.astype(cache.ssm_conv.dtype)))
+    with jax.named_scope("ssm_gate"):
+        y = (y.astype(jnp.float32)
+             + p["D"].astype(jnp.float32)[:, None] * xs.astype(jnp.float32))
+        y = y.reshape(b, t, inner) * jax.nn.silu(z.astype(jnp.float32))
+        y = y.reshape(b, t, ng, inner // ng)
+        y = y * jax.lax.rsqrt(
+            jnp.mean(jnp.square(y), axis=-1, keepdims=True) + cfg.norm_eps)
+        y = (y.reshape(b, t, inner)
+             * p["norm_w"].astype(jnp.float32)).astype(x.dtype)
+    return layers._contract(y, p["out_proj"], "btf,fd->btd", 1, "k"), cache
+
+
+def ssm_counts(cfg: ModelConfig, call: Call, shape: tuple) -> jax.Array:
+    """What a pass did in ONE state-space layer, int32 [3], a by-product
+    beside the expert layers' counts (:func:`run_layers`' third value): the
+    real tokens an admission scanned and the chunks it walked (those that
+    hold a real token), the rows a decode step advanced."""
+    b, t = shape
+    lens = (jnp.full((b,), t, jnp.int32) if call.seq_lens is None
+            else call.seq_lens.astype(jnp.int32))
+    zero = jnp.zeros((), jnp.int32)
+    if call.kind == "decode":
+        return jnp.stack([zero, zero, jnp.sum((lens > 0).astype(jnp.int32))])
+    return jnp.stack([jnp.sum(lens), jnp.sum(-(-lens // cfg.ssm_chunk)),
+                      zero])
+
+
 def gpt2_block(x, p, cfg, call, layer_cache, layer=None):
     """-> (x, new_cache, aux): aux is the MoE load-balance term (0 here).
     Shared by the gpt2 and opt families (pre-LN + learned positions);
@@ -1095,18 +1208,14 @@ def run_blocks(
 
 def layer_runs(cfg: ModelConfig) -> tuple:
     """The hybrid family's layers as runs: ((unit, repeats), ...), a unit a
-    tuple of layers (operator kind, FFN kind) that repeats ``repeats``
-    times in the published order.  LFM2-8B-A1B: a convolution layer with a
+    tuple of layers (operator kind, FFN kind; None: a block that is its
+    operator alone) that repeats ``repeats`` times in the published order.  LFM2-8B-A1B: a convolution layer with a
     dense FFN twice, (attention, conv, conv, conv) with experts four times,
     (attention, conv, conv) with experts twice.  A run that repeats is one
     ``lax.scan`` (:func:`run_layers`), so a program holds each unit's
     kernels once: 44 Pallas calls where 24 unrolled layers hold 116, and
     compiles in a third of the time (PERF.md, PR 28)."""
-    seq = [
-        (t, "dense" if l < cfg.num_dense_layers or not cfg.num_experts
-         else "moe")
-        for l, t in enumerate(cfg.layer_types)
-    ]
+    seq = list(zip(cfg.layer_types, cfg.ffn_kinds))  # (ffn None: no FFN)
     runs, i = [], 0
     while i < len(seq):
         best = (1, 1)  # (period, repeats) covering the most layers
@@ -1163,6 +1272,7 @@ def run_layers(
     moe = jnp.zeros((4 if cfg.experts_held is None else 5,), jnp.int32)
     if cfg.ret_layers:  # the retention layers' counts ride there instead
         moe = retention_counts(cfg, call, x.shape[:2])
+    shape = x.shape[:2]
     rows, token_mask, paged = (
         call.rows, call.token_mask, call.kv_tables is not None)
 
@@ -1188,6 +1298,8 @@ def run_layers(
                     at[op]].set(new.astype(cache.conv.dtype)))
         elif op == "ret":
             out, cache = retention_layer(h, p, cfg, call, cache, at[op])
+        elif op == "ssm":
+            out, cache = ssm_layer(h, p, cfg, call, cache, at[op])
         elif cfg.swa_layers:  # windowed and full attention layers mixed
             with jax.named_scope("swa_attn" if op == "swa" else "full_attn"):
                 out, cache = mixed_attention(
@@ -1211,7 +1323,8 @@ def run_layers(
                 layer_cache = cache
             else:
                 layer_cache = (cache.k[ai], cache.v[ai])
-            out, new = _attention(h, p, cfg, True, call, layer_cache, ai)
+            out, new = _attention(
+                h, p, cfg, cfg.attn_rope, call, layer_cache, ai)
             if paged:
                 cache = new
             elif cache is not None:
@@ -1219,6 +1332,8 @@ def run_layers(
                     cache, k=cache.k.at[ai].set(new[0]),
                     v=cache.v.at[ai].set(new[1]))
         x = x + out
+        if ffn is None:  # a block that is its operator alone
+            return x, cache, moe
         h = layers.rms_norm(x, p["ln2"]["scale"], cfg.norm_eps)
 
         def add_ffn(x, h, mask, rows, route=None):
@@ -1233,10 +1348,11 @@ def run_layers(
                 h, blocks["moe"], cfg, mask, layer=at[ffn], logits=route)
             x = x + y
             if cfg.n_shared_experts:
+                shared = layer_of(blocks["moe"]["shared"], at[ffn], rows)
                 with jax.named_scope("shared_expert"):
-                    x = x + layers.mlp_swiglu(
-                        h, layer_of(blocks["moe"]["shared"], at[ffn], rows),
-                        cfg.gate_act)
+                    x = x + (layers.mlp_plain(h, shared, cfg.gate_act)
+                             if cfg.moe_latent_size
+                             else layers.mlp_swiglu(h, shared, cfg.gate_act))
             return x, stats
 
         b, t, d = x.shape
@@ -1263,19 +1379,19 @@ def run_layers(
                 moe + jnp.sum(stats, axis=0))
 
     carry = (x, cache, moe)
-    base = dict(conv=0, attn=0, swa=0, mla=0, ret=0, dense=0, moe=0)
+    base = dict(conv=0, attn=0, swa=0, mla=0, ret=0, ssm=0, dense=0, moe=0)
     for unit, reps in layer_runs(cfg):
-        kinds = [kind for pair in unit for kind in pair]
+        kinds = [kind for pair in unit for kind in pair if kind]
         per_unit = {kind: kinds.count(kind) for kind in base}
 
         def run(carry, rep, unit=unit, per_unit=per_unit, base=dict(base)):
             seen = dict.fromkeys(base, 0)
             for op, ffn in unit:
                 at = {kind: base[kind] + rep * per_unit[kind] + seen[kind]
-                      for kind in (op, ffn)}
+                      for kind in (op, ffn) if kind}
                 carry = layer(carry, op, ffn, at)
-                seen[op] += 1
-                seen[ffn] += 1
+                for kind in at:
+                    seen[kind] += 1
             return carry, None
 
         if reps == 1:
@@ -1285,6 +1401,10 @@ def run_layers(
                 run, carry, jnp.arange(reps, dtype=jnp.int32))
         for kind in base:
             base[kind] += reps * per_unit[kind]
+    if cfg.ssm_layers:  # a state-space layer's counts ride behind the experts'
+        x, cache, moe = carry
+        carry = (x, cache, jnp.concatenate(
+            [moe, ssm_counts(cfg, call, shape)]))
     return carry
 
 
@@ -1293,7 +1413,7 @@ def hybrid_layers(params: Params, cfg: ModelConfig):
     order: dicts {"ln1", "ln2": {"scale"}, "conv" | "attn": {...}, "mlp":
     {...}} as models/reference/lfm2_moe.py reads them.  A generator, so a
     caller that dequantizes what it is handed holds one layer in float32."""
-    at = dict(conv=0, attn=0, swa=0, mla=0, ret=0, dense=0, moe=0)
+    at = dict(conv=0, attn=0, swa=0, mla=0, ret=0, ssm=0, dense=0, moe=0)
     blocks = params["blocks"]
 
     def take(kind):
@@ -1301,12 +1421,10 @@ def hybrid_layers(params: Params, cfg: ModelConfig):
         at[kind] += 1
         return out
 
-    for l, op in enumerate(cfg.layer_types):
+    for op, ffn in zip(cfg.layer_types, cfg.ffn_kinds):
         p = take(op)
-        ffn = "dense" if l < cfg.num_dense_layers or not cfg.num_experts \
-            else "moe"
         yield {"ln1": p.pop("ln1"), "ln2": p.pop("ln2"), op: p,
-               "mlp": take(ffn)}
+               "mlp": take(ffn) if ffn else None}
 
 
 # ---------------------------------------------------------------------------
@@ -1516,6 +1634,28 @@ def hybrid_fan_in(name: str, shape: tuple) -> int:
     return shape[1]
 
 
+# A state-space layer's leaves that are drawn by a rule of their own.
+SSM_LEAVES = ("A_log", "dt_bias", "D", "conv_bias")
+
+
+def ssm_leaf(key: jax.Array, leaf: str, shape: tuple, dtype: Any) -> jax.Array:
+    """A state-space layer's small leaves as the published initialiser draws
+    them: ``A`` uniform in [1, 16] a head (``A_log`` its log), ``dt`` log-
+    uniform in [0.001, 0.1] and ``dt_bias`` its inverse softplus, so that a
+    head forgets over tens to hundreds of tokens and a state kept at less
+    than float32 shows; ``D`` ones; the convolution's bias N(0, 0.1) (a
+    trained model's is learned; zeros would leave it unread)."""
+    if leaf == "D":
+        return jnp.ones(shape, dtype)
+    if leaf == "conv_bias":
+        return (0.1 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+    u = jax.random.uniform(key, shape, jnp.float32)
+    if leaf == "A_log":
+        return jnp.log(1.0 + 15.0 * u).astype(dtype)
+    dt = jnp.exp(u * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
 def _init_hybrid_blocks(rng: jax.Array, cfg: ModelConfig, dtype: Any) -> Params:
     """One stack a kind of layer (models.model.run_layers): ``conv``,
     ``attn`` and ``swa`` (each with its layers' two norms; the windowed
@@ -1530,8 +1670,7 @@ def _init_hybrid_blocks(rng: jax.Array, cfg: ModelConfig, dtype: Any) -> Params:
     H, KVH, HD = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     E = cfg.num_experts
     NC, NA = len(cfg.conv_layers), len(cfg.attn_layers)
-    ND = cfg.num_dense_layers if E else cfg.num_layers
-    NM = cfg.num_layers - ND
+    ND, NM = cfg.ffn_kinds.count("dense"), cfg.ffn_kinds.count("moe")
 
     def dense(name, shape, dt=dtype):
         full = f"blocks/{name}"
@@ -1597,19 +1736,47 @@ def _init_hybrid_blocks(rng: jax.Array, cfg: ModelConfig, dtype: Any) -> Params:
         if kind == "ret":  # the gate: a scalar a token a key/value head,
             # in the model's dtype (checkpoint.quantize leaves it float)
             blocks[kind]["wg"] = dense("ret/wg", (n, D, KVH))
+    if cfg.ssm_layers:
+        NS, W = len(cfg.ssm_layers), cfg.ssm_conv_width
+        NH, IN = cfg.ssm_heads, cfg.ssm_inner
+        blocks["ssm"] = {
+            **norms(NS),
+            # [z | x B C | dt], in that order
+            "in_proj": dense("ssm/in_proj", (NS, D, IN + W + NH)),
+            "taps": dense("ssm/taps", (NS, W, cfg.ssm_conv_kernel)),
+            "out_proj": dense("ssm/out_proj", (NS, IN, D)),
+            "norm_w": jnp.ones((NS, IN), dtype),
+            **{leaf: ssm_leaf(
+                jax.random.fold_in(rng, zlib.crc32(f"blocks/ssm/{leaf}".encode())),
+                leaf, (NS, W if leaf == "conv_bias" else NH),
+                dtype if leaf == "conv_bias" else jnp.float32)
+               for leaf in SSM_LEAVES},
+        }
     if NM:
         EH = cfg.held_experts  # (a chip's share; the router scores all E)
+        LAT = cfg.moe_latent_size
         blocks["moe"] = {
             "router": dense("moe/router", (NM, D, E), jnp.float32),
             "experts": {
                 "w_gate_up": dense("moe/experts/w_gate_up", (NM, EH, D, 2 * FE)),
                 "w_down": dense("moe/experts/w_down", (NM, EH, FE, D)),
+            } if not LAT else {  # two matrices an expert, on the latent
+                "w_up": dense("moe/experts/w_up", (NM, EH, LAT, FE)),
+                "w_down": dense("moe/experts/w_down", (NM, EH, FE, LAT)),
             },
         }
+        if LAT:
+            blocks["moe"]["latent"] = {
+                "w_dn": dense("moe/latent/w_dn", (NM, D, LAT)),
+                "w_up": dense("moe/latent/w_up", (NM, LAT, D)),
+            }
         if cfg.n_shared_experts:
-            FS = cfg.n_shared_experts * FE
+            FS = cfg.shared_size
             blocks["moe"]["shared"] = {
                 "w_gate": dense("moe/shared/w_gate", (NM, D, FS)),
+                "w_up": dense("moe/shared/w_up", (NM, D, FS)),
+                "w_down": dense("moe/shared/w_down", (NM, FS, D)),
+            } if not LAT else {
                 "w_up": dense("moe/shared/w_up", (NM, D, FS)),
                 "w_down": dense("moe/shared/w_down", (NM, FS, D)),
             }
@@ -1689,8 +1856,10 @@ def init_params_quantized(
             return qt.data, jnp.repeat(qt.scale, repeat, axis=-2)
 
         def gen():
-            if leaf in ("scale", "q_norm", "k_norm", "kv_norm"):
+            if leaf in ("scale", "q_norm", "k_norm", "kv_norm", "norm_w"):
                 return jnp.ones(sd.shape, sd.dtype)
+            if leaf in SSM_LEAVES and name.startswith("blocks/ssm/"):
+                return ssm_leaf(key, leaf, sd.shape, sd.dtype)
             if leaf == "expert_bias":  # drawn, so that it changes the choice
                 return 0.1 * jax.random.normal(key, sd.shape, sd.dtype)
             if leaf.startswith("b"):  # bias, bq/bk/bv/bo, b_in/b_out

@@ -9,6 +9,27 @@ from dataclasses import replace
 
 from ..core.config import ModelConfig
 
+def blocks_of_pattern(pattern: str) -> dict:
+    """A ``hybrid_override_pattern`` of single sub-layers (``M`` Mamba-2,
+    ``*`` attention, ``E`` experts; each ``x <- x + f(rms(x))``) folded into
+    (operator, FFN) blocks: an ``E`` joins the operator in front of it, and
+    an operator that no ``E`` follows is a block with no FFN.  -> the
+    ``num_layers`` / ``layer_types`` / ``no_ffn_layers`` of a ModelConfig."""
+    kinds, bare = [], []
+    for i, c in enumerate(pattern):
+        if c == "E":
+            if i == 0 or pattern[i - 1] == "E":
+                raise ValueError(
+                    f"pattern {pattern!r}: an expert layer with no operator "
+                    "in front of it folds into no block")
+            continue
+        if i + 1 == len(pattern) or pattern[i + 1] != "E":
+            bare.append(len(kinds))
+        kinds.append({"M": "ssm", "*": "attn"}[c])
+    return {"num_layers": len(kinds), "layer_types": tuple(kinds),
+            "no_ffn_layers": tuple(bare)}
+
+
 PRESETS: dict[str, ModelConfig] = {
     "gpt2-125m": ModelConfig(
         family="gpt2", vocab_size=50257, hidden_size=768, intermediate_size=3072,
@@ -197,7 +218,42 @@ PRESETS: dict[str, ModelConfig] = {
         norm_eps=1e-6, tie_embeddings=False, qk_norm=True,
         layer_types=("ret",) * 10,
     ),
+    # NVIDIA-Nemotron-3-Super-120B-A12B (model_type nemotron_h) as ONE
+    # CHIP'S SHARE of a four-chip pipeline stage: the first 22 of the 88
+    # published single sub-layers (`MEMEMEM*EMEMEMEM*EMEME`: 10 Mamba-2, 10
+    # LatentMoE, 2 GQA) folded into 12 (operator, FFN) blocks, two of them
+    # with no FFN (:func:`blocks_of_pattern`); every width; experts 0-127 of
+    # the 512 (the router keeps 512 outputs and 22 a token), a 32,768-row
+    # slice of the vocabulary; no rotation in the attention layers; the
+    # multi-token-prediction layer is not served:
+    # benchmark/configs/nemotron3-super-int8-ep4.json has the deployment.
+    "nemotron3-super-ep4": ModelConfig(
+        family="hybrid", vocab_size=32768, hidden_size=4096,
+        intermediate_size=5376, moe_intermediate_size=2688, num_heads=32,
+        num_kv_heads=2, head_dim=128, max_seq_len=262144, rope_theta=10000.0,
+        norm_eps=1e-5, tie_embeddings=False, attn_rope=False,
+        **blocks_of_pattern("MEMEMEM*EMEMEMEM*EMEME"),
+        gate_act="relu2", num_experts=512, num_experts_per_token=22,
+        moe_score_fn="sigmoid", moe_expert_bias=True, moe_norm_eps=1e-20,
+        moe_routed_scale=5.0, moe_capacity=False, n_shared_experts=1,
+        moe_shared_intermediate_size=5376, moe_latent_size=1024,
+        experts_held=128, experts_offset=0, ssm_heads=128, ssm_head_dim=64,
+        ssm_groups=8, ssm_state=128, ssm_conv_kernel=4, ssm_chunk=128,
+    ),
     # Tiny configs for unit tests / CPU fake-mesh integration tests.
+    "nemotron3-super-tiny": ModelConfig(
+        family="hybrid", vocab_size=256, hidden_size=256,
+        intermediate_size=128, moe_intermediate_size=96, num_heads=4,
+        num_kv_heads=2, head_dim=32, max_seq_len=1024, norm_eps=1e-5,
+        tie_embeddings=False, dtype="float32", attn_rope=False,
+        **blocks_of_pattern("MEM*EME"),
+        gate_act="relu2", num_experts=32, num_experts_per_token=6,
+        moe_score_fn="sigmoid", moe_expert_bias=True, moe_norm_eps=1e-20,
+        moe_routed_scale=5.0, moe_capacity=False, n_shared_experts=1,
+        moe_shared_intermediate_size=128, moe_latent_size=64,
+        experts_held=8, experts_offset=0, ssm_heads=16, ssm_head_dim=64,
+        ssm_groups=2, ssm_state=128, ssm_conv_kernel=4, ssm_chunk=128,
+    ),
     "brumby-tiny": ModelConfig(
         family="hybrid", vocab_size=256, hidden_size=64,
         intermediate_size=224, num_layers=2, num_heads=10, num_kv_heads=2,

@@ -90,7 +90,7 @@ def _tiles(k: int, n: int, qb: int) -> tuple[int, int] | None:
 
 
 def _kernel(te_ref, nt_ref, ly_ref, x_ref, q_ref, s_ref, o_ref, acc_ref, *,
-            qb, nk):
+            qb, nk, act=None):
     del te_ref, ly_ref  # read by the index maps only
     t, k = pl.program_id(0), pl.program_id(2)
 
@@ -110,12 +110,14 @@ def _kernel(te_ref, nt_ref, ly_ref, x_ref, q_ref, s_ref, o_ref, acc_ref, *,
 
         @pl.when(k == nk - 1)
         def _():
-            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+            acc = acc_ref[...]
+            o_ref[...] = (acc if act is None else act(acc)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("bm", "bk", "bn", "interpret", "act"))
 def _grouped_quant_matmul(x, q, s, tile_expert, num_tiles, layer, *, bm, bk,
-                          bn, interpret=False):
+                          bn, interpret=False, act=None):
     """x [Pp, K] (row tiles of ``bm``, each of one expert) @ layer
     ``layer`` [1] of the int8 stack q [L, E, K, N] with scales s
     [L, E, K/qb, N] -> [Pp, N].  The stack goes in whole, every layer's
@@ -125,7 +127,10 @@ def _grouped_quant_matmul(x, q, s, tile_expert, num_tiles, layer, *, bm, bk,
     are skipped: their index maps repeat the last real step's blocks, so
     nothing is fetched, computed or written for them.  ``num_tiles`` may
     be 0 (a chip's share of the experts, and no pair fell on it): every
-    step then names block 0, and nothing is computed."""
+    step then names block 0, and nothing is computed.  ``act``: an
+    elementwise function of the float32 sums, applied as a tile is written
+    (the activation of an expert of two matrices: no pass over the list
+    between the two calls, whose rows past the live tiles nobody wrote)."""
     pp, kd = x.shape
     n = q.shape[3]
     qb = kd // s.shape[2]
@@ -152,7 +157,7 @@ def _grouped_quant_matmul(x, q, s, tile_expert, num_tiles, layer, *, bm, bk,
         return t, j
 
     return pl.pallas_call(
-        functools.partial(_kernel, qb=qb, nk=grid[2]),
+        functools.partial(_kernel, qb=qb, nk=grid[2], act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
@@ -192,10 +197,12 @@ def _layer_of(w, layer, dtype):
 def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
                    layer: jax.Array | int = 0,
                    of_experts: int | None = None,
-                   act=jax.nn.silu) -> jax.Array:
+                   act=jax.nn.silu, gated: bool = True) -> jax.Array:
     """Every (token, choice) pair through its expert's gated MLP,
     ``(act(x W_gate) * (x W_up)) W_down``: ``act`` is the configuration's
-    (layers.gate_fn; silu for a SwiGLU).
+    (layers.gate_fn; silu for a SwiGLU).  ``gated`` False: an expert is two
+    matrices, ``act(x W_up) W_down``, and ``w_gate_up`` is ``W_up`` alone,
+    [L, E, D, F].
 
     xf [S, D]; topi [S, k] int32 expert ids; w_gate_up [L, E, D, 2F] and
     w_down [L, E, F, D], every layer's experts in one stack, arrays or
@@ -213,7 +220,7 @@ def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
     k = topi.shape[1]
     quant = _is_quantized(w_gate_up)
     _, e, _, f2 = (w_gate_up.data if quant else w_gate_up).shape
-    f = f2 // 2
+    f = f2 // 2 if gated else f2
     p = s * k
     eid = topi.reshape(p).astype(jnp.int32)  # pair (token, choice) -> expert
     token = jnp.arange(p, dtype=jnp.int32) // k
@@ -253,12 +260,15 @@ def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
         y = yp.at[dest].get(mode="fill", fill_value=0) if share else yp[dest]
         return y.reshape(s, k, d)
 
+    def hidden(h):  # the first projection's output -> the second's input
+        return act(h[:, :f]) * h[:, f:] if gated else act(h)
+
     if tiles is None:
         dispatch.record("moe_experts", "fallback", (p, e, d, f))
         h = jax.lax.ragged_dot(
             xp, _layer_of(w_gate_up, layer, xf.dtype), counts)
-        a = act(h[:, :f]) * h[:, f:]
-        yp = jax.lax.ragged_dot(a, _layer_of(w_down, layer, xf.dtype), counts)
+        yp = jax.lax.ragged_dot(
+            hidden(h), _layer_of(w_down, layer, xf.dtype), counts)
         return pairs(yp)
 
     dispatch.record("moe_experts", mode, (p, e, d, f))
@@ -270,9 +280,11 @@ def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
     kw = dict(bm=bm, interpret=mode == "interpret")
     where = (tile_expert, num_tiles, jnp.asarray(layer, jnp.int32).reshape(1))
     (bk1, bn1), (bk2, bn2) = tiles
+    # (an expert of two matrices takes its activation as the first call
+    # writes its tiles; a gate's product needs both halves of a row)
     h = _grouped_quant_matmul(xp, w_gate_up.data, w_gate_up.scale, *where,
-                              bk=bk1, bn=bn1, **kw)
-    a = act(h[:, :f]) * h[:, f:]
-    yp = _grouped_quant_matmul(a, w_down.data, w_down.scale, *where,
-                               bk=bk2, bn=bn2, **kw)
+                              bk=bk1, bn=bn1, act=None if gated else act,
+                              **kw)
+    yp = _grouped_quant_matmul(hidden(h) if gated else h, w_down.data,
+                               w_down.scale, *where, bk=bk2, bn=bn2, **kw)
     return pairs(yp)

@@ -227,3 +227,70 @@ def test_the_states_refusal_table_names_every_feature_with_a_reason():
         cfg, paged_pages=None, prefix_cache=False, kv_bits=False,
         host_pages=0, speculative=False, prefill_chunk=None,
         token_budget=None, mesh=False)
+
+
+# -- a state a slot BESIDE the pool: Mamba-2 beside attention (PR 55) --------
+
+def test_a_state_space_models_pool_holds_pages_and_a_state_a_slot():
+    """``slot_state`` sizes the leaves: [ssm layers, rows, R, N, 128] float32
+    whatever the activations' dtype, and the convolution's last K - 1 inputs
+    in that dtype; the pool counts the attention layers alone."""
+    from distributed_llms_tpu.ops import ssm
+
+    cfg = dataclasses.replace(get_preset("nemotron3-super-tiny"),
+                              dtype="bfloat16")
+    state = kv_cache.slot_state(cfg, 3, jnp.bfloat16)
+    assert set(state) == {"ssm_h", "ssm_conv"}
+    assert state["ssm_h"].shape == (3, 3, 8, 128, 128)
+    assert state["ssm_h"].dtype == jnp.float32
+    assert state["ssm_conv"].shape == (3, 3, 3, 1536)
+    assert state["ssm_conv"].dtype == jnp.bfloat16
+    pool = kv_cache.make_pool(cfg, PAGES, BLK, slots=3)
+    assert isinstance(pool, kv_cache.HybridCache)
+    assert pool.k.shape[:2] == pool.v.shape[:2] == (1, PAGES)
+    assert pool.conv is None and pool.ret_s is None
+    assert kv_cache.format_bytes(pool, cfg) == {
+        "ssm_state": 3.0 * 3 * (ssm.state_bytes(16, 64, 128) + 3 * 1536 * 2)}
+    assert kv_cache.pages_are_private(cfg)
+    assert {"ssm_h", "ssm_conv"} <= set(kv_cache._SLOT_FIELDS)
+
+
+def test_write_row_puts_a_rows_pages_in_the_pool_and_its_state_in_its_slot():
+    cfg = get_preset("nemotron3-super-tiny")
+    keys = iter(jax.random.split(jax.random.key(4), 16))
+    noise = lambda x: jax.random.normal(next(keys), x.shape).astype(x.dtype)
+    pool = jax.tree.map(noise, kv_cache.make_pool(cfg, 9, 8, slots=3))
+    row = jax.tree.map(noise, kv_cache.init_cache(cfg, 1, 16))
+    out = kv_cache.write_row(pool, jnp.asarray([4, 7], jnp.int32), row,
+                             jnp.int32(2))
+    for f in ("ssm_h", "ssm_conv"):
+        got = np.asarray(getattr(out, f))
+        np.testing.assert_array_equal(got[:, 2], np.asarray(
+            getattr(row, f))[:, 0])
+        np.testing.assert_array_equal(
+            got[:, :2], np.asarray(getattr(pool, f))[:, :2])
+    got = np.asarray(out.k).reshape(1, 9, 8, -1)
+    np.testing.assert_array_equal(
+        got[0, [4, 7]].reshape(16, -1), np.asarray(row.k)[0, 0].reshape(16, -1))
+    np.testing.assert_array_equal(
+        got[0, [0, 1, 2, 3, 5, 6, 8]],
+        np.asarray(pool.k).reshape(1, 9, 8, -1)[0, [0, 1, 2, 3, 5, 6, 8]])
+
+
+def test_the_state_beside_a_pool_refuses_all_but_the_pool():
+    cfg = get_preset("nemotron3-super-tiny")
+    for name, why in kv_cache._STATE_REFUSALS.items():
+        if name == "paged_pages":  # the pool IS what serves it
+            kv_cache.refuse_unpaged_state(cfg, paged_pages=40)
+            continue
+        with pytest.raises(ValueError) as e:
+            kv_cache.refuse_unpaged_state(cfg, paged_pages=40, **{name: True})
+        assert str(e.value).startswith(f"{name} is not supported")
+        assert "recurrent state beside their pages" in str(e.value)
+        assert why in str(e.value)
+    with pytest.raises(ValueError, match="pass paged_pages"):
+        kv_cache.refuse_unpaged_state(cfg, paged_pages=None)
+    kv_cache.refuse_unpaged_state(
+        cfg, paged_pages=40, prefix_cache=False, kv_bits=False,
+        host_pages=0, speculative=False, prefill_chunk=None,
+        token_budget=None, mesh=False)

@@ -301,6 +301,85 @@ def test_the_8192_admissions_flash_kernel_ends_with_the_real_tokens(
 
 
 @pytest.fixture(scope="module")
+def compiled_state_beside_pool():
+    """``decode_chunk`` and ``admit_row_paged`` at the 512 and the 8,192
+    bucket of nemotron3-super-ep4 at the cell's shapes (64 slots, 5,184
+    pages of the 2 attention layers, the 10 Mamba-2 layers' float32 states
+    beside them; some 50 s to lower and compile the three)."""
+    from distributed_llms_tpu.models.presets import get_preset
+    from tools import aot_decode
+
+    try:
+        aot_decode.v5e_devices()
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    shapes = dict(slots=64, max_len=16384, pages=5184, page_size=BLK)
+    cfg = get_preset("nemotron3-super-ep4")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DLT_QUANT_MATMUL", "kernel")
+        mp.setenv("DLT_RAGGED_DECODE", "kernel")
+        mp.setenv("DLT_MOE_EXPERTS", "kernel")
+        return {
+            "decode_chunk": aot_decode.analyse("decode_chunk", cfg, **shapes),
+            **{bucket: aot_decode.analyse(
+                "admit_row_paged", cfg, prompt_len=bucket, **shapes)
+               for bucket in (512, 8192)}}
+
+
+STATES = "[10,64,64,128,128]"  # every layer's and slot's, 2.68 GB
+
+
+def test_the_states_beside_the_pool_are_updated_where_they_lie(
+        compiled_state_beside_pool):
+    """A decode step: nothing but the kernel ``ssm_decode`` produces an
+    array shaped like the stack of states, a layer of it or a row of it
+    (the stack is aliased through the kernel whole), nothing but the step's
+    scatters one shaped like the pool [2,5184,64,2,128]; no expert stack
+    ([10,128,1024,2688], [10,128,2688,1024]) and no layer of another int8
+    weight is copied; weights (9.63 GB), states and taps (2.72) and pool
+    (0.68) are all the program is given, and 0.13 GB of temporaries."""
+    decode = compiled_state_beside_pool["decode_chunk"]
+    states = decode["state_shaped"]
+    assert states and {e[0] for e in states} == {"custom-call"}
+    assert all("ssm_decode" in e[1] and STATES in e[2] for e in states)
+    found = decode["pool_shaped"]
+    assert found and {e[0] for e in found} == {"scatter", "fusion:scatter"}
+    assert all("[2,5184,64,2,128]" in e[2] for e in found), found
+    assert decode["expert_shaped"] == []
+    assert decode["weight_shaped"] == []
+    assert 13.0 < decode["argument_gb"] < 13.1
+    assert decode["alias_gb"] * 1e9 >= 2_723_676_160 + 5184 * 131_072
+    assert decode["temp_gb"] < 0.2
+    assert {"ssm_decode", "moe_experts"} <= _kernels(decode["hlo"])
+
+
+@pytest.mark.parametrize("bucket,temp_gb", [(512, 0.5), (8192, 2.0)])
+def test_an_admission_writes_one_rows_state_into_its_slot(
+        compiled_state_beside_pool, bucket, temp_gb):
+    """The 512 and the 8,192 bucket: the stack of states takes one
+    dynamic-update-slice of the row's slot, no instruction holds a layer's
+    64 slots, and what is shaped like ONE row's state (4 MB a layer, 42 MB
+    the row cache's ten) is the scan's output and its way into the row
+    cache; the pool is written a page at a time; no expert stack is copied;
+    1.89 GB of temporaries at 8,192 beside 13.04 GB of arguments, inside
+    the chip's 15.75 GB with more than 0.5 GB to spare."""
+    admit = compiled_state_beside_pool[bucket]
+    whole = [e for e in admit["state_shaped"] if STATES in e[2]]
+    assert whole and {e[0] for e in whole} <= {
+        "dynamic-update-slice", "fusion:dynamic-update-slice"}
+    assert not [e for e in admit["state_shaped"]
+                if "[64,64,128,128]" in e[2]]
+    assert admit["expert_shaped"] == []
+    assert admit["weight_shaped"] == []
+    assert {e[0] for e in admit["pool_shaped"]} <= {
+        "dynamic-update-slice", "fusion:dynamic-update-slice"}
+    assert admit["temp_gb"] < temp_gb
+    assert admit["argument_gb"] + admit["temp_gb"] < 15.75 - 0.5
+    assert {"ssm_prefill", "moe_experts", "flash_attn"} <= _kernels(
+        admit["hlo"])
+
+
+@pytest.fixture(scope="module")
 def compiled_blocked_rings():
     """``decode_chunk`` and ``admit_row_paged`` at the 16,384 bucket of
     smallthinker-pp4 at its 12 layers and the cell's shapes (32 slots,

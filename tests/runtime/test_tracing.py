@@ -343,7 +343,7 @@ def _host_reads_outside_wait_device(b, monkeypatch):
 
 
 @pytest.mark.parametrize("kind", ["fresh", "prefix-hit", "experts",
-                                  "retention"])
+                                  "retention", "state-space"])
 def test_no_blocking_fetch_outside_a_wait_device_span(tiny, monkeypatch, kind):
     """On the paths the benchmark's cells run (admit_row_paged,
     admit_row_auto_paged behind cached pages, decode_chunk, and an expert
@@ -356,6 +356,12 @@ def test_no_blocking_fetch_outside_a_wait_device_span(tiny, monkeypatch, kind):
         b = ContinuousBatcher(cfg, model_lib.init_params(jax.random.key(0), cfg),
                               batch_slots=3, max_len=64, chunk_steps=4,
                               paged_pages=24, page_size=8)
+    elif kind == "state-space":  # (a state beside the pool: the paged
+        # programs, whose counts of the scan ride out behind the experts')
+        cfg = presets.get_preset("nemotron3-super-tiny")
+        b = ContinuousBatcher(cfg, model_lib.init_params(jax.random.key(0), cfg),
+                              batch_slots=3, max_len=64, chunk_steps=4,
+                              paged_pages=24, page_size=8)
     elif kind == "retention":  # (no pool: admit_row and the contiguous
         # decode_chunk, whose counts of the state's work ride out too)
         cfg = presets.get_preset("brumby-tiny")
@@ -364,6 +370,7 @@ def test_no_blocking_fetch_outside_a_wait_device_span(tiny, monkeypatch, kind):
     else:
         b = paged(tiny, prefix_cache=kind == "prefix-hit")
     ret0 = METRICS.get_counter("ret.decode.row_steps")
+    ssm0 = METRICS.get_counter("ssm.decode.row_steps")
     doc = list(range(40, 75))                 # two full pages and a bit
     if kind == "prefix-hit":
         b.submit(doc + [3], max_new_tokens=2)
@@ -382,6 +389,9 @@ def test_no_blocking_fetch_outside_a_wait_device_span(tiny, monkeypatch, kind):
         assert METRICS.get_counter("moe.layer_passes") > moe0
     if kind == "retention":  # 8 and 5 decode steps behind the admissions
         assert METRICS.get_counter("ret.decode.row_steps") == ret0 + 13
+    if kind == "state-space":
+        assert METRICS.get_counter("ssm.decode.row_steps") == ssm0 + 13
+        assert METRICS.get_counter("moe.layer_passes") > moe0
 
 
 def _spec_models():
@@ -691,6 +701,111 @@ def test_a_retention_models_counters_are_added_at_delivery(monkeypatch):
             assert "batcher.loop.wait_device" not in spans
     assert METRICS.snapshot()["gauges"]["batcher.ret_state_bytes"] == \
         2 * 2 * 2 * 66 * 128 * 128 * 4
+
+
+def test_a_state_space_models_scopes_in_program_order():
+    """``nemotron3-super-tiny``: a Mamba-2 layer's projection under
+    ``ssm_proj``, its convolution under ``ssm_conv``, the scan (or the
+    recurrence step) under ``ssm_scan``; an expert layer's latent
+    projections under ``moe_latent`` on either side of ``moe_experts``;
+    ``head`` last; in an admission's program and in a decode step's against
+    the pool."""
+    from distributed_llms_tpu.models import kv_cache, model as model_lib
+    from distributed_llms_tpu.models.presets import get_preset
+
+    cfg = get_preset("nemotron3-super-tiny")
+    params = jax.eval_shape(
+        lambda: model_lib.init_params(jax.random.key(0), cfg))
+    pool = jax.eval_shape(lambda: kv_cache.make_pool(cfg, 9, 8, slots=2))
+
+    def admission(params):
+        return model_lib.forward(params, cfg, np.zeros((1, 16), np.int32))[0]
+
+    def step(params, pool):
+        lens = np.asarray([5, 9], np.int32)
+        return model_lib.forward(
+            params, cfg, np.zeros((2, 1), np.int32), positions=lens[:, None],
+            cache=pool, cache_index=lens,
+            kv_tables=jax.numpy.asarray([[1, 2], [3, 4]], "int32"),
+            seq_lens=np.ones((2,), np.int32))[0]
+
+    want = ["ssm_proj", "ssm_conv", "ssm_scan", "moe_route", "moe_latent",
+            "moe_experts", "shared_expert", "head"]
+    for fn, args in ((admission, (params,)), (step, (params, pool))):
+        order = []
+        for eqn in _walk_eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+            stack = str(eqn.source_info.name_stack)
+            for scope in want:
+                if scope in stack and scope not in order:
+                    order.append(scope)
+        assert order == want
+        text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+        assert all(scope in text for scope in order)
+
+
+def test_a_state_space_models_counters_are_added_at_delivery(monkeypatch):
+    """``ssm.*`` leave each admission and each decode chunk as outputs
+    behind the experts' counts and are added on the host: a chunk's inside
+    ``batcher.loop.deliver``, an admission's behind its one fetch inside
+    ``batcher.admit.row``, whose span carries the ``chunks`` the scan
+    walks; the gauge is the slots' states and taps."""
+    from distributed_llms_tpu.models import model as model_lib
+    from distributed_llms_tpu.models.presets import get_preset
+
+    for name in ("ssm.admit.tokens", "ssm.admit.chunks",
+                 "ssm.decode.row_steps", "batcher.ssm_state_bytes"):
+        assert name in METRIC_DOCS
+    cfg = get_preset("nemotron3-super-tiny")
+    params = model_lib.init_params(jax.random.key(0), cfg)
+    b = batcher_mod.ContinuousBatcher(
+        cfg, params, batch_slots=2, max_len=256, chunk_steps=4, eos_id=-1,
+        paged_pages=40, page_size=16)
+    open_spans, seen, attrs = [], [], []
+    span = b._span
+
+    class watched:
+        def __init__(self, name, **kw):
+            self.name, self.inner = name, span(name, **kw)
+            if name == "batcher.admit.row":
+                attrs.append(kw)
+
+        def __enter__(self):
+            open_spans.append(self.name)
+            return self.inner.__enter__()
+
+        def __exit__(self, *exc):
+            open_spans.pop()
+            return self.inner.__exit__(*exc)
+
+    inc = METRICS.inc
+
+    def watching(name, n=1):
+        if name.startswith("ssm."):
+            seen.append((name, n, tuple(open_spans)))
+        return inc(name, n)
+
+    monkeypatch.setattr(b, "_span", watched)
+    monkeypatch.setattr(METRICS, "inc", watching)
+    names = ("ssm.admit.tokens", "ssm.admit.chunks", "ssm.decode.row_steps",
+             "moe.routed_pairs", "moe.held_pairs")
+    before = METRICS.snapshot()["counters"]
+    b.submit(list(range(1, 141)), max_new_tokens=6)  # 140 tokens: 2 chunks
+    b.run()
+    after = METRICS.snapshot()["counters"]
+    delta = {k: after.get(k, 0) - before.get(k, 0) for k in names}
+    assert delta["ssm.admit.tokens"] == 140
+    assert delta["ssm.admit.chunks"] == 2
+    assert delta["ssm.decode.row_steps"] == 5
+    assert delta["moe.routed_pairs"] == 3 * 6 * 145
+    assert 0 < delta["moe.held_pairs"] < delta["moe.routed_pairs"]
+    assert [a["chunks"] for a in attrs] == [2]
+    for name, n, spans in seen:
+        if n and name.startswith("ssm.decode"):
+            assert spans[-1] == "batcher.loop.deliver", (name, spans)
+        if n and name.startswith("ssm.admit"):
+            assert "batcher.loop.wait_device" not in spans
+    assert METRICS.snapshot()["gauges"]["batcher.ssm_state_bytes"] == \
+        2 * 3 * (16 * 64 * 128 * 4 + 3 * 1536 * 4)
 
 
 def test_the_rings_counters_and_gauges_of_a_windowed_model():

@@ -250,16 +250,25 @@ def state_shaped(hlo_text: str, cfg, slots: int) -> list:
     ([ret layers, slots, KVH, 65, 128, 128]).  The stack is the decode
     scans' carry: only the kernel that updates it where it lies
     (``custom-call``) and an admission's write of one row into its slot
-    may be on the list.  Empty for a model without retention layers."""
-    if not cfg.ret_layers:
-        return []
-    from distributed_llms_tpu.ops.retention import state_shapes
+    may be on the list.  The same for a model of Mamba-2 layers, whose
+    states ([ssm layers, slots, R, N, 128]) lie beside a pool.  Empty for a
+    model without either."""
+    if cfg.ssm_layers:
+        from distributed_llms_tpu.ops.ssm import state_shape
 
-    row = ",".join(str(n) for n in state_shapes(cfg.num_kv_heads)[0])
+        layers = len(cfg.ssm_layers)
+        row = ",".join(str(n) for n in state_shape(
+            cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state))
+    elif cfg.ret_layers:
+        from distributed_llms_tpu.ops.retention import state_shapes
+
+        layers = len(cfg.ret_layers)
+        row = ",".join(str(n) for n in state_shapes(cfg.num_kv_heads)[0])
+    else:
+        return []
     return shaped_like(hlo_text, [
         f"[{row}]", f"[1,{row}]", f"[{slots},{row}]",
-        f"[{len(cfg.ret_layers)},{slots},{row}]",
-        f"[{len(cfg.ret_layers)},1,{row}]"])
+        f"[{layers},{slots},{row}]", f"[{layers},1,{row}]"])
 
 
 _ARRAY = re.compile(r"\b(pred|[a-z]+(\d+)\w*)\[([\d,]*)\]")
@@ -311,13 +320,16 @@ def expert_shaped(hlo_text: str, cfg) -> list:
     if not cfg.num_experts or cfg.moe_capacity:
         return []
     d, f, e = cfg.hidden_size, cfg.expert_size, cfg.held_experts
+    up = 2 * f
+    if cfg.moe_latent_size:  # two matrices an expert, on the latent
+        d, up = cfg.moe_latent_size, f
     shapes = []
-    for one in (f"{d},{2 * f}", f"{f},{d}"):
+    for one in (f"{d},{up}", f"{f},{d}"):
         shapes += [f"[{e},{one}]",
-                   f"[{cfg.num_layers - cfg.num_dense_layers},{e},{one}]"]
+                   f"[{cfg.ffn_kinds.count('moe')},{e},{one}]"]
     # (one expert's [D, 2F] too; its [F, D] is left out: the dense FFN's
     # w_down, prefetched a quarter at a time, has that shape in LFM2)
-    return shaped_like(hlo_text, shapes + [f"[{d},{2 * f}]"])
+    return shaped_like(hlo_text, shapes + [f"[{d},{up}]"])
 
 
 _MOVES = {"copy", "copy-start", "copy-done", "slice", "slice-start",

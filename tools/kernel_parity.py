@@ -402,7 +402,8 @@ def mla_paged_parity(blk: int = 64, h: int = 64, latent: int = 512,
 
 
 def moe_parity(e: int = 32, d: int = 2048, f: int = 1792, k: int = 4,
-               of_experts: int | None = None, act: str = "silu") -> None:
+               of_experts: int | None = None, act: str = "silu",
+               gated: bool = True) -> None:
     """The expert kernel at lfm2-8b-a1b's widths (32 experts of 2048 x
     1792, int8 with blocks along the contracted axis), layer 1 of a
     2-layer stack: a decode step's 64 pairs (tiles of 16 rows, most
@@ -411,8 +412,12 @@ def moe_parity(e: int = 32, d: int = 2048, f: int = 1792, k: int = 4,
     at [7168 x 4096] and [2048 x 7168]); ids are drawn over twice the held
     ones, so half the pairs name an absent expert, leave the list and come
     back as zeros.  ``act``: the gate's activation (layers.gate_fn; relu:
-    SmallThinker's 64 experts of [2560 x 1536] and [768 x 2560])."""
+    SmallThinker's 64 experts of [2560 x 1536] and [768 x 2560]).
+    ``gated`` False: two matrices an expert, ``act(x U) V`` (Nemotron-H's
+    128 of 512 on a latent of 1,024: [1024 x 2688] and [2688 x 1024], 22 a
+    token, relu2)."""
     gate = gate_fn(act)
+    up = 2 * f if gated else f
     key = jax.random.PRNGKey(5)
     key13, key2 = jax.random.split(key)
 
@@ -429,7 +434,7 @@ def moe_parity(e: int = 32, d: int = 2048, f: int = 1792, k: int = 4,
             scale=scale.reshape(2, e, kd // 128, n), bits=8,
             orig_shape=(2, e, kd, n), block_axis=-2)
 
-    w13 = jax.jit(lambda: stack(key13, d, 2 * f))()
+    w13 = jax.jit(lambda: stack(key13, d, up))()
     w2 = jax.jit(lambda: stack(key2, f, d))()
     for s in (16, 512):
         kx, kt = jax.random.split(jax.random.fold_in(key, s))
@@ -437,7 +442,7 @@ def moe_parity(e: int = 32, d: int = 2048, f: int = 1792, k: int = 4,
         topi = jax.random.randint(
             kt, (s, k), 0, 2 * e if of_experts else e, jnp.int32)
         got = jax.jit(lambda x, t, a, b: moe_experts.grouped_swiglu(
-            x, t, a, b, 1, of_experts=of_experts, act=gate))(
+            x, t, a, b, 1, of_experts=of_experts, act=gate, gated=gated))(
             x, topi, w13, w2)
 
         def want_of(x, topi, w13, w2):
@@ -446,8 +451,9 @@ def moe_parity(e: int = 32, d: int = 2048, f: int = 1792, k: int = 4,
             d13 = dequantize(jax.tree.map(lambda a: a[1], w13), jnp.float32)
             d2 = dequantize(jax.tree.map(lambda a: a[1], w2), jnp.float32)
             g = jnp.einsum("sd,edf->sef", xf, d13)
-            y = jnp.einsum("sef,efd->sed",
-                           gate(g[..., :f]) * g[..., f:], d2)
+            y = jnp.einsum(
+                "sef,efd->sed",
+                gate(g[..., :f]) * g[..., f:] if gated else gate(g), d2)
             y = jnp.take_along_axis(
                 y, jnp.minimum(topi, e - 1)[:, :, None], axis=1)
             return jnp.where((topi < e)[:, :, None], y, 0.0)
@@ -455,7 +461,7 @@ def moe_parity(e: int = 32, d: int = 2048, f: int = 1792, k: int = 4,
         with jax.default_matmul_precision("highest"):
             want = jax.jit(want_of)(x, topi, w13, w2)
         held = f" of {of_experts}" if of_experts else ""
-        check(f"moe experts S{s} k{k} E{e}{held} [{d}x{2 * f}] [{f}x{d}] "
+        check(f"moe experts S{s} k{k} E{e}{held} [{d}x{up}] [{f}x{d}] "
               f"{act}", got, want, rtol=3e-2, atol=3e-2)
 
 
@@ -586,6 +592,54 @@ def retention_parity(t: int, real: int, h: int, kvh: int, chunk: int,
     if want is not None:
         check(f"{tag} decode step", got[1], want, rt, at)
         check(f"{tag} decode step, last slot", got[slots - 1], want, rt, at)
+    assert not np.asarray(states[0]).any(), "layer 0's states were written"
+    assert not np.asarray(states[1, 0]).any(), "a row that did not decode moved"
+
+
+def ssm_parity(t: int, real: int, h: int, g: int, slots: int,
+               dtype=jnp.float32) -> None:
+    """Mamba-2's two kernels (ops/ssm.py) against the plain ``jax.numpy``
+    forms: an admission of ``real`` tokens in a bucket of ``t`` (chunks of
+    128; the chunks of padding are not walked) against the chunked scan in
+    float32 and against the recurrence token by token; then one recurrence
+    step of every batch slot in layer 1 of a stack of two, the slots'
+    states where they lie, against the recurrence's next token.  ``h`` heads
+    of 64 x 128 in ``g`` groups; dt and A as the published initialiser draws
+    them, so that every chunk boundary behind a token weighs on it."""
+    from distributed_llms_tpu.ops import ssm as S
+
+    ks = jax.random.split(jax.random.key(55), 5)
+    x = jax.random.normal(ks[0], (t, h, 64), dtype)
+    bm = (0.3 * jax.random.normal(ks[1], (t, g, 128))).astype(dtype)
+    cm = (0.3 * jax.random.normal(ks[2], (t, g, 128))).astype(dtype)
+    dt = jnp.exp(jax.random.uniform(
+        ks[3], (t, h), minval=np.log(1e-3), maxval=np.log(0.1)))
+    a = -jax.random.uniform(ks[4], (h,), minval=1.0, maxval=16.0)
+    n = real - 1  # the admission; token n is the decode step's
+    loose = dtype != jnp.float32
+    f32 = lambda v: v.astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        y, s = jax.jit(S.ssm_prefill)(x, bm, cm, dt, a, jnp.int32(n))
+        want_y, want_s = jax.jit(S.recurrence)(
+            x[:real], bm[:real], cm[:real], dt[:real], a)
+        _, at_n = jax.jit(S.recurrence)(x[:n], bm[:n], cm[:n], dt[:n], a)
+    tag = f"ssm {jnp.dtype(dtype).name} {real}/{t} h{h}/{g}"
+    rt, at = (2e-2, 2e-2) if loose else (1e-4, 1e-4)
+    check(f"{tag} admission", f32(y[:n]), want_y[:n], rt, at)
+    check(f"{tag} state", S.from_layout(s, 64), at_n, 0, 1e-5)
+    # one step for every slot, the row above in slot 1 and in the last one
+    states = jnp.zeros((2, slots, *s.shape), jnp.float32)
+    for b in (1, slots - 1):
+        states = states.at[1, b].set(S.to_layout(at_n))
+    rows = lambda v: jnp.broadcast_to(v[n], (slots, *v.shape[1:]))
+    live = jnp.ones((slots,), bool).at[0].set(False)
+    step = jax.jit(S.ssm_decode, donate_argnums=(5,))
+    got, states = step(rows(x), rows(bm), rows(cm), rows(dt), a, states,
+                       jnp.int32(1), live)
+    check(f"{tag} decode step", got[1], want_y[n], 0, 1e-5)
+    check(f"{tag} decode step, last slot", got[slots - 1], want_y[n], 0, 1e-5)
+    check(f"{tag} state after the step", S.from_layout(states[1, 1], 64),
+          want_s, 0, 1e-5)
     assert not np.asarray(states[0]).any(), "layer 0's states were written"
     assert not np.asarray(states[1, 0]).any(), "a row that did not decode moved"
 
@@ -790,6 +844,20 @@ def main() -> int:
                  (2048, 1500, 40, 8, 256, 16, jnp.bfloat16)) if ON_TPU else
                 ((192, 150, 4, 2, 64, 3), (8, 5, 4, 2, 64, 3))):
         retention_parity(*leg)
+    # Mamba-2 (Nemotron-H: 128 heads of 64 x 128 in 8 groups): an admission
+    # of the 2,048 bucket with a mean prompt in float32 and in bfloat16, the
+    # smallest bucket (padded to one chunk of 128), and the 64 slots' step;
+    # its 128 of 512 non-gated experts on the latent, 22 a token.
+    for leg in (((2048, 1500, 128, 8, 64), (64, 33, 128, 8, 64),
+                 (2048, 1500, 128, 8, 64, jnp.bfloat16)) if ON_TPU else
+                ((300, 260, 16, 2, 3), (8, 5, 16, 2, 3))):
+        ssm_parity(*leg)
+    if ON_TPU:
+        moe_parity(e=128, d=1024, f=2688, k=22, of_experts=512, act="relu2",
+                   gated=False)
+    else:
+        moe_parity(e=8, d=256, f=384, k=3, of_experts=32, act="relu2",
+                   gated=False)
     # No leg may pass on another path than the one asked for: the dispatch
     # record (ops/dispatch.py) counts every trace by the path it took.
     took = {k[len("ops.dispatch."):]: int(v)
@@ -837,7 +905,10 @@ def main() -> int:
     # read where the cache has them, at qwen2-7b's and pythia's heads — 64
     # legs.  v16: power retention's chunked scan and recurrence step at
     # Brumby's heads, float32 and bfloat16, the smallest bucket — 67 legs.
-    print(f"kernel_parity: ALL PASS v16 ({mode}, backend={backend})")
+    # v17: Mamba-2's chunked scan and recurrence step at Nemotron-H's heads,
+    # float32 and bfloat16, the smallest bucket, and the expert kernel's
+    # non-gated leg holding 128 of 512 on the latent — 72 legs.
+    print(f"kernel_parity: ALL PASS v17 ({mode}, backend={backend})")
     return 0
 
 

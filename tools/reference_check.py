@@ -176,6 +176,41 @@ TOLS = {
     # The mechanism leg above tells the same control by a factor of 40.
     "as_served": {"max_abs_logit": 0.80, "mean_abs_logit": 0.0604},
   },
+  "nemotron3-super-int8-ep4": {
+    # float32 activations, the state float32, the scan's kernels contracting
+    # at full precision: the order of summation (a chunk's [128, 128] tile
+    # and the state between chunks against one step a token; pairs grouped
+    # by expert against a loop over the experts; the flash kernel's tiles)
+    # AND the router's choice: 22 of 512 sigmoid scores lie a thousandth
+    # apart, so a rounding in the seventh digit changes the 22nd pick of
+    # about one token a layer in a thousand, the more tokens the more often,
+    # and a changed pick moves that token's hidden state and, through the
+    # states, every later one's.  The chip gave 3.0e-5 / 5.0e-6 on probe 32,
+    # 3.4e-3 / 3.4e-4 on probe 1500 and 1.25e-2 / 1.74e-3 on the 6,000-byte
+    # one (the most); with the state held in bfloat16 between the programs
+    # the same leg gives 0.823 / 0.0386 at the most over the three probes
+    # (probe 32; 0.450 / 0.0157 on the 6,000-byte one, 0.0087 / 0.00104 on
+    # probe 1500, whose 8 steps' roundings mostly cancel); on probe 1500 one
+    # expert fewer a token gives 1.53 / 0.229, silu for relu^2 2.66 / 0.459,
+    # the latent left out 4.34 / 0.699, the norm before the gate 3.72 /
+    # 0.617, no convolution bias 2.67 / 0.465, int4 weights 3.66 / 0.618
+    # (against the as-served leg's tokens).
+    # Each limit lies four times over the sound readings' most and six
+    # times (the mean) to sixteen (the maximum) under the control's most.
+    "mechanism": {"max_abs_logit": 5e-2, "mean_abs_logit": 6e-3},
+    # bfloat16 activations through 22 sub-layers whose expert layers weigh
+    # their routed sum by 5: the chip gave 1.408 / 0.1896 at the most over
+    # the three probes (probe 1500; 1.297 / 0.1753 on probe 32, 1.293 /
+    # 0.1525 on the 6,000-byte one), and 1.401 / 0.1999 at the most with the
+    # state held in bfloat16 (probe 1500): ONE rounding of the state is one
+    # more bfloat16 rounding among dozens a token, so the control moves the
+    # mean by 5% and the maximum not at all, and only the mean's limit can
+    # lie between its two readings (outside by one limit, not by each).
+    # The mechanism leg above tells the same control by a factor of 22.  The
+    # wrong models of that leg lie outside the mean's too (0.229 the least:
+    # one expert of 22 fewer; the others 0.46-0.70).
+    "as_served": {"max_abs_logit": 1.9, "mean_abs_logit": 0.195},
+  },
 }
 # A dense model's continuation behind cached pages (prefix_cache_probes): the
 # hit against the same prompt served with the cache off, and each against the
@@ -217,6 +252,9 @@ def reference_cfg(cfg) -> dict:
         qk_nope_head_dim=cfg.qk_nope_head_dim,
         qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
         n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
+        ssm_heads=cfg.ssm_heads, ssm_head_dim=cfg.ssm_head_dim,
+        ssm_groups=cfg.ssm_groups, ssm_state=cfg.ssm_state,
+        conv_kernel=cfg.ssm_conv_kernel,
         rope_scaling=dict(
             factor=cfg.rope_scaling_factor,
             original_max_position_embeddings=cfg.rope_original_max_len,
@@ -269,7 +307,7 @@ def reference_logits(params, cfg, tokens, int4=False, **changed):
     from distributed_llms_tpu.checkpoint import quantize as quant_lib
     from distributed_llms_tpu.models import model as model_lib
     from distributed_llms_tpu.models.reference import (
-        axk1, brumby, exaone_moe, lfm2_moe, smallthinker)
+        axk1, brumby, exaone_moe, lfm2_moe, nemotron_h, smallthinker)
 
     def floats(tree):
         def one(x):
@@ -284,7 +322,7 @@ def reference_logits(params, cfg, tokens, int4=False, **changed):
 
     def layer(p):
         ex = (p["mlp"].pop("experts", None)
-              if cfg.experts_held is not None else None)
+              if cfg.experts_held is not None and p["mlp"] else None)
         p = floats(p)
         if ex is not None:
             p["mlp"]["experts"] = {
@@ -298,10 +336,14 @@ def reference_logits(params, cfg, tokens, int4=False, **changed):
     if cfg.ret_layers:
         return brumby.forward(
             lazy, ref_cfg, toks, 512 if len(toks) > 2048 else None)
-    if not (cfg.kv_lora_rank or cfg.swa_layers):
-        return lfm2_moe.forward(lazy, ref_cfg, toks)
     held = (None if cfg.experts_held is None
             else (cfg.experts_offset, cfg.experts_held))
+    if cfg.ssm_layers:
+        return nemotron_h.forward(
+            lazy, ref_cfg, toks, experts_held=held,
+            query_block=512 if len(toks) > 2048 else None)
+    if not (cfg.kv_lora_rank or cfg.swa_layers):
+        return lfm2_moe.forward(lazy, ref_cfg, toks)
     if cfg.kv_lora_rank:
         return axk1.forward(lazy, ref_cfg, toks, experts_held=held)
     query_block = 512 if len(toks) > 2048 else None
@@ -501,7 +543,13 @@ def _tie_to_batcher(row, served, theirs, their_logprobs, tol, apart) -> bool:
 
 def retention_probes(a, config) -> int:
     """A model of power-retention layers (Brumby), which is served WITHOUT
-    a pool: for each of the configuration's probes the ``mechanism`` leg
+    a pool; and a model of Mamba-2 layers beside attention layers
+    (Nemotron-H, ``cfg.ssm_layers``), whose rows hold a float32 state BESIDE
+    the page pool, through the same legs FROM its pool (the batcher's PAGED
+    programs: ``admit_row_paged``'s prefill and splice, ``_decode_steps``'
+    forward against the pool; a probe under one chunk of the scan, the
+    1,500-byte one and the longest): for each of the
+    configuration's probes the ``mechanism`` leg
     (float32 activations, the same programs, kernels and state) and the
     ``as_served`` leg against the reference's full forward over the same
     tokens, admission at the prompt's bucket (the chunked scan) and then 8
@@ -523,10 +571,20 @@ def retention_probes(a, config) -> int:
 
     serve, preset, TOL = dict(config["serve"]), config["preset"], TOLS[a.config]
     probe_bytes = tuple(serve["probe_bytes"])
-    if a.rehearsal:
+    paged = bool(get_preset(preset).ssm_layers)
+    if paged:  # under one chunk, the 1,500-byte one, the longest
+        probe_bytes = (probe_bytes[0], probe_bytes[3], probe_bytes[-1])
+    if a.rehearsal and paged:
+        preset, probe_bytes = "nemotron3-super-tiny", (5, 140, 300)
+        serve.update(slots=4, max_len=512, page_size=16, paged_pages=100)
+    elif a.rehearsal:
         preset, probe_bytes = "brumby-tiny", (5, 9, 33, 60, 140)
         serve.update(slots=4, max_len=256)
     cfg = get_preset(preset)
+    state_fields = ("ssm_h",) if paged else ("ret_s", "ret_z")
+    chunk = cfg.ssm_chunk if paged else cfg.ret_chunk
+    blk = serve["page_size"]
+    ppr = serve["max_len"] // blk
     tok = get_tokenizer(None)
     if cfg.vocab_size < tok.vocab_size:  # as dlt-serve widens a tiny preset
         cfg = dataclasses.replace(cfg, vocab_size=512)
@@ -541,20 +599,26 @@ def retention_probes(a, config) -> int:
     @functools.cache
     def programs(c):  # (one set a dtype: a leg and its control share it)
         @partial(jax.jit, donate_argnums=(1,))
-        def admit(params, cache, prompt, plen, slot):
+        def admit(params, cache, prompt, plen, slot, page_list=None):
             logits, row, _ = B._prefill_row(
-                model_lib.forward, params, c, cache.k.dtype, s_len, prompt,
-                plen)
+                model_lib.forward, params, c, kv_cache.row_dtype(cache),
+                s_len, prompt, plen)
+            if page_list is not None:  # the pool, and the state beside it
+                return (kv_cache.write_row(cache, page_list, row, slot),
+                        logits[0, 0])
             return B._splice_row(cache, slot, row), logits[0, 0]
 
         @partial(jax.jit, donate_argnums=(1,))
-        def step(params, cache, last_tok, real_lens, active):
+        def step(params, cache, last_tok, real_lens, active, tables=None):
             mask = (jnp.arange(s_len)[None, :] <= real_lens[:, None])
+            how = ({"attn_mask": mask[:, None, None, :]} if tables is None
+                   else {"kv_tables": tables})
             logits, cache = model_lib.forward(
-                params, c, last_tok[:, None], positions=real_lens[:, None],
+                params, c if tables is None else dataclasses.replace(
+                    c, ragged_decode=dispatch.attention_mode() != "fallback"),
+                last_tok[:, None], positions=real_lens[:, None],
                 cache=cache, cache_index=real_lens,
-                attn_mask=mask[:, None, None, :],
-                seq_lens=active.astype(jnp.int32))
+                seq_lens=active.astype(jnp.int32), **how)
             return logits[:, 0], cache
 
         @partial(jax.jit, donate_argnums=(0,))
@@ -563,7 +627,7 @@ def retention_probes(a, config) -> int:
             # allowed by default)
             return dataclasses.replace(cache, **{
                 f: jax.lax.reduce_precision(getattr(cache, f), 8, 7)
-                for f in ("ret_s", "ret_z")})
+                for f in state_fields})
 
         return admit, step, to_bf16
 
@@ -576,21 +640,43 @@ def retention_probes(a, config) -> int:
         plen, bucket = len(ids), bucket_length(len(ids))
         prompt = np.zeros((bucket,), np.int32)
         prompt[:plen] = ids
-        cache = kv_cache.init_cache(c, slots, s_len)
-        cache, first = admit(params, cache, jnp.asarray(prompt),
-                             jnp.int32(plen), jnp.int32(a.slot))
+        where = {}
+        if paged:
+            n_pages = -(-(plen + PROBE_TOKENS) // blk)
+            page_list = np.zeros((ppr,), np.int32)
+            page_list[:n_pages] = 1 + np.arange(n_pages)
+            # (float32 activations double an 8,192-bucket admission's
+            # temporaries, 3.8 GB beside 13 GB resident: that leg keeps 8
+            # slots' states, the probe's among them, and a pool of one
+            # row's pages, three times what the longest probe fills)
+            wide = dtype == "float32" and slots > 8
+            slots_here = 8 if wide else slots
+            tables = np.zeros((slots_here, ppr), np.int32)
+            tables[a.slot] = page_list
+            cache = kv_cache.make_pool(
+                c, ppr + 1 if wide else serve["paged_pages"], blk,
+                slots=slots_here)
+            cache, first = admit(
+                params, cache, jnp.asarray(prompt), jnp.int32(plen),
+                jnp.int32(a.slot), jnp.asarray(page_list))
+            where = {"tables": jnp.asarray(tables)}
+        else:
+            cache = kv_cache.init_cache(c, slots, s_len)
+            cache, first = admit(params, cache, jnp.asarray(prompt),
+                                 jnp.int32(plen), jnp.int32(a.slot))
         logits = [np.asarray(first, np.float32)]
         toks = [int(np.argmax(logits[0])) if force is None else force[0]]
-        active = np.zeros((slots,), bool)
+        rows = cache.ssm_h.shape[1] if paged else slots
+        active = np.zeros((rows,), bool)
         active[a.slot] = True
-        last = np.zeros((slots,), np.int32)
-        lens = np.zeros((slots,), np.int32)
+        last = np.zeros((rows,), np.int32)
+        lens = np.zeros((rows,), np.int32)
         for j in range(PROBE_TOKENS - 1):
             if state_bf16:
                 cache = to_bf16(cache)
             last[a.slot], lens[a.slot] = toks[-1], plen + j
             lg, cache = step(params, cache, jnp.asarray(last),
-                             jnp.asarray(lens), jnp.asarray(active))
+                             jnp.asarray(lens), jnp.asarray(active), **where)
             logits.append(np.asarray(lg[a.slot], np.float32))
             toks.append(int(np.argmax(logits[-1])) if force is None
                         else force[j + 1])
@@ -611,7 +697,7 @@ def retention_probes(a, config) -> int:
         plen = len(ids)
         row = {"bytes": n, "prompt_tokens": plen,
                "bucket": bucket_length(plen),
-               "chunks": -(-plen // cfg.ret_chunk)}
+               "chunks": -(-plen // chunk)}
         for leg, dtype in (("mechanism", "float32"), ("as_served", cfg.dtype)):
             precision = "highest" if leg == "mechanism" else "default"
             with jax.default_matmul_precision(precision):
@@ -649,6 +735,15 @@ def retention_probes(a, config) -> int:
                       "no_qk_norm": {"qk_norm": False},
                       "no_rope": {"rope": False},
                       "int4_weights": {"int4": True}}
+            if paged:
+                wrongs = {
+                    "one_expert_fewer": {"num_experts_per_token":
+                                         cfg.num_experts_per_token - 1},
+                    "silu_experts": {"expert_act": "silu"},
+                    "no_latent": {"latent": False},
+                    "norm_before_gate": {"gate_first": False},
+                    "no_conv_bias": {"conv_bias": False},
+                    "int4_weights": {"int4": True}}
             row["wrong"] = {
                 name: against(np.asarray(reference_logits(
                     params, cfg, ids + toks[:-1], **changed),
@@ -657,7 +752,9 @@ def retention_probes(a, config) -> int:
         # The batcher's own programs on the same probe, sent alone.
         batcher = B.ContinuousBatcher(
             cfg, params, tok, batch_slots=slots, max_len=s_len,
-            chunk_steps=serve["chunk_steps"])
+            chunk_steps=serve["chunk_steps"], **(dict(
+                paged_pages=serve["paged_pages"], page_size=blk)
+                if paged else {}))
         rid = batcher.submit(ids, max_new_tokens=PROBE_TOKENS)
         out = batcher.run()
         tied = _tie_to_batcher(
@@ -765,7 +862,9 @@ def main() -> int:
         config = json.load(f)
     if a.config in PREFIX_TOLS:  # a dense model: the prefix cache's probes
         return prefix_cache_probes(a, config)
-    if get_preset(config["preset"]).ret_layers:  # a state and no pool
+    served = get_preset(config["preset"])
+    if served.ret_layers or served.ssm_layers:  # a state a row: with no
+        # pool, or beside one
         return retention_probes(a, config)
     serve = dict(config["serve"])
     preset, probe_bytes = config["preset"], PROBE_BYTES
