@@ -339,7 +339,8 @@ def mlp_plain(x: jax.Array, p: Params, act: str) -> jax.Array:
 
 
 def route_experts(
-    logits: jax.Array, cfg: ModelConfig, bias: jax.Array | None = None
+    logits: jax.Array, cfg: ModelConfig, bias: jax.Array | None = None,
+    summed: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Router logits [S, E] float32 -> (weights [S, k] float32, expert ids
     [S, k]).  ``cfg.moe_score_fn``: "softmax" takes the top k logits and
@@ -350,7 +351,15 @@ def route_experts(
     (LFM2-MoE).  With ``moe_n_group`` > 1 the experts are that many
     consecutive runs, a group scores as its best member, and only the
     experts of the ``moe_topk_group`` best groups can be picked (A.X-K1,
-    DeepSeek-V3's rule without a correction bias)."""
+    DeepSeek-V3's rule without a correction bias).
+
+    ``summed``: the chosen scores as a sum over E of the one score a pick
+    names, not a gather of S x k scalars: the same bits, and a scalar
+    gathered costs 10 ns on the chip (16 against 2 us a layer at a decode
+    step's 64 x 22 picks, 0.47 against 0.05 ms at an admission block's
+    2,048 x 22: PERF.md section 6, PR 56).  The served path asks for it
+    (:func:`moe_dropless`); the capacity path, which is differentiated and
+    was not measured, does not."""
     k = cfg.num_experts_per_token
     if cfg.moe_score_fn == "softmax":
         topv, topi = jax.lax.top_k(logits, k)
@@ -366,7 +375,15 @@ def route_experts(
             axis=1)  # [S, groups]
         pick = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(s, e)
     _, topi = jax.lax.top_k(pick, k)
-    w = jnp.take_along_axis(scores, topi, axis=-1)
+    if summed:
+        # Behind a barrier: XLA otherwise folds this sum into the
+        # normaliser's sum over k below, another order, and the weights
+        # move by an ulp, which the goldens do not forgive.
+        w = jax.lax.optimization_barrier(jnp.sum(jnp.where(
+            topi[:, :, None] == jnp.arange(scores.shape[-1])[None, None, :],
+            scores[:, None, :], 0.0), axis=-1))
+    else:
+        w = jnp.take_along_axis(scores, topi, axis=-1)
     if cfg.moe_norm_topk:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + cfg.moe_norm_eps)
     return w * cfg.moe_routed_scale, topi
@@ -399,8 +416,9 @@ def moe_dropless(
     [L, E, D, 2F], experts/w_down [L, E, F, D].  The router's slice is
     cut out here; the expert stacks go to the kernel whole.
     ``token_mask`` [B, T] marks the real tokens (padding of an admission
-    bucket and rows that are not decoding are routed too, which is cheaper
-    than masking them, but are not counted).  Returns (y, stats): stats
+    bucket and rows that are not decoding are routed too, but get no row
+    of the grouped list, ops.moe_experts.grouped_swiglu, and zeros; they
+    are not counted).  Returns (y, stats): stats
     int32 [4] = routed pairs, layer passes (1 if any token is real),
     experts with at least one real token, the fullest expert's real tokens
     — the sources of ``moe.*`` counters (runtime/batcher.py).
@@ -433,7 +451,7 @@ def moe_dropless(
             logits = router_logits(xf, p["router"][layer])
         logits = logits.reshape(b * t, -1)
         bias = p["expert_bias"][layer] if "expert_bias" in p else None
-        w, topi = route_experts(logits, cfg, bias)
+        w, topi = route_experts(logits, cfg, bias, summed=True)
         real = (jnp.ones((b * t,), bool) if token_mask is None
                 else token_mask.reshape(b * t))
         share = cfg.experts_held is not None
@@ -458,7 +476,8 @@ def moe_dropless(
         y = moe_experts.grouped_swiglu(
             xf, local, ex["w_up" if latent else "w_gate_up"], ex["w_down"],
             layer, of_experts=cfg.num_experts if share else None,
-            act=gate_fn(cfg.gate_act), gated=not latent)
+            act=gate_fn(cfg.gate_act), gated=not latent,
+            token_mask=None if token_mask is None else real)
         y = jnp.sum(y.astype(jnp.float32) * w[:, :, None], axis=1)
     y = y.reshape(b, t, -1).astype(x.dtype)
     if latent:

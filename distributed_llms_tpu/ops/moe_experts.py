@@ -194,10 +194,45 @@ def _layer_of(w, layer, dtype):
     return dequantize(w, dtype)
 
 
+_RANK_BLOCK = 256  # pairs a block of the ranks' triangular product
+
+
+def _grouped_list(eid, live, e, bm, rows):
+    """Each ``live`` pair's row in the grouped layout: its expert's first
+    row (every group padded to whole tiles of bm) plus its rank among the
+    expert's live pairs.  The rank is the pairs before it in its block of
+    ``_RANK_BLOCK``, a product of the block's 0/1 columns with a strictly
+    lower triangle of ones on the MXU, plus the blocks before, a cumulative
+    sum over [P / block, E]: 0/1 operands and a float32 sum are exact below
+    2^24, and nothing costs by P x E x log P as a cumulative sum over
+    [P, E] does (PERF.md section 6, PR 56: 5.6 against 12.3 us a layer at a
+    decode step's 512 pairs, 0.03 against 0.26 ms at an admission block's
+    45,056).  A dead pair (an absent expert's, a padding token's) has no
+    column, no count and the row ``rows``, past the list.
+    -> (dest [P], counts [E], ends [E])."""
+    p = eid.shape[0]
+    blk = _RANK_BLOCK
+    key = jnp.pad(jnp.where(live, eid, e), (0, -p % blk), constant_values=e)
+    oh = jax.nn.one_hot(key, e, dtype=jnp.bfloat16).reshape(-1, blk, e)
+    lower = jnp.tril(jnp.ones((blk, blk), jnp.bfloat16), -1)
+    within = jnp.einsum("ij,bje->bie", lower, oh,
+                        preferred_element_type=jnp.float32)
+    sums = jnp.sum(oh, axis=1, dtype=jnp.float32)  # [P / block, E]
+    before = jnp.cumsum(sums, axis=0) - sums
+    counts = (before[-1] + sums[-1]).astype(jnp.int32)
+    padded = -(-counts // bm) * bm
+    ends = jnp.cumsum(padded)
+    first = (ends - padded).astype(jnp.float32) + before  # [P / block, E]
+    dest = jnp.sum((within + first[:, None, :]) * oh, axis=-1,
+                   dtype=jnp.float32).reshape(-1)[:p].astype(jnp.int32)
+    return jnp.where(live, dest, rows), counts, ends
+
+
 def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
                    layer: jax.Array | int = 0,
                    of_experts: int | None = None,
-                   act=jax.nn.silu, gated: bool = True) -> jax.Array:
+                   act=jax.nn.silu, gated: bool = True,
+                   token_mask: jax.Array | None = None) -> jax.Array:
     """Every (token, choice) pair through its expert's gated MLP,
     ``(act(x W_gate) * (x W_up)) W_down``: ``act`` is the configuration's
     (layers.gate_fn; silu for a SwiGLU).  ``gated`` False: an expert is two
@@ -215,7 +250,12 @@ def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
     [0, E) names an expert that is elsewhere.  Such a pair leaves the
     grouped list (it is given no row, and its output is zeros); the row
     tile is sized for the share of the pairs that an even routing sends
-    here, the list for all of them."""
+    here, the list for all of them.
+
+    ``token_mask`` [S] bool marks the real tokens (None: all): the padding
+    of an admission's block and a row that is not decoding get no row
+    either, and zeros.  A live pair's tile and bits do not depend on the
+    dead ones."""
     s, d = xf.shape
     k = topi.shape[1]
     quant = _is_quantized(w_gate_up)
@@ -238,27 +278,17 @@ def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
     share = of_experts is not None
     # (the fallback pads no group)
     bm = row_tile(p * e // of_experts if share else p, e) if tiles else 1
-
-    # Each pair's row in the grouped layout: its expert's first row (every
-    # group padded to whole tiles of bm) plus its rank among the expert's
-    # pairs.  One-hot sums, not a sort and gathers: a decode step has 64
-    # pairs, and every small operation costs its launch 22 layers a step.
-    oh = jax.nn.one_hot(eid, e, dtype=jnp.int32)  # [P, E]
-    counts = jnp.sum(oh, axis=0)
-    rank = jnp.sum((jnp.cumsum(oh, axis=0) - 1) * oh, axis=1)
-    padded = -(-counts // bm) * bm
-    ends = jnp.cumsum(padded)
-    dest = jnp.sum(oh * (ends - padded), axis=1) + rank  # [P]
     rows = (-(-p // bm) + e) * bm if tiles else p  # sum_e ceil(c_e/bm) tiles
-    if share:  # an id outside [0, E) has no one-hot column: no row
-        here = jnp.logical_and(eid >= 0, eid < e)
-        dest = jnp.where(here, dest, rows)
+
+    live = jnp.logical_and(eid >= 0, eid < e)
+    if token_mask is not None:
+        live = jnp.logical_and(live, jnp.repeat(token_mask, k))
+    dest, counts, ends = _grouped_list(eid, live, e, bm, rows)
     src = jnp.zeros((rows,), jnp.int32).at[dest].set(token, mode="drop")
     xp = xf[src]  # padding rows repeat token 0: computed, never read back
 
-    def pairs(yp):
-        y = yp.at[dest].get(mode="fill", fill_value=0) if share else yp[dest]
-        return y.reshape(s, k, d)
+    def pairs(yp):  # (a dead pair's row is past the list: zeros)
+        return yp.at[dest].get(mode="fill", fill_value=0).reshape(s, k, d)
 
     def hidden(h):  # the first projection's output -> the second's input
         return act(h[:, :f]) * h[:, f:] if gated else act(h)

@@ -1,0 +1,84 @@
+"""The counts a hybrid model's programs hand ``ContinuousBatcher._note_moe``
+beside their tokens, which reads them BY POSITION: the experts' four, a
+share's fifth, a decode chunk's one or two of the tokens its rows held, a
+state-space model's three last of all.  Every family's layout in both kinds
+of program, pinned before anyone moves a slot."""
+
+import dataclasses
+
+import jax
+import numpy as np
+import pytest
+
+from distributed_llms_tpu.core.observability import METRICS
+from distributed_llms_tpu.models import model as model_lib
+from distributed_llms_tpu.models.presets import get_preset
+from distributed_llms_tpu.runtime.batcher import ContinuousBatcher
+
+MOE = ["moe.routed_pairs", "moe.layer_passes", "moe.experts_touched",
+       "moe.max_load_tokens"]
+SSM = ["ssm.admit.tokens", "ssm.admit.chunks", "ssm.decode.row_steps"]
+# family -> (preset, experts held of its experts, an admission's counters in
+# the order of its array, a decode chunk's)
+FAMILIES = {
+    "whole": ("lfm2-tiny", None, MOE, MOE),
+    "share": ("lfm2-tiny", 4, MOE + ["moe.held_pairs"],
+              MOE + ["moe.held_pairs"]),
+    "latent-pages": (
+        "ax-k1-tiny", 4, MOE + ["moe.held_pairs"],
+        MOE + ["moe.held_pairs", "mla.decode.resident_tokens"]),
+    "pages-and-rings": (
+        "k-exaone-tiny", 4, MOE + ["moe.held_pairs"],
+        MOE + ["moe.held_pairs", "attn.decode.resident_tokens",
+               "swa.decode.window_tokens"]),
+    "state-space": (
+        "nemotron3-super-tiny", 8, MOE + ["moe.held_pairs"] + SSM,
+        MOE + ["moe.held_pairs"] + SSM),
+}
+
+
+def _batcher(family):
+    name, held, *_ = FAMILIES[family]
+    cfg = get_preset(name)
+    params = model_lib.init_params(jax.random.key(0), cfg)
+    if held is not None and cfg.experts_held is None:
+        blocks = dict(params["blocks"])
+        blocks["moe"] = dict(blocks["moe"], experts=jax.tree.map(
+            lambda a: a[:, :held], blocks["moe"]["experts"]))
+        params = dict(params, blocks=blocks)
+        cfg = dataclasses.replace(cfg, experts_held=held, experts_offset=0)
+    return ContinuousBatcher(cfg, params, batch_slots=3, max_len=64,
+                             chunk_steps=4, paged_pages=24, page_size=8)
+
+
+def _delta(before, names):
+    after = METRICS.snapshot()["counters"]
+    return {n: after.get(n, 0) - before.get(n, 0) for n in names}
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_every_familys_layout_in_an_admission_and_a_decode_chunk(family):
+    _, _, admission, chunk = FAMILIES[family]
+    b = _batcher(family)
+    seen = []
+    note = b._note_moe
+
+    def spy(stats=None):
+        if stats is not None:
+            seen.append(len(stats))
+        note(stats)
+
+    b._note_moe = spy
+    b.submit(list(range(40, 59)), max_new_tokens=6)
+    b.run()
+    assert set(seen) == {len(admission), len(chunk)}
+    # Each slot lands on its own counter: a number a slot, read back.
+    for names in (admission, chunk):
+        before = METRICS.snapshot()["counters"]
+        values = [1000 * (i + 1) for i in range(len(names))]
+        if "swa.decode.window_tokens" in names:
+            values[0] = 0  # (ring_tokens is reckoned from the routed pairs)
+        note(np.asarray(values, np.int32))
+        got = _delta(before, names)
+        assert [got[n] for n in names] == values
+
