@@ -10,6 +10,8 @@ tokens/s, p50/p95 hop latency, HBM occupancy, per-stage step time.
 
 from __future__ import annotations
 
+import bisect
+import itertools
 import json
 import logging
 import threading
@@ -49,6 +51,29 @@ def get_logger(name: str, json_format: bool = False, level: int = logging.INFO) 
     return logger
 
 
+# The one ladder of upper edges, in seconds, for the latency histograms a
+# scrape can take percentiles of over a WINDOW: twenty edges a decade from
+# 1 ms to 125.9 s, each a whole number of microseconds (the series' names
+# carry it), neighbours 1.122 apart.  A percentile interpolated inside a
+# bucket is good to a few percent where the values spread; the gaps of a
+# steady decode are a SPIKE one chunk long, which reads as the middle of
+# its bucket, so a bucket's half width (6%) is what a median can be off by:
+# at ten edges a decade (1.26 apart) that was 13%, too coarse to hold a
+# median against chunk_steps x the engine's step.
+LATENCY_EDGES_US: tuple[int, ...] = tuple(
+    round(1000 * 10 ** (k / 20)) for k in range(103))
+LATENCY_EDGES_S: tuple[float, ...] = tuple(e / 1e6 for e in LATENCY_EDGES_US)
+
+# The histograms that count on that ladder, by name, and only these: every
+# other one exports what it always did (some 40 histograms x 103 edges would
+# be 4,000 lines a scrape that nobody reads).
+BUCKETED: tuple[str, ...] = (
+    "batcher.row.gap_seconds",
+    "server.ttft_seconds",
+    "batcher.queue_wait_seconds",
+)
+
+
 @dataclass
 class _Histogram:
     values: list[float] = field(default_factory=list)
@@ -57,6 +82,17 @@ class _Histogram:
     # the percentile window above slides, these never reset.
     total_count: int = 0
     total_sum: float = 0.0
+    # A bucketed histogram's ladder of upper edges and the observations
+    # that fell in each bucket ((edges[i-1], edges[i]]; the last entry is
+    # what lay over the top edge), never reset either: a scrape exports the
+    # running sums, ``count of observations <= edge``, and the difference
+    # of two scrapes is the window's distribution.
+    edges: tuple[float, ...] | None = None
+    buckets: list[int] = field(default_factory=list)
+
+    def __post_init__(self) -> None:
+        if self.edges is not None:
+            self.buckets = [0] * (len(self.edges) + 1)
 
     def observe(self, v: float) -> None:
         if len(self.values) >= self.max_keep:
@@ -65,6 +101,12 @@ class _Histogram:
         self.values.append(v)
         self.total_count += 1
         self.total_sum += v
+        if self.edges is not None:
+            self.buckets[bisect.bisect_left(self.edges, v)] += 1
+
+    def cumulative(self) -> list[int]:
+        """Observations <= each edge, in the ladder's order."""
+        return list(itertools.accumulate(self.buckets[:-1]))
 
     def summary(self) -> dict[str, float]:
         if not self.values:
@@ -86,6 +128,16 @@ class _Histogram:
         }
 
 
+class _Histograms(dict):
+    """name -> histogram, made on first use: on the latency ladder where
+    ``BUCKETED`` names it, plain otherwise."""
+
+    def __missing__(self, name: str) -> _Histogram:
+        h = self[name] = _Histogram(
+            edges=LATENCY_EDGES_S if name in BUCKETED else None)
+        return h
+
+
 class Metrics:
     """Thread-safe in-process metrics registry."""
 
@@ -93,7 +145,7 @@ class Metrics:
         self._lock = threading.Lock()
         self._counters: dict[str, float] = defaultdict(float)  # guarded-by: self._lock
         self._gauges: dict[str, float] = {}  # guarded-by: self._lock
-        self._hists: dict[str, _Histogram] = defaultdict(_Histogram)  # guarded-by: self._lock
+        self._hists: dict[str, _Histogram] = _Histograms()  # guarded-by: self._lock
 
     def inc(self, name: str, value: float = 1.0) -> None:
         with self._lock:
@@ -113,6 +165,14 @@ class Metrics:
     def observe(self, name: str, value: float) -> None:
         with self._lock:
             self._hists[name].observe(value)
+
+    def observe_many(self, name: str, values) -> None:
+        """Observe a batch under one lock acquisition: what one delivery
+        call of the batcher saw, a value a delivering row."""
+        with self._lock:
+            h = self._hists[name]
+            for v in values:
+                h.observe(v)
 
     def get_counter(self, name: str) -> float:
         """Point read of one counter (0.0 when never incremented) — the
@@ -144,7 +204,12 @@ class Metrics:
     def prometheus_text(self) -> str:
         """Render the registry in Prometheus exposition format (text/plain
         version 0.0.4).  Histograms export as summaries: quantile series plus
-        cumulative _count/_sum.  The reference planned a Prometheus endpoint
+        cumulative _count/_sum.  A histogram of ``BUCKETED`` adds one
+        LABEL-FREE series an edge of its ladder, ``<name>_le_us_<edge in
+        whole microseconds> <observations <= edge>``: a scraper that keeps
+        only label-free lines (``benchmark/client.py``) can then difference
+        the distribution over a window, which the lifetime quantiles do not
+        allow.  The reference planned a Prometheus endpoint
         (implementation.md:34-37, :146-157) but never built one."""
 
         def name_of(raw: str) -> str:
@@ -171,6 +236,10 @@ class Metrics:
                         lines.append(f'{n}{{quantile="0.{q[1:]}"}} {s[q]}')
                 lines.append(f"{n}_count {h.total_count}")
                 lines.append(f"{n}_sum {h.total_sum}")
+                if h.edges is not None:
+                    lines.extend(
+                        f"{n}_le_us_{edge} {count}" for edge, count
+                        in zip(LATENCY_EDGES_US, h.cumulative()))
         return "\n".join(lines) + "\n"
 
 
@@ -331,7 +400,35 @@ METRIC_DOCS: dict[str, str] = {
                                   "request's selection or, launched "
                                   "behind an admission still on the "
                                   "chip, that one's fetch; one sample "
-                                  "per admission (histogram)",
+                                  "per admission (histogram, bucketed)",
+    "batcher.queue_wait_seconds.le_us.*": "of those waits, how many were no "
+                                          "longer than the edge the name "
+                                          "gives in microseconds (a counter "
+                                          "an edge of the latency ladder; "
+                                          "the difference of two scrapes is "
+                                          "the window's distribution)",
+    # -- what a resident request waits between two deliveries, stamped
+    #    where tokens are delivered (_collect), on the batcher's clock --
+    "batcher.row.gap_seconds": "the interval between two deliveries of "
+                               "tokens to one request: from its admission's "
+                               "first token, or its last chunk's tokens, to "
+                               "the next chunk's that brought it at least "
+                               "one, or to the token a re-admission after a "
+                               "preemption samples (the requeue wait lies "
+                               "inside that one gap); the interval a cancel "
+                               "or a deadline cuts is dropped; its sum over "
+                               "batcher.decode.committed_tokens is the gap "
+                               "a token (histogram, bucketed; its count is "
+                               "the deliveries less the first tokens)",
+    "batcher.row.gap_seconds.le_us.*": "of those gaps, how many were no "
+                                       "longer than the edge the name gives "
+                                       "in microseconds (a counter an edge "
+                                       "of the latency ladder)",
+    "batcher.row.gap_admit_seconds": "of batcher.row.gap_seconds' sum, the "
+                                     "seconds the engine thread was inside "
+                                     "batcher.loop.admit: what resident "
+                                     "requests waited through OTHER "
+                                     "requests' admission rounds (counter)",
     # -- the time no model program was in flight (from a blocking fetch
     #    that returned the newest one's output to the next dispatch call),
     #    charged to the batcher.loop.* span it fell in: a lower bound of
@@ -413,7 +510,12 @@ METRIC_DOCS: dict[str, str] = {
     "server.requests": "completion requests accepted past the shed gates",
     "server.disconnects": "requests whose client went away mid-serve",
     "server.request_seconds": "request latency, receipt to close (histogram)",
-    "server.ttft_seconds": "time to first token, from receipt (histogram)",
+    "server.ttft_seconds": "time to first token, from receipt (histogram, "
+                           "bucketed)",
+    "server.ttft_seconds.le_us.*": "of those first tokens, how many came no "
+                                   "later than the edge the name gives in "
+                                   "microseconds (a counter an edge of the "
+                                   "latency ladder)",
     "server.pre_submit_seconds": "receipt to batcher.submit: parsing, "
                                  "tokenizing, the shed gates (histogram)",
     "server.engine.idle_seconds": "the engine thread parked with no "

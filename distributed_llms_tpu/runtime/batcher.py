@@ -1293,6 +1293,27 @@ class _Timeline:
     pre_submit_s: float = 0.0  # gateway: receipt -> submit (its clock)
     prompt_tokens: int = 0  # as submitted (a resume's ids grow)
     cached_tokens: int = 0  # of the first admission
+    # The gap between deliveries (opened by ``_note_resident``, closed in
+    # ``_collect`` or by a re-admission's token): the latest delivery's
+    # stamps, and what the request's gaps came to.
+    t_delivered: float = 0.0         # the latest delivery of tokens
+    admit_at_delivered: float = 0.0  # the engine's admit clock then
+    deliveries: int = 0     # the first token + each later delivery
+    max_gap_s: float = 0.0  # the longest interval between two of them
+    stalled_s: float = 0.0  # of those intervals, inside admission rounds
+
+    def deliver(self, now: float, admit_now: float) -> tuple[float, float]:
+        """A delivery at ``now``, the admit clock reading ``admit_now``:
+        close the gap since the latest one and move the stamps.  Returns
+        the gap and the part of it inside admission rounds."""
+        gap = now - self.t_delivered
+        part = admit_now - self.admit_at_delivered
+        self.t_delivered, self.admit_at_delivered = now, admit_now
+        self.deliveries += 1
+        self.stalled_s += part
+        if gap > self.max_gap_s:
+            self.max_gap_s = gap
+        return gap, part
 
 
 @dataclass(eq=False)  # identity equality: deque.remove/queue scans then
@@ -2001,6 +2022,14 @@ class ContinuousBatcher:
         self._n_fetched = 0
         self._starved_at: float | None = None
         self._loop_at: str | None = None
+        # The admit clock: the seconds this thread has spent inside
+        # ``batcher.loop.admit``, advanced where a round's span exits
+        # (``_loop_exit``); ``_admit_t0`` is when the round it is in began
+        # (None outside one).  A delivery stamps its reading on the
+        # request's timeline, so the part of a gap that admission rounds
+        # filled is a difference.
+        self._admit_clock = 0.0
+        self._admit_t0: float | None = None
         # Submission lock: the ONE cross-thread boundary of this class.
         # Serving front-ends submit() from their own thread while the
         # engine thread scans/admits; PR 3 relied on GIL-atomic deque ops
@@ -2575,10 +2604,26 @@ class ContinuousBatcher:
         on_exit = None
         if name.startswith("batcher.loop."):
             self._loop_at = name
-            on_exit = self._charge_starved
+            on_exit = self._loop_exit
         # graftlint: ignore[GL302](forwarded: GL302 checks the self._span("...") call sites)
         return profiling.span(name, clock=self._clock, on_exit=on_exit,
                               **attrs)
+
+    def _loop_exit(self, now: float) -> None:
+        """A ``batcher.loop.*`` span closes at ``now``: it takes the starved
+        time that fell in it, and an admission round advances the admit
+        clock by its length."""
+        self._charge_starved(now)
+        if self._admit_t0 is not None:  # (the loop's spans do not nest)
+            self._admit_clock += now - self._admit_t0
+            self._admit_t0 = None
+
+    def _admit_now(self, now: float) -> float:
+        """The admit clock at ``now``: the rounds that ended, and of the
+        round the thread is in (an admission's first token is stamped
+        inside its own round) the part up to ``now``."""
+        t0 = self._admit_t0
+        return self._admit_clock + (now - t0 if t0 is not None else 0.0)
 
     def _launch(self, program, *operands, **kw):
         """Dispatch a model program (an admission program, a prefill bite,
@@ -2643,11 +2688,30 @@ class ContinuousBatcher:
         self._note_fetched(ticket)
         return host
 
-    def _note_resident(self, req: "_Request") -> None:
+    def _note_resident(self, req: "_Request",
+                       first_token: bool = False) -> None:
         """An admission ended (row resident, or finished by its admission
-        token): close the timeline's admission part."""
+        token): close the timeline's admission part.  Its ``first_token``
+        (a swap restore samples none) is a delivery.  A request's first
+        OPENS its first gap: it stamps and observes nothing.  A resumed
+        request has its stamps already (they live on the timeline), so the
+        token its re-admission samples CLOSES the gap across the
+        preemption, ONE gap with the requeue wait and the re-admission up
+        to here inside it, its admit part every round that ran meanwhile;
+        after a swap restore the resumed row's first chunk closes it."""
         tl = req.timeline
-        tl.admit_s += self._clock() - tl.t_admit
+        now = self._clock()
+        tl.admit_s += now - tl.t_admit
+        if not first_token:
+            return
+        admit_now = self._admit_now(now)
+        if tl.deliveries:
+            gap, part = tl.deliver(now, admit_now)
+            METRICS.observe("batcher.row.gap_seconds", gap)
+            METRICS.inc("batcher.row.gap_admit_seconds", part)
+        else:
+            tl.t_delivered, tl.admit_at_delivered = now, admit_now
+            tl.deliveries = 1
 
     def _note_finished(self, req: "_Request", out_tokens: int,
                        finish: str) -> None:
@@ -2656,7 +2720,13 @@ class ContinuousBatcher:
         ``cancelled`` (the gateway's timeout, stop string or disconnect),
         ``shed`` (queue deadline).  decode_ms is the rest of submit ->
         done once queue and admission are taken out: resident time,
-        neighbours' admissions included."""
+        neighbours' admissions included.  ``stalled_ms`` is that part of
+        it: of the intervals between the request's ``deliveries`` (its
+        first token and every chunk that brought it a token), the time the
+        engine thread was inside admission rounds (other requests', and
+        after a preemption the start of its own re-admission);
+        ``max_gap_ms`` is the longest of those intervals.  The interval
+        that a cancel or a deadline cuts is in none of the three."""
         tl = req.timeline
         total = self._clock() - tl.t_submit
         rec = {
@@ -2667,6 +2737,8 @@ class ContinuousBatcher:
             "queue_ms": tl.queue_s * 1e3, "admit_ms": tl.admit_s * 1e3,
             "decode_ms": max(0.0, total - tl.queue_s - tl.admit_s) * 1e3,
             "residencies": tl.residencies, "finish": finish,
+            "deliveries": tl.deliveries, "max_gap_ms": tl.max_gap_s * 1e3,
+            "stalled_ms": tl.stalled_s * 1e3,
         }
         with self._lock:
             self.finished.append(rec)
@@ -3355,6 +3427,7 @@ class ContinuousBatcher:
 
     def _admit_pending(self) -> None:
         with self._span("batcher.loop.admit"):
+            self._admit_t0 = self._clock()
             if self.faults is not None:
                 # Injection site "batcher.admit": one hit per admission round.
                 self.faults.fire("batcher.admit")
@@ -3765,7 +3838,7 @@ class ContinuousBatcher:
             self.rows[i].streamed = len(prior) + 1
             self._on_tokens(req.rid, [tok], False, [float(lp)])
         METRICS.inc("batcher.admitted")
-        self._note_resident(req)
+        self._note_resident(req, first_token=True)
 
     # -- chunked prefill ---------------------------------------------------
 
@@ -3960,12 +4033,26 @@ class ContinuousBatcher:
         # mirrors are stale while the carry is device-resident); the
         # synchronous path leaves it None and reads the freshly-synced
         # mirror, exactly as before.
+        #
+        # A row that this call brings at least one new token (streamed
+        # below, or final) has a DELIVERY: the interval since its last one
+        # is a sample of ``batcher.row.gap_seconds``, and the admit clock's
+        # advance over it is the part other requests' admission rounds
+        # filled.  One clock reading a call: the rows of a chunk are
+        # delivered together.  A ``done`` that brings no token observes
+        # nothing, and the last partial interval of a cancelled or
+        # deadline-cut row is dropped with the row (the closing cut of a
+        # benchmark's window is the benchmark's, not a user's wait).
+        now = self._clock()
+        admit_now = self._admit_now(now)
+        gaps: list[float] = []
+        gap_admit = 0.0
         committed = 0
         for i in range(self.b):
             row = self.rows[i]
             if row.rid is None or not was_active[i]:
                 continue
-            committed -= len(row.emitted)
+            had = len(row.emitted)
             # Speculative rounds emit a VARIABLE count per row; columns past
             # counts[i] are padding, not tokens (a legit pad-id token inside
             # the count still collects).  decode_chunk's fixed-step output
@@ -3981,9 +4068,17 @@ class ContinuousBatcher:
                 row.remaining -= 1
                 if t == self.eos_id:
                     break
-            committed += len(row.emitted)
+            new = len(row.emitted) - had
+            committed += new
+            if new and row.req is not None:
+                gap, part = row.req.timeline.deliver(now, admit_now)
+                gaps.append(gap)
+                gap_admit += part
         if committed:
             METRICS.inc("batcher.decode.committed_tokens", committed)
+        if gaps:
+            METRICS.observe_many("batcher.row.gap_seconds", gaps)
+            METRICS.inc("batcher.row.gap_admit_seconds", gap_admit)
         # Rows that finished this chunk publish their result and free up.
         # (Chunked prefills in flight are inactive but NOT finished.)
         if active_host is None:

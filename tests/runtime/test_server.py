@@ -10,10 +10,12 @@ a solo batcher run on an identical fresh batcher.
 
 import asyncio
 import json
+import re
 
 import jax
 import pytest
 
+from distributed_llms_tpu.core import observability
 from distributed_llms_tpu.models import model as model_lib, presets
 from distributed_llms_tpu.runtime.batcher import ContinuousBatcher
 from distributed_llms_tpu.runtime.server import InferenceServer
@@ -660,7 +662,8 @@ def test_debug_requests_lists_finished_requests(tiny):
     field there, `n` bounding the answer."""
     fields = {"rid", "tenant", "prompt_tokens", "cached_tokens",
               "out_tokens", "pre_submit_ms", "queue_ms", "admit_ms",
-              "decode_ms", "residencies", "finish"}
+              "decode_ms", "residencies", "finish",
+              "deliveries", "max_gap_ms", "stalled_ms"}
 
     async def fn(host, port, srv):
         status, body = await _request(host, port, "GET", "/debug/requests")
@@ -680,6 +683,8 @@ def test_debug_requests_lists_finished_requests(tiny):
             assert rec["prompt_tokens"] == len(srv.batcher.tokenizer.encode(prompt))
             assert rec["out_tokens"] == n and rec["residencies"] == 1
             assert rec["pre_submit_ms"] > 0 and rec["decode_ms"] >= 0
+            assert rec["deliveries"] >= 2 and rec["max_gap_ms"] > 0
+            assert 0 <= rec["stalled_ms"] <= rec["decode_ms"]
         status, body = await _request(host, port, "GET", "/debug/requests?n=1")
         assert json.loads(body)["requests"] == recs[-1:]
         status, _ = await _request(host, port, "GET", "/debug/requests?n=x")
@@ -688,6 +693,19 @@ def test_debug_requests_lists_finished_requests(tiny):
         status, body = await _request(host, port, "GET", "/metrics")
         assert b"server_pre_submit_seconds_count" in body
         assert b"batcher_queue_wait_seconds_count" in body
+        # ... and first tokens, queue waits and the gap between deliveries
+        # are counted an edge of the latency ladder, label-free.
+        scraped = dict(line.split(" ") for line in body.decode().splitlines()
+                       if re.fullmatch(r"[A-Za-z_:][\w:]* \S+", line))
+        top = observability.LATENCY_EDGES_US[-1]
+        assert float(scraped[f"server_ttft_seconds_le_us_{top}"]) == \
+            float(scraped["server_ttft_seconds_count"]) >= 2
+        assert float(scraped[f"batcher_queue_wait_seconds_le_us_{top}"]) >= 2
+        assert float(scraped[f"batcher_row_gap_seconds_le_us_{top}"]) == \
+            float(scraped["batcher_row_gap_seconds_count"]) >= 2
+        assert not [k for k in scraped if "_le_us_" in k and not k.startswith(
+            ("server_ttft_seconds_", "batcher_queue_wait_seconds_",
+             "batcher_row_gap_seconds_"))]
         assert b"server_engine_idle_seconds_count" in body
 
     run_with_server(make_batcher(tiny), fn)

@@ -511,7 +511,12 @@ def test_finished_ring_has_every_field_and_is_bounded(tiny):
         "queue_ms": recs[1]["queue_ms"], "admit_ms": recs[1]["admit_ms"],
         "decode_ms": recs[1]["decode_ms"], "residencies": 1,
         "finish": "length",
+        # 6 tokens at chunk_steps 4: the first token and two chunks.
+        "deliveries": 3, "max_gap_ms": recs[1]["max_gap_ms"],
+        "stalled_ms": recs[1]["stalled_ms"],
     }
+    assert 0 <= recs[1]["stalled_ms"] <= recs[1]["decode_ms"]
+    assert recs[1]["max_gap_ms"] > 0 and recs[0]["deliveries"] == 0
     assert min(recs[1][k] for k in ("queue_ms", "admit_ms", "decode_ms")) >= 0
     assert b.finished_requests(1) == recs[-1:] and b.finished_requests(0) == []
     for i in range(FINISHED_KEEP + 5):         # the ring forgets the oldest
@@ -903,3 +908,282 @@ def test_counters_say_how_many_pairs_the_flash_kernel_scores(
         want = (cfg.num_layers * 3 * 1024 ** 2,) * 2
     assert got == want
     assert [a["attn_pairs_live"] for a in spans] == [want[1], 0][:len(spans)]
+
+
+# -- the gap between a row's deliveries (batcher.row.gap_seconds) ------------
+
+GAP = ("batcher.row.gap_admit_seconds", "batcher.decode.committed_tokens")
+
+
+def gap_reads():
+    return (METRICS.get_histogram("batcher.row.gap_seconds"),
+            {n: METRICS.get_counter(n) for n in GAP})
+
+
+def gaps_since(before):
+    (n0, s0), c0 = before
+    (n1, s1), c1 = gap_reads()
+    return n1 - n0, s1 - s0, {k: c1[k] - c0[k] for k in GAP}
+
+
+class Shadow:
+    """The test's own record of a run, on the fake clock: when each rid was
+    handed tokens (``on_tokens``) and when the engine thread was inside
+    ``batcher.loop.admit``.  From them, without the batcher's stamps, what
+    the gaps must come to."""
+
+    def __init__(self, b, now, monkeypatch, then=None):
+        self.now, self.then = now, then
+        self.delivered: dict[int, list[tuple[float, int]]] = {}
+        self.rounds: list[list[float]] = []
+        self.resumed: dict[int, list[float]] = {}
+        span = b._span
+        shadow = self
+
+        class watched:
+            def __init__(self, name, **attrs):
+                self.inner = span(name, **attrs)
+                self.admit = name == "batcher.loop.admit"
+
+            def __enter__(self):
+                if self.admit:
+                    shadow.rounds.append([now[0], None])
+                return self.inner.__enter__()
+
+            def __exit__(self, *exc):
+                if self.admit:
+                    shadow.rounds[-1][1] = now[0]
+                return self.inner.__exit__(*exc)
+
+        monkeypatch.setattr(b, "_span", watched)
+
+    def on_tokens(self, rid, toks, done, lps):
+        if toks:
+            self.delivered.setdefault(rid, []).append((self.now[0], len(toks)))
+            if self.rounds and self.rounds[-1][1] is None \
+                    and len(self.delivered[rid]) > 1:
+                # Inside a round, and not the request's first: the
+                # admission token of a resume after a preemption.
+                self.resumed.setdefault(rid, []).append(self.now[0])
+        if self.then is not None:
+            self.then(rid, toks, done)
+
+    def in_rounds(self, t0, t1):
+        return sum(max(0.0, min(t1, b) - max(t0, a)) for a, b in self.rounds)
+
+    def gaps(self, rid):
+        """[(gap, its part inside admission rounds)] of ``rid``: between
+        every two times it was handed tokens."""
+        at = [t for t, _ in self.delivered[rid]]
+        return [(b - a, self.in_rounds(a, b)) for a, b in zip(at, at[1:])]
+
+    def chunk_tokens(self):
+        """Tokens the chunks brought: all but each request's first and
+        the token of each re-admission."""
+        return sum(k for d in self.delivered.values() for _, k in d) \
+            - len(self.delivered) - sum(map(len, self.resumed.values()))
+
+
+@pytest.mark.parametrize("overlap", [True, False])
+def test_a_gap_is_the_interval_between_two_deliveries(tiny, monkeypatch,
+                                                      overlap):
+    """Two rows and one admission round between two chunks, scripted: A
+    decodes alone, B is submitted from A's first chunk and admitted before
+    A's second.  Every delivery but a request's first token closes a gap;
+    the gaps add up to first token -> last delivery; a gap's admit part is
+    the admission rounds that fell in it: B's whole round for the resident
+    A, nothing for a chunk with no round before it."""
+    b, now, calls = ticking_batcher(tiny, monkeypatch, COST, batch_slots=2,
+                                    overlap=overlap)
+    later = []
+
+    def then(rid, toks, done):
+        if not later and len(shadow.delivered.get(rid, [])) == 2:
+            later.append(b.submit([4, 4, 4, 4], max_new_tokens=9))
+
+    shadow = Shadow(b, now, monkeypatch, then)
+    a = b.submit([7, 1, 9], max_new_tokens=13)
+    before = gap_reads()
+    b.run(on_tokens=shadow.on_tokens)
+    n, total, c = gaps_since(before)
+    rid_b, = later
+    want = {rid: shadow.gaps(rid) for rid in (a, rid_b)}
+    # 13 tokens: the first and three chunks of 4; 9: the first and two.
+    assert [len(want[a]), len(want[rid_b])] == [3, 2]
+    deliveries = sum(len(d) for d in shadow.delivered.values())
+    assert n == deliveries - calls["_activate_row"] == 5
+    assert total == pytest.approx(sum(g for w in want.values() for g, _ in w))
+    assert total == pytest.approx(sum(
+        d[-1][0] - d[0][0] for d in shadow.delivered.values()))
+    assert c["batcher.row.gap_admit_seconds"] == pytest.approx(
+        sum(p for w in want.values() for _, p in w))
+    assert c["batcher.decode.committed_tokens"] == 13 + 9 - 2 \
+        == shadow.chunk_tokens()
+    recs = {r["rid"]: r for r in b.finished_requests()}
+    for rid, w in want.items():
+        r = recs[rid]
+        assert r["deliveries"] == len(w) + 1
+        assert r["max_gap_ms"] == pytest.approx(1e3 * max(g for g, _ in w))
+        assert r["stalled_ms"] == pytest.approx(1e3 * sum(p for _, p in w))
+        assert r["stalled_ms"] <= r["decode_ms"] + 1e-6
+    # B's round: its pages, its program, its fetch and its activation.
+    b_round = 2.0 + 64.0 + 16.0 + 4.0
+    # It fell in ONE of A's gaps: the second, or with a chunk dispatched
+    # ahead of the delivery that submitted B, the third.
+    parts_a = [p for _, p in want[a]]
+    assert parts_a == pytest.approx(
+        [0.0, 0.0, b_round] if overlap else [0.0, b_round, 0.0])
+    assert recs[a]["stalled_ms"] == pytest.approx(1e3 * b_round)
+    # B's first token is stamped as its round ends and opens its first
+    # gap: it waited through no round.
+    assert recs[rid_b]["stalled_ms"] == 0.0
+    if not overlap:
+        # plan, the chunk's dispatch and fetch, the delivery: a chunk.
+        chunk = 0.5 + 32.0 + 16.0 + 1.0
+        assert [g for g, _ in want[a]] == pytest.approx(
+            [chunk, chunk + b_round, chunk])
+
+
+def test_a_first_token_and_a_bare_done_observe_no_gap(tiny):
+    """The admission's first token opens a gap and observes nothing; a row
+    finished by that token is published by a ``done`` that brings none."""
+    now = [10.0]
+    b = paged(tiny, clock=lambda: now[0])
+    one = b.submit([7, 1, 9], max_new_tokens=1)
+    before = gap_reads()
+    got = []
+    b.run(on_tokens=lambda rid, toks, done, lps: got.append((toks, done)))
+    assert [len(t) for t, _ in got] == [1, 0] and got[-1][1]
+    n, total, c = gaps_since(before)
+    assert (n, total) == (0, 0.0) and not any(c.values())
+    rec, = [r for r in b.finished_requests() if r["rid"] == one]
+    assert (rec["deliveries"], rec["max_gap_ms"], rec["stalled_ms"]) == \
+        (1, 0.0, 0.0)
+
+
+@pytest.mark.parametrize("resume", ["recompute", "swap"])
+def test_a_preempted_rows_gap_spans_its_requeue(tiny, monkeypatch, resume):
+    """The stamps live on the timeline the resume request shares: the gap
+    across a preemption is ONE gap, the requeue wait inside it, from the
+    row's last chunk before it to the next time the caller is handed a
+    token.  A recompute's re-admission samples one and streams it, so that
+    token closes the gap, inside its own round; a swap restore samples
+    none, and the resumed row's first chunk does.  Either way a request's
+    gaps add up to first token -> last delivery."""
+    cost = {"decode_chunk": 32.0, "device_get": 16.0, "_collect": 1.0,
+            "admit_row_paged": 64.0}
+    kw = {"host_pages": 16} if resume == "swap" else {}
+    b, now, calls = ticking_batcher(tiny, monkeypatch, cost, paged_pages=9,
+                                    **kw)
+    shadow = Shadow(b, now, monkeypatch)
+    reqs = [([7, 1, 9, 2], 44), ([4, 4, 4, 4], 44), ([9, 8, 7, 3], 44)]
+    rids = [b.submit(ids, max_new_tokens=n) for ids, n in reqs]
+    before = gap_reads()
+    swaps0 = METRICS.get_counter("batcher.kv_swaps.in")
+    b.run(on_tokens=shadow.on_tokens)
+    n, total, c = gaps_since(before)
+    assert b.preemptions >= 1
+    swapped = METRICS.get_counter("batcher.kv_swaps.in") - swaps0
+    assert (swapped >= 1) == (resume == "swap")
+    recs = {r["rid"]: r for r in b.finished_requests()}
+    assert sorted(recs) == rids
+    assert n == sum(r["deliveries"] for r in recs.values()) - len(reqs)
+    assert total == pytest.approx(sum(
+        d[-1][0] - d[0][0] for d in shadow.delivered.values()))
+    assert c["batcher.row.gap_admit_seconds"] == pytest.approx(sum(
+        p for rid in rids for _, p in shadow.gaps(rid)))
+    assert c["batcher.decode.committed_tokens"] == shadow.chunk_tokens()
+    back = [r for r in recs.values() if r["residencies"] > 1]
+    stayed = [r for r in recs.values() if r["residencies"] == 1]
+    assert back and stayed
+    for r in recs.values():
+        want = shadow.gaps(r["rid"])
+        assert r["deliveries"] == len(want) + 1
+        assert r["max_gap_ms"] == pytest.approx(1e3 * max(g for g, _ in want))
+        assert r["stalled_ms"] == pytest.approx(1e3 * sum(p for _, p in want))
+    for r in back:
+        assert r["max_gap_ms"] > max(s["max_gap_ms"] for s in stayed)
+        again = shadow.resumed.get(r["rid"], [])
+        if resume == "swap":
+            assert not again                  # no token but the chunks'
+            continue
+        # The re-admission's token CLOSES the longest gap, the requeue wait
+        # before it and its own admission up to the token inside; the next
+        # chunk's gap starts there and holds the rest of the round.
+        at = [t for t, _ in shadow.delivered[r["rid"]]]
+        assert len(again) == r["residencies"] - 1
+        k = at.index(again[0])
+        assert at[k] - at[k - 1] == pytest.approx(1e-3 * r["max_gap_ms"])
+        gap, part = shadow.gaps(r["rid"])[k - 1]
+        assert 64.0 <= part < gap             # its own program at the least
+        assert at[k + 1] - at[k] < gap
+
+
+def test_a_cancelled_rows_last_interval_is_dropped(tiny, monkeypatch):
+    cost = {"decode_chunk": 32.0, "device_get": 16.0, "_collect": 1.0}
+    b, now, calls = ticking_batcher(tiny, monkeypatch, cost)
+    seen = []
+
+    def on_tokens(rid, toks, done, lps):
+        seen.append(now[0])
+        if len(seen) == 3:                    # first token, two chunks
+            assert b.cancel_row(rid)
+
+    rid = b.submit([7, 1, 9], max_new_tokens=40)
+    before = gap_reads()
+    b.run(on_tokens=on_tokens)
+    n, total, c = gaps_since(before)
+    rec, = [r for r in b.finished_requests() if r["rid"] == rid]
+    assert rec["finish"] == "cancelled" and rec["deliveries"] == 3
+    assert n == 2
+    assert c["batcher.decode.committed_tokens"] == 8 == rec["out_tokens"] - 1
+    assert total == pytest.approx(seen[2] - seen[0])
+    assert now[0] > seen[2]                   # the chunk in flight: dropped
+
+
+@pytest.mark.parametrize("kind", [
+    "contiguous", "chunked-alternate", "chunked-mixed",
+    pytest.param("speculative", marks=pytest.mark.fragile_xla_cpu)])
+def test_every_schedule_delivers_through_the_same_stamps(tiny, kind):
+    """The speculative and the mixed schedule deliver through ``_collect``,
+    and a chunked prefill's finish opens a gap like any admission: in each
+    the gaps are the deliveries less one a request, carry every token but
+    each request's first, and add up to first token -> last delivery on a
+    clock that moves a second a reading."""
+    now = [0.0]
+
+    def clock():
+        now[0] += 1.0
+        return now[0]
+
+    if kind == "speculative":
+        cfg, params, dcfg, dparams = _spec_models()
+        b = ContinuousBatcher(cfg, params, batch_slots=2, max_len=64,
+                              chunk_steps=4, paged_pages=24, page_size=16,
+                              spec_k=3, draft_params=dparams, draft_cfg=dcfg,
+                              clock=clock)
+    elif kind == "contiguous":
+        b = paged(tiny, paged_pages=None, clock=clock)
+    else:
+        b = paged(tiny, prefill_chunk=8, clock=clock,
+                  schedule=kind.split("-")[1])
+    jobs = [(list(range(40, 75)), 9), ([7, 1, 9], 14), ([5] * 20, 6)]
+    before = gap_reads()
+    chunks0 = METRICS.get_counter("batcher.prefill_chunks")
+    got: dict[int, list[int]] = {}
+    rids = [b.submit(ids, max_new_tokens=n) for ids, n in jobs]
+    b.run(on_tokens=lambda rid, toks, done, lps:
+          got.setdefault(rid, []).append(len(toks)) if toks else None)
+    n, total, c = gaps_since(before)
+    if kind.startswith("chunked"):
+        assert METRICS.get_counter("batcher.prefill_chunks") > chunks0
+    assert [sum(got[r]) for r in rids] == [k for _, k in jobs]
+    assert n == sum(len(d) for d in got.values()) - len(jobs)
+    assert c["batcher.decode.committed_tokens"] == \
+        sum(k for _, k in jobs) - len(jobs)
+    recs = {r["rid"]: r for r in b.finished_requests()}
+    assert [recs[r]["deliveries"] for r in rids] == [len(got[r]) for r in rids]
+    assert all(0 < recs[r]["max_gap_ms"] and
+               0 <= recs[r]["stalled_ms"] <= recs[r]["decode_ms"]
+               for r in rids)
+    assert 0 <= c["batcher.row.gap_admit_seconds"] < total

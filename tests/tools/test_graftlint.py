@@ -226,6 +226,35 @@ def test_metric_drift(tmp_path):
     assert any("stale.gauge" in f.message for f in findings)
 
 
+def test_a_bucketed_histogram_emits_its_edge_series(tmp_path):
+    """A histogram named in BUCKETED beside the registry exports
+    "<name>.le_us.*" (prometheus_text makes the names): the tuple is the
+    emitter GL305 counts, and an entry it lacks is GL302's finding;
+    observe_many is an emitter like observe."""
+    project = _project(tmp_path, {
+        "pkg/core/observability.py": (
+            "METRICS = None\n"
+            "BUCKETED: tuple[str, ...] = ('row.gap_seconds', 'ttft_seconds')\n"
+            "METRIC_DOCS: dict[str, str] = {\n"
+            "    'row.gap_seconds': 'a bucketed histogram',\n"
+            "    'row.gap_seconds.le_us.*': 'its edges',\n"
+            "    'ttft_seconds': 'bucketed, its edges not declared',\n"
+            "    'wait_seconds.le_us.*': 'edges of a histogram not bucketed',\n"
+            "}\n"
+        ),
+        "pkg/srv.py": (
+            "from .core.observability import METRICS\n"
+            "def f(gaps):\n"
+            "    METRICS.observe_many('row.gap_seconds', gaps)\n"
+            "    METRICS.observe('ttft_seconds', 1.0)\n"
+        ),
+    })
+    findings = registry.check_metrics(project)
+    assert _rules(findings) == ["GL302", "GL305"]
+    assert any("'ttft_seconds.le_us.*'" in f.message for f in findings)
+    assert any("'wait_seconds.le_us.*'" in f.message for f in findings)
+
+
 def test_span_calls_emit_their_seconds_histogram(tmp_path):
     """span("x") (core/profiling.py) observes "x_seconds": the call site
     is the emitter GL302 checks and GL305 counts, through any of the

@@ -12,9 +12,12 @@ declared registry:
   literals — in tests too, for dotted site names) must appear in
   ``FAULT_SITES`` in ``runtime/faults.py``.
 - GL302: every metric name passed to ``METRICS.inc / set_gauge /
-  set_gauges / observe`` in the package must appear in
+  set_gauges / observe / observe_many`` in the package must appear in
   ``METRIC_DOCS`` in ``core/observability.py``; a ``span("x")`` call
-  (``core/profiling.py``) emits the histogram ``x_seconds``.  f-string names are
+  (``core/profiling.py``) emits the histogram ``x_seconds``, and a
+  histogram named in the ``BUCKETED`` tuple beside the registry emits the
+  series ``<name>.le_us.*`` (one an edge of its ladder, written by
+  ``prometheus_text``).  f-string names are
   checked as patterns (each interpolation becomes ``*``) and must be
   registered VERBATIM as that pattern (e.g. ``faults.fired.*``); a fully
   dynamic name needs an ``ignore[GL302](<reason>)``.
@@ -48,7 +51,7 @@ OBS_MODULE = "core/observability.py"
 SERVE_MODULE = "cli/serve_main.py"
 CONFIG_MODULE = "core/config.py"
 
-_METRIC_METHODS = {"inc", "set_gauge", "observe"}
+_METRIC_METHODS = {"inc", "set_gauge", "observe", "observe_many"}
 
 
 def _find_module(project: Project, suffix: str) -> SourceFile | None:
@@ -246,6 +249,16 @@ def check_metrics(project: Project) -> list[Finding]:
                         "name/pattern -> one-line doc) declared")]
     findings: list[Finding] = []
     used: set[str] = set()
+    for name in sorted(_literal_strset(reg_file, "BUCKETED") or ()):
+        # The edge series of a bucketed histogram: no call site names
+        # them, the export makes them from the histogram's own name.
+        used.add(name + ".le_us.*")
+        if name + ".le_us.*" not in registry:
+            findings.append(Finding(
+                RULE_METRIC, reg_file.rel, 1,
+                f"bucketed histogram '{name}' has no '{name}.le_us.*' "
+                f"entry in METRIC_DOCS",
+            ))
     for sf in project.package_files():
         for name_node, line in _metric_name_nodes(sf):
             pattern = _pattern_of(name_node)
