@@ -742,6 +742,12 @@ METRIC_DOCS: dict[str, str] = {
                                  "every layer's rows (kv_cache.page_bytes)",
     "moe.held_pairs": "routed pairs that fell on an expert this chip holds "
                       "(ModelConfig.experts_held), real tokens only",
+    "moe.combine_rows": "of moe.held_pairs, those whose rows the expert "
+                        "layer's combine fetched singly (the kernel "
+                        "moe_combine: an admission block whose grouped list "
+                        "outgrows fast memory); the rest, and every pair of "
+                        "a model that holds all its experts, came by the "
+                        "gather of every routed pair's row",
     "mla.decode.resident_tokens": "tokens the decoding rows held, summed "
                                   "over decode steps: what the latent decode "
                                   "kernel read a layer",
@@ -757,7 +763,8 @@ METRIC_DOCS: dict[str, str] = {
                            "load imbalance (1.0 is even)",
     # -- kernel dispatch (ops/dispatch.py) --
     "ops.dispatch.*.*": "trace-time dispatches of a Pallas op (quant_matmul, "
-                        "paged_decode, ragged_decode, flash, moe_experts) "
+                        "paged_decode, ragged_decode, flash, moe_experts, "
+                        "moe_combine) "
                         "by the path "
                         "taken: kernel (compiled), interpret (Pallas "
                         "interpreter) or fallback (dense jax.numpy)",

@@ -434,7 +434,9 @@ def moe_dropless(
     the pairs that fell on experts [offset, offset + held): the rest add
     nothing here (their chips would), experts touched and the fullest
     expert's load count the held ones, and stats has a fifth entry, the
-    pairs that fell on a held expert.  (A shared expert, which every
+    pairs that fell on a held expert, and a sixth, those of them whose rows
+    the combine fetched singly (ops.moe_experts._pairs_rows; 0 where the
+    gather of every pair's row made its operand).  (A shared expert, which every
     token goes through, is the caller's to add: models.model.run_layers.)
 
     With ``cfg.moe_latent_size`` the routed experts work in a latent:
@@ -473,11 +475,14 @@ def moe_dropless(
                            "btd,dn->btn", 1, "n").reshape(b * t, -1)
     with jax.named_scope("moe_experts"):
         ex = p["experts"]
-        y = moe_experts.grouped_swiglu(
+        y, fetched = moe_experts.grouped_swiglu(
             xf, local, ex["w_up" if latent else "w_gate_up"], ex["w_down"],
             layer, of_experts=cfg.num_experts if share else None,
             act=gate_fn(cfg.gate_act), gated=not latent,
-            token_mask=None if token_mask is None else real)
+            token_mask=None if token_mask is None else real,
+            count_fetched=True)
+        if share:
+            stats = jnp.concatenate([stats, fetched[None]])
         y = jnp.sum(y.astype(jnp.float32) * w[:, :, None], axis=1)
     y = y.reshape(b, t, -1).astype(x.dtype)
     if latent:

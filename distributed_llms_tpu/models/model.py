@@ -1269,7 +1269,7 @@ def run_layers(
     pairs, layer passes, experts touched, fullest expert's tokens), a
     by-product like the dense families' aux loss and no part of the
     state."""
-    moe = jnp.zeros((4 if cfg.experts_held is None else 5,), jnp.int32)
+    moe = jnp.zeros((4 if cfg.experts_held is None else 6,), jnp.int32)
     if cfg.ret_layers:  # the retention layers' counts ride there instead
         moe = retention_counts(cfg, call, x.shape[:2])
     shape = x.shape[:2]

@@ -23,8 +23,16 @@ dequantizes a weight tile by splitting its rows into blocks of 128 and
 multiplying each by its scale row: no lane is moved, where blocks along the
 last axis cost a lane-splitting reshape of every tile.
 
+The pairs' outputs come back as [S, k, D], a dead pair's zeros (an absent
+expert's, a padding token's), for the caller's sum over k: a gather of
+every pair's row of the grouped outputs, or, where a chip holds a share of
+the experts and the list lies in HBM, a second kernel that fetches the rows
+of the pairs that hold one (:func:`_pairs_rows`, ``moe_combine``): the same
+array bit for bit, so the sum keeps its operand and its order.
+
 Modes (``DLT_MOE_EXPERTS``: kernel | interpret | fallback | auto = kernel
-iff TPU), recorded as ``ops.dispatch.moe_experts.<path>``.  The fallback is
+iff TPU), recorded as ``ops.dispatch.moe_experts.<path>`` (and
+``ops.dispatch.moe_combine.<path>`` where the second kernel is taken).  The fallback is
 ``jax.lax.ragged_dot`` over the same sorted pairs (dequantizing the stacks
 first): the reference the kernel is parity-tested against, and the path of
 float weights (tests, training).  Inference-only.
@@ -58,6 +66,17 @@ def row_tile(pairs: int, experts: int) -> int:
     while bm < _BM_MAX and bm * experts < 2 * pairs:
         bm *= 2
     return bm
+
+
+def list_shape(pairs: int, experts: int,
+               of_experts: int | None = None) -> tuple[int, int]:
+    """(rows a tile, rows of the grouped list) for ``pairs`` pairs over
+    ``experts`` held experts of ``of_experts`` (None: all are held): the
+    tile is sized for the share of the pairs that an even routing sends
+    here, the list for all of them, every group padded to whole tiles."""
+    bm = row_tile(pairs * experts // of_experts if of_experts else pairs,
+                  experts)
+    return bm, (-(-pairs // bm) + experts) * bm  # sum_e ceil(c_e/bm) tiles
 
 
 def _tiles(k: int, n: int, qb: int) -> tuple[int, int] | None:
@@ -228,11 +247,148 @@ def _grouped_list(eid, live, e, bm, rows):
     return jnp.where(live, dest, rows), counts, ends
 
 
+_STAGE_BYTES = 16 * 1024 * 1024  # the fetched tiles of two blocks of pairs
+# When the pairs' rows are fetched by the pairs that hold one and not gathered
+# for every pair (PERF.md section 6, PR 58; both by what a trace can see):
+# - the list is at least _COPY_MIN_BYTES: a smaller one XLA keeps in fast
+#   memory, where its gather reads a row in 6-22 ns whatever the row's width
+#   (K-EXAONE's 512-token block, 62.9 MB: 0.05 ms a call against the
+#   kernel's 0.13), and from HBM 24 ns at 2 KB a row and 113 at 14 (A.X-K1's
+#   512-token block, 69.7 MB: 0.37 ms a call against 0.135);
+# - the rows the gather moves for one held pair, ``of_experts / E`` of them,
+#   come to _COPY_PAIR_BYTES: a held pair costs the kernel 0.07-0.15 us
+#   beside its bytes, what the gather needs for 20 KB of rows (A.X-K1's
+#   sixteen rows of 14 KB a held pair: 0.52 ms a block against 1.86;
+#   nemotron's four of 2 KB stay gathered: 0.73 against 1.12, and behind a
+#   custom call XLA makes the reshape to [S, 22, D] float32, 0.30 more:
+#   PERF.md section 7, From PR 58 (a), has what would take it).
+_COPY_MIN_BYTES = 64 * 1024 * 1024
+_COPY_PAIR_BYTES = 20 * 1024
+
+
+def _copy_block(p: int, d: int) -> int | None:
+    """Pairs a block of :func:`_pairs_rows`: the largest power of two up to
+    256 that divides ``p`` and keeps two blocks' fetched tiles, 8 rows of
+    16 bits a pair, in ``_STAGE_BYTES`` (A.X-K1's D of 7,168: 64), or None
+    where the kernel does not apply: 32 pairs are a word of the held
+    pairs' bits, D is whole lanes."""
+    if d % 128 or p % 32:
+        return None
+    bp = 32
+    while (bp < 256 and p % (2 * bp) == 0
+           and 2 * (2 * bp) * 8 * d * 2 <= _STAGE_BYTES):
+        bp *= 2
+    return bp
+
+
+def _copy_kernel(bits_ref, dest_ref, yp_ref, o_ref, o32, stage, sems, count,
+                 held, *, bp):
+    """One block of ``bp`` pairs.  The 16-bit rows of ``yp`` lie two to a
+    32-bit word (rows 2r and 2r + 1, the even one low) and eight to a tile
+    in HBM, and nothing smaller than a tile is copied from there: a held
+    pair's row comes with the seven beside it, as the tile's 4 rows of
+    words, and its half of one of them is put into the pair's half of the
+    block's word row, which began as zeros (a dead pair's stay).  The held
+    pairs are the set bits of ``bits_ref``, 32 pairs a word, so a dead
+    pair costs nothing; step i lists block i's and starts their copies
+    before it waits for block i - 1's, whose place in the output it holds
+    (one step more than blocks, each half of the body traced once)."""
+    i, n = pl.program_id(0), pl.num_programs(0) - 1  # n blocks, n + 1 steps
+    yp32 = yp_ref.bitcast(jnp.uint32)  # [rows / 2, D]
+
+    def tile(d, slot, c):  # the tile that holds row d -> the slot's c-th
+        return pltpu.make_async_copy(
+            yp32.at[pl.ds(d // 8 * 4, 4)], stage.at[slot, c], sems.at[slot])
+
+    @pl.when(i < n)
+    def _fetch():  # block i: list its held pairs, start their copies
+        slot = i % 2
+
+        def word(wi, c):
+            def bit(state):
+                w, c = state
+                low = w & -w
+                j = wi * 32 + 31 - jax.lax.clz(low)
+                d = dest_ref[i * bp + j]
+                tile(d, slot, c).start()
+                held[slot, 0, c] = j
+                held[slot, 1, c] = d
+                return w ^ low, c + 1
+
+            return jax.lax.while_loop(
+                lambda state: state[0] != 0, bit,
+                (bits_ref[i * (bp // 32) + wi], c))[1]
+
+        count[slot] = jax.lax.fori_loop(0, bp // 32, word, jnp.int32(0))
+
+    @pl.when(i > 0)
+    def _place():  # block i - 1: wait for its copies, put its rows together
+        slot = (i - 1) % 2
+        o32[...] = jnp.zeros_like(o32)
+
+        def wait(_, carry):
+            tile(0, slot, 0).wait()
+            return carry
+
+        jax.lax.fori_loop(0, count[slot], wait, 0)
+
+        def place(c, carry):
+            j, d = held[slot, 0, c], held[slot, 1, c]
+            w = stage[slot, c, pl.ds(d % 8 // 2, 1), :]
+            half = (w >> (d % 2 * 16).astype(jnp.uint32)) & 0xFFFF
+            at = pl.ds(j // 2, 1)
+            o32[at, :] = o32[at, :] | (
+                half << (j % 2 * 16).astype(jnp.uint32))
+            return carry
+
+        jax.lax.fori_loop(0, count[slot], place, 0)
+        o_ref[...] = pltpu.bitcast(o32[...], o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pairs_rows(yp, dest, *, interpret=False):
+    """yp [rows, D] in 16 bits, dest [P] (a pair's row of ``yp``, ``rows``
+    for a dead pair) -> [P, D]: ``yp.at[dest].get(mode="fill",
+    fill_value=0)`` bit for bit, made by fetching the held pairs' rows and
+    writing each block once, where XLA's gather fetches a tile for every
+    pair, dead or held."""
+    rows, d = yp.shape
+    p = dest.shape[0]
+    bp = _copy_block(p, d)
+    bits = jnp.sum(  # the held pairs, 32 a word, the first pair lowest
+        (dest.reshape(-1, 32) < rows).astype(jnp.uint32)
+        << jnp.arange(32, dtype=jnp.uint32), axis=1, dtype=jnp.uint32)
+    return pl.pallas_call(
+        functools.partial(_copy_kernel, bp=bp),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(p // bp + 1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(
+                (bp, d), lambda i, *_: (jnp.maximum(i - 1, 0), 0)),
+            scratch_shapes=[
+                pltpu.VMEM((bp // 2, d), jnp.uint32),  # the block, in words
+                pltpu.VMEM((2, bp, 4, d), jnp.uint32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.SMEM((2, 2, bp), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((p, d), yp.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=32 * 1024 * 1024),
+        interpret=interpret,
+        name="moe_combine",  # the operation's name in a trace
+    )(jax.lax.bitcast_convert_type(bits, jnp.int32), dest, yp)
+
+
 def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
                    layer: jax.Array | int = 0,
                    of_experts: int | None = None,
                    act=jax.nn.silu, gated: bool = True,
-                   token_mask: jax.Array | None = None) -> jax.Array:
+                   token_mask: jax.Array | None = None,
+                   count_fetched: bool = False):
     """Every (token, choice) pair through its expert's gated MLP,
     ``(act(x W_gate) * (x W_up)) W_down``: ``act`` is the configuration's
     (layers.gate_fn; silu for a SwiGLU).  ``gated`` False: an expert is two
@@ -255,7 +411,11 @@ def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
     ``token_mask`` [S] bool marks the real tokens (None: all): the padding
     of an admission's block and a row that is not decoding get no row
     either, and zeros.  A live pair's tile and bits do not depend on the
-    dead ones."""
+    dead ones.
+
+    ``count_fetched``: return (pairs' outputs, rows fetched), the second the
+    held pairs whose rows :func:`_pairs_rows` fetched, int32 and 0 where the
+    gather made the outputs (the source of ``moe.combine_rows``)."""
     s, d = xf.shape
     k = topi.shape[1]
     quant = _is_quantized(w_gate_up)
@@ -277,8 +437,7 @@ def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
         tiles = plans if all(plans) else None
     share = of_experts is not None
     # (the fallback pads no group)
-    bm = row_tile(p * e // of_experts if share else p, e) if tiles else 1
-    rows = (-(-p // bm) + e) * bm if tiles else p  # sum_e ceil(c_e/bm) tiles
+    bm, rows = list_shape(p, e, of_experts) if tiles else (1, p)
 
     live = jnp.logical_and(eid >= 0, eid < e)
     if token_mask is not None:
@@ -287,8 +446,26 @@ def grouped_swiglu(xf: jax.Array, topi: jax.Array, w_gate_up, w_down,
     src = jnp.zeros((rows,), jnp.int32).at[dest].set(token, mode="drop")
     xp = xf[src]  # padding rows repeat token 0: computed, never read back
 
+    # A share's pairs are mostly dead: the rows of a large list are fetched
+    # by the pairs that hold one (_pairs_rows).  Where every expert is held,
+    # at a decode step's sizes and for narrow rows the gather is the faster
+    # of the two, and stays (_COPY_MIN_BYTES, _COPY_PAIR_BYTES).
+    copy = (tiles is not None and share and xf.dtype.itemsize == 2
+            and rows * d * 2 >= _COPY_MIN_BYTES
+            and d * 2 * of_experts >= _COPY_PAIR_BYTES * e
+            and _copy_block(p, d) is not None)
+
     def pairs(yp):  # (a dead pair's row is past the list: zeros)
-        return yp.at[dest].get(mode="fill", fill_value=0).reshape(s, k, d)
+        with jax.named_scope("moe_combine"):
+            if copy:
+                dispatch.record("moe_combine", mode, (p, rows, d))
+                y = _pairs_rows(yp, dest, interpret=mode == "interpret")
+            else:
+                y = yp.at[dest].get(mode="fill", fill_value=0)
+            y = y.reshape(s, k, d)
+        if not count_fetched:
+            return y
+        return y, jnp.sum(live, dtype=jnp.int32) if copy else jnp.int32(0)
 
     def hidden(h):  # the first projection's output -> the second's input
         return act(h[:, :f]) * h[:, f:] if gated else act(h)

@@ -977,11 +977,11 @@ def _decode_steps(
             )
             moe = aux[0] if aux else None
             if cfg.kv_lora_rank or cfg.swa_layers:
-                # Sixth (_note_moe), behind the fifth that only a chip's
-                # share of the experts counts: what the decode kernel of
+                # Seventh (_note_moe), behind the fifth and sixth that only a
+                # chip's share of the experts counts: what the decode kernel of
                 # the paged layers (latent pages; the full layers' beside
                 # windowed ones) read this step, the tokens each decoding
-                # row holds, its new one included.  Seventh, with windowed
+                # row holds, its new one included.  Eighth, with windowed
                 # layers: the min(that, window) of them its ring holds.
                 held = jnp.where(active, real_lens + 1, 0)
                 read = [jnp.sum(held, dtype=jnp.int32)[None]]
@@ -990,7 +990,7 @@ def _decode_steps(
                         jnp.minimum(held, cfg.sliding_window),
                         dtype=jnp.int32)[None])
                 moe = jnp.concatenate([
-                    moe, jnp.zeros((5 - moe.shape[0],), jnp.int32), *read])
+                    moe, jnp.zeros((6 - moe.shape[0],), jnp.int32), *read])
         else:
             mask = (valid | (slots[None, :] == real_lens[:, None]))[:, None, None, :]
             # (a model of retention layers is the one hybrid served here,
@@ -4691,9 +4691,10 @@ class ContinuousBatcher:
         """Add a program's expert counts (layers.moe_dropless, real tokens
         only) to ``moe.*``: what a hybrid model's admission or decode chunk
         handed out beside its tokens, fetched with them (host values), None
-        for any other model.  Four counts; a fifth where the config holds a
-        chip's share of the experts; a decode chunk against latent pages
-        hands out six, one against pages and rings seven (_decode_steps)."""
+        for any other model.  Four counts; a fifth and a sixth where the
+        config holds a chip's share of the experts; a decode chunk against
+        latent pages hands out seven, one against pages and rings eight
+        (_decode_steps)."""
         if stats is None:
             return
         counts = [int(x) for x in stats]
@@ -4720,19 +4721,21 @@ class ContinuousBatcher:
         METRICS.inc("moe.layer_passes", counts[1])
         METRICS.inc("moe.experts_touched", counts[2])
         METRICS.inc("moe.max_load_tokens", counts[3])
-        if len(counts) > 4:  # a chip's share of the experts
+        if len(counts) > 4:  # a chip's share of the experts: ... and of
+            # the held pairs, those whose rows the combine fetched singly
             METRICS.inc("moe.held_pairs", counts[4])
-        if len(counts) > 5 and self.cfg.swa_layers:  # a decode chunk: the
+            METRICS.inc("moe.combine_rows", counts[5])
+        if len(counts) > 6 and self.cfg.swa_layers:  # a decode chunk: the
             # tokens its rows held (full layers' pages; latent pages)
-            METRICS.inc("attn.decode.resident_tokens", counts[5])
-        elif len(counts) > 5:
-            METRICS.inc("mla.decode.resident_tokens", counts[5])
-        if len(counts) > 6:  # ... and of them, those inside the window,
+            METRICS.inc("attn.decode.resident_tokens", counts[6])
+        elif len(counts) > 6:
+            METRICS.inc("mla.decode.resident_tokens", counts[6])
+        if len(counts) > 7:  # ... and of them, those inside the window,
             # beside the tokens the rows' rings hold room for: the window
             # times the row-steps that decoded, which are the pairs routed
             # over the k choices of every expert layer (no count of its
             # own in the program: K-EXAONE's decode chunk stays as it was)
-            METRICS.inc("swa.decode.window_tokens", counts[6])
+            METRICS.inc("swa.decode.window_tokens", counts[7])
             cfg = self.cfg
             METRICS.inc(
                 "swa.decode.ring_tokens", cfg.sliding_window * counts[0] // (

@@ -86,7 +86,7 @@ def test_prefill_then_decode_through_the_latent_pool(tiny, n, bucket, held):
         return_aux=True)
     np.testing.assert_allclose(np.asarray(logits[0, :n]), ref[:n], atol=ATOL)
     assert [int(x) for x in stats[:2]] == [n * 4 * 3, 3]
-    assert len(stats) == (5 if held else 4)
+    assert len(stats) == (6 if held else 4)  # (a share: held pairs, fetched)
     page_list = jnp.asarray(2 + np.arange(pages)[::-1].copy(), jnp.int32)
     pool = kv_cache.write_row(
         kv_cache.make_pool(cfg, 16, blk), page_list, row)

@@ -234,8 +234,8 @@ def test_two_rows_of_unlike_length_do_not_move_each_other(tiny):
             params, cfg, pool, jnp.int32(slot), pages_of(slot),
             jnp.asarray(prompt), jnp.int32(n))
         np.testing.assert_array_equal(np.asarray(first), alone[0])
-        # behind the experts' five: the scan's tokens, chunks, row-steps
-        assert [int(x) for x in counts[5:]] == [n, -(-n // 128), 0]
+        # behind the experts' six: the scan's tokens, chunks, row-steps
+        assert [int(x) for x in counts[6:]] == [n, -(-n // 128), 0]
         tables = tables.at[slot].set(pages_of(slot))
     active = jnp.asarray([True, False, True])
     for j in range(STEPS):
@@ -245,7 +245,7 @@ def test_two_rows_of_unlike_length_do_not_move_each_other(tiny):
             jnp.asarray([200 + j, 0, 20 + j], jnp.int32), active, tables)
         np.testing.assert_allclose(logits[0, 0], alone_a[1 + j], atol=2e-6)
         np.testing.assert_allclose(logits[2, 0], alone_b[1 + j], atol=2e-6)
-        assert [int(x) for x in counts[5:]] == [0, 0, 2]
+        assert [int(x) for x in counts[6:]] == [0, 0, 2]
     # the slot that did not decode kept its (empty) state and taps
     assert not np.asarray(pool.ssm_h[:, 1]).any()
     assert not np.asarray(pool.ssm_conv[:, 1]).any()

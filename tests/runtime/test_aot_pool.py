@@ -289,6 +289,32 @@ def _kernels(hlo: str) -> set:
                           r'"tpu_custom_call"', hlo))
 
 
+def _gathers(hlo: str, shape: str) -> list:
+    """Instructions that gather (alone or inside a fusion) an array of
+    ``shape``."""
+    from tools import aot_decode
+
+    return [e for e in aot_decode.shaped_like(hlo, [shape])
+            if "gather" in e[0]]
+
+
+def test_an_admissions_combine_gathers_no_row_for_an_absent_expert(
+        compiled_windowed):
+    """K-EXAONE's admission at the 8,192 bucket walks its expert layers in
+    blocks of 2,048 tokens, 16,384 pairs of which one in eight falls on a
+    held expert: the operand of the sum over k is made by the kernel
+    ``moe_combine`` (Mosaic takes its copies of a tile of 8 bf16 rows, the
+    held pairs' bits scalar-prefetched) and no ``gather`` of [16384, 6144]
+    is left in the program (PR 58); the decode step's 512 pairs, whose list
+    lies in fast memory, keep the gather and hold no such kernel."""
+    admit = compiled_windowed["admit_row_paged"]["hlo"]
+    assert "moe_combine" in _kernels(admit)
+    assert _gathers(admit, "[16384,6144]") == []
+    decode = compiled_windowed["decode_chunk"]["hlo"]
+    assert "moe_combine" not in _kernels(decode)
+    assert _gathers(decode, "[512,6144]")
+
+
 def test_the_8192_admissions_flash_kernel_ends_with_the_real_tokens(
         compiled_windowed):
     """Mosaic takes the flash kernel with a traced bound on its axis of
@@ -501,6 +527,15 @@ def test_a_fresh_rows_admission_holds_no_scores_over_the_row_cache(
             if not re.search(rf"[\[,]{bucket}[,\]]", e[2])] == []
     assert admit["temp_gb"] < temp_gb
     assert admit["argument_gb"] + admit["temp_gb"] < 15.75 - 0.5
+    # A chip's share of the experts at a 2,048-token block: the pairs' rows
+    # are fetched by the kernel, not gathered for all 16,384 pairs (PR 58);
+    # a model that holds every expert keeps the gather.
+    if preset == "ax-k1-ep16":
+        assert "moe_combine" in _kernels(admit["hlo"])
+        assert not _gathers(admit["hlo"], "[16384,7168]")
+    if preset == "lfm2-8b-a1b":
+        assert "moe_combine" not in _kernels(admit["hlo"])
+        assert _gathers(admit["hlo"], "[8192,2048]")
 
 
 @pytest.mark.parametrize("preset,slots,max_len,pages,bucket,group", [

@@ -1,7 +1,8 @@
 """The counts a hybrid model's programs hand ``ContinuousBatcher._note_moe``
 beside their tokens, which reads them BY POSITION: the experts' four, a
-share's fifth, a decode chunk's one or two of the tokens its rows held, a
-state-space model's three last of all.  Every family's layout in both kinds
+share's fifth and sixth (the held pairs; those of them whose rows the
+combine fetched singly, PR 58), a decode chunk's one or two of the tokens
+its rows held, a state-space model's three last of all.  Every family's layout in both kinds
 of program, pinned before anyone moves a slot."""
 
 import dataclasses
@@ -17,23 +18,22 @@ from distributed_llms_tpu.runtime.batcher import ContinuousBatcher
 
 MOE = ["moe.routed_pairs", "moe.layer_passes", "moe.experts_touched",
        "moe.max_load_tokens"]
+SHARE = ["moe.held_pairs", "moe.combine_rows"]
 SSM = ["ssm.admit.tokens", "ssm.admit.chunks", "ssm.decode.row_steps"]
 # family -> (preset, experts held of its experts, an admission's counters in
 # the order of its array, a decode chunk's)
 FAMILIES = {
     "whole": ("lfm2-tiny", None, MOE, MOE),
-    "share": ("lfm2-tiny", 4, MOE + ["moe.held_pairs"],
-              MOE + ["moe.held_pairs"]),
+    "share": ("lfm2-tiny", 4, MOE + SHARE, MOE + SHARE),
     "latent-pages": (
-        "ax-k1-tiny", 4, MOE + ["moe.held_pairs"],
-        MOE + ["moe.held_pairs", "mla.decode.resident_tokens"]),
+        "ax-k1-tiny", 4, MOE + SHARE,
+        MOE + SHARE + ["mla.decode.resident_tokens"]),
     "pages-and-rings": (
-        "k-exaone-tiny", 4, MOE + ["moe.held_pairs"],
-        MOE + ["moe.held_pairs", "attn.decode.resident_tokens",
-               "swa.decode.window_tokens"]),
+        "k-exaone-tiny", 4, MOE + SHARE,
+        MOE + SHARE + ["attn.decode.resident_tokens",
+                       "swa.decode.window_tokens"]),
     "state-space": (
-        "nemotron3-super-tiny", 8, MOE + ["moe.held_pairs"] + SSM,
-        MOE + ["moe.held_pairs"] + SSM),
+        "nemotron3-super-tiny", 8, MOE + SHARE + SSM, MOE + SHARE + SSM),
 }
 
 
@@ -82,3 +82,20 @@ def test_every_familys_layout_in_an_admission_and_a_decode_chunk(family):
         got = _delta(before, names)
         assert [got[n] for n in names] == values
 
+
+
+@pytest.mark.parametrize("family", [f for f in FAMILIES if f != "whole"])
+def test_the_rows_the_combine_fetched_are_held_pairs(family):
+    """``moe.combine_rows`` counts, of the held pairs, those whose rows the
+    kernel ``moe_combine`` fetched: never more than ``moe.held_pairs``, which
+    is never more than ``moe.routed_pairs``, and here none, since these
+    batchers hold float stacks and a float stack's pairs are gathered (the
+    kernel's own count is held in tests/ops/test_moe_experts.py)."""
+    names = ["moe.routed_pairs", "moe.held_pairs", "moe.combine_rows"]
+    before = METRICS.snapshot()["counters"]
+    b = _batcher(family)
+    b.submit(list(range(40, 59)), max_new_tokens=6)
+    b.run()
+    got = _delta(before, names)
+    assert 0 == got["moe.combine_rows"] <= got["moe.held_pairs"]
+    assert 0 < got["moe.held_pairs"] < got["moe.routed_pairs"]

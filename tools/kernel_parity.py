@@ -465,6 +465,33 @@ def moe_parity(e: int = 32, d: int = 2048, f: int = 1792, k: int = 4,
               f"{act}", got, want, rtol=3e-2, atol=3e-2)
 
 
+def combine_parity(s: int, k: int, e: int, of: int, d: int) -> None:
+    """The rows of an admission block's pairs fetched by the pairs that hold
+    one (``moe_experts._pairs_rows``, the kernel ``moe_combine``) against
+    the gather of every pair's row, bit for bit, zeros included: ``s``
+    tokens of ``k`` picks over ``of`` experts of which ``e`` are held, the
+    last quarter of the tokens padding."""
+    rs = np.random.RandomState(s + k)
+    p = s * k
+    eid = jnp.asarray(
+        np.argsort(rs.rand(s, of), axis=1)[:, :k].reshape(p), jnp.int32)
+    live = (eid < e) & (jnp.arange(p) // k < s - s // 4)
+    bm, rows = moe_experts.list_shape(p, e, of)
+    dest = moe_experts._grouped_list(eid, live, e, bm, rows)[0]
+    yp = jnp.asarray(rs.randn(rows, d), jnp.bfloat16)
+    want = jax.jit(lambda y, i: y.at[i].get(mode="fill", fill_value=0))(
+        yp, dest)
+    got = moe_experts._pairs_rows(yp, dest, interpret=not ON_TPU)
+    same = bool(jnp.array_equal(
+        jax.lax.bitcast_convert_type(got, jnp.uint16),
+        jax.lax.bitcast_convert_type(want, jnp.uint16)))
+    print(f"  moe combine S{s} k{k} E{e} of {of} D{d} "
+          f"held {int(jnp.sum(live))} of {p}: bits equal {same}")
+    if not same:
+        raise AssertionError("moe_combine: the fetched rows are not the "
+                             "gather's")
+
+
 def flash_band_parity(t: int, h: int, kvh: int, win: int) -> None:
     """The flash kernel as a windowed layer's long admission calls it
     (models.model._self_attention: tiles of 512): a band of ``win`` keys on
@@ -858,6 +885,13 @@ def main() -> int:
     else:
         moe_parity(e=8, d=256, f=384, k=3, of_experts=32, act="relu2",
                    gated=False)
+    # The combine's operand at A.X-K1's and K-EXAONE's 2,048-token blocks
+    # and at A.X-K1's 512 (the smallest list that takes the kernel), and at
+    # nemotron's k = 22 (which keeps the gather in the served model).
+    for leg in (((2048, 8, 12, 192, 7168), (2048, 8, 16, 128, 6144),
+                 (512, 8, 12, 192, 7168), (2048, 22, 128, 512, 1024))
+                if ON_TPU else ((64, 8, 4, 32, 256), (32, 22, 16, 64, 128))):
+        combine_parity(*leg)
     # No leg may pass on another path than the one asked for: the dispatch
     # record (ops/dispatch.py) counts every trace by the path it took.
     took = {k[len("ops.dispatch."):]: int(v)
@@ -907,8 +941,11 @@ def main() -> int:
     # Brumby's heads, float32 and bfloat16, the smallest bucket — 67 legs.
     # v17: Mamba-2's chunked scan and recurrence step at Nemotron-H's heads,
     # float32 and bfloat16, the smallest bucket, and the expert kernel's
-    # non-gated leg holding 128 of 512 on the latent — 72 legs.
-    print(f"kernel_parity: ALL PASS v17 ({mode}, backend={backend})")
+    # non-gated leg holding 128 of 512 on the latent — 72 legs.  v18: the
+    # combine's operand fetched by the pairs that hold a row (moe_combine)
+    # against the gather, bit for bit, at A.X-K1's, K-EXAONE's and
+    # nemotron's blocks — 76 legs.
+    print(f"kernel_parity: ALL PASS v18 ({mode}, backend={backend})")
     return 0
 
 
