@@ -235,9 +235,14 @@ _BIAS_NAMES = frozenset({"bq", "bk", "bv", "bo", "b_in", "b_out", "b_gate", "b_u
 # paths read the same stored values; of a retention layer (blocks/ret/...)
 # the gate's projection ``wg`` [L, D, KVH], 8 columns wide; of a Mamba-2
 # layer (blocks/ssm/...) the convolution's taps and bias and a head's
-# ``A_log``, ``dt_bias`` and ``D`` [L, heads], which set what a state forgets.
+# ``A_log``, ``dt_bias`` and ``D`` [L, heads], which set what a state forgets;
+# of a gated delta-rule layer (blocks/gdn/...) the taps, ``A_log`` and
+# ``dt_bias`` the same and ``w_ba`` [L, D, 2 heads], which gives a token's
+# ``beta`` and decay; of an expert layer the shared expert's scalar gate
+# ``shared_gate`` [L, D].
 _HYBRID_FLOAT = frozenset({"taps", "router", "expert_bias", "wkv_a", "wkv_b",
-                           "wg", "A_log", "dt_bias", "D", "conv_bias"})
+                           "wg", "A_log", "dt_bias", "D", "conv_bias",
+                           "w_ba", "shared_gate"})
 
 
 def block_axis_of(path: str) -> int:
@@ -252,7 +257,7 @@ def _should_quantize(path: str, x: Any) -> bool:
         return False
     leaf = path.split("/")[-1]
     if path.startswith(("blocks/conv/", "blocks/moe/", "blocks/mla/",
-                        "blocks/ret/", "blocks/ssm/")) \
+                        "blocks/ret/", "blocks/ssm/", "blocks/gdn/")) \
             and leaf in _HYBRID_FLOAT:
         return False
     if "norm" in path or "ln" in path.split("/")[-2:][0]:

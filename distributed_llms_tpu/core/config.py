@@ -164,12 +164,12 @@ class ModelConfig:
             )
         if self.layer_types:
             bad = set(self.layer_types) - {"conv", "attn", "mla", "swa",
-                                           "ret", "ssm"}
+                                           "ret", "ssm", "gdn"}
             if bad or len(self.layer_types) != self.num_layers:
                 raise ValueError(
                     f"layer_types must name {self.num_layers} layers as "
-                    f"'conv', 'attn', 'swa', 'mla', 'ret' or 'ssm', got "
-                    f"{self.layer_types!r}"
+                    f"'conv', 'attn', 'swa', 'mla', 'ret', 'ssm' or 'gdn', "
+                    f"got {self.layer_types!r}"
                 )
         if self.no_ffn_layers and (
                 not self.layer_types
@@ -190,6 +190,28 @@ class ModelConfig:
                 "alone, and its heads lie 128 // ssm_head_dim to a 128-lane "
                 "row, each row inside ONE group (the state's layout, "
                 "ops/ssm.py)"
+            )
+        if "gdn" in self.layer_types and (
+                set(self.layer_types) - {"gdn", "attn"}
+                or self.gdn_key_dim % 128 or self.gdn_value_dim % 128
+                or not self.gdn_key_heads
+                or self.gdn_value_heads % self.gdn_key_heads
+                or self.gdn_chunk % 16
+                or self.gdn_chunk & (self.gdn_chunk - 1)):
+            raise ValueError(
+                "a model of gated delta-rule layers mixes 'gdn' with 'attn' "
+                "alone; its key and value heads are whole 128-lane tiles, "
+                "each key head read by gdn_value_heads // gdn_key_heads "
+                "value heads, and a chunk of its scan is a power of two of "
+                "16 tokens or more (the triangle's blocks, ops/gdn.py)"
+            )
+        if (self.attn_out_gate or self.moe_shared_gate) and (
+                set(self.layer_types) - {"gdn", "ssm", "conv", "attn"}
+                or not self.layer_types):
+            raise ValueError(
+                "attn_out_gate and moe_shared_gate are the hybrid family's, "
+                "on its 'attn' layers (models.model._attention) and its "
+                "shared expert (models.model.run_layers)"
             )
         if (self.gate_act == "relu2") != bool(self.moe_latent_size):
             raise ValueError(
@@ -284,9 +306,9 @@ class ModelConfig:
     # per-expert selection bias (``moe_expert_bias``: the bias picks, it
     # does not weigh), weights = the chosen scores, divided by their sum +
     # 1e-6 when ``moe_norm_topk``, times ``moe_routed_scale`` (LFM2-MoE).
-    # (``moe_norm_topk``, ``moe_routed_scale`` and ``conv_kernel`` below
-    # mirror published keys and have ONE value in use, LFM2's: constants
-    # until a second model needs another.)
+    # (``moe_norm_topk`` and ``conv_kernel`` below mirror published keys and
+    # have ONE value in use, LFM2's: constants until a second model needs
+    # another.  ``moe_routed_scale`` has three: 1, 2.5, 5.)
     moe_score_fn: str = "softmax"
     # What an expert layer's router reads.  "ffn_norm": the FFN norm's
     # output, what the experts read (every model but one).  "block_input":
@@ -319,8 +341,10 @@ class ModelConfig:
     # row's whole memory one float32 state a key/value head and no key:
     # ops/retention.py) or "ssm" (Mamba-2, the ssm_* fields below: a float32
     # state a head and the convolution's last inputs a row, beside the
-    # "attn" layers' pages).  Empty for the families whose layers are all
-    # alike.
+    # "attn" layers' pages) or "gdn" (Gated DeltaNet, the gdn_* fields
+    # below: a float32 state a value head, corrected by a rank-one
+    # delta rule, and the convolution's last inputs, beside the pages too).
+    # Empty for the families whose layers are all alike.
     layer_types: tuple[str, ...] = ()
     # Tokens a chunk of a "ret" layer's admission scan (ops/retention.py):
     # the attention form inside a chunk, the state between chunks.
@@ -403,6 +427,26 @@ class ModelConfig:
     # shared expert, ``moe_shared_intermediate_size`` wide.
     moe_latent_size: int = 0
     moe_shared_intermediate_size: int | None = None
+    # Gated DeltaNet (layer kind "gdn", ops/gdn.py; Qwen3-Next's
+    # ``linear_*`` keys): ``gdn_key_heads`` key (and query) heads of
+    # ``gdn_key_dim``, ``gdn_value_heads`` value heads of ``gdn_value_dim``
+    # (value head h reads key head h // (value heads / key heads)), a state
+    # [key_dim, value_dim] a value head in float32, ``gdn_conv_kernel`` taps
+    # of a causal depthwise convolution over [q | k | v], ``gdn_chunk``
+    # tokens a chunk of an admission's scan.
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 128
+    gdn_value_dim: int = 128
+    gdn_conv_kernel: int = 4
+    gdn_chunk: int = 64
+    # The hybrid family's "attn" layers gate their output: ``W_q`` is twice
+    # as wide, a head's outputs [query | gate], and the attention's output
+    # is multiplied by ``sigmoid(gate)`` before ``W_o`` (Qwen3-Next).
+    attn_out_gate: bool = False
+    # The shared expert's output is multiplied by ``sigmoid(h w_s)``, one
+    # scalar a token (``shared_expert_gate``, Qwen2-MoE's and Qwen3-Next's).
+    moe_shared_gate: bool = False
 
     @property
     def head_dim_(self) -> int:
@@ -465,6 +509,36 @@ class ModelConfig:
         (kv_cache.HybridCache.ssm_h / ssm_conv) BESIDE the page pool of
         the attention layers."""
         return tuple(i for i, t in enumerate(self.layer_types) if t == "ssm")
+
+    @property
+    def gdn_layers(self) -> tuple[int, ...]:
+        """Indices of the gated delta-rule layers, which keep a float32
+        state a row and a value head and the convolution's last inputs
+        (kv_cache.HybridCache.gdn_s / gdn_conv) BESIDE the page pool of the
+        attention layers."""
+        return tuple(i for i, t in enumerate(self.layer_types) if t == "gdn")
+
+    @property
+    def scan_chunk(self) -> int:
+        """Tokens a chunk of an admission's scan in a model whose rows hold a
+        recurrent state (the "ret", "ssm" or "gdn" layers' own field); 0 for
+        a model without one."""
+        return (self.ret_chunk if self.ret_layers
+                else self.ssm_chunk if self.ssm_layers
+                else self.gdn_chunk if self.gdn_layers else 0)
+
+    @property
+    def gdn_key_width(self) -> int:
+        return self.gdn_key_heads * self.gdn_key_dim
+
+    @property
+    def gdn_value_width(self) -> int:
+        return self.gdn_value_heads * self.gdn_value_dim
+
+    @property
+    def gdn_conv_width(self) -> int:
+        """Channels the convolution runs over: [q | k | v]."""
+        return 2 * self.gdn_key_width + self.gdn_value_width
 
     @property
     def ssm_inner(self) -> int:

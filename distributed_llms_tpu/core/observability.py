@@ -725,6 +725,22 @@ METRIC_DOCS: dict[str, str] = {
     "ssm.decode.row_steps": "rows that took a recurrence step, summed over "
                             "decode steps: each reads and writes its state "
                             "once a layer",
+    "batcher.gdn_state_bytes": "bytes of the state a model of gated "
+                               "delta-rule layers keeps BESIDE its page "
+                               "pool, one entry a layer and a batch slot: "
+                               "the float32 state of every value head and "
+                               "the convolution's last inputs, whatever the "
+                               "rows hold (gauge)",
+    # -- delta-rule layers (models.model.ssm_counts with gdn_chunk; real
+    #    tokens only, behind the experts' counts; a layer's) --
+    "gdn.admit.tokens": "real prompt tokens the delta-rule layers' chunked "
+                        "scan took in, summed over admissions",
+    "gdn.admit.chunks": "chunks of gdn_chunk tokens that scan walked (a "
+                        "triangle solved in each): those that hold a real "
+                        "token",
+    "gdn.decode.row_steps": "rows that took a recurrence step, summed over "
+                            "decode steps: each reads and writes its state "
+                            "once a layer",
     "attn.decode.resident_tokens": "tokens the decoding rows held, summed "
                                    "over decode steps: what the paged decode "
                                    "kernel read a full attention layer (a "

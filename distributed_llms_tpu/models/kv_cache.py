@@ -104,7 +104,13 @@ class HybridCache(KVCache):
     K-1 inputs of its convolution, in the activations' dtype.  They lie
     BESIDE the pool: such a model's attention layers page their keys as any
     other's do, ``k``/``v`` count those layers, and it is served from the
-    pool."""
+    pool.
+    ``gdn_s`` [gdn layers, B, value heads, key_dim, value_dim] float32: each
+    gated delta-rule layer's state a row, a value head's [keys x values]
+    whole 128-lane tiles as the recurrence writes them (ops/gdn.py);
+    ``gdn_conv`` [gdn layers, B, K-1, q + k + v channels], the last K-1
+    inputs of its convolution, in the activations' dtype.  BESIDE the pool
+    as ``ssm_h`` is."""
 
     conv: Any = None
     ring_k: Any = None
@@ -113,6 +119,8 @@ class HybridCache(KVCache):
     ret_z: Any = None
     ssm_h: Any = None
     ssm_conv: Any = None
+    gdn_s: Any = None
+    gdn_conv: Any = None
 
 
 @jax.tree_util.register_dataclass
@@ -164,8 +172,19 @@ def slot_state(cfg: ModelConfig, rows: int, dtype) -> dict:
     zeroed, by field: the convolution layers' state, the windowed layers'
     rings (in the keys' dtype), the retention layers' state and normaliser
     (float32: ``ops.retention.state_shapes``), the Mamba-2 layers' state
-    (float32: ``ops.ssm.state_shape``) and their convolutions' last inputs."""
+    (float32: ``ops.ssm.state_shape``) and their convolutions' last inputs,
+    the gated delta-rule layers' the same (``ops.gdn.state_shape``)."""
     out = {}
+    if cfg.gdn_layers:
+        from ..ops.gdn import state_shape
+
+        lead = (len(cfg.gdn_layers), rows)
+        out["gdn_s"] = jnp.zeros(
+            lead + state_shape(cfg.gdn_value_heads, cfg.gdn_key_dim,
+                               cfg.gdn_value_dim), jnp.float32)
+        out["gdn_conv"] = jnp.zeros(
+            lead + (cfg.gdn_conv_kernel - 1, cfg.gdn_conv_width),
+            jnp.dtype(cfg.dtype))
     if cfg.ssm_layers:
         from ..ops.ssm import state_shape
 
@@ -257,8 +276,9 @@ def format_bytes(pool, cfg: ModelConfig) -> dict[str, float]:
     state a :class:`HybridCache` keeps beside its pages (``conv_state``,
     ``window_state``: the windowed layers' rings, ``ret_state``: the
     retention layers' state and normaliser, ``ssm_state``: the Mamba-2
-    layers' state and their convolutions' last inputs), the bytes of one page
-    of a :class:`LatentCache` (``latent_page``)."""
+    layers' state and their convolutions' last inputs, ``gdn_state``: the
+    gated delta-rule layers' the same), the bytes of one page of a
+    :class:`LatentCache` (``latent_page``)."""
     match pool:
         case HybridCache():
             sizes = {}
@@ -273,6 +293,9 @@ def format_bytes(pool, cfg: ModelConfig) -> dict[str, float]:
             if pool.ssm_h is not None:
                 sizes["ssm_state"] = float(
                     pool.ssm_h.nbytes + pool.ssm_conv.nbytes)
+            if pool.gdn_s is not None:
+                sizes["gdn_state"] = float(
+                    pool.gdn_s.nbytes + pool.gdn_conv.nbytes)
             return sizes
         case LatentCache():
             return {"latent_page": float(
@@ -284,7 +307,7 @@ def format_bytes(pool, cfg: ModelConfig) -> dict[str, float]:
 # The leaves of a :class:`HybridCache` that hold one entry a batch slot
 # ([layers of the kind, B, ...]) and no page.
 _SLOT_FIELDS = ("conv", "ring_k", "ring_v", "ret_s", "ret_z", "ssm_h",
-                "ssm_conv")
+                "ssm_conv", "gdn_s", "gdn_conv")
 
 
 def splice_slot(cache: "HybridCache", slot, row_cache: "HybridCache"):
@@ -612,7 +635,7 @@ def pages_are_private(cfg: ModelConfig) -> bool:
     a 128-lane row lie folded in the pool
     (ops.decode_attn.pool_head_shape; heads of 128 never fold)."""
     return bool(cfg.conv_layers or cfg.swa_layers or cfg.ret_layers
-                or cfg.ssm_layers)
+                or cfg.ssm_layers or cfg.gdn_layers)
 
 
 _LATENT_REFUSALS = {
@@ -711,8 +734,8 @@ def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
     and ``_STATE_REFUSALS`` names what cannot snapshot, ship or roll back
     the state.
 
-    A model of Mamba-2 layers beside attention layers (``cfg.ssm_layers``)
-    holds such a state AND keys: it is served FROM the pool (``paged_pages``
+    A model of Mamba-2 or gated delta-rule layers beside attention layers
+    (``cfg.ssm_layers``, ``cfg.gdn_layers``) holds such a state AND keys: it is served FROM the pool (``paged_pages``
     is required, as for convolution state), and refuses for the state's sake
     what ``_STATE_REFUSALS`` names, all but its ``paged_pages`` entry.
 
@@ -747,7 +770,7 @@ def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
                     f", power retention): {_STATE_REFUSALS[name]}"
                 )
         return
-    if cfg.ssm_layers:
+    if cfg.ssm_layers or cfg.gdn_layers:
         # A recurrent state BESIDE a pool: the attention layers' keys are
         # paged, the state is one entry a batch slot, and whatever would
         # snapshot, ship or roll back the state is refused until something
@@ -756,14 +779,17 @@ def refuse_unpaged_state(cfg: ModelConfig, **asked) -> None:
             raise ValueError(
                 f"{cfg.family} model: the batcher serves its attention "
                 "layers' keys and values from the page pool only, the "
-                "state-space layers' state beside it; pass paged_pages"
+                f"{'state-space' if cfg.ssm_layers else 'delta-rule'} "
+                "layers' state beside it; pass paged_pages"
             )
         for name, value in asked.items():
             if value:
                 raise ValueError(
                     f"{name} is not supported for a model whose rows hold "
                     f"a recurrent state beside their pages (family "
-                    f"{cfg.family!r}, Mamba-2): {_STATE_REFUSALS[name]}"
+                    f"{cfg.family!r}, "
+                    f"{'Mamba-2' if cfg.ssm_layers else 'Gated DeltaNet'}): "
+                    f"{_STATE_REFUSALS[name]}"
                 )
         return
     if not pages_are_private(cfg):

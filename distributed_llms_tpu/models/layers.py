@@ -258,9 +258,11 @@ def qkv_project(x: jax.Array, p: Params, cfg: ModelConfig) -> tuple[jax.Array, j
     """
     if _weight_ndim(p["wq"]) == 2:
         # [D, H * hd], the head axes stored flat (the hybrid family).
+        # (-1: a gated layer's W_q is twice as wide, [query | gate] a head:
+        # cfg.attn_out_gate, split in models.model._attention)
         q, k, v = (
             _contract(x, p[w], "btd,dn->btn", 1, "n").reshape(
-                *x.shape[:2], n, cfg.head_dim_)
+                *x.shape[:2], n, -1)
             for w, n in (("wq", cfg.num_heads), ("wk", cfg.num_kv_heads),
                          ("wv", cfg.num_kv_heads))
         )
@@ -275,8 +277,15 @@ def qkv_project(x: jax.Array, p: Params, cfg: ModelConfig) -> tuple[jax.Array, j
     return q, k, v
 
 
-def out_project(x: jax.Array, p: Params) -> jax.Array:
-    """x: [B, T, H, hd] -> [B, T, D].  wo: [H, hd, D]."""
+def out_project(x: jax.Array, p: Params,
+                gate: jax.Array | None = None) -> jax.Array:
+    """x: [B, T, H, hd] -> [B, T, D].  wo: [H, hd, D].  ``gate`` [B, T, H,
+    hd]: the attention's output times ``sigmoid(gate)`` first (a gated
+    attention layer's, ``cfg.attn_out_gate``)."""
+    if gate is not None:
+        with jax.named_scope("attn_gate"):
+            x = (x.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(x.dtype)
     out = _contract(x, p["wo"], "bthk,hkd->btd", 2, "k")
     if "bo" in p:
         out = out + _plain(p["bo"])
@@ -526,6 +535,31 @@ def moe_dropless_layer(x: jax.Array, p: Params, cfg: ModelConfig) -> jax.Array:
     if "expert_bias" in p:
         stacked["expert_bias"] = p["expert_bias"][None]
     return moe_dropless(x, stacked, cfg)[0]
+
+
+def causal_conv(x: jax.Array, taps: jax.Array, state: jax.Array | None,
+                seq_lens: jax.Array | None) -> tuple[jax.Array, jax.Array]:
+    """A causal depthwise convolution of K taps a channel over x [B, T, C]
+    behind the row's last K - 1 inputs ``state`` [B, K - 1, C] (None: zeros,
+    a row's start); taps [C, K].  -> (the sums [B, T, C] float32, the K - 1
+    inputs that end at each row's ``seq_lens`` REAL tokens (None: all T): a
+    right-padded prompt leaves the inputs of its true length and a row with
+    no real token keeps its own).  The state-space and the delta-rule
+    layers' (models.model.ssm_layer, gdn_layer), each with its own bias,
+    activation and split behind it."""
+    b, t, width = x.shape
+    taps = taps.astype(jnp.float32)
+    k = taps.shape[-1]
+    if state is None:
+        state = jnp.zeros((b, k - 1, width), x.dtype)
+    win = jnp.concatenate([state.astype(x.dtype), x], axis=1)
+    out = sum(taps[:, j] * win[:, j: j + t].astype(jnp.float32)
+              for j in range(k))
+    if seq_lens is None:
+        return out, win[:, t:]
+    return out, jax.vmap(
+        lambda w, n: jax.lax.dynamic_slice_in_dim(w, n, k - 1, axis=0)
+    )(win, seq_lens)
 
 
 @jax.named_scope("conv")  # profiler scope; HLO metadata only

@@ -119,7 +119,8 @@ def call_of(shape: tuple[int, int], positions=None, cache_index=None,
 # Blocks
 # ---------------------------------------------------------------------------
 
-def _paged_attention(q, k, v, p, pool, layer, cache_index, kv_tables):
+def _paged_attention(q, k, v, p, pool, layer, cache_index, kv_tables,
+                     gate=None):
     """Attention of T new tokens a row (T static: 1 in a decode step,
     spec_k + 1 in the speculative draft/verify pass) against the page
     pool.  ``pool`` is the WHOLE stack, every layer's pages (a page-pool
@@ -159,7 +160,7 @@ def _paged_attention(q, k, v, p, pool, layer, cache_index, kv_tables):
         ],
         axis=1,
     )
-    return layers.out_project(out, p), pool
+    return layers.out_project(out, p, gate), pool
 
 
 @jax.named_scope("attn")  # profiler scope; HLO metadata only
@@ -177,6 +178,10 @@ def _attention(
     positions, cache_index, kind = call.positions, call.cache_index, call.kind
     attn_mask, key_positions = call.attn_mask, call.key_positions
     q, k, v = layers.qkv_project(x, p, cfg)
+    gate = None
+    if cfg.attn_out_gate:  # a head's outputs of W_q are [query | gate]; the
+        # attention's output times sigmoid(gate), in layers.out_project
+        q, gate = q[..., :cfg.head_dim_], q[..., cfg.head_dim_:]
     if cfg.qk_norm:  # per head, over the head dim, before the rotation
         q = layers.rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = layers.rms_norm(k, p["k_norm"], cfg.norm_eps)
@@ -215,10 +220,10 @@ def _attention(
                 "cannot honor sliding_window"
             )
         return _paged_attention(
-            q, k, v, p, layer_cache, layer, cache_index, call.kv_tables
+            q, k, v, p, layer_cache, layer, cache_index, call.kv_tables, gate
         )
     if kind == "plain":
-        return _plain_attention(q, k, v, p, cfg, call), None
+        return _plain_attention(q, k, v, p, cfg, call, gate), None
     if cfg.attn_impl in ("ring", "ulysses"):
         # Sequence-parallel cached generation (SURVEY §5.7): the KV cache is
         # split into a seq-sharded prefill region and a small replicated
@@ -232,7 +237,7 @@ def _attention(
         # repeated to the query heads or scored.
         t = x.shape[1]
         out = _self_attention(q, k, v, positions, cfg.model_window)
-        return layers.out_project(out, p), (
+        return layers.out_project(out, p, gate), (
             ck.at[:, :t].set(k.astype(ck.dtype)),
             cv.at[:, :t].set(v.astype(cv.dtype)))
     if kind == "decode":
@@ -265,7 +270,7 @@ def _attention(
             out = decode_attn.ragged_decode_attention(
                 q, ck, cv, cache_index + 1, window=cfg.model_window,
             )
-            return layers.out_project(out, p), (ck, cv)
+            return layers.out_project(out, p, gate), (ck, cv)
     else:
         ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, cache_index, 0, 0))
         cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, cache_index, 0, 0))
@@ -273,7 +278,7 @@ def _attention(
         out = _continuation_attention(
             q, ck, cv, positions, cache_index, cfg.model_window,
             key_positions)
-        return layers.out_project(out, p), (ck, cv)
+        return layers.out_project(out, p, gate), (ck, cv)
     # "masked", and a decode step the ragged kernel does not take.
     if cfg.model_window is not None:
         # Caller-supplied masks (continuous batching's per-row prefix
@@ -291,10 +296,11 @@ def _attention(
     k_full = layers.repeat_kv(ck.astype(q.dtype), cfg.q_per_kv)
     v_full = layers.repeat_kv(cv.astype(q.dtype), cfg.q_per_kv)
     out = layers.dot_product_attention(q, k_full, v_full, attn_mask)
-    return layers.out_project(out, p), (ck, cv)
+    return layers.out_project(out, p, gate), (ck, cv)
 
 
-def _plain_attention(q, k, v, p, cfg: ModelConfig, call: Call) -> jax.Array:
+def _plain_attention(q, k, v, p, cfg: ModelConfig, call: Call,
+                     gate=None) -> jax.Array:
     """Attention of a call without a cache, the input block over itself
     (q, k, v rotated; -> the projected output)."""
     positions, attn_mask = call.positions, call.attn_mask
@@ -339,7 +345,7 @@ def _plain_attention(q, k, v, p, cfg: ModelConfig, call: Call) -> jax.Array:
         k_full = layers.repeat_kv(k, cfg.q_per_kv)
         v_full = layers.repeat_kv(v, cfg.q_per_kv)
         out = layers.dot_product_attention(q, k_full, v_full, mask)
-    return layers.out_project(out, p)
+    return layers.out_project(out, p, gate)
 
 
 def _seq_cached_attention(
@@ -645,7 +651,7 @@ def _self_attention(q, k, v, positions, window: int | None = None,
         return _expanded_attention(
             q, layers.repeat_kv(k, g), layers.repeat_kv(v, g),
             layers.causal_mask(positions, positions, window=window), scale)
-    block = _flash_block(window)
+    block = _flash_block(window, q.shape[-1] * q.dtype.itemsize)
     return flash.flash_attention(
         q, k, v, causal=True, window=window, block_q=block, block_k=block,
         interpret=mode == "interpret", scale=scale, rows=rows)
@@ -736,11 +742,14 @@ def _flash_mode(head_dim: int) -> str | None:
 _CONTINUATION_BLOCKS = (1024, 512)
 
 
-def _flash_block(window: int | None) -> int:
+def _flash_block(window: int | None, row_bytes: int = 512) -> int:
     """The flash kernel's tile for a row's start.  A band of 128 inside
     tiles of 1,024 would score eight times the keys it needs: tiles of 512
-    for a windowed layer."""
-    return 1024 if window is None else 512
+    for a windowed layer.  And for a head whose row is over 512 bytes (256
+    wide in float32, a reference check's leg: tiles of 1,024 of q, k, v and
+    the scores are 22.9 MB of the kernel's 16; in bfloat16, as served, a
+    256-wide head's row is 512 bytes and keeps 1,024)."""
+    return 1024 if window is None and row_bytes <= 512 else 512
 
 
 def self_attention_pairs(cfg: ModelConfig, t: int, rows: int
@@ -764,7 +773,8 @@ def self_attention_pairs(cfg: ModelConfig, t: int, rows: int
         kinds.append((len(cfg.swa_layers), cfg.sliding_window))
     # (mixed_attention alone hands the kernel the count)
     real = rows if cfg.swa_layers else t
-    pairs = [(n, live_tiles(t, real, _flash_block(w), w)) for n, w in kinds]
+    block = lambda w: _flash_block(w, head * jnp.dtype(cfg.dtype).itemsize)
+    pairs = [(n, live_tiles(t, real, block(w), w)) for n, w in kinds]
     return (sum(n * p[0] for n, p in pairs), sum(n * p[1] for n, p in pairs))
 
 
@@ -950,7 +960,7 @@ def retention_counts(cfg: ModelConfig, call: Call, shape: tuple) -> jax.Array:
         held = jnp.where(lens > 0, call.cache_index + 1, 0)
         return jnp.stack([zero, zero, jnp.sum((lens > 0).astype(jnp.int32)),
                           jnp.sum(held, dtype=jnp.int32)])
-    return jnp.stack([jnp.sum(lens), jnp.sum(-(-lens // cfg.ret_chunk)),
+    return jnp.stack([jnp.sum(lens), jnp.sum(-(-lens // cfg.scan_chunk)),
                       zero, zero])
 
 
@@ -994,7 +1004,7 @@ def ssm_layer(
         )
     nh, hd, ng, ns = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
                       cfg.ssm_state)
-    inner, width, k = cfg.ssm_inner, cfg.ssm_conv_width, cfg.ssm_conv_kernel
+    inner, width = cfg.ssm_inner, cfg.ssm_conv_width
     with jax.named_scope("ssm_proj"):
         zxd = layers._contract(x, p["in_proj"], "btd,df->btf", 1, "n")
         z, xbc = zxd[..., :inner], zxd[..., inner:inner + width]
@@ -1002,18 +1012,11 @@ def ssm_layer(
                              + p["dt_bias"].astype(jnp.float32))
         a = -jnp.exp(p["A_log"].astype(jnp.float32))
     with jax.named_scope("ssm_conv"):
-        taps = p["taps"].astype(jnp.float32)  # [C, K]
-        state = (jnp.zeros((b, k - 1, width), xbc.dtype) if cache is None
-                 or not decode else cache.ssm_conv[layer].astype(xbc.dtype))
-        win = jnp.concatenate([state, xbc], axis=1)  # [B, T + K - 1, C]
-        xbc = jax.nn.silu(sum(
-            taps[:, j] * win[:, j: j + t].astype(jnp.float32)
-            for j in range(k)) + p["conv_bias"].astype(jnp.float32)
-        ).astype(x.dtype)
-        # the K - 1 inputs that end at the row's real tokens
-        new_taps = win[:, t:] if call.seq_lens is None else jax.vmap(
-            lambda w, n: jax.lax.dynamic_slice_in_dim(w, n, k - 1, axis=0)
-        )(win, call.seq_lens)
+        xbc, new_taps = layers.causal_conv(
+            xbc, p["taps"], cache.ssm_conv[layer] if decode else None,
+            call.seq_lens)
+        xbc = jax.nn.silu(
+            xbc + p["conv_bias"].astype(jnp.float32)).astype(x.dtype)
     xs = xbc[..., :inner].reshape(b, t, nh, hd)
     bm = xbc[..., inner:inner + ng * ns].reshape(b, t, ng, ns)
     cm = xbc[..., inner + ng * ns:].reshape(b, t, ng, ns)
@@ -1052,18 +1055,110 @@ def ssm_layer(
     return layers._contract(y, p["out_proj"], "btf,fd->btd", 1, "k"), cache
 
 
+def gdn_layer(
+    x: jax.Array,  # [B, T, D], normed
+    p: Params,  # w_qkvz, w_ba, taps, dt_bias, A_log, norm_w, out_proj
+    cfg: ModelConfig,
+    call: Call,
+    cache: kv_cache.HybridCache | None,  # the slots' states and taps (a
+    #   decode step) or a fresh row's (an admission); None: nothing is kept
+    layer: jax.Array | int,  # index among the delta-rule layers
+) -> tuple[jax.Array, kv_cache.HybridCache | None]:
+    """Gated DeltaNet (layer kind "gdn"; ops/gdn.py has the scan's
+    equations).  ``[q | k | v | z] = x W_qkvz`` and ``[b | a] = x W_ba``, in
+    that order; ``[q | k | v] <- silu(causal depthwise conv_K([q | k | v]))``,
+    no bias; ``q`` and ``k`` L2-normalised a head (eps 1e-6), ``q`` times
+    ``key_dim^-0.5`` (inside the scan's operators); ``beta = sigmoid(b)`` and ``g = -exp(A_log) softplus(a +
+    dt_bias)`` in float32; the scan; then an RMS norm over each head's values
+    times ``norm_w`` FIRST and the gate ``silu(z)`` after it (the other way
+    round from :func:`ssm_layer`); ``o W_out``.  The kinds of call are
+    :func:`ssm_layer`'s: a decode step is one recurrence step against the
+    slots' states, updated where they lie, and moves the taps by one (a row
+    that does not decode keeps both); any other call is a row's start and
+    runs the chunked scan from an empty state, ``cfg.gdn_chunk`` tokens a
+    chunk, leaving state and taps at the ``call.seq_lens`` REAL tokens."""
+    from ..ops import gdn
+
+    if call.kind in ("continuation", "masked"):
+        raise ValueError(
+            "a delta-rule layer prefills a row from its start (cache_index "
+            "0, no mask and no map of the caller's): the state holds no "
+            "prefix to continue from"
+        )
+    decode = call.kind == "decode"
+    b, t, _ = x.shape
+    if decode and (t != 1 or cache is None):
+        raise ValueError(
+            "a delta-rule layer decodes one token a row against the slots' "
+            "states"
+        )
+    hk, hv, dk, dv = (cfg.gdn_key_heads, cfg.gdn_value_heads,
+                      cfg.gdn_key_dim, cfg.gdn_value_dim)
+    kw, width = cfg.gdn_key_width, cfg.gdn_conv_width
+    f32 = jnp.float32
+    with jax.named_scope("gdn_proj"):
+        qkvz = layers._contract(x, p["w_qkvz"], "btd,df->btf", 1, "n")
+        qkv, z = qkvz[..., :width], qkvz[..., width:]
+        ba = jnp.einsum("btd,dh->bth", x, p["w_ba"].astype(x.dtype),
+                        preferred_element_type=f32)
+        beta = jax.nn.sigmoid(ba[..., :hv])
+        g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
+            ba[..., hv:] + p["dt_bias"].astype(f32))
+    with jax.named_scope("gdn_conv"):
+        qkv, new_taps = layers.causal_conv(
+            qkv, p["taps"], cache.gdn_conv[layer] if decode else None,
+            call.seq_lens)
+        qkv = jax.nn.silu(qkv).astype(x.dtype)
+        # (q and k go to the scan as they are: ops/gdn.py normalises them)
+        q = qkv[..., :kw].reshape(b, t, hk, dk)
+        k = qkv[..., kw:2 * kw].reshape(b, t, hk, dk)
+        v = qkv[..., 2 * kw:].reshape(b, t, hv, dv)
+    with jax.named_scope("gdn_scan"):
+        if decode:
+            live = None if call.seq_lens is None else call.seq_lens > 0
+            o, gdn_s = gdn.gdn_decode(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], cache.gdn_s,
+                layer, live)
+            o = o[:, None]
+            cache = dataclasses.replace(
+                cache, gdn_s=gdn_s, gdn_conv=cache.gdn_conv.at[layer].set(
+                    new_taps.astype(cache.gdn_conv.dtype)))
+        else:
+            rows = [gdn.gdn_prefill(
+                q[i], k[i], v[i], g[i], beta[i],
+                None if call.seq_lens is None else call.seq_lens[i],
+                cfg.gdn_chunk) for i in range(b)]
+            o = jnp.stack([r[0] for r in rows])
+            if cache is not None:
+                cache = dataclasses.replace(
+                    cache,
+                    gdn_s=cache.gdn_s.at[layer].set(
+                        jnp.stack([r[1] for r in rows])),
+                    gdn_conv=cache.gdn_conv.at[layer].set(
+                        new_taps.astype(cache.gdn_conv.dtype)))
+    with jax.named_scope("gdn_gate"):
+        o = o.astype(f32)
+        o = o * jax.lax.rsqrt(
+            jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps)
+        o = (o * p["norm_w"].astype(f32)
+             * jax.nn.silu(z.astype(f32).reshape(b, t, hv, dv)))
+        o = o.reshape(b, t, hv * dv).astype(x.dtype)
+    return layers._contract(o, p["out_proj"], "btf,fd->btd", 1, "k"), cache
+
+
 def ssm_counts(cfg: ModelConfig, call: Call, shape: tuple) -> jax.Array:
-    """What a pass did in ONE state-space layer, int32 [3], a by-product
-    beside the expert layers' counts (:func:`run_layers`' third value): the
-    real tokens an admission scanned and the chunks it walked (those that
-    hold a real token), the rows a decode step advanced."""
+    """What a pass did in ONE state-space layer (or ONE delta-rule layer),
+    int32 [3], a by-product beside the expert layers' counts
+    (:func:`run_layers`' third value): the real tokens an admission scanned
+    and the chunks it walked (those that hold a real token), the rows a
+    decode step advanced."""
     b, t = shape
     lens = (jnp.full((b,), t, jnp.int32) if call.seq_lens is None
             else call.seq_lens.astype(jnp.int32))
     zero = jnp.zeros((), jnp.int32)
     if call.kind == "decode":
         return jnp.stack([zero, zero, jnp.sum((lens > 0).astype(jnp.int32))])
-    return jnp.stack([jnp.sum(lens), jnp.sum(-(-lens // cfg.ssm_chunk)),
+    return jnp.stack([jnp.sum(lens), jnp.sum(-(-lens // cfg.scan_chunk)),
                       zero])
 
 
@@ -1300,6 +1395,8 @@ def run_layers(
             out, cache = retention_layer(h, p, cfg, call, cache, at[op])
         elif op == "ssm":
             out, cache = ssm_layer(h, p, cfg, call, cache, at[op])
+        elif op == "gdn":
+            out, cache = gdn_layer(h, p, cfg, call, cache, at[op])
         elif cfg.swa_layers:  # windowed and full attention layers mixed
             with jax.named_scope("swa_attn" if op == "swa" else "full_attn"):
                 out, cache = mixed_attention(
@@ -1350,9 +1447,16 @@ def run_layers(
             if cfg.n_shared_experts:
                 shared = layer_of(blocks["moe"]["shared"], at[ffn], rows)
                 with jax.named_scope("shared_expert"):
-                    x = x + (layers.mlp_plain(h, shared, cfg.gate_act)
-                             if cfg.moe_latent_size
-                             else layers.mlp_swiglu(h, shared, cfg.gate_act))
+                    y = (layers.mlp_plain(h, shared, cfg.gate_act)
+                         if cfg.moe_latent_size
+                         else layers.mlp_swiglu(h, shared, cfg.gate_act))
+                    if cfg.moe_shared_gate:  # one scalar a token
+                        y = (y * jax.nn.sigmoid(jnp.einsum(
+                            "btd,d->bt", h,
+                            blocks["moe"]["shared_gate"][at[ffn]].astype(
+                                h.dtype), preferred_element_type=jnp.float32
+                        ))[..., None]).astype(y.dtype)
+                    x = x + y
             return x, stats
 
         b, t, d = x.shape
@@ -1379,7 +1483,8 @@ def run_layers(
                 moe + jnp.sum(stats, axis=0))
 
     carry = (x, cache, moe)
-    base = dict(conv=0, attn=0, swa=0, mla=0, ret=0, ssm=0, dense=0, moe=0)
+    base = dict(conv=0, attn=0, swa=0, mla=0, ret=0, ssm=0, gdn=0, dense=0,
+                moe=0)
     for unit, reps in layer_runs(cfg):
         kinds = [kind for pair in unit for kind in pair if kind]
         per_unit = {kind: kinds.count(kind) for kind in base}
@@ -1401,7 +1506,8 @@ def run_layers(
                 run, carry, jnp.arange(reps, dtype=jnp.int32))
         for kind in base:
             base[kind] += reps * per_unit[kind]
-    if cfg.ssm_layers:  # a state-space layer's counts ride behind the experts'
+    if cfg.ssm_layers or cfg.gdn_layers:  # (a scan's counts ride behind
+        # the experts')
         x, cache, moe = carry
         carry = (x, cache, jnp.concatenate(
             [moe, ssm_counts(cfg, call, shape)]))
@@ -1413,7 +1519,8 @@ def hybrid_layers(params: Params, cfg: ModelConfig):
     order: dicts {"ln1", "ln2": {"scale"}, "conv" | "attn": {...}, "mlp":
     {...}} as models/reference/lfm2_moe.py reads them.  A generator, so a
     caller that dequantizes what it is handed holds one layer in float32."""
-    at = dict(conv=0, attn=0, swa=0, mla=0, ret=0, ssm=0, dense=0, moe=0)
+    at = dict(conv=0, attn=0, swa=0, mla=0, ret=0, ssm=0, gdn=0, dense=0,
+              moe=0)
     blocks = params["blocks"]
 
     def take(kind):
@@ -1725,7 +1832,9 @@ def _init_hybrid_blocks(rng: jax.Array, cfg: ModelConfig, dtype: Any) -> Params:
             # [D, H * hd], the head axes flat: quantized with their own
             # axis last, heads of 64 would get absmax blocks of 64, which
             # the fused kernel cannot tile (layers.qkv_project unflattens).
-            "wq": dense(f"{kind}/wq", (n, D, H * HD)),
+            # (a gated attention layer's: [query | gate] a head)
+            "wq": dense(f"{kind}/wq", (n, D, H * HD * (
+                2 if kind == "attn" and cfg.attn_out_gate else 1))),
             "wk": dense(f"{kind}/wk", (n, D, KVH * HD)),
             "wv": dense(f"{kind}/wv", (n, D, KVH * HD)),
             "wo": dense(f"{kind}/wo", (n, H, HD, D)),
@@ -1751,6 +1860,22 @@ def _init_hybrid_blocks(rng: jax.Array, cfg: ModelConfig, dtype: Any) -> Params:
                 leaf, (NS, W if leaf == "conv_bias" else NH),
                 dtype if leaf == "conv_bias" else jnp.float32)
                for leaf in SSM_LEAVES},
+        }
+    if cfg.gdn_layers:
+        NG, W = len(cfg.gdn_layers), cfg.gdn_conv_width
+        VW, HV = cfg.gdn_value_width, cfg.gdn_value_heads
+        blocks["gdn"] = {
+            **norms(NG),
+            # [q | k | v | z] and [b | a], in that order, each flat by head
+            "w_qkvz": dense("gdn/w_qkvz", (NG, D, W + VW)),
+            "w_ba": dense("gdn/w_ba", (NG, D, 2 * HV)),
+            "taps": dense("gdn/taps", (NG, W, cfg.gdn_conv_kernel)),
+            "out_proj": dense("gdn/out_proj", (NG, VW, D)),
+            "norm_w": jnp.ones((NG, cfg.gdn_value_dim), dtype),
+            **{leaf: ssm_leaf(
+                jax.random.fold_in(rng, zlib.crc32(f"blocks/gdn/{leaf}".encode())),
+                leaf, (NG, HV), jnp.float32)
+               for leaf in ("A_log", "dt_bias")},
         }
     if NM:
         EH = cfg.held_experts  # (a chip's share; the router scores all E)
@@ -1780,6 +1905,8 @@ def _init_hybrid_blocks(rng: jax.Array, cfg: ModelConfig, dtype: Any) -> Params:
                 "w_up": dense("moe/shared/w_up", (NM, D, FS)),
                 "w_down": dense("moe/shared/w_down", (NM, FS, D)),
             }
+        if cfg.moe_shared_gate:  # w_s: a scalar a token, in the model's dtype
+            blocks["moe"]["shared_gate"] = dense("moe/shared_gate", (NM, D))
         if cfg.moe_expert_bias:
             key = jax.random.fold_in(
                 rng, zlib.crc32(b"blocks/moe/expert_bias"))
@@ -1858,7 +1985,8 @@ def init_params_quantized(
         def gen():
             if leaf in ("scale", "q_norm", "k_norm", "kv_norm", "norm_w"):
                 return jnp.ones(sd.shape, sd.dtype)
-            if leaf in SSM_LEAVES and name.startswith("blocks/ssm/"):
+            if leaf in SSM_LEAVES and name.startswith(
+                    ("blocks/ssm/", "blocks/gdn/")):
                 return ssm_leaf(key, leaf, sd.shape, sd.dtype)
             if leaf == "expert_bias":  # drawn, so that it changes the choice
                 return 0.1 * jax.random.normal(key, sd.shape, sd.dtype)

@@ -240,7 +240,42 @@ PRESETS: dict[str, ModelConfig] = {
         experts_held=128, experts_offset=0, ssm_heads=128, ssm_head_dim=64,
         ssm_groups=8, ssm_state=128, ssm_conv_kernel=4, ssm_chunk=128,
     ),
+    # Qwen3-Next-80B-A3B-Instruct (model_type qwen3_next) as ONE CHIP'S SHARE
+    # of a four-chip pipeline stage: published layers 0-11, three periods of
+    # (3 Gated DeltaNet, 1 gated attention), every one with the expert layer
+    # behind it; every width; experts 0-127 of the 512 (the router keeps 512
+    # outputs and 10 a token) and the gated shared expert; a quarter of the
+    # vocabulary (37,984 rows); the multi-token-prediction layer is not
+    # served: benchmark/configs/qwen3-next-int8-ep4.json has the deployment.
+    "qwen3-next-ep4": ModelConfig(
+        family="hybrid", vocab_size=37984, hidden_size=2048,
+        intermediate_size=5120, moe_intermediate_size=512, num_layers=12,
+        num_heads=16, num_kv_heads=2, head_dim=256, max_seq_len=262144,
+        rope_theta=10000000.0, rotary_pct=0.25, norm_eps=1e-6,
+        tie_embeddings=False, qk_norm=True, attn_out_gate=True,
+        layer_types=("gdn", "gdn", "gdn", "attn") * 3,
+        num_experts=512, num_experts_per_token=10, moe_score_fn="softmax",
+        moe_capacity=False, n_shared_experts=1,
+        moe_shared_intermediate_size=512, moe_shared_gate=True,
+        experts_held=128, experts_offset=0, gdn_key_heads=16,
+        gdn_value_heads=32, gdn_key_dim=128, gdn_value_dim=128,
+        gdn_conv_kernel=4, gdn_chunk=64,
+    ),
     # Tiny configs for unit tests / CPU fake-mesh integration tests.
+    "qwen3-next-tiny": ModelConfig(
+        family="hybrid", vocab_size=256, hidden_size=256,
+        intermediate_size=128, moe_intermediate_size=128, num_layers=8,
+        num_heads=4, num_kv_heads=2, head_dim=64, max_seq_len=1024,
+        rope_theta=10000.0, rotary_pct=0.25, norm_eps=1e-6,
+        tie_embeddings=False, dtype="float32", qk_norm=True,
+        attn_out_gate=True, layer_types=("gdn", "gdn", "gdn", "attn") * 2,
+        num_experts=32, num_experts_per_token=4, moe_score_fn="softmax",
+        moe_capacity=False, n_shared_experts=1,
+        moe_shared_intermediate_size=128, moe_shared_gate=True,
+        experts_held=8, experts_offset=0, gdn_key_heads=2,
+        gdn_value_heads=4, gdn_key_dim=128, gdn_value_dim=128,
+        gdn_conv_kernel=4, gdn_chunk=64,
+    ),
     "nemotron3-super-tiny": ModelConfig(
         family="hybrid", vocab_size=256, hidden_size=256,
         intermediate_size=128, moe_intermediate_size=96, num_heads=4,

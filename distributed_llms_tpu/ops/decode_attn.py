@@ -920,7 +920,15 @@ def pool_head_shape(kvh: int, d: int, fold_narrow: bool) -> tuple[int, int]:
     [.., KVH / fold, fold * D]: a [.., 8, 64] array is tiled (8, 128), half
     of it padding, and handing it to the 128-lane kernel reshaped would
     copy the whole pool into the other layout in every layer.  The bytes
-    and their order are those of [.., KVH, D]."""
+    and their order are those of [.., KVH, D].  Heads WIDER than a row
+    (256) fold too, all KVH of them into one row of KVH * D lanes: the
+    device tiles [.., KVH, 256] a (KVH, 128) lane group at a time, the
+    kernel's rows (token, head) lie in another order, and the reshape would
+    be the same copy of the pool (2 GB a layer a step at 5,184 pages:
+    tools/aot_decode.py's ``temp_gb``); [.., 1, KVH * 256] is tiled over
+    (BLK, KVH * 256) and goes in as it lies."""
+    if fold_narrow and d > 128 and d % 128 == 0:
+        return 1, kvh * d
     fold = 128 // d if fold_narrow and d < 128 and 128 % d == 0 else 1
     return (kvh // fold, d * fold) if kvh % fold == 0 else (kvh, d)
 

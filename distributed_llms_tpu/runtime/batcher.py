@@ -1908,6 +1908,9 @@ class ContinuousBatcher:
             if "ssm_state" in sizes:
                 METRICS.set_gauge("batcher.ssm_state_bytes",
                                   sizes["ssm_state"])
+            if "gdn_state" in sizes:
+                METRICS.set_gauge("batcher.gdn_state_bytes",
+                                  sizes["gdn_state"])
             if "window_state" in sizes:
                 METRICS.set_gauge("batcher.window_state_bytes",
                                   sizes["window_state"])
@@ -3525,10 +3528,8 @@ class ContinuousBatcher:
                             self.cfg, behind, self.s, held + behind))
                     prev = self._admit_inflight
                     ahead = {} if prev is None else {"fetched_rid": prev.req.rid}
-                    if self.cfg.ret_layers:  # the chunks the scan walks
-                        ahead["chunks"] = -(-total_len // self.cfg.ret_chunk)
-                    if self.cfg.ssm_layers:
-                        ahead["chunks"] = -(-total_len // self.cfg.ssm_chunk)
+                    if self.cfg.scan_chunk:  # the chunks the scan walks
+                        ahead["chunks"] = -(-total_len // self.cfg.scan_chunk)
                     with self._span(
                         "batcher.admit.row", rid=req.rid,
                         prompt_tokens=total_len, cached_tokens=cached_len,
@@ -4708,15 +4709,20 @@ class ContinuousBatcher:
             METRICS.inc("ret.decode.row_steps", counts[2])
             METRICS.inc("ret.decode.resident_tokens", counts[3])
             return
-        if self.cfg.ssm_layers:
-            # A model of state-space layers hands out three more behind its
-            # experts' five (models.model.ssm_counts, a layer's): an
-            # admission's real tokens and the chunks that hold one, a
+        if self.cfg.ssm_layers or self.cfg.gdn_layers:
+            # A model of state-space (or delta-rule) layers hands out three
+            # more behind its experts' (models.model.ssm_counts, a layer's):
+            # an admission's real tokens and the chunks that hold one, a
             # decode chunk's row-steps.
             *counts, tokens, chunks, row_steps = counts
-            METRICS.inc("ssm.admit.tokens", tokens)
-            METRICS.inc("ssm.admit.chunks", chunks)
-            METRICS.inc("ssm.decode.row_steps", row_steps)
+            if self.cfg.ssm_layers:
+                METRICS.inc("ssm.admit.tokens", tokens)
+                METRICS.inc("ssm.admit.chunks", chunks)
+                METRICS.inc("ssm.decode.row_steps", row_steps)
+            else:
+                METRICS.inc("gdn.admit.tokens", tokens)
+                METRICS.inc("gdn.admit.chunks", chunks)
+                METRICS.inc("gdn.decode.row_steps", row_steps)
         METRICS.inc("moe.routed_pairs", counts[0])
         METRICS.inc("moe.layer_passes", counts[1])
         METRICS.inc("moe.experts_touched", counts[2])

@@ -63,7 +63,8 @@ def test_padded_prefill_then_decode_is_the_reference(tiny, n, bucket):
     # the counts are a by-product (forward's aux), no leaf of the cache.
     assert [int(x) for x in stats[:2]] == [n * 2 * 6, 6]
     assert set(vars(cache)) == {"k", "v", "conv", "ring_k", "ring_v",
-                                "ret_s", "ret_z", "ssm_h", "ssm_conv"}
+                                "ret_s", "ret_z", "ssm_h", "ssm_conv",
+                                "gdn_s", "gdn_conv"}
     assert cache.ret_s is None  # (a retention layer's state: none here)
     assert cache.ssm_h is None  # (a state-space layer's: none here)
     assert cache.ring_k is None  # (the windowed layers' rings: none here)

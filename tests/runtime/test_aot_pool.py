@@ -406,6 +406,78 @@ def test_an_admission_writes_one_rows_state_into_its_slot(
 
 
 @pytest.fixture(scope="module")
+def compiled_delta_state_beside_pool():
+    """``decode_chunk`` and ``admit_row_paged`` at the 8,192 bucket of
+    qwen3-next-ep4 at the cell's shapes (64 slots, 5,184 pages of the 3
+    gated attention layers' 256-wide heads, the 9 delta-rule layers' float32
+    states beside them; some 80 s to lower and compile the two)."""
+    from distributed_llms_tpu.models.presets import get_preset
+    from tools import aot_decode
+
+    try:
+        aot_decode.v5e_devices()
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    shapes = dict(slots=64, max_len=16384, pages=5184, page_size=BLK)
+    cfg = get_preset("qwen3-next-ep4")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("DLT_QUANT_MATMUL", "kernel")
+        mp.setenv("DLT_RAGGED_DECODE", "kernel")
+        mp.setenv("DLT_MOE_EXPERTS", "kernel")
+        return {
+            "decode_chunk": aot_decode.analyse("decode_chunk", cfg, **shapes),
+            8192: aot_decode.analyse(
+                "admit_row_paged", cfg, prompt_len=8192, **shapes)}
+
+
+DELTA_STATES = "[9,64,32,128,128]"  # every layer's and slot's, 1.21 GB
+
+
+def test_the_delta_states_are_updated_where_they_lie_and_no_pool_is_copied(
+        compiled_delta_state_beside_pool):
+    """A decode step: nothing but the kernel ``gdn_decode`` produces an array
+    shaped like the stack of states; no expert stack ([12,128,2048,1024],
+    [12,128,512,2048]) and no layer of another int8 weight is copied;
+    weights (5.78 GB), states and taps (1.24) and pool (2.04) are all the
+    program is given.  The pool keeps both 256-wide heads in ONE row of 512
+    lanes (ops.decode_attn.pool_head_shape): as [.., 2, 256] the paged
+    kernel's view of it was a copy of the pool a layer a step, 2.06 GB of
+    temporaries where there are 0.005."""
+    decode = compiled_delta_state_beside_pool["decode_chunk"]
+    states = decode["state_shaped"]
+    assert states and {e[0] for e in states} == {"custom-call"}
+    assert all("gdn_decode" in e[1] and DELTA_STATES in e[2] for e in states)
+    assert decode["expert_shaped"] == []
+    assert decode["weight_shaped"] == []
+    assert 9.0 < decode["argument_gb"] < 9.1
+    assert decode["alias_gb"] * 1e9 >= 1_236_271_104 + 5184 * 393_216
+    assert decode["temp_gb"] < 0.1
+    assert {"gdn_decode", "moe_experts", "paged_decode_attn"} <= _kernels(
+        decode["hlo"])
+
+
+def test_an_8192_admission_solves_its_triangles_in_a_gigabyte_and_a_half(
+        compiled_delta_state_beside_pool):
+    """The stack of states takes one dynamic-update-slice of the row's slot
+    and no instruction holds a layer's 64 slots; the pool is written a page
+    at a time; no expert stack is copied; 1.50 GB of temporaries beside 9.06
+    GB of arguments."""
+    admit = compiled_delta_state_beside_pool[8192]
+    whole = [e for e in admit["state_shaped"] if DELTA_STATES in e[2]]
+    assert whole and {e[0] for e in whole} <= {
+        "dynamic-update-slice", "fusion:dynamic-update-slice"}
+    assert not [e for e in admit["state_shaped"]
+                if "[64,32,128,128]" in e[2]]
+    assert admit["expert_shaped"] == []
+    assert admit["weight_shaped"] == []
+    assert {e[0] for e in admit["pool_shaped"]} <= {
+        "dynamic-update-slice", "fusion:dynamic-update-slice"}
+    assert admit["temp_gb"] < 1.7
+    assert {"gdn_prefill", "moe_experts", "flash_attn"} <= _kernels(
+        admit["hlo"])
+
+
+@pytest.fixture(scope="module")
 def compiled_blocked_rings():
     """``decode_chunk`` and ``admit_row_paged`` at the 16,384 bucket of
     smallthinker-pp4 at its 12 layers and the cell's shapes (32 slots,

@@ -251,9 +251,16 @@ def state_shaped(hlo_text: str, cfg, slots: int) -> list:
     scans' carry: only the kernel that updates it where it lies
     (``custom-call``) and an admission's write of one row into its slot
     may be on the list.  The same for a model of Mamba-2 layers, whose
-    states ([ssm layers, slots, R, N, 128]) lie beside a pool.  Empty for a
-    model without either."""
-    if cfg.ssm_layers:
+    states ([ssm layers, slots, R, N, 128]) lie beside a pool, and of gated
+    delta-rule layers ([gdn layers, slots, HV, dk, dv]).  Empty for a model
+    without any."""
+    if cfg.gdn_layers:
+        from distributed_llms_tpu.ops.gdn import state_shape
+
+        layers = len(cfg.gdn_layers)
+        row = ",".join(str(n) for n in state_shape(
+            cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim))
+    elif cfg.ssm_layers:
         from distributed_llms_tpu.ops.ssm import state_shape
 
         layers = len(cfg.ssm_layers)

@@ -510,7 +510,7 @@ def flash_band_parity(t: int, h: int, kvh: int, win: int) -> None:
 
 
 def flash_cut_parity(t: int, real: int, h: int, kvh: int, win: int | None,
-                     block: int) -> None:
+                     block: int, d: int = 128) -> None:
     """The flash kernel told an admission's count of real tokens
     (``rows``): its grid ends at the last tile of queries that holds one.
     Against the call without the count, which the legs above hold to the
@@ -519,7 +519,7 @@ def flash_cut_parity(t: int, real: int, h: int, kvh: int, win: int | None,
     ks = jax.random.split(jax.random.PRNGKey(19), 3)
     live = -(-real // block) * block
     q, kk, v = (
-        jax.random.normal(key, (1, t, heads, 128), jnp.bfloat16)
+        jax.random.normal(key, (1, t, heads, d), jnp.bfloat16)
         for key, heads in zip(ks, (h, kvh, kvh)))
     run = jax.jit(lambda q, k, v, rows=None: flash_attention(
         q, k, v, causal=True, window=win, block_q=block, block_k=block,
@@ -530,7 +530,7 @@ def flash_cut_parity(t: int, real: int, h: int, kvh: int, win: int | None,
     if np.asarray(got[:, live:], np.float32).any():
         raise AssertionError("a tile of queries past the real tokens is not "
                              "zeros")
-    check(f"flash T{t} real{real} H{h}/{kvh} win{win} tiles{block}",
+    check(f"flash T{t} real{real} H{h}/{kvh} D{d} win{win} tiles{block}",
           got[:, :live], want[:, :live], rtol=0, atol=0)
 
 
@@ -667,6 +667,53 @@ def ssm_parity(t: int, real: int, h: int, g: int, slots: int,
     check(f"{tag} decode step, last slot", got[slots - 1], want_y[n], 0, 1e-5)
     check(f"{tag} state after the step", S.from_layout(states[1, 1], 64),
           want_s, 0, 1e-5)
+    assert not np.asarray(states[0]).any(), "layer 0's states were written"
+    assert not np.asarray(states[1, 0]).any(), "a row that did not decode moved"
+
+
+def gdn_parity(t: int, real: int, hk: int, hv: int, slots: int,
+               dtype=jnp.float32) -> None:
+    """Gated DeltaNet's two kernels (ops/gdn.py) against the recurrence token
+    by token: an admission of ``real`` tokens in a bucket of ``t`` (chunks of
+    64, a triangle solved in each; the chunks of padding are not walked), the
+    state it leaves, then one recurrence step of every batch slot in layer 1
+    of a stack of two, the slots' states where they lie.  ``hk`` key heads
+    and ``hv`` value heads of 128 x 128; q and k raw silu outputs (the
+    operators normalise them; any two keys share a part), beta a sigmoid, the
+    decay as
+    ``models.model.ssm_leaf`` draws it."""
+    from distributed_llms_tpu.ops import gdn as G
+
+    ks = jax.random.split(jax.random.key(59), 7)
+    q = jax.nn.silu(jax.random.normal(ks[0], (t, hk, 128))).astype(dtype)
+    kk = jax.nn.silu(jax.random.normal(ks[1], (t, hk, 128))).astype(dtype)
+    v = jax.random.normal(ks[2], (t, hv, 128), dtype)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[3], (t, hv)))
+    g = -jax.random.uniform(ks[4], (hv,), minval=1.0, maxval=16.0) * jnp.exp(
+        jax.random.uniform(ks[5], (t, hv), minval=np.log(1e-3),
+                           maxval=np.log(0.1)))
+    x = (q, kk, v, g, beta)
+    n = real - 1  # the admission; token n is the decode step's
+    loose = dtype != jnp.float32
+    with jax.default_matmul_precision("highest"):
+        o, s = jax.jit(G.gdn_prefill)(*x, jnp.int32(n))
+        want_o, want_s = jax.jit(G.recurrence)(*(a[:real] for a in x))
+        _, at_n = jax.jit(G.recurrence)(*(a[:n] for a in x))
+    tag = f"gdn {jnp.dtype(dtype).name} {real}/{t} h{hk}/{hv}"
+    rt, at = (2e-2, 2e-2) if loose else (1e-4, 1e-5)
+    check(f"{tag} admission", o[:n].astype(jnp.float32), want_o[:n], rt, at)
+    check(f"{tag} state", s, at_n, 0, 1e-4)
+    # one step for every slot, the row above in slot 1 and in the last one
+    states = jnp.zeros((2, slots, *s.shape), jnp.float32)
+    for b in (1, slots - 1):
+        states = states.at[1, b].set(at_n)
+    rows = [jnp.broadcast_to(a[n], (slots, *a.shape[1:])) for a in x]
+    live = jnp.ones((slots,), bool).at[0].set(False)
+    step = jax.jit(G.gdn_decode, donate_argnums=(5,))
+    got, states = step(*rows, states, jnp.int32(1), live)
+    check(f"{tag} decode step", got[1], want_o[n], 0, 1e-5)
+    check(f"{tag} decode step, last slot", got[slots - 1], want_o[n], 0, 1e-5)
+    check(f"{tag} state after the step", states[1, 1], want_s, 0, 1e-4)
     assert not np.asarray(states[0]).any(), "layer 0's states were written"
     assert not np.asarray(states[1, 0]).any(), "a row that did not decode moved"
 
@@ -885,6 +932,28 @@ def main() -> int:
     else:
         moe_parity(e=8, d=256, f=384, k=3, of_experts=32, act="relu2",
                    gated=False)
+    # Gated DeltaNet (Qwen3-Next: 16 key heads and 32 value heads of 128 x
+    # 128): an admission of the 2,048 bucket with a mean prompt in float32
+    # and in bfloat16, the smallest bucket (padded to one grid step of 128),
+    # and the 64 slots' step; its 128 of 512 gated experts of [2048 x 1024]
+    # and [512 x 2048], 10 a token; its attention's heads of 256, 16 over 2,
+    # both in ONE pool row of 512 lanes, on rows 1 to 151 pages deep; the
+    # flash kernel at heads of 256.
+    for leg in (((2048, 1500, 16, 32, 64), (64, 33, 16, 32, 64),
+                 (2048, 1500, 16, 32, 64, jnp.bfloat16)) if ON_TPU else
+                ((300, 260, 2, 4, 3), (8, 5, 2, 4, 3))):
+        gdn_parity(*leg)
+    if ON_TPU:
+        moe_parity(e=128, d=2048, f=512, k=10, of_experts=512)
+        deep = 64 * 64
+        paged_parity(blk=64, h=16, kvh=2, d=256, layer=2, rows=(64, [
+            1, 64, deep * 94 // 128 - 17, deep - 63, deep, 64]))
+        flash_cut_parity(8192, 5690, 16, 2, None, 1024, d=256)
+    else:
+        moe_parity(e=8, d=256, f=128, k=4, of_experts=32)
+        paged_parity(blk=64, h=4, kvh=2, d=256, layer=2, rows=(12, [
+            1, 64, 12 * 64 * 94 // 128 - 17, 12 * 64 - 63, 12 * 64, 64]))
+        flash_cut_parity(1024, 300, 4, 2, None, 256, d=256)
     # The combine's operand at A.X-K1's and K-EXAONE's 2,048-token blocks
     # and at A.X-K1's 512 (the smallest list that takes the kernel), and at
     # nemotron's k = 22 (which keeps the gather in the served model).
@@ -944,8 +1013,12 @@ def main() -> int:
     # non-gated leg holding 128 of 512 on the latent — 72 legs.  v18: the
     # combine's operand fetched by the pairs that hold a row (moe_combine)
     # against the gather, bit for bit, at A.X-K1's, K-EXAONE's and
-    # nemotron's blocks — 76 legs.
-    print(f"kernel_parity: ALL PASS v18 ({mode}, backend={backend})")
+    # nemotron's blocks — 76 legs.  v19: Gated DeltaNet's chunked scan (a
+    # triangle a chunk) and recurrence step at Qwen3-Next's heads, float32
+    # and bfloat16, the smallest bucket; its 128 of 512 experts of 3.1 M;
+    # the paged kernel at heads of 256 in one pool row of 512 lanes; the
+    # flash kernel at heads of 256 — 82 legs.
+    print(f"kernel_parity: ALL PASS v19 ({mode}, backend={backend})")
     return 0
 
 
