@@ -803,6 +803,19 @@ METRIC_DOCS: dict[str, str] = {
                                            "last trace worked it out from "
                                            "the pool's shapes (a gauge; "
                                            "ops/decode_attn._run_pages)",
+    "ops.gdn_prefill.bf16_operands": "of gdn_prefill's traces (whatever "
+                                     "the path), those handed q and k in "
+                                     "bfloat16: K K^T and Q K^T are ONE MXU "
+                                     "pass over the raw rows, [K ; Q] S and "
+                                     "the state's update three (a float32 "
+                                     "operand's bfloat16 pieces), where "
+                                     "float32 rows take HIGHEST's six; a "
+                                     "served model's admissions all count",
+    "ops.gdn_prefill.paired_heads": "of gdn_prefill's traces, those whose "
+                                    "key heads carry an even count of value "
+                                    "heads: a chunk's triangles are "
+                                    "inverted two side by side in 128 "
+                                    "lanes, ten matmuls for both",
     "ops.dispatch.*.shard_map": "of those, dispatches traced inside the "
                                 "per-shard shard_map body of a "
                                 "tensor-parallel mesh (the kernel then "

@@ -144,14 +144,17 @@ def scan_inputs(t, hk=2, hv=4, d=128, seed=0, beta=None, g=None, agree=0.0):
 
 # -- (a) the identity the design rests on ---------------------------------
 
-@pytest.mark.parametrize("t,n,hard", [
+CASES = [
     (100, None, False),  # ends inside its second chunk of 64
     (128, None, False),  # ends on a chunk's edge
     (400, None, False),  # crosses six chunk boundaries
     (512, 300, False),   # 212 padded positions behind 300 real ones
     (256, None, True),   # beta 0.999 and g -1e-4: the triangle at its worst
     (256, 130, True),    # the same, ending 2 tokens past a chunk's edge
-])
+]
+
+
+@pytest.mark.parametrize("t,n,hard", CASES)
 def test_the_chunked_form_is_the_recurrence(t, n, hard):
     x = scan_inputs(t, seed=t, **(dict(beta=0.999, g=-1e-4) if hard else {}))
     o, s = gdn.gdn_prefill(*x, None if n is None else jnp.int32(n), chunk=64)
@@ -162,6 +165,128 @@ def test_the_chunked_form_is_the_recurrence(t, n, hard):
     np.testing.assert_allclose(s, want_s, atol=3e-5)
     assert np.abs(np.asarray(want_o)).max() > 0.05
     assert np.abs(np.asarray(want_s)).max() > 0.5
+
+
+def bfloat16_rows(x):
+    """The same inputs as a served admission hands them over: q and k in
+    bfloat16 (the route of 1 and 3 MXU passes).  v holds bfloat16 VALUES in
+    float32, so that the operator's output comes back in float32 and can be
+    read at the float32 cases' tolerances (the output takes v's dtype; in
+    bfloat16 it is this one rounded: ``test_the_routes_...``)."""
+    q, k, v, g, beta = x
+    return (q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
+            v.astype(jnp.bfloat16).astype(jnp.float32), g, beta)
+
+
+@pytest.mark.parametrize("t,n,hard", CASES)
+def test_the_chunked_form_is_the_recurrence_on_bfloat16_rows(t, n, hard):
+    """The six cases above with q and k in bfloat16, against the recurrence
+    run in float32 on the same bfloat16 values, AT THE SAME TOLERANCES: the
+    products of 1 and 3 passes lost no bit."""
+    x = bfloat16_rows(scan_inputs(
+        t, seed=t, **(dict(beta=0.999, g=-1e-4) if hard else {})))
+    o, s = gdn.gdn_prefill(*x, None if n is None else jnp.int32(n), chunk=64)
+    m = t if n is None else n
+    want_o, want_s = gdn.recurrence(*(a[:m] for a in x))
+    assert o.dtype == jnp.float32
+    np.testing.assert_allclose(o[:m], want_o, atol=3e-6)
+    np.testing.assert_allclose(s, want_s, atol=3e-5)
+    assert np.abs(np.asarray(want_o)).max() > 0.05
+    assert np.abs(np.asarray(want_s)).max() > 0.5
+
+
+def test_the_hard_triangle_on_bfloat16_rows():
+    """The triangle of the test below with q and k in bfloat16: ``K K^T`` is
+    ONE pass over the raw rows, scaled after, and the recurrence is met at
+    the float32 case's tolerances."""
+    x = bfloat16_rows(scan_inputs(128, seed=3, beta=0.999, g=-1e-4,
+                                  agree=0.9))
+    want_o, want_s = gdn.recurrence(*x)
+    o, s = gdn.gdn_prefill(*x, chunk=64)
+    np.testing.assert_allclose(o, want_o, atol=2e-4)  # (reads 6.6e-5)
+    np.testing.assert_allclose(s, want_s, atol=5e-4)  # (reads 2.6e-4)
+
+
+@pytest.mark.parametrize("mode", ["fallback", "interpret"])
+@pytest.mark.parametrize("per_key", [1, 2, 4])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_routes_by_dtype_and_by_pairs_are_the_recurrence(
+        monkeypatch, dtype, per_key, mode):
+    """Both routes (float32 rows: every product at ``HIGHEST``; bfloat16
+    rows: 1 and 3 passes), value heads singly (``hk == hv``) and in pairs
+    (two and four a key head), the kernel's program and the plain body: 200
+    tokens of which 150 are real, at the float32 tolerances.  And v in
+    bfloat16 is the same output rounded once."""
+    monkeypatch.setenv("DLT_RAGGED_DECODE", mode)
+    x = scan_inputs(200, hk=2, hv=2 * per_key, seed=per_key)
+    if dtype == jnp.bfloat16:
+        x = bfloat16_rows(x)
+    o, s = gdn.gdn_prefill(*x, jnp.int32(150))
+    want_o, want_s = gdn.recurrence(*(a[:150] for a in x))
+    np.testing.assert_allclose(o[:150], want_o, atol=3e-6)
+    np.testing.assert_allclose(s, want_s, atol=3e-5)
+    low = x[:2] + (x[2].astype(jnp.bfloat16),) + x[3:]
+    o16, s16 = gdn.gdn_prefill(*low, jnp.int32(150))
+    if dtype == jnp.bfloat16:  # (float32 v holds other values)
+        np.testing.assert_array_equal(o16, o.astype(jnp.bfloat16))
+        np.testing.assert_array_equal(s16, s)
+    assert o16.dtype == jnp.bfloat16 and s16.dtype == jnp.float32
+
+
+def _dots(fn, *args):
+    """(lhs dtype, rhs dtype, lhs shape, rhs shape) of every ``dot_general``
+    in ``fn``'s jaxpr, the kernel's body and the branches of its ``pl.when``
+    included."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                a, b = (v.aval for v in eqn.invars)
+                found.append((a.dtype, b.dtype, a.shape, b.shape))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def test_the_kernel_asks_for_the_passes_its_operands_need(monkeypatch):
+    """The route is the one reckoned, read off the kernel's own jaxpr (a
+    grid step holds four chunks of 64).  bfloat16 q and k, a PAIR of value
+    heads a key head: the only products of two float32 operands are the
+    triangle's ten, ``[64 x 128] x [128 x 128]`` for both heads, ``T R`` and
+    ``tril(Q K^T o G) V'``; ``K K^T`` and ``Q K^T`` are one product of
+    bfloat16 operands each, ``[K ; Q] S`` and the state's update three (a
+    float32 operand's pieces).  float32 q and k: no bfloat16 operand at
+    all.  ``hk == hv``: the triangles singly, ``[64 x 64]``."""
+    monkeypatch.setenv("DLT_RAGGED_DECODE", "interpret")
+    f32, bf16 = jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)
+    before = METRICS.snapshot()["counters"]
+    took = lambda name: METRICS.snapshot()["counters"].get(
+        name, 0) - before.get(name, 0)
+    chunks = gdn._STEP // 64
+    pair = _dots(gdn.gdn_prefill, *bfloat16_rows(scan_inputs(128)))
+    assert took("ops.gdn_prefill.bf16_operands") == 1
+    assert took("ops.gdn_prefill.paired_heads") == 1
+    assert {d[:2] for d in pair} == {(f32, f32), (bf16, bf16)}
+    full = [d[2:] for d in pair if d[0] == f32]
+    assert sorted(full) == sorted(chunks * (
+        10 * [((64, 128), (128, 128))] + 2 * [((64, 128), (128, 256))]))
+    exact = [d[2:] for d in pair if d[0] == bf16]
+    assert sorted(exact) == sorted(chunks * (
+        2 * [((64, 128), (128, 128))]        # K K^T, Q K^T: once a head
+        + 3 * [((128, 128), (128, 256))]     # [K ; Q] x the state's pieces
+        + 3 * [((128, 64), (64, 256))]))     # K^T x the update's pieces
+    plain = _dots(gdn.gdn_prefill, *scan_inputs(128))
+    assert {d[:2] for d in plain} == {(f32, f32)}
+    assert len(plain) == chunks * 16
+    single = _dots(gdn.gdn_prefill, *bfloat16_rows(scan_inputs(128, hv=2)))
+    assert sorted(d[2:] for d in single if d[0] == f32) == sorted(
+        chunks * (10 * [((64, 64), (64, 64))]
+                  + 2 * [((64, 64), (64, 128))]))
+    assert took("ops.gdn_prefill.bf16_operands") == 2
+    assert took("ops.gdn_prefill.paired_heads") == 2
 
 
 def test_the_triangle_is_solved_by_blocks_and_not_by_one_product():
@@ -338,6 +463,10 @@ def test_the_scan_kernels_in_interpreter_mode_are_the_dense_operator(
     for op in ("gdn_prefill", "gdn_decode"):
         assert took(f"ops.dispatch.{op}.interpret") == 1
         assert took(f"ops.dispatch.{op}.fallback") == 1
+    # float32 rows, two value heads a key head: both traces paired the heads
+    # and neither took the bfloat16 operands' route
+    assert took("ops.gdn_prefill.paired_heads") == 2
+    assert took("ops.gdn_prefill.bf16_operands") == 0
 
 
 # -- (g) the share ties to the model ----------------------------------------

@@ -694,15 +694,26 @@ def gdn_parity(t: int, real: int, hk: int, hv: int, slots: int,
                            maxval=np.log(0.1)))
     x = (q, kk, v, g, beta)
     n = real - 1  # the admission; token n is the decode step's
-    loose = dtype != jnp.float32
+    # The admission is read in float32 whatever ``dtype``: the bfloat16
+    # leg's v goes in as the same VALUES in float32 (the output takes v's
+    # dtype), and is held to the recurrence on those values at the float32
+    # leg's tolerance: q and k in bfloat16 take the products of 1 and 3 MXU
+    # passes, which lose no bit (v20).  As served, in bfloat16, the output is
+    # that one rounded.
+    wide = (q, kk, v.astype(jnp.float32), g, beta)
     with jax.default_matmul_precision("highest"):
-        o, s = jax.jit(G.gdn_prefill)(*x, jnp.int32(n))
+        o, s = jax.jit(G.gdn_prefill)(*wide, jnp.int32(n))
         want_o, want_s = jax.jit(G.recurrence)(*(a[:real] for a in x))
         _, at_n = jax.jit(G.recurrence)(*(a[:n] for a in x))
     tag = f"gdn {jnp.dtype(dtype).name} {real}/{t} h{hk}/{hv}"
-    rt, at = (2e-2, 2e-2) if loose else (1e-4, 1e-5)
-    check(f"{tag} admission", o[:n].astype(jnp.float32), want_o[:n], rt, at)
+    check(f"{tag} admission", o[:n], want_o[:n], 1e-4, 1e-5)
     check(f"{tag} state", s, at_n, 0, 1e-4)
+    if dtype != jnp.float32:
+        served, s16 = jax.jit(G.gdn_prefill)(*x, jnp.int32(n))
+        assert served.dtype == dtype
+        check(f"{tag} admission as served", served[:n].astype(jnp.float32),
+              o[:n].astype(dtype).astype(jnp.float32), 0, 0)
+        check(f"{tag} state as served", s16, s, 0, 0)
     # one step for every slot, the row above in slot 1 and in the last one
     states = jnp.zeros((2, slots, *s.shape), jnp.float32)
     for b in (1, slots - 1):
@@ -939,9 +950,16 @@ def main() -> int:
     # and [512 x 2048], 10 a token; its attention's heads of 256, 16 over 2,
     # both in ONE pool row of 512 lanes, on rows 1 to 151 pages deep; the
     # flash kernel at heads of 256.
+    # v20: the bfloat16 leg at the float32 leg's tolerance, value heads
+    # singly (``hk == hv``) and four a key head (two pairs).
     for leg in (((2048, 1500, 16, 32, 64), (64, 33, 16, 32, 64),
-                 (2048, 1500, 16, 32, 64, jnp.bfloat16)) if ON_TPU else
-                ((300, 260, 2, 4, 3), (8, 5, 2, 4, 3))):
+                 (2048, 1500, 16, 32, 64, jnp.bfloat16),
+                 (2048, 1500, 32, 32, 64, jnp.bfloat16),
+                 (2048, 1500, 8, 32, 64, jnp.bfloat16)) if ON_TPU else
+                ((300, 260, 2, 4, 3), (8, 5, 2, 4, 3),
+                 (300, 260, 2, 4, 3, jnp.bfloat16),
+                 (300, 260, 2, 2, 3, jnp.bfloat16),
+                 (300, 260, 1, 4, 3, jnp.bfloat16))):
         gdn_parity(*leg)
     if ON_TPU:
         moe_parity(e=128, d=2048, f=512, k=10, of_experts=512)
@@ -1017,8 +1035,11 @@ def main() -> int:
     # triangle a chunk) and recurrence step at Qwen3-Next's heads, float32
     # and bfloat16, the smallest bucket; its 128 of 512 experts of 3.1 M;
     # the paged kernel at heads of 256 in one pool row of 512 lanes; the
-    # flash kernel at heads of 256 — 82 legs.
-    print(f"kernel_parity: ALL PASS v19 ({mode}, backend={backend})")
+    # flash kernel at heads of 256 — 82 legs.  v20: gdn_prefill's bfloat16
+    # leg (q and k in bfloat16: products of 1 and 3 MXU passes) held to the
+    # recurrence at the float32 leg's tolerance and, as served, to that
+    # output rounded; value heads singly and four a key head — 84 legs.
+    print(f"kernel_parity: ALL PASS v20 ({mode}, backend={backend})")
     return 0
 
 
