@@ -767,6 +767,12 @@ METRIC_DOCS: dict[str, str] = {
     "mla.decode.resident_tokens": "tokens the decoding rows held, summed "
                                   "over decode steps: what the latent decode "
                                   "kernel read a layer",
+    "mla.decode.scored_keys": "keys the latent decode kernel's products "
+                              "covered a layer for those rows, summed over "
+                              "decode steps: their pages in whole blocks of "
+                              "the kernel's body (resident_tokens over it is "
+                              "the share of the products that falls on a key "
+                              "a row holds)",
     "moe.routed_pairs": "(token, expert) pairs routed, summed over the "
                         "expert layers: tokens x experts a token x layers",
     "moe.layer_passes": "expert-layer passes that had a real token (22 a "

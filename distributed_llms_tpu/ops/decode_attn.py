@@ -259,19 +259,177 @@ def _softmax_all_heads(
     _softmax_update(jnp.where(keep, s, _NEG_INF), vb, acc_ref, m_ref, l_ref)
 
 
+def _kernel_latent_paged(
+    lengths_ref,  # scalar-prefetch [B] int32
+    tables_ref,  # scalar-prefetch [B, P] int32: each row's page ids
+    layer_ref,  # scalar-prefetch [1] int32: the layer of the stack to read
+    q_ref,  # [1, H, W]: every head's absorbed query
+    k_hbm,  # [L, NB, BLK, W]: the latent pool where it lies
+    o_ref,  # [1, H, latent]
+    k_buf,  # [SLOTS, run * BLK, W]: a run's rows, the keys of every head
+    sem,  # DMA[SLOTS]
+    slot_ref,  # SMEM[1]
+    acc_ref, m_ref, l_ref,  # [H, latent], [H, 128], [H, 128] float32
+    *, scale: float, blk: int, run: int, latent: int,
+):
+    """:func:`_kernel_paged` for latent pages: the same walk (grid the
+    rows, a row's pages a run of a mebibyte at a time, fetched by the kernel
+    out of the pool where it lies, later runs' copies started before this
+    one is computed on), with a body and an issue of its own.  The other
+    legs are paced by their bytes (7-28 operations a byte); this one
+    multiplies H queries by rows of W lanes, then by ``latent`` of them
+    again (121 operations a byte at 64 heads), and is paced by the
+    instructions it issues: under the shared walk a row took 0.34 us + 0.045
+    a page (its copy started and awaited, a page a turn of a loop) + 1.00 a
+    RUN, whatever the run held, where a page's bytes take 0.10 (PERF.md,
+    PR 63).  Three things differ:
+
+    - the body is ONE online-softmax update over the blocks of a run that
+      hold a page of the row (blocks of :func:`_latent_block_pages`): the
+      number of them picks the program, one a size.  An update costs 0.35
+      us before its first key (the chain from the scores through the rows'
+      maxima to the value product) and 0.8 ns a key, so the live blocks
+      are not walked one update each, which lost to the whole run.  A
+      block's pages past the row's last keep what an earlier run left
+      there: masked out of the scores, and on the value side multiplied by
+      0.0 (the buffers start as zeros).  The arithmetic a (query, key)
+      pair sees is :func:`_softmax_all_heads`' at one KV head;
+    - a run's copies are started and awaited by the powers of two its page
+      count is the sum of, each power straight-line code (0.018 us a page;
+      a wait of n pages is one wait: a DMA semaphore counts what arrived);
+    - the copies run TWO runs ahead, through three buffers: with the next
+      run alone in flight a row's short last run, once its products follow
+      what it holds, ends before the next row's first run has landed."""
+    block = _latent_block_pages(run)
+    slots = k_buf.shape[0]
+    bi, rows = pl.program_id(0), pl.num_programs(0)
+    layer = layer_ref[0]
+    powers = [1 << i for i in reversed(range(run.bit_length()))]
+
+    def pages_of(b):
+        # At least one: a row of length 0 takes one (wholly masked) run.
+        return jnp.clip(
+            pl.cdiv(lengths_ref[b], blk), 1, tables_ref.shape[1])
+
+    def by_powers(pages, fn):
+        """``fn(first, n)`` for the pieces [first, first + n) of ``pages``,
+        n a power of two (static), largest first."""
+        for n in powers:
+            @pl.when(pages & n != 0)
+            def _(n=n):
+                fn(pages - (pages & (2 * n - 1)), n)
+
+    def start(b, j, slot):
+        def pieces(first, n):
+            for r in range(n):
+                pltpu.make_async_copy(
+                    k_hbm.at[layer, tables_ref[b, j * run + first + r]],
+                    k_buf.at[slot, pl.ds((first + r) * blk, blk)],
+                    sem.at[slot]).start()
+        by_powers(jnp.minimum(run, pages_of(b) - j * run), pieces)
+
+    def wait(slot, pages):
+        def pieces(first, n):
+            into = k_buf.at[slot, pl.ds(first * blk, n * blk)]
+            pltpu.make_async_copy(into, into, sem.at[slot]).wait()
+        by_powers(pages, pieces)
+
+    def ahead(b, j, turns: int):
+        """The run ``turns`` after row ``b``'s run ``j`` in the order they
+        are computed on: the row's next, else the next row's first (row
+        ``rows``: there is none)."""
+        for _ in range(turns):
+            more = j + 1 < pl.cdiv(pages_of(jnp.minimum(b, rows - 1)), run)
+            b, j = jnp.where(more, b, b + 1), jnp.where(more, j + 1, 0)
+        return b, j
+
+    def start_ahead(b, j, slot):
+        @pl.when(b < rows)
+        def _():
+            start(b, j, slot)
+
+    @pl.when(bi == 0)
+    def _first():
+        # (see _kernel_paged: never-written VMEM could hold a NaN)
+        k_buf[...] = jnp.zeros_like(k_buf)
+        slot_ref[0] = 0
+        for turns in range(slots - 1):
+            start_ahead(*ahead(0, 0, turns), turns)
+
+    _softmax_init(acc_ref, m_ref, l_ref)
+    length = lengths_ref[bi]
+    runs = pl.cdiv(pages_of(bi), run)
+    qa = q_ref[0]
+
+    def one_run(j, slot):
+        start_ahead(*ahead(bi, j, slots - 1), (slot + slots - 1) % slots)
+        live = jnp.minimum(run, pages_of(bi) - j * run)
+        wait(slot, live)
+        jax.lax.switch(pl.cdiv(live, block) - 1, [
+            functools.partial(
+                _latent_update, qa, k_buf.at[slot], acc_ref, m_ref, l_ref,
+                n=k * block * blk, latent=latent, first_key=j * (run * blk),
+                length=length, scale=scale)
+            for k in range(1, run // block + 1)])
+        return (slot + 1) % slots
+
+    slot_ref[0] = jax.lax.fori_loop(0, runs, one_run, slot_ref[0])
+    _softmax_done(o_ref, acc_ref, l_ref)
+
+
+def _lanes(x, n: int):
+    """[H, 128] with a row's value in every lane, as [H, n]."""
+    if n % 128 == 0:
+        return pltpu.repeat(x, n // 128, 1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _latent_update(qa, k_ref, acc_ref, m_ref, l_ref, *, n: int, latent: int,
+                   first_key, length, scale: float):
+    """One online-softmax update of every head's state with the first
+    ``n`` rows of ``k_ref``, keys at positions ``first_key ...``:
+    :func:`_softmax_all_heads`' arithmetic at one KV head, the values the
+    first ``latent`` columns of the keys' rows, with the running maximum
+    and sum read and written as they lie, a row's value in every lane of
+    its [H, 128] scratch row (a column pulled out into a lane vector and
+    put back, twice an update, was 0.10 us of an update's 0.45 before its
+    first key: PERF.md, PR 63)."""
+    s = (
+        jax.lax.dot_general(
+            qa, k_ref[:n, :].astype(qa.dtype), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        * scale
+    )  # [H, n] f32
+    col = first_key + jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    s = jnp.where(col < length, s, _NEG_INF)
+    vb = k_ref[:n, :latent].astype(qa.dtype)
+    m_prev, l_prev = m_ref[...], l_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    safe = jnp.where(m_new <= _NEG_INF * 0.5, 0.0, m_new)
+    p = jnp.exp(s - _lanes(safe, n))
+    alpha = jnp.exp(m_prev - safe)
+    l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * _lanes(alpha, latent) + (
+        jax.lax.dot_general(
+            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    )
+    m_ref[...] = m_new
+
+
 def _kernel_paged(
     lengths_ref,  # scalar-prefetch [B] int32
     tables_ref,  # scalar-prefetch [B, P] int32: each row's page ids
     layer_ref,  # scalar-prefetch [1] int32: the layer of the stack to read
     q_ref,  # [1, Hp, D] (int8 leg: [1, KVH*Gp, D], as _kernel's)
     k_hbm,  # the pool where it lies, never blocked or copied:
-    #   [L, NB, BLK*KVH, D] (int8 leg: [L, NB, BLK, KVH, D]; latent pages:
-    #   [L, NB, BLK, W])
-    *rest,  # v_hbm (not for latent pages: the values are the first
-    #   ``latent`` columns of the keys' rows); int8 leg: [ks_hbm
+    #   [L, NB, BLK*KVH, D] (int8 leg: [L, NB, BLK, KVH, D])
+    *rest,  # v_hbm; int8 leg: [ks_hbm
     #   [NB, BLK, 128] f32 (this layer's), vs_hbm]; then o_ref and the
     #   scratch: k_buf / v_buf [2, run pages' rows, D] ([ks_buf / vs_buf
-    #   [2, run*BLK, 128]]), sem DMA[2], slot SMEM[1], acc [Hp, Dv],
+    #   [2, run*BLK, 128]]), sem DMA[2], slot SMEM[1], acc [Hp, D],
     #   m / l [Hp, 128]
     scale: float,
     blk: int,
@@ -279,8 +437,6 @@ def _kernel_paged(
     kvh: int,
     g: int,  # queries a KV head (int8 leg: padded to eight, _kernel's gp)
     quant: bool = False,
-    latent: int | None = None,  # latent (MLA) pages: one buffer, every
-    #   head reads every row, values = its first ``latent`` columns
     zero_unread: bool = False,  # see _softmax_all_heads (the rings)
 ):
     """Paged variant: grid ``(B,)``, and inside a row a loop over its RUNS
@@ -292,11 +448,10 @@ def _kernel_paged(
     turns of the loop and none for page slots it cannot fill; only pages
     the row holds are read.  The compute is one online-softmax update a
     run: :func:`_softmax_all_heads`, or for int8 pages, whose scales lie a
-    head to a lane, the contiguous kernel's :func:`_softmax_block`."""
-    if latent:
-        o_ref, k_buf, sem, slot_ref, acc_ref, m_ref, l_ref = rest
-        v_buf = k_buf
-    elif quant:
+    head to a lane, the contiguous kernel's :func:`_softmax_block`.
+    (Latent pages have a kernel of their own on the same walk,
+    :func:`_kernel_latent_paged`.)"""
+    if quant:
         (v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem,
          slot_ref, acc_ref, m_ref, l_ref) = rest
     else:
@@ -319,9 +474,8 @@ def _kernel_paged(
         def page(r, carry):
             pg = tables_ref[b, first + r] if start else 0
             into = pl.ds(r * page_rows, page_rows)
-            pairs = [(k_hbm.at[layer, pg], k_buf.at[slot, into])]
-            if not latent:
-                pairs += [(v_hbm.at[layer, pg], v_buf.at[slot, into])]
+            pairs = [(k_hbm.at[layer, pg], k_buf.at[slot, into]),
+                     (v_hbm.at[layer, pg], v_buf.at[slot, into])]
             if quant:
                 into = pl.ds(r * blk, blk)
                 pairs += [(ks_hbm.at[pg], ks_buf.at[slot, into]),
@@ -365,10 +519,6 @@ def _kernel_paged(
             _softmax_block(q_ref, k_buf.at[slot], v_buf.at[slot],
                            (ks_buf.at[slot], vs_buf.at[slot]),
                            acc_ref, m_ref, l_ref, gp=g, **state)
-        elif latent:
-            _softmax_all_heads(q_ref, k_buf.at[slot],
-                               k_buf.at[slot, :, pl.ds(0, latent)],
-                               acc_ref, m_ref, l_ref, g=g, **state)
         else:
             _softmax_all_heads(q_ref, k_buf.at[slot], v_buf.at[slot],
                                acc_ref, m_ref, l_ref, g=g,
@@ -830,8 +980,40 @@ def swa_decode_attention(
 def _latent_run_pages(blk: int, w: int, dtype, p: int) -> int:
     """:func:`_run_pages` for latent pages, which have one buffer where
     keys and values have two: a mebibyte of rows (12 pages of 64 x 640
-    bf16), at most the ``p`` a row can hold."""
-    return max(1, min((1 << 20) // (blk * w * jnp.dtype(dtype).itemsize), p))
+    bf16), at most the ``p`` a row can hold, and whole blocks of
+    ``_LATENT_BLOCK_PAGES`` where it is more than one."""
+    run = max(1, min((1 << 20) // (blk * w * jnp.dtype(dtype).itemsize), p))
+    return run - run % min(run, _LATENT_BLOCK_PAGES)
+
+
+# Pages a block of the latent kernel's body (:func:`_kernel_latent_paged`):
+# 256 keys of a 64-token page, two 128-wide tiles of the matrix unit, three
+# sizes of update a run of 12.  Chosen on the chip among 2, 4 and 6 (2.95,
+# 2.88 and 2.91 us a row: PERF.md, PR 63); a constant of the kernel.
+_LATENT_BLOCK_PAGES = 4
+# Buffers of a run the latent kernel's copies go through: the one computed
+# on and the two runs after it (2.9 MB of VMEM at 12 pages of 64 x 640).
+_LATENT_SLOTS = 3
+
+
+def _latent_block_pages(run: int) -> int:
+    """Pages a block where a run is ``run`` pages: ``_LATENT_BLOCK_PAGES``,
+    or the most under it that divide the run (a run that
+    tools/paged_attn_bench.py names; :func:`_latent_run_pages` gives whole
+    blocks)."""
+    return next(n for n in range(min(run, _LATENT_BLOCK_PAGES), 0, -1)
+                if run % n == 0)
+
+
+def mla_scored_keys(lengths: jax.Array, blk: int, w: int, dtype,
+                    p: int) -> jax.Array:
+    """Keys the latent kernel's products cover for rows of ``lengths``
+    tokens, a layer: the pages a row holds (one at least, ``p`` at most)
+    in whole blocks.  ``lengths`` over it is the share of the products that
+    falls on a key the row holds (``mla.decode.scored_keys``)."""
+    # A run is whole blocks, so a row's blocks are those of its pages.
+    n = _latent_block_pages(_latent_run_pages(blk, w, dtype, p))
+    return pl.cdiv(jnp.clip(pl.cdiv(lengths, blk), 1, p), n) * (n * blk)
 
 
 def mla_paged_decode_attention(
@@ -848,10 +1030,10 @@ def mla_paged_decode_attention(
     form: a token's row [c_kv | k_rope | 0] is the key of EVERY head (one
     KV head, H queries a group) and its first ``latent`` columns are every
     head's value, so a page is fetched once and serves both products.  The
-    walk is :func:`_kernel_paged`'s (the pool left in HBM, a run of about a
-    mebibyte of pages in flight, the next run's copies started before this
-    one is computed on); the body is :func:`_softmax_all_heads` at one KV
-    head.  Returns [B, 1, H, latent]: per head the attention-weighted sum
+    walk is :func:`_kernel_paged`'s (the pool left in HBM, a row's pages
+    fetched a run of about a mebibyte at a time) in a kernel of its own,
+    :func:`_kernel_latent_paged`: one update over the blocks of a run that
+    hold a page of the row, the copies two runs ahead.  Returns [B, 1, H, latent]: per head the attention-weighted sum
     of latents, which the caller multiplies by the head's W_uv.
     Single-device (the format refuses a mesh)."""
     return _mla_paged_impl(
@@ -887,7 +1069,7 @@ def _mla_paged_impl(q, pages, lengths, tables, layer, *, latent: int,
     q_spec = pl.BlockSpec((1, h, w), lambda bi, L, T, Y: (bi, 0, 0))
     out = pl.pallas_call(
         functools.partial(
-            _kernel_paged, scale=scale, blk=blk, run=run, kvh=1, g=h,
+            _kernel_latent_paged, scale=scale, blk=blk, run=run,
             latent=latent,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -897,8 +1079,8 @@ def _mla_paged_impl(q, pages, lengths, tables, layer, *, latent: int,
             out_specs=pl.BlockSpec(
                 (1, h, latent), lambda bi, L, T, Y: (bi, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, run * blk, w), pages.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((_LATENT_SLOTS, run * blk, w), pages.dtype),
+                pltpu.SemaphoreType.DMA((_LATENT_SLOTS,)),
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((h, latent), jnp.float32),
                 pltpu.VMEM((h, 128), jnp.float32),

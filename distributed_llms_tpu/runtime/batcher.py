@@ -91,6 +91,7 @@ from ..core.config import ModelConfig
 from ..core.observability import METRICS, get_logger
 from ..models import kv_cache, model as model_lib
 from ..models.kv_cache import KVCache
+from ..ops import decode_attn
 from ..ops.quant_matmul import live_rows
 from . import constrain as constrain_lib
 from . import sampling
@@ -983,12 +984,22 @@ def _decode_steps(
                 # windowed ones) read this step, the tokens each decoding
                 # row holds, its new one included.  Eighth, with windowed
                 # layers: the min(that, window) of them its ring holds.
+                # Ninth, against latent pages (the eighth a zero, so that
+                # eight stay the rings'): the keys the latent kernel's
+                # products covered for those rows, their pages in whole
+                # blocks (decode_attn.mla_scored_keys).
                 held = jnp.where(active, real_lens + 1, 0)
                 read = [jnp.sum(held, dtype=jnp.int32)[None]]
                 if cfg.swa_layers:
                     read.append(jnp.sum(
                         jnp.minimum(held, cfg.sliding_window),
                         dtype=jnp.int32)[None])
+                else:
+                    scored = decode_attn.mla_scored_keys(
+                        held, cache.k.shape[2], cache.k.shape[3],
+                        cache.k.dtype, tables.shape[1])
+                    read += [jnp.zeros((1,), jnp.int32), jnp.sum(
+                        jnp.where(active, scored, 0), dtype=jnp.int32)[None]]
                 moe = jnp.concatenate([
                     moe, jnp.zeros((6 - moe.shape[0],), jnp.int32), *read])
         else:
@@ -4694,7 +4705,7 @@ class ContinuousBatcher:
         handed out beside its tokens, fetched with them (host values), None
         for any other model.  Four counts; a fifth and a sixth where the
         config holds a chip's share of the experts; a decode chunk against
-        latent pages hands out seven, one against pages and rings eight
+        pages and rings hands out eight, one against latent pages nine
         (_decode_steps)."""
         if stats is None:
             return
@@ -4734,9 +4745,11 @@ class ContinuousBatcher:
         if len(counts) > 6 and self.cfg.swa_layers:  # a decode chunk: the
             # tokens its rows held (full layers' pages; latent pages)
             METRICS.inc("attn.decode.resident_tokens", counts[6])
-        elif len(counts) > 6:
+        elif len(counts) > 6:  # ... and, ninth, the keys the latent
+            # kernel's products covered for them (whole blocks of pages)
             METRICS.inc("mla.decode.resident_tokens", counts[6])
-        if len(counts) > 7:  # ... and of them, those inside the window,
+            METRICS.inc("mla.decode.scored_keys", counts[8])
+        if len(counts) == 8:  # ... and of them, those inside the window,
             # beside the tokens the rows' rings hold room for: the window
             # times the row-steps that decoded, which are the pairs routed
             # over the k choices of every expert layer (no count of its

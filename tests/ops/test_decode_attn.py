@@ -216,6 +216,76 @@ def test_paged_matches_contiguous(monkeypatch, dispatched, b, pool, blk,
     )
 
 
+# The latent (MLA) leg: pages of 8 tokens, 19 page slots a row.  The run the
+# kernel works out is 16 pages (whole blocks of 4 under the 19), so a full
+# row is two runs; 8 is two blocks a run, 6 two blocks of 3 (a run that
+# tools/paged_attn_bench.py names and no block of 4 divides).
+LATENT_SHAPES = {"toy": (8, 256, 128), "64x640": (64, 640, 512)}
+LATENT_SLOTS, LATENT_BLK = 19, 8
+
+
+def _latent_depths(run: int) -> dict:
+    """The depths the walk treats apart, in tokens, by name."""
+    blk = LATENT_BLK
+    block = decode_attn._latent_block_pages(run) * blk
+    return {
+        "empty": 0, "one": 1, "inside-block": block - 3,
+        "block-last": block, "block-next": block + 1,
+        "run-last": run * blk, "run-next": run * blk + 1,
+        "full": LATENT_SLOTS * blk, "shared": 2 * blk + 5,
+    }
+
+
+@pytest.mark.parametrize("case", list(_latent_depths(8)))
+@pytest.mark.parametrize("run", [None, 8, 6])
+@pytest.mark.parametrize("shape", list(LATENT_SHAPES))
+def test_latent_paged_matches_its_dense_form(dispatched, shape, run, case):
+    """``_mla_paged_impl``'s kernel (the interpreter) against its own dense
+    fallback: a row at one of the depths the walk by runs and blocks treats
+    apart, between a full row and a row of one token, read out of layer 2
+    of a 3-layer stack.  Every table slot past a row's depth names a page
+    of NaNs (one fetched, or one dead page's value multiplied by anything
+    but the buffer's leftovers, would show); ``shared``: the row starts
+    with the pages of the row before it.  A row of no token answers zeros
+    and disturbs no neighbour."""
+    h, w, latent = LATENT_SHAPES[shape]
+    blk, pages = LATENT_BLK, LATENT_SLOTS
+    worked_out = decode_attn._latent_run_pages(blk, w, jnp.float32, pages)
+    assert worked_out == 16 and decode_attn._latent_block_pages(6) == 3
+    lengths = [pages * blk, _latent_depths(run or worked_out)[case], 1]
+    b, pool = len(lengths), 3 * pages + 1
+    tables = np.random.RandomState(0).permutation(pool - 1).reshape(b, pages)
+    lanes = (jnp.arange(w) < latent + w // 10).astype(jnp.float32)
+    q = _rand(0, (b, 1, h, w)) * lanes
+    rows = _rand(1, (b, pages * blk, w)) * lanes
+    if case == "shared":
+        tables[1, :2] = tables[0, :2]
+        rows = rows.at[1, : 2 * blk].set(rows[0, : 2 * blk])
+    # (A row of no token still takes one wholly masked turn with the page
+    # its first slot names, as every leg of the walk does.)
+    held = np.maximum(-(-np.asarray(lengths) // blk), 1)
+    junk = np.where(np.arange(pages)[None, :] < held[:, None], tables,
+                    pool - 1)
+    page_pool = jnp.zeros((pool, blk, w)).at[tables.reshape(-1)].set(
+        rows.reshape(b * pages, blk, w))
+    call = dict(latent=latent, scale=0.3 * w ** -0.5)
+    args = (jnp.asarray(lengths, jnp.int32),)
+    layer = jnp.asarray([2], jnp.int32)
+    got = decode_attn._mla_paged_impl(
+        q, _stacked(page_pool.at[pool - 1].set(np.nan), 2), *args,
+        jnp.asarray(junk, jnp.int32), layer, mode="interpret", run=run,
+        **call)
+    want = decode_attn._mla_paged_impl(
+        q, _stacked(page_pool, 2), *args, jnp.asarray(tables, jnp.int32),
+        layer, mode="fallback", **call)
+    assert dispatched()["mla_paged_decode.interpret"] == 1
+    assert got.shape == (b, 1, h, latent)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=2e-5, atol=2e-5)
+    assert not np.asarray(got)[~live].any()
+
+
 @pytest.mark.parametrize("stack", STACKS)
 def test_paged_fallback_matches_reference(monkeypatch, dispatched, stack):
     """The dense fallback (untileable head_dim) gathers pages correctly,

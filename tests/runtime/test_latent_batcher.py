@@ -15,6 +15,7 @@ from distributed_llms_tpu.core.observability import METRICS
 from distributed_llms_tpu.models import kv_cache, model as model_lib
 from distributed_llms_tpu.models.presets import get_preset
 from distributed_llms_tpu.models.reference import axk1
+from distributed_llms_tpu.ops import decode_attn
 from distributed_llms_tpu.runtime.batcher import ContinuousBatcher
 from distributed_llms_tpu.runtime.engine import InferenceEngine
 from tools.reference_check import reference_cfg
@@ -30,6 +31,17 @@ def batcher(cfg, params, **kw):
     kw = {"batch_slots": 4, "max_len": 64, "chunk_steps": 4,
           "paged_pages": 24, "page_size": 8, **kw}
     return ContinuousBatcher(cfg, params, **kw)
+
+
+def scored_keys(n, blk, w, dtype, p):
+    """The latent kernel's blocks by hand: a row of ``n`` tokens holds
+    ceil(n / blk) pages (one at least), walked a run at a time, and the
+    products cover each run's live pages in whole blocks."""
+    run = decode_attn._latent_run_pages(blk, w, dtype, p)
+    block = decode_attn._latent_block_pages(run)
+    pages = min(max(-(-n // blk), 1), p)
+    return sum(-(-min(run, pages - first) // block) * block * blk
+               for first in range(0, pages, run))
 
 
 def prompt(n, seed):
@@ -104,7 +116,7 @@ def test_counters_of_a_chips_share(tiny):
     d = {k: after["counters"].get(k, 0) - before.get(k, 0)
          for k in ("moe.routed_pairs", "moe.held_pairs", "moe.layer_passes",
                    "moe.experts_touched", "moe.max_load_tokens",
-                   "mla.decode.resident_tokens")}
+                   "mla.decode.resident_tokens", "mla.decode.scored_keys")}
     real = (5 + 3) + (9 + 6)  # prompt tokens + decoded tokens fed back
     assert d["moe.routed_pairs"] == real * 4 * 3
     assert 0 < d["moe.held_pairs"] < d["moe.routed_pairs"]
@@ -112,9 +124,36 @@ def test_counters_of_a_chips_share(tiny):
     assert 0 < d["moe.experts_touched"] <= 4 * d["moe.layer_passes"]
     assert d["moe.max_load_tokens"] <= d["moe.held_pairs"]
     # Decode steps read rows of 6, 7, 8 and of 10 .. 15 tokens.
-    assert d["mla.decode.resident_tokens"] == (6 + 7 + 8) + sum(range(10, 16))
+    rows = [6, 7, 8, *range(10, 16)]
+    assert d["mla.decode.resident_tokens"] == sum(rows)
+    # ... and the kernel's products covered their pages in whole blocks.
+    assert d["mla.decode.scored_keys"] == sum(
+        scored_keys(n, 8, cfg.latent_width, jnp.float32, b.pages_per_row)
+        for n in rows)
+    assert d["mla.decode.scored_keys"] >= d["mla.decode.resident_tokens"]
     assert after["gauges"]["batcher.latent_page_bytes"] == \
         kv_cache.page_bytes(cfg, 8) == 4 * 8 * 128 * 4
+
+
+@pytest.mark.parametrize("blk,w,dtype,p", [
+    (64, 640, jnp.bfloat16, 64),  # the cell's: runs of 12 pages
+    (64, 640, jnp.bfloat16, 19),  # page slots no run divides
+    (8, 128, jnp.float32, 8),  # the tiny model's: a row is one run
+    (8, 256, jnp.float32, 3),  # fewer slots than a block
+])
+def test_scored_keys_are_the_kernels_blocks(blk, w, dtype, p):
+    """``decode_attn.mla_scored_keys``, which the decode program counts
+    ``mla.decode.scored_keys`` with, is the walk's arithmetic at every
+    depth that walk treats apart, and never under the tokens held."""
+    run = decode_attn._latent_run_pages(blk, w, dtype, p)
+    block = decode_attn._latent_block_pages(run) * blk
+    lengths = sorted({0, 1, block - 1, block, block + 1, run * blk,
+                      run * blk + 1, p * blk - 1, p * blk})
+    got = [int(x) for x in decode_attn.mla_scored_keys(
+        jnp.asarray(lengths, jnp.int32), blk, w, dtype, p)]
+    assert got == [scored_keys(n, blk, w, dtype, p) for n in lengths]
+    assert all(g >= min(n, p * blk) and g % block == 0
+               for g, n in zip(got, lengths))
 
 
 REFUSED = {

@@ -2,8 +2,10 @@
 beside their tokens, which reads them BY POSITION: the experts' four, a
 share's fifth and sixth (the held pairs; those of them whose rows the
 combine fetched singly, PR 58), a decode chunk's one or two of the tokens
-its rows held, a state-space model's three last of all.  Every family's layout in both kinds
-of program, pinned before anyone moves a slot."""
+its rows held (against latent pages a third, the keys the kernel's products
+covered, in the NINTH place behind an empty eighth, so that eight counts stay
+the rings': PR 63), a state-space model's three last of all.  Every family's
+layout in both kinds of program, pinned before anyone moves a slot."""
 
 import dataclasses
 
@@ -19,6 +21,8 @@ from distributed_llms_tpu.runtime.batcher import ContinuousBatcher
 MOE = ["moe.routed_pairs", "moe.layer_passes", "moe.experts_touched",
        "moe.max_load_tokens"]
 SHARE = ["moe.held_pairs", "moe.combine_rows"]
+RINGS = ["attn.decode.resident_tokens", "swa.decode.window_tokens",
+         "swa.decode.ring_tokens"]
 SSM = ["ssm.admit.tokens", "ssm.admit.chunks", "ssm.decode.row_steps"]
 # family -> (preset, experts held of its experts, an admission's counters in
 # the order of its array, a decode chunk's)
@@ -27,7 +31,8 @@ FAMILIES = {
     "share": ("lfm2-tiny", 4, MOE + SHARE, MOE + SHARE),
     "latent-pages": (
         "ax-k1-tiny", 4, MOE + SHARE,
-        MOE + SHARE + ["mla.decode.resident_tokens"]),
+        MOE + SHARE + ["mla.decode.resident_tokens", None,
+                       "mla.decode.scored_keys"]),
     "pages-and-rings": (
         "k-exaone-tiny", 4, MOE + SHARE,
         MOE + SHARE + ["attn.decode.resident_tokens",
@@ -79,8 +84,13 @@ def test_every_familys_layout_in_an_admission_and_a_decode_chunk(family):
         if "swa.decode.window_tokens" in names:
             values[0] = 0  # (ring_tokens is reckoned from the routed pairs)
         note(np.asarray(values, np.int32))
-        got = _delta(before, names)
-        assert [got[n] for n in names] == values
+        counted = [n for n in names if n]  # (None: a place nobody reads)
+        got = _delta(before, counted + RINGS)
+        assert [got[n] for n in counted] == [
+            v for n, v in zip(names, values) if n]
+        # ... and on no other family's: the rings' counters move for pages
+        # and rings alone, whatever the others hand out.
+        assert all(got[n] == 0 for n in RINGS if n not in names)
 
 
 

@@ -370,16 +370,27 @@ def swa_parity(w: int = 128, h: int = 64, kvh: int = 8, d: int = 128,
 
 
 def mla_paged_parity(blk: int = 64, h: int = 64, latent: int = 512,
-                     rope: int = 64, layer: int = 2) -> None:
+                     rope: int = 64, layer: int = 2,
+                     block_edges: bool = False) -> None:
     """The latent decode kernel at A.X-K1's shapes: 64 heads against page
     rows of 640 lanes (latent 512, the shared rotated key 64, 64 zeros),
     values the first 512 columns of the same rows, layer 2 of a 3-layer
     stack.  The rows are :func:`_paged_case`'s (length 1, inside a run, on
     a run's last slot, on the next run's first key, full, one page that
-    shares the pages of the row before it), junk ids name a NaN page."""
+    shares the pages of the row before it), junk ids name a NaN page.
+    ``block_edges``: the depths the kernel's update over a run's live
+    BLOCKS treats apart instead: a block's last key, the next block's
+    first, a last run of one page, a block's last key in the second run,
+    the last key before a run's last block, one page."""
     w = -(-(latent + rope) // 128) * 128
     run = decode_attn._latent_run_pages(blk, w, jnp.bfloat16, PAGED_SLOTS)
-    ln, tables, junk, pool = _paged_case(blk, 1, w, jnp.bfloat16, run=run)
+    rows = None
+    if block_edges:
+        n = decode_attn._latent_block_pages(run) * blk
+        rows = (PAGED_SLOTS, [n, n + 1, run * blk + 30, run * blk + n,
+                              run * blk - n, blk])
+    ln, tables, junk, pool = _paged_case(blk, 1, w, jnp.bfloat16, run=run,
+                                         rows=rows)
     b, pages = tables.shape
     kq, kr = jax.random.split(jax.random.PRNGKey(11))
     lanes = (jnp.arange(w) < latent + rope).astype(jnp.bfloat16)
@@ -398,7 +409,8 @@ def mla_paged_parity(blk: int = 64, h: int = 64, latent: int = 512,
     want = jnp.einsum("bhs,bsc->bhc", probs.astype(jnp.bfloat16),
                       rows[..., :latent])[:, None]
     check(f"mla paged decode B{b} pool{pool} blk{blk} H{h} W{w} run{run} "
-          f"L3[{layer}]", got, want, rtol=3e-2, atol=3e-2)
+          f"L3[{layer}]{' block edges' if block_edges else ''}", got, want,
+          rtol=3e-2, atol=3e-2)
 
 
 def moe_parity(e: int = 32, d: int = 2048, f: int = 1792, k: int = 4,
@@ -872,6 +884,7 @@ def main() -> int:
     paged_parity(**SERVED_H64, layer=2)
     moe_parity()
     mla_paged_parity()
+    mla_paged_parity(block_edges=True)
     # A chip's share of the experts at A.X-K1's widths (off the chip the
     # interpreter gets the same list at a tenth of the widths).
     moe_parity(e=12, d=7168, f=2048, k=8, of_experts=192) if ON_TPU else \
@@ -1039,7 +1052,10 @@ def main() -> int:
     # leg (q and k in bfloat16: products of 1 and 3 MXU passes) held to the
     # recurrence at the float32 leg's tolerance and, as served, to that
     # output rounded; value heads singly and four a key head — 84 legs.
-    print(f"kernel_parity: ALL PASS v20 ({mode}, backend={backend})")
+    # v21: the latent kernel's rows at the depths its update over a run's
+    # live blocks treats apart (a block's edges, a last run of one page) —
+    # 85 legs.
+    print(f"kernel_parity: ALL PASS v21 ({mode}, backend={backend})")
     return 0
 
 
